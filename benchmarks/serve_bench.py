@@ -30,52 +30,6 @@ import ray_tpu
 from ray_tpu import serve
 
 
-def _compile_cache_ab(seq: int) -> dict:
-    """Replica-restart compile cost on the REAL chip: the same jitted
-    BERT forward in two fresh subprocesses sharing one persistent XLA
-    cache dir — first pays the cold compile, second is what a replica
-    restart pays (VERDICT r3 weak #4 / SURVEY §7.3 'Serve cold starts on
-    TPU')."""
-    import subprocess
-    import tempfile
-    import textwrap
-    cache = tempfile.mkdtemp(prefix="rtpu_serve_cache_")
-    snippet = textwrap.dedent(f"""
-        import time, functools, json
-        import jax, numpy as np
-        jax.config.update("jax_compilation_cache_dir", {cache!r})
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        from ray_tpu.models import bert
-        cfg = bert.PRESETS["bert-base"]()
-        params = bert.init_params(jax.random.key(0), cfg)
-        fn = jax.jit(functools.partial(bert.classify, cfg=cfg))
-        # a batching replica warms one program per batch-size bucket
-        # (serve/batching.py powers of two) — replica readiness pays all
-        # of them
-        t0 = time.perf_counter()
-        for b in (1, 2, 4, 8):
-            np.asarray(fn(params, np.zeros((b, {seq}), np.int32)))
-        print(json.dumps({{"ready_s": round(time.perf_counter()-t0, 2),
-                           "platform": jax.devices()[0].platform}}))
-    """)
-    out = {}
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
-    for phase in ("cold", "hot"):
-        r = subprocess.run([sys.executable, "-c", snippet],
-                           capture_output=True, text=True, timeout=900,
-                           cwd="/", env=env)
-        line = [ln for ln in r.stdout.splitlines() if ln.startswith("{")]
-        if not line:
-            return {"error": (r.stderr or "no output")[-300:]}
-        d = json.loads(line[-1])
-        out[f"{phase}_ready_s"] = d["ready_s"]
-        out["platform"] = d["platform"]
-    out["speedup"] = round(out["cold_ready_s"] / max(out["hot_ready_s"], 1e-9), 1)
-    return out
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--tiny", action="store_true",
@@ -85,9 +39,6 @@ def main() -> None:
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--warm-pool", type=int, default=0,
                     help="prestart N workers (warm pool) before serving")
-    ap.add_argument("--compile-cache-ab", action="store_true",
-                    help="also measure cold vs hot persistent-XLA-cache "
-                         "replica compile on the attached chip")
     ap.add_argument("--quick", action="store_true",
                     help="CI scale: implies --tiny, small request budget")
     ap.add_argument("--json", dest="json_path",
@@ -247,10 +198,6 @@ def main() -> None:
               "error": str(e)[:200]})
 
     ray_tpu.shutdown()
-
-    if args.compile_cache_ab:
-        emit({"metric": "serve_replica_compile_cache_ab",
-              **_compile_cache_ab(args.seq)})
 
     if args.json_path:
         os.makedirs(os.path.dirname(args.json_path) or ".", exist_ok=True)

@@ -43,18 +43,23 @@ _head = None  # GcsServer when this process started the cluster
 
 
 def _detect_tpu_chips() -> float:
-    """Count local TPU chips without initializing JAX eagerly on workers."""
+    """Count local TPU chips WITHOUT starting a JAX backend.
+
+    One process holds a chip: a driver that called ``jax.devices()`` to
+    count would own it for life, and the TPU worker spawned for the
+    trainer could never open it.  So the count is of the chips' device
+    nodes (``/dev/accel<N>`` up to v4, the ``/dev/vfio/<N>`` groups
+    since v5e), or ``RTPU_NUM_TPUS``.  Not of PCI functions: a machine
+    that was handed one chip of a four-chip host still lists all four
+    (seen on the v5e, PR 22) but has a node only for its own."""
     override = os.environ.get("RTPU_NUM_TPUS")
     if override is not None:
         return float(override)
     if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
         return 0.0
-    try:
-        import jax
-        return float(len([d for d in jax.devices()
-                          if d.platform not in ("cpu",)]))
-    except Exception:  # noqa: BLE001 - no TPU runtime present
-        return 0.0
+    import glob
+    return float(len(glob.glob("/dev/accel[0-9]*"))
+                 or len(glob.glob("/dev/vfio/[0-9]*")))
 
 
 def init(address: Optional[str] = None, *,
@@ -76,17 +81,18 @@ def init(address: Optional[str] = None, *,
             raise RuntimeError("ray_tpu.init() called twice "
                                "(pass ignore_reinit_error=True to allow)")
         GLOBAL_CONFIG.apply_system_config(_system_config)
-        # persistent XLA compile cache for the driver process too;
-        # effective even if jax is already imported (config knob),
-        # harmless when no TPU is attached
+        # persistent XLA compile cache for the driver process too.  A
+        # jax imported earlier read the variable then: hand it the
+        # directory only if it has none (one placed from outside, by the
+        # variable or by jax.config, is never replaced)
         GLOBAL_CONFIG.apply_xla_cache_env(os.environ)
-        if GLOBAL_CONFIG.xla_cache_dir and "jax" in sys.modules:
-            try:
-                sys.modules["jax"].config.update(
-                    "jax_compilation_cache_dir",
-                    GLOBAL_CONFIG.xla_cache_dir)
-            except Exception:  # noqa: BLE001 - best effort
-                pass
+        jax_mod = sys.modules.get("jax")
+        cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        if jax_mod is not None and cache_dir:
+            if jax_mod.config.jax_compilation_cache_dir is None:
+                jax_mod.config.update("jax_compilation_cache_dir", cache_dir)
+            jax_mod.config.update(
+                "jax_include_full_tracebacks_in_locations", False)
         from ray_tpu._private.gcs import GcsServer
 
         if address is None or address == "local":

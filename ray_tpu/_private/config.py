@@ -201,11 +201,16 @@ _FLAG_DEFS = [
           "Device-holding worker processes per node (concurrent jax inits "
           "contend for the same chips; raise only with per-worker chip "
           "partitioning, e.g. TPU_VISIBLE_DEVICES plumbing)."),
-    _flag("xla_cache_dir", "/tmp/rtpu_xla_cache",
+    _flag("xla_cache_dir",
+          # a fixed path in the checkout (git-ignored): the path is part
+          # of the cache's key, so a directory that moves never hits
+          os.path.abspath(os.path.join(
+              os.path.dirname(__file__), "..", "..", ".xla_cache")),
           "Persistent XLA compilation cache shared across sessions and "
           "worker restarts (SURVEY.md §7.3: big-model compiles take "
           "minutes; Serve replica restarts and trainer elastic restarts "
-          "must not pay them again).  '' disables."),
+          "must not pay them again).  JAX_COMPILATION_CACHE_DIR, when "
+          "set, is used instead.  '' disables."),
     # --- wire protocol -------------------------------------------------------
     _flag("proto_min_version", 0,
           "Minimum control-plane wire version the GCS accepts (0 = legacy "
@@ -404,6 +409,14 @@ class RayTpuConfig:
         env-var spelling."""
         if self.xla_cache_dir:
             env.setdefault("JAX_COMPILATION_CACHE_DIR", self.xla_cache_dir)
+        if env.get("JAX_COMPILATION_CACHE_DIR"):
+            # A Pallas kernel's payload keeps its MLIR locations in the
+            # cache key, and with full tracebacks those name the whole
+            # Python call path: the one train step got one key from a
+            # script and another from a trainer's worker, and neither
+            # found the other's entry (seen on the v5e, PR 22).
+            env.setdefault("JAX_INCLUDE_FULL_TRACEBACKS_IN_LOCATIONS",
+                           "false")
 
     def to_env(self) -> Dict[str, str]:
         """Encode the resolved config as RTPU_* env vars for child processes."""

@@ -248,11 +248,8 @@ class ElasticKv:
 def _clear_jax_backends() -> None:
     """Forget the cached XLA clients so the next ``jax.distributed
     .initialize`` is legal in this same process (the re-mesh enabling
-    trick; jax >= 0.4.36 moved it under jax.extend)."""
-    try:
-        from jax.extend.backend import clear_backends
-    except ImportError:  # pragma: no cover - older jax spelling
-        from jax import clear_backends  # type: ignore[attr-defined]
+    trick)."""
+    from jax.extend.backend import clear_backends
     clear_backends()
 
 

@@ -163,7 +163,11 @@ def _layer_norm(x, scale, bias, eps=1e-5):
     # flagship bench shape; step-level impact there is ~neutral — XLA was
     # already fusing LN into neighbors — but the pinned layout keeps the
     # trace legible and protects shapes where XLA picks T-minor).
-    if x.shape[-1] % 128 == 0:
+    # Under GSPMD on several devices (the final norm after the manual
+    # region, every norm of the plain _block path) XLA's own LN runs:
+    # a Mosaic kernel cannot be partitioned automatically.
+    from ray_tpu.parallel import mesh as mesh_lib
+    if x.shape[-1] % 128 == 0 and not mesh_lib.under_gspmd():
         from ray_tpu.ops.layer_norm import layer_norm
         return layer_norm(x, scale, bias, eps)
     x32 = x.astype(jnp.float32)
@@ -289,10 +293,8 @@ def _manual_parallel_axes(cfg: GPT2Config, mesh, seq_len: int):
     sp, tp = model_parallel_sizes(mesh)
     if sp * tp == 1:
         return None
-    from ray_tpu._private.jax_compat import shard_map_available
     impl = resolved_attn_impl(cfg)
-    ok = (shard_map_available()
-          and shape.get("context", 1) == 1
+    ok = (shape.get("context", 1) == 1
           and shape.get("pipeline", 1) == 1
           and impl not in ("ring", "ulysses")
           and cfg.n_head % tp == 0
@@ -302,7 +304,7 @@ def _manual_parallel_axes(cfg: GPT2Config, mesh, seq_len: int):
         if sp > 1:
             raise ValueError(
                 f"mesh has seq={sp} but the sequence-parallel region "
-                f"cannot run: needs shard_map, context=pipeline=1, a "
+                f"cannot run: needs context=pipeline=1, a "
                 f"non-ring/ulysses attn_impl (have {impl!r}), heads/"
                 f"embed divisible by tensor={tp}, and seq_len "
                 f"({seq_len}) divisible by seq*tensor ({sp * tp})")
@@ -410,7 +412,7 @@ def forward_hidden(params: Params, tokens: jax.Array,
         # projection a decomposed collective matmul.  x enters/leaves
         # per-shard as (B_local, T/(sp·tp), E).
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from ray_tpu._private.jax_compat import shard_map
+        from jax import shard_map
         sp, tp = manual
         xspec = P(("data", "fsdp"), ("seq", "tensor"), None)
         block = shard_map(

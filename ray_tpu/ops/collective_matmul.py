@@ -1,6 +1,6 @@
 """Decomposed sharded matmuls: collective legs hidden behind compute.
 
-The MFU plateau (BENCH_r04→r05: 0.505→0.508 with ``mfu_vs_delivered``
+The MFU plateau (driver runs r04→r05: 0.505→0.508 with ``mfu_vs_delivered``
 0.64) is unoverlapped collectives: GSPMD materializes a model-parallel
 matmul as ``all-gather → one big matmul`` or ``one big matmul → psum /
 reduce-scatter``, and the collective leg serializes against the compute

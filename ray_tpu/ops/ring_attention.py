@@ -86,7 +86,7 @@ def ring_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array, *,
              head_axis if head_axis in mesh.shape else None, None)
     inner = partial(ring_attention, axis_name=axis_name,
                     axis_size=axis_size, causal=causal)
-    from ray_tpu._private.jax_compat import shard_map
+    from jax import shard_map
     return shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
                      out_specs=spec, check_vma=False)(q, k, v)
 

@@ -60,7 +60,7 @@ def ulysses_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array, *,
     spec = P(tuple(a for a in batch_axes if a in mesh.shape), axis_name,
              None, None)
     inner = partial(ulysses_attention, axis_name=axis_name, causal=causal)
-    from ray_tpu._private.jax_compat import shard_map
+    from jax import shard_map
     return shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
                      out_specs=spec, check_vma=False)(q, k, v)
 

@@ -115,6 +115,17 @@ def ambient_mesh(mesh: Mesh):
         _AMBIENT_MESH.reset(token)
 
 
+def under_gspmd() -> bool:
+    """True where a traced op will be partitioned by GSPMD over several
+    devices: the ambient mesh has more than one and no shard_map region
+    encloses the trace.  Mosaic (Pallas TPU) kernels ask before they are
+    placed — the compiler cannot partition one automatically."""
+    mesh = get_ambient_mesh()
+    if mesh is None or mesh.size == 1:
+        return False
+    return not jax.sharding.get_abstract_mesh().manual_axes
+
+
 def build_mesh(config: MeshConfig,
                devices: Optional[Sequence[Any]] = None) -> Mesh:
     """Assemble a ``jax.sharding.Mesh`` with the canonical axis names.
@@ -175,8 +186,7 @@ TRANSFORMER_RULES: Rules = [
 ]
 
 
-# Logical ACTIVATION axis → mesh axis (SNIPPETS.md [3] lineage: the
-# sharding-rules table whose ``"seq": None  # TODO`` this fills).  Params
+# Logical ACTIVATION axis → mesh axis.  Params
 # are matched by the regex Rules above; intermediate activations are
 # placed by logical-axis name through :func:`activation_spec`.  A value
 # may be one mesh axis, a tuple of mesh axes (the dim shards over their
@@ -307,16 +317,35 @@ def param_specs(params: Any, rules: Rules = TRANSFORMER_RULES,
     return jax.tree_util.tree_map(leaf, paths, params)
 
 
-def named_shardings(mesh: Mesh, specs: Any) -> Any:
+def _fit_spec(mesh: Mesh, spec: P, shape: Tuple[int, ...]) -> P:
+    """``spec`` without the entries whose mesh-axis product does not
+    divide the dim they shard (that dim is then replicated)."""
+    parts = []
+    for dim, axes in zip(shape, tuple(spec)):
+        group = axes if isinstance(axes, tuple) else (axes,)
+        total = math.prod(mesh.shape[a] for a in group if a is not None)
+        parts.append(axes if dim % total == 0 else None)
+    return P(*parts)
+
+
+def named_shardings(mesh: Mesh, specs: Any, like: Any = None) -> Any:
+    """NamedShardings for a pytree of PartitionSpecs.  With ``like`` (the
+    matching pytree of arrays or shapes) each spec is first fitted to its
+    leaf: jit and device_put refuse an uneven sharding, and a rule table
+    cannot know that GPT-2's 50,257-row embedding has no even split."""
+    is_spec = lambda x: isinstance(x, P)  # noqa: E731
+    if like is None:
+        return jax.tree_util.tree_map(
+            lambda s: NamedSharding(mesh, s), specs, is_leaf=is_spec)
     return jax.tree_util.tree_map(
-        lambda s: NamedSharding(mesh, s), specs,
-        is_leaf=lambda x: isinstance(x, P))
+        lambda s, x: NamedSharding(mesh, _fit_spec(mesh, s, x.shape)),
+        specs, like, is_leaf=is_spec)
 
 
 def shard_params(mesh: Mesh, params: Any,
                  rules: Rules = TRANSFORMER_RULES) -> Any:
     """Place a host pytree onto the mesh per the rules (lazy, via device_put)."""
-    shardings = named_shardings(mesh, param_specs(params, rules))
+    shardings = named_shardings(mesh, param_specs(params, rules), params)
     return jax.device_put(params, shardings)
 
 

@@ -71,29 +71,10 @@ def initialize(coordinator_address: str, num_processes: int,
                 or "").split(",")[0]
     if platform in ("cpu", ""):
         if local_device_count:
-            try:
-                jax.config.update("jax_num_cpu_devices",
-                                  int(local_device_count))
-            except AttributeError:
-                # older jaxlib (≤0.4.x): the only device-count knob is the
-                # XLA flag, honored because the backend isn't built yet
-                flag = ("--xla_force_host_platform_device_count="
-                        f"{int(local_device_count)}")
-                kept = [f for f in os.environ.get("XLA_FLAGS", "").split()
-                        if not f.startswith(
-                            "--xla_force_host_platform_device_count")]
-                os.environ["XLA_FLAGS"] = " ".join(kept + [flag])
+            jax.config.update("jax_num_cpu_devices", int(local_device_count))
         if cpu_collectives:
-            try:
-                jax.config.update("jax_cpu_collectives_implementation",
-                                  cpu_collectives)
-            except AttributeError:
-                if cpu_collectives == "gloo":
-                    try:  # pre-rename spelling of the same knob
-                        jax.config.update("jax_cpu_enable_gloo_collectives",
-                                          True)
-                    except AttributeError:
-                        pass
+            jax.config.update("jax_cpu_collectives_implementation",
+                              cpu_collectives)
     kw: dict = dict(coordinator_address=coordinator_address,
                     num_processes=num_processes, process_id=process_id)
     if init_timeout_s is not None:

@@ -6,7 +6,7 @@ with f32 state resident per replica and DDP syncing grads at runtime.  On a
 16GB-HBM TPU chip the optimizer state IS the capacity wall: f32 Adam moments
 for GPT-2-1.5B are 12.5GB alone, and the optimizer phase of the train step
 is HBM-bandwidth-floored (15.1ms of f32 state traffic at the flagship bench
-config, benchmarks/results/step_breakdown_r03.md).  Storing moments in bf16
+config in the r3 device trace).  Storing moments in bf16
 halves both the footprint and the traffic; the update MATH stays f32 — the
 storage dtype only bounds what survives between steps.
 

@@ -143,7 +143,7 @@ def pipeline_apply(
         return jax.lax.all_gather(ys, axis)[S - 1]
 
     pspec = jax.tree_util.tree_map(lambda _: P(axis), stage_params)
-    from ray_tpu._private.jax_compat import shard_map
+    from jax import shard_map
     out = shard_map(
         per_shard, mesh=mesh,
         in_specs=(pspec, P()), out_specs=P(),

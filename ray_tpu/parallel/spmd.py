@@ -94,6 +94,9 @@ class SpmdProgram:
     step_fn: Callable[[TrainState, Any], Tuple[TrainState, Dict[str, jax.Array]]]
     state_shardings: Any
     batch_sharding: Any
+    # the jax.jit object behind step_fn, for .lower()/.compile() (what
+    # the step was compiled to: kernels, collectives, memory)
+    jitted_step: Any = None
 
 
 def build_train_program(
@@ -140,8 +143,10 @@ def build_train_program(
                    opt_state=abstract_state.opt_state), rules)
     state_sh = TrainState(
         step=NamedSharding(mesh, P()),
-        params=mesh_lib.named_shardings(mesh, specs.params),
-        opt_state=mesh_lib.named_shardings(mesh, specs.opt_state))
+        params=mesh_lib.named_shardings(mesh, specs.params,
+                                        abstract_params),
+        opt_state=mesh_lib.named_shardings(mesh, specs.opt_state,
+                                           abstract_state.opt_state))
     batch_sh = NamedSharding(mesh, mesh_lib.batch_spec(mesh_config, batch_rank))
 
     def _init(rng: jax.Array) -> TrainState:
@@ -239,7 +244,7 @@ def build_train_program(
 
     return SpmdProgram(mesh=mesh, mesh_config=mesh_config, init_fn=init_fn,
                        step_fn=guarded_step, state_shardings=state_sh,
-                       batch_sharding=batch_sh)
+                       batch_sharding=batch_sh, jitted_step=step_fn)
 
 
 def shard_batch(program: SpmdProgram, batch: Any) -> Any:

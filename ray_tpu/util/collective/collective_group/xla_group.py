@@ -83,7 +83,7 @@ class XlaCollectiveGroup:
         else:
             raise ValueError(kind)
 
-        from ray_tpu._private.jax_compat import shard_map
+        from jax import shard_map
         fn = shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec)
         return jax.jit(fn)
 

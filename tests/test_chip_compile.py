@@ -1,0 +1,94 @@
+"""The main path's Pallas kernels compile for a TPU v5e, at real widths.
+
+No chip is attached: the TPU compiler installed with jax compiles for a
+*described* ``v5e:2x2`` device (on-chip-measurement guide, section 2), so
+what Mosaic refuses — a misaligned slice, too much VMEM — fails here and
+costs no chip time.  Nothing runs; a compile that passes is not a chip
+run.  The whole GPT-2-124M step (~20 s) is left to ``chip_smoke.py``.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from ray_tpu.ops.flash_attention import flash_attention  # noqa: E402
+from ray_tpu.ops.layer_norm import layer_norm  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Sharding on one described v5e chip; the persistent compile cache
+    is off meanwhile (an entry written for an absent chip cannot be read
+    back, and the next compile would warn about it)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e!r}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, sharding, *shapes_dtypes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes_dtypes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text        # Mosaic, not interpret mode
+    return text
+
+
+def _flash(q, k, v):
+    return flash_attention(q, k, v, True, None, False)
+
+
+def _flash_loss(q, k, v):
+    return _flash(q, k, v).astype(jnp.float32).sum()
+
+
+def _ln(x, scale, bias):
+    return layer_norm(x, scale, bias, 1e-5, False)
+
+
+def _ln_loss(x, scale, bias):
+    return _ln(x, scale, bias).astype(jnp.float32).sum()
+
+
+TRAIN_QKV = ((32, 1024, 12, 64), jnp.bfloat16)
+
+
+@pytest.mark.parametrize("fn", [
+    pytest.param(_flash, id="forward"),
+    pytest.param(jax.grad(_flash_loss, argnums=(0, 1, 2)),
+                 id="forward_backward"),
+])
+def test_flash_attention_at_train_shape(v5e, fn):
+    _compile(fn, v5e, TRAIN_QKV, TRAIN_QKV, TRAIN_QKV)
+
+
+@pytest.mark.parametrize("bucket,dtype", [(64, jnp.bfloat16),
+                                          (512, jnp.float32)])
+def test_flash_attention_at_prefill_bucket(v5e, bucket, dtype):
+    qkv = ((1, bucket, 12, 64), dtype)
+    _compile(_flash, v5e, qkv, qkv, qkv)
+
+
+@pytest.mark.parametrize("fn,shape", [
+    pytest.param(_ln, (32, 1024, 768), id="train_forward"),
+    pytest.param(jax.grad(_ln_loss, argnums=(0, 1, 2)), (32, 1024, 768),
+                 id="train_forward_backward"),
+    pytest.param(_ln, (4, 1, 768), id="decode_forward"),
+])
+def test_layer_norm(v5e, fn, shape):
+    affine = ((768,), jnp.float32)
+    _compile(fn, v5e, (shape, jnp.bfloat16), affine, affine)

@@ -19,13 +19,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu._private.jax_compat import shard_map, shard_map_available
 from ray_tpu.ops import collective_matmul as cm
-
-pytestmark = pytest.mark.skipif(not shard_map_available(),
-                                reason="no shard_map in this jax build")
 
 
 def _ring_mesh(n: int) -> Mesh:

@@ -15,13 +15,6 @@ from ray_tpu.ops import (blockwise_attention, dense_attention,
                          flash_attention, ring_attention_sharded,
                          ulysses_attention_sharded)
 
-from ray_tpu._private.jax_compat import shard_map_available
-
-needs_shard_map = pytest.mark.skipif(
-    not shard_map_available(),
-    reason="no jax.shard_map or jax.experimental.shard_map in this "
-           "jax build (ring/ulysses attention lower through shard_map)")
-
 B, T, H, D = 2, 64, 4, 16
 
 
@@ -96,7 +89,6 @@ def test_flash_grads(qkv, causal):
 
 
 @pytest.mark.parametrize("causal", [True, False])
-@needs_shard_map
 def test_ring_attention_matches_dense(qkv, mesh, causal):
     q, k, v = qkv
     ref = dense_attention(q, k, v, causal=causal)
@@ -105,7 +97,6 @@ def test_ring_attention_matches_dense(qkv, mesh, causal):
     _allclose(out, ref)
 
 
-@needs_shard_map
 def test_ring_attention_grads(qkv, mesh):
     q, k, v = qkv
 
@@ -122,7 +113,6 @@ def test_ring_attention_grads(qkv, mesh):
 
 
 @pytest.mark.parametrize("causal", [True, False])
-@needs_shard_map
 def test_ulysses_matches_dense(qkv, mesh, causal):
     q, k, v = qkv
     ref = dense_attention(q, k, v, causal=causal)
@@ -131,7 +121,6 @@ def test_ulysses_matches_dense(qkv, mesh, causal):
     _allclose(out, ref)
 
 
-@needs_shard_map
 def test_ulysses_rejects_indivisible_heads(qkv, mesh):
     q, k, v = qkv
     with pytest.raises(ValueError, match="divisible"):

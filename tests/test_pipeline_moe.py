@@ -9,17 +9,6 @@ import pytest
 from ray_tpu.ops import moe as moe_lib
 from ray_tpu.parallel import mesh as mesh_lib, pipeline as pp
 from ray_tpu.parallel.mesh import MeshConfig
-from ray_tpu._private.jax_compat import partial_shard_map_available
-
-# pipeline_apply runs the pipeline axis manual and every other mesh
-# axis GSPMD-automatic — that partial-manual shard_map only lowers on
-# builds with native jax.shard_map(axis_names=...) (the experimental
-# auto= spelling hits an XLA "PartitionId under SPMD" rejection)
-needs_partial_shard_map = pytest.mark.skipif(
-    not partial_shard_map_available(),
-    reason="no partial-manual shard_map on this jax build "
-           "(jax.shard_map axis_names= missing; experimental auto= "
-           "lowers through PartitionId, rejected by SPMD partitioning)")
 
 
 def _mesh(**axes):
@@ -48,7 +37,6 @@ def _sequential(params, x):
     return h
 
 
-@needs_partial_shard_map
 def test_pipeline_matches_sequential():
     mesh = _mesh(data=2, pipeline=4)
     d, B, L, S = 16, 8, 8, 4
@@ -82,7 +70,6 @@ def test_pipeline_single_stage_path():
                                rtol=2e-5, atol=2e-5)
 
 
-@needs_partial_shard_map
 def test_pipeline_grads_match_sequential():
     mesh = _mesh(pipeline=4, data=2)
     d, B, L, S = 8, 8, 4, 4
@@ -204,7 +191,6 @@ def test_moe_grads_flow():
 
 # ------------------------------------------------------- GPT-2 PP end-to-end
 
-@needs_partial_shard_map
 def test_gpt2_pipeline_forward_matches_scan():
     from ray_tpu.models import gpt2
     mesh = _mesh(data=2, pipeline=4)
@@ -223,7 +209,6 @@ def test_gpt2_pipeline_forward_matches_scan():
                                rtol=2e-4, atol=2e-4)
 
 
-@needs_partial_shard_map
 def test_gpt2_pipeline_train_step():
     """Full fwd+bwd+optimizer over a pp=2,tensor=2,data=2 mesh."""
     from ray_tpu.models import gpt2

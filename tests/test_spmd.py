@@ -96,8 +96,8 @@ def test_gpt2_vocab_chunked_ce_matches_full():
 
 
 def test_seq_activation_rules_filled():
-    """The SNIPPETS.md [3] sharding-rules table's ``"seq": None  # TODO``
-    is filled: sequence-parallel regions shard tokens over the seq axis
+    """The sharding-rules table has no ``"seq": None`` hole:
+    sequence-parallel regions shard tokens over the seq axis
     composed with the tensor group (Megatron-SP), and the helper builds
     the canonical residual-stream spec from logical names."""
     assert mesh_lib.ACTIVATION_RULES["seq"] == ("seq", "tensor")
@@ -279,3 +279,47 @@ def test_tensor_parallel_matches_dp_numerics():
         _, m = prog.step_fn(state, spmd.shard_batch(prog, {"tokens": toks}))
         losses[name] = float(m["loss"])
     assert losses["dp"] == pytest.approx(losses["tp"], rel=2e-3)
+
+
+def test_odd_vocab_trains_on_a_tensor_mesh():
+    """GPT-2's 50,257-row embedding has no even split: a rule's mesh axis
+    that does not divide a leaf's dim is dropped for that leaf (jit
+    refuses an uneven sharding), and the step still runs."""
+    cfg = gpt2.GPT2Config(vocab_size=509, n_positions=32, n_embd=64,
+                          n_layer=2, n_head=4)
+    mc = MeshConfig(data=1, fsdp=2, tensor=2).resolved(4)
+    mesh = mesh_lib.build_mesh(mc, jax.devices()[:4])
+    prog = spmd.build_train_program(
+        loss_fn=lambda p, b: gpt2.loss_fn(p, b, cfg),
+        init_params_fn=lambda rng: gpt2.init_params(rng, cfg),
+        mesh=mesh, mesh_config=mc)
+    assert prog.state_shardings.params["wte"].spec == P(None, "fsdp")
+    assert prog.state_shardings.params["blocks"]["mlp_in"]["kernel"].spec \
+        == P("pipeline", "fsdp", "tensor")
+    state = prog.init_fn(jax.random.key(0))
+    toks = np.random.default_rng(0).integers(0, 509, (4, 33)).astype(np.int32)
+    b = spmd.shard_batch(prog, {"inputs": toks[:, :-1],
+                                "targets": toks[:, 1:]})
+    _, m = prog.step_fn(state, b)
+    assert np.isfinite(float(m["loss"]))
+    assert "wte" in prog.jitted_step.lower(state, b).as_text()
+
+
+def test_under_gspmd_is_false_inside_shard_map():
+    """Kernels ask ``under_gspmd()`` before they are placed: true only on
+    a mesh of several devices outside every shard_map region."""
+    seen = {}
+
+    def body(x):
+        seen["inside"] = mesh_lib.under_gspmd()
+        return x
+
+    assert not mesh_lib.under_gspmd()                   # no ambient mesh
+    with mesh_lib.ambient_mesh(mesh_lib.single_device_mesh()):
+        assert not mesh_lib.under_gspmd()               # one device
+    mesh = mesh_lib.build_mesh(MeshConfig(data=4), jax.devices()[:4])
+    with mesh_lib.ambient_mesh(mesh):
+        assert mesh_lib.under_gspmd()
+        jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                              out_specs=P("data")))(jnp.zeros((4, 2)))
+    assert seen == {"inside": False}
