@@ -156,6 +156,28 @@ def init_params(rng: jax.Array, cfg: GPT2Config) -> Params:
 
 
 # ------------------------------------------------------------------ forward
+# Every layer kind runs under a ``jax.named_scope`` (embed, ln_1, attn_qkv,
+# attn, attn_out, ln_2, mlp, ln_f, lm_head, loss_ce; in the decode step also
+# kv_layout, and cast_weights wherever stored weights are cast to the
+# compute dtype).  Metadata only: the scope path names the HLO operations,
+# and PERF.md section 3 lists the per-layer metric that reads each.
+_scope = jax.named_scope
+
+
+def _cast(w: jax.Array, cfg: "GPT2Config") -> jax.Array:
+    """A stored weight in the compute dtype: a convert only where it is
+    stored wider (the serving engine's float32 weights)."""
+    with _scope("cast_weights"):
+        return w.astype(cfg.dtype)
+
+
+def _embed(params: Params, tokens: jax.Array, positions: jax.Array,
+           cfg: "GPT2Config") -> jax.Array:
+    with _scope("embed"):
+        x = _cast(params["wte"], cfg)[tokens]
+        return x + _cast(params["wpe"], cfg)[positions]
+
+
 def _layer_norm(x, scale, bias, eps=1e-5):
     # Pallas fused LN (ops/layer_norm.py) when the lane tiling allows it:
     # pins the residual stream to its natural E-minor layout and collapses
@@ -232,16 +254,18 @@ def _block(x: jax.Array, lp: Params, cfg: GPT2Config,
     engine's prefill cache fill, so the two paths cannot diverge."""
     B, T, E = x.shape
     H, D = cfg.n_head, cfg.head_dim
-    h = _layer_norm(x, lp["ln_1"]["scale"], lp["ln_1"]["bias"])
-    qkv = jnp.einsum("bte,eck->btck",
-                     h, lp["attn_qkv"]["kernel"].astype(cfg.dtype))
-    qkv = qkv + lp["attn_qkv"]["bias"].astype(cfg.dtype)
-    # Named so remat_policy="attn" can pin it: re-projecting qkv is the one
-    # matmul the rematerialized backward would otherwise re-run (the flash
-    # kernel's q/k/v residuals flow from here).
-    from jax.ad_checkpoint import checkpoint_name
-    qkv = checkpoint_name(qkv, "attn_qkv")
-    q, k, v = [qkv[:, :, i, :].reshape(B, T, H, D) for i in range(3)]
+    with _scope("ln_1"):
+        h = _layer_norm(x, lp["ln_1"]["scale"], lp["ln_1"]["bias"])
+    with _scope("attn_qkv"):
+        qkv = jnp.einsum("bte,eck->btck",
+                         h, _cast(lp["attn_qkv"]["kernel"], cfg))
+        qkv = qkv + _cast(lp["attn_qkv"]["bias"], cfg)
+        # Named so remat_policy="attn" can pin it: re-projecting qkv is
+        # the one matmul the rematerialized backward would otherwise
+        # re-run (the flash kernel's q/k/v residuals flow from here).
+        from jax.ad_checkpoint import checkpoint_name
+        qkv = checkpoint_name(qkv, "attn_qkv")
+        q, k, v = [qkv[:, :, i, :].reshape(B, T, H, D) for i in range(3)]
     # Pin the attention-region layout (DESIGN.md §4q / ACTIVATION_RULES):
     # heads shard over tensor, sequence-through-attention over context
     # (ring CP), per-head features replicated.  No-op without an
@@ -250,20 +274,24 @@ def _block(x: jax.Array, lp: Params, cfg: GPT2Config,
     q = mesh_lib.constrain(q, "batch", "seq_attn", "heads", "kv")
     k = mesh_lib.constrain(k, "batch", "seq_attn", "heads", "kv")
     v = mesh_lib.constrain(v, "batch", "seq_attn", "heads", "kv")
-    a = attn(q, k, v, cfg).reshape(B, T, E)
-    a = a @ lp["attn_out"]["kernel"].astype(cfg.dtype) \
-        + lp["attn_out"]["bias"].astype(cfg.dtype)
-    x = x + a
-    h = _layer_norm(x, lp["ln_2"]["scale"], lp["ln_2"]["bias"])
-    h = h @ lp["mlp_in"]["kernel"].astype(cfg.dtype) \
-        + lp["mlp_in"]["bias"].astype(cfg.dtype)
-    # MLP hidden shards over tensor (Megatron TP): pinned so the gelu
-    # runs on the sharded layout instead of an all-gathered one.
-    h = mesh_lib.constrain(h, "batch", "seq_attn", "mlp")
-    h = jax.nn.gelu(h, approximate=True)
-    h = h @ lp["mlp_out"]["kernel"].astype(cfg.dtype) \
-        + lp["mlp_out"]["bias"].astype(cfg.dtype)
-    out = x + h
+    with _scope("attn"):
+        a = attn(q, k, v, cfg).reshape(B, T, E)
+    with _scope("attn_out"):
+        a = a @ _cast(lp["attn_out"]["kernel"], cfg) \
+            + _cast(lp["attn_out"]["bias"], cfg)
+        x = x + a
+    with _scope("ln_2"):
+        h = _layer_norm(x, lp["ln_2"]["scale"], lp["ln_2"]["bias"])
+    with _scope("mlp"):
+        h = h @ _cast(lp["mlp_in"]["kernel"], cfg) \
+            + _cast(lp["mlp_in"]["bias"], cfg)
+        # MLP hidden shards over tensor (Megatron TP): pinned so the gelu
+        # runs on the sharded layout instead of an all-gathered one.
+        h = mesh_lib.constrain(h, "batch", "seq_attn", "mlp")
+        h = jax.nn.gelu(h, approximate=True)
+        h = h @ _cast(lp["mlp_out"]["kernel"], cfg) \
+            + _cast(lp["mlp_out"]["bias"], cfg)
+        out = x + h
     if collect_kv:
         return out, (k, v)
     return out
@@ -332,43 +360,49 @@ def _block_manual(x: jax.Array, lp: Params, *, cfg: GPT2Config,
     H, D = cfg.n_head, cfg.head_dim
     Hl = H // tp
 
-    h = _layer_norm(x, lp["ln_1"]["scale"], lp["ln_1"]["bias"])
-    wqkv = lp["attn_qkv"]["kernel"].astype(cfg.dtype).reshape(E, 3 * Hl * D)
-    qkv = cm.all_gather_matmul(h, wqkv, "tensor", tp)     # (B, T/sp, 3E/tp)
-    qkv = qkv + lp["attn_qkv"]["bias"].astype(cfg.dtype).reshape(-1)
-    Ts = Tl * tp                                          # = T / sp
-    qkv = qkv.reshape(B, Ts, 3, Hl, D)
-    from jax.ad_checkpoint import checkpoint_name
-    qkv = checkpoint_name(qkv, "attn_qkv")
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    if sp > 1:
-        from ray_tpu.ops.ring_attention import ring_attention
-        a = ring_attention(q, k, v, axis_name="seq", axis_size=sp,
-                           causal=True)
-    elif attn_name == "flash" and _flash_tiles(Ts):
-        from ray_tpu.ops.flash_attention import flash_attention
-        a = flash_attention(q, k, v, True)
-    elif attn_name == "blockwise":
-        from ray_tpu.ops.attention import blockwise_attention
-        a = blockwise_attention(q, k, v, causal=True)
-    else:
-        from ray_tpu.ops.attention import dense_attention
-        a = dense_attention(q, k, v, causal=True)
-    wout = lp["attn_out"]["kernel"].astype(cfg.dtype).reshape(Hl * D, E)
-    aout = cm.matmul_reduce_scatter(a.reshape(B, Ts, Hl * D), wout,
-                                    "tensor", tp)         # (B, Tl, E)
-    # biases ride AFTER the reduce-scatter: inside it they would be
-    # summed tp times
-    x = x + aout + lp["attn_out"]["bias"].astype(cfg.dtype)
+    with _scope("ln_1"):
+        h = _layer_norm(x, lp["ln_1"]["scale"], lp["ln_1"]["bias"])
+    with _scope("attn_qkv"):
+        wqkv = _cast(lp["attn_qkv"]["kernel"], cfg).reshape(E, 3 * Hl * D)
+        qkv = cm.all_gather_matmul(h, wqkv, "tensor", tp)  # (B, T/sp, 3E/tp)
+        qkv = qkv + _cast(lp["attn_qkv"]["bias"], cfg).reshape(-1)
+        Ts = Tl * tp                                       # = T / sp
+        qkv = qkv.reshape(B, Ts, 3, Hl, D)
+        from jax.ad_checkpoint import checkpoint_name
+        qkv = checkpoint_name(qkv, "attn_qkv")
+        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+    with _scope("attn"):
+        if sp > 1:
+            from ray_tpu.ops.ring_attention import ring_attention
+            a = ring_attention(q, k, v, axis_name="seq", axis_size=sp,
+                               causal=True)
+        elif attn_name == "flash" and _flash_tiles(Ts):
+            from ray_tpu.ops.flash_attention import flash_attention
+            a = flash_attention(q, k, v, True)
+        elif attn_name == "blockwise":
+            from ray_tpu.ops.attention import blockwise_attention
+            a = blockwise_attention(q, k, v, causal=True)
+        else:
+            from ray_tpu.ops.attention import dense_attention
+            a = dense_attention(q, k, v, causal=True)
+    with _scope("attn_out"):
+        wout = _cast(lp["attn_out"]["kernel"], cfg).reshape(Hl * D, E)
+        aout = cm.matmul_reduce_scatter(a.reshape(B, Ts, Hl * D), wout,
+                                        "tensor", tp)      # (B, Tl, E)
+        # biases ride AFTER the reduce-scatter: inside it they would be
+        # summed tp times
+        x = x + aout + _cast(lp["attn_out"]["bias"], cfg)
 
-    h = _layer_norm(x, lp["ln_2"]["scale"], lp["ln_2"]["bias"])
-    m = cm.all_gather_matmul(
-        h, lp["mlp_in"]["kernel"].astype(cfg.dtype), "tensor", tp)
-    m = jax.nn.gelu(m + lp["mlp_in"]["bias"].astype(cfg.dtype),
-                    approximate=True)
-    mo = cm.matmul_reduce_scatter(
-        m, lp["mlp_out"]["kernel"].astype(cfg.dtype), "tensor", tp)
-    return x + mo + lp["mlp_out"]["bias"].astype(cfg.dtype)
+    with _scope("ln_2"):
+        h = _layer_norm(x, lp["ln_2"]["scale"], lp["ln_2"]["bias"])
+    with _scope("mlp"):
+        m = cm.all_gather_matmul(
+            h, _cast(lp["mlp_in"]["kernel"], cfg), "tensor", tp)
+        m = jax.nn.gelu(m + _cast(lp["mlp_in"]["bias"], cfg),
+                        approximate=True)
+        mo = cm.matmul_reduce_scatter(
+            m, _cast(lp["mlp_out"]["kernel"], cfg), "tensor", tp)
+        return x + mo + _cast(lp["mlp_out"]["bias"], cfg)
 
 
 def _manual_block_specs(cfg: GPT2Config):
@@ -397,11 +431,10 @@ def forward_hidden(params: Params, tokens: jax.Array,
     """tokens (B, T) int32 → final-LN hidden states (B, T, E) in cfg.dtype."""
     B, T = tokens.shape
     attn = _resolve_attn(cfg)
-    x = params["wte"].astype(cfg.dtype)[tokens]
     # Arrays here are GLOBAL (GSPMD view) even when the sequence dim is
     # sharded over the context axis — only the attention impl drops into
     # shard_map (where chunk offsets come from lax.axis_index).
-    x = x + params["wpe"].astype(cfg.dtype)[jnp.arange(T)]
+    x = _embed(params, tokens, jnp.arange(T), cfg)
 
     from ray_tpu.parallel import mesh as mesh_lib
     amb_mesh = mesh_lib.get_ambient_mesh()
@@ -498,7 +531,8 @@ def forward_hidden(params: Params, tokens: jax.Array,
             mesh=pp_mesh, axis=cfg.pipeline_axis, remat=False))
     else:
         x, _ = lax.scan(scan_body, x, params["blocks"])
-    x = _layer_norm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
+    with _scope("ln_f"):
+        x = _layer_norm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
     return x
 
 
@@ -506,12 +540,13 @@ def forward(params: Params, tokens: jax.Array,
             cfg: GPT2Config) -> jax.Array:
     """tokens (B, T) int32 → logits (B, T, vocab) in f32."""
     x = forward_hidden(params, tokens, cfg)
-    logits = jnp.einsum("bte,ve->btv", x, params["wte"].astype(cfg.dtype))
-    # Vocab dim shards over tensor (the wte is tensor-sharded on vocab):
-    # pinned so the (B, T, V) f32 logits never replicate.
-    from ray_tpu.parallel import mesh as mesh_lib
-    logits = mesh_lib.constrain(logits, "batch", None, "vocab")
-    return logits.astype(jnp.float32)
+    with _scope("lm_head"):
+        logits = jnp.einsum("bte,ve->btv", x, _cast(params["wte"], cfg))
+        # Vocab dim shards over tensor (the wte is tensor-sharded on
+        # vocab): pinned so the (B, T, V) f32 logits never replicate.
+        from ray_tpu.parallel import mesh as mesh_lib
+        logits = mesh_lib.constrain(logits, "batch", None, "vocab")
+        return logits.astype(jnp.float32)
 
 
 def _chunked_ce(x: jax.Array, wte: jax.Array, tgt: jax.Array,
@@ -533,10 +568,13 @@ def _chunked_ce(x: jax.Array, wte: jax.Array, tgt: jax.Array,
     @jax.checkpoint
     def body(acc, chunk):
         xcb, tcb = chunk
-        logits = jnp.einsum("bte,ve->btv", xcb, wte).astype(jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        correct = jnp.take_along_axis(logits, tcb[..., None], -1)[..., 0]
-        return acc + (lse - correct).sum(), None
+        with _scope("lm_head"):
+            logits = jnp.einsum("bte,ve->btv", xcb, wte).astype(jnp.float32)
+        with _scope("loss_ce"):
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            correct = jnp.take_along_axis(logits, tcb[..., None],
+                                          -1)[..., 0]
+            return acc + (lse - correct).sum(), None
 
     total, _ = lax.scan(body, jnp.zeros((), jnp.float32), (xc, tc))
     return total / (B * T)
@@ -568,18 +606,21 @@ def _vocab_chunked_ce(x: jax.Array, wte: jax.Array, tgt: jax.Array,
     def body(carry, chunk):
         run_lse, correct = carry
         w, off = chunk
-        logits = jnp.einsum("bte,ve->btv", x, w).astype(jnp.float32)
-        # mask padded vocab columns out of the reduction
-        valid = (off + jnp.arange(vc_len)) < V
-        logits = jnp.where(valid[None, None, :], logits, -jnp.inf)
-        chunk_lse = jax.nn.logsumexp(logits, axis=-1)
-        run_lse = jnp.logaddexp(run_lse, chunk_lse)
-        local = tgt - off                 # (B, T), may be out of range
-        in_chunk = (local >= 0) & (local < vc_len)
-        got = jnp.take_along_axis(
-            logits, jnp.clip(local, 0, vc_len - 1)[..., None], -1)[..., 0]
-        correct = correct + jnp.where(in_chunk, got, 0.0)
-        return (run_lse, correct), None
+        with _scope("lm_head"):
+            logits = jnp.einsum("bte,ve->btv", x, w).astype(jnp.float32)
+        with _scope("loss_ce"):
+            # mask padded vocab columns out of the reduction
+            valid = (off + jnp.arange(vc_len)) < V
+            logits = jnp.where(valid[None, None, :], logits, -jnp.inf)
+            chunk_lse = jax.nn.logsumexp(logits, axis=-1)
+            run_lse = jnp.logaddexp(run_lse, chunk_lse)
+            local = tgt - off             # (B, T), may be out of range
+            in_chunk = (local >= 0) & (local < vc_len)
+            got = jnp.take_along_axis(
+                logits, jnp.clip(local, 0, vc_len - 1)[..., None],
+                -1)[..., 0]
+            correct = correct + jnp.where(in_chunk, got, 0.0)
+            return (run_lse, correct), None
 
     init = (jnp.full((B, T), -jnp.inf, jnp.float32),
             jnp.zeros((B, T), jnp.float32))
@@ -599,23 +640,25 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array],
         raise ValueError("loss_chunks and loss_vocab_chunks are exclusive")
     if cfg.loss_vocab_chunks:
         x = forward_hidden(params, inp, cfg)
-        return _vocab_chunked_ce(x, params["wte"].astype(cfg.dtype), tgt,
+        return _vocab_chunked_ce(x, _cast(params["wte"], cfg), tgt,
                                  cfg.loss_vocab_chunks)
     if cfg.loss_chunks:
         x = forward_hidden(params, inp, cfg)
-        return _chunked_ce(x, params["wte"].astype(cfg.dtype), tgt,
+        return _chunked_ce(x, _cast(params["wte"], cfg), tgt,
                            cfg.loss_chunks)
     # CE via logsumexp, NOT log_softmax: log_softmax materializes a second
     # (B,T,V) f32 tensor (6.6GB at the flagship bench shape) just to read
     # one element per row.  The correct-class logit is gathered from the
     # bf16 logits so the f32 convert has exactly one consumer (the lse
     # reduce) and XLA fuses it without materializing f32 logits at all
-    # (trace-measured ~14ms/step, benchmarks/step_decompose.py).
+    # (trace-measured ~14ms/step on a v5e at b32/s1024, r3).
     x = forward_hidden(params, inp, cfg)
-    logits = jnp.einsum("bte,ve->btv", x, params["wte"].astype(cfg.dtype))
-    lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
-    correct = jnp.take_along_axis(logits, tgt[..., None], -1)[..., 0]
-    return (lse - correct.astype(jnp.float32)).mean()
+    with _scope("lm_head"):
+        logits = jnp.einsum("bte,ve->btv", x, _cast(params["wte"], cfg))
+    with _scope("loss_ce"):
+        lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+        correct = jnp.take_along_axis(logits, tgt[..., None], -1)[..., 0]
+        return (lse - correct.astype(jnp.float32)).mean()
 
 
 # -------------------------------------------------- inference (KV cache)
@@ -632,20 +675,21 @@ def forward_prefill(params: Params, tokens: jax.Array, cfg: GPT2Config,
     device→host traffic.  None returns the full (B, T, V)."""
     B, T = tokens.shape
     attn = _resolve_attn(cfg)
-    x = params["wte"].astype(cfg.dtype)[tokens]
-    x = x + params["wpe"].astype(cfg.dtype)[jnp.arange(T)]
+    x = _embed(params, tokens, jnp.arange(T), cfg)
 
     def body(carry, lp):
         return _block(carry, lp, cfg, attn, collect_kv=True)
 
     x, (ks, vs) = lax.scan(body, x, params["blocks"])
-    x = _layer_norm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
-    if last_pos is not None:
-        x = lax.dynamic_slice_in_dim(x, last_pos, 1, axis=1)
-    logits = jnp.einsum("bte,ve->btv", x, params["wte"].astype(cfg.dtype))
-    if last_pos is not None:
-        logits = logits[:, 0]
-    return logits.astype(jnp.float32), ks, vs
+    with _scope("ln_f"):
+        x = _layer_norm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
+    with _scope("lm_head"):
+        if last_pos is not None:
+            x = lax.dynamic_slice_in_dim(x, last_pos, 1, axis=1)
+        logits = jnp.einsum("bte,ve->btv", x, _cast(params["wte"], cfg))
+        if last_pos is not None:
+            logits = logits[:, 0]
+        return logits.astype(jnp.float32), ks, vs
 
 
 def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
@@ -664,40 +708,48 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
     from ray_tpu.ops.paged_attention import paged_attention_decode
     B = tokens.shape[0]
     E, H, D = cfg.n_embd, cfg.n_head, cfg.head_dim
-    x = params["wte"].astype(cfg.dtype)[tokens]
-    x = x + params["wpe"].astype(cfg.dtype)[positions]          # (B, E)
-    # (N, L, 2, bs, H, D) → per-layer pools (L, N, bs, H, D)
-    k_pools = kv_pool[:, :, 0].transpose(1, 0, 2, 3, 4)
-    v_pools = kv_pool[:, :, 1].transpose(1, 0, 2, 3, 4)
+    x = _embed(params, tokens, positions, cfg)                  # (B, E)
+    with _scope("kv_layout"):
+        # (N, L, 2, bs, H, D) → per-layer pools (L, N, bs, H, D)
+        k_pools = kv_pool[:, :, 0].transpose(1, 0, 2, 3, 4)
+        v_pools = kv_pool[:, :, 1].transpose(1, 0, 2, 3, 4)
 
     def body(carry, xs):
         x = carry
         lp, k_pool, v_pool = xs
-        h = _layer_norm(x[:, None, :], lp["ln_1"]["scale"],
-                        lp["ln_1"]["bias"])[:, 0]
-        qkv = jnp.einsum("be,eck->bck",
-                         h, lp["attn_qkv"]["kernel"].astype(cfg.dtype))
-        qkv = qkv + lp["attn_qkv"]["bias"].astype(cfg.dtype)
-        q, k, v = [qkv[:, i, :].reshape(B, H, D) for i in range(3)]
-        a = paged_attention_decode(q, k_pool, v_pool, block_tables,
-                                   ctx_lens, k, v).reshape(B, E)
-        a = a @ lp["attn_out"]["kernel"].astype(cfg.dtype) \
-            + lp["attn_out"]["bias"].astype(cfg.dtype)
-        x = x + a
-        h = _layer_norm(x[:, None, :], lp["ln_2"]["scale"],
-                        lp["ln_2"]["bias"])[:, 0]
-        h = h @ lp["mlp_in"]["kernel"].astype(cfg.dtype) \
-            + lp["mlp_in"]["bias"].astype(cfg.dtype)
-        h = jax.nn.gelu(h, approximate=True)
-        h = h @ lp["mlp_out"]["kernel"].astype(cfg.dtype) \
-            + lp["mlp_out"]["bias"].astype(cfg.dtype)
-        return x + h, (k, v)
+        with _scope("ln_1"):
+            h = _layer_norm(x[:, None, :], lp["ln_1"]["scale"],
+                            lp["ln_1"]["bias"])[:, 0]
+        with _scope("attn_qkv"):
+            qkv = jnp.einsum("be,eck->bck",
+                             h, _cast(lp["attn_qkv"]["kernel"], cfg))
+            qkv = qkv + _cast(lp["attn_qkv"]["bias"], cfg)
+            q, k, v = [qkv[:, i, :].reshape(B, H, D) for i in range(3)]
+        with _scope("attn"):
+            a = paged_attention_decode(q, k_pool, v_pool, block_tables,
+                                       ctx_lens, k, v).reshape(B, E)
+        with _scope("attn_out"):
+            a = a @ _cast(lp["attn_out"]["kernel"], cfg) \
+                + _cast(lp["attn_out"]["bias"], cfg)
+            x = x + a
+        with _scope("ln_2"):
+            h = _layer_norm(x[:, None, :], lp["ln_2"]["scale"],
+                            lp["ln_2"]["bias"])[:, 0]
+        with _scope("mlp"):
+            h = h @ _cast(lp["mlp_in"]["kernel"], cfg) \
+                + _cast(lp["mlp_in"]["bias"], cfg)
+            h = jax.nn.gelu(h, approximate=True)
+            h = h @ _cast(lp["mlp_out"]["kernel"], cfg) \
+                + _cast(lp["mlp_out"]["bias"], cfg)
+            return x + h, (k, v)
 
     x, (ks, vs) = lax.scan(body, x, (params["blocks"], k_pools, v_pools))
-    x = _layer_norm(x[:, None, :], params["ln_f"]["scale"],
-                    params["ln_f"]["bias"])[:, 0]
-    logits = jnp.einsum("be,ve->bv", x, params["wte"].astype(cfg.dtype))
-    return logits.astype(jnp.float32), ks, vs
+    with _scope("ln_f"):
+        x = _layer_norm(x[:, None, :], params["ln_f"]["scale"],
+                        params["ln_f"]["bias"])[:, 0]
+    with _scope("lm_head"):
+        logits = jnp.einsum("be,ve->bv", x, _cast(params["wte"], cfg))
+        return logits.astype(jnp.float32), ks, vs
 
 
 def flops_per_token(cfg: GPT2Config, seq_len: int) -> float:

@@ -7,8 +7,8 @@ MXU with running (m, l, acc) accumulators — the classic flash schedule,
 expressed the Pallas way (grid + BlockSpecs; see
 /opt/skills/guides/pallas_guide.md).
 
-Design notes (r3 device-trace driven — benchmarks/step_decompose.py,
-flash_kernel_decompose.py):
+Design notes (r3 device-trace driven; today's kernel times per step are
+the ``kernels.flash_fwd_ms`` / ``kernels.flash_bwd_ms`` metrics, PERF.md §5):
 - Probabilities use ``exp2`` with the 1/sqrt(D) scale and log2(e) folded
   into the score matmul's epilogue multiply — the VPU transcendental is
   the kernel's throughput bound, so no extra multiplies ride with it.
@@ -241,6 +241,7 @@ def _flash_forward_lse_flat(qf, kf, vf, *, causal: bool, bs: int,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf)
     return res if want_lse else (res[0], None)
 
@@ -297,6 +298,7 @@ def _flash_backward_flat(qf, kf, vf, lse, delta, dof, *, causal: bool,
                    jax.ShapeDtypeStruct((BH, T, D), vf.dtype)],
         scratch_shapes=[pltpu.VMEM((T, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd",
     )(qf, kf, vf, dof, lse, delta)
     return dq, dk, dv
 

@@ -1,6 +1,7 @@
 """Pallas fused LayerNorm (TPU) with a fused backward.
 
-Why this exists (r3 device-trace finding, benchmarks/step_decompose.py):
+Why this exists (r3 device-trace finding; today's kernel time per step is
+the ``kernels.layer_norm_ms`` metric, PERF.md §5):
 with LayerNorm left to XLA, the compiler chooses a T-minor layout for its
 LN fusions (trace: ~32ms/step of LN-backward fusions at the flagship
 GPT-2 bench shape, all {1,2,0} layouts).  The Pallas kernel pins the
@@ -93,6 +94,7 @@ def _ln_fwd(x2, scale, bias, eps, interpret):
             jax.ShapeDtypeStruct((1, N), jnp.float32),
         ],
         interpret=interpret,
+        name="layer_norm_fwd",
     )(x2, scale, bias)
     return y, mu, rstd
 
@@ -122,6 +124,7 @@ def _ln_bwd(x2, scale, g2, mu, rstd, interpret):
             jax.ShapeDtypeStruct((nb, 1, E), jnp.float32),
         ],
         interpret=interpret,
+        name="layer_norm_bwd",
     )(x2, scale, g2, mu, rstd)
     return dx, dscale_p.sum(axis=(0, 1)), dbias_p.sum(axis=(0, 1))
 

@@ -41,8 +41,9 @@ def gather_kv(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
     """
     n, bs, kv, d = pool.shape
     b, mb = block_tables.shape
-    g = jnp.take(pool, block_tables.reshape(-1), axis=0)
-    return g.reshape(b, mb * bs, kv, d)
+    with jax.named_scope("paged_gather"):
+        g = jnp.take(pool, block_tables.reshape(-1), axis=0)
+        return g.reshape(b, mb * bs, kv, d)
 
 
 def paged_attention_decode(q: jax.Array, k_pool: jax.Array,
@@ -70,21 +71,22 @@ def paged_attention_decode(q: jax.Array, k_pool: jax.Array,
     k_ctx = gather_kv(k_pool, block_tables)          # (B, T, KV, D)
     v_ctx = gather_kv(v_pool, block_tables)
     t = k_ctx.shape[1]
-    if kvh != h:                                     # grouped-query heads
-        rep = h // kvh
-        k_ctx = jnp.repeat(k_ctx, rep, axis=2)
-        v_ctx = jnp.repeat(v_ctx, rep, axis=2)
-        k_new = jnp.repeat(k_new, rep, axis=1)
-        v_new = jnp.repeat(v_new, rep, axis=1)
-    logits = jnp.einsum("bhd,bkhd->bhk", q, k_ctx,
-                        preferred_element_type=jnp.float32) * scale
-    valid = jnp.arange(t)[None, :] < ctx_lens[:, None]      # (B, T)
-    logits = jnp.where(valid[:, None, :], logits, NEG_INF)
-    self_logit = jnp.einsum("bhd,bhd->bh", q, k_new,
+    with jax.named_scope("paged_attention"):
+        if kvh != h:                                 # grouped-query heads
+            rep = h // kvh
+            k_ctx = jnp.repeat(k_ctx, rep, axis=2)
+            v_ctx = jnp.repeat(v_ctx, rep, axis=2)
+            k_new = jnp.repeat(k_new, rep, axis=1)
+            v_new = jnp.repeat(v_new, rep, axis=1)
+        logits = jnp.einsum("bhd,bkhd->bhk", q, k_ctx,
                             preferred_element_type=jnp.float32) * scale
-    logits = jnp.concatenate([logits, self_logit[..., None]], axis=-1)
-    probs = jax.nn.softmax(logits, axis=-1)                 # f32
-    out = jnp.einsum("bhk,bkhd->bhd", probs[..., :-1],
-                     v_ctx.astype(jnp.float32))
-    out = out + probs[..., -1][..., None] * v_new.astype(jnp.float32)
-    return out.astype(q.dtype)
+        valid = jnp.arange(t)[None, :] < ctx_lens[:, None]      # (B, T)
+        logits = jnp.where(valid[:, None, :], logits, NEG_INF)
+        self_logit = jnp.einsum("bhd,bhd->bh", q, k_new,
+                                preferred_element_type=jnp.float32) * scale
+        logits = jnp.concatenate([logits, self_logit[..., None]], axis=-1)
+        probs = jax.nn.softmax(logits, axis=-1)                 # f32
+        out = jnp.einsum("bhk,bkhd->bhd", probs[..., :-1],
+                         v_ctx.astype(jnp.float32))
+        out = out + probs[..., -1][..., None] * v_new.astype(jnp.float32)
+        return out.astype(q.dtype)

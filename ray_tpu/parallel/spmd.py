@@ -159,7 +159,7 @@ def build_train_program(
     def _grads(params: Any, batch: Any):
         # Runs at trace time: model code (e.g. ring attention) can pick up
         # the program mesh via mesh_lib.get_ambient_mesh() to nest shard_map.
-        with mesh_lib.ambient_mesh(mesh):
+        with mesh_lib.ambient_mesh(mesh), jax.named_scope("grads"):
             return jax.value_and_grad(loss_fn)(params, batch)
 
     def _grads_accum(params: Any, batch: Any):
@@ -191,23 +191,25 @@ def build_train_program(
                 lambda a, g: a + g.astype(a.dtype), g_acc, grads)
             return (loss_acc + loss, g_acc), None
 
-        (loss_sum, acc), _ = jax.lax.scan(
-            body, (jnp.zeros((), jnp.float32), acc0), mbs)
-        inv = jnp.float32(1.0 / A)
-        grads = jax.tree_util.tree_map(
-            lambda a, p: (a.astype(jnp.float32) * inv).astype(p.dtype),
-            acc, params)
-        return loss_sum * inv, grads
+        with jax.named_scope("grad_accum"):
+            (loss_sum, acc), _ = jax.lax.scan(
+                body, (jnp.zeros((), jnp.float32), acc0), mbs)
+            inv = jnp.float32(1.0 / A)
+            grads = jax.tree_util.tree_map(
+                lambda a, p: (a.astype(jnp.float32) * inv).astype(p.dtype),
+                acc, params)
+            return loss_sum * inv, grads
 
     def _step(state: TrainState, batch: Any):
         if accum_steps > 1:
             loss, grads = _grads_accum(state.params, batch)
         else:
             loss, grads = _grads(state.params, batch)
-        updates, opt_state = optimizer.update(
-            grads, state.opt_state, state.params)
-        from ray_tpu.parallel.optim import apply_updates_mixed
-        params = apply_updates_mixed(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params)
+            from ray_tpu.parallel.optim import apply_updates_mixed
+            params = apply_updates_mixed(state.params, updates)
         new = TrainState(step=state.step + 1, params=params,
                          opt_state=opt_state)
         gnorm = optax.global_norm(grads)

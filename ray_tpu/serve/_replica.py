@@ -69,6 +69,7 @@ class Replica:
                           "rtpu_llm_batch_occupancy",
                           "rtpu_llm_preemptions_total",
                           "rtpu_llm_ttft_seconds",
+                          "rtpu_llm_queue_seconds",
                           "rtpu_llm_tpot_seconds",
                           "rtpu_llm_tokens_total"):
                 mcat.get(_name).set_default_tags({"group": dep_key})

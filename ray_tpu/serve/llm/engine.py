@@ -34,11 +34,12 @@ from ray_tpu._private.config import GLOBAL_CONFIG
 from ray_tpu.serve.llm.config import EngineConfig, SamplingParams
 from ray_tpu.serve.llm.kv_cache import (NoFreeBlocks, PagedKVCache,
                                         reap_orphan_segments)
-from ray_tpu.serve.llm.model_runner import ModelRunner
+from ray_tpu.serve.llm.model_runner import ModelRunner, _bucket
 from ray_tpu.serve.llm.scheduler import (FAILED, FINISHED, IterationScheduler,
                                          Plan, Sequence)
 from ray_tpu.util import metrics_catalog as mcat
 from ray_tpu.util import tracing
+from ray_tpu.util.tracing import hot_span
 
 logger = rtlog.get("serve.llm.engine")
 
@@ -146,6 +147,15 @@ class LLMEngine:
         self.decode_steps = 0
         self.preemptions = 0
         self.tokens_out = 0
+        # queue wait, stamped where it ends (_do_prefill): first
+        # admissions apart from re-admissions after a preemption
+        self.admitted = 0
+        self.queue_wait_s = 0.0
+        self.requeue_wait_s = 0.0
+        # hot-span totals of the loop and the runner, name ->
+        # [count, seconds] (tracing.hot_span); the names are a contract,
+        # PERF.md section 3 lists each with the metric that reads it
+        self.span_s = self.runner.span_s
         self._export_server = None
         self._export_spool: Optional[str] = None
         self._exports: deque = deque()               # guarded by: _lock
@@ -193,25 +203,27 @@ class LLMEngine:
     def submit(self, prompt: List[int],
                sampling: Optional[SamplingParams] = None) -> RequestStream:
         sampling = sampling or SamplingParams()
-        seq = Sequence(seq_id=uuid.uuid4().hex[:12],
-                       prompt=[int(t) for t in prompt], sampling=sampling)
-        # request tracing: the submitter's span (serve replica method /
-        # driver trace) parents every engine span for this sequence —
-        # captured HERE because the engine loop thread has no context
-        span = tracing.current_span()
-        if span is not None and span.sampled:
-            seq.trace = span
-        q: queue.Queue = queue.Queue()
-        with self._lock:
-            # checked under the same lock shutdown() drains streams
-            # under: a submit that slips in before the drain gets its
-            # _ERR from the drain; one after it raises here — either
-            # way no reader can block on a never-serviced queue
-            if self._stop.is_set():
-                raise RuntimeError("engine shut down")
-            self._streams[seq.seq_id] = q
-            self._inbox.append(seq)
-        self._wake.set()
+        seq_id = uuid.uuid4().hex[:12]
+        with hot_span("llm.submit", self.span_s, seq=seq_id):
+            seq = Sequence(seq_id=seq_id, prompt=[int(t) for t in prompt],
+                           sampling=sampling)
+            # request tracing: the submitter's span (serve replica method
+            # / driver trace) parents every engine span for this sequence
+            # — captured HERE because the engine loop thread has no context
+            span = tracing.current_span()
+            if span is not None and span.sampled:
+                seq.trace = span
+            q: queue.Queue = queue.Queue()
+            with self._lock:
+                # checked under the same lock shutdown() drains streams
+                # under: a submit that slips in before the drain gets its
+                # _ERR from the drain; one after it raises here — either
+                # way no reader can block on a never-serviced queue
+                if self._stop.is_set():
+                    raise RuntimeError("engine shut down")
+                self._streams[seq.seq_id] = q
+                self._inbox.append(seq)
+            self._wake.set()
         return RequestStream(seq.seq_id, q, self)
 
     def generate(self, prompt: List[int],
@@ -250,6 +262,31 @@ class LLMEngine:
     def step(self) -> bool:
         """One iteration: admit, (maybe) prefill, decode, publish.
         Returns False when nothing was runnable (loop backs off)."""
+        spans = self.span_s
+        with hot_span("llm.step", spans):
+            with hot_span("llm.step.admit", spans):
+                self._admit()
+            with hot_span("llm.step.plan", spans):
+                plan = self.sched.plan(self.cache.free_block_count(),
+                                       self.cache.blocks_needed)
+            from ray_tpu._private import flight_recorder
+            if flight_recorder.enabled() and \
+                    (plan.prefill is not None or plan.decode):
+                flight_recorder.record(
+                    "llm_step",
+                    f"prefill={'1' if plan.prefill is not None else '0'} "
+                    f"decode={len(plan.decode)} "
+                    f"free={self.cache.free_block_count()}")
+            if plan.prefill is not None:
+                self._do_prefill(plan.prefill)
+            elif plan.decode:
+                self._do_decode(plan.decode)
+            with hot_span("llm.step.publish", spans):
+                self._publish_metrics(plan)
+        return plan.prefill is not None or bool(plan.decode)
+
+    def _admit(self) -> None:
+        """Cancels, attached sequences and the inbox into the scheduler."""
         self._drain_cancels()
         self._drain_attached()
         with self._lock:
@@ -270,63 +307,92 @@ class LLMEngine:
             self._finish(head, FAILED,
                          f"prompt needs more KV blocks than the pool "
                          f"holds ({self.cache.num_blocks})")
-        plan = self.sched.plan(self.cache.free_block_count(),
-                               self.cache.blocks_needed)
-        from ray_tpu._private import flight_recorder
-        if flight_recorder.enabled() and \
-                (plan.prefill is not None or plan.decode):
-            flight_recorder.record(
-                "llm_step",
-                f"prefill={'1' if plan.prefill is not None else '0'} "
-                f"decode={len(plan.decode)} "
-                f"free={self.cache.free_block_count()}")
-        if plan.prefill is not None:
-            self._do_prefill(plan.prefill)
-        elif plan.decode:
-            self._do_decode(plan.decode)
-        self._publish_metrics(plan)
-        return plan.prefill is not None or bool(plan.decode)
 
     # ---------------------------------------------------------------- prefill
     def _do_prefill(self, seq: Sequence) -> None:
-        try:
-            self.cache.alloc_seq(seq.seq_id, seq.ctx_len)
-        except NoFreeBlocks:
-            # plan() checked free blocks, but be safe: requeue
-            self.sched.waiting.appendleft(seq)
+        t0 = time.time()    # the cluster timeline's clock: a start only
+        with hot_span("llm.prefill", self.span_s, seq=seq.seq_id,
+                      tokens=len(seq.prompt)) as span:
+            tok = self._prefill_one(seq, span)
+        if tok is None:
             return
-        t0 = time.time()
-        try:
-            logits, ks, vs = self.runner.prefill(seq.prompt)
-        except Exception as e:  # noqa: BLE001 - surface to the caller
-            self.cache.free_seq(seq.seq_id)
-            self._finish(seq, FAILED, f"prefill failed: {e!r}")
-            return
-        with self._lock:
-            self.prefill_steps += 1
-        self.cache.scatter_prefill(seq.seq_id,
-                                   np.asarray(ks, np.float32),
-                                   np.asarray(vs, np.float32),
-                                   len(seq.prompt))
-        # sampling step = tokens generated so far RELATIVE TO THE
-        # ORIGINAL prompt, so a preemption re-prefill (k tokens folded
-        # into the prompt) draws the same rng stream position as the
-        # pressure-free run — seeded sampling stays reproducible
-        tok = self.runner.sample(logits, seq.sampling, step=seq.generated)
         if seq.trace is not None:
             # per-sequence prefill span (explicit parent: the engine
-            # loop thread never holds the request's context variable)
-            tracing.emit_span("llm.prefill", seq.trace, t0,
-                              time.time() - t0, cat="llm",
-                              seq_id=seq.seq_id, tokens=len(seq.prompt),
-                              model=self.cfg.model)
+            # loop thread never holds the request's context variable);
+            # its duration is the hot span's own measurement
+            tracing.emit_span("llm.prefill", seq.trace, t0, span.dur,
+                              cat="llm", seq_id=seq.seq_id,
+                              tokens=len(seq.prompt), model=self.cfg.model)
         self.sched.start_running(seq)
         self._emit(seq, tok)
         self._count_tokens(len(seq.prompt), phase="prefill")
         self._maybe_finish(seq)
 
+    def _prefill_one(self, seq: Sequence, span: hot_span) -> Optional[int]:
+        """Blocks, the model's prefill, the scatter into the pool and the
+        first token; None when the sequence did not start."""
+        try:
+            self.cache.alloc_seq(seq.seq_id, seq.ctx_len)
+        except NoFreeBlocks:
+            # plan() checked free blocks, but be safe: requeue
+            self.sched.waiting.appendleft(seq)
+            return None
+        span.set(bucket=_bucket(len(seq.prompt),
+                                self.cfg.prefill_len_buckets),
+                 queue_ms=round(1e3 * self._note_admission(seq), 3))
+        try:
+            logits, ks, vs = self.runner.prefill(seq.prompt)
+        except Exception as e:  # noqa: BLE001 - surface to the caller
+            self.cache.free_seq(seq.seq_id)
+            self._finish(seq, FAILED, f"prefill failed: {e!r}")
+            return None
+        with self._lock:
+            self.prefill_steps += 1
+        with hot_span("llm.prefill.scatter", self.span_s):
+            self.cache.scatter_prefill(seq.seq_id,
+                                       np.asarray(ks, np.float32),
+                                       np.asarray(vs, np.float32),
+                                       len(seq.prompt))
+        # sampling step = tokens generated so far RELATIVE TO THE
+        # ORIGINAL prompt, so a preemption re-prefill (k tokens folded
+        # into the prompt) draws the same rng stream position as the
+        # pressure-free run — seeded sampling stays reproducible
+        return self.runner.sample(logits, seq.sampling, step=seq.generated)
+
+    def _note_admission(self, seq: Sequence) -> float:
+        """Queue wait ends here, where the prefill begins: seconds since
+        the sequence joined the waiting line.  A first admission is
+        counted apart from a re-admission after a preemption."""
+        now = time.monotonic()
+        wait = now - seq.queued_at
+        if seq.admitted_at is None:
+            seq.admitted_at = now
+            self.admitted += 1
+            self.queue_wait_s += wait
+            if GLOBAL_CONFIG.metrics_enabled:
+                mcat.get("rtpu_llm_queue_seconds").observe(
+                    wait, tags={"model": self.cfg.model})
+        else:
+            self.requeue_wait_s += wait
+        return wait
+
     # ----------------------------------------------------------------- decode
     def _do_decode(self, seqs: List[Sequence]) -> None:
+        t0 = time.time()    # the cluster timeline's clock: a start only
+        with hot_span("llm.decode", self.span_s) as span:
+            batch = self._decode_batch(seqs, span)
+        traced = next((s for s in batch if s.trace is not None), None)
+        if traced is not None:
+            # one span per decode ITERATION (the batch is the unit of
+            # execution), parented to the first traced sequence in it;
+            # its duration is the hot span's own measurement
+            tracing.emit_span("llm.decode_step", traced.trace, t0,
+                              span.dur, cat="llm", batch=len(batch),
+                              seq_id=traced.seq_id, model=self.cfg.model)
+
+    def _reserve_slots(self, seqs: List[Sequence]):
+        """A pool slot for each sequence's new token, preempting under
+        cache pressure: (slots by sequence id, the batch that remains)."""
         slots = {}
         batch = list(seqs)
         for seq in list(batch):
@@ -347,23 +413,33 @@ class LLMEngine:
                             "sequence running")
             # preemption may have evicted members of THIS batch
             batch = [s for s in batch if s in self.sched.running]
+        return slots, batch
+
+    def _decode_batch(self, seqs: List[Sequence],
+                      span: hot_span) -> List[Sequence]:
+        """One decode iteration; returns the batch that ran."""
+        spans = self.span_s
+        with hot_span("llm.decode.slots", spans):
+            slots, batch = self._reserve_slots(seqs)
         if not batch:
-            return
-        maxb = self.cfg.max_blocks_per_seq
-        tables = np.zeros((len(batch), maxb), np.int32)
-        toks = np.zeros(len(batch), np.int32)
-        poss = np.zeros(len(batch), np.int32)
-        lens = np.zeros(len(batch), np.int32)
-        for i, s in enumerate(batch):
-            t = self.cache.table(s.seq_id)
-            tables[i, :len(t)] = t
-            # the token being processed is the last SAMPLED one — its KV
-            # is not in the pool yet (this step writes it); both its
-            # position and the valid pool length are ctx_len - 1
-            toks[i] = s.output[-1] if s.output else s.prompt[-1]
-            poss[i] = s.ctx_len - 1
-            lens[i] = s.ctx_len - 1
-        t0 = time.time()
+            return batch
+        span.set(batch=len(batch),
+                 seqs="|".join(s.seq_id for s in batch))
+        with hot_span("llm.decode.tables", spans):
+            maxb = self.cfg.max_blocks_per_seq
+            tables = np.zeros((len(batch), maxb), np.int32)
+            toks = np.zeros(len(batch), np.int32)
+            poss = np.zeros(len(batch), np.int32)
+            lens = np.zeros(len(batch), np.int32)
+            for i, s in enumerate(batch):
+                t = self.cache.table(s.seq_id)
+                tables[i, :len(t)] = t
+                # the token being processed is the last SAMPLED one — its
+                # KV is not in the pool yet (this step writes it); both
+                # its position and the valid pool length are ctx_len - 1
+                toks[i] = s.output[-1] if s.output else s.prompt[-1]
+                poss[i] = s.ctx_len - 1
+                lens[i] = s.ctx_len - 1
         try:
             logits, ks, vs = self.runner.decode(toks, poss,
                                                 self.cache.pool, tables,
@@ -377,24 +453,18 @@ class LLMEngine:
                     self.cache.rollback_slot(s.seq_id, ent[2])
             raise
         self.decode_steps += 1
-        for i, s in enumerate(batch):
-            blk, off, _grew = slots[s.seq_id]
-            self.cache.write_token(blk, off,
-                                   np.asarray(ks[:, i], np.float32),
-                                   np.asarray(vs[:, i], np.float32))
-            tok = self.runner.sample(logits[i], s.sampling,
-                                     step=s.generated)
-            self._emit(s, tok)
-            self._maybe_finish(s)
-        traced = next((s for s in batch if s.trace is not None), None)
-        if traced is not None:
-            # one span per decode ITERATION (the batch is the unit of
-            # execution), parented to the first traced sequence in it
-            tracing.emit_span("llm.decode_step", traced.trace, t0,
-                              time.time() - t0, cat="llm",
-                              batch=len(batch), seq_id=traced.seq_id,
-                              model=self.cfg.model)
+        with hot_span("llm.decode.commit", spans):
+            for i, s in enumerate(batch):
+                blk, off, _grew = slots[s.seq_id]
+                self.cache.write_token(blk, off,
+                                       np.asarray(ks[:, i], np.float32),
+                                       np.asarray(vs[:, i], np.float32))
+                tok = self.runner.sample(logits[i], s.sampling,
+                                         step=s.generated)
+                self._emit(s, tok)
+                self._maybe_finish(s)
         self._count_tokens(len(batch), phase="decode")
+        return batch
 
     def _preempt_one(self, slots: Dict) -> bool:
         """Evict the scheduler's victim (latest arrival — possibly one
@@ -404,6 +474,12 @@ class LLMEngine:
         victim = self.sched.victim()
         if victim is None:
             return False
+        with hot_span("llm.preempt", self.span_s, seq=victim.seq_id,
+                      ctx=victim.ctx_len):
+            self._evict(victim, slots)
+        return True
+
+    def _evict(self, victim: Sequence, slots: Dict) -> None:
         logger.info("preempting %s under cache pressure (ctx=%d)",
                     victim.seq_id, victim.ctx_len)
         from ray_tpu._private import flight_recorder
@@ -421,7 +497,6 @@ class LLMEngine:
         if GLOBAL_CONFIG.metrics_enabled:
             mcat.get("rtpu_llm_preemptions_total").inc(
                 tags={"model": self.cfg.model})
-        return True
 
     # --------------------------------------------------- prefill/decode split
     def _ensure_export_plane(self):
@@ -470,7 +545,7 @@ class LLMEngine:
         span = tracing.current_span()   # caller's thread context
         if span is not None and not span.sampled:
             span = None
-        t0 = time.time()
+        t0, p0 = time.time(), time.perf_counter()
         self.cache.alloc_seq(seq_id, len(prompt))
         try:
             logits, ks, vs = self.runner.prefill(prompt)
@@ -503,7 +578,7 @@ class LLMEngine:
             # form): attach() on the decode engine parents its tree to
             # it — the cross-process link between the two engines
             ctx = tracing.emit_span(
-                "llm.prefill_remote", span, t0, time.time() - t0,
+                "llm.prefill_remote", span, t0, time.perf_counter() - p0,
                 cat="llm", tokens=len(prompt), blocks=len(oids),
                 model=self.cfg.model) if span is not None else None
             return dict(addr=srv.advertise_addr, blocks=oids,
@@ -559,7 +634,7 @@ class LLMEngine:
         if parent is None:
             cur = tracing.current_span()
             parent = cur if cur is not None and cur.sampled else None
-        t0 = time.time()
+        t0, p0 = time.time(), time.perf_counter()
         self.cache.alloc_seq(seq.seq_id, len(prompt))
         tok = tracing.adopt(parent) if parent is not None else None
         try:
@@ -581,7 +656,8 @@ class LLMEngine:
                 tracing.restore(tok)
         if parent is not None:
             seq.trace = tracing.emit_span(
-                "llm.attach", parent, t0, time.time() - t0, cat="llm",
+                "llm.attach", parent, t0, time.perf_counter() - p0,
+                cat="llm",
                 seq_id=seq.seq_id, blocks=len(manifest["blocks"]),
                 tokens=len(prompt), model=self.cfg.model)
         q: queue.Queue = queue.Queue()
@@ -719,4 +795,9 @@ class LLMEngine:
                     running=len(self.sched.running),
                     waiting=len(self.sched.waiting),
                     blocks_free=self.cache.free_block_count(),
-                    compiles=self.runner.compiles)
+                    compiles=self.runner.compiles,
+                    admitted=self.admitted,
+                    queue_wait_s=self.queue_wait_s,
+                    requeue_wait_s=self.requeue_wait_s,
+                    span_s={k: list(v) for k, v in
+                            list(self.span_s.items())})

@@ -19,6 +19,7 @@ happens host-side on the (B, V) logits.
 
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 from typing import Tuple
 
@@ -28,6 +29,7 @@ from ray_tpu._private import rtlog
 from ray_tpu._private.xla_watchdog import compile_budget
 from ray_tpu.serve.llm.config import EngineConfig, SamplingParams, \
     resolve_model
+from ray_tpu.util.tracing import hot_span
 
 logger = rtlog.get("serve.llm.runner")
 
@@ -61,6 +63,9 @@ class ModelRunner:
                                        cfg=self.mcfg))
         self.compiles = 0          # observability: distinct programs built
         self._shapes_seen: set = set()
+        # hot-span totals, name -> [count, seconds]; the engine shares
+        # this dict with its own spans (LLMEngine.stats()["span_s"])
+        self.span_s: dict = {}
         # XLA watchdog step regions (DESIGN.md §4q): one compile per
         # bucket for the runner's life, zero host transfers inside the
         # dispatch.  The post-dispatch np.asarray pulls are designed
@@ -91,18 +96,22 @@ class ModelRunner:
         import jax.numpy as jnp
         n = len(token_ids)
         tb = _bucket(n, self.cfg.prefill_len_buckets)
-        self._note_shape(("prefill", tb))
+        compiling = self._note_shape("prefill", tb)
         toks = np.zeros((1, tb), np.int32)
         toks[0, :n] = token_ids
         # last_pos is TRACED (one compile per bucket, not per length);
         # only the last real position's (1, V) logits come back to host
         last_pos = jnp.int32(n - 1)
-        with self._prefill_budget:
+        # dispatch ends at the ENQUEUE (the jitted call returns before
+        # the device finishes); pull ends when the results are on the host
+        with compiling, hot_span("llm.prefill.dispatch", self.span_s), \
+                self._prefill_budget:
             logits, ks, vs = self._prefill(self.params, toks,
                                            last_pos=last_pos)
-        logits = np.asarray(logits)[0]                           # (V,)
-        ks = np.asarray(ks)[:, 0]                                # (L,T,KV,D)
-        vs = np.asarray(vs)[:, 0]
+        with hot_span("llm.prefill.pull", self.span_s):
+            logits = np.asarray(logits)[0]                       # (V,)
+            ks = np.asarray(ks)[:, 0]                            # (L,T,KV,D)
+            vs = np.asarray(vs)[:, 0]
         return logits, ks, vs
 
     # ----------------------------------------------------------------- decode
@@ -120,29 +129,41 @@ class ModelRunner:
         """
         b = len(tokens)
         bb = _bucket(b, self.cfg.decode_batch_buckets)
-        self._note_shape(("decode", bb))
+        compiling = self._note_shape("decode", bb)
         pad = bb - b
         if pad:
-            tokens = np.concatenate([tokens, np.zeros(pad, np.int32)])
-            positions = np.concatenate([positions,
-                                        np.zeros(pad, np.int32)])
-            ctx_lens = np.concatenate([ctx_lens, np.zeros(pad, np.int32)])
-            block_tables = np.concatenate(
-                [block_tables, np.zeros((pad, block_tables.shape[1]),
-                                        np.int32)])
-        with self._decode_budget:
+            with hot_span("llm.decode.tables", self.span_s):
+                tokens = np.concatenate([tokens, np.zeros(pad, np.int32)])
+                positions = np.concatenate([positions,
+                                            np.zeros(pad, np.int32)])
+                ctx_lens = np.concatenate([ctx_lens,
+                                           np.zeros(pad, np.int32)])
+                block_tables = np.concatenate(
+                    [block_tables, np.zeros((pad, block_tables.shape[1]),
+                                            np.int32)])
+        # dispatch holds the whole jitted call, the numpy pool argument's
+        # way to the device included, and ends at the ENQUEUE; pull ends
+        # when logits and the new K/V are on the host
+        with compiling, hot_span("llm.decode.dispatch", self.span_s), \
+                self._decode_budget:
             logits, ks, vs = self._decode(self.params, tokens,
                                           positions, kv_pool,
                                           block_tables, ctx_lens)
-        return (np.asarray(logits)[:b], np.asarray(ks)[:, :b],
-                np.asarray(vs)[:, :b])
+        with hot_span("llm.decode.pull", self.span_s):
+            return (np.asarray(logits)[:b], np.asarray(ks)[:, :b],
+                    np.asarray(vs)[:, :b])
 
-    def _note_shape(self, key) -> None:
-        if key not in self._shapes_seen:
-            self._shapes_seen.add(key)
-            self.compiles += 1
-            logger.info("compiling %s program (total %d)",
-                        key, self.compiles)
+    def _note_shape(self, program: str, bucket: int):
+        """A context for the call that follows: an ``llm.compile`` span
+        around the first call of a (program, bucket), nothing after."""
+        key = (program, bucket)
+        if key in self._shapes_seen:
+            return contextlib.nullcontext()
+        self._shapes_seen.add(key)
+        self.compiles += 1
+        logger.info("compiling %s program (total %d)", key, self.compiles)
+        return hot_span("llm.compile", self.span_s, program=program,
+                        bucket=bucket)
 
     # --------------------------------------------------------------- sampling
     @staticmethod

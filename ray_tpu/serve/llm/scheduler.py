@@ -45,6 +45,11 @@ class Sequence:
     # timing for TTFT/TPOT accounting (engine fills these in)
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
+    # queue wait (engine._note_admission): when the sequence last joined
+    # the waiting line (arrival, then each preemption) and when its
+    # first prefill began
+    queued_at: float = 0.0
+    admitted_at: Optional[float] = None
     preemptions: int = 0
     error: Optional[str] = None
     # preemption folds generated tokens into the prompt for re-prefill;
@@ -58,6 +63,7 @@ class Sequence:
     def __post_init__(self):
         if not self.orig_len:
             self.orig_len = len(self.prompt)
+        self.queued_at = self.arrival
 
     @property
     def ctx_len(self) -> int:
@@ -135,6 +141,7 @@ class IterationScheduler:
         seq.output = []
         seq.state = WAITING
         seq.preemptions += 1
+        seq.queued_at = time.monotonic()
         self.waiting.appendleft(seq)
 
     def start_running(self, seq: Sequence) -> None:

@@ -209,6 +209,12 @@ CATALOG: Dict[str, dict] = {
         description="Time to first token: request submission to the "
                     "first sampled token (queueing + prefill)",
         emitted_by="llm replica"),
+    "rtpu_llm_queue_seconds": dict(
+        kind="histogram", tag_keys=("model", "group"), buckets=LATENCY_BUCKETS,
+        description="Queue wait: request submission to the start of its "
+                    "first prefill (the part of TTFT spent in the waiting "
+                    "line; re-admissions after a preemption not counted)",
+        emitted_by="llm replica"),
     "rtpu_llm_tpot_seconds": dict(
         kind="histogram", tag_keys=("model", "group"), buckets=LATENCY_BUCKETS,
         description="Time per output token after the first (decode "

@@ -36,6 +36,12 @@ Usage::
     # both land in ray_tpu.timeline(): host spans carry
     # trace_id/span_id/parent_id args; device events carry cat="device".
 
+Hot loops (the LLM engine's step, the model runner) use ``hot_span``
+instead: no context, no sampling, no shipping — a
+``jax.profiler.TraceAnnotation`` on the device trace's own clock when jax
+is already imported, and a running total the caller reads in-process
+(DESIGN.md §4h; the span names are a contract, PERF.md §3).
+
 Span context lives in a ``contextvars.ContextVar`` (not a bare
 ``threading.local``): each thread still has its own current span, and the
 context additionally flows into asyncio tasks scheduled from a thread
@@ -50,10 +56,11 @@ import contextvars
 import itertools
 import os
 import random
+import sys
 import threading
 import time
 import weakref
-from typing import Iterator, List, Optional
+from typing import Dict, Iterator, List, Optional
 
 _SPAN: "contextvars.ContextVar[Optional[SpanContext]]" = \
     contextvars.ContextVar("rtpu_span", default=None)
@@ -367,6 +374,56 @@ def _emit(events) -> None:
             pass
     else:
         w._send_event({"kind": "profile_events", "events": events})
+
+
+class hot_span:
+    """A span for hot loops (the LLM engine's step, the model runner):
+    one measurement, two sinks.
+
+    (a) When ``jax`` is ALREADY imported in this process, entering opens
+    a ``jax.profiler.TraceAnnotation(name, **attrs)``, so under any
+    ``jax.profiler`` capture the span lies on the device trace's own
+    clock; with no capture running that is a no-op of under a
+    microsecond.  This module never imports jax itself (drivers import
+    it and must stay off the chip).
+    (b) On exit, ``perf_counter`` seconds and one count are added to
+    ``totals[name]`` (``[count, seconds]``, a dict the caller owns and
+    reads, e.g. ``LLMEngine.stats()["span_s"]``) and kept as ``.dur`` for
+    a caller that also ships the span to the cluster timeline
+    (``emit_span``).  Updates are plain read-modify-writes: a name
+    entered from several threads at once may lose a count.
+
+    Attribute values are str/int/float; a comma cuts a value short in
+    the profiler's encoding, so join lists with ``|``.  ``set()`` adds
+    attributes known only inside the span."""
+
+    __slots__ = ("name", "totals", "dur", "_t0", "_ann")
+
+    def __init__(self, name: str, totals: Dict[str, list], **attrs):
+        self.name = name
+        self.totals = totals
+        self.dur = 0.0
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        self._ann = None if profiler is None else \
+            profiler.TraceAnnotation(name, **attrs)
+
+    def set(self, **attrs) -> None:
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
+
+    def __enter__(self) -> "hot_span":
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.dur = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        total = self.totals.setdefault(self.name, [0, 0.0])
+        total[0] += 1
+        total[1] += self.dur
 
 
 def profile_event_lists(out_dir: str):

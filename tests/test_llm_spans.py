@@ -1,0 +1,303 @@
+"""The serving engine measures itself (ISSUE 25): ``llm.*`` hot spans on
+the profiler's clock and as running totals, queue wait as a counter, and
+the timeline spans' durations from the same measurement.
+
+The names are a contract (PERF.md, section 3): perfbench's per-layer
+metrics read them out of a capture.
+"""
+
+import glob
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
+
+import pytest
+
+from ray_tpu.serve.llm import EngineConfig, LLMEngine, SamplingParams
+from ray_tpu.util import metrics
+from ray_tpu.util import tracing
+
+LOOP_SPANS = [
+    "llm.step", "llm.step.admit", "llm.step.plan", "llm.step.publish",
+    "llm.prefill", "llm.prefill.dispatch", "llm.prefill.pull",
+    "llm.prefill.scatter", "llm.decode", "llm.decode.slots",
+    "llm.decode.tables", "llm.decode.dispatch", "llm.decode.pull",
+    "llm.decode.commit", "llm.compile", "llm.preempt"]
+ALL_SPANS = LOOP_SPANS + ["llm.submit"]
+ROOT = Path(__file__).resolve().parent.parent
+
+
+def small_pool_cfg(**kw):
+    """Four sequences that outgrow ten blocks of 8: preemptions happen."""
+    base = dict(model="gpt2:tiny", num_blocks=10, block_size=8,
+                max_num_seqs=4, max_model_len=64, max_prefill_tokens=32,
+                prefill_len_buckets=(16, 32, 64),
+                decode_batch_buckets=(1, 2, 4), share_weights=False)
+    base.update(kw)
+    return EngineConfig(**base)
+
+
+def _serve(eng, n=4, max_tokens=20):
+    streams = [eng.submit(list(range(1, 12 + i)),
+                          SamplingParams(max_tokens=max_tokens))
+               for i in range(n)]
+    tokens = [s.tokens() for s in streams]
+    assert all(len(t) == max_tokens for t in tokens)
+    return [s.seq_id for s in streams]
+
+
+def _settled_stats(eng):
+    """stats() once the loop has left its last step: a stream ends inside
+    the step's commit, before the step's spans close."""
+    import time
+    deadline = time.monotonic() + 10
+    while time.monotonic() < deadline:
+        stats = eng.stats()
+        spans = stats["span_s"]
+        if not stats["running"] and not stats["waiting"] and \
+                spans["llm.step"][0] == spans["llm.step.admit"][0]:
+            return stats
+        time.sleep(0.01)
+    raise AssertionError("the engine's loop did not settle")
+
+
+def _queue_count(model):
+    entry = metrics.registry_snapshot().get("rtpu_llm_queue_seconds")
+    return sum(s["value"]["count"] for s in (entry or {"series": []})["series"]
+               if s["tags"].get("model") == model)
+
+
+# ------------------------------------------------- under a profiler capture
+@pytest.fixture(scope="module")
+def captured(tmp_path_factory):
+    """One engine run under a jax.profiler capture on the CPU backend:
+    (events by thread line, submitted ids, stats before, stats after)."""
+    import jax
+    from jax.profiler import ProfileData
+
+    out = str(tmp_path_factory.mktemp("capture"))
+    eng = LLMEngine(small_pool_cfg())
+    try:
+        before = eng.stats()
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(out, profiler_options=options)
+        ids = _serve(eng)
+        after = _settled_stats(eng)
+        jax.profiler.stop_trace()
+    finally:
+        eng.shutdown()
+    path = sorted(glob.glob(out + "/plugins/profile/*/*.xplane.pb"))[-1]
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for ln in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                    dict(e.stats))
+                   for e in ln.events if e.name.startswith("llm.")]
+            if evs:
+                lines.append(sorted(evs, key=lambda e: e[1]))
+    return lines, ids, before, after
+
+
+def _loop_line(lines):
+    return next(ln for ln in lines if any(e[0] == "llm.step" for e in ln))
+
+
+@pytest.mark.parametrize("name", LOOP_SPANS)
+def test_capture_holds_the_span_on_the_loop_thread(captured, name):
+    lines = captured[0]
+    assert any(e[0] == name for e in _loop_line(lines))
+    for ln in lines:
+        if ln is not _loop_line(lines):
+            assert not any(e[0] == name for e in ln)
+
+
+def test_submit_spans_lie_on_the_callers_thread(captured):
+    lines, ids = captured[0], captured[1]
+    submits = [e for ln in lines if ln is not _loop_line(lines)
+               for e in ln if e[0] == "llm.submit"]
+    assert sorted(e[3]["seq"] for e in submits) == sorted(ids)
+    assert not any(e[0] == "llm.submit" for e in _loop_line(lines))
+
+
+def test_spans_nest_by_time_and_count_the_steps(captured):
+    lines, _, before, after = captured
+    loop = _loop_line(lines)
+
+    def named(name):
+        return [e for e in loop if e[0] == name]
+
+    def within(inner, outers):
+        return any(o[1] <= inner[1] and inner[2] <= o[2] for o in outers)
+
+    decodes, steps = named("llm.decode"), named("llm.step")
+    assert len(decodes) == after["decode_steps"] - before["decode_steps"] > 0
+    assert len(named("llm.prefill")) == \
+        after["prefill_steps"] - before["prefill_steps"]
+    assert len(named("llm.preempt")) == \
+        after["preemptions"] - before["preemptions"] > 0
+    for part in ("llm.decode.dispatch", "llm.decode.pull",
+                 "llm.decode.slots", "llm.decode.commit"):
+        spans = named(part)
+        assert len(spans) == len(decodes)
+        assert all(within(s, decodes) for s in spans)
+    for part in ("llm.decode", "llm.prefill", "llm.step.admit",
+                 "llm.step.plan", "llm.step.publish"):
+        assert all(within(s, steps) for s in named(part))
+    for part in ("llm.prefill.dispatch", "llm.prefill.pull",
+                 "llm.prefill.scatter"):
+        assert all(within(s, named("llm.prefill")) for s in named(part))
+    assert all(within(s, named("llm.decode.slots"))
+               for s in named("llm.preempt"))
+    # the first call of a (program, bucket) is a compile, once
+    compiles = [(e[3]["program"], e[3]["bucket"])
+                for e in named("llm.compile")]
+    assert len(compiles) == len(set(compiles)) == \
+        after["compiles"] - before["compiles"]
+
+
+def test_spans_of_one_request_share_its_id(captured):
+    lines, ids = captured[0], captured[1]
+    loop = _loop_line(lines)
+    prefills = [e[3] for e in loop if e[0] == "llm.prefill"]
+    assert {p["seq"] for p in prefills} == set(ids)
+    for p in prefills:
+        assert p["queue_ms"] >= 0 and p["tokens"] <= p["bucket"]
+    for e in loop:
+        if e[0] == "llm.decode":
+            members = e[3]["seqs"].split("|")
+            assert len(members) == e[3]["batch"] and set(members) <= set(ids)
+        if e[0] == "llm.preempt":
+            assert e[3]["seq"] in ids and e[3]["ctx"] > 0
+
+
+# -------------------------------------------------------- with no capture
+@pytest.fixture(scope="module")
+def served():
+    """(submitted ids, stats, queue-histogram count added) of one run."""
+    cfg = small_pool_cfg(model="gpt2:tiny")
+    count0 = _queue_count(cfg.model)
+    eng = LLMEngine(cfg)
+    try:
+        ids = _serve(eng)
+        stats = _settled_stats(eng)
+    finally:
+        eng.shutdown()
+    return ids, stats, _queue_count(cfg.model) - count0
+
+
+@pytest.mark.parametrize("name", ALL_SPANS)
+def test_totals_without_a_capture_have_the_span(served, name):
+    count, seconds = served[1]["span_s"][name]
+    assert count > 0 and seconds > 0
+
+
+def test_totals_count_the_steps_and_children_fit_their_parent(served):
+    _, stats, _ = served
+    spans = stats["span_s"]
+    assert spans["llm.decode"][0] == stats["decode_steps"]
+    assert spans["llm.prefill"][0] == stats["prefill_steps"]
+    assert spans["llm.preempt"][0] == stats["preemptions"]
+    assert spans["llm.compile"][0] == stats["compiles"]
+    assert spans["llm.submit"][0] == len(served[0])
+    for part in ("dispatch", "pull", "slots", "commit"):
+        assert spans[f"llm.decode.{part}"][0] == stats["decode_steps"]
+    assert stats["decode_steps"] <= spans["llm.decode.tables"][0] \
+        <= 2 * stats["decode_steps"]
+    for part in ("admit", "plan", "publish"):
+        assert spans[f"llm.step.{part}"][0] == spans["llm.step"][0]
+    step_children = ("llm.step.admit", "llm.step.plan", "llm.step.publish",
+                     "llm.prefill", "llm.decode")
+    assert sum(spans[c][1] for c in step_children) <= spans["llm.step"][1]
+    decode_children = [f"llm.decode.{p}" for p in
+                       ("slots", "tables", "dispatch", "pull", "commit")]
+    assert sum(spans[c][1] for c in decode_children) <= spans["llm.decode"][1]
+
+
+def test_queue_wait_counts_first_admissions_apart_from_readmissions(served):
+    ids, stats, observed = served
+    assert stats["admitted"] == len(ids) == observed
+    assert stats["queue_wait_s"] > 0
+    assert stats["preemptions"] > 0            # the pool is that small
+    assert stats["prefill_steps"] == len(ids) + stats["preemptions"]
+    assert stats["requeue_wait_s"] > 0
+
+
+def test_no_preemption_no_requeue_wait():
+    eng = LLMEngine(small_pool_cfg(num_blocks=64))
+    try:
+        _serve(eng, n=2, max_tokens=4)
+        stats = _settled_stats(eng)
+    finally:
+        eng.shutdown()
+    assert stats["preemptions"] == 0 and stats["requeue_wait_s"] == 0.0
+    assert stats["admitted"] == 2 and "llm.preempt" not in stats["span_s"]
+
+
+def test_a_traced_request_still_yields_timeline_events(monkeypatch):
+    """One measurement, two sinks: the cluster timeline's llm.prefill /
+    llm.decode_step events carry the hot span's duration, on the wall
+    clock's start."""
+    import time
+
+    events = []
+    monkeypatch.setattr(tracing, "_emit", events.extend)
+    eng = LLMEngine(small_pool_cfg(num_blocks=64))
+    try:
+        t_before = time.time()
+        with tracing.trace("request"):
+            stream = eng.submit([1, 2, 3, 4, 5], SamplingParams(max_tokens=4))
+        stream.tokens()
+        spans = _settled_stats(eng)["span_s"]
+        t_after = time.time()
+    finally:
+        eng.shutdown()
+    by_name = {}
+    for ev in events:
+        if ev.get("ph") == "X":
+            by_name.setdefault(ev["name"], []).append(ev)
+    assert len(by_name["llm.prefill"]) == 1
+    assert len(by_name["llm.decode_step"]) == 3
+    for ev in by_name["llm.prefill"] + by_name["llm.decode_step"]:
+        assert ev["dur"] > 0 and ev["cat"] == "llm"
+        assert t_before * 1e6 <= ev["ts"] <= t_after * 1e6
+    assert by_name["llm.prefill"][0]["dur"] == \
+        pytest.approx(spans["llm.prefill"][1] * 1e6)
+    assert sum(ev["dur"] for ev in by_name["llm.decode_step"]) == \
+        pytest.approx(spans["llm.decode"][1] * 1e6)
+
+
+# ------------------------------------------------------------- the primitive
+def test_hot_span_opens_no_annotation_and_imports_nothing_without_jax():
+    code = textwrap.dedent("""
+        import sys
+        from ray_tpu.util import tracing
+        totals = {}
+        with tracing.hot_span("llm.x", totals, seq="a") as span:
+            span.set(batch=2)
+        with tracing.hot_span("llm.x", totals):
+            pass
+        assert span._ann is None
+        assert totals["llm.x"][0] == 2 and totals["llm.x"][1] > 0
+        assert span.dur > 0
+        assert "jax" not in sys.modules and "numpy" not in sys.modules
+        print("ok")
+    """)
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=60, cwd=ROOT)
+    assert proc.returncode == 0 and proc.stdout.strip() == "ok", proc.stderr
+
+
+def test_hot_span_totals_are_the_callers_and_survive_an_exception():
+    import jax  # noqa: F401 - with jax imported an annotation is opened
+
+    mine, other = {}, {}
+    with pytest.raises(ValueError):
+        with tracing.hot_span("llm.x", mine) as span:
+            raise ValueError("boom")
+    assert span._ann is not None
+    assert mine["llm.x"][0] == 1 and mine["llm.x"][1] == span.dur > 0
+    assert other == {}
