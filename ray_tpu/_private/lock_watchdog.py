@@ -112,12 +112,17 @@ SHM_STORE_LOCK_DAG: Dict[str, Set[str]] = {
 
 SHM_STORE_CV_ALIASES: Dict[str, str] = {}
 
-# serve/llm paged KV cache (kv_cache.py): one leaf lock guards the
-# allocator tables (free list, block tables, fills, refcounts).  Pool
-# byte writes (scatter/write_token) are engine-loop-owned and happen
-# OUTSIDE it by design — the lock protects placement, not payload.
+# serve/llm paged KV cache (kv_cache.py): two leaf locks, never nested.
+# ``_lock`` guards the allocator tables (free list, block tables, fills,
+# refcounts) and the host-bytes counter: placement, not payload.
+# ``_pool_lock`` (DevicePool) guards the payload's one handle: every
+# program over the pool's device array (the decode step, the scatter,
+# attach's load_block from its caller's thread) reads and rebinds the
+# array under it, held for the enqueue only — a donated array must not
+# be seen by a second caller.
 LLM_KV_LOCK_DAG: Dict[str, Set[str]] = {
     "_lock": set(),
+    "_pool_lock": set(),
 }
 
 LLM_KV_CV_ALIASES: Dict[str, str] = {}
@@ -540,8 +545,16 @@ STEP_PATHS: Set[str] = {
 # moments alias their outputs; the optional ``donate_batch`` argnum is
 # deliberately NOT declared (callers that enable it feed fresh batches
 # and the static rule covers the unconditional donation only).
+# The serve/llm block pool (DESIGN.md §4g) is donated by every program
+# that writes it — the runner's decode step and the cache's three
+# writers — and none is called with the array by name: each runs through
+# ``DevicePool.donate``, which rebinds the array it gets back.
 DONATED: Dict[str, Tuple[int, ...]] = {
     "step_fn": (0,),
+    "llm_decode_step": (0,),
+    "kv_write_rows": (0,),
+    "kv_scatter_prefill": (0,),
+    "kv_load_block": (0,),
 }
 
 # compile_budget site -> declared steady-state compile ceiling (count
@@ -564,6 +577,10 @@ COMPILE_BUDGETS: Dict[str, int] = {
     # ceilings)
     "llm.prefill": 6,
     "llm.decode": 5,
+    # kv_cache: the pool's donating writers — one scatter program per
+    # prefill bucket (built with the bucket's model program, nested
+    # inside its llm.prefill region), write_token, load_block
+    "llm.kv_write": 8,
 }
 
 

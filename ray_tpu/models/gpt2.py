@@ -700,8 +700,8 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
     """One decode step over the paged KV pool.
 
     tokens/positions (B,) int32; kv_pool (N, L, 2, bs, H, D) — the
-    engine's shm-backed block pool (read-only here: the new token's K/V
-    is returned, not written); block_tables (B, MAXB) int32;
+    engine's block pool, on the device (read-only here: the new token's K/V
+    is returned, the runner's program writes it); block_tables (B, MAXB) int32;
     ctx_lens (B,) int32.  Returns (logits (B, V) f32,
     new_k (L, B, H, D), new_v (L, B, H, D)).
     """

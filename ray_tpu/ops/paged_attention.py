@@ -4,8 +4,9 @@ Reference design: PagedAttention (Kwon et al., SOSP '23 / vLLM) — the KV
 cache of a running sequence is not one contiguous region but a list of
 fixed-size *blocks* owned by an allocator; attention reads through a
 per-sequence **block table** (block indices into a shared pool).  The
-engine (``ray_tpu/serve/llm``) keeps the pool in a shared-memory segment
-so prefill/decode replicas and the data plane see the same bytes.
+engine (``ray_tpu/serve/llm``) keeps the pool on the device, one array
+that its programs take donated and hand back; replicas exchange blocks
+by explicit copies over the data plane.
 
 This module is the math: a jit-friendly gather-then-attend decode kernel
 over ``(num_blocks, block_size, n_kv, d)`` pools.  On the CPU rig (and
@@ -59,9 +60,9 @@ def paged_attention_decode(q: jax.Array, k_pool: jax.Array,
     ctx_lens: (B,) int32      — tokens already IN the pool per sequence
                                 (the new token is not in the pool yet).
     k_new, v_new: (B, KV, D)  — this token's key/value, attended in
-                                explicitly so the pool stays read-only
-                                inside the step (the engine writes it
-                                back to the shm block after the step).
+                                explicitly: the pool is only read here,
+                                and the runner's program writes the new
+                                K/V into it after these reads.
 
     Returns (B, H, D) in q.dtype.
     """

@@ -9,12 +9,12 @@ machinery this framework already has:
   immediately, and the lowest-priority sequence is preempted (blocks
   freed, re-prefilled later) under cache pressure.
 - **paged KV cache** per PagedAttention (Kwon et al., SOSP '23): the KV
-  cache is fixed-size blocks in a shared-memory pool with a block table
+  cache is fixed-size blocks in one pool on the device with a block table
   per sequence (``ops/paged_attention.py``), so memory is allocated in
   block grains, prefilled cache is exported/attached between replicas
   over the PR-4 streamed data plane instead of recomputed, and model
-  weights are shared across same-node replicas through the same shm
-  plane (``serve/llm/weights.py``).
+  weights are shared across same-node replicas through the shm plane
+  (``serve/llm/weights.py``).
 
 Entry points::
 

@@ -87,7 +87,7 @@ def llm_deployment(cfg: EngineConfig, *, num_replicas: int = 1,
                 replace(self._cfg, model=model_id)
             eng = LLMEngine(ecfg)
 
-            # engines hold a KV pool segment + an engine thread: the mux
+            # engines hold a KV pool on the device + an engine thread: the mux
             # LRU must tear an evicted engine down, not just drop it.
             # Async + offloaded: shutdown joins the engine thread (up to
             # 10s) and must not stall the replica's event loop mid-evict.
@@ -118,8 +118,7 @@ def llm_deployment(cfg: EngineConfig, *, num_replicas: int = 1,
         async def engine_stats(self) -> dict:
             import os as _os
             engine = await self._engine_for(self._cfg.model)
-            return dict(engine.stats(), pid=_os.getpid(),
-                        kv_segment=engine.cache.segment_path)
+            return dict(engine.stats(), pid=_os.getpid())
 
         def shutdown(self):
             """Serve graceful-drain hook (replica prepare_shutdown):

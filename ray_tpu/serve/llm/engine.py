@@ -4,11 +4,14 @@ One engine = one model on one replica process.  Requests enter through
 ``submit()`` (thread-safe, returns a token stream); a dedicated engine
 thread runs ``step()`` forever: drain new requests, plan the iteration
 (``scheduler.py``), execute a prefill or a bucketed decode batch
-(``model_runner.py``), write new KV into the shm block pool
-(``kv_cache.py``), push sampled tokens to the per-request streams.
+(``model_runner.py``), push sampled tokens to the per-request streams.
+New KV goes into the block pool (``kv_cache.py``) on the device, where
+the pool lives: the decode program writes its own token, prefill's K/V is
+scattered by a second program; only logits come to the host.
 
 Disaggregated prefill/decode rides the PR-4 data plane:
-``prefill_remote()`` copies the filled blocks into a tmpfs export spool
+``prefill_remote()`` copies the filled blocks from the device into a
+tmpfs export spool
 (under /dev/shm when available, so publish is a page-cache write) served
 by the engine's ``DataPlaneServer``; ``attach()`` on another engine
 pulls them with pooled streamed ``DataPlanePool`` pulls (sendfile from
@@ -32,8 +35,7 @@ import numpy as np
 from ray_tpu._private import rtlog
 from ray_tpu._private.config import GLOBAL_CONFIG
 from ray_tpu.serve.llm.config import EngineConfig, SamplingParams
-from ray_tpu.serve.llm.kv_cache import (NoFreeBlocks, PagedKVCache,
-                                        reap_orphan_segments)
+from ray_tpu.serve.llm.kv_cache import NoFreeBlocks, PagedKVCache
 from ray_tpu.serve.llm.model_runner import ModelRunner, _bucket
 from ray_tpu.serve.llm.scheduler import (FAILED, FINISHED, IterationScheduler,
                                          Plan, Sequence)
@@ -121,7 +123,6 @@ class LLMEngine:
                 f"largest decode batch bucket "
                 f"{cfg.decode_batch_buckets[-1]} < max_num_seqs "
                 f"{cfg.max_num_seqs}: a full batch could never compile")
-        reap_orphan_segments()
         from ray_tpu.serve.llm import weights as _weights
         _weights.reap_orphans()
         self.cfg = cfg
@@ -129,6 +130,7 @@ class LLMEngine:
         self.cache = PagedKVCache(
             cfg.num_blocks, self.runner.n_layer, cfg.block_size,
             self.runner.n_kv, self.runner.head_dim, dtype=np.float32)
+        self.runner.cache = self.cache
         self.sched = IterationScheduler(cfg.max_num_seqs,
                                         cfg.max_prefill_tokens,
                                         cfg.max_model_len)
@@ -348,11 +350,10 @@ class LLMEngine:
             return None
         with self._lock:
             self.prefill_steps += 1
+        # K/V never left the device: the scatter is the enqueue of a
+        # second device program
         with hot_span("llm.prefill.scatter", self.span_s):
-            self.cache.scatter_prefill(seq.seq_id,
-                                       np.asarray(ks, np.float32),
-                                       np.asarray(vs, np.float32),
-                                       len(seq.prompt))
+            self.cache.scatter_prefill(seq.seq_id, ks, vs, len(seq.prompt))
         # sampling step = tokens generated so far RELATIVE TO THE
         # ORIGINAL prompt, so a preemption re-prefill (k tokens folded
         # into the prompt) draws the same rng stream position as the
@@ -441,9 +442,10 @@ class LLMEngine:
                 poss[i] = s.ctx_len - 1
                 lens[i] = s.ctx_len - 1
         try:
-            logits, ks, vs = self.runner.decode(toks, poss,
-                                                self.cache.pool, tables,
-                                                lens)
+            # the step writes each new token's K/V into its slot itself;
+            # the K/V it also returns stay on the device, unread
+            logits, _, _ = self.runner.decode(toks, poss, self.cache.pool,
+                                              tables, lens)
         except BaseException:
             # return every slot reserved for THIS step, or every later
             # append_slot is off by one and the cache silently corrupts
@@ -455,10 +457,6 @@ class LLMEngine:
         self.decode_steps += 1
         with hot_span("llm.decode.commit", spans):
             for i, s in enumerate(batch):
-                blk, off, _grew = slots[s.seq_id]
-                self.cache.write_token(blk, off,
-                                       np.asarray(ks[:, i], np.float32),
-                                       np.asarray(vs[:, i], np.float32))
                 tok = self.runner.sample(logits[i], s.sampling,
                                          step=s.generated)
                 self._emit(s, tok)
@@ -513,7 +511,7 @@ class LLMEngine:
         base = "/dev/shm" if os.path.isdir("/dev/shm") else None
         reap_orphan_export_spools(base)
         # pid in the name so a SIGKILLed publisher's spool is reapable
-        # by the next engine on the node, like the KV pool segments
+        # by the next engine on the node
         spool = tempfile.mkdtemp(
             prefix=f"rtpu_llm_export_{os.getpid()}_", dir=base)
         server = DataPlaneServer(spool, host="127.0.0.1",
@@ -551,9 +549,7 @@ class LLMEngine:
             logits, ks, vs = self.runner.prefill(prompt)
             with self._lock:
                 self.prefill_steps += 1
-            self.cache.scatter_prefill(seq_id, np.asarray(ks, np.float32),
-                                       np.asarray(vs, np.float32),
-                                       len(prompt))
+            self.cache.scatter_prefill(seq_id, ks, vs, len(prompt))
             first = self.runner.sample(logits, sampling, step=0)
             srv = self._ensure_export_plane()
             oids = []
@@ -799,5 +795,6 @@ class LLMEngine:
                     admitted=self.admitted,
                     queue_wait_s=self.queue_wait_s,
                     requeue_wait_s=self.requeue_wait_s,
+                    kv_host_bytes=self.cache.host_bytes,
                     span_s={k: list(v) for k, v in
                             list(self.span_s.items())})

@@ -1,92 +1,58 @@
-"""Paged KV cache: a shm-backed block pool + per-sequence block tables.
+"""Paged KV cache: a device-resident block pool + per-sequence block tables.
 
-Layout (PagedAttention, Kwon et al. SOSP '23): the cache is ONE
-shared-memory segment (created through ``ShmObjectStore`` so it rides
-the same /dev/shm naming, accounting, and zero-copy mmap semantics as
-every other object) viewed as::
+Layout (PagedAttention, Kwon et al. SOSP '23): the cache is ONE device
+array::
 
     pool[num_blocks, n_layer, 2, block_size, n_kv, head_dim]
 
-Block-major: block ``i`` is a contiguous byte range — one ``tobytes()``
-slice is a complete, self-describing transfer unit for the data-plane
-export path (``engine.export_seq``), and the whole pool is what the
-bucketed decode step reads through the block table
+Block-major: block ``i`` is one contiguous slab — one ``block_bytes(i)``
+is a complete, self-describing transfer unit for the data-plane export
+path (``engine.prefill_remote`` / ``attach``), and the whole pool is what
+the bucketed decode step reads through the block table
 (``ops/paged_attention.py``).
 
-The allocator hands out block indices (free list), tracks a block table
-and a refcount per sequence, and frees in block grains — preemption
-under cache pressure returns exactly the preempted sequence's blocks.
-Shared blocks (an attached sequence re-exported, future prefix caching)
-are refcounted: ``free_seq`` returns a block to the free list only at
-refcount zero.
+The pool never leaves the device.  Every write to it is a jitted program
+that takes the array donated and hands it back (:class:`DevicePool`):
+the decode step writes its own new token (``model_runner.py``, through
+:func:`write_rows`), prefill scatters a prompt's K/V, ``attach`` loads
+imported blocks.  What crosses the host link is what the host asks for
+by name: a decode step's logits, and exported or imported blocks
+(``PagedKVCache.host_bytes`` counts the latter; the engine's own loop
+leaves it at 0).  A device pool dies with its process: there is no
+segment to unlink and nothing to reap.
 
-Crash hygiene: /dev/shm files outlive a SIGKILLed replica.  Segment
-names embed the owning pid; ``reap_orphan_segments()`` unlinks segments
-whose owner is gone — called at engine boot (each new engine sweeps its
-predecessors' wreckage) and by the chaos suite's assertions.
+The host keeps what is host work: the allocator hands out block indices
+(free list), tracks a block table and a refcount per sequence, and frees
+in block grains — preemption under cache pressure returns exactly the
+preempted sequence's blocks.  Shared blocks (an attached sequence
+re-exported, future prefix caching) are refcounted: ``free_seq`` returns
+a block to the free list only at refcount zero.
 """
 
 from __future__ import annotations
 
+import functools
+import operator
 import os
 import threading
-import uuid
-from typing import Dict, List, Optional
+from types import SimpleNamespace
+from typing import Dict, List
 
 import numpy as np
 
 from ray_tpu._private import rtlog
-from ray_tpu._private.shm_store import (ShmObjectStore, _seg_path,
-                                        _SHM_DIR, _PREFIX)
-from ray_tpu.exceptions import ObjectStoreFullError
+from ray_tpu._private.xla_watchdog import compile_budget
 
 logger = rtlog.get("serve.llm.kv")
-
-_POOL_TAG = "llmkv"
 
 
 class NoFreeBlocks(Exception):
     """Allocation failed: the pool is exhausted (caller should preempt)."""
 
 
-def pool_segment_name(pid: int, nonce: str) -> str:
-    return f"{_POOL_TAG}_{pid}_{nonce}"
-
-
-def reap_orphan_segments() -> List[str]:
-    """Unlink llmkv pool segments whose owning pid is dead.
-
-    A SIGKILLed replica cannot unlink its own segment; the file (and its
-    tmpfs pages) would leak until reboot.  Any engine boot — and the
-    chaos suite — sweeps them by the pid baked into the name."""
-    reaped = []
-    try:
-        names = os.listdir(_SHM_DIR)
-    except OSError:
-        return reaped
-    for name in names:
-        if not name.startswith(f"{_PREFIX}{_POOL_TAG}_"):
-            continue
-        try:
-            pid = int(name.split("_")[2])
-        except (IndexError, ValueError):
-            continue
-        if pid == os.getpid() or _pid_alive(pid):
-            continue
-        try:
-            os.unlink(_SHM_DIR / name)
-            reaped.append(name)
-        except OSError:
-            pass
-    if reaped:
-        logger.info("reaped %d orphaned KV pool segment(s): %s",
-                    len(reaped), reaped)
-    return reaped
-
-
 def reap_orphan_export_spools(base) -> List[str]:
-    """Remove rtpu_llm_export_<pid>_* spool dirs whose owner is dead
-    (the data-plane export half of :func:`reap_orphan_segments`)."""
+    """Remove rtpu_llm_export_<pid>_* spool dirs whose owner is dead: a
+    SIGKILLed prefill replica cannot remove its own tmpfs spool."""
     import shutil
     reaped = []
     if not base:
@@ -122,6 +88,127 @@ def _pid_alive(pid: int) -> bool:
     return True
 
 
+# write_rows makes one masked pass over the pool for up to this many rows
+# (a decode batch), and leaves more (a prompt) to XLA's scatter
+_ROWS_IN_ONE_PASS = 16
+
+
+def write_rows(pool, blocks, offsets, k, v):
+    """``pool[blocks[r], :, 0 / 1, offsets[r]] = k / v[:, r]`` for every
+    row ``r``, cast to the pool's type; traceable.
+
+    k, v: (L, R, KV, D).  A row whose block is ``>= num_blocks`` writes
+    nowhere: how a decode batch padded up to its bucket, and a prompt
+    padded up to its bucket, keep their padding out of live blocks.
+
+    The TPU compiler lays a pool of this shape out with the block index
+    on the lanes (no padding that way), so one token's K/V lies in every
+    sixteenth tile of the pool and any write costs a pass over it.  For
+    the few rows of a decode batch that pass is made directly: selects
+    fused into one read and one write of the pool (GPT-2 XL's 1.26 GB:
+    4.1 ms on the v5e).  XLA's scatter instead copies the pool to a
+    blocks-major layout and back (12.0 ms a decode step, 13.7-17.8 ms
+    for a prompt of 128-512 rows; PERF.md, PR 26), which many rows are
+    worth and 8 are not.  A pool held in a blocks-major layout would make
+    both cost what the rows cost (ROADMAP S10)."""
+    import jax.numpy as jnp
+    kv = jnp.stack([k, v], axis=2).astype(pool.dtype)       # (L, R, 2, KV, D)
+    if kv.shape[1] > _ROWS_IN_ONE_PASS:
+        return pool.at[blocks, :, :, offsets].set(jnp.moveaxis(kv, 1, 0),
+                                                  mode="drop")
+    num_blocks, block_size = pool.shape[0], pool.shape[3]
+    hit = (blocks[:, None, None] == jnp.arange(num_blocks)[:, None]) \
+        & (offsets[:, None, None] == jnp.arange(block_size))  # (R, N, bs)
+    for r in range(kv.shape[1]):
+        pool = jnp.where(hit[r][:, None, None, :, None, None],
+                         kv[:, r][None, :, :, None], pool)
+    return pool
+
+
+@functools.cache
+def _programs() -> SimpleNamespace:
+    """The pool's jitted programs, built on first use (importing this
+    module must not import jax: drivers import it).  The three writers
+    take the pool donated and return ``(pool, None)``, what
+    :meth:`DevicePool.donate` expects; block ids, offsets, tables and
+    lengths are traced, so a program is built once per shape of K/V,
+    never per value."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    def _write_rows(pool, blocks, offsets, k, v):
+        return write_rows(pool, blocks, offsets, k, v), None
+
+    def _scatter_prefill(pool, table, ks, vs, n_tokens):
+        # token t of the padded prompt -> slot t % bs of block table[t // bs];
+        # padding (t >= n_tokens) is sent out of range and dropped
+        bs = pool.shape[3]
+        t = jnp.arange(ks.shape[1])
+        blocks = jnp.where(t < n_tokens, table[t // bs], pool.shape[0])
+        return write_rows(pool, blocks, t % bs, ks, vs), None
+
+    def _load_block(pool, block_id, block):
+        return lax.dynamic_update_index_in_dim(pool, block, block_id, 0), None
+
+    # the names are rows of lock_watchdog.DONATED (jaxlint pins them)
+    kv_write_rows = jax.jit(_write_rows, donate_argnums=(0,))
+    kv_scatter_prefill = jax.jit(_scatter_prefill, donate_argnums=(0,))
+    kv_load_block = jax.jit(_load_block, donate_argnums=(0,))
+    read_block = jax.jit(
+        lambda pool, block_id: lax.dynamic_index_in_dim(
+            pool, block_id, 0, keepdims=False))
+    return SimpleNamespace(write_rows=kv_write_rows,
+                           scatter_prefill=kv_scatter_prefill,
+                           load_block=kv_load_block, read_block=read_block)
+
+
+class DevicePool:
+    """The block pool's device array, whoever holds it now.
+
+    A donating program deletes the array it was given and returns a new
+    one over the same memory, so nobody may keep the array itself:
+    callers keep this holder (``cache.pool``) and every program runs
+    through :meth:`donate` or :meth:`read`, which take and rebind the
+    array under one lock.  That lock is what lets ``attach`` load blocks
+    from its caller's thread while the engine's loop decodes: it is held
+    for the enqueue only, and the device runs the programs in the order
+    they were enqueued."""
+
+    def __init__(self, shape, dtype):
+        self.shape, self.dtype = tuple(shape), dtype
+        self._pool_lock = threading.Lock()
+        self._array = None                             # guarded by: _pool_lock
+        self.fill(0)
+
+    def donate(self, program, *args):
+        """Run ``program(array, *args) -> (array, result)``, which donates
+        the array; keep the array it returns and hand back the result."""
+        with self._pool_lock:
+            self._array, result = program(self._array, *args)
+        return result
+
+    def read(self, program, *args):
+        """``program(array, *args)``, for a program that only reads."""
+        with self._pool_lock:
+            return program(self._array, *args)
+
+    def __getitem__(self, index):
+        return self.read(operator.getitem, index)
+
+    def fill(self, value) -> None:
+        """A new array of ``value``.  The old one is waited for and
+        deleted first: an array that a program still has to write or read
+        keeps its memory past its deletion, the new one is allocated at
+        the enqueue, and two pools may not fit the device (seen on the
+        v5e: 2.52 GB in use after a fill of a 1.26 GB pool)."""
+        import jax.numpy as jnp
+        with self._pool_lock:
+            if self._array is not None:
+                self._array.block_until_ready().delete()
+            self._array = jnp.full(self.shape, value, self.dtype)
+
+
 class PagedKVCache:
     """Block pool + tables + refcounts for one engine instance."""
 
@@ -133,23 +220,18 @@ class PagedKVCache:
         self.dtype = np.dtype(dtype)
         self.block_nbytes = int(np.prod(self.block_shape)) * \
             self.dtype.itemsize
-        nbytes = self.block_nbytes * num_blocks
-        self._seg_name = pool_segment_name(os.getpid(), uuid.uuid4().hex[:8])
-        # ShmObjectStore.create gives the O_EXCL + rollback discipline and
-        # capacity accounting for free; the pool stays "unsealed" (mutable)
-        # for the engine's whole life and is deleted at close().
-        self._store = ShmObjectStore(capacity_bytes=nbytes + 1)
-        view, handle = self._store.create(self._seg_name, nbytes)
-        self._view = view
-        self._mm = handle
-        self.pool = np.frombuffer(view, dtype=self.dtype).reshape(
-            (num_blocks,) + self.block_shape)
+        self.pool = DevicePool((num_blocks,) + self.block_shape, self.dtype)
+        # one step region for the writers below (DESIGN.md §4q): a
+        # scatter program per prefill bucket, write_token, load_block
+        self._write_budget = compile_budget("llm.kv_write")
         self._lock = threading.Lock()
         self._free: List[int] = list(range(num_blocks - 1, -1, -1))  # guarded by: _lock
         self._tables: Dict[str, List[int]] = {}                      # guarded by: _lock
         self._fill: Dict[str, int] = {}                              # guarded by: _lock
         self._ref: Dict[int, int] = {}                               # guarded by: _lock
-        self._closed = False                                         # guarded by: _lock
+        # bytes of pool data that crossed between host and device, either
+        # way: K/V given as numpy, blocks exported or imported
+        self.host_bytes = 0                                          # guarded by: _lock
 
     # ------------------------------------------------------------ allocation
     def blocks_needed(self, n_tokens: int) -> int:
@@ -265,59 +347,62 @@ class PagedKVCache:
             return list(self._tables)
 
     # ------------------------------------------------------- block transfer
+    def _crossed(self, nbytes: int) -> None:
+        with self._lock:
+            self.host_bytes += nbytes
+
+    def _write(self, program, *args, host=()) -> None:
+        """One donating write; ``host`` are the arguments that cross the
+        host link when they are numpy."""
+        self._crossed(sum(a.nbytes for a in host
+                          if isinstance(a, np.ndarray)))
+        with self._write_budget:
+            self.pool.donate(program, *args)
+
     def block_bytes(self, block_id: int) -> bytes:
-        """One block's contiguous bytes (the data-plane export unit)."""
-        return self.pool[block_id].tobytes()
+        """One block's contiguous bytes (the data-plane export unit),
+        copied from the device."""
+        block = self.pool.read(_programs().read_block, np.int32(block_id))
+        self._crossed(self.block_nbytes)
+        return np.asarray(block).tobytes()
 
     def load_block(self, block_id: int, raw) -> None:
-        np.copyto(self.pool[block_id],
-                  np.frombuffer(raw, dtype=self.dtype).reshape(
-                      self.block_shape))
+        """Copy one imported block's bytes to the device, into its block."""
+        block = np.frombuffer(raw, dtype=self.dtype).reshape(self.block_shape)
+        self._write(_programs().load_block, np.int32(block_id), block,
+                    host=(block,))
 
-    def scatter_prefill(self, seq_id: str, ks: np.ndarray,
-                        vs: np.ndarray, n_tokens: int) -> None:
-        """Write prefill KV (L, T_pad, KV, D) into the seq's blocks
-        (only the first ``n_tokens`` positions are real)."""
-        table = self.table(seq_id)
-        bs = self.block_size
-        for i, b in enumerate(table):
-            lo = i * bs
-            hi = min(n_tokens, lo + bs)
-            if hi <= lo:
-                break
-            self.pool[b, :, 0, :hi - lo] = ks[:, lo:hi]
-            self.pool[b, :, 1, :hi - lo] = vs[:, lo:hi]
+    def scatter_prefill(self, seq_id: str, ks, vs, n_tokens: int) -> None:
+        """Write prefill KV (L, T_pad, KV, D), numpy or device arrays of
+        any float type, into the seq's blocks (only the first
+        ``n_tokens`` positions are real, and only they are written)."""
+        self._scatter(self.table(seq_id), ks, vs, n_tokens)
 
-    def write_token(self, block_id: int, offset: int, k: np.ndarray,
-                    v: np.ndarray) -> None:
-        """Write one decoded token's (L, KV, D) K/V into its slot."""
-        self.pool[block_id, :, 0, offset] = k
-        self.pool[block_id, :, 1, offset] = v
+    def warm_scatter(self, ks, vs) -> None:
+        """Build the scatter program for this shape of K/V without
+        writing a token (the runner calls it with a bucket's first
+        prefill, so the bucket's two programs are built together)."""
+        self._scatter([], ks, vs, 0)
+
+    def _scatter(self, table: List[int], ks, vs, n_tokens: int) -> None:
+        # the table at the width of the padded prompt; the blocks past
+        # the sequence's own are out of range, and dropped on the device
+        padded = np.full(-(-ks.shape[1] // self.block_size),
+                         self.num_blocks, np.int32)
+        padded[:len(table)] = table[:len(padded)]
+        self._write(_programs().scatter_prefill, padded, ks, vs,
+                    np.int32(n_tokens), host=(ks, vs))
+
+    def write_token(self, block_id: int, offset: int, k, v) -> None:
+        """Write one token's (L, KV, D) K/V into its slot.  The decode
+        step writes its own (``ModelRunner.decode``); this is for a
+        caller that holds K/V from elsewhere, and rewriting what a step
+        wrote changes nothing."""
+        self._write(_programs().write_rows, np.int32([block_id]),
+                    np.int32([offset]), k[:, None], v[:, None], host=(k, v))
 
     # -------------------------------------------------------------- teardown
     def close(self) -> None:
-        """Unmap and unlink the pool segment (idempotent)."""
-        with self._lock:
-            if self._closed:
-                return
-            self._closed = True
-        self.pool = None   # drop the ndarray ref before releasing its buffer
-        try:
-            self._view.release()
-        except (BufferError, ValueError):
-            pass
-        try:
-            self._mm.close()
-        except (BufferError, ValueError):
-            pass
-        self._store.delete_object(self._seg_name)
-
-    def __del__(self):  # pragma: no cover - best effort
-        try:
-            self.close()
-        except Exception:
-            pass
-
-    @property
-    def segment_path(self) -> str:
-        return str(_seg_path(self._seg_name))
+        """Drop the pool: its device memory goes with the last program
+        that uses it (idempotent)."""
+        self.pool = None

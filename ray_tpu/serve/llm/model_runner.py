@@ -13,15 +13,20 @@ equivalent of connection pooling).
 The runner is model-family-agnostic: ``models/gpt2.py`` and
 ``models/llama.py`` each export ``forward_prefill`` / ``forward_decode``
 (the decode step reads the paged pool through
-``ops/paged_attention.py``); sampling (greedy / temperature / top-k)
-happens host-side on the (B, V) logits.
+``ops/paged_attention.py`` and returns the new token's K/V); the runner's
+decode program then writes that K/V into the pool, which it was given
+donated, so the pool stays on the device (``kv_cache.DevicePool``) and
+both families get the write-back from one place.  Prefill leaves a
+prompt's K/V on the device for ``PagedKVCache.scatter_prefill``.  What
+comes to the host is the logits: sampling (greedy / temperature / top-k)
+happens host-side on (V,) rows.
 """
 
 from __future__ import annotations
 
 import contextlib
 from functools import partial
-from typing import Tuple
+from typing import Optional, Tuple
 
 import numpy as np
 
@@ -29,9 +34,13 @@ from ray_tpu._private import rtlog
 from ray_tpu._private.xla_watchdog import compile_budget
 from ray_tpu.serve.llm.config import EngineConfig, SamplingParams, \
     resolve_model
+from ray_tpu.serve.llm.kv_cache import DevicePool, PagedKVCache, write_rows
 from ray_tpu.util.tracing import hot_span
 
 logger = rtlog.get("serve.llm.runner")
+
+
+_SEEN = contextlib.nullcontext()     # _note_shape: nothing to build
 
 
 def _bucket(n: int, buckets) -> int:
@@ -46,6 +55,7 @@ class ModelRunner:
 
     def __init__(self, cfg: EngineConfig, params=None):
         import jax
+        import jax.numpy as jnp
 
         self.cfg = cfg
         self.mod, self.mcfg = resolve_model(cfg)
@@ -57,10 +67,37 @@ class ModelRunner:
         self.n_kv = getattr(self.mcfg, "n_kv_head", self.mcfg.n_head)
         self.head_dim = self.mcfg.head_dim
         self.vocab = self.mcfg.vocab_size
-        self._prefill = jax.jit(partial(self.mod.forward_prefill,
-                                        cfg=self.mcfg))
-        self._decode = jax.jit(partial(self.mod.forward_decode,
-                                       cfg=self.mcfg))
+        forward_prefill = partial(self.mod.forward_prefill, cfg=self.mcfg)
+        forward_decode = partial(self.mod.forward_decode, cfg=self.mcfg)
+
+        def prefill_step(params, toks, last_pos):
+            logits, ks, vs = forward_prefill(params, toks, last_pos=last_pos)
+            return logits[0], ks[:, 0], vs[:, 0]
+
+        def decode_step(pool, params, tokens, positions, block_tables,
+                        ctx_lens, n_real):
+            # the model reads the pool and attends the new token
+            # explicitly; its K/V goes to the slot append_slot reserved,
+            # (table[ctx // bs], ctx % bs), after the reads.  Rows padded
+            # up to the bucket are sent out of range: they write nowhere
+            logits, k, v = forward_decode(params, tokens, positions, pool,
+                                          block_tables, ctx_lens)
+            bs = pool.shape[3]
+            rows = jnp.arange(tokens.shape[0])
+            blocks = jnp.where(rows < n_real,
+                               block_tables[rows, ctx_lens // bs],
+                               pool.shape[0])
+            pool = write_rows(pool, blocks, ctx_lens % bs, k, v)
+            return pool, (logits, k, v)
+
+        # bound to a name of its own: jaxlint pins a donating jit by the
+        # name it is assigned to (lock_watchdog.DONATED)
+        llm_decode_step = jax.jit(decode_step, donate_argnums=(0,))
+        self._prefill = jax.jit(prefill_step)
+        self._decode = llm_decode_step
+        # the engine's cache: a bucket's scatter program is built with
+        # the bucket's first prefill (None: a runner on its own)
+        self.cache: Optional[PagedKVCache] = None
         self.compiles = 0          # observability: distinct programs built
         self._shapes_seen: set = set()
         # hot-span totals, name -> [count, seconds]; the engine shares
@@ -68,8 +105,8 @@ class ModelRunner:
         self.span_s: dict = {}
         # XLA watchdog step regions (DESIGN.md §4q): one compile per
         # bucket for the runner's life, zero host transfers inside the
-        # dispatch.  The post-dispatch np.asarray pulls are designed
-        # syncs and sit OUTSIDE the regions.
+        # dispatch.  The post-dispatch np.asarray pulls of the logits
+        # are designed syncs and sit OUTSIDE the regions.
         self._prefill_budget = compile_budget(
             "llm.prefill", len(cfg.prefill_len_buckets))
         self._decode_budget = compile_budget(
@@ -86,13 +123,14 @@ class ModelRunner:
         return init()
 
     # ---------------------------------------------------------------- prefill
-    def prefill(self, token_ids) -> Tuple[np.ndarray, np.ndarray,
-                                          np.ndarray]:
-        """One prompt → (last-position logits (V,), k, v (L, T, KV, D)).
+    def prefill(self, token_ids) -> Tuple[np.ndarray, "jax.Array",
+                                          "jax.Array"]:
+        """One prompt → (last-position logits (V,) on the host, k, v
+        (L, T, KV, D) on the device).
 
         The prompt is padded to its length bucket; KV for pad positions
-        is garbage and never referenced (the block table fill stops at
-        the true length)."""
+        is garbage and never written (``scatter_prefill`` stops at the
+        true length)."""
         import jax.numpy as jnp
         n = len(token_ids)
         tb = _bucket(n, self.cfg.prefill_len_buckets)
@@ -100,32 +138,33 @@ class ModelRunner:
         toks = np.zeros((1, tb), np.int32)
         toks[0, :n] = token_ids
         # last_pos is TRACED (one compile per bucket, not per length);
-        # only the last real position's (1, V) logits come back to host
+        # only the last real position's (V,) logits come back to host
         last_pos = jnp.int32(n - 1)
         # dispatch ends at the ENQUEUE (the jitted call returns before
-        # the device finishes); pull ends when the results are on the host
+        # the device finishes); pull ends when the logits are on the host
         with compiling, hot_span("llm.prefill.dispatch", self.span_s), \
                 self._prefill_budget:
-            logits, ks, vs = self._prefill(self.params, toks,
-                                           last_pos=last_pos)
+            logits, ks, vs = self._prefill(self.params, toks, last_pos)
+            if compiling is not _SEEN and self.cache is not None:
+                self.cache.warm_scatter(ks, vs)
         with hot_span("llm.prefill.pull", self.span_s):
-            logits = np.asarray(logits)[0]                       # (V,)
-            ks = np.asarray(ks)[:, 0]                            # (L,T,KV,D)
-            vs = np.asarray(vs)[:, 0]
+            logits = np.asarray(logits)                          # (V,)
         return logits, ks, vs
 
     # ----------------------------------------------------------------- decode
     def decode(self, tokens: np.ndarray, positions: np.ndarray,
-               kv_pool: np.ndarray, block_tables: np.ndarray,
-               ctx_lens: np.ndarray) -> Tuple[np.ndarray, np.ndarray,
-                                              np.ndarray]:
+               kv_pool: DevicePool, block_tables: np.ndarray,
+               ctx_lens: np.ndarray) -> Tuple[np.ndarray, "jax.Array",
+                                              "jax.Array"]:
         """One iteration over a batch of sequences.
 
         tokens/positions/ctx_lens (B,); block_tables (B, MAXB);
-        kv_pool — the cache's shm-backed ndarray, passed whole (the
-        device copy is the CPU rig's stand-in for the pool living in
-        HBM).  Returns (logits (B, V), new_k, new_v (L, B, KV, D));
-        only the first B rows are real after bucket padding.
+        kv_pool — the cache's ``pool``: the holder of the device array,
+        which the step takes donated and hands back with each row's new
+        K/V written at ``(block_tables[i, ctx_lens[i] // block_size],
+        ctx_lens[i] % block_size)``.  Returns (logits (B, V) on the host,
+        new_k, new_v (L, bucket, KV, D) on the device; only the first B
+        rows are real after bucket padding).
         """
         b = len(tokens)
         bb = _bucket(b, self.cfg.decode_batch_buckets)
@@ -141,24 +180,24 @@ class ModelRunner:
                 block_tables = np.concatenate(
                     [block_tables, np.zeros((pad, block_tables.shape[1]),
                                             np.int32)])
-        # dispatch holds the whole jitted call, the numpy pool argument's
-        # way to the device included, and ends at the ENQUEUE; pull ends
-        # when logits and the new K/V are on the host
+        # dispatch holds the jitted call and ends at the ENQUEUE; pull
+        # ends when the logits are on the host, so it holds the wait for
+        # the step and nothing else: the pool stays where it is
         with compiling, hot_span("llm.decode.dispatch", self.span_s), \
                 self._decode_budget:
-            logits, ks, vs = self._decode(self.params, tokens,
-                                          positions, kv_pool,
-                                          block_tables, ctx_lens)
+            logits, ks, vs = kv_pool.donate(
+                self._decode, self.params, tokens, positions, block_tables,
+                ctx_lens, np.int32(b))
         with hot_span("llm.decode.pull", self.span_s):
-            return (np.asarray(logits)[:b], np.asarray(ks)[:, :b],
-                    np.asarray(vs)[:, :b])
+            logits = np.asarray(logits)[:b]
+        return logits, ks, vs
 
     def _note_shape(self, program: str, bucket: int):
         """A context for the call that follows: an ``llm.compile`` span
         around the first call of a (program, bucket), nothing after."""
         key = (program, bucket)
         if key in self._shapes_seen:
-            return contextlib.nullcontext()
+            return _SEEN
         self._shapes_seen.add(key)
         self.compiles += 1
         logger.info("compiling %s program (total %d)", key, self.compiles)

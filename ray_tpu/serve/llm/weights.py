@@ -13,7 +13,7 @@ Publication protocol (crash-safe, single-writer):
 - segment ``rtpu_llmw_<key>.<publisher_pid>`` holds header (json: leaf
   shapes/dtypes/offsets) + raw leaf bytes; the pid in the name makes a
   SIGKILLed publisher's segment recognizably orphaned, the same
-  discipline the KV pool segments use (``kv_cache.py``);
+  discipline the export spools use (``kv_cache.py``);
 - writers race on an O_EXCL ``.lock`` sentinel; the loser polls for a
   live publisher's ``.ready`` sentinel.  A writer that dies mid-publish
   leaves no ``.ready``; a stale lock (dead pid) is broken by rename

@@ -113,23 +113,6 @@ def test_kv_cache_alloc_refcount_and_pressure():
         c.close()
 
 
-def test_kv_pool_segment_lifecycle_and_orphan_reap(tmp_path):
-    c = PagedKVCache(num_blocks=2, n_layer=1, block_size=2, n_kv=1,
-                     head_dim=4)
-    path = c.segment_path
-    assert os.path.exists(path)
-    c.close()
-    assert not os.path.exists(path)
-    # orphan with a dead pid in the name gets reaped
-    orphan = os.path.join(os.path.dirname(path),
-                          "rtpu_llmkv_999999999_deadbeef")
-    with open(orphan, "wb") as f:
-        f.write(b"\0" * 64)
-    reaped = kvmod.reap_orphan_segments()
-    assert not os.path.exists(orphan)
-    assert any("999999999" in r for r in reaped)
-
-
 # ------------------------------------------------------ scheduler units
 def test_scheduler_admission_preempt_order():
     s = IterationScheduler(max_num_seqs=2, max_prefill_tokens=8,
@@ -307,6 +290,270 @@ def test_handoff_rejects_geometry_mismatch():
         dec.shutdown()
 
 
+# ------------------------------------------- the pool lives on the device
+def host_pool_decode(cfg, params, prompt, n):
+    """The semantics the device pool replaced, kept as the reference: a
+    numpy pool written on the host around ``forward_prefill`` /
+    ``forward_decode`` (greedy, one sequence)."""
+    import jax
+    mod, mcfg = resolve_model(cfg)
+    prefill = jax.jit(lambda p, t, last: mod.forward_prefill(
+        p, t, mcfg, last_pos=last))
+    decode = jax.jit(lambda *a: mod.forward_decode(*a, mcfg))
+    bs, maxb = cfg.block_size, cfg.max_blocks_per_seq
+    n_kv = getattr(mcfg, "n_kv_head", mcfg.n_head)
+    pool = np.zeros((cfg.num_blocks, mcfg.n_layer, 2, bs, n_kv,
+                     mcfg.head_dim), np.float32)
+    table = list(range(3, 3 + maxb))             # any distinct blocks
+    tb = next(b for b in cfg.prefill_len_buckets if len(prompt) <= b)
+    toks = np.zeros((1, tb), np.int32)
+    toks[0, :len(prompt)] = prompt
+    logits, ks, vs = prefill(params, toks, np.int32(len(prompt) - 1))
+    ks, vs = np.asarray(ks, np.float32)[:, 0], np.asarray(vs, np.float32)[:, 0]
+    for t in range(len(prompt)):
+        pool[table[t // bs], :, 0, t % bs] = ks[:, t]
+        pool[table[t // bs], :, 1, t % bs] = vs[:, t]
+    out = [int(np.argmax(np.asarray(logits)[0]))]
+    tables = np.asarray([table], np.int32)
+    while len(out) < n:
+        at = len(prompt) + len(out) - 1         # position of the last token
+        lens = np.asarray([at], np.int32)
+        logits, k, v = decode(params, np.asarray([out[-1]], np.int32), lens,
+                              pool, tables, lens)
+        pool[table[at // bs], :, 0, at % bs] = np.asarray(k, np.float32)[:, 0]
+        pool[table[at // bs], :, 1, at % bs] = np.asarray(v, np.float32)[:, 0]
+        out.append(int(np.argmax(np.asarray(logits)[0])))
+    return out
+
+
+@pytest.mark.parametrize("model", ["gpt2:tiny", "llama:tiny"])
+def test_device_pool_matches_host_pool_semantics(model):
+    """A mixed batch through the engine (pool on the device, written by
+    the step's own program) emits what the host-written numpy pool did."""
+    eng = LLMEngine(tiny_cfg(model=model))
+    try:
+        rng = np.random.default_rng(5)
+        jobs = [(rng.integers(1, 100, size=k).tolist(), n)
+                for k, n in ((3, 9), (11, 5), (17, 12))]
+        streams = [eng.submit(p, SamplingParams(max_tokens=n))
+                   for p, n in jobs]
+        outs = [s.tokens() for s in streams]
+        for (p, n), o in zip(jobs, outs):
+            assert o == host_pool_decode(eng.cfg, eng.runner.params, p, n)
+        assert eng.stats()["kv_host_bytes"] == 0
+    finally:
+        eng.shutdown()
+
+
+def _random_pool(cache, seed=0):
+    """Every block of the cache set to known random bytes; the copy."""
+    rng = np.random.default_rng(seed)
+    want = rng.standard_normal(
+        (cache.num_blocks,) + cache.block_shape).astype(np.float32)
+    for b in range(cache.num_blocks):
+        cache.load_block(b, want[b].tobytes())
+    return want
+
+
+@pytest.mark.parametrize("model", ["gpt2:tiny", "llama:tiny"])
+def test_padded_decode_rows_write_nowhere(model):
+    """3 sequences in a bucket of 8: the step writes their 3 slots and
+    leaves every other byte of the pool as it was — block 0, slot 0
+    included, which the padded rows' tables of zeros name."""
+    from ray_tpu.serve.llm.model_runner import ModelRunner
+    cfg = tiny_cfg(model=model, num_blocks=16, decode_batch_buckets=(8,),
+                   max_num_seqs=8)
+    runner = ModelRunner(cfg)
+    cache = PagedKVCache(cfg.num_blocks, runner.n_layer, cfg.block_size,
+                         runner.n_kv, runner.head_dim)
+    before = _random_pool(cache)
+    tables = np.zeros((3, cfg.max_blocks_per_seq), np.int32)
+    tables[:, :3] = [[5, 6, 7], [9, 2, 11], [12, 13, 1]]
+    lens = np.asarray([9, 16, 23], np.int32)     # slots (6,1) (11,0) (1,7)
+    logits, k, v = runner.decode(np.asarray([4, 5, 6], np.int32), lens,
+                                 cache.pool, tables, lens)
+    assert logits.shape == (3, runner.vocab)
+    after = np.asarray(cache.pool[:])
+    want = before.copy()
+    for i, (blk, off) in enumerate([(6, 1), (11, 0), (1, 7)]):
+        want[blk, :, 0, off] = np.asarray(k, np.float32)[:, i]
+        want[blk, :, 1, off] = np.asarray(v, np.float32)[:, i]
+    assert not np.array_equal(want, before)
+    np.testing.assert_array_equal(after, want)
+
+
+@pytest.mark.parametrize("t_pad", [16, 32])
+def test_prefill_scatter_writes_only_the_real_tokens(t_pad):
+    """The padded prompt's tail and the padded table's tail write
+    nowhere; numpy and device K/V run the same program.  16 rows go
+    through ``write_rows``' one pass over the pool, 32 through its
+    scatter: the same bytes either way."""
+    import jax.numpy as jnp
+    assert kvmod._ROWS_IN_ONE_PASS == 16
+    cache = PagedKVCache(num_blocks=8, n_layer=2, block_size=4, n_kv=2,
+                         head_dim=3)
+    before = _random_pool(cache, seed=1)
+    host0 = cache.host_bytes
+    rng = np.random.default_rng(2)
+    ks = rng.standard_normal((2, t_pad, 2, 3)).astype(np.float32)
+    vs = rng.standard_normal((2, t_pad, 2, 3)).astype(np.float32)
+    cache.alloc_seq("a", 6)                       # 2 blocks of the 4 padded
+    table = cache.table("a")
+    cache.scatter_prefill("a", jnp.asarray(ks), jnp.asarray(vs), 6)
+    assert cache.host_bytes == host0              # device K/V: nothing crossed
+    want = before.copy()
+    for t in range(6):
+        want[table[t // 4], :, 0, t % 4] = ks[:, t]
+        want[table[t // 4], :, 1, t % 4] = vs[:, t]
+    np.testing.assert_array_equal(np.asarray(cache.pool[:]), want)
+    cache.scatter_prefill("a", ks, vs, 6)         # numpy: same bytes, counted
+    np.testing.assert_array_equal(np.asarray(cache.pool[:]), want)
+    assert cache.host_bytes == host0 + ks.nbytes + vs.nbytes
+    assert cache.block_bytes(table[1]) == want[table[1]].tobytes()
+
+
+def test_kv_host_bytes_counts_only_exported_and_imported_blocks():
+    cfg = tiny_cfg()
+    pre, dec = LLMEngine(cfg), LLMEngine(cfg)
+    try:
+        sp = SamplingParams(max_tokens=7)
+        prompt = list(range(2, 21))               # 19 tokens: 3 blocks
+        pre.generate(prompt, sp)
+        dec.generate([4, 5], sp)
+        assert pre.stats()["decode_steps"] > 0
+        assert pre.stats()["kv_host_bytes"] == 0
+        assert dec.stats()["kv_host_bytes"] == 0
+        man = pre.prefill_remote(prompt, sp)
+        moved = len(man["blocks"]) * pre.cache.block_nbytes
+        assert len(man["blocks"]) == 3
+        assert pre.stats()["kv_host_bytes"] == moved
+        assert dec.attach(man, sp).tokens() == pre.generate(prompt, sp)
+        assert dec.stats()["kv_host_bytes"] == moved
+        assert pre.stats()["kv_host_bytes"] == moved
+    finally:
+        pre.shutdown()
+        dec.shutdown()
+
+
+def test_pool_programs_alias_the_pool_to_their_result():
+    """The decode step and the scatter take the pool donated: the
+    lowered programs alias argument 0 to result 0 (a copy of the pool
+    a step would be the transfer this design removed, in another place)."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.serve.llm.model_runner import ModelRunner
+    cfg = tiny_cfg(decode_batch_buckets=(4,))
+    runner = ModelRunner(cfg)
+    S = jax.ShapeDtypeStruct
+    pool = S((cfg.num_blocks, runner.n_layer, 2, cfg.block_size,
+              runner.n_kv, runner.head_dim), jnp.float32)
+    i32 = lambda *shape: S(shape, jnp.int32)      # noqa: E731
+    kv = S((runner.n_layer, 32, runner.n_kv, runner.head_dim), jnp.float32)
+    lowered = {
+        "decode": runner._decode.lower(
+            pool, runner.params, i32(4), i32(4),
+            i32(4, cfg.max_blocks_per_seq), i32(4), i32()),
+        "scatter": kvmod._programs().scatter_prefill.lower(
+            pool, i32(4), kv, kv, i32()),
+    }
+    for name, low in lowered.items():
+        assert "tf.aliasing_output = 0" in \
+            low.as_text().split("%arg1")[0], name
+        compiled = low.compile()
+        assert "input_output_alias={ {" in compiled.as_text(), name
+        assert compiled.memory_analysis().alias_size_in_bytes == \
+            int(np.prod(pool.shape)) * 4, name
+
+
+def test_attach_from_another_thread_while_the_loop_decodes(caplog):
+    """attach loads blocks into the pool from its caller's thread while
+    the engine's loop donates the same pool step after step: no program
+    sees a donated array, and every stream equals its solo run."""
+    import logging
+    import sys
+    import threading
+    cfg = tiny_cfg(max_num_seqs=4)
+    pre, dec = LLMEngine(cfg), LLMEngine(cfg)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        sp = SamplingParams(max_tokens=24)
+        prompts = [[3 + i, 5, 7, 11, 13, 17, 19, 23, 29] for i in range(6)]
+        solo = [pre.generate(p, sp) for p in prompts]
+        mans = [pre.prefill_remote(p, sp) for p in prompts[1:]]
+        outs, errs = {}, []
+
+        def adopt(i, man):
+            try:
+                outs[i] = dec.attach(man, sp).tokens()
+            except Exception as e:  # noqa: BLE001 - reported below
+                errs.append(repr(e))
+
+        with caplog.at_level(logging.ERROR):
+            first = dec.submit(prompts[0], sp)    # the loop is decoding
+            threads = [threading.Thread(target=adopt, args=(i + 1, m))
+                       for i, m in enumerate(mans)]
+            for t in threads:
+                t.start()
+            outs[0] = first.tokens()
+            for t in threads:
+                t.join(timeout=120 * time_scale())
+            assert not any(t.is_alive() for t in threads)
+        assert not errs, errs
+        assert not [r for r in caplog.records
+                    if "step failed" in r.getMessage()
+                    or "deleted" in r.getMessage()]
+        assert [outs[i] for i in range(6)] == solo
+        assert dec.prefill_steps == 1 and dec.decode_steps > 0
+    finally:
+        sys.setswitchinterval(old)
+        pre.shutdown()
+        dec.shutdown()
+
+
+def test_the_benchmark_harness_calls_keep_working():
+    """What ``perfbench/jobs/serve.py`` does to an engine it does not
+    own the code of: ``pool.fill(0)``, ``runner.decode`` twice on the
+    same ``cache.pool`` expression (the harness rebinds nothing),
+    ``scatter_prefill`` and ``write_token`` with numpy K/V after a step
+    that already wrote them."""
+    eng = LLMEngine(tiny_cfg(), start=False)
+    try:
+        runner, cache = eng.runner, eng.cache
+        cache.pool.fill(0)
+        assert not np.asarray(cache.pool[:]).any()
+        maxb = eng.cfg.max_blocks_per_seq
+        for _ in range(2):                        # _warm_programs
+            runner.decode(np.zeros(4, np.int32), np.zeros(4, np.int32),
+                          cache.pool, np.zeros((4, maxb), np.int32),
+                          np.ones(4, np.int32))
+        cache.pool.fill(0)
+        prompt = list(range(1, 12))               # check_logits
+        cache.alloc_seq("chk", len(prompt))
+        logits, ks, vs = runner.prefill(prompt)
+        assert logits.shape == (runner.vocab,)
+        cache.scatter_prefill("chk", np.asarray(ks, np.float32),
+                              np.asarray(vs, np.float32), len(prompt))
+        seq = prompt + [int(np.argmax(logits))]
+        blk, off, _ = cache.append_slot("chk")
+        tables = np.zeros((1, maxb), np.int32)
+        table = cache.table("chk")
+        tables[0, :len(table)] = table
+        at = np.asarray([len(seq) - 1], np.int32)
+        lg, ks, vs = runner.decode(np.asarray([seq[-1]], np.int32), at,
+                                   cache.pool, tables, at)
+        assert lg[0].shape == (runner.vocab,)
+        stepped = np.asarray(cache.pool[:])
+        assert stepped[blk, :, :, off].any()      # the step wrote the slot
+        cache.write_token(blk, off, np.asarray(ks[:, 0], np.float32),
+                          np.asarray(vs[:, 0], np.float32))
+        np.testing.assert_array_equal(np.asarray(cache.pool[:]), stepped)
+        assert eng.stats()["kv_host_bytes"] > 0   # the harness's numpy K/V
+        assert seq[-1] == oracle_decode(eng, prompt, 1)[0]
+    finally:
+        eng.shutdown()
+
+
 # ------------------------------------------------------- weights plane
 def test_weights_shared_through_shm_plane():
     from ray_tpu.serve.llm import weights as wmod
@@ -401,9 +648,9 @@ def test_naive_baseline_serves(ray_start_regular):
 def test_chaos_sigkill_decode_replica_no_leaked_kv(monkeypatch):
     """SIGKILL a decode replica mid-generation under the resource
     sanitizer: in-flight streams fail cleanly (RayServeError, not a
-    hang), the controller replaces the replica, new traffic flows, and
-    the killed process's shm KV pool segment is reaped — no leaked
-    blocks (ISSUE 6 satellite)."""
+    hang), the controller replaces the replica and new traffic flows.
+    The KV pool is device memory: it went with the killed process, and
+    there is no segment that could leak (ISSUE 6 satellite)."""
     import signal
 
     from ray_tpu import serve
@@ -416,8 +663,7 @@ def test_chaos_sigkill_decode_replica_no_leaked_kv(monkeypatch):
         warm = h.remote({"prompt": [1, 2], "max_tokens": 2}).result()
         assert len(list(warm)) == 2
         st = h.engine_stats.remote().result()
-        victim_pid, seg = st["pid"], st["kv_segment"]
-        assert os.path.exists(seg)
+        victim_pid = st["pid"]
         # long generation, token-granular stream; kill mid-flight
         gen = h.remote({"prompt": [3, 4, 5], "max_tokens": 48}).result()
         got = [next(gen), next(gen)]
@@ -426,8 +672,7 @@ def test_chaos_sigkill_decode_replica_no_leaked_kv(monkeypatch):
         with pytest.raises(ray_tpu.exceptions.RayServeError):
             for _ in gen:       # fails cleanly, never hangs
                 pass
-        # controller replaces the replica; a NEW request succeeds (its
-        # engine boot reaps the dead pid's orphaned pool segment)
+        # controller replaces the replica; a NEW request succeeds
         deadline = time.monotonic() + 240 * time_scale()
         out = None
         while time.monotonic() < deadline:
@@ -442,8 +687,6 @@ def test_chaos_sigkill_decode_replica_no_leaked_kv(monkeypatch):
         assert out is not None and len(out) == 3, out
         st2 = h.engine_stats.remote().result()
         assert st2["pid"] != victim_pid
-        assert not os.path.exists(seg), \
-            "killed replica's KV pool segment leaked"
         serve.shutdown()
     finally:
         try:
