@@ -40,8 +40,26 @@ class LlamaConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = True
-    attn_impl: str = "dense"     # dense | flash | ring | ulysses
+    # full: recompute the block in the backward.  attn: keep the flash
+    # kernel's output and lse (gpt2.py's policy of that name), so that the
+    # backward does not run the forward kernel again; needs flash.
+    remat_policy: str = "full"   # full | attn
+    # auto: the Pallas flash kernel where the code can see a TPU and the
+    # kernel's block tiles the sequence, XLA dense elsewhere
+    attn_impl: str = "dense"     # auto | dense | flash | ring | ulysses
     context_axis: Optional[str] = None
+    # RMSNorm over the whole projected q and k (own scales), before the
+    # split into heads and before RoPE (OLMoE)
+    qk_norm: bool = False
+    # > 0: the FFN is n_experts SwiGLU experts of width ffn_dim,
+    # experts_per_token of them a token, dropless (ops/moe.py); the loss
+    # gains the two router terms at these coefficients
+    n_experts: int = 0
+    experts_per_token: int = 0
+    router_aux_coef: float = 0.0
+    router_z_coef: float = 0.0
+    # False: wo and w_down start at 0.02 like every other matrix
+    scaled_residual_init: bool = True
 
     @property
     def head_dim(self) -> int:
@@ -63,33 +81,53 @@ def tiny(vocab: int = 128, seq: int = 64) -> LlamaConfig:
                        n_layer=2, n_head=4, n_kv_head=2, ffn_dim=128)
 
 
-PRESETS = {"llama2-7b": llama2_7b, "llama3-8b": llama3_8b, "tiny": tiny}
+def tiny_moe(vocab: int = 200, seq: int = 48) -> LlamaConfig:
+    """OLMoE's block at a test's size: QK-norm, 8 experts, 2 a token."""
+    return LlamaConfig(vocab_size=vocab, max_positions=seq, n_embd=64,
+                       n_layer=2, n_head=4, n_kv_head=4, ffn_dim=32,
+                       qk_norm=True, n_experts=8, experts_per_token=2,
+                       router_aux_coef=0.01, router_z_coef=0.001,
+                       scaled_residual_init=False)
+
+
+PRESETS = {"llama2-7b": llama2_7b, "llama3-8b": llama3_8b, "tiny": tiny,
+           "tiny-moe": tiny_moe}
 
 
 # ------------------------------------------------------------------- params
 def init_params(rng: jax.Array, cfg: LlamaConfig) -> Params:
+    """Block leaves are stacked on a leading n_layer axis (and the experts'
+    on an n_experts axis behind it), each drawn in one call."""
     pd = cfg.param_dtype
-    E, L = cfg.n_embd, cfg.n_layer
+    E, L, F = cfg.n_embd, cfg.n_layer, cfg.ffn_dim
     kv_dim = cfg.n_kv_head * cfg.head_dim
-    k = iter(jax.random.split(rng, 4 + 7 * L))
-    scale = 0.02
-    out_scale = 0.02 / math.sqrt(2 * L)
+    k = iter(jax.random.split(rng, 10))
+    out_scale = 0.02 / math.sqrt(2 * L) if cfg.scaled_residual_init else 0.02
 
-    def stack(shape, s=scale):
-        return jnp.stack([normal_init(next(k), shape, pd, s)
-                          for _ in range(L)])
+    def stacked(*shape, scale=0.02):
+        return normal_init(next(k), (L, *shape), pd, scale)
 
     blocks = {
         "attn_norm": {"scale": jnp.ones((L, E), pd)},
-        "wq": {"kernel": stack((E, E))},
-        "wk": {"kernel": stack((E, kv_dim))},
-        "wv": {"kernel": stack((E, kv_dim))},
-        "wo": {"kernel": stack((E, E), out_scale)},
+        "wq": {"kernel": stacked(E, E)},
+        "wk": {"kernel": stacked(E, kv_dim)},
+        "wv": {"kernel": stacked(E, kv_dim)},
+        "wo": {"kernel": stacked(E, E, scale=out_scale)},
         "mlp_norm": {"scale": jnp.ones((L, E), pd)},
-        "w_gate": {"kernel": stack((E, cfg.ffn_dim))},
-        "w_up": {"kernel": stack((E, cfg.ffn_dim))},
-        "w_down": {"kernel": stack((cfg.ffn_dim, E), out_scale)},
     }
+    if cfg.qk_norm:
+        blocks["q_norm"] = {"scale": jnp.ones((L, E), pd)}
+        blocks["k_norm"] = {"scale": jnp.ones((L, kv_dim), pd)}
+    if cfg.n_experts:
+        X = cfg.n_experts
+        blocks["router"] = {"kernel": stacked(E, X)}
+        blocks["experts"] = {"w_gate": stacked(X, E, F),
+                             "w_up": stacked(X, E, F),
+                             "w_down": stacked(X, F, E, scale=out_scale)}
+    else:
+        blocks["w_gate"] = {"kernel": stacked(E, F)}
+        blocks["w_up"] = {"kernel": stacked(E, F)}
+        blocks["w_down"] = {"kernel": stacked(F, E, scale=out_scale)}
     return {
         "wte": normal_init(next(k), (cfg.vocab_size, E), pd),
         "blocks": blocks,
@@ -127,60 +165,116 @@ def _gqa_expand(kv: jax.Array, n_head: int) -> jax.Array:
     return jnp.repeat(kv, rep, axis=2)
 
 
+def _resolved_attn_impl(cfg: LlamaConfig, seq_len: int) -> str:
+    if cfg.attn_impl != "auto":
+        return cfg.attn_impl
+    from ray_tpu.models.gpt2 import _flash_tiles
+    on_chip = jax.default_backend() == "tpu"
+    return "flash" if on_chip and _flash_tiles(seq_len) else "dense"
+
+
 def _attention(q, k, v, cfg: LlamaConfig):
-    if cfg.attn_impl == "dense":
+    impl = _resolved_attn_impl(cfg, q.shape[1])
+    if impl == "dense":
         from ray_tpu.models.gpt2 import dense_causal_attention
         return dense_causal_attention(q, k, v, None)
-    if cfg.attn_impl == "flash":
+    if impl == "flash":
         from ray_tpu.ops.flash_attention import flash_attention
         return flash_attention(q, k, v, True)
-    if cfg.attn_impl == "ring":
+    if impl == "ring":
         from ray_tpu.ops.ring_attention import ring_attention_for_model
         return ring_attention_for_model(q, k, v, cfg,
                                         axis_name=cfg.context_axis)
-    if cfg.attn_impl == "ulysses":
+    if impl == "ulysses":
         from ray_tpu.ops.ulysses import ulysses_attention_for_model
         return ulysses_attention_for_model(q, k, v, cfg,
                                            axis_name=cfg.context_axis)
     raise ValueError(f"unknown attn_impl {cfg.attn_impl!r}")
 
 
-def _block(x: jax.Array, lp: Params, cfg: LlamaConfig,
-           collect_kv: bool = False):
-    """One decoder block; with ``collect_kv`` also returns the post-RoPE
-    pre-GQA-expand (k, v) — the SAME body serves training and the
-    serving engine's prefill cache fill, so the paths cannot diverge."""
-    B, T, E = x.shape
+def _qkv(h: jax.Array, lp: Params, cfg: LlamaConfig):
+    """Normed hidden states (..., E) -> q (..., H, D), k, v (..., KV, D),
+    before RoPE."""
     H, D, KV = cfg.n_head, cfg.head_dim, cfg.n_kv_head
-    h = _rms_norm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
-    q = (h @ lp["wq"]["kernel"].astype(cfg.dtype)).reshape(B, T, H, D)
-    k = (h @ lp["wk"]["kernel"].astype(cfg.dtype)).reshape(B, T, KV, D)
-    v = (h @ lp["wv"]["kernel"].astype(cfg.dtype)).reshape(B, T, KV, D)
-    q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
-    ke, ve = _gqa_expand(k, H), _gqa_expand(v, H)
-    a = _attention(q, ke, ve, cfg).reshape(B, T, E)
-    x = x + a @ lp["wo"]["kernel"].astype(cfg.dtype)
-    h = _rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
+    q = h @ lp["wq"]["kernel"].astype(cfg.dtype)
+    k = h @ lp["wk"]["kernel"].astype(cfg.dtype)
+    v = h @ lp["wv"]["kernel"].astype(cfg.dtype)
+    if cfg.qk_norm:
+        with jax.named_scope("qk_norm"):
+            q = _rms_norm(q, lp["q_norm"]["scale"], cfg.rms_eps)
+            k = _rms_norm(k, lp["k_norm"]["scale"], cfg.rms_eps)
+    lead = h.shape[:-1]
+    return (q.reshape(*lead, H, D), k.reshape(*lead, KV, D),
+            v.reshape(*lead, KV, D))
+
+
+def _ffn(h: jax.Array, lp: Params, cfg: LlamaConfig):
+    """The block's feed-forward on normed hidden states (..., E): one
+    SwiGLU, or the dropless experts.  Returns (out, RouterStats | None);
+    training, prefill and decode all come through here."""
+    if cfg.n_experts:
+        from ray_tpu.ops.moe import dropless_moe_ffn
+        ex = lp["experts"]
+        out, stats = dropless_moe_ffn(
+            h.reshape(-1, h.shape[-1]), lp["router"]["kernel"],
+            ex["w_gate"], ex["w_up"], ex["w_down"], k=cfg.experts_per_token)
+        return out.reshape(h.shape), stats
     gate = jax.nn.silu(h @ lp["w_gate"]["kernel"].astype(cfg.dtype))
     up = h @ lp["w_up"]["kernel"].astype(cfg.dtype)
-    out = x + (gate * up) @ lp["w_down"]["kernel"].astype(cfg.dtype)
+    return (gate * up) @ lp["w_down"]["kernel"].astype(cfg.dtype), None
+
+
+def _block(x: jax.Array, lp: Params, cfg: LlamaConfig,
+           collect_kv: bool = False):
+    """One decoder block -> (out, RouterStats | None); with ``collect_kv``
+    -> (out, (k, v)), post-RoPE and pre-GQA-expand: the SAME body serves
+    training and the serving engine's prefill cache fill, so the paths
+    cannot diverge."""
+    B, T, E = x.shape
+    H = cfg.n_head
+    h = _rms_norm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
+    q, k, v = _qkv(h, lp, cfg)
+    with jax.named_scope("rope"):
+        q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
+    ke, ve = _gqa_expand(k, H), _gqa_expand(v, H)
+    with jax.named_scope("attn"):
+        a = _attention(q, ke, ve, cfg).reshape(B, T, E)
+    x = x + a @ lp["wo"]["kernel"].astype(cfg.dtype)
+    h = _rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
+    f, stats = _ffn(h, lp, cfg)
+    out = x + f
     if collect_kv:
         return out, (k, v)
-    return out
+    return out, stats
+
+
+def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig):
+    """tokens (B, T) int32 -> (final-norm hidden states (B, T, E) in
+    cfg.dtype, the layers' RouterStats stacked on a leading n_layer axis,
+    or None for a dense model)."""
+    x = params["wte"].astype(cfg.dtype)[tokens]
+    block = partial(_block, cfg=cfg)
+    if cfg.remat and cfg.remat_policy == "attn":
+        impl = _resolved_attn_impl(cfg, tokens.shape[1])
+        if impl != "flash":
+            raise ValueError("remat_policy='attn' keeps what only the flash "
+                             f"kernel names; attn_impl resolves to {impl!r}")
+        block = jax.checkpoint(
+            block, policy=jax.checkpoint_policies.save_only_these_names(
+                "flash_attn_out", "flash_attn_lse"))
+    elif cfg.remat and cfg.remat_policy == "full":
+        block = jax.checkpoint(block)
+    elif cfg.remat:
+        raise ValueError(f"unknown remat_policy {cfg.remat_policy!r} "
+                         "(expected 'full' or 'attn')")
+
+    x, stats = lax.scan(block, x, params["blocks"])
+    return _rms_norm(x, params["norm_f"]["scale"], cfg.rms_eps), stats
 
 
 def forward(params: Params, tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:
     """tokens (B, T) int32 → logits (B, T, vocab) f32."""
-    x = params["wte"].astype(cfg.dtype)[tokens]
-    block = partial(_block, cfg=cfg)
-    if cfg.remat:
-        block = jax.checkpoint(block)
-
-    def body(carry, lp):
-        return block(carry, lp), None
-
-    x, _ = lax.scan(body, x, params["blocks"])
-    x = _rms_norm(x, params["norm_f"]["scale"], cfg.rms_eps)
+    x, _ = forward_hidden(params, tokens, cfg)
     logits = x @ params["lm_head"]["kernel"].astype(cfg.dtype)
     return logits.astype(jnp.float32)
 
@@ -237,7 +331,7 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
     new_k (L, B, KV, D), new_v (L, B, KV, D))."""
     from ray_tpu.ops.paged_attention import paged_attention_decode
     B = tokens.shape[0]
-    E, H, D, KV = cfg.n_embd, cfg.n_head, cfg.head_dim, cfg.n_kv_head
+    E = cfg.n_embd
     x = params["wte"].astype(cfg.dtype)[tokens]                 # (B, E)
     k_pools = kv_pool[:, :, 0].transpose(1, 0, 2, 3, 4)
     v_pools = kv_pool[:, :, 1].transpose(1, 0, 2, 3, 4)
@@ -246,18 +340,14 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
         x = carry
         lp, k_pool, v_pool = xs
         h = _rms_norm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
-        q = (h @ lp["wq"]["kernel"].astype(cfg.dtype)).reshape(B, H, D)
-        k = (h @ lp["wk"]["kernel"].astype(cfg.dtype)).reshape(B, KV, D)
-        v = (h @ lp["wv"]["kernel"].astype(cfg.dtype)).reshape(B, KV, D)
+        q, k, v = _qkv(h, lp, cfg)
         q = _rope_at(q, positions, cfg.rope_theta)
         k = _rope_at(k, positions, cfg.rope_theta)
         a = paged_attention_decode(q, k_pool, v_pool, block_tables,
                                    ctx_lens, k, v).reshape(B, E)
         x = x + a @ lp["wo"]["kernel"].astype(cfg.dtype)
         h = _rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
-        gate = jax.nn.silu(h @ lp["w_gate"]["kernel"].astype(cfg.dtype))
-        up = h @ lp["w_up"]["kernel"].astype(cfg.dtype)
-        x = x + (gate * up) @ lp["w_down"]["kernel"].astype(cfg.dtype)
+        x = x + _ffn(h, lp, cfg)[0]
         return x, (k, v)
 
     x, (ks, vs) = lax.scan(body, x, (params["blocks"], k_pools, v_pools))
@@ -266,15 +356,40 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
     return logits.astype(jnp.float32), ks, vs
 
 
+def _cross_entropy(x: jax.Array, head: jax.Array,
+                   tgt: jax.Array) -> jax.Array:
+    """Mean next-token NLL of hidden states x (B, T, E) under the head
+    (E, V), as gpt2.loss_fn computes it: logsumexp of the float32 logits
+    less the target's logit read from the activation-dtype logits, so no
+    float32 (B, T, V) tensor exists."""
+    with jax.named_scope("lm_head"):
+        logits = x @ head
+    with jax.named_scope("loss_ce"):
+        lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+        correct = jnp.take_along_axis(logits, tgt[..., None], -1)[..., 0]
+        return (lse - correct.astype(jnp.float32)).mean()
+
+
 def loss_fn(params: Params, batch: Dict[str, jax.Array],
             cfg: LlamaConfig) -> jax.Array:
+    """Next-token cross entropy, a scalar.  With experts it also holds the
+    two router terms (balance and z, each a mean over layers) at the
+    configuration's coefficients, and hands both and the worst expert load
+    to the train step's metrics (spmd.report_step_metrics)."""
     if "inputs" in batch:
         inp, tgt = batch["inputs"], batch["targets"]
     else:
         inp, tgt = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
-    logits = forward(params, inp, cfg)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    return -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0].mean()
+    x, stats = forward_hidden(params, inp, cfg)
+    loss = _cross_entropy(x, params["lm_head"]["kernel"].astype(cfg.dtype),
+                          tgt)
+    if stats is None:
+        return loss
+    balance, z = stats.balance_loss.mean(), stats.z_loss.mean()
+    from ray_tpu.parallel.spmd import report_step_metrics
+    report_step_metrics(moe_aux_loss=balance, moe_z_loss=z,
+                        moe_load_max_over_mean=stats.load_max_over_mean.max())
+    return loss + cfg.router_aux_coef * balance + cfg.router_z_coef * z
 
 
 # Sharding: attention/MLP matrices split fsdp×tensor; RoPE/norms replicated.
@@ -286,6 +401,8 @@ LLAMA_RULES = [
     (r".*blocks/w_gate/kernel$", P("pipeline", "fsdp", "tensor")),
     (r".*blocks/w_up/kernel$", P("pipeline", "fsdp", "tensor")),
     (r".*blocks/w_down/kernel$", P("pipeline", "tensor", "fsdp")),
+    (r".*blocks/experts/w_(gate|up)$", P("pipeline", "expert", "fsdp", "tensor")),
+    (r".*blocks/experts/w_down$", P("pipeline", "expert", "tensor", "fsdp")),
     (r".*norm.*scale$",        P(None)),
     (r".*lm_head/kernel$",     P("fsdp", "tensor")),
     (r".*", P(None)),

@@ -13,6 +13,12 @@ Design follows the GShard/Switch dispatch formulation (public): top-k gating
 with an auxiliary load-balancing loss, fixed expert capacity with token
 dropping, einsum-based dispatch/combine (MXU-friendly — the dispatch tensors
 are the only non-matmul cost and XLA fuses their construction).
+
+``dropless_moe_ffn`` is the other formulation (OLMoE, MegaBlocks): no
+capacity and no dropped token; the assignments are sorted by expert and
+the experts run as grouped matmuls over ragged groups.  At 8 of 64
+experts a token the one-hot dispatch above costs 5-7 times the experts'
+own matmuls; the sort costs a few passes over the rows.
 """
 
 from __future__ import annotations
@@ -121,6 +127,128 @@ def moe_ffn(x: jax.Array,
     metrics = MoEMetrics(aux_loss=aux, router_z_loss=z,
                          fraction_dropped=dropped.mean())
     return y.reshape(B, S, d), metrics
+
+
+# ------------------------------------------------------- dropless experts
+class RouterStats(NamedTuple):
+    """What one dropless expert layer reports beside its output (scalars)."""
+    balance_loss: jax.Array       # E * sum_e f_e P_e
+    z_loss: jax.Array             # mean_n logsumexp(router logits)^2
+    load_max_over_mean: jax.Array  # most-loaded expert's rows / mean rows
+
+
+@jax.custom_vjp
+def _rows_to_experts(x, order, inverse):
+    """(N, d) tokens -> (N k, d) rows grouped by expert: row r holds token
+    ``order[r] // k``.  ``order`` is a permutation of the N k (token, slot)
+    assignments and ``inverse`` its inverse, so the backward is a gather
+    and a sum over a token's k slots, not the scatter-add XLA would
+    derive from the forward gather."""
+    k = order.shape[0] // x.shape[0]
+    return jnp.take(x, order // k, axis=0)
+
+
+def _rows_to_experts_fwd(x, order, inverse):
+    return _rows_to_experts(x, order, inverse), (x.shape[0], inverse)
+
+
+def _rows_to_experts_bwd(res, g):
+    n, inverse = res
+    dx = jnp.take(g, inverse, axis=0).reshape(n, -1, g.shape[-1])
+    return dx.sum(1, dtype=jnp.float32).astype(g.dtype), None, None
+
+
+_rows_to_experts.defvjp(_rows_to_experts_fwd, _rows_to_experts_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(y, perm, inverse):
+    """``y[perm]`` for a permutation whose inverse is known: the backward
+    is ``g[inverse]``."""
+    return jnp.take(y, perm, axis=0)
+
+
+def _permute_rows_fwd(y, perm, inverse):
+    return jnp.take(y, perm, axis=0), (perm, inverse)
+
+
+def _permute_rows_bwd(res, g):
+    perm, inverse = res
+    return jnp.take(g, inverse, axis=0), None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+# megablox's (rows, contracted, output) tile: the fastest of those tried on
+# the v5e at the OLMoE cell's shape that fits VMEM (PERF.md section 6, PR 27)
+GMM_TILING = (512, 1024, 1024)
+
+
+def grouped_matmul(rows: jax.Array, w: jax.Array,
+                   group_sizes: jax.Array) -> jax.Array:
+    """rows (M, d) sorted by group, w (E, d, f), group_sizes (E,) summing
+    to M -> (M, f): row r times the matrix of the group it lies in.
+
+    On a TPU, where the tile divides the shapes: megablox's Pallas ``gmm``
+    (kernels ``gmm`` and, for the weights' gradient, ``tgmm``), which read
+    the transposed weights in place for the rows' gradient.  Elsewhere
+    ``jax.lax.ragged_dot``, which XLA lowers on a TPU to its own kernels
+    (``ragged-dot*``): slower there by a fifth to a third in all three
+    products, and its backward copies the weights transposed.
+    """
+    m, d = rows.shape
+    tm, tk, tn = GMM_TILING
+    f = w.shape[-1]
+    if jax.default_backend() == "tpu" and not (m % tm or d % tk or f % tn):
+        from jax.experimental.pallas.ops.tpu.megablox import ops
+        return ops.gmm(rows, w, group_sizes, rows.dtype, GMM_TILING)
+    return jax.lax.ragged_dot(rows, w, group_sizes)
+
+
+def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
+                     w_up: jax.Array, w_down: jax.Array, *, k: int
+                     ) -> Tuple[jax.Array, RouterStats]:
+    """Token-choice SwiGLU experts with no capacity: every token is
+    computed by each of its top-k experts, whatever the imbalance.
+
+    x (N, d); w_router (d, E); w_gate, w_up (E, d, f); w_down (E, f, d).
+    The router's softmax is float32 over all E experts and its top-k
+    probabilities weigh the experts' outputs as they are (not
+    renormalised).  Dispatch is a sort: the N k assignments are ordered by
+    expert, the rows gathered, three grouped matmuls run over the ragged
+    groups, and the rows are put back and summed per token.  No (N, E, C)
+    tensor exists and nothing is dropped by construction.
+    """
+    n, d = x.shape
+    num_experts = w_router.shape[-1]
+    with jax.named_scope("router"):
+        logits = jnp.dot(x, w_router.astype(x.dtype),
+                         preferred_element_type=jnp.float32)     # (N, E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, expert_idx = jax.lax.top_k(probs, k)          # (N, k)
+    with jax.named_scope("moe_dispatch"):
+        flat_expert = expert_idx.reshape(n * k)
+        order = jnp.argsort(flat_expert, stable=True)
+        inverse = jnp.argsort(order)
+        group_sizes = jnp.bincount(flat_expert, length=num_experts
+                                   ).astype(jnp.int32)
+        rows = _rows_to_experts(x, order, inverse)               # (N k, d)
+    with jax.named_scope("moe_experts"):
+        gate = grouped_matmul(rows, w_gate.astype(x.dtype), group_sizes)
+        up = grouped_matmul(rows, w_up.astype(x.dtype), group_sizes)
+        out = grouped_matmul(jax.nn.silu(gate) * up,
+                             w_down.astype(x.dtype), group_sizes)
+    with jax.named_scope("moe_combine"):
+        out = _permute_rows(out, inverse, order).reshape(n, k, d)
+        y = jnp.einsum("nkd,nk->nd", out, gate_vals.astype(out.dtype),
+                       preferred_element_type=jnp.float32).astype(x.dtype)
+    with jax.named_scope("router"):
+        share = group_sizes.astype(jnp.float32) / (n * k)        # f_e
+        balance = num_experts * jnp.sum(share * probs.mean(0))
+        z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
+        load = share.max() * num_experts
+    return y, RouterStats(balance, z, load)
 
 
 # Sharding rules for MoE params (compose with TRANSFORMER_RULES by

@@ -176,6 +176,9 @@ TRANSFORMER_RULES: Rules = [
     (r".*blocks/mlp_out/kernel$",   P("pipeline", "tensor", "fsdp")),
     (r".*blocks/mlp_out/bias$",     P("pipeline", "fsdp")),
     (r".*blocks/(ln_1|ln_2)/(scale|bias)$", P("pipeline", None)),
+    # stacked experts (layer, expert, in, out): models/llama.py with n_experts
+    (r".*blocks/experts/w_(gate|up)$", P("pipeline", "expert", "fsdp", "tensor")),
+    (r".*blocks/experts/w_down$",   P("pipeline", "expert", "tensor", "fsdp")),
     # Non-stacked variants (single-layer modules, BERT/ResNet dense layers).
     (r".*attn_qkv/kernel$",         P("fsdp", None, "tensor")),
     (r".*attn_out/kernel$",         P("tensor", "fsdp")),

@@ -10,6 +10,8 @@ collectives are inserted by GSPMD and ride ICI (SURVEY.md §5.8 item 3).
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -42,6 +44,34 @@ jax.tree_util.register_pytree_node(
     TrainState,
     lambda s: ((s.step, s.params, s.opt_state), None),
     lambda _, c: TrainState(*c))
+
+
+# What a loss function computes beside its loss and wants in the step's
+# metrics (routing statistics, auxiliary terms): None outside a step's
+# trace, the step's dictionary inside it.
+_STEP_METRICS: contextvars.ContextVar[Optional[Dict[str, jax.Array]]] = \
+    contextvars.ContextVar("ray_tpu_step_metrics", default=None)
+
+
+def report_step_metrics(**scalars: jax.Array) -> None:
+    """Called by a loss function at its top level (not inside a scan or a
+    checkpointed block: the values leave the trace they are made in as
+    auxiliary outputs of the loss): the step built by
+    :func:`build_train_program` returns them under these names beside
+    ``loss``.  Anywhere else a no-op, so the loss stays a scalar function."""
+    sink = _STEP_METRICS.get()
+    if sink is not None:
+        sink.update(scalars)
+
+
+@contextlib.contextmanager
+def _collect_step_metrics():
+    sink: Dict[str, jax.Array] = {}
+    token = _STEP_METRICS.set(sink)
+    try:
+        yield sink
+    finally:
+        _STEP_METRICS.reset(token)
 
 
 def default_optimizer(lr: float = 3e-4, weight_decay: float = 0.01,
@@ -114,7 +144,10 @@ def build_train_program(
     """Assemble the one-jit distributed train step.
 
     ``loss_fn(params, batch) -> scalar``; GSPMD derives every collective from
-    the shardings — there is no explicit allreduce anywhere.
+    the shardings — there is no explicit allreduce anywhere.  Scalars the
+    loss function hands to :func:`report_step_metrics` join the step's
+    metrics (means over microbatches under ``accum_steps``); a loss that
+    reports nothing compiles to the program it always did.
 
     ``accum_steps > 1`` runs microbatch gradient accumulation INSIDE the one
     jit: the global batch is split on its leading dim into ``accum_steps``
@@ -156,11 +189,18 @@ def build_train_program(
 
     init_fn = jax.jit(_init, out_shardings=state_sh)
 
+    def _loss_and_reported(params: Any, batch: Any):
+        with _collect_step_metrics() as reported:
+            loss = loss_fn(params, batch)
+        return loss, reported
+
     def _grads(params: Any, batch: Any):
+        """-> ((loss, what the loss function reported), grads)."""
         # Runs at trace time: model code (e.g. ring attention) can pick up
         # the program mesh via mesh_lib.get_ambient_mesh() to nest shard_map.
         with mesh_lib.ambient_mesh(mesh), jax.named_scope("grads"):
-            return jax.value_and_grad(loss_fn)(params, batch)
+            return jax.value_and_grad(_loss_and_reported, has_aux=True)(
+                params, batch)
 
     def _grads_accum(params: Any, batch: Any):
         # Microbatch split on the leading (batch) dim.  The reshape keeps
@@ -186,25 +226,26 @@ def build_train_program(
 
         def body(carry, mb):
             loss_acc, g_acc = carry
-            loss, grads = _grads(params, mb)
+            (loss, reported), grads = _grads(params, mb)
             g_acc = jax.tree_util.tree_map(
                 lambda a, g: a + g.astype(a.dtype), g_acc, grads)
-            return (loss_acc + loss, g_acc), None
+            return (loss_acc + loss, g_acc), reported
 
         with jax.named_scope("grad_accum"):
-            (loss_sum, acc), _ = jax.lax.scan(
+            (loss_sum, acc), reported = jax.lax.scan(
                 body, (jnp.zeros((), jnp.float32), acc0), mbs)
             inv = jnp.float32(1.0 / A)
             grads = jax.tree_util.tree_map(
                 lambda a, p: (a.astype(jnp.float32) * inv).astype(p.dtype),
                 acc, params)
-            return loss_sum * inv, grads
+            reported = {k: v.mean(0) for k, v in reported.items()}
+            return (loss_sum * inv, reported), grads
 
     def _step(state: TrainState, batch: Any):
         if accum_steps > 1:
-            loss, grads = _grads_accum(state.params, batch)
+            (loss, reported), grads = _grads_accum(state.params, batch)
         else:
-            loss, grads = _grads(state.params, batch)
+            (loss, reported), grads = _grads(state.params, batch)
         with jax.named_scope("optimizer"):
             updates, opt_state = optimizer.update(
                 grads, state.opt_state, state.params)
@@ -213,7 +254,7 @@ def build_train_program(
         new = TrainState(step=state.step + 1, params=params,
                          opt_state=opt_state)
         gnorm = optax.global_norm(grads)
-        return new, {"loss": loss, "grad_norm": gnorm,
+        return new, {**reported, "loss": loss, "grad_norm": gnorm,
                      "step": new.step.astype(jnp.float32)}
 
     # Donation: the WHOLE TrainState — params AND both Adam moments —

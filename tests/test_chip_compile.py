@@ -92,3 +92,61 @@ def test_flash_attention_at_prefill_bucket(v5e, bucket, dtype):
 def test_layer_norm(v5e, fn, shape):
     affine = ((768,), jnp.float32)
     _compile(fn, v5e, (shape, jnp.bfloat16), affine, affine)
+
+
+# ------------------------------------------------- OLMoE's training cell
+OLMOE_QKV = ((2, 4096, 16, 128), jnp.bfloat16)      # 16 heads x 128, T 4096
+
+
+@pytest.mark.parametrize("fn", [
+    pytest.param(_flash, id="forward"),
+    pytest.param(jax.grad(_flash_loss, argnums=(0, 1, 2)),
+                 id="forward_backward"),
+])
+def test_flash_attention_at_olmoe_train_shape(v5e, fn):
+    """One head's whole K and V (forward) and q, dO, dq (backward) are
+    (4096, 128) blocks here: 1 MB an operand before double buffering."""
+    _compile(fn, v5e, OLMOE_QKV, OLMOE_QKV, OLMOE_QKV)
+
+
+def _experts(x, w_router, w_gate, w_up, w_down):
+    from ray_tpu.ops.moe import dropless_moe_ffn
+    return dropless_moe_ffn(x, w_router, w_gate, w_up, w_down, k=8)[0]
+
+
+def _experts_loss(*args):
+    return _experts(*args).astype(jnp.float32).sum()
+
+
+@pytest.mark.parametrize("fn,n_grouped", [
+    pytest.param(_experts, 3, id="forward"),
+    pytest.param(jax.grad(_experts_loss, argnums=(0, 1, 2, 3, 4)), 9,
+                 id="forward_backward"),
+])
+def test_dropless_experts_at_olmoe_train_shape(v5e, monkeypatch, fn,
+                                               n_grouped):
+    """8,192 tokens, 8 of 64 experts each: 65,536 rows in 64 ragged
+    groups through 2048 -> 1024 twice and 1024 -> 2048; every grouped
+    matmul, forward and both backward products, is a Mosaic kernel
+    (megablox at ``GMM_TILING``) whose result has one of the shapes
+    ``moe.expert_matmul_ms`` is keyed on, and no (N, E, C) dispatch tensor
+    is built."""
+    import json
+    import re
+    from pathlib import Path
+    # the code under compile asks for the backend and must take the
+    # branch it takes on the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bf = jnp.bfloat16
+    text = _compile(fn, v5e, ((8192, 2048), bf), ((2048, 64), bf),
+                    ((64, 2048, 1024), bf), ((64, 2048, 1024), bf),
+                    ((64, 1024, 2048), bf))
+    kernels = re.findall(r"= (bf16\[[\d,]+\])\S* custom-call\(.*"
+                         r'custom_call_target="tpu_custom_call"', text)
+    assert len(kernels) == n_grouped, kernels
+    keyed = json.loads((Path(__file__).parent.parent / "perfbench" /
+                        "layer_metrics" / "moe.expert_matmul_ms.json")
+                       .read_text())["params"]["shapes"]
+    assert set(kernels) <= set(keyed), kernels
+    assert "ragged-dot" not in text
+    assert not re.search(r"\[8192,64,\d+\]", text)
