@@ -709,14 +709,15 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
     B = tokens.shape[0]
     E, H, D = cfg.n_embd, cfg.n_head, cfg.head_dim
     x = _embed(params, tokens, positions, cfg)                  # (B, E)
-    with _scope("kv_layout"):
-        # (N, L, 2, bs, H, D) → per-layer pools (L, N, bs, H, D)
-        k_pools = kv_pool[:, :, 0].transpose(1, 0, 2, 3, 4)
-        v_pools = kv_pool[:, :, 1].transpose(1, 0, 2, 3, 4)
 
     def body(carry, xs):
         x = carry
-        lp, k_pool, v_pool = xs
+        lp, layer = xs
+        with _scope("kv_layout"):
+            # this layer's pools (N, bs, H, D), sliced where they lie: a
+            # split of the whole pool ahead of the scan is a pass over it
+            kv = kv_pool[:, layer]
+            k_pool, v_pool = kv[:, 0], kv[:, 1]
         with _scope("ln_1"):
             h = _layer_norm(x[:, None, :], lp["ln_1"]["scale"],
                             lp["ln_1"]["bias"])[:, 0]
@@ -743,7 +744,8 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
                 + _cast(lp["mlp_out"]["bias"], cfg)
             return x + h, (k, v)
 
-    x, (ks, vs) = lax.scan(body, x, (params["blocks"], k_pools, v_pools))
+    x, (ks, vs) = lax.scan(body, x,
+                           (params["blocks"], jnp.arange(cfg.n_layer)))
     with _scope("ln_f"):
         x = _layer_norm(x[:, None, :], params["ln_f"]["scale"],
                         params["ln_f"]["bias"])[:, 0]

@@ -333,12 +333,13 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
     B = tokens.shape[0]
     E = cfg.n_embd
     x = params["wte"].astype(cfg.dtype)[tokens]                 # (B, E)
-    k_pools = kv_pool[:, :, 0].transpose(1, 0, 2, 3, 4)
-    v_pools = kv_pool[:, :, 1].transpose(1, 0, 2, 3, 4)
 
     def body(carry, xs):
         x = carry
-        lp, k_pool, v_pool = xs
+        lp, layer = xs
+        # this layer's pools (N, bs, KV, D), sliced where they lie
+        kv = kv_pool[:, layer]
+        k_pool, v_pool = kv[:, 0], kv[:, 1]
         h = _rms_norm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
         q, k, v = _qkv(h, lp, cfg)
         q = _rope_at(q, positions, cfg.rope_theta)
@@ -350,7 +351,8 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
         x = x + _ffn(h, lp, cfg)[0]
         return x, (k, v)
 
-    x, (ks, vs) = lax.scan(body, x, (params["blocks"], k_pools, v_pools))
+    x, (ks, vs) = lax.scan(body, x,
+                           (params["blocks"], jnp.arange(cfg.n_layer)))
     x = _rms_norm(x, params["norm_f"]["scale"], cfg.rms_eps)
     logits = x @ params["lm_head"]["kernel"].astype(cfg.dtype)
     return logits.astype(jnp.float32), ks, vs

@@ -8,26 +8,49 @@ engine (``ray_tpu/serve/llm``) keeps the pool on the device, one array
 that its programs take donated and hand back; replicas exchange blocks
 by explicit copies over the data plane.
 
-This module is the math: a jit-friendly gather-then-attend decode kernel
-over ``(num_blocks, block_size, n_kv, d)`` pools.  On the CPU rig (and
-for moderate context lengths on TPU) XLA fuses the gather + matmul chain
-well; the long-context TPU path would drop the same signature into a
-Pallas kernel that walks the table block-by-block in VMEM (the
-``ops/flash_attention.py`` machinery) — the call-site contract here is
-written so that swap is local to this file.
+This module is the math, behind one call: ``paged_attention_decode``.
+Two implementations of the same algorithm sit behind it, chosen from
+what the code can see (the backend and the shapes), never by an option:
+
+* ``_paged_decode_kernel`` — on a TPU.  A Pallas kernel that takes the
+  block tables and context lengths as prefetched scalars and, sequence
+  by sequence, copies only the blocks the context holds
+  (``ceil(ctx_len / bs)`` table columns) out of the layer's pool into
+  VMEM, double-buffered 128 positions at a time, folding each chunk
+  into an online softmax.  Table columns past the context cost no copy
+  and no arithmetic; a row padded up to the decode bucket
+  (``ctx_len == 0``) costs the new token's own term.  Mosaic copies
+  whole 128-lane tiles, so the kernel reads a layer's pool with a
+  position's heads side by side along the lanes (``(N, bs, KV * D)``,
+  padded: 25 x 64 -> 1,664); XLA builds that form per layer from the
+  layer's pools, which ``forward_decode`` slices out of the engine's
+  pool inside its scan.  In that form all heads share two float32
+  matmuls a chunk, against a block-diagonal query.
+* ``_paged_decode_gather`` — everywhere else (the CPU rig), and the
+  tests' reference: gather every table column as a padded dense view,
+  attend, mask by ``ctx_lens``.  XLA fuses the chain; its cost is that
+  of the table, not of the contexts.
 
 Accumulators are float32 regardless of input dtype (bf16-safe softmax),
-matching ``ops/attention.py``.
+matching ``ops/attention.py``.  The pool is only read here.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
 
 NEG_INF = jnp.finfo(jnp.float32).min
+
+# positions copied into VMEM per step of a sequence's walk, as whole
+# blocks: one buffer of K and one of V, each double-buffered (3.3 MiB
+# in all at XL's 1,600 float32 lanes a position)
+_CHUNK_TOKENS = 128
 
 
 def gather_kv(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
@@ -47,25 +70,9 @@ def gather_kv(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
         return g.reshape(b, mb * bs, kv, d)
 
 
-def paged_attention_decode(q: jax.Array, k_pool: jax.Array,
-                           v_pool: jax.Array, block_tables: jax.Array,
-                           ctx_lens: jax.Array, k_new: jax.Array,
-                           v_new: jax.Array) -> jax.Array:
-    """Single-token decode attention through a block table.
-
-    q:       (B, H, D)        — query for the token being decoded.
-    k_pool:  (N, bs, KV, D)   — shared key pool (this layer's view).
-    v_pool:  (N, bs, KV, D)   — shared value pool.
-    block_tables: (B, MAXB) int32.
-    ctx_lens: (B,) int32      — tokens already IN the pool per sequence
-                                (the new token is not in the pool yet).
-    k_new, v_new: (B, KV, D)  — this token's key/value, attended in
-                                explicitly: the pool is only read here,
-                                and the runner's program writes the new
-                                K/V into it after these reads.
-
-    Returns (B, H, D) in q.dtype.
-    """
+def _paged_decode_gather(q, k_pool, v_pool, block_tables, ctx_lens,
+                         k_new, v_new):
+    """Gather-then-mask: the CPU path and the kernel's reference."""
     b, h, d = q.shape
     kvh = k_pool.shape[2]
     scale = 1.0 / math.sqrt(d)
@@ -91,3 +98,204 @@ def paged_attention_decode(q: jax.Array, k_pool: jax.Array,
                          v_ctx.astype(jnp.float32))
         out = out + probs[..., -1][..., None] * v_new.astype(jnp.float32)
         return out.astype(q.dtype)
+
+
+def _decode_kernel(tables_ref, lens_ref, q_ref, k_new_ref, v_new_ref,
+                   k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_ref, l_ref,
+                   acc_ref, *, head_dim, kv_rows):
+    """One sequence (grid step): walk its blocks, a chunk at a time.
+
+    Heads lie along the lanes: a block is (bs, F), F = KV * D padded to
+    whole tiles.  q_ref (1, R, F) holds one row a query head, zero
+    outside its KV head's D lanes (block-diagonal, pre-scaled), so one
+    matmul against a chunk's keys (T, F) gives every head's scores
+    (R, T), and probabilities @ values (T, F) every head's result in its
+    own lanes of (R, F).  Rows are ordered (group member, KV head), each
+    group member's KV heads padded to ``kv_rows``.  k_hbm / v_hbm
+    (N, bs, F) are left where they are and copied from by block; k_buf /
+    v_buf (2, C, bs, F) are the VMEM landing buffers; sems (2, 2):
+    [k|v, buffer].
+    """
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    _, chunk, bs, f = k_buf.shape
+    t = chunk * bs
+    ctx = lens_ref[b]
+    n_blocks = pl.cdiv(ctx, bs)
+    n_chunks = pl.cdiv(n_blocks, chunk)
+    hi = lax.Precision.HIGHEST      # float32 K/V stay float32 on the MXU
+
+    def copies(c, slot):
+        """The chunk's copies, each with the guard it runs under."""
+        out = []
+        for i in range(chunk):
+            j = c * chunk + i
+            # clamped: the read stays inside the row when the guard fails
+            blk = tables_ref[b, jnp.minimum(j, tables_ref.shape[1] - 1)]
+            out.append((j < n_blocks, (
+                pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[slot, i],
+                                      sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[slot, i],
+                                      sems.at[1, slot]))))
+        return out
+
+    def start(c, slot):
+        for live, (ck, cv) in copies(c, slot):
+            @pl.when(live)
+            def _():
+                ck.start()
+                cv.start()
+
+    def wait(c, slot):
+        for live, (ck, cv) in copies(c, slot):
+            @pl.when(live)
+            def _():
+                ck.wait()
+                cv.wait()
+
+    @pl.when(n_chunks > 0)
+    def _():
+        start(0, 0)
+
+    # the new token's own term seeds the running softmax: max = its
+    # score, sum = 1, accumulator = v_new.  A padded row ends here.
+    q = q_ref[0]                                                # (R, F)
+    m_ref[...] = jnp.sum(q * k_new_ref[0], axis=-1, keepdims=True)
+    l_ref[...] = jnp.ones_like(l_ref)
+    acc_ref[...] = jnp.broadcast_to(v_new_ref[0], acc_ref.shape)
+
+    @pl.loop(0, n_chunks)
+    def _(c):
+        slot = c % 2
+
+        @pl.when(c + 1 < n_chunks)
+        def _():
+            start(c + 1, 1 - slot)
+
+        wait(c, slot)
+        k = k_buf[slot].reshape(t, f)
+        v = v_buf[slot].reshape(t, f)
+        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())), precision=hi,
+                            preferred_element_type=jnp.float32)  # (R, T)
+        # what was not copied, and the last block's tail, may hold
+        # anything: select, never multiply by zero
+        live = c * t + lax.broadcasted_iota(jnp.int32, (1, t), 1) < ctx
+        s = jnp.where(live, s, NEG_INF)
+        live = c * t + lax.broadcasted_iota(jnp.int32, (t, 1), 0) < ctx
+        v = jnp.where(live, v, 0.0)
+        m = m_ref[...]
+        m_next = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_next)
+        p = jnp.exp(s - m_next)
+        m_ref[...] = m_next
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p, v, precision=hi, preferred_element_type=jnp.float32)
+
+    # row (r, kv) keeps the D lanes of KV head kv; the rows of one group
+    # member then sum to its (1, F) result
+    out = acc_ref[...] / l_ref[...]
+    head = lax.broadcasted_iota(jnp.int32, (kv_rows, f), 1) // head_dim
+    own = head == lax.broadcasted_iota(jnp.int32, (kv_rows, f), 0)
+    for r in range(o_ref.shape[1]):
+        part = out[r * kv_rows:(r + 1) * kv_rows]
+        o_ref[0, r] = jnp.sum(jnp.where(own, part, 0.0), axis=0
+                              ).astype(o_ref.dtype)
+
+
+def _lane_flat(x: jax.Array) -> jax.Array:
+    """(..., KV, D) -> (..., F): a position's heads side by side along
+    the lanes, zero-padded to whole 128-lane tiles.  Mosaic copies
+    nothing narrower out of HBM (64 or 1,600 lanes are refused)."""
+    f = x.shape[-2] * x.shape[-1]
+    flat = x.reshape(x.shape[:-2] + (f,))
+    return jnp.pad(flat, [(0, 0)] * (flat.ndim - 1) + [(0, -f % 128)])
+
+
+def _paged_decode_kernel(q, k_pool, v_pool, block_tables, ctx_lens,
+                         k_new, v_new, *, interpret=False):
+    """The block-table walk as one Pallas call over the batch."""
+    # imported where the kernel is built (flash_attention's idiom), so
+    # that importing ray_tpu.ops costs a training process nothing more
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, d = q.shape
+    _, bs, kvh, _ = k_pool.shape
+    rep, f32 = h // kvh, jnp.float32
+    kv_rows = -(-kvh // 8) * 8
+    chunk = max(1, min(_CHUNK_TOKENS // bs, block_tables.shape[1]))
+    with jax.named_scope("paged_attention"):
+        k_flat, v_flat = _lane_flat(k_pool), _lane_flat(v_pool)
+        f = k_flat.shape[-1]
+        # head h reads KV head h // rep (jnp.repeat's order).  Row
+        # (r, kv) of the query operand: q[kv * rep + r], scaled, in
+        # lanes [kv * D, (kv + 1) * D), zero elsewhere
+        q4 = q.astype(f32).reshape(b, kvh, rep, d).transpose(0, 2, 1, 3)
+        q4 = q4 * (1.0 / math.sqrt(d))
+        qbd = _lane_flat(q4[:, :, :, None, :]
+                         * jnp.eye(kvh, dtype=f32)[:, :, None])
+        qbd = jnp.pad(qbd, ((0, 0), (0, 0), (0, kv_rows - kvh), (0, 0)))
+        row = lambda i, tables, lens: (i, 0, 0)                # noqa: E731
+        out = pl.pallas_call(
+            functools.partial(_decode_kernel, head_dim=d, kv_rows=kv_rows),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(b,),
+                in_specs=[
+                    pl.BlockSpec((1, rep * kv_rows, f), row),
+                    pl.BlockSpec((1, 1, f), row),
+                    pl.BlockSpec((1, 1, f), row),
+                    pl.BlockSpec(memory_space=pl.ANY),
+                    pl.BlockSpec(memory_space=pl.ANY),
+                ],
+                out_specs=pl.BlockSpec((1, rep, f), row),
+                scratch_shapes=[
+                    pltpu.VMEM((2, chunk, bs, f), k_pool.dtype),
+                    pltpu.VMEM((2, chunk, bs, f), v_pool.dtype),
+                    pltpu.SemaphoreType.DMA((2, 2)),
+                    pltpu.VMEM((rep * kv_rows, 1), f32),
+                    pltpu.VMEM((rep * kv_rows, 1), f32),
+                    pltpu.VMEM((rep * kv_rows, f), f32),
+                ]),
+            out_shape=jax.ShapeDtypeStruct((b, rep, f), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+            name="paged_decode",
+        )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
+          qbd.reshape(b, rep * kv_rows, f),
+          _lane_flat(k_new.astype(f32))[:, None],
+          _lane_flat(v_new.astype(f32))[:, None], k_flat, v_flat)
+        out = out[..., :kvh * d].reshape(b, rep, kvh, d)
+        return out.transpose(0, 2, 1, 3).reshape(b, h, d)
+
+
+def paged_attention_decode(q: jax.Array, k_pool: jax.Array,
+                           v_pool: jax.Array, block_tables: jax.Array,
+                           ctx_lens: jax.Array, k_new: jax.Array,
+                           v_new: jax.Array) -> jax.Array:
+    """Single-token decode attention through a block table.
+
+    q:       (B, H, D)        — query for the token being decoded.
+    k_pool:  (N, bs, KV, D)   — shared key pool (this layer's view).
+    v_pool:  (N, bs, KV, D)   — shared value pool.
+    block_tables: (B, MAXB) int32.
+    ctx_lens: (B,) int32      — tokens already IN the pool per sequence
+                                (the new token is not in the pool yet).
+    k_new, v_new: (B, KV, D)  — this token's key/value, attended in
+                                explicitly: the pool is only read here,
+                                and the runner's program writes the new
+                                K/V into it after these reads.
+
+    Returns (B, H, D) in q.dtype.  On a TPU the blocks a context holds
+    are all that is read; elsewhere every table column is gathered.
+    """
+    # the kernel is built for whole query groups per KV head and a
+    # float32 pool, as the engine's is
+    if jax.default_backend() == "tpu" and k_pool.dtype == jnp.float32 \
+            and q.shape[1] % k_pool.shape[2] == 0:
+        return _paged_decode_kernel(q, k_pool, v_pool, block_tables,
+                                    ctx_lens, k_new, v_new)
+    return _paged_decode_gather(q, k_pool, v_pool, block_tables, ctx_lens,
+                                k_new, v_new)

@@ -154,6 +154,11 @@ class LLMEngine:
         self.admitted = 0
         self.queue_wait_s = 0.0
         self.requeue_wait_s = 0.0
+        # pool blocks the decode steps' contexts held, and the block-
+        # table entries those steps were compiled for: their ratio is
+        # the share of the table a step has to read (loop-owned ints)
+        self.attn_blocks_read = 0
+        self.attn_blocks_table = 0
         # hot-span totals of the loop and the runner, name ->
         # [count, seconds] (tracing.hot_span); the names are a contract,
         # PERF.md section 3 lists each with the metric that reads it
@@ -441,6 +446,15 @@ class LLMEngine:
                 toks[i] = s.output[-1] if s.output else s.prompt[-1]
                 poss[i] = s.ctx_len - 1
                 lens[i] = s.ctx_len - 1
+            # what the decode attention has to read against what the
+            # compiled step's block tables can name (padded rows and
+            # columns past a context included)
+            bs = self.cfg.block_size
+            blocks = int((-(-lens // bs)).sum())
+            span.set(blocks=blocks)
+            self.attn_blocks_read += blocks
+            self.attn_blocks_table += maxb * _bucket(
+                len(batch), self.cfg.decode_batch_buckets)
         try:
             # the step writes each new token's K/V into its slot itself;
             # the K/V it also returns stay on the device, unread
@@ -796,5 +810,7 @@ class LLMEngine:
                     queue_wait_s=self.queue_wait_s,
                     requeue_wait_s=self.requeue_wait_s,
                     kv_host_bytes=self.cache.host_bytes,
+                    attn_blocks_read=self.attn_blocks_read,
+                    attn_blocks_table=self.attn_blocks_table,
                     span_s={k: list(v) for k, v in
                             list(self.span_s.items())})

@@ -150,3 +150,79 @@ def test_dropless_experts_at_olmoe_train_shape(v5e, monkeypatch, fn,
     assert set(kernels) <= set(keyed), kernels
     assert "ragged-dot" not in text
     assert not re.search(r"\[8192,64,\d+\]", text)
+
+
+# ----------------------------------------------- the serving cell's decode
+def _paged(q, k_pool, v_pool, tables, lens, k_new, v_new):
+    from ray_tpu.ops.paged_attention import _paged_decode_kernel
+    return _paged_decode_kernel(q, k_pool, v_pool, tables, lens, k_new,
+                                v_new)
+
+
+@pytest.mark.parametrize("heads,kv_heads,head_dim,blocks", [
+    pytest.param(25, 25, 64, 128, id="gpt2_xl"),         # 1,600 lanes
+    pytest.param(16, 16, 128, 128, id="olmoe"),
+    pytest.param(32, 8, 128, 256, id="grouped_query"),
+])
+def test_paged_decode_kernel_at_decode_shape(v5e, heads, kv_heads, head_dim,
+                                             blocks):
+    """8 slots, a float32 pool of 16-position blocks, a table of 64
+    columns: Mosaic copies whole 128-lane tiles, so a position's heads
+    lie side by side, padded (1,600 -> 1,664 lanes for XL)."""
+    q = ((8, heads, head_dim), jnp.bfloat16)
+    new = ((8, kv_heads, head_dim), jnp.bfloat16)
+    pool = ((blocks, 16, kv_heads, head_dim), jnp.float32)
+    text = _compile(_paged, v5e, q, pool, pool, ((8, 64), jnp.int32),
+                    ((8,), jnp.int32), new, new)
+    assert "paged_decode" in text
+
+
+def test_serving_cell_decode_program_fits_and_gathers_nothing(
+        v5e, monkeypatch):
+    """The XL cell's whole decode step (48 layers, float32 weights, 128
+    blocks, one bucket of 8), as ``ModelRunner`` jits it: one Mosaic
+    kernel in the layer scan, no gather of every slot's whole table
+    (8 x 64 columns = 512 blocks of 16 x 25 x 64), no copy of the pool,
+    and arguments, result and temporaries together inside the chip's
+    16.9e9 bytes."""
+    import json
+    from pathlib import Path
+
+    from ray_tpu.serve.llm import EngineConfig
+    from ray_tpu.serve.llm.config import resolve_model
+    from ray_tpu.serve.llm.model_runner import ModelRunner
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    engine = json.loads((Path(__file__).parent.parent / "perfbench" /
+                         "configs" / "gpt2-xl-1558m.json").read_text()
+                        )["serve"]["engine"]
+    for key in ("decode_batch_buckets", "prefill_len_buckets"):
+        engine[key] = tuple(engine[key])
+    ecfg = EngineConfig(**engine)
+    mod, mcfg = resolve_model(ecfg)
+    params = jax.eval_shape(lambda key: mod.init_params(key, mcfg),
+                            jax.random.key(0))
+    runner = ModelRunner(ecfg, params=params)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    bucket, i32 = ecfg.decode_batch_buckets[-1], jnp.int32
+    pool = on_chip((ecfg.num_blocks, mcfg.n_layer, 2, ecfg.block_size,
+                    mcfg.n_head, mcfg.head_dim), jnp.float32)
+    compiled = runner._decode.lower(
+        pool, jax.tree.map(lambda x: on_chip(x.shape, x.dtype), params),
+        on_chip((bucket,), i32), on_chip((bucket,), i32),
+        on_chip((bucket, ecfg.max_blocks_per_seq), i32),
+        on_chip((bucket,), i32), on_chip((), i32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "paged_decode" in text
+    assert "[512,16,25,64]" not in text
+    # nor a split of the pool into per-layer pools ahead of the scan:
+    # each layer slices its K and V where they lie
+    assert "f32[48,128,16,25,64]" not in text
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= 1.25e9        # the pool, donated
+    assert held < 16.9e9, held

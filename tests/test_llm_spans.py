@@ -174,6 +174,16 @@ def test_spans_of_one_request_share_its_id(captured):
             assert e[3]["seq"] in ids and e[3]["ctx"] > 0
 
 
+def test_decode_spans_carry_the_blocks_their_contexts_hold(captured):
+    lines, _, before, after = captured
+    blocks = [int(e[3]["blocks"]) for e in _loop_line(lines)
+              if e[0] == "llm.decode"]
+    assert len(blocks) == after["decode_steps"] - before["decode_steps"]
+    assert all(b > 0 for b in blocks)
+    assert sum(blocks) == \
+        after["attn_blocks_read"] - before["attn_blocks_read"]
+
+
 # -------------------------------------------------------- with no capture
 @pytest.fixture(scope="module")
 def served():
@@ -235,6 +245,39 @@ def test_no_preemption_no_requeue_wait():
         eng.shutdown()
     assert stats["preemptions"] == 0 and stats["requeue_wait_s"] == 0.0
     assert stats["admitted"] == 2 and "llm.preempt" not in stats["span_s"]
+
+
+def test_block_counters_count_what_a_scripted_run_read():
+    """Two sequences of known lengths, stepped by hand: the blocks read
+    are those their contexts held at each decode step, the table is what
+    each step's bucket could name."""
+    cfg = small_pool_cfg(num_blocks=64)
+    eng = LLMEngine(cfg, start=False)
+    prompts, max_tokens, batches = (11, 20), 4, []
+    decode = eng.runner.decode
+    eng.runner.decode = lambda toks, *a: batches.append(len(toks)) or \
+        decode(toks, *a)
+    try:
+        assert eng.stats()["attn_blocks_table"] == 0
+        streams = [eng.submit(list(range(1, n + 1)),
+                              SamplingParams(max_tokens=max_tokens))
+                   for n in prompts]
+        while eng.step():
+            pass
+        stats = eng.stats()
+    finally:
+        eng.shutdown()
+    assert all(len(s.tokens()) == max_tokens for s in streams)
+    # the first token comes from the prefill; decode step k of a sequence
+    # reads the n + k positions in the pool so far
+    bs = cfg.block_size
+    assert stats["attn_blocks_read"] == sum(
+        -(-(n + k) // bs) for n in prompts for k in range(max_tokens - 1))
+    assert stats["decode_steps"] == len(batches) and 2 in batches
+    # batches of 1 and 2 are their own buckets of (1, 2, 4)
+    assert stats["attn_blocks_table"] == sum(
+        cfg.max_blocks_per_seq * b for b in batches)
+    assert stats["attn_blocks_read"] < stats["attn_blocks_table"]
 
 
 def test_a_traced_request_still_yields_timeline_events(monkeypatch):
