@@ -208,9 +208,10 @@ def phase_train(args) -> dict:
     dev = jax.devices()[0]
     cfg, prog, state, b = _build_program(args.rehearse, [dev],
                                          MeshConfig(data=1))
-    _say("attn_impl", gpt2.resolved_attn_impl(cfg))
-    _check(gpt2.resolved_attn_impl(cfg) == "flash",
-           "attn_impl resolves to the flash kernel")
+    from ray_tpu.ops.attention import flash_runs
+    runs = flash_runs(b["inputs"].shape[1], cfg.attn_impl)
+    _say("attn_impl", f"{cfg.attn_impl}: " + ("flash" if runs else "dense"))
+    _check(runs, "the step's attention is the flash kernel")
     lowered = prog.jitted_step.lower(state, b).as_text()
     n_custom = lowered.count("tpu_custom_call")
     _say("tpu_custom_calls_in_step", n_custom)
@@ -354,7 +355,7 @@ def phase_serve(args) -> dict:
     import numpy as np
 
     from ray_tpu.models import gpt2
-    from ray_tpu.ops.flash_attention import pick_block_size
+    from ray_tpu.ops.attention import flash_runs
     from ray_tpu.serve import llm
 
     cache = _count_cache_events()
@@ -379,11 +380,9 @@ def phase_serve(args) -> dict:
     eng = llm.LLMEngine(ecfg)
     try:
         mcfg = eng.runner.mcfg
-        impl = gpt2.resolved_attn_impl(mcfg)
         for t in ecfg.prefill_len_buckets:
-            tiles = t % pick_block_size(t) == 0
             _say(f"prefill_bucket_{t}",
-                 "flash" if impl == "flash" and tiles else "dense")
+                 "flash" if flash_runs(t, mcfg.attn_impl) else "dense")
         prompts = np.random.default_rng(SEED).integers(
             0, mcfg.vocab_size, (4, n_prompt)).tolist()
         streams = [eng.submit(p, llm.SamplingParams(max_tokens=n_new))

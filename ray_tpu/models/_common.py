@@ -1,10 +1,13 @@
-"""Shared helpers for the model zoo (single source for init/count logic)."""
+"""What the decoders share: init and count helpers, the remat rule of a
+block, the batch's two forms and the loss head.  Each model file keeps
+its own block, parameter tree and named scopes."""
 
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Callable, Dict, Tuple
 
 import jax
+import jax.numpy as jnp
 
 
 def normal_init(key: jax.Array, shape, dtype, scale: float = 0.02):
@@ -13,3 +16,61 @@ def normal_init(key: jax.Array, shape, dtype, scale: float = 0.02):
 
 def param_count(params: Any) -> int:
     return sum(int(x.size) for x in jax.tree_util.tree_leaves(params))
+
+
+def remat_block(block: Callable, policy: str, flash_runs: bool) -> Callable:
+    """``block`` under ``jax.checkpoint`` by a config's ``remat_policy``.
+
+    full: recompute the whole block in the backward (least memory).
+    attn: keep only the flash kernel's output and its compact lse (tagged
+    with ``checkpoint_name`` in ops/flash_attention.py), so the backward
+    does not run the forward kernel again: the largest recompute of a
+    step, and what lets GPT-2 XL fit one chip.  attn_qkv: also keep the
+    projection a block tags ``attn_qkv``, the one matmul the replay would
+    re-run ((B, T, 3E) a layer: right for small models, too much from
+    GPT-2 medium up at b32/s1024 on a 16 GB chip).
+
+    ``flash_runs``: whether this program's attention is the flash kernel
+    (``ops.attention.flash_runs``, and no sequence-axis KV ring).  The
+    kept names exist only inside that kernel's vjp; without it ``attn*``
+    would silently be full remat, so it raises."""
+    if policy == "full":
+        return jax.checkpoint(block)
+    if policy not in ("attn", "attn_qkv"):
+        raise ValueError(f"unknown remat_policy {policy!r} "
+                         "(expected full | attn | attn_qkv)")
+    if not flash_runs:
+        raise ValueError(
+            f"remat_policy={policy!r} keeps what only the flash kernel "
+            "names, and flash attention will not run here: it needs "
+            "attn_impl 'flash', or 'auto' on a TPU, a sequence length the "
+            "kernel's block tiles, and no seq-axis KV ring")
+    names = ["flash_attn_out", "flash_attn_lse"]
+    if policy == "attn_qkv":
+        names.append("attn_qkv")
+    return jax.checkpoint(
+        block, policy=jax.checkpoint_policies.save_only_these_names(*names))
+
+
+def split_batch(batch: Dict[str, jax.Array]) -> Tuple[jax.Array, jax.Array]:
+    """A training batch's (inputs, targets), each (B, T): the pair as
+    given, or ``{"tokens": (B, T+1)}`` shifted by one."""
+    if "inputs" in batch:
+        return batch["inputs"], batch["targets"]
+    return batch["tokens"][:, :-1], batch["tokens"][:, 1:]
+
+
+def next_token_nll(logits: jax.Array, targets: jax.Array) -> jax.Array:
+    """Mean next-token negative log-likelihood of (B, T, V) logits in the
+    activation dtype.
+
+    logsumexp, NOT log_softmax: log_softmax materializes a second
+    (B, T, V) float32 tensor just to read one element a row.  The
+    target's logit is read from the activation-dtype logits, so the
+    float32 convert has exactly one consumer (the lse reduce) and XLA
+    fuses it without materializing float32 logits at all (trace-measured
+    ~14 ms a step on a v5e at b32/s1024, r3)."""
+    with jax.named_scope("loss_ce"):
+        lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+        correct = jnp.take_along_axis(logits, targets[..., None], -1)[..., 0]
+        return (lse - correct.astype(jnp.float32)).mean()

@@ -14,24 +14,23 @@ Design notes (TPU-first, not a torch translation):
   what pipeline parallelism shards.
 - ``jax.checkpoint`` (remat) around each block trades FLOPs for HBM.
 - bf16 activations / f32 params+optimizer by default: MXU-native.
-- Attention is pluggable (``attn_impl``): dense causal (XLA fuses to a good
-  kernel), or ring/Ulysses context-parallel kernels from ``ray_tpu.ops``.
+- Attention is ``ops.attention.causal_attention``: it chooses the Pallas
+  flash kernel or XLA's dense attention from the backend and the sequence
+  length; ``attn_impl`` passes through to it.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import math
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from functools import partial
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 Params = Dict[str, Any]
-AttnImpl = Callable[..., jax.Array]  # (q, k, v, config) -> out
 
 
 @dataclass(frozen=True)
@@ -44,36 +43,20 @@ class GPT2Config:
     dtype: Any = jnp.bfloat16          # activation dtype
     param_dtype: Any = jnp.float32
     remat: bool = True
-    # full: recompute everything in bwd (min HBM).  dots: save matmul
-    # outputs without batch dims (MLP/projections) and recompute only
-    # attention.  attn: save ONLY the flash-attention residuals (out+lse,
-    # tagged via checkpoint_name in ops/flash_attention.py) so the
-    # rematerialized backward skips re-running the flash forward kernel —
-    # measured v5e b32/s1024: the biggest recompute in the step; requires
-    # attn_impl="flash".  Ignored when remat=False.
-    remat_policy: str = "full"  # full | dots | attn
-    # "auto" (default) resolves per backend: the Pallas flash kernel on
-    # TPU — the overlap-scheduled train step's default, no longer a
-    # bench-only config — and XLA dense elsewhere (interpret-mode Pallas
-    # on CPU is a debugging tool, not a default).
-    attn_impl: str = "auto"    # auto | dense | flash | blockwise | ring | ulysses
+    # _common.remat_block: full recomputes a block in the backward; attn
+    # keeps the flash kernel's output and lse, attn_qkv the qkv projection
+    # too (both need the flash kernel).  Ignored when remat=False.
+    remat_policy: str = "full"  # full | attn | attn_qkv
+    # passed to ops.attention.causal_attention, which chooses: "auto" is
+    # the Pallas flash kernel on a TPU where its block tiles the sequence
+    # and XLA's dense attention elsewhere
+    attn_impl: str = "auto"    # auto | dense | flash | ring | ulysses
     # Decomposed collective matmuls (ops/collective_matmul.py): "auto"
     # routes the qkv/attn-out/MLP projections through chunked
     # ppermute-ring all-gather-matmul / matmul-reduce-scatter whenever
     # the ambient mesh has a model axis (seq or tensor > 1) and the
     # shapes divide; "off" keeps GSPMD's serialized collective legs.
     collective_matmul: str = "auto"  # auto | off
-    # >0: compute the LM-head matmul + cross entropy in this many sequence
-    # chunks under jax.checkpoint, so the (B, T, vocab) f32 logits never
-    # materialize (peak activation drops by ~B*T*V*4/chunks bytes; the
-    # chunk logits are recomputed in the backward).  0 = single fused CE.
-    loss_chunks: int = 0
-    # >0: chunk the LM head over the VOCAB axis instead (online-softmax
-    # accumulation of per-chunk lse, jax.checkpoint per chunk): the
-    # (B, T, V) logits AND the backward's dlogits never materialize —
-    # each scan step touches (B, T, V/c).  Mutually exclusive with
-    # loss_chunks.  0 = off.  (VERDICT r4 weak #3: the LM-head+CE block.)
-    loss_vocab_chunks: int = 0
     context_axis: Optional[str] = None  # mesh axis for SP/CP ("context")
     pipeline_axis: Optional[str] = None  # mesh axis for PP ("pipeline")
     num_microbatches: int = 0  # 0 = auto (4x stages, divisor of batch)
@@ -112,7 +95,9 @@ PRESETS = {"gpt2": gpt2_small, "gpt2-124m": gpt2_small,
 
 
 # ------------------------------------------------------------------- params
-from ray_tpu.models._common import normal_init as _dense_init, param_count  # noqa: E402
+from ray_tpu.models._common import (  # noqa: E402
+    next_token_nll, normal_init as _dense_init, param_count, remat_block,
+    split_batch)
 
 
 def init_params(rng: jax.Array, cfg: GPT2Config) -> Params:
@@ -179,19 +164,9 @@ def _embed(params: Params, tokens: jax.Array, positions: jax.Array,
 
 
 def _layer_norm(x, scale, bias, eps=1e-5):
-    # Pallas fused LN (ops/layer_norm.py) when the lane tiling allows it:
-    # pins the residual stream to its natural E-minor layout and collapses
-    # the LN fwd+bwd chain to one VMEM pass each (~4ms/step total at the
-    # flagship bench shape; step-level impact there is ~neutral — XLA was
-    # already fusing LN into neighbors — but the pinned layout keeps the
-    # trace legible and protects shapes where XLA picks T-minor).
-    # Under GSPMD on several devices (the final norm after the manual
-    # region, every norm of the plain _block path) XLA's own LN runs:
-    # a Mosaic kernel cannot be partitioned automatically.
-    from ray_tpu.parallel import mesh as mesh_lib
-    if x.shape[-1] % 128 == 0 and not mesh_lib.under_gspmd():
-        from ray_tpu.ops.layer_norm import layer_norm
-        return layer_norm(x, scale, bias, eps)
+    """LayerNorm with bias over the last axis, statistics in float32:
+    XLA's own, which it fuses into the neighbouring operations at every
+    width (and can partition under GSPMD)."""
     x32 = x.astype(jnp.float32)
     mu = x32.mean(-1, keepdims=True)
     var = x32.var(-1, keepdims=True)
@@ -199,56 +174,8 @@ def _layer_norm(x, scale, bias, eps=1e-5):
     return (y * scale + bias).astype(x.dtype)
 
 
-def dense_causal_attention(q, k, v, cfg: GPT2Config) -> jax.Array:
-    """Reference attention: (B, T, H, D) → (B, T, H, D). XLA fuses this well
-    on the MXU for moderate T; long-context paths use ray_tpu.ops kernels."""
-    del cfg
-    T = q.shape[1]
-    scale = 1.0 / math.sqrt(q.shape[-1])
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    mask = jnp.tril(jnp.ones((T, T), bool))
-    logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-
-
-def resolved_attn_impl(cfg: GPT2Config) -> str:
-    """Concrete attention impl for ``attn_impl='auto'``: the Pallas flash
-    kernel on TPU, XLA dense elsewhere."""
-    if cfg.attn_impl == "auto":
-        return "flash" if jax.default_backend() == "tpu" else "dense"
-    return cfg.attn_impl
-
-
-def _flash_tiles(seq_len: int) -> bool:
-    """Whether the flash kernel's best block tiles ``seq_len`` — the
-    same gate ``flash_attention_for_model`` uses for its dense
-    fallback (an odd serving bucket must not crash the trace)."""
-    from ray_tpu.ops.flash_attention import pick_block_size
-    return seq_len % pick_block_size(seq_len) == 0
-
-
-def _resolve_attn(cfg: GPT2Config) -> AttnImpl:
-    cfg = dataclasses.replace(cfg, attn_impl=resolved_attn_impl(cfg))
-    if cfg.attn_impl == "dense":
-        return dense_causal_attention
-    if cfg.attn_impl == "flash":
-        from ray_tpu.ops.flash_attention import flash_attention_for_model
-        return flash_attention_for_model
-    if cfg.attn_impl == "blockwise":
-        from ray_tpu.ops.attention import blockwise_attention
-        return lambda q, k, v, cfg: blockwise_attention(q, k, v, causal=True)
-    if cfg.attn_impl == "ring":
-        from ray_tpu.ops.ring_attention import ring_attention_for_model
-        return partial(ring_attention_for_model, axis_name=cfg.context_axis)
-    if cfg.attn_impl == "ulysses":
-        from ray_tpu.ops.ulysses import ulysses_attention_for_model
-        return partial(ulysses_attention_for_model, axis_name=cfg.context_axis)
-    raise ValueError(f"unknown attn_impl {cfg.attn_impl!r}")
-
-
 def _block(x: jax.Array, lp: Params, cfg: GPT2Config,
-           attn: AttnImpl, collect_kv: bool = False):
+           collect_kv: bool = False):
     """One transformer block; with ``collect_kv`` also returns the
     per-head (k, v) — the SAME body serves training and the serving
     engine's prefill cache fill, so the two paths cannot diverge."""
@@ -275,7 +202,9 @@ def _block(x: jax.Array, lp: Params, cfg: GPT2Config,
     k = mesh_lib.constrain(k, "batch", "seq_attn", "heads", "kv")
     v = mesh_lib.constrain(v, "batch", "seq_attn", "heads", "kv")
     with _scope("attn"):
-        a = attn(q, k, v, cfg).reshape(B, T, E)
+        from ray_tpu.ops.attention import causal_attention
+        a = causal_attention(q, k, v, impl=cfg.attn_impl,
+                             context_axis=cfg.context_axis).reshape(B, T, E)
     with _scope("attn_out"):
         a = a @ _cast(lp["attn_out"]["kernel"], cfg) \
             + _cast(lp["attn_out"]["bias"], cfg)
@@ -321,7 +250,7 @@ def _manual_parallel_axes(cfg: GPT2Config, mesh, seq_len: int):
     sp, tp = model_parallel_sizes(mesh)
     if sp * tp == 1:
         return None
-    impl = resolved_attn_impl(cfg)
+    impl = cfg.attn_impl
     ok = (shape.get("context", 1) == 1
           and shape.get("pipeline", 1) == 1
           and impl not in ("ring", "ulysses")
@@ -341,7 +270,7 @@ def _manual_parallel_axes(cfg: GPT2Config, mesh, seq_len: int):
 
 
 def _block_manual(x: jax.Array, lp: Params, *, cfg: GPT2Config,
-                  attn_name: str, sp: int, tp: int) -> jax.Array:
+                  sp: int, tp: int) -> jax.Array:
     """Per-shard transformer block (inside shard_map over the mesh).
 
     ``x``: (B_local, T_local, E) with T_local = T / (sp·tp) — the
@@ -376,15 +305,9 @@ def _block_manual(x: jax.Array, lp: Params, *, cfg: GPT2Config,
             from ray_tpu.ops.ring_attention import ring_attention
             a = ring_attention(q, k, v, axis_name="seq", axis_size=sp,
                                causal=True)
-        elif attn_name == "flash" and _flash_tiles(Ts):
-            from ray_tpu.ops.flash_attention import flash_attention
-            a = flash_attention(q, k, v, True)
-        elif attn_name == "blockwise":
-            from ray_tpu.ops.attention import blockwise_attention
-            a = blockwise_attention(q, k, v, causal=True)
         else:
-            from ray_tpu.ops.attention import dense_attention
-            a = dense_attention(q, k, v, causal=True)
+            from ray_tpu.ops.attention import causal_attention
+            a = causal_attention(q, k, v, impl=cfg.attn_impl)
     with _scope("attn_out"):
         wout = _cast(lp["attn_out"]["kernel"], cfg).reshape(Hl * D, E)
         aout = cm.matmul_reduce_scatter(a.reshape(B, Ts, Hl * D), wout,
@@ -430,7 +353,6 @@ def forward_hidden(params: Params, tokens: jax.Array,
                    cfg: GPT2Config) -> jax.Array:
     """tokens (B, T) int32 → final-LN hidden states (B, T, E) in cfg.dtype."""
     B, T = tokens.shape
-    attn = _resolve_attn(cfg)
     # Arrays here are GLOBAL (GSPMD view) even when the sequence dim is
     # sharded over the context axis — only the attention impl drops into
     # shard_map (where chunk offsets come from lax.axis_index).
@@ -449,8 +371,7 @@ def forward_hidden(params: Params, tokens: jax.Array,
         sp, tp = manual
         xspec = P(("data", "fsdp"), ("seq", "tensor"), None)
         block = shard_map(
-            partial(_block_manual, cfg=cfg,
-                    attn_name=resolved_attn_impl(cfg), sp=sp, tp=tp),
+            partial(_block_manual, cfg=cfg, sp=sp, tp=tp),
             mesh=amb_mesh, in_specs=(xspec, _manual_block_specs(cfg)),
             out_specs=xspec, check_vma=False)
         x = jax.lax.with_sharding_constraint(
@@ -458,46 +379,13 @@ def forward_hidden(params: Params, tokens: jax.Array,
                              mesh_lib.activation_spec("batch", "seq",
                                                       "embed")))
     else:
-        block = partial(_block, cfg=cfg, attn=attn)
+        block = partial(_block, cfg=cfg)
     if cfg.remat:
-        if cfg.remat_policy == "dots":
-            block = jax.checkpoint(
-                block,
-                policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-        elif cfg.remat_policy in ("attn", "attn_qkv"):
-            # the saved names are tagged only inside the flash vjp; with
-            # any other impl — or a shape where the flash hook falls
-            # back to dense, or the seq>1 KV ring — this policy would
-            # silently behave as full remat
-            sp = 1 if manual is None else manual[0]
-            flash_runs = (resolved_attn_impl(cfg) == "flash"
-                          and sp == 1 and _flash_tiles(T // sp))
-            if not flash_runs:
-                raise ValueError(
-                    "remat_policy='attn' requires attn_impl='flash' "
-                    "with a flash-tileable sequence length and no "
-                    "seq-axis KV ring (the policy's saved names exist "
-                    "only inside the flash kernel's vjp)")
-            # "attn": save the flash out + compact lse residuals so the
-            # backward never re-runs the attention kernel (cheap: ~52MB
-            # per GPT-2-small layer at b32/s1024).  "attn_qkv" also pins
-            # the qkv projection — the one matmul the replay would re-run
-            # — at (B,T,3E) bf16 per layer; right for small models,
-            # OOMs ≥ gpt2-medium at b32/s1024 on 16GB chips.  (Pinning
-            # the kernel-layout q/k/v instead measured +15ms on the
-            # forward scan — see step_breakdown_r04.md.)
-            names = ["flash_attn_out", "flash_attn_lse"]
-            if cfg.remat_policy == "attn_qkv":
-                names.append("attn_qkv")
-            block = jax.checkpoint(
-                block,
-                policy=jax.checkpoint_policies.save_only_these_names(*names))
-        elif cfg.remat_policy == "full":
-            block = jax.checkpoint(block)
-        else:
-            raise ValueError(
-                f"unknown remat_policy {cfg.remat_policy!r} "
-                f"(expected 'full' or 'dots')")
+        from ray_tpu.ops.attention import flash_runs
+        # the seq > 1 KV ring of the manual region is not the flash kernel
+        sp = 1 if manual is None else manual[0]
+        block = remat_block(block, cfg.remat_policy,
+                            flash_runs(T, cfg.attn_impl) and sp == 1)
 
     def scan_body(carry, lp):
         return block(carry, lp), None
@@ -549,116 +437,15 @@ def forward(params: Params, tokens: jax.Array,
         return logits.astype(jnp.float32)
 
 
-def _chunked_ce(x: jax.Array, wte: jax.Array, tgt: jax.Array,
-                n_chunks: int) -> jax.Array:
-    """Mean next-token NLL with the LM head applied per sequence chunk.
-
-    Each chunk's (B, T/c, V) logits live only inside one checkpointed scan
-    step (recomputed in the backward) — the full-sequence logits tensor
-    never exists in HBM.
-    """
-    B, T, E = x.shape
-    if T % n_chunks:
-        raise ValueError(f"seq len {T} not divisible by loss_chunks "
-                         f"{n_chunks}")
-    tc_len = T // n_chunks
-    xc = x.reshape(B, n_chunks, tc_len, E).swapaxes(0, 1)
-    tc = tgt.reshape(B, n_chunks, tc_len).swapaxes(0, 1)
-
-    @jax.checkpoint
-    def body(acc, chunk):
-        xcb, tcb = chunk
-        with _scope("lm_head"):
-            logits = jnp.einsum("bte,ve->btv", xcb, wte).astype(jnp.float32)
-        with _scope("loss_ce"):
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            correct = jnp.take_along_axis(logits, tcb[..., None],
-                                          -1)[..., 0]
-            return acc + (lse - correct).sum(), None
-
-    total, _ = lax.scan(body, jnp.zeros((), jnp.float32), (xc, tc))
-    return total / (B * T)
-
-
-def _vocab_chunked_ce(x: jax.Array, wte: jax.Array, tgt: jax.Array,
-                      n_chunks: int) -> jax.Array:
-    """Mean next-token NLL with the LM head applied per VOCAB chunk.
-
-    Online-softmax over the vocab axis: each scan step computes the
-    (B, T, V/c) logits for one slice of the vocabulary, folds them into a
-    running logsumexp, and picks up the correct-class logit when the
-    target falls in the slice.  Neither the (B, T, V) logits nor the
-    backward's same-sized dlogits ever exist in HBM — the checkpointed
-    chunk recomputes its slice.  V is padded up to a multiple of
-    ``n_chunks`` with masked (-inf) columns.
-    """
-    B, T, E = x.shape
-    V = wte.shape[0]
-    vc_len = -(-V // n_chunks)            # ceil
-    pad = vc_len * n_chunks - V
-    if pad:
-        wte = jnp.concatenate(
-            [wte, jnp.zeros((pad, E), wte.dtype)], axis=0)
-    wc = wte.reshape(n_chunks, vc_len, E)
-    offsets = jnp.arange(n_chunks, dtype=jnp.int32) * vc_len
-
-    @jax.checkpoint
-    def body(carry, chunk):
-        run_lse, correct = carry
-        w, off = chunk
-        with _scope("lm_head"):
-            logits = jnp.einsum("bte,ve->btv", x, w).astype(jnp.float32)
-        with _scope("loss_ce"):
-            # mask padded vocab columns out of the reduction
-            valid = (off + jnp.arange(vc_len)) < V
-            logits = jnp.where(valid[None, None, :], logits, -jnp.inf)
-            chunk_lse = jax.nn.logsumexp(logits, axis=-1)
-            run_lse = jnp.logaddexp(run_lse, chunk_lse)
-            local = tgt - off             # (B, T), may be out of range
-            in_chunk = (local >= 0) & (local < vc_len)
-            got = jnp.take_along_axis(
-                logits, jnp.clip(local, 0, vc_len - 1)[..., None],
-                -1)[..., 0]
-            correct = correct + jnp.where(in_chunk, got, 0.0)
-            return (run_lse, correct), None
-
-    init = (jnp.full((B, T), -jnp.inf, jnp.float32),
-            jnp.zeros((B, T), jnp.float32))
-    (lse, correct), _ = lax.scan(body, init, (wc, offsets))
-    return (lse - correct).mean()
-
-
 def loss_fn(params: Params, batch: Dict[str, jax.Array],
             cfg: GPT2Config) -> jax.Array:
     """Next-token cross entropy. batch: {"tokens": (B, T+1) int32} or
     {"inputs","targets"} pair of (B, T)."""
-    if "inputs" in batch:
-        inp, tgt = batch["inputs"], batch["targets"]
-    else:
-        inp, tgt = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
-    if cfg.loss_chunks and cfg.loss_vocab_chunks:
-        raise ValueError("loss_chunks and loss_vocab_chunks are exclusive")
-    if cfg.loss_vocab_chunks:
-        x = forward_hidden(params, inp, cfg)
-        return _vocab_chunked_ce(x, _cast(params["wte"], cfg), tgt,
-                                 cfg.loss_vocab_chunks)
-    if cfg.loss_chunks:
-        x = forward_hidden(params, inp, cfg)
-        return _chunked_ce(x, _cast(params["wte"], cfg), tgt,
-                           cfg.loss_chunks)
-    # CE via logsumexp, NOT log_softmax: log_softmax materializes a second
-    # (B,T,V) f32 tensor (6.6GB at the flagship bench shape) just to read
-    # one element per row.  The correct-class logit is gathered from the
-    # bf16 logits so the f32 convert has exactly one consumer (the lse
-    # reduce) and XLA fuses it without materializing f32 logits at all
-    # (trace-measured ~14ms/step on a v5e at b32/s1024, r3).
+    inp, tgt = split_batch(batch)
     x = forward_hidden(params, inp, cfg)
     with _scope("lm_head"):
         logits = jnp.einsum("bte,ve->btv", x, _cast(params["wte"], cfg))
-    with _scope("loss_ce"):
-        lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
-        correct = jnp.take_along_axis(logits, tgt[..., None], -1)[..., 0]
-        return (lse - correct.astype(jnp.float32)).mean()
+    return next_token_nll(logits, tgt)
 
 
 # -------------------------------------------------- inference (KV cache)
@@ -674,11 +461,10 @@ def forward_prefill(params: Params, tokens: jax.Array, cfg: GPT2Config,
     full (B, T, V) head projection would be mostly wasted work and
     device→host traffic.  None returns the full (B, T, V)."""
     B, T = tokens.shape
-    attn = _resolve_attn(cfg)
     x = _embed(params, tokens, jnp.arange(T), cfg)
 
     def body(carry, lp):
-        return _block(carry, lp, cfg, attn, collect_kv=True)
+        return _block(carry, lp, cfg, collect_kv=True)
 
     x, (ks, vs) = lax.scan(body, x, params["blocks"])
     with _scope("ln_f"):
@@ -699,13 +485,15 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
                                              jax.Array]:
     """One decode step over the paged KV pool.
 
-    tokens/positions (B,) int32; kv_pool (N, L, 2, bs, H, D) — the
-    engine's block pool, on the device (read-only here: the new token's K/V
-    is returned, the runner's program writes it); block_tables (B, MAXB) int32;
+    tokens/positions (B,) int32; kv_pool — the engine's block pool, on
+    the device, in the format ``ops/paged_attention.layer_pools`` reads
+    (read-only here: the new token's K/V is returned, the runner's
+    program writes it); block_tables (B, MAXB) int32;
     ctx_lens (B,) int32.  Returns (logits (B, V) f32,
     new_k (L, B, H, D), new_v (L, B, H, D)).
     """
-    from ray_tpu.ops.paged_attention import paged_attention_decode
+    from ray_tpu.ops.paged_attention import (layer_pools,
+                                             paged_attention_decode)
     B = tokens.shape[0]
     E, H, D = cfg.n_embd, cfg.n_head, cfg.head_dim
     x = _embed(params, tokens, positions, cfg)                  # (B, E)
@@ -714,10 +502,9 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
         x = carry
         lp, layer = xs
         with _scope("kv_layout"):
-            # this layer's pools (N, bs, H, D), sliced where they lie: a
-            # split of the whole pool ahead of the scan is a pass over it
-            kv = kv_pool[:, layer]
-            k_pool, v_pool = kv[:, 0], kv[:, 1]
+            # sliced where they lie: a split of the whole pool ahead of
+            # the scan is a pass over it
+            k_pool, v_pool = layer_pools(kv_pool, layer)
         with _scope("ln_1"):
             h = _layer_norm(x[:, None, :], lp["ln_1"]["scale"],
                             lp["ln_1"]["bias"])[:, 0]

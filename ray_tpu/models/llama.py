@@ -6,7 +6,7 @@ GPT-2.  Same architecture conventions as the public Llama-2/3 papers:
 pre-RMSNorm, rotary position embeddings, grouped-query attention, SwiGLU
 MLP, untied output head.  Layout follows gpt2.py: stacked per-layer params
 + ``lax.scan`` (pipeline-axis ready), bf16 activations / f32 params,
-pluggable attention impls for long-context (ring/Ulysses/flash).
+attention through ``ops.attention.causal_attention``, which chooses.
 """
 
 from __future__ import annotations
@@ -21,7 +21,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models._common import normal_init, param_count  # noqa: F401
+from ray_tpu.models._common import (  # noqa: F401
+    next_token_nll, normal_init, param_count, remat_block, split_batch)
 
 Params = Dict[str, Any]
 
@@ -40,13 +41,14 @@ class LlamaConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = True
-    # full: recompute the block in the backward.  attn: keep the flash
-    # kernel's output and lse (gpt2.py's policy of that name), so that the
-    # backward does not run the forward kernel again; needs flash.
-    remat_policy: str = "full"   # full | attn
-    # auto: the Pallas flash kernel where the code can see a TPU and the
+    # _common.remat_block: full recomputes the block in the backward;
+    # attn keeps the flash kernel's output and lse, so that the backward
+    # does not run the forward kernel again; needs flash
+    remat_policy: str = "full"   # full | attn | attn_qkv
+    # passed to ops.attention.causal_attention, which chooses: "auto" is
+    # the Pallas flash kernel where the code can see a TPU and the
     # kernel's block tiles the sequence, XLA dense elsewhere
-    attn_impl: str = "dense"     # auto | dense | flash | ring | ulysses
+    attn_impl: str = "auto"      # auto | dense | flash | ring | ulysses
     context_axis: Optional[str] = None
     # RMSNorm over the whole projected q and k (own scales), before the
     # split into heads and before RoPE (OLMoE)
@@ -165,33 +167,6 @@ def _gqa_expand(kv: jax.Array, n_head: int) -> jax.Array:
     return jnp.repeat(kv, rep, axis=2)
 
 
-def _resolved_attn_impl(cfg: LlamaConfig, seq_len: int) -> str:
-    if cfg.attn_impl != "auto":
-        return cfg.attn_impl
-    from ray_tpu.models.gpt2 import _flash_tiles
-    on_chip = jax.default_backend() == "tpu"
-    return "flash" if on_chip and _flash_tiles(seq_len) else "dense"
-
-
-def _attention(q, k, v, cfg: LlamaConfig):
-    impl = _resolved_attn_impl(cfg, q.shape[1])
-    if impl == "dense":
-        from ray_tpu.models.gpt2 import dense_causal_attention
-        return dense_causal_attention(q, k, v, None)
-    if impl == "flash":
-        from ray_tpu.ops.flash_attention import flash_attention
-        return flash_attention(q, k, v, True)
-    if impl == "ring":
-        from ray_tpu.ops.ring_attention import ring_attention_for_model
-        return ring_attention_for_model(q, k, v, cfg,
-                                        axis_name=cfg.context_axis)
-    if impl == "ulysses":
-        from ray_tpu.ops.ulysses import ulysses_attention_for_model
-        return ulysses_attention_for_model(q, k, v, cfg,
-                                           axis_name=cfg.context_axis)
-    raise ValueError(f"unknown attn_impl {cfg.attn_impl!r}")
-
-
 def _qkv(h: jax.Array, lp: Params, cfg: LlamaConfig):
     """Normed hidden states (..., E) -> q (..., H, D), k, v (..., KV, D),
     before RoPE."""
@@ -238,7 +213,9 @@ def _block(x: jax.Array, lp: Params, cfg: LlamaConfig,
         q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
     ke, ve = _gqa_expand(k, H), _gqa_expand(v, H)
     with jax.named_scope("attn"):
-        a = _attention(q, ke, ve, cfg).reshape(B, T, E)
+        from ray_tpu.ops.attention import causal_attention
+        a = causal_attention(q, ke, ve, impl=cfg.attn_impl,
+                             context_axis=cfg.context_axis).reshape(B, T, E)
     x = x + a @ lp["wo"]["kernel"].astype(cfg.dtype)
     h = _rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
     f, stats = _ffn(h, lp, cfg)
@@ -254,19 +231,10 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig):
     or None for a dense model)."""
     x = params["wte"].astype(cfg.dtype)[tokens]
     block = partial(_block, cfg=cfg)
-    if cfg.remat and cfg.remat_policy == "attn":
-        impl = _resolved_attn_impl(cfg, tokens.shape[1])
-        if impl != "flash":
-            raise ValueError("remat_policy='attn' keeps what only the flash "
-                             f"kernel names; attn_impl resolves to {impl!r}")
-        block = jax.checkpoint(
-            block, policy=jax.checkpoint_policies.save_only_these_names(
-                "flash_attn_out", "flash_attn_lse"))
-    elif cfg.remat and cfg.remat_policy == "full":
-        block = jax.checkpoint(block)
-    elif cfg.remat:
-        raise ValueError(f"unknown remat_policy {cfg.remat_policy!r} "
-                         "(expected 'full' or 'attn')")
+    if cfg.remat:
+        from ray_tpu.ops.attention import flash_runs
+        block = remat_block(block, cfg.remat_policy,
+                            flash_runs(tokens.shape[1], cfg.attn_impl))
 
     x, stats = lax.scan(block, x, params["blocks"])
     return _rms_norm(x, params["norm_f"]["scale"], cfg.rms_eps), stats
@@ -325,11 +293,12 @@ def forward_prefill(params: Params, tokens: jax.Array, cfg: LlamaConfig,
 def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
                    kv_pool: jax.Array, block_tables: jax.Array,
                    ctx_lens: jax.Array, cfg: LlamaConfig):
-    """One decode step over the paged KV pool (see gpt2.forward_decode).
+    """One decode step over the engine's paged KV pool, in the format
+    ``ops/paged_attention.layer_pools`` reads (read-only here).
 
-    kv_pool (N, L, 2, bs, KV, D); returns (logits (B, V) f32,
-    new_k (L, B, KV, D), new_v (L, B, KV, D))."""
-    from ray_tpu.ops.paged_attention import paged_attention_decode
+    Returns (logits (B, V) f32, new_k (L, B, KV, D), new_v (L, B, KV, D))."""
+    from ray_tpu.ops.paged_attention import (layer_pools,
+                                             paged_attention_decode)
     B = tokens.shape[0]
     E = cfg.n_embd
     x = params["wte"].astype(cfg.dtype)[tokens]                 # (B, E)
@@ -337,9 +306,7 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
     def body(carry, xs):
         x = carry
         lp, layer = xs
-        # this layer's pools (N, bs, KV, D), sliced where they lie
-        kv = kv_pool[:, layer]
-        k_pool, v_pool = kv[:, 0], kv[:, 1]
+        k_pool, v_pool = layer_pools(kv_pool, layer)
         h = _rms_norm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
         q, k, v = _qkv(h, lp, cfg)
         q = _rope_at(q, positions, cfg.rope_theta)
@@ -358,33 +325,18 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
     return logits.astype(jnp.float32), ks, vs
 
 
-def _cross_entropy(x: jax.Array, head: jax.Array,
-                   tgt: jax.Array) -> jax.Array:
-    """Mean next-token NLL of hidden states x (B, T, E) under the head
-    (E, V), as gpt2.loss_fn computes it: logsumexp of the float32 logits
-    less the target's logit read from the activation-dtype logits, so no
-    float32 (B, T, V) tensor exists."""
-    with jax.named_scope("lm_head"):
-        logits = x @ head
-    with jax.named_scope("loss_ce"):
-        lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
-        correct = jnp.take_along_axis(logits, tgt[..., None], -1)[..., 0]
-        return (lse - correct.astype(jnp.float32)).mean()
-
-
 def loss_fn(params: Params, batch: Dict[str, jax.Array],
             cfg: LlamaConfig) -> jax.Array:
     """Next-token cross entropy, a scalar.  With experts it also holds the
     two router terms (balance and z, each a mean over layers) at the
     configuration's coefficients, and hands both and the worst expert load
     to the train step's metrics (spmd.report_step_metrics)."""
-    if "inputs" in batch:
-        inp, tgt = batch["inputs"], batch["targets"]
-    else:
-        inp, tgt = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
+    inp, tgt = split_batch(batch)
     x, stats = forward_hidden(params, inp, cfg)
-    loss = _cross_entropy(x, params["lm_head"]["kernel"].astype(cfg.dtype),
-                          tgt)
+    head = params["lm_head"]["kernel"].astype(cfg.dtype)
+    with jax.named_scope("lm_head"):
+        logits = x @ head
+    loss = next_token_nll(logits, tgt)
     if stats is None:
         return loss
     balance, z = stats.balance_loss.mean(), stats.z_loss.mean()

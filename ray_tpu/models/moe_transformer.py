@@ -24,7 +24,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models import gpt2 as gpt2_lib
-from ray_tpu.models._common import normal_init, param_count  # noqa: F401
+from ray_tpu.models._common import (  # noqa: F401
+    normal_init, param_count, split_batch)
+from ray_tpu.ops.attention import causal_attention
 from ray_tpu.ops import moe as moe_lib
 
 Params = Dict[str, Any]
@@ -112,7 +114,9 @@ def _block(x, lp, cfg: MoEConfig):
                      lp["attn_qkv"]["kernel"].astype(cfg.dtype))
     qkv = qkv + lp["attn_qkv"]["bias"].astype(cfg.dtype)
     q, kk, v = [qkv[:, :, i, :].reshape(B, T, H, D) for i in range(3)]
-    a = gpt2_lib.dense_causal_attention(q, kk, v, None).reshape(B, T, E)
+    # dense: under GSPMD over the expert axis a Mosaic kernel cannot be
+    # partitioned (ROADMAP D15)
+    a = causal_attention(q, kk, v, impl="dense").reshape(B, T, E)
     a = a @ lp["attn_out"]["kernel"].astype(cfg.dtype) \
         + lp["attn_out"]["bias"].astype(cfg.dtype)
     x = x + a
@@ -151,10 +155,7 @@ def forward(params: Params, tokens: jax.Array, cfg: MoEConfig
 
 def loss_fn(params: Params, batch: Dict[str, jax.Array],
             cfg: MoEConfig) -> jax.Array:
-    if "inputs" in batch:
-        inp, tgt = batch["inputs"], batch["targets"]
-    else:
-        inp, tgt = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
+    inp, tgt = split_batch(batch)
     logits, metrics = forward(params, inp, cfg)
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]

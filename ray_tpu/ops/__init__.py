@@ -1,14 +1,15 @@
 """ray_tpu.ops — TPU kernels and long-context attention (SURVEY.md §5.7).
 
 The reference framework ships no kernels; these are greenfield TPU-first
-components: flash/blockwise attention, a Pallas flash kernel, the two
-context-parallel schedules (ring via ppermute, Ulysses via all-to-all),
-and the decomposed collective matmuls that hide model-parallel
+components: causal attention behind one choice (XLA dense or the Pallas
+flash kernel), paged decode attention, the two context-parallel
+schedules (ring via ppermute, Ulysses via all-to-all), and the
+decomposed collective matmuls that hide model-parallel
 all-gather/reduce-scatter legs behind chunked compute (DESIGN.md §4m).
 """
 
 from ray_tpu.ops.attention import (  # noqa: F401
-    blockwise_attention, dense_attention,
+    causal_attention, dense_attention, flash_runs,
 )
 from ray_tpu.ops.collective_matmul import (  # noqa: F401
     all_gather_matmul, matmul_reduce_scatter, ring_scan,
@@ -25,7 +26,7 @@ from ray_tpu.ops.ulysses import (  # noqa: F401
 )
 
 __all__ = [
-    "dense_attention", "blockwise_attention", "flash_attention",
+    "causal_attention", "flash_runs", "dense_attention", "flash_attention",
     "all_gather_matmul", "matmul_reduce_scatter", "ring_scan",
     "paged_attention_decode",
     "ring_attention", "ring_attention_sharded",

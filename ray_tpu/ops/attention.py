@@ -1,14 +1,17 @@
-"""Attention primitives: streaming-softmax (flash-style) building blocks.
+"""Causal attention for training and prefill: the choice, and its pieces.
 
 The reference framework (Ray) contains no kernels at all (SURVEY.md §5.7);
-these are greenfield TPU-first components.  This module holds the
-single-device pieces:
+these are greenfield TPU-first components.
 
-- ``flash_update``: the online-softmax block update shared by blockwise,
-  ring (``ray_tpu.ops.ring_attention``) and Ulysses attention.
-- ``blockwise_attention``: memory-efficient causal attention via
-  ``lax.scan`` over KV blocks — O(T·block) activation memory instead of
-  O(T²), differentiable by autodiff, XLA keeps the block matmuls on the MXU.
+- ``causal_attention``: what every decoder block calls.  It chooses the
+  implementation from what it can see (the backend, the sequence length,
+  the ambient mesh), as ``ops/paged_attention.paged_attention_decode``
+  does for decode; ``flash_runs`` is the one statement of when the Pallas
+  kernel (``ops/flash_attention.py``) runs.
+- ``dense_attention``: XLA's O(T²) attention, the path everywhere the
+  kernel does not run and the tests' reference.
+- ``flash_update`` / ``flash_finalize``: the online-softmax block update
+  that ring attention (``ops/ring_attention.py``) walks around its ring.
 
 Accumulators are float32 regardless of input dtype (bf16-safe softmax).
 """
@@ -16,12 +19,10 @@ Accumulators are float32 regardless of input dtype (bf16-safe softmax).
 from __future__ import annotations
 
 import math
-from functools import partial
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 NEG_INF = jnp.finfo(jnp.float32).min
 
@@ -65,49 +66,6 @@ def causal_mask(q_pos: jax.Array, k_pos: jax.Array) -> jax.Array:
     return q_pos[:, None] >= k_pos[None, :]
 
 
-@partial(jax.jit, static_argnames=("causal", "block_size"))
-def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                        *, causal: bool = True,
-                        block_size: int = 512) -> jax.Array:
-    """Memory-efficient attention. (B,T,H,D)×3 → (B,T,H,D).
-
-    Scans KV in blocks with online softmax; with an outer ``jax.checkpoint``
-    this is the long-sequence single-device path (activation memory
-    O(B·H·T·D), never O(T²)).
-    """
-    B, Tq, H, D = q.shape
-    Tk = k.shape[1]
-    bs = min(block_size, Tk)
-    if Tk % bs:
-        raise ValueError(f"kv length {Tk} not divisible by block {bs}")
-    scale = 1.0 / math.sqrt(D)
-    nblocks = Tk // bs
-    kb = k.reshape(B, nblocks, bs, H, D).transpose(1, 0, 2, 3, 4)
-    vb = v.reshape(B, nblocks, bs, H, D).transpose(1, 0, 2, 3, 4)
-    q_pos = jnp.arange(Tq)
-
-    o0 = jnp.zeros((B, H, Tq, D), jnp.float32)
-    m0 = jnp.full((B, H, Tq), NEG_INF)
-    l0 = jnp.zeros((B, H, Tq), jnp.float32)
-
-    def body(carry, xs):
-        o, m, l = carry
-        i, kblk, vblk = xs
-        if causal:
-            k_pos = i * bs + jnp.arange(bs)
-            mask = causal_mask(q_pos, k_pos)[None, None]
-        else:
-            mask = None
-        o, m, l = flash_update(o, m, l, q, kblk, vblk, mask, scale)
-        return (o, m, l), None
-
-    # Forward block order satisfies flash_update's masking contract for
-    # causal attention: block 0 contains k=0, a valid key for every row.
-    idx = jnp.arange(nblocks)
-    (o, _, l), _ = lax.scan(body, (o0, m0, l0), (idx, kb, vb))
-    return flash_finalize(o, l, q.dtype)
-
-
 def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     *, causal: bool = True,
                     q_offset: int | jax.Array = 0) -> jax.Array:
@@ -125,3 +83,52 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         logits = jnp.where(mask[None, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+IMPLS = ("auto", "dense", "flash", "ring", "ulysses")
+
+
+def flash_runs(seq_len: int, impl: str = "auto") -> bool:
+    """Whether ``causal_attention`` runs the Pallas flash kernel on a
+    sequence of this length: asked for (``"flash"``; off a TPU that is
+    the kernel's interpret mode) or seen (``"auto"`` on a TPU backend),
+    and the kernel's best block tiles the sequence.  A length with no
+    clean tile (a 192-token serving bucket: block 128 does not divide)
+    takes XLA's dense attention and must not take the engine down."""
+    if impl not in IMPLS:
+        raise ValueError(f"unknown attn_impl {impl!r} (expected one of "
+                         f"{' | '.join(IMPLS)})")
+    wanted = impl == "flash" or (impl == "auto"
+                                 and jax.default_backend() == "tpu")
+    if not wanted:
+        return False
+    from ray_tpu.ops.flash_attention import pick_block_size
+    return seq_len % pick_block_size(seq_len) == 0
+
+
+def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                     impl: str = "auto",
+                     context_axis: Optional[str] = None) -> jax.Array:
+    """Causal self-attention over (B, T, H, D) for training and prefill.
+
+    ``impl`` is a model config's ``attn_impl``: ``auto`` (the flash
+    kernel where ``flash_runs`` says so, XLA's dense attention
+    elsewhere), ``dense``, ``flash``, or one of the context-parallel
+    schedules ``ring`` / ``ulysses`` over the ambient mesh's
+    ``context_axis`` (dense where the mesh does not split that axis)."""
+    if flash_runs(q.shape[1], impl):
+        from ray_tpu.ops.flash_attention import flash_attention
+        return flash_attention(q, k, v, True)
+    if impl in ("ring", "ulysses"):
+        from ray_tpu.parallel import mesh as mesh_lib
+        axis = context_axis or "context"
+        mesh = mesh_lib.get_ambient_mesh()
+        if mesh is not None and mesh.shape.get(axis, 1) > 1:
+            if impl == "ring":
+                from ray_tpu.ops.ring_attention import (
+                    ring_attention_sharded as sharded)
+            else:
+                from ray_tpu.ops.ulysses import (
+                    ulysses_attention_sharded as sharded)
+            return sharded(q, k, v, mesh=mesh, axis_name=axis, causal=True)
+    return dense_attention(q, k, v, causal=True)

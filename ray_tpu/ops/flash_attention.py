@@ -373,17 +373,3 @@ def pick_block_size(T: int) -> int:
             return bs
     return min(T, DEFAULT_BLOCK)
 
-
-def flash_attention_for_model(q, k, v, cfg=None, **_):
-    """Model hook (``attn_impl='flash'``, and what ``'auto'`` resolves
-    to on TPU).  Sequence lengths with no clean tile (e.g. a 192-token
-    serving bucket: best block 128 does not divide) fall back to the
-    XLA dense path instead of raising — the hook serves every model
-    entry point (train step, serving prefill), and an odd-shaped
-    bucket must not take the engine down.  Direct ``flash_attention``
-    callers still get the loud ValueError."""
-    T = q.shape[1]
-    if T % pick_block_size(T):
-        from ray_tpu.ops.attention import dense_attention
-        return dense_attention(q, k, v, causal=True)
-    return flash_attention(q, k, v, True)

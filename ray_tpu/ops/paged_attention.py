@@ -299,3 +299,17 @@ def paged_attention_decode(q: jax.Array, k_pool: jax.Array,
                                     ctx_lens, k_new, v_new)
     return _paged_decode_gather(q, k_pool, v_pool, block_tables, ctx_lens,
                                 k_new, v_new)
+
+
+def layer_pools(kv_pool: jax.Array, layer) -> tuple[jax.Array, jax.Array]:
+    """One layer's (k_pool, v_pool), each (N, bs, KV, D), out of the
+    engine's pool.
+
+    The pool's format, ``(N, L, 2, bs, KV, D)`` (block, layer, K or V,
+    position in the block, KV head, feature), belongs to its writer
+    (``serve/llm/kv_cache.py``) and to this module, its reader; the
+    models' decode steps call this inside their layer scans (``layer`` is
+    traced) and index no axis of the pool themselves.  A split of the
+    whole pool ahead of the scan would be a pass over it (PR 29)."""
+    kv = kv_pool[:, layer]
+    return kv[:, 0], kv[:, 1]

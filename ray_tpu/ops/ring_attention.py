@@ -90,16 +90,3 @@ def ring_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
                      out_specs=spec, check_vma=False)(q, k, v)
 
-
-def ring_attention_for_model(q, k, v, cfg=None, *,
-                             axis_name: Optional[str] = "context"):
-    """Model hook (``GPT2Config.attn_impl='ring'``): mesh comes from the
-    ambient program mesh set by ``ray_tpu.parallel.spmd``."""
-    from ray_tpu.parallel import mesh as mesh_lib
-    axis_name = axis_name or "context"
-    mesh = mesh_lib.get_ambient_mesh()
-    if mesh is None or axis_name not in mesh.shape \
-            or mesh.shape[axis_name] == 1:
-        return dense_attention(q, k, v, causal=True)
-    return ring_attention_sharded(q, k, v, mesh=mesh, axis_name=axis_name,
-                                  causal=True)

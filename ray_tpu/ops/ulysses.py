@@ -14,7 +14,6 @@ cheaper on ICI for moderate sequence lengths; ring wins when T is huge
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional
 
 import jax
 from jax import lax
@@ -64,15 +63,3 @@ def ulysses_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
                      out_specs=spec, check_vma=False)(q, k, v)
 
-
-def ulysses_attention_for_model(q, k, v, cfg=None, *,
-                                axis_name: Optional[str] = "context"):
-    """Model hook (``GPT2Config.attn_impl='ulysses'``)."""
-    from ray_tpu.parallel import mesh as mesh_lib
-    axis_name = axis_name or "context"
-    mesh = mesh_lib.get_ambient_mesh()
-    if mesh is None or axis_name not in mesh.shape \
-            or mesh.shape[axis_name] == 1:
-        return dense_attention(q, k, v, causal=True)
-    return ulysses_attention_sharded(q, k, v, mesh=mesh,
-                                     axis_name=axis_name, causal=True)

@@ -82,11 +82,11 @@ class ModelRunner:
             # up to the bucket are sent out of range: they write nowhere
             logits, k, v = forward_decode(params, tokens, positions, pool,
                                           block_tables, ctx_lens)
-            bs = pool.shape[3]
+            bs = cfg.block_size
             rows = jnp.arange(tokens.shape[0])
             blocks = jnp.where(rows < n_real,
                                block_tables[rows, ctx_lens // bs],
-                               pool.shape[0])
+                               cfg.num_blocks)
             pool = write_rows(pool, blocks, ctx_lens % bs, k, v)
             return pool, (logits, k, v)
 

@@ -109,11 +109,11 @@ class ShmCollectiveGroup:
             blob = b"R" + ref.hex().encode()
         self._kv_put(self._key(seq, phase, self.rank), blob)
 
-    def _fetch(self, blob: bytes) -> Any:
+    def _fetch(self, blob: bytes, timeout: Optional[float] = None) -> Any:
         if blob[:1] == b"I":
             return pickle.loads(blob[1:])
         ref = ObjectRef(blob[1:].decode(), self._w, skip_release=True)
-        return self._w.get_one(ref)
+        return self._w.get_one(ref, timeout=timeout)
 
     def _await_keys(self, seq: int, phase: str, ranks: Sequence[int],
                     timeout: float) -> Dict[int, bytes]:
@@ -298,8 +298,16 @@ class ShmCollectiveGroup:
         while True:
             blob = self._kv_get(key)
             if blob is not None:
+                # read the object, THEN delete the key: the sender keeps
+                # its ref pinned for as long as the key is there (send's
+                # lazy unpin).  Deleted first, a sender's next send could
+                # free the object under a receiver descheduled between
+                # the two calls, whose get then waited for an object that
+                # no longer existed (the ring tests' hang under load)
+                value = self._fetch(
+                    blob, max(deadline - time.monotonic(), _POLL_MAX))
                 self._kv_del(key)
-                return self._fetch(blob)
+                return value
             if time.monotonic() > deadline:
                 raise TimeoutError(
                     f"recv from rank {src_rank} timed out ({key})")

@@ -90,6 +90,19 @@ def ray_start_cluster():
 
 @pytest.fixture(autouse=True)
 def _ensure_shutdown():
+    """No test leaves its cluster, or what ``ray_tpu.init()`` did to this
+    process's jax, to the next test of the worker.  ``init`` turns
+    ``jax_include_full_tracebacks_in_locations`` off for good wherever a
+    compile cache is in use (a Pallas kernel's cache key needs it: PR 22),
+    and with it off a lowered program names other locations (nothing
+    inside a forward-only ``jax.checkpoint`` keeps its scope): tests that
+    read locations passed alone and failed in a worker that had run a
+    cluster test before them (tests/test_named_scopes.py, the driver's
+    run of PR 29)."""
+    full_tracebacks = jax.config.jax_include_full_tracebacks_in_locations
     yield
     if ray_tpu.is_initialized():
         ray_tpu.shutdown()
+    if jax.config.jax_include_full_tracebacks_in_locations != full_tracebacks:
+        jax.config.update("jax_include_full_tracebacks_in_locations",
+                          full_tracebacks)

@@ -17,7 +17,6 @@ import pytest  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from ray_tpu.ops.flash_attention import flash_attention  # noqa: E402
-from ray_tpu.ops.layer_norm import layer_norm  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -56,14 +55,6 @@ def _flash_loss(q, k, v):
     return _flash(q, k, v).astype(jnp.float32).sum()
 
 
-def _ln(x, scale, bias):
-    return layer_norm(x, scale, bias, 1e-5, False)
-
-
-def _ln_loss(x, scale, bias):
-    return _ln(x, scale, bias).astype(jnp.float32).sum()
-
-
 TRAIN_QKV = ((32, 1024, 12, 64), jnp.bfloat16)
 
 
@@ -81,17 +72,6 @@ def test_flash_attention_at_train_shape(v5e, fn):
 def test_flash_attention_at_prefill_bucket(v5e, bucket, dtype):
     qkv = ((1, bucket, 12, 64), dtype)
     _compile(_flash, v5e, qkv, qkv, qkv)
-
-
-@pytest.mark.parametrize("fn,shape", [
-    pytest.param(_ln, (32, 1024, 768), id="train_forward"),
-    pytest.param(jax.grad(_ln_loss, argnums=(0, 1, 2)), (32, 1024, 768),
-                 id="train_forward_backward"),
-    pytest.param(_ln, (4, 1, 768), id="decode_forward"),
-])
-def test_layer_norm(v5e, fn, shape):
-    affine = ((768,), jnp.float32)
-    _compile(fn, v5e, (shape, jnp.bfloat16), affine, affine)
 
 
 # ------------------------------------------------- OLMoE's training cell

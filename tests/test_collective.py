@@ -155,6 +155,36 @@ class TestShmBackend:
         assert ray_tpu.get(r0) is None
         np.testing.assert_allclose(ray_tpu.get(r1), [7.0, 8.0])
 
+    def test_p2p_object_outlives_a_slow_receiver(self, ray_start_regular,
+                                                 monkeypatch):
+        """A receiver that has seen a send's key but not yet read its
+        object (descheduled under load) must still find the object after
+        the sender's NEXT send, which unpins every send whose key is gone:
+        recv reads first and deletes the key after.  Deleting first was
+        the ring tests' load-sensitive hang (the get waited for a freed
+        object).  Both ranks live in this process, so the race is played
+        step by step."""
+        from ray_tpu.util.collective.collective_group.shm_group import (
+            ShmCollectiveGroup)
+        g0 = ShmCollectiveGroup(2, 0, "slow_recv")
+        g1 = ShmCollectiveGroup(2, 1, "slow_recv")
+        first = np.arange(300_000, dtype=np.float32)        # over INLINE_LIMIT
+        second = first + 1
+        g0.send(first, 1)
+        fetch = g1._fetch
+
+        def slow_fetch(blob, timeout=None):
+            # between the receiver's look at the key and its read of the
+            # object: the sender's next send, and its releases flushed
+            g0.send(second, 1)
+            g0._w._flush_releases()
+            return fetch(blob, 20.0)
+
+        monkeypatch.setattr(g1, "_fetch", slow_fetch)
+        np.testing.assert_array_equal(g1.recv(0, timeout=20.0), first)
+        monkeypatch.setattr(g1, "_fetch", fetch)
+        np.testing.assert_array_equal(g1.recv(0, timeout=20.0), second)
+
     def test_rank_introspection(self, ray_start_regular):
         actors = _mk_group(2)
         infos = ray_tpu.get([a.rank_info.remote() for a in actors])

@@ -1,11 +1,10 @@
 """Every layer kind and kernel of the main path has a name (ISSUE 25):
 ``jax.named_scope`` on the model's layers and the train step's phases,
-``name=`` on the four Pallas kernels.  Metadata only: the names are in
+``name=`` on the Pallas kernels.  Metadata only: the names are in
 the lowered program's locations and in the jaxpr, where profilers and
 HLO dumps find them (what reaches the v5e trace: PERF.md, section 7).
 """
 
-import dataclasses
 import re
 
 import jax
@@ -15,7 +14,6 @@ import pytest
 
 from ray_tpu.models import gpt2
 from ray_tpu.ops.flash_attention import flash_attention
-from ray_tpu.ops.layer_norm import layer_norm
 from ray_tpu.parallel import mesh as mesh_lib
 from ray_tpu.parallel import spmd
 from ray_tpu.parallel.mesh import MeshConfig
@@ -95,14 +93,26 @@ def test_train_step_names_the_scope(train_scopes, name):
     assert name in train_scopes
 
 
-@pytest.mark.parametrize("chunks", ["loss_chunks", "loss_vocab_chunks"])
-def test_chunked_losses_name_head_and_loss(params, chunks):
-    cfg = dataclasses.replace(CFG, **{chunks: 2})
+@pytest.mark.parametrize("case", ["a_cluster_in_this_process", "the_next_test"])
+def test_locations_are_named_alike_after_a_cluster_test(params, case):
+    """``ray_tpu.init()`` turns jax's full tracebacks off in its process
+    (a compile cache's keys need that), and a forward-only program under
+    ``jax.checkpoint`` then loses its scopes; ``conftest.py`` puts the
+    option back after every test, so the case that follows reads the
+    names this file's other cases read when run alone."""
+    import ray_tpu
+    if case == "a_cluster_in_this_process":
+        ray_tpu.init(num_cpus=1)
+        ray_tpu.shutdown()
+        if jax.config.jax_compilation_cache_dir:
+            assert not jax.config.jax_include_full_tracebacks_in_locations
+        return
+    assert jax.config.jax_include_full_tracebacks_in_locations
     batch = {"inputs": np.zeros((2, 16), np.int32),
              "targets": np.zeros((2, 16), np.int32)}
-    got = _scopes(jax.jit(lambda p, b: gpt2.loss_fn(p, b, cfg))
+    got = _scopes(jax.jit(lambda p, b: gpt2.loss_fn(p, b, CFG))
                   .lower(params, batch))
-    assert {"lm_head", "loss_ce", "ln_f", "mlp"} <= got
+    assert {"mlp", "attn", "lm_head", "loss_ce", "ln_f"} <= got
 
 
 def test_grad_accumulation_is_named():
@@ -114,18 +124,10 @@ def _flash_loss(q):
     return flash_attention(q, q, q, True, None, True).sum()
 
 
-def _ln_loss(x):
-    return layer_norm(x, jnp.ones(128), jnp.ones(128), 1e-5, True).sum()
-
-
-@pytest.mark.parametrize("kernel,loss,arg", [
-    ("flash_fwd", _flash_loss, (1, 128, 2, 64)),
-    ("flash_bwd", _flash_loss, (1, 128, 2, 64)),
-    ("layer_norm_fwd", _ln_loss, (8, 128)),
-    ("layer_norm_bwd", _ln_loss, (8, 128)),
-])
-def test_pallas_kernel_is_named_in_the_jaxpr(kernel, loss, arg):
-    jaxpr = str(jax.make_jaxpr(jax.grad(loss))(jnp.ones(arg, jnp.float32)))
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd"])
+def test_pallas_kernel_is_named_in_the_jaxpr(kernel):
+    jaxpr = str(jax.make_jaxpr(jax.grad(_flash_loss))(
+        jnp.ones((1, 128, 2, 64), jnp.float32)))
     assert f"name={kernel}" in jaxpr
-    named = re.findall(r"name=(?:flash|layer_norm)_(?:fwd|bwd)\b", jaxpr)
+    named = re.findall(r"name=flash_(?:fwd|bwd)\b", jaxpr)
     assert jaxpr.count("pallas_call") == len(named) == 2    # none unnamed

@@ -11,8 +11,8 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ray_tpu.ops import (blockwise_attention, dense_attention,
-                         flash_attention, ring_attention_sharded,
+from ray_tpu.ops import (causal_attention, dense_attention, flash_attention,
+                         flash_runs, ring_attention_sharded,
                          ulysses_attention_sharded)
 
 B, T, H, D = 2, 64, 4, 16
@@ -34,30 +34,6 @@ def mesh():
 def _allclose(a, b, tol=2e-5):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=tol, atol=tol)
-
-
-@pytest.mark.parametrize("causal", [True, False])
-def test_blockwise_matches_dense(qkv, causal):
-    q, k, v = qkv
-    ref = dense_attention(q, k, v, causal=causal)
-    out = blockwise_attention(q, k, v, causal=causal, block_size=16)
-    _allclose(out, ref)
-
-
-def test_blockwise_grads_match_dense(qkv):
-    q, k, v = qkv
-
-    def loss_d(q, k, v):
-        return dense_attention(q, k, v, causal=True).sum()
-
-    def loss_b(q, k, v):
-        return blockwise_attention(q, k, v, causal=True,
-                                   block_size=16).sum()
-
-    gd = jax.grad(loss_d, argnums=(0, 1, 2))(q, k, v)
-    gb = jax.grad(loss_b, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(gd, gb):
-        _allclose(a, b, tol=1e-4)
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -158,3 +134,48 @@ def test_gpt2_context_parallel_end_to_end(mesh):
         loss_ring = jax.jit(
             lambda p, b: gpt2.loss_fn(p, b, cfg_r))(params, batch)
     _allclose(loss_ring, loss_dense, tol=1e-5)
+
+
+# --------------------------------------------- the choice (ops/attention.py)
+TILES = {64: True, 128: True, 192: False, 1024: True, 4096: True}
+
+
+@pytest.mark.parametrize("impl", ["auto", "dense", "flash"])
+@pytest.mark.parametrize("seq_len", sorted(TILES))
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_flash_runs_says_what_causal_attention_traces(monkeypatch, backend,
+                                                      seq_len, impl):
+    """``flash_runs`` is the one statement of when the Pallas kernel runs:
+    asked for, or ``auto`` on a TPU, and the kernel's block tiles the
+    sequence (192 has no clean tile).  The traced call holds a
+    ``pallas_call`` named ``flash_fwd`` exactly when it says so."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    want = (impl == "flash" or (impl == "auto" and backend == "tpu")) \
+        and TILES[seq_len]
+    assert flash_runs(seq_len, impl) is want
+    q = jax.ShapeDtypeStruct((1, seq_len, 1, 64), jnp.bfloat16)
+    jaxpr = str(jax.make_jaxpr(
+        lambda q, k, v: causal_attention(q, k, v, impl=impl))(q, q, q))
+    assert ("name=flash_fwd" in jaxpr) is want
+    assert ("pallas_call" in jaxpr) is want
+
+
+@pytest.mark.parametrize("impl", ["blockwise", "Flash", ""])
+def test_unknown_attn_impl_raises_in_one_place(qkv, impl):
+    with pytest.raises(ValueError, match="unknown attn_impl"):
+        flash_runs(64, impl)
+    with pytest.raises(ValueError, match="unknown attn_impl"):
+        causal_attention(*qkv, impl=impl)
+
+
+@pytest.mark.parametrize("impl", ["ring", "ulysses"])
+def test_context_parallel_impls_through_the_choice(qkv, mesh, impl):
+    """``ring`` / ``ulysses`` run over the ambient mesh's context axis and
+    are XLA's dense attention where no mesh splits it."""
+    from ray_tpu.parallel import mesh as mesh_lib
+    ref = dense_attention(*qkv, causal=True)
+    _allclose(causal_attention(*qkv, impl=impl), ref)       # no mesh
+    with mesh_lib.ambient_mesh(mesh):
+        out = jax.jit(lambda q, k, v: causal_attention(
+            q, k, v, impl=impl, context_axis="context"))(*qkv)
+    _allclose(out, ref)

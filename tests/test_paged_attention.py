@@ -116,3 +116,21 @@ def test_cpu_calls_take_the_gather_path(monkeypatch):
     bf16_pools = tuple(p.astype(jnp.bfloat16) for p in pools)
     pa.paged_attention_decode(q, *bf16_pools, *rest)  # declined: not f32
     assert len(called) == 1
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_layer_pools_is_the_pools_layer(traced):
+    """``layer_pools`` against numpy indexing of the engine's
+    ``(N, L, 2, bs, KV, D)`` pool, with the layer static and traced (as
+    the models' decode scans pass it).  Both models' ``forward_decode``
+    through it are held to the gather reference by
+    ``tests/test_serve_llm.py``."""
+    pool = np.random.default_rng(0).normal(
+        size=(5, 3, 2, 4, 2, 8)).astype(np.float32)
+    for layer in range(3):
+        if traced:
+            k, v = jax.jit(pa.layer_pools)(pool, jnp.int32(layer))
+        else:
+            k, v = pa.layer_pools(jnp.asarray(pool), layer)
+        np.testing.assert_array_equal(np.asarray(k), pool[:, layer, 0])
+        np.testing.assert_array_equal(np.asarray(v), pool[:, layer, 1])
