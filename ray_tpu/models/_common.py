@@ -1,9 +1,11 @@
-"""What the decoders share: init and count helpers, the remat rule of a
-block, the batch's two forms and the loss head.  Each model file keeps
-its own block, parameter tree and named scopes."""
+"""What the decoders share: init and count helpers, the rule that makes a
+serving tree of a stored one, the remat rule of a block, the batch's two
+forms and the loss head.  Each model file keeps its own block, parameter
+tree and named scopes."""
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Callable, Dict, Tuple
 
 import jax
@@ -16,6 +18,51 @@ def normal_init(key: jax.Array, shape, dtype, scale: float = 0.02):
 
 def param_count(params: Any) -> int:
     return sum(int(x.size) for x in jax.tree_util.tree_leaves(params))
+
+
+def tree_bytes(tree: Any) -> int:
+    """Bytes of a tree's leaves (arrays or ``ShapeDtypeStruct``s)."""
+    return sum(int(x.size) * jnp.dtype(x.dtype).itemsize
+               for x in jax.tree_util.tree_leaves(tree))
+
+
+def _stays_wide(path, wide: Tuple[str, ...]) -> bool:
+    return any(getattr(k, "key", None) in wide for k in path)
+
+
+@partial(jax.jit, static_argnames=("dtype", "wide"))
+def _cast_leaves(params: Any, dtype: Any, wide: Tuple[str, ...]) -> Any:
+    return jax.tree_util.tree_map_with_path(
+        lambda path, w: w if _stays_wide(path, wide) else w.astype(dtype),
+        params)
+
+
+def serving_params(params: Any, dtype: Any, wide: Tuple[str, ...]) -> Any:
+    """A stored parameter tree as the inference forwards should be handed
+    it: the same structure and names, each weight stored once in the type
+    the forward computes in.
+
+    A forward casts a stored weight to ``cfg.dtype`` at its use
+    (``gpt2._cast``, ``.astype(cfg.dtype)`` in llama.py,
+    ``w.astype(x.dtype)`` in ops/moe.py).  On a float32 tree under a bf16
+    config that convert is part of every program run: XLA hoists it out of
+    the scan over layers as one pass over the whole stack, 13.85 of a
+    27 ms GPT-2 XL decode step (PERF.md, PR 31).  Handed this tree, the
+    same ``astype`` emits no operation.  A convert is exact and each
+    matmul took ``dtype`` operands already, so the arithmetic is the same.
+
+    ``wide``: the keys under which the family's forward uses a leaf as it
+    is stored (norm scales and biases, multiplied in float32); the model
+    module states them beside its ``init_params`` (``WIDE_PARAMS``).
+    Every other leaf is cast.
+
+    One jitted call; where no leaf would change type it returns ``params``
+    itself and builds no program."""
+    dtype, wide = jnp.dtype(dtype), tuple(wide)
+    if all(_stays_wide(path, wide) or w.dtype == dtype
+           for path, w in jax.tree_util.tree_leaves_with_path(params)):
+        return params
+    return _cast_leaves(params, dtype=dtype, wide=wide)
 
 
 def remat_block(block: Callable, policy: str, flash_runs: bool) -> Callable:

@@ -41,6 +41,10 @@ class GPT2Config:
     n_layer: int = 12
     n_head: int = 12
     dtype: Any = jnp.bfloat16          # activation dtype
+    # the type init_params stores in: what training keeps and updates
+    # (the benchmark's training cells store bf16).  Not what a serving
+    # program reads: ModelRunner hands its programs _common.serving_params
+    # of the tree, every leaf outside WIDE_PARAMS already in ``dtype``
     param_dtype: Any = jnp.float32
     remat: bool = True
     # _common.remat_block: full recomputes a block in the backward; attn
@@ -140,6 +144,12 @@ def init_params(rng: jax.Array, cfg: GPT2Config) -> Params:
     }
 
 
+# The keys whose leaves the forwards use as stored: _layer_norm multiplies
+# its scale and adds its bias in float32.  Every other leaf goes through
+# _cast at its use, so _common.serving_params may store it in cfg.dtype.
+WIDE_PARAMS = ("ln_1", "ln_2", "ln_f")
+
+
 # ------------------------------------------------------------------ forward
 # Every layer kind runs under a ``jax.named_scope`` (embed, ln_1, attn_qkv,
 # attn, attn_out, ln_2, mlp, ln_f, lm_head, loss_ce; in the decode step also
@@ -150,8 +160,11 @@ _scope = jax.named_scope
 
 
 def _cast(w: jax.Array, cfg: "GPT2Config") -> jax.Array:
-    """A stored weight in the compute dtype: a convert only where it is
-    stored wider (the serving engine's float32 weights)."""
+    """A stored weight in the compute dtype.  A convert only where it is
+    stored in another type: training over float32 state, or a forward
+    called with a float32 tree.  The serving engine's programs are handed
+    ``_common.serving_params`` of the tree (``ModelRunner``), and the
+    benchmark's training state is bf16, so there this emits nothing."""
     with _scope("cast_weights"):
         return w.astype(cfg.dtype)
 

@@ -39,6 +39,8 @@ class LlamaConfig:
     rope_theta: float = 10000.0
     rms_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
+    # what init_params stores and training updates; a serving program is
+    # handed _common.serving_params of the tree (see WIDE_PARAMS)
     param_dtype: Any = jnp.float32
     remat: bool = True
     # _common.remat_block: full recomputes the block in the backward;
@@ -136,6 +138,14 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Params:
         "norm_f": {"scale": jnp.ones((E,), pd)},
         "lm_head": {"kernel": normal_init(next(k), (E, cfg.vocab_size), pd)},
     }
+
+
+# The keys whose leaves the forwards use as stored: _rms_norm multiplies
+# its scale in float32.  Every other leaf is cast at its use, by
+# ``.astype(cfg.dtype)`` here or by ``w.astype(x.dtype)`` in
+# ops/moe.dropless_moe_ffn (the router and the experts), so
+# _common.serving_params may store it in cfg.dtype.
+WIDE_PARAMS = ("attn_norm", "mlp_norm", "q_norm", "k_norm", "norm_f")
 
 
 # ------------------------------------------------------------------ forward

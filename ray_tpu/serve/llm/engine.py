@@ -806,6 +806,7 @@ class LLMEngine:
                     waiting=len(self.sched.waiting),
                     blocks_free=self.cache.free_block_count(),
                     compiles=self.runner.compiles,
+                    param_bytes=self.runner.param_bytes,
                     admitted=self.admitted,
                     queue_wait_s=self.queue_wait_s,
                     requeue_wait_s=self.requeue_wait_s,

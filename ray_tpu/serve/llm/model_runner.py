@@ -20,6 +20,15 @@ both families get the write-back from one place.  Prefill leaves a
 prompt's K/V on the device for ``PagedKVCache.scatter_prefill``.  What
 comes to the host is the logits: sampling (greedy / temperature / top-k)
 happens host-side on (V,) rows.
+
+Where the serving type of the weights is decided: here, once.  Whatever
+tree the runner ends up with (the caller's, ``init_params``' own in the
+model's ``param_dtype``, the shm plane's) goes through
+``models/_common.serving_params`` in ``__init__``: every leaf the
+family's forward casts to the model's ``dtype`` is stored in that type,
+the leaves the module names in ``WIDE_PARAMS`` stay as stored.  The step
+programs take that tree (``runner.params``), so a weight is converted
+once in an engine's life and not in every program run.
 """
 
 from __future__ import annotations
@@ -57,12 +66,25 @@ class ModelRunner:
         import jax
         import jax.numpy as jnp
 
+        from ray_tpu.models._common import serving_params, tree_bytes
+
         self.cfg = cfg
         self.mod, self.mcfg = resolve_model(cfg)
         self.weights_key: str = ""      # set when the shm plane is used
+        # hot-span totals, name -> [count, seconds]; the engine shares
+        # this dict with its own spans (LLMEngine.stats()["span_s"])
+        self.span_s: dict = {}
         if params is None:
             params = self._load_params()
-        self.params = params
+        # once in a runner's life, like llm.compile absent from a window;
+        # bytes_out == bytes_in: the tree was in its serving type already
+        with hot_span("llm.weights.prepare", self.span_s,
+                      bytes_in=tree_bytes(params)) as span:
+            self.params = jax.block_until_ready(serving_params(
+                params, self.mcfg.dtype, self.mod.WIDE_PARAMS))
+            # LLMEngine.stats()["param_bytes"]: what a step program reads
+            self.param_bytes = tree_bytes(self.params)
+            span.set(bytes_out=self.param_bytes)
         self.n_layer = self.mcfg.n_layer
         self.n_kv = getattr(self.mcfg, "n_kv_head", self.mcfg.n_head)
         self.head_dim = self.mcfg.head_dim
@@ -100,9 +122,6 @@ class ModelRunner:
         self.cache: Optional[PagedKVCache] = None
         self.compiles = 0          # observability: distinct programs built
         self._shapes_seen: set = set()
-        # hot-span totals, name -> [count, seconds]; the engine shares
-        # this dict with its own spans (LLMEngine.stats()["span_s"])
-        self.span_s: dict = {}
         # XLA watchdog step regions (DESIGN.md §4q): one compile per
         # bucket for the runner's life, zero host transfers inside the
         # dispatch.  The post-dispatch np.asarray pulls of the logits

@@ -554,6 +554,189 @@ def test_the_benchmark_harness_calls_keep_working():
         eng.shutdown()
 
 
+# ------------------------------------ the weights are cast once (ISSUE 31)
+SERVED = ["gpt2:tiny", "llama:tiny", "llama:tiny-moe"]
+
+
+def _stored_tree(cfg):
+    """The tree ``init_params`` makes: float32 under a bf16-computing
+    preset, what a caller like the benchmark's harness hands over."""
+    import jax
+    mod, mcfg = resolve_model(cfg)
+    assert mcfg.param_dtype != mcfg.dtype
+    return mod, mcfg, mod.init_params(jax.random.key(3), mcfg)
+
+
+def _weight_converts(jaxpr, shapes):
+    """convert_element_type equations, sub-programs included, that narrow
+    a float32 operand of one of ``shapes``."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "convert_element_type":
+            a = eqn.invars[0].aval
+            if a.shape in shapes and a.dtype == np.float32 \
+                    and eqn.outvars[0].aval.dtype != np.float32:
+                found.append(a.shape)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _weight_converts(sub, shapes)
+    return found
+
+
+@pytest.mark.parametrize("model", SERVED)
+def test_step_programs_convert_no_weight(model):
+    """Handed the runner's tree, decode and prefill hold no convert of a
+    weight; handed the stored tree, the same programs hold one a cast
+    leaf (the detector can see them).  The leaves a module keeps wide
+    are as stored, all others in the compute type."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.serve.llm.model_runner import ModelRunner
+    cfg = tiny_cfg(model=model, decode_batch_buckets=(4,))
+    mod, mcfg, stored = _stored_tree(cfg)
+    runner = ModelRunner(cfg, params=stored)
+    flat = jax.tree_util.tree_flatten_with_path(runner.params)[0]
+    assert len(flat) == len(jax.tree_util.tree_leaves(stored))
+    wide = 0
+    for path, leaf in flat:
+        keep = any(k.key in mod.WIDE_PARAMS for k in path)
+        wide += keep
+        assert leaf.dtype == (jnp.float32 if keep else mcfg.dtype), path
+    assert 0 < wide < len(flat)
+    S = jax.ShapeDtypeStruct
+    i32 = lambda *shape: S(shape, jnp.int32)      # noqa: E731
+    pool = S((cfg.num_blocks, runner.n_layer, 2, cfg.block_size,
+              runner.n_kv, runner.head_dim), jnp.float32)
+    # a stacked leaf is sliced to one layer inside the scan
+    shapes = {x.shape for x in jax.tree_util.tree_leaves(stored)} \
+        | {x.shape[1:] for x in jax.tree_util.tree_leaves(stored["blocks"])}
+    for tree, n_cast in ((runner.params, 0), (stored, len(flat) - wide)):
+        decode = jax.make_jaxpr(runner._decode)(
+            pool, tree, i32(4), i32(4), i32(4, cfg.max_blocks_per_seq),
+            i32(4), i32())
+        prefill = jax.make_jaxpr(runner._prefill)(tree, i32(1, 32), i32())
+        for jaxpr in (decode, prefill):
+            got = _weight_converts(jaxpr.jaxpr, shapes)
+            assert (len(got) >= n_cast) if n_cast else not got, got
+
+
+@pytest.mark.parametrize("model", SERVED)
+def test_prepared_tree_gives_the_stored_trees_logits(model):
+    """Prefill and 4 decode steps of an engine handed the float32 tree
+    against the models' own forwards over that float32 tree: the same
+    bits.  A convert is exact and each matmul took bf16 operands
+    already, so only the place of the conversion moved."""
+    import jax
+    cfg = tiny_cfg(model=model)
+    mod, mcfg, stored = _stored_tree(cfg)
+    pure_prefill = jax.jit(lambda p, t, last: mod.forward_prefill(
+        p, t, mcfg, last_pos=last))
+    pure_decode = jax.jit(lambda *a: mod.forward_decode(*a, mcfg))
+    eng = LLMEngine(cfg, params=stored, start=False)
+    try:
+        runner, cache = eng.runner, eng.cache
+        prompt = np.random.default_rng(7).integers(1, 100, 11).tolist()
+        cache.alloc_seq("chk", len(prompt))
+        logits, ks, vs = runner.prefill(prompt)
+        toks = np.zeros((1, 16), np.int32)
+        toks[0, :len(prompt)] = prompt
+        want, wk, wv = pure_prefill(stored, toks, np.int32(len(prompt) - 1))
+        np.testing.assert_array_equal(logits, np.asarray(want)[0])
+        np.testing.assert_array_equal(np.asarray(ks, np.float32),
+                                      np.asarray(wk, np.float32)[:, 0])
+        cache.scatter_prefill("chk", ks, vs, len(prompt))
+        seq = list(prompt)
+        maxb = cfg.max_blocks_per_seq
+        for _ in range(4):
+            seq.append(int(np.argmax(logits)))
+            cache.append_slot("chk")
+            tables = np.zeros((1, maxb), np.int32)
+            table = cache.table("chk")
+            tables[0, :len(table)] = table
+            at = np.asarray([len(seq) - 1], np.int32)
+            tok = np.asarray([seq[-1]], np.int32)
+            before = np.asarray(cache.pool[:])
+            want, wk, wv = pure_decode(stored, tok, at, before, tables, at)
+            lg, k, v = runner.decode(tok, at, cache.pool, tables, at)
+            logits = lg[0]
+            np.testing.assert_array_equal(logits, np.asarray(want)[0])
+            np.testing.assert_array_equal(np.asarray(v, np.float32)[:, :1],
+                                          np.asarray(wv, np.float32))
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("model", SERVED)
+def test_a_tree_in_its_serving_type_is_taken_as_it_is(model):
+    """Equal stored and compute types (a prepared tree handed on; bf16
+    training state, norms included): the runner's leaves are the
+    caller's own and no program is built."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models import _common
+    from ray_tpu.serve.llm.model_runner import ModelRunner
+    cfg = tiny_cfg(model=model)
+    mod, mcfg, stored = _stored_tree(cfg)
+    first = ModelRunner(cfg, params=stored)
+    assert first.span_s["llm.weights.prepare"][0] == 1
+    trained = jax.tree_util.tree_map(lambda x: x.astype(jnp.bfloat16), stored)
+    built = _common._cast_leaves._cache_size()
+    for tree in (first.params, trained):
+        runner = ModelRunner(cfg, params=tree)
+        for mine, theirs in zip(jax.tree_util.tree_leaves(runner.params),
+                                jax.tree_util.tree_leaves(tree)):
+            assert mine is theirs
+        assert runner.param_bytes == _common.tree_bytes(tree)
+        assert runner.span_s["llm.weights.prepare"][0] == 1
+    assert _common._cast_leaves._cache_size() == built
+
+
+@pytest.mark.parametrize("model", SERVED)
+def test_weights_are_prepared_once_and_counted(model):
+    import jax
+    eng = LLMEngine(tiny_cfg(model=model))
+    try:
+        eng.generate(list(range(1, 9)), SamplingParams(max_tokens=20))
+        stats = eng.stats()
+        assert stats["prefill_steps"] + stats["decode_steps"] >= 20
+        assert stats["span_s"]["llm.weights.prepare"][0] == 1
+        leaves = jax.tree_util.tree_leaves(eng.runner.params)
+        assert stats["param_bytes"] == sum(x.nbytes for x in leaves)
+        # float32 as stored; every matrix is a cast leaf
+        stored = sum(x.size * 4 for x in leaves)
+        assert stored / 2 < stats["param_bytes"] < 0.6 * stored
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("model", SERVED)
+def test_weights_through_the_shm_plane_serve_the_same_tokens(model):
+    """The plane stores what ``init_params`` made; an engine that
+    published it, one that attached and one that loaded privately serve
+    the same tokens from trees in the serving type."""
+    import jax
+    sp = SamplingParams(max_tokens=8)
+    prompt = list(range(3, 14))
+    engines = [LLMEngine(tiny_cfg(model=model, seed=31, share_weights=share))
+               for share in (True, True, False)]
+    try:
+        assert engines[0].runner.weights_key and engines[1].runner.weights_key
+        outs = [eng.generate(prompt, sp) for eng in engines]
+        assert outs[0] == outs[1] == outs[2]
+        for eng in engines:
+            assert eng.stats()["param_bytes"] == \
+                engines[2].stats()["param_bytes"]
+            for a, b in zip(jax.tree_util.tree_leaves(eng.runner.params),
+                            jax.tree_util.tree_leaves(
+                                engines[2].runner.params)):
+                assert a.dtype == b.dtype
+    finally:
+        for eng in engines:
+            eng.shutdown()
+
+
 # ------------------------------------------------------- weights plane
 def test_weights_shared_through_shm_plane():
     from ray_tpu.serve.llm import weights as wmod
