@@ -552,6 +552,8 @@ STEP_PATHS: Set[str] = {
 DONATED: Dict[str, Tuple[int, ...]] = {
     "step_fn": (0,),
     "llm_decode_step": (0,),
+    "llm_prefill_state_step": (0,),
+    "llm_decode_state_step": (0,),
     "kv_write_rows": (0,),
     "kv_scatter_prefill": (0,),
     "kv_load_block": (0,),

@@ -27,8 +27,9 @@ class EngineConfig:
     """Engine-level knobs (model, cache geometry, batching limits).
 
     ``model`` is "<family>:<preset>" over the in-tree model zoo —
-    ``gpt2:tiny``, ``gpt2:gpt2-124m``, ``llama:tiny``, ``llama:llama3-8b``
-    … (``models/gpt2.py`` / ``models/llama.py`` PRESETS).
+    ``gpt2:tiny``, ``gpt2:gpt2-124m``, ``llama:tiny``, ``llama:llama3-8b``,
+    ``falcon_h1:tiny`` … (``models/gpt2.py`` / ``models/llama.py`` /
+    ``models/falcon_h1.py`` PRESETS).
     """
 
     model: str = "gpt2:tiny"
@@ -65,9 +66,11 @@ def resolve_model(cfg: EngineConfig):
         from ray_tpu.models import gpt2 as mod
     elif family == "llama":
         from ray_tpu.models import llama as mod
+    elif family == "falcon_h1":
+        from ray_tpu.models import falcon_h1 as mod
     else:
         raise ValueError(f"unknown model family {family!r} "
-                         "(expected gpt2|llama)")
+                         "(expected gpt2|llama|falcon_h1)")
     try:
         mcfg = mod.PRESETS[preset]()
     except KeyError:

@@ -127,9 +127,12 @@ class LLMEngine:
         _weights.reap_orphans()
         self.cfg = cfg
         self.runner = ModelRunner(cfg, params)
+        # a family with recurrent state gets a row of it per sequence
+        # slot beside the blocks, in the same holder (kv_cache.py)
         self.cache = PagedKVCache(
             cfg.num_blocks, self.runner.n_layer, cfg.block_size,
-            self.runner.n_kv, self.runner.head_dim, dtype=np.float32)
+            self.runner.n_kv, self.runner.head_dim, dtype=np.float32,
+            state=self.runner.state_spec, max_seqs=cfg.max_num_seqs)
         self.runner.cache = self.cache
         self.sched = IterationScheduler(cfg.max_num_seqs,
                                         cfg.max_prefill_tokens,
@@ -159,6 +162,9 @@ class LLMEngine:
         # the share of the table a step has to read (loop-owned ints)
         self.attn_blocks_read = 0
         self.attn_blocks_table = 0
+        # rows of recurrent state the compiled decode steps read and
+        # wrote: the whole store each step, whatever the batch (loop-owned)
+        self.state_rows_stepped = 0
         # hot-span totals of the loop and the runner, name ->
         # [count, seconds] (tracing.hot_span); the names are a contract,
         # PERF.md section 3 lists each with the metric that reads it
@@ -356,8 +362,11 @@ class LLMEngine:
         with self._lock:
             self.prefill_steps += 1
         # K/V never left the device: the scatter is the enqueue of a
-        # second device program
-        with hot_span("llm.prefill.scatter", self.span_s):
+        # second device program; with recurrent state it also commits the
+        # prompt's to the sequence's row, which the span then names
+        row = {"row": self.cache.state_row(seq.seq_id)} \
+            if self.cache.state_rows else {}
+        with hot_span("llm.prefill.scatter", self.span_s, **row):
             self.cache.scatter_prefill(seq.seq_id, ks, vs, len(seq.prompt))
         # sampling step = tokens generated so far RELATIVE TO THE
         # ORIGINAL prompt, so a preemption re-prefill (k tokens folded
@@ -455,6 +464,9 @@ class LLMEngine:
             self.attn_blocks_read += blocks
             self.attn_blocks_table += maxb * _bucket(
                 len(batch), self.cfg.decode_batch_buckets)
+            if self.cache.state_rows:
+                span.set(state_rows=len(batch))
+                self.state_rows_stepped += self.cache.state_rows + 1
         try:
             # the step writes each new token's K/V into its slot itself;
             # the K/V it also returns stay on the device, unread
@@ -549,6 +561,7 @@ class LLMEngine:
         Runs on the caller's thread (the engine loop keeps decoding its
         own batch meanwhile; cache alloc/free are thread-safe)."""
         from ray_tpu._private.data_plane import write_spool
+        self._blocks_are_all_a_sequence_holds("prefill_remote")
         sampling = sampling or SamplingParams()
         if self._stop.is_set():
             raise RuntimeError("engine shut down")
@@ -612,6 +625,7 @@ class LLMEngine:
         """Adopt a remotely-prefilled sequence: pull its KV blocks over
         the streamed data plane and continue decoding — no re-prefill."""
         from ray_tpu._private.data_plane import DataPlanePool
+        self._blocks_are_all_a_sequence_holds("attach")
         if manifest["model"] != self.cfg.model:
             raise ValueError(f"manifest model {manifest['model']!r} != "
                              f"engine model {self.cfg.model!r}")
@@ -685,6 +699,15 @@ class LLMEngine:
             raise RuntimeError("engine shut down")
         self._wake.set()
         return RequestStream(seq.seq_id, q, self)
+
+    def _blocks_are_all_a_sequence_holds(self, what: str) -> None:
+        """The manifest of ``prefill_remote`` / ``attach`` carries blocks
+        and nothing else: half a sequence, for a model with more."""
+        if self.cache.state_rows:
+            raise NotImplementedError(
+                f"{what}: {self.cfg.model} keeps recurrent state beside "
+                "its K/V blocks, and the manifest exports blocks only; "
+                "nothing moves the state yet, so nothing is moved")
 
     def _drain_cancels(self) -> None:
         with self._lock:
@@ -813,5 +836,10 @@ class LLMEngine:
                     kv_host_bytes=self.cache.host_bytes,
                     attn_blocks_read=self.attn_blocks_read,
                     attn_blocks_table=self.attn_blocks_table,
+                    state_bytes=self.cache.state_bytes,
+                    state_rows=self.cache.state_rows,
+                    state_rows_used=self.cache.state_rows_used(),
+                    state_rows_stepped=self.state_rows_stepped,
+                    state_commits=self.cache.state_commits,
                     span_s={k: list(v) for k, v in
                             list(self.span_s.items())})

@@ -27,12 +27,27 @@ in block grains — preemption under cache pressure returns exactly the
 preempted sequence's blocks.  Shared blocks (an attached sequence
 re-exported, future prefix caching) are refcounted: ``free_seq`` returns
 a block to the free list only at refcount zero.
+
+Recurrent state.  A model family whose sequences hold more than K/V (a
+state-space mixer's scan state and conv tail: ``models/falcon_h1.py``)
+describes one sequence's state per layer, and the cache is built with
+that description (``state=``).  What ``cache.pool`` holds is then not the
+pool's array alone but ``{"kv": the array, "state": the store}``, the
+store one leaf per kind of state, ``(n_layer, max_seqs + 1, *shape)``: a
+*row* per sequence slot and a last one for staging.  The same holder, the
+same donating programs, one lifetime: ``alloc_seq`` gives a sequence its
+row with its blocks and ``free_seq`` (finish, cancel, preemption) takes
+both back.  The row is fixed-size and never paged.  A prompt's prefill
+program leaves its state in the staging row (``model_runner.py``), and
+``scatter_prefill`` moves it to the sequence's row in the program that
+scatters its K/V; the decode program steps the rows ``rows_of`` names
+for the tables it was given.  Without ``state=`` the holder holds the
+array as before and every program lowers as before.
 """
 
 from __future__ import annotations
 
 import functools
-import operator
 import os
 import threading
 from types import SimpleNamespace
@@ -88,6 +103,15 @@ def _pid_alive(pid: int) -> bool:
     return True
 
 
+def _kv(held):
+    """The K/V pool's array out of what a :class:`DevicePool` holds."""
+    return held["kv"] if isinstance(held, dict) else held
+
+
+def _with_kv(held, kv):
+    return {**held, "kv": kv} if isinstance(held, dict) else kv
+
+
 # write_rows makes one masked pass over the pool for up to this many rows
 # (a decode batch), and leaves more (a prompt) to XLA's scatter
 _ROWS_IN_ONE_PASS = 16
@@ -137,34 +161,47 @@ def _programs() -> SimpleNamespace:
     import jax.numpy as jnp
     from jax import lax
 
-    def _write_rows(pool, blocks, offsets, k, v):
-        return write_rows(pool, blocks, offsets, k, v), None
+    def _write_rows(held, blocks, offsets, k, v):
+        return _with_kv(held, write_rows(_kv(held), blocks, offsets, k, v)), \
+            None
 
-    def _scatter_prefill(pool, table, ks, vs, n_tokens):
+    def _scatter_prefill(held, table, ks, vs, n_tokens, *row):
         # token t of the padded prompt -> slot t % bs of block table[t // bs];
         # padding (t >= n_tokens) is sent out of range and dropped
+        pool = _kv(held)
         bs = pool.shape[3]
         t = jnp.arange(ks.shape[1])
         blocks = jnp.where(t < n_tokens, table[t // bs], pool.shape[0])
-        return write_rows(pool, blocks, t % bs, ks, vs), None
+        held = _with_kv(held, write_rows(pool, blocks, t % bs, ks, vs))
+        if row:
+            # recurrent state: the prompt's, which its prefill left in the
+            # staging row, goes to the sequence's row
+            held = {**held, "state": jax.tree.map(
+                lambda s: lax.dynamic_update_index_in_dim(
+                    s, s[:, -1], row[0], 1), held["state"])}
+        return held, None
 
-    def _load_block(pool, block_id, block):
-        return lax.dynamic_update_index_in_dim(pool, block, block_id, 0), None
+    def _load_block(held, block_id, block):
+        return _with_kv(held, lax.dynamic_update_index_in_dim(
+            _kv(held), block, block_id, 0)), None
 
     # the names are rows of lock_watchdog.DONATED (jaxlint pins them)
     kv_write_rows = jax.jit(_write_rows, donate_argnums=(0,))
     kv_scatter_prefill = jax.jit(_scatter_prefill, donate_argnums=(0,))
     kv_load_block = jax.jit(_load_block, donate_argnums=(0,))
     read_block = jax.jit(
-        lambda pool, block_id: lax.dynamic_index_in_dim(
-            pool, block_id, 0, keepdims=False))
+        lambda held, block_id: lax.dynamic_index_in_dim(
+            _kv(held), block_id, 0, keepdims=False))
     return SimpleNamespace(write_rows=kv_write_rows,
                            scatter_prefill=kv_scatter_prefill,
                            load_block=kv_load_block, read_block=read_block)
 
 
 class DevicePool:
-    """The block pool's device array, whoever holds it now.
+    """The block pool's device array, whoever holds it now; with ``state``
+    (a store's leaves as ``ShapeDtypeStruct``s) what it holds is
+    ``{"kv": that array, "state": the store}``, and ``donate`` / ``read``
+    / ``fill`` do not care which.
 
     A donating program deletes the array it was given and returns a new
     one over the same memory, so nobody may keep the array itself:
@@ -175,8 +212,9 @@ class DevicePool:
     for the enqueue only, and the device runs the programs in the order
     they were enqueued."""
 
-    def __init__(self, shape, dtype):
+    def __init__(self, shape, dtype, state=None):
         self.shape, self.dtype = tuple(shape), dtype
+        self.state = state
         self._pool_lock = threading.Lock()
         self._array = None                             # guarded by: _pool_lock
         self.fill(0)
@@ -194,7 +232,8 @@ class DevicePool:
             return program(self._array, *args)
 
     def __getitem__(self, index):
-        return self.read(operator.getitem, index)
+        """Of the K/V pool's array."""
+        return self.read(lambda held: _kv(held)[index])
 
     def fill(self, value) -> None:
         """A new array of ``value``.  The old one is waited for and
@@ -202,25 +241,50 @@ class DevicePool:
         keeps its memory past its deletion, the new one is allocated at
         the enqueue, and two pools may not fit the device (seen on the
         v5e: 2.52 GB in use after a fill of a 1.26 GB pool)."""
+        import jax
         import jax.numpy as jnp
         with self._pool_lock:
-            if self._array is not None:
-                self._array.block_until_ready().delete()
+            for old in jax.tree.leaves(self._array):
+                old.block_until_ready().delete()
             self._array = jnp.full(self.shape, value, self.dtype)
+            if self.state is not None:
+                self._array = {"kv": self._array, "state": jax.tree.map(
+                    lambda s: jnp.full(s.shape, value, s.dtype), self.state)}
 
 
 class PagedKVCache:
     """Block pool + tables + refcounts for one engine instance."""
 
     def __init__(self, num_blocks: int, n_layer: int, block_size: int,
-                 n_kv: int, head_dim: int, dtype=np.float32):
+                 n_kv: int, head_dim: int, dtype=np.float32, *,
+                 state=None, max_seqs: int = 0):
+        """``state``: one sequence's recurrent state in one layer, name ->
+        ``ShapeDtypeStruct`` (a model module's ``recurrent_state``), for a
+        family that has one; the store then has ``max_seqs`` rows and one
+        for staging."""
         self.num_blocks = num_blocks
         self.block_shape = (n_layer, 2, block_size, n_kv, head_dim)
         self.block_size = block_size
         self.dtype = np.dtype(dtype)
         self.block_nbytes = int(np.prod(self.block_shape)) * \
             self.dtype.itemsize
-        self.pool = DevicePool((num_blocks,) + self.block_shape, self.dtype)
+        # rows of recurrent state a sequence can be given, the staging
+        # row (the store's last) and the store's bytes; 0 without state
+        self.state_rows = max_seqs if state else 0
+        self.staging_row = self.state_rows
+        # names no row: a decode step reads somewhere and writes nowhere
+        self.no_row = self.state_rows + 1
+        store = None
+        if state:
+            import jax
+            store = {name: jax.ShapeDtypeStruct(
+                (n_layer, max_seqs + 1) + tuple(s.shape), s.dtype)
+                for name, s in state.items()}
+        self.state_bytes = sum(
+            int(np.prod(s.shape)) * np.dtype(s.dtype).itemsize
+            for s in (store or {}).values())
+        self.pool = DevicePool((num_blocks,) + self.block_shape, self.dtype,
+                               state=store)
         # one step region for the writers below (DESIGN.md §4q): a
         # scatter program per prefill bucket, write_token, load_block
         self._write_budget = compile_budget("llm.kv_write")
@@ -229,6 +293,12 @@ class PagedKVCache:
         self._tables: Dict[str, List[int]] = {}                      # guarded by: _lock
         self._fill: Dict[str, int] = {}                              # guarded by: _lock
         self._ref: Dict[int, int] = {}                               # guarded by: _lock
+        # a sequence's row of recurrent state, the rows nobody has, and
+        # who owns a table's first block (how rows_of knows a table)
+        self._rows: Dict[str, int] = {}                              # guarded by: _lock
+        self._free_rows: List[int] = list(range(self.state_rows - 1, -1, -1))  # guarded by: _lock
+        self._owner: Dict[int, str] = {}                             # guarded by: _lock
+        self.state_commits = 0                                       # guarded by: _lock
         # bytes of pool data that crossed between host and device, either
         # way: K/V given as numpy, blocks exported or imported
         self.host_bytes = 0                                          # guarded by: _lock
@@ -251,7 +321,8 @@ class PagedKVCache:
 
     def alloc_seq(self, seq_id: str, n_tokens: int) -> List[int]:
         """Allocate blocks for ``n_tokens`` of context; table starts full
-        to ``n_tokens`` (prefill scatters into them immediately)."""
+        to ``n_tokens`` (prefill scatters into them immediately).  With
+        recurrent state the sequence gets its row as well, or nothing."""
         n = self.blocks_needed(n_tokens)
         with self._lock:
             if seq_id in self._tables:
@@ -259,11 +330,19 @@ class PagedKVCache:
             if len(self._free) < n:
                 raise NoFreeBlocks(
                     f"need {n} blocks, {len(self._free)} free")
+            if self.state_rows:
+                if not self._free_rows:
+                    raise NoFreeBlocks(
+                        f"all {self.state_rows} rows of recurrent state "
+                        "are taken")
+                self._rows[seq_id] = self._free_rows.pop()
             blocks = [self._free.pop() for _ in range(n)]
             for b in blocks:
                 self._ref[b] = 1
             self._tables[seq_id] = blocks
             self._fill[seq_id] = n_tokens
+            if self.state_rows:
+                self._owner[blocks[0]] = seq_id
         return blocks
 
     def append_slot(self, seq_id: str) -> tuple:
@@ -308,6 +387,10 @@ class PagedKVCache:
         with self._lock:
             blocks = self._tables.pop(seq_id, None)
             self._fill.pop(seq_id, None)
+            row = self._rows.pop(seq_id, None)
+            if row is not None:
+                self._free_rows.append(row)
+                self._owner.pop(blocks[0], None)
             if not blocks:
                 return 0
             freed = 0
@@ -322,6 +405,10 @@ class PagedKVCache:
     def fork_seq(self, seq_id: str, new_seq_id: str) -> None:
         """Share a sequence's blocks with a new id (refcount bump) —
         the prefix-sharing/export primitive."""
+        if self.state_rows:
+            raise NotImplementedError(
+                "a sequence with recurrent state cannot be forked: its row "
+                "is its own, and a shared first block would name two rows")
         with self._lock:
             blocks = list(self._tables[seq_id])
             for b in blocks:
@@ -345,6 +432,23 @@ class PagedKVCache:
     def seq_ids(self) -> List[str]:
         with self._lock:
             return list(self._tables)
+
+    def state_rows_used(self) -> int:
+        with self._lock:
+            return len(self._rows)
+
+    def state_row(self, seq_id: str) -> int:
+        with self._lock:
+            return self._rows[seq_id]
+
+    def rows_of(self, block_tables: np.ndarray) -> np.ndarray:
+        """The row of recurrent state behind each block table (B, MAXB):
+        that of the sequence which owns the table's first block.  A
+        table no sequence owns gets ``no_row``, outside the store."""
+        with self._lock:
+            return np.asarray(
+                [self._rows.get(self._owner.get(int(t[0])), self.no_row)
+                 for t in block_tables], np.int32)
 
     # ------------------------------------------------------- block transfer
     def _crossed(self, nbytes: int) -> None:
@@ -375,23 +479,33 @@ class PagedKVCache:
     def scatter_prefill(self, seq_id: str, ks, vs, n_tokens: int) -> None:
         """Write prefill KV (L, T_pad, KV, D), numpy or device arrays of
         any float type, into the seq's blocks (only the first
-        ``n_tokens`` positions are real, and only they are written)."""
-        self._scatter(self.table(seq_id), ks, vs, n_tokens)
+        ``n_tokens`` positions are real, and only they are written).
+        With recurrent state, the same program commits the state the
+        prompt's prefill staged to the sequence's row."""
+        row = ()
+        if self.state_rows:
+            with self._lock:
+                row = (self._rows[seq_id],)
+                self.state_commits += 1
+        self._scatter(self.table(seq_id), ks, vs, n_tokens, *row)
 
     def warm_scatter(self, ks, vs) -> None:
         """Build the scatter program for this shape of K/V without
         writing a token (the runner calls it with a bucket's first
         prefill, so the bucket's two programs are built together)."""
-        self._scatter([], ks, vs, 0)
+        row = (self.staging_row,) if self.state_rows else ()
+        self._scatter([], ks, vs, 0, *row)
 
-    def _scatter(self, table: List[int], ks, vs, n_tokens: int) -> None:
+    def _scatter(self, table: List[int], ks, vs, n_tokens: int,
+                 *row: int) -> None:
         # the table at the width of the padded prompt; the blocks past
         # the sequence's own are out of range, and dropped on the device
         padded = np.full(-(-ks.shape[1] // self.block_size),
                          self.num_blocks, np.int32)
         padded[:len(table)] = table[:len(padded)]
         self._write(_programs().scatter_prefill, padded, ks, vs,
-                    np.int32(n_tokens), host=(ks, vs))
+                    np.int32(n_tokens), *(np.int32(r) for r in row),
+                    host=(ks, vs))
 
     def write_token(self, block_id: int, offset: int, k, v) -> None:
         """Write one token's (L, KV, D) K/V into its slot.  The decode

@@ -10,16 +10,31 @@ len(decode_buckets)`` for the engine's life (SURVEY.md §7.3: replica
 cold starts are XLA compiles; bounding them is the TPU-serving
 equivalent of connection pooling).
 
-The runner is model-family-agnostic: ``models/gpt2.py`` and
-``models/llama.py`` each export ``forward_prefill`` / ``forward_decode``
-(the decode step reads the paged pool through
-``ops/paged_attention.py`` and returns the new token's K/V); the runner's
-decode program then writes that K/V into the pool, which it was given
-donated, so the pool stays on the device (``kv_cache.DevicePool``) and
-both families get the write-back from one place.  Prefill leaves a
-prompt's K/V on the device for ``PagedKVCache.scatter_prefill``.  What
-comes to the host is the logits: sampling (greedy / temperature / top-k)
-happens host-side on (V,) rows.
+The runner is model-family-agnostic: ``models/gpt2.py``,
+``models/llama.py`` and ``models/falcon_h1.py`` each export
+``forward_prefill`` / ``forward_decode`` (the decode step reads the paged
+pool through ``ops/paged_attention.py`` and returns the new token's K/V);
+the runner's decode program then writes that K/V into the pool, which it
+was given donated, so the pool stays on the device
+(``kv_cache.DevicePool``) and every family gets the write-back from one
+place.  Prefill leaves a prompt's K/V on the device for
+``PagedKVCache.scatter_prefill``.  What comes to the host is the logits:
+sampling (greedy / temperature / top-k) happens host-side on (V,) rows.
+
+Recurrent state.  A family whose sequences hold more than K/V says so by
+exporting ``recurrent_state(cfg)``: one sequence's state in one layer,
+name -> shape and type.  That description is all the runner and the cache
+know of it (``runner.state_spec``; the engine builds its cache with it):
+what the cache's holder holds is then ``{"kv": pool, "state": store}``
+(``kv_cache.py``), and both step programs take it donated.  The prefill
+program writes the state at the prompt's last real position into the
+store's staging row, for ``scatter_prefill`` to commit to the sequence's
+row; the decode program hands the model the store and, for each batch
+row, the store row the cache names for its block table (rows padded up
+to the bucket name none, and write nowhere).  ``prefill`` and ``decode``
+keep their signatures and results: the state travels behind them.  A
+module that exports no description is served by the programs it always
+had, which lower as before.
 
 Where the serving type of the weights is decided: here, once.  Whatever
 tree the runner ends up with (the caller's, ``init_params``' own in the
@@ -89,6 +104,10 @@ class ModelRunner:
         self.n_kv = getattr(self.mcfg, "n_kv_head", self.mcfg.n_head)
         self.head_dim = self.mcfg.head_dim
         self.vocab = self.mcfg.vocab_size
+        # one sequence's recurrent state in one layer, for a family that
+        # has one (None: K/V is all a sequence holds)
+        describe = getattr(self.mod, "recurrent_state", None)
+        self.state_spec = describe(self.mcfg) if describe else None
         forward_prefill = partial(self.mod.forward_prefill, cfg=self.mcfg)
         forward_decode = partial(self.mod.forward_decode, cfg=self.mcfg)
 
@@ -96,27 +115,59 @@ class ModelRunner:
             logits, ks, vs = forward_prefill(params, toks, last_pos=last_pos)
             return logits[0], ks[:, 0], vs[:, 0]
 
-        def decode_step(pool, params, tokens, positions, block_tables,
-                        ctx_lens, n_real):
-            # the model reads the pool and attends the new token
-            # explicitly; its K/V goes to the slot append_slot reserved,
-            # (table[ctx // bs], ctx % bs), after the reads.  Rows padded
-            # up to the bucket are sent out of range: they write nowhere
-            logits, k, v = forward_decode(params, tokens, positions, pool,
-                                          block_tables, ctx_lens)
+        def new_kv_written(pool, k, v, block_tables, ctx_lens, n_real):
+            # a row's new K/V goes to the slot append_slot reserved,
+            # (table[ctx // bs], ctx % bs).  Rows padded up to the bucket
+            # are sent out of range: they write nowhere
             bs = cfg.block_size
-            rows = jnp.arange(tokens.shape[0])
+            rows = jnp.arange(ctx_lens.shape[0])
             blocks = jnp.where(rows < n_real,
                                block_tables[rows, ctx_lens // bs],
                                cfg.num_blocks)
-            pool = write_rows(pool, blocks, ctx_lens % bs, k, v)
+            return write_rows(pool, blocks, ctx_lens % bs, k, v)
+
+        def decode_step(pool, params, tokens, positions, block_tables,
+                        ctx_lens, n_real):
+            # the model reads the pool and attends the new token
+            # explicitly; its K/V is written after the reads
+            logits, k, v = forward_decode(params, tokens, positions, pool,
+                                          block_tables, ctx_lens)
+            pool = new_kv_written(pool, k, v, block_tables, ctx_lens, n_real)
             return pool, (logits, k, v)
+
+        def prefill_state_step(held, params, toks, last_pos):
+            # the state at the prompt's last real position goes to the
+            # store's last row, where scatter_prefill finds it
+            logits, ks, vs, state = forward_prefill(params, toks,
+                                                    last_pos=last_pos)
+            store = jax.tree.map(lambda s, new: s.at[:, -1].set(new[:, 0]),
+                                 held["state"], state)
+            return {**held, "state": store}, (logits[0], ks[:, 0], vs[:, 0])
+
+        def decode_state_step(held, params, tokens, positions, block_tables,
+                              ctx_lens, n_real, state_rows):
+            # as decode_step, and the model steps the rows of the store
+            # that state_rows names
+            logits, k, v, store = forward_decode(
+                params, tokens, positions, held["kv"], block_tables,
+                ctx_lens, state=held["state"], rows=state_rows)
+            pool = new_kv_written(held["kv"], k, v, block_tables, ctx_lens,
+                                  n_real)
+            return {"kv": pool, "state": store}, (logits, k, v)
 
         # bound to a name of its own: jaxlint pins a donating jit by the
         # name it is assigned to (lock_watchdog.DONATED)
-        llm_decode_step = jax.jit(decode_step, donate_argnums=(0,))
-        self._prefill = jax.jit(prefill_step)
-        self._decode = llm_decode_step
+        if self.state_spec is None:
+            llm_decode_step = jax.jit(decode_step, donate_argnums=(0,))
+            self._prefill = jax.jit(prefill_step)
+            self._decode = llm_decode_step
+        else:
+            llm_prefill_state_step = jax.jit(prefill_state_step,
+                                             donate_argnums=(0,))
+            llm_decode_state_step = jax.jit(decode_state_step,
+                                            donate_argnums=(0,))
+            self._prefill = llm_prefill_state_step
+            self._decode = llm_decode_state_step
         # the engine's cache: a bucket's scatter program is built with
         # the bucket's first prefill (None: a runner on its own)
         self.cache: Optional[PagedKVCache] = None
@@ -163,7 +214,11 @@ class ModelRunner:
         # the device finishes); pull ends when the logits are on the host
         with compiling, hot_span("llm.prefill.dispatch", self.span_s), \
                 self._prefill_budget:
-            logits, ks, vs = self._prefill(self.params, toks, last_pos)
+            if self.state_spec is None:
+                logits, ks, vs = self._prefill(self.params, toks, last_pos)
+            else:
+                logits, ks, vs = self._state_cache().pool.donate(
+                    self._prefill, self.params, toks, last_pos)
             if compiling is not _SEEN and self.cache is not None:
                 self.cache.warm_scatter(ks, vs)
         with hot_span("llm.prefill.pull", self.span_s):
@@ -199,6 +254,13 @@ class ModelRunner:
                 block_tables = np.concatenate(
                     [block_tables, np.zeros((pad, block_tables.shape[1]),
                                             np.int32)])
+        state_rows = ()
+        if self.state_spec is not None:
+            # whose table each is, the cache knows; padded rows name none
+            cache = self._state_cache()
+            state_rows = (np.concatenate(
+                [cache.rows_of(block_tables[:b]),
+                 np.full(pad, cache.no_row, np.int32)]),)
         # dispatch holds the jitted call and ends at the ENQUEUE; pull
         # ends when the logits are on the host, so it holds the wait for
         # the step and nothing else: the pool stays where it is
@@ -206,10 +268,18 @@ class ModelRunner:
                 self._decode_budget:
             logits, ks, vs = kv_pool.donate(
                 self._decode, self.params, tokens, positions, block_tables,
-                ctx_lens, np.int32(b))
+                ctx_lens, np.int32(b), *state_rows)
         with hot_span("llm.decode.pull", self.span_s):
             logits = np.asarray(logits)[:b]
         return logits, ks, vs
+
+    def _state_cache(self) -> PagedKVCache:
+        """The cache whose holder has the store of recurrent state."""
+        if self.cache is None:
+            raise RuntimeError(
+                f"{self.cfg.model} keeps recurrent state, which lives in "
+                "the engine's cache: a runner on its own cannot serve it")
+        return self.cache
 
     def _note_shape(self, program: str, bucket: int):
         """A context for the call that follows: an ``llm.compile`` span

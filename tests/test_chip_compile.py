@@ -143,6 +143,7 @@ def _paged(q, k_pool, v_pool, tables, lens, k_new, v_new):
     pytest.param(25, 25, 64, 128, id="gpt2_xl"),         # 1,600 lanes
     pytest.param(16, 16, 128, 128, id="olmoe"),
     pytest.param(32, 8, 128, 256, id="grouped_query"),
+    pytest.param(20, 4, 128, 1024, id="falcon_h1"),      # 5 queries a KV head
 ])
 def test_paged_decode_kernel_at_decode_shape(v5e, heads, kv_heads, head_dim,
                                              blocks):
@@ -212,3 +213,88 @@ def test_serving_cell_decode_program_fits_and_gathers_nothing(
         - mem.alias_size_in_bytes + mem.temp_size_in_bytes
     assert mem.alias_size_in_bytes >= 1.25e9        # the pool, donated
     assert held < 5e9, held
+
+
+# ------------------------------------------- the Falcon-H1 cell's programs
+@pytest.fixture(scope="module")
+def falcon_h1_runner(v5e):
+    """The cell's runner over abstract weights, and what its holder holds
+    (the K/V pool and the store of recurrent state) as shapes on the chip."""
+    import json
+    from pathlib import Path
+
+    from ray_tpu.models import falcon_h1
+    from ray_tpu.serve.llm import EngineConfig
+    from ray_tpu.serve.llm.config import resolve_model
+    from ray_tpu.serve.llm.model_runner import ModelRunner
+    engine = json.loads((Path(__file__).parent.parent / "perfbench" /
+                         "configs" / "falcon-h1-34b.json").read_text()
+                        )["serve"]["engine"]
+    for key in ("decode_batch_buckets", "prefill_len_buckets"):
+        engine[key] = tuple(engine[key])
+    ecfg = EngineConfig(**engine)
+    mod, mcfg = resolve_model(ecfg)
+    assert mod is falcon_h1
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.eval_shape(lambda key: mod.init_params(key, mcfg),
+                            jax.random.key(0))
+    runner = ModelRunner(ecfg, params=params)
+    assert runner.params is params      # drawn in its serving type
+    held = {
+        "kv": on_chip((ecfg.num_blocks, mcfg.n_layer, 2, ecfg.block_size,
+                       mcfg.n_kv_head, mcfg.head_dim), jnp.float32),
+        "state": {name: on_chip((mcfg.n_layer, ecfg.max_num_seqs + 1)
+                                + s.shape, s.dtype)
+                  for name, s in runner.state_spec.items()}}
+    weights = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), params)
+    return runner, ecfg, held, weights, on_chip
+
+
+def _held_bytes(compiled):
+    mem = compiled.memory_analysis()
+    return mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes, mem
+
+
+def test_falcon_h1_decode_program_fits_and_steps_the_store_in_place(
+        falcon_h1_runner, monkeypatch):
+    """The cell's decode step at its one bucket of 32 (6 layers at the
+    published widths, 1,024 blocks, 33 rows of state): the paged kernel
+    at 20 / 4 heads x 128 in the layer scan, K/V pool and store donated
+    (1.25e9 bytes aliased), no second copy of the store among the
+    temporaries, and everything in 11.9e9 of the chip's 16.9e9 bytes."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, ecfg, held, weights, on_chip = falcon_h1_runner
+    bucket, i32 = ecfg.decode_batch_buckets[-1], jnp.int32
+    assert runner.param_bytes == 10_509_371_648
+    compiled = runner._decode.lower(
+        held, weights, on_chip((bucket,), i32), on_chip((bucket,), i32),
+        on_chip((bucket, ecfg.max_blocks_per_seq), i32),
+        on_chip((bucket,), i32), on_chip((), i32),
+        on_chip((bucket,), i32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "paged_decode" in text
+    total, mem = _held_bytes(compiled)
+    assert mem.alias_size_in_bytes >= 1.24e9
+    assert mem.temp_size_in_bytes < 0.2e9       # the store is 0.84e9
+    assert total < 12.5e9 < 16.9e9, total
+
+
+def test_falcon_h1_prefill_program_fits_at_bucket_512(falcon_h1_runner,
+                                                      monkeypatch):
+    """The prefill program at the traffic's largest bucket: flash
+    attention at 20 heads x 128 and the chunked scan, the state written
+    to the staging row of the donated store."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, _, held, weights, on_chip = falcon_h1_runner
+    compiled = runner._prefill.lower(
+        held, weights, on_chip((1, 512), jnp.int32),
+        on_chip((), jnp.int32)).compile()
+    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+    total, mem = _held_bytes(compiled)
+    assert mem.alias_size_in_bytes >= 1.24e9
+    assert total < 12.5e9 < 16.9e9, total
