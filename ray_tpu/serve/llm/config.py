@@ -21,6 +21,12 @@ class SamplingParams:
     stop_token: Optional[int] = None
     seed: int = 0
 
+    @property
+    def greedy(self) -> bool:
+        """The next token is the logits' argmax: the step program's own
+        choice serves it, and no logits need come to the host."""
+        return self.temperature <= 0.0
+
 
 @dataclass(frozen=True)
 class EngineConfig:

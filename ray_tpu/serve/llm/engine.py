@@ -7,7 +7,9 @@ thread runs ``step()`` forever: drain new requests, plan the iteration
 (``model_runner.py``), push sampled tokens to the per-request streams.
 New KV goes into the block pool (``kv_cache.py``) on the device, where
 the pool lives: the decode program writes its own token, prefill's K/V is
-scattered by a second program; only logits come to the host.
+scattered by a second program.  A greedy token is chosen by the step
+program too: what comes to the host is a token id a row, and the logits
+of a row whose request samples (temperature > 0) and of no other.
 
 Disaggregated prefill/decode rides the PR-4 data plane:
 ``prefill_remote()`` copies the filled blocks from the device into a
@@ -36,7 +38,7 @@ from ray_tpu._private import rtlog
 from ray_tpu._private.config import GLOBAL_CONFIG
 from ray_tpu.serve.llm.config import EngineConfig, SamplingParams
 from ray_tpu.serve.llm.kv_cache import NoFreeBlocks, PagedKVCache
-from ray_tpu.serve.llm.model_runner import ModelRunner, _bucket
+from ray_tpu.serve.llm.model_runner import Chosen, ModelRunner, _bucket
 from ray_tpu.serve.llm.scheduler import (FAILED, FINISHED, IterationScheduler,
                                          Plan, Sequence)
 from ray_tpu.util import metrics_catalog as mcat
@@ -47,6 +49,12 @@ logger = rtlog.get("serve.llm.engine")
 
 _DONE = "__llm_done__"
 _ERR = "__llm_err__"
+
+
+def _sampled_rows(samplings) -> List[int]:
+    """The rows of a step whose logits the host needs: those whose
+    request is not greedy (``Chosen.token`` asks the same property)."""
+    return [i for i, sp in enumerate(samplings) if not sp.greedy]
 
 
 class RequestStream:
@@ -165,6 +173,13 @@ class LLMEngine:
         # rows of recurrent state the compiled decode steps read and
         # wrote: the whole store each step, whatever the batch (loop-owned)
         self.state_rows_stepped = 0
+        # tokens by where they were chosen (the step program's argmax for
+        # a greedy request; ModelRunner.sample on a pulled row for any
+        # other) and the bytes of logits pulled for the latter.
+        # prefill_remote() counts too, so these += run under _lock
+        self.sampled_on_device = 0
+        self.sampled_on_host = 0
+        self.logits_host_bytes = 0
         # hot-span totals of the loop and the runner, name ->
         # [count, seconds] (tracing.hot_span); the names are a contract,
         # PERF.md section 3 lists each with the metric that reads it
@@ -354,13 +369,15 @@ class LLMEngine:
                                 self.cfg.prefill_len_buckets),
                  queue_ms=round(1e3 * self._note_admission(seq), 3))
         try:
-            logits, ks, vs = self.runner.prefill(seq.prompt)
+            chosen, ks, vs = self.runner.prefill(
+                seq.prompt, logit_rows=_sampled_rows([seq.sampling]))
         except Exception as e:  # noqa: BLE001 - surface to the caller
             self.cache.free_seq(seq.seq_id)
             self._finish(seq, FAILED, f"prefill failed: {e!r}")
             return None
         with self._lock:
             self.prefill_steps += 1
+            self._count_chosen_locked(chosen)
         # K/V never left the device: the scatter is the enqueue of a
         # second device program; with recurrent state it also commits the
         # prompt's to the sequence's row, which the span then names
@@ -372,7 +389,7 @@ class LLMEngine:
         # ORIGINAL prompt, so a preemption re-prefill (k tokens folded
         # into the prompt) draws the same rng stream position as the
         # pressure-free run — seeded sampling stays reproducible
-        return self.runner.sample(logits, seq.sampling, step=seq.generated)
+        return chosen.token(0, seq.sampling, step=seq.generated)
 
     def _note_admission(self, seq: Sequence) -> float:
         """Queue wait ends here, where the prefill begins: seconds since
@@ -469,9 +486,11 @@ class LLMEngine:
                 self.state_rows_stepped += self.cache.state_rows + 1
         try:
             # the step writes each new token's K/V into its slot itself;
-            # the K/V it also returns stay on the device, unread
-            logits, _, _ = self.runner.decode(toks, poss, self.cache.pool,
-                                              tables, lens)
+            # the K/V it also returns stay on the device, unread, and so
+            # do the logits of every row whose request is greedy
+            chosen, _, _ = self.runner.decode(
+                toks, poss, self.cache.pool, tables, lens,
+                logit_rows=_sampled_rows(s.sampling for s in batch))
         except BaseException:
             # return every slot reserved for THIS step, or every later
             # append_slot is off by one and the cache silently corrupts
@@ -483,10 +502,10 @@ class LLMEngine:
         self.decode_steps += 1
         with hot_span("llm.decode.commit", spans):
             for i, s in enumerate(batch):
-                tok = self.runner.sample(logits[i], s.sampling,
-                                         step=s.generated)
-                self._emit(s, tok)
+                self._emit(s, chosen.token(i, s.sampling, step=s.generated))
                 self._maybe_finish(s)
+            with self._lock:
+                self._count_chosen_locked(chosen)
         self._count_tokens(len(batch), phase="decode")
         return batch
 
@@ -573,11 +592,13 @@ class LLMEngine:
         t0, p0 = time.time(), time.perf_counter()
         self.cache.alloc_seq(seq_id, len(prompt))
         try:
-            logits, ks, vs = self.runner.prefill(prompt)
+            chosen, ks, vs = self.runner.prefill(
+                prompt, logit_rows=_sampled_rows([sampling]))
             with self._lock:
                 self.prefill_steps += 1
+                self._count_chosen_locked(chosen)
             self.cache.scatter_prefill(seq_id, ks, vs, len(prompt))
-            first = self.runner.sample(logits, sampling, step=0)
+            first = chosen.token(0, sampling, step=0)
             srv = self._ensure_export_plane()
             oids = []
             for b in self.cache.table(seq_id):
@@ -802,6 +823,13 @@ class LLMEngine:
             mcat.get("rtpu_llm_tokens_total").inc(
                 n, tags={"model": self.cfg.model, "phase": phase})
 
+    def _count_chosen_locked(self, chosen: Chosen) -> None:
+        """One step's tokens by where they were chosen; the rows whose
+        logits were pulled are the rows sampled here."""
+        self.sampled_on_host += len(chosen.logits)
+        self.sampled_on_device += len(chosen.ids) - len(chosen.logits)
+        self.logits_host_bytes += chosen.logits_nbytes
+
     def _publish_metrics(self, plan: Plan) -> None:
         if not GLOBAL_CONFIG.metrics_enabled:
             return
@@ -834,6 +862,9 @@ class LLMEngine:
                     queue_wait_s=self.queue_wait_s,
                     requeue_wait_s=self.requeue_wait_s,
                     kv_host_bytes=self.cache.host_bytes,
+                    logits_host_bytes=self.logits_host_bytes,
+                    sampled_on_device=self.sampled_on_device,
+                    sampled_on_host=self.sampled_on_host,
                     attn_blocks_read=self.attn_blocks_read,
                     attn_blocks_table=self.attn_blocks_table,
                     state_bytes=self.cache.state_bytes,

@@ -18,8 +18,17 @@ the runner's decode program then writes that K/V into the pool, which it
 was given donated, so the pool stays on the device
 (``kv_cache.DevicePool``) and every family gets the write-back from one
 place.  Prefill leaves a prompt's K/V on the device for
-``PagedKVCache.scatter_prefill``.  What comes to the host is the logits:
-sampling (greedy / temperature / top-k) happens host-side on (V,) rows.
+``PagedKVCache.scatter_prefill``.
+
+What comes to the host.  Each step program returns, beside its float32
+logits, every row's greedy token (``argmax`` over the vocabulary, the
+first index of the maximum as ``np.argmax`` takes it): still one program
+a bucket, the logits a result of it that stays on the device.  A caller
+that names the rows whose logits it needs (``logit_rows=``: the engine's
+loop names those whose ``SamplingParams`` is not greedy, usually none)
+gets ``Chosen``: the ``(B,)`` ids and those rows.  Seeded temperature /
+top-k sampling stays host-side on a pulled ``(V,)`` row (``sample``).  A
+caller that names nothing gets all the logits on the host, as always.
 
 Recurrent state.  A family whose sequences hold more than K/V says so by
 exporting ``recurrent_state(cfg)``: one sequence's state in one layer,
@@ -50,7 +59,7 @@ from __future__ import annotations
 
 import contextlib
 from functools import partial
-from typing import Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -72,6 +81,24 @@ def _bucket(n: int, buckets) -> int:
         if n <= b:
             return b
     raise ValueError(f"{n} exceeds largest bucket {buckets[-1]}")
+
+
+class Chosen(NamedTuple):
+    """What a step hands a caller that named ``logit_rows``."""
+
+    ids: np.ndarray                  # (B,) int32: each row's greedy token
+    logits: Dict[int, np.ndarray]    # row -> (V,) float32, the rows named
+
+    def token(self, row: int, sp: SamplingParams, step: int) -> int:
+        """The row's next token: the device's choice for a greedy
+        request, sampled here from the row's logits for any other."""
+        if sp.greedy:
+            return int(self.ids[row])
+        return ModelRunner.sample(self.logits[row], sp, step)
+
+    @property
+    def logits_nbytes(self) -> int:
+        return sum(row.nbytes for row in self.logits.values())
 
 
 class ModelRunner:
@@ -111,9 +138,14 @@ class ModelRunner:
         forward_prefill = partial(self.mod.forward_prefill, cfg=self.mcfg)
         forward_decode = partial(self.mod.forward_decode, cfg=self.mcfg)
 
+        def greedy(logits):
+            # each row's token at temperature 0, chosen where the logits
+            # are: the first index of the maximum, as np.argmax takes it
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
         def prefill_step(params, toks, last_pos):
             logits, ks, vs = forward_prefill(params, toks, last_pos=last_pos)
-            return logits[0], ks[:, 0], vs[:, 0]
+            return (logits, greedy(logits)), ks[:, 0], vs[:, 0]
 
         def new_kv_written(pool, k, v, block_tables, ctx_lens, n_real):
             # a row's new K/V goes to the slot append_slot reserved,
@@ -133,7 +165,7 @@ class ModelRunner:
             logits, k, v = forward_decode(params, tokens, positions, pool,
                                           block_tables, ctx_lens)
             pool = new_kv_written(pool, k, v, block_tables, ctx_lens, n_real)
-            return pool, (logits, k, v)
+            return pool, ((logits, greedy(logits)), k, v)
 
         def prefill_state_step(held, params, toks, last_pos):
             # the state at the prompt's last real position goes to the
@@ -142,7 +174,8 @@ class ModelRunner:
                                                     last_pos=last_pos)
             store = jax.tree.map(lambda s, new: s.at[:, -1].set(new[:, 0]),
                                  held["state"], state)
-            return {**held, "state": store}, (logits[0], ks[:, 0], vs[:, 0])
+            return {**held, "state": store}, (
+                (logits, greedy(logits)), ks[:, 0], vs[:, 0])
 
         def decode_state_step(held, params, tokens, positions, block_tables,
                               ctx_lens, n_real, state_rows):
@@ -153,7 +186,8 @@ class ModelRunner:
                 ctx_lens, state=held["state"], rows=state_rows)
             pool = new_kv_written(held["kv"], k, v, block_tables, ctx_lens,
                                   n_real)
-            return {"kv": pool, "state": store}, (logits, k, v)
+            return {"kv": pool, "state": store}, (
+                (logits, greedy(logits)), k, v)
 
         # bound to a name of its own: jaxlint pins a donating jit by the
         # name it is assigned to (lock_watchdog.DONATED)
@@ -168,6 +202,9 @@ class ModelRunner:
                                             donate_argnums=(0,))
             self._prefill = llm_prefill_state_step
             self._decode = llm_decode_state_step
+        # one named row of a step's logits, for a request that samples:
+        # built with the first such row a bucket meets, not before
+        self._logits_row = jax.jit(lambda logits, row: logits[row])
         # the engine's cache: a bucket's scatter program is built with
         # the bucket's first prefill (None: a runner on its own)
         self.cache: Optional[PagedKVCache] = None
@@ -175,8 +212,8 @@ class ModelRunner:
         self._shapes_seen: set = set()
         # XLA watchdog step regions (DESIGN.md §4q): one compile per
         # bucket for the runner's life, zero host transfers inside the
-        # dispatch.  The post-dispatch np.asarray pulls of the logits
-        # are designed syncs and sit OUTSIDE the regions.
+        # dispatch.  The post-dispatch np.asarray pulls of the ids and
+        # the logits (_pull) are designed syncs and sit OUTSIDE the regions.
         self._prefill_budget = compile_budget(
             "llm.prefill", len(cfg.prefill_len_buckets))
         self._decode_budget = compile_budget(
@@ -193,10 +230,14 @@ class ModelRunner:
         return init()
 
     # ---------------------------------------------------------------- prefill
-    def prefill(self, token_ids) -> Tuple[np.ndarray, "jax.Array",
-                                          "jax.Array"]:
+    def prefill(self, token_ids, *,
+                logit_rows: Optional[Sequence[int]] = None
+                ) -> Tuple[Union[np.ndarray, Chosen], "jax.Array",
+                           "jax.Array"]:
         """One prompt → (last-position logits (V,) on the host, k, v
-        (L, T, KV, D) on the device).
+        (L, T, KV, D) on the device).  With ``logit_rows`` (``()``, or
+        ``(0,)`` for a request that samples) the first result is
+        ``Chosen``: the first token's greedy id and the logits if named.
 
         The prompt is padded to its length bucket; KV for pad positions
         is garbage and never written (``scatter_prefill`` stops at the
@@ -208,28 +249,30 @@ class ModelRunner:
         toks = np.zeros((1, tb), np.int32)
         toks[0, :n] = token_ids
         # last_pos is TRACED (one compile per bucket, not per length);
-        # only the last real position's (V,) logits come back to host
+        # only the last real position's (1, V) logits leave the model
         last_pos = jnp.int32(n - 1)
         # dispatch ends at the ENQUEUE (the jitted call returns before
-        # the device finishes); pull ends when the logits are on the host
+        # the device finishes); pull ends when the id or the logits are
+        # on the host
         with compiling, hot_span("llm.prefill.dispatch", self.span_s), \
                 self._prefill_budget:
             if self.state_spec is None:
-                logits, ks, vs = self._prefill(self.params, toks, last_pos)
+                picked, ks, vs = self._prefill(self.params, toks, last_pos)
             else:
-                logits, ks, vs = self._state_cache().pool.donate(
+                picked, ks, vs = self._state_cache().pool.donate(
                     self._prefill, self.params, toks, last_pos)
             if compiling is not _SEEN and self.cache is not None:
                 self.cache.warm_scatter(ks, vs)
-        with hot_span("llm.prefill.pull", self.span_s):
-            logits = np.asarray(logits)                          # (V,)
-        return logits, ks, vs
+        out = self._pull("llm.prefill.pull", picked, 1, logit_rows)
+        return (out[0] if logit_rows is None else out), ks, vs
 
     # ----------------------------------------------------------------- decode
     def decode(self, tokens: np.ndarray, positions: np.ndarray,
                kv_pool: DevicePool, block_tables: np.ndarray,
-               ctx_lens: np.ndarray) -> Tuple[np.ndarray, "jax.Array",
-                                              "jax.Array"]:
+               ctx_lens: np.ndarray, *,
+               logit_rows: Optional[Sequence[int]] = None
+               ) -> Tuple[Union[np.ndarray, Chosen], "jax.Array",
+                          "jax.Array"]:
         """One iteration over a batch of sequences.
 
         tokens/positions/ctx_lens (B,); block_tables (B, MAXB);
@@ -238,7 +281,10 @@ class ModelRunner:
         K/V written at ``(block_tables[i, ctx_lens[i] // block_size],
         ctx_lens[i] % block_size)``.  Returns (logits (B, V) on the host,
         new_k, new_v (L, bucket, KV, D) on the device; only the first B
-        rows are real after bucket padding).
+        rows are real after bucket padding).  With ``logit_rows`` (the
+        rows whose request samples; ``()`` for a greedy batch) the first
+        result is ``Chosen``: the (B,) ids and those rows' logits, and
+        the rest of the logits stay on the device.
         """
         b = len(tokens)
         bb = _bucket(b, self.cfg.decode_batch_buckets)
@@ -262,16 +308,30 @@ class ModelRunner:
                 [cache.rows_of(block_tables[:b]),
                  np.full(pad, cache.no_row, np.int32)]),)
         # dispatch holds the jitted call and ends at the ENQUEUE; pull
-        # ends when the logits are on the host, so it holds the wait for
-        # the step and nothing else: the pool stays where it is
+        # ends when the ids or the logits are on the host, so it holds the
+        # wait for the step and nothing else: the pool stays where it is
         with compiling, hot_span("llm.decode.dispatch", self.span_s), \
                 self._decode_budget:
-            logits, ks, vs = kv_pool.donate(
+            picked, ks, vs = kv_pool.donate(
                 self._decode, self.params, tokens, positions, block_tables,
                 ctx_lens, np.int32(b), *state_rows)
-        with hot_span("llm.decode.pull", self.span_s):
-            logits = np.asarray(logits)[:b]
-        return logits, ks, vs
+        return self._pull("llm.decode.pull", picked, b, logit_rows), ks, vs
+
+    def _pull(self, span: str, picked, n: int,
+              logit_rows: Optional[Sequence[int]]):
+        """A step's results for the host, inside ``span`` (whose ``bytes``
+        is what crossed): all its logits, (n, V), for a caller that named
+        no rows; else the n ids and the rows named."""
+        logits, ids = picked
+        with hot_span(span, self.span_s) as pull:
+            if logit_rows is None:
+                pull.set(bytes=logits.nbytes)
+                return np.asarray(logits)[:n]
+            chosen = Chosen(np.asarray(ids)[:n], {
+                int(row): np.asarray(self._logits_row(logits, np.int32(row)))
+                for row in logit_rows})
+            pull.set(bytes=ids.nbytes + chosen.logits_nbytes)
+            return chosen
 
     def _state_cache(self) -> PagedKVCache:
         """The cache whose holder has the store of recurrent state."""
@@ -298,7 +358,7 @@ class ModelRunner:
     def sample(logits: np.ndarray, sp: SamplingParams,
                step: int) -> int:
         """Host-side sampling of one token from (V,) logits."""
-        if sp.temperature <= 0.0:
+        if sp.greedy:
             return int(np.argmax(logits))
         x = logits.astype(np.float64) / sp.temperature
         if sp.top_k:

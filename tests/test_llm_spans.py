@@ -184,6 +184,23 @@ def test_decode_spans_carry_the_blocks_their_contexts_hold(captured):
         after["attn_blocks_read"] - before["attn_blocks_read"]
 
 
+def test_pull_spans_carry_the_bytes_that_crossed(captured):
+    """A greedy run: a decode step's pull is its bucket's ids, a
+    prefill's pull one id, and no logits crossed."""
+    lines, _, before, after = captured
+    loop = _loop_line(lines)
+    decodes = [e[3] for e in loop if e[0] == "llm.decode"]
+    pulls = [int(e[3]["bytes"]) for e in loop if e[0] == "llm.decode.pull"]
+    from ray_tpu.serve.llm.model_runner import _bucket
+    buckets = small_pool_cfg().decode_batch_buckets
+    assert pulls == [4 * _bucket(int(d["batch"]), buckets) for d in decodes]
+    assert {int(e[3]["bytes"]) for e in loop
+            if e[0] == "llm.prefill.pull"} == {4}
+    assert after["logits_host_bytes"] == before["logits_host_bytes"] == 0
+    assert after["sampled_on_host"] == 0
+    assert after["sampled_on_device"] == after["tokens_out"]
+
+
 # -------------------------------------------------------- with no capture
 @pytest.fixture(scope="module")
 def served():
@@ -255,8 +272,8 @@ def test_block_counters_count_what_a_scripted_run_read():
     eng = LLMEngine(cfg, start=False)
     prompts, max_tokens, batches = (11, 20), 4, []
     decode = eng.runner.decode
-    eng.runner.decode = lambda toks, *a: batches.append(len(toks)) or \
-        decode(toks, *a)
+    eng.runner.decode = lambda toks, *a, **kw: batches.append(len(toks)) or \
+        decode(toks, *a, **kw)
     try:
         assert eng.stats()["attn_blocks_table"] == 0
         streams = [eng.submit(list(range(1, n + 1)),

@@ -738,6 +738,211 @@ def test_weights_through_the_shm_plane_serve_the_same_tokens(model):
 
 
 # ------------------------------------------------------- weights plane
+# ----------------------- a greedy token is chosen on the device (ISSUE 33)
+FAMILIES = ["gpt2:tiny", "llama:tiny", "falcon_h1:tiny"]
+PROMPTS = [list(range(3, 14)), list(range(20, 27)), list(range(40, 59))]
+SAMPLED = SamplingParams(max_tokens=10, temperature=0.8, top_k=5, seed=7)
+
+
+def _twin_head(cfg):
+    """The model's own weights with the head's second half of the
+    vocabulary a copy of its first: every logit has an equal twin, so
+    each row's maximum is a tie.  (the tree, half the vocabulary)"""
+    import jax
+    mod, mcfg = resolve_model(cfg)
+    params = mod.init_params(jax.random.key(cfg.seed), mcfg)
+    half = mcfg.vocab_size // 2
+    if "lm_head" in params:                     # (E, V), untied
+        kernel = params["lm_head"]["kernel"]
+        params["lm_head"] = {
+            "kernel": kernel.at[:, half:2 * half].set(kernel[:, :half])}
+    else:                                       # gpt2: the embedding, (V, E)
+        wte = params["wte"]
+        params["wte"] = wte.at[half:2 * half].set(wte[:half])
+    return params, half
+
+
+def _prefill_and_step(eng, every_row: bool):
+    """PROMPTS prefilled into the engine's cache one by one, then one
+    decode step over the three of them in the bucket of 4: the first
+    result of each call, as the default call gives it or (``every_row``)
+    as ``logit_rows`` naming every row does."""
+    runner, cache = eng.runner, eng.cache
+    firsts = []
+    for i, prompt in enumerate(PROMPTS):
+        cache.alloc_seq(f"s{i}", len(prompt))
+        first, ks, vs = runner.prefill(
+            prompt, **({"logit_rows": (0,)} if every_row else {}))
+        cache.scatter_prefill(f"s{i}", ks, vs, len(prompt))
+        firsts.append(first)
+    tables = np.zeros((len(PROMPTS), eng.cfg.max_blocks_per_seq), np.int32)
+    for i in range(len(PROMPTS)):
+        cache.append_slot(f"s{i}")
+        table = cache.table(f"s{i}")
+        tables[i, :len(table)] = table
+    lens = np.asarray([len(p) for p in PROMPTS], np.int32)
+    step, _, _ = runner.decode(
+        np.asarray([7, 8, 9], np.int32), lens, cache.pool, tables, lens,
+        **({"logit_rows": range(len(PROMPTS))} if every_row else {}))
+    return firsts, step
+
+
+def _both_calls(cfg, params=None):
+    """(default call's results, ``logit_rows`` call's results) of the same
+    inputs, each on an engine of its own: a step moves what a cache holds."""
+    out = []
+    for every_row in (False, True):
+        eng = LLMEngine(cfg, params=params, start=False)
+        try:
+            out.append(_prefill_and_step(eng, every_row))
+        finally:
+            eng.shutdown()
+    return out
+
+
+@pytest.mark.parametrize("model", FAMILIES)
+def test_the_default_call_still_returns_all_logits_on_the_host(model):
+    eng = LLMEngine(tiny_cfg(model=model), start=False)
+    try:
+        firsts, step = _prefill_and_step(eng, every_row=False)
+        vocab = eng.runner.vocab
+    finally:
+        eng.shutdown()
+    for logits, shape in [(f, (vocab,)) for f in firsts] + \
+            [(step, (len(PROMPTS), vocab))]:
+        assert type(logits) is np.ndarray and logits.dtype == np.float32
+        assert logits.shape == shape and np.isfinite(logits).all()
+
+
+@pytest.mark.parametrize("model", FAMILIES)
+def test_step_ids_are_np_argmax_of_the_default_calls_logits(model):
+    """Ties included (every maximum has a twin half a vocabulary on: the
+    first is taken), and the row padded up to the bucket is not among
+    them."""
+    from ray_tpu.serve.llm.model_runner import Chosen
+    cfg = tiny_cfg(model=model)
+    params, half = _twin_head(cfg)
+    (firsts, step), (chosen_firsts, chosen_step) = _both_calls(cfg, params)
+    for logits, chosen in zip(firsts + [step], chosen_firsts + [chosen_step]):
+        logits = np.atleast_2d(logits)
+        assert type(chosen) is Chosen and chosen.ids.dtype == np.int32
+        assert chosen.ids.shape == (len(logits),)
+        np.testing.assert_array_equal(chosen.ids, np.argmax(logits, -1))
+        assert sorted(chosen.logits) == list(range(len(logits)))
+        for row, pulled in chosen.logits.items():
+            np.testing.assert_array_equal(pulled, logits[row])
+        np.testing.assert_array_equal(logits[:, :half],
+                                      logits[:, half:2 * half])
+        assert (chosen.ids < half).all()
+
+
+def _chosen_on_the_host(eng):
+    """The engine's runner answers its loop from the default call alone:
+    every row's logits pulled, every id ``ModelRunner.sample``'s at
+    temperature 0 (``np.argmax``)."""
+    from ray_tpu.serve.llm.model_runner import Chosen, ModelRunner
+    runner = eng.runner
+    prefill, decode = runner.prefill, runner.decode
+    greedy = SamplingParams()
+
+    def on_host(logits):
+        rows = np.atleast_2d(logits)
+        ids = [ModelRunner.sample(row, greedy, 0) for row in rows]
+        return Chosen(np.asarray(ids, np.int32), dict(enumerate(rows)))
+
+    def host_prefill(token_ids, *, logit_rows):
+        logits, ks, vs = prefill(token_ids)
+        return on_host(logits), ks, vs
+
+    def host_decode(*args, logit_rows):
+        logits, ks, vs = decode(*args)
+        return on_host(logits), ks, vs
+
+    runner.prefill, runner.decode = host_prefill, host_decode
+
+
+def _scripted(cfg, requests, on_host=False):
+    """(each request's tokens, the engine's stats) of ``requests``
+    submitted together and stepped by hand to the end."""
+    eng = LLMEngine(cfg, start=False)
+    try:
+        if on_host:
+            _chosen_on_the_host(eng)
+        streams = [eng.submit(prompt, sp) for prompt, sp in requests]
+        while eng.step():
+            pass
+        return [s.tokens() for s in streams], eng.stats()
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("model", FAMILIES)
+def test_greedy_loop_emits_what_sample_on_pulled_logits_emits(model):
+    cfg = tiny_cfg(model=model)
+    requests = [(p, SamplingParams(max_tokens=12)) for p in PROMPTS]
+    got, stats = _scripted(cfg, requests)
+    want, _ = _scripted(cfg, requests, on_host=True)
+    assert got == want and all(len(t) == 12 for t in got)
+    assert stats["sampled_on_device"] == stats["tokens_out"] == 36
+    assert stats["sampled_on_host"] == 0 and stats["logits_host_bytes"] == 0
+
+
+@pytest.mark.parametrize("model", FAMILIES)
+def test_mixed_batch_samples_its_seeded_row_from_pulled_logits(model):
+    """One greedy row and one seeded temperature / top-k row in the same
+    steps: the sampled row gets what ``ModelRunner.sample`` gives on the
+    default call's row, and only its logits cross."""
+    cfg = tiny_cfg(model=model)
+    requests = [(PROMPTS[0], SamplingParams(max_tokens=10)),
+                (PROMPTS[1], SAMPLED)]
+    got, stats = _scripted(cfg, requests)
+    want, _ = _scripted(cfg, requests, on_host=True)
+    assert got == want and [len(t) for t in got] == [10, 10]
+    greedy_alone, _ = _scripted(cfg, [(PROMPTS[1],
+                                       SamplingParams(max_tokens=10))])
+    assert got[1] != greedy_alone[0]            # it did sample
+    vocab = resolve_model(cfg)[1].vocab_size
+    assert stats["sampled_on_device"] == 10
+    assert stats["sampled_on_host"] == 10
+    assert stats["logits_host_bytes"] == 10 * vocab * 4
+
+
+@pytest.mark.parametrize("model", FAMILIES)
+def test_greedy_run_through_the_loop_thread_pulls_no_logits(model):
+    eng = LLMEngine(tiny_cfg(model=model))
+    try:
+        streams = [eng.submit(p, SamplingParams(max_tokens=8))
+                   for p in PROMPTS]
+        assert all(len(s.tokens()) == 8 for s in streams)
+        stats = eng.stats()
+    finally:
+        eng.shutdown()
+    assert stats["logits_host_bytes"] == 0 and stats["sampled_on_host"] == 0
+    assert stats["sampled_on_device"] == stats["tokens_out"] == 24
+
+
+def test_prefill_remote_takes_its_first_token_by_the_same_rule():
+    """An exported prefill's first token: the device's id for a greedy
+    request, ``sample`` on the pulled row for a seeded one."""
+    from ray_tpu.serve.llm.model_runner import ModelRunner
+    eng = LLMEngine(tiny_cfg(), start=False)
+    try:
+        logits, _, _ = eng.runner.prefill(PROMPTS[0])
+        assert eng.stats()["sampled_on_device"] == 0    # not the engine's
+        greedy = eng.prefill_remote(PROMPTS[0], SamplingParams())
+        assert greedy["first_token"] == int(np.argmax(logits))
+        stats = eng.stats()
+        assert (stats["sampled_on_device"], stats["sampled_on_host"],
+                stats["logits_host_bytes"]) == (1, 0, 0)
+        sampled = eng.prefill_remote(PROMPTS[0], SAMPLED)
+        assert sampled["first_token"] == ModelRunner.sample(logits, SAMPLED, 0)
+        stats = eng.stats()
+        assert (stats["sampled_on_device"], stats["sampled_on_host"],
+                stats["logits_host_bytes"]) == (1, 1, logits.nbytes)
+    finally:
+        eng.shutdown()
+
+
 def test_weights_shared_through_shm_plane():
     from ray_tpu.serve.llm import weights as wmod
     key = f"testshare_{os.getpid()}"
