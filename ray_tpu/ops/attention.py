@@ -70,6 +70,8 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     *, causal: bool = True,
                     q_offset: int | jax.Array = 0) -> jax.Array:
     """Plain O(T²) attention (B,T,H,D); the XLA-fused short-sequence path.
+    ``v`` may be (B,T,H,Dv) with Dv != D: the scale is 1/sqrt(D), the
+    keys' width, and the output is Dv wide.
 
     ``q_offset`` shifts query positions for causal masking when q is a
     chunk of a longer sequence (used by decode / chunked prefill).
@@ -109,7 +111,10 @@ def flash_runs(seq_len: int, impl: str = "auto") -> bool:
 def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                      impl: str = "auto",
                      context_axis: Optional[str] = None) -> jax.Array:
-    """Causal self-attention over (B, T, H, D) for training and prefill.
+    """Causal self-attention over (B, T, H, D) for training and prefill;
+    values may be narrower than keys (latent attention: q, k 192 wide,
+    v and the output 128), which the flash kernel and the dense path take
+    as they are, with no padding.
 
     ``impl`` is a model config's ``attn_impl``: ``auto`` (the flash
     kernel where ``flash_runs`` says so, XLA's dense attention

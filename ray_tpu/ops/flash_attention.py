@@ -55,7 +55,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_out, block_q: int,
     # preferred_element_type.  scale*log2(e) folds into the score
     # multiply so the exp2 chain carries no extra VPU work.
     q = q_ref[0]                                      # (block_q, D) bf16
-    D = q.shape[-1]
+    Dv = v_ref.shape[-1]          # values may be narrower than keys (MLA)
     s_scale = scale * LOG2E
 
     def tile(j, carry, masked):
@@ -79,7 +79,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_out, block_q: int,
             preferred_element_type=jnp.float32)
         return acc, m_new, l
 
-    acc0 = jnp.zeros((block_q, D), jnp.float32)
+    acc0 = jnp.zeros((block_q, Dv), jnp.float32)
     m0 = jnp.full((block_q,), NEG_INF)
     l0 = jnp.zeros((block_q,), jnp.float32)
     nblocks = seq_len // block_k
@@ -130,7 +130,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0]                                      # (block_k, D)
     v = v_ref[0]
     ks = (k.astype(jnp.float32) * scale).astype(k.dtype)
-    D = k.shape[-1]
+    D, Dv = k.shape[-1], v.shape[-1]
     s_scale = scale * LOG2E
 
     @pl.when(kj == 0)
@@ -172,7 +172,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         return dk, dv
 
     dk0 = jnp.zeros((block_k, D), jnp.float32)
-    dv0 = jnp.zeros((block_k, D), jnp.float32)
+    dv0 = jnp.zeros((block_k, Dv), jnp.float32)
     if causal:
         # k-block kj is seen by q-blocks i ≥ kj: diagonal first (masked),
         # then the fully-visible strictly-lower rows.
@@ -211,6 +211,29 @@ def _resolve(block_size, T, interpret):
     return bs, interpret
 
 
+# Mosaic's default scoped VMEM on the v5e, of its 128 MiB.
+_VMEM_DEFAULT = 16 * 2 ** 20
+
+
+def _compiler_params(resident_bytes: int) -> dict:
+    """``pallas_call`` keywords for a kernel that keeps ``resident_bytes``
+    of whole-sequence operands in VMEM, each double-buffered: nothing
+    while they fit Mosaic's default scoped limit with room for the tiles
+    (every shape up to 4,096 positions x 128 lanes: those programs lower
+    as they always did), else a limit that holds them.  8,192 positions
+    of 192-wide keys are 4 MiB an operand (lanes pad to 256)."""
+    need = 2 * resident_bytes + 6 * 2 ** 20
+    if need <= _VMEM_DEFAULT:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=min(need + 8 * 2 ** 20, 100 * 2 ** 20))}
+
+
+def _lanes(d: int) -> int:
+    return -(-d // 128) * 128
+
+
 def _flash_forward_lse_flat(qf, kf, vf, *, causal: bool, bs: int,
                             interpret: bool, want_lse: bool = True):
     """Core forward on kernel-layout (B·H, T, D) operands.
@@ -219,11 +242,12 @@ def _flash_forward_lse_flat(qf, kf, vf, *, causal: bool, bs: int,
     and writing the lse tensor — it is only a residual for the fused
     backward, and Pallas cannot DCE a declared output."""
     BH, T, D = qf.shape
+    Dv = vf.shape[-1]
     scale = 1.0 / math.sqrt(D)
     kernel = functools.partial(_flash_kernel, block_q=bs, block_k=bs,
                                seq_len=T, causal=causal, scale=scale)
-    out_specs = [pl.BlockSpec((1, bs, D), lambda bh, qi: (bh, qi, 0))]
-    out_shape = [jax.ShapeDtypeStruct((BH, T, D), qf.dtype)]
+    out_specs = [pl.BlockSpec((1, bs, Dv), lambda bh, qi: (bh, qi, 0))]
+    out_shape = [jax.ShapeDtypeStruct((BH, T, Dv), qf.dtype)]
     if want_lse:
         # Compact (B·H, 1, T) f32 — lse rides the lane axis; the unit
         # middle dim satisfies Mosaic's (8,128) last-two-dims tiling rule.
@@ -236,12 +260,13 @@ def _flash_forward_lse_flat(qf, kf, vf, *, causal: bool, bs: int,
         in_specs=[
             pl.BlockSpec((1, bs, D), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, T, D), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, T, D), lambda bh, qi: (bh, 0, 0)),
+            pl.BlockSpec((1, T, Dv), lambda bh, qi: (bh, 0, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
         name="flash_fwd",
+        **_compiler_params(T * (_lanes(D) + _lanes(Dv)) * qf.dtype.itemsize),
     )(qf, kf, vf)
     return res if want_lse else (res[0], None)
 
@@ -271,6 +296,7 @@ def _flash_backward_flat(qf, kf, vf, lse, delta, dof, *, causal: bool,
     output.
     """
     BH, T, D = qf.shape
+    Dv = vf.shape[-1]
     # NOTE: a 1024-wide backward block measured marginally faster in the
     # standalone kernel bench but 20x SLOWER inside the remat'd train
     # step (VMEM pressure next to the replayed ops) — block choice is
@@ -281,7 +307,9 @@ def _flash_backward_flat(qf, kf, vf, lse, delta, dof, *, causal: bool,
     from jax.experimental.pallas import tpu as pltpu
 
     kspec = pl.BlockSpec((1, bs, D), lambda bh, kj: (bh, kj, 0))
+    vspec = pl.BlockSpec((1, bs, Dv), lambda bh, kj: (bh, kj, 0))
     fullspec = pl.BlockSpec((1, T, D), lambda bh, kj: (bh, 0, 0))
+    dospec = pl.BlockSpec((1, T, Dv), lambda bh, kj: (bh, 0, 0))
     # dq: constant index along the k grid axis → flushed from scratch at
     # the last k-step.
     dqspec = pl.BlockSpec((1, T, D), lambda bh, kj: (bh, 0, 0))
@@ -291,14 +319,17 @@ def _flash_backward_flat(qf, kf, vf, lse, delta, dof, *, causal: bool,
         functools.partial(_bwd_kernel, block_q=bs, block_k=bs, seq_len=T,
                           causal=causal, scale=scale),
         grid=(BH, T // bs),
-        in_specs=[fullspec, kspec, kspec, fullspec, rowspec, rowspec],
-        out_specs=[dqspec, kspec, kspec],
+        in_specs=[fullspec, kspec, vspec, dospec, rowspec, rowspec],
+        out_specs=[dqspec, kspec, vspec],
         out_shape=[jax.ShapeDtypeStruct((BH, T, D), qf.dtype),
                    jax.ShapeDtypeStruct((BH, T, D), kf.dtype),
-                   jax.ShapeDtypeStruct((BH, T, D), vf.dtype)],
+                   jax.ShapeDtypeStruct((BH, T, Dv), vf.dtype)],
         scratch_shapes=[pltpu.VMEM((T, D), jnp.float32)],
         interpret=interpret,
         name="flash_bwd",
+        # q, dO and dq stay whole; the float32 dq scratch is single
+        **_compiler_params(T * (2 * _lanes(D) + _lanes(Dv))
+                           * qf.dtype.itemsize + T * _lanes(D) * 2),
     )(qf, kf, vf, dof, lse, delta)
     return dq, dk, dv
 
@@ -308,7 +339,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True,
                     block_size: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
-    """(B,T,H,D)×3 → (B,T,H,D) tiled attention; differentiable.
+    """(B,T,H,D)×3 → (B,T,H,D) tiled attention; differentiable.  ``v``
+    may be (B,T,H,Dv): scores are scaled by 1/sqrt(D), the keys' width,
+    and the output (and dv) is Dv wide.
 
     ``block_size=None`` (default) resolves via ``pick_block_size`` — the
     measured-fastest tile for the sequence length — so every caller gets

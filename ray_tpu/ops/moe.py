@@ -24,7 +24,7 @@ own matmuls; the sort costs a few passes over the rows.
 from __future__ import annotations
 
 import math
-from typing import Callable, Dict, NamedTuple, Tuple
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -180,55 +180,137 @@ def _permute_rows_bwd(res, g):
 _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
 
 
-# megablox's (rows, contracted, output) tile: the fastest of those tried on
-# the v5e at the OLMoE cell's shape that fits VMEM (PERF.md section 6, PR 27)
-GMM_TILING = (512, 1024, 1024)
+class HeldStats(NamedTuple):
+    """What an expert layer that holds a share of the experts reports
+    beside its output (scalars)."""
+    held_rows: jax.Array          # rows of the N k that went to held experts
+    load_max_over_mean: jax.Array  # among the held: most-loaded / mean rows
+    choice_share_held: jax.Array  # held_rows / (N k): H / E when even
+
+
+# megablox tiles, largest first.  A row tile of 512 and contracted and
+# output tiles of 1,024 were the fastest of those tried on the v5e at the
+# OLMoE cell's shape that fit VMEM (PERF.md section 6, PR 27); a dimension
+# those do not divide (768-wide experts) takes the largest that does.
+_GMM_ROW_TILE = 512
+_GMM_TILES = (1024, 768, 512, 256, 128)
+
+
+def gmm_tiling(m: int, d: int, f: int) -> Optional[Tuple[int, int, int]]:
+    """megablox's (rows, contracted, output) tile for an (m, d) x (d, f)
+    product over ragged groups: the tile follows the shape.  None where
+    no tile divides (the caller then takes ``ragged_dot``)."""
+    def tile(dim):
+        return next((t for t in _GMM_TILES if dim % t == 0), None)
+    tiling = (_GMM_ROW_TILE if m % _GMM_ROW_TILE == 0 else None,
+              tile(d), tile(f))
+    return None if None in tiling else tiling
+
+
+@jax.custom_vjp
+def _megablox(rows, w, group_sizes):
+    """megablox's ``gmm`` with each of its three products tiled for its
+    own shape (megablox's own vjp hands the forward's tile to both
+    backward products, whose contracted and output dimensions are the
+    forward's swapped).  ``w`` may hold fewer groups than ``group_sizes``
+    counts: the leading ones, and rows of the others come out zero."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    (m, d), f = rows.shape, w.shape[-1]
+    return gmm(rows, w, group_sizes, rows.dtype, gmm_tiling(m, d, f))
+
+
+def _megablox_fwd(rows, w, group_sizes):
+    return _megablox(rows, w, group_sizes), (rows, w, group_sizes)
+
+
+def _megablox_bwd(res, g):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+    rows, w, group_sizes = res
+    (m, d), f = rows.shape, w.shape[-1]
+    d_rows = gmm(g, w, group_sizes, rows.dtype, gmm_tiling(m, f, d),
+                 transpose_rhs=True)
+    d_w = tgmm(rows.swapaxes(0, 1), g, group_sizes, w.dtype,
+               gmm_tiling(m, d, f), num_actual_groups=w.shape[0])
+    return d_rows, d_w, None
+
+
+_megablox.defvjp(_megablox_fwd, _megablox_bwd)
 
 
 def grouped_matmul(rows: jax.Array, w: jax.Array,
                    group_sizes: jax.Array) -> jax.Array:
-    """rows (M, d) sorted by group, w (E, d, f), group_sizes (E,) summing
-    to M -> (M, f): row r times the matrix of the group it lies in.
+    """rows (M, d) sorted by group, w (H, d, f), group_sizes (E,) with
+    H <= E -> (M, f): row r times the matrix of the group it lies in.
+    ``w`` holds the first H of the E groups; rows of the other groups
+    (they lie behind the held ones) cost no matmul and come out zero.
 
-    On a TPU, where the tile divides the shapes: megablox's Pallas ``gmm``
-    (kernels ``gmm`` and, for the weights' gradient, ``tgmm``), which read
-    the transposed weights in place for the rows' gradient.  Elsewhere
-    ``jax.lax.ragged_dot``, which XLA lowers on a TPU to its own kernels
-    (``ragged-dot*``): slower there by a fifth to a third in all three
-    products, and its backward copies the weights transposed.
+    On a TPU, where a tile divides the shapes (``gmm_tiling``): megablox's
+    Pallas ``gmm`` (kernels ``gmm`` and, for the weights' gradient,
+    ``tgmm``), which read the transposed weights in place for the rows'
+    gradient.  Elsewhere ``jax.lax.ragged_dot``, which XLA lowers on a TPU
+    to its own kernels (``ragged-dot*``): slower there by a fifth to a
+    third in all three products, and its backward copies the weights
+    transposed.
     """
-    m, d = rows.shape
-    tm, tk, tn = GMM_TILING
-    f = w.shape[-1]
-    if jax.default_backend() == "tpu" and not (m % tm or d % tk or f % tn):
-        from jax.experimental.pallas.ops.tpu.megablox import ops
-        return ops.gmm(rows, w, group_sizes, rows.dtype, GMM_TILING)
-    return jax.lax.ragged_dot(rows, w, group_sizes)
+    (m, d), (held, _, f) = rows.shape, w.shape
+    if jax.default_backend() == "tpu" and gmm_tiling(m, d, f):
+        return _megablox(rows, w, group_sizes)
+    return jax.lax.ragged_dot(rows, w, group_sizes[:held])
 
 
-def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
-                     w_up: jax.Array, w_down: jax.Array, *, k: int
-                     ) -> Tuple[jax.Array, RouterStats]:
-    """Token-choice SwiGLU experts with no capacity: every token is
-    computed by each of its top-k experts, whatever the imbalance.
+def route_softmax(x: jax.Array, w_router: jax.Array, k: int):
+    """-> (expert_idx (N, k), weights (N, k), logits, probs (N, E)): a
+    float32 softmax over all E experts whose top-k probabilities weigh
+    the experts' outputs as they are (not renormalised)."""
+    logits = jnp.dot(x, w_router.astype(x.dtype),
+                     preferred_element_type=jnp.float32)         # (N, E)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate_vals, expert_idx = jax.lax.top_k(probs, k)              # (N, k)
+    return expert_idx, gate_vals, logits, probs
 
-    x (N, d); w_router (d, E); w_gate, w_up (E, d, f); w_down (E, f, d).
-    The router's softmax is float32 over all E experts and its top-k
-    probabilities weigh the experts' outputs as they are (not
-    renormalised).  Dispatch is a sort: the N k assignments are ordered by
-    expert, the rows gathered, three grouped matmuls run over the ragged
-    groups, and the rows are put back and summed per token.  No (N, E, C)
-    tensor exists and nothing is dropped by construction.
+
+def route_sigmoid(x: jax.Array, w_router: jax.Array, select_bias: jax.Array,
+                  k: int, weight_scale: float):
+    """-> (expert_idx (N, k), weights (N, k)): float32 sigmoid scores over
+    all E experts; the k chosen are the top of score + ``select_bias``
+    (the bias decides the choice and never a weight: it balances the load
+    with no auxiliary loss and has no gradient), and their weights are
+    the scores alone, renormalised over the k chosen and scaled."""
+    logits = jnp.dot(x, w_router.astype(x.dtype),
+                     preferred_element_type=jnp.float32)         # (N, E)
+    scores = jax.nn.sigmoid(logits)
+    _, expert_idx = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)), k)
+    chosen = jnp.take_along_axis(scores, expert_idx, axis=-1)
+    weights = chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+    return expert_idx, weights * weight_scale
+
+
+def dropless_experts(x: jax.Array, expert_idx: jax.Array, weights: jax.Array,
+                     w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                     *, num_experts: int, first_held: int = 0):
+    """Each token through those of its chosen experts that are held here,
+    weighted and summed -> (y (N, d), group_sizes (E,)).
+
+    ``w_gate``, ``w_up`` (H, d, f) and ``w_down`` (H, f, d) are experts
+    ``first_held .. first_held + H - 1`` of the ``num_experts`` the router
+    chose among (all of them when H == E): the share of an expert-
+    parallel layer that this chip holds.  The N k assignments are sorted
+    by expert, the held experts' first, the rows gathered, three grouped
+    matmuls run over the held groups, and the rows are put back and
+    summed per token.  A choice of an absent expert costs no matmul and
+    adds nothing: what that expert would add is another chip's part, and
+    on several chips the exchange of rows goes between the sort and the
+    matmuls (DESIGN.md, held experts).  No capacity: no (N, E, C) tensor
+    exists and nothing is dropped among the held, whatever the imbalance.
+    ``group_sizes[i]`` counts the rows of expert ``first_held + i`` (mod E).
     """
     n, d = x.shape
-    num_experts = w_router.shape[-1]
-    with jax.named_scope("router"):
-        logits = jnp.dot(x, w_router.astype(x.dtype),
-                         preferred_element_type=jnp.float32)     # (N, E)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, expert_idx = jax.lax.top_k(probs, k)          # (N, k)
+    k = expert_idx.shape[-1]
     with jax.named_scope("moe_dispatch"):
         flat_expert = expert_idx.reshape(n * k)
+        if first_held:
+            flat_expert = (flat_expert - first_held) % num_experts
         order = jnp.argsort(flat_expert, stable=True)
         inverse = jnp.argsort(order)
         group_sizes = jnp.bincount(flat_expert, length=num_experts
@@ -241,9 +323,50 @@ def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
                              w_down.astype(x.dtype), group_sizes)
     with jax.named_scope("moe_combine"):
         out = _permute_rows(out, inverse, order).reshape(n, k, d)
-        y = jnp.einsum("nkd,nk->nd", out, gate_vals.astype(out.dtype),
+        y = jnp.einsum("nkd,nk->nd", out, weights.astype(out.dtype),
                        preferred_element_type=jnp.float32).astype(x.dtype)
+    return y, group_sizes
+
+
+def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
+                     w_up: jax.Array, w_down: jax.Array, *, k: int,
+                     scoring: str = "softmax",
+                     select_bias: Optional[jax.Array] = None,
+                     weight_scale: float = 1.0, first_held: int = 0):
+    """Token-choice SwiGLU experts with no capacity: every token is
+    computed by each of its top-k experts that is held here, whatever the
+    imbalance.
+
+    x (N, d); w_router (d, E): the router always has its full width.
+    w_gate, w_up (H, d, f); w_down (H, f, d): the H <= E experts held
+    here, ``first_held`` the first (``dropless_experts``).  ``scoring``
+    ``"softmax"`` (``route_softmax``) returns ``RouterStats`` over all E;
+    ``"sigmoid"`` (``route_sigmoid`` with ``select_bias`` (E,) and
+    ``weight_scale``) has no auxiliary term and returns ``HeldStats``.
+    """
+    n = x.shape[0]
+    num_experts, held = w_router.shape[-1], w_gate.shape[0]
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"unknown scoring {scoring!r} "
+                         "(expected softmax | sigmoid)")
     with jax.named_scope("router"):
+        if scoring == "softmax":
+            expert_idx, weights, logits, probs = route_softmax(x, w_router, k)
+        else:
+            expert_idx, weights = route_sigmoid(x, w_router, select_bias, k,
+                                                weight_scale)
+    y, group_sizes = dropless_experts(
+        x, expert_idx, weights, w_gate, w_up, w_down,
+        num_experts=num_experts, first_held=first_held)
+    with jax.named_scope("router"):
+        if scoring == "sigmoid":
+            mine = group_sizes[:held].astype(jnp.float32)
+            rows = mine.sum()
+            return y, HeldStats(rows, mine.max() / jnp.maximum(mine.mean(),
+                                                               1e-9),
+                                rows / (n * k))
+        if first_held:
+            group_sizes = jnp.roll(group_sizes, first_held)
         share = group_sizes.astype(jnp.float32) / (n * k)        # f_e
         balance = num_experts * jnp.sum(share * probs.mean(0))
         z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)

@@ -179,6 +179,16 @@ TRANSFORMER_RULES: Rules = [
     # stacked experts (layer, expert, in, out): models/llama.py with n_experts
     (r".*blocks/experts/w_(gate|up)$", P("pipeline", "expert", "fsdp", "tensor")),
     (r".*blocks/experts/w_down$",   P("pipeline", "expert", "tensor", "fsdp")),
+    # stacked latent-attention blocks (models/deepseek_v3.py: dense_blocks
+    # and moe_blocks, whose experts take the two rules above): heads and
+    # hidden widths over ``tensor``; the one latent and rotary key of a
+    # token, the router and its selection bias are every tensor shard's
+    (r".*(dense|moe)_blocks/(wq|wkv_b)/kernel$", P("pipeline", "fsdp", "tensor")),
+    (r".*(dense|moe)_blocks/wkv_a/kernel$",     P("pipeline", "fsdp", None)),
+    (r".*(dense|moe)_blocks/wo/kernel$",        P("pipeline", "tensor", "fsdp")),
+    (r".*(dense|moe)_blocks/(shared/)?w_(gate|up)/kernel$", P("pipeline", "fsdp", "tensor")),
+    (r".*(dense|moe)_blocks/(shared/)?w_down/kernel$", P("pipeline", "tensor", "fsdp")),
+    (r".*(dense|moe)_blocks/router/(kernel|select_bias)$", P("pipeline")),
     # Non-stacked variants (single-layer modules, BERT/ResNet dense layers).
     (r".*attn_qkv/kernel$",         P("fsdp", None, "tensor")),
     (r".*attn_out/kernel$",         P("tensor", "fsdp")),

@@ -75,6 +75,19 @@ def scale_by_adam_compact(
     return optax.GradientTransformation(init, update)
 
 
+# Leaves a loss reads and never differentiates: a router's selection bias
+# (models/deepseek_v3.py: a choice has no gradient).  Their gradient is
+# zero, so Adam's update of them is zero; weight decay must leave them too.
+BUFFER_KEYS = ("select_bias",)
+
+
+def decayed(params: Any) -> Any:
+    """The weight-decay mask: every leaf but those under ``BUFFER_KEYS``."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: not any(getattr(k, "key", None) in BUFFER_KEYS
+                                for k in path), params)
+
+
 def adamw_compact(
         learning_rate: Union[float, Callable[[jax.Array], jax.Array]],
         *, b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8,
@@ -88,7 +101,7 @@ def adamw_compact(
     parts += [
         scale_by_adam_compact(b1=b1, b2=b2, eps=eps,
                               mu_dtype=mu_dtype, nu_dtype=nu_dtype),
-        optax.add_decayed_weights(weight_decay),
+        optax.add_decayed_weights(weight_decay, mask=decayed),
         optax.scale_by_learning_rate(learning_rate),
     ]
     return optax.chain(*parts)

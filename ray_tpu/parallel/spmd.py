@@ -81,17 +81,18 @@ def default_optimizer(lr: float = 3e-4, weight_decay: float = 0.01,
     """AdamW with warmup-cosine schedule.  ``moments_dtype`` (e.g.
     ``jnp.bfloat16``) stores BOTH Adam moments compactly — halves the
     optimizer's HBM footprint and its bandwidth-floored step phase
-    (parallel/optim.py); None keeps optax's f32 state."""
+    (parallel/optim.py); None keeps optax's f32 state.  Weight decay
+    leaves out what a loss never differentiates (``optim.BUFFER_KEYS``)."""
     sched = optax.warmup_cosine_decay_schedule(
         0.0, lr, warmup, max(total_steps, warmup + 1), end_value=lr * 0.1)
+    from ray_tpu.parallel.optim import adamw_compact, decayed
     if moments_dtype is not None:
-        from ray_tpu.parallel.optim import adamw_compact
         return adamw_compact(sched, b1=0.9, b2=b2,
                              weight_decay=weight_decay, clip=clip,
                              mu_dtype=moments_dtype, nu_dtype=moments_dtype)
     return optax.chain(optax.clip_by_global_norm(clip),
                        optax.adamw(sched, b1=0.9, b2=b2,
-                                   weight_decay=weight_decay))
+                                   weight_decay=weight_decay, mask=decayed))
 
 
 def state_specs(state: TrainState, rules: Rules) -> TrainState:

@@ -298,3 +298,128 @@ def test_falcon_h1_prefill_program_fits_at_bucket_512(falcon_h1_runner,
     total, mem = _held_bytes(compiled)
     assert mem.alias_size_in_bytes >= 1.24e9
     assert total < 12.5e9 < 16.9e9, total
+
+
+# ------------------------------------------------ the training cells' steps
+def _train_program(cell_name, v5e):
+    """A training cell's program as ``perfbench/jobs/train.py`` builds it,
+    with its state and batch as shapes on the described chip."""
+    from perfbench import manifest
+    from ray_tpu.parallel import mesh as mesh_lib, spmd
+    from ray_tpu.parallel.mesh import MeshConfig
+    cell = manifest.load_cell(manifest.load_manifest(), cell_name)
+    config, spec = cell["config_file"], cell["traffic_file"]
+    fam = manifest.family(config["family"])
+    mod, options = fam.module(), config["train"]
+    cfg = fam.model_config(config, options["model_options"])
+    mc = MeshConfig(**options["mesh"]).resolved(1)
+    mesh = mesh_lib.build_mesh(mc, list(v5e.device_set))
+    prog = spmd.build_train_program(
+        loss_fn=lambda p, b: mod.loss_fn(p, b, cfg),
+        init_params_fn=lambda rng: mod.init_params(rng, cfg),
+        optimizer=spmd.default_optimizer(moments_dtype=jnp.dtype(
+            options["optimizer"]["moments_dtype"])),
+        mesh=mesh, mesh_config=mc)
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e),
+        jax.eval_shape(prog.init_fn, jax.random.key(0)))
+    batch = {k: jax.ShapeDtypeStruct((spec["batch"], spec["seq"]), jnp.int32,
+                                     sharding=v5e)
+             for k in ("inputs", "targets")}
+    return prog, state, batch
+
+
+def test_kanana_step_compiles_with_its_kernels_and_fits(v5e, monkeypatch):
+    """The whole step of ``kanana-2-30b-a3b.train-b2-s8192`` (910.6 M
+    parameters in bf16 with bf16 moments, 2 x 8,192 tokens): the flash
+    kernel at key width 192 and value width 128 with no padding (forward
+    and backward once in each of the two layer scans; 8,192 positions need
+    more than Mosaic's default scoped VMEM), every grouped matmul over the
+    16 held experts a megablox kernel at a tile that divides 768 and 2,048
+    (12 in the sparse scan), each with a result shape its metric is keyed
+    on, no ``ragged-dot`` fallback, and everything inside the chip."""
+    import json
+    from pathlib import Path
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    prog, state, batch = _train_program("kanana-2-30b-a3b.train-b2-s8192",
+                                        v5e)
+    compiled = prog.jitted_step.lower(state, batch).compile()
+    text = compiled.as_text()
+    kernels = re.findall(r"= \(?(bf16\[[\d,]+\])\S* .*custom-call\(.*"
+                         r'custom_call_target="tpu_custom_call"', text)
+    metrics = Path(__file__).parent.parent / "perfbench" / "layer_metrics"
+    keyed = {name: json.loads((metrics / f"{name}.json").read_text())
+             ["params"]["shapes"]
+             for name in ("mla.attention_ms", "moe.held_expert_ms")}
+    flash = [k for k in kernels if k in keyed["mla.attention_ms"]]
+    experts = [k for k in kernels if k in keyed["moe.held_expert_ms"]]
+    assert sorted(flash) == ["bf16[64,8192,128]"] * 2 \
+        + ["bf16[64,8192,192]"] * 2, kernels
+    assert len(experts) == 12 and len(flash) + len(experts) == len(kernels)
+    assert set(experts) == set(keyed["moe.held_expert_ms"])
+    assert "ragged-dot" not in text
+    assert not re.search(r"\[16384,128,\d+\]", text)    # no dispatch tensor
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= 5.46e9        # the state, donated
+    assert held < 16.9e9, held
+
+
+def _operations_and_kernels(lowered_text):
+    """What a lowered step computes, without how it is nested or where it
+    was written: the count of each StableHLO operation, and each Mosaic
+    kernel as (its MLIR printed without locations, its operand and result
+    types).  A kernel's payload holds source lines, and a private
+    function's name its number in the module: neither is the program."""
+    import base64
+    import collections
+    import hashlib
+    import json
+
+    from jax._src.lib.mlir import ir
+    ops = collections.Counter(
+        re.findall(r"= \"?((?:stablehlo|chlo)\.[a-z_]+)", lowered_text))
+    kernels = collections.Counter()
+    for line in lowered_text.splitlines():
+        if "@tpu_custom_call" not in line:
+            continue
+        raw = re.search(r'backend_config = "((?:[^"\\]|\\.)*)"', line).group(1)
+        config = json.loads(re.sub(r"\\([0-9A-Fa-f]{2})",
+                                   lambda m: chr(int(m.group(1), 16)), raw))
+        body = base64.b64decode(config["custom_call_config"]["body"])
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            asm = ir.Module.parse(body).operation.get_asm(
+                enable_debug_info=False)
+        kernels[(hashlib.sha256(asm.encode()).hexdigest(),
+                 line.rsplit(" : ", 1)[1])] += 1
+    summary = json.dumps([sorted(ops.items()),
+                          sorted((k[0], k[1], n) for k, n in kernels.items())])
+    return hashlib.sha256(summary.encode()).hexdigest()[:16], ops, kernels
+
+
+# The digests of the two older training cells' steps as the parent of the PR
+# that taught the expert layer its share lowered them (commit 19ffdeb, this
+# jax).  OLMoE: 4,092 operations and 10 Mosaic kernels (flash forward,
+# twice, and backward; three gmm at (512, 1024, 1024), their transposes and
+# tgmm); GPT-2 XL: 1,851 and the two flash kernels.  ops/moe.py, the flash
+# kernel's widths and VMEM rule and the optimizer's mask changed around
+# them; what these steps compute did not.  A PR that changes such a step on
+# purpose replaces its digest.
+PARENT_STEPS = {
+    "olmoe-1b-7b.train-b2-s4096": ("2cf88e601e4040c4", 4092, 10),
+    "gpt2-xl-1558m.train-b8-s1024": ("87069c55eb334fc3", 1851, 2),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PARENT_STEPS))
+def test_older_training_steps_lower_to_the_operations_and_kernels_they_had(
+        v5e, monkeypatch, cell):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    prog, state, batch = _train_program(cell, v5e)
+    digest, ops, kernels = _operations_and_kernels(
+        prog.jitted_step.lower(state, batch).as_text())
+    assert (digest, sum(ops.values()), sum(kernels.values())) == \
+        PARENT_STEPS[cell]

@@ -13,11 +13,12 @@ import numpy as np
 import pytest
 from jax.ad_checkpoint import print_saved_residuals
 
-from ray_tpu.models import gpt2, llama
+from ray_tpu.models import deepseek_v3, gpt2, llama
 from ray_tpu.models._common import next_token_nll, remat_block, split_batch
 
 MODELS = {"gpt2": (gpt2, gpt2.tiny()), "llama": (llama, llama.tiny()),
-          "llama_moe": (llama, llama.tiny_moe(seq=64))}
+          "llama_moe": (llama, llama.tiny_moe(seq=64)),
+          "deepseek_v3": (deepseek_v3, deepseek_v3.tiny(seq=64))}
 T = 64                        # flash_runs(64, "flash"): one 64-wide block
 
 
