@@ -6,9 +6,10 @@ path it replaces there, at the shape of the GPT-2 XL serving cell: 8 slots,
     chiprun -- python benchmarks/paged_attention_bench.py
 
 Each measurement is the attention of one decode step as ``forward_decode``
-runs it: a scan over the layers, each slicing its K and V pools out of the
-engine's pool, so what XLA does to feed the kernel is inside the time.  At
-three fills (3 live chat contexts, 8 live, the whole table), with the bytes
+runs it: a scan over the layers, each handing the engine's whole pool
+(``kv_cache.device_shape``) and its own index on, so whatever XLA does to
+feed either path is inside the time.  At three fills (3 live chat
+contexts, 8 live, the whole table), with the bytes
 of K/V read as a share of the chip's 819 GB/s.  Also the largest
 difference between the two paths' results on the chip (they are compared on
 the CPU in ``tests/test_paged_attention.py``; the chip's matmuls are not
@@ -34,6 +35,7 @@ from jax import lax
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from ray_tpu.ops import paged_attention as pa  # noqa: E402
+from ray_tpu.serve.llm.kv_cache import device_shape  # noqa: E402
 
 HBM_BYTES_PER_S = 819e9     # v5e, perfbench/peaks.json
 
@@ -56,31 +58,17 @@ def timed(fn, *args, iters: int = 20):
     return (time.perf_counter() - t0) / iters
 
 
-def attention_of_a_step(path, split_ahead: bool = False):
-    """q (L, B, H, D) and the engine's pool -> every layer's result.
-
-    The feed is ``forward_decode``'s: each layer's K and V pools sliced
-    out of the pool inside the scan.  ``split_ahead`` is the feed it had
-    before PR 29, per-layer pools made ahead of the scan (a pass over
-    the whole pool), kept here to show what the change of feed is worth.
-    """
+def attention_of_a_step(path):
+    """q (L, B, H, D) and the engine's pool -> every layer's result, fed
+    as ``forward_decode`` feeds it: the pool closed over by the scan, the
+    layer's index scanned."""
     def step(pool, q, k_new, v_new, tables, lens):
-        layers = jnp.arange(q.shape[0])
-        if split_ahead:
-            k_pools = pool[:, :, 0].transpose(1, 0, 2, 3, 4)
-            v_pools = pool[:, :, 1].transpose(1, 0, 2, 3, 4)
-
         def body(_, xs):
             q, kn, vn, layer = xs
-            if split_ahead:
-                kp, vp = k_pools[layer], v_pools[layer]
-            else:
-                kv = lax.dynamic_index_in_dim(pool, layer, axis=1,
-                                              keepdims=False)
-                kp, vp = kv[:, 0], kv[:, 1]
-            return None, path(q, kp, vp, tables, lens, kn, vn)
+            return None, path(q, pool, layer, tables, lens, kn, vn)
 
-        return lax.scan(body, None, (q, k_new, v_new, layers))[1]
+        return lax.scan(body, None,
+                        (q, k_new, v_new, jnp.arange(q.shape[0])))[1]
     return jax.jit(step)
 
 
@@ -94,13 +82,12 @@ def main() -> None:
         raise SystemExit(f"needs a TPU, found {dev.platform}")
     n, bs, h, d, b, maxb, layers = 128, 16, 25, 64, 8, 64, args.layers
     rng = np.random.default_rng(args.seed)
-    pool = jnp.asarray(rng.standard_normal((n, layers, 2, bs, h, d)),
+    # the lanes that pad 1,600 to 1,664 hold noise too: nothing reads them
+    pool = jnp.asarray(rng.standard_normal(device_shape(n, layers, bs, h, d)),
                        jnp.float32)
     q, k_new, v_new = (jnp.asarray(rng.standard_normal((layers, b, h, d)),
                                    jnp.bfloat16) for _ in range(3))
     kernel = attention_of_a_step(pa._paged_decode_kernel)
-    kernel_split = attention_of_a_step(pa._paged_decode_kernel,
-                                       split_ahead=True)
     gather = attention_of_a_step(pa._paged_decode_gather)
     rows = []
     for name, lens in FILLS.items():
@@ -122,7 +109,6 @@ def main() -> None:
             "fill": name, "device": dev.device_kind, "layers": layers,
             "blocks_read": blocks, "blocks_table": b * maxb,
             "kernel_ms": t_kernel * 1e3,
-            "kernel_split_ahead_ms": timed(kernel_split, *operands) * 1e3,
             "gather_ms": t_gather * 1e3,
             "kv_bytes_read": read,
             "kv_bytes_over_peak_ms": read / HBM_BYTES_PER_S * 1e3,

@@ -436,8 +436,7 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
     .shape)``; ``rows`` (B,) the store row of each batch row (one outside
     the store: none).  Returns (logits (B, V) f32, new_k, new_v (L, B,
     KV, D), the store with the named rows stepped)."""
-    from ray_tpu.ops.paged_attention import (layer_pools,
-                                             paged_attention_decode)
+    from ray_tpu.ops.paged_attention import paged_attention_decode
     B = tokens.shape[0]
     order = _store_order(rows, state["ssm"].shape[1])
 
@@ -452,11 +451,10 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
         state = jax.tree.map(
             lambda s, new: lax.dynamic_update_index_in_dim(s, new, layer, 0),
             state, mine)
-        k_pool, v_pool = layer_pools(kv_pool, layer)
         q, k, v = _qkv(u, lp, cfg)
         q = _rope_at(q, positions, cfg.rope_theta)
         k = _rope_at(k, positions, cfg.rope_theta)
-        a = paged_attention_decode(q, k_pool, v_pool, block_tables,
+        a = paged_attention_decode(q, kv_pool, layer, block_tables,
                                    ctx_lens, k, v)
         a = a.reshape(B, cfg.n_head * cfg.head_dim) \
             @ lp["wo"]["kernel"].astype(cfg.dtype)

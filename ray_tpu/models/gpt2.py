@@ -152,10 +152,10 @@ WIDE_PARAMS = ("ln_1", "ln_2", "ln_f")
 
 # ------------------------------------------------------------------ forward
 # Every layer kind runs under a ``jax.named_scope`` (embed, ln_1, attn_qkv,
-# attn, attn_out, ln_2, mlp, ln_f, lm_head, loss_ce; in the decode step also
-# kv_layout, and cast_weights wherever stored weights are cast to the
-# compute dtype).  Metadata only: the scope path names the HLO operations,
-# and PERF.md section 3 lists the per-layer metric that reads each.
+# attn, attn_out, ln_2, mlp, ln_f, lm_head, loss_ce, and cast_weights
+# wherever stored weights are cast to the compute dtype).  Metadata only:
+# the scope path names the HLO operations, and PERF.md section 3 lists the
+# per-layer metric that reads each.
 _scope = jax.named_scope
 
 
@@ -499,14 +499,13 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
     """One decode step over the paged KV pool.
 
     tokens/positions (B,) int32; kv_pool — the engine's block pool, on
-    the device, in the format ``ops/paged_attention.layer_pools`` reads
+    the device, whole, in the format ``ops/paged_attention`` reads
     (read-only here: the new token's K/V is returned, the runner's
     program writes it); block_tables (B, MAXB) int32;
     ctx_lens (B,) int32.  Returns (logits (B, V) f32,
     new_k (L, B, H, D), new_v (L, B, H, D)).
     """
-    from ray_tpu.ops.paged_attention import (layer_pools,
-                                             paged_attention_decode)
+    from ray_tpu.ops.paged_attention import paged_attention_decode
     B = tokens.shape[0]
     E, H, D = cfg.n_embd, cfg.n_head, cfg.head_dim
     x = _embed(params, tokens, positions, cfg)                  # (B, E)
@@ -514,10 +513,6 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
     def body(carry, xs):
         x = carry
         lp, layer = xs
-        with _scope("kv_layout"):
-            # sliced where they lie: a split of the whole pool ahead of
-            # the scan is a pass over it
-            k_pool, v_pool = layer_pools(kv_pool, layer)
         with _scope("ln_1"):
             h = _layer_norm(x[:, None, :], lp["ln_1"]["scale"],
                             lp["ln_1"]["bias"])[:, 0]
@@ -527,7 +522,9 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
             qkv = qkv + _cast(lp["attn_qkv"]["bias"], cfg)
             q, k, v = [qkv[:, i, :].reshape(B, H, D) for i in range(3)]
         with _scope("attn"):
-            a = paged_attention_decode(q, k_pool, v_pool, block_tables,
+            # the pool whole and this layer's index: nothing of it is
+            # sliced or copied on the way to the kernel
+            a = paged_attention_decode(q, kv_pool, layer, block_tables,
                                        ctx_lens, k, v).reshape(B, E)
         with _scope("attn_out"):
             a = a @ _cast(lp["attn_out"]["kernel"], cfg) \

@@ -303,12 +303,11 @@ def forward_prefill(params: Params, tokens: jax.Array, cfg: LlamaConfig,
 def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
                    kv_pool: jax.Array, block_tables: jax.Array,
                    ctx_lens: jax.Array, cfg: LlamaConfig):
-    """One decode step over the engine's paged KV pool, in the format
-    ``ops/paged_attention.layer_pools`` reads (read-only here).
+    """One decode step over the engine's paged KV pool, handed whole to
+    ``ops/paged_attention`` with the layer's index (read-only here).
 
     Returns (logits (B, V) f32, new_k (L, B, KV, D), new_v (L, B, KV, D))."""
-    from ray_tpu.ops.paged_attention import (layer_pools,
-                                             paged_attention_decode)
+    from ray_tpu.ops.paged_attention import paged_attention_decode
     B = tokens.shape[0]
     E = cfg.n_embd
     x = params["wte"].astype(cfg.dtype)[tokens]                 # (B, E)
@@ -316,12 +315,11 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
     def body(carry, xs):
         x = carry
         lp, layer = xs
-        k_pool, v_pool = layer_pools(kv_pool, layer)
         h = _rms_norm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
         q, k, v = _qkv(h, lp, cfg)
         q = _rope_at(q, positions, cfg.rope_theta)
         k = _rope_at(k, positions, cfg.rope_theta)
-        a = paged_attention_decode(q, k_pool, v_pool, block_tables,
+        a = paged_attention_decode(q, kv_pool, layer, block_tables,
                                    ctx_lens, k, v).reshape(B, E)
         x = x + a @ lp["wo"]["kernel"].astype(cfg.dtype)
         h = _rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)

@@ -13,23 +13,32 @@ Two implementations of the same algorithm sit behind it, chosen from
 what the code can see (the backend and the shapes), never by an option:
 
 * ``_paged_decode_kernel`` — on a TPU.  A Pallas kernel that takes the
-  block tables and context lengths as prefetched scalars and, sequence
-  by sequence, copies only the blocks the context holds
-  (``ceil(ctx_len / bs)`` table columns) out of the layer's pool into
-  VMEM, double-buffered 128 positions at a time, folding each chunk
-  into an online softmax.  Table columns past the context cost no copy
-  and no arithmetic; a row padded up to the decode bucket
-  (``ctx_len == 0``) costs the new token's own term.  Mosaic copies
-  whole 128-lane tiles, so the kernel reads a layer's pool with a
-  position's heads side by side along the lanes (``(N, bs, KV * D)``,
-  padded: 25 x 64 -> 1,664); XLA builds that form per layer from the
-  layer's pools, which ``forward_decode`` slices out of the engine's
-  pool inside its scan.  In that form all heads share two float32
-  matmuls a chunk, against a block-diagonal query.
+  block tables, the context lengths and the layer's index as prefetched
+  scalars and the engine's whole pool where it lies in HBM, and,
+  sequence by sequence, copies only the blocks the context holds
+  (``ceil(ctx_len / bs)`` table columns) of that layer into VMEM,
+  double-buffered 128 positions at a time, folding each chunk into an
+  online softmax.  Table columns past the context cost no copy and no
+  arithmetic; a row padded up to the decode bucket (``ctx_len == 0``)
+  costs the new token's own term.  Mosaic copies whole 128-lane tiles,
+  and the pool is stored so that a block is made of them (below):
+  nothing is sliced, transposed or padded for the kernel.  With a
+  position's heads side by side along the lanes all heads share two
+  float32 matmuls a chunk, against a block-diagonal query.
 * ``_paged_decode_gather`` — everywhere else (the CPU rig), and the
-  tests' reference: gather every table column as a padded dense view,
-  attend, mask by ``ctx_lens``.  XLA fuses the chain; its cost is that
-  of the table, not of the contexts.
+  tests' reference: slice the layer out, gather every table column as a
+  padded dense view, attend, mask by ``ctx_lens``.  XLA fuses the chain;
+  its cost is that of the table, not of the contexts.
+
+The pool's format, ``(L, 2, N, bs, F)`` (layer, K or V, block, position
+in the block, the position's ``KV * D`` features flat along the lanes and
+zero-padded to whole 128-lane tiles: 25 x 64 -> 1,664), belongs to its
+writer (``serve/llm/kv_cache.py``: ``device_shape``, ``write_rows``) and
+to this module, its reader.  The models' decode steps hand
+``paged_attention_decode`` the pool whole and their scan's layer index,
+and index no axis of it themselves: a ``pool[layer]`` with a traced
+layer handed on as an operand would be a copy of the layer in every
+step of the scan, the pass over the pool that this format removes.
 
 Accumulators are float32 regardless of input dtype (bf16-safe softmax),
 matching ``ops/attention.py``.  The pool is only read here.
@@ -70,11 +79,28 @@ def gather_kv(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
         return g.reshape(b, mb * bs, kv, d)
 
 
-def _paged_decode_gather(q, k_pool, v_pool, block_tables, ctx_lens,
+def lane_flat(x: jax.Array, width: int) -> jax.Array:
+    """(..., KV, D) -> (..., width): a position's heads side by side
+    along the lanes, zero-padded to ``width`` (the pool's ``F``)."""
+    f = x.shape[-2] * x.shape[-1]
+    flat = x.reshape(x.shape[:-2] + (f,))
+    return jnp.pad(flat, [(0, 0)] * (flat.ndim - 1) + [(0, width - f)])
+
+
+def heads_apart(x: jax.Array, n_kv: int, head_dim: int) -> jax.Array:
+    """``lane_flat`` undone: (..., F) -> (..., KV, D), the padding cut."""
+    return x[..., :n_kv * head_dim].reshape(x.shape[:-1] + (n_kv, head_dim))
+
+
+def _paged_decode_gather(q, kv_pool, layer, block_tables, ctx_lens,
                          k_new, v_new):
     """Gather-then-mask: the CPU path and the kernel's reference."""
     b, h, d = q.shape
-    kvh = k_pool.shape[2]
+    kvh = k_new.shape[1]
+    with jax.named_scope("kv_layout"):
+        # the layer's K and V, each (N, bs, KV, D); here a slice costs
+        # nothing that matters
+        k_pool, v_pool = heads_apart(kv_pool[layer], kvh, d)
     scale = 1.0 / math.sqrt(d)
     k_ctx = gather_kv(k_pool, block_tables)          # (B, T, KV, D)
     v_ctx = gather_kv(v_pool, block_tables)
@@ -100,9 +126,9 @@ def _paged_decode_gather(q, k_pool, v_pool, block_tables, ctx_lens,
         return out.astype(q.dtype)
 
 
-def _decode_kernel(tables_ref, lens_ref, q_ref, k_new_ref, v_new_ref,
-                   k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_ref, l_ref,
-                   acc_ref, *, head_dim, kv_rows):
+def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_new_ref,
+                   v_new_ref, pool_hbm, o_ref, k_buf, v_buf, sems, m_ref,
+                   l_ref, acc_ref, *, head_dim, kv_rows):
     """One sequence (grid step): walk its blocks, a chunk at a time.
 
     Heads lie along the lanes: a block is (bs, F), F = KV * D padded to
@@ -111,10 +137,11 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_new_ref, v_new_ref,
     matmul against a chunk's keys (T, F) gives every head's scores
     (R, T), and probabilities @ values (T, F) every head's result in its
     own lanes of (R, F).  Rows are ordered (group member, KV head), each
-    group member's KV heads padded to ``kv_rows``.  k_hbm / v_hbm
-    (N, bs, F) are left where they are and copied from by block; k_buf /
-    v_buf (2, C, bs, F) are the VMEM landing buffers; sems (2, 2):
-    [k|v, buffer].
+    group member's KV heads padded to ``kv_rows``.  pool_hbm
+    (L, 2, N, bs, F) is left where it is and copied from by block,
+    ``pool_hbm[layer, 0 / 1, table[j]]``, each contiguous whole tiles;
+    k_buf / v_buf (2, C, bs, F) are the VMEM landing buffers; sems
+    (2, 2): [k|v, buffer].
     """
     from jax.experimental.pallas import tpu as pltpu
 
@@ -122,6 +149,7 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_new_ref, v_new_ref,
     _, chunk, bs, f = k_buf.shape
     t = chunk * bs
     ctx = lens_ref[b]
+    layer = layer_ref[0]
     n_blocks = pl.cdiv(ctx, bs)
     n_chunks = pl.cdiv(n_blocks, chunk)
     hi = lax.Precision.HIGHEST      # float32 K/V stay float32 on the MXU
@@ -134,10 +162,10 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_new_ref, v_new_ref,
             # clamped: the read stays inside the row when the guard fails
             blk = tables_ref[b, jnp.minimum(j, tables_ref.shape[1] - 1)]
             out.append((j < n_blocks, (
-                pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[slot, i],
-                                      sems.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[slot, i],
-                                      sems.at[1, slot]))))
+                pltpu.make_async_copy(pool_hbm.at[layer, 0, blk],
+                                      k_buf.at[slot, i], sems.at[0, slot]),
+                pltpu.make_async_copy(pool_hbm.at[layer, 1, blk],
+                                      v_buf.at[slot, i], sems.at[1, slot]))))
         return out
 
     def start(c, slot):
@@ -204,16 +232,7 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_new_ref, v_new_ref,
                               ).astype(o_ref.dtype)
 
 
-def _lane_flat(x: jax.Array) -> jax.Array:
-    """(..., KV, D) -> (..., F): a position's heads side by side along
-    the lanes, zero-padded to whole 128-lane tiles.  Mosaic copies
-    nothing narrower out of HBM (64 or 1,600 lanes are refused)."""
-    f = x.shape[-2] * x.shape[-1]
-    flat = x.reshape(x.shape[:-2] + (f,))
-    return jnp.pad(flat, [(0, 0)] * (flat.ndim - 1) + [(0, -f % 128)])
-
-
-def _paged_decode_kernel(q, k_pool, v_pool, block_tables, ctx_lens,
+def _paged_decode_kernel(q, kv_pool, layer, block_tables, ctx_lens,
                          k_new, v_new, *, interpret=False):
     """The block-table walk as one Pallas call over the batch."""
     # imported where the kernel is built (flash_attention's idiom), so
@@ -221,38 +240,36 @@ def _paged_decode_kernel(q, k_pool, v_pool, block_tables, ctx_lens,
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, d = q.shape
-    _, bs, kvh, _ = k_pool.shape
+    bs, f = kv_pool.shape[3:]
+    kvh = k_new.shape[1]
     rep, f32 = h // kvh, jnp.float32
     kv_rows = -(-kvh // 8) * 8
     chunk = max(1, min(_CHUNK_TOKENS // bs, block_tables.shape[1]))
     with jax.named_scope("paged_attention"):
-        k_flat, v_flat = _lane_flat(k_pool), _lane_flat(v_pool)
-        f = k_flat.shape[-1]
         # head h reads KV head h // rep (jnp.repeat's order).  Row
         # (r, kv) of the query operand: q[kv * rep + r], scaled, in
         # lanes [kv * D, (kv + 1) * D), zero elsewhere
         q4 = q.astype(f32).reshape(b, kvh, rep, d).transpose(0, 2, 1, 3)
         q4 = q4 * (1.0 / math.sqrt(d))
-        qbd = _lane_flat(q4[:, :, :, None, :]
-                         * jnp.eye(kvh, dtype=f32)[:, :, None])
+        qbd = lane_flat(q4[:, :, :, None, :]
+                        * jnp.eye(kvh, dtype=f32)[:, :, None], f)
         qbd = jnp.pad(qbd, ((0, 0), (0, 0), (0, kv_rows - kvh), (0, 0)))
-        row = lambda i, tables, lens: (i, 0, 0)                # noqa: E731
+        row = lambda i, tables, lens, layer: (i, 0, 0)         # noqa: E731
         out = pl.pallas_call(
             functools.partial(_decode_kernel, head_dim=d, kv_rows=kv_rows),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
+                num_scalar_prefetch=3,
                 grid=(b,),
                 in_specs=[
                     pl.BlockSpec((1, rep * kv_rows, f), row),
                     pl.BlockSpec((1, 1, f), row),
                     pl.BlockSpec((1, 1, f), row),
                     pl.BlockSpec(memory_space=pl.ANY),
-                    pl.BlockSpec(memory_space=pl.ANY),
                 ],
                 out_specs=pl.BlockSpec((1, rep, f), row),
                 scratch_shapes=[
-                    pltpu.VMEM((2, chunk, bs, f), k_pool.dtype),
-                    pltpu.VMEM((2, chunk, bs, f), v_pool.dtype),
+                    pltpu.VMEM((2, chunk, bs, f), kv_pool.dtype),
+                    pltpu.VMEM((2, chunk, bs, f), kv_pool.dtype),
                     pltpu.SemaphoreType.DMA((2, 2)),
                     pltpu.VMEM((rep * kv_rows, 1), f32),
                     pltpu.VMEM((rep * kv_rows, 1), f32),
@@ -264,22 +281,24 @@ def _paged_decode_kernel(q, k_pool, v_pool, block_tables, ctx_lens,
             interpret=interpret,
             name="paged_decode",
         )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
+          jnp.asarray(layer, jnp.int32).reshape(1),
           qbd.reshape(b, rep * kv_rows, f),
-          _lane_flat(k_new.astype(f32))[:, None],
-          _lane_flat(v_new.astype(f32))[:, None], k_flat, v_flat)
-        out = out[..., :kvh * d].reshape(b, rep, kvh, d)
+          lane_flat(k_new.astype(f32), f)[:, None],
+          lane_flat(v_new.astype(f32), f)[:, None], kv_pool)
+        out = heads_apart(out, kvh, d).reshape(b, rep, kvh, d)
         return out.transpose(0, 2, 1, 3).reshape(b, h, d)
 
 
-def paged_attention_decode(q: jax.Array, k_pool: jax.Array,
-                           v_pool: jax.Array, block_tables: jax.Array,
-                           ctx_lens: jax.Array, k_new: jax.Array,
-                           v_new: jax.Array) -> jax.Array:
+def paged_attention_decode(q: jax.Array, kv_pool: jax.Array, layer,
+                           block_tables: jax.Array, ctx_lens: jax.Array,
+                           k_new: jax.Array, v_new: jax.Array) -> jax.Array:
     """Single-token decode attention through a block table.
 
     q:       (B, H, D)        — query for the token being decoded.
-    k_pool:  (N, bs, KV, D)   — shared key pool (this layer's view).
-    v_pool:  (N, bs, KV, D)   — shared value pool.
+    kv_pool: (L, 2, N, bs, F) — the engine's whole pool
+                                (``kv_cache.device_shape``), read-only.
+    layer:   int32 scalar     — whose K and V to read; traced inside the
+                                models' layer scans.
     block_tables: (B, MAXB) int32.
     ctx_lens: (B,) int32      — tokens already IN the pool per sequence
                                 (the new token is not in the pool yet).
@@ -293,23 +312,9 @@ def paged_attention_decode(q: jax.Array, k_pool: jax.Array,
     """
     # the kernel is built for whole query groups per KV head and a
     # float32 pool, as the engine's is
-    if jax.default_backend() == "tpu" and k_pool.dtype == jnp.float32 \
-            and q.shape[1] % k_pool.shape[2] == 0:
-        return _paged_decode_kernel(q, k_pool, v_pool, block_tables,
+    if jax.default_backend() == "tpu" and kv_pool.dtype == jnp.float32 \
+            and q.shape[1] % k_new.shape[1] == 0:
+        return _paged_decode_kernel(q, kv_pool, layer, block_tables,
                                     ctx_lens, k_new, v_new)
-    return _paged_decode_gather(q, k_pool, v_pool, block_tables, ctx_lens,
+    return _paged_decode_gather(q, kv_pool, layer, block_tables, ctx_lens,
                                 k_new, v_new)
-
-
-def layer_pools(kv_pool: jax.Array, layer) -> tuple[jax.Array, jax.Array]:
-    """One layer's (k_pool, v_pool), each (N, bs, KV, D), out of the
-    engine's pool.
-
-    The pool's format, ``(N, L, 2, bs, KV, D)`` (block, layer, K or V,
-    position in the block, KV head, feature), belongs to its writer
-    (``serve/llm/kv_cache.py``) and to this module, its reader; the
-    models' decode steps call this inside their layer scans (``layer`` is
-    traced) and index no axis of the pool themselves.  A split of the
-    whole pool ahead of the scan would be a pass over it (PR 29)."""
-    kv = kv_pool[:, layer]
-    return kv[:, 0], kv[:, 1]

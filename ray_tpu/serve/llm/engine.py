@@ -477,7 +477,8 @@ class LLMEngine:
             # columns past a context included)
             bs = self.cfg.block_size
             blocks = int((-(-lens // bs)).sum())
-            span.set(blocks=blocks)
+            span.set(blocks=blocks,
+                     kv_lane_pad_bytes=self.cache.lane_pad_bytes)
             self.attn_blocks_read += blocks
             self.attn_blocks_table += maxb * _bucket(
                 len(batch), self.cfg.decode_batch_buckets)
@@ -858,6 +859,7 @@ class LLMEngine:
                     blocks_free=self.cache.free_block_count(),
                     compiles=self.runner.compiles,
                     param_bytes=self.runner.param_bytes,
+                    kv_lane_pad_bytes=self.cache.lane_pad_bytes,
                     admitted=self.admitted,
                     queue_wait_s=self.queue_wait_s,
                     requeue_wait_s=self.requeue_wait_s,

@@ -1,15 +1,31 @@
 """Paged KV cache: a device-resident block pool + per-sequence block tables.
 
 Layout (PagedAttention, Kwon et al. SOSP '23): the cache is ONE device
-array::
+array, held in the form its reader copies (:func:`device_shape`)::
 
-    pool[num_blocks, n_layer, 2, block_size, n_kv, head_dim]
+    pool[n_layer, 2, num_blocks, block_size, F]
 
-Block-major: block ``i`` is one contiguous slab — one ``block_bytes(i)``
-is a complete, self-describing transfer unit for the data-plane export
-path (``engine.prefill_remote`` / ``attach``), and the whole pool is what
-the bucketed decode step reads through the block table
-(``ops/paged_attention.py``).
+Blocks-major and lane-flat: a position's heads lie side by side along the
+lanes, ``n_kv * head_dim`` of them zero-padded up to whole 128-lane tiles
+(GPT-2 XL: 25 x 64 = 1,600 -> 1,664; Falcon-H1: 4 x 128 = 512, none), so
+one block of one layer's K (or V) is ``(block_size, F)``: contiguous whole
+tiles.  The decode kernel (``ops/paged_attention.py``) copies exactly
+those out of the pool where it lies, by layer and block table, and a
+token's K/V is one row of each: a write touches the rows it writes
+(:func:`write_rows`) and nothing is re-laid for the kernel.  Held
+blocks-first with the heads apart, ``(N, L, 2, bs, KV, D)``, the TPU
+compiler put the block index on the lanes: every write was a pass over
+the pool and every layer of every step a re-laid copy (PERF.md, PR 35).
+
+On the wire a block is another thing: ``block_shape`` = ``(n_layer, 2,
+block_size, n_kv, head_dim)``, one contiguous, self-describing slab of
+``block_nbytes`` without the lane padding, the data-plane export unit
+(``engine.prefill_remote`` / ``attach``).  :meth:`PagedKVCache.block_bytes`
+gathers ``pool[:, :, i]`` into it and :meth:`PagedKVCache.load_block`
+does the reverse; exports are rare and off the decode path, so the
+format that crosses replicas does not follow the device's tiles.
+:func:`device_shape`, :func:`write_rows` and the two programs behind those
+methods are all that know the device format here.
 
 The pool never leaves the device.  Every write to it is a jitted program
 that takes the array donated and hands it back (:class:`DevicePool`):
@@ -112,41 +128,48 @@ def _with_kv(held, kv):
     return {**held, "kv": kv} if isinstance(held, dict) else kv
 
 
-# write_rows makes one masked pass over the pool for up to this many rows
-# (a decode batch), and leaves more (a prompt) to XLA's scatter
-_ROWS_IN_ONE_PASS = 16
+def device_shape(num_blocks: int, n_layer: int, block_size: int,
+                 n_kv: int, head_dim: int) -> tuple:
+    """The pool's shape on the device, ``(L, 2, N, bs, F)``: the one
+    place that says it (the cache, the tests and whoever compiles a step
+    program for shapes alone ask here).  ``F`` is a position's
+    ``n_kv * head_dim`` features padded up to whole 128-lane tiles, the
+    narrowest thing Mosaic copies out of HBM."""
+    f = n_kv * head_dim
+    return (n_layer, 2, num_blocks, block_size, f + -f % 128)
 
 
 def write_rows(pool, blocks, offsets, k, v):
-    """``pool[blocks[r], :, 0 / 1, offsets[r]] = k / v[:, r]`` for every
+    """``pool[:, 0 / 1, blocks[r], offsets[r]] = k / v[:, r]`` for every
     row ``r``, cast to the pool's type; traceable.
 
-    k, v: (L, R, KV, D).  A row whose block is ``>= num_blocks`` writes
-    nowhere: how a decode batch padded up to its bucket, and a prompt
-    padded up to its bucket, keep their padding out of live blocks.
+    k, v: (L, R, KV, D), in head form as the models return them; they
+    are laid flat along the lanes and zero-padded to the pool's ``F``
+    here.  A row whose block is ``>= num_blocks`` writes nowhere: how a
+    decode batch padded up to its bucket, and a prompt padded up to its
+    bucket, keep their padding out of live blocks.
 
-    The TPU compiler lays a pool of this shape out with the block index
-    on the lanes (no padding that way), so one token's K/V lies in every
-    sixteenth tile of the pool and any write costs a pass over it.  For
-    the few rows of a decode batch that pass is made directly: selects
-    fused into one read and one write of the pool (GPT-2 XL's 1.26 GB:
-    4.1 ms on the v5e).  XLA's scatter instead copies the pool to a
-    blocks-major layout and back (12.0 ms a decode step, 13.7-17.8 ms
-    for a prompt of 128-512 rows; PERF.md, PR 26), which many rows are
-    worth and 8 are not.  A pool held in a blocks-major layout would make
-    both cost what the rows cost (ROADMAP S10)."""
+    One indexed update for a decode batch's few rows and a prompt's
+    hundreds alike.  A row of one layer's K is ``F`` contiguous lanes,
+    so the pool is taken as what it is in memory, ``L x 2 x N x bs`` rows
+    of ``F``, and the ``L x 2 x R`` written ones are scattered into it by
+    row number: the donated pool is updated in place and nothing else of
+    it is passed over.  (Indexed as ``pool.at[:, :, blocks, offsets]``
+    the TPU compiler copies the whole pool to a layout with the layers
+    inside the tiles and back: the pass this format exists to remove.)"""
     import jax.numpy as jnp
-    kv = jnp.stack([k, v], axis=2).astype(pool.dtype)       # (L, R, 2, KV, D)
-    if kv.shape[1] > _ROWS_IN_ONE_PASS:
-        return pool.at[blocks, :, :, offsets].set(jnp.moveaxis(kv, 1, 0),
-                                                  mode="drop")
-    num_blocks, block_size = pool.shape[0], pool.shape[3]
-    hit = (blocks[:, None, None] == jnp.arange(num_blocks)[:, None]) \
-        & (offsets[:, None, None] == jnp.arange(block_size))  # (R, N, bs)
-    for r in range(kv.shape[1]):
-        pool = jnp.where(hit[r][:, None, None, :, None, None],
-                         kv[:, r][None, :, :, None], pool)
-    return pool
+
+    from ray_tpu.ops.paged_attention import lane_flat
+    n_layer, _, num_blocks, bs, f = pool.shape
+    kv = jnp.stack([lane_flat(k, f), lane_flat(v, f)], axis=1)  # (L, 2, R, F)
+    per_slab = num_blocks * bs              # rows of one layer's K (or V)
+    row = blocks * bs + offsets                                 # (R,)
+    rows = jnp.arange(2 * n_layer)[:, None] * per_slab + row    # (2 L, R)
+    # a row sent out of range lies outside every slab, not in the next
+    rows = jnp.where(blocks < num_blocks, rows, 2 * n_layer * per_slab)
+    flat = pool.reshape(-1, f).at[rows.reshape(-1)].set(
+        kv.reshape(-1, f).astype(pool.dtype), mode="drop")
+    return flat.reshape(pool.shape)
 
 
 @functools.cache
@@ -161,6 +184,8 @@ def _programs() -> SimpleNamespace:
     import jax.numpy as jnp
     from jax import lax
 
+    from ray_tpu.ops.paged_attention import heads_apart, lane_flat
+
     def _write_rows(held, blocks, offsets, k, v):
         return _with_kv(held, write_rows(_kv(held), blocks, offsets, k, v)), \
             None
@@ -169,9 +194,9 @@ def _programs() -> SimpleNamespace:
         # token t of the padded prompt -> slot t % bs of block table[t // bs];
         # padding (t >= n_tokens) is sent out of range and dropped
         pool = _kv(held)
-        bs = pool.shape[3]
+        num_blocks, bs = pool.shape[2:4]
         t = jnp.arange(ks.shape[1])
-        blocks = jnp.where(t < n_tokens, table[t // bs], pool.shape[0])
+        blocks = jnp.where(t < n_tokens, table[t // bs], num_blocks)
         held = _with_kv(held, write_rows(pool, blocks, t % bs, ks, vs))
         if row:
             # recurrent state: the prompt's, which its prefill left in the
@@ -181,20 +206,30 @@ def _programs() -> SimpleNamespace:
                     s, s[:, -1], row[0], 1), held["state"])}
         return held, None
 
+    # a block between its wire format (L, 2, bs, KV, D) and the pool's
+    # pool[:, :, i], (L, 2, bs, F): the lane padding never crosses
     def _load_block(held, block_id, block):
+        pool = _kv(held)
+        flat = lane_flat(block, pool.shape[-1]).astype(pool.dtype)
         return _with_kv(held, lax.dynamic_update_index_in_dim(
-            _kv(held), block, block_id, 0)), None
+            pool, flat, block_id, 2)), None
+
+    def _read_block(held, block_id, heads):
+        return heads_apart(lax.dynamic_index_in_dim(
+            _kv(held), block_id, 2, keepdims=False), *heads)
+
+    def _read_blocks(held, heads):
+        return heads_apart(jnp.moveaxis(_kv(held), 2, 0), *heads)
 
     # the names are rows of lock_watchdog.DONATED (jaxlint pins them)
     kv_write_rows = jax.jit(_write_rows, donate_argnums=(0,))
     kv_scatter_prefill = jax.jit(_scatter_prefill, donate_argnums=(0,))
     kv_load_block = jax.jit(_load_block, donate_argnums=(0,))
-    read_block = jax.jit(
-        lambda held, block_id: lax.dynamic_index_in_dim(
-            _kv(held), block_id, 0, keepdims=False))
-    return SimpleNamespace(write_rows=kv_write_rows,
-                           scatter_prefill=kv_scatter_prefill,
-                           load_block=kv_load_block, read_block=read_block)
+    return SimpleNamespace(
+        write_rows=kv_write_rows, scatter_prefill=kv_scatter_prefill,
+        load_block=kv_load_block,
+        read_block=jax.jit(_read_block, static_argnames="heads"),
+        read_blocks=jax.jit(_read_blocks, static_argnames="heads"))
 
 
 class DevicePool:
@@ -226,13 +261,16 @@ class DevicePool:
             self._array, result = program(self._array, *args)
         return result
 
-    def read(self, program, *args):
-        """``program(array, *args)``, for a program that only reads."""
+    def read(self, program, *args, **static):
+        """``program(array, *args, **static)``, for a program that only
+        reads."""
         with self._pool_lock:
-            return program(self._array, *args)
+            return program(self._array, *args, **static)
 
     def __getitem__(self, index):
-        """Of the K/V pool's array."""
+        """Of the K/V pool's array, in its device format: how a model's
+        decode step handed the holder itself (eagerly, outside the
+        runner's programs) reads a layer."""
         return self.read(lambda held: _kv(held)[index])
 
     def fill(self, value) -> None:
@@ -283,8 +321,12 @@ class PagedKVCache:
         self.state_bytes = sum(
             int(np.prod(s.shape)) * np.dtype(s.dtype).itemsize
             for s in (store or {}).values())
-        self.pool = DevicePool((num_blocks,) + self.block_shape, self.dtype,
-                               state=store)
+        shape = device_shape(num_blocks, n_layer, block_size, n_kv, head_dim)
+        # what the device format costs in memory beside the wire format's
+        # bytes: the lanes that pad F (LLMEngine.stats()["kv_lane_pad_bytes"])
+        self.lane_pad_bytes = int(np.prod(shape)) * self.dtype.itemsize \
+            - num_blocks * self.block_nbytes
+        self.pool = DevicePool(shape, self.dtype, state=store)
         # one step region for the writers below (DESIGN.md §4q): a
         # scatter program per prefill bucket, write_token, load_block
         self._write_budget = compile_budget("llm.kv_write")
@@ -464,14 +506,24 @@ class PagedKVCache:
             self.pool.donate(program, *args)
 
     def block_bytes(self, block_id: int) -> bytes:
-        """One block's contiguous bytes (the data-plane export unit),
-        copied from the device."""
-        block = self.pool.read(_programs().read_block, np.int32(block_id))
+        """One block's contiguous bytes in its wire format (the data-plane
+        export unit), gathered out of the pool and copied from the
+        device."""
+        block = self.pool.read(_programs().read_block, np.int32(block_id),
+                               heads=self.block_shape[3:])
         self._crossed(self.block_nbytes)
         return np.asarray(block).tobytes()
 
+    def blocks(self) -> np.ndarray:
+        """Every block in its wire format, ``(num_blocks,) + block_shape``,
+        copied from the device: for a caller that checks what the pool
+        holds without knowing how the device holds it (the tests)."""
+        return np.asarray(self.pool.read(_programs().read_blocks,
+                                         heads=self.block_shape[3:]))
+
     def load_block(self, block_id: int, raw) -> None:
-        """Copy one imported block's bytes to the device, into its block."""
+        """Copy one imported block's bytes (wire format) to the device,
+        into its block."""
         block = np.frombuffer(raw, dtype=self.dtype).reshape(self.block_shape)
         self._write(_programs().load_block, np.int32(block_id), block,
                     host=(block,))

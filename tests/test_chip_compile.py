@@ -7,6 +7,7 @@ costs no chip time.  Nothing runs; a compile that passes is not a chip
 run.  The whole GPT-2-124M step (~20 s) is left to ``chip_smoke.py``.
 """
 
+import math
 import os
 import re
 
@@ -133,9 +134,9 @@ def test_dropless_experts_at_olmoe_train_shape(v5e, monkeypatch, fn,
 
 
 # ----------------------------------------------- the serving cell's decode
-def _paged(q, k_pool, v_pool, tables, lens, k_new, v_new):
+def _paged(q, kv_pool, layer, tables, lens, k_new, v_new):
     from ray_tpu.ops.paged_attention import _paged_decode_kernel
-    return _paged_decode_kernel(q, k_pool, v_pool, tables, lens, k_new,
+    return _paged_decode_kernel(q, kv_pool, layer, tables, lens, k_new,
                                 v_new)
 
 
@@ -147,33 +148,74 @@ def _paged(q, k_pool, v_pool, tables, lens, k_new, v_new):
 ])
 def test_paged_decode_kernel_at_decode_shape(v5e, heads, kv_heads, head_dim,
                                              blocks):
-    """8 slots, a float32 pool of 16-position blocks, a table of 64
-    columns: Mosaic copies whole 128-lane tiles, so a position's heads
-    lie side by side, padded (1,600 -> 1,664 lanes for XL)."""
+    """8 slots, a float32 pool of 16-position blocks handed over whole
+    (six layers of it, the layer's index a traced scalar), a table of 64
+    columns: Mosaic copies whole 128-lane tiles, and the pool is stored
+    in them, a position's heads side by side, padded (1,600 -> 1,664
+    lanes for XL).  Nothing of the pool's size is made on the way in."""
+    from ray_tpu.serve.llm.kv_cache import device_shape
     q = ((8, heads, head_dim), jnp.bfloat16)
     new = ((8, kv_heads, head_dim), jnp.bfloat16)
-    pool = ((blocks, 16, kv_heads, head_dim), jnp.float32)
-    text = _compile(_paged, v5e, q, pool, pool, ((8, 64), jnp.int32),
-                    ((8,), jnp.int32), new, new)
+    pool = device_shape(blocks, 6, 16, kv_heads, head_dim)
+    text = _compile(_paged, v5e, q, (pool, jnp.float32), ((), jnp.int32),
+                    ((8, 64), jnp.int32), ((8,), jnp.int32), new, new)
     assert "paged_decode" in text
+    assert not _made(text, math.prod(pool), math.prod(pool[1:]),
+                     math.prod(pool[2:]))
+
+
+def _made(text, *counts):
+    """The float32 arrays of one of these element counts that a compiled
+    program makes: ``(opcode, shape)`` of every instruction with such a
+    result, a fusion's inner instructions included.  Naming what is
+    there makes nothing: parameters, tuple elements, bitcasts."""
+    free = ("parameter", "get-tuple-element", "bitcast")
+    found = []
+    for shape, opcode in re.findall(
+            r"= f32\[([\d,]+)\]\S* ([\w-]+)\(", text):
+        if opcode not in free and \
+                math.prod(int(d) for d in shape.split(",")) in counts:
+            found.append((opcode, shape))
+    return found
+
+
+def _assert_the_pool_is_read_in_place_and_written_by_rows(text, pool,
+                                                          lanes_used):
+    """``pool``: the K/V pool's device shape ``(L, 2, N, bs, F)``, of
+    whose F lanes a position uses ``lanes_used``.  The one thing the
+    program makes at the pool's size is the row update, a scatter (alone
+    in its fusion) over the pool as rows of F lanes, aliased to the
+    donated argument; and it makes nothing the size of a layer's K or V
+    (N x bs x F as stored, or the N x bs x KV x D in use), or of both: no
+    slice, select, copy, transpose or pad on the kernel's way."""
+    rows = f"{math.prod(pool[:-1])},{pool[-1]}"
+    assert sorted(_made(text, math.prod(pool))) == [
+        ("fusion", rows), ("scatter", rows)], _made(text, math.prod(pool))
+    assert "input_output_alias={ {0}: (0, {}, may-alias)" in text
+    layer = math.prod(pool[2:4])
+    sizes = {k * layer * f for k in (1, 2) for f in (pool[-1], lanes_used)}
+    assert not _made(text, *sizes), _made(text, *sizes)
 
 
 def test_serving_cell_decode_program_fits_and_gathers_nothing(
         v5e, monkeypatch):
     """The XL cell's whole decode step (48 layers, the weights as the
     runner prepares them, 128 blocks, one bucket of 8), as
-    ``ModelRunner`` jits it: one Mosaic kernel in the layer scan, no
-    gather of every slot's whole table (8 x 64 columns = 512 blocks of
-    16 x 25 x 64), no copy of the pool, no convert of a stacked weight
-    (handed float32 weights the program cast all 48 layers in every run
-    and held a 3.1 GB bf16 copy: 10.73e9 bytes), and arguments, result
-    and temporaries together in 4.59e9 of the chip's 16.9e9 bytes."""
+    ``ModelRunner`` jits it: one Mosaic kernel in the layer scan that
+    takes the pool whole, no gather of every slot's whole table (8 x 64
+    columns = 512 blocks of 16 x 25 x 64), no operation over the pool
+    but the in-place update of the step's rows and none over a layer of
+    it, no convert of a stacked weight (handed float32 weights the
+    program cast all 48 layers in every run and held a 3.1 GB bf16 copy:
+    10.73e9 bytes), and arguments, result and temporaries together in
+    4.64e9 of the chip's 16.9e9 bytes."""
     import json
     from pathlib import Path
 
     from ray_tpu.models._common import serving_params
     from ray_tpu.serve.llm import EngineConfig
     from ray_tpu.serve.llm.config import resolve_model
+    from ray_tpu.serve.llm.kv_cache import device_shape
     from ray_tpu.serve.llm.model_runner import ModelRunner
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     engine = json.loads((Path(__file__).parent.parent / "perfbench" /
@@ -193,8 +235,10 @@ def test_serving_cell_decode_program_fits_and_gathers_nothing(
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
     bucket, i32 = ecfg.decode_batch_buckets[-1], jnp.int32
-    pool = on_chip((ecfg.num_blocks, mcfg.n_layer, 2, ecfg.block_size,
-                    mcfg.n_head, mcfg.head_dim), jnp.float32)
+    pool = on_chip(device_shape(ecfg.num_blocks, mcfg.n_layer,
+                                ecfg.block_size, mcfg.n_head, mcfg.head_dim),
+                   jnp.float32)
+    assert pool.shape == (48, 2, 128, 16, 1664)
     compiled = runner._decode.lower(
         pool, jax.tree.map(lambda x: on_chip(x.shape, x.dtype), params),
         on_chip((bucket,), i32), on_chip((bucket,), i32),
@@ -203,15 +247,15 @@ def test_serving_cell_decode_program_fits_and_gathers_nothing(
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "paged_decode" in text
-    assert "[512,16,25,64]" not in text
-    # nor a split of the pool into per-layer pools ahead of the scan:
-    # each layer slices its K and V where they lie
-    assert "f32[48,128,16,25,64]" not in text
+    assert "[512,16,25,64]" not in text and "[512,16,1664]" not in text
+    _assert_the_pool_is_read_in_place_and_written_by_rows(
+        text, pool.shape, lanes_used=25 * 64)
     assert not re.search(r"= bf16\[48,\d+,[\d,]+\]\S* convert\(", text)
     mem = compiled.memory_analysis()
     held = mem.argument_size_in_bytes + mem.output_size_in_bytes \
         - mem.alias_size_in_bytes + mem.temp_size_in_bytes
-    assert mem.alias_size_in_bytes >= 1.25e9        # the pool, donated
+    assert mem.alias_size_in_bytes >= 1.30e9        # the pool, donated
+    assert mem.temp_size_in_bytes < 0.2e9           # and no second one
     assert held < 5e9, held
 
 
@@ -226,6 +270,7 @@ def falcon_h1_runner(v5e):
     from ray_tpu.models import falcon_h1
     from ray_tpu.serve.llm import EngineConfig
     from ray_tpu.serve.llm.config import resolve_model
+    from ray_tpu.serve.llm.kv_cache import device_shape
     from ray_tpu.serve.llm.model_runner import ModelRunner
     engine = json.loads((Path(__file__).parent.parent / "perfbench" /
                          "configs" / "falcon-h1-34b.json").read_text()
@@ -244,8 +289,9 @@ def falcon_h1_runner(v5e):
     runner = ModelRunner(ecfg, params=params)
     assert runner.params is params      # drawn in its serving type
     held = {
-        "kv": on_chip((ecfg.num_blocks, mcfg.n_layer, 2, ecfg.block_size,
-                       mcfg.n_kv_head, mcfg.head_dim), jnp.float32),
+        "kv": on_chip(device_shape(ecfg.num_blocks, mcfg.n_layer,
+                                   ecfg.block_size, mcfg.n_kv_head,
+                                   mcfg.head_dim), jnp.float32),
         "state": {name: on_chip((mcfg.n_layer, ecfg.max_num_seqs + 1)
                                 + s.shape, s.dtype)
                   for name, s in runner.state_spec.items()}}
@@ -263,9 +309,12 @@ def test_falcon_h1_decode_program_fits_and_steps_the_store_in_place(
         falcon_h1_runner, monkeypatch):
     """The cell's decode step at its one bucket of 32 (6 layers at the
     published widths, 1,024 blocks, 33 rows of state): the paged kernel
-    at 20 / 4 heads x 128 in the layer scan, K/V pool and store donated
-    (1.25e9 bytes aliased), no second copy of the store among the
-    temporaries, and everything in 11.9e9 of the chip's 16.9e9 bytes."""
+    at 20 / 4 heads x 128 in the layer scan, reading the pool whole (4 x
+    128 = 512 lanes, whole tiles: no padding); K/V pool and store donated
+    (1.25e9 bytes aliased), the pool touched by the update of the step's
+    32 rows alone and no layer of it by anything, no second copy of the
+    store among the temporaries, and everything in 11.9e9 of the chip's
+    16.9e9 bytes."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     runner, ecfg, held, weights, on_chip = falcon_h1_runner
     bucket, i32 = ecfg.decode_batch_buckets[-1], jnp.int32
@@ -278,6 +327,9 @@ def test_falcon_h1_decode_program_fits_and_steps_the_store_in_place(
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "paged_decode" in text
+    assert held["kv"].shape == (6, 2, 1024, 16, 512)
+    _assert_the_pool_is_read_in_place_and_written_by_rows(
+        text, held["kv"].shape, lanes_used=4 * 128)
     total, mem = _held_bytes(compiled)
     assert mem.alias_size_in_bytes >= 1.24e9
     assert mem.temp_size_in_bytes < 0.2e9       # the store is 0.84e9

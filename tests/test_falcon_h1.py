@@ -26,7 +26,8 @@ from perfbench.reference import falcon_h1_ref
 from ray_tpu.models import falcon_h1 as fh
 from ray_tpu.ops import ssm
 from ray_tpu.serve.llm import EngineConfig, LLMEngine, SamplingParams
-from ray_tpu.serve.llm.kv_cache import NoFreeBlocks, PagedKVCache
+from ray_tpu.serve.llm.kv_cache import (NoFreeBlocks, PagedKVCache,
+                                       device_shape)
 
 TIGHT, BF16 = 2e-4, 0.15
 
@@ -233,7 +234,9 @@ def test_a_row_is_given_with_the_blocks_and_taken_back_with_them():
 def test_a_cache_without_state_holds_the_array_alone():
     cache = PagedKVCache(4, 2, 4, 2, 8)
     assert cache.state_rows == 0 and cache.state_bytes == 0
-    assert cache.pool.read(lambda held: held.shape) == (4, 2, 2, 4, 2, 8)
+    # (L, 2, N, bs, F): 2 x 8 = 16 lanes in use of the tile's 128
+    assert cache.pool.read(lambda held: held.shape) == (2, 2, 4, 4, 128) \
+        == device_shape(4, 2, 4, 2, 8)
     cache.alloc_seq("a", 3)
     assert cache.state_rows_used() == 0
 
@@ -427,19 +430,20 @@ def test_bf16_engine_stays_within_the_bf16_tolerance():
 # stateless path did not grow a branch.  A PR that changes one of these
 # programs on purpose lowers them again and replaces the digests: PR 33
 # did so for ``prefill`` and ``decode`` of both families (each returns
-# its rows' greedy ids beside the logits); the pool's three writers keep
-# the digests of 2c891de.
+# its rows' greedy ids beside the logits), and PR 35 for ``decode`` and the
+# pool's three writers (the pool's device format, ``device_shape``);
+# ``prefill`` keeps PR 33's.
 PARENT_LOWERINGS = {
     ("gpt2:tiny", "prefill"): "3286e5649835dc0f",
-    ("gpt2:tiny", "decode"): "c9207aa0a5108ba3",
-    ("gpt2:tiny", "scatter"): "e77d230525ecb990",
-    ("gpt2:tiny", "write_rows"): "509f16dc28e06f5a",
-    ("gpt2:tiny", "load_block"): "9da8ae875d69843f",
+    ("gpt2:tiny", "decode"): "2aa90d74078a3595",
+    ("gpt2:tiny", "scatter"): "cc9d38f81a332a87",
+    ("gpt2:tiny", "write_rows"): "80383ff336f4f9fd",
+    ("gpt2:tiny", "load_block"): "d29caf7c6730e16d",
     ("llama:tiny", "prefill"): "b821c7fcd0890e93",
-    ("llama:tiny", "decode"): "d332ea6304a3a00b",
-    ("llama:tiny", "scatter"): "b856a51af58e5561",
-    ("llama:tiny", "write_rows"): "c20acf3658c65672",
-    ("llama:tiny", "load_block"): "c436b978b4004941",
+    ("llama:tiny", "decode"): "35dbeac9779436a4",
+    ("llama:tiny", "scatter"): "e05bda51b7431fe4",
+    ("llama:tiny", "write_rows"): "465b3826c9842b2e",
+    ("llama:tiny", "load_block"): "d933f36507a6630f",
 }
 
 
@@ -457,7 +461,7 @@ def test_stateless_families_lower_byte_for_byte_as_on_the_parent(model):
         return S(shape, jnp.int32)
 
     layers, kv_heads, d = runner.n_layer, runner.n_kv, runner.head_dim
-    pool = S((64, layers, 2, 8, kv_heads, d), jnp.float32)
+    pool = S(kvmod.device_shape(64, layers, 8, kv_heads, d), jnp.float32)
     kv = S((layers, 32, kv_heads, d), jnp.float32)
     one = S((layers, 1, kv_heads, d), jnp.float32)
     programs = kvmod._programs()
@@ -469,8 +473,8 @@ def test_stateless_families_lower_byte_for_byte_as_on_the_parent(model):
                                                   i32()),
         "write_rows": programs.write_rows.lower(pool, i32(1), i32(1), one,
                                                 one),
-        "load_block": programs.load_block.lower(pool, i32(),
-                                                S(pool.shape[1:], jnp.float32)),
+        "load_block": programs.load_block.lower(
+            pool, i32(), S((layers, 2, 8, kv_heads, d), jnp.float32)),
     }
     got = {(model, name): hashlib.sha256(low.as_text().encode())
            .hexdigest()[:16] for name, low in lowered.items()}

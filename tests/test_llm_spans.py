@@ -184,6 +184,23 @@ def test_decode_spans_carry_the_blocks_their_contexts_hold(captured):
         after["attn_blocks_read"] - before["attn_blocks_read"]
 
 
+def test_decode_spans_and_stats_carry_the_pools_lane_padding(captured):
+    """What the pool's device format costs in memory, beside
+    ``param_bytes``: N x L x 2 x bs x (F - KV x D) x 4 bytes of lanes
+    that pad a position's heads up to whole 128-lane tiles."""
+    from ray_tpu.serve.llm.config import resolve_model
+    lines, _, before, after = captured
+    cfg = small_pool_cfg()
+    mcfg = resolve_model(cfg)[1]
+    used = mcfg.n_head * mcfg.head_dim
+    want = cfg.num_blocks * mcfg.n_layer * 2 * cfg.block_size \
+        * (-used % 128) * 4
+    assert want > 0 and after["param_bytes"] > 0
+    assert before["kv_lane_pad_bytes"] == after["kv_lane_pad_bytes"] == want
+    assert {int(e[3]["kv_lane_pad_bytes"]) for e in _loop_line(lines)
+            if e[0] == "llm.decode"} == {want}
+
+
 def test_pull_spans_carry_the_bytes_that_crossed(captured):
     """A greedy run: a decode step's pull is its bucket's ids, a
     prefill's pull one id, and no logits crossed."""

@@ -44,8 +44,9 @@ def params():
 
 @pytest.fixture(scope="module")
 def decode_scopes(params):
-    pool = np.zeros((4, CFG.n_layer, 2, 8, CFG.n_head, CFG.head_dim),
-                    np.float32)
+    from ray_tpu.serve.llm.kv_cache import device_shape
+    pool = np.zeros(device_shape(4, CFG.n_layer, 8, CFG.n_head,
+                                 CFG.head_dim), np.float32)
     fn = jax.jit(lambda *a: gpt2.forward_decode(*a, cfg=CFG))
     return _scopes(fn.lower(
         params, np.zeros(2, np.int32), np.zeros(2, np.int32), pool,

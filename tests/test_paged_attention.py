@@ -3,8 +3,11 @@
 The kernel (``ops/paged_attention._paged_decode_kernel``) runs here in
 Pallas' TPU interpreter at tiny shapes: memory nothing wrote reads as NaN
 there and a read out of bounds raises.  The ``jnp`` path in the same file
-is the reference.  Each edge the block-table walk has is one case of
-one test.  The kernel at the chip's real shapes is compiled, not run, in
+is the reference, and is itself held to plain numpy over K/V in head
+form.  Both read the engine's pool whole, in its device format
+``(L, 2, N, bs, F)`` (``kv_cache.device_shape``), and one layer of it.
+Each edge the block-table walk has is one case of one test.  The kernel
+at the chip's real shapes is compiled, not run, in
 ``tests/test_chip_compile.py``.
 """
 
@@ -15,16 +18,32 @@ import pytest
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import paged_attention as pa
+from ray_tpu.serve.llm.kv_cache import device_shape
 
 BS = 4          # block size
 MAXB = 24       # table columns: three of the kernel's chunks, as cut here
 N = 80          # blocks in the pool
+LAYERS, LAYER = 3, 1    # the pool's layers, and the one that is read
+
+
+def _device_pool(k_pool, v_pool, rng):
+    """The engine's pool with ``k_pool`` / ``v_pool`` (N, bs, KV, D) as
+    layer ``LAYER`` and noise in every other layer.  The lanes that pad
+    ``KV * D`` up to ``F`` hold noise too: the writer leaves zeros there,
+    and the reader must not care."""
+    n, bs, kvh, d = k_pool.shape
+    pool = rng.standard_normal(device_shape(n, LAYERS, bs, kvh, d)
+                               ).astype(np.float32)
+    pool[LAYER, 0, :, :, :kvh * d] = k_pool.reshape(n, bs, kvh * d)
+    pool[LAYER, 1, :, :, :kvh * d] = v_pool.reshape(n, bs, kvh * d)
+    return jnp.asarray(pool)
 
 
 def _case(ctx_lens, *, h=3, kvh=3, d=8, q_dtype=jnp.float32, seed=0,
           shared=False, poison=False):
-    """Inputs for one call.  Block 0 is never named by a live column.
-    ``poison``: every table column past a row's context names a block
+    """Inputs for one call: the query, the pool the kernel gets, the
+    pool the reference gets, and the rest.  Block 0 is never named by a
+    live column.  ``poison``: every table column past a row's context names a block
     (one of the pool's, so a valid index) that holds NaN in the pool the
     kernel gets; the reference gets the same pool with those blocks
     zeroed, since the gather path multiplies what it masks by zero."""
@@ -57,8 +76,8 @@ def _case(ctx_lens, *, h=3, kvh=3, d=8, q_dtype=jnp.float32, seed=0,
     v_new = jnp.asarray(rng.standard_normal((b, kvh, d)), q_dtype)
     rest = (jnp.asarray(tables), jnp.asarray(ctx_lens, jnp.int32),
             k_new, v_new)
-    return q, (jnp.asarray(k_pool), jnp.asarray(v_pool)), \
-        (jnp.asarray(clean[0]), jnp.asarray(clean[1])), rest
+    return q, _device_pool(k_pool, v_pool, np.random.default_rng(seed)), \
+        _device_pool(*clean, np.random.default_rng(seed)), rest
 
 
 @pytest.fixture(autouse=True)
@@ -76,15 +95,22 @@ CASES = {
     "shared_blocks": dict(ctx_lens=[40, 37, 12, 40], shared=True),
     "grouped_query": dict(ctx_lens=[5, 33, 70, 0], h=6, kvh=2),
     "bf16_query": dict(ctx_lens=[5, 33, 70, 0], q_dtype=jnp.bfloat16),
+    # the cells' lanes: 25 x 64 = 1,600, padded to 1,664; 4 x 128 = 512,
+    # whole tiles as they are, five queries a KV head
+    "xl_lanes_padded": dict(ctx_lens=[5, 33, 70, 0], h=25, kvh=25, d=64),
+    "falcon_h1_lanes_whole": dict(ctx_lens=[5, 33, 70, 0], h=20, kvh=4,
+                                  d=128),
 }
 
 
 @pytest.mark.parametrize("case", CASES.values(), ids=CASES.keys())
 def test_kernel_matches_the_gather_path(case):
-    q, pools, clean, rest = _case(**case)
-    got = pa._paged_decode_kernel(q, *pools, *rest,
-                                  interpret=pltpu.InterpretParams())
-    want = pa._paged_decode_gather(q, *clean, *rest)
+    q, pool, clean, rest = _case(**case)
+    # the layer traced, as the models' scans hand it over
+    got = jax.jit(lambda layer: pa._paged_decode_kernel(
+        q, pool, layer, *rest, interpret=pltpu.InterpretParams()))(
+            jnp.int32(LAYER))
+    want = pa._paged_decode_gather(q, clean, LAYER, *rest)
     assert got.dtype == want.dtype == q.dtype
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     assert np.isfinite(got).all()
@@ -102,35 +128,55 @@ def test_kernel_matches_the_gather_path(case):
 
 def test_cpu_calls_take_the_gather_path(monkeypatch):
     """What the call chooses from: the backend, then the shapes."""
-    q, pools, _, rest = _case([5, 9])
+    q, pool, _, rest = _case([5, 9])
     called = []
     monkeypatch.setattr(pa, "_paged_decode_kernel",
                         lambda *a, **k: called.append(a) or a[0])
-    want = pa._paged_decode_gather(q, *pools, *rest)
+    want = pa._paged_decode_gather(q, pool, LAYER, *rest)
     np.testing.assert_array_equal(
-        pa.paged_attention_decode(q, *pools, *rest), want)
+        pa.paged_attention_decode(q, pool, LAYER, *rest), want)
     assert not called                               # this rig is a CPU
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    pa.paged_attention_decode(q, *pools, *rest)
+    pa.paged_attention_decode(q, pool, LAYER, *rest)
     assert len(called) == 1
-    bf16_pools = tuple(p.astype(jnp.bfloat16) for p in pools)
-    pa.paged_attention_decode(q, *bf16_pools, *rest)  # declined: not f32
+    pa.paged_attention_decode(q, pool.astype(jnp.bfloat16), LAYER,
+                              *rest)                # declined: not f32
     assert len(called) == 1
 
 
 @pytest.mark.parametrize("traced", [False, True])
-def test_layer_pools_is_the_pools_layer(traced):
-    """``layer_pools`` against numpy indexing of the engine's
-    ``(N, L, 2, bs, KV, D)`` pool, with the layer static and traced (as
-    the models' decode scans pass it).  Both models' ``forward_decode``
-    through it are held to the gather reference by
-    ``tests/test_serve_llm.py``."""
-    pool = np.random.default_rng(0).normal(
-        size=(5, 3, 2, 4, 2, 8)).astype(np.float32)
-    for layer in range(3):
-        if traced:
-            k, v = jax.jit(pa.layer_pools)(pool, jnp.int32(layer))
-        else:
-            k, v = pa.layer_pools(jnp.asarray(pool), layer)
-        np.testing.assert_array_equal(np.asarray(k), pool[:, layer, 0])
-        np.testing.assert_array_equal(np.asarray(v), pool[:, layer, 1])
+def test_gather_path_reads_the_pools_layer(traced):
+    """The gather path over the device format against plain numpy over
+    K/V in head form: the layer it is told, static and traced (as the
+    models' decode scans pass it), heads apart and the lane padding cut,
+    grouped-query heads repeated in ``jnp.repeat``'s order."""
+    ctx_lens, h, kvh, d = [5, 33, 70, 0], 6, 2, 8
+    rng = np.random.default_rng(3)
+    k_pool, v_pool = rng.standard_normal((2, N, BS, kvh, d)
+                                         ).astype(np.float32)
+    pool = _device_pool(k_pool, v_pool, rng)
+    assert pool.shape == (LAYERS, 2, N, BS, 128)
+    tables = rng.integers(1, N, (len(ctx_lens), MAXB)).astype(np.int32)
+    q, k_new, v_new = (rng.standard_normal((len(ctx_lens), n, d)
+                                           ).astype(np.float32)
+                       for n in (h, kvh, kvh))
+    args = (jnp.asarray(tables), jnp.asarray(ctx_lens, jnp.int32),
+            jnp.asarray(k_new), jnp.asarray(v_new))
+    if traced:
+        got = jax.jit(lambda layer: pa._paged_decode_gather(
+            jnp.asarray(q), pool, layer, *args))(jnp.int32(LAYER))
+    else:
+        got = pa._paged_decode_gather(jnp.asarray(q), pool, LAYER, *args)
+    for i, ctx in enumerate(ctx_lens):
+        # (ctx + 1, KV, D): the context through the table, then the token
+        keys = np.concatenate([k_pool[tables[i]].reshape(-1, kvh, d)[:ctx],
+                               k_new[i][None]])
+        vals = np.concatenate([v_pool[tables[i]].reshape(-1, kvh, d)[:ctx],
+                               v_new[i][None]])
+        for head in range(h):
+            kv = head // (h // kvh)
+            s = keys[:, kv] @ q[i, head] / np.sqrt(d)
+            p = np.exp(s - s.max())
+            np.testing.assert_allclose(
+                np.asarray(got)[i, head], (p / p.sum()) @ vals[:, kv],
+                rtol=1e-5, atol=1e-5)

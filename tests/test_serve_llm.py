@@ -70,8 +70,12 @@ def test_paged_attention_matches_dense():
     lens = np.array([10, 5], np.int32)
     k_new = rng.standard_normal((B, KV, D), np.float32)
     v_new = rng.standard_normal((B, KV, D), np.float32)
+    # the engine's pool as the device holds it, one layer of it
+    pool = np.zeros(kvmod.device_shape(N, 1, bs, KV, D), np.float32)
+    pool[0, 0, :, :, :KV * D] = pool_k.reshape(N, bs, KV * D)
+    pool[0, 1, :, :, :KV * D] = pool_v.reshape(N, bs, KV * D)
     got = np.asarray(paged_attention_decode(
-        jnp.asarray(q), jnp.asarray(pool_k), jnp.asarray(pool_v),
+        jnp.asarray(q), jnp.asarray(pool), 0,
         jnp.asarray(tables), jnp.asarray(lens), jnp.asarray(k_new),
         jnp.asarray(v_new)))
     rep = H // KV
@@ -302,8 +306,14 @@ def host_pool_decode(cfg, params, prompt, n):
     decode = jax.jit(lambda *a: mod.forward_decode(*a, mcfg))
     bs, maxb = cfg.block_size, cfg.max_blocks_per_seq
     n_kv = getattr(mcfg, "n_kv_head", mcfg.n_head)
-    pool = np.zeros((cfg.num_blocks, mcfg.n_layer, 2, bs, n_kv,
-                     mcfg.head_dim), np.float32)
+    pool = np.zeros(kvmod.device_shape(cfg.num_blocks, mcfg.n_layer, bs,
+                                       n_kv, mcfg.head_dim), np.float32)
+    f = n_kv * mcfg.head_dim                     # a row's lanes in use
+
+    def write(t, k, v):                          # k, v: (L, KV, D)
+        pool[:, 0, table[t // bs], t % bs, :f] = k.reshape(len(k), f)
+        pool[:, 1, table[t // bs], t % bs, :f] = v.reshape(len(v), f)
+
     table = list(range(3, 3 + maxb))             # any distinct blocks
     tb = next(b for b in cfg.prefill_len_buckets if len(prompt) <= b)
     toks = np.zeros((1, tb), np.int32)
@@ -311,8 +321,7 @@ def host_pool_decode(cfg, params, prompt, n):
     logits, ks, vs = prefill(params, toks, np.int32(len(prompt) - 1))
     ks, vs = np.asarray(ks, np.float32)[:, 0], np.asarray(vs, np.float32)[:, 0]
     for t in range(len(prompt)):
-        pool[table[t // bs], :, 0, t % bs] = ks[:, t]
-        pool[table[t // bs], :, 1, t % bs] = vs[:, t]
+        write(t, ks[:, t], vs[:, t])
     out = [int(np.argmax(np.asarray(logits)[0]))]
     tables = np.asarray([table], np.int32)
     while len(out) < n:
@@ -320,8 +329,8 @@ def host_pool_decode(cfg, params, prompt, n):
         lens = np.asarray([at], np.int32)
         logits, k, v = decode(params, np.asarray([out[-1]], np.int32), lens,
                               pool, tables, lens)
-        pool[table[at // bs], :, 0, at % bs] = np.asarray(k, np.float32)[:, 0]
-        pool[table[at // bs], :, 1, at % bs] = np.asarray(v, np.float32)[:, 0]
+        write(at, np.asarray(k, np.float32)[:, 0],
+              np.asarray(v, np.float32)[:, 0])
         out.append(int(np.argmax(np.asarray(logits)[0])))
     return out
 
@@ -346,7 +355,9 @@ def test_device_pool_matches_host_pool_semantics(model):
 
 
 def _random_pool(cache, seed=0):
-    """Every block of the cache set to known random bytes; the copy."""
+    """Every block of the cache set to known random bytes; the copy, in
+    the wire format ``(num_blocks,) + block_shape`` as ``cache.blocks()``
+    reads the pool back."""
     rng = np.random.default_rng(seed)
     want = rng.standard_normal(
         (cache.num_blocks,) + cache.block_shape).astype(np.float32)
@@ -373,7 +384,7 @@ def test_padded_decode_rows_write_nowhere(model):
     logits, k, v = runner.decode(np.asarray([4, 5, 6], np.int32), lens,
                                  cache.pool, tables, lens)
     assert logits.shape == (3, runner.vocab)
-    after = np.asarray(cache.pool[:])
+    after = cache.blocks()
     want = before.copy()
     for i, (blk, off) in enumerate([(6, 1), (11, 0), (1, 7)]):
         want[blk, :, 0, off] = np.asarray(k, np.float32)[:, i]
@@ -385,11 +396,9 @@ def test_padded_decode_rows_write_nowhere(model):
 @pytest.mark.parametrize("t_pad", [16, 32])
 def test_prefill_scatter_writes_only_the_real_tokens(t_pad):
     """The padded prompt's tail and the padded table's tail write
-    nowhere; numpy and device K/V run the same program.  16 rows go
-    through ``write_rows``' one pass over the pool, 32 through its
-    scatter: the same bytes either way."""
+    nowhere; numpy and device K/V run the same program, ``write_rows``'
+    one form at either width."""
     import jax.numpy as jnp
-    assert kvmod._ROWS_IN_ONE_PASS == 16
     cache = PagedKVCache(num_blocks=8, n_layer=2, block_size=4, n_kv=2,
                          head_dim=3)
     before = _random_pool(cache, seed=1)
@@ -405,11 +414,74 @@ def test_prefill_scatter_writes_only_the_real_tokens(t_pad):
     for t in range(6):
         want[table[t // 4], :, 0, t % 4] = ks[:, t]
         want[table[t // 4], :, 1, t % 4] = vs[:, t]
-    np.testing.assert_array_equal(np.asarray(cache.pool[:]), want)
+    np.testing.assert_array_equal(cache.blocks(), want)
     cache.scatter_prefill("a", ks, vs, 6)         # numpy: same bytes, counted
-    np.testing.assert_array_equal(np.asarray(cache.pool[:]), want)
+    np.testing.assert_array_equal(cache.blocks(), want)
     assert cache.host_bytes == host0 + ks.nbytes + vs.nbytes
     assert cache.block_bytes(table[1]) == want[table[1]].tobytes()
+
+
+@pytest.mark.parametrize("n_kv,head_dim,lanes", [
+    pytest.param(25, 64, 1664, id="1600_lanes_padded"),
+    pytest.param(4, 128, 512, id="512_lanes_whole")])
+@pytest.mark.parametrize("rows", [1, 8, 32, 512])
+def test_write_rows_writes_its_rows_and_drops_the_rest(rows, n_kv, head_dim,
+                                                       lanes):
+    """``write_rows``, traced and donated as the step programs run it: a
+    token's row, a decode batch's 8 or 32 and a prompt's 512, at both
+    cells' lane widths.  Every third row is sent out of range (block
+    ``num_blocks`` or beyond) and writes nowhere; the others land in
+    their slots, K beside V, and every other byte stays."""
+    import jax
+    import jax.numpy as jnp
+    n_layer, bs = 2, 16
+    num_blocks = max(4, 2 * rows // bs)
+    shape = kvmod.device_shape(num_blocks, n_layer, bs, n_kv, head_dim)
+    assert shape == (n_layer, 2, num_blocks, bs, lanes)
+    rng = np.random.default_rng(rows)
+    before = rng.standard_normal(shape).astype(np.float32)
+    slots = rng.permutation(num_blocks * bs)[:rows]   # distinct slots
+    blocks, offsets = (slots // bs).astype(np.int32), \
+        (slots % bs).astype(np.int32)
+    dropped = np.arange(rows) % 3 == 2
+    blocks[dropped] = num_blocks + np.arange(dropped.sum()) % 2
+    k, v = rng.standard_normal((2, n_layer, rows, n_kv, head_dim)
+                               ).astype(np.float32)
+    after = np.asarray(jax.jit(kvmod.write_rows, donate_argnums=0)(
+        jnp.asarray(before), blocks, offsets, k, v))
+    want, f = before.copy(), n_kv * head_dim
+    for r in np.flatnonzero(~dropped):
+        want[:, 0, blocks[r], offsets[r]] = 0.0       # the padding's lanes
+        want[:, 1, blocks[r], offsets[r]] = 0.0
+        want[:, 0, blocks[r], offsets[r], :f] = k[:, r].reshape(n_layer, f)
+        want[:, 1, blocks[r], offsets[r], :f] = v[:, r].reshape(n_layer, f)
+    np.testing.assert_array_equal(after, want)
+
+
+@pytest.mark.parametrize("n_kv,head_dim", [(25, 64), (4, 128), (2, 3)])
+def test_an_exported_block_loads_bit_identical_into_another_cache(n_kv,
+                                                                  head_dim):
+    """The wire format did not follow the device's: a block is
+    ``(L, 2, bs, KV, D)`` without lane padding, ``block_nbytes`` is that
+    shape's bytes, and ``block_bytes`` -> ``load_block`` into another
+    cache (another block id) gives the same bytes back."""
+    n_layer, bs = 3, 16
+    src = PagedKVCache(4, n_layer, bs, n_kv, head_dim)
+    dst = PagedKVCache(6, n_layer, bs, n_kv, head_dim)
+    assert src.block_shape == (n_layer, 2, bs, n_kv, head_dim)
+    assert src.block_nbytes == dst.block_nbytes == \
+        n_layer * 2 * bs * n_kv * head_dim * 4
+    assert src.lane_pad_bytes == 4 * n_layer * 2 * bs * 4 * \
+        (-(n_kv * head_dim) % 128)
+    want = _random_pool(src, seed=4)
+    others = dst.blocks()
+    raw = src.block_bytes(2)
+    assert raw == want[2].tobytes() and len(raw) == src.block_nbytes
+    dst.load_block(5, raw)
+    assert dst.block_bytes(5) == raw
+    got = dst.blocks()
+    np.testing.assert_array_equal(got[5], want[2])
+    np.testing.assert_array_equal(got[:5], others[:5])    # untouched
 
 
 def test_kv_host_bytes_counts_only_exported_and_imported_blocks():
@@ -445,8 +517,9 @@ def test_pool_programs_alias_the_pool_to_their_result():
     cfg = tiny_cfg(decode_batch_buckets=(4,))
     runner = ModelRunner(cfg)
     S = jax.ShapeDtypeStruct
-    pool = S((cfg.num_blocks, runner.n_layer, 2, cfg.block_size,
-              runner.n_kv, runner.head_dim), jnp.float32)
+    pool = S(kvmod.device_shape(cfg.num_blocks, runner.n_layer,
+                                cfg.block_size, runner.n_kv,
+                                runner.head_dim), jnp.float32)
     i32 = lambda *shape: S(shape, jnp.int32)      # noqa: E731
     kv = S((runner.n_layer, 32, runner.n_kv, runner.head_dim), jnp.float32)
     lowered = {
@@ -521,7 +594,7 @@ def test_the_benchmark_harness_calls_keep_working():
     try:
         runner, cache = eng.runner, eng.cache
         cache.pool.fill(0)
-        assert not np.asarray(cache.pool[:]).any()
+        assert not cache.blocks().any()
         maxb = eng.cfg.max_blocks_per_seq
         for _ in range(2):                        # _warm_programs
             runner.decode(np.zeros(4, np.int32), np.zeros(4, np.int32),
@@ -543,11 +616,11 @@ def test_the_benchmark_harness_calls_keep_working():
         lg, ks, vs = runner.decode(np.asarray([seq[-1]], np.int32), at,
                                    cache.pool, tables, at)
         assert lg[0].shape == (runner.vocab,)
-        stepped = np.asarray(cache.pool[:])
+        stepped = cache.blocks()
         assert stepped[blk, :, :, off].any()      # the step wrote the slot
         cache.write_token(blk, off, np.asarray(ks[:, 0], np.float32),
                           np.asarray(vs[:, 0], np.float32))
-        np.testing.assert_array_equal(np.asarray(cache.pool[:]), stepped)
+        np.testing.assert_array_equal(cache.blocks(), stepped)
         assert eng.stats()["kv_host_bytes"] > 0   # the harness's numpy K/V
         assert seq[-1] == oracle_decode(eng, prompt, 1)[0]
     finally:
@@ -607,8 +680,9 @@ def test_step_programs_convert_no_weight(model):
     assert 0 < wide < len(flat)
     S = jax.ShapeDtypeStruct
     i32 = lambda *shape: S(shape, jnp.int32)      # noqa: E731
-    pool = S((cfg.num_blocks, runner.n_layer, 2, cfg.block_size,
-              runner.n_kv, runner.head_dim), jnp.float32)
+    pool = S(kvmod.device_shape(cfg.num_blocks, runner.n_layer,
+                                cfg.block_size, runner.n_kv,
+                                runner.head_dim), jnp.float32)
     # a stacked leaf is sliced to one layer inside the scan
     shapes = {x.shape for x in jax.tree_util.tree_leaves(stored)} \
         | {x.shape[1:] for x in jax.tree_util.tree_leaves(stored["blocks"])}
