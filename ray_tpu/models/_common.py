@@ -65,6 +65,29 @@ def serving_params(params: Any, dtype: Any, wide: Tuple[str, ...]) -> Any:
     return _cast_leaves(params, dtype=dtype, wide=wide)
 
 
+def _keep_scopes_under_checkpoint() -> None:
+    """Lower ``jax.checkpoint``'s equation in place, not through jax's
+    cache of lowered equations.
+
+    A cached equation is lowered once into a function whose operations
+    all carry the call site's location, so everything inside a
+    checkpointed block's backward (its recomputation and its transpose:
+    most of a train step) would reach the compiled module without its
+    ``jax.named_scope`` path, and ``tracing.op_map`` could not say what a
+    backward operation is.  Lowered in place the operations keep their
+    own locations.  Locations only: the StableHLO is the same text, and
+    so is the compile-cache key (tests/test_op_map.py pins both).  jax
+    keeps this switch for "primitives that have problems with caching"
+    (``register_lowering(cacheable=False)``); where a later jax has no
+    such set, the scopes are lost again and nothing else."""
+    try:
+        from jax._src import ad_checkpoint
+        from jax._src.interpreters import mlir
+        mlir._uncacheable_primitives.add(ad_checkpoint.remat_p)
+    except (ImportError, AttributeError):
+        pass
+
+
 def remat_block(block: Callable, policy: str, flash_runs: bool) -> Callable:
     """``block`` under ``jax.checkpoint`` by a config's ``remat_policy``.
 
@@ -81,6 +104,7 @@ def remat_block(block: Callable, policy: str, flash_runs: bool) -> Callable:
     (``ops.attention.flash_runs``, and no sequence-axis KV ring).  The
     kept names exist only inside that kernel's vjp; without it ``attn*``
     would silently be full remat, so it raises."""
+    _keep_scopes_under_checkpoint()
     if policy == "full":
         return jax.checkpoint(block)
     if policy not in ("attn", "attn_qkv"):

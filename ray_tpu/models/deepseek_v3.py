@@ -167,7 +167,9 @@ def _latent_attention(u: jax.Array, lp: Params, cfg: DeepseekV3Config):
     B, T, _ = u.shape
     H, nope, rope = cfg.n_head, cfg.qk_nope_dim, cfg.qk_rope_dim
     latent = cfg.kv_latent_dim
-    q = (u @ lp["wq"]["kernel"].astype(cfg.dtype)).reshape(B, T, H, nope + rope)
+    with jax.named_scope("attn_qkv"):
+        q = (u @ lp["wq"]["kernel"].astype(cfg.dtype)).reshape(
+            B, T, H, nope + rope)
     with jax.named_scope("mla/latent"):
         kva = u @ lp["wkv_a"]["kernel"].astype(cfg.dtype)
         c = _rms_norm(kva[..., :latent], lp["kv_norm"]["scale"], cfg.rms_eps)
@@ -182,8 +184,9 @@ def _latent_attention(u: jax.Array, lp: Params, cfg: DeepseekV3Config):
     with jax.named_scope("attn"):
         from ray_tpu.ops.attention import causal_attention
         a = causal_attention(q, k, kvb[..., nope:], impl=cfg.attn_impl)
-    return a.reshape(B, T, H * cfg.v_head_dim) \
-        @ lp["wo"]["kernel"].astype(cfg.dtype)
+    with jax.named_scope("attn_out"):
+        return a.reshape(B, T, H * cfg.v_head_dim) \
+            @ lp["wo"]["kernel"].astype(cfg.dtype)
 
 
 def _swiglu(h: jax.Array, lp: Params, cfg: DeepseekV3Config) -> jax.Array:
@@ -209,20 +212,26 @@ def _experts(h: jax.Array, lp: Params, cfg: DeepseekV3Config):
 
 
 def _block(x: jax.Array, lp: Params, cfg: DeepseekV3Config, sparse: bool):
-    """One decoder block -> (out, HeldStats | None)."""
-    u = _rms_norm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
+    """One decoder block -> (out, HeldStats | None).  The layer kinds
+    shared with the other decoders run under GPT-2's scope names (ln_1,
+    attn_qkv, attn_out, ln_2, mlp; models/gpt2.py)."""
+    with jax.named_scope("ln_1"):
+        u = _rms_norm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
     x = x + _latent_attention(u, lp, cfg)
-    h = _rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
+    with jax.named_scope("ln_2"):
+        h = _rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
     if sparse:
         f, stats = _experts(h, lp, cfg)
         return x + f, stats
-    return x + _swiglu(h, lp, cfg), None
+    with jax.named_scope("mlp"):
+        return x + _swiglu(h, lp, cfg), None
 
 
 def forward_hidden(params: Params, tokens: jax.Array, cfg: DeepseekV3Config):
     """tokens (B, T) int32 -> (final-norm hidden states (B, T, E) in
     cfg.dtype, the sparse layers' HeldStats stacked on a leading axis)."""
-    x = params["wte"].astype(cfg.dtype)[tokens]
+    with jax.named_scope("embed"):
+        x = params["wte"].astype(cfg.dtype)[tokens]
     stats = None
     for name, sparse in (("dense_blocks", False), ("moe_blocks", True)):
         block = partial(_block, cfg=cfg, sparse=sparse)
@@ -231,15 +240,17 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: DeepseekV3Config):
             block = remat_block(block, cfg.remat_policy,
                                 flash_runs(tokens.shape[1], cfg.attn_impl))
         x, stats = lax.scan(block, x, params[name])
-    return _rms_norm(x, params["norm_f"]["scale"], cfg.rms_eps), stats
+    with jax.named_scope("ln_f"):
+        return _rms_norm(x, params["norm_f"]["scale"], cfg.rms_eps), stats
 
 
 def forward(params: Params, tokens: jax.Array,
             cfg: DeepseekV3Config) -> jax.Array:
     """tokens (B, T) int32 -> logits (B, T, vocab held) f32."""
     x, _ = forward_hidden(params, tokens, cfg)
-    logits = x @ params["lm_head"]["kernel"].astype(cfg.dtype)
-    return logits.astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        logits = x @ params["lm_head"]["kernel"].astype(cfg.dtype)
+        return logits.astype(jnp.float32)
 
 
 def loss_fn(params: Params, batch: Dict[str, jax.Array],

@@ -231,27 +231,35 @@ def _scaled(x: jax.Array, multiplier: float) -> jax.Array:
     return (x.astype(jnp.float32) * multiplier).astype(x.dtype)
 
 
+# The layer kinds shared with the other decoders run under GPT-2's
+# ``jax.named_scope`` names (embed, ln_1, attn_qkv, attn_out, ln_2, mlp,
+# ln_f, lm_head; models/gpt2.py), the mixer's under ssm_*.  Metadata only:
+# PERF.md section 3 lists the metric that reads each.
 def _embed(params: Params, tokens: jax.Array, cfg: FalconH1Config):
-    return _scaled(params["wte"].astype(cfg.dtype)[tokens],
-                   cfg.embedding_multiplier)
+    with jax.named_scope("embed"):
+        return _scaled(params["wte"].astype(cfg.dtype)[tokens],
+                       cfg.embedding_multiplier)
 
 
 def _logits(params: Params, x: jax.Array, cfg: FalconH1Config) -> jax.Array:
-    x = _rms_norm(x, params["norm_f"]["scale"], cfg.rms_eps)
-    logits = jnp.dot(x, params["lm_head"]["kernel"].astype(cfg.dtype),
-                     preferred_element_type=jnp.float32)
-    return logits * cfg.lm_head_multiplier
+    with jax.named_scope("ln_f"):
+        x = _rms_norm(x, params["norm_f"]["scale"], cfg.rms_eps)
+    with jax.named_scope("lm_head"):
+        logits = jnp.dot(x, params["lm_head"]["kernel"].astype(cfg.dtype),
+                         preferred_element_type=jnp.float32)
+        return logits * cfg.lm_head_multiplier
 
 
 def _qkv(u: jax.Array, lp: Params, cfg: FalconH1Config):
     """Normed hidden states (..., E) -> q (..., H, D), k, v (..., KV, D),
     before RoPE, the keys scaled."""
     H, D, KV = cfg.n_head, cfg.head_dim, cfg.n_kv_head
-    a_in = _scaled(u, cfg.attention_in_multiplier)
-    q = a_in @ lp["wq"]["kernel"].astype(cfg.dtype)
-    k = _scaled(a_in @ lp["wk"]["kernel"].astype(cfg.dtype),
-                cfg.key_multiplier)
-    v = a_in @ lp["wv"]["kernel"].astype(cfg.dtype)
+    with jax.named_scope("attn_qkv"):
+        a_in = _scaled(u, cfg.attention_in_multiplier)
+        q = a_in @ lp["wq"]["kernel"].astype(cfg.dtype)
+        k = _scaled(a_in @ lp["wk"]["kernel"].astype(cfg.dtype),
+                    cfg.key_multiplier)
+        v = a_in @ lp["wv"]["kernel"].astype(cfg.dtype)
     lead = u.shape[:-1]
     return (q.reshape(*lead, H, D), k.reshape(*lead, KV, D),
             v.reshape(*lead, KV, D))
@@ -259,18 +267,21 @@ def _qkv(u: jax.Array, lp: Params, cfg: FalconH1Config):
 
 def _mlp(h: jax.Array, lp: Params, cfg: FalconH1Config) -> jax.Array:
     gate_m, down_m = cfg.mlp_multipliers
-    gate = jax.nn.silu(_scaled(h @ lp["w_gate"]["kernel"].astype(cfg.dtype),
-                               gate_m))
-    up = h @ lp["w_up"]["kernel"].astype(cfg.dtype)
-    return _scaled((gate * up) @ lp["w_down"]["kernel"].astype(cfg.dtype),
-                   down_m)
+    with jax.named_scope("mlp"):
+        gate = jax.nn.silu(_scaled(
+            h @ lp["w_gate"]["kernel"].astype(cfg.dtype), gate_m))
+        up = h @ lp["w_up"]["kernel"].astype(cfg.dtype)
+        return _scaled(
+            (gate * up) @ lp["w_down"]["kernel"].astype(cfg.dtype), down_m)
 
 
 def _residual(x, m, a, cfg: FalconH1Config):
     """x + ssm_out_multiplier * m + attention_out_multiplier * a."""
     f32 = jnp.float32
-    return (x.astype(f32) + m.astype(f32) * cfg.ssm_out_multiplier
-            + a.astype(f32) * cfg.attention_out_multiplier).astype(x.dtype)
+    with jax.named_scope("residual"):
+        return (x.astype(f32) + m.astype(f32) * cfg.ssm_out_multiplier
+                + a.astype(f32) * cfg.attention_out_multiplier
+                ).astype(x.dtype)
 
 
 def _ssm_project(u: jax.Array, lp: Params, cfg: FalconH1Config) -> jax.Array:
@@ -398,7 +409,8 @@ def forward_prefill(params: Params, tokens: jax.Array, cfg: FalconH1Config,
     H = cfg.n_head
 
     def body(x, lp):
-        u = _rms_norm(x, lp["norm"]["scale"], cfg.rms_eps)
+        with jax.named_scope("ln_1"):
+            u = _rms_norm(x, lp["norm"]["scale"], cfg.rms_eps)
         m, state = _mixer(u, lp, cfg, last_pos)
         q, k, v = _qkv(u, lp, cfg)
         with jax.named_scope("rope"):
@@ -406,10 +418,12 @@ def forward_prefill(params: Params, tokens: jax.Array, cfg: FalconH1Config,
         with jax.named_scope("attn"):
             a = causal_attention(q, _gqa_expand(k, H), _gqa_expand(v, H),
                                  impl=cfg.attn_impl)
-        a = a.reshape(B, T, H * cfg.head_dim) \
-            @ lp["wo"]["kernel"].astype(cfg.dtype)
+        with jax.named_scope("attn_out"):
+            a = a.reshape(B, T, H * cfg.head_dim) \
+                @ lp["wo"]["kernel"].astype(cfg.dtype)
         h = _residual(x, m, a, cfg)
-        n = _rms_norm(h, lp["mlp_norm"]["scale"], cfg.rms_eps)
+        with jax.named_scope("ln_2"):
+            n = _rms_norm(h, lp["mlp_norm"]["scale"], cfg.rms_eps)
         return h + _mlp(n, lp, cfg), (k, v, state)
 
     x, (ks, vs, state) = lax.scan(body, _embed(params, tokens, cfg),
@@ -443,23 +457,31 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
     def body(carry, xs):
         x, state = carry
         lp, layer = xs
-        u = _rms_norm(x, lp["norm"]["scale"], cfg.rms_eps)
-        mine = jax.tree.map(
-            lambda s: lax.dynamic_index_in_dim(s, layer, 0, keepdims=False),
-            state)
+        with jax.named_scope("ln_1"):
+            u = _rms_norm(x, lp["norm"]["scale"], cfg.rms_eps)
+        # a layer's rows out of the store and back into it: the store's
+        # own passes, with the step that they surround
+        with jax.named_scope("ssm_step"):
+            mine = jax.tree.map(
+                lambda s: lax.dynamic_index_in_dim(s, layer, 0,
+                                                   keepdims=False), state)
         m, mine = _mixer_step(u, lp, cfg, mine, order)
-        state = jax.tree.map(
-            lambda s, new: lax.dynamic_update_index_in_dim(s, new, layer, 0),
-            state, mine)
+        with jax.named_scope("ssm_step"):
+            state = jax.tree.map(
+                lambda s, new: lax.dynamic_update_index_in_dim(
+                    s, new, layer, 0), state, mine)
         q, k, v = _qkv(u, lp, cfg)
-        q = _rope_at(q, positions, cfg.rope_theta)
-        k = _rope_at(k, positions, cfg.rope_theta)
+        with jax.named_scope("rope"):
+            q = _rope_at(q, positions, cfg.rope_theta)
+            k = _rope_at(k, positions, cfg.rope_theta)
         a = paged_attention_decode(q, kv_pool, layer, block_tables,
                                    ctx_lens, k, v)
-        a = a.reshape(B, cfg.n_head * cfg.head_dim) \
-            @ lp["wo"]["kernel"].astype(cfg.dtype)
+        with jax.named_scope("attn_out"):
+            a = a.reshape(B, cfg.n_head * cfg.head_dim) \
+                @ lp["wo"]["kernel"].astype(cfg.dtype)
         h = _residual(x, m, a, cfg)
-        n = _rms_norm(h, lp["mlp_norm"]["scale"], cfg.rms_eps)
+        with jax.named_scope("ln_2"):
+            n = _rms_norm(h, lp["mlp_norm"]["scale"], cfg.rms_eps)
         return (h + _mlp(n, lp, cfg), state), (k, v)
 
     (x, state), (ks, vs) = lax.scan(

@@ -149,6 +149,31 @@ WIDE_PARAMS = ("attn_norm", "mlp_norm", "q_norm", "k_norm", "norm_f")
 
 
 # ------------------------------------------------------------------ forward
+# The layer kinds run under GPT-2's ``jax.named_scope`` names (embed, ln_1,
+# attn_qkv, attn_out, ln_2, mlp, ln_f, lm_head; models/gpt2.py) beside this
+# block's own (qk_norm, rope, attn, and the router and moe_* of ops/moe.py).
+# Metadata only: PERF.md section 3 lists the metric that reads each.
+def _embed(params: Params, tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:
+    with jax.named_scope("embed"):
+        return params["wte"].astype(cfg.dtype)[tokens]
+
+
+def _final_norm(params: Params, x: jax.Array, cfg: LlamaConfig) -> jax.Array:
+    with jax.named_scope("ln_f"):
+        return _rms_norm(x, params["norm_f"]["scale"], cfg.rms_eps)
+
+
+def _head(params: Params, x: jax.Array, cfg: LlamaConfig,
+          only_position: bool = False) -> jax.Array:
+    """Final-norm hidden states -> float32 logits; ``only_position``:
+    x is (B, 1, E) and the logits (B, V)."""
+    with jax.named_scope("lm_head"):
+        logits = x @ params["lm_head"]["kernel"].astype(cfg.dtype)
+        if only_position:
+            logits = logits[:, 0]
+        return logits.astype(jnp.float32)
+
+
 def _rms_norm(x, scale, eps):
     x32 = x.astype(jnp.float32)
     y = x32 * lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + eps)
@@ -181,9 +206,10 @@ def _qkv(h: jax.Array, lp: Params, cfg: LlamaConfig):
     """Normed hidden states (..., E) -> q (..., H, D), k, v (..., KV, D),
     before RoPE."""
     H, D, KV = cfg.n_head, cfg.head_dim, cfg.n_kv_head
-    q = h @ lp["wq"]["kernel"].astype(cfg.dtype)
-    k = h @ lp["wk"]["kernel"].astype(cfg.dtype)
-    v = h @ lp["wv"]["kernel"].astype(cfg.dtype)
+    with jax.named_scope("attn_qkv"):
+        q = h @ lp["wq"]["kernel"].astype(cfg.dtype)
+        k = h @ lp["wk"]["kernel"].astype(cfg.dtype)
+        v = h @ lp["wv"]["kernel"].astype(cfg.dtype)
     if cfg.qk_norm:
         with jax.named_scope("qk_norm"):
             q = _rms_norm(q, lp["q_norm"]["scale"], cfg.rms_eps)
@@ -204,9 +230,10 @@ def _ffn(h: jax.Array, lp: Params, cfg: LlamaConfig):
             h.reshape(-1, h.shape[-1]), lp["router"]["kernel"],
             ex["w_gate"], ex["w_up"], ex["w_down"], k=cfg.experts_per_token)
         return out.reshape(h.shape), stats
-    gate = jax.nn.silu(h @ lp["w_gate"]["kernel"].astype(cfg.dtype))
-    up = h @ lp["w_up"]["kernel"].astype(cfg.dtype)
-    return (gate * up) @ lp["w_down"]["kernel"].astype(cfg.dtype), None
+    with jax.named_scope("mlp"):
+        gate = jax.nn.silu(h @ lp["w_gate"]["kernel"].astype(cfg.dtype))
+        up = h @ lp["w_up"]["kernel"].astype(cfg.dtype)
+        return (gate * up) @ lp["w_down"]["kernel"].astype(cfg.dtype), None
 
 
 def _block(x: jax.Array, lp: Params, cfg: LlamaConfig,
@@ -217,7 +244,8 @@ def _block(x: jax.Array, lp: Params, cfg: LlamaConfig,
     cannot diverge."""
     B, T, E = x.shape
     H = cfg.n_head
-    h = _rms_norm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
+    with jax.named_scope("ln_1"):
+        h = _rms_norm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
     q, k, v = _qkv(h, lp, cfg)
     with jax.named_scope("rope"):
         q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
@@ -226,8 +254,10 @@ def _block(x: jax.Array, lp: Params, cfg: LlamaConfig,
         from ray_tpu.ops.attention import causal_attention
         a = causal_attention(q, ke, ve, impl=cfg.attn_impl,
                              context_axis=cfg.context_axis).reshape(B, T, E)
-    x = x + a @ lp["wo"]["kernel"].astype(cfg.dtype)
-    h = _rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
+    with jax.named_scope("attn_out"):
+        x = x + a @ lp["wo"]["kernel"].astype(cfg.dtype)
+    with jax.named_scope("ln_2"):
+        h = _rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
     f, stats = _ffn(h, lp, cfg)
     out = x + f
     if collect_kv:
@@ -239,7 +269,7 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig):
     """tokens (B, T) int32 -> (final-norm hidden states (B, T, E) in
     cfg.dtype, the layers' RouterStats stacked on a leading n_layer axis,
     or None for a dense model)."""
-    x = params["wte"].astype(cfg.dtype)[tokens]
+    x = _embed(params, tokens, cfg)
     block = partial(_block, cfg=cfg)
     if cfg.remat:
         from ray_tpu.ops.attention import flash_runs
@@ -247,14 +277,13 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig):
                             flash_runs(tokens.shape[1], cfg.attn_impl))
 
     x, stats = lax.scan(block, x, params["blocks"])
-    return _rms_norm(x, params["norm_f"]["scale"], cfg.rms_eps), stats
+    return _final_norm(params, x, cfg), stats
 
 
 def forward(params: Params, tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:
     """tokens (B, T) int32 → logits (B, T, vocab) f32."""
     x, _ = forward_hidden(params, tokens, cfg)
-    logits = x @ params["lm_head"]["kernel"].astype(cfg.dtype)
-    return logits.astype(jnp.float32)
+    return _head(params, x, cfg)
 
 
 # -------------------------------------------------- inference (KV cache)
@@ -285,19 +314,16 @@ def forward_prefill(params: Params, tokens: jax.Array, cfg: LlamaConfig,
 
     ``last_pos`` (traced scalar): logits only at that position as
     (B, V); None returns the full (B, T, V) — see gpt2.forward_prefill."""
-    x = params["wte"].astype(cfg.dtype)[tokens]
+    x = _embed(params, tokens, cfg)
 
     def body(carry, lp):
         return _block(carry, lp, cfg, collect_kv=True)
 
     x, (ks, vs) = lax.scan(body, x, params["blocks"])
-    x = _rms_norm(x, params["norm_f"]["scale"], cfg.rms_eps)
+    x = _final_norm(params, x, cfg)
     if last_pos is not None:
         x = lax.dynamic_slice_in_dim(x, last_pos, 1, axis=1)
-    logits = x @ params["lm_head"]["kernel"].astype(cfg.dtype)
-    if last_pos is not None:
-        logits = logits[:, 0]
-    return logits.astype(jnp.float32), ks, vs
+    return _head(params, x, cfg, only_position=last_pos is not None), ks, vs
 
 
 def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
@@ -310,27 +336,29 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
     from ray_tpu.ops.paged_attention import paged_attention_decode
     B = tokens.shape[0]
     E = cfg.n_embd
-    x = params["wte"].astype(cfg.dtype)[tokens]                 # (B, E)
+    x = _embed(params, tokens, cfg)                             # (B, E)
 
     def body(carry, xs):
         x = carry
         lp, layer = xs
-        h = _rms_norm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
+        with jax.named_scope("ln_1"):
+            h = _rms_norm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
         q, k, v = _qkv(h, lp, cfg)
-        q = _rope_at(q, positions, cfg.rope_theta)
-        k = _rope_at(k, positions, cfg.rope_theta)
+        with jax.named_scope("rope"):
+            q = _rope_at(q, positions, cfg.rope_theta)
+            k = _rope_at(k, positions, cfg.rope_theta)
         a = paged_attention_decode(q, kv_pool, layer, block_tables,
                                    ctx_lens, k, v).reshape(B, E)
-        x = x + a @ lp["wo"]["kernel"].astype(cfg.dtype)
-        h = _rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
+        with jax.named_scope("attn_out"):
+            x = x + a @ lp["wo"]["kernel"].astype(cfg.dtype)
+        with jax.named_scope("ln_2"):
+            h = _rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
         x = x + _ffn(h, lp, cfg)[0]
         return x, (k, v)
 
     x, (ks, vs) = lax.scan(body, x,
                            (params["blocks"], jnp.arange(cfg.n_layer)))
-    x = _rms_norm(x, params["norm_f"]["scale"], cfg.rms_eps)
-    logits = x @ params["lm_head"]["kernel"].astype(cfg.dtype)
-    return logits.astype(jnp.float32), ks, vs
+    return _head(params, _final_norm(params, x, cfg), cfg), ks, vs
 
 
 def loss_fn(params: Params, batch: Dict[str, jax.Array],

@@ -128,6 +128,20 @@ class SpmdProgram:
     # the jax.jit object behind step_fn, for .lower()/.compile() (what
     # the step was compiled to: kernels, collectives, memory)
     jitted_step: Any = None
+    # (state, batch) as shapes, kept at the step's first call
+    abstract_args: Optional[tuple] = None
+
+    def op_map(self) -> Dict[str, dict]:
+        """What each instruction of the compiled step is
+        (``tracing.op_map``): lowers with the first call's shapes and
+        compiles, both hits in jax's in-memory caches in the process that
+        ran the step, then parses the module's text."""
+        from ray_tpu.util import tracing
+        if self.abstract_args is None:
+            raise RuntimeError("the step has not been called yet: its "
+                               "batch's shape is not known")
+        return tracing.op_map(
+            self.jitted_step.lower(*self.abstract_args).compile())
 
 
 def build_train_program(
@@ -283,12 +297,23 @@ def build_train_program(
     step_budget = compile_budget("train.step")
 
     def guarded_step(state: TrainState, batch: Any):
+        if program.abstract_args is None:
+            # the first call: keep the shapes it is called with, so that
+            # tracing.op_maps() can say what the step's operations are.
+            # A reference and nothing else: nothing is lowered or parsed
+            # until someone asks
+            from ray_tpu.util import tracing
+            program.abstract_args = tracing.abstract((state, batch))
+            tracing.register_program("train.step", step_fn,
+                                     program.abstract_args)
         with step_budget:
             return step_fn(state, batch)
 
-    return SpmdProgram(mesh=mesh, mesh_config=mesh_config, init_fn=init_fn,
-                       step_fn=guarded_step, state_shardings=state_sh,
-                       batch_sharding=batch_sh, jitted_step=step_fn)
+    program = SpmdProgram(
+        mesh=mesh, mesh_config=mesh_config, init_fn=init_fn,
+        step_fn=guarded_step, state_shardings=state_sh,
+        batch_sharding=batch_sh, jitted_step=step_fn)
+    return program
 
 
 def shard_batch(program: SpmdProgram, batch: Any) -> Any:

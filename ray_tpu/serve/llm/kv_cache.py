@@ -195,9 +195,10 @@ def _programs() -> SimpleNamespace:
         # padding (t >= n_tokens) is sent out of range and dropped
         pool = _kv(held)
         num_blocks, bs = pool.shape[2:4]
-        t = jnp.arange(ks.shape[1])
-        blocks = jnp.where(t < n_tokens, table[t // bs], num_blocks)
-        held = _with_kv(held, write_rows(pool, blocks, t % bs, ks, vs))
+        with jax.named_scope("kv_write"):
+            t = jnp.arange(ks.shape[1])
+            blocks = jnp.where(t < n_tokens, table[t // bs], num_blocks)
+            held = _with_kv(held, write_rows(pool, blocks, t % bs, ks, vs))
         if row:
             # recurrent state: the prompt's, which its prefill left in the
             # staging row, goes to the sequence's row
@@ -253,6 +254,13 @@ class DevicePool:
         self._pool_lock = threading.Lock()
         self._array = None                             # guarded by: _pool_lock
         self.fill(0)
+
+    def abstract(self):
+        """What the holder holds, as shapes (for whoever lowers a program
+        that takes it, without the array)."""
+        import jax
+        kv = jax.ShapeDtypeStruct(self.shape, self.dtype)
+        return kv if self.state is None else {"kv": kv, "state": self.state}
 
     def donate(self, program, *args):
         """Run ``program(array, *args) -> (array, result)``, which donates
@@ -545,18 +553,28 @@ class PagedKVCache:
         """Build the scatter program for this shape of K/V without
         writing a token (the runner calls it with a bucket's first
         prefill, so the bucket's two programs are built together)."""
+        from ray_tpu.util import tracing
         row = (self.staging_row,) if self.state_rows else ()
-        self._scatter([], ks, vs, 0, *row)
+        args = self._scatter_args([], ks, vs, 0, *row)
+        tracing.register_program(
+            f"llm.prefill.scatter.{ks.shape[1]}", _programs().scatter_prefill,
+            (self.pool.abstract(), *tracing.abstract(args)))
+        self._write(_programs().scatter_prefill, *args, host=(ks, vs))
 
-    def _scatter(self, table: List[int], ks, vs, n_tokens: int,
-                 *row: int) -> None:
+    def _scatter_args(self, table: List[int], ks, vs, n_tokens: int,
+                      *row: int) -> tuple:
         # the table at the width of the padded prompt; the blocks past
         # the sequence's own are out of range, and dropped on the device
         padded = np.full(-(-ks.shape[1] // self.block_size),
                          self.num_blocks, np.int32)
         padded[:len(table)] = table[:len(padded)]
-        self._write(_programs().scatter_prefill, padded, ks, vs,
-                    np.int32(n_tokens), *(np.int32(r) for r in row),
+        return (padded, ks, vs, np.int32(n_tokens),
+                *(np.int32(r) for r in row))
+
+    def _scatter(self, table: List[int], ks, vs, n_tokens: int,
+                 *row: int) -> None:
+        self._write(_programs().scatter_prefill,
+                    *self._scatter_args(table, ks, vs, n_tokens, *row),
                     host=(ks, vs))
 
     def write_token(self, block_id: int, offset: int, k, v) -> None:

@@ -68,7 +68,7 @@ from ray_tpu._private.xla_watchdog import compile_budget
 from ray_tpu.serve.llm.config import EngineConfig, SamplingParams, \
     resolve_model
 from ray_tpu.serve.llm.kv_cache import DevicePool, PagedKVCache, write_rows
-from ray_tpu.util.tracing import hot_span
+from ray_tpu.util.tracing import abstract, hot_span, register_program
 
 logger = rtlog.get("serve.llm.runner")
 
@@ -141,7 +141,8 @@ class ModelRunner:
         def greedy(logits):
             # each row's token at temperature 0, chosen where the logits
             # are: the first index of the maximum, as np.argmax takes it
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("lm_head"):
+                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
         def prefill_step(params, toks, last_pos):
             logits, ks, vs = forward_prefill(params, toks, last_pos=last_pos)
@@ -152,11 +153,12 @@ class ModelRunner:
             # (table[ctx // bs], ctx % bs).  Rows padded up to the bucket
             # are sent out of range: they write nowhere
             bs = cfg.block_size
-            rows = jnp.arange(ctx_lens.shape[0])
-            blocks = jnp.where(rows < n_real,
-                               block_tables[rows, ctx_lens // bs],
-                               cfg.num_blocks)
-            return write_rows(pool, blocks, ctx_lens % bs, k, v)
+            with jax.named_scope("kv_write"):
+                rows = jnp.arange(ctx_lens.shape[0])
+                blocks = jnp.where(rows < n_real,
+                                   block_tables[rows, ctx_lens // bs],
+                                   cfg.num_blocks)
+                return write_rows(pool, blocks, ctx_lens % bs, k, v)
 
         def decode_step(pool, params, tokens, positions, block_tables,
                         ctx_lens, n_real):
@@ -261,8 +263,14 @@ class ModelRunner:
             else:
                 picked, ks, vs = self._state_cache().pool.donate(
                     self._prefill, self.params, toks, last_pos)
-            if compiling is not _SEEN and self.cache is not None:
-                self.cache.warm_scatter(ks, vs)
+            if compiling is not _SEEN:
+                held = () if self.state_spec is None else (
+                    self._state_cache().pool.abstract(),)
+                register_program(
+                    f"llm.prefill.{tb}", self._prefill,
+                    held + abstract((self.params, toks, last_pos)))
+                if self.cache is not None:
+                    self.cache.warm_scatter(ks, vs)
         out = self._pull("llm.prefill.pull", picked, 1, logit_rows)
         return (out[0] if logit_rows is None else out), ks, vs
 
@@ -310,11 +318,14 @@ class ModelRunner:
         # dispatch holds the jitted call and ends at the ENQUEUE; pull
         # ends when the ids or the logits are on the host, so it holds the
         # wait for the step and nothing else: the pool stays where it is
+        args = (self.params, tokens, positions, block_tables, ctx_lens,
+                np.int32(b), *state_rows)
+        if compiling is not _SEEN:
+            register_program(f"llm.decode.{bb}", self._decode,
+                             (kv_pool.abstract(), *abstract(args)))
         with compiling, hot_span("llm.decode.dispatch", self.span_s), \
                 self._decode_budget:
-            picked, ks, vs = kv_pool.donate(
-                self._decode, self.params, tokens, positions, block_tables,
-                ctx_lens, np.int32(b), *state_rows)
+            picked, ks, vs = kv_pool.donate(self._decode, *args)
         return self._pull("llm.decode.pull", picked, b, logit_rows), ks, vs
 
     def _pull(self, span: str, picked, n: int,

@@ -42,6 +42,20 @@ instead: no context, no sampling, no shipping — a
 is already imported, and a running total the caller reads in-process
 (DESIGN.md §4h; the span names are a contract, PERF.md §3).
 
+What a device operation is.  A device trace names an operation by its
+HLO instruction (``fusion.414``); what that instruction computes is known
+to the program that compiled it.  The places that compile step programs
+(``spmd.build_train_program``, the LLM ``ModelRunner``, the paged cache's
+scatter) call ``register_program(name, jitted, args)``, which keeps a
+reference and does nothing else; ``op_maps()`` (or ``op_map(compiled)``
+for one executable) is what an operator or a benchmark calls afterwards
+to read, from the compiled module's own text, each instruction's
+``jax.named_scope`` path, pass, primitive and source line (the contract
+of the keys: PERF.md section 3).
+
+``hot_span`` and ``register_program`` / ``op_map`` / ``op_maps`` are the
+two things a hot path may use from this module.
+
 Span context lives in a ``contextvars.ContextVar`` (not a bare
 ``threading.local``): each thread still has its own current span, and the
 context additionally flows into asyncio tasks scheduled from a thread
@@ -56,11 +70,12 @@ import contextvars
 import itertools
 import os
 import random
+import re
 import sys
 import threading
 import time
 import weakref
-from typing import Dict, Iterator, List, Optional
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 _SPAN: "contextvars.ContextVar[Optional[SpanContext]]" = \
     contextvars.ContextVar("rtpu_span", default=None)
@@ -510,3 +525,343 @@ def profile_device(name: str = "device",
             _emit(events)
         if keep_dir is None:
             shutil.rmtree(out_dir, ignore_errors=True)
+
+
+# ---------------------------------------------------------------- op maps
+# What each instruction of a compiled step program is: its named-scope
+# path, pass, primitive and source line, read from the executable's own
+# text.  JAX writes the name stack into the module's stack-frame tables
+# (``FunctionNames``) even with full tracebacks off, where an
+# instruction's ``op_name`` is the bare primitive; the device trace prints
+# the instruction's name, so the join is by name within a module.
+
+# name -> (the jax.jit object, its abstract arguments); a new program of
+# a name replaces the old, so the registry is bounded by the names
+_PROGRAMS: Dict[str, Tuple[Any, tuple]] = {}
+
+# path components that transforms and control flow write, not the program
+# (those this repository's programs show; another is kept as a scope)
+_TRANSFORMS = ("transpose", "jvp", "vmap")
+_STRUCTURE = frozenset((
+    "while", "body", "cond", "closed_call", "checkpoint",
+    "rematted_computation", "remat2", "jit", "custom_vjp_call"))
+# what takes no scope from its users: it holds or passes on other work
+_NOT_INFERRED = frozenset(("while", "conditional", "call", "tuple",
+                           "parameter", "constant", "get-tuple-element"))
+_TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%(\S+) = (.*)$")
+_ARRAY = re.compile(r"\(?([a-z]+[0-9]*\[[0-9,]*\])")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_FRAME = re.compile(r"stack_frame_id=(\d+)")
+_SOURCE = re.compile(r'source_file="([^"]*)" source_line=(\d+)')
+_CALLED = re.compile(
+    r"\b(calls|body|condition|to_apply|true_computation|false_computation)"
+    r"=%([^\s,)}]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_OPERAND = re.compile(r"%([^\s,)}]+)")
+
+
+def register_program(name: str, jitted: Any, args: tuple) -> None:
+    """Keep a reference to a step program and the abstract arguments it
+    is called with (``jax.ShapeDtypeStruct`` trees), for ``op_maps``.
+    Nothing is lowered, compiled or parsed here."""
+    _PROGRAMS[name] = (jitted, args)
+
+
+def abstract(tree: Any) -> Any:
+    """A tree of arrays as ``jax.ShapeDtypeStruct``s, the abstract
+    arguments ``register_program`` keeps (the caller has jax imported;
+    this module does not import it).  A committed array keeps its
+    sharding, any other (numpy, an array jax placed by default) none: so
+    lowering with these finds the very program the call built, in jax's
+    own in-memory caches."""
+    jax = sys.modules["jax"]
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype,
+            sharding=x.sharding if getattr(x, "committed", False) else None),
+        tree)
+
+
+def registered_programs() -> List[str]:
+    return sorted(_PROGRAMS)
+
+
+def op_maps() -> Dict[str, dict]:
+    """Every registered program as ``{"module": its HLO module's name,
+    "ops": op_map(compiled)}``.  Lowers with the registered arguments
+    and compiles: in the process that has run the program both are hits
+    in jax's in-memory caches and what is left is the parsing (under a
+    second for a train step of 7,000 instructions); a process that has
+    not pays a load from its compilation cache, or a whole compile."""
+    out = {}
+    for name, (jitted, args) in sorted(_PROGRAMS.items()):
+        text = jitted.lower(*args).compile().as_text()
+        out[name] = {"module": module_name(text), "ops": op_map(text)}
+    return out
+
+
+def module_name(compiled: Any) -> str:
+    """``HloModule jit__step, ...`` -> ``jit__step``."""
+    text = compiled if isinstance(compiled, str) else compiled.as_text()
+    head = text[:text.find("\n")]
+    return head.split()[1].rstrip(",") if head.startswith("HloModule") else ""
+
+
+def scope_of(path: str) -> str:
+    """The components of a name-stack path that the program wrote, joined
+    by ``/``: ``jit(_step)/grads/transpose(jvp(moe))/moe_dispatch`` ->
+    ``grads/moe/moe_dispatch``."""
+    kept = []
+    for part in path.split("/"):
+        while True:
+            head, paren, rest = part.partition("(")
+            if paren and head in _TRANSFORMS and rest.endswith(")"):
+                part = rest[:-1]
+            else:
+                break
+        head = part.partition("(")[0]
+        if part and head not in _STRUCTURE and "->" not in part:
+            kept.append(part)
+    return "/".join(kept)
+
+
+def _frame_tables(text: str) -> Tuple[Dict[int, Tuple[str, str]], int]:
+    """stack_frame_id -> (function name, ``file.py:line``), and where the
+    tables end.  With full tracebacks off a location is one frame, so a
+    frame's parent is not followed."""
+    rows: Dict[str, Dict[int, str]] = {t: {} for t in _TABLES}
+    table, end = None, 0
+    for m in re.finditer(r"^(.*)$", text[:text.find("\n%") + 1 or None],
+                         re.M):
+        line = m.group(1)
+        if line in _TABLES:
+            table = line
+        elif table and line[:1].isdigit():
+            key, _, value = line.partition(" ")
+            rows[table][int(key)] = value
+            end = m.end()
+        elif line and table:
+            table = None
+
+    def field(row: str, key: str) -> int:
+        hit = re.search(rf"{key}=(\d+)", row)
+        return int(hit.group(1)) if hit else 0
+
+    frames = {}
+    for fid, row in rows["StackFrames"].items():
+        loc = rows["FileLocations"].get(field(row, "file_location_id"), "")
+        fn = rows["FunctionNames"].get(field(loc, "function_name_id"), '""')
+        fname = rows["FileNames"].get(field(loc, "file_name_id"), '""')
+        frames[fid] = (fn.strip('"'),
+                       f"{os.path.basename(fname.strip(chr(34)))}:"
+                       f"{field(loc, 'line')}")
+    return frames, end
+
+
+def _split_shape(rest: str) -> Tuple[str, str]:
+    """``(bf16[6400]{0}, f32[8]) fusion(...)`` -> (shape, what follows)."""
+    if not rest.startswith("("):
+        shape, _, tail = rest.partition(" ")
+        return shape, tail
+    depth = 0
+    for i, ch in enumerate(rest):
+        depth += ch == "("
+        depth -= ch == ")"
+        if depth == 0:
+            return rest[:i + 1], rest[i + 2:]
+    return rest, ""
+
+
+class _Module:
+    """A compiled module's text as parsed: each instruction's entry and
+    opcode, the computation it lies in, the computations it calls and
+    the instructions of its computation that use it."""
+
+    def __init__(self, text: str):
+        frames, at = _frame_tables(text)
+        # the module holds a backward at all
+        self.differentiated = "transpose(jvp(" in text
+        self.ops: Dict[str, dict] = {}
+        self.opcode: Dict[str, str] = {}
+        self.members: Dict[str, List[str]] = {}     # computation -> its own
+        self.comp_of: Dict[str, str] = {}
+        self.called: Dict[str, List[Tuple[str, str]]] = {}  # (how, comp)
+        self.users: Dict[str, List[str]] = {}
+        operands: Dict[str, List[str]] = {}
+        comp = ""
+        for line in text[at:].splitlines():
+            if not line.startswith(" "):
+                head = _COMPUTATION.match(line)
+                if head:
+                    comp = head.group(1)
+                continue
+            m = _INSTRUCTION.match(line)
+            if not m:
+                continue
+            name, rest = m.groups()
+            shape, tail = _split_shape(rest)
+            opcode, _, args = tail.partition("(")
+            op_name = (_OP_NAME.search(tail) or [None, ""])[1]
+            fn, src = frames.get(int((_FRAME.search(tail) or [0, 0])[1]),
+                                 ("", ""))
+            old = _SOURCE.search(tail)
+            if old:                         # metadata without frame tables
+                src = f"{os.path.basename(old.group(1))}:{old.group(2)}"
+            # jax writes the name stack into the frame's function name and
+            # the bare primitive into op_name, or (under an inlined jit)
+            # the whole of it into op_name
+            path, _, prim = op_name.rpartition("/")
+            if not path and fn != prim:
+                path = fn
+            first = _ARRAY.match(shape)
+            self.ops[name] = {
+                "scope": scope_of(path), "path": path,
+                "pass": self.pass_of(path), "prim": prim, "src": src,
+                "shape": first.group(1) if first else ""}
+            self.opcode[name] = opcode.strip()
+            self.comp_of[name] = comp
+            self.members.setdefault(comp, []).append(name)
+            calls = _CALLED.findall(tail)
+            branches = _BRANCHES.search(tail)
+            if branches:
+                calls += [("branch", c.strip().lstrip("%"))
+                          for c in branches.group(1).split(",")]
+            if calls:
+                self.called[name] = calls
+            operands[name] = _OPERAND.findall(args.partition("), ")[0])
+        for name, ins in operands.items():
+            for i in ins:
+                if self.comp_of.get(i) == self.comp_of[name]:
+                    self.users.setdefault(i, []).append(name)
+
+    def pass_of(self, path: str) -> str:
+        """What a path says of its pass by itself.  In a differentiated
+        module what ``jax.checkpoint`` lowers (its name stack starts with
+        ``checkpoint``) is the backward's: the recomputation and the
+        transposed block."""
+        if "transpose(" in path or "rematted_computation" in path or (
+                self.differentiated and path.startswith("checkpoint/")):
+            return "bwd"
+        return "fwd" if "jvp(" in path else ""
+
+    def infer_from_users(self) -> None:
+        """An instruction with no path of its own takes the one path that
+        its users agree on; a chain of such (copy-start, copy-done) is
+        followed."""
+        for _ in range(4):
+            moved = False
+            for name, entry in self.ops.items():
+                if entry["path"] or name not in self.users \
+                        or self.opcode[name] in _NOT_INFERRED:
+                    continue
+                said = {(self.ops[u]["path"], self.ops[u]["src"])
+                        for u in self.users[name] if self.ops[u]["path"]}
+                if len({path for path, _ in said}) == 1:
+                    path, src = sorted(said)[0]
+                    entry.update(path=path, scope=scope_of(path),
+                                 src=entry["src"] or src, inferred=True,
+                                 **{"pass": self.pass_of(path)})
+                    moved = True
+            if not moved:
+                break
+
+    def said(self, comp: str, seen: frozenset) -> str:
+        """The pass that a computation's instructions, and those of what
+        it calls, name by themselves."""
+        out = set()
+        for name in self.members.get(comp, ()):
+            # a constant does no work, and one that the compiler shares
+            # between the two loops keeps either's metadata
+            if self.opcode[name] != "constant":
+                out.add(self.ops[name]["pass"])
+            for _, inner in self.called.get(name, ()):
+                if inner not in seen:
+                    out.add(self.said(inner, seen | {inner}))
+        return "bwd" if "bwd" in out else "fwd" if "fwd" in out else ""
+
+    def feeds_backward(self, loop: str, body_pass: Dict[str, str]) -> bool:
+        seen, todo = {loop}, [loop]
+        while todo:
+            for user in self.users.get(todo.pop(), ()):
+                if user in seen:
+                    continue
+                if "bwd" in (self.ops[user]["pass"], body_pass.get(user)):
+                    return True
+                seen.add(user)
+                todo.append(user)
+        return False
+
+    def resolve_passes(self) -> None:
+        """Inside a loop body a name stack is relative to the loop, so the
+        pass is the body's: what its instructions say by themselves
+        (``said``); a body that says nothing is ``fwd`` if what its loop
+        returns reaches a ``bwd`` instruction of the same computation (the
+        forward scan of a differentiated program), else it has its
+        caller's pass.  A loop of one trip is no loop in the compiled
+        module: its body's relative paths lie in the entry computation,
+        where in a differentiated module they are the forward's (the
+        backward's say ``checkpoint`` or ``transpose``)."""
+        done = set()
+
+        def descend(comp: str, inherited: str, top: bool = False) -> None:
+            done.add(comp)
+            body_pass = {
+                name: self.said(inner, frozenset((inner,)))
+                for name in self.members.get(comp, ())
+                for how, inner in self.called.get(name, ()) if how == "body"}
+            for name in self.members.get(comp, ()):
+                entry = self.ops[name]
+                if name in body_pass:
+                    entry["pass"] = body_pass[name] or entry["pass"] or (
+                        "fwd" if self.feeds_backward(name, body_pass)
+                        else inherited)
+                elif not entry["pass"]:
+                    relative = entry["path"] and not entry["path"] \
+                        .startswith(("jit(", "pjit("))
+                    entry["pass"] = inherited or (
+                        "fwd" if top and self.differentiated and relative
+                        else "")
+                for _, inner in self.called.get(name, ()):
+                    if inner not in done:
+                        descend(inner, entry["pass"])
+
+        inner_comps = {c for calls in self.called.values() for _, c in calls}
+        for comp in self.members:
+            if comp not in inner_comps:      # the entry computation
+                descend(comp, "", top=True)
+
+    def note_mixed_fusions(self) -> None:
+        for name, calls in self.called.items():
+            if self.opcode[name] != "fusion":
+                continue
+            inner = sorted({self.ops[i]["scope"]
+                            for i in self.members.get(calls[0][1], ())
+                            if self.ops[i]["scope"]})
+            if len(inner) > 1:
+                self.ops[name]["mixed"] = inner
+
+
+def op_map(compiled: Any) -> Dict[str, dict]:
+    """Instruction name (as a device trace prints it) -> what it is, for
+    every instruction of every computation of a compiled executable (or
+    of its ``as_text()``).  Pure text parsing, no device work.
+
+    ``path``: the name stack as the module holds it; ``scope``: its
+    components that the program wrote (``scope_of``); ``pass``: ``fwd``
+    under ``jvp``, ``bwd`` under ``transpose`` or in what a checkpoint
+    lowers, else what the enclosing loop body says, else ``""``;
+    ``prim``: the jax primitive; ``src``: ``file.py:line``; ``shape``:
+    the (first) result, as ``perfbench.trace.short_name`` prints it.
+    A fusion has its root's metadata and, where the instructions it
+    calls name more than one scope, ``mixed``: those scopes.  An
+    instruction without metadata (a kernel's custom call, a copy the
+    compiler added) takes the one path its users agree on and says so
+    with ``inferred``."""
+    module = _Module(compiled if isinstance(compiled, str)
+                     else compiled.as_text())
+    module.infer_from_users()
+    module.resolve_passes()
+    module.note_mixed_fusions()
+    return module.ops

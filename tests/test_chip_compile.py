@@ -76,6 +76,34 @@ def test_flash_attention_at_prefill_bucket(v5e, bucket, dtype):
     _compile(_flash, v5e, qkv, qkv, qkv)
 
 
+def test_the_op_map_puts_the_flash_kernels_under_their_scope(v5e):
+    """The trace names a Pallas kernel ``tpu_custom_call.<n>``; the op map
+    says which is which: ``attn/flash_fwd`` forward, ``attn/flash_bwd``
+    backward, with ``jax_include_full_tracebacks_in_locations`` off as the
+    programs run.  (Inside a whole step's layer scan the compiler leaves
+    the custom call without metadata and its get-tuple-elements carry
+    ``pallas_call``: there the map takes the scope from those users.)"""
+    from ray_tpu.util import tracing
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+
+    def loss(q, k, v):
+        with jax.named_scope("attn"):
+            return _flash_loss(q, k, v)
+
+    ops = tracing.op_map(_compile(jax.grad(loss, argnums=(0, 1, 2)), v5e,
+                                  TRAIN_QKV, TRAIN_QKV, TRAIN_QKV))
+    kernels = {name: e for name, e in ops.items()
+               if name.startswith("tpu_custom_call")}
+    assert len(kernels) == 2, sorted(kernels)
+    for entry in kernels.values():
+        assert entry["scope"].split("/")[0] == "attn", entry
+        assert entry["prim"] == "pallas_call"
+        assert entry["src"].startswith(("test_chip_compile.py:",
+                                        "flash_attention.py:"))
+    assert sorted((e["scope"], e["pass"]) for e in kernels.values()) == [
+        ("attn/flash_bwd", "bwd"), ("attn/flash_fwd", "fwd")]
+
+
 # ------------------------------------------------- OLMoE's training cell
 OLMOE_QKV = ((2, 4096, 16, 128), jnp.bfloat16)      # 16 heads x 128, T 4096
 
