@@ -11,6 +11,18 @@ scattered by a second program.  A greedy token is chosen by the step
 program too: what comes to the host is a token id a row, and the logits
 of a row whose request samples (temperature > 0) and of no other.
 
+The loop keeps one decode step in flight.  A greedy row's next token is
+an id on the device (the step program feeds it to the next step there),
+and a row's position and slot follow from how many tokens it has, not
+from which: so an iteration enqueues decode step n+1 first and reads step
+n's ids (and commits them: streams, finishes) after that enqueue, while
+the device runs n+1.  What needs every token committed drains the step in
+flight first (``_drain``, counted by cause): a cancel, an attached
+sequence or a prefill (``admit``), a preemption (``pressure``), a step
+with a row that samples, whose token is drawn here from pulled logits
+(``sampled``), and nothing left to enqueue (``tail``).  DESIGN.md,
+"Scheduler loop".
+
 Disaggregated prefill/decode rides the PR-4 data plane:
 ``prefill_remote()`` copies the filled blocks from the device into a
 tmpfs export spool
@@ -30,7 +42,7 @@ import threading
 import time
 import uuid
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -38,9 +50,10 @@ from ray_tpu._private import rtlog
 from ray_tpu._private.config import GLOBAL_CONFIG
 from ray_tpu.serve.llm.config import EngineConfig, SamplingParams
 from ray_tpu.serve.llm.kv_cache import NoFreeBlocks, PagedKVCache
-from ray_tpu.serve.llm.model_runner import Chosen, ModelRunner, _bucket
-from ray_tpu.serve.llm.scheduler import (FAILED, FINISHED, IterationScheduler,
-                                         Plan, Sequence)
+from ray_tpu.serve.llm.model_runner import (Chosen, Enqueued, ModelRunner,
+                                            _bucket)
+from ray_tpu.serve.llm.scheduler import (FAILED, FINISHED, RUNNING,
+                                         IterationScheduler, Plan, Sequence)
 from ray_tpu.util import metrics_catalog as mcat
 from ray_tpu.util import tracing
 from ray_tpu.util.tracing import hot_span
@@ -55,6 +68,16 @@ def _sampled_rows(samplings) -> List[int]:
     """The rows of a step whose logits the host needs: those whose
     request is not greedy (``Chosen.token`` asks the same property)."""
     return [i for i, sp in enumerate(samplings) if not sp.greedy]
+
+
+class _InFlight(NamedTuple):
+    """A decode step enqueued and not yet read: its tokens are not in any
+    sequence's ``output`` yet."""
+
+    step: Enqueued                   # what the runner's pull takes
+    batch: List[Sequence]            # row i of the step is batch[i]
+    rows: Dict[str, int]             # sequence id -> its row
+    slots: Dict[str, tuple]          # the pool slots reserved for it
 
 
 class RequestStream:
@@ -180,6 +203,15 @@ class LLMEngine:
         self.sampled_on_device = 0
         self.sampled_on_host = 0
         self.logits_host_bytes = 0
+        # the decode step enqueued and not yet read, and how often the
+        # loop had one: steps enqueued behind another, steps read with
+        # nothing behind them (by what made the loop wait), and rows of a
+        # step that were stepped for a sequence its stop token had ended
+        # a step before (loop-owned)
+        self._inflight: Optional[_InFlight] = None
+        self.decode_steps_ahead = 0
+        self.decode_drains = dict(sampled=0, pressure=0, admit=0, tail=0)
+        self.decode_rows_discarded = 0
         # hot-span totals of the loop and the runner, name ->
         # [count, seconds] (tracing.hot_span); the names are a contract,
         # PERF.md section 3 lists each with the metric that reads it
@@ -306,6 +338,10 @@ class LLMEngine:
                     f"decode={len(plan.decode)} "
                     f"free={self.cache.free_block_count()}")
             if plan.prefill is not None:
+                # the commit first, the prefill's enqueue after it: the
+                # tokens of the step in flight are what clients wait for,
+                # and behind the prefill they would wait it out
+                self._drain("admit")
                 self._do_prefill(plan.prefill)
             elif plan.decode:
                 self._do_decode(plan.decode)
@@ -315,6 +351,13 @@ class LLMEngine:
 
     def _admit(self) -> None:
         """Cancels, attached sequences and the inbox into the scheduler."""
+        with self._lock:
+            settle = bool(self._cancels) or bool(
+                self._attached and self.max_num_seqs_room() > 0)
+        if settle:
+            # a cancel frees and an attached sequence joins: both find
+            # every running sequence with all its tokens
+            self._drain("admit")
         self._drain_cancels()
         self._drain_attached()
         with self._lock:
@@ -410,6 +453,16 @@ class LLMEngine:
 
     # ----------------------------------------------------------------- decode
     def _do_decode(self, seqs: List[Sequence]) -> None:
+        # a sequence whose token in flight is its last by length is known
+        # to end at that token's commit: no row of this step is spent on it
+        flight = self._inflight
+        if flight is not None:
+            seqs = [s for s in seqs if s.seq_id not in flight.rows
+                    or s.generated + 1 < s.sampling.max_tokens]
+        if not seqs:
+            # every running sequence has its last token in flight
+            self._drain("tail")
+            return
         t0 = time.time()    # the cluster timeline's clock: a start only
         with hot_span("llm.decode", self.span_s) as span:
             batch = self._decode_batch(seqs, span)
@@ -435,6 +488,12 @@ class LLMEngine:
                     slots[seq.seq_id] = self.cache.append_slot(seq.seq_id)
                     break
                 except NoFreeBlocks:
+                    if self._inflight is not None:
+                        # a preemption folds output into prompt, which
+                        # must hold every token; and the commit may free
+                        # the blocks of a sequence that ends with it
+                        self._drain("pressure")
+                        continue
                     if not self._preempt_one(slots):
                         # unreachable: sched.running contains at least
                         # `seq` itself (checked at the loop top, same
@@ -449,29 +508,38 @@ class LLMEngine:
 
     def _decode_batch(self, seqs: List[Sequence],
                       span: hot_span) -> List[Sequence]:
-        """One decode iteration; returns the batch that ran."""
+        """One decode iteration: enqueue a step for ``seqs``, then read
+        and commit the step that was in flight.  Returns the batch
+        enqueued."""
         spans = self.span_s
         with hot_span("llm.decode.slots", spans):
             slots, batch = self._reserve_slots(seqs)
         if not batch:
             return batch
-        span.set(batch=len(batch),
+        flight = self._inflight
+        behind = flight.rows if flight is not None else {}
+        span.set(batch=len(batch), ahead=int(flight is not None),
                  seqs="|".join(s.seq_id for s in batch))
         with hot_span("llm.decode.tables", spans):
             maxb = self.cfg.max_blocks_per_seq
             tables = np.zeros((len(batch), maxb), np.int32)
             toks = np.zeros(len(batch), np.int32)
-            poss = np.zeros(len(batch), np.int32)
+            src = np.full(len(batch), -1, np.int32)
             lens = np.zeros(len(batch), np.int32)
             for i, s in enumerate(batch):
                 t = self.cache.table(s.seq_id)
                 tables[i, :len(t)] = t
-                # the token being processed is the last SAMPLED one — its
+                # the token being processed is the last CHOSEN one: its
                 # KV is not in the pool yet (this step writes it); both
-                # its position and the valid pool length are ctx_len - 1
-                toks[i] = s.output[-1] if s.output else s.prompt[-1]
-                poss[i] = s.ctx_len - 1
-                lens[i] = s.ctx_len - 1
+                # its position and the valid pool length are the tokens
+                # before it.  In flight it is not in ``output`` yet and
+                # the step takes it from the device, by its row there
+                src[i] = behind.get(s.seq_id, -1)
+                if src[i] < 0:
+                    toks[i] = s.output[-1] if s.output else s.prompt[-1]
+                    lens[i] = s.ctx_len - 1
+                else:
+                    lens[i] = s.ctx_len
             # what the decode attention has to read against what the
             # compiled step's block tables can name (padded rows and
             # columns past a context included)
@@ -485,30 +553,77 @@ class LLMEngine:
             if self.cache.state_rows:
                 span.set(state_rows=len(batch))
                 self.state_rows_stepped += self.cache.state_rows + 1
+        sampled = _sampled_rows(s.sampling for s in batch)
         try:
             # the step writes each new token's K/V into its slot itself;
             # the K/V it also returns stay on the device, unread, and so
             # do the logits of every row whose request is greedy
-            chosen, _, _ = self.runner.decode(
-                toks, poss, self.cache.pool, tables, lens,
-                logit_rows=_sampled_rows(s.sampling for s in batch))
+            step, _, _ = self.runner.decode(
+                toks, lens, self.cache.pool, tables, lens,
+                logit_rows=sampled, wait=False, rows=src,
+                after=None if flight is None else flight.step)
         except BaseException:
             # return every slot reserved for THIS step, or every later
             # append_slot is off by one and the cache silently corrupts
-            for s in batch:
-                ent = slots.get(s.seq_id)
-                if ent is not None:
-                    self.cache.rollback_slot(s.seq_id, ent[2])
+            self._return_slots(batch, slots)
             raise
         self.decode_steps += 1
-        with hot_span("llm.decode.commit", spans):
-            for i, s in enumerate(batch):
+        span.set(step=step.step)
+        self._inflight = _InFlight(
+            step, batch, {s.seq_id: i for i, s in enumerate(batch)}, slots)
+        if flight is not None:
+            # the device has this step queued behind that one: now read it
+            self.decode_steps_ahead += 1
+            self._commit(flight)
+        if sampled:
+            # its token is drawn here, from logits only this step has
+            self._drain("sampled")
+        return batch
+
+    def _drain(self, cause: str) -> None:
+        """Read and commit the decode step in flight, if there is one,
+        with nothing enqueued behind it: the order the loop had before it
+        kept one in flight, for what needs every token committed."""
+        flight, self._inflight = self._inflight, None
+        if flight is None:
+            return
+        self.decode_drains[cause] += 1
+        with hot_span("llm.decode.drain", self.span_s, cause=cause):
+            self._commit(flight)
+
+    def _commit(self, flight: _InFlight) -> None:
+        """Wait for ``flight``'s ids and give each sequence its token."""
+        try:
+            chosen = self.runner.pull_step(flight.step)
+        except BaseException:
+            # none of its tokens arrived, and a step behind it was fed
+            # them: the slots of both go back
+            for lost in (flight, self._inflight):
+                if lost is not None:
+                    self._return_slots(lost.batch, lost.slots)
+            self._inflight = None
+            raise
+        discarded = 0
+        with hot_span("llm.decode.commit", self.span_s):
+            for i, s in enumerate(flight.batch):
+                if s.state != RUNNING:
+                    # ended by its stop token at the commit before this
+                    # one, when this step was enqueued already: the row
+                    # is nobody's, and free_seq took its slot back
+                    discarded += 1
+                    continue
                 self._emit(s, chosen.token(i, s.sampling, step=s.generated))
                 self._maybe_finish(s)
             with self._lock:
-                self._count_chosen_locked(chosen)
-        self._count_tokens(len(batch), phase="decode")
-        return batch
+                self._count_chosen_locked(chosen, discarded)
+        self.decode_rows_discarded += discarded
+        self._count_tokens(len(flight.batch) - discarded, phase="decode")
+
+    def _return_slots(self, batch: List[Sequence], slots: Dict) -> None:
+        for s in batch:
+            ent = slots.get(s.seq_id)
+            if ent is not None:
+                self.cache.rollback_slot(s.seq_id, ent[2])
 
     def _preempt_one(self, slots: Dict) -> bool:
         """Evict the scheduler's victim (latest arrival — possibly one
@@ -793,6 +908,13 @@ class LLMEngine:
         reason = seq.finish_reason()
         if reason is None:
             return
+        # a sequence ended by its stop token may still have a row in the
+        # step in flight, which will write the slot reserved for it and
+        # step its row of recurrent state.  Blocks and row are free for
+        # the next prefill all the same: every program takes the pool
+        # donated and the device runs them in the order they were
+        # enqueued, so that write lands before the new owner's scatter
+        # (which commits the state row whole) and before any later step's
         self.cache.free_seq(seq.seq_id)
         self.sched.finish(seq, FINISHED)
         if GLOBAL_CONFIG.metrics_enabled and len(seq.output) > 1 and \
@@ -824,11 +946,14 @@ class LLMEngine:
             mcat.get("rtpu_llm_tokens_total").inc(
                 n, tags={"model": self.cfg.model, "phase": phase})
 
-    def _count_chosen_locked(self, chosen: Chosen) -> None:
+    def _count_chosen_locked(self, chosen: Chosen,
+                             discarded: int = 0) -> None:
         """One step's tokens by where they were chosen; the rows whose
-        logits were pulled are the rows sampled here."""
+        logits were pulled are the rows sampled here.  ``discarded``
+        greedy rows gave nobody a token."""
         self.sampled_on_host += len(chosen.logits)
-        self.sampled_on_device += len(chosen.ids) - len(chosen.logits)
+        self.sampled_on_device += len(chosen.ids) - len(chosen.logits) \
+            - discarded
         self.logits_host_bytes += chosen.logits_nbytes
 
     def _publish_metrics(self, plan: Plan) -> None:
@@ -852,6 +977,9 @@ class LLMEngine:
     def stats(self) -> dict:
         return dict(prefill_steps=self.prefill_steps,
                     decode_steps=self.decode_steps,
+                    decode_steps_ahead=self.decode_steps_ahead,
+                    decode_drains=dict(self.decode_drains),
+                    decode_rows_discarded=self.decode_rows_discarded,
                     preemptions=self.preemptions,
                     tokens_out=self.tokens_out,
                     running=len(self.sched.running),

@@ -30,6 +30,19 @@ gets ``Chosen``: the ``(B,)`` ids and those rows.  Seeded temperature /
 top-k sampling stays host-side on a pulled ``(V,)`` row (``sample``).  A
 caller that names nothing gets all the logits on the host, as always.
 
+One step behind another.  A greedy row's next token is the id the step
+before chose, and that id is on the device: the decode programs take the
+last step's ids (``last_ids``, at the widest bucket's width, which every
+bucket's program also returns its own at) and a row map ``src``: row i's
+token is ``last_ids[src[i]]``, or ``tokens[i]`` from the host where
+``src[i]`` is -1.  ``decode(..., after=step, rows=src, wait=False)``
+enqueues such a step and returns at the enqueue with an ``Enqueued``;
+``pull_step`` waits for one and brings its ids.  So the engine's loop keeps a
+step in flight and reads step n after it has enqueued step n+1.  A call
+without ``after`` sends zeros and a map of -1s of the same type and
+placement (``_no_ids``): one executable a bucket serves both, and the
+harness's warm call has built it.
+
 Recurrent state.  A family whose sequences hold more than K/V says so by
 exporting ``recurrent_state(cfg)``: one sequence's state in one layer,
 name -> shape and type.  That description is all the runner and the cache
@@ -101,6 +114,17 @@ class Chosen(NamedTuple):
         return sum(row.nbytes for row in self.logits.values())
 
 
+class Enqueued(NamedTuple):
+    """A decode step the device has been handed: what ``ModelRunner.pull_step``
+    brings to the host, and what the step enqueued behind it reads."""
+
+    step: int                        # the runner's count of decode steps
+    picked: tuple                    # (logits, ids), on the device
+    carry: "jax.Array"               # the ids at the widest bucket's width
+    n: int                           # the rows that are real
+    logit_rows: Optional[Sequence[int]]
+
+
 class ModelRunner:
     """Owns params + the jitted, bucketed prefill/decode programs."""
 
@@ -160,14 +184,31 @@ class ModelRunner:
                                    cfg.num_blocks)
                 return write_rows(pool, blocks, ctx_lens % bs, k, v)
 
+        widest = cfg.decode_batch_buckets[-1]
+
+        def tokens_in(tokens, last_ids, src):
+            # a row that the step before also held takes the token that
+            # step chose from where it lies; -1: the host's
+            with jax.named_scope("embed"):
+                return jnp.where(src >= 0, last_ids[jnp.maximum(src, 0)],
+                                 tokens)
+
+        def chosen_from(logits):
+            # (logits, ids), and the ids at the width every bucket's
+            # program takes them back at
+            ids = greedy(logits)
+            pad = widest - ids.shape[0]
+            return (logits, ids), jnp.pad(ids, (0, pad)) if pad else ids
+
         def decode_step(pool, params, tokens, positions, block_tables,
-                        ctx_lens, n_real):
+                        ctx_lens, n_real, last_ids, src):
             # the model reads the pool and attends the new token
             # explicitly; its K/V is written after the reads
-            logits, k, v = forward_decode(params, tokens, positions, pool,
-                                          block_tables, ctx_lens)
+            logits, k, v = forward_decode(
+                params, tokens_in(tokens, last_ids, src), positions, pool,
+                block_tables, ctx_lens)
             pool = new_kv_written(pool, k, v, block_tables, ctx_lens, n_real)
-            return pool, ((logits, greedy(logits)), k, v)
+            return pool, (*chosen_from(logits), k, v)
 
         def prefill_state_step(held, params, toks, last_pos):
             # the state at the prompt's last real position goes to the
@@ -180,16 +221,16 @@ class ModelRunner:
                 (logits, greedy(logits)), ks[:, 0], vs[:, 0])
 
         def decode_state_step(held, params, tokens, positions, block_tables,
-                              ctx_lens, n_real, state_rows):
+                              ctx_lens, n_real, last_ids, src, state_rows):
             # as decode_step, and the model steps the rows of the store
             # that state_rows names
             logits, k, v, store = forward_decode(
-                params, tokens, positions, held["kv"], block_tables,
-                ctx_lens, state=held["state"], rows=state_rows)
+                params, tokens_in(tokens, last_ids, src), positions,
+                held["kv"], block_tables, ctx_lens, state=held["state"],
+                rows=state_rows)
             pool = new_kv_written(held["kv"], k, v, block_tables, ctx_lens,
                                   n_real)
-            return {"kv": pool, "state": store}, (
-                (logits, greedy(logits)), k, v)
+            return {"kv": pool, "state": store}, (*chosen_from(logits), k, v)
 
         # bound to a name of its own: jaxlint pins a donating jit by the
         # name it is assigned to (lock_watchdog.DONATED)
@@ -207,6 +248,18 @@ class ModelRunner:
         # one named row of a step's logits, for a request that samples:
         # built with the first such row a bucket meets, not before
         self._logits_row = jax.jit(lambda logits, row: logits[row])
+        # what a decode step with no step before it takes as last_ids:
+        # zeros placed as a step's own ids will be.  A program's results
+        # are committed to a device when an argument is (the weights: the
+        # pool and these are placed by default), and jit keeps an entry
+        # for each placement of its arguments: zeros placed otherwise
+        # than a real step's ids would be a second entry, and its first
+        # call a miss inside somebody's measured window
+        weights = jax.tree.leaves(self.params)
+        self._no_ids = jax.device_put(np.zeros(widest, np.int32), next(
+            (w.sharding for w in weights if getattr(w, "committed", False)),
+            None))
+        self.steps_enqueued = 0    # decode steps, this runner's life
         # the engine's cache: a bucket's scatter program is built with
         # the bucket's first prefill (None: a runner on its own)
         self.cache: Optional[PagedKVCache] = None
@@ -278,8 +331,10 @@ class ModelRunner:
     def decode(self, tokens: np.ndarray, positions: np.ndarray,
                kv_pool: DevicePool, block_tables: np.ndarray,
                ctx_lens: np.ndarray, *,
-               logit_rows: Optional[Sequence[int]] = None
-               ) -> Tuple[Union[np.ndarray, Chosen], "jax.Array",
+               logit_rows: Optional[Sequence[int]] = None,
+               after: Optional[Enqueued] = None,
+               rows: Optional[np.ndarray] = None, wait: bool = True
+               ) -> Tuple[Union[np.ndarray, Chosen, Enqueued], "jax.Array",
                           "jax.Array"]:
         """One iteration over a batch of sequences.
 
@@ -293,11 +348,21 @@ class ModelRunner:
         rows whose request samples; ``()`` for a greedy batch) the first
         result is ``Chosen``: the (B,) ids and those rows' logits, and
         the rest of the logits stay on the device.
+
+        ``after`` and ``rows`` (B,): the decode step enqueued before this
+        one and, for each row here, its row there; such a row's token is
+        the id that step chose, read on the device, and ``tokens[i]`` is
+        not looked at (-1: the row was not in that step, ``tokens[i]`` it
+        is).  With ``wait=False`` the call returns at the enqueue and the
+        first result is the ``Enqueued`` that ``pull_step`` takes.
         """
         b = len(tokens)
         bb = _bucket(b, self.cfg.decode_batch_buckets)
         compiling = self._note_shape("decode", bb)
         pad = bb - b
+        last_ids, src = self._no_ids, np.full(bb, -1, np.int32)
+        if after is not None:
+            last_ids, src[:b] = after.carry, rows
         if pad:
             with hot_span("llm.decode.tables", self.span_s):
                 tokens = np.concatenate([tokens, np.zeros(pad, np.int32)])
@@ -319,22 +384,31 @@ class ModelRunner:
         # ends when the ids or the logits are on the host, so it holds the
         # wait for the step and nothing else: the pool stays where it is
         args = (self.params, tokens, positions, block_tables, ctx_lens,
-                np.int32(b), *state_rows)
+                np.int32(b), last_ids, src, *state_rows)
         if compiling is not _SEEN:
             register_program(f"llm.decode.{bb}", self._decode,
                              (kv_pool.abstract(), *abstract(args)))
         with compiling, hot_span("llm.decode.dispatch", self.span_s), \
                 self._decode_budget:
-            picked, ks, vs = kv_pool.donate(self._decode, *args)
-        return self._pull("llm.decode.pull", picked, b, logit_rows), ks, vs
+            picked, carry, ks, vs = kv_pool.donate(self._decode, *args)
+        self.steps_enqueued += 1
+        step = Enqueued(self.steps_enqueued, picked, carry, b, logit_rows)
+        return (self.pull_step(step) if wait else step), ks, vs
+
+    def pull_step(self, step: Enqueued) -> Union[np.ndarray, Chosen]:
+        """Wait for an enqueued decode step and bring the host what its
+        caller named (``decode``'s first result), inside an
+        ``llm.decode.pull`` span that says which step it is."""
+        return self._pull("llm.decode.pull", step.picked, step.n,
+                          step.logit_rows, step=step.step)
 
     def _pull(self, span: str, picked, n: int,
-              logit_rows: Optional[Sequence[int]]):
+              logit_rows: Optional[Sequence[int]], **attrs):
         """A step's results for the host, inside ``span`` (whose ``bytes``
         is what crossed): all its logits, (n, V), for a caller that named
         no rows; else the n ids and the rows named."""
         logits, ids = picked
-        with hot_span(span, self.span_s) as pull:
+        with hot_span(span, self.span_s, **attrs) as pull:
             if logit_rows is None:
                 pull.set(bytes=logits.nbytes)
                 return np.asarray(logits)[:n]

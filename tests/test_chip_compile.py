@@ -225,6 +225,22 @@ def _assert_the_pool_is_read_in_place_and_written_by_rows(text, pool,
     assert not _made(text, *sizes), _made(text, *sizes)
 
 
+def _assert_a_rows_token_may_come_from_the_last_steps_ids(text, bucket):
+    """The decode program's operands ``last_ids`` and ``src`` (the ids the
+    step before chose and, a row, its row there): ``bucket`` ids are
+    looked up under the ``embed`` scope, the ids go out again at the same
+    width, and that is all of it (the callers go on to assert that the
+    pool is aliased and nothing of its size is made)."""
+    entry = text[text.index("ENTRY "):]
+    ids = f"s32[{bucket}]"
+    assert len(re.findall(rf"= {re.escape(ids)}\S* parameter\(", entry)) >= 5
+    picks = [line for line in text.splitlines()
+             if "/embed/" in line and f"= {ids}" in line]
+    assert picks, "no s32[bucket] operation under the embed scope"
+    root = next(line for line in entry.splitlines() if " ROOT " in line)
+    assert root.count(ids) >= 2, root       # the ids, and the ids carried
+
+
 def test_serving_cell_decode_program_fits_and_gathers_nothing(
         v5e, monkeypatch):
     """The XL cell's whole decode step (48 layers, the weights as the
@@ -271,8 +287,10 @@ def test_serving_cell_decode_program_fits_and_gathers_nothing(
         pool, jax.tree.map(lambda x: on_chip(x.shape, x.dtype), params),
         on_chip((bucket,), i32), on_chip((bucket,), i32),
         on_chip((bucket, ecfg.max_blocks_per_seq), i32),
-        on_chip((bucket,), i32), on_chip((), i32)).compile()
+        on_chip((bucket,), i32), on_chip((), i32),
+        on_chip((bucket,), i32), on_chip((bucket,), i32)).compile()
     text = compiled.as_text()
+    _assert_a_rows_token_may_come_from_the_last_steps_ids(text, bucket)
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "paged_decode" in text
     assert "[512,16,25,64]" not in text and "[512,16,1664]" not in text
@@ -351,8 +369,10 @@ def test_falcon_h1_decode_program_fits_and_steps_the_store_in_place(
         held, weights, on_chip((bucket,), i32), on_chip((bucket,), i32),
         on_chip((bucket, ecfg.max_blocks_per_seq), i32),
         on_chip((bucket,), i32), on_chip((), i32),
+        on_chip((bucket,), i32), on_chip((bucket,), i32),
         on_chip((bucket,), i32)).compile()
     text = compiled.as_text()
+    _assert_a_rows_token_may_come_from_the_last_steps_ids(text, bucket)
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "paged_decode" in text
     assert held["kv"].shape == (6, 2, 1024, 16, 512)

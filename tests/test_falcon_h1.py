@@ -262,13 +262,17 @@ def _recorded(eng):
             (len(token_ids), chosen.logits[0]))
         return chosen, ks, vs
 
-    def spy_decode(tokens, positions, pool, tables, lens, *, logit_rows):
-        chosen, ks, vs = decode(tokens, positions, pool, tables, lens,
-                                logit_rows=range(len(tokens)))
+    def spy_decode(tokens, positions, pool, tables, lens, *, logit_rows,
+                   **ahead):
+        # the loop pulls a step after it has enqueued the next: the spy
+        # reads this one's logits now, which waits for it
+        step, ks, vs = decode(tokens, positions, pool, tables, lens,
+                              logit_rows=range(len(tokens)), **ahead)
+        chosen = runner.pull_step(step)
         owners = [eng.cache._owner[int(t[0])] for t in tables]
         for row, (sid, at) in enumerate(zip(owners, positions)):
             got[sid].append((int(at) + 1, chosen.logits[row]))
-        return chosen, ks, vs
+        return step, ks, vs
 
     eng._prefill_one = spy_prefill_one
     runner.prefill, runner.decode = spy_prefill, spy_decode
@@ -432,15 +436,16 @@ def test_bf16_engine_stays_within_the_bf16_tolerance():
 # did so for ``prefill`` and ``decode`` of both families (each returns
 # its rows' greedy ids beside the logits), and PR 35 for ``decode`` and the
 # pool's three writers (the pool's device format, ``device_shape``);
-# ``prefill`` keeps PR 33's.
+# ``prefill`` keeps PR 33's; PR 37 for ``decode`` again (a row's token may
+# be the id the step before chose: ``last_ids`` and ``src``).
 PARENT_LOWERINGS = {
     ("gpt2:tiny", "prefill"): "3286e5649835dc0f",
-    ("gpt2:tiny", "decode"): "2aa90d74078a3595",
+    ("gpt2:tiny", "decode"): "fc24d6ed8fdb6bd0",
     ("gpt2:tiny", "scatter"): "cc9d38f81a332a87",
     ("gpt2:tiny", "write_rows"): "80383ff336f4f9fd",
     ("gpt2:tiny", "load_block"): "d29caf7c6730e16d",
     ("llama:tiny", "prefill"): "b821c7fcd0890e93",
-    ("llama:tiny", "decode"): "35dbeac9779436a4",
+    ("llama:tiny", "decode"): "324976c6f02f00bf",
     ("llama:tiny", "scatter"): "e05bda51b7431fe4",
     ("llama:tiny", "write_rows"): "465b3826c9842b2e",
     ("llama:tiny", "load_block"): "d933f36507a6630f",
@@ -468,7 +473,8 @@ def test_stateless_families_lower_byte_for_byte_as_on_the_parent(model):
     lowered = {
         "prefill": runner._prefill.lower(runner.params, i32(1, 32), i32()),
         "decode": runner._decode.lower(pool, runner.params, i32(4), i32(4),
-                                       i32(4, 8), i32(4), i32()),
+                                       i32(4, 8), i32(4), i32(), i32(4),
+                                       i32(4)),
         "scatter": programs.scatter_prefill.lower(pool, i32(4), kv, kv,
                                                   i32()),
         "write_rows": programs.write_rows.lower(pool, i32(1), i32(1), one,

@@ -23,7 +23,7 @@ LOOP_SPANS = [
     "llm.prefill", "llm.prefill.dispatch", "llm.prefill.pull",
     "llm.prefill.scatter", "llm.decode", "llm.decode.slots",
     "llm.decode.tables", "llm.decode.dispatch", "llm.decode.pull",
-    "llm.decode.commit", "llm.compile", "llm.preempt"]
+    "llm.decode.commit", "llm.decode.drain", "llm.compile", "llm.preempt"]
 ALL_SPANS = LOOP_SPANS + ["llm.submit"]
 ROOT = Path(__file__).resolve().parent.parent
 
@@ -139,13 +139,38 @@ def test_spans_nest_by_time_and_count_the_steps(captured):
         after["prefill_steps"] - before["prefill_steps"]
     assert len(named("llm.preempt")) == \
         after["preemptions"] - before["preemptions"] > 0
-    for part in ("llm.decode.dispatch", "llm.decode.pull",
-                 "llm.decode.slots", "llm.decode.commit"):
+    for part in ("llm.decode.dispatch", "llm.decode.slots"):
         spans = named(part)
         assert len(spans) == len(decodes)
         assert all(within(s, decodes) for s in spans)
-    for part in ("llm.decode", "llm.prefill", "llm.step.admit",
-                 "llm.step.plan", "llm.step.publish"):
+    # every step is pulled and committed once: behind the enqueue of the
+    # next, inside that one's llm.decode, or in a drain (which the slots
+    # of a decode may hold: cache pressure)
+    drains = named("llm.decode.drain")
+    for part in ("llm.decode.pull", "llm.decode.commit"):
+        spans = named(part)
+        assert len(spans) == len(decodes)
+        assert all(within(s, decodes + drains) for s in spans)
+        assert sum(within(s, drains) for s in spans) == len(drains) > 0
+    ahead = sum(int(e[3]["ahead"]) for e in decodes)
+    assert ahead == after["decode_steps_ahead"] - before["decode_steps_ahead"]
+    assert len(decodes) == ahead + len(drains)
+    causes = [e[3]["cause"] for e in drains]
+    assert {c: causes.count(c) for c in before["decode_drains"]} == {
+        c: n - before["decode_drains"][c]
+        for c, n in after["decode_drains"].items()}
+    assert causes.count("pressure") > 0 and causes.count("tail") > 0
+    # a pull names the step it pulls, the decode span the step it enqueued
+    assert sorted(int(e[3]["step"]) for e in named("llm.decode.pull")) == \
+        sorted(int(e[3]["step"]) for e in decodes)
+    for pull in named("llm.decode.pull"):
+        holder = next((d for d in decodes if within(pull, [d])), None)
+        if holder is not None and not any(
+                within(pull, [d]) for d in drains):
+            assert int(pull[3]["step"]) == int(holder[3]["step"]) - 1
+            assert int(holder[3]["ahead"]) == 1
+    for part in ("llm.decode", "llm.decode.drain", "llm.prefill",
+                 "llm.step.admit", "llm.step.plan", "llm.step.publish"):
         assert all(within(s, steps) for s in named(part))
     for part in ("llm.prefill.dispatch", "llm.prefill.pull",
                  "llm.prefill.scatter"):
@@ -249,6 +274,12 @@ def test_totals_count_the_steps_and_children_fit_their_parent(served):
     assert spans["llm.submit"][0] == len(served[0])
     for part in ("dispatch", "pull", "slots", "commit"):
         assert spans[f"llm.decode.{part}"][0] == stats["decode_steps"]
+    assert stats["decode_steps"] == stats["decode_steps_ahead"] \
+        + spans["llm.decode.drain"][0]
+    assert spans["llm.decode.drain"][0] == \
+        sum(stats["decode_drains"].values())
+    assert stats["decode_steps_ahead"] > 0
+    assert stats["decode_rows_discarded"] == 0
     assert stats["decode_steps"] <= spans["llm.decode.tables"][0] \
         <= 2 * stats["decode_steps"]
     for part in ("admit", "plan", "publish"):

@@ -525,7 +525,7 @@ def test_pool_programs_alias_the_pool_to_their_result():
     lowered = {
         "decode": runner._decode.lower(
             pool, runner.params, i32(4), i32(4),
-            i32(4, cfg.max_blocks_per_seq), i32(4), i32()),
+            i32(4, cfg.max_blocks_per_seq), i32(4), i32(), i32(4), i32(4)),
         "scatter": kvmod._programs().scatter_prefill.lower(
             pool, i32(4), kv, kv, i32()),
     }
@@ -623,8 +623,55 @@ def test_the_benchmark_harness_calls_keep_working():
         np.testing.assert_array_equal(cache.blocks(), stepped)
         assert eng.stats()["kv_host_bytes"] > 0   # the harness's numpy K/V
         assert seq[-1] == oracle_decode(eng, prompt, 1)[0]
+        cache.free_seq("chk")
+        _the_harness_labels_every_iteration(eng)
     finally:
         eng.shutdown()
+
+
+def _the_harness_labels_every_iteration(eng):
+    """``Served._instrument`` and ``_wait_idle``: ``eng.step`` and
+    ``runner.decode`` are wrapped, an iteration is a decode step if
+    ``decode_steps`` rose over it (and then it called ``runner.decode``
+    once: a ``pb.step`` is a ``pb.decode`` only if it holds a
+    ``pb.decode.run``), and after the traffic ``running`` and ``waiting``
+    reach 0.  An iteration that only reads the step in flight is neither
+    a decode nor a prefill, and still says there was work."""
+    runner, log, calls = eng.runner, [], []
+    step, decode = eng.step, runner.decode
+
+    def timed_step():
+        before, n = eng.stats(), len(calls)
+        ran = step()
+        after = eng.stats()
+        log.append((ran, after["decode_steps"] - before["decode_steps"],
+                    after["prefill_steps"] - before["prefill_steps"],
+                    len(calls) - n, sum(after["decode_drains"].values())
+                    - sum(before["decode_drains"].values())))
+        return ran
+
+    def spanned(*args, **kwargs):
+        calls.append(len(args[0]))
+        return decode(*args, **kwargs)
+
+    eng.step, runner.decode = timed_step, spanned
+    jobs = [(PROMPTS[0], 9), (PROMPTS[1], 5)]
+    streams = [eng.submit(p, SamplingParams(max_tokens=n)) for p, n in jobs]
+    while eng.step():
+        pass
+    stats = eng.stats()
+    assert stats["running"] == 0 and stats["waiting"] == 0
+    assert [len(s.tokens()) for s in streams] == [9, 5]
+    assert all(ran for ran, *_ in log[:-1]) and not log[-1][0]
+    for _, decodes, prefills, called, drained in log:
+        assert (decodes, prefills) in ((1, 0), (0, 1), (0, 0))
+        assert called == decodes
+    # no row-step is spent on a sequence known to end by length
+    assert calls == [2] * 4 + [1] * 4
+    only_drained = [e for e in log[:-1] if e[1:4] == (0, 0, 0)]
+    assert [e[4] for e in only_drained] == [1]    # the tail, and it ran
+    assert stats["decode_steps_ahead"] == 7 and \
+        stats["decode_drains"]["tail"] == 1
 
 
 # ------------------------------------ the weights are cast once (ISSUE 31)
@@ -689,7 +736,7 @@ def test_step_programs_convert_no_weight(model):
     for tree, n_cast in ((runner.params, 0), (stored, len(flat) - wide)):
         decode = jax.make_jaxpr(runner._decode)(
             pool, tree, i32(4), i32(4), i32(4, cfg.max_blocks_per_seq),
-            i32(4), i32())
+            i32(4), i32(), i32(4), i32(4))
         prefill = jax.make_jaxpr(runner._prefill)(tree, i32(1, 32), i32())
         for jaxpr in (decode, prefill):
             got = _weight_converts(jaxpr.jaxpr, shapes)
@@ -928,11 +975,19 @@ def _chosen_on_the_host(eng):
         logits, ks, vs = prefill(token_ids)
         return on_host(logits), ks, vs
 
-    def host_decode(*args, logit_rows):
-        logits, ks, vs = decode(*args)
-        return on_host(logits), ks, vs
+    # the loop enqueues a step and pulls it later: every row's logits
+    # are named at the enqueue, and sampled here at the pull
+    def host_decode(*args, logit_rows, **ahead):
+        return decode(*args, logit_rows=range(len(args[0])), **ahead)
 
+    def host_pull(step):
+        chosen = pull(step)
+        return on_host(np.stack([chosen.logits[i]
+                                 for i in range(len(chosen.ids))]))
+
+    pull = runner.pull_step
     runner.prefill, runner.decode = host_prefill, host_decode
+    runner.pull_step = host_pull
 
 
 def _scripted(cfg, requests, on_host=False):
@@ -1013,6 +1068,257 @@ def test_prefill_remote_takes_its_first_token_by_the_same_rule():
         stats = eng.stats()
         assert (stats["sampled_on_device"], stats["sampled_on_host"],
                 stats["logits_host_bytes"]) == (1, 1, logits.nbytes)
+    finally:
+        eng.shutdown()
+
+
+# --------------- the loop keeps one decode step in flight (ISSUE 37)
+class _Solo:
+    """The oracle: one request alone through the runner's default calls,
+    in the order the loop had before it kept a step in flight (each step
+    pulled before the next is built, its token chosen here from the
+    pulled logits and handed back as ``tokens``), on an engine of its
+    own whose pool is never short."""
+
+    def __init__(self, cfg, params=None):
+        import dataclasses
+        self.eng = LLMEngine(dataclasses.replace(cfg, num_blocks=64),
+                             params=params, start=False)
+
+    def tokens(self, prompt, sp):
+        from ray_tpu.serve.llm.model_runner import ModelRunner
+        runner, cache = self.eng.runner, self.eng.cache
+        cache.alloc_seq("solo", len(prompt))
+        try:
+            logits, ks, vs = runner.prefill(prompt)
+            cache.scatter_prefill("solo", ks, vs, len(prompt))
+            out = [ModelRunner.sample(logits, sp, 0)]
+            tables = np.zeros((1, self.eng.cfg.max_blocks_per_seq), np.int32)
+            while len(out) < sp.max_tokens and out[-1] != sp.stop_token:
+                cache.append_slot("solo")
+                table = cache.table("solo")
+                tables[0, :len(table)] = table
+                at = np.asarray([len(prompt) + len(out) - 1], np.int32)
+                logits, _, _ = runner.decode(
+                    np.asarray([out[-1]], np.int32), at, cache.pool, tables,
+                    at)
+                out.append(ModelRunner.sample(logits[0], sp, len(out)))
+            return out
+        finally:
+            cache.free_seq("solo")
+
+
+def _telling_weights(monkeypatch, cfg):
+    """Weights under which a wrong token, position or row shows in the
+    tokens (None: the engine's own).  GPT-2's tiny preset repeats its
+    prompt's last token for ever (tied embeddings at random weights), so
+    its position embedding is made ten times louder: the next token then
+    follows from where the sequence is.  Falcon-H1's runs in float32: a
+    batch of 4 and a batch of 1 are different programs, and the oracle
+    compares tokens, not logits."""
+    import jax
+    mod, mcfg = resolve_model(cfg)
+    if cfg.model.startswith("falcon_h1"):
+        import dataclasses
+
+        import jax.numpy as jnp
+        wide = dataclasses.replace(mcfg, dtype=jnp.float32)
+        monkeypatch.setitem(mod.PRESETS, "tiny", lambda: wide)
+    if not cfg.model.startswith("gpt2"):
+        return None
+    params = mod.init_params(jax.random.key(cfg.seed), mcfg)
+    return {**params, "wpe": params["wpe"] * 10}
+
+
+def _drive(eng, requests, late=(), cancel=None):
+    """``requests`` submitted together and stepped by hand to the end;
+    ``late``: (iteration, prompt, sampling) submitted after that
+    iteration; ``cancel``: (iteration, index of the stream).  Returns the
+    streams and, per iteration, each live sequence's block table and row
+    of recurrent state."""
+    streams = [eng.submit(p, sp) for p, sp in requests]
+    held, it = [], 0
+    while eng.step():
+        it += 1
+        cache = eng.cache
+        held.append({sid: (cache.table(sid), cache.state_row(sid)
+                           if cache.state_rows else None)
+                     for sid in cache.seq_ids()})
+        for at, prompt, sp in late:
+            if at == it:
+                streams.append(eng.submit(prompt, sp))
+        if cancel is not None and cancel[0] == it:
+            streams[cancel[1]].cancel()
+    return streams, held
+
+
+SMALL_POOL = dict(num_blocks=6, block_size=4, max_model_len=32,
+                  max_prefill_tokens=16, prefill_len_buckets=(16, 32))
+TWO_SLOTS = dict(max_num_seqs=2, decode_batch_buckets=(1, 2))
+
+
+@pytest.mark.parametrize("traffic", ["lengths", "join", "stop", "cancel",
+                                     "preempt"])
+@pytest.mark.parametrize("model", FAMILIES)
+def test_a_step_in_flight_changes_no_token(model, traffic, monkeypatch):
+    """The loop enqueues step n+1 before it reads step n: every request
+    gets its solo run's tokens, whatever ends, joins, stops, is cancelled
+    or preempted on the way; the step in flight is drained where it has
+    to be, by the cause counted; and blocks and state rows that a commit freed serve
+    the next prefill while the discarded row of the step in flight was
+    still to write them."""
+    cfg = tiny_cfg(model=model, **{"preempt": SMALL_POOL,
+                                   "stop": TWO_SLOTS}.get(traffic, {}))
+    params = _telling_weights(monkeypatch, cfg)
+    oracle = _Solo(cfg, params)
+    eng = LLMEngine(cfg, params=params, start=False)
+    try:
+        greedy = lambda n, **kw: SamplingParams(max_tokens=n, **kw)  # noqa: E731
+        late, cancel = (), None
+        if traffic == "lengths":
+            requests = [(PROMPTS[0], greedy(3)), (PROMPTS[1], greedy(7)),
+                        (PROMPTS[2], greedy(12))]
+        elif traffic == "join":
+            requests = [(PROMPTS[0], greedy(10)), (PROMPTS[1], greedy(12))]
+            late = [(5, PROMPTS[2], greedy(6))]
+        elif traffic == "stop":
+            # the last token of the solo run that no earlier one equals,
+            # a decode step's: the stop hits at that step's commit, with
+            # the next step, and the sequence's row in it, enqueued
+            solo = oracle.tokens(PROMPTS[0], greedy(20))
+            k = max(i for i in range(1, 19) if solo[i] not in solo[:i])
+            requests = [(PROMPTS[0], greedy(20, stop_token=solo[k])),
+                        (PROMPTS[1], greedy(30)), (PROMPTS[2], greedy(6))]
+        elif traffic == "cancel":
+            requests = [(PROMPTS[0], greedy(10)), (PROMPTS[1], greedy(30)),
+                        (PROMPTS[2], greedy(12))]
+            cancel = (7, 1)
+        else:
+            requests = [([1 + i, 2, 3], greedy(12)) for i in range(3)]
+        streams, held = _drive(eng, requests, late, cancel)
+        stats = eng.stats()
+        want = [oracle.tokens(p, sp) for p, sp in list(requests)
+                + [(p, sp) for _, p, sp in late]]
+        for i, (stream, tokens) in enumerate(zip(streams, want)):
+            if cancel is not None and i == cancel[1]:
+                got, done = stream.poll(max_items=64, timeout=0)
+                assert not done and 0 < len(got) < len(tokens)
+                assert got == tokens[:len(got)]
+            else:
+                assert stream.tokens() == tokens, i
+        # it engaged, every step was read once, and nothing is held
+        drains = stats["decode_drains"]
+        assert stats["decode_steps_ahead"] > 0
+        assert stats["decode_steps"] == \
+            stats["decode_steps_ahead"] + sum(drains.values())
+        assert stats["running"] == stats["waiting"] == 0
+        assert eng.cache.used_block_count() == 0
+        assert stats["state_rows_used"] == 0
+        assert stats["sampled_on_device"] == stats["tokens_out"]
+        assert stats["decode_rows_discarded"] == (traffic == "stop")
+        assert drains["sampled"] == 0
+        assert (stats["preemptions"] > 0) == (traffic == "preempt")
+        if traffic == "lengths":
+            # no row-step for a sequence known to end by length, and the
+            # one drain is the last step's
+            assert drains == dict(sampled=0, pressure=0, admit=0, tail=1)
+            assert stats["decode_steps"] == 11
+        elif traffic in ("join", "cancel"):
+            assert drains["admit"] >= 1 and drains["pressure"] == 0
+        elif traffic == "preempt":
+            assert drains["pressure"] >= 1
+        else:
+            assert stream_reason(streams[0]) == "stop"
+            assert want[0][-1] == requests[0][1].stop_token
+            assert len(want[0]) == k + 1 < 20 and drains["admit"] >= 1
+            # the third request waited for a slot: it was given the
+            # stopped sequence's blocks and row, which the step in flight
+            # at the stop still wrote, and reads what its own prefill and
+            # steps wrote (its tokens are its solo run's, above)
+            stopped, third = streams[0].seq_id, streams[2].seq_id
+            last = [h[stopped] for h in held if stopped in h][-1]
+            first = next(h[third] for h in held if third in h)
+            assert set(last[0]) & set(first[0]) and first[1] == last[1]
+    finally:
+        eng.shutdown()
+        oracle.eng.shutdown()
+
+
+def stream_reason(stream):
+    stream.poll(timeout=0)
+    return stream.finish_reason
+
+
+@pytest.mark.parametrize("model", FAMILIES)
+def test_a_sampled_row_keeps_the_loop_in_step(model, monkeypatch):
+    """A seeded temperature / top-k row beside a greedy one: its token is
+    drawn on the host from the step's pulled logits, so while it runs
+    every step is read before the next is built (no step is enqueued
+    behind another) and it gets its seed's tokens; when it has ended, the
+    greedy row goes on with a step in flight."""
+    cfg = tiny_cfg(model=model)
+    params = _telling_weights(monkeypatch, cfg)
+    oracle = _Solo(cfg, params)
+    eng = LLMEngine(cfg, params=params, start=False)
+    try:
+        requests = [(PROMPTS[0], SamplingParams(max_tokens=24)),
+                    (PROMPTS[1], SAMPLED)]
+        streams = [eng.submit(p, sp) for p, sp in requests]
+        for _ in range(2 + SAMPLED.max_tokens - 1):   # two prefills first
+            assert eng.step()
+            assert eng.stats()["decode_steps_ahead"] == 0
+        mid = eng.stats()
+        assert mid["decode_drains"]["sampled"] == mid["decode_steps"] == \
+            SAMPLED.max_tokens - 1
+        assert [s.sampling.greedy for s in eng.sched.running] == [True]
+        while eng.step():
+            pass
+        stats = eng.stats()
+        assert [s.tokens() for s in streams] == \
+            [oracle.tokens(p, sp) for p, sp in requests]
+        assert stats["decode_steps_ahead"] == 24 - SAMPLED.max_tokens - 1
+        assert stats["decode_drains"] == dict(
+            sampled=SAMPLED.max_tokens - 1, pressure=0, admit=0, tail=1)
+        assert stats["sampled_on_host"] == SAMPLED.max_tokens
+    finally:
+        eng.shutdown()
+        oracle.eng.shutdown()
+
+
+@pytest.mark.parametrize("placed", ["by_default", "committed"])
+@pytest.mark.parametrize("model", FAMILIES)
+def test_a_step_behind_another_runs_the_warmed_executable(model, placed):
+    """The harness warms each bucket through the default call alone, with
+    numpy arguments: the first step enqueued behind another (the last
+    step's ids a device array, a real row map) builds nothing and adds no
+    entry to the jitted step's cache, whether the weights were placed by
+    default (the harness's) or committed to a device."""
+    import jax
+    cfg = tiny_cfg(model=model)
+    mod, mcfg = resolve_model(cfg)
+    params = mod.init_params(jax.random.key(cfg.seed), mcfg)
+    if placed == "committed":
+        params = jax.device_put(params, jax.devices()[0])
+    eng = LLMEngine(cfg, params=params, start=False)
+    try:
+        runner, cache = eng.runner, eng.cache
+        maxb = cfg.max_blocks_per_seq
+        for prompt in PROMPTS:
+            runner.prefill([0] * len(prompt))
+        for b in cfg.decode_batch_buckets:            # _warm_programs
+            runner.decode(np.zeros(b, np.int32), np.zeros(b, np.int32),
+                          cache.pool, np.zeros((b, maxb), np.int32),
+                          np.ones(b, np.int32))
+        built = (runner.compiles, runner._decode._cache_size())
+        streams = [eng.submit(p, SamplingParams(max_tokens=n))
+                   for p, n in zip(PROMPTS, (9, 4, 6))]
+        while eng.step():
+            pass
+        assert [len(s.tokens()) for s in streams] == [9, 4, 6]
+        stats = eng.stats()
+        assert stats["decode_steps_ahead"] > 0
+        assert stats["span_s"]["llm.compile"][0] == stats["compiles"]
+        assert (runner.compiles, runner._decode._cache_size()) == built
     finally:
         eng.shutdown()
 
