@@ -12,11 +12,22 @@ positions become theirs and the expert width shrinks with the hidden size,
 rehearsal checks the loss on 2 x 32 tokens, too few for bf16 routing flips
 to average out, and a rehearsal is control flow; the chip decides `correct`
 at the published widths in the precision the configuration states.
+
+Serving.  The family routes, and says so with ``routed(config_file)``: the
+serving job (``jobs/serve.py``) then asks the program's runner for the
+experts it chose, hands them to ``reference_logits(..., choices=ids)`` and
+holds the audit to the configuration's ``serve.route_margin`` and
+``serve.route_differing_share`` (``perfbench/README.md``, "A routed
+family").  A rehearsal's serving engine is the toy GPT-2 (GPT-2's names
+present), which does not route: ``check_sizes``, ``routed`` and
+``reference_logits`` hand such a configuration to ``families/gpt2.py``, as
+Falcon-H1's family does.
 """
 
 from __future__ import annotations
 
 from perfbench import flops_olmoe
+from perfbench.families import gpt2
 from perfbench.reference import olmoe_ref
 
 SIZE_KEYS = ("vocab_size", "max_position_embeddings", "hidden_size",
@@ -27,6 +38,14 @@ SETTING_KEYS = ("rms_norm_eps", "rope_theta", "router_aux_loss_coef",
 GPT2_NAMES = {"n_embd": "hidden_size", "n_layer": "num_hidden_layers",
               "n_head": "num_attention_heads",
               "n_positions": "max_position_embeddings"}
+# config.json key -> the attribute of the program's LlamaConfig
+ATTRS = {"vocab_size": "vocab_size",
+         "max_position_embeddings": "max_positions", "hidden_size": "n_embd",
+         "intermediate_size": "ffn_dim",
+         "num_hidden_layers": "n_layer", "num_attention_heads": "n_head",
+         "num_key_value_heads": "n_kv_head", "num_experts": "n_experts",
+         "num_experts_per_tok": "experts_per_token",
+         "rms_norm_eps": "rms_eps", "rope_theta": "rope_theta"}
 
 
 def module():
@@ -87,5 +106,35 @@ def reference_loss(params, inputs, targets, config_file: dict):
     return olmoe_ref.loss(params, inputs, targets, sizes(config_file))
 
 
-def reference_logits(params, tokens, config_file: dict):
-    return olmoe_ref.logits(params, tokens, sizes(config_file))
+def check_sizes(config_file: dict, model_cfg) -> None:
+    """The program's serving preset must have the file's sizes and the
+    OLMoE block, or the cell is not the configuration it says it is."""
+    if shrunk(config_file):
+        return gpt2.check_sizes(config_file, model_cfg)
+    want = sizes(config_file)
+    got = {k: getattr(model_cfg, attr) for k, attr in ATTRS.items()}
+    differ = {k: (got[k], want[k]) for k in ATTRS if got[k] != want[k]}
+    if differ or not model_cfg.qk_norm:
+        raise ValueError("the program's model and the configuration file "
+                         f"differ in (program, file): {differ}"
+                         + ("" if model_cfg.qk_norm else "; no QK-norm"))
+
+
+def routed(config_file: dict):
+    """What the serving check has to be handed by the program: the chosen
+    expert ids of every routed layer, int (layers, rows, k), each below
+    ``experts``.  None: the configuration does not route (a rehearsal)."""
+    if shrunk(config_file):
+        return None
+    return {"layers": config_file["num_hidden_layers"],
+            "k": config_file["num_experts_per_tok"],
+            "experts": config_file["num_experts"]}
+
+
+def reference_logits(params, tokens, config_file: dict, choices=None):
+    """Float32 logits (B, T, V); under the program's ``choices`` (layers,
+    B x T, k) -> (logits, audit): ``olmoe_ref.logits``."""
+    if shrunk(config_file):
+        return gpt2.reference_logits(params, tokens, config_file)
+    return olmoe_ref.logits(params, tokens, sizes(config_file),
+                            choices=choices)

@@ -23,7 +23,20 @@ from perfbench import manifest, stats, trace, traffic
 # reference is float32, so equal mathematics agrees to some hundredths, more
 # with more layers: the tolerance is the configuration's own
 # (``serve.logit_atol`` in its file, with the reason beside it).
+#
+# A family that routes (``families/<f>.routed(config)`` is not None).  Where
+# the float32 reference scores two experts a rounding apart the program
+# decides either way, both are correct executions, and one expert taken the
+# other way moves single logits by more than any tolerance that still
+# catches a fault.  So the program says what it chose: its runner has
+# ``route_spec`` ({"layers", "k"}, as ``state_spec`` describes recurrent
+# state) and, after each ``prefill`` / ``decode``, ``choices``: the chosen
+# expert ids of that step, int (routed layers, rows of the bucket, k), from
+# the run that made the logits.  The reference is computed UNDER that choice
+# and audits it in its own scores; the two limits of the audit are the
+# configuration's too, and a routed configuration without them is refused.
 POLL_S = 0.002
+ROUTE_LIMITS = ("route_margin", "route_differing_share")
 
 
 class _Rec:
@@ -58,6 +71,16 @@ class Served:
                                      **kwargs)
         mod, mcfg = resolve_model(self.ecfg)
         self.fam.check_sizes(self.config, mcfg)
+        describe = getattr(self.fam, "routed", None)
+        self.routed = describe(self.config) if describe else None
+        for key in ROUTE_LIMITS if self.routed else ():
+            if key not in self.config["serve"] \
+                    or f"why_{key}" not in self.config["serve"]:
+                raise ValueError(
+                    f"the configuration routes ({self.routed}) and its file "
+                    f"has no serve.{key} with a why_{key} beside it: the "
+                    "serving check holds a routed model to limits measured "
+                    "for it, never to a default (perfbench/README.md)")
         # the weights in one jitted call on the device, in the type served
         self.params = jax.jit(lambda key: mod.init_params(key, mcfg))(
             jax.random.key(self.ecfg.seed))
@@ -67,6 +90,15 @@ class Served:
         self.llm = llm
         self.eng = llm.LLMEngine(self.ecfg, params=self.params, start=False)
         marks["engine_s"] = time.perf_counter() - t_start
+        offered = getattr(self.eng.runner, "route_spec", None)
+        if self.routed and (not offered or any(
+                offered[key] != self.routed[key] for key in ("layers", "k"))):
+            self.eng.shutdown()
+            raise RuntimeError(
+                f"the configuration routes ({self.routed}) and the program's "
+                f"runner offers {offered!r} as its route_spec: without the "
+                "experts the program chose there is no reference for its "
+                "logits (perfbench/README.md, a routed family)")
         self.steps = []         # (t0, t1, kind, running, waiting, preempted)
         self._instrument()
         self._warm_programs()
@@ -286,10 +318,29 @@ class Served:
         }
 
     # -------------------------------------------------------- correctness
+    def _choices(self, rows: int) -> np.ndarray:
+        """The expert ids the step just run chose, for its first ``rows``
+        rows: (routed layers, rows, k), as the runner holds them."""
+        want = self.routed
+        ids = np.asarray(self.eng.runner.choices)
+        if ids.ndim != 3 or ids.shape[0] != want["layers"] \
+                or ids.shape[1] < rows or ids.shape[2] != want["k"] \
+                or ids.dtype.kind not in "iu":
+            raise ValueError(f"the runner's choices are {ids.dtype}"
+                             f"{list(ids.shape)}: not {rows} rows or more of "
+                             f"{want}")
+        ids = ids[:, :rows].astype(np.int32)
+        if ids.min() < 0 or ids.max() >= want["experts"]:
+            raise ValueError("the runner's choices name an expert outside "
+                             f"0..{want['experts'] - 1}")
+        return ids
+
     def check_logits(self, seed: int) -> dict:
         """Outside the window: one prompt through prefill, then decode
         steps through the paged cache as the engine's loop makes them,
-        against the plain reference's full forward."""
+        against the plain reference's full forward; for a family that
+        routes, the reference under the experts those steps chose, and the
+        choice against the reference's own scores."""
         eng, spec = self.eng, self.spec
         runner, cache = eng.runner, eng.cache
         n, k = spec["check_prompt_tokens"], spec["check_decode_steps"]
@@ -299,6 +350,7 @@ class Served:
         cache.alloc_seq(sid, n)
         try:
             logits, ks, vs = runner.prefill(prompt)
+            chose = [self._choices(n)] if self.routed else []
             cache.scatter_prefill(sid, np.asarray(ks, np.float32),
                                   np.asarray(vs, np.float32), n)
             got, seq = [logits], list(prompt)
@@ -313,19 +365,37 @@ class Served:
                 lg, ks, vs = runner.decode(
                     np.asarray([seq[-1]], np.int32), at, cache.pool,
                     tables, at)
+                if self.routed:
+                    chose.append(self._choices(1))
                 cache.write_token(blk, off, np.asarray(ks[:, 0], np.float32),
                                   np.asarray(vs[:, 0], np.float32))
                 got.append(lg[0])
         finally:
             cache.free_seq(sid)
-        ref = np.asarray(self.fam.reference_logits(
-            self.params, [seq], self.config))[0]
+        limits, audit = self.config["serve"], None
+        if self.routed:
+            # the ids of every position of seq, (routed layers, n + k, K)
+            ref, audit = self.fam.reference_logits(
+                self.params, [seq], self.config,
+                choices=np.concatenate(chose, axis=1))
+        else:
+            ref = self.fam.reference_logits(self.params, [seq], self.config)
+        ref = np.asarray(ref)[0]
         diffs = [float(np.abs(g - ref[n - 1 + i]).max())
                  for i, g in enumerate(got)]
-        atol = self.config["serve"]["logit_atol"]
-        return {"prefill_logit_diff": diffs[0],
-                "decode_logit_diff": max(diffs[1:]),
-                "logit_atol": atol, "ok": max(diffs) <= atol}
+        out = {"prefill_logit_diff": diffs[0],
+               "decode_logit_diff": max(diffs[1:]),
+               "logit_atol": limits["logit_atol"]}
+        if audit:
+            out.update(
+                route_decisions=audit["decisions"],
+                route_differing=audit["differing"],
+                route_worst_margin=audit["worst_margin"],
+                route_margin=limits["route_margin"],
+                route_differing_share=limits["route_differing_share"])
+        out["ok"] = all(value <= limit
+                        for value, limit in _compared(out).values())
+        return out
 
     def close(self) -> None:
         self.eng.shutdown()
@@ -337,6 +407,21 @@ def _bucket(n: int, buckets) -> int:
 
 def _mean(xs):
     return stats.mean(xs) if xs else None
+
+
+def _compared(check: dict) -> dict:
+    """Each number of ``check_logits`` that decides its verdict, beside its
+    limit: name -> [value, limit]."""
+    atol = check["logit_atol"]
+    out = {"prefill_logit_diff": [check["prefill_logit_diff"], atol],
+           "decode_logit_diff": [check["decode_logit_diff"], atol]}
+    if "route_decisions" in check:
+        out["route_worst_margin"] = [check["route_worst_margin"],
+                                     check["route_margin"]]
+        out["route_differing"] = [
+            check["route_differing"],
+            check["route_differing_share"] * check["route_decisions"]]
+    return out
 
 
 def run(ctx: dict) -> dict:
@@ -353,5 +438,7 @@ def run(ctx: dict) -> dict:
     facts["setup_s"] = facts.pop("t_open") - ctx["t_start"]
     facts["correct"] = all(checks.values())
     facts["checks"] = checks
+    facts["compared"] = {"wrong_length": [facts["wrong_length"], 0],
+                         **_compared(check)}
     facts["notes"].update(check)
     return facts

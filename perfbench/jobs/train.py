@@ -136,6 +136,7 @@ def run(ctx: dict) -> dict:
         "peak_flops_per_s": peak,
         "attempted": steps, "failed": 0 if finite else steps,
         "correct": all(checks.values()), "checks": checks,
+        "compared": {"loss_abs_diff": [loss_diff, LOSS_ATOL]},
         "notes": {"first_losses": losses[:k], "last_losses": losses[-k:],
                   "program_loss": got, "reference_loss": want,
                   "loss_abs_diff": loss_diff, "loss_atol": LOSS_ATOL},

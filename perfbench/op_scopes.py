@@ -62,7 +62,7 @@ def of_run(facts: dict) -> Optional[dict]:
     share of it the maps name, the largest operations they do not)."""
     if not facts.get("trace"):
         return None
-    path = program_trace.newest_capture()
+    path = program_trace.capture_of(facts)
     if path is None:
         return None
     if path not in _LOADED:

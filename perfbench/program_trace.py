@@ -40,9 +40,19 @@ _LOADED: Dict[str, dict] = {}        # capture path -> program trace
 
 # ------------------------------------------------------------------ loading
 def newest_capture(scratch=SCRATCH) -> Optional[str]:
-    files = glob.glob(os.path.join(str(scratch), "trace-*", "plugins",
-                                   "profile", "*", "*.xplane.pb"))
+    """The newest capture file under one run's trace directory, or under
+    the scratch directory that holds every run's."""
+    tail = ("plugins", "profile", "*", "*.xplane.pb")
+    files = glob.glob(os.path.join(str(scratch), *tail)) \
+        or glob.glob(os.path.join(str(scratch), "trace-*", *tail))
     return max(files, key=os.path.getmtime) if files else None
+
+
+def capture_of(facts: dict) -> Optional[str]:
+    """This run's capture file: under the directory its trace names."""
+    traced = facts["trace"]
+    where = traced.get("capture_dir") if isinstance(traced, dict) else None
+    return newest_capture(where or SCRATCH)
 
 
 def of_run(facts: dict) -> Optional[dict]:
@@ -51,7 +61,7 @@ def of_run(facts: dict) -> Optional[dict]:
     not traced or the capture is gone."""
     if not facts.get("trace"):
         return None
-    path = newest_capture()
+    path = capture_of(facts)
     if path is None:
         return None
     if path not in _LOADED:

@@ -38,6 +38,21 @@ second full copy, and attention runs one sequence at a time.  Every entry
 point sets ``jax.default_matmul_precision("highest")``: on a TPU a float32
 matmul runs in lower precision without it.
 
+Under a program's choice of experts (``logits(..., choices=ids)``: the
+serving check, ``perfbench/jobs/serve.py``).  Where the reference scores two
+experts a rounding apart, a program in bfloat16 decides between them either
+way and both are correct executions, so a forward under the reference's OWN
+top-k is no reference for that program's logits.  With ``choices`` (routed
+layers, tokens, k) every layer still computes its own p and its own top-k
+set R, meets the program's set P, and goes on UNDER P: the experts of P
+weighed by the reference's p of them as they are (the family's rule:
+``norm_topk_prob`` false), so that what follows a decision made the other
+way stays comparable.  The choice itself is audited in the reference's own
+scores: a decision's margin is ``p_(k) - min over e in P of p_e``, how far
+under the reference's own cut the program's lowest pick lies (0 where P is
+R), and ``audit`` counts the ``decisions`` (routed layers x tokens), those
+``differing`` (P is not R as a set) and holds the ``worst_margin``.
+
 ``qk_norm`` and ``rope`` select deliberately wrong conventions
 (``"head"``: the norm over each head's 128 features; ``"interleaved"``:
 pairs (2i, 2i + 1)); the tests use them to show that either mistake in
@@ -121,6 +136,23 @@ def _route(h, mlp_scale, w_router, *, k, eps):
     return z, p * chosen, balance, z_loss
 
 
+@partial(jax.jit, static_argnames=("k", "eps"))
+def _route_under(h, mlp_scale, w_router, chosen_ids, *, k, eps):
+    """h (N, E), the program's choice (N, K) -> z, gates (N, X): the
+    reference's own p on the chosen experts and zero elsewhere; and per
+    token whether the chosen set differs from the reference's own top-k,
+    and the margin p_(k) - min p[chosen]."""
+    num_experts = w_router.shape[-1]
+    z = _rms_norm(h, mlp_scale, eps)
+    p = jax.nn.softmax(z @ w_router, axis=-1)
+    own_p, own = jax.lax.top_k(p, k)
+    taken = jax.nn.one_hot(chosen_ids, num_experts, dtype=jnp.float32).sum(1)
+    own_set = jax.nn.one_hot(own, num_experts, dtype=jnp.float32).sum(1)
+    differs = jnp.any(taken != own_set, axis=-1)
+    margin = own_p[:, -1] - jnp.take_along_axis(p, chosen_ids, -1).min(-1)
+    return z, p * taken, differs, margin
+
+
 @jax.jit
 def _expert_block(z, gates, w_gate, w_up, w_down):
     """Every expert of the block on every token, weighted by its gate."""
@@ -129,11 +161,15 @@ def _expert_block(z, gates, w_gate, w_up, w_down):
     return jnp.einsum("nxf,xfd,nx->nd", hidden, w_down, gates)
 
 
-def _moe(h, lp, *, k, eps):
-    """h (N, E) float32 -> (h + experts, L_balance, L_z) of one layer."""
-    z, gates, balance, z_loss = _route(
-        h, _f32(lp["mlp_norm"]["scale"]), _f32(lp["router"]["kernel"]),
-        k=k, eps=eps)
+def _moe(h, lp, *, k, eps, chosen=None):
+    """h (N, E) float32 -> (h + experts, L_balance, L_z) of one layer; under
+    a program's choice ``chosen`` (N, K) -> (h + experts, differs (N,),
+    margin (N,))."""
+    norm, router = _f32(lp["mlp_norm"]["scale"]), _f32(lp["router"]["kernel"])
+    if chosen is None:
+        z, gates, *terms = _route(h, norm, router, k=k, eps=eps)
+    else:
+        z, gates, *terms = _route_under(h, norm, router, chosen, k=k, eps=eps)
     ex = lp["experts"]
     y = h
     for at in range(0, gates.shape[-1], EXPERT_BLOCK):
@@ -141,15 +177,20 @@ def _moe(h, lp, *, k, eps):
         y = y + _expert_block(z, gates[:, block], _f32(ex["w_gate"][block]),
                               _f32(ex["w_up"][block]),
                               _f32(ex["w_down"][block]))
-    return y, balance, z_loss
+    return (y, *terms)
 
 
 def hidden(params, tokens, settings: dict, *, qk_norm="projection",
-           rope="half"):
+           rope="half", choices=None):
     """tokens (B, T) -> (final-norm states (B, T, E), L_balance, L_z), the
     two router terms averaged over the layers.  ``settings`` holds the
     config.json keys num_attention_heads, num_key_value_heads,
-    num_experts_per_tok, rms_norm_eps and rope_theta."""
+    num_experts_per_tok, rms_norm_eps and rope_theta.
+
+    Under ``choices`` (layers, B x T, K), a program's chosen expert ids for
+    every token in the tokens' row-major order -> (states, differs, margin):
+    per layer and token whether the choice is the reference's own set, and
+    its margin (``_route_under``), each (layers, B x T)."""
     eps, k = float(settings["rms_norm_eps"]), settings["num_experts_per_tok"]
     attn = partial(_attention, n_head=settings["num_attention_heads"],
                    n_kv_head=settings["num_key_value_heads"], eps=eps,
@@ -161,23 +202,42 @@ def hidden(params, tokens, settings: dict, *, qk_norm="projection",
     blocks = params["blocks"]
     n_layer = blocks["attn_norm"]["scale"].shape[0]
     attn_keys = ("attn_norm", "wq", "wk", "wv", "wo", "q_norm", "k_norm")
-    balance = z_loss = 0.0
+    if choices is not None:
+        choices = jnp.asarray(choices, jnp.int32)
+        if choices.shape[:2] != (n_layer, b * t):
+            raise ValueError(f"choices of shape {choices.shape} for "
+                             f"{n_layer} routed layers and {b * t} tokens")
+    first, second = [], []      # L_balance and L_z, or differs and margin
     for layer in range(n_layer):
         lp = jax.tree_util.tree_map(lambda a: a[layer], blocks)
         alp = jax.tree_util.tree_map(_f32, {w: lp[w] for w in attn_keys})
         x = jnp.stack([attn(x[i], alp) for i in range(b)])
-        y, bal, zl = _moe(x.reshape(b * t, -1), lp, k=k, eps=eps)
+        y, one, two = _moe(x.reshape(b * t, -1), lp, k=k, eps=eps,
+                           chosen=None if choices is None else choices[layer])
         x = y.reshape(b, t, -1)
-        balance, z_loss = balance + bal / n_layer, z_loss + zl / n_layer
-    return (_rms_norm(x, _f32(params["norm_f"]["scale"]), eps),
-            balance, z_loss)
+        first.append(one)
+        second.append(two)
+    x = _rms_norm(x, _f32(params["norm_f"]["scale"]), eps)
+    if choices is not None:
+        return x, jnp.stack(first), jnp.stack(second)
+    return (x, sum(v / n_layer for v in first),
+            sum(v / n_layer for v in second))
 
 
-def logits(params, tokens, settings: dict, **variant):
-    """tokens (B, T) int -> logits (B, T, V) float32."""
+def logits(params, tokens, settings: dict, choices=None, **variant):
+    """tokens (B, T) int -> logits (B, T, V) float32; under a program's
+    ``choices`` (layers, B x T, K) -> (logits, audit): the reference's
+    logits with the chosen experts, and ``decisions``, ``differing`` and
+    ``worst_margin`` of the choice in the reference's own scores."""
     with jax.default_matmul_precision("highest"):
-        x, _, _ = hidden(params, tokens, settings, **variant)
-        return x @ _f32(params["lm_head"]["kernel"])
+        x, differs, margin = hidden(params, tokens, settings,
+                                    choices=choices, **variant)
+        out = x @ _f32(params["lm_head"]["kernel"])
+    if choices is None:
+        return out
+    return out, {"decisions": int(differs.size),
+                 "differing": int(differs.sum()),
+                 "worst_margin": float(margin.max())}
 
 
 def loss_terms(params, inputs, targets, settings: dict, **variant):

@@ -116,6 +116,11 @@ def run_cell(args) -> dict:
         result["device"]["window_s"] = end - start
         result["breakdown"] = {"device_ops": trace.top_ops(traced),
                                "idle_gaps": trace.idle_gaps(traced)}
+    # what `correct` compared, each number beside its limit: the last key
+    # of the line (the driver's record of a run at fault keeps the line's end)
+    result["compared"] = {name: {"value": value, "limit": limit}
+                          for name, (value, limit)
+                          in facts.get("compared", {}).items()}
     if args.notes:
         notes = {"checks": facts["checks"], **facts.get("notes", {}),
                  "setup_marks_s": ctx["marks"],
@@ -150,6 +155,10 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     result = run_cell(args)
     sys.stdout.flush()
+    for name, pair in result["compared"].items():   # stderr's last lines
+        print(f"compared {name} = {pair['value']!r} limit {pair['limit']!r}",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
 
 

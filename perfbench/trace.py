@@ -135,7 +135,10 @@ def load_window(cap: "Capture") -> dict:
     if not marks:
         raise RuntimeError(f"the trace holds no {WINDOW_SPAN} span")
     full["host"] = [e for e in full["host"] if e[0] != WINDOW_SPAN]
-    return clip_to_window(full, *marks[-1])
+    # where the capture lies: the readers of the program's spans and of the
+    # op map open THIS run's file, not the newest under the scratch
+    # directory, which another run in the same checkout may be writing
+    return {**clip_to_window(full, *marks[-1]), "capture_dir": cap.out_dir}
 
 
 def _line(ln) -> str:

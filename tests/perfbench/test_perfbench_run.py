@@ -12,7 +12,8 @@ import pytest
 from perfbench import manifest
 
 ROOT = manifest.ROOT
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
@@ -41,9 +42,19 @@ def _cells():
 @pytest.mark.parametrize("cell", _cells())
 def test_rehearsal_prints_the_contracts_line(cell):
     bench = manifest.load_manifest()
-    out = _last_line(_run("--workload", cell, "--seed", str(2 ** 31 + 17),
-                          "--seconds", "2", "--trace", "0", "--rehearse"))
+    proc = _run("--workload", cell, "--seed", str(2 ** 31 + 17),
+                "--seconds", "2", "--trace", "0", "--rehearse")
+    out = _last_line(proc)
     assert set(out) == RESULT_KEYS
+    # each number `correct` compared beside its limit: the line's last key,
+    # and the last lines of standard error
+    assert list(out)[-1] == "compared" and out["compared"]
+    said = proc.stderr.strip().splitlines()[-len(out["compared"]):]
+    for line, (name, pair) in zip(said, out["compared"].items()):
+        assert set(pair) == {"value", "limit"}
+        assert pair["value"] <= pair["limit"]
+        assert line == (f"compared {name} = {pair['value']!r} "
+                        f"limit {pair['limit']!r}")
     assert out["correct"] is True and out["failed"] == 0
     assert out["attempted"] > 0
     assert set(out["device"]) == DEVICE_KEYS

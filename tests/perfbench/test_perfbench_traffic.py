@@ -102,3 +102,34 @@ def test_prompt_tokens_come_from_the_seed_alone(spec):
     assert [r.prompt for r in a] != [r.prompt for r in c]
     assert all(0 <= t < 50257 for r in a for t in r.prompt)
     assert traffic.key_seed(2 ** 31 + 11) < 2 ** 31
+
+
+def _serving_cells():
+    bench = manifest.load_manifest()
+    cells = [manifest.load_cell(bench, w["name"]) for w in bench["workloads"]]
+    return [(c["name"], c["traffic_file"], bench["run_seconds"])
+            for c in cells if c["traffic_file"]["kind"] == "serve"]
+
+
+@pytest.mark.parametrize("name,spec,seconds", _serving_cells(),
+                         ids=[c[0] for c in _serving_cells()])
+def test_every_seed_has_arrivals_in_the_traced_stretch(name, spec, seconds):
+    """A traced run captures the last ``trace_seconds`` of the window, and
+    a capture with no device operation in it is refused (PR 38: XL's
+    steady mix traced 10 s of 51, and seed 798041194's last request was due
+    at 39.6 s).  Arrival times need no token ids: the generator's stream."""
+    period = traffic.cycle_seconds(spec)
+    lead = seconds - min(spec["trace_seconds"], seconds)
+    n = len(traffic.length_grid(spec))
+    for seed in list(range(400)) + [798041194, 2 ** 31 + 11]:
+        due = np.sort(traffic.rng_for(seed, "serve_arrivals")
+                      .uniform(0.0, period, n))
+        times = np.concatenate([k * period + due for k in
+                                range(int(seconds // period) + 1)])
+        # a second of room: the capture starts a little late, and a request
+        # that arrives as it closes decodes outside it
+        inside = (times >= lead + 1.0) & (times < seconds - 1.0)
+        assert inside.any(), (name, seed, due.tolist())
+    cycle = traffic.serve_cycle(spec, 50257, 798041194)
+    assert [r.due_s for r in cycle] == list(np.sort(
+        traffic.rng_for(798041194, "serve_arrivals").uniform(0.0, period, n)))
