@@ -219,17 +219,23 @@ def _qkv(h: jax.Array, lp: Params, cfg: LlamaConfig):
             v.reshape(*lead, KV, D))
 
 
-def _ffn(h: jax.Array, lp: Params, cfg: LlamaConfig):
+def _ffn(h: jax.Array, lp: Params, cfg: LlamaConfig, choices: bool = False,
+         live: Optional[jax.Array] = None):
     """The block's feed-forward on normed hidden states (..., E): one
     SwiGLU, or the dropless experts.  Returns (out, RouterStats | None);
-    training, prefill and decode all come through here."""
+    training, prefill and decode all come through here.  ``choices`` (a
+    serving step of a model with experts): the second result is the
+    chosen expert ids, (rows, k) int32, from the routing that made
+    ``out``; ``live`` (rows,) bool: a decode step's rows that are some
+    sequence's (``ops/moe.choice_of_live_rows``)."""
     if cfg.n_experts:
         from ray_tpu.ops.moe import dropless_moe_ffn
         ex = lp["experts"]
-        out, stats = dropless_moe_ffn(
+        out, *told = dropless_moe_ffn(
             h.reshape(-1, h.shape[-1]), lp["router"]["kernel"],
-            ex["w_gate"], ex["w_up"], ex["w_down"], k=cfg.experts_per_token)
-        return out.reshape(h.shape), stats
+            ex["w_gate"], ex["w_up"], ex["w_down"], k=cfg.experts_per_token,
+            choices=choices, live=live)
+        return out.reshape(h.shape), told[-1]        # the stats, or the ids
     with jax.named_scope("mlp"):
         gate = jax.nn.silu(h @ lp["w_gate"]["kernel"].astype(cfg.dtype))
         up = h @ lp["w_up"]["kernel"].astype(cfg.dtype)
@@ -237,11 +243,12 @@ def _ffn(h: jax.Array, lp: Params, cfg: LlamaConfig):
 
 
 def _block(x: jax.Array, lp: Params, cfg: LlamaConfig,
-           collect_kv: bool = False):
+           collect_kv: bool = False, choices: bool = False):
     """One decoder block -> (out, RouterStats | None); with ``collect_kv``
     -> (out, (k, v)), post-RoPE and pre-GQA-expand: the SAME body serves
     training and the serving engine's prefill cache fill, so the paths
-    cannot diverge."""
+    cannot diverge.  ``choices`` (with ``collect_kv``): -> (out, (k, v,
+    the chosen expert ids))."""
     B, T, E = x.shape
     H = cfg.n_head
     with jax.named_scope("ln_1"):
@@ -258,10 +265,10 @@ def _block(x: jax.Array, lp: Params, cfg: LlamaConfig,
         x = x + a @ lp["wo"]["kernel"].astype(cfg.dtype)
     with jax.named_scope("ln_2"):
         h = _rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
-    f, stats = _ffn(h, lp, cfg)
+    f, stats = _ffn(h, lp, cfg, choices)
     out = x + f
     if collect_kv:
-        return out, (k, v)
+        return out, ((k, v, stats) if choices else (k, v))
     return out, stats
 
 
@@ -305,34 +312,51 @@ def _rope_at(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
+def routed_layers(cfg: LlamaConfig) -> Optional[Dict[str, int]]:
+    """What the serving step programs of a preset with experts hand over
+    beside the logits (``serve/llm/model_runner.py``): the expert ids each
+    layer chose, int32 (layers, rows, k).  None: the preset is dense."""
+    if not cfg.n_experts:
+        return None
+    return {"layers": cfg.n_layer, "k": cfg.experts_per_token}
+
+
 def forward_prefill(params: Params, tokens: jax.Array, cfg: LlamaConfig,
-                    last_pos: Optional[jax.Array] = None):
+                    last_pos: Optional[jax.Array] = None,
+                    choices: bool = False):
     """Prefill forward: tokens (B, T) → (logits, k, v) with
     k/v (L, B, T, KV, D).  Keys are cached post-RoPE, values
     pre-GQA-expand (the paged decode attention expands groups itself) —
-    the layout the serve/llm engine scatters into its pool.
+    the layout the serve/llm engine scatters into its pool.  With
+    ``choices`` (a preset with experts) a fourth result: the experts
+    chosen, (L, B x T, k) int32.
 
     ``last_pos`` (traced scalar): logits only at that position as
     (B, V); None returns the full (B, T, V) — see gpt2.forward_prefill."""
     x = _embed(params, tokens, cfg)
 
     def body(carry, lp):
-        return _block(carry, lp, cfg, collect_kv=True)
+        return _block(carry, lp, cfg, collect_kv=True, choices=choices)
 
-    x, (ks, vs) = lax.scan(body, x, params["blocks"])
+    x, kept = lax.scan(body, x, params["blocks"])
     x = _final_norm(params, x, cfg)
     if last_pos is not None:
         x = lax.dynamic_slice_in_dim(x, last_pos, 1, axis=1)
-    return _head(params, x, cfg, only_position=last_pos is not None), ks, vs
+    return (_head(params, x, cfg, only_position=last_pos is not None),
+            *kept)
 
 
 def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
                    kv_pool: jax.Array, block_tables: jax.Array,
-                   ctx_lens: jax.Array, cfg: LlamaConfig):
+                   ctx_lens: jax.Array, cfg: LlamaConfig,
+                   choices: bool = False,
+                   live: Optional[jax.Array] = None):
     """One decode step over the engine's paged KV pool, handed whole to
     ``ops/paged_attention`` with the layer's index (read-only here).
 
-    Returns (logits (B, V) f32, new_k (L, B, KV, D), new_v (L, B, KV, D))."""
+    Returns (logits (B, V) f32, new_k (L, B, KV, D), new_v (L, B, KV, D))
+    and, with ``choices``, the experts chosen, (L, B, k) int32 (``live``
+    (B,) bool: the rows that are not padding, see ``_ffn``)."""
     from ray_tpu.ops.paged_attention import paged_attention_decode
     B = tokens.shape[0]
     E = cfg.n_embd
@@ -353,12 +377,11 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
             x = x + a @ lp["wo"]["kernel"].astype(cfg.dtype)
         with jax.named_scope("ln_2"):
             h = _rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
-        x = x + _ffn(h, lp, cfg)[0]
-        return x, (k, v)
+        f, ids = _ffn(h, lp, cfg, choices, live)
+        return x + f, ((k, v, ids) if choices else (k, v))
 
-    x, (ks, vs) = lax.scan(body, x,
-                           (params["blocks"], jnp.arange(cfg.n_layer)))
-    return _head(params, _final_norm(params, x, cfg), cfg), ks, vs
+    x, kept = lax.scan(body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
+    return (_head(params, _final_norm(params, x, cfg), cfg), *kept)
 
 
 def loss_fn(params: Params, batch: Dict[str, jax.Array],

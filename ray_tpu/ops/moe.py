@@ -270,20 +270,33 @@ def route_softmax(x: jax.Array, w_router: jax.Array, k: int):
 
 
 def route_sigmoid(x: jax.Array, w_router: jax.Array, select_bias: jax.Array,
-                  k: int, weight_scale: float):
+                  k: int, weight_scale: float, eps: float = 1e-20):
     """-> (expert_idx (N, k), weights (N, k)): float32 sigmoid scores over
     all E experts; the k chosen are the top of score + ``select_bias``
     (the bias decides the choice and never a weight: it balances the load
     with no auxiliary loss and has no gradient), and their weights are
-    the scores alone, renormalised over the k chosen and scaled."""
+    the scores alone, renormalised over the k chosen and scaled.
+    ``eps`` is what the family adds to the divisor (DeepSeek-V3's 1e-20,
+    LFM2's 1e-6): part of its arithmetic, not a setting."""
     logits = jnp.dot(x, w_router.astype(x.dtype),
                      preferred_element_type=jnp.float32)         # (N, E)
     scores = jax.nn.sigmoid(logits)
     _, expert_idx = jax.lax.top_k(
         scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)), k)
     chosen = jnp.take_along_axis(scores, expert_idx, axis=-1)
-    weights = chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+    weights = chosen / (chosen.sum(-1, keepdims=True) + eps)
     return expert_idx, weights * weight_scale
+
+
+def choice_of_live_rows(expert_idx: jax.Array, live: jax.Array) -> jax.Array:
+    """expert_idx (N, k) with the rows that are not ``live`` (N,) bool (a
+    decode step's rows padded up to its bucket) given the first live row's
+    choice: a padded row then lies in groups that a live row opened, so
+    the grouped matmuls read no expert for it that no sequence chose (at
+    a decode step's row counts an expert's weights read is the cost)."""
+    with jax.named_scope("moe_router"):
+        return jnp.where(live[:, None], expert_idx,
+                         expert_idx[jnp.argmax(live)])
 
 
 def dropless_experts(x: jax.Array, expert_idx: jax.Array, weights: jax.Array,
@@ -332,7 +345,9 @@ def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
                      w_up: jax.Array, w_down: jax.Array, *, k: int,
                      scoring: str = "softmax",
                      select_bias: Optional[jax.Array] = None,
-                     weight_scale: float = 1.0, first_held: int = 0):
+                     weight_scale: float = 1.0, first_held: int = 0,
+                     choices: bool = False,
+                     live: Optional[jax.Array] = None):
     """Token-choice SwiGLU experts with no capacity: every token is
     computed by each of its top-k experts that is held here, whatever the
     imbalance.
@@ -343,6 +358,9 @@ def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
     ``"softmax"`` (``route_softmax``) returns ``RouterStats`` over all E;
     ``"sigmoid"`` (``route_sigmoid`` with ``select_bias`` (E,) and
     ``weight_scale``) has no auxiliary term and returns ``HeldStats``.
+    ``choices`` (a serving step): a third result, the expert ids (N, k)
+    int32 that made ``y``; ``live`` (N,) bool with it: the rows that are
+    some sequence's (``choice_of_live_rows``).
     """
     n = x.shape[0]
     num_experts, held = w_router.shape[-1], w_gate.shape[0]
@@ -355,23 +373,26 @@ def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
         else:
             expert_idx, weights = route_sigmoid(x, w_router, select_bias, k,
                                                 weight_scale)
+    if live is not None:
+        expert_idx = choice_of_live_rows(expert_idx, live)
     y, group_sizes = dropless_experts(
         x, expert_idx, weights, w_gate, w_up, w_down,
         num_experts=num_experts, first_held=first_held)
+    chose = (expert_idx.astype(jnp.int32),) if choices else ()
     with jax.named_scope("router"):
         if scoring == "sigmoid":
             mine = group_sizes[:held].astype(jnp.float32)
             rows = mine.sum()
-            return y, HeldStats(rows, mine.max() / jnp.maximum(mine.mean(),
-                                                               1e-9),
-                                rows / (n * k))
+            return (y, HeldStats(rows, mine.max() / jnp.maximum(mine.mean(),
+                                                                1e-9),
+                                 rows / (n * k)), *chose)
         if first_held:
             group_sizes = jnp.roll(group_sizes, first_held)
         share = group_sizes.astype(jnp.float32) / (n * k)        # f_e
         balance = num_experts * jnp.sum(share * probs.mean(0))
         z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
         load = share.max() * num_experts
-    return y, RouterStats(balance, z, load)
+    return (y, RouterStats(balance, z, load), *chose)
 
 
 # Sharding rules for MoE params (compose with TRANSFORMER_RULES by

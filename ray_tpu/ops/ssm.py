@@ -35,11 +35,12 @@ _HI = lax.Precision.HIGHEST
 
 
 # --------------------------------------------------------------------- conv
-def causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
+def causal_conv(x: jax.Array, w: jax.Array, b: Optional[jax.Array],
                 last_pos: Optional[jax.Array] = None
                 ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """Depthwise causal conv over (B, T, C): ``y_t = b + sum_k w[k] *
-    x_{t-(K-1)+k}`` (``w[K-1]`` takes the current input), in float32.
+    x_{t-(K-1)+k}`` (``w[K-1]`` takes the current input), in float32;
+    ``b`` None for a conv without a bias.
 
     Also returns the conv's *tail* at ``last_pos`` (traced scalar): the
     K-1 inputs ``x_{last_pos-K+2} .. x_{last_pos}`` as (B, K-1, C), zeros
@@ -48,7 +49,7 @@ def causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
     k_w, t = w.shape[0], x.shape[1]
     xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (k_w - 1, 0), (0, 0)))
     w = w.astype(jnp.float32)
-    y = b.astype(jnp.float32)
+    y = 0.0 if b is None else b.astype(jnp.float32)
     for k in range(k_w):
         y = y + w[k] * xp[:, k:k + t]
     if last_pos is None:
@@ -56,11 +57,13 @@ def causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
     return y, lax.dynamic_slice_in_dim(xp, last_pos + 1, k_w - 1, axis=1)
 
 
-def conv_step(tail: jax.Array, x: jax.Array, w: jax.Array, b: jax.Array
-              ) -> Tuple[jax.Array, jax.Array]:
+def conv_step(tail: jax.Array, x: jax.Array, w: jax.Array,
+              b: Optional[jax.Array]) -> Tuple[jax.Array, jax.Array]:
     """One token through the conv: tail (R, K-1, C) float32, x (R, C) ->
     (y (R, C) float32, the tail one token on)."""
     window = jnp.concatenate([tail, x.astype(jnp.float32)[:, None]], axis=1)
+    if b is None:
+        return (w.astype(jnp.float32) * window).sum(1), window[:, 1:]
     y = b.astype(jnp.float32) + (w.astype(jnp.float32) * window).sum(1)
     return y, window[:, 1:]
 

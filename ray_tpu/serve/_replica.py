@@ -71,6 +71,7 @@ class Replica:
                           "rtpu_llm_ttft_seconds",
                           "rtpu_llm_queue_seconds",
                           "rtpu_llm_tpot_seconds",
+                          "rtpu_llm_moe_experts_touched",
                           "rtpu_llm_tokens_total"):
                 mcat.get(_name).set_default_tags({"group": dep_key})
         self._instance = user_cls(*init_args, **init_kwargs)

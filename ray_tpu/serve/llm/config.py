@@ -74,9 +74,11 @@ def resolve_model(cfg: EngineConfig):
         from ray_tpu.models import llama as mod
     elif family == "falcon_h1":
         from ray_tpu.models import falcon_h1 as mod
+    elif family == "lfm2":
+        from ray_tpu.models import lfm2 as mod
     else:
         raise ValueError(f"unknown model family {family!r} "
-                         "(expected gpt2|llama|falcon_h1)")
+                         "(expected gpt2|llama|falcon_h1|lfm2)")
     try:
         mcfg = mod.PRESETS[preset]()
     except KeyError:

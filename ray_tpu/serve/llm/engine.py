@@ -159,11 +159,14 @@ class LLMEngine:
         self.cfg = cfg
         self.runner = ModelRunner(cfg, params)
         # a family with recurrent state gets a row of it per sequence
-        # slot beside the blocks, in the same holder (kv_cache.py)
+        # slot beside the blocks, in the same holder (kv_cache.py); the
+        # pool is laid out for the layers that hold K/V and the store for
+        # those that hold state, which the runner counts
         self.cache = PagedKVCache(
-            cfg.num_blocks, self.runner.n_layer, cfg.block_size,
+            cfg.num_blocks, self.runner.kv_layers, cfg.block_size,
             self.runner.n_kv, self.runner.head_dim, dtype=np.float32,
-            state=self.runner.state_spec, max_seqs=cfg.max_num_seqs)
+            state=self.runner.state_spec, max_seqs=cfg.max_num_seqs,
+            state_layers=self.runner.state_layers)
         self.runner.cache = self.cache
         self.sched = IterationScheduler(cfg.max_num_seqs,
                                         cfg.max_prefill_tokens,
@@ -196,6 +199,12 @@ class LLMEngine:
         # rows of recurrent state the compiled decode steps read and
         # wrote: the whole store each step, whatever the batch (loop-owned)
         self.state_rows_stepped = 0
+        # a model that routes: the distinct experts the decode steps' live
+        # rows chose, summed over steps and routed layers, and how many
+        # (step, routed layer) pairs that is: their ratio is the experts
+        # a layer's step has to read (loop-owned)
+        self.experts_touched = 0
+        self.routed_layer_steps = 0
         # tokens by where they were chosen (the step program's argmax for
         # a greedy request; ModelRunner.sample on a pulled row for any
         # other) and the bytes of logits pulled for the latter.
@@ -546,7 +555,9 @@ class LLMEngine:
             bs = self.cfg.block_size
             blocks = int((-(-lens // bs)).sum())
             span.set(blocks=blocks,
-                     kv_lane_pad_bytes=self.cache.lane_pad_bytes)
+                     kv_lane_pad_bytes=self.cache.lane_pad_bytes,
+                     kv_layers=self.cache.kv_layers,
+                     state_layers=self.cache.state_layers)
             self.attn_blocks_read += blocks
             self.attn_blocks_table += maxb * _bucket(
                 len(batch), self.cfg.decode_batch_buckets)
@@ -574,7 +585,7 @@ class LLMEngine:
         if flight is not None:
             # the device has this step queued behind that one: now read it
             self.decode_steps_ahead += 1
-            self._commit(flight)
+            self._commit(flight, span)
         if sampled:
             # its token is drawn here, from logits only this step has
             self._drain("sampled")
@@ -588,11 +599,13 @@ class LLMEngine:
         if flight is None:
             return
         self.decode_drains[cause] += 1
-        with hot_span("llm.decode.drain", self.span_s, cause=cause):
-            self._commit(flight)
+        with hot_span("llm.decode.drain", self.span_s, cause=cause) as span:
+            self._commit(flight, span)
 
-    def _commit(self, flight: _InFlight) -> None:
-        """Wait for ``flight``'s ids and give each sequence its token."""
+    def _commit(self, flight: _InFlight, span: hot_span) -> None:
+        """Wait for ``flight``'s ids and give each sequence its token;
+        ``span`` (the ``llm.decode`` or ``llm.decode.drain`` that reads the
+        step) is told what a routing model's step touched."""
         try:
             chosen = self.runner.pull_step(flight.step)
         except BaseException:
@@ -603,6 +616,14 @@ class LLMEngine:
                     self._return_slots(lost.batch, lost.slots)
             self._inflight = None
             raise
+        if chosen.touched is not None:
+            layers = self.runner.route_spec["layers"]
+            span.set(experts_touched=chosen.touched)
+            self.experts_touched += chosen.touched
+            self.routed_layer_steps += layers
+            if GLOBAL_CONFIG.metrics_enabled:
+                mcat.get("rtpu_llm_moe_experts_touched").observe(
+                    chosen.touched / layers, tags={"model": self.cfg.model})
         discarded = 0
         with hot_span("llm.decode.commit", self.span_s):
             for i, s in enumerate(flight.batch):
@@ -1002,5 +1023,9 @@ class LLMEngine:
                     state_rows_used=self.cache.state_rows_used(),
                     state_rows_stepped=self.state_rows_stepped,
                     state_commits=self.cache.state_commits,
+                    kv_layers=self.cache.kv_layers,
+                    state_layers=self.cache.state_layers,
+                    experts_touched=self.experts_touched,
+                    routed_layer_steps=self.routed_layer_steps,
                     span_s={k: list(v) for k, v in
                             list(self.span_s.items())})

@@ -59,6 +59,16 @@ program leaves its state in the staging row (``model_runner.py``), and
 scatters its K/V; the decode program steps the rows ``rows_of`` names
 for the tables it was given.  Without ``state=`` the holder holds the
 array as before and every program lowers as before.
+
+Layers that differ in kind.  The pool's ``n_layer`` counts the layers that
+hold K/V and the store's leading axis the layers that hold state
+(``state_layers=``): the same number where every layer holds both
+(Falcon-H1), two numbers where some layers attend and the others keep a
+conv's tail and none does both (``models/lfm2.py``: the module counts them
+in ``cache_layers(cfg)``).  Each kind is numbered among its own in layer
+order; a pool laid out for all the layers of such a model would be mostly
+empty and the decode kernel's layer index wrong.  Nothing else here knows:
+a row is a row and a block a block.
 """
 
 from __future__ import annotations
@@ -303,11 +313,13 @@ class PagedKVCache:
 
     def __init__(self, num_blocks: int, n_layer: int, block_size: int,
                  n_kv: int, head_dim: int, dtype=np.float32, *,
-                 state=None, max_seqs: int = 0):
-        """``state``: one sequence's recurrent state in one layer, name ->
+                 state=None, max_seqs: int = 0, state_layers=None):
+        """``n_layer``: the layers that hold K/V.  ``state``: one
+        sequence's recurrent state in one layer, name ->
         ``ShapeDtypeStruct`` (a model module's ``recurrent_state``), for a
         family that has one; the store then has ``max_seqs`` rows and one
-        for staging."""
+        for staging, in each of ``state_layers`` layers (None: as many as
+        hold K/V)."""
         self.num_blocks = num_blocks
         self.block_shape = (n_layer, 2, block_size, n_kv, head_dim)
         self.block_size = block_size
@@ -321,10 +333,14 @@ class PagedKVCache:
         # names no row: a decode step reads somewhere and writes nowhere
         self.no_row = self.state_rows + 1
         store = None
+        self.kv_layers = n_layer
+        self.state_layers = 0
         if state:
             import jax
+            self.state_layers = n_layer if state_layers is None \
+                else state_layers
             store = {name: jax.ShapeDtypeStruct(
-                (n_layer, max_seqs + 1) + tuple(s.shape), s.dtype)
+                (self.state_layers, max_seqs + 1) + tuple(s.shape), s.dtype)
                 for name, s in state.items()}
         self.state_bytes = sum(
             int(np.prod(s.shape)) * np.dtype(s.dtype).itemsize

@@ -11,8 +11,8 @@ cold starts are XLA compiles; bounding them is the TPU-serving
 equivalent of connection pooling).
 
 The runner is model-family-agnostic: ``models/gpt2.py``,
-``models/llama.py`` and ``models/falcon_h1.py`` each export
-``forward_prefill`` / ``forward_decode`` (the decode step reads the paged
+``models/llama.py``, ``models/falcon_h1.py`` and ``models/lfm2.py`` each
+export ``forward_prefill`` / ``forward_decode`` (the decode step reads the paged
 pool through ``ops/paged_attention.py`` and returns the new token's K/V);
 the runner's decode program then writes that K/V into the pool, which it
 was given donated, so the pool stays on the device
@@ -58,6 +58,29 @@ keep their signatures and results: the state travels behind them.  A
 module that exports no description is served by the programs it always
 had, which lower as before.
 
+Layers that differ in kind.  A family in which some layers hold K/V and
+others recurrent state, and none both (``models/lfm2.py``), says how many
+of each with ``cache_layers(cfg)`` -> ``{"kv": n, "state": m}``, beside
+its ``recurrent_state``: the runner reads it into ``kv_layers`` /
+``state_layers`` and the engine builds the pool for the one count and the
+store for the other.  Without that export both are ``n_layer`` (the store's
+only where there is state), which is what every other family has.
+
+The hand-over of the choice of experts.  A module that routes exports
+``routed_layers(cfg)`` -> ``{"layers": n, "k": k}`` (None for a preset
+that does not); the runner offers it as ``route_spec``, asks the module's
+forwards for the ids (``choices=True``), and its step programs return them
+as one more result, int32 ``(routed layers, rows of the bucket, k)``: the
+last step's stay on the device as ``runner.choices`` until somebody reads
+them (the serving check of ``perfbench/jobs/serve.py``).  A decode program
+of such a module tells the forward which rows of the bucket are live
+(``live=``): a padded row takes a live row's choice and reads no expert of
+its own (``ops/moe.choice_of_live_rows``).  It also counts the distinct
+experts the step chose, summed over the routed layers, and sends that one
+number behind the ids it returns (``Chosen.touched``: 4 more bytes of the
+pull there is).
+``prefill`` and ``decode`` keep their signatures and results.
+
 Where the serving type of the weights is decided: here, once.  Whatever
 tree the runner ends up with (the caller's, ``init_params``' own in the
 model's ``param_dtype``, the shm plane's) goes through
@@ -101,6 +124,9 @@ class Chosen(NamedTuple):
 
     ids: np.ndarray                  # (B,) int32: each row's greedy token
     logits: Dict[int, np.ndarray]    # row -> (V,) float32, the rows named
+    # a decode step of a module that routes: the distinct experts its
+    # live rows chose, summed over the routed layers
+    touched: Optional[int] = None
 
     def token(self, row: int, sp: SamplingParams, step: int) -> int:
         """The row's next token: the device's choice for a greedy
@@ -159,8 +185,23 @@ class ModelRunner:
         # has one (None: K/V is all a sequence holds)
         describe = getattr(self.mod, "recurrent_state", None)
         self.state_spec = describe(self.mcfg) if describe else None
-        forward_prefill = partial(self.mod.forward_prefill, cfg=self.mcfg)
-        forward_decode = partial(self.mod.forward_decode, cfg=self.mcfg)
+        # the layers that hold K/V and those that hold state: a module
+        # whose layers differ in kind counts them (cache_layers)
+        describe = getattr(self.mod, "cache_layers", None)
+        layers = describe(self.mcfg) if describe else {
+            "kv": self.n_layer,
+            "state": self.n_layer if self.state_spec else 0}
+        self.kv_layers, self.state_layers = layers["kv"], layers["state"]
+        # the choice of experts a module that routes hands over ({"layers",
+        # "k"}; None: it does not route), and the last step's, on the device
+        describe = getattr(self.mod, "routed_layers", None)
+        self.route_spec = describe(self.mcfg) if describe else None
+        self.choices = None
+        asked = {"choices": True} if self.route_spec else {}
+        forward_prefill = partial(self.mod.forward_prefill, cfg=self.mcfg,
+                                  **asked)
+        forward_decode = partial(self.mod.forward_decode, cfg=self.mcfg,
+                                 **asked)
 
         def greedy(logits):
             # each row's token at temperature 0, chosen where the logits
@@ -168,9 +209,27 @@ class ModelRunner:
             with jax.named_scope("lm_head"):
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
+        def touched(ids):
+            # the distinct experts a decode step chose, summed over the
+            # routed layers: ids (layers, rows, k), a padded row holding a
+            # live row's choice (live_rows)
+            with jax.named_scope("moe_router"):
+                flat = jnp.sort(ids.reshape(ids.shape[0], -1), axis=1)
+                return (1 + (flat[:, 1:] != flat[:, :-1]).sum(1)).sum() \
+                    .astype(jnp.int32)
+
+        def live_rows(tokens, n_real):
+            # a routing module is told which rows of the bucket are some
+            # sequence's: the padded ones then choose no expert of their
+            # own, and what ``touched`` counts is what the step reads
+            if not self.route_spec:
+                return {}
+            return {"live": jnp.arange(tokens.shape[0]) < n_real}
+
         def prefill_step(params, toks, last_pos):
-            logits, ks, vs = forward_prefill(params, toks, last_pos=last_pos)
-            return (logits, greedy(logits)), ks[:, 0], vs[:, 0]
+            logits, ks, vs, *ids = forward_prefill(params, toks,
+                                                   last_pos=last_pos)
+            return (logits, greedy(logits)), ks[:, 0], vs[:, 0], *ids
 
         def new_kv_written(pool, k, v, block_tables, ctx_lens, n_real):
             # a row's new K/V goes to the slot append_slot reserved,
@@ -193,44 +252,50 @@ class ModelRunner:
                 return jnp.where(src >= 0, last_ids[jnp.maximum(src, 0)],
                                  tokens)
 
-        def chosen_from(logits):
+        def chosen_from(logits, chose=()):
             # (logits, ids), and the ids at the width every bucket's
-            # program takes them back at
+            # program takes them back at.  ``chose``: a routing module's
+            # expert ids; the count of those its live rows touched rides
+            # behind the ids, in the pull there is
             ids = greedy(logits)
             pad = widest - ids.shape[0]
-            return (logits, ids), jnp.pad(ids, (0, pad)) if pad else ids
+            carry = jnp.pad(ids, (0, pad)) if pad else ids
+            if chose:
+                ids = jnp.concatenate([ids, touched(chose[0])[None]])
+            return (logits, ids), carry
 
         def decode_step(pool, params, tokens, positions, block_tables,
                         ctx_lens, n_real, last_ids, src):
             # the model reads the pool and attends the new token
             # explicitly; its K/V is written after the reads
-            logits, k, v = forward_decode(
+            logits, k, v, *ids = forward_decode(
                 params, tokens_in(tokens, last_ids, src), positions, pool,
-                block_tables, ctx_lens)
+                block_tables, ctx_lens, **live_rows(tokens, n_real))
             pool = new_kv_written(pool, k, v, block_tables, ctx_lens, n_real)
-            return pool, (*chosen_from(logits), k, v)
+            return pool, (*chosen_from(logits, ids), k, v, *ids)
 
         def prefill_state_step(held, params, toks, last_pos):
             # the state at the prompt's last real position goes to the
             # store's last row, where scatter_prefill finds it
-            logits, ks, vs, state = forward_prefill(params, toks,
-                                                    last_pos=last_pos)
+            logits, ks, vs, state, *ids = forward_prefill(
+                params, toks, last_pos=last_pos)
             store = jax.tree.map(lambda s, new: s.at[:, -1].set(new[:, 0]),
                                  held["state"], state)
             return {**held, "state": store}, (
-                (logits, greedy(logits)), ks[:, 0], vs[:, 0])
+                (logits, greedy(logits)), ks[:, 0], vs[:, 0], *ids)
 
         def decode_state_step(held, params, tokens, positions, block_tables,
                               ctx_lens, n_real, last_ids, src, state_rows):
             # as decode_step, and the model steps the rows of the store
             # that state_rows names
-            logits, k, v, store = forward_decode(
+            logits, k, v, store, *ids = forward_decode(
                 params, tokens_in(tokens, last_ids, src), positions,
                 held["kv"], block_tables, ctx_lens, state=held["state"],
-                rows=state_rows)
+                rows=state_rows, **live_rows(tokens, n_real))
             pool = new_kv_written(held["kv"], k, v, block_tables, ctx_lens,
                                   n_real)
-            return {"kv": pool, "state": store}, (*chosen_from(logits), k, v)
+            return {"kv": pool, "state": store}, (
+                *chosen_from(logits, ids), k, v, *ids)
 
         # bound to a name of its own: jaxlint pins a donating jit by the
         # name it is assigned to (lock_watchdog.DONATED)
@@ -312,10 +377,13 @@ class ModelRunner:
         with compiling, hot_span("llm.prefill.dispatch", self.span_s), \
                 self._prefill_budget:
             if self.state_spec is None:
-                picked, ks, vs = self._prefill(self.params, toks, last_pos)
+                picked, ks, vs, *ids = self._prefill(self.params, toks,
+                                                     last_pos)
             else:
-                picked, ks, vs = self._state_cache().pool.donate(
+                picked, ks, vs, *ids = self._state_cache().pool.donate(
                     self._prefill, self.params, toks, last_pos)
+            if ids:
+                self.choices, = ids
             if compiling is not _SEEN:
                 held = () if self.state_spec is None else (
                     self._state_cache().pool.abstract(),)
@@ -390,7 +458,9 @@ class ModelRunner:
                              (kv_pool.abstract(), *abstract(args)))
         with compiling, hot_span("llm.decode.dispatch", self.span_s), \
                 self._decode_budget:
-            picked, carry, ks, vs = kv_pool.donate(self._decode, *args)
+            picked, carry, ks, vs, *ids = kv_pool.donate(self._decode, *args)
+        if ids:
+            self.choices, = ids
         self.steps_enqueued += 1
         step = Enqueued(self.steps_enqueued, picked, carry, b, logit_rows)
         return (self.pull_step(step) if wait else step), ks, vs
@@ -400,21 +470,27 @@ class ModelRunner:
         caller named (``decode``'s first result), inside an
         ``llm.decode.pull`` span that says which step it is."""
         return self._pull("llm.decode.pull", step.picked, step.n,
-                          step.logit_rows, step=step.step)
+                          step.logit_rows, touched=self.route_spec is not None,
+                          step=step.step)
 
     def _pull(self, span: str, picked, n: int,
-              logit_rows: Optional[Sequence[int]], **attrs):
+              logit_rows: Optional[Sequence[int]], touched: bool = False,
+              **attrs):
         """A step's results for the host, inside ``span`` (whose ``bytes``
         is what crossed): all its logits, (n, V), for a caller that named
-        no rows; else the n ids and the rows named."""
+        no rows; else the n ids and the rows named (``touched``: the count
+        that a routing module's decode step sends behind its ids)."""
         logits, ids = picked
         with hot_span(span, self.span_s, **attrs) as pull:
             if logit_rows is None:
                 pull.set(bytes=logits.nbytes)
                 return np.asarray(logits)[:n]
-            chosen = Chosen(np.asarray(ids)[:n], {
+            ids = np.asarray(ids)
+            chosen = Chosen(ids[:n], {
                 int(row): np.asarray(self._logits_row(logits, np.int32(row)))
-                for row in logit_rows})
+                for row in logit_rows}, int(ids[-1]) if touched else None)
+            if touched:
+                pull.set(experts_touched=chosen.touched)
             pull.set(bytes=ids.nbytes + chosen.logits_nbytes)
             return chosen
 

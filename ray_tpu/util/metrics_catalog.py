@@ -32,6 +32,10 @@ from ray_tpu.util import metrics as _metrics
 LATENCY_BUCKETS = (0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.25, 0.5,
                    1.0, 2.5, 5.0, 10.0, 30.0, 60.0)
 
+# Counts of experts (of 8 to 256 a layer) a decode step touches.
+EXPERT_COUNT_BUCKETS = (1, 2, 4, 8, 16, 24, 32, 40, 48, 56, 64, 96, 128,
+                        192, 256)
+
 # Microsecond-scale buckets for control-plane handler CPU (a hot-kind
 # handler at its floor runs in tens of µs; the ms range is the
 # contention tail we watch for).
@@ -219,6 +223,15 @@ CATALOG: Dict[str, dict] = {
         kind="histogram", tag_keys=("model", "group"), buckets=LATENCY_BUCKETS,
         description="Time per output token after the first (decode "
                     "cadence), observed once per finished sequence",
+        emitted_by="llm replica"),
+    "rtpu_llm_moe_experts_touched": dict(
+        kind="histogram", tag_keys=("model", "group"),
+        buckets=EXPERT_COUNT_BUCKETS,
+        description="Distinct experts a decode step's live rows chose in "
+                    "one routed layer (the step's count over its routed "
+                    "layers, per layer): the expert weights the step has "
+                    "to read; observed once per decode step of a model "
+                    "that routes",
         emitted_by="llm replica"),
     "rtpu_llm_tokens_total": dict(
         kind="counter", tag_keys=("model", "phase", "group"),

@@ -523,3 +523,120 @@ def test_older_training_steps_lower_to_the_operations_and_kernels_they_had(
         prog.jitted_step.lower(state, batch).as_text())
     assert (digest, sum(ops.values()), sum(kernels.values())) == \
         PARENT_STEPS[cell]
+
+
+# --------------------------------------------- the LFM2-MoE cell's programs
+@pytest.fixture(scope="module")
+def lfm2_runner(v5e):
+    """The cell's runner over abstract weights, and what its holder holds
+    as shapes on the chip: a K/V pool over the 2 attention layers and a
+    store of conv tails over the 7 conv layers."""
+    import json
+    from pathlib import Path
+
+    from ray_tpu.models import lfm2
+    from ray_tpu.serve.llm import EngineConfig
+    from ray_tpu.serve.llm.config import resolve_model
+    from ray_tpu.serve.llm.kv_cache import device_shape
+    from ray_tpu.serve.llm.model_runner import ModelRunner
+    engine = json.loads((Path(__file__).parent.parent / "perfbench" /
+                         "configs" / "lfm2-24b-a2b.json").read_text()
+                        )["serve"]["engine"]
+    for key in ("decode_batch_buckets", "prefill_len_buckets"):
+        engine[key] = tuple(engine[key])
+    ecfg = EngineConfig(**engine)
+    mod, mcfg = resolve_model(ecfg)
+    assert mod is lfm2
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.eval_shape(lambda key: mod.init_params(key, mcfg),
+                            jax.random.key(0))
+    runner = ModelRunner(ecfg, params=params)
+    assert runner.params is params      # drawn in its serving type
+    assert (runner.kv_layers, runner.state_layers) == (2, 7)
+    held = {
+        "kv": on_chip(device_shape(ecfg.num_blocks, runner.kv_layers,
+                                   ecfg.block_size, mcfg.n_kv_head,
+                                   mcfg.head_dim), jnp.float32),
+        "state": {name: on_chip((runner.state_layers, ecfg.max_num_seqs + 1)
+                                + s.shape, s.dtype)
+                  for name, s in runner.state_spec.items()}}
+    weights = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), params)
+    return runner, ecfg, held, weights, on_chip
+
+
+# the float32 reference widens a routed layer's experts beside the engine
+# it checks: 64 x 3 x 2,048 x 1,536 x 4 bytes and the layer's other leaves
+LFM2_REFERENCE_LAYER_BYTES = 2.48e9
+
+
+def test_lfm2_decode_program_reads_the_experts_in_place_and_fits(
+        lfm2_runner, monkeypatch):
+    """The cell's decode step at its one bucket of 32 (9 layers at the
+    published widths, 2,048 blocks, 33 rows of conv tails): the paged
+    kernel at 32 / 8 heads x 64 once in the period's body, reading the
+    2-layer pool whole (8 x 64 = 512 lanes: no padding); 128 assignments
+    are no multiple of megablox's row tile, so the experts' 24 grouped
+    matmuls are XLA's ragged-dot kernels, each over the WHOLE stack of a
+    period position's experts (2 x 64 groups, the other period's empty):
+    nothing the size of a layer's experts is made (sliced out of the
+    stack by the scan, each of 12 would be a 0.4 GB copy: 0.41e9 bytes of
+    temporaries, seen before the experts were kept out of the scan);
+    pool and store donated, the pool touched by the step's 32 rows alone;
+    the ids come back as (8, 32, 4); and everything fits the chip with
+    the check's float32 layer beside it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, ecfg, held, weights, on_chip = lfm2_runner
+    bucket, i32 = ecfg.decode_batch_buckets[-1], jnp.int32
+    assert runner.param_bytes == 10_355_981_312
+    lowered = runner._decode.lower(
+        held, weights, on_chip((bucket,), i32), on_chip((bucket,), i32),
+        on_chip((bucket, ecfg.max_blocks_per_seq), i32),
+        on_chip((bucket,), i32), on_chip((), i32),
+        on_chip((bucket,), i32), on_chip((bucket,), i32),
+        on_chip((bucket,), i32))
+    ids = jax.tree.leaves(lowered.out_info)[-1]
+    assert ids.shape == (8, 32, 4) and ids.dtype == jnp.int32
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    # the ids go out with the count of touched experts behind them (33),
+    # and again at the bucket's width for the step enqueued behind this one
+    root = next(line for line in text[text.index("ENTRY "):].splitlines()
+                if " ROOT " in line)
+    assert "s32[33]" in root and "s32[32]" in root and "s32[8,32,4]" in root
+    assert "paged_decode" in text
+    assert len(re.findall(r"= bf16\[128,(?:1536|2048)\]\S* custom-call\(.*"
+                          r"ragged_dot_tiling", text)) == 12
+    assert not re.search(r"= bf16\[64,(2048,1536|1536,2048)\]", text)
+    assert held["kv"].shape == (2, 2, 2048, 16, 512)
+    assert held["state"]["conv"].shape == (7, 33, 2, 2048)
+    _assert_the_pool_is_read_in_place_and_written_by_rows(
+        text, held["kv"].shape, lanes_used=8 * 64)
+    total, mem = _held_bytes(compiled)
+    assert mem.alias_size_in_bytes >= 0.27e9
+    assert mem.temp_size_in_bytes < 0.05e9
+    assert total + LFM2_REFERENCE_LAYER_BYTES < 16.9e9, total
+
+
+def test_lfm2_prefill_program_fits_at_bucket_512(lfm2_runner, monkeypatch):
+    """The prefill program at the traffic's largest bucket: flash
+    attention at 32 heads x 64, 2,048 assignments through megablox (12
+    kernels over the whole stacks), the conv tails written to the staging
+    row of the donated store, the ids (8, 512, 4)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, _, held, weights, on_chip = lfm2_runner
+    lowered = runner._prefill.lower(
+        held, weights, on_chip((1, 512), jnp.int32), on_chip((), jnp.int32))
+    ids = jax.tree.leaves(lowered.out_info)[-1]
+    assert ids.shape == (8, 512, 4) and ids.dtype == jnp.int32
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%gmm[.\d]* = bf16\[2048,(?:1536|2048)\]",
+                          text)) == 12
+    assert "ragged_dot_tiling" not in text
+    assert not re.search(r"= bf16\[64,(2048,1536|1536,2048)\]", text)
+    total, mem = _held_bytes(compiled)
+    assert mem.alias_size_in_bytes >= 0.27e9
+    assert total + LFM2_REFERENCE_LAYER_BYTES < 16.9e9, total
