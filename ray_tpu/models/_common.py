@@ -6,7 +6,7 @@ tree and named scopes."""
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,10 +37,22 @@ def _cast_leaves(params: Any, dtype: Any, wide: Tuple[str, ...]) -> Any:
         params)
 
 
-def serving_params(params: Any, dtype: Any, wide: Tuple[str, ...]) -> Any:
+# The lanes of a tile of the device's memory: a table whose rows are a
+# whole number of them is held in row order, any other in column order.
+LANES = 128
+
+
+@jax.jit
+def _pad_rows(table: jax.Array) -> jax.Array:
+    return jnp.pad(table, ((0, 0), (0, -table.shape[-1] % LANES)))
+
+
+def serving_params(params: Any, dtype: Any, wide: Tuple[str, ...],
+                   row_tables: Optional[Dict[str, str]] = None) -> Any:
     """A stored parameter tree as the inference forwards should be handed
     it: the same structure and names, each weight stored once in the type
-    the forward computes in.
+    the forward computes in, and a table that is both multiplied and
+    gathered from a second time, in the order the gather reads.
 
     A forward casts a stored weight to ``cfg.dtype`` at its use
     (``gpt2._cast``, ``.astype(cfg.dtype)`` in llama.py,
@@ -56,13 +68,33 @@ def serving_params(params: Any, dtype: Any, wide: Tuple[str, ...]) -> Any:
     module states them beside its ``init_params`` (``WIDE_PARAMS``).
     Every other leaf is cast.
 
-    One jitted call; where no leaf would change type it returns ``params``
-    itself and builds no program."""
+    ``row_tables``: top-level (rows, width) leaf -> the key of its second
+    holding (``ROW_TABLES`` of a module whose head is tied to its
+    embedding, so that one leaf has two uses).  The device holds a
+    parameter by its SHAPE, not by its uses: rows of whole lanes in row
+    order, any other width in column order, the compact one (row-major
+    tiles would pad every row: GPT-2 XL's 1,600 is 12.5 x 128).  The
+    head's matmul reads column order in place; a gather of rows cannot,
+    so every XL step copied all 161 MB of ``wte`` to row order to fetch
+    8 rows, 0.50 of its 5.88 ms (PERF.md, PR 41; ``compiled.as_text()``:
+    the parameter ``{0,1}``, a ``copy`` of it to ``{1,0}``).  So at such
+    a width the tree gains the table once more, zeros behind each row up
+    to whole lanes: that leaf is held in row order, each of the two has
+    one use, and both are read in place.  The forward cuts the zeros off
+    the rows it fetched (``gpt2._embed``): the same rows, the same
+    values.  At a width of whole lanes one leaf serves both uses and
+    nothing is added.
+
+    A jitted call for each; where no leaf would change type and no table
+    is added it returns ``params`` itself and builds no program."""
     dtype, wide = jnp.dtype(dtype), tuple(wide)
-    if all(_stays_wide(path, wide) or w.dtype == dtype
-           for path, w in jax.tree_util.tree_leaves_with_path(params)):
-        return params
-    return _cast_leaves(params, dtype=dtype, wide=wide)
+    if not all(_stays_wide(path, wide) or w.dtype == dtype
+               for path, w in jax.tree_util.tree_leaves_with_path(params)):
+        params = _cast_leaves(params, dtype=dtype, wide=wide)
+    rows = {held: _pad_rows(params[name])
+            for name, held in (row_tables or {}).items()
+            if params[name].shape[-1] % LANES and held not in params}
+    return {**params, **rows} if rows else params
 
 
 def _keep_scopes_under_checkpoint() -> None:

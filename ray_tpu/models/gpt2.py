@@ -148,6 +148,11 @@ def init_params(rng: jax.Array, cfg: GPT2Config) -> Params:
 # its scale and adds its bias in float32.  Every other leaf goes through
 # _cast at its use, so _common.serving_params may store it in cfg.dtype.
 WIDE_PARAMS = ("ln_1", "ln_2", "ln_f")
+# The head is tied to the embedding, so ``wte`` has two uses: lm_head
+# multiplies by it, _embed fetches rows of it.  The key under which
+# _common.serving_params holds it a second time for _embed, its rows
+# padded to whole lanes, at a width that is none (XL's 1,600).
+ROW_TABLES = {"wte": "wte_rows"}
 
 
 # ------------------------------------------------------------------ forward
@@ -172,7 +177,10 @@ def _cast(w: jax.Array, cfg: "GPT2Config") -> jax.Array:
 def _embed(params: Params, tokens: jax.Array, positions: jax.Array,
            cfg: "GPT2Config") -> jax.Array:
     with _scope("embed"):
-        x = _cast(params["wte"], cfg)[tokens]
+        # a serving tree's second holding of the table where it has one
+        # (the pad cut off the fetched rows: the same rows), else ``wte``
+        table = params.get(ROW_TABLES["wte"], params["wte"])
+        x = _cast(table, cfg)[tokens][..., :cfg.n_embd]
         return x + _cast(params["wpe"], cfg)[positions]
 
 

@@ -86,9 +86,12 @@ tree the runner ends up with (the caller's, ``init_params``' own in the
 model's ``param_dtype``, the shm plane's) goes through
 ``models/_common.serving_params`` in ``__init__``: every leaf the
 family's forward casts to the model's ``dtype`` is stored in that type,
-the leaves the module names in ``WIDE_PARAMS`` stay as stored.  The step
-programs take that tree (``runner.params``), so a weight is converted
-once in an engine's life and not in every program run.
+the leaves the module names in ``WIDE_PARAMS`` stay as stored, and a
+table a module with a tied head names in ``ROW_TABLES`` is held a second
+time for the embedding's gather where its rows are no whole lanes.  The
+step programs take that tree (``runner.params``), so a weight is
+converted, and a table laid out as its gather reads it, once in an
+engine's life and not in every program run.
 """
 
 from __future__ import annotations
@@ -169,11 +172,13 @@ class ModelRunner:
         if params is None:
             params = self._load_params()
         # once in a runner's life, like llm.compile absent from a window;
-        # bytes_out == bytes_in: the tree was in its serving type already
+        # bytes_out - bytes_in of a tree that was in its serving type
+        # already: the table held a second time for the gather, or 0
         with hot_span("llm.weights.prepare", self.span_s,
                       bytes_in=tree_bytes(params)) as span:
             self.params = jax.block_until_ready(serving_params(
-                params, self.mcfg.dtype, self.mod.WIDE_PARAMS))
+                params, self.mcfg.dtype, self.mod.WIDE_PARAMS,
+                getattr(self.mod, "ROW_TABLES", None)))
             # LLMEngine.stats()["param_bytes"]: what a step program reads
             self.param_bytes = tree_bytes(self.params)
             span.set(bytes_out=self.param_bytes)

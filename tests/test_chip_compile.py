@@ -241,27 +241,40 @@ def _assert_a_rows_token_may_come_from_the_last_steps_ids(text, bucket):
     assert root.count(ids) >= 2, root       # the ids, and the ids carried
 
 
-def test_serving_cell_decode_program_fits_and_gathers_nothing(
-        v5e, monkeypatch):
-    """The XL cell's whole decode step (48 layers, the weights as the
-    runner prepares them, 128 blocks, one bucket of 8), as
-    ``ModelRunner`` jits it: one Mosaic kernel in the layer scan that
-    takes the pool whole, no gather of every slot's whole table (8 x 64
-    columns = 512 blocks of 16 x 25 x 64), no operation over the pool
-    but the in-place update of the step's rows and none over a layer of
-    it, no convert of a stacked weight (handed float32 weights the
-    program cast all 48 layers in every run and held a 3.1 GB bf16 copy:
-    10.73e9 bytes), and arguments, result and temporaries together in
-    4.64e9 of the chip's 16.9e9 bytes."""
+@pytest.mark.parametrize("width,order,copied", [(1600, "{0,1:", True),
+                                                (1664, "{1,0:", False)])
+def test_the_device_holds_a_table_by_its_shape_not_by_its_use(
+        v5e, width, order, copied):
+    """What ``_common.serving_params`` rests on.  A table whose ONLY use
+    is a gather of rows is still held in column order at GPT-2 XL's
+    width (1,600 = 12.5 x 128 lanes: the compact order) and copied whole
+    to row order in every run; a second leaf of that shape would buy
+    nothing.  With its rows padded to whole lanes it is held in row
+    order and read in place."""
+    compiled = jax.jit(lambda table, ids: table[ids]).lower(
+        jax.ShapeDtypeStruct((50257, width), jnp.bfloat16, sharding=v5e),
+        jax.ShapeDtypeStruct((8,), jnp.int32, sharding=v5e)).compile()
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY "):]
+    table = next(l for l in entry.splitlines() if " parameter(0)" in l)
+    assert f"= bf16[50257,{width}]{order}" in table, table
+    assert bool(re.search(rf"= bf16\[50257,{width}\]\S* copy\(", text)) \
+        == copied
+    assert (compiled.memory_analysis().temp_size_in_bytes > 160e6) == copied
+
+
+@pytest.fixture(scope="module")
+def xl_runner(v5e):
+    """The XL cell's runner over the weights as it prepares them, as
+    shapes on the chip: bf16, and ``wte`` a second time for ``_embed``'s
+    gather, its 1,600-wide rows padded to 13 x 128 lanes."""
     import json
     from pathlib import Path
 
     from ray_tpu.models._common import serving_params
     from ray_tpu.serve.llm import EngineConfig
     from ray_tpu.serve.llm.config import resolve_model
-    from ray_tpu.serve.llm.kv_cache import device_shape
     from ray_tpu.serve.llm.model_runner import ModelRunner
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     engine = json.loads((Path(__file__).parent.parent / "perfbench" /
                          "configs" / "gpt2-xl-1558m.json").read_text()
                         )["serve"]["engine"]
@@ -271,20 +284,66 @@ def test_serving_cell_decode_program_fits_and_gathers_nothing(
     mod, mcfg = resolve_model(ecfg)
     params = jax.eval_shape(
         lambda key: serving_params(mod.init_params(key, mcfg), mcfg.dtype,
-                                   mod.WIDE_PARAMS), jax.random.key(0))
+                                   mod.WIDE_PARAMS, mod.ROW_TABLES),
+        jax.random.key(0))
     runner = ModelRunner(ecfg, params=params)
-    assert runner.params is params and runner.param_bytes < 3.2e9
+    assert runner.params is params
+    # Held twice on purpose: the token table, 3,115,843,200 B of weights
+    # and 50,257 x 1,664 x 2 = 167,255,296 B more.  The device holds a
+    # 1,600-wide table in column order, which the head's matmul reads in
+    # place and the embedding's gather cannot: one leaf for each use
+    # costs 1% of the chip and saves a 161 MB copy in every step (PR 41).
+    assert runner.param_bytes == 3_283_098_496
+    assert params["wte_rows"].shape == (50257, 1664)
 
     def on_chip(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
+    weights = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), params)
+    return runner, ecfg, mcfg, weights, on_chip
+
+
+def _assert_each_use_of_the_token_table_reads_its_own_leaf_in_place(text):
+    """No ``copy`` makes a ``bf16[50257,1600]`` (handed ``wte`` alone the
+    program laid all 161 MB of it out in row order for the gather of a
+    step's rows, ``copy.27`` of the chip's trace); the gather reads the
+    lane-padded leaf and the head's matmul ``wte``, each as the device
+    holds it (row order, column order), with no operation between."""
+    assert not re.search(r"= bf16\[50257,16\d\d\]\S* copy\(", text)
+    entry = text[text.index("ENTRY "):]
+    assert re.search(r"params__wte_rows__\S* = bf16\[50257,1664\]\{1,0:",
+                     entry)
+    assert re.search(r"params__wte__\S* = bf16\[50257,1600\]\{0,1:", entry)
+    gather = [line for line in entry.splitlines()
+              if "(%params__wte_rows__" in line]
+    head = [line for line in entry.splitlines() if "(%params__wte__" in line]
+    assert len(gather) == 1 and "/embed/gather" in gather[0], gather
+    assert len(head) == 1 and "/lm_head/" in head[0] \
+        and "dot_general" in head[0], head
+
+
+def test_serving_cell_decode_program_fits_and_gathers_nothing(
+        xl_runner, monkeypatch):
+    """The XL cell's whole decode step (48 layers, the weights as the
+    runner prepares them, 128 blocks, one bucket of 8), as
+    ``ModelRunner`` jits it: one Mosaic kernel in the layer scan that
+    takes the pool whole, no gather of every slot's whole table (8 x 64
+    columns = 512 blocks of 16 x 25 x 64), no operation over the pool
+    but the in-place update of the step's rows and none over a layer of
+    it, no convert of a stacked weight (handed float32 weights the
+    program cast all 48 layers in every run and held a 3.1 GB bf16 copy:
+    10.73e9 bytes), no copy of the token table, and arguments, result
+    and temporaries together in 4.64e9 of the chip's 16.9e9 bytes."""
+    from ray_tpu.serve.llm.kv_cache import device_shape
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, ecfg, mcfg, weights, on_chip = xl_runner
     bucket, i32 = ecfg.decode_batch_buckets[-1], jnp.int32
     pool = on_chip(device_shape(ecfg.num_blocks, mcfg.n_layer,
                                 ecfg.block_size, mcfg.n_head, mcfg.head_dim),
                    jnp.float32)
     assert pool.shape == (48, 2, 128, 16, 1664)
     compiled = runner._decode.lower(
-        pool, jax.tree.map(lambda x: on_chip(x.shape, x.dtype), params),
+        pool, weights,
         on_chip((bucket,), i32), on_chip((bucket,), i32),
         on_chip((bucket, ecfg.max_blocks_per_seq), i32),
         on_chip((bucket,), i32), on_chip((), i32),
@@ -297,12 +356,35 @@ def test_serving_cell_decode_program_fits_and_gathers_nothing(
     _assert_the_pool_is_read_in_place_and_written_by_rows(
         text, pool.shape, lanes_used=25 * 64)
     assert not re.search(r"= bf16\[48,\d+,[\d,]+\]\S* convert\(", text)
+    _assert_each_use_of_the_token_table_reads_its_own_leaf_in_place(text)
     mem = compiled.memory_analysis()
     held = mem.argument_size_in_bytes + mem.output_size_in_bytes \
         - mem.alias_size_in_bytes + mem.temp_size_in_bytes
     assert mem.alias_size_in_bytes >= 1.30e9        # the pool, donated
-    assert mem.temp_size_in_bytes < 0.2e9           # and no second one
+    # no second pool, and since PR 41 no second token table: 168,024,576 B
+    # of temporaries with ``wte`` alone, none with a leaf for each use
+    assert mem.temp_size_in_bytes < 0.01e9
     assert held < 5e9, held
+
+
+@pytest.mark.parametrize("bucket", [128, 512])
+def test_serving_cell_prefill_program_copies_no_token_table(
+        xl_runner, monkeypatch, bucket):
+    """The XL cell's prefill at the bucket most of its prompts take and at
+    its largest: the same ``_embed``, so the same two uses of the token
+    table, each of its own leaf in place (the program made the decode
+    step's 161 MB copy once a prompt too), and its temporaries fall by the
+    table's bytes."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, ecfg, _, weights, on_chip = xl_runner
+    assert bucket in ecfg.prefill_len_buckets
+    compiled = runner._prefill.lower(
+        weights, on_chip((1, bucket), jnp.int32),
+        on_chip((), jnp.int32)).compile()
+    _assert_each_use_of_the_token_table_reads_its_own_leaf_in_place(
+        compiled.as_text())
+    # 167,605,248 B with ``wte`` alone, at either bucket; 0.3e6 now
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.01e9
 
 
 # ------------------------------------------- the Falcon-H1 cell's programs

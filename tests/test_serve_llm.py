@@ -687,6 +687,16 @@ def _stored_tree(cfg):
     return mod, mcfg, mod.init_params(jax.random.key(3), mcfg)
 
 
+def _second_holdings(mod, tree):
+    """key -> shape of the tables ``serving_params`` holds a second time:
+    those a module with a tied head names whose rows are no whole lanes."""
+    from ray_tpu.models._common import LANES
+    return {held: (tree[name].shape[0],
+                   -(-tree[name].shape[1] // LANES) * LANES)
+            for name, held in getattr(mod, "ROW_TABLES", {}).items()
+            if tree[name].shape[1] % LANES}
+
+
 def _weight_converts(jaxpr, shapes):
     """convert_element_type equations, sub-programs included, that narrow
     a float32 operand of one of ``shapes``."""
@@ -710,7 +720,9 @@ def test_step_programs_convert_no_weight(model):
     """Handed the runner's tree, decode and prefill hold no convert of a
     weight; handed the stored tree, the same programs hold one a cast
     leaf (the detector can see them).  The leaves a module keeps wide
-    are as stored, all others in the compute type."""
+    are as stored, all others in the compute type, and the token table of
+    a tied head at a width of no whole lanes (gpt2:tiny's 64) is there a
+    second time."""
     import jax
     import jax.numpy as jnp
     from ray_tpu.serve.llm.model_runner import ModelRunner
@@ -718,7 +730,10 @@ def test_step_programs_convert_no_weight(model):
     mod, mcfg, stored = _stored_tree(cfg)
     runner = ModelRunner(cfg, params=stored)
     flat = jax.tree_util.tree_flatten_with_path(runner.params)[0]
-    assert len(flat) == len(jax.tree_util.tree_leaves(stored))
+    added = _second_holdings(mod, stored)
+    assert bool(added) == (model == "gpt2:tiny")
+    assert len(flat) == len(jax.tree_util.tree_leaves(stored)) + len(added)
+    assert {key: runner.params[key].shape for key in added} == added
     wide = 0
     for path, leaf in flat:
         keep = any(k.key in mod.WIDE_PARAMS for k in path)
@@ -733,7 +748,8 @@ def test_step_programs_convert_no_weight(model):
     # a stacked leaf is sliced to one layer inside the scan
     shapes = {x.shape for x in jax.tree_util.tree_leaves(stored)} \
         | {x.shape[1:] for x in jax.tree_util.tree_leaves(stored["blocks"])}
-    for tree, n_cast in ((runner.params, 0), (stored, len(flat) - wide)):
+    for tree, n_cast in ((runner.params, 0),
+                         (stored, len(flat) - len(added) - wide)):
         decode = jax.make_jaxpr(runner._decode)(
             pool, tree, i32(4), i32(4), i32(4, cfg.max_blocks_per_seq),
             i32(4), i32(), i32(4), i32(4))
@@ -793,7 +809,9 @@ def test_prepared_tree_gives_the_stored_trees_logits(model):
 def test_a_tree_in_its_serving_type_is_taken_as_it_is(model):
     """Equal stored and compute types (a prepared tree handed on; bf16
     training state, norms included): the runner's leaves are the
-    caller's own and no program is built."""
+    caller's own and no cast is built.  A prepared tree comes back as the
+    object it is; training state gains the table its gather wants, and
+    ``bytes_out - bytes_in`` of the prepare span is that table's bytes."""
     import jax
     import jax.numpy as jnp
     from ray_tpu.models import _common
@@ -804,14 +822,126 @@ def test_a_tree_in_its_serving_type_is_taken_as_it_is(model):
     assert first.span_s["llm.weights.prepare"][0] == 1
     trained = jax.tree_util.tree_map(lambda x: x.astype(jnp.bfloat16), stored)
     built = _common._cast_leaves._cache_size()
+    assert ModelRunner(cfg, params=first.params).params is first.params
     for tree in (first.params, trained):
         runner = ModelRunner(cfg, params=tree)
-        for mine, theirs in zip(jax.tree_util.tree_leaves(runner.params),
-                                jax.tree_util.tree_leaves(tree)):
+        added = {key: shape
+                 for key, shape in _second_holdings(mod, tree).items()
+                 if key not in tree}
+        assert set(runner.params) == set(tree) | set(added)
+        for mine, theirs in zip(
+                jax.tree_util.tree_leaves({k: runner.params[k] for k in tree}),
+                jax.tree_util.tree_leaves(tree)):
             assert mine is theirs
-        assert runner.param_bytes == _common.tree_bytes(tree)
+        assert runner.param_bytes - _common.tree_bytes(tree) == sum(
+            rows * width * 2 for rows, width in added.values())
         assert runner.span_s["llm.weights.prepare"][0] == 1
     assert _common._cast_leaves._cache_size() == built
+
+
+@pytest.mark.parametrize("width", [64, 128, 192, 256])
+def test_a_tied_token_table_is_held_a_second_time_for_its_gather(width):
+    """GPT-2's head is its embedding, so ``wte`` has two uses.  At a width
+    of no whole lanes the serving tree holds the table a second time: the
+    same rows bit for bit, in ``cfg.dtype``, zeros behind each up to whole
+    lanes (what the device holds in row order, so the gather of a step's
+    rows reads it in place and the head ``wte`` in place:
+    tests/test_chip_compile.py), beside the leaves it had.  ``_embed``,
+    the prefill's and the decode step's logits are the same bits from the
+    serving tree and from the stored one.  At whole lanes one leaf serves
+    both uses and the tree comes back as it is."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models import _common, gpt2
+    cfg = dataclasses.replace(gpt2.tiny(), n_embd=width)
+    assert cfg.param_dtype == jnp.float32 and cfg.dtype == jnp.bfloat16
+    stored = gpt2.init_params(jax.random.key(5), cfg)
+    served = _common.serving_params(stored, cfg.dtype, gpt2.WIDE_PARAMS,
+                                    gpt2.ROW_TABLES)
+    assert _common.serving_params(served, cfg.dtype, gpt2.WIDE_PARAMS,
+                                  gpt2.ROW_TABLES) is served
+    assert gpt2.ROW_TABLES == {"wte": "wte_rows"}
+    if width % _common.LANES == 0:
+        assert set(served) == set(stored)
+    else:
+        lanes = -(-width // _common.LANES) * _common.LANES
+        assert set(served) == set(stored) | {"wte_rows"}
+        rows = served["wte_rows"]
+        assert rows.shape == (cfg.vocab_size, lanes)
+        assert rows.dtype == served["wte"].dtype == cfg.dtype
+        np.testing.assert_array_equal(
+            np.asarray(rows[:, :width], np.float32),
+            np.asarray(served["wte"], np.float32))
+        assert not np.asarray(rows[:, width:], np.float32).any()
+        assert _common.tree_bytes(served) - _common.tree_bytes(
+            {k: served[k] for k in stored}) == cfg.vocab_size * lanes * 2
+
+    tokens = np.random.default_rng(width).integers(
+        0, cfg.vocab_size, (1, 16)).astype(np.int32)
+    at = np.asarray([5, 9], np.int32)
+    pool = jnp.zeros(kvmod.device_shape(4, cfg.n_layer, 8, cfg.n_head,
+                                        cfg.head_dim), jnp.float32)
+    tables = np.zeros((2, 2), np.int32)
+    embed = jax.jit(lambda p: gpt2._embed(p, tokens, jnp.arange(16), cfg))
+    prefill = jax.jit(lambda p: gpt2.forward_prefill(
+        p, tokens, cfg, last_pos=jnp.int32(11))[0])
+    decode = jax.jit(lambda p: gpt2.forward_decode(
+        p, tokens[0, :2], at, pool, tables, at, cfg)[0])
+    for program in (embed, prefill, decode):
+        got, want = program(served), program(stored)
+        assert got.shape == want.shape and got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("model", ["llama:tiny", "llama:tiny-moe",
+                                   "falcon_h1:tiny", "lfm2:tiny"])
+def test_a_family_that_names_no_table_gets_the_tree_it_got(model):
+    """Only a module that says its head is tied (``ROW_TABLES``) has a
+    table held twice.  Llama and Falcon-H1 have a head of their own;
+    LFM2's tied table is 2,048 wide, whole lanes, and its step copies
+    nothing (tests/test_chip_compile.py): a tree of theirs in its serving
+    type comes back as the object it is, through the runner too."""
+    import jax
+    from ray_tpu.models._common import serving_params
+    from ray_tpu.serve.llm.model_runner import ModelRunner
+    cfg = tiny_cfg(model=model)
+    mod, mcfg = resolve_model(cfg)
+    assert not hasattr(mod, "ROW_TABLES")
+    first = ModelRunner(cfg, params=mod.init_params(jax.random.key(3), mcfg))
+    assert serving_params(first.params, mcfg.dtype, mod.WIDE_PARAMS,
+                          None) is first.params
+    again = ModelRunner(cfg, params=first.params)
+    assert again.params is first.params
+    assert again.param_bytes == first.param_bytes
+
+
+@pytest.mark.parametrize("param_dtype", ["float32", "bfloat16"])
+def test_gpt2_embeds_from_a_stored_tree_as_it_did(param_dtype):
+    """``_embed`` over a tree with no second holding, training's and
+    ``forward``'s, is the program it was: the rows of ``wte`` and of
+    ``wpe`` gathered and added, nothing cut off (the XL training step's
+    digest on the described v5e: tests/test_chip_compile.py)."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models import gpt2
+    cfg = dataclasses.replace(gpt2.tiny(), param_dtype=jnp.dtype(param_dtype))
+    stored = gpt2.init_params(jax.random.key(5), cfg)
+    tokens, positions = jnp.zeros((2, 16), jnp.int32), jnp.arange(16)
+
+    def plain(p):
+        return p["wte"].astype(cfg.dtype)[tokens] \
+            + p["wpe"].astype(cfg.dtype)[positions]
+
+    def embed(p):
+        return gpt2._embed(p, tokens, positions, cfg)
+
+    assert str(jax.make_jaxpr(embed)(stored)) == \
+        str(jax.make_jaxpr(plain)(stored))
 
 
 @pytest.mark.parametrize("model", SERVED)
