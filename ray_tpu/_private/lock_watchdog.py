@@ -554,6 +554,9 @@ DONATED: Dict[str, Tuple[int, ...]] = {
     "llm_decode_step": (0,),
     "llm_prefill_state_step": (0,),
     "llm_decode_state_step": (0,),
+    # a prompt's chunk also donates the runner's staging K/V (argnum 2),
+    # which comes back in the program's result and is rebound there
+    "llm_prefill_chunk_step": (0, 2),
     "kv_write_rows": (0,),
     "kv_scatter_prefill": (0,),
     "kv_load_block": (0,),

@@ -30,6 +30,17 @@ what the code can see (the backend and the shapes), never by an option:
   padded dense view, attend, mask by ``ctx_lens``.  XLA fuses the chain;
   its cost is that of the table, not of the contexts.
 
+A list of pages.  A layer that selects (``ops/sparse_attention.py``:
+block-sparse attention that chooses its pages) hands the same call, beside
+the table, ``pages`` (B, KV, K): for each row and KV head the table
+COLUMNS it chose, and ``counts`` (B, KV): how many of them.  The kernel
+then walks that list and not the table's first ``ceil(ctx_len / bs)``
+columns, one (row, KV head) a grid step, and copies of a page the head's
+own ``D`` lanes; a position's place in the context is its column's, so the
+walk may be in any order.  The call without a list is the case "every
+page of the context, all heads together": one kernel body, and which walk
+is built follows from whether a list was given, never from an option.
+
 The pool's format, ``(L, 2, N, bs, F)`` (layer, K or V, block, position
 in the block, the position's ``KV * D`` features flat along the lanes and
 zero-padded to whole 128-lane tiles: 25 x 64 -> 1,664), belongs to its
@@ -60,6 +71,9 @@ NEG_INF = jnp.finfo(jnp.float32).min
 # blocks: one buffer of K and one of V, each double-buffered (3.3 MiB
 # in all at XL's 1,600 float32 lanes a position)
 _CHUNK_TOKENS = 128
+# and of a listed walk, whose pages are one head's lanes: 0.5 MiB of K and
+# as much of V a buffer at D = 128
+_LIST_CHUNK_TOKENS = 512
 
 
 def gather_kv(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
@@ -93,10 +107,13 @@ def heads_apart(x: jax.Array, n_kv: int, head_dim: int) -> jax.Array:
 
 
 def _paged_decode_gather(q, kv_pool, layer, block_tables, ctx_lens,
-                         k_new, v_new):
+                         k_new, v_new, pages=None, counts=None):
     """Gather-then-mask: the CPU path and the kernel's reference."""
     b, h, d = q.shape
     kvh = k_new.shape[1]
+    if pages is not None:
+        return _listed_decode_gather(q, kv_pool, layer, block_tables,
+                                     ctx_lens, k_new, v_new, pages, counts)
     with jax.named_scope("kv_layout"):
         # the layer's K and V, each (N, bs, KV, D); here a slice costs
         # nothing that matters
@@ -126,9 +143,46 @@ def _paged_decode_gather(q, kv_pool, layer, block_tables, ctx_lens,
         return out.astype(q.dtype)
 
 
-def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_new_ref,
-                   v_new_ref, pool_hbm, o_ref, k_buf, v_buf, sems, m_ref,
-                   l_ref, acc_ref, *, head_dim, kv_rows):
+def _listed_decode_gather(q, kv_pool, layer, block_tables, ctx_lens,
+                          k_new, v_new, pages, counts):
+    """The gather path under a list of pages a (row, KV head): only the
+    listed table columns are gathered, each head its own."""
+    b, h, d = q.shape
+    kvh, bs = k_new.shape[1], kv_pool.shape[3]
+    rep, n_list = h // kvh, pages.shape[2]
+    with jax.named_scope("kv_layout"):
+        k_pool, v_pool = heads_apart(kv_pool[layer], kvh, d)  # (N,bs,KV,D)
+    scale = 1.0 / math.sqrt(d)
+    with jax.named_scope("paged_gather"):
+        phys = jnp.take_along_axis(
+            block_tables, pages.reshape(b, kvh * n_list), axis=1
+        ).reshape(b, kvh, n_list)
+        head = jnp.arange(kvh)[None, :, None]
+        k_ctx = k_pool[phys, :, head]              # (B, KV, K, bs, D)
+        v_ctx = v_pool[phys, :, head]
+    with jax.named_scope("paged_attention"):
+        f32 = jnp.float32
+        pos = pages[..., None] * bs + jnp.arange(bs)            # (B,KV,K,bs)
+        valid = (pos < ctx_lens[:, None, None, None]) & (
+            jnp.arange(n_list)[None, None, :, None]
+            < counts[:, :, None, None])
+        qg = q.reshape(b, kvh, rep, d)
+        logits = jnp.einsum("bgrd,bgkpd->bgrkp", qg, k_ctx,
+                            preferred_element_type=f32) * scale
+        logits = jnp.where(valid[:, :, None], logits, NEG_INF)
+        logits = logits.reshape(b, kvh, rep, n_list * bs)
+        self_logit = jnp.einsum("bgrd,bgd->bgr", qg, k_new,
+                                preferred_element_type=f32) * scale
+        logits = jnp.concatenate([logits, self_logit[..., None]], axis=-1)
+        probs = jax.nn.softmax(logits, axis=-1)
+        out = jnp.einsum("bgrk,bgkd->bgrd", probs[..., :-1],
+                         v_ctx.astype(f32).reshape(b, kvh, n_list * bs, d))
+        out = out + probs[..., -1:] * v_new.astype(f32)[:, :, None]
+        return out.reshape(b, h, d).astype(q.dtype)
+
+
+def _decode_kernel(tables_ref, lens_ref, layer_ref, *refs, head_dim,
+                   kv_rows, listed):
     """One sequence (grid step): walk its blocks, a chunk at a time.
 
     Heads lie along the lanes: a block is (bs, F), F = KV * D padded to
@@ -142,29 +196,56 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_new_ref,
     ``pool_hbm[layer, 0 / 1, table[j]]``, each contiguous whole tiles;
     k_buf / v_buf (2, C, bs, F) are the VMEM landing buffers; sems
     (2, 2): [k|v, buffer].
+
+    ``listed``: the walk is over a list of table columns a (row, KV head)
+    and a grid step is one such pair: two more prefetched scalars lead
+    ``refs`` (pages (B, KV, K), counts (B, KV)), q_ref (1, 1, rep, D) holds
+    the head's query group as it is, a page's copy is the head's own D
+    lanes, ``pool_hbm[layer, 0 / 1, table[pages[j]], :, g D : g D + D]``,
+    and a position's place in the context is its column's.
     """
     from jax.experimental.pallas import tpu as pltpu
 
+    if listed:
+        pages_ref, counts_ref, *refs = refs
+    (q_ref, k_new_ref, v_new_ref, pool_hbm, o_ref, k_buf, v_buf, sems,
+     m_ref, l_ref, acc_ref) = refs
     b = pl.program_id(0)
     _, chunk, bs, f = k_buf.shape
     t = chunk * bs
     ctx = lens_ref[b]
     layer = layer_ref[0]
-    n_blocks = pl.cdiv(ctx, bs)
+    if listed:
+        g = pl.program_id(1)
+        n_blocks = counts_ref[b, g]
+        lanes = pl.ds(pl.multiple_of(g * head_dim, head_dim), head_dim)
+    else:
+        n_blocks = pl.cdiv(ctx, bs)
     n_chunks = pl.cdiv(n_blocks, chunk)
     hi = lax.Precision.HIGHEST      # float32 K/V stay float32 on the MXU
+
+    def column(j):
+        """The table column of the walk's j-th page, clamped: the read
+        stays inside the row when the guard fails."""
+        if listed:
+            j = pages_ref[b, g, jnp.minimum(j, pages_ref.shape[2] - 1)]
+        return jnp.minimum(j, tables_ref.shape[1] - 1)
+
+    def page(kv, blk):
+        if listed:
+            return pool_hbm.at[layer, kv, blk, :, lanes]
+        return pool_hbm.at[layer, kv, blk]
 
     def copies(c, slot):
         """The chunk's copies, each with the guard it runs under."""
         out = []
         for i in range(chunk):
             j = c * chunk + i
-            # clamped: the read stays inside the row when the guard fails
-            blk = tables_ref[b, jnp.minimum(j, tables_ref.shape[1] - 1)]
+            blk = tables_ref[b, column(j)]
             out.append((j < n_blocks, (
-                pltpu.make_async_copy(pool_hbm.at[layer, 0, blk],
+                pltpu.make_async_copy(page(0, blk),
                                       k_buf.at[slot, i], sems.at[0, slot]),
-                pltpu.make_async_copy(pool_hbm.at[layer, 1, blk],
+                pltpu.make_async_copy(page(1, blk),
                                       v_buf.at[slot, i], sems.at[1, slot]))))
         return out
 
@@ -182,16 +263,32 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_new_ref,
                 ck.wait()
                 cv.wait()
 
+    def live_positions(c, shape, axis):
+        """Which of the chunk's T positions, laid along ``axis`` of
+        ``shape``, are in the context."""
+        if not listed:
+            return c * t + lax.broadcasted_iota(jnp.int32, shape, axis) < ctx
+        at = lax.broadcasted_iota(jnp.int32, shape, axis)
+        # a listed page lies where its column says, and past the count
+        # nothing was copied
+        nth = at // bs
+        first = jnp.zeros(shape, jnp.int32)
+        for i in range(chunk):
+            first = jnp.where(nth == i, column(c * chunk + i) * bs, first)
+        return (first + at % bs < ctx) & (c * chunk + nth < n_blocks)
+
     @pl.when(n_chunks > 0)
     def _():
         start(0, 0)
 
     # the new token's own term seeds the running softmax: max = its
     # score, sum = 1, accumulator = v_new.  A padded row ends here.
-    q = q_ref[0]                                                # (R, F)
-    m_ref[...] = jnp.sum(q * k_new_ref[0], axis=-1, keepdims=True)
+    q = q_ref[0, 0] if listed else q_ref[0]                     # (R, F)
+    k_new = k_new_ref[0, 0] if listed else k_new_ref[0]
+    m_ref[...] = jnp.sum(q * k_new, axis=-1, keepdims=True)
     l_ref[...] = jnp.ones_like(l_ref)
-    acc_ref[...] = jnp.broadcast_to(v_new_ref[0], acc_ref.shape)
+    v_new = v_new_ref[0, 0] if listed else v_new_ref[0]
+    acc_ref[...] = jnp.broadcast_to(v_new, acc_ref.shape)
 
     @pl.loop(0, n_chunks)
     def _(c):
@@ -208,10 +305,8 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_new_ref,
                             preferred_element_type=jnp.float32)  # (R, T)
         # what was not copied, and the last block's tail, may hold
         # anything: select, never multiply by zero
-        live = c * t + lax.broadcasted_iota(jnp.int32, (1, t), 1) < ctx
-        s = jnp.where(live, s, NEG_INF)
-        live = c * t + lax.broadcasted_iota(jnp.int32, (t, 1), 0) < ctx
-        v = jnp.where(live, v, 0.0)
+        s = jnp.where(live_positions(c, (1, t), 1), s, NEG_INF)
+        v = jnp.where(live_positions(c, (t, 1), 0), v, 0.0)
         m = m_ref[...]
         m_next = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_next)
@@ -221,9 +316,12 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_new_ref,
         acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
             p, v, precision=hi, preferred_element_type=jnp.float32)
 
+    out = acc_ref[...] / l_ref[...]
+    if listed:
+        o_ref[0, 0] = out.astype(o_ref.dtype)
+        return
     # row (r, kv) keeps the D lanes of KV head kv; the rows of one group
     # member then sum to its (1, F) result
-    out = acc_ref[...] / l_ref[...]
     head = lax.broadcasted_iota(jnp.int32, (kv_rows, f), 1) // head_dim
     own = head == lax.broadcasted_iota(jnp.int32, (kv_rows, f), 0)
     for r in range(o_ref.shape[1]):
@@ -233,8 +331,10 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_new_ref,
 
 
 def _paged_decode_kernel(q, kv_pool, layer, block_tables, ctx_lens,
-                         k_new, v_new, *, interpret=False):
-    """The block-table walk as one Pallas call over the batch."""
+                         k_new, v_new, pages=None, counts=None, *,
+                         interpret=False):
+    """The block-table walk as one Pallas call over the batch (with
+    ``pages``: over the batch's (row, KV head) pairs)."""
     # imported where the kernel is built (flash_attention's idiom), so
     # that importing ray_tpu.ops costs a training process nothing more
     from jax.experimental.pallas import tpu as pltpu
@@ -243,6 +343,10 @@ def _paged_decode_kernel(q, kv_pool, layer, block_tables, ctx_lens,
     bs, f = kv_pool.shape[3:]
     kvh = k_new.shape[1]
     rep, f32 = h // kvh, jnp.float32
+    if pages is not None:
+        return _listed_decode_kernel(q, kv_pool, layer, block_tables,
+                                     ctx_lens, k_new, v_new, pages, counts,
+                                     interpret=interpret)
     kv_rows = -(-kvh // 8) * 8
     chunk = max(1, min(_CHUNK_TOKENS // bs, block_tables.shape[1]))
     with jax.named_scope("paged_attention"):
@@ -256,7 +360,8 @@ def _paged_decode_kernel(q, kv_pool, layer, block_tables, ctx_lens,
         qbd = jnp.pad(qbd, ((0, 0), (0, 0), (0, kv_rows - kvh), (0, 0)))
         row = lambda i, tables, lens, layer: (i, 0, 0)         # noqa: E731
         out = pl.pallas_call(
-            functools.partial(_decode_kernel, head_dim=d, kv_rows=kv_rows),
+            functools.partial(_decode_kernel, head_dim=d, kv_rows=kv_rows,
+                              listed=False),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3,
                 grid=(b,),
@@ -289,9 +394,60 @@ def _paged_decode_kernel(q, kv_pool, layer, block_tables, ctx_lens,
         return out.transpose(0, 2, 1, 3).reshape(b, h, d)
 
 
+def _listed_decode_kernel(q, kv_pool, layer, block_tables, ctx_lens, k_new,
+                          v_new, pages, counts, *, interpret=False):
+    """The same kernel body walking each (row, KV head)'s list of pages:
+    a grid step a pair, the head's query group (rep, D) as it is, a
+    page's copy the head's own D lanes."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, d = q.shape
+    bs = kv_pool.shape[3]
+    kvh = k_new.shape[1]
+    rep, f32 = h // kvh, jnp.float32
+    chunk = max(1, min(_LIST_CHUNK_TOKENS // bs, pages.shape[2]))
+    with jax.named_scope("paged_attention"):
+        qg = q.astype(f32).reshape(b, kvh, rep, d) * (1.0 / math.sqrt(d))
+        pair = lambda i, g, *prefetched: (i, g, 0, 0)          # noqa: E731
+        out = pl.pallas_call(
+            functools.partial(_decode_kernel, head_dim=d, kv_rows=1,
+                              listed=True),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=5,
+                grid=(b, kvh),
+                in_specs=[
+                    pl.BlockSpec((1, 1, rep, d), pair),
+                    pl.BlockSpec((1, 1, 1, d), pair),
+                    pl.BlockSpec((1, 1, 1, d), pair),
+                    pl.BlockSpec(memory_space=pl.ANY),
+                ],
+                out_specs=pl.BlockSpec((1, 1, rep, d), pair),
+                scratch_shapes=[
+                    pltpu.VMEM((2, chunk, bs, d), kv_pool.dtype),
+                    pltpu.VMEM((2, chunk, bs, d), kv_pool.dtype),
+                    pltpu.SemaphoreType.DMA((2, 2)),
+                    pltpu.VMEM((rep, 1), f32),
+                    pltpu.VMEM((rep, 1), f32),
+                    pltpu.VMEM((rep, d), f32),
+                ]),
+            out_shape=jax.ShapeDtypeStruct((b, kvh, rep, d), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")),
+            interpret=interpret,
+            name="paged_decode_listed",
+        )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
+          jnp.asarray(layer, jnp.int32).reshape(1),
+          pages.astype(jnp.int32), counts.astype(jnp.int32), qg,
+          k_new.astype(f32)[:, :, None], v_new.astype(f32)[:, :, None],
+          kv_pool)
+        return out.reshape(b, h, d)
+
+
 def paged_attention_decode(q: jax.Array, kv_pool: jax.Array, layer,
                            block_tables: jax.Array, ctx_lens: jax.Array,
-                           k_new: jax.Array, v_new: jax.Array) -> jax.Array:
+                           k_new: jax.Array, v_new: jax.Array,
+                           pages: jax.Array = None,
+                           counts: jax.Array = None) -> jax.Array:
     """Single-token decode attention through a block table.
 
     q:       (B, H, D)        — query for the token being decoded.
@@ -306,15 +462,22 @@ def paged_attention_decode(q: jax.Array, kv_pool: jax.Array, layer,
                                 explicitly: the pool is only read here,
                                 and the runner's program writes the new
                                 K/V into it after these reads.
+    pages, counts: (B, KV, K), (B, KV) int32 — a layer that selects: the
+                                table columns each (row, KV head) reads
+                                (its first ``counts`` entries, in any
+                                order) in place of every column of the
+                                context.
 
     Returns (B, H, D) in q.dtype.  On a TPU the blocks a context holds
     are all that is read; elsewhere every table column is gathered.
     """
     # the kernel is built for whole query groups per KV head and a
     # float32 pool, as the engine's is
+    # (a listed walk copies a head's own lanes: whole tiles of them)
     if jax.default_backend() == "tpu" and kv_pool.dtype == jnp.float32 \
-            and q.shape[1] % k_new.shape[1] == 0:
+            and q.shape[1] % k_new.shape[1] == 0 \
+            and (pages is None or q.shape[2] % 128 == 0):
         return _paged_decode_kernel(q, kv_pool, layer, block_tables,
-                                    ctx_lens, k_new, v_new)
+                                    ctx_lens, k_new, v_new, pages, counts)
     return _paged_decode_gather(q, kv_pool, layer, block_tables, ctx_lens,
-                                k_new, v_new)
+                                k_new, v_new, pages, counts)

@@ -8,7 +8,12 @@ C in groups)::
     y_t = S_t C_t
 
 ``ssd_scan`` computes it for a sequence as Dao & Gu's chunked form: inside
-a chunk a masked (Q, Q) matrix product, between chunks the carried state.
+a chunk a masked (Q, Q) matrix product, between chunks the carried state
+(and between two calls, for a caller that hands the state of the one to the
+next: ``state0``).  Lightning Attention's ``S_t = lambda S_{t-1} + k_t v_t^T,
+o_t = S_t^T q_t`` is this recurrence with ``dt = 1``, ``A = log lambda``,
+``B = k``, ``C = q``, ``x = v`` and a group a head
+(``models/minicpm_sala.py``).
 ``ssm_step`` is the two lines above for one token.  Both are plain
 ``jax.numpy`` / ``lax`` in float32 (state and decay are float32 whatever
 the activations are), and the matrix products ask for ``HIGHEST``: they
@@ -77,8 +82,11 @@ def _grouped(a: jax.Array, groups: int) -> jax.Array:
 
 
 def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
-             c: jax.Array, chunk: int) -> Tuple[jax.Array, jax.Array]:
-    """The recurrence over a sequence from a zero state, chunked.
+             c: jax.Array, chunk: int, state0: Optional[jax.Array] = None
+             ) -> Tuple[jax.Array, jax.Array]:
+    """The recurrence over a sequence, chunked, from a zero state or from
+    ``state0`` (B, H, P, N): the state a sequence's earlier positions left,
+    for a caller that runs a long sequence as several calls.
 
     x (B, T, H, P); dt (B, T, H), after the softplus and 0 wherever the
     state must not move; a (H,) negative; b, c (B, T, G, N).  Any T: it
@@ -119,7 +127,8 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         add, dec = chunk_in
         return dec[..., None, None] * state + add, state
 
-    state0 = jnp.zeros((bsz, g, h // g, p, n), f32)
+    state0 = jnp.zeros((bsz, g, h // g, p, n), f32) if state0 is None \
+        else state0.astype(f32).reshape(bsz, g, h // g, p, n)
     state, entering = lax.scan(
         carry, state0, (jnp.moveaxis(adds, 1, 0), jnp.moveaxis(whole, 1, 0)))
     entering = jnp.moveaxis(entering, 0, 1)               # (B,c,G,Hg,P,N)

@@ -72,6 +72,9 @@ class Replica:
                           "rtpu_llm_queue_seconds",
                           "rtpu_llm_tpot_seconds",
                           "rtpu_llm_moe_experts_touched",
+                          "rtpu_llm_prefill_chunks_total",
+                          "rtpu_llm_sparse_pages_read",
+                          "rtpu_llm_sparse_pages_held",
                           "rtpu_llm_tokens_total"):
                 mcat.get(_name).set_default_tags({"group": dep_key})
         self._instance = user_cls(*init_args, **init_kwargs)

@@ -158,6 +158,11 @@ class LLMEngine:
         _weights.reap_orphans()
         self.cfg = cfg
         self.runner = ModelRunner(cfg, params)
+        select = self.runner.select_spec or {}
+        if select and select["block"] != cfg.block_size:
+            raise ValueError(
+                f"{cfg.model} selects pages of {select['block']} positions: "
+                f"block_size {cfg.block_size} is not its page")
         # a family with recurrent state gets a row of it per sequence
         # slot beside the blocks, in the same holder (kv_cache.py); the
         # pool is laid out for the layers that hold K/V and the store for
@@ -166,7 +171,8 @@ class LLMEngine:
             cfg.num_blocks, self.runner.kv_layers, cfg.block_size,
             self.runner.n_kv, self.runner.head_dim, dtype=np.float32,
             state=self.runner.state_spec, max_seqs=cfg.max_num_seqs,
-            state_layers=self.runner.state_layers)
+            state_layers=self.runner.state_layers,
+            select_stride=select.get("stride", 0))
         self.runner.cache = self.cache
         self.sched = IterationScheduler(cfg.max_num_seqs,
                                         cfg.max_prefill_tokens,
@@ -205,6 +211,14 @@ class LLMEngine:
         # a layer's step has to read (loop-owned)
         self.experts_touched = 0
         self.routed_layer_steps = 0
+        # a model whose attention chooses its pages: the pages its decode
+        # steps read and the pages their rows' contexts held, over live
+        # rows, sparse layers and KV heads (the step program counts both);
+        # and the chunks of prompts run by a model that prefills in chunks
+        # (each is also one of prefill_steps) (loop-owned)
+        self.sparse_pages_read = 0
+        self.sparse_pages_held = 0
+        self.prefill_chunks = 0
         # tokens by where they were chosen (the step program's argmax for
         # a greedy request; ModelRunner.sample on a pulled row for any
         # other) and the bytes of logits pulled for the latter.
@@ -346,7 +360,15 @@ class LLMEngine:
                     f"prefill={'1' if plan.prefill is not None else '0'} "
                     f"decode={len(plan.decode)} "
                     f"free={self.cache.free_block_count()}")
-            if plan.prefill is not None:
+            if plan.prefill is not None and self.runner.chunk:
+                # one chunk of the prompt, and behind it a decode step of
+                # whoever runs (the prompt's own sequence from its last
+                # chunk on): a long prompt stalls the live rows a chunk at
+                # a time, not for the whole of it
+                self._do_prefill_chunk(plan.prefill)
+                if self.sched.running:
+                    self._do_decode(list(self.sched.running))
+            elif plan.prefill is not None:
                 # the commit first, the prefill's enqueue after it: the
                 # tokens of the step in flight are what clients wait for,
                 # and behind the prefill they would wait it out
@@ -394,6 +416,12 @@ class LLMEngine:
         with hot_span("llm.prefill", self.span_s, seq=seq.seq_id,
                       tokens=len(seq.prompt)) as span:
             tok = self._prefill_one(seq, span)
+        self._started(seq, tok, t0, span)
+
+    def _started(self, seq: Sequence, tok: Optional[int], t0: float,
+                 span: hot_span) -> None:
+        """A prefilled sequence joins the running ones with its first
+        token (None: it did not start)."""
         if tok is None:
             return
         if seq.trace is not None:
@@ -408,9 +436,77 @@ class LLMEngine:
         self._count_tokens(len(seq.prompt), phase="prefill")
         self._maybe_finish(seq)
 
+    def _do_prefill_chunk(self, seq: Sequence) -> None:
+        """The next chunk of ``seq``'s prompt (a model that prefills in
+        chunks).  The first takes the sequence's blocks and row and makes
+        it the scheduler's ``prefilling``; the last reads the first token
+        and commits the prompt as ``_do_prefill`` does."""
+        runner = self.runner
+        if self.sched.prefilling is not seq:
+            try:
+                self.cache.alloc_seq(seq.seq_id, seq.ctx_len)
+            except NoFreeBlocks:
+                self.sched.waiting.appendleft(seq)
+                return
+            self.sched.prefilling = seq
+            seq.chunks_done, seq.chunk_flight = 0, None
+            self._note_admission(seq)
+        last = seq.chunks_done + 1 == runner.prefill_chunks(len(seq.prompt))
+        if last:
+            # the first token is read below: the step in flight first,
+            # whose tokens clients wait for
+            self._drain("admit")
+            t0 = time.time()
+            with hot_span("llm.prefill", self.span_s, seq=seq.seq_id,
+                          tokens=len(seq.prompt),
+                          chunks=seq.chunks_done + 1) as span:
+                tok = self._prefill_one(seq, span)
+            self._started(seq, tok, t0, span)
+        else:
+            self._run_chunk(seq)
+
+    def _run_chunk(self, seq: Sequence) -> bool:
+        """Enqueue ``seq``'s next chunk; False when it failed (the sequence
+        is finished as failed and nothing is prefilling)."""
+        try:
+            seq.chunk_flight = self.runner.prefill_chunk(
+                seq.prompt, seq.chunks_done, after=seq.chunk_flight)
+        except Exception as e:  # noqa: BLE001 - surface to the caller
+            self.sched.prefilling = None
+            self.cache.free_seq(seq.seq_id)
+            self._finish(seq, FAILED, f"prefill failed: {e!r}")
+            return False
+        seq.chunks_done += 1
+        self.prefill_chunks += 1
+        with self._lock:
+            self.prefill_steps += 1
+        if GLOBAL_CONFIG.metrics_enabled:
+            mcat.get("rtpu_llm_prefill_chunks_total").inc(
+                tags={"model": self.cfg.model})
+        return True
+
     def _prefill_one(self, seq: Sequence, span: hot_span) -> Optional[int]:
         """Blocks, the model's prefill, the scatter into the pool and the
-        first token; None when the sequence did not start."""
+        first token; None when the sequence did not start.  For a model
+        that prefills in chunks: the prompt's last chunk, the sequence
+        having its blocks since its first."""
+        if self.runner.chunk:
+            if not self._run_chunk(seq):
+                return None
+            self.sched.prefilling = None
+            try:
+                chosen, ks, vs = self.runner.prefill_result(
+                    len(seq.prompt), seq.chunk_flight,
+                    _sampled_rows([seq.sampling]))
+            except Exception as e:  # noqa: BLE001 - surface to the caller
+                self.cache.free_seq(seq.seq_id)
+                self._finish(seq, FAILED, f"prefill failed: {e!r}")
+                return None
+            finally:
+                seq.chunk_flight = None
+            with self._lock:
+                self._count_chosen_locked(chosen)
+            return self._scattered(seq, chosen, ks, vs)
         try:
             self.cache.alloc_seq(seq.seq_id, seq.ctx_len)
         except NoFreeBlocks:
@@ -430,6 +526,11 @@ class LLMEngine:
         with self._lock:
             self.prefill_steps += 1
             self._count_chosen_locked(chosen)
+        return self._scattered(seq, chosen, ks, vs)
+
+    def _scattered(self, seq: Sequence, chosen: Chosen, ks, vs) -> int:
+        """The prompt's K/V into its blocks (and its state to its row);
+        the first token."""
         # K/V never left the device: the scatter is the enqueue of a
         # second device program; with recurrent state it also commits the
         # prompt's to the sequence's row, which the span then names
@@ -624,6 +725,15 @@ class LLMEngine:
             if GLOBAL_CONFIG.metrics_enabled:
                 mcat.get("rtpu_llm_moe_experts_touched").observe(
                     chosen.touched / layers, tags={"model": self.cfg.model})
+        if chosen.pages is not None:
+            read, held = chosen.pages
+            span.set(sparse_pages_read=read, sparse_pages_held=held)
+            self.sparse_pages_read += read
+            self.sparse_pages_held += held
+            if GLOBAL_CONFIG.metrics_enabled:
+                tags = {"model": self.cfg.model}
+                mcat.get("rtpu_llm_sparse_pages_read").inc(read, tags=tags)
+                mcat.get("rtpu_llm_sparse_pages_held").inc(held, tags=tags)
         discarded = 0
         with hot_span("llm.decode.commit", self.span_s):
             for i, s in enumerate(flight.batch):
@@ -883,6 +993,14 @@ class LLMEngine:
                                    if it[0].seq_id not in cancelled)
         for seq in dropped:     # block free OUTSIDE _lock (leaf locks
             self.cache.free_seq(seq.seq_id)    # must never nest)
+        part_way = self.sched.prefilling
+        if part_way is not None and part_way.seq_id in cancelled:
+            # its chunks so far are in the staging, which the next prompt
+            # overwrites; blocks and row go back
+            self.sched.prefilling = None
+            part_way.chunk_flight = None
+            self.cache.free_seq(part_way.seq_id)
+            self.sched.finish(part_way, FINISHED)
         for seq in [s for s in self.sched.running
                     if s.seq_id in cancelled]:
             self.cache.free_seq(seq.seq_id)
@@ -1004,7 +1122,10 @@ class LLMEngine:
                     preemptions=self.preemptions,
                     tokens_out=self.tokens_out,
                     running=len(self.sched.running),
-                    waiting=len(self.sched.waiting),
+                    # a prompt part-way through its chunks is not running
+                    # yet: it waits for its first token
+                    waiting=len(self.sched.waiting)
+                    + (self.sched.prefilling is not None),
                     blocks_free=self.cache.free_block_count(),
                     compiles=self.runner.compiles,
                     param_bytes=self.runner.param_bytes,
@@ -1027,5 +1148,10 @@ class LLMEngine:
                     state_layers=self.cache.state_layers,
                     experts_touched=self.experts_touched,
                     routed_layer_steps=self.routed_layer_steps,
+                    prefill_chunks=self.prefill_chunks,
+                    sparse_pages_read=self.sparse_pages_read,
+                    sparse_pages_held=self.sparse_pages_held,
+                    select_bytes=self.cache.select_bytes,
+                    staging_bytes=self.runner.staging_bytes,
                     span_s={k: list(v) for k, v in
                             list(self.span_s.items())})

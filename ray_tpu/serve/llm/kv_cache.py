@@ -69,6 +69,28 @@ in ``cache_layers(cfg)``).  Each kind is numbered among its own in layer
 order; a pool laid out for all the layers of such a model would be mostly
 empty and the decode kernel's layer index wrong.  Nothing else here knows:
 a row is a row and a block a block.
+
+A selector's cache.  A family whose attention chooses the pages it reads
+(``models/minicpm_sala.py``: block-sparse attention over compressed keys)
+holds a third thing, which, unlike a row of state, grows with the context:
+for every ``stride`` positions of every page of every layer that holds
+K/V, the sum of those positions' keys (a *half-kernel*:
+``ops/sparse_attention.py`` pools a compressed key from two neighbouring
+ones, so no pooled key straddles a page in storage even where its window
+does).  The cache is built with the stride (``select_stride=``, from the
+module's ``page_selector(cfg)``) and the holder then holds ``{"kv", "state",
+"sel"}``, ``sel`` :func:`selector_shape` = ``(n_layer, num_blocks,
+block_size / stride, F)``: paged by the sequence's own block table, so a
+page's slots come and go with the page (``alloc_seq``, ``free_seq``,
+preemption) and cost no allocator of their own.  It is written by the
+cache's own writers and by nobody else: ``scatter_prefill`` computes a
+prompt's slots from the K it scatters, and ``write_rows`` (the decode
+step's write, ``write_token``) sums the slot a new row falls in again
+from the pool's rows, so writing a row twice changes nothing and no
+caller can write K and forget its slot.  (``load_block`` writes none: the
+one family with a selector keeps state rows too, and the engine refuses to
+attach a sequence of such a model.)  A family that names no selector gets
+the holder and the programs it had.
 """
 
 from __future__ import annotations
@@ -138,6 +160,12 @@ def _with_kv(held, kv):
     return {**held, "kv": kv} if isinstance(held, dict) else kv
 
 
+def _sel(held):
+    """The selector's cache out of what a :class:`DevicePool` holds; None
+    for a family that keeps none."""
+    return held.get("sel") if isinstance(held, dict) else None
+
+
 def device_shape(num_blocks: int, n_layer: int, block_size: int,
                  n_kv: int, head_dim: int) -> tuple:
     """The pool's shape on the device, ``(L, 2, N, bs, F)``: the one
@@ -149,9 +177,49 @@ def device_shape(num_blocks: int, n_layer: int, block_size: int,
     return (n_layer, 2, num_blocks, block_size, f + -f % 128)
 
 
-def write_rows(pool, blocks, offsets, k, v):
+def selector_shape(pool_shape: tuple, stride: int) -> tuple:
+    """The selector's cache beside a pool of ``pool_shape``: ``(L, N, bs /
+    stride, F)``, a half-kernel (``ops/sparse_attention.py``) for every
+    ``stride`` positions of every page of every layer that holds K/V."""
+    n_layer, _, num_blocks, bs, f = pool_shape
+    return (n_layer, num_blocks, bs // stride, f)
+
+
+def _halves_rewritten(sel, pool, blocks, offsets):
+    """The half-kernels that rows just written fall in, summed again from
+    the pool: slot ``(blocks[r], offsets[r] // stride)`` of every layer
+    becomes the sum of the K rows of its ``stride`` positions up to
+    ``offsets[r]`` (what lies behind it in the page is an earlier owner's).
+    From the pool and not from the row's K alone, so that writing a row a
+    second time changes nothing, as for the K/V itself."""
+    import jax.numpy as jnp
+    n_layer, num_blocks, per_page, f = sel.shape
+    bs = pool.shape[3]
+    stride = bs // per_page
+    first = offsets // stride * stride
+    within = jnp.arange(stride)
+    per_slab = num_blocks * bs
+    at = jnp.minimum(blocks, num_blocks - 1) * bs + first         # (R,)
+    rows = (2 * jnp.arange(n_layer)[:, None, None] * per_slab
+            + at[None, :, None] + within)                        # (L, R, s)
+    keys = pool.reshape(-1, f)[rows.reshape(-1)].reshape(*rows.shape, f)
+    keep = (within[None, :] <= (offsets - first)[:, None])[None, :, :, None]
+    halves = jnp.where(keep, keys, 0.0).sum(2)                    # (L, R, F)
+    slot = blocks * per_page + offsets // stride
+    slots = jnp.arange(n_layer)[:, None] * (num_blocks * per_page) + slot
+    slots = jnp.where(blocks < num_blocks, slots,
+                      n_layer * num_blocks * per_page)
+    flat = sel.reshape(-1, f).at[slots.reshape(-1)].set(
+        halves.reshape(-1, f).astype(sel.dtype), mode="drop")
+    return flat.reshape(sel.shape)
+
+
+def write_rows(pool, blocks, offsets, k, v, sel=None):
     """``pool[:, 0 / 1, blocks[r], offsets[r]] = k / v[:, r]`` for every
-    row ``r``, cast to the pool's type; traceable.
+    row ``r``, cast to the pool's type; traceable.  With ``sel`` (the
+    selector's cache of a family that has one) the half-kernels those rows
+    fall in are brought up to date from the K just written, and the result
+    is ``(pool, sel)``: whoever writes K writes them.
 
     k, v: (L, R, KV, D), in head form as the models return them; they
     are laid flat along the lanes and zero-padded to the pool's ``F``
@@ -179,7 +247,10 @@ def write_rows(pool, blocks, offsets, k, v):
     rows = jnp.where(blocks < num_blocks, rows, 2 * n_layer * per_slab)
     flat = pool.reshape(-1, f).at[rows.reshape(-1)].set(
         kv.reshape(-1, f).astype(pool.dtype), mode="drop")
-    return flat.reshape(pool.shape)
+    pool = flat.reshape(pool.shape)
+    if sel is None:
+        return pool
+    return pool, _halves_rewritten(sel, pool, blocks, offsets)
 
 
 @functools.cache
@@ -195,10 +266,19 @@ def _programs() -> SimpleNamespace:
     from jax import lax
 
     from ray_tpu.ops.paged_attention import heads_apart, lane_flat
+    from ray_tpu.ops.sparse_attention import halves_of
+
+    def _written(held, blocks, offsets, k, v):
+        """``held`` with the rows written, and the half-kernels they fall
+        in where it keeps a selector's cache."""
+        if _sel(held) is not None:
+            pool, sel = write_rows(held["kv"], blocks, offsets, k, v,
+                                   held["sel"])
+            return {**held, "kv": pool, "sel": sel}
+        return _with_kv(held, write_rows(_kv(held), blocks, offsets, k, v))
 
     def _write_rows(held, blocks, offsets, k, v):
-        return _with_kv(held, write_rows(_kv(held), blocks, offsets, k, v)), \
-            None
+        return _written(held, blocks, offsets, k, v), None
 
     def _scatter_prefill(held, table, ks, vs, n_tokens, *row):
         # token t of the padded prompt -> slot t % bs of block table[t // bs];
@@ -208,6 +288,23 @@ def _programs() -> SimpleNamespace:
         with jax.named_scope("kv_write"):
             t = jnp.arange(ks.shape[1])
             blocks = jnp.where(t < n_tokens, table[t // bs], num_blocks)
+            sel = _sel(held)
+            if sel is not None:
+                # a prompt's half-kernels from its K whole, a slot each
+                # ``stride`` positions; the last may be part of one, which
+                # the decode steps' writes complete
+                per_page, f = sel.shape[2:]
+                stride = bs // per_page
+                halves = halves_of(lane_flat(ks, f), n_tokens, stride)
+                first = jnp.arange(0, ks.shape[1], stride)
+                slots = table[first // bs] * per_page + first % bs // stride
+                slots = jnp.arange(sel.shape[0])[:, None] \
+                    * (num_blocks * per_page) + slots
+                slots = jnp.where(first < n_tokens, slots, sel.size // f)
+                held = {**held, "sel": sel.reshape(-1, f).at[
+                    slots.reshape(-1)].set(
+                        halves.reshape(-1, f).astype(sel.dtype),
+                        mode="drop").reshape(sel.shape)}
             held = _with_kv(held, write_rows(pool, blocks, t % bs, ks, vs))
         if row:
             # recurrent state: the prompt's, which its prefill left in the
@@ -258,9 +355,12 @@ class DevicePool:
     for the enqueue only, and the device runs the programs in the order
     they were enqueued."""
 
-    def __init__(self, shape, dtype, state=None):
+    def __init__(self, shape, dtype, state=None, sel=None):
         self.shape, self.dtype = tuple(shape), dtype
         self.state = state
+        # the selector's cache (a ShapeDtypeStruct), for a family that
+        # chooses its pages: one more entry of what is held, ``"sel"``
+        self.sel = sel
         self._pool_lock = threading.Lock()
         self._array = None                             # guarded by: _pool_lock
         self.fill(0)
@@ -270,7 +370,7 @@ class DevicePool:
         that takes it, without the array)."""
         import jax
         kv = jax.ShapeDtypeStruct(self.shape, self.dtype)
-        return kv if self.state is None else {"kv": kv, "state": self.state}
+        return self._held(kv, lambda s: s)
 
     def donate(self, program, *args):
         """Run ``program(array, *args) -> (array, result)``, which donates
@@ -302,10 +402,23 @@ class DevicePool:
         with self._pool_lock:
             for old in jax.tree.leaves(self._array):
                 old.block_until_ready().delete()
-            self._array = jnp.full(self.shape, value, self.dtype)
-            if self.state is not None:
-                self._array = {"kv": self._array, "state": jax.tree.map(
-                    lambda s: jnp.full(s.shape, value, s.dtype), self.state)}
+            self._array = self._held(
+                jnp.full(self.shape, value, self.dtype),
+                lambda s: jnp.full(s.shape, value, s.dtype))
+
+    def _held(self, kv, made):
+        """What the holder holds around ``kv``: the array alone, or with
+        the store and the selector's cache, each leaf ``made`` from its
+        description."""
+        import jax
+        if self.state is None and self.sel is None:
+            return kv
+        held = {"kv": kv}
+        if self.state is not None:
+            held["state"] = jax.tree.map(made, self.state)
+        if self.sel is not None:
+            held["sel"] = made(self.sel)
+        return held
 
 
 class PagedKVCache:
@@ -313,13 +426,16 @@ class PagedKVCache:
 
     def __init__(self, num_blocks: int, n_layer: int, block_size: int,
                  n_kv: int, head_dim: int, dtype=np.float32, *,
-                 state=None, max_seqs: int = 0, state_layers=None):
+                 state=None, max_seqs: int = 0, state_layers=None,
+                 select_stride: int = 0):
         """``n_layer``: the layers that hold K/V.  ``state``: one
         sequence's recurrent state in one layer, name ->
         ``ShapeDtypeStruct`` (a model module's ``recurrent_state``), for a
         family that has one; the store then has ``max_seqs`` rows and one
         for staging, in each of ``state_layers`` layers (None: as many as
-        hold K/V)."""
+        hold K/V).  ``select_stride``: the positions a half-kernel of the
+        selector's cache pools (a model module's ``page_selector``), for a
+        family whose attention chooses its pages; 0: none is kept."""
         self.num_blocks = num_blocks
         self.block_shape = (n_layer, 2, block_size, n_kv, head_dim)
         self.block_size = block_size
@@ -350,7 +466,18 @@ class PagedKVCache:
         # bytes: the lanes that pad F (LLMEngine.stats()["kv_lane_pad_bytes"])
         self.lane_pad_bytes = int(np.prod(shape)) * self.dtype.itemsize \
             - num_blocks * self.block_nbytes
-        self.pool = DevicePool(shape, self.dtype, state=store)
+        sel = None
+        if select_stride:
+            import jax
+            if block_size % select_stride:
+                raise ValueError(
+                    f"pages of {block_size} positions are no whole number "
+                    f"of the selector's {select_stride}")
+            sel = jax.ShapeDtypeStruct(selector_shape(shape, select_stride),
+                                       self.dtype)
+        self.select_bytes = int(np.prod(sel.shape)) * self.dtype.itemsize \
+            if sel is not None else 0
+        self.pool = DevicePool(shape, self.dtype, state=store, sel=sel)
         # one step region for the writers below (DESIGN.md §4q): a
         # scatter program per prefill bucket, write_token, load_block
         self._write_budget = compile_budget("llm.kv_write")
@@ -498,6 +625,11 @@ class PagedKVCache:
     def seq_ids(self) -> List[str]:
         with self._lock:
             return list(self._tables)
+
+    def half_kernels(self) -> np.ndarray:
+        """The selector's cache, ``(L, N, halves a page, F)``, copied from
+        the device (the tests)."""
+        return np.asarray(self.pool.read(lambda held: held["sel"]))
 
     def state_rows_used(self) -> int:
         with self._lock:

@@ -11,7 +11,8 @@ cold starts are XLA compiles; bounding them is the TPU-serving
 equivalent of connection pooling).
 
 The runner is model-family-agnostic: ``models/gpt2.py``,
-``models/llama.py``, ``models/falcon_h1.py`` and ``models/lfm2.py`` each
+``models/llama.py``, ``models/falcon_h1.py``, ``models/lfm2.py`` and
+``models/minicpm_sala.py`` each
 export ``forward_prefill`` / ``forward_decode`` (the decode step reads the paged
 pool through ``ops/paged_attention.py`` and returns the new token's K/V);
 the runner's decode program then writes that K/V into the pool, which it
@@ -65,6 +66,36 @@ its ``recurrent_state``: the runner reads it into ``kv_layers`` /
 ``state_layers`` and the engine builds the pool for the one count and the
 store for the other.  Without that export both are ``n_layer`` (the store's
 only where there is state), which is what every other family has.
+
+A selector's cache.  A module whose attention chooses the pages it reads
+(``models/minicpm_sala.py``; ``ops/sparse_attention.py``) exports
+``page_selector(cfg)`` -> ``{"stride", "block"}``: the positions one slot
+of the selector's cache pools, and the page the selection wants (the
+engine refuses another ``block_size``).  The runner offers it as
+``select_spec``; the holder then has a third entry, ``"sel"``
+(``kv_cache.py``), the decode program hands it to the forward read-only
+(``selector=``) and writes the new K's slot with the K, in
+``write_rows``; the forward returns the pages its sparse layers read and
+the pages its rows' contexts held, two numbers that ride behind the ids
+(``Chosen.pages``), as the count of touched experts does.
+
+A prompt in chunks.  A module that exports ``forward_prefill_chunk`` (and
+``prefill_staging``) has ONE prefill program, of ``cfg.prefill_chunk``
+positions, whatever the prompt's length: ``prefill_chunk(token_ids,
+index)`` runs chunk ``index`` over a staging K/V of the largest bucket's
+positions, which the runner keeps and the program takes donated and
+returns, and over the store's staging row, which carries the recurrent
+state from chunk to chunk; positions past the prompt's end move neither.
+``prefill(prompt)`` is those chunks one behind another and
+``prefill_result`` after the last: same signature, same results (logits,
+K/V of the bucket's length out of the staging, the state in the staging
+row), so ``scatter_prefill`` commits a chunked prompt as any other.  The
+prefill buckets of such a family are lengths in whole chunks and cost a
+scatter program each, not a model program.  The engine's loop calls
+``prefill_chunk`` itself, one an iteration with a decode step of the live
+rows behind it (``engine.py``), and ``after=`` keeps one chunk in flight.
+One prompt is in prefill at a time: a chunk 0 starts the next.  A module
+without the export has the one-program prefill it had.
 
 The hand-over of the choice of experts.  A module that routes exports
 ``routed_layers(cfg)`` -> ``{"layers": n, "k": k}`` (None for a preset
@@ -130,6 +161,10 @@ class Chosen(NamedTuple):
     # a decode step of a module that routes: the distinct experts its
     # live rows chose, summed over the routed layers
     touched: Optional[int] = None
+    # a decode step of a module that chooses its pages: (pages its sparse
+    # layers read, pages its rows' contexts hold), each summed over live
+    # rows, sparse layers and KV heads
+    pages: Optional[Tuple[int, int]] = None
 
     def token(self, row: int, sp: SamplingParams, step: int) -> int:
         """The row's next token: the device's choice for a greedy
@@ -202,6 +237,25 @@ class ModelRunner:
         describe = getattr(self.mod, "routed_layers", None)
         self.route_spec = describe(self.mcfg) if describe else None
         self.choices = None
+        # what the cache keeps a page for a module whose attention chooses
+        # its pages ({"stride", "block"}; None: every page is read), and
+        # the positions of one prefill program for a module that runs a
+        # prompt in chunks (0: a prompt is one program of its bucket)
+        describe = getattr(self.mod, "page_selector", None)
+        self.select_spec = describe(self.mcfg) if describe else None
+        self.chunk = self.mcfg.prefill_chunk \
+            if hasattr(self.mod, "forward_prefill_chunk") else 0
+        if self.select_spec and self.state_spec is None:
+            raise NotImplementedError(
+                f"{cfg.model} chooses its pages and holds no recurrent "
+                "state: the selector's cache is read and written by the "
+                "step of a module with state rows (decode_state_step)")
+        if self.chunk and any(b % self.chunk
+                              for b in cfg.prefill_len_buckets):
+            raise ValueError(
+                f"{cfg.model} prefills in chunks of {self.chunk} positions: "
+                f"its prefill buckets {cfg.prefill_len_buckets} are lengths "
+                "in whole chunks")
         asked = {"choices": True} if self.route_spec else {}
         forward_prefill = partial(self.mod.forward_prefill, cfg=self.mcfg,
                                   **asked)
@@ -236,17 +290,19 @@ class ModelRunner:
                                                    last_pos=last_pos)
             return (logits, greedy(logits)), ks[:, 0], vs[:, 0], *ids
 
-        def new_kv_written(pool, k, v, block_tables, ctx_lens, n_real):
+        def new_kv_written(pool, k, v, block_tables, ctx_lens, n_real,
+                           sel=None):
             # a row's new K/V goes to the slot append_slot reserved,
             # (table[ctx // bs], ctx % bs).  Rows padded up to the bucket
-            # are sent out of range: they write nowhere
+            # are sent out of range: they write nowhere.  With the
+            # selector's cache: (pool, sel), the half-kernels written too
             bs = cfg.block_size
             with jax.named_scope("kv_write"):
                 rows = jnp.arange(ctx_lens.shape[0])
                 blocks = jnp.where(rows < n_real,
                                    block_tables[rows, ctx_lens // bs],
                                    cfg.num_blocks)
-                return write_rows(pool, blocks, ctx_lens % bs, k, v)
+                return write_rows(pool, blocks, ctx_lens % bs, k, v, sel)
 
         widest = cfg.decode_batch_buckets[-1]
 
@@ -257,16 +313,19 @@ class ModelRunner:
                 return jnp.where(src >= 0, last_ids[jnp.maximum(src, 0)],
                                  tokens)
 
-        def chosen_from(logits, chose=()):
+        def chosen_from(logits, chose=(), pages=None):
             # (logits, ids), and the ids at the width every bucket's
             # program takes them back at.  ``chose``: a routing module's
             # expert ids; the count of those its live rows touched rides
-            # behind the ids, in the pull there is
+            # behind the ids, in the pull there is.  ``pages``: a
+            # selecting module's two counts of pages, behind that
             ids = greedy(logits)
             pad = widest - ids.shape[0]
             carry = jnp.pad(ids, (0, pad)) if pad else ids
             if chose:
                 ids = jnp.concatenate([ids, touched(chose[0])[None]])
+            if pages is not None:
+                ids = jnp.concatenate([ids, pages])
             return (logits, ids), carry
 
         def decode_step(pool, params, tokens, positions, block_tables,
@@ -292,15 +351,36 @@ class ModelRunner:
         def decode_state_step(held, params, tokens, positions, block_tables,
                               ctx_lens, n_real, last_ids, src, state_rows):
             # as decode_step, and the model steps the rows of the store
-            # that state_rows names
+            # that state_rows names.  A module that chooses its pages reads
+            # the selector's cache and says, last of its results, how many
+            # pages it read; the new K's half-kernels are written with the K
+            sel = held["sel"] if self.select_spec else None
+            reads = {} if sel is None else {"selector": sel}
             logits, k, v, store, *ids = forward_decode(
                 params, tokens_in(tokens, last_ids, src), positions,
                 held["kv"], block_tables, ctx_lens, state=held["state"],
-                rows=state_rows, **live_rows(tokens, n_real))
+                rows=state_rows, **live_rows(tokens, n_real), **reads)
+            pages = None if sel is None else ids.pop()
             pool = new_kv_written(held["kv"], k, v, block_tables, ctx_lens,
-                                  n_real)
-            return {"kv": pool, "state": store}, (
-                *chosen_from(logits, ids), k, v, *ids)
+                                  n_real, sel)
+            if sel is None:
+                held = {"kv": pool, "state": store}
+            else:
+                held = {"kv": pool[0], "state": store, "sel": pool[1]}
+            return held, (*chosen_from(logits, ids, pages), k, v, *ids)
+
+        def prefill_chunk_step(held, params, staging, toks, start, n_total):
+            # one chunk of one prompt: K/V and half-kernels into the
+            # staging, the state from the store's staging row and back
+            # into it; the logits are those of the prompt's last position
+            # once a chunk holds it
+            logits, staging, state = self.mod.forward_prefill_chunk(
+                params, toks, self.mcfg, start, n_total, staging,
+                jax.tree.map(lambda s: s[:, -1], held["state"]))
+            store = jax.tree.map(lambda s, new: s.at[:, -1].set(new),
+                                 held["state"], state)
+            return {**held, "state": store}, (
+                staging, (logits, greedy(logits)))
 
         # bound to a name of its own: jaxlint pins a donating jit by the
         # name it is assigned to (lock_watchdog.DONATED)
@@ -315,6 +395,20 @@ class ModelRunner:
                                             donate_argnums=(0,))
             self._prefill = llm_prefill_state_step
             self._decode = llm_decode_state_step
+        self.staging_bytes = 0
+        if self.chunk:
+            # the staging is donated with the holder and comes back in the
+            # program's result: the runner keeps it from chunk to chunk
+            llm_prefill_chunk_step = jax.jit(prefill_chunk_step,
+                                             donate_argnums=(0, 2))
+            self._prefill_chunk = llm_prefill_chunk_step
+            positions = -(-cfg.prefill_len_buckets[-1] // self.chunk) \
+                * self.chunk
+            # made with the first chunk: a runner over shapes alone (a
+            # compile for a described chip) holds none
+            self.staging_spec = self.mod.prefill_staging(self.mcfg, positions)
+            self._staging = None
+            self.staging_bytes = tree_bytes(self.staging_spec)
         # one named row of a step's logits, for a request that samples:
         # built with the first such row a bucket meets, not before
         self._logits_row = jax.jit(lambda logits, row: logits[row])
@@ -370,6 +464,11 @@ class ModelRunner:
         import jax.numpy as jnp
         n = len(token_ids)
         tb = _bucket(n, self.cfg.prefill_len_buckets)
+        if self.chunk:
+            picked = None
+            for index in range(self.prefill_chunks(n)):
+                picked = self.prefill_chunk(token_ids, index)
+            return self.prefill_result(n, picked, logit_rows)
         compiling = self._note_shape("prefill", tb)
         toks = np.zeros((1, tb), np.int32)
         toks[0, :n] = token_ids
@@ -397,6 +496,63 @@ class ModelRunner:
                     held + abstract((self.params, toks, last_pos)))
                 if self.cache is not None:
                     self.cache.warm_scatter(ks, vs)
+        out = self._pull("llm.prefill.pull", picked, 1, logit_rows)
+        return (out[0] if logit_rows is None else out), ks, vs
+
+    # ------------------------------------------------------ prefill in chunks
+    def prefill_chunks(self, n_tokens: int) -> int:
+        """The chunks a prompt of ``n_tokens`` runs as."""
+        return -(-n_tokens // self.chunk)
+
+    def prefill_chunk(self, token_ids, index: int, after=None):
+        """Enqueue chunk ``index`` of one prompt (a module with
+        ``forward_prefill_chunk``): one program whatever the prompt's
+        length or the chunk's place in it, over the runner's staging K/V
+        and the store's staging row, which carry what the chunks before
+        left.  One prompt at a time: a chunk 0 starts the next.  Returns
+        what :meth:`prefill_result` takes, on the device.
+
+        ``after``: the chunk before, waited for inside this call's
+        ``llm.prefill.chunk`` span before this one is enqueued: a caller
+        that enqueues other work between two chunks (the engine's loop: a
+        decode step) then keeps one chunk in flight, and the span is about
+        as long as a chunk takes."""
+        import jax
+        c, n = self.chunk, len(token_ids)
+        part = token_ids[index * c:(index + 1) * c]
+        toks = np.zeros((1, c), np.int32)
+        toks[0, :len(part)] = part
+        compiling = self._note_shape("prefill_chunk", c)
+        if self._staging is None:
+            self._staging = jax.tree.map(
+                lambda s: jax.numpy.zeros(s.shape, s.dtype),
+                self.staging_spec)
+        args = (self.params, self._staging, toks, np.int32(index * c),
+                np.int32(n))
+        pool = self._state_cache().pool
+        if compiling is not _SEEN:
+            register_program(f"llm.prefill.chunk.{c}", self._prefill_chunk,
+                             (pool.abstract(), *abstract(args)))
+        with compiling, hot_span("llm.prefill.chunk", self.span_s,
+                                 chunk=index, tokens=n), \
+                self._prefill_budget:
+            if after is not None:
+                np.asarray(after[1])    # its greedy id: 4 bytes, the wait
+            self._staging, picked = pool.donate(self._prefill_chunk, *args)
+        return picked
+
+    def prefill_result(self, n_tokens: int, picked, logit_rows=None):
+        """What :meth:`prefill` returns, after a prompt's last chunk: its
+        logits (or ``Chosen``) and the prompt's K/V out of the staging,
+        ``(L, bucket, KV, D)`` on the device."""
+        tb = _bucket(n_tokens, self.cfg.prefill_len_buckets)
+        heads = (self.n_kv, self.head_dim)
+        ks, vs = (self._staging[name][:, :tb].reshape(
+            self.kv_layers, tb, *heads) for name in ("k", "v"))
+        if ("scatter", tb) not in self._shapes_seen and \
+                self.cache is not None:
+            self._shapes_seen.add(("scatter", tb))
+            self.cache.warm_scatter(ks, vs)
         out = self._pull("llm.prefill.pull", picked, 1, logit_rows)
         return (out[0] if logit_rows is None else out), ks, vs
 
@@ -476,11 +632,11 @@ class ModelRunner:
         ``llm.decode.pull`` span that says which step it is."""
         return self._pull("llm.decode.pull", step.picked, step.n,
                           step.logit_rows, touched=self.route_spec is not None,
-                          step=step.step)
+                          paged=self.select_spec is not None, step=step.step)
 
     def _pull(self, span: str, picked, n: int,
               logit_rows: Optional[Sequence[int]], touched: bool = False,
-              **attrs):
+              paged: bool = False, **attrs):
         """A step's results for the host, inside ``span`` (whose ``bytes``
         is what crossed): all its logits, (n, V), for a caller that named
         no rows; else the n ids and the rows named (``touched``: the count
@@ -491,11 +647,18 @@ class ModelRunner:
                 pull.set(bytes=logits.nbytes)
                 return np.asarray(logits)[:n]
             ids = np.asarray(ids)
+            # behind the ids: a routing module's count, then a selecting
+            # module's two
+            pages = (int(ids[-2]), int(ids[-1])) if paged else None
+            count = int(ids[-3 if paged else -1]) if touched else None
             chosen = Chosen(ids[:n], {
                 int(row): np.asarray(self._logits_row(logits, np.int32(row)))
-                for row in logit_rows}, int(ids[-1]) if touched else None)
+                for row in logit_rows}, count, pages)
             if touched:
                 pull.set(experts_touched=chosen.touched)
+            if paged:
+                pull.set(sparse_pages_read=pages[0],
+                         sparse_pages_held=pages[1])
             pull.set(bytes=ids.nbytes + chosen.logits_nbytes)
             return chosen
 

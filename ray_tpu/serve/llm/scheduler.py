@@ -7,7 +7,11 @@ looks at the waiting and running sets and decides what THIS step runs:
 - **prefill** of the oldest admissible waiting sequence (one per step:
   interleaving a single prefill between decode steps bounds the decode
   stall — TPOT — that a long prompt would otherwise inject), admitted
-  only if a decode batch slot AND enough KV blocks are free;
+  only if a decode batch slot AND enough KV blocks are free; for a model
+  that prefills in chunks the sequence stays ``prefilling`` from its first
+  chunk to its last, every plan until then names it again (one chunk an
+  iteration, a decode step of the running sequences behind it) and nobody
+  else is admitted meanwhile: at most one prompt is in prefill;
 - **decode** of every running sequence (token-budget = batch bucket cap);
 - **preemption** under cache pressure: when a running sequence cannot
   get its next block, the LOWEST-priority running sequence (latest
@@ -59,6 +63,10 @@ class Sequence:
     # submit()/attach() on the caller's thread; the engine loop parents
     # its per-sequence prefill/decode/preempt spans to it
     trace: Optional[object] = None
+    # a prefill in chunks: the chunks enqueued so far and the last of
+    # them, which the next waits for (model_runner.prefill_chunk)
+    chunks_done: int = 0
+    chunk_flight: Optional[object] = None
 
     def __post_init__(self):
         if not self.orig_len:
@@ -99,6 +107,9 @@ class IterationScheduler:
         self.max_model_len = max_model_len
         self.waiting: Deque[Sequence] = deque()
         self.running: List[Sequence] = []
+        # the sequence whose prompt is part-way through its chunks: it has
+        # its blocks and a slot of max_num_seqs, and is in neither list
+        self.prefilling: Optional[Sequence] = None
 
     # ------------------------------------------------------------- lifecycle
     def add(self, seq: Sequence) -> None:
@@ -117,7 +128,9 @@ class IterationScheduler:
         """Decide this iteration.  ``blocks_needed_fn(n_tokens)`` maps a
         context length to its block cost (cache geometry lives there)."""
         p = Plan()
-        if self.waiting and len(self.running) < self.max_num_seqs:
+        if self.prefilling is not None:
+            p.prefill = self.prefilling
+        elif self.waiting and len(self.running) < self.max_num_seqs:
             head = self.waiting[0]
             # +1: room for the first decode step's block growth so a
             # just-admitted sequence can't immediately trigger preemption
@@ -161,4 +174,5 @@ class IterationScheduler:
             pass
 
     def has_work(self) -> bool:
-        return bool(self.waiting or self.running)
+        return bool(self.waiting or self.running
+                    or self.prefilling is not None)

@@ -233,6 +233,24 @@ CATALOG: Dict[str, dict] = {
                     "to read; observed once per decode step of a model "
                     "that routes",
         emitted_by="llm replica"),
+    "rtpu_llm_prefill_chunks_total": dict(
+        kind="counter", tag_keys=("model", "group"),
+        description="Chunks of prompts a model that prefills in chunks "
+                    "has run (one program run each, a decode step of the "
+                    "live rows between two)",
+        emitted_by="llm replica"),
+    "rtpu_llm_sparse_pages_read": dict(
+        kind="counter", tag_keys=("model", "group"),
+        description="KV pages the decode steps' sparse attention read: "
+                    "the chosen pages of every live row, sparse layer and "
+                    "KV head, summed over committed steps",
+        emitted_by="llm replica"),
+    "rtpu_llm_sparse_pages_held": dict(
+        kind="counter", tag_keys=("model", "group"),
+        description="KV pages the same rows' contexts held in the same "
+                    "layers and heads: what reading every page would have "
+                    "read (read / held is what the selection left)",
+        emitted_by="llm replica"),
     "rtpu_llm_tokens_total": dict(
         kind="counter", tag_keys=("model", "phase", "group"),
         description="Tokens processed by an LLM engine: 'prefill' = "

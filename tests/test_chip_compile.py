@@ -722,3 +722,163 @@ def test_lfm2_prefill_program_fits_at_bucket_512(lfm2_runner, monkeypatch):
     total, mem = _held_bytes(compiled)
     assert mem.alias_size_in_bytes >= 0.27e9
     assert total + LFM2_REFERENCE_LAYER_BYTES < 16.9e9, total
+
+
+# ------------------------------------------- the MiniCPM-SALA cell's programs
+@pytest.fixture(scope="module")
+def minicpm_sala_runner(v5e):
+    """The cell's runner over abstract weights, and what its holder holds
+    as shapes on the chip: a K/V pool over the 4 sparse layers, the
+    selector's cache beside it (a half-kernel every 16 positions of every
+    page) and a store of Lightning states over the 12 Lightning layers."""
+    import json
+    from pathlib import Path
+
+    from ray_tpu.models import minicpm_sala
+    from ray_tpu.serve.llm import EngineConfig
+    from ray_tpu.serve.llm.config import resolve_model
+    from ray_tpu.serve.llm.kv_cache import device_shape, selector_shape
+    from ray_tpu.serve.llm.model_runner import ModelRunner
+    engine = json.loads((Path(__file__).parent.parent / "perfbench" /
+                         "configs" / "minicpm-sala-9b.json").read_text()
+                        )["serve"]["engine"]
+    for key in ("decode_batch_buckets", "prefill_len_buckets"):
+        engine[key] = tuple(engine[key])
+    ecfg = EngineConfig(**engine)
+    mod, mcfg = resolve_model(ecfg)
+    assert mod is minicpm_sala
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.eval_shape(lambda key: mod.init_params(key, mcfg),
+                            jax.random.key(0))
+    runner = ModelRunner(ecfg, params=params)
+    assert runner.params is params      # drawn in its serving type
+    assert (runner.kv_layers, runner.state_layers) == (4, 12)
+    assert runner.select_spec == {"stride": 16, "block": 64}
+    assert runner.chunk == 2048
+    pool = device_shape(ecfg.num_blocks, runner.kv_layers, ecfg.block_size,
+                        mcfg.n_kv_head, mcfg.head_dim)
+    held = {
+        "kv": on_chip(pool, jnp.float32),
+        "state": {name: on_chip((runner.state_layers, ecfg.max_num_seqs + 1)
+                                + s.shape, s.dtype)
+                  for name, s in runner.state_spec.items()},
+        "sel": on_chip(selector_shape(pool, runner.select_spec["stride"]),
+                       jnp.float32)}
+    weights = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), params)
+    return runner, ecfg, held, weights, on_chip
+
+
+# the float32 reference beside the engine: a feed-forward matrix widened
+# (4,096 x 16,384 x 4 bytes, three of them in one jitted layer) and a block
+# of 256 queries' scores over the check's 12,288 positions
+MINICPM_SALA_REFERENCE_BYTES = 1.3e9
+
+
+def test_minicpm_sala_decode_program_walks_the_chosen_pages_and_fits(
+        minicpm_sala_runner, monkeypatch):
+    """The cell's decode step at its one bucket of 4 (16 layers at the
+    published widths, 4,224 pages of 64 positions, 5 rows of state): the
+    paged kernel in its listed form once a sparse layer (4 custom calls),
+    the pool (2.21e9 bytes), the store and the selector's cache donated,
+    the pool touched by the update of the step's 4 rows alone, no second
+    array of the pool's size, the two counts of pages behind the ids, and
+    everything in the chip with the check's reference beside it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, ecfg, held, weights, on_chip = minicpm_sala_runner
+    bucket, i32 = ecfg.decode_batch_buckets[-1], jnp.int32
+    assert runner.param_bytes == 10_079_082_496
+    assert held["kv"].shape == (4, 2, 4224, 64, 256)
+    assert held["sel"].shape == (4, 4224, 4, 256)
+    assert held["state"]["s"].shape == (12, 5, 32, 128, 128)
+    compiled = runner._decode.lower(
+        held, weights, on_chip((bucket,), i32), on_chip((bucket,), i32),
+        on_chip((bucket, ecfg.max_blocks_per_seq), i32),
+        on_chip((bucket,), i32), on_chip((), i32),
+        on_chip((bucket,), i32), on_chip((bucket,), i32),
+        on_chip((bucket,), i32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert "paged_decode_listed" in text
+    # the ids with the pages read and the pages held behind them (6), and
+    # again at the bucket's width for the step enqueued behind this one
+    root = next(line for line in text[text.index("ENTRY "):].splitlines()
+                if " ROOT " in line)
+    assert "s32[6]" in root and "s32[4]" in root
+    _assert_the_pool_is_read_in_place_and_written_by_rows(
+        text, held["kv"].shape, lanes_used=2 * 128)
+    total, mem = _held_bytes(compiled)
+    assert mem.alias_size_in_bytes >= 2.40e9    # pool, store and selector
+    assert mem.temp_size_in_bytes < 0.15e9
+    assert total + MINICPM_SALA_REFERENCE_BYTES < 16.9e9, total
+
+
+def test_minicpm_sala_chunk_program_is_one_and_keeps_the_pool_out(
+        minicpm_sala_runner, monkeypatch):
+    """The one prefill program: a chunk of 2,048 positions over a staging
+    K/V of 67,584 positions (donated with the holder and returned: 0.57e9
+    bytes with its half-kernels) and the store's staging row; the sparse
+    layers' attention is the flash kernel over the staging (4 custom
+    calls); the pool goes through untouched: nothing of its size is made,
+    and the chunk's own temporaries are 0.95e9 bytes, of which the
+    kernel's mask of positions (2 x 2,048 x 67,584, as int8 and as bool
+    before it) is 0.55e9."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, ecfg, held, weights, on_chip = minicpm_sala_runner
+    staging = jax.tree.map(lambda s: on_chip(s.shape, s.dtype),
+                           runner.staging_spec)
+    assert staging["k"].shape == (4, 67584, 256)
+    assert staging["halves"].shape == (4, 67584 // 16, 256)
+    assert runner.staging_bytes == 2 * 4 * 67584 * 256 * 4 \
+        + 4 * 4224 * 256 * 4
+    i32 = jnp.int32
+    compiled = runner._prefill_chunk.lower(
+        held, weights, staging, on_chip((1, runner.chunk), i32),
+        on_chip((), i32), on_chip((), i32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert "sparse_prefill" in text
+    assert not _made(text, math.prod(held["kv"].shape))
+    total, mem = _held_bytes(compiled)
+    # holder and staging aliased to the result
+    assert mem.alias_size_in_bytes >= 2.40e9 + 0.57e9
+    assert mem.temp_size_in_bytes < 1.0e9
+    assert total + MINICPM_SALA_REFERENCE_BYTES < 16.9e9, total
+
+
+# The serving cells' decode steps as the parent of the PR that taught the
+# paged kernel to walk a list of pages lowered them (commit 1a45b60, this
+# jax): digest, StableHLO operations, Mosaic kernels.  The kernel's body
+# builds either walk; the walk without a list is the one there was,
+# operation for operation.
+PARENT_DECODE_STEPS = {
+    "xl": ("b5e4a17574d47c3c", 413, 1),
+    "falcon_h1": ("6dc9e09f46d43408", 673, 1),
+    "lfm2": ("a6798ee27cf55288", 1314, 1),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PARENT_DECODE_STEPS))
+def test_older_decode_steps_lower_to_the_operations_and_kernels_they_had(
+        cell, request, monkeypatch):
+    from ray_tpu.serve.llm.kv_cache import device_shape
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, ecfg, third, weights, on_chip = request.getfixturevalue(
+        f"{cell}_runner")
+    if cell == "xl":        # no holder: the pool alone, all layers K/V
+        held = on_chip(device_shape(ecfg.num_blocks, third.n_layer,
+                                    ecfg.block_size, third.n_head,
+                                    third.head_dim), jnp.float32)
+    else:
+        held = third
+    bucket, i32 = ecfg.decode_batch_buckets[-1], jnp.int32
+    rows = [on_chip((bucket,), i32)] * (2 if cell == "xl" else 3)
+    lowered = runner._decode.lower(
+        held, weights, on_chip((bucket,), i32), on_chip((bucket,), i32),
+        on_chip((bucket, ecfg.max_blocks_per_seq), i32),
+        on_chip((bucket,), i32), on_chip((), i32), *rows)
+    digest, ops, kernels = _operations_and_kernels(lowered.as_text())
+    assert (digest, sum(ops.values()), sum(kernels.values())) == \
+        PARENT_DECODE_STEPS[cell]
