@@ -163,27 +163,34 @@ def _rope_interleaved(x: jax.Array, theta: float) -> jax.Array:
 
 
 def _latent_attention(u: jax.Array, lp: Params, cfg: DeepseekV3Config):
-    """Normed hidden states (B, T, E) -> W_o . attention (B, T, E)."""
-    B, T, _ = u.shape
+    """Normed hidden states (B, T, E) -> W_o . attention (B, T, E).  Each
+    of the kernel's operands is a projection's own result: W_q and W_kv_b
+    are read by column group (a slice of the weight, not of 16,384 rows of
+    activations), so no (nope + rope)-wide query or key and no joined
+    gradient of one is ever built."""
+    B, T, E = u.shape
     H, nope, rope = cfg.n_head, cfg.qk_nope_dim, cfg.qk_rope_dim
     latent = cfg.kv_latent_dim
     with jax.named_scope("attn_qkv"):
-        q = (u @ lp["wq"]["kernel"].astype(cfg.dtype)).reshape(
-            B, T, H, nope + rope)
+        wq = lp["wq"]["kernel"].astype(cfg.dtype).reshape(E, H, nope + rope)
+        q_nope = jnp.einsum("bte,ehd->bthd", u, wq[..., :nope])
+        q_rope = jnp.einsum("bte,ehd->bthd", u, wq[..., nope:])
     with jax.named_scope("mla/latent"):
         kva = u @ lp["wkv_a"]["kernel"].astype(cfg.dtype)
         c = _rms_norm(kva[..., :latent], lp["kv_norm"]["scale"], cfg.rms_eps)
-        kvb = (c @ lp["wkv_b"]["kernel"].astype(cfg.dtype)).reshape(
-            B, T, H, nope + cfg.v_head_dim)
+        wkv_b = lp["wkv_b"]["kernel"].astype(cfg.dtype).reshape(
+            latent, H, nope + cfg.v_head_dim)
+        k_nope = jnp.einsum("btc,chd->bthd", c, wkv_b[..., :nope])
+        v = jnp.einsum("btc,chd->bthd", c, wkv_b[..., nope:])
     with jax.named_scope("mla/rope"):
-        q_rope = _rope_interleaved(q[..., nope:], cfg.rope_theta)
-        k_rope = _rope_interleaved(kva[..., None, latent:], cfg.rope_theta)
-        q = jnp.concatenate([q[..., :nope], q_rope], -1)
-        k = jnp.concatenate(
-            [kvb[..., :nope], jnp.broadcast_to(k_rope, (B, T, H, rope))], -1)
+        q_rope = _rope_interleaved(q_rope, cfg.rope_theta)
+        k_rope = _rope_interleaved(kva[..., None, latent:],
+                                   cfg.rope_theta)[:, :, 0]
     with jax.named_scope("attn"):
-        from ray_tpu.ops.attention import causal_attention
-        a = causal_attention(q, k, kvb[..., nope:], impl=cfg.attn_impl)
+        # the one rotary key a token goes once, for every head to read
+        from ray_tpu.ops.attention import latent_causal_attention
+        a = latent_causal_attention(q_nope, q_rope, k_nope, k_rope, v,
+                                    impl=cfg.attn_impl)
     with jax.named_scope("attn_out"):
         return a.reshape(B, T, H * cfg.v_head_dim) \
             @ lp["wo"]["kernel"].astype(cfg.dtype)

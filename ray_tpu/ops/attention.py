@@ -8,6 +8,10 @@ these are greenfield TPU-first components.
   the ambient mesh), as ``ops/paged_attention.paged_attention_decode``
   does for decode; ``flash_runs`` is the one statement of when the Pallas
   kernel (``ops/flash_attention.py``) runs.
+- ``latent_causal_attention``: the same choice for a caller whose
+  queries and keys come in two column groups with ONE rotary key for all
+  heads (latent attention): on the kernel the five operands go as they
+  are, off it they are joined and ``causal_attention`` goes on.
 - ``dense_attention``: XLA's O(T²) attention, the path everywhere the
   kernel does not run and the tests' reference.
 - ``flash_update`` / ``flash_finalize``: the online-softmax block update
@@ -112,9 +116,9 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                      impl: str = "auto",
                      context_axis: Optional[str] = None) -> jax.Array:
     """Causal self-attention over (B, T, H, D) for training and prefill;
-    values may be narrower than keys (latent attention: q, k 192 wide,
-    v and the output 128), which the flash kernel and the dense path take
-    as they are, with no padding.
+    values may be narrower than keys, which the flash kernel and the dense
+    path take as they are, with no padding.  (A caller with a rotary key
+    shared by its heads has ``latent_causal_attention``.)
 
     ``impl`` is a model config's ``attn_impl``: ``auto`` (the flash
     kernel where ``flash_runs`` says so, XLA's dense attention
@@ -137,3 +141,26 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     ulysses_attention_sharded as sharded)
             return sharded(q, k, v, mesh=mesh, axis_name=axis, causal=True)
     return dense_attention(q, k, v, causal=True)
+
+
+def latent_causal_attention(q_nope: jax.Array, q_rope: jax.Array,
+                            k_nope: jax.Array, k_rope: jax.Array,
+                            v: jax.Array, *, impl: str = "auto") -> jax.Array:
+    """Causal self-attention for a caller that holds its queries and keys
+    in two column groups and ONE rotary key for all heads (latent
+    attention): q_nope, k_nope (B,T,H,nope), q_rope (B,T,H,rope), k_rope
+    (B,T,rope), v (B,T,H,Dv) -> (B,T,H,Dv); the score is
+    ``q_nope·k_nopeᵀ + q_rope·k_ropeᵀ`` over 1/sqrt(nope + rope).
+
+    Where ``flash_runs`` says so the flash kernels take the five operands
+    as they are (``flash_attention.latent_flash_attention``).  Everywhere
+    else the parts are joined, the rotary key repeated a head, and
+    ``causal_attention`` goes on as for any other caller."""
+    if flash_runs(v.shape[1], impl):
+        from ray_tpu.ops.flash_attention import latent_flash_attention
+        return latent_flash_attention(q_nope, q_rope, k_nope, k_rope, v)
+    k_rope = jnp.broadcast_to(k_rope[:, :, None],
+                              k_nope.shape[:3] + k_rope.shape[-1:])
+    return causal_attention(jnp.concatenate([q_nope, q_rope], -1),
+                            jnp.concatenate([k_nope, k_rope], -1), v,
+                            impl=impl)

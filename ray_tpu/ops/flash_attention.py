@@ -28,6 +28,17 @@ the ``kernels.flash_fwd_ms`` / ``kernels.flash_bwd_ms`` metrics, PERF.md §5):
   resident k-block; dq accumulates into a full-T f32 output block whose
   index map is constant in the k-grid axis, so Mosaic keeps it VMEM-
   resident across k-steps and writes it back once.
+- Queries and keys may come in PARTS, column groups whose partial scores
+  add in float32 (PR 43).  ``flash_attention`` hands over one part, and
+  traces and lowers as it did before there were parts;
+  ``latent_flash_attention`` two, (nope, rope), the rope key ONE
+  (B, T, rope) array whose BlockSpec sends grid row ``bh`` to batch
+  ``bh // H``: latent attention's 192-wide query and key, the broadcast
+  of the rotary key to every head and their backward (the slices of dq
+  and dk, dk's sum over the heads inside a 192-wide array) are never
+  built.  ``ops/attention.py`` says which call a model makes.  Every
+  kernel's first result is the output or dq's first part, (B·H, T, ·):
+  PERF.md's ``mla.attention_ms`` knows the kernels by it.
 """
 
 from __future__ import annotations
@@ -47,23 +58,37 @@ DEFAULT_BLOCK = 128
 LOG2E = math.log2(math.e)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_out, block_q: int,
-                  block_k: int, seq_len: int, causal: bool, scale: float):
+def _scores(qs, ks):
+    """Σ over the parts of q_part · k_partᵀ, float32: one product for
+    whole queries and keys, two for latent attention's (nope, rope)."""
+    s = None
+    for q, k in zip(qs, ks):
+        part = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+        s = part if s is None else s + part
+    return s
+
+
+def _flash_kernel(*refs, parts: int, block_q: int, block_k: int,
+                  seq_len: int, causal: bool, scale: float):
+    """refs: the queries' ``parts`` column groups, the keys' (in the same
+    order), v, o and, for the vjp's forward, lse."""
+    q_refs, k_refs = refs[:parts], refs[parts:2 * parts]
+    v_ref, o_ref, *lse_out = refs[2 * parts:]
     qi = pl.program_id(1)
     # Keep q/k/v in their storage dtype (bf16) for the MXU — f32 inputs
     # would quarter matmul throughput; accumulation stays f32 via
     # preferred_element_type.  scale*log2(e) folds into the score
     # multiply so the exp2 chain carries no extra VPU work.
-    q = q_ref[0]                                      # (block_q, D) bf16
+    qs = [q_ref[0] for q_ref in q_refs]               # (block_q, D) bf16
     Dv = v_ref.shape[-1]          # values may be narrower than keys (MLA)
     s_scale = scale * LOG2E
 
     def tile(j, carry, masked):
         acc, m, l = carry
-        k = k_ref[0, pl.ds(j * block_k, block_k), :]
+        ks = [k_ref[0, pl.ds(j * block_k, block_k), :] for k_ref in k_refs]
         v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * s_scale
+        s = _scores(qs, ks) * s_scale
         if masked:
             q_pos = qi * block_q + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
@@ -100,11 +125,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_out, block_q: int,
         lse_out[0][0, 0] = lse                        # lse rides the lanes
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dq_ref, dk_ref, dv_ref, dq_acc, *,
-                block_q: int, block_k: int, seq_len: int, causal: bool,
-                scale: float):
+def _bwd_kernel(*refs, parts: int, block_q: int, block_k: int, seq_len: int,
+                causal: bool, scale: float):
     """One-pass backward: grid (B·H, k-block); inner loop over q-blocks.
+    refs: q's ``parts`` column groups, k's, v, do, lse, delta; then dq's
+    parts, dk's, dv; then a float32 dq scratch a part.
 
     Each (q, k) tile: recompute s and p (one exp2 chain), then
       dv += pᵀ·do        dp = do·vᵀ        ds = p*(dp-delta)
@@ -124,29 +149,34 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
       all five dots have a 64-wide contracting or output dimension
       (D=64) against the 128-deep systolic array.
     """
+    q_refs, k_refs = refs[:parts], refs[parts:2 * parts]
+    v_ref, do_ref, lse_ref, delta_ref = refs[2 * parts:2 * parts + 4]
+    outs = refs[2 * parts + 4:]
+    dq_refs, dk_refs = outs[:parts], outs[parts:2 * parts]
+    dv_ref, *dq_accs = outs[2 * parts:]
     kj = pl.program_id(1)
     nq = seq_len // block_q
     nk = seq_len // block_k
-    k = k_ref[0]                                      # (block_k, D)
+    ks = [k_ref[0] for k_ref in k_refs]               # (block_k, D)
     v = v_ref[0]
-    ks = (k.astype(jnp.float32) * scale).astype(k.dtype)
-    D, Dv = k.shape[-1], v.shape[-1]
+    ks_scaled = [(k.astype(jnp.float32) * scale).astype(k.dtype) for k in ks]
+    Dv = v.shape[-1]
     s_scale = scale * LOG2E
 
     @pl.when(kj == 0)
     def _init_dq():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
+        for dq_acc in dq_accs:
+            dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def tile(i, carry, masked):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(i * block_q, block_q), :]
+        dks, dv = carry
+        qs = [q_ref[0, pl.ds(i * block_q, block_q), :] for q_ref in q_refs]
         do = do_ref[0, pl.ds(i * block_q, block_q), :]
         lse_lanes = lse_ref[0, 0, pl.ds(i * block_q, block_q)]  # lanes
         lse_rows = jnp.transpose(lse_lanes[None, :])         # (block_q, 1)
         d_lanes = delta_ref[0, 0, pl.ds(i * block_q, block_q)]
         delta = jnp.transpose(d_lanes[None, :])              # (block_q, 1)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * s_scale
+        s = _scores(qs, ks) * s_scale
         if masked:
             q_pos = i * block_q + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
@@ -160,39 +190,50 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta)                # scale deferred to dk/dq below
-        dsl = ds.astype(k.dtype)
-        dk = dk + jax.lax.dot_general(
+        dsl = ds.astype(ks[0].dtype)
+        dks = tuple(dk + jax.lax.dot_general(
             dsl, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dq_tile = jax.lax.dot_general(
-            dsl, ks, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        sl = pl.ds(i * block_q, block_q)
-        dq_acc[sl, :] = dq_acc[sl, :] + dq_tile
-        return dk, dv
+            preferred_element_type=jnp.float32) for dk, q in zip(dks, qs))
+        for dq_acc, k_scaled in zip(dq_accs, ks_scaled):
+            dq_tile = jax.lax.dot_general(
+                dsl, k_scaled, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            sl = pl.ds(i * block_q, block_q)
+            dq_acc[sl, :] = dq_acc[sl, :] + dq_tile
+        return dks, dv
 
-    dk0 = jnp.zeros((block_k, D), jnp.float32)
+    dks0 = tuple(jnp.zeros(k.shape, jnp.float32) for k in ks)
     dv0 = jnp.zeros((block_k, Dv), jnp.float32)
     if causal:
         # k-block kj is seen by q-blocks i ≥ kj: diagonal first (masked),
         # then the fully-visible strictly-lower rows.
-        dk, dv = tile(kj, (dk0, dv0), masked=True)
-        dk, dv = lax.fori_loop(
-            kj + 1, nq, lambda i, c: tile(i, c, masked=False), (dk, dv))
+        dks, dv = tile(kj, (dks0, dv0), masked=True)
+        dks, dv = lax.fori_loop(
+            kj + 1, nq, lambda i, c: tile(i, c, masked=False), (dks, dv))
     else:
-        dk, dv = lax.fori_loop(
-            0, nq, lambda i, c: tile(i, c, masked=False), (dk0, dv0))
-    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
+        dks, dv = lax.fori_loop(
+            0, nq, lambda i, c: tile(i, c, masked=False), (dks0, dv0))
+    for dk_ref, dk in zip(dk_refs, dks):
+        dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
     @pl.when(kj == nk - 1)
     def _flush_dq():
-        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+        for dq_ref, dq_acc in zip(dq_refs, dq_accs):
+            dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _flatten(x):
+    """(B,T,H,D) -> the kernels' (B·H,T,D); an operand with no head axis
+    (a key part the heads share) is handed on as it is."""
+    if x.ndim == 3:
+        return x
     B, T, H, D = x.shape
     return x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
+
+
+def _flatten_all(xs):
+    return tuple(_flatten(x) for x in xs)
 
 
 def _unflatten(x, B, H):
@@ -234,20 +275,39 @@ def _lanes(d: int) -> int:
     return -(-d // 128) * 128
 
 
-def _flash_forward_lse_flat(qf, kf, vf, *, causal: bool, bs: int,
+def _spec(x, BH, rows, stepped):
+    """BlockSpec of ``rows`` positions of a (B·H, T, D) operand for a
+    (B·H, block) grid: the block at the grid's second index
+    (``stepped``), or from position 0 (a whole-sequence operand).  An
+    operand whose leading dimension is a batch, not a (batch, head), is
+    the one array every head of its batch reads (latent attention's
+    rotary key): grid row ``bh`` reads batch ``bh // H`` of it, and
+    nothing broadcasts it."""
+    heads = BH // x.shape[0]
+
+    def index(bh, i):
+        return (bh if heads == 1 else lax.div(bh, heads),
+                i if stepped else 0, 0)
+    return pl.BlockSpec((1, rows, x.shape[-1]), index)
+
+
+def _flash_forward_lse_flat(qs, ks, vf, *, causal: bool, bs: int,
                             interpret: bool, want_lse: bool = True):
-    """Core forward on kernel-layout (B·H, T, D) operands.
+    """Core forward on kernel-layout operands: ``qs`` and ``ks`` are the
+    queries' and keys' column groups, (B·H, T, D_i) each, (q,) and (k,)
+    for whole ones; a key part may be (B, T, D_i), shared by the heads.
 
     ``want_lse=False`` (the primal / inference path) skips computing
     and writing the lse tensor — it is only a residual for the fused
     backward, and Pallas cannot DCE a declared output."""
-    BH, T, D = qf.shape
+    BH, T, _ = qs[0].shape
     Dv = vf.shape[-1]
-    scale = 1.0 / math.sqrt(D)
-    kernel = functools.partial(_flash_kernel, block_q=bs, block_k=bs,
-                               seq_len=T, causal=causal, scale=scale)
-    out_specs = [pl.BlockSpec((1, bs, Dv), lambda bh, qi: (bh, qi, 0))]
-    out_shape = [jax.ShapeDtypeStruct((BH, T, Dv), qf.dtype)]
+    scale = 1.0 / math.sqrt(sum(q.shape[-1] for q in qs))
+    kernel = functools.partial(_flash_kernel, parts=len(qs), block_q=bs,
+                               block_k=bs, seq_len=T, causal=causal,
+                               scale=scale)
+    out_specs = [_spec(vf, BH, bs, True)]
+    out_shape = [jax.ShapeDtypeStruct((BH, T, Dv), qs[0].dtype)]
     if want_lse:
         # Compact (B·H, 1, T) f32 — lse rides the lane axis; the unit
         # middle dim satisfies Mosaic's (8,128) last-two-dims tiling rule.
@@ -257,35 +317,40 @@ def _flash_forward_lse_flat(qf, kf, vf, *, causal: bool, bs: int,
     res = pl.pallas_call(
         kernel,
         grid=(BH, T // bs),
-        in_specs=[
-            pl.BlockSpec((1, bs, D), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, T, D), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, T, Dv), lambda bh, qi: (bh, 0, 0)),
-        ],
+        in_specs=[*(_spec(q, BH, bs, True) for q in qs),
+                  *(_spec(k, BH, T, False) for k in ks),
+                  _spec(vf, BH, T, False)],
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
         name="flash_fwd",
-        **_compiler_params(T * (_lanes(D) + _lanes(Dv)) * qf.dtype.itemsize),
-    )(qf, kf, vf)
+        **_compiler_params(T * (sum(_lanes(k.shape[-1]) for k in ks)
+                                + _lanes(Dv)) * qs[0].dtype.itemsize),
+    )(*qs, *ks, vf)
     return res if want_lse else (res[0], None)
 
 
-def _flash_forward_lse(q, k, v, *, causal: bool, block_size: int,
+def _flash_forward_lse(qs, ks, v, *, causal: bool, block_size: int,
                        interpret: Optional[bool], want_lse: bool = True):
-    B, T, H, D = q.shape
+    """``qs`` / ``ks``: the queries' and keys' column groups as the
+    caller holds them, (B,T,H,D_i); a key part shared by the heads is
+    (B,T,D_i)."""
+    B, T, H, _ = v.shape
     bs, interpret = _resolve(block_size, T, interpret)
     # (B,T,H,D) -> (B*H, T, D): one grid row per (batch, head).
-    qf, kf, vf = _flatten(q), _flatten(k), _flatten(v)
+    qf, kf, vf = _flatten_all(qs), _flatten_all(ks), _flatten(v)
     out, lse = _flash_forward_lse_flat(qf, kf, vf, causal=causal, bs=bs,
                                        interpret=interpret,
                                        want_lse=want_lse)
     return _unflatten(out, B, H), lse
 
 
-def _flash_backward_flat(qf, kf, vf, lse, delta, dof, *, causal: bool,
+def _flash_backward_flat(qs, ks, vf, lse, delta, dof, *, causal: bool,
                          block_size: int, interpret: Optional[bool]):
-    """Backward on kernel-layout (B·H, T, D) operands.
+    """Backward on kernel-layout operands (``qs``, ``ks`` as the flat
+    forward takes them) -> (dq's parts, dk's parts, dv); a shared key
+    part's gradient comes back a head, (B·H, T, D_i), for the caller to
+    sum.
 
     ``out`` never enters: its only backward use is delta = Σ do·o, which
     the caller precomputes in the residual layout (the r3 kernel both
@@ -295,43 +360,46 @@ def _flash_backward_flat(qf, kf, vf, lse, delta, dof, *, causal: bool,
     bf16 once per (B·H) row — half the HBM traffic of the r3 f32 dq
     output.
     """
-    BH, T, D = qf.shape
+    n = len(qs)
+    BH, T, _ = qs[0].shape
     Dv = vf.shape[-1]
     # NOTE: a 1024-wide backward block measured marginally faster in the
     # standalone kernel bench but 20x SLOWER inside the remat'd train
     # step (VMEM pressure next to the replayed ops) — block choice is
     # shared with the forward on purpose.
     bs, interpret = _resolve(block_size, T, interpret)
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(sum(q.shape[-1] for q in qs))
 
     from jax.experimental.pallas import tpu as pltpu
 
-    kspec = pl.BlockSpec((1, bs, D), lambda bh, kj: (bh, kj, 0))
-    vspec = pl.BlockSpec((1, bs, Dv), lambda bh, kj: (bh, kj, 0))
-    fullspec = pl.BlockSpec((1, T, D), lambda bh, kj: (bh, 0, 0))
-    dospec = pl.BlockSpec((1, T, Dv), lambda bh, kj: (bh, 0, 0))
-    # dq: constant index along the k grid axis → flushed from scratch at
-    # the last k-step.
-    dqspec = pl.BlockSpec((1, T, D), lambda bh, kj: (bh, 0, 0))
+    # q, dO and dq whole (dq: constant index along the k grid axis →
+    # flushed from scratch at the last k-step); k, v, dk, dv by k-block.
+    # A shared key part's dk is a result a (batch, head) like the others.
+    dk_shapes = [jax.ShapeDtypeStruct((BH,) + k.shape[1:], k.dtype)
+                 for k in ks]
+    q_specs = [_spec(q, BH, T, False) for q in qs]
+    vspec = _spec(vf, BH, bs, True)
     rowspec = pl.BlockSpec((1, 1, T), lambda bh, kj: (bh, 0, 0))
+    lanes = sum(_lanes(q.shape[-1]) for q in qs)
 
-    dq, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_kernel, block_q=bs, block_k=bs, seq_len=T,
-                          causal=causal, scale=scale),
+    res = pl.pallas_call(
+        functools.partial(_bwd_kernel, parts=n, block_q=bs, block_k=bs,
+                          seq_len=T, causal=causal, scale=scale),
         grid=(BH, T // bs),
-        in_specs=[fullspec, kspec, vspec, dospec, rowspec, rowspec],
-        out_specs=[dqspec, kspec, vspec],
-        out_shape=[jax.ShapeDtypeStruct((BH, T, D), qf.dtype),
-                   jax.ShapeDtypeStruct((BH, T, D), kf.dtype),
-                   jax.ShapeDtypeStruct((BH, T, Dv), vf.dtype)],
-        scratch_shapes=[pltpu.VMEM((T, D), jnp.float32)],
+        in_specs=[*q_specs, *(_spec(k, BH, bs, True) for k in ks), vspec,
+                  _spec(dof, BH, T, False), rowspec, rowspec],
+        out_specs=[*q_specs, *(_spec(dk, BH, bs, True) for dk in dk_shapes),
+                   vspec],
+        out_shape=[*(jax.ShapeDtypeStruct(q.shape, q.dtype) for q in qs),
+                   *dk_shapes, jax.ShapeDtypeStruct((BH, T, Dv), vf.dtype)],
+        scratch_shapes=[pltpu.VMEM(q.shape[1:], jnp.float32) for q in qs],
         interpret=interpret,
         name="flash_bwd",
         # q, dO and dq stay whole; the float32 dq scratch is single
-        **_compiler_params(T * (2 * _lanes(D) + _lanes(Dv))
-                           * qf.dtype.itemsize + T * _lanes(D) * 2),
-    )(qf, kf, vf, dof, lse, delta)
-    return dq, dk, dv
+        **_compiler_params(T * (2 * lanes + _lanes(Dv))
+                           * qs[0].dtype.itemsize + T * lanes * 2),
+    )(*qs, *ks, vf, dof, lse, delta)
+    return res[:n], res[n:2 * n], res[2 * n]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -346,14 +414,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``block_size=None`` (default) resolves via ``pick_block_size`` — the
     measured-fastest tile for the sequence length — so every caller gets
     the tuned configuration without opting in."""
-    out, _ = _flash_forward_lse(q, k, v, causal=causal,
+    out, _ = _flash_forward_lse((q,), (k,), v, causal=causal,
                                 block_size=block_size, interpret=interpret,
                                 want_lse=False)
     return out
 
 
-def _fwd(q, k, v, causal, block_size, interpret):
-    out, lse = _flash_forward_lse(q, k, v, causal=causal,
+def _forward_residuals(qs, ks, v, causal, block_size, interpret):
+    out, lse = _flash_forward_lse(qs, ks, v, causal=causal,
                                   block_size=block_size, interpret=interpret)
     # Name the backward residuals so a jax.checkpoint policy
     # (save_only_these_names, models/gpt2.py remat_policy="attn") can pin
@@ -367,11 +435,12 @@ def _fwd(q, k, v, causal, block_size, interpret):
     from jax.ad_checkpoint import checkpoint_name
     out = checkpoint_name(out, "flash_attn_out")
     lse = checkpoint_name(lse, "flash_attn_lse")
-    return out, (q, k, v, out, lse)
+    return out, (qs, ks, v, out, lse)
 
 
-def _bwd(causal, block_size, interpret, res, g):
-    q, k, v, out, lse = res
+def _backward(causal, block_size, interpret, res, g):
+    """-> (dq's parts, dk's parts, dv), each as its operand was handed."""
+    qs, ks, v, out, lse = res
     B, H = g.shape[0], g.shape[2]       # cotangent is (B, T, H, D)
     # delta = Σ_D do·o computed in the RESIDUAL layout — one fused
     # multiply-reduce pass; ``out`` then never needs flattening (the r3
@@ -380,19 +449,68 @@ def _bwd(causal, block_size, interpret, res, g):
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)                          # (B, T, H) f32
     delta = delta.transpose(0, 2, 1).reshape(B * H, 1, -1)  # tiny: BHT f32
-    qf, kf, vf = _flatten(q), _flatten(k), _flatten(v)
-    dof = _flatten(g).astype(q.dtype)
-    dq, dk, dv = _flash_backward_flat(qf, kf, vf, lse, delta, dof,
-                                      causal=causal, block_size=block_size,
-                                      interpret=interpret)
+    qf, kf, vf = _flatten_all(qs), _flatten_all(ks), _flatten(v)
+    dof = _flatten(g).astype(qs[0].dtype)
+    dqs, dks, dv = _flash_backward_flat(qf, kf, vf, lse, delta, dof,
+                                        causal=causal, block_size=block_size,
+                                        interpret=interpret)
+
     # The bf16 dq emerges from VMEM scratch; converts fuse into the
     # unflatten transposes' single HBM pass.
-    return (_unflatten(dq, B, H).astype(q.dtype),
-            _unflatten(dk, B, H).astype(k.dtype),
-            _unflatten(dv, B, H).astype(v.dtype))
+    def handed(dx, x):
+        if x.ndim == 4:
+            return _unflatten(dx, B, H).astype(x.dtype)
+        # a key part the heads share: its gradient is the heads' sum
+        return dx.reshape(B, H, *dx.shape[1:]).astype(jnp.float32) \
+            .sum(1).astype(x.dtype)
+    return (tuple(map(handed, dqs, qs)), tuple(map(handed, dks, ks)),
+            handed(dv, v))
+
+
+def _fwd(q, k, v, causal, block_size, interpret):
+    return _forward_residuals((q,), (k,), v, causal, block_size, interpret)
+
+
+def _bwd(causal, block_size, interpret, res, g):
+    (dq,), (dk,), dv = _backward(causal, block_size, interpret, res, g)
+    return dq, dk, dv
 
 
 flash_attention.defvjp(_fwd, _bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def latent_flash_attention(q_nope: jax.Array, q_rope: jax.Array,
+                           k_nope: jax.Array, k_rope: jax.Array,
+                           v: jax.Array, block_size: Optional[int] = None,
+                           interpret: Optional[bool] = None) -> jax.Array:
+    """Causal attention whose score is ``q_nope·k_nopeᵀ + q_rope·k_ropeᵀ``
+    over 1/sqrt(nope + rope), on latent attention's operands as its
+    projections produce them: q_nope, k_nope (B,T,H,nope), q_rope
+    (B,T,H,rope), the rotary key every head shares ONCE, (B,T,rope), and
+    v (B,T,H,Dv) -> (B,T,H,Dv); differentiable.  The same kernels as
+    ``flash_attention`` with the queries and keys in two column groups:
+    the two partial scores add in float32 before the softmax, no joined
+    (nope + rope)-wide query or key is ever built, and the rotary key is
+    read in place by every head of its batch (its gradient: one
+    (B·H,T,rope) result summed over the heads)."""
+    out, _ = _flash_forward_lse((q_nope, q_rope), (k_nope, k_rope), v,
+                                causal=True, block_size=block_size,
+                                interpret=interpret, want_lse=False)
+    return out
+
+
+def _latent_fwd(q_nope, q_rope, k_nope, k_rope, v, block_size, interpret):
+    return _forward_residuals((q_nope, q_rope), (k_nope, k_rope), v, True,
+                              block_size, interpret)
+
+
+def _latent_bwd(block_size, interpret, res, g):
+    dqs, dks, dv = _backward(True, block_size, interpret, res, g)
+    return (*dqs, *dks, dv)
+
+
+latent_flash_attention.defvjp(_latent_fwd, _latent_bwd)
 
 
 def pick_block_size(T: int) -> int:

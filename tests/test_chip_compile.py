@@ -49,6 +49,13 @@ def _compile(fn, sharding, *shapes_dtypes):
     return text
 
 
+def _kernel_results(text):
+    """The first result of every Mosaic kernel of a compiled module, as
+    the chip's trace prints it beside the kernel's name."""
+    return re.findall(r"= \(?(bf16\[[\d,]+\])\S* .*custom-call\(.*"
+                      r'custom_call_target="tpu_custom_call"', text)
+
+
 def _flash(q, k, v):
     return flash_attention(q, k, v, True, None, False)
 
@@ -117,6 +124,35 @@ def test_flash_attention_at_olmoe_train_shape(v5e, fn):
     """One head's whole K and V (forward) and q, dO, dq (backward) are
     (4096, 128) blocks here: 1 MB an operand before double buffering."""
     _compile(fn, v5e, OLMOE_QKV, OLMOE_QKV, OLMOE_QKV)
+
+
+# ------------------------------------ Kanana's training cell: five operands
+def _latent(*parts):
+    from ray_tpu.ops.flash_attention import latent_flash_attention
+    return latent_flash_attention(*parts, None, False)
+
+
+def _latent_loss(*parts):
+    return _latent(*parts).astype(jnp.float32).sum()
+
+
+@pytest.mark.parametrize("fn,results", [
+    pytest.param(_latent, ["bf16[64,8192,128]"], id="forward"),
+    pytest.param(jax.grad(_latent_loss, argnums=(0, 1, 2, 3, 4)),
+                 ["bf16[64,8192,128]"] * 2, id="forward_backward"),
+])
+def test_latent_flash_at_kanana_train_shape(v5e, fn, results):
+    """2 x 8,192 positions, 32 heads, q_nope / k_nope / v 128 wide, q_rope
+    64 and the rotary key (2, 8192, 64) with no head axis: k_nope and the
+    rotary key whole in VMEM are the 4 MiB an operand that the joined
+    192-wide keys were (64 lanes pad to 128), q, dO and two dq scratches
+    in the backward.  Each kernel's first result is 128 wide, and the
+    rotary key enters as it is: no operation makes it 32 heads wide."""
+    wide = ((2, 8192, 32, 128), jnp.bfloat16)
+    text = _compile(fn, v5e, wide, ((2, 8192, 32, 64), jnp.bfloat16), wide,
+                    ((2, 8192, 64), jnp.bfloat16), wide)
+    assert _kernel_results(text) == results
+    assert not re.search(r"bf16\[2,8192,32,64\]\S* broadcast\(", text)
 
 
 def _experts(x, w_router, w_gate, w_up, w_down):
@@ -514,9 +550,11 @@ def _train_program(cell_name, v5e):
 def test_kanana_step_compiles_with_its_kernels_and_fits(v5e, monkeypatch):
     """The whole step of ``kanana-2-30b-a3b.train-b2-s8192`` (910.6 M
     parameters in bf16 with bf16 moments, 2 x 8,192 tokens): the flash
-    kernel at key width 192 and value width 128 with no padding (forward
-    and backward once in each of the two layer scans; 8,192 positions need
-    more than Mosaic's default scoped VMEM), every grouped matmul over the
+    kernels on latent attention's five operands (forward and backward once
+    in each of the two layer scans, each known to ``mla.attention_ms`` by
+    a first result ``bf16[64,8192,128]``: the output, dq_nope; 8,192
+    positions need more than Mosaic's default scoped VMEM) and nothing 192
+    wide anywhere in the step, every grouped matmul over the
     16 held experts a megablox kernel at a tile that divides 768 and 2,048
     (12 in the sparse scan), each with a result shape its metric is keyed
     on, no ``ragged-dot`` fallback, and everything inside the chip."""
@@ -527,16 +565,16 @@ def test_kanana_step_compiles_with_its_kernels_and_fits(v5e, monkeypatch):
                                         v5e)
     compiled = prog.jitted_step.lower(state, batch).compile()
     text = compiled.as_text()
-    kernels = re.findall(r"= \(?(bf16\[[\d,]+\])\S* .*custom-call\(.*"
-                         r'custom_call_target="tpu_custom_call"', text)
+    kernels = _kernel_results(text)
     metrics = Path(__file__).parent.parent / "perfbench" / "layer_metrics"
     keyed = {name: json.loads((metrics / f"{name}.json").read_text())
              ["params"]["shapes"]
              for name in ("mla.attention_ms", "moe.held_expert_ms")}
     flash = [k for k in kernels if k in keyed["mla.attention_ms"]]
     experts = [k for k in kernels if k in keyed["moe.held_expert_ms"]]
-    assert sorted(flash) == ["bf16[64,8192,128]"] * 2 \
-        + ["bf16[64,8192,192]"] * 2, kernels
+    assert flash == ["bf16[64,8192,128]"] * 4, kernels
+    for joined in ("[2,8192,32,192]", "[2,32,8192,192]", "[64,8192,192]"):
+        assert joined not in text
     assert len(experts) == 12 and len(flash) + len(experts) == len(kernels)
     assert set(experts) == set(keyed["moe.held_expert_ms"])
     assert "ragged-dot" not in text

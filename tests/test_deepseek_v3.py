@@ -67,14 +67,17 @@ def batch():
             "targets": jnp.asarray(toks[:, 1:], jnp.int32)}
 
 
+def _flat(tree):
+    return {jax.tree_util.keystr(p): np.asarray(a)
+            for p, a in jax.tree_util.tree_leaves_with_path(tree)}
+
+
 @pytest.fixture(scope="module")
 def grads(params, batch):
     got = jax.grad(ds.loss_fn)(params, batch, CFG)
     want = jax.grad(kanana_ref.loss)(params, batch["inputs"],
                                      batch["targets"], settings(CFG))
-    flat = lambda t: {jax.tree_util.keystr(p): np.asarray(a)  # noqa: E731
-                      for p, a in jax.tree_util.tree_leaves_with_path(t)}
-    return flat(got), flat(want)
+    return _flat(got), _flat(want)
 
 
 def test_logits_and_loss_equal_the_references(params, batch):
@@ -328,6 +331,44 @@ def test_flash_attention_takes_keys_wider_than_values():
     for a, b in zip(g_got, g_want):
         assert a.shape == b.shape
         assert np.abs(np.asarray(a - b)).max() < 2e-4
+
+
+# ------------------------------------- the model on the five-operand kernels
+KERNEL_CFG = dataclasses.replace(CFG, attn_impl="flash")    # interpret mode
+
+
+def test_the_model_on_the_kernels_equals_the_reference(params, batch):
+    """32 positions are one tile: the flash kernels take q_nope, q_rope,
+    k_nope, the one rotary key and v as the projections made them, and the
+    logits are the float32 reference's."""
+    want = np.asarray(kanana_ref.logits(params, batch["inputs"],
+                                        settings(CFG)))
+    got = np.asarray(ds.forward(params, batch["inputs"], KERNEL_CFG))
+    assert np.abs(got - want).max() < ATOL
+    jaxpr = str(jax.make_jaxpr(lambda p, t: ds.forward(p, t, KERNEL_CFG))(
+        params, batch["inputs"]))
+    assert "name=flash_fwd" in jaxpr
+
+
+@pytest.fixture(scope="module")
+def kernel_grads(params, batch):
+    return _flat(jax.grad(ds.loss_fn)(params, batch, KERNEL_CFG))
+
+
+@pytest.mark.parametrize("group", ["wq", "wkv_a", "kv_norm", "wkv_b", "wo",
+                                   "attn_norm", "wte"])
+def test_gradients_through_the_kernels_equal_the_references(
+        grads, kernel_grads, group):
+    """What the backward kernel's five results reach: W_q by column group,
+    W_kv_a (its rotary columns through the gradient summed over the
+    heads), W_kv_b, and everything below."""
+    _, want = grads
+    keys = [k for k in want if f"'{group}" in k]
+    assert keys
+    for key in keys:
+        scale = np.abs(want[key]).max()
+        assert np.abs(kernel_grads[key] - want[key]).max() \
+            < 1e-4 * scale + 1e-7, key
 
 
 @pytest.mark.parametrize("axes", [{"fsdp": 2, "tensor": 2},

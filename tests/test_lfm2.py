@@ -603,13 +603,15 @@ def test_bf16_engine_stays_within_the_bf16_tolerance():
 # this family shares with the cells the benchmark has (``ops/ssm.py``'s conv
 # with an optional bias, ``ops/moe.route_sigmoid``'s divisor as an argument,
 # the cache's two layer counts, the runner's hand-over) did not change what
-# they run.  Kanana's block passes no ``eps`` and keeps its 1e-20.
+# they run.  Kanana's block passes no ``eps`` and keeps its 1e-20; its
+# digest is the step's since PR 43 changed it on purpose (latent attention
+# hands over its parts unjoined, W_q and W_kv_b by column group).
 PARENT_LOWERINGS = {
     "falcon_h1 prefill": "3842cac6da8389af",
     "falcon_h1 decode": "671116cd471eec2f",
     "falcon_h1 scatter": "a42f2945b7eeeff1",
     "olmoe train": "44e4d27a01d81a57",
-    "kanana train": "d31df77c4c2f2be9",
+    "kanana train": "cf1b61d7351b8ac3",
 }
 
 

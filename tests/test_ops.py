@@ -5,6 +5,8 @@ TPU slice; every kernel/schedule is checked against the dense reference
 for values AND gradients; the Pallas kernel runs in interpret mode.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,8 +14,8 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops import (causal_attention, dense_attention, flash_attention,
-                         flash_runs, ring_attention_sharded,
-                         ulysses_attention_sharded)
+                         flash_runs, latent_causal_attention,
+                         ring_attention_sharded, ulysses_attention_sharded)
 
 B, T, H, D = 2, 64, 4, 16
 
@@ -158,6 +160,141 @@ def test_flash_runs_says_what_causal_attention_traces(monkeypatch, backend,
         lambda q, k, v: causal_attention(q, k, v, impl=impl))(q, q, q))
     assert ("name=flash_fwd" in jaxpr) is want
     assert ("pallas_call" in jaxpr) is want
+
+
+# ------------------------------------------- latent attention's five operands
+# (nope, rope, Dv, T, tile): a small one, and the Kanana cell's widths
+LATENT = {"small": (16, 8, 16, 64, 16), "kanana": (128, 64, 128, 256, 128)}
+PARTS = ("q_nope", "q_rope", "k_nope", "k_rope", "v")
+
+
+def _latent_operands(nope, rope, dv, t, heads=2, batch=2):
+    ks = jax.random.split(jax.random.key(7), 6)
+    shapes = [(batch, t, heads, nope), (batch, t, heads, rope),
+              (batch, t, heads, nope), (batch, t, rope),
+              (batch, t, heads, dv)]
+    operands = [jax.random.normal(k, s, jnp.float32)
+                for k, s in zip(ks, shapes)]
+    probe = jax.random.normal(ks[5], (batch, t, heads, dv), jnp.float32)
+    return operands, probe
+
+
+def _joined(q_nope, q_rope, k_nope, k_rope, v):
+    """What the model built before the kernels took the parts: the rotary
+    key repeated a head behind each head's k_nope."""
+    k_rope = jnp.broadcast_to(k_rope[:, :, None],
+                              k_nope.shape[:3] + k_rope.shape[-1:])
+    return (jnp.concatenate([q_nope, q_rope], -1),
+            jnp.concatenate([k_nope, k_rope], -1), v)
+
+
+@pytest.fixture(scope="module", params=sorted(LATENT))
+def latent_case(request):
+    """-> {forward, gradient a part}: (the five-operand kernels in
+    interpret mode, dense attention on the joined operands)."""
+    from ray_tpu.ops.flash_attention import latent_flash_attention
+    nope, rope, dv, t, tile = LATENT[request.param]
+    operands, probe = _latent_operands(nope, rope, dv, t)
+
+    def kernel(*parts):
+        return latent_flash_attention(*parts, tile, True)
+
+    def dense(*parts):
+        return dense_attention(*_joined(*parts))
+
+    found = {"forward": (kernel(*operands), dense(*operands))}
+    grads = [jax.grad(lambda *parts, fn=fn: (fn(*parts) * probe).sum(),
+                      argnums=tuple(range(5)))(*operands)
+             for fn in (kernel, dense)]
+    for name, got, want in zip(PARTS, *grads):
+        found[name] = (got, want)
+    return found
+
+
+@pytest.mark.parametrize("what", ("forward",) + PARTS)
+def test_latent_flash_equals_dense_on_the_joined_operands(latent_case, what):
+    """Output and all five gradients; ``k_rope``'s is (B, T, rope): the
+    kernel's result a head, summed over the heads, against the gradient of
+    the broadcast."""
+    got, want = latent_case[what]
+    assert got.shape == want.shape
+    _allclose(got, want, 1e-4)
+
+
+def test_latent_kernels_read_the_rotary_key_once_and_build_nothing_joined():
+    """Traced at bf16: the kernels' operands are the five parts (the rotary
+    key with no head axis), and no array nope + rope wide exists, forward
+    or backward; the first results are Dv and nope wide."""
+    nope, rope, dv, t, _ = LATENT["kanana"]
+    operands, _ = _latent_operands(nope, rope, dv, t)
+    operands = [x.astype(jnp.bfloat16) for x in operands]
+    jaxpr = str(jax.make_jaxpr(jax.grad(
+        lambda *parts: latent_causal_attention(*parts, impl="flash")
+        .astype(jnp.float32).sum(), argnums=tuple(range(5))))(*operands))
+    assert jaxpr.count("name=flash_fwd") == 1
+    assert jaxpr.count("name=flash_bwd") == 1
+    assert f",{nope + rope}]" not in jaxpr and "concatenate" not in jaxpr
+    assert not re.search(rf"\[2,{t},2,{rope}\] = broadcast_in_dim", jaxpr)
+    # the backward's results: dq_nope first (what mla.attention_ms knows
+    # the kernel by), a rotary-key gradient a (batch, head) fourth
+    assert re.search(rf"bf16\[4,{t},{nope}\] \w+:bf16\[4,{t},{rope}\] "
+                     rf"\w+:bf16\[4,{t},{nope}\] \w+:bf16\[4,{t},{rope}\] "
+                     rf"\w+:bf16\[4,{t},{dv}\] = pallas_call", jaxpr)
+
+
+@pytest.mark.parametrize("backend,seq_len,impl,kernel", [
+    ("tpu", 128, "auto", True), ("cpu", 128, "auto", False),
+    ("cpu", 128, "flash", True), ("tpu", 192, "auto", False),
+    ("tpu", 128, "dense", False)])
+def test_latent_attention_asks_flash_runs_as_causal_attention_does(
+        monkeypatch, backend, seq_len, impl, kernel):
+    """Off the kernel the parts are joined for ``causal_attention``:
+    the same values either way."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert flash_runs(seq_len, impl) is kernel
+    shapes = [jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in (
+        (1, seq_len, 2, 16), (1, seq_len, 2, 8), (1, seq_len, 2, 16),
+        (1, seq_len, 8), (1, seq_len, 2, 16))]
+    jaxpr = str(jax.make_jaxpr(
+        lambda *parts: latent_causal_attention(*parts, impl=impl))(*shapes))
+    assert ("name=flash_fwd" in jaxpr) is kernel
+    assert ("concatenate" in jaxpr) is not kernel
+
+
+def test_latent_attention_off_the_kernel_is_dense_on_the_joined_operands():
+    operands, _ = _latent_operands(16, 8, 16, 48)
+    _allclose(latent_causal_attention(*operands, impl="dense"),
+              dense_attention(*_joined(*operands)), 1e-6)
+
+
+# The jaxpr text of a three-operand call as the parent of the PR that gave
+# latent attention its entry traced it (commit 9c24c8f, this jax): forward
+# and gradient, at GPT-2's head width and at keys wider than values.  The
+# kernels' body is shared with the five-operand call; what every other
+# caller lowers, and its compile-cache key, must not move with it.
+PARENT_JAXPRS = {
+    ("forward", 64, 64): "c1b26728f00cf0d7",
+    ("gradient", 64, 64): "1962c8f36f723ea0",
+    ("forward", 192, 128): "43732ca3c853f0ee",
+    ("gradient", 192, 128): "852c6025357501e7",
+}
+
+
+@pytest.mark.parametrize("what,d,dv", sorted(PARENT_JAXPRS))
+def test_a_three_operand_flash_call_traces_to_the_jaxpr_it_had(what, d, dv):
+    import hashlib
+    shape = {64: (2, 256, 4), 192: (1, 512, 2)}[d]
+    qk = jax.ShapeDtypeStruct(shape + (d,), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct(shape + (dv,), jnp.bfloat16)
+
+    def forward(q, k, v):
+        return flash_attention(q, k, v, True, None, True)
+
+    fn = forward if what == "forward" else jax.grad(
+        lambda *a: forward(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    text = str(jax.make_jaxpr(fn)(qk, qk, v))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == PARENT_JAXPRS[(what, d, dv)]
 
 
 @pytest.mark.parametrize("impl", ["blockwise", "Flash", ""])
