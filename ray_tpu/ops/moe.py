@@ -18,11 +18,17 @@ are the only non-matmul cost and XLA fuses their construction).
 capacity and no dropped token; the assignments are sorted by expert and
 the experts run as grouped matmuls over ragged groups.  At 8 of 64
 experts a token the one-hot dispatch above costs 5-7 times the experts'
-own matmuls; the sort costs a few passes over the rows.
+own matmuls; the sort costs a few passes over the rows: the gather into
+expert order and, back, a gather and a sum over a token's k slots, each
+the other's backward.  The router's weight goes into the experts with the
+sorted rows and multiplies the hidden rows (``down(w h) = w down(h)``), so
+the way back carries no weight, writes no float32 copy of the rows and
+needs none of them for its gradient.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
@@ -137,6 +143,18 @@ class RouterStats(NamedTuple):
     load_max_over_mean: jax.Array  # most-loaded expert's rows / mean rows
 
 
+def _spread_rows(x, order, k):
+    """(N, d) -> (N k, d): row r is token ``order[r] // k``."""
+    return jnp.take(x, order // k, axis=0)
+
+
+def _sum_slots(rows, inverse, k):
+    """(N k, d) rows in expert order -> (N, d): each token the float32
+    sum of its k rows (``inverse`` puts them back in token order)."""
+    back = jnp.take(rows, inverse, axis=0).reshape(-1, k, rows.shape[-1])
+    return back.sum(1, dtype=jnp.float32).astype(rows.dtype)
+
+
 @jax.custom_vjp
 def _rows_to_experts(x, order, inverse):
     """(N, d) tokens -> (N k, d) rows grouped by expert: row r holds token
@@ -144,8 +162,7 @@ def _rows_to_experts(x, order, inverse):
     assignments and ``inverse`` its inverse, so the backward is a gather
     and a sum over a token's k slots, not the scatter-add XLA would
     derive from the forward gather."""
-    k = order.shape[0] // x.shape[0]
-    return jnp.take(x, order // k, axis=0)
+    return _spread_rows(x, order, order.shape[0] // x.shape[0])
 
 
 def _rows_to_experts_fwd(x, order, inverse):
@@ -154,11 +171,30 @@ def _rows_to_experts_fwd(x, order, inverse):
 
 def _rows_to_experts_bwd(res, g):
     n, inverse = res
-    dx = jnp.take(g, inverse, axis=0).reshape(n, -1, g.shape[-1])
-    return dx.sum(1, dtype=jnp.float32).astype(g.dtype), None, None
+    return _sum_slots(g, inverse, g.shape[0] // n), None, None
 
 
 _rows_to_experts.defvjp(_rows_to_experts_fwd, _rows_to_experts_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _experts_to_rows(out, order, inverse, k):
+    """``_rows_to_experts`` turned round: (N k, d) rows grouped by expert
+    -> (N, d) tokens, each the float32 sum of its k rows.  The backward is
+    ``_rows_to_experts``' forward, a gather from the (N, d) gradient: it
+    reads nothing of ``out``, so no row is saved or recomputed for it."""
+    return _sum_slots(out, inverse, k)
+
+
+def _experts_to_rows_fwd(out, order, inverse, k):
+    return _experts_to_rows(out, order, inverse, k), order
+
+
+def _experts_to_rows_bwd(k, order, g):
+    return _spread_rows(g, order, k), None, None
+
+
+_experts_to_rows.defvjp(_experts_to_rows_fwd, _experts_to_rows_bwd)
 
 
 @jax.custom_vjp
@@ -309,17 +345,25 @@ def dropless_experts(x: jax.Array, expert_idx: jax.Array, weights: jax.Array,
     ``first_held .. first_held + H - 1`` of the ``num_experts`` the router
     chose among (all of them when H == E): the share of an expert-
     parallel layer that this chip holds.  The N k assignments are sorted
-    by expert, the held experts' first, the rows gathered, three grouped
-    matmuls run over the held groups, and the rows are put back and
-    summed per token.  A choice of an absent expert costs no matmul and
-    adds nothing: what that expert would add is another chip's part, and
-    on several chips the exchange of rows goes between the sort and the
-    matmuls (DESIGN.md, held experts).  No capacity: no (N, E, C) tensor
-    exists and nothing is dropped among the held, whatever the imbalance.
-    ``group_sizes[i]`` counts the rows of expert ``first_held + i`` (mod E).
+    by expert, the held experts' first, the rows gathered and each row's
+    weight with them, three grouped matmuls run over the held groups, and
+    the rows are put back and summed per token.  The weight multiplies
+    the hidden rows ``silu(gate) * up`` in float32, inside the fusion that
+    makes them, and not the output rows: the down projection is linear,
+    so ``down(w h) = w down(h)``, and the combine is then the dispatch
+    transposed (``_experts_to_rows``), whose gradient reads no output row.
+    (A weight on the output rows has the gradient ``sum_d out * g``: a
+    checkpointed layer would run the down projection a second time in
+    its backward only to feed that product.)  A choice of an absent
+    expert costs no matmul and adds nothing (its rows come out of
+    ``grouped_matmul`` zero): what that expert would add is another
+    chip's part, and on several chips the exchange of rows goes between
+    the sort and the matmuls (DESIGN.md, held experts).  No capacity: no
+    (N, E, C) tensor exists and nothing is dropped among the held,
+    whatever the imbalance.  ``group_sizes[i]`` counts the rows of expert
+    ``first_held + i`` (mod E).
     """
-    n, d = x.shape
-    k = expert_idx.shape[-1]
+    n, k = expert_idx.shape
     with jax.named_scope("moe_dispatch"):
         flat_expert = expert_idx.reshape(n * k)
         if first_held:
@@ -329,15 +373,17 @@ def dropless_experts(x: jax.Array, expert_idx: jax.Array, weights: jax.Array,
         group_sizes = jnp.bincount(flat_expert, length=num_experts
                                    ).astype(jnp.int32)
         rows = _rows_to_experts(x, order, inverse)               # (N k, d)
+        w_sorted = _permute_rows(weights.astype(jnp.float32).reshape(n * k),
+                                 order, inverse)                 # (N k,)
     with jax.named_scope("moe_experts"):
         gate = grouped_matmul(rows, w_gate.astype(x.dtype), group_sizes)
         up = grouped_matmul(rows, w_up.astype(x.dtype), group_sizes)
-        out = grouped_matmul(jax.nn.silu(gate) * up,
-                             w_down.astype(x.dtype), group_sizes)
+        hidden = (jax.nn.silu(gate.astype(jnp.float32))
+                  * up.astype(jnp.float32) * w_sorted[:, None])
+        out = grouped_matmul(hidden.astype(x.dtype), w_down.astype(x.dtype),
+                             group_sizes)
     with jax.named_scope("moe_combine"):
-        out = _permute_rows(out, inverse, order).reshape(n, k, d)
-        y = jnp.einsum("nkd,nk->nd", out, weights.astype(out.dtype),
-                       preferred_element_type=jnp.float32).astype(x.dtype)
+        y = _experts_to_rows(out, order, inverse, k)
     return y, group_sizes
 
 

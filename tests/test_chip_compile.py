@@ -166,8 +166,10 @@ def _experts_loss(*args):
 
 @pytest.mark.parametrize("fn,n_grouped", [
     pytest.param(_experts, 3, id="forward"),
-    pytest.param(jax.grad(_experts_loss, argnums=(0, 1, 2, 3, 4)), 9,
-                 id="forward_backward"),
+    # the value too: no gradient reads the experts' output rows, so a
+    # function of the gradients alone runs no down projection at all
+    pytest.param(jax.value_and_grad(_experts_loss, argnums=(0, 1, 2, 3, 4)),
+                 9, id="forward_backward"),
 ])
 def test_dropless_experts_at_olmoe_train_shape(v5e, monkeypatch, fn,
                                                n_grouped):
@@ -556,8 +558,11 @@ def test_kanana_step_compiles_with_its_kernels_and_fits(v5e, monkeypatch):
     positions need more than Mosaic's default scoped VMEM) and nothing 192
     wide anywhere in the step, every grouped matmul over the
     16 held experts a megablox kernel at a tile that divides 768 and 2,048
-    (12 in the sparse scan), each with a result shape its metric is keyed
-    on, no ``ragged-dot`` fallback, and everything inside the chip."""
+    (11 in the sparse scan: the forward's three, gate and up recomputed,
+    six of the backward; no down projection is recomputed since the
+    combine's gradient reads no output row), each with a result shape its
+    metric is keyed on, no float32 copy of the sorted rows, no
+    ``ragged-dot`` fallback, and everything inside the chip."""
     import json
     from pathlib import Path
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -575,10 +580,13 @@ def test_kanana_step_compiles_with_its_kernels_and_fits(v5e, monkeypatch):
     assert flash == ["bf16[64,8192,128]"] * 4, kernels
     for joined in ("[2,8192,32,192]", "[2,32,8192,192]", "[64,8192,192]"):
         assert joined not in text
-    assert len(experts) == 12 and len(flash) + len(experts) == len(kernels)
+    assert len(experts) == 11 and len(flash) + len(experts) == len(kernels)
     assert set(experts) == set(keyed["moe.held_expert_ms"])
     assert "ragged-dot" not in text
     assert not re.search(r"\[16384,128,\d+\]", text)    # no dispatch tensor
+    # the combine makes no float32 copy of the sorted rows, forward or back
+    assert "f32[16384,6,2048]" not in text
+    assert not re.search(r"= f32\[98304,2048\].*moe_combine", text)
     mem = compiled.memory_analysis()
     held = mem.argument_size_in_bytes + mem.output_size_in_bytes \
         - mem.alias_size_in_bytes + mem.temp_size_in_bytes
@@ -627,9 +635,13 @@ def _operations_and_kernels(lowered_text):
 # tgmm); GPT-2 XL: 1,851 and the two flash kernels.  ops/moe.py, the flash
 # kernel's widths and VMEM rule and the optimizer's mask changed around
 # them; what these steps compute did not.  A PR that changes such a step on
-# purpose replaces its digest.
+# purpose replaces its digest: PR 45 did OLMoE's (``dropless_experts``: the
+# router's weight multiplies the hidden rows in float32 and the combine is
+# the dispatch transposed, so the checkpointed layer's backward calls no
+# down projection a second time: 4,039 operations and 9 kernel calls where
+# there were 4,092 and 10).
 PARENT_STEPS = {
-    "olmoe-1b-7b.train-b2-s4096": ("2cf88e601e4040c4", 4092, 10),
+    "olmoe-1b-7b.train-b2-s4096": ("fcf2f1d1eab312c5", 4039, 9),
     "gpt2-xl-1558m.train-b8-s1024": ("87069c55eb334fc3", 1851, 2),
 }
 
@@ -890,11 +902,14 @@ def test_minicpm_sala_chunk_program_is_one_and_keeps_the_pool_out(
 # paged kernel to walk a list of pages lowered them (commit 1a45b60, this
 # jax): digest, StableHLO operations, Mosaic kernels.  The kernel's body
 # builds either walk; the walk without a list is the one there was,
-# operation for operation.
+# operation for operation.  ``lfm2`` is the step's since PR 45 changed
+# ``ops/moe.dropless_experts`` on purpose (the weights sorted with the rows
+# and multiplied into the hidden rows in float32, no ``nkd,nk->nd`` product:
+# 1,367 operations where there were 1,314).
 PARENT_DECODE_STEPS = {
     "xl": ("b5e4a17574d47c3c", 413, 1),
     "falcon_h1": ("6dc9e09f46d43408", 673, 1),
-    "lfm2": ("a6798ee27cf55288", 1314, 1),
+    "lfm2": ("c56be286eeb06544", 1367, 1),
 }
 
 

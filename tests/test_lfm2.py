@@ -605,13 +605,16 @@ def test_bf16_engine_stays_within_the_bf16_tolerance():
 # the cache's two layer counts, the runner's hand-over) did not change what
 # they run.  Kanana's block passes no ``eps`` and keeps its 1e-20; its
 # digest is the step's since PR 43 changed it on purpose (latent attention
-# hands over its parts unjoined, W_q and W_kv_b by column group).
+# hands over its parts unjoined, W_q and W_kv_b by column group), and both
+# training digests are the steps' since PR 45 did (``ops/moe.
+# dropless_experts``: the router's weight multiplies the hidden rows and the
+# combine is the dispatch transposed; one grouped matmul fewer a layer).
 PARENT_LOWERINGS = {
     "falcon_h1 prefill": "3842cac6da8389af",
     "falcon_h1 decode": "671116cd471eec2f",
     "falcon_h1 scatter": "a42f2945b7eeeff1",
-    "olmoe train": "44e4d27a01d81a57",
-    "kanana train": "cf1b61d7351b8ac3",
+    "olmoe train": "98084bcc33e6cbb4",
+    "kanana train": "80ca33e22ad26c4d",
 }
 
 
