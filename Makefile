@@ -2,7 +2,8 @@
 
 PY ?= python
 
-.PHONY: test sanitize fuzz bench lint rtlint jaxlint xlacheck \
+.PHONY: test sanitize fuzz bench perfbench-rehearse lint rtlint jaxlint \
+	xlacheck \
 	check-metrics microbench-quick \
 	databench-quick servebench-quick llmbench-quick tracebench-quick \
 	releasebench-quick fleetbench-quick obsbench-quick \
@@ -87,6 +88,17 @@ fuzz:
 	RTPU_SIM_STEPS=2000000 $(PY) -m pytest \
 		tests/test_protocol_sim.py -q -x
 
+# The benchmark that judges the repo is perfbench (BENCHMARK.json: seven
+# cells on one TPU v5e; PERF.md, PERF_LEDGER.jsonl).  On the chip:
+#   python3 -m perfbench.run --workload <cell> --seed N --seconds 51 --trace 0
+# Here, one serving cell's control flow at a toy size, under CPU names:
+perfbench-rehearse:
+	JAX_PLATFORMS=cpu $(PY) -m perfbench.run \
+		--workload gpt2-xl-1558m.serve-chat-steady --seed 1 \
+		--seconds 5 --trace 0 --rehearse
+
+# An older script no chip has run since the step was last edited (ROADMAP
+# D7); it measures nothing the ledger holds.
 bench:
 	$(PY) bench.py
 

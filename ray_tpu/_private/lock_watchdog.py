@@ -546,14 +546,16 @@ STEP_PATHS: Set[str] = {
 # deliberately NOT declared (callers that enable it feed fresh batches
 # and the static rule covers the unconditional donation only).
 # The serve/llm block pool (DESIGN.md §4g) is donated by every program
-# that writes it — the runner's decode step and the cache's three
-# writers — and none is called with the array by name: each runs through
+# that writes it — the runner's decode step, its prefill where a family
+# keeps state there, the chunk, and the cache's three writers — and none
+# is called with the array by name: each runs through
 # ``DevicePool.donate``, which rebinds the array it gets back.
 DONATED: Dict[str, Tuple[int, ...]] = {
     "step_fn": (0,),
     "llm_decode_step": (0,),
-    "llm_prefill_state_step": (0,),
-    "llm_decode_state_step": (0,),
+    # the holder of a family that stages a prompt's state in it; any other
+    # hands the same program None in its place
+    "llm_prefill_step": (0,),
     # a prompt's chunk also donates the runner's staging K/V (argnum 2),
     # which comes back in the program's result and is rebound there
     "llm_prefill_chunk_step": (0, 2),

@@ -1,7 +1,9 @@
 """What the decoders share: init and count helpers, the rule that makes a
 serving tree of a stored one, the remat rule of a block, the batch's two
-forms and the loss head.  Each model file keeps its own block, parameter
-tree and named scopes."""
+forms, the loss head, and the primitives more than one family's block is
+written with (RMS norm, the three rotary embeddings, the GQA repeat).  Each
+model file keeps its own block, parameter tree and named scopes, and
+imports no sibling's private name: what two families need lives here."""
 
 from __future__ import annotations
 
@@ -10,6 +12,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 
 def normal_init(key: jax.Array, shape, dtype, scale: float = 0.02):
@@ -177,3 +180,64 @@ def next_token_nll(logits: jax.Array, targets: jax.Array) -> jax.Array:
         lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
         correct = jnp.take_along_axis(logits, targets[..., None], -1)[..., 0]
         return (lse - correct.astype(jnp.float32)).mean()
+
+
+# ------------------------------------- primitives of more than one family
+def _rms_norm(x, scale, eps):
+    x32 = x.astype(jnp.float32)
+    y = x32 * lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + eps)
+    return (y * scale).astype(x.dtype)
+
+
+def _rope(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary embeddings over (B, T, H, D); rotates pairs (d, d+D/2)."""
+    B, T, H, D = x.shape
+    half = D // 2
+    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    angles = jnp.arange(T, dtype=jnp.float32)[:, None] * freqs[None, :]
+    cos = jnp.cos(angles)[None, :, None, :]  # (1, T, 1, half)
+    sin = jnp.sin(angles)[None, :, None, :]
+    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+    return out.astype(x.dtype)
+
+
+def _rope_at(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """Rotary embedding for single tokens at explicit positions.
+
+    x (B, H, D); positions (B,) int32 — the absolute position of each
+    sequence's token (decode caches post-RoPE keys, so each key is
+    rotated once, at its own position)."""
+    B, H, D = x.shape
+    half = D // 2
+    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    angles = positions.astype(jnp.float32)[:, None] * freqs[None, :]
+    cos = jnp.cos(angles)[:, None, :]            # (B, 1, half)
+    sin = jnp.sin(angles)[:, None, :]
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:].astype(jnp.float32)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+    return out.astype(x.dtype)
+
+
+def _rope_interleaved(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary embedding over (B, T, H, D) on the pairs (2i, 2i + 1):
+    position t turns pair i by t . theta^(-2i / D)."""
+    B, T, H, D = x.shape
+    half = D // 2
+    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    angles = jnp.arange(T, dtype=jnp.float32)[:, None] * freqs[None, :]
+    cos, sin = jnp.cos(angles)[None, :, None, :], jnp.sin(angles)[None, :, None, :]
+    pairs = x.astype(jnp.float32).reshape(B, T, H, half, 2)
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return out.reshape(B, T, H, D).astype(x.dtype)
+
+
+def _gqa_expand(kv: jax.Array, n_head: int) -> jax.Array:
+    """(B, T, n_kv, D) → (B, T, n_head, D) by repeating KV groups."""
+    B, T, n_kv, D = kv.shape
+    if n_kv == n_head:
+        return kv
+    rep = n_head // n_kv
+    return jnp.repeat(kv, rep, axis=2)

@@ -43,9 +43,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models._common import (next_token_nll, normal_init,
-                                    remat_block, split_batch)
-from ray_tpu.models.llama import _rms_norm
+from ray_tpu.models._common import (  # noqa: F401
+    _rms_norm, _rope_interleaved, next_token_nll, normal_init, remat_block,
+    split_batch)
 
 Params = Dict[str, Any]
 
@@ -148,20 +148,6 @@ def init_params(rng: jax.Array, cfg: DeepseekV3Config) -> Params:
 
 
 # ------------------------------------------------------------------ forward
-def _rope_interleaved(x: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding over (B, T, H, D) on the pairs (2i, 2i + 1):
-    position t turns pair i by t . theta^(-2i / D)."""
-    B, T, H, D = x.shape
-    half = D // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    angles = jnp.arange(T, dtype=jnp.float32)[:, None] * freqs[None, :]
-    cos, sin = jnp.cos(angles)[None, :, None, :], jnp.sin(angles)[None, :, None, :]
-    pairs = x.astype(jnp.float32).reshape(B, T, H, half, 2)
-    x1, x2 = pairs[..., 0], pairs[..., 1]
-    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-    return out.reshape(B, T, H, D).astype(x.dtype)
-
-
 def _latent_attention(u: jax.Array, lp: Params, cfg: DeepseekV3Config):
     """Normed hidden states (B, T, E) -> W_o . attention (B, T, E).  Each
     of the kernel's operands is a projection's own result: W_q and W_kv_b

@@ -70,8 +70,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models._common import normal_init, param_count  # noqa: F401
-from ray_tpu.models.llama import _gqa_expand, _rms_norm, _rope, _rope_at
+from ray_tpu.models._common import (  # noqa: F401
+    _gqa_expand, _rms_norm, _rope, _rope_at, normal_init, param_count)
 from ray_tpu.ops import short_conv
 
 Params = Dict[str, Any]

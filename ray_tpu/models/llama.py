@@ -22,7 +22,8 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models._common import (  # noqa: F401
-    next_token_nll, normal_init, param_count, remat_block, split_batch)
+    _gqa_expand, _rms_norm, _rope, _rope_at, next_token_nll, normal_init,
+    param_count, remat_block, split_batch)
 
 Params = Dict[str, Any]
 
@@ -174,34 +175,6 @@ def _head(params: Params, x: jax.Array, cfg: LlamaConfig,
         return logits.astype(jnp.float32)
 
 
-def _rms_norm(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + eps)
-    return (y * scale).astype(x.dtype)
-
-
-def _rope(x: jax.Array, theta: float) -> jax.Array:
-    """Rotary embeddings over (B, T, H, D); rotates pairs (d, d+D/2)."""
-    B, T, H, D = x.shape
-    half = D // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    angles = jnp.arange(T, dtype=jnp.float32)[:, None] * freqs[None, :]
-    cos = jnp.cos(angles)[None, :, None, :]  # (1, T, 1, half)
-    sin = jnp.sin(angles)[None, :, None, :]
-    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-    return out.astype(x.dtype)
-
-
-def _gqa_expand(kv: jax.Array, n_head: int) -> jax.Array:
-    """(B, T, n_kv, D) → (B, T, n_head, D) by repeating KV groups."""
-    B, T, n_kv, D = kv.shape
-    if n_kv == n_head:
-        return kv
-    rep = n_head // n_kv
-    return jnp.repeat(kv, rep, axis=2)
-
-
 def _qkv(h: jax.Array, lp: Params, cfg: LlamaConfig):
     """Normed hidden states (..., E) -> q (..., H, D), k, v (..., KV, D),
     before RoPE."""
@@ -294,24 +267,6 @@ def forward(params: Params, tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:
 
 
 # -------------------------------------------------- inference (KV cache)
-def _rope_at(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding for single tokens at explicit positions.
-
-    x (B, H, D); positions (B,) int32 — the absolute position of each
-    sequence's token (decode caches post-RoPE keys, so each key is
-    rotated once, at its own position)."""
-    B, H, D = x.shape
-    half = D // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    angles = positions.astype(jnp.float32)[:, None] * freqs[None, :]
-    cos = jnp.cos(angles)[:, None, :]            # (B, 1, half)
-    sin = jnp.sin(angles)[:, None, :]
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-    return out.astype(x.dtype)
-
-
 def routed_layers(cfg: LlamaConfig) -> Optional[Dict[str, int]]:
     """What the serving step programs of a preset with experts hand over
     beside the logits (``serve/llm/model_runner.py``): the expert ids each

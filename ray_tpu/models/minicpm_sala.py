@@ -60,8 +60,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models._common import normal_init, param_count  # noqa: F401
-from ray_tpu.models.llama import _rms_norm, _rope_at
+from ray_tpu.models._common import (  # noqa: F401
+    _rms_norm, _rope_at, normal_init, param_count)
 from ray_tpu.ops import sparse_attention as sparse
 from ray_tpu.ops import ssm
 from ray_tpu.ops.sparse_attention import SparseSpec

@@ -47,8 +47,9 @@ a block to the free list only at refcount zero.
 Recurrent state.  A model family whose sequences hold more than K/V (a
 state-space mixer's scan state and conv tail: ``models/falcon_h1.py``)
 describes one sequence's state per layer, and the cache is built with
-that description (``state=``).  What ``cache.pool`` holds is then not the
-pool's array alone but ``{"kv": the array, "state": the store}``, the
+that description (``state=``).  What ``cache.pool`` holds is always a
+dict, ``{"kv": the array}``, and for such a family ``{"kv": the array,
+"state": the store}``, the
 store one leaf per kind of state, ``(n_layer, max_seqs + 1, *shape)``: a
 *row* per sequence slot and a last one for staging.  The same holder, the
 same donating programs, one lifetime: ``alloc_seq`` gives a sequence its
@@ -57,8 +58,8 @@ both back.  The row is fixed-size and never paged.  A prompt's prefill
 program leaves its state in the staging row (``model_runner.py``), and
 ``scatter_prefill`` moves it to the sequence's row in the program that
 scatters its K/V; the decode program steps the rows ``rows_of`` names
-for the tables it was given.  Without ``state=`` the holder holds the
-array as before and every program lowers as before.
+for the tables it was given.  Without ``state=`` the dict has no
+``"state"`` and the programs no operand for it.
 
 Layers that differ in kind.  The pool's ``n_layer`` counts the layers that
 hold K/V and the store's leading axis the layers that hold state
@@ -89,8 +90,8 @@ step's write, ``write_token``) sums the slot a new row falls in again
 from the pool's rows, so writing a row twice changes nothing and no
 caller can write K and forget its slot.  (``load_block`` writes none: the
 one family with a selector keeps state rows too, and the engine refuses to
-attach a sequence of such a model.)  A family that names no selector gets
-the holder and the programs it had.
+attach a sequence of such a model.)  A family that names no selector has
+no ``"sel"`` in its holder, and its programs none of this.
 """
 
 from __future__ import annotations
@@ -149,21 +150,6 @@ def _pid_alive(pid: int) -> bool:
     except PermissionError:
         return True
     return True
-
-
-def _kv(held):
-    """The K/V pool's array out of what a :class:`DevicePool` holds."""
-    return held["kv"] if isinstance(held, dict) else held
-
-
-def _with_kv(held, kv):
-    return {**held, "kv": kv} if isinstance(held, dict) else kv
-
-
-def _sel(held):
-    """The selector's cache out of what a :class:`DevicePool` holds; None
-    for a family that keeps none."""
-    return held.get("sel") if isinstance(held, dict) else None
 
 
 def device_shape(num_blocks: int, n_layer: int, block_size: int,
@@ -268,27 +254,25 @@ def _programs() -> SimpleNamespace:
     from ray_tpu.ops.paged_attention import heads_apart, lane_flat
     from ray_tpu.ops.sparse_attention import halves_of
 
-    def _written(held, blocks, offsets, k, v):
-        """``held`` with the rows written, and the half-kernels they fall
-        in where it keeps a selector's cache."""
-        if _sel(held) is not None:
-            pool, sel = write_rows(held["kv"], blocks, offsets, k, v,
-                                   held["sel"])
-            return {**held, "kv": pool, "sel": sel}
-        return _with_kv(held, write_rows(_kv(held), blocks, offsets, k, v))
-
     def _write_rows(held, blocks, offsets, k, v):
-        return _written(held, blocks, offsets, k, v), None
+        # the half-kernels the rows fall in too, where a selector's cache
+        # is kept
+        sel = held.get("sel")
+        if sel is None:
+            return {**held, "kv": write_rows(held["kv"], blocks, offsets,
+                                             k, v)}, None
+        pool, sel = write_rows(held["kv"], blocks, offsets, k, v, sel)
+        return {**held, "kv": pool, "sel": sel}, None
 
     def _scatter_prefill(held, table, ks, vs, n_tokens, *row):
         # token t of the padded prompt -> slot t % bs of block table[t // bs];
         # padding (t >= n_tokens) is sent out of range and dropped
-        pool = _kv(held)
+        pool = held["kv"]
         num_blocks, bs = pool.shape[2:4]
         with jax.named_scope("kv_write"):
             t = jnp.arange(ks.shape[1])
             blocks = jnp.where(t < n_tokens, table[t // bs], num_blocks)
-            sel = _sel(held)
+            sel = held.get("sel")
             if sel is not None:
                 # a prompt's half-kernels from its K whole, a slot each
                 # ``stride`` positions; the last may be part of one, which
@@ -305,7 +289,7 @@ def _programs() -> SimpleNamespace:
                     slots.reshape(-1)].set(
                         halves.reshape(-1, f).astype(sel.dtype),
                         mode="drop").reshape(sel.shape)}
-            held = _with_kv(held, write_rows(pool, blocks, t % bs, ks, vs))
+            held = {**held, "kv": write_rows(pool, blocks, t % bs, ks, vs)}
         if row:
             # recurrent state: the prompt's, which its prefill left in the
             # staging row, goes to the sequence's row
@@ -317,17 +301,17 @@ def _programs() -> SimpleNamespace:
     # a block between its wire format (L, 2, bs, KV, D) and the pool's
     # pool[:, :, i], (L, 2, bs, F): the lane padding never crosses
     def _load_block(held, block_id, block):
-        pool = _kv(held)
+        pool = held["kv"]
         flat = lane_flat(block, pool.shape[-1]).astype(pool.dtype)
-        return _with_kv(held, lax.dynamic_update_index_in_dim(
-            pool, flat, block_id, 2)), None
+        return {**held, "kv": lax.dynamic_update_index_in_dim(
+            pool, flat, block_id, 2)}, None
 
     def _read_block(held, block_id, heads):
         return heads_apart(lax.dynamic_index_in_dim(
-            _kv(held), block_id, 2, keepdims=False), *heads)
+            held["kv"], block_id, 2, keepdims=False), *heads)
 
     def _read_blocks(held, heads):
-        return heads_apart(jnp.moveaxis(_kv(held), 2, 0), *heads)
+        return heads_apart(jnp.moveaxis(held["kv"], 2, 0), *heads)
 
     # the names are rows of lock_watchdog.DONATED (jaxlint pins them)
     kv_write_rows = jax.jit(_write_rows, donate_argnums=(0,))
@@ -341,10 +325,10 @@ def _programs() -> SimpleNamespace:
 
 
 class DevicePool:
-    """The block pool's device array, whoever holds it now; with ``state``
-    (a store's leaves as ``ShapeDtypeStruct``s) what it holds is
-    ``{"kv": that array, "state": the store}``, and ``donate`` / ``read``
-    / ``fill`` do not care which.
+    """The block pool's device array, whoever holds it now, as ``{"kv":
+    that array}``; with ``state`` (a store's leaves as
+    ``ShapeDtypeStruct``s) the store beside it, ``"state"``, and with
+    ``sel`` the selector's cache, ``"sel"``.
 
     A donating program deletes the array it was given and returns a new
     one over the same memory, so nobody may keep the array itself:
@@ -389,7 +373,7 @@ class DevicePool:
         """Of the K/V pool's array, in its device format: how a model's
         decode step handed the holder itself (eagerly, outside the
         runner's programs) reads a layer."""
-        return self.read(lambda held: _kv(held)[index])
+        return self.read(lambda held: held["kv"][index])
 
     def fill(self, value) -> None:
         """A new array of ``value``.  The old one is waited for and
@@ -407,12 +391,10 @@ class DevicePool:
                 lambda s: jnp.full(s.shape, value, s.dtype))
 
     def _held(self, kv, made):
-        """What the holder holds around ``kv``: the array alone, or with
-        the store and the selector's cache, each leaf ``made`` from its
-        description."""
+        """What the holder holds around ``kv``: beside it the store and
+        the selector's cache of a family that has them, each leaf ``made``
+        from its description."""
         import jax
-        if self.state is None and self.sel is None:
-            return kv
         held = {"kv": kv}
         if self.state is not None:
             held["state"] = jax.tree.map(made, self.state)

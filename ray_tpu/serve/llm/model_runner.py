@@ -49,15 +49,18 @@ exporting ``recurrent_state(cfg)``: one sequence's state in one layer,
 name -> shape and type.  That description is all the runner and the cache
 know of it (``runner.state_spec``; the engine builds its cache with it):
 what the cache's holder holds is then ``{"kv": pool, "state": store}``
-(``kv_cache.py``), and both step programs take it donated.  The prefill
-program writes the state at the prompt's last real position into the
-store's staging row, for ``scatter_prefill`` to commit to the sequence's
-row; the decode program hands the model the store and, for each batch
-row, the store row the cache names for its block table (rows padded up
-to the bucket name none, and write nowhere).  ``prefill`` and ``decode``
-keep their signatures and results: the state travels behind them.  A
-module that exports no description is served by the programs it always
-had, which lower as before.
+(``kv_cache.py``; ``{"kv": pool}`` for every other family).  There is one
+decode body and one prefill body, and each reads what the holder's dict
+has.  The prefill program of such a family takes the holder donated and
+writes the state at the prompt's last real position into the store's
+staging row, for ``scatter_prefill`` to commit to the sequence's row; a
+family without a store hands the same body no holder (``None``, no
+operand: a pool of 1.3 GB does not go through a donating program to
+change nothing).  The decode program hands the model the store and, for
+each batch row, the store row the cache names for its block table (rows
+padded up to the bucket name none, and write nowhere), an operand that
+exists only where there is a store.  ``prefill`` and ``decode`` keep
+their signatures and results: the state travels behind them.
 
 Layers that differ in kind.  A family in which some layers hold K/V and
 others recurrent state, and none both (``models/lfm2.py``), says how many
@@ -153,6 +156,14 @@ def _bucket(n: int, buckets) -> int:
     raise ValueError(f"{n} exceeds largest bucket {buckets[-1]}")
 
 
+class _NoHolder:
+    """``DevicePool``'s two calls for a program that is handed no holder:
+    ``None`` in its place (no operand), and the results alone back."""
+
+    abstract = staticmethod(lambda: None)
+    donate = staticmethod(lambda program, *args: program(None, *args))
+
+
 class Chosen(NamedTuple):
     """What a step hands a caller that named ``logit_rows``."""
 
@@ -245,11 +256,11 @@ class ModelRunner:
         self.select_spec = describe(self.mcfg) if describe else None
         self.chunk = self.mcfg.prefill_chunk \
             if hasattr(self.mod, "forward_prefill_chunk") else 0
-        if self.select_spec and self.state_spec is None:
+        if self.chunk and self.state_spec is None:
             raise NotImplementedError(
-                f"{cfg.model} chooses its pages and holds no recurrent "
-                "state: the selector's cache is read and written by the "
-                "step of a module with state rows (decode_state_step)")
+                f"{cfg.model} prefills in chunks and holds no recurrent "
+                "state: a chunk hands the next what it carries in the "
+                "store's staging row (prefill_chunk_step)")
         if self.chunk and any(b % self.chunk
                               for b in cfg.prefill_len_buckets):
             raise ValueError(
@@ -285,24 +296,38 @@ class ModelRunner:
                 return {}
             return {"live": jnp.arange(tokens.shape[0]) < n_real}
 
-        def prefill_step(params, toks, last_pos):
+        def prefill_step(held, params, toks, last_pos):
+            # a family with a store is handed the holder, donated: the
+            # state at the prompt's last real position goes to the store's
+            # last row, where scatter_prefill finds it.  Any other hands
+            # None and gets the results alone
             logits, ks, vs, *ids = forward_prefill(params, toks,
                                                    last_pos=last_pos)
-            return (logits, greedy(logits)), ks[:, 0], vs[:, 0], *ids
+            if held is not None:
+                state, *ids = ids
+                held = {**held, "state": jax.tree.map(
+                    lambda s, new: s.at[:, -1].set(new[:, 0]),
+                    held["state"], state)}
+            out = (logits, greedy(logits)), ks[:, 0], vs[:, 0], *ids
+            return out if held is None else (held, out)
 
-        def new_kv_written(pool, k, v, block_tables, ctx_lens, n_real,
-                           sel=None):
+        def new_kv_written(held, k, v, block_tables, ctx_lens, n_real):
             # a row's new K/V goes to the slot append_slot reserved,
             # (table[ctx // bs], ctx % bs).  Rows padded up to the bucket
-            # are sent out of range: they write nowhere.  With the
-            # selector's cache: (pool, sel), the half-kernels written too
+            # are sent out of range: they write nowhere.  With a
+            # selector's cache the half-kernels are written too
             bs = cfg.block_size
             with jax.named_scope("kv_write"):
                 rows = jnp.arange(ctx_lens.shape[0])
                 blocks = jnp.where(rows < n_real,
                                    block_tables[rows, ctx_lens // bs],
                                    cfg.num_blocks)
-                return write_rows(pool, blocks, ctx_lens % bs, k, v, sel)
+                sel = held.get("sel")
+                out = write_rows(held["kv"], blocks, ctx_lens % bs, k, v,
+                                 sel)
+            if sel is None:
+                return {**held, "kv": out}
+            return {**held, "kv": out[0], "sel": out[1]}
 
         widest = cfg.decode_batch_buckets[-1]
 
@@ -328,45 +353,29 @@ class ModelRunner:
                 ids = jnp.concatenate([ids, pages])
             return (logits, ids), carry
 
-        def decode_step(pool, params, tokens, positions, block_tables,
-                        ctx_lens, n_real, last_ids, src):
+        def decode_step(held, params, tokens, positions, block_tables,
+                        ctx_lens, n_real, last_ids, src, *state_rows):
             # the model reads the pool and attends the new token
-            # explicitly; its K/V is written after the reads
+            # explicitly; its K/V is written after the reads.  Where the
+            # holder has a store the model steps the rows of it that
+            # state_rows names (the operand exists only then) and returns
+            # the store after its K/V.  Where it has a selector's cache the
+            # model reads it and says, last of its results, how many pages
+            # it read; the new K's half-kernels are written with the K
+            reads = {}
+            if "state" in held:
+                reads.update(state=held["state"], rows=state_rows[0])
+            if "sel" in held:
+                reads["selector"] = held["sel"]
             logits, k, v, *ids = forward_decode(
-                params, tokens_in(tokens, last_ids, src), positions, pool,
-                block_tables, ctx_lens, **live_rows(tokens, n_real))
-            pool = new_kv_written(pool, k, v, block_tables, ctx_lens, n_real)
-            return pool, (*chosen_from(logits, ids), k, v, *ids)
-
-        def prefill_state_step(held, params, toks, last_pos):
-            # the state at the prompt's last real position goes to the
-            # store's last row, where scatter_prefill finds it
-            logits, ks, vs, state, *ids = forward_prefill(
-                params, toks, last_pos=last_pos)
-            store = jax.tree.map(lambda s, new: s.at[:, -1].set(new[:, 0]),
-                                 held["state"], state)
-            return {**held, "state": store}, (
-                (logits, greedy(logits)), ks[:, 0], vs[:, 0], *ids)
-
-        def decode_state_step(held, params, tokens, positions, block_tables,
-                              ctx_lens, n_real, last_ids, src, state_rows):
-            # as decode_step, and the model steps the rows of the store
-            # that state_rows names.  A module that chooses its pages reads
-            # the selector's cache and says, last of its results, how many
-            # pages it read; the new K's half-kernels are written with the K
-            sel = held["sel"] if self.select_spec else None
-            reads = {} if sel is None else {"selector": sel}
-            logits, k, v, store, *ids = forward_decode(
                 params, tokens_in(tokens, last_ids, src), positions,
-                held["kv"], block_tables, ctx_lens, state=held["state"],
-                rows=state_rows, **live_rows(tokens, n_real), **reads)
-            pages = None if sel is None else ids.pop()
-            pool = new_kv_written(held["kv"], k, v, block_tables, ctx_lens,
-                                  n_real, sel)
-            if sel is None:
-                held = {"kv": pool, "state": store}
-            else:
-                held = {"kv": pool[0], "state": store, "sel": pool[1]}
+                held["kv"], block_tables, ctx_lens,
+                **live_rows(tokens, n_real), **reads)
+            if "state" in held:
+                store, *ids = ids
+                held = {**held, "state": store}
+            pages = ids.pop() if "sel" in held else None
+            held = new_kv_written(held, k, v, block_tables, ctx_lens, n_real)
             return held, (*chosen_from(logits, ids, pages), k, v, *ids)
 
         def prefill_chunk_step(held, params, staging, toks, start, n_total):
@@ -384,17 +393,15 @@ class ModelRunner:
 
         # bound to a name of its own: jaxlint pins a donating jit by the
         # name it is assigned to (lock_watchdog.DONATED)
-        if self.state_spec is None:
-            llm_decode_step = jax.jit(decode_step, donate_argnums=(0,))
-            self._prefill = jax.jit(prefill_step)
-            self._decode = llm_decode_step
-        else:
-            llm_prefill_state_step = jax.jit(prefill_state_step,
-                                             donate_argnums=(0,))
-            llm_decode_state_step = jax.jit(decode_state_step,
-                                            donate_argnums=(0,))
-            self._prefill = llm_prefill_state_step
-            self._decode = llm_decode_state_step
+        llm_prefill_step = jax.jit(prefill_step, donate_argnums=(0,))
+        llm_decode_step = jax.jit(decode_step, donate_argnums=(0,))
+        self._prefill = llm_prefill_step
+        self._decode = llm_decode_step
+        # the prefill body's two call forms: through the cache's holder,
+        # donated, for a family that stages its state there; no holder for
+        # any other
+        self._prefill_holder = (lambda: self._state_cache().pool) \
+            if self.state_spec else _NoHolder
         self.staging_bytes = 0
         if self.chunk:
             # the staging is donated with the holder and comes back in the
@@ -478,22 +485,18 @@ class ModelRunner:
         # dispatch ends at the ENQUEUE (the jitted call returns before
         # the device finishes); pull ends when the id or the logits are
         # on the host
+        holder = self._prefill_holder()
         with compiling, hot_span("llm.prefill.dispatch", self.span_s), \
                 self._prefill_budget:
-            if self.state_spec is None:
-                picked, ks, vs, *ids = self._prefill(self.params, toks,
-                                                     last_pos)
-            else:
-                picked, ks, vs, *ids = self._state_cache().pool.donate(
-                    self._prefill, self.params, toks, last_pos)
+            picked, ks, vs, *ids = holder.donate(self._prefill, self.params,
+                                                 toks, last_pos)
             if ids:
                 self.choices, = ids
             if compiling is not _SEEN:
-                held = () if self.state_spec is None else (
-                    self._state_cache().pool.abstract(),)
                 register_program(
                     f"llm.prefill.{tb}", self._prefill,
-                    held + abstract((self.params, toks, last_pos)))
+                    (holder.abstract(),
+                     *abstract((self.params, toks, last_pos))))
                 if self.cache is not None:
                     self.cache.warm_scatter(ks, vs)
         out = self._pull("llm.prefill.pull", picked, 1, logit_rows)
@@ -603,7 +606,7 @@ class ModelRunner:
                     [block_tables, np.zeros((pad, block_tables.shape[1]),
                                             np.int32)])
         state_rows = ()
-        if self.state_spec is not None:
+        if kv_pool.state:
             # whose table each is, the cache knows; padded rows name none
             cache = self._state_cache()
             state_rows = (np.concatenate(

@@ -19,6 +19,28 @@ if "xla_force_host_platform_device_count" not in _flags:
         _flags + " --xla_force_host_platform_device_count=8")
 os.environ.setdefault("RTPU_OBJECT_STORE_MEMORY_MB", "256")
 
+# The compile cache of this process and of every worker it spawns: a
+# directory of its own, empty at the start and gone at the end, whatever
+# the environment or ``xla_cache_dir`` (which defaults into the checkout)
+# say.  A run must not read what an earlier run left: on a filled
+# ``.xla_cache/`` tests/test_spmd.py::test_odd_vocab_trains_on_a_tensor_mesh
+# aborted its xdist worker inside XLA's CPU runtime, loading an executable
+# it would otherwise have compiled.  Set before jax or ray_tpu is imported,
+# so that every process of the run has it from its first compile on.
+import atexit  # noqa: E402
+import shutil  # noqa: E402
+import tempfile  # noqa: E402
+
+os.environ["JAX_COMPILATION_CACHE_DIR"] = tempfile.mkdtemp(
+    prefix="rtpu_test_xla_cache_")
+atexit.register(shutil.rmtree, os.environ["JAX_COMPILATION_CACHE_DIR"],
+                ignore_errors=True)
+
+
+def pytest_unconfigure(config):
+    # an xdist worker leaves through os._exit and runs no atexit
+    shutil.rmtree(os.environ["JAX_COMPILATION_CACHE_DIR"], ignore_errors=True)
+
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")

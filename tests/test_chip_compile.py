@@ -312,6 +312,7 @@ def xl_runner(v5e):
     from ray_tpu.models._common import serving_params
     from ray_tpu.serve.llm import EngineConfig
     from ray_tpu.serve.llm.config import resolve_model
+    from ray_tpu.serve.llm.kv_cache import device_shape
     from ray_tpu.serve.llm.model_runner import ModelRunner
     engine = json.loads((Path(__file__).parent.parent / "perfbench" /
                          "configs" / "gpt2-xl-1558m.json").read_text()
@@ -338,7 +339,10 @@ def xl_runner(v5e):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
     weights = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), params)
-    return runner, ecfg, mcfg, weights, on_chip
+    held = {"kv": on_chip(device_shape(
+        ecfg.num_blocks, mcfg.n_layer, ecfg.block_size, mcfg.n_head,
+        mcfg.head_dim), jnp.float32)}
+    return runner, ecfg, held, weights, on_chip
 
 
 def _assert_each_use_of_the_token_table_reads_its_own_leaf_in_place(text):
@@ -372,16 +376,13 @@ def test_serving_cell_decode_program_fits_and_gathers_nothing(
     program cast all 48 layers in every run and held a 3.1 GB bf16 copy:
     10.73e9 bytes), no copy of the token table, and arguments, result
     and temporaries together in 4.64e9 of the chip's 16.9e9 bytes."""
-    from ray_tpu.serve.llm.kv_cache import device_shape
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    runner, ecfg, mcfg, weights, on_chip = xl_runner
+    runner, ecfg, held, weights, on_chip = xl_runner
     bucket, i32 = ecfg.decode_batch_buckets[-1], jnp.int32
-    pool = on_chip(device_shape(ecfg.num_blocks, mcfg.n_layer,
-                                ecfg.block_size, mcfg.n_head, mcfg.head_dim),
-                   jnp.float32)
+    pool = held["kv"]
     assert pool.shape == (48, 2, 128, 16, 1664)
     compiled = runner._decode.lower(
-        pool, weights,
+        held, weights,
         on_chip((bucket,), i32), on_chip((bucket,), i32),
         on_chip((bucket, ecfg.max_blocks_per_seq), i32),
         on_chip((bucket,), i32), on_chip((), i32),
@@ -417,7 +418,7 @@ def test_serving_cell_prefill_program_copies_no_token_table(
     runner, ecfg, _, weights, on_chip = xl_runner
     assert bucket in ecfg.prefill_len_buckets
     compiled = runner._prefill.lower(
-        weights, on_chip((1, bucket), jnp.int32),
+        None, weights, on_chip((1, bucket), jnp.int32),
         on_chip((), jnp.int32)).compile()
     _assert_each_use_of_the_token_table_reads_its_own_leaf_in_place(
         compiled.as_text())
@@ -916,18 +917,12 @@ PARENT_DECODE_STEPS = {
 @pytest.mark.parametrize("cell", sorted(PARENT_DECODE_STEPS))
 def test_older_decode_steps_lower_to_the_operations_and_kernels_they_had(
         cell, request, monkeypatch):
-    from ray_tpu.serve.llm.kv_cache import device_shape
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    runner, ecfg, third, weights, on_chip = request.getfixturevalue(
+    runner, ecfg, held, weights, on_chip = request.getfixturevalue(
         f"{cell}_runner")
-    if cell == "xl":        # no holder: the pool alone, all layers K/V
-        held = on_chip(device_shape(ecfg.num_blocks, third.n_layer,
-                                    ecfg.block_size, third.n_head,
-                                    third.head_dim), jnp.float32)
-    else:
-        held = third
     bucket, i32 = ecfg.decode_batch_buckets[-1], jnp.int32
-    rows = [on_chip((bucket,), i32)] * (2 if cell == "xl" else 3)
+    # last_ids, src, and state_rows where the holder has a store
+    rows = [on_chip((bucket,), i32)] * (2 + ("state" in held))
     lowered = runner._decode.lower(
         held, weights, on_chip((bucket,), i32), on_chip((bucket,), i32),
         on_chip((bucket, ecfg.max_blocks_per_seq), i32),

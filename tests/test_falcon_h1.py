@@ -231,11 +231,14 @@ def test_a_row_is_given_with_the_blocks_and_taken_back_with_them():
         cache.fork_seq("a", "b")
 
 
-def test_a_cache_without_state_holds_the_array_alone():
+def test_a_cache_without_state_holds_the_pool_and_nothing_beside_it():
     cache = PagedKVCache(4, 2, 4, 2, 8)
     assert cache.state_rows == 0 and cache.state_bytes == 0
+    # the one form of what is held: a dict, here of the pool alone
+    held = cache.pool.read(lambda held: held)
+    assert list(held) == ["kv"] == list(cache.pool.abstract())
     # (L, 2, N, bs, F): 2 x 8 = 16 lanes in use of the tile's 128
-    assert cache.pool.read(lambda held: held.shape) == (2, 2, 4, 4, 128) \
+    assert held["kv"].shape == (2, 2, 4, 4, 128) \
         == device_shape(4, 2, 4, 2, 8)
     cache.alloc_seq("a", 3)
     assert cache.state_rows_used() == 0
@@ -441,18 +444,22 @@ def test_bf16_engine_stays_within_the_bf16_tolerance():
 # GPT-2's ``prefill`` and ``decode`` (its head is tied and tiny's 64-wide
 # rows are no whole lanes: the serving tree holds ``wte`` a second time,
 # padded, and ``_embed`` gathers from that; llama names no table and keeps
-# both digests).
+# both digests); PR 46 for ``decode`` and the pool's three writers of both
+# families, in one label and nothing else: the holder always holds a dict,
+# so the result that was ``jax.result_info = "result[0]"`` is
+# ``"result[0]['kv']"`` (CHANGES.md has the diff of each text); ``prefill``
+# is handed no holder and keeps its text.
 PARENT_LOWERINGS = {
     ("gpt2:tiny", "prefill"): "cc6581d8156c0206",
-    ("gpt2:tiny", "decode"): "18ed6f0aa08f601f",
-    ("gpt2:tiny", "scatter"): "cc9d38f81a332a87",
-    ("gpt2:tiny", "write_rows"): "80383ff336f4f9fd",
-    ("gpt2:tiny", "load_block"): "d29caf7c6730e16d",
+    ("gpt2:tiny", "decode"): "228a438727aaa5d5",
+    ("gpt2:tiny", "scatter"): "3480cd9e58132979",
+    ("gpt2:tiny", "write_rows"): "89efab135b94e92d",
+    ("gpt2:tiny", "load_block"): "7ccb454b3c8064fd",
     ("llama:tiny", "prefill"): "b821c7fcd0890e93",
-    ("llama:tiny", "decode"): "324976c6f02f00bf",
-    ("llama:tiny", "scatter"): "e05bda51b7431fe4",
-    ("llama:tiny", "write_rows"): "465b3826c9842b2e",
-    ("llama:tiny", "load_block"): "d933f36507a6630f",
+    ("llama:tiny", "decode"): "66e11f2a99f4cedc",
+    ("llama:tiny", "scatter"): "0525f342bdfe481a",
+    ("llama:tiny", "write_rows"): "9cf7c8c73ff29605",
+    ("llama:tiny", "load_block"): "9a0914875cdb5c7d",
 }
 
 
@@ -470,12 +477,14 @@ def test_stateless_families_lower_byte_for_byte_as_on_the_parent(model):
         return S(shape, jnp.int32)
 
     layers, kv_heads, d = runner.n_layer, runner.n_kv, runner.head_dim
-    pool = S(kvmod.device_shape(64, layers, 8, kv_heads, d), jnp.float32)
+    pool = {"kv": S(kvmod.device_shape(64, layers, 8, kv_heads, d),
+                    jnp.float32)}
     kv = S((layers, 32, kv_heads, d), jnp.float32)
     one = S((layers, 1, kv_heads, d), jnp.float32)
     programs = kvmod._programs()
     lowered = {
-        "prefill": runner._prefill.lower(runner.params, i32(1, 32), i32()),
+        "prefill": runner._prefill.lower(None, runner.params, i32(1, 32),
+                                         i32()),
         "decode": runner._decode.lower(pool, runner.params, i32(4), i32(4),
                                        i32(4, 8), i32(4), i32(), i32(4),
                                        i32(4)),

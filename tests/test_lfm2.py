@@ -609,10 +609,19 @@ def test_bf16_engine_stays_within_the_bf16_tolerance():
 # training digests are the steps' since PR 45 did (``ops/moe.
 # dropless_experts``: the router's weight multiplies the hidden rows and the
 # combine is the dispatch transposed; one grouped matmul fewer a layer).
+# MiniCPM-SALA's three were lowered at the parent of PR 46 (commit ac47f06),
+# before that PR merged the runner's step bodies under them.  It renamed
+# three modules and changed nothing else of their texts (CHANGES.md has the
+# diffs): ``@jit_prefill_state_step`` -> ``@jit_prefill_step`` (Falcon-H1's
+# prefill), ``@jit_decode_state_step`` -> ``@jit_decode_step`` (Falcon-H1's
+# and MiniCPM-SALA's decode).
 PARENT_LOWERINGS = {
-    "falcon_h1 prefill": "3842cac6da8389af",
-    "falcon_h1 decode": "671116cd471eec2f",
+    "falcon_h1 prefill": "f6d472b498d90e52",
+    "falcon_h1 decode": "29ec77e102f27e49",
     "falcon_h1 scatter": "a42f2945b7eeeff1",
+    "minicpm_sala chunk": "050e0ff1c618ee13",
+    "minicpm_sala decode": "95b933bcb41266bb",
+    "minicpm_sala scatter": "1b0195ec5bd06f38",
     "olmoe train": "98084bcc33e6cbb4",
     "kanana train": "80ca33e22ad26c4d",
 }
@@ -623,7 +632,17 @@ def _digest(lowered):
     return hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]
 
 
-def _falcon_lowering(program):
+# the engine's sizes where tiny's defaults do not serve: MiniCPM-SALA's
+# prompts run in chunks of 32 and its buckets are lengths in whole chunks
+_SERVED = {"falcon_h1": {},
+           "minicpm_sala": dict(max_model_len=128, max_prefill_tokens=128,
+                                prefill_len_buckets=(32, 64, 128),
+                                decode_batch_buckets=(4,))}
+
+
+def _serving_lowering(family, program):
+    """A family's program over its holder as shapes: the pool, the store
+    and, for the family that chooses its pages, the selector's cache."""
     from ray_tpu.serve.llm import kv_cache as kvmod
     from ray_tpu.serve.llm.model_runner import ModelRunner
     S = jax.ShapeDtypeStruct
@@ -631,17 +650,24 @@ def _falcon_lowering(program):
     def i32(*shape):
         return S(shape, jnp.int32)
 
-    r = ModelRunner(engine_cfg(model="falcon_h1:tiny"))
-    held = {"kv": S(kvmod.device_shape(64, r.n_layer, 8, r.n_kv, r.head_dim),
-                    jnp.float32),
-            "state": {n: S((r.n_layer, 5) + s.shape, s.dtype)
+    r = ModelRunner(engine_cfg(model=f"{family}:tiny", **_SERVED[family]))
+    pool = kvmod.device_shape(64, r.kv_layers, 8, r.n_kv, r.head_dim)
+    held = {"kv": S(pool, jnp.float32),
+            "state": {n: S((r.state_layers, 5) + s.shape, s.dtype)
                       for n, s in r.state_spec.items()}}
+    if r.select_spec:
+        held["sel"] = S(kvmod.selector_shape(pool, r.select_spec["stride"]),
+                        jnp.float32)
     if program == "prefill":
         return r._prefill.lower(held, r.params, i32(1, 32), i32())
+    if program == "chunk":
+        return r._prefill_chunk.lower(held, r.params, r.staging_spec,
+                                      i32(1, r.chunk), i32(), i32())
     if program == "decode":
-        return r._decode.lower(held, r.params, i32(4), i32(4), i32(4, 8),
-                               i32(4), i32(), i32(4), i32(4), i32(4))
-    kv = S((r.n_layer, 32, r.n_kv, r.head_dim), jnp.float32)
+        return r._decode.lower(
+            held, r.params, i32(4), i32(4), i32(4, r.cfg.max_blocks_per_seq),
+            i32(4), i32(), i32(4), i32(4), i32(4))
+    kv = S((r.kv_layers, 32, r.n_kv, r.head_dim), jnp.float32)
     return kvmod._programs().scatter_prefill.lower(held, i32(4), kv, kv,
                                                    i32(), i32())
 
@@ -658,8 +684,8 @@ def _train_lowering(mod, cfg):
 @pytest.mark.parametrize("name", sorted(PARENT_LOWERINGS))
 def test_the_shared_code_lowers_byte_for_byte_as_on_the_parent(name):
     family_name, program = name.split()
-    if family_name == "falcon_h1":
-        lowered = _falcon_lowering(program)
+    if family_name in _SERVED:
+        lowered = _serving_lowering(family_name, program)
     elif family_name == "olmoe":
         from ray_tpu.models import llama
         lowered = _train_lowering(llama, llama.tiny_moe())

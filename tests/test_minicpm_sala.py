@@ -386,9 +386,10 @@ def test_the_engine_refuses_a_page_that_is_not_the_selections():
         _engine(prefill_len_buckets=(48, 128))
 
 
-def test_a_selecting_module_without_state_rows_is_refused(monkeypatch):
-    # the selector's cache is read and written by the step of a module with
-    # state rows: one without would decode through a step with no selector
+def test_a_chunking_module_without_state_rows_is_refused(monkeypatch):
+    # a chunk hands the next its recurrent state in the store's staging row:
+    # a module without a store has no chunk program (the decode step reads a
+    # selector's cache with or without one)
     monkeypatch.delattr(sala, "recurrent_state")
-    with pytest.raises(NotImplementedError, match="chooses its pages"):
+    with pytest.raises(NotImplementedError, match="prefills in chunks"):
         _engine()

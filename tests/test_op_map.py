@@ -58,8 +58,15 @@ def _having(ops, component, **keys):
 # ------------------------------------------------------------ the train step
 @pytest.fixture(scope="module")
 def gpt2_map():
+    # a module's fixture is set up before conftest's per-test one reads the
+    # flag it puts back: left off here, it stayed off for every later file
+    # of the worker (tests/test_named_scopes.py then read other locations)
+    full = jax.config.jax_include_full_tracebacks_in_locations
     jax.config.update("jax_include_full_tracebacks_in_locations", False)
-    return _step_map(gpt2, gpt2.tiny(), 64)
+    try:
+        return _step_map(gpt2, gpt2.tiny(), 64)
+    finally:
+        jax.config.update("jax_include_full_tracebacks_in_locations", full)
 
 
 @pytest.mark.parametrize("scope", ["mlp", "attn", "ln_1", "ln_2", "attn_qkv",

@@ -520,14 +520,15 @@ def test_pool_programs_alias_the_pool_to_their_result():
     pool = S(kvmod.device_shape(cfg.num_blocks, runner.n_layer,
                                 cfg.block_size, runner.n_kv,
                                 runner.head_dim), jnp.float32)
+    held = {"kv": pool}
     i32 = lambda *shape: S(shape, jnp.int32)      # noqa: E731
     kv = S((runner.n_layer, 32, runner.n_kv, runner.head_dim), jnp.float32)
     lowered = {
         "decode": runner._decode.lower(
-            pool, runner.params, i32(4), i32(4),
+            held, runner.params, i32(4), i32(4),
             i32(4, cfg.max_blocks_per_seq), i32(4), i32(), i32(4), i32(4)),
         "scatter": kvmod._programs().scatter_prefill.lower(
-            pool, i32(4), kv, kv, i32()),
+            held, i32(4), kv, kv, i32()),
     }
     for name, low in lowered.items():
         assert "tf.aliasing_output = 0" in \
@@ -751,9 +752,10 @@ def test_step_programs_convert_no_weight(model):
     for tree, n_cast in ((runner.params, 0),
                          (stored, len(flat) - len(added) - wide)):
         decode = jax.make_jaxpr(runner._decode)(
-            pool, tree, i32(4), i32(4), i32(4, cfg.max_blocks_per_seq),
-            i32(4), i32(), i32(4), i32(4))
-        prefill = jax.make_jaxpr(runner._prefill)(tree, i32(1, 32), i32())
+            {"kv": pool}, tree, i32(4), i32(4),
+            i32(4, cfg.max_blocks_per_seq), i32(4), i32(), i32(4), i32(4))
+        prefill = jax.make_jaxpr(runner._prefill)(None, tree, i32(1, 32),
+                                                  i32())
         for jaxpr in (decode, prefill):
             got = _weight_converts(jaxpr.jaxpr, shapes)
             assert (len(got) >= n_cast) if n_cast else not got, got

@@ -2,6 +2,7 @@
 (SURVEY.md §2.3 state API, §5.1 tracing, §5.5 metrics, §4 microbenchmark)."""
 
 import json
+import os
 import subprocess
 import sys
 import time
@@ -217,10 +218,11 @@ def test_timeline_chrome_trace(ray_start_regular, tmp_path):
 
 # ---------------------------------------------------------------------- CLI
 
-def _cli(*argv, timeout=240):
+def _cli(*argv, timeout=240, **env):
     return subprocess.run(
         [sys.executable, "-m", "ray_tpu.scripts.cli", *argv],
-        capture_output=True, text=True, timeout=timeout, cwd="/root/repo")
+        capture_output=True, text=True, timeout=timeout, cwd="/root/repo",
+        env={**os.environ, **env})
 
 
 def test_cli_version():
@@ -237,15 +239,37 @@ def test_cli_microbenchmark_quick():
 
 
 def test_cli_start_status_stop():
-    r = _cli("start")
-    assert r.returncode == 0, r.stderr[-2000:]
+    """``start`` waits for the head it forked by reading ``session_latest``,
+    and ``status --address auto`` and ``stop`` resolve the same link: under
+    the sessions root every test shares, the latest session is whichever
+    test of the six xdist workers started a cluster last (``start`` then
+    never sees its own pid, ``status`` dials a cluster that is shutting
+    down, ``stop`` signals somebody else's head).  The three calls get a
+    root of their own, and the status line is waited for until the head
+    answers, not asked for once."""
+    import shutil
+    import tempfile
+
+    from conftest import time_scale
+
+    # not tmp_path: a session's socket paths have 108 characters at most
+    sessions = tempfile.mkdtemp(prefix="rtpu_cli_")
+    root = {"RTPU_SESSION_DIR_ROOT": sessions}
+    r = _cli("start", **root)
     try:
-        r2 = _cli("status", "--address", "auto")
+        assert r.returncode == 0, r.stderr[-2000:]
+        deadline = time.monotonic() + 60 * time_scale()
+        while True:
+            r2 = _cli("status", "--address", "auto", **root)
+            if r2.returncode == 0 or time.monotonic() > deadline:
+                break
+            time.sleep(0.5)
         assert r2.returncode == 0, r2.stderr[-2000:]
         summary = json.loads(r2.stdout[r2.stdout.index("{"):])
         assert summary["nodes"] >= 1
     finally:
-        r3 = _cli("stop")
+        r3 = _cli("stop", **root)
+        shutil.rmtree(sessions, ignore_errors=True)
         assert r3.returncode == 0, r3.stderr[-2000:]
 
 

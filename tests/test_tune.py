@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 import ray_tpu
+from conftest import time_scale
 from ray_tpu import tune
 from ray_tpu.train import RunConfig
 from ray_tpu.tune import (ASHAScheduler, PopulationBasedTraining, Trainable,
@@ -163,21 +164,42 @@ def test_class_trainable_with_checkpointing(ray_start_regular, tmp_path):
     assert r.checkpoint is not None
 
 
-def test_pbt_clones_from_better_trial(ray_start_regular, tmp_path):
-    # two trials: "slow" (rate 1) and "fast" (rate 10); PBT should stop the
-    # slow one at the perturbation interval and clone from the fast one
+def _climber(tmp_path):
+    """A trial that adds its rate to its score 12 times, from its
+    checkpoint on.  Of a population of two the slow one (rate 1) is
+    launched first, and a perturbation with nobody to clone from passes: on
+    a loaded host it ran its 12 steps before the fast one's worker was up,
+    and no clone happened.  So it waits for what a clone needs, the fast
+    trial's first report (``report`` returns once the controller has the
+    score and the checkpoint), with a deadline of its own."""
+    reported = tmp_path / "fast_trial_reported"
+    patience = 120 * time_scale()
+
     def f(config):
+        import time
         start = 0
         ck = tune.get_checkpoint()
         if ck is not None:
             start = ck.to_dict()["score"]
+        deadline = time.monotonic() + patience
+        while config["rate"] == 1 and not reported.exists() \
+                and time.monotonic() < deadline:
+            time.sleep(0.02)
         score = start
         for i in range(12):
-            score += config["rate"]
+            score += config["rate"]          # higher rate = better trial
             tune.report({"score": score},
                         checkpoint=tune.Checkpoint.from_dict(
                             {"score": score}))
+            if config["rate"] != 1:
+                reported.touch()
+    return f
 
+
+def test_pbt_clones_from_better_trial(ray_start_regular, tmp_path):
+    # two trials: "slow" (rate 1) and "fast" (rate 10); PBT should stop the
+    # slow one at the perturbation interval and clone from the fast one
+    f = _climber(tmp_path)
     results = Tuner(
         f, param_space={"rate": tune.grid_search([1, 10])},
         tune_config=TuneConfig(
@@ -236,18 +258,7 @@ def test_pb2_bandit_explore_clones_and_improves(ray_start_regular, tmp_path):
     is populated and the population improves over its worst member."""
     from ray_tpu.tune import PB2
 
-    def f(config):
-        start = 0.0
-        ck = tune.get_checkpoint()
-        if ck is not None:
-            start = ck.to_dict()["score"]
-        score = start
-        for i in range(12):
-            score += config["rate"]          # higher rate = better trial
-            tune.report({"score": score},
-                        checkpoint=tune.Checkpoint.from_dict(
-                            {"score": score}))
-
+    f = _climber(tmp_path)
     sched = PB2(perturbation_interval=3,
                 hyperparam_bounds={"rate": [0.5, 10.0]}, seed=0)
     results = Tuner(
