@@ -56,6 +56,25 @@ def _kernel_results(text):
                       r'custom_call_target="tpu_custom_call"', text)
 
 
+def _assert_the_sorted_passes_are_only_the_layers_work(text, rows, slots):
+    """What ``ops/moe.dropless_experts`` spares the compiler by telling it
+    what the sort makes true (PR 49), read off a compiled module: the
+    gathers promise their indices, so no ``select`` fills a row of the
+    ``rows`` sorted (the shape as HLO prints it) under the dispatch's or
+    the combine's scope; the k slots lead the rows gathered back, so no
+    (N, k, d) array ``slots`` exists, which in a tiled layout is a copy of
+    all N k rows; the counts are a compare and a sum and the weights'
+    gradient a sort, so the dispatch holds no scatter."""
+    assert slots not in text
+    under = [line for line in text.splitlines()
+             if "moe_dispatch" in line or "moe_combine" in line]
+    assert len(under) > 20, len(under)
+    assert not [line for line in under
+                if re.search(rf"= {re.escape(rows)}\S* select\(", line)]
+    assert not [line for line in under
+                if "scatter" in line and "moe_dispatch" in line]
+
+
 def _flash(q, k, v):
     return flash_attention(q, k, v, True, None, False)
 
@@ -177,8 +196,9 @@ def test_dropless_experts_at_olmoe_train_shape(v5e, monkeypatch, fn,
     groups through 2048 -> 1024 twice and 1024 -> 2048; every grouped
     matmul, forward and both backward products, is a Mosaic kernel
     (megablox at ``GMM_TILING``) whose result has one of the shapes
-    ``moe.expert_matmul_ms`` is keyed on, and no (N, E, C) dispatch tensor
-    is built."""
+    ``moe.expert_matmul_ms`` is keyed on, no (N, E, C) dispatch tensor
+    is built, and the passes over the sorted rows are the gathers and the
+    sums alone."""
     import json
     from pathlib import Path
     # the code under compile asks for the backend and must take the
@@ -197,6 +217,8 @@ def test_dropless_experts_at_olmoe_train_shape(v5e, monkeypatch, fn,
     assert set(kernels) <= set(keyed), kernels
     assert "ragged-dot" not in text
     assert not re.search(r"\[8192,64,\d+\]", text)
+    _assert_the_sorted_passes_are_only_the_layers_work(
+        text, "bf16[65536,2048]", "[8192,8,2048]")
 
 
 # ----------------------------------------------- the serving cell's decode
@@ -562,8 +584,9 @@ def test_kanana_step_compiles_with_its_kernels_and_fits(v5e, monkeypatch):
     (11 in the sparse scan: the forward's three, gate and up recomputed,
     six of the backward; no down projection is recomputed since the
     combine's gradient reads no output row), each with a result shape its
-    metric is keyed on, no float32 copy of the sorted rows, no
-    ``ragged-dot`` fallback, and everything inside the chip."""
+    metric is keyed on, no float32 copy of the sorted rows, no select,
+    re-tiled copy or scatter among the sorted passes, no ``ragged-dot``
+    fallback, and everything inside the chip."""
     import json
     from pathlib import Path
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -588,6 +611,8 @@ def test_kanana_step_compiles_with_its_kernels_and_fits(v5e, monkeypatch):
     # the combine makes no float32 copy of the sorted rows, forward or back
     assert "f32[16384,6,2048]" not in text
     assert not re.search(r"= f32\[98304,2048\].*moe_combine", text)
+    _assert_the_sorted_passes_are_only_the_layers_work(
+        text, "bf16[98304,2048]", "[16384,6,2048]")
     mem = compiled.memory_analysis()
     held = mem.argument_size_in_bytes + mem.output_size_in_bytes \
         - mem.alias_size_in_bytes + mem.temp_size_in_bytes
@@ -640,9 +665,13 @@ def _operations_and_kernels(lowered_text):
 # router's weight multiplies the hidden rows in float32 and the combine is
 # the dispatch transposed, so the checkpointed layer's backward calls no
 # down projection a second time: 4,039 operations and 9 kernel calls where
-# there were 4,092 and 10).
+# there were 4,092 and 10); PR 49 did it again (the one sort carries the
+# weights and is sorted back for the inverse and for the weights' gradient,
+# the gathers promise their indices, the k slots lead the rows gathered
+# back, the counts are a compare and a sum: no ``select``, ``clamp`` or
+# ``scatter`` of ``jnp.take`` and ``bincount``, 3,905 operations).
 PARENT_STEPS = {
-    "olmoe-1b-7b.train-b2-s4096": ("fcf2f1d1eab312c5", 4039, 9),
+    "olmoe-1b-7b.train-b2-s4096": ("440bf1d631cb4a95", 3905, 9),
     "gpt2-xl-1558m.train-b8-s1024": ("87069c55eb334fc3", 1851, 2),
 }
 
@@ -656,6 +685,26 @@ def test_older_training_steps_lower_to_the_operations_and_kernels_they_had(
         prog.jitted_step.lower(state, batch).as_text())
     assert (digest, sum(ops.values()), sum(kernels.values())) == \
         PARENT_STEPS[cell]
+
+
+def test_olmoe_step_holds_no_more_temporaries_than_its_parent(v5e,
+                                                              monkeypatch):
+    """The whole step of ``olmoe-1b-7b.train-b2-s4096`` stands at 95.6% of
+    the chip (8.79e9 B of state, donated, and 7.36e9 of temporaries), so a
+    change to the expert layer may add no array to it: the temporaries are
+    no more than the 7,358,946,304 B of PR 49's parent (5278554, this jax),
+    whose ``jnp.take`` filled, re-tiled ``bf16[8192,8,2048]`` and counted
+    by scatter-add; and the sorted passes of the whole step are the
+    layer's work alone."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    prog, state, batch = _train_program("olmoe-1b-7b.train-b2-s4096", v5e)
+    compiled = prog.jitted_step.lower(state, batch).compile()
+    _assert_the_sorted_passes_are_only_the_layers_work(
+        compiled.as_text(), "bf16[65536,2048]", "[8192,8,2048]")
+    held, mem = _held_bytes(compiled)
+    assert mem.alias_size_in_bytes >= 8.78e9        # the state, donated
+    assert mem.temp_size_in_bytes <= 7_358_946_304, mem.temp_size_in_bytes
+    assert held < 16.9e9, held
 
 
 # --------------------------------------------- the LFM2-MoE cell's programs
@@ -906,11 +955,14 @@ def test_minicpm_sala_chunk_program_is_one_and_keeps_the_pool_out(
 # operation for operation.  ``lfm2`` is the step's since PR 45 changed
 # ``ops/moe.dropless_experts`` on purpose (the weights sorted with the rows
 # and multiplied into the hidden rows in float32, no ``nkd,nk->nd`` product:
-# 1,367 operations where there were 1,314).
+# 1,367 operations where there were 1,314), and since PR 49 did again (one
+# sort of (expert id, iota, weight) and one of the order back, gathers that
+# promise their indices, the assignments numbered slot by slot, the counts
+# a compare and a sum: 1,372 operations, none of them a scatter-add).
 PARENT_DECODE_STEPS = {
     "xl": ("b5e4a17574d47c3c", 413, 1),
     "falcon_h1": ("6dc9e09f46d43408", 673, 1),
-    "lfm2": ("c56be286eeb06544", 1367, 1),
+    "lfm2": ("92e664a01969a09a", 1372, 1),
 }
 
 

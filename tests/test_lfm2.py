@@ -608,7 +608,10 @@ def test_bf16_engine_stays_within_the_bf16_tolerance():
 # hands over its parts unjoined, W_q and W_kv_b by column group), and both
 # training digests are the steps' since PR 45 did (``ops/moe.
 # dropless_experts``: the router's weight multiplies the hidden rows and the
-# combine is the dispatch transposed; one grouped matmul fewer a layer).
+# combine is the dispatch transposed; one grouped matmul fewer a layer) and
+# since PR 49 did again (the same function: one sort carries the weights
+# and gives the order, its inverse and the weights' gradient; the gathers
+# promise their indices; the k slots lead; the counts are a compare).
 # MiniCPM-SALA's three were lowered at the parent of PR 46 (commit ac47f06),
 # before that PR merged the runner's step bodies under them.  It renamed
 # three modules and changed nothing else of their texts (CHANGES.md has the
@@ -622,8 +625,8 @@ PARENT_LOWERINGS = {
     "minicpm_sala chunk": "050e0ff1c618ee13",
     "minicpm_sala decode": "95b933bcb41266bb",
     "minicpm_sala scatter": "1b0195ec5bd06f38",
-    "olmoe train": "98084bcc33e6cbb4",
-    "kanana train": "80ca33e22ad26c4d",
+    "olmoe train": "4289a77c8300a44d",
+    "kanana train": "22560f398dcec728",
 }
 
 

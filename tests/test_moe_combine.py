@@ -1,7 +1,10 @@
-"""The dropless layer's way back from the experts (``ops/moe.py``): the
+"""The dropless layer's way to the experts and back (``ops/moe.py``): the
 router's weight multiplies the hidden rows, and the combine is the dispatch
 transposed, so its gradient reads no output row and a checkpointed layer's
-backward runs no down projection a second time."""
+backward runs no down projection a second time.  One sort makes the
+permutation, its inverse, the sorted weights and (beside it) the counts:
+the indices are permutations whatever the routing, which is what the
+layer's gathers promise."""
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +64,8 @@ CASES = [
     pytest.param(6, 3, 0, id="k6-held-3-of-8"),
     pytest.param(2, 3, 5, id="k2-held-3-of-8-from-5"),
     pytest.param(6, 2, 7, id="k6-held-2-of-8-from-7-wrapping"),
+    pytest.param(8, E, 0, id="k8-all-held"),
+    pytest.param(8, 3, 5, id="k8-held-3-of-8-from-5"),
 ]
 
 
@@ -79,22 +84,32 @@ def test_the_combine_equals_the_weighted_sum_it_replaced(k, held, first_held):
                                rtol=1e-5, atol=1e-5)
 
 
+def _value_and_grads(fn, x, expert_idx, *rest, seed=7):
+    """``fn``'s output and the gradients, for x, the weights and the three
+    expert matrices, of its product with one drawn cotangent."""
+    cotangent = jax.random.normal(jax.random.key(seed), (N, D), jnp.float32)
+
+    def loss(x, weights, w_gate, w_up, w_down):
+        y = fn(x, expert_idx, weights, w_gate, w_up, w_down)
+        return (y * cotangent).sum(), y
+
+    (_, y), grads = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
+                                       has_aux=True)(x, *rest)
+    return y, grads
+
+
+def _both_ways(first_held, *args):
+    """(y, gradients) of the layer and of the dense reference."""
+    return (_value_and_grads(lambda *a: moe.dropless_experts(
+                *a, num_experts=E, first_held=first_held)[0], *args),
+            _value_and_grads(lambda *a: _dense(*a, first_held=first_held),
+                             *args))
+
+
 @pytest.mark.parametrize("k,held,first_held", CASES)
 def test_the_gradients_equal_a_dense_float32_reference(k, held, first_held):
     x, expert_idx, *rest = _draw(k, held, seed=1)
-    cotangent = jax.random.normal(jax.random.key(7), (N, D), jnp.float32)
-
-    def loss(fn, x, weights, w_gate, w_up, w_down):
-        return (fn(x, expert_idx, weights, w_gate, w_up, w_down)
-                * cotangent).sum()
-
-    got = jax.grad(lambda *a: loss(
-        lambda *b: moe.dropless_experts(*b, num_experts=E,
-                                        first_held=first_held)[0], *a),
-        argnums=(0, 1, 2, 3, 4))(x, *rest)
-    want = jax.grad(lambda *a: loss(
-        lambda *b: _dense(*b, first_held=first_held), *a),
-        argnums=(0, 1, 2, 3, 4))(x, *rest)
+    (_, got), (_, want) = _both_ways(first_held, x, expert_idx, *rest)
     for name, g, w in zip(("x", "weights", "w_gate", "w_up", "w_down"),
                           got, want):
         assert np.abs(np.asarray(w)).max() > 0, name
@@ -104,6 +119,70 @@ def test_the_gradients_equal_a_dense_float32_reference(k, held, first_held):
         absent = (expert_idx - first_held) % E >= held
         assert absent.any()
         assert np.abs(np.asarray(got[1])[np.asarray(absent)]).max() == 0
+
+
+def _every_token_to_one_expert(expert_idx):
+    return jnp.full_like(expert_idx, 6)
+
+
+def _an_expert_nobody_chose(expert_idx):
+    return jnp.where(expert_idx == 5, 7, expert_idx)
+
+
+def _padded_rows(expert_idx):
+    """A decode step's bucket: rows 3 .. 28 are some sequence's, the others
+    take the first live row's choice (``models/lfm2.py``)."""
+    row = jnp.arange(N)
+    return moe.choice_of_live_rows(expert_idx, (row >= 3) & (row < 29))
+
+
+SKEWED = [
+    pytest.param(route, k, held, first_held,
+                 id=f"{route.__name__.strip('_')}-k{k}-held-{held}-from-"
+                    f"{first_held}")
+    for route in (_every_token_to_one_expert, _an_expert_nobody_chose,
+                  _padded_rows)
+    for k, held, first_held in ((6, E, 0), (8, E, 0), (6, 3, 5))
+]
+
+
+@pytest.mark.parametrize("route,k,held,first_held", SKEWED)
+def test_the_sort_gives_permutations_whatever_the_routing(route, k, held,
+                                                          first_held):
+    """What the gathers promise: ``order`` and ``inverse`` are permutations
+    of the N k assignments and each other's inverse, a row's token
+    ``order % N`` is a token, the rows lie by expert (as numbered inside
+    one), the weights came along, and the counts are ``bincount``'s."""
+    _, expert_idx, weights, *_ = _draw(k, held)
+    expert_idx = route(expert_idx)
+    order, inverse, w_sorted, sizes = map(np.asarray, moe._sorted_assignments(
+        expert_idx, weights, E, first_held))
+    every = np.arange(N * k)
+    np.testing.assert_array_equal(np.sort(order), every)
+    np.testing.assert_array_equal(np.sort(inverse), every)
+    np.testing.assert_array_equal(order[inverse], every)
+    assert (order % N).min() >= 0 and (order % N).max() < N
+    local = np.asarray((expert_idx - first_held) % E).T.reshape(-1)
+    by_expert = local[order]
+    assert (np.diff(by_expert) >= 0).all()
+    assert (np.diff(order)[np.diff(by_expert) == 0] > 0).all()   # stable
+    np.testing.assert_array_equal(
+        w_sorted, np.asarray(weights).T.reshape(-1)[order])
+    assert sizes.dtype == np.int32
+    np.testing.assert_array_equal(sizes, jnp.bincount(local, length=E))
+
+
+@pytest.mark.parametrize("route,k,held,first_held", SKEWED)
+def test_skewed_routing_equals_the_dense_reference_forward_and_back(
+        route, k, held, first_held):
+    x, expert_idx, *rest = _draw(k, held, seed=2)
+    (y, got), (y_dense, want) = _both_ways(first_held, x, route(expert_idx),
+                                           *rest)
+    np.testing.assert_allclose(y, y_dense, rtol=1e-4, atol=1e-4)
+    for name, g, w in zip(("x", "weights", "w_gate", "w_up", "w_down"),
+                          got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4, err_msg=name)
+    assert np.abs(np.asarray(want[0])).max() > 0
 
 
 def _grouped_products(jaxpr):

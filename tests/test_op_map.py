@@ -212,12 +212,14 @@ def test_a_new_program_of_a_name_replaces_the_old():
 # ``as_text()`` does not print.  The serving programs' digests are pinned
 # in tests/test_falcon_h1.py.  ``llama:tiny-moe`` is the step's since PR 45
 # changed it on purpose (ops/moe.dropless_experts: the router's weight on
-# the hidden rows, the combine the dispatch transposed).
+# the hidden rows, the combine the dispatch transposed) and PR 49 did again
+# (the same function: everything read off one sort, gathers that promise
+# their indices, the k slots leading, the counts a compare and a sum).
 PARENT_TRAIN_STEPS = {
     "gpt2:tiny": (gpt2, gpt2.tiny, 64, "f76d5da583f18826"),
     "llama:tiny": (llama, llama.PRESETS["tiny"], 32, "4d2cd3d1fa54a1c8"),
     "llama:tiny-moe": (llama, llama.PRESETS["tiny-moe"], 32,
-                       "003132efed0ec936"),
+                       "484dd4b681c5563a"),
 }
 
 
