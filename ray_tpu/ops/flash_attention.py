@@ -8,7 +8,8 @@ expressed the Pallas way (grid + BlockSpecs; see
 /opt/skills/guides/pallas_guide.md).
 
 Design notes (r3 device-trace driven; today's kernel times per step are
-the ``kernels.flash_fwd_ms`` / ``kernels.flash_bwd_ms`` metrics, PERF.md §5):
+the ``kernels.custom_call_ms`` and ``mla.attention_ms`` metrics, and by
+the tile ``benchmarks/attention_bench.py``'s, PERF.md §5):
 - Probabilities use ``exp2`` with the 1/sqrt(D) scale and log2(e) folded
   into the score matmul's epilogue multiply — the VPU transcendental is
   the kernel's throughput bound, so no extra multiplies ride with it.
@@ -17,9 +18,12 @@ the ``kernels.flash_fwd_ms`` / ``kernels.flash_bwd_ms`` metrics, PERF.md §5):
 - The row-statistics residual (logsumexp) is stored COMPACT as (B·H, T)
   f32 — the r2 kernel lane-replicated it to (B·H, T, 128), which cost
   128× the HBM (200MB/layer at the flagship shape) and made saving it
-  across a remat boundary pointless.  The (1, block) lane-vector ↔
-  (block, 1) sublane-vector relayout this needs is a few hundred elements
-  per tile — noise next to the exp chain.
+  across a remat boundary pointless.  The forward turns its (block,)
+  row statistics into that lane vector once a grid step; the backward
+  computes its tile keys-down, so that lse and delta are read as the
+  (1, block) lane vectors they are stored as and broadcast down the
+  sublanes: turning them into (block, 1) sublane vectors for a
+  queries-down tile cost 0.08-0.28 us of every 512 x 512 tile (PR 50).
 - The backward is ONE kernel, gridded over (batch·head, k-block): k/v
   tiles stay resident while an inner loop walks q-blocks ≥ the diagonal;
   each (q,k) tile computes probabilities ONCE (the r2 two-kernel design
@@ -60,7 +64,8 @@ LOG2E = math.log2(math.e)
 
 def _scores(qs, ks):
     """Σ over the parts of q_part · k_partᵀ, float32: one product for
-    whole queries and keys, two for latent attention's (nope, rope)."""
+    whole queries and keys, two for latent attention's (nope, rope).  The
+    backward hands the keys first and gets its tile keys-down, k · qᵀ."""
     s = None
     for q, k in zip(qs, ks):
         part = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -131,9 +136,16 @@ def _bwd_kernel(*refs, parts: int, block_q: int, block_k: int, seq_len: int,
     refs: q's ``parts`` column groups, k's, v, do, lse, delta; then dq's
     parts, dk's, dv; then a float32 dq scratch a part.
 
-    Each (q, k) tile: recompute s and p (one exp2 chain), then
-      dv += pᵀ·do        dp = do·vᵀ        ds = p*(dp-delta)
-      dk += dsᵀ·q        dq[i] += ds·(k·scale)
+    Each (q, k) tile is computed KEYS-DOWN, (block_k, block_q), the
+    orientation dv and dk accumulate in (PR 50): recompute sᵀ = k·qᵀ and
+    pᵀ (one exp2 chain; lse and delta are the (1, block_q) lane vectors
+    they are stored as, broadcast down the sublanes), then
+      dv += pᵀ·do        dpᵀ = v·doᵀ       dsᵀ = pᵀ*(dpᵀ-delta)
+      dk += dsᵀ·q        dq[i] += (dsᵀ)ᵀ·(k·scale)
+    Seven of a tile's products are plain or contract their operands' last
+    dimensions, the MXU's native forms; dq's, one a part and all on the
+    one dsᵀ, contract dimension 0 of their left operand: the tile's only
+    transposed (block_k, block_q) array.
     dq accumulates in an f32 VMEM scratch across the k grid axis and is
     flushed (bf16) once per (B·H) row at the last k-step.
 
@@ -145,9 +157,23 @@ def _bwd_kernel(*refs, parts: int, block_q: int, block_k: int, seq_len: int,
     - The 1/sqrt(D) factor on ds cost a full (block_q, block_k) VPU
       multiply per tile; it now rides the O(block·D) operands instead:
       pre-scaled k for the dq dot, post-loop scale on the dk accumulator.
-    - The kernel's floor is MXU shape-efficiency, not the exp2 chain:
-      all five dots have a 64-wide contracting or output dimension
-      (D=64) against the 128-deep systolic array.
+    - What the tile's time is made of (PR 50: the kernel alone on a v5e
+      at the three training cells' shapes with one piece cut out at a
+      time, ``benchmarks/attention_bench.py``; PERF.md §6).  Beside the
+      MXU's 1.70-2.73 us for a 512 x 512 tile's five products (a 64-wide
+      dimension counted as the 128 it occupies) a queries-down tile took
+      0.7-0.9 us more at EVERY head width, so the floor is not the MXU's
+      shape efficiency at D = 64.  Of that, turning lse and delta from
+      lane to sublane vectors was 0.26-0.28 us at one part and 0.08 in
+      Kanana, the two transposed tiles 0.13 us in Kanana and nothing at
+      one part (the XLU hides them), the ``s_scale`` pass nothing; the
+      keys-down tile has none of the three.  The exp2 chain is free too
+      (VPU and EUP run under the MXU): the five products ALONE take what
+      this tile takes, 0.42-0.50 us over the MXU's time, which is the
+      products as Mosaic issues them.  Walking the tile in 2 or 4 query
+      chunks to overlap them is slower at every shape, and accumulating
+      dq transposed, (D, T), does not compile (libtpu's MXU transform
+      refuses the small transposed operand).
     """
     q_refs, k_refs = refs[:parts], refs[parts:2 * parts]
     v_ref, do_ref, lse_ref, delta_ref = refs[2 * parts:2 * parts + 4]
@@ -170,36 +196,34 @@ def _bwd_kernel(*refs, parts: int, block_q: int, block_k: int, seq_len: int,
 
     def tile(i, carry, masked):
         dks, dv = carry
-        qs = [q_ref[0, pl.ds(i * block_q, block_q), :] for q_ref in q_refs]
-        do = do_ref[0, pl.ds(i * block_q, block_q), :]
-        lse_lanes = lse_ref[0, 0, pl.ds(i * block_q, block_q)]  # lanes
-        lse_rows = jnp.transpose(lse_lanes[None, :])         # (block_q, 1)
-        d_lanes = delta_ref[0, 0, pl.ds(i * block_q, block_q)]
-        delta = jnp.transpose(d_lanes[None, :])              # (block_q, 1)
-        s = _scores(qs, ks) * s_scale
+        rows = pl.ds(i * block_q, block_q)
+        qs = [q_ref[0, rows, :] for q_ref in q_refs]
+        do = do_ref[0, rows, :]
+        lse = lse_ref[0, :, rows]             # (1, block_q): the lanes
+        delta = delta_ref[0, :, rows]         # they are stored in
+        sT = _scores(ks, qs) * s_scale        # (block_k, block_q)
         if masked:
-            q_pos = i * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
             k_pos = kj * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp2(s - lse_rows)                    # (block_q, block_k)
+                jnp.int32, (block_k, block_q), 0)
+            q_pos = i * block_q + lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 1)
+            sT = jnp.where(q_pos >= k_pos, sT, NEG_INF)
+        pT = jnp.exp2(sT - lse)
         dv = dv + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            pT.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)                # scale deferred to dk/dq below
-        dsl = ds.astype(ks[0].dtype)
+        dpT = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        # scale deferred to dk/dq below
+        dsT = (pT * (dpT - delta)).astype(ks[0].dtype)
         dks = tuple(dk + jax.lax.dot_general(
-            dsl, q, (((0,), (0,)), ((), ())),
+            dsT, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) for dk, q in zip(dks, qs))
         for dq_acc, k_scaled in zip(dq_accs, ks_scaled):
-            dq_tile = jax.lax.dot_general(
-                dsl, k_scaled, (((1,), (0,)), ((), ())),
+            # the tile's one operand contracted over dimension 0
+            dq_acc[rows, :] = dq_acc[rows, :] + jax.lax.dot_general(
+                dsT, k_scaled, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            sl = pl.ds(i * block_q, block_q)
-            dq_acc[sl, :] = dq_acc[sl, :] + dq_tile
         return dks, dv
 
     dks0 = tuple(jnp.zeros(k.shape, jnp.float32) for k in ks)

@@ -174,6 +174,60 @@ def test_latent_flash_at_kanana_train_shape(v5e, fn, results):
     assert not re.search(r"bf16\[2,8192,32,64\]\S* broadcast\(", text)
 
 
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold (a
+    loop's body, a branch), each with the jaxpr it stands in."""
+    for eqn in jaxpr.eqns:
+        yield jaxpr, eqn
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(inner)
+
+
+def test_flash_backward_tile_is_keys_down_at_kanana_train_shape():
+    """``flash_bwd`` as traced at the Kanana cell's shape computes its tile
+    keys-down (PR 50): lse and delta are read as the lane vectors they are
+    stored as, so no ``transpose`` stands anywhere in the kernel, and of a
+    tile's eight products (two parts' scores, dv, dp, two dk, two dq) only
+    dq's, one a part, contract dimension 0 of their left operand, all on
+    the one ``dsT`` that dk's plain products take too; dv's left operand is
+    another array (``pT``).  A later edit cannot bring the second
+    transposed (512, 512) tile back unseen.  A trace: no chip described."""
+    from ray_tpu.ops.flash_attention import _flash_backward_flat
+    parts = 2
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype)
+    row = shape(64, 1, 8192, dtype=jnp.float32)
+    traced = jax.make_jaxpr(lambda qs, ks, *rest: _flash_backward_flat(
+        qs, ks, *rest, causal=True, block_size=None, interpret=False))(
+        (shape(64, 8192, 128), shape(64, 8192, 64)),
+        (shape(64, 8192, 128), shape(2, 8192, 64)),
+        shape(64, 8192, 128), row, row, shape(64, 8192, 128))
+    (call,) = [e for e in traced.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    assert call.params["name"] == "flash_bwd"
+    tiles = {}          # the jaxpr a tile stands in -> its products
+    for inside, eqn in _equations(call.params["jaxpr"]):
+        assert eqn.primitive.name != "transpose", eqn
+        if eqn.primitive.name == "dot_general":
+            tiles.setdefault(id(inside), []).append(eqn)
+    assert len(tiles) == 2              # the diagonal tile, the loop's body
+    for products in tiles.values():
+        assert len(products) == 3 * parts + 2
+
+        def left_contracts(eqn):
+            return eqn.params["dimension_numbers"][0][0]
+        transposed = [e for e in products if left_contracts(e) == (0,)]
+        assert len(transposed) == parts
+        (ds_t,) = {id(e.invars[0]) for e in transposed}
+        assert sorted(e.outvars[0].aval.shape for e in transposed) == \
+            [(512, 64), (512, 128)]                     # dq's two parts
+        plain = [e for e in products if left_contracts(e) == (1,)
+                 and e.params["dimension_numbers"][0][1] == (0,)]
+        assert len(plain) == parts + 1                  # dk's two, dv
+        assert sum(id(e.invars[0]) == ds_t for e in plain) == parts
+
+
 def _experts(x, w_router, w_gate, w_up, w_down):
     from ray_tpu.ops.moe import dropless_moe_ffn
     return dropless_moe_ffn(x, w_router, w_gate, w_up, w_down, k=8)[0]
@@ -669,10 +723,16 @@ def _operations_and_kernels(lowered_text):
 # weights and is sorted back for the inverse and for the weights' gradient,
 # the gathers promise their indices, the k slots lead the rows gathered
 # back, the counts are a compare and a sum: no ``select``, ``clamp`` or
-# ``scatter`` of ``jnp.take`` and ``bincount``, 3,905 operations).
+# ``scatter`` of ``jnp.take`` and ``bincount``, 3,905 operations).  PR 50
+# replaced both: the flash backward's Mosaic body is in them, and its tile
+# is computed keys-down now (``k . q^T``, lse and delta read as the lane
+# vectors they are stored as, dv and dk plain products, dq's the one
+# contracted over dimension 0: no ``vector.transpose`` of lse or delta in
+# the body).  The kernel's operands, results, grid and name are what they
+# were, and so are the counts: 3,905 operations and 9 kernels, 1,851 and 2.
 PARENT_STEPS = {
-    "olmoe-1b-7b.train-b2-s4096": ("440bf1d631cb4a95", 3905, 9),
-    "gpt2-xl-1558m.train-b8-s1024": ("87069c55eb334fc3", 1851, 2),
+    "olmoe-1b-7b.train-b2-s4096": ("479998fc66d84fe8", 3905, 9),
+    "gpt2-xl-1558m.train-b8-s1024": ("c4122fa3fdfb67d5", 1851, 2),
 }
 
 

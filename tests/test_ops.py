@@ -162,6 +162,71 @@ def test_flash_runs_says_what_causal_attention_traces(monkeypatch, backend,
     assert ("pallas_call" in jaxpr) is want
 
 
+# ------------------------------------------------- the one-pass backward
+# (the queries' and keys' parts, the values' width, the last key part one
+# (B, T, D_i) array for all heads): what the training cells run, GPT-2 XL,
+# OLMoE, Kanana's latent call
+BACKWARD_WIDTHS = {"d64": ((64,), 64, False), "d128": ((128,), 128, False),
+                   "latent": ((128, 64), 128, True)}
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-4),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("tiles", [1, 3])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("widths", sorted(BACKWARD_WIDTHS))
+def test_flash_backward_equals_dense_gradients(widths, causal, tiles, dtype,
+                                               tol):
+    """dq, dk and dv of ``flash_bwd`` on the kernels' own flat operands
+    against ``dense_attention``'s gradients on the joined ones.  One tile
+    is the diagonal alone; three run the masked and the unmasked path and
+    accumulate dq over the k-steps.  A shared key part's gradient comes
+    back a head."""
+    from ray_tpu.ops.flash_attention import (_flash_backward_flat,
+                                             _flash_forward_lse_flat)
+    parts, dv, shared = BACKWARD_WIDTHS[widths]
+    batch, heads, bs = 2, 2, 32
+    t = tiles * bs
+    keys = iter(jax.random.split(jax.random.key(11), 2 * len(parts) + 2))
+
+    def draw(lead, d):
+        return jax.random.normal(next(keys), (lead, t, d),
+                                 jnp.float32).astype(dtype)
+    qs = tuple(draw(batch * heads, d) for d in parts)
+    ks = tuple(draw(batch if shared and i == len(parts) - 1
+                    else batch * heads, d) for i, d in enumerate(parts))
+    v, do = draw(batch * heads, dv), draw(batch * heads, dv)
+    out, lse = _flash_forward_lse_flat(qs, ks, v, causal=causal, bs=bs,
+                                       interpret=True)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)[:, None, :]
+    dqs, dks, dvs = _flash_backward_flat(qs, ks, v, lse, delta, do,
+                                         causal=causal, block_size=bs,
+                                         interpret=True)
+
+    def heads_out(x):       # (B.H, T, D) or (B, T, D) -> float32 (B,T,H,D)
+        x = x.astype(jnp.float32)
+        if x.shape[0] == batch:
+            return jnp.broadcast_to(x[:, :, None], (batch, t, heads,
+                                                    x.shape[-1]))
+        return x.reshape(batch, heads, t, -1).transpose(0, 2, 1, 3)
+
+    def dense(q, k, v):
+        return dense_attention(q, k, v, causal=causal)
+    joined = (jnp.concatenate([heads_out(q) for q in qs], -1),
+              jnp.concatenate([heads_out(k) for k in ks], -1), heads_out(v))
+    want_q, want_k, want_v = jax.vjp(dense, *joined)[1](heads_out(do))
+    got_q = jnp.concatenate([heads_out(dq) for dq in dqs], -1)
+    got_k = jnp.concatenate([heads_out(dk) for dk in dks], -1)
+    assert [dk.shape[0] for dk in dks] == [batch * heads] * len(parts)
+    for got, want in ((got_q, want_q), (got_k, want_k),
+                      (heads_out(dvs), want_v)):
+        assert got.shape == want.shape
+        scale = 1.0 if dtype == jnp.float32 else float(jnp.abs(want).max())
+        _allclose(got, want, tol * max(1.0, scale))
+
+
 # ------------------------------------------- latent attention's five operands
 # (nope, rope, Dv, T, tile): a small one, and the Kanana cell's widths
 LATENT = {"small": (16, 8, 16, 64, 16), "kanana": (128, 64, 128, 256, 128)}
@@ -271,12 +336,15 @@ def test_latent_attention_off_the_kernel_is_dense_on_the_joined_operands():
 # latent attention its entry traced it (commit 9c24c8f, this jax): forward
 # and gradient, at GPT-2's head width and at keys wider than values.  The
 # kernels' body is shared with the five-operand call; what every other
-# caller lowers, and its compile-cache key, must not move with it.
+# caller lowers, and its compile-cache key, must not move with it.  PR 50
+# replaced the two gradients' digests: the backward kernel's body, which a
+# jaxpr prints, computes its tile keys-down since then, for every caller;
+# the forward's did not move.
 PARENT_JAXPRS = {
     ("forward", 64, 64): "c1b26728f00cf0d7",
-    ("gradient", 64, 64): "1962c8f36f723ea0",
+    ("gradient", 64, 64): "2bc4c709c2cd664a",
     ("forward", 192, 128): "43732ca3c853f0ee",
-    ("gradient", 192, 128): "852c6025357501e7",
+    ("gradient", 192, 128): "bf54646e7181a4a9",
 }
 
 
