@@ -347,12 +347,22 @@ def grouped_matmul(rows: jax.Array, w: jax.Array,
     gradient.  Elsewhere ``jax.lax.ragged_dot``, which XLA lowers on a TPU
     to its own kernels (``ragged-dot*``): slower there by a fifth to a
     third in all three products, and its backward copies the weights
-    transposed.
+    transposed; with ``H < E`` its rows behind the held groups are set to
+    zero here, which the TPU's kernel does not do itself.
     """
     (m, d), (held, _, f) = rows.shape, w.shape
     if jax.default_backend() == "tpu" and gmm_tiling(m, d, f):
         return _megablox(rows, w, group_sizes)
-    return jax.lax.ragged_dot(rows, w, group_sizes[:held])
+    out = jax.lax.ragged_dot(rows, w, group_sizes[:held])
+    if jax.default_backend() == "tpu" and held < group_sizes.shape[0]:
+        # XLA's TPU kernel writes the rows its groups cover and no other:
+        # behind the held groups lies what the buffer held (NaNs, seen on
+        # the v5e at a decode step's 64 rows: PERF.md, PR 52).  The CPU's
+        # ragged_dot zeroes them, and a share of the experts had met
+        # ragged_dot there only (Kanana trains through megablox)
+        covered = jnp.arange(m)[:, None] < group_sizes[:held].sum()
+        out = jnp.where(covered, out, 0)
+    return out
 
 
 def route_softmax(x: jax.Array, w_router: jax.Array, k: int):

@@ -41,6 +41,14 @@ walk may be in any order.  The call without a list is the case "every
 page of the context, all heads together": one kernel body, and which walk
 is built follows from whether a list was given, never from an option.
 
+A window.  A sliding-window layer (``models/afmoe.py``) hands the call
+``window``: the new token sees that many positions, its own the last.  The
+walk over the table then starts at the column that holds the first of
+them and masks what lies before it in that column; the columns behind are
+not read, and the cache has given their blocks back
+(``serve/llm/kv_cache.py``, pages of two kinds).  A static argument: a
+layer without one compiles to the walk it had.
+
 The pool's format, ``(L, 2, N, bs, F)`` (layer, K or V, block, position
 in the block, the position's ``KV * D`` features flat along the lanes and
 zero-padded to whole 128-lane tiles: 25 x 64 -> 1,664), belongs to its
@@ -107,7 +115,7 @@ def heads_apart(x: jax.Array, n_kv: int, head_dim: int) -> jax.Array:
 
 
 def _paged_decode_gather(q, kv_pool, layer, block_tables, ctx_lens,
-                         k_new, v_new, pages=None, counts=None):
+                         k_new, v_new, pages=None, counts=None, window=None):
     """Gather-then-mask: the CPU path and the kernel's reference."""
     b, h, d = q.shape
     kvh = k_new.shape[1]
@@ -119,6 +127,10 @@ def _paged_decode_gather(q, kv_pool, layer, block_tables, ctx_lens,
         # nothing that matters
         k_pool, v_pool = heads_apart(kv_pool[layer], kvh, d)
     scale = 1.0 / math.sqrt(d)
+    if window is not None:
+        # a column behind the window names no block (out of range): it is
+        # masked below, and gathered from somewhere that exists
+        block_tables = jnp.minimum(block_tables, k_pool.shape[0] - 1)
     k_ctx = gather_kv(k_pool, block_tables)          # (B, T, KV, D)
     v_ctx = gather_kv(v_pool, block_tables)
     t = k_ctx.shape[1]
@@ -132,6 +144,10 @@ def _paged_decode_gather(q, kv_pool, layer, block_tables, ctx_lens,
         logits = jnp.einsum("bhd,bkhd->bhk", q, k_ctx,
                             preferred_element_type=jnp.float32) * scale
         valid = jnp.arange(t)[None, :] < ctx_lens[:, None]      # (B, T)
+        if window is not None:
+            # the new token stands at ctx_lens and sees ``window``
+            # positions, its own the last
+            valid &= jnp.arange(t)[None, :] > (ctx_lens - window)[:, None]
         logits = jnp.where(valid[:, None, :], logits, NEG_INF)
         self_logit = jnp.einsum("bhd,bhd->bh", q, k_new,
                                 preferred_element_type=jnp.float32) * scale
@@ -182,7 +198,7 @@ def _listed_decode_gather(q, kv_pool, layer, block_tables, ctx_lens,
 
 
 def _decode_kernel(tables_ref, lens_ref, layer_ref, *refs, head_dim,
-                   kv_rows, listed):
+                   kv_rows, listed, window=None):
     """One sequence (grid step): walk its blocks, a chunk at a time.
 
     Heads lie along the lanes: a block is (bs, F), F = KV * D padded to
@@ -203,6 +219,12 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, *refs, head_dim,
     the head's query group as it is, a page's copy is the head's own D
     lanes, ``pool_hbm[layer, 0 / 1, table[pages[j]], :, g D : g D + D]``,
     and a position's place in the context is its column's.
+
+    ``window`` (the walk over a table, not a list): the new token sees
+    that many positions, its own the last, so the walk starts at the table
+    column that holds position ``ctx - window + 1`` and the positions of
+    that column before it are masked; the columns behind it are never
+    read, and may name no block any more.
     """
     from jax.experimental.pallas import tpu as pltpu
 
@@ -221,6 +243,12 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, *refs, head_dim,
         lanes = pl.ds(pl.multiple_of(g * head_dim, head_dim), head_dim)
     else:
         n_blocks = pl.cdiv(ctx, bs)
+    # the first position the new token sees and the column that holds it
+    lo = col0 = 0
+    if window is not None:
+        lo = jnp.maximum(ctx - (window - 1), 0)
+        col0 = lo // bs
+        n_blocks = n_blocks - col0
     n_chunks = pl.cdiv(n_blocks, chunk)
     hi = lax.Precision.HIGHEST      # float32 K/V stay float32 on the MXU
 
@@ -229,6 +257,8 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, *refs, head_dim,
         stays inside the row when the guard fails."""
         if listed:
             j = pages_ref[b, g, jnp.minimum(j, pages_ref.shape[2] - 1)]
+        if window is not None:
+            j = j + col0
         return jnp.minimum(j, tables_ref.shape[1] - 1)
 
     def page(kv, blk):
@@ -266,8 +296,12 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, *refs, head_dim,
     def live_positions(c, shape, axis):
         """Which of the chunk's T positions, laid along ``axis`` of
         ``shape``, are in the context."""
-        if not listed:
+        if not listed and window is None:
             return c * t + lax.broadcasted_iota(jnp.int32, shape, axis) < ctx
+        if not listed:
+            at = col0 * bs + c * t \
+                + lax.broadcasted_iota(jnp.int32, shape, axis)
+            return (at < ctx) & (at >= lo)
         at = lax.broadcasted_iota(jnp.int32, shape, axis)
         # a listed page lies where its column says, and past the count
         # nothing was copied
@@ -332,7 +366,7 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, *refs, head_dim,
 
 def _paged_decode_kernel(q, kv_pool, layer, block_tables, ctx_lens,
                          k_new, v_new, pages=None, counts=None, *,
-                         interpret=False):
+                         window=None, interpret=False):
     """The block-table walk as one Pallas call over the batch (with
     ``pages``: over the batch's (row, KV head) pairs)."""
     # imported where the kernel is built (flash_attention's idiom), so
@@ -361,7 +395,7 @@ def _paged_decode_kernel(q, kv_pool, layer, block_tables, ctx_lens,
         row = lambda i, tables, lens, layer: (i, 0, 0)         # noqa: E731
         out = pl.pallas_call(
             functools.partial(_decode_kernel, head_dim=d, kv_rows=kv_rows,
-                              listed=False),
+                              listed=False, window=window),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3,
                 grid=(b,),
@@ -384,7 +418,7 @@ def _paged_decode_kernel(q, kv_pool, layer, block_tables, ctx_lens,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
-            name="paged_decode",
+            name="paged_decode" if window is None else "paged_decode_window",
         )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
           jnp.asarray(layer, jnp.int32).reshape(1),
           qbd.reshape(b, rep * kv_rows, f),
@@ -447,7 +481,8 @@ def paged_attention_decode(q: jax.Array, kv_pool: jax.Array, layer,
                            block_tables: jax.Array, ctx_lens: jax.Array,
                            k_new: jax.Array, v_new: jax.Array,
                            pages: jax.Array = None,
-                           counts: jax.Array = None) -> jax.Array:
+                           counts: jax.Array = None,
+                           window: int = None) -> jax.Array:
     """Single-token decode attention through a block table.
 
     q:       (B, H, D)        — query for the token being decoded.
@@ -467,6 +502,10 @@ def paged_attention_decode(q: jax.Array, kv_pool: jax.Array, layer,
                                 (its first ``counts`` entries, in any
                                 order) in place of every column of the
                                 context.
+    window: int               — a sliding-window layer: the new token sees
+                                that many positions, its own the last; the
+                                table's columns wholly behind them are not
+                                read (their blocks may have gone back).
 
     Returns (B, H, D) in q.dtype.  On a TPU the blocks a context holds
     are all that is read; elsewhere every table column is gathered.
@@ -478,6 +517,7 @@ def paged_attention_decode(q: jax.Array, kv_pool: jax.Array, layer,
             and q.shape[1] % k_new.shape[1] == 0 \
             and (pages is None or q.shape[2] % 128 == 0):
         return _paged_decode_kernel(q, kv_pool, layer, block_tables,
-                                    ctx_lens, k_new, v_new, pages, counts)
+                                    ctx_lens, k_new, v_new, pages, counts,
+                                    window=window)
     return _paged_decode_gather(q, kv_pool, layer, block_tables, ctx_lens,
-                                k_new, v_new, pages, counts)
+                                k_new, v_new, pages, counts, window)

@@ -172,7 +172,9 @@ class LLMEngine:
             self.runner.n_kv, self.runner.head_dim, dtype=np.float32,
             state=self.runner.state_spec, max_seqs=cfg.max_num_seqs,
             state_layers=self.runner.state_layers,
-            select_stride=select.get("stride", 0))
+            select_stride=select.get("stride", 0),
+            window_layers=self.runner.window_layers,
+            window=self.runner.window)
         self.runner.cache = self.cache
         self.sched = IterationScheduler(cfg.max_num_seqs,
                                         cfg.max_prefill_tokens,
@@ -219,6 +221,13 @@ class LLMEngine:
         self.sparse_pages_read = 0
         self.sparse_pages_held = 0
         self.prefill_chunks = 0
+        # a model with window layers: the blocks its decode steps' window
+        # layers read and what full layers would have read in their place,
+        # over live rows and window layers (loop-owned; the cache counts
+        # the blocks held and given back)
+        self.window_blocks_read = 0
+        self.window_blocks_unwindowed = 0
+        self._window_released_sent = 0
         # tokens by where they were chosen (the step program's argmax for
         # a greedy request; ModelRunner.sample on a pulled row for any
         # other) and the bytes of logits pulled for the latter.
@@ -734,6 +743,13 @@ class LLMEngine:
                 tags = {"model": self.cfg.model}
                 mcat.get("rtpu_llm_sparse_pages_read").inc(read, tags=tags)
                 mcat.get("rtpu_llm_sparse_pages_held").inc(held, tags=tags)
+        if flight.step.reads:
+            reads = flight.step.reads
+            span.set(**reads)
+            self.window_blocks_read += reads["window_blocks"]
+            self.window_blocks_unwindowed += reads["window_blocks_unwindowed"]
+            if GLOBAL_CONFIG.metrics_enabled:
+                self._publish_window_blocks()
         discarded = 0
         with hot_span("llm.decode.commit", self.span_s):
             for i, s in enumerate(flight.batch):
@@ -749,6 +765,20 @@ class LLMEngine:
                 self._count_chosen_locked(chosen, discarded)
         self.decode_rows_discarded += discarded
         self._count_tokens(len(flight.batch) - discarded, phase="decode")
+
+    def _publish_window_blocks(self) -> None:
+        """The cache's three counts of window blocks to the catalog: held
+        and what one table for all layers would hold as gauges' worth of
+        counters (each step adds what the live sequences hold now, so
+        their ratio is the mean over steps), given back as a total."""
+        tags = {"model": self.cfg.model}
+        held, released, unwindowed = self.cache.window_counts()
+        mcat.get("rtpu_llm_kv_window_blocks_held").inc(held, tags=tags)
+        mcat.get("rtpu_llm_kv_window_blocks_unwindowed").inc(
+            unwindowed, tags=tags)
+        mcat.get("rtpu_llm_kv_window_blocks_released_total").inc(
+            released - self._window_released_sent, tags=tags)
+        self._window_released_sent = released
 
     def _return_slots(self, batch: List[Sequence], slots: Dict) -> None:
         for s in batch:
@@ -976,6 +1006,12 @@ class LLMEngine:
                 f"{what}: {self.cfg.model} keeps recurrent state beside "
                 "its K/V blocks, and the manifest exports blocks only; "
                 "nothing moves the state yet, so nothing is moved")
+        if self.cache.window_layers:
+            raise NotImplementedError(
+                f"{what}: {self.cfg.model} keeps its window layers' K/V in "
+                "a second pool under a second table, and the manifest "
+                "exports the one table's blocks; nothing moves the window "
+                "layers' yet, so nothing is moved")
 
     def _drain_cancels(self) -> None:
         with self._lock:
@@ -1153,5 +1189,12 @@ class LLMEngine:
                     sparse_pages_held=self.sparse_pages_held,
                     select_bytes=self.cache.select_bytes,
                     staging_bytes=self.runner.staging_bytes,
+                    window_layers=self.cache.window_layers,
+                    window_bytes=self.cache.window_bytes,
+                    window_blocks=dict(zip(
+                        ("held", "released", "unwindowed"),
+                        self.cache.window_counts())),
+                    window_blocks_read=self.window_blocks_read,
+                    window_blocks_unwindowed=self.window_blocks_unwindowed,
                     span_s={k: list(v) for k, v in
                             list(self.span_s.items())})

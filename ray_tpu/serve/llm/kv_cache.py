@@ -92,11 +92,45 @@ caller can write K and forget its slot.  (``load_block`` writes none: the
 one family with a selector keeps state rows too, and the engine refuses to
 attach a sequence of such a model.)  A family that names no selector has
 no ``"sel"`` in its holder, and its programs none of this.
+
+Pages of two kinds.  A family whose attention is full in some layers and a
+sliding window in the others (``models/afmoe.py``; its ``cache_layers``
+counts a third kind, ``"window"``) needs every position of a context in
+the one kind of layer and the last ``window`` in the other.  Held in one
+pool under one table, the window layers would keep what they never read
+again: 4 of 5 layers at a 25k context.  So the holder has a second pool,
+``"kvw"``, :func:`device_shape` over the window layers and over
+``max_seqs x (ceil(window / bs) + 1)`` blocks (what the sequence slots can
+hold at once: it cannot run out, and the scheduler is told nothing of
+it), and the manager a second free list and a second table a sequence,
+under the one lock, with the one lifetime.  The window table is indexed
+like the other (column ``j`` is positions ``j bs ..``), so both grow in
+one :meth:`append_slot` and one ``grew`` undoes both; a column wholly
+behind the window names no block (``window_blocks``, out of range) and its
+block is back on the window free list: given back in ``alloc_seq`` (a
+prompt's window blocks are only those its first decode step can see) and
+in every ``append_slot`` that carries the window past a block's last
+position.  The decode kernel starts its walk at the window's first column
+(``ops/paged_attention.py``), so a released column is never read.  A
+released block may be handed to another sequence at once: every program
+that reads or writes the pool takes it donated, the device runs them in
+the order they were enqueued, and the step that last read the block was
+enqueued before any write of its next owner.  ``free_seq`` (finish,
+cancel, preemption) returns both tables' blocks; ``rollback_slot`` undoes
+a growth in both and leaves what was released released (it lies behind the
+window of the position reserved again); ``fork_seq`` refuses, because a
+shared window block would be given back by whichever holder passes it
+first.  ``window_held`` / ``window_released`` / ``window_unwindowed`` count
+the window blocks held now, given back so far, and what one table for all
+layers would hold for the sequences alive now.  A family that names no
+window has no ``"kvw"`` in its holder, no second list, and none of this in
+its programs.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 import os
 import threading
 from types import SimpleNamespace
@@ -171,6 +205,20 @@ def selector_shape(pool_shape: tuple, stride: int) -> tuple:
     return (n_layer, num_blocks, bs // stride, f)
 
 
+def window_columns(window: int, block_size: int) -> int:
+    """The most table columns a window layer holds blocks for: the window's
+    ``ceil(window / bs)`` and one more, for a window that starts inside a
+    block."""
+    return -(-window // block_size) + 1
+
+
+def window_first_column(n_tokens: int, window: int, block_size: int) -> int:
+    """The first table column a window layer still needs once ``n_tokens``
+    positions are cached: the one that holds position ``n_tokens - window +
+    1``, the first that the token at ``n_tokens`` sees."""
+    return max(0, n_tokens - window + 1) // block_size
+
+
 def _halves_rewritten(sel, pool, blocks, offsets):
     """The half-kernels that rows just written fall in, summed again from
     the pool: slot ``(blocks[r], offsets[r] // stride)`` of every layer
@@ -239,6 +287,19 @@ def write_rows(pool, blocks, offsets, k, v, sel=None):
     return pool, _halves_rewritten(sel, pool, blocks, offsets)
 
 
+def write_rows_by_kind(held, blocks, wblocks, offsets, k, v):
+    """:func:`write_rows` where pages are of two kinds: k, v (L, R, KV, D)
+    hold the full layers' rows first and then the window layers'; the one
+    go to ``held["kv"]`` at ``blocks``, the other to ``held["kvw"]`` at
+    ``wblocks``, both at ``offsets``.  Returns the holder's dict."""
+    n_full = held["kv"].shape[0]
+    return {**held,
+            "kv": write_rows(held["kv"], blocks, offsets, k[:n_full],
+                             v[:n_full]),
+            "kvw": write_rows(held["kvw"], wblocks, offsets, k[n_full:],
+                              v[n_full:])}
+
+
 @functools.cache
 def _programs() -> SimpleNamespace:
     """The pool's jitted programs, built on first use (importing this
@@ -254,9 +315,12 @@ def _programs() -> SimpleNamespace:
     from ray_tpu.ops.paged_attention import heads_apart, lane_flat
     from ray_tpu.ops.sparse_attention import halves_of
 
-    def _write_rows(held, blocks, offsets, k, v):
+    def _write_rows(held, blocks, offsets, k, v, *wblocks):
         # the half-kernels the rows fall in too, where a selector's cache
         # is kept
+        if wblocks:
+            return write_rows_by_kind(held, blocks, wblocks[0], offsets, k,
+                                      v), None
         sel = held.get("sel")
         if sel is None:
             return {**held, "kv": write_rows(held["kv"], blocks, offsets,
@@ -269,6 +333,32 @@ def _programs() -> SimpleNamespace:
         # padding (t >= n_tokens) is sent out of range and dropped
         pool = held["kv"]
         num_blocks, bs = pool.shape[2:4]
+        if "kvw" in held:
+            # pages of two kinds: ks / vs are (1, rows, KV, D), the full
+            # layers' K/V of the padded prompt one behind another and then
+            # each window layer's run of positions ``first ..`` (whole
+            # blocks, the window's columns); ``wtable``: those columns
+            wtable, first = row
+            wpool = held["kvw"]
+            run = wtable.shape[0] * bs
+            n_full, n_win = pool.shape[0], wpool.shape[0]
+            heads = ks.shape[2:]
+            split = ks.shape[1] - n_win * run
+            with jax.named_scope("kv_write"):
+                t = jnp.arange(split // n_full)
+                blocks = jnp.where(t < n_tokens, table[t // bs], num_blocks)
+                pool = write_rows(
+                    pool, blocks, t % bs,
+                    ks[0, :split].reshape(n_full, -1, *heads),
+                    vs[0, :split].reshape(n_full, -1, *heads))
+                t = jnp.arange(run)
+                blocks = jnp.where(first + t < n_tokens, wtable[t // bs],
+                                   wpool.shape[2])
+                wpool = write_rows(
+                    wpool, blocks, t % bs,
+                    ks[0, split:].reshape(n_win, run, *heads),
+                    vs[0, split:].reshape(n_win, run, *heads))
+            return {**held, "kv": pool, "kvw": wpool}, None
         with jax.named_scope("kv_write"):
             t = jnp.arange(ks.shape[1])
             blocks = jnp.where(t < n_tokens, table[t // bs], num_blocks)
@@ -310,8 +400,8 @@ def _programs() -> SimpleNamespace:
         return heads_apart(lax.dynamic_index_in_dim(
             held["kv"], block_id, 2, keepdims=False), *heads)
 
-    def _read_blocks(held, heads):
-        return heads_apart(jnp.moveaxis(held["kv"], 2, 0), *heads)
+    def _read_blocks(held, heads, name="kv"):
+        return heads_apart(jnp.moveaxis(held[name], 2, 0), *heads)
 
     # the names are rows of lock_watchdog.DONATED (jaxlint pins them)
     kv_write_rows = jax.jit(_write_rows, donate_argnums=(0,))
@@ -321,14 +411,15 @@ def _programs() -> SimpleNamespace:
         write_rows=kv_write_rows, scatter_prefill=kv_scatter_prefill,
         load_block=kv_load_block,
         read_block=jax.jit(_read_block, static_argnames="heads"),
-        read_blocks=jax.jit(_read_blocks, static_argnames="heads"))
+        read_blocks=jax.jit(_read_blocks, static_argnames=("heads", "name")))
 
 
 class DevicePool:
     """The block pool's device array, whoever holds it now, as ``{"kv":
     that array}``; with ``state`` (a store's leaves as
-    ``ShapeDtypeStruct``s) the store beside it, ``"state"``, and with
-    ``sel`` the selector's cache, ``"sel"``.
+    ``ShapeDtypeStruct``s) the store beside it, ``"state"``, with ``sel``
+    the selector's cache, ``"sel"``, and with ``window`` the window
+    layers' pool, ``"kvw"``.
 
     A donating program deletes the array it was given and returns a new
     one over the same memory, so nobody may keep the array itself:
@@ -339,9 +430,12 @@ class DevicePool:
     for the enqueue only, and the device runs the programs in the order
     they were enqueued."""
 
-    def __init__(self, shape, dtype, state=None, sel=None):
+    def __init__(self, shape, dtype, state=None, sel=None, window=None):
         self.shape, self.dtype = tuple(shape), dtype
         self.state = state
+        # the window layers' pool (a ShapeDtypeStruct), for a family whose
+        # layers hold pages of two kinds: one more entry, ``"kvw"``
+        self.window = window
         # the selector's cache (a ShapeDtypeStruct), for a family that
         # chooses its pages: one more entry of what is held, ``"sel"``
         self.sel = sel
@@ -400,6 +494,8 @@ class DevicePool:
             held["state"] = jax.tree.map(made, self.state)
         if self.sel is not None:
             held["sel"] = made(self.sel)
+        if self.window is not None:
+            held["kvw"] = made(self.window)
         return held
 
 
@@ -409,7 +505,8 @@ class PagedKVCache:
     def __init__(self, num_blocks: int, n_layer: int, block_size: int,
                  n_kv: int, head_dim: int, dtype=np.float32, *,
                  state=None, max_seqs: int = 0, state_layers=None,
-                 select_stride: int = 0):
+                 select_stride: int = 0, window_layers: int = 0,
+                 window: int = 0):
         """``n_layer``: the layers that hold K/V.  ``state``: one
         sequence's recurrent state in one layer, name ->
         ``ShapeDtypeStruct`` (a model module's ``recurrent_state``), for a
@@ -417,7 +514,12 @@ class PagedKVCache:
         for staging, in each of ``state_layers`` layers (None: as many as
         hold K/V).  ``select_stride``: the positions a half-kernel of the
         selector's cache pools (a model module's ``page_selector``), for a
-        family whose attention chooses its pages; 0: none is kept."""
+        family whose attention chooses its pages; 0: none is kept.
+        ``window_layers`` and ``window``: the layers that hold the last
+        ``window`` positions only (a model module's ``cache_layers`` and
+        sliding window), beside the ``n_layer`` that hold every position:
+        a second pool of ``max_seqs`` x :func:`window_columns` blocks, which
+        the sequence slots cannot exhaust."""
         self.num_blocks = num_blocks
         self.block_shape = (n_layer, 2, block_size, n_kv, head_dim)
         self.block_size = block_size
@@ -459,7 +561,24 @@ class PagedKVCache:
                                        self.dtype)
         self.select_bytes = int(np.prod(sel.shape)) * self.dtype.itemsize \
             if sel is not None else 0
-        self.pool = DevicePool(shape, self.dtype, state=store, sel=sel)
+        self.window_layers, self.window = window_layers, window
+        self.window_blocks = 0
+        wpool = None
+        if window_layers:
+            import jax
+            if not max_seqs or window < 1:
+                raise ValueError(
+                    "window layers need max_seqs (their pool is sized by "
+                    "the sequence slots) and a window of positions")
+            self.window_blocks = max_seqs * window_columns(window,
+                                                           block_size)
+            wpool = jax.ShapeDtypeStruct(
+                device_shape(self.window_blocks, window_layers, block_size,
+                             n_kv, head_dim), self.dtype)
+        self.window_bytes = math.prod(wpool.shape) * self.dtype.itemsize \
+            if wpool is not None else 0
+        self.pool = DevicePool(shape, self.dtype, state=store, sel=sel,
+                               window=wpool)
         # one step region for the writers below (DESIGN.md §4q): a
         # scatter program per prefill bucket, write_token, load_block
         self._write_budget = compile_budget("llm.kv_write")
@@ -474,6 +593,14 @@ class PagedKVCache:
         self._free_rows: List[int] = list(range(self.state_rows - 1, -1, -1))  # guarded by: _lock
         self._owner: Dict[int, str] = {}                             # guarded by: _lock
         self.state_commits = 0                                       # guarded by: _lock
+        # pages of two kinds: the window pool's free blocks, a sequence's
+        # window table (indexed like its table; ``window_blocks``, out of
+        # range, where the block went back) and the blocks given back
+        self._wfree: List[int] = list(range(self.window_blocks - 1, -1, -1))  # guarded by: _lock
+        self._wtables: Dict[str, List[int]] = {}                     # guarded by: _lock
+        # its first column that still names a block
+        self._wfirst: Dict[str, int] = {}                            # guarded by: _lock
+        self.window_released = 0                                     # guarded by: _lock
         # bytes of pool data that crossed between host and device, either
         # way: K/V given as numpy, blocks exported or imported
         self.host_bytes = 0                                          # guarded by: _lock
@@ -505,6 +632,16 @@ class PagedKVCache:
             if len(self._free) < n:
                 raise NoFreeBlocks(
                     f"need {n} blocks, {len(self._free)} free")
+            first = 0
+            if self.window_layers:
+                # the columns the first decode step can see, and no other
+                first = window_first_column(n_tokens, self.window,
+                                            self.block_size)
+                if len(self._wfree) < n - first:
+                    raise NoFreeBlocks(
+                        f"need {n - first} window blocks, "
+                        f"{len(self._wfree)} free: more sequences hold "
+                        "blocks than the slots the window pool is sized for")
             if self.state_rows:
                 if not self._free_rows:
                     raise NoFreeBlocks(
@@ -516,8 +653,12 @@ class PagedKVCache:
                 self._ref[b] = 1
             self._tables[seq_id] = blocks
             self._fill[seq_id] = n_tokens
-            if self.state_rows:
+            if self.state_rows or self.window_layers:
                 self._owner[blocks[0]] = seq_id
+            if self.window_layers:
+                self._wfirst[seq_id] = first
+                self._wtables[seq_id] = [self.window_blocks] * first + [
+                    self._wfree.pop() for _ in range(n - first)]
         return blocks
 
     def append_slot(self, seq_id: str) -> tuple:
@@ -541,6 +682,20 @@ class PagedKVCache:
                 self._ref[b] = 1
                 table.append(b)
                 grew = True
+            if self.window_layers:
+                # the token at ``fill`` sees ``fill - window + 1 ..``: the
+                # columns wholly behind that go back first, then the
+                # window table grows with the other
+                wtable = self._wtables[seq_id]
+                first = window_first_column(fill, self.window,
+                                            self.block_size)
+                for j in range(self._wfirst[seq_id], first):
+                    self._wfree.append(wtable[j])
+                    wtable[j] = self.window_blocks
+                    self.window_released += 1
+                self._wfirst[seq_id] = max(first, self._wfirst[seq_id])
+                if grew:
+                    wtable.append(self._wfree.pop())
             self._fill[seq_id] = fill + 1
             return table[blk_i], off, grew
 
@@ -550,6 +705,8 @@ class PagedKVCache:
             if seq_id not in self._fill:
                 return                     # freed/preempted meanwhile
             self._fill[seq_id] -= 1
+            if grew and self.window_layers:
+                self._wfree.append(self._wtables[seq_id].pop())
             if grew:
                 b = self._tables[seq_id].pop()
                 self._ref[b] -= 1
@@ -565,7 +722,11 @@ class PagedKVCache:
             row = self._rows.pop(seq_id, None)
             if row is not None:
                 self._free_rows.append(row)
+            if blocks:
                 self._owner.pop(blocks[0], None)
+            self._wfree.extend(b for b in self._wtables.pop(seq_id, ())
+                               if b != self.window_blocks)
+            self._wfirst.pop(seq_id, None)
             if not blocks:
                 return 0
             freed = 0
@@ -584,6 +745,12 @@ class PagedKVCache:
             raise NotImplementedError(
                 "a sequence with recurrent state cannot be forked: its row "
                 "is its own, and a shared first block would name two rows")
+        if self.window_layers:
+            raise NotImplementedError(
+                "a sequence with window layers cannot be forked: a shared "
+                "window block would be given back by whichever holder's "
+                "context passes it first (prefix sharing across window "
+                "layers: ROADMAP R4)")
         with self._lock:
             blocks = list(self._tables[seq_id])
             for b in blocks:
@@ -607,6 +774,41 @@ class PagedKVCache:
     def seq_ids(self) -> List[str]:
         with self._lock:
             return list(self._tables)
+
+    def window_table(self, seq_id: str) -> List[int]:
+        """The sequence's window table, indexed like :meth:`table`; a
+        column whose block went back holds ``window_blocks``."""
+        with self._lock:
+            return list(self._wtables[seq_id])
+
+    def window_tables(self, block_tables: np.ndarray) -> np.ndarray:
+        """The window table behind each block table (B, MAXB), at the same
+        width: that of the sequence which owns the table's first block.  A
+        table no sequence owns (a row padded up to the bucket, a warm-up
+        call) gets zeros: block 0, read under a context of no length."""
+        out = np.zeros(block_tables.shape, np.int32)
+        with self._lock:
+            for i, t in enumerate(block_tables):
+                wtable = self._wtables.get(self._owner.get(int(t[0])))
+                if wtable is not None:
+                    out[i, :len(wtable)] = wtable[:out.shape[1]]
+        return out
+
+    def window_counts(self) -> tuple:
+        """(window blocks held now, given back so far, what the sequences
+        alive now would hold if the window layers kept one table with the
+        others: a block a column of every table)."""
+        with self._lock:
+            return (self.window_blocks - len(self._wfree),
+                    self.window_released,
+                    sum(len(t) for t in self._wtables.values()))
+
+    def window_pool_blocks(self) -> np.ndarray:
+        """Every block of the window layers' pool in the wire format of
+        :meth:`blocks`, ``(window_blocks, window layers, 2, bs, KV, D)``,
+        copied from the device (the tests)."""
+        return np.asarray(self.pool.read(
+            _programs().read_blocks, heads=self.block_shape[3:], name="kvw"))
 
     def half_kernels(self) -> np.ndarray:
         """The selector's cache, ``(L, N, halves a page, F)``, copied from
@@ -677,7 +879,32 @@ class PagedKVCache:
             with self._lock:
                 row = (self._rows[seq_id],)
                 self.state_commits += 1
+        if self.window_layers:
+            row = self._window_run(self.window_table(seq_id), n_tokens)
         self._scatter(self.table(seq_id), ks, vs, n_tokens, *row)
+
+    def window_run(self, n_tokens: int) -> tuple:
+        """(first position, positions) of the run of a prompt of
+        ``n_tokens`` that its window layers' blocks hold: whole blocks from
+        :func:`window_first_column` on, :func:`window_columns` of them.
+        What a prefill hands :meth:`scatter_prefill` of those layers, behind
+        the full layers' K/V of the padded prompt, all along axis 1 of a
+        ``(1, rows, KV, D)`` array (the runner packs it,
+        ``prefill_result``)."""
+        first = window_first_column(n_tokens, self.window, self.block_size)
+        return (first * self.block_size,
+                window_columns(self.window, self.block_size)
+                * self.block_size)
+
+    def _window_run(self, wtable: List[int], n_tokens: int) -> tuple:
+        """The scatter program's two operands for the window layers: the
+        window table's columns of the run, and its first position."""
+        first, positions = self.window_run(n_tokens)
+        columns = np.full(positions // self.block_size, self.window_blocks,
+                          np.int32)
+        have = wtable[first // self.block_size:][:len(columns)]
+        columns[:len(have)] = have
+        return columns, np.int32(first)
 
     def warm_scatter(self, ks, vs) -> None:
         """Build the scatter program for this shape of K/V without
@@ -685,6 +912,8 @@ class PagedKVCache:
         prefill, so the bucket's two programs are built together)."""
         from ray_tpu.util import tracing
         row = (self.staging_row,) if self.state_rows else ()
+        if self.window_layers:
+            row = self._window_run([], 0)
         args = self._scatter_args([], ks, vs, 0, *row)
         tracing.register_program(
             f"llm.prefill.scatter.{ks.shape[1]}", _programs().scatter_prefill,
@@ -695,11 +924,16 @@ class PagedKVCache:
                       *row: int) -> tuple:
         # the table at the width of the padded prompt; the blocks past
         # the sequence's own are out of range, and dropped on the device
-        padded = np.full(-(-ks.shape[1] // self.block_size),
-                         self.num_blocks, np.int32)
+        rows = ks.shape[1]
+        if self.window_layers:
+            # behind the full layers' K/V lie the window layers' runs
+            rows = (rows - self.window_layers * self.window_run(0)[1]) \
+                // self.kv_layers
+        padded = np.full(-(-rows // self.block_size), self.num_blocks,
+                         np.int32)
         padded[:len(table)] = table[:len(padded)]
         return (padded, ks, vs, np.int32(n_tokens),
-                *(np.int32(r) for r in row))
+                *(np.int32(r) if np.ndim(r) == 0 else r for r in row))
 
     def _scatter(self, table: List[int], ks, vs, n_tokens: int,
                  *row: int) -> None:
@@ -712,8 +946,18 @@ class PagedKVCache:
         step writes its own (``ModelRunner.decode``); this is for a
         caller that holds K/V from elsewhere, and rewriting what a step
         wrote changes nothing."""
+        wblock = ()
+        if self.window_layers:
+            # the window block of the same column of whoever holds
+            # ``block_id``; k / v hold the full layers' rows first
+            with self._lock:
+                wblock = (np.int32([next(
+                    (self._wtables[sid][t.index(block_id)]
+                     for sid, t in self._tables.items() if block_id in t),
+                    self.window_blocks)]),)
         self._write(_programs().write_rows, np.int32([block_id]),
-                    np.int32([offset]), k[:, None], v[:, None], host=(k, v))
+                    np.int32([offset]), k[:, None], v[:, None], *wblock,
+                    host=(k, v))
 
     # -------------------------------------------------------------- teardown
     def close(self) -> None:

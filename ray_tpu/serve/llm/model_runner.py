@@ -98,7 +98,29 @@ scatter program each, not a model program.  The engine's loop calls
 ``prefill_chunk`` itself, one an iteration with a decode step of the live
 rows behind it (``engine.py``), and ``after=`` keeps one chunk in flight.
 One prompt is in prefill at a time: a chunk 0 starts the next.  A module
-without the export has the one-program prefill it had.
+without the export has the one-program prefill it had.  A chunked family
+that carries no recurrent state (``models/afmoe.py``) hands the same chunk
+body no holder, as its one-program prefill would: what its chunks carry is
+the staging alone.
+
+Pages of two kinds.  A module whose ``cache_layers(cfg)`` counts a third
+kind, ``"window"`` (``models/afmoe.py``: layers that hold only the last
+``cfg.sliding_window`` positions), gets a holder with a second pool,
+``"kvw"`` (``kv_cache.py``).  The decode program hands the forward both
+pools and, beside the block tables, the window tables of the same rows
+(``window_tables=``: an operand that exists only then; the cache names
+them by who owns a table's first block, as it names rows of state), and
+writes the new K/V of the full layers into the one pool and of the window
+layers into the other, each through its own table at the same column and
+offset.  The forwards return K/V with the full layers first.  After a
+prompt's last chunk ``prefill_result`` packs what the cache scatters as
+ONE array a K and a V, ``(1, rows, KV, D)``: the full layers' K/V of the
+bucket's length and behind them each window layer's run of the prompt's
+last positions out of the ring (``PagedKVCache.window_run``), so that
+``prefill`` keeps its three results and a caller that copies them to the
+host (the serving check) copies the window's run and not the prompt.
+``llm.decode.pull`` is told the positions and blocks the step's window
+layers read and what full layers would have read in their place.
 
 The hand-over of the choice of experts.  A module that routes exports
 ``routed_layers(cfg)`` -> ``{"layers": n, "k": k}`` (None for a preset
@@ -140,7 +162,8 @@ from ray_tpu._private import rtlog
 from ray_tpu._private.xla_watchdog import compile_budget
 from ray_tpu.serve.llm.config import EngineConfig, SamplingParams, \
     resolve_model
-from ray_tpu.serve.llm.kv_cache import DevicePool, PagedKVCache, write_rows
+from ray_tpu.serve.llm.kv_cache import DevicePool, PagedKVCache, \
+    write_rows, write_rows_by_kind
 from ray_tpu.util.tracing import abstract, hot_span, register_program
 
 logger = rtlog.get("serve.llm.runner")
@@ -198,6 +221,9 @@ class Enqueued(NamedTuple):
     carry: "jax.Array"               # the ids at the widest bucket's width
     n: int                           # the rows that are real
     logit_rows: Optional[Sequence[int]]
+    # what the step's window layers read (``ModelRunner._window_reads``),
+    # told to the pull's span; empty without window layers
+    reads: dict = {}
 
 
 class ModelRunner:
@@ -243,6 +269,10 @@ class ModelRunner:
             "kv": self.n_layer,
             "state": self.n_layer if self.state_spec else 0}
         self.kv_layers, self.state_layers = layers["kv"], layers["state"]
+        # layers that hold the last ``window`` positions only, in a pool
+        # of their own (0: every K/V layer holds the whole context)
+        self.window_layers = layers.get("window", 0)
+        self.window = self.mcfg.sliding_window if self.window_layers else 0
         # the choice of experts a module that routes hands over ({"layers",
         # "k"}; None: it does not route), and the last step's, on the device
         describe = getattr(self.mod, "routed_layers", None)
@@ -256,11 +286,6 @@ class ModelRunner:
         self.select_spec = describe(self.mcfg) if describe else None
         self.chunk = self.mcfg.prefill_chunk \
             if hasattr(self.mod, "forward_prefill_chunk") else 0
-        if self.chunk and self.state_spec is None:
-            raise NotImplementedError(
-                f"{cfg.model} prefills in chunks and holds no recurrent "
-                "state: a chunk hands the next what it carries in the "
-                "store's staging row (prefill_chunk_step)")
         if self.chunk and any(b % self.chunk
                               for b in cfg.prefill_len_buckets):
             raise ValueError(
@@ -282,11 +307,22 @@ class ModelRunner:
         def touched(ids):
             # the distinct experts a decode step chose, summed over the
             # routed layers: ids (layers, rows, k), a padded row holding a
-            # live row's choice (live_rows)
+            # live row's choice (live_rows).  Where the module holds a
+            # share of the experts (``route_spec["held"]``: first, count)
+            # the distinct ones AMONG THE HELD: what the step reads
+            held = self.route_spec.get("held")
             with jax.named_scope("moe_router"):
-                flat = jnp.sort(ids.reshape(ids.shape[0], -1), axis=1)
-                return (1 + (flat[:, 1:] != flat[:, :-1]).sum(1)).sum() \
-                    .astype(jnp.int32)
+                flat = ids.reshape(ids.shape[0], -1)
+                if held:
+                    first, count = held
+                    flat = jnp.where((flat >= first) & (flat < first + count),
+                                     flat, -1)
+                flat = jnp.sort(flat, axis=1)
+                distinct = 1 + (flat[:, 1:] != flat[:, :-1]).sum(1)
+                if held:
+                    # the absent ones were all made one more, the lowest
+                    distinct = distinct - (flat[:, 0] < 0)
+                return distinct.sum().astype(jnp.int32)
 
         def live_rows(tokens, n_real):
             # a routing module is told which rows of the bucket are some
@@ -311,7 +347,8 @@ class ModelRunner:
             out = (logits, greedy(logits)), ks[:, 0], vs[:, 0], *ids
             return out if held is None else (held, out)
 
-        def new_kv_written(held, k, v, block_tables, ctx_lens, n_real):
+        def new_kv_written(held, k, v, block_tables, ctx_lens, n_real,
+                           window_tables=None):
             # a row's new K/V goes to the slot append_slot reserved,
             # (table[ctx // bs], ctx % bs).  Rows padded up to the bucket
             # are sent out of range: they write nowhere.  With a
@@ -322,6 +359,15 @@ class ModelRunner:
                 blocks = jnp.where(rows < n_real,
                                    block_tables[rows, ctx_lens // bs],
                                    cfg.num_blocks)
+                if window_tables is not None:
+                    # pages of two kinds: the full layers' rows lead k / v;
+                    # the window layers' go to their pool through the
+                    # window table, same column, same offset
+                    wblocks = jnp.where(
+                        rows < n_real, window_tables[rows, ctx_lens // bs],
+                        held["kvw"].shape[2])
+                    return write_rows_by_kind(held, blocks, wblocks,
+                                              ctx_lens % bs, k, v)
                 sel = held.get("sel")
                 out = write_rows(held["kv"], blocks, ctx_lens % bs, k, v,
                                  sel)
@@ -354,7 +400,7 @@ class ModelRunner:
             return (logits, ids), carry
 
         def decode_step(held, params, tokens, positions, block_tables,
-                        ctx_lens, n_real, last_ids, src, *state_rows):
+                        ctx_lens, n_real, last_ids, src, *by_row):
             # the model reads the pool and attends the new token
             # explicitly; its K/V is written after the reads.  Where the
             # holder has a store the model steps the rows of it that
@@ -362,11 +408,19 @@ class ModelRunner:
             # the store after its K/V.  Where it has a selector's cache the
             # model reads it and says, last of its results, how many pages
             # it read; the new K's half-kernels are written with the K
-            reads = {}
+            # ``by_row``: what the cache names for each row's table, an
+            # operand each: the store's rows where there is a store, the
+            # window tables where there is a window pool
+            reads, by_row = {}, list(by_row)
+            window_tables = None
             if "state" in held:
-                reads.update(state=held["state"], rows=state_rows[0])
+                reads.update(state=held["state"], rows=by_row.pop(0))
             if "sel" in held:
                 reads["selector"] = held["sel"]
+            if "kvw" in held:
+                window_tables = by_row.pop(0)
+                reads.update(window_pool=held["kvw"],
+                             window_tables=window_tables)
             logits, k, v, *ids = forward_decode(
                 params, tokens_in(tokens, last_ids, src), positions,
                 held["kv"], block_tables, ctx_lens,
@@ -375,21 +429,26 @@ class ModelRunner:
                 store, *ids = ids
                 held = {**held, "state": store}
             pages = ids.pop() if "sel" in held else None
-            held = new_kv_written(held, k, v, block_tables, ctx_lens, n_real)
+            held = new_kv_written(held, k, v, block_tables, ctx_lens, n_real,
+                                  window_tables)
             return held, (*chosen_from(logits, ids, pages), k, v, *ids)
 
         def prefill_chunk_step(held, params, staging, toks, start, n_total):
             # one chunk of one prompt: K/V and half-kernels into the
             # staging, the state from the store's staging row and back
             # into it; the logits are those of the prompt's last position
-            # once a chunk holds it
-            logits, staging, state = self.mod.forward_prefill_chunk(
+            # once a chunk holds it.  A family without a store is handed no
+            # holder, and carries the staging alone
+            logits, staging, state, *ids = self.mod.forward_prefill_chunk(
                 params, toks, self.mcfg, start, n_total, staging,
-                jax.tree.map(lambda s: s[:, -1], held["state"]))
+                None if held is None else jax.tree.map(
+                    lambda s: s[:, -1], held["state"]), **asked)
+            if held is None:
+                return (staging, (logits, greedy(logits)), *ids)
             store = jax.tree.map(lambda s, new: s.at[:, -1].set(new),
                                  held["state"], state)
             return {**held, "state": store}, (
-                staging, (logits, greedy(logits)))
+                staging, (logits, greedy(logits)), *ids)
 
         # bound to a name of its own: jaxlint pins a donating jit by the
         # name it is assigned to (lock_watchdog.DONATED)
@@ -415,7 +474,29 @@ class ModelRunner:
             # compile for a described chip) holds none
             self.staging_spec = self.mod.prefill_staging(self.mcfg, positions)
             self._staging = None
+            self._chunk_choices: list = []
             self.staging_bytes = tree_bytes(self.staging_spec)
+        if self.chunk:
+            # a routing module's choices, chunk behind chunk
+            self._joined = jax.jit(
+                lambda parts: jnp.concatenate(parts, axis=1))
+        if self.chunk and self.window_layers:
+            def packed(staging, first, *, bucket, positions):
+                # what prefill_result hands the cache of a prompt's K/V
+                # where pages are of two kinds: (1, rows, KV, D), the full
+                # layers' first ``bucket`` positions one layer behind
+                # another, then each window layer's ``positions`` from
+                # ``first`` on, out of the staging's ring (the module
+                # knows its ring)
+                heads = (self.n_kv, self.head_dim)
+                bands = self.mod.staged_window(self.mcfg, staging, first,
+                                               positions)
+                return tuple(jnp.concatenate(
+                    [staging[name][:, :bucket].reshape(1, -1, *heads),
+                     band.reshape(1, -1, *heads)], axis=1)
+                    for name, band in zip(("k", "v"), bands))
+            self._packed = jax.jit(packed,
+                                   static_argnames=("bucket", "positions"))
         # one named row of a step's logits, for a request that samples:
         # built with the first such row a bucket meets, not before
         self._logits_row = jax.jit(lambda logits, row: logits[row])
@@ -532,7 +613,7 @@ class ModelRunner:
                 self.staging_spec)
         args = (self.params, self._staging, toks, np.int32(index * c),
                 np.int32(n))
-        pool = self._state_cache().pool
+        pool = self._prefill_holder()
         if compiling is not _SEEN:
             register_program(f"llm.prefill.chunk.{c}", self._prefill_chunk,
                              (pool.abstract(), *abstract(args)))
@@ -541,7 +622,14 @@ class ModelRunner:
                 self._prefill_budget:
             if after is not None:
                 np.asarray(after[1])    # its greedy id: 4 bytes, the wait
-            self._staging, picked = pool.donate(self._prefill_chunk, *args)
+            self._staging, picked, *ids = pool.donate(self._prefill_chunk,
+                                                      *args)
+        if ids:
+            # a routing module: the chunks' choices, joined when the
+            # prompt is done (prefill_result)
+            if index == 0:
+                self._chunk_choices = []
+            self._chunk_choices.append(ids[0])
         return picked
 
     def prefill_result(self, n_tokens: int, picked, logit_rows=None):
@@ -549,9 +637,20 @@ class ModelRunner:
         logits (or ``Chosen``) and the prompt's K/V out of the staging,
         ``(L, bucket, KV, D)`` on the device."""
         tb = _bucket(n_tokens, self.cfg.prefill_len_buckets)
-        heads = (self.n_kv, self.head_dim)
-        ks, vs = (self._staging[name][:, :tb].reshape(
-            self.kv_layers, tb, *heads) for name in ("k", "v"))
+        if self.window_layers:
+            # pages of two kinds: one array a K and a V, the full layers'
+            # rows one behind another and then each window layer's run of
+            # the prompt's last positions, as the cache scatters them
+            first, run = self._state_cache().window_run(n_tokens)
+            ks, vs = self._packed(self._staging, first, bucket=tb,
+                                  positions=run)
+        else:
+            heads = (self.n_kv, self.head_dim)
+            ks, vs = (self._staging[name][:, :tb].reshape(
+                self.kv_layers, tb, *heads) for name in ("k", "v"))
+        if self._chunk_choices:
+            self.choices = self._joined(self._chunk_choices)
+            self._chunk_choices = []
         if ("scatter", tb) not in self._shapes_seen and \
                 self.cache is not None:
             self._shapes_seen.add(("scatter", tb))
@@ -605,13 +704,18 @@ class ModelRunner:
                 block_tables = np.concatenate(
                     [block_tables, np.zeros((pad, block_tables.shape[1]),
                                             np.int32)])
-        state_rows = ()
+        state_rows, reads = (), {}
         if kv_pool.state:
             # whose table each is, the cache knows; padded rows name none
             cache = self._state_cache()
             state_rows = (np.concatenate(
                 [cache.rows_of(block_tables[:b]),
                  np.full(pad, cache.no_row, np.int32)]),)
+        if kv_pool.window is not None:
+            # the window tables of the same rows, and what the step's
+            # window layers read of them (for the pull's span)
+            state_rows += (self._state_cache().window_tables(block_tables),)
+            reads = self._window_reads(ctx_lens[:b])
         # dispatch holds the jitted call and ends at the ENQUEUE; pull
         # ends when the ids or the logits are on the host, so it holds the
         # wait for the step and nothing else: the pool stays where it is
@@ -626,8 +730,24 @@ class ModelRunner:
         if ids:
             self.choices, = ids
         self.steps_enqueued += 1
-        step = Enqueued(self.steps_enqueued, picked, carry, b, logit_rows)
+        step = Enqueued(self.steps_enqueued, picked, carry, b, logit_rows,
+                        reads)
         return (self.pull_step(step) if wait else step), ks, vs
+
+    def _window_reads(self, ctx_lens: np.ndarray) -> dict:
+        """What a decode step's window layers read, from its rows'
+        context lengths: positions (the window's, or the context where it
+        is shorter), blocks (the walk's columns, the first one whole), and
+        the blocks full layers would have read in their place; each summed
+        over the rows and the window layers."""
+        bs, layers = self.cfg.block_size, self.window_layers
+        lens = np.asarray(ctx_lens, np.int64)
+        lo = np.maximum(lens - (self.window - 1), 0)
+        held = -(-lens // bs)
+        return dict(
+            window_positions=int((lens - lo).sum()) * layers,
+            window_blocks=int((held - lo // bs).sum()) * layers,
+            window_blocks_unwindowed=int(held.sum()) * layers)
 
     def pull_step(self, step: Enqueued) -> Union[np.ndarray, Chosen]:
         """Wait for an enqueued decode step and bring the host what its
@@ -635,7 +755,8 @@ class ModelRunner:
         ``llm.decode.pull`` span that says which step it is."""
         return self._pull("llm.decode.pull", step.picked, step.n,
                           step.logit_rows, touched=self.route_spec is not None,
-                          paged=self.select_spec is not None, step=step.step)
+                          paged=self.select_spec is not None, step=step.step,
+                          **step.reads)
 
     def _pull(self, span: str, picked, n: int,
               logit_rows: Optional[Sequence[int]], touched: bool = False,

@@ -251,6 +251,26 @@ CATALOG: Dict[str, dict] = {
                     "layers and heads: what reading every page would have "
                     "read (read / held is what the selection left)",
         emitted_by="llm replica"),
+    "rtpu_llm_kv_window_blocks_released_total": dict(
+        kind="counter", tag_keys=("model", "group"),
+        description="KV blocks a model's sliding-window layers gave back "
+                    "to the cache as their sequences' contexts passed them "
+                    "(at a prompt's end and at every decode step that "
+                    "crosses a block), one a block of the window pool",
+        emitted_by="llm replica"),
+    "rtpu_llm_kv_window_blocks_held": dict(
+        kind="counter", tag_keys=("model", "group"),
+        description="Window-pool blocks the live sequences held, summed "
+                    "over committed decode steps (over "
+                    "rtpu_llm_kv_window_blocks_unwindowed: the share of "
+                    "one table for all layers that the window layers keep)",
+        emitted_by="llm replica"),
+    "rtpu_llm_kv_window_blocks_unwindowed": dict(
+        kind="counter", tag_keys=("model", "group"),
+        description="What the same sequences would hold in the window "
+                    "layers under one block table for all layers (a block "
+                    "a column of every table), summed over the same steps",
+        emitted_by="llm replica"),
     "rtpu_llm_tokens_total": dict(
         kind="counter", tag_keys=("model", "phase", "group"),
         description="Tokens processed by an LLM engine: 'prefill' = "

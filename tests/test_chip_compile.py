@@ -1042,3 +1042,139 @@ def test_older_decode_steps_lower_to_the_operations_and_kernels_they_had(
     digest, ops, kernels = _operations_and_kernels(lowered.as_text())
     assert (digest, sum(ops.values()), sum(kernels.values())) == \
         PARENT_DECODE_STEPS[cell]
+
+
+# ------------------------------------------------ the Trinity cell's programs
+@pytest.fixture(scope="module")
+def afmoe_runner(v5e):
+    """The cell's runner over abstract weights, and what its holder holds
+    as shapes on the chip: pages of two kinds, a pool over the one full
+    layer and a pool over the 4 window layers."""
+    import json
+    from pathlib import Path
+
+    from ray_tpu.models import afmoe
+    from ray_tpu.serve.llm import EngineConfig
+    from ray_tpu.serve.llm.config import resolve_model
+    from ray_tpu.serve.llm.kv_cache import device_shape, window_columns
+    from ray_tpu.serve.llm.model_runner import ModelRunner
+    engine = json.loads((Path(__file__).parent.parent / "perfbench" /
+                         "configs" / "trinity-large-preview.json").read_text()
+                        )["serve"]["engine"]
+    for key in ("decode_batch_buckets", "prefill_len_buckets"):
+        engine[key] = tuple(engine[key])
+    ecfg = EngineConfig(**engine)
+    mod, mcfg = resolve_model(ecfg)
+    assert mod is afmoe
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.eval_shape(lambda key: mod.init_params(key, mcfg),
+                            jax.random.key(0))
+    runner = ModelRunner(ecfg, params=params)
+    assert runner.params is params      # drawn in its serving type
+    assert (runner.kv_layers, runner.window_layers, runner.state_layers,
+            runner.window, runner.chunk) == (1, 4, 0, 4096, 2048)
+    assert runner.state_spec is None and runner.select_spec is None
+    window_blocks = ecfg.max_num_seqs * window_columns(runner.window,
+                                                       ecfg.block_size)
+    held = {
+        "kv": on_chip(device_shape(ecfg.num_blocks, 1, ecfg.block_size,
+                                   mcfg.n_kv_head, mcfg.head_dim),
+                      jnp.float32),
+        "kvw": on_chip(device_shape(window_blocks, 4, ecfg.block_size,
+                                    mcfg.n_kv_head, mcfg.head_dim),
+                       jnp.float32)}
+    weights = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), params)
+    return runner, ecfg, held, weights, on_chip
+
+
+# the float32 reference beside the engine: a block of 4 experts widened
+# (3 x 4 x 3,072 x 3,072 x 4 bytes), their hidden rows at the check's 6,152
+# positions, and a block of 256 queries' scores over them
+AFMOE_REFERENCE_BYTES = 1.2e9
+
+
+def test_afmoe_decode_program_walks_each_kind_of_page_and_fits(
+        afmoe_runner, monkeypatch):
+    """The cell's decode step at its one bucket of 16 (5 layers at the
+    published widths): the paged kernel once a layer, under the window in
+    the 4 sliding layers (``paged_decode_window``, over the window pool
+    and the window tables) and over the whole context in the full one;
+    both pools donated (2.15e9 + 2.18e9 bytes) and each touched by the
+    update of the step's 16 rows alone; 64 assignments are no multiple of
+    megablox's row tile, so the held experts' 12 grouped matmuls are XLA's
+    ragged-dot kernels, each over a layer's own 32 experts (no layer scan,
+    no slice of a stack); the ids come back as (4, 16, 4) with the count of
+    held experts touched behind the step's 16 ids."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, ecfg, held, weights, on_chip = afmoe_runner
+    bucket, i32 = ecfg.decode_batch_buckets[-1], jnp.int32
+    assert runner.param_bytes == 8_643_941_376
+    tables = on_chip((bucket, ecfg.max_blocks_per_seq), i32)
+    lowered = runner._decode.lower(
+        held, weights, on_chip((bucket,), i32), on_chip((bucket,), i32),
+        tables, on_chip((bucket,), i32), on_chip((), i32),
+        on_chip((bucket,), i32), on_chip((bucket,), i32), tables)
+    ids = jax.tree.leaves(lowered.out_info)[-1]
+    assert ids.shape == (4, 16, 4) and ids.dtype == jnp.int32
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    root = next(line for line in text[text.index("ENTRY "):].splitlines()
+                if " ROOT " in line)
+    assert "s32[17]" in root and "s32[16]" in root and "s32[4,16,4]" in root
+    kernels = re.findall(r'custom_call_target="tpu_custom_call".*'
+                         r'op_name="[^"]*/(\w+)/pallas_call"', text)
+    assert sorted(kernels) == ["paged_decode"] + ["paged_decode_window"] * 4
+    assert len(re.findall(r"= bf16\[64,3072\]\S* custom-call\(.*"
+                          r"ragged_dot_tiling", text)) == 12
+    assert held["kv"].shape == (1, 2, 4096, 64, 1024)
+    assert held["kvw"].shape == (4, 2, 1040, 64, 1024)
+    # each pool is made once at its size: the row update, aliased
+    for pool in (held["kv"].shape, held["kvw"].shape):
+        rows = f"{math.prod(pool[:-1])},{pool[-1]}"
+        assert sorted(_made(text, math.prod(pool))) == [
+            ("fusion", rows), ("scatter", rows)], _made(text,
+                                                       math.prod(pool))
+    total, mem = _held_bytes(compiled)
+    assert mem.alias_size_in_bytes >= 2.147e9 + 2.181e9
+    assert mem.temp_size_in_bytes < 0.05e9
+    assert total + runner.staging_bytes + AFMOE_REFERENCE_BYTES < 16.9e9, \
+        total
+
+
+def test_afmoe_chunk_program_runs_the_band_and_carries_no_holder(
+        afmoe_runner, monkeypatch):
+    """The one prefill program: a chunk of 2,048 positions over the
+    staging (the full layer's 26,624 positions and the window layers' ring
+    of 6,144: 0.42e9 bytes, donated and returned), no holder among its
+    operands; the band kernel in the 4 sliding layers and the causal one in
+    the full layer; 8,192 assignments through megablox; the ids (4, 2048,
+    4)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, ecfg, held, weights, on_chip = afmoe_runner
+    staging = jax.tree.map(lambda s: on_chip(s.shape, s.dtype),
+                           runner.staging_spec)
+    assert {k: v.shape for k, v in staging.items()} == {
+        "k": (1, 26624, 1024), "v": (1, 26624, 1024),
+        "kw": (4, 6144, 1024), "vw": (4, 6144, 1024)}
+    assert runner.staging_bytes == 419_430_400
+    lowered = runner._prefill_chunk.lower(
+        None, weights, staging, on_chip((1, 2048), jnp.int32),
+        on_chip((), jnp.int32), on_chip((), jnp.int32))
+    ids = jax.tree.leaves(lowered.out_info)[-1]
+    assert ids.shape == (4, 2048, 4) and ids.dtype == jnp.int32
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    kernels = re.findall(r'custom_call_target="tpu_custom_call".*'
+                         r'op_name="[^"]*/(\w+)/pallas_call"', text)
+    assert kernels.count("band_prefill") == 4
+    assert kernels.count("causal_prefill") == 1
+    assert len(re.findall(r"%gmm[.\d]* = bf16\[8192,3072\]", text)) == 12
+    assert "ragged_dot_tiling" not in text
+    total, mem = _held_bytes(compiled)
+    assert mem.alias_size_in_bytes >= 0.419e9           # the staging
+    assert mem.temp_size_in_bytes < 0.5e9
+    pools = sum(math.prod(h.shape) * 4 for h in held.values())
+    assert total + pools + AFMOE_REFERENCE_BYTES < 16.9e9, total

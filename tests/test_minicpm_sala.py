@@ -386,10 +386,26 @@ def test_the_engine_refuses_a_page_that_is_not_the_selections():
         _engine(prefill_len_buckets=(48, 128))
 
 
-def test_a_chunking_module_without_state_rows_is_refused(monkeypatch):
-    # a chunk hands the next its recurrent state in the store's staging row:
-    # a module without a store has no chunk program (the decode step reads a
-    # selector's cache with or without one)
-    monkeypatch.delattr(sala, "recurrent_state")
-    with pytest.raises(NotImplementedError, match="prefills in chunks"):
-        _engine()
+def test_a_chunking_module_without_state_rows_is_handed_no_holder():
+    # a chunk hands the next its recurrent state in the store's staging row
+    # where the family has a store (this one: the holder goes through the
+    # chunk program, donated); a family that prefills in chunks and carries
+    # K/V alone (afmoe, PR 52) runs the same chunk body with no holder, as
+    # a one-program prefill without a store does, and what its chunks carry
+    # is the staging
+    from ray_tpu.serve.llm.model_runner import _NoHolder
+    eng = _engine()
+    try:
+        assert eng.runner.chunk == CFG.prefill_chunk
+        assert eng.runner._prefill_holder() is eng.cache.pool
+    finally:
+        eng.shutdown()
+    eng = _engine(model="afmoe:tiny")
+    try:
+        assert eng.runner.chunk and eng.runner.state_spec is None
+        assert eng.runner._prefill_holder is _NoHolder
+        eng.start()
+        assert len(eng.generate(_prompt(70),
+                                llm.SamplingParams(max_tokens=3))) == 3
+    finally:
+        eng.shutdown()
