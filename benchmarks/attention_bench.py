@@ -16,6 +16,15 @@ the forward) at the published 197 TFLOP/s, ``mxu_us`` as executed and
 the 128 it occupies.  Writes ``chiprun_out/attention_bench.<kernel>.jsonl``.
 Fails off the chip: a time from a CPU is no device number.
 
+GPT-2 XL's pass is also timed between its projections, a program of its own
+in each of two layouts (``"layout"`` on the line, PR 53): ``split``, the
+present kernels on (B.H, T, 64) operands cut out of a (B, T, 3, E)
+projection, the output and the gradients laid back, XLA's copies round the
+kernel counted (``other_device_ms``); ``pairs``, ``flash_fwd_pairs`` /
+``flash_bwd_pairs`` on the (B, 3, T, E) projection as it stands, two heads a
+128-lane block, 25 heads in 13 blocks.  ``us_a_tile`` there is a tile A HEAD
+(200 x 3 of them, the half-empty thirteenth block's time spread over the 25).
+
 Each line holds the kernel's own device time from a profiler capture (the
 number a cell's ``kernels.custom_call_ms`` / ``mla.attention_ms`` divides),
 what XLA puts round a kernel that stands alone in its program (copies of
@@ -129,8 +138,8 @@ def timed(fn, *args, seconds=0.5, sets=5):
 
 
 def device_seconds(fn, *args, calls=8):
-    """-> (the Mosaic kernel's name in the trace, its seconds, the seconds
-    of every other device operation) a call of ``fn``, from a profiler
+    """-> (the Mosaic kernel's name in the trace, its seconds, every other
+    device operation's name and seconds) a call of ``fn``, from a profiler
     capture of ``calls`` calls: the kernel's own time as a cell's
     ``mla.attention_ms`` / ``kernels.custom_call_ms`` read it, apart from
     what XLA puts round a kernel that stands alone in its program.  The
@@ -145,7 +154,7 @@ def device_seconds(fn, *args, calls=8):
         capture.stop()
         ops = trace.top_ops(trace.load_window(capture), n=1000)
     (name, kernel), *others = ops
-    return name, kernel / calls, sum(s for _, s in others) / calls
+    return name, kernel / calls, [(n, s / calls) for n, s in others]
 
 
 def kernel_call(cell, kernel):
@@ -161,16 +170,80 @@ def kernel_call(cell, kernel):
                                            interpret=False))
 
 
+XL = "gpt2-xl-1558m.train-b8-s1024"
+
+
+def in_the_model(kernel, layout):
+    """-> (the jitted pass, its arguments): GPT-2 XL's attention between
+    its projections, forward with its lse or backward from the output's
+    cotangent (B, T, E) to the projection's, in one of the two layouts."""
+    B, H, T, *_ = CELLS[XL]
+    E = H * fa.HEAD
+    k_qkv, k_g = jax.random.split(jax.random.key(0))
+    g = jax.random.normal(k_g, (B, T, E), jnp.bfloat16)
+    if layout == "pairs":
+        qkv = jax.random.normal(k_qkv, (B, 3, T, E), jnp.bfloat16)
+
+        def fwd(qkv):
+            return fa._pairs_forward(qkv, H, None, False, want_lse=True)
+
+        def bwd(qkv, out, lse, g):
+            return fa._pairs_bwd(H, None, False, (qkv, out, lse), g)[0]
+    else:
+        qkv = jax.random.normal(k_qkv, (B, T, 3, E), jnp.bfloat16)
+
+        def split(qkv):
+            return [qkv[:, :, i].reshape(B, T, H, fa.HEAD) for i in range(3)]
+
+        def fwd(qkv):
+            q, k, v = split(qkv)
+            out, lse = fa._flash_forward_lse((q,), (k,), v, causal=True,
+                                             block_size=None, interpret=False)
+            return out.reshape(B, T, E), lse
+
+        def bwd(qkv, out, lse, g):
+            q, k, v = split(qkv)
+            res = ((q,), (k,), v, out.reshape(B, T, H, fa.HEAD), lse)
+            dq, dk, dv = fa._bwd(True, None, False, res,
+                                 g.reshape(B, T, H, fa.HEAD))
+            return jnp.stack([d.reshape(B, T, E) for d in (dq, dk, dv)], 2)
+    if kernel == "fwd":
+        return jax.jit(fwd), (qkv,)
+    out, lse = jax.jit(fwd)(qkv)
+    return jax.jit(bwd), (qkv, out, lse, g)
+
+
+def layouts_agree(kernel):
+    """The two layouts on the SAME numbers (the split projection's planes
+    transposed): the largest difference between their results beside the
+    largest result, on the chip's own arithmetic."""
+    split, args = in_the_model(kernel, "split")
+    pairs, _ = in_the_model(kernel, "pairs")
+    qkv, planes = args[0], args[0].transpose(0, 2, 1, 3)
+    if kernel == "fwd":         # (out, lse): compare the outputs
+        want, got = split(qkv)[0], pairs(planes)[0]
+    else:                       # the projection's gradient, (B, T, 3, E)
+        g = args[-1]
+        out, lse = in_the_model("fwd", "pairs")[0](planes)   # its own lse
+        want = split(*args)
+        got = pairs(planes, out, lse, g).transpose(0, 2, 1, 3)
+    want, got = want.astype(jnp.float32), got.astype(jnp.float32)
+    return {"cell": XL, "kernel": kernel, "layouts": "pairs against split",
+            "max_abs_difference": float(jnp.abs(got - want).max()),
+            "max_abs": float(jnp.abs(want).max())}
+
+
 def row(cell, kernel, fn, args, **tags):
     """One result line: the kernel's device time by the tile (device
     trace), the host's clock for a whole call beside it."""
     n = tiles(cell)
-    name, kernel_s, other_s = device_seconds(fn, *args)
+    name, kernel_s, others = device_seconds(fn, *args)
     us, padded = kernel_s * 1e6 / n, mxu_us(cell, kernel, True)
     return {"cell": cell, "kernel": kernel, **tags,
             "device": jax.devices()[0].device_kind, "tiles": n,
             "traced_as": name, "kernel_ms": kernel_s * 1e3,
-            "other_device_ms": other_s * 1e3,
+            "other_device_ms": sum(s for _, s in others) * 1e3,
+            "other_ops_ms": {n: round(s * 1e3, 4) for n, s in others[:6]},
             "host_clock_call_ms": timed(fn, *args) * 1e3, "us_a_tile": us,
             "mxu_us": mxu_us(cell, kernel, False), "mxu_us_padded": padded,
             "not_under_the_mxu_us": us - padded}
@@ -217,6 +290,9 @@ def main():
         return by_sequence_length(args)
     rows = [row(cell, args.kernel, kernel_call(cell, args.kernel),
                 operands(cell)) for cell in CELLS]
+    rows += [row(XL, args.kernel, *in_the_model(args.kernel, layout),
+                 layout=layout) for layout in ("split", "pairs")]
+    rows.append(layouts_agree(args.kernel))
     out = Path("chiprun_out")
     out.mkdir(exist_ok=True)
     with open(out / f"attention_bench.{args.kernel}.jsonl", "w") as f:

@@ -16,7 +16,9 @@ Design notes (TPU-first, not a torch translation):
 - bf16 activations / f32 params+optimizer by default: MXU-native.
 - Attention is ``ops.attention.causal_attention``: it chooses the Pallas
   flash kernel or XLA's dense attention from the backend and the sequence
-  length; ``attn_impl`` passes through to it.
+  length; ``attn_impl`` passes through to it.  Where the kernel runs on
+  heads of 64 (every preset here) the block hands it the fused projection
+  whole, heads unsplit (``ops.attention.unsplit_causal_attention``).
 """
 
 from __future__ import annotations
@@ -204,28 +206,41 @@ def _block(x: jax.Array, lp: Params, cfg: GPT2Config,
     H, D = cfg.n_head, cfg.head_dim
     with _scope("ln_1"):
         h = _layer_norm(x, lp["ln_1"]["scale"], lp["ln_1"]["bias"])
-    with _scope("attn_qkv"):
-        qkv = jnp.einsum("bte,eck->btck",
-                         h, _cast(lp["attn_qkv"]["kernel"], cfg))
-        qkv = qkv + _cast(lp["attn_qkv"]["bias"], cfg)
-        # Named so remat_policy="attn" can pin it: re-projecting qkv is
-        # the one matmul the rematerialized backward would otherwise
-        # re-run (the flash kernel's q/k/v residuals flow from here).
-        from jax.ad_checkpoint import checkpoint_name
-        qkv = checkpoint_name(qkv, "attn_qkv")
-        q, k, v = [qkv[:, :, i, :].reshape(B, T, H, D) for i in range(3)]
-    # Pin the attention-region layout (DESIGN.md §4q / ACTIVATION_RULES):
-    # heads shard over tensor, sequence-through-attention over context
-    # (ring CP), per-head features replicated.  No-op without an
-    # ambient mesh; GSPMD otherwise guesses from the qkv matmul.
+    from jax.ad_checkpoint import checkpoint_name
+    from ray_tpu.ops import attention
     from ray_tpu.parallel import mesh as mesh_lib
-    q = mesh_lib.constrain(q, "batch", "seq_attn", "heads", "kv")
-    k = mesh_lib.constrain(k, "batch", "seq_attn", "heads", "kv")
-    v = mesh_lib.constrain(v, "batch", "seq_attn", "heads", "kv")
-    with _scope("attn"):
-        from ray_tpu.ops.attention import causal_attention
-        a = causal_attention(q, k, v, impl=cfg.attn_impl,
-                             context_axis=cfg.context_axis).reshape(B, T, E)
+    # heads of 64 on the flash kernel: q, k, v stay the projection's three
+    # (T, E) planes, which the kernel reads two heads a lane block
+    unsplit = attention.unsplit_heads_run(E, H, T, cfg.attn_impl)
+    with _scope("attn_qkv"):
+        qkv = jnp.einsum("bte,eck->bctk" if unsplit else "bte,eck->btck",
+                         h, _cast(lp["attn_qkv"]["kernel"], cfg))
+        bias = _cast(lp["attn_qkv"]["bias"], cfg)
+        qkv = qkv + (bias[:, None] if unsplit else bias)
+        # Named so remat_policy="attn_qkv" can pin it: re-projecting qkv
+        # is the one matmul the rematerialized backward would otherwise
+        # re-run (the flash kernel's q/k/v residuals flow from here).
+        qkv = checkpoint_name(qkv, "attn_qkv")
+        if not unsplit:
+            q, k, v = [qkv[:, :, i, :].reshape(B, T, H, D) for i in range(3)]
+    if unsplit:
+        with _scope("attn"):
+            a = attention.unsplit_causal_attention(qkv, H)
+        if collect_kv:
+            k, v = [qkv[:, i].reshape(B, T, H, D) for i in (1, 2)]
+    else:
+        # Pin the attention-region layout (DESIGN.md §4q /
+        # ACTIVATION_RULES): heads shard over tensor, sequence-through-
+        # attention over context (ring CP), per-head features replicated.
+        # No-op without an ambient mesh; GSPMD otherwise guesses from the
+        # qkv matmul.
+        q = mesh_lib.constrain(q, "batch", "seq_attn", "heads", "kv")
+        k = mesh_lib.constrain(k, "batch", "seq_attn", "heads", "kv")
+        v = mesh_lib.constrain(v, "batch", "seq_attn", "heads", "kv")
+        with _scope("attn"):
+            a = attention.causal_attention(
+                q, k, v, impl=cfg.attn_impl,
+                context_axis=cfg.context_axis).reshape(B, T, E)
     with _scope("attn_out"):
         a = a @ _cast(lp["attn_out"]["kernel"], cfg) \
             + _cast(lp["attn_out"]["bias"], cfg)

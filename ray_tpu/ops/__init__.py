@@ -2,8 +2,8 @@
 
 The reference framework ships no kernels; these are greenfield TPU-first
 components: causal attention behind one choice (XLA dense or the Pallas
-flash kernel; latent attention hands it its parts unjoined), paged decode
-attention, the two context-parallel
+flash kernel; latent attention hands it its parts unjoined, GPT-2 its
+fused projection with the heads unsplit), paged decode attention, the two context-parallel
 schedules (ring via ppermute, Ulysses via all-to-all), and the
 decomposed collective matmuls that hide model-parallel
 all-gather/reduce-scatter legs behind chunked compute (DESIGN.md §4m).
@@ -11,6 +11,7 @@ all-gather/reduce-scatter legs behind chunked compute (DESIGN.md §4m).
 
 from ray_tpu.ops.attention import (  # noqa: F401
     causal_attention, dense_attention, flash_runs, latent_causal_attention,
+    unsplit_causal_attention, unsplit_heads_run,
 )
 from ray_tpu.ops.collective_matmul import (  # noqa: F401
     all_gather_matmul, matmul_reduce_scatter, ring_scan,
@@ -28,7 +29,8 @@ from ray_tpu.ops.ulysses import (  # noqa: F401
 
 __all__ = [
     "causal_attention", "flash_runs", "dense_attention", "flash_attention",
-    "latent_causal_attention",
+    "latent_causal_attention", "unsplit_causal_attention",
+    "unsplit_heads_run",
     "all_gather_matmul", "matmul_reduce_scatter", "ring_scan",
     "paged_attention_decode",
     "ring_attention", "ring_attention_sharded",

@@ -8,6 +8,10 @@ these are greenfield TPU-first components.
   the ambient mesh), as ``ops/paged_attention.paged_attention_decode``
   does for decode; ``flash_runs`` is the one statement of when the Pallas
   kernel (``ops/flash_attention.py``) runs.
+- ``unsplit_causal_attention``: for a caller that holds q, k and v as ONE
+  fused projection with heads of 64 unsplit (GPT-2): the flash kernels
+  read two heads a 128-lane block in place; ``unsplit_heads_run`` says
+  when, from the widths, ``flash_runs`` and the ambient mesh.
 - ``latent_causal_attention``: the same choice for a caller whose
   queries and keys come in two column groups with ONE rotary key for all
   heads (latent attention): on the kernel the five operands go as they
@@ -110,6 +114,40 @@ def flash_runs(seq_len: int, impl: str = "auto") -> bool:
         return False
     from ray_tpu.ops.flash_attention import pick_block_size
     return seq_len % pick_block_size(seq_len) == 0
+
+
+def unsplit_heads_run(n_embd: int, n_head: int, seq_len: int,
+                      impl: str = "auto") -> bool:
+    """Whether ``unsplit_causal_attention`` runs for a caller that holds
+    q, k and v as ONE fused projection (B, 3, T, E) with its heads unsplit:
+    the flash kernel runs (``flash_runs``), the heads are 64 wide, so two
+    fill a 128-lane block of E, and no ambient mesh splits the heads or the
+    sequence through attention (GSPMD would have to cut inside E).  A head
+    of 128 has no second head in its lanes, and a caller with grouped or
+    shared keys holds three arrays: both call ``causal_attention``."""
+    from ray_tpu.ops.flash_attention import HEAD
+    if n_embd != n_head * HEAD or not flash_runs(seq_len, impl):
+        return False
+    from ray_tpu.parallel import mesh as mesh_lib
+    mesh = mesh_lib.get_ambient_mesh()
+    if mesh is None or mesh.empty:
+        return True
+    for axes in mesh_lib.activation_spec("seq_attn", "heads"):
+        for axis in (axes,) if isinstance(axes, str) else axes or ():
+            if mesh.shape.get(axis, 1) > 1:
+                return False
+    return True
+
+
+def unsplit_causal_attention(qkv: jax.Array, n_head: int) -> jax.Array:
+    """Causal self-attention on a fused projection (B, 3, T, E), the planes
+    q, k, v with ``n_head`` heads of 64 side by side in E, -> (B, T, E),
+    where ``unsplit_heads_run`` says so: the flash kernels read a PAIR of
+    heads a 128-lane block in place and write the output and the
+    projection's gradient the same way, so nothing is re-laid between the
+    projection, the kernel and the output projection."""
+    from ray_tpu.ops.flash_attention import flash_attention_pairs
+    return flash_attention_pairs(qkv, n_head)
 
 
 def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,

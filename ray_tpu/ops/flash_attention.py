@@ -43,6 +43,16 @@ the tile ``benchmarks/attention_bench.py``'s, PERF.md §5):
   built.  ``ops/attention.py`` says which call a model makes.  Every
   kernel's first result is the output or dq's first part, (B·H, T, ·):
   PERF.md's ``mla.attention_ms`` knows the kernels by it.
+- Heads of 64 may stay UNSPLIT (PR 53): ``flash_attention_pairs`` takes
+  GPT-2's fused projection (B, 3, T, E) as it stands and returns (B, T,
+  E), forward and one-pass backward, a grid step owning a PAIR of heads,
+  one 128-lane column block of E.  A (B·H, T, 64) operand fills half of
+  every lane tile and XLA re-lays each one on the way in and out (nine
+  copies a layer of GPT-2 XL's step, 55 of its 813 ms); at 128 lanes
+  there is nothing to re-lay.  Kernels of their own (``flash_fwd_pairs``,
+  ``flash_bwd_pairs``) round the same tiles (``_fwd_tile``,
+  ``_bwd_tile``): a 128-wide head has no second head in its lanes, so the
+  kernels above and what they lower to stay as they are.
 """
 
 from __future__ import annotations
@@ -74,6 +84,30 @@ def _scores(qs, ks):
     return s
 
 
+def _fwd_tile(qs, ks, v, carry, s_scale, diagonal):
+    """One (q-block, k-block) tile of the forward's online softmax on
+    loaded operands: -> the new (acc, m, l).  ``diagonal``: None for a
+    tile strictly under the diagonal, all of it visible, else ``(qi,
+    block_q, j, block_k)``, the tile's place, for the causal mask."""
+    acc, m, l = carry
+    s = _scores(qs, ks) * s_scale
+    if diagonal is not None:
+        qi, block_q, j, block_k = diagonal
+        q_pos = qi * block_q + lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        k_pos = j * block_k + lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+    m_new = jnp.maximum(m, s.max(axis=-1))
+    p = jnp.exp2(s - m_new[:, None])
+    corr = jnp.exp2(m - m_new)
+    l = l * corr + p.sum(axis=-1)
+    acc = acc * corr[:, None] + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return acc, m_new, l
+
+
 def _flash_kernel(*refs, parts: int, block_q: int, block_k: int,
                   seq_len: int, causal: bool, scale: float):
     """refs: the queries' ``parts`` column groups, the keys' (in the same
@@ -90,24 +124,10 @@ def _flash_kernel(*refs, parts: int, block_q: int, block_k: int,
     s_scale = scale * LOG2E
 
     def tile(j, carry, masked):
-        acc, m, l = carry
         ks = [k_ref[0, pl.ds(j * block_k, block_k), :] for k_ref in k_refs]
         v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        s = _scores(qs, ks) * s_scale
-        if masked:
-            q_pos = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = j * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.exp2(s - m_new[:, None])
-        corr = jnp.exp2(m - m_new)
-        l = l * corr + p.sum(axis=-1)
-        acc = acc * corr[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return acc, m_new, l
+        return _fwd_tile(qs, ks, v, carry, s_scale,
+                         (qi, block_q, j, block_k) if masked else None)
 
     acc0 = jnp.zeros((block_q, Dv), jnp.float32)
     m0 = jnp.full((block_q,), NEG_INF)
@@ -128,6 +148,40 @@ def _flash_kernel(*refs, parts: int, block_q: int, block_k: int,
         # lse in base-2 units (m + log2 l); consumers stay in base 2.
         lse = m + jnp.log2(l)                         # (block_q,)
         lse_out[0][0, 0] = lse                        # lse rides the lanes
+
+
+def _bwd_tile(ks, ks_scaled, v, qs, do, lse, delta, carry, dq_accs, rows,
+              s_scale, diagonal):
+    """One (k-block, q-block) tile of the backward, keys-down, on loaded
+    operands: -> the new (dks, dv); dq's share is added to ``dq_accs`` at
+    ``rows``.  ``diagonal``: None, or the tile's place ``(kj, block_k, i,
+    block_q)`` for the causal mask."""
+    dks, dv = carry
+    sT = _scores(ks, qs) * s_scale        # (block_k, block_q)
+    if diagonal is not None:
+        kj, block_k, i, block_q = diagonal
+        k_pos = kj * block_k + lax.broadcasted_iota(
+            jnp.int32, (block_k, block_q), 0)
+        q_pos = i * block_q + lax.broadcasted_iota(
+            jnp.int32, (block_k, block_q), 1)
+        sT = jnp.where(q_pos >= k_pos, sT, NEG_INF)
+    pT = jnp.exp2(sT - lse)
+    dv = dv + jax.lax.dot_general(
+        pT.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dpT = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+    # scale deferred to dk/dq below
+    dsT = (pT * (dpT - delta)).astype(ks[0].dtype)
+    dks = tuple(dk + jax.lax.dot_general(
+        dsT, q, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) for dk, q in zip(dks, qs))
+    for dq_acc, k_scaled in zip(dq_accs, ks_scaled):
+        # the tile's one operand contracted over dimension 0
+        dq_acc[rows, :] = dq_acc[rows, :] + jax.lax.dot_general(
+            dsT, k_scaled, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    return dks, dv
 
 
 def _bwd_kernel(*refs, parts: int, block_q: int, block_k: int, seq_len: int,
@@ -195,36 +249,14 @@ def _bwd_kernel(*refs, parts: int, block_q: int, block_k: int, seq_len: int,
             dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def tile(i, carry, masked):
-        dks, dv = carry
         rows = pl.ds(i * block_q, block_q)
         qs = [q_ref[0, rows, :] for q_ref in q_refs]
         do = do_ref[0, rows, :]
         lse = lse_ref[0, :, rows]             # (1, block_q): the lanes
         delta = delta_ref[0, :, rows]         # they are stored in
-        sT = _scores(ks, qs) * s_scale        # (block_k, block_q)
-        if masked:
-            k_pos = kj * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 0)
-            q_pos = i * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 1)
-            sT = jnp.where(q_pos >= k_pos, sT, NEG_INF)
-        pT = jnp.exp2(sT - lse)
-        dv = dv + jax.lax.dot_general(
-            pT.astype(do.dtype), do, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dpT = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        # scale deferred to dk/dq below
-        dsT = (pT * (dpT - delta)).astype(ks[0].dtype)
-        dks = tuple(dk + jax.lax.dot_general(
-            dsT, q, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) for dk, q in zip(dks, qs))
-        for dq_acc, k_scaled in zip(dq_accs, ks_scaled):
-            # the tile's one operand contracted over dimension 0
-            dq_acc[rows, :] = dq_acc[rows, :] + jax.lax.dot_general(
-                dsT, k_scaled, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        return dks, dv
+        return _bwd_tile(ks, ks_scaled, v, qs, do, lse, delta, carry,
+                         dq_accs, rows, s_scale,
+                         (kj, block_k, i, block_q) if masked else None)
 
     dks0 = tuple(jnp.zeros(k.shape, jnp.float32) for k in ks)
     dv0 = jnp.zeros((block_k, Dv), jnp.float32)
@@ -535,6 +567,240 @@ def _latent_bwd(block_size, interpret, res, g):
 
 
 latent_flash_attention.defvjp(_latent_fwd, _latent_bwd)
+
+
+# ------------------------------------------- heads of 64, two a lane block
+HEAD = 64            # the head width whose pairs fill a 128-lane block
+PAIR = 2 * HEAD
+
+
+def _pair_lanes(rows: int, pair, width: int):
+    """What the lanes of pair ``pair``'s (rows, 128) blocks hold, as masks:
+    (head 0's lanes, head 1's), and the lanes inside the ``width`` columns
+    there are, or None where every block is whole.  An odd head count ends
+    in half a pair: the last block's upper lanes lie past the array, what a
+    kernel reads there is unspecified (NaN bits included), and head 1's
+    mask leaves them out."""
+    lane = lax.broadcasted_iota(jnp.int32, (rows, PAIR), 1)
+    first = lane < HEAD
+    if width % PAIR == 0:
+        return (first, lane >= HEAD), None
+    inside = lane < width - pair * PAIR
+    return (first, (lane >= HEAD) & inside), inside
+
+
+def _lanes_of(mask, x):
+    """``x`` in the lanes of ``mask`` and zero in the others.  A select,
+    never a multiply: 0 x NaN."""
+    return x if mask is None else jnp.where(mask, x, jnp.zeros_like(x))
+
+
+def _flash_pairs_kernel(q_ref, k_ref, v_ref, o_ref, *lse_out, block: int,
+                        width: int, scale: float):
+    """The causal forward for heads of 64 read where the fused projection
+    left them: grid (batch, pair, q-block), every operand a 128-lane
+    column block of a (T, E) plane, two heads side by side.  The heads
+    share the loaded tiles by masks, not by lane shuffles: head h's scores
+    contract all 128 lanes of ``q`` with the other head's zeroed (the MXU
+    spends 128 of depth on a 64-wide head either way), ``p_h . v`` fills
+    128 lanes of which the output takes head h's 64.  The tile itself is
+    ``_fwd_tile``, a head at a time."""
+    pair, qi = pl.program_id(1), pl.program_id(2)
+    heads, inside = _pair_lanes(block, pair, width)
+    q = q_ref[0, 0]
+    qs = [_lanes_of(mask, q) for mask in heads]
+    s_scale = scale * LOG2E
+
+    def tile(j, carry, masked):
+        rows = pl.ds(j * block, block)
+        # lanes past E would meet q's zeros in the contraction: 0 x NaN
+        k = _lanes_of(inside, k_ref[0, 0, rows, :])
+        v = v_ref[0, 0, rows, :]      # its lanes past E reach only o's
+        diagonal = (qi, block, j, block) if masked else None
+        return tuple(_fwd_tile((q_h,), (k,), v, c, s_scale, diagonal)
+                     for q_h, c in zip(qs, carry))
+
+    one = (jnp.zeros((block, PAIR), jnp.float32),
+           jnp.full((block,), NEG_INF), jnp.zeros((block,), jnp.float32))
+    carry = lax.fori_loop(0, qi, lambda j, c: tile(j, c, masked=False),
+                          (one, one))
+    (acc0, m0, l0), (acc1, m1, l1) = tile(qi, carry, masked=True)
+    l0, l1 = jnp.maximum(l0, 1e-30), jnp.maximum(l1, 1e-30)
+    o_ref[0] = jnp.where(heads[0], acc0 / l0[:, None],
+                         acc1 / l1[:, None]).astype(o_ref.dtype)
+    if lse_out:                                       # vjp forward only
+        lse_out[0][0, 0, 0] = m0 + jnp.log2(l0)       # base 2, in the lanes
+        lse_out[0][0, 0, 1] = m1 + jnp.log2(l1)
+
+
+def _flash_pairs_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                            dqkv_ref, dq_acc, delta_acc, *, block: int,
+                            seq_len: int, width: int, scale: float):
+    """The one-pass backward for pairs of heads: grid (batch, pair,
+    k-block), ``_bwd_tile`` keys-down a head at a time on the pair's shared
+    tiles.  The resident k and v are masked a head (so scores and dp
+    contract the head's lanes alone, and dq's product lands in them: the
+    two heads' dq simply add in one float32 scratch); dk and dv come out
+    128 lanes wide a head and a lane select takes each head's own.  The
+    result is ONE (3, T, 128) block of the projection's gradient, resident
+    over the k-blocks: dk and dv rows a k-block, dq flushed at the last.
+
+    delta = sum over a head's lanes of do . o is made here, once a (batch,
+    pair) at the first k-block: with o in the cotangent's own layout it is
+    one small product a q-block, (head's lanes, 128) . (do * o)^T, which
+    leaves both heads' rows in the lanes the tile reads them from; outside
+    it was a pass of its own over do and o and a re-laid (B, T, H) sum."""
+    pair, kj = pl.program_id(1), pl.program_id(2)
+    nq = seq_len // block
+    heads, inside = _pair_lanes(block, pair, width)
+    k, v = k_ref[0, 0], v_ref[0, 0]
+    ks = [_lanes_of(mask, k) for mask in heads]
+    vs = [_lanes_of(mask, v) for mask in heads]
+    ks_scaled = [(k_h.astype(jnp.float32) * scale).astype(k.dtype)
+                 for k_h in ks]
+    s_scale = scale * LOG2E
+
+    @pl.when(kj == 0)
+    def _start():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        # row h of ``of_head`` holds ones in head h's lanes
+        row = lax.broadcasted_iota(jnp.int32, (8, PAIR), 0)
+        lane = lax.broadcasted_iota(jnp.int32, (8, PAIR), 1)
+        of_head = (row == lane // HEAD).astype(jnp.float32)
+
+        def rows_delta(i, _):
+            rows = pl.ds(i * block, block)
+            prod = _lanes_of(inside, do_ref[0, rows, :].astype(jnp.float32)
+                             * o_ref[0, rows, :].astype(jnp.float32))
+            delta_acc[:, rows] = jax.lax.dot_general(
+                of_head, prod, (((1,), (1,)), ((), ())),
+                precision=lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+        lax.fori_loop(0, nq, rows_delta, None)
+
+    def tile(i, carry, masked):
+        rows = pl.ds(i * block, block)
+        q = _lanes_of(inside, q_ref[0, 0, rows, :])
+        do = _lanes_of(inside, do_ref[0, rows, :])
+        diagonal = (kj, block, i, block) if masked else None
+        return tuple(_bwd_tile(
+            (ks[h],), (ks_scaled[h],), vs[h], (q,), do,
+            lse_ref[0, 0, pl.ds(h, 1), rows], delta_acc[pl.ds(h, 1), rows],
+            carry[h], (dq_acc,), rows, s_scale, diagonal) for h in (0, 1))
+
+    zero = ((jnp.zeros((block, PAIR), jnp.float32),),
+            jnp.zeros((block, PAIR), jnp.float32))
+    carry = tile(kj, (zero, zero), masked=True)
+    ((dk0,), dv0), ((dk1,), dv1) = lax.fori_loop(
+        kj + 1, nq, lambda i, c: tile(i, c, masked=False), carry)
+    rows = pl.ds(kj * block, block)
+    dqkv_ref[0, 1, rows, :] = (jnp.where(heads[0], dk0, dk1)
+                               * scale).astype(dqkv_ref.dtype)
+    dqkv_ref[0, 2, rows, :] = jnp.where(heads[0], dv0,
+                                        dv1).astype(dqkv_ref.dtype)
+
+    @pl.when(kj == nq - 1)
+    def _flush_dq():
+        dqkv_ref[0, 0] = dq_acc[...].astype(dqkv_ref.dtype)
+
+
+def _pairs_geometry(qkv, n_head, block_size, interpret):
+    B, three, T, E = qkv.shape
+    if three != 3 or E != n_head * HEAD:
+        raise ValueError(
+            f"flash_attention_pairs takes a fused projection (B, 3, T, "
+            f"n_head * {HEAD}); got {qkv.shape} for {n_head} heads")
+    bs, interpret = _resolve(block_size, T, interpret)
+    return B, -(-n_head // 2), T, E, bs, interpret
+
+
+def _plane(c: int, rows: int, stepped: bool):
+    """BlockSpec of ``rows`` positions of plane ``c`` (q, k, v: 0, 1, 2) of
+    a (B, 3, T, E) projection for a (batch, pair, block) grid: one pair's
+    128 lanes, the rows at the grid's third index (``stepped``) or from
+    position 0 (a whole-sequence operand)."""
+    return pl.BlockSpec((1, 1, rows, PAIR),
+                        lambda b, p, i: (b, c, i if stepped else 0, p))
+
+
+def _pairs_forward(qkv, n_head, block_size, interpret, want_lse):
+    B, P, T, E, bs, interpret = _pairs_geometry(qkv, n_head, block_size,
+                                                interpret)
+    out_specs = [pl.BlockSpec((1, bs, PAIR), lambda b, p, i: (b, i, p))]
+    out_shape = [jax.ShapeDtypeStruct((B, T, E), qkv.dtype)]
+    if want_lse:
+        # compact, a pair's two heads on two sublanes: (B, pairs, 2, T)
+        out_specs.append(
+            pl.BlockSpec((1, 1, 2, bs), lambda b, p, i: (b, p, 0, i)))
+        out_shape.append(jax.ShapeDtypeStruct((B, P, 2, T), jnp.float32))
+    res = pl.pallas_call(
+        functools.partial(_flash_pairs_kernel, block=bs, width=E,
+                          scale=1.0 / math.sqrt(HEAD)),
+        grid=(B, P, T // bs),
+        in_specs=[_plane(0, bs, True), _plane(1, T, False),
+                  _plane(2, T, False)],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        interpret=interpret,
+        name="flash_fwd_pairs",
+        **_compiler_params(2 * T * PAIR * qkv.dtype.itemsize),
+    )(qkv, qkv, qkv)
+    return res if want_lse else (res[0], None)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def flash_attention_pairs(qkv: jax.Array, n_head: int,
+                          block_size: Optional[int] = None,
+                          interpret: Optional[bool] = None) -> jax.Array:
+    """Causal attention on a fused projection with its heads unsplit:
+    ``qkv`` (B, 3, T, E), the planes q, k, v with heads of 64 side by side
+    in E = n_head * 64, -> (B, T, E); differentiable, its gradient one
+    (B, 3, T, E) array.  A grid step owns a PAIR of heads, one 128-lane
+    column block of E, so no (B.H, T, 64) array exists on either side of
+    the kernels: a 64-wide minor dimension fills half of every lane tile
+    and costs a re-laid copy of each operand and result.  An odd head
+    count ends in half a pair, a block half past the array's edge."""
+    out, _ = _pairs_forward(qkv, n_head, block_size, interpret,
+                            want_lse=False)
+    return out
+
+
+def _pairs_fwd(qkv, n_head, block_size, interpret):
+    from jax.ad_checkpoint import checkpoint_name
+    out, lse = _pairs_forward(qkv, n_head, block_size, interpret,
+                              want_lse=True)
+    # the names ``remat_block`` keeps, as ``_forward_residuals`` gives them
+    out = checkpoint_name(out, "flash_attn_out")
+    lse = checkpoint_name(lse, "flash_attn_lse")
+    return out, (qkv, out, lse)
+
+
+def _pairs_bwd(n_head, block_size, interpret, res, g):
+    qkv, out, lse = res
+    B, P, T, E, bs, interpret = _pairs_geometry(qkv, n_head, block_size,
+                                                interpret)
+    from jax.experimental.pallas import tpu as pltpu
+    whole = pl.BlockSpec((1, T, PAIR), lambda b, p, j: (b, 0, p))
+    return (pl.pallas_call(
+        functools.partial(_flash_pairs_bwd_kernel, block=bs, seq_len=T,
+                          width=E, scale=1.0 / math.sqrt(HEAD)),
+        grid=(B, P, T // bs),
+        in_specs=[_plane(0, T, False), _plane(1, bs, True),
+                  _plane(2, bs, True), whole, whole,
+                  pl.BlockSpec((1, 1, 2, T), lambda b, p, j: (b, p, 0, 0))],
+        out_specs=pl.BlockSpec((1, 3, T, PAIR), lambda b, p, j: (b, 0, 0, p)),
+        out_shape=jax.ShapeDtypeStruct(qkv.shape, qkv.dtype),
+        scratch_shapes=[pltpu.VMEM((T, PAIR), jnp.float32),
+                        pltpu.VMEM((8, T), jnp.float32)],
+        interpret=interpret,
+        name="flash_bwd_pairs",
+        # q, o, dO and the three planes of the result stay whole; the
+        # float32 dq scratch is single
+        **_compiler_params(6 * T * PAIR * qkv.dtype.itemsize + T * PAIR * 2),
+    )(qkv, qkv, qkv, out, g.astype(qkv.dtype), lse),)
+
+
+flash_attention_pairs.defvjp(_pairs_fwd, _pairs_bwd)
 
 
 def pick_block_size(T: int) -> int:

@@ -130,6 +130,37 @@ def test_the_op_map_puts_the_flash_kernels_under_their_scope(v5e):
         ("attn/flash_bwd", "bwd"), ("attn/flash_fwd", "fwd")]
 
 
+# ------------------------------------ GPT-2 XL: heads of 64 left unsplit
+def _pairs(qkv):
+    from ray_tpu.ops.flash_attention import flash_attention_pairs
+    return flash_attention_pairs(qkv, qkv.shape[-1] // 64, None, False)
+
+
+def _pairs_loss(qkv):
+    return _pairs(qkv).astype(jnp.float32).sum()
+
+
+@pytest.mark.parametrize("fn,batch,seq_len,heads", [
+    pytest.param(_pairs, 8, 1024, 25, id="xl_forward"),
+    pytest.param(jax.grad(_pairs_loss), 8, 1024, 25,
+                 id="xl_forward_backward"),
+    pytest.param(jax.grad(_pairs_loss), 32, 1024, 12,
+                 id="small_forward_backward"),
+    pytest.param(_pairs, 1, 16, 25, id="xl_prefill_bucket_16"),
+    pytest.param(_pairs, 1, 128, 25, id="xl_prefill_bucket_128"),
+    pytest.param(jax.grad(_pairs_loss), 1, 8192, 25, id="xl_8192_positions"),
+])
+def test_flash_attention_pairs_compiles(v5e, fn, batch, seq_len, heads):
+    """The kernels that read GPT-2's fused projection with its heads
+    unsplit, at the XL cell's training shape, at GPT-2 small's, at the XL
+    serving cell's smallest and commonest prefill buckets and at a length
+    whose whole-sequence blocks need more than Mosaic's default VMEM.  25
+    heads are 12.5 pairs: the thirteenth 128-lane block of E = 1,600 is
+    half past the array, which Mosaic takes as a partial edge block."""
+    text = _compile(fn, v5e, ((batch, 3, seq_len, heads * 64), jnp.bfloat16))
+    assert "flash_fwd_pairs" in text
+
+
 # ------------------------------------------------- OLMoE's training cell
 OLMOE_QKV = ((2, 4096, 16, 128), jnp.bfloat16)      # 16 heads x 128, T 4096
 
@@ -730,9 +761,16 @@ def _operations_and_kernels(lowered_text):
 # contracted over dimension 0: no ``vector.transpose`` of lse or delta in
 # the body).  The kernel's operands, results, grid and name are what they
 # were, and so are the counts: 3,905 operations and 9 kernels, 1,851 and 2.
+# PR 53 replaced GPT-2 XL's on purpose: its block hands the fused projection
+# to ``flash_fwd_pairs`` / ``flash_bwd_pairs`` with the heads unsplit
+# (``"bte,eck->bctk"``, two 64-wide heads a 128-lane block, delta made in
+# the backward kernel), so the slices, reshapes, transposes, sharding
+# constraints and the delta pass round the old kernels are gone: 1,795
+# operations where there were 1,851, still 2 kernels, other bodies.  OLMoE's
+# stands: ``flash_attention`` lowers to what it lowered to.
 PARENT_STEPS = {
     "olmoe-1b-7b.train-b2-s4096": ("479998fc66d84fe8", 3905, 9),
-    "gpt2-xl-1558m.train-b8-s1024": ("c4122fa3fdfb67d5", 1851, 2),
+    "gpt2-xl-1558m.train-b8-s1024": ("bcfab170aaa276dc", 1795, 2),
 }
 
 
@@ -745,6 +783,44 @@ def test_older_training_steps_lower_to_the_operations_and_kernels_they_had(
         prog.jitted_step.lower(state, batch).as_text())
     assert (digest, sum(ops.values()), sum(kernels.values())) == \
         PARENT_STEPS[cell]
+
+
+def test_xl_step_holds_no_split_head_and_no_copy_round_its_kernels(
+        v5e, monkeypatch):
+    """The whole step of ``gpt2-xl-1558m.train-b8-s1024`` (48 layers, 25
+    heads of 64, 8 x 1,024 tokens, ``remat_policy`` ``attn``): the fused
+    projection goes to ``flash_fwd_pairs`` / ``flash_bwd_pairs`` as it
+    stands, so no array anywhere in the step has a 64-wide minor dimension
+    (the parent held ``bf16[8,1024,25,64]`` q, k, v, dO, dq, dk, dv and a
+    kept ``bf16[200,1024,64]`` output, half of every lane tile padding), no
+    ``copy`` stands under ``attn`` or ``attn_qkv`` in either layer scan
+    (the parent: nine a layer) and delta is made in the backward kernel;
+    one forward and one backward kernel, each under its own name; and the
+    temporaries are no more than the parent's 9,499,432,448 B (599491d,
+    this jax).  What the described compile holds in all, 18.8e9 B with the
+    donated state counted twice over, is not asserted: the parent reads
+    18.96e9 there and the cell runs (PERF.md section 7)."""
+    from ray_tpu.util import tracing
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # as the programs run: the op map reads one frame a location
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    prog, state, batch = _train_program("gpt2-xl-1558m.train-b8-s1024", v5e)
+    compiled = prog.jitted_step.lower(state, batch).compile()
+    text = compiled.as_text()
+    for split in ("[8,1024,25,64]", "[8,25,1024,64]", "[200,1024,64]"):
+        assert split not in text
+    ops = tracing.op_map(text)
+    under = {name: e for name, e in ops.items()
+             if e["scope"].split("/")[0] in ("attn", "attn_qkv")}
+    assert len(under) > 20, len(under)
+    assert not [name for name in under if name.startswith("copy.")]
+    kernels = sorted((e["scope"], e["pass"]) for name, e in ops.items()
+                     if name.startswith("tpu_custom_call"))
+    assert kernels == [("attn/flash_bwd_pairs", "bwd"),
+                       ("attn/flash_fwd_pairs", "fwd")]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 9.46e9        # the state, donated
+    assert mem.temp_size_in_bytes <= 9_499_432_448, mem.temp_size_in_bytes
 
 
 def test_olmoe_step_holds_no_more_temporaries_than_its_parent(v5e,

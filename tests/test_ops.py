@@ -227,6 +227,132 @@ def test_flash_backward_equals_dense_gradients(widths, causal, tiles, dtype,
         _allclose(got, want, tol * max(1.0, scale))
 
 
+# ------------------------------- heads of 64 unsplit, two a 128-lane block
+def _split_heads(qkv, heads):
+    """(B, 3, T, E) -> q, k, v (B, T, H, 64), float32."""
+    b, _, t, _ = qkv.shape
+    return [qkv[:, i].astype(jnp.float32).reshape(b, t, heads, 64)
+            for i in range(3)]
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-4),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("tiles", [1, 3])
+@pytest.mark.parametrize("heads", [12, 5])
+def test_unsplit_flash_equals_dense_attention_and_its_gradients(
+        capsys, heads, tiles, dtype, tol):
+    """``flash_attention_pairs`` on a fused projection (B, 3, T, H x 64)
+    against ``dense_attention`` on the split heads, output and vjp.  12
+    heads are six whole pairs; 5 end in half a pair, a block whose upper
+    lanes lie past the array (the interpreter fills them with NaN, the
+    chip with whatever stands there: nothing of them may reach head 4).
+    One tile is the diagonal alone; three run the masked and the unmasked
+    path, accumulate dq over the k-steps and make delta for every q-block
+    at the first.  Under ``remat_block(..., "attn", True)`` the block
+    keeps the two names the policy lists and nothing else."""
+    from jax.ad_checkpoint import print_saved_residuals
+    from ray_tpu.models._common import remat_block
+    from ray_tpu.ops.flash_attention import flash_attention_pairs
+    batch, bs = 2, 32
+    t, e = tiles * bs, heads * 64
+    k_qkv, k_do = jax.random.split(jax.random.key(13))
+    qkv = jax.random.normal(k_qkv, (batch, 3, t, e), jnp.float32) \
+        .astype(dtype)
+    do = jax.random.normal(k_do, (batch, t, e), jnp.float32).astype(dtype)
+
+    def pairs(x):
+        return flash_attention_pairs(x, heads, bs, True)
+
+    def dense(x):
+        return dense_attention(*_split_heads(x, heads)).reshape(batch, t, e)
+    got, vjp = jax.vjp(pairs, qkv)
+    want, want_vjp = jax.vjp(dense, qkv.astype(jnp.float32))
+    assert got.shape == (batch, t, e) and got.dtype == dtype
+    _allclose(got.astype(jnp.float32), want, tol)
+    (dqkv,), (want_dqkv,) = vjp(do), want_vjp(do.astype(jnp.float32))
+    assert dqkv.shape == qkv.shape and dqkv.dtype == dtype
+    scale = 1.0 if dtype == jnp.float32 else float(jnp.abs(want_dqkv).max())
+    _allclose(dqkv.astype(jnp.float32), want_dqkv, tol * max(1.0, scale))
+
+    kept = remat_block(lambda x: pairs(x * 2), "attn", True)
+    print_saved_residuals(lambda x: kept(x).astype(jnp.float32).sum(), qkv)
+    saved = [line for line in capsys.readouterr().out.splitlines()
+             if line.strip() and "from the argument" not in line]
+    assert len(saved) == 2, saved
+    assert any("flash_attn_out" in line and f"[{batch},{t},{e}]" in line
+               for line in saved), saved
+    pair_form = f"f32[{batch},{-(-heads // 2)},2,{t}]"      # lse, compact
+    assert any("flash_attn_lse" in line and pair_form in line
+               for line in saved), saved
+
+
+def _kernels_of(jaxpr_text):
+    return sorted(set(re.findall(r"name=(flash_(?:fwd|bwd)\w*)", jaxpr_text)))
+
+
+@pytest.mark.parametrize("embd,heads,seq_len,impl,backend,unsplit", [
+    (1600, 25, 1024, "auto", "tpu", True),      # GPT-2 XL's step
+    (768, 12, 128, "flash", "cpu", True),       # asked for: interpret mode
+    (1600, 25, 192, "auto", "tpu", False),      # no tile: dense
+    (1600, 25, 1024, "auto", "cpu", False),     # auto off a TPU: dense
+    (1600, 25, 1024, "dense", "tpu", False),
+    (2048, 16, 1024, "auto", "tpu", False),     # heads of 128
+    (64, 4, 128, "flash", "cpu", False),        # heads of 16
+])
+def test_unsplit_heads_run_says_which_kernels_a_gpt2_block_traces(
+        monkeypatch, embd, heads, seq_len, impl, backend, unsplit):
+    """``unsplit_heads_run`` is the one statement of when GPT-2's block
+    hands the projection over whole: heads of 64 and ``flash_runs``.  The
+    traced block then holds ``flash_fwd_pairs`` and no (B, T, H, 64)
+    array; every other width, length and backend traces what it traced
+    (``flash_fwd`` on split heads, or no kernel), and a caller with three
+    arrays reaches the old kernel whatever its width."""
+    from ray_tpu.models import gpt2
+    from ray_tpu.ops.attention import unsplit_heads_run
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert unsplit_heads_run(embd, heads, seq_len, impl) is unsplit
+    cfg = gpt2.GPT2Config(n_embd=embd, n_head=heads, n_layer=1,
+                          attn_impl=impl, n_positions=seq_len)
+    params = jax.eval_shape(lambda k: gpt2.init_params(k, cfg),
+                            jax.random.key(0))
+    layer = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
+                         params["blocks"])
+    x = jax.ShapeDtypeStruct((1, seq_len, embd), cfg.dtype)
+    text = str(jax.make_jaxpr(
+        lambda x, lp: gpt2._block(x, lp, cfg, collect_kv=True))(x, layer))
+    flash = flash_runs(seq_len, impl)
+    assert _kernels_of(text) == (["flash_fwd_pairs"] if unsplit else
+                                 ["flash_fwd"] if flash else [])
+    split = f"[1,{seq_len},{heads},{embd // heads}]"
+    # the prefill's K and V are the only split arrays of an unsplit block
+    assert text.count(split) == (2 if unsplit else text.count(split))
+    q = jax.ShapeDtypeStruct((1, seq_len, heads, embd // heads), jnp.bfloat16)
+    three = str(jax.make_jaxpr(
+        lambda q, k, v: causal_attention(q, k, v, impl=impl))(q, q, q))
+    assert _kernels_of(three) == (["flash_fwd"] if flash else [])
+
+
+def test_unsplit_heads_stay_split_where_a_mesh_splits_them(monkeypatch):
+    """An ambient mesh that splits the heads (tensor) or the sequence
+    through attention (context) keeps today's block, constraints
+    included; one that splits neither hands the projection over whole."""
+    from ray_tpu.ops.attention import unsplit_heads_run
+    from ray_tpu.parallel import mesh as mesh_lib
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    devices = np.array(jax.devices()[:1])
+    for axes, want in ((("data", "fsdp"), True), (("tensor",), True)):
+        with mesh_lib.ambient_mesh(Mesh(devices.reshape((1,) * len(axes)),
+                                        axes)):
+            assert unsplit_heads_run(1600, 25, 1024) is want
+    if len(jax.devices()) >= 2:
+        two = np.array(jax.devices()[:2])
+        for axis, want in (("tensor", False), ("context", False),
+                           ("data", True)):
+            with mesh_lib.ambient_mesh(Mesh(two, (axis,))):
+                assert unsplit_heads_run(1600, 25, 1024) is want
+
+
 # ------------------------------------------- latent attention's five operands
 # (nope, rope, Dv, T, tile): a small one, and the Kanana cell's widths
 LATENT = {"small": (16, 8, 16, 64, 16), "kanana": (128, 64, 128, 256, 128)}
