@@ -64,6 +64,14 @@ _DONE = "__llm_done__"
 _ERR = "__llm_err__"
 
 
+def _new_seq_id() -> str:
+    """Twelve characters that no reader takes for a number: a capture
+    hands a span's attribute back as an int or a float where its text
+    parses as one (``000123456789``, ``12e345678901``), and the spans name
+    a sequence by this id."""
+    return "s" + uuid.uuid4().hex[:11]
+
+
 def _sampled_rows(samplings) -> List[int]:
     """The rows of a step whose logits the host needs: those whose
     request is not greedy (``Chosen.token`` asks the same property)."""
@@ -295,7 +303,7 @@ class LLMEngine:
     def submit(self, prompt: List[int],
                sampling: Optional[SamplingParams] = None) -> RequestStream:
         sampling = sampling or SamplingParams()
-        seq_id = uuid.uuid4().hex[:12]
+        seq_id = _new_seq_id()
         with hot_span("llm.submit", self.span_s, seq=seq_id):
             seq = Sequence(seq_id=seq_id, prompt=[int(t) for t in prompt],
                            sampling=sampling)
@@ -330,9 +338,13 @@ class LLMEngine:
 
     # ------------------------------------------------------------ engine loop
     def _loop(self) -> None:
+        # every wait of this thread lies in an ``llm.idle`` span that says
+        # why it waits, so between two ``llm.step`` spans nothing of the
+        # loop's is dark (PERF.md section 3)
         while not self._stop.is_set():
             if not self._work_pending():
-                self._wake.wait(timeout=0.2)
+                with hot_span("llm.idle", self.span_s, cause="empty"):
+                    self._wake.wait(timeout=0.2)
                 self._wake.clear()
                 continue
             try:
@@ -340,11 +352,13 @@ class LLMEngine:
                     # work exists but nothing runnable this iteration
                     # (e.g. the waiting head cannot fit in the free
                     # list yet): don't busy-spin the core
-                    self._wake.wait(timeout=0.02)
+                    with hot_span("llm.idle", self.span_s, cause="blocked"):
+                        self._wake.wait(timeout=0.02)
                     self._wake.clear()
             except Exception:  # noqa: BLE001 - engine must survive a step
                 logger.exception("engine step failed")
-                time.sleep(0.05)
+                with hot_span("llm.idle", self.span_s, cause="error"):
+                    time.sleep(0.05)
 
     def _work_pending(self) -> bool:
         with self._lock:
@@ -440,10 +454,17 @@ class LLMEngine:
             tracing.emit_span("llm.prefill", seq.trace, t0, span.dur,
                               cat="llm", seq_id=seq.seq_id,
                               tokens=len(seq.prompt), model=self.cfg.model)
-        self.sched.start_running(seq)
-        self._emit(seq, tok)
+        self._first_token(seq, tok)
         self._count_tokens(len(seq.prompt), phase="prefill")
-        self._maybe_finish(seq)
+
+    def _first_token(self, seq: Sequence, tok: int) -> None:
+        """``seq`` joins the running ones and its first token goes on its
+        stream: there at the END of this ``llm.prefill.commit``, as a later
+        one is at the end of the ``llm.decode.commit`` that names ``seq``."""
+        with hot_span("llm.prefill.commit", self.span_s, seq=seq.seq_id):
+            self.sched.start_running(seq)
+            self._emit(seq, tok)
+            self._maybe_finish(seq)
 
     def _do_prefill_chunk(self, seq: Sequence) -> None:
         """The next chunk of ``seq``'s prompt (a model that prefills in
@@ -662,13 +683,10 @@ class LLMEngine:
             # what the decode attention has to read against what the
             # compiled step's block tables can name (padded rows and
             # columns past a context included)
-            bs = self.cfg.block_size
-            blocks = int((-(-lens // bs)).sum())
-            span.set(blocks=blocks,
-                     kv_lane_pad_bytes=self.cache.lane_pad_bytes,
-                     kv_layers=self.cache.kv_layers,
+            span.set(kv_layers=self.cache.kv_layers,
                      state_layers=self.cache.state_layers)
-            self.attn_blocks_read += blocks
+            self.attn_blocks_read += int(
+                (-(-lens // self.cfg.block_size)).sum())
             self.attn_blocks_table += maxb * _bucket(
                 len(batch), self.cfg.decode_batch_buckets)
             if self.cache.state_rows:
@@ -750,21 +768,26 @@ class LLMEngine:
             self.window_blocks_unwindowed += reads["window_blocks_unwindowed"]
             if GLOBAL_CONFIG.metrics_enabled:
                 self._publish_window_blocks()
-        discarded = 0
-        with hot_span("llm.decode.commit", self.span_s):
+        # the span says whose tokens it put on their streams, and of which
+        # step: a token is on its stream at this span's END
+        with hot_span("llm.decode.commit", self.span_s,
+                      step=flight.step.step) as commit:
+            emitted = []
             for i, s in enumerate(flight.batch):
                 if s.state != RUNNING:
                     # ended by its stop token at the commit before this
                     # one, when this step was enqueued already: the row
                     # is nobody's, and free_seq took its slot back
-                    discarded += 1
                     continue
                 self._emit(s, chosen.token(i, s.sampling, step=s.generated))
                 self._maybe_finish(s)
+                emitted.append(s.seq_id)
+            discarded = len(flight.batch) - len(emitted)
             with self._lock:
                 self._count_chosen_locked(chosen, discarded)
+            commit.set(tokens=len(emitted), seqs="|".join(emitted))
         self.decode_rows_discarded += discarded
-        self._count_tokens(len(flight.batch) - discarded, phase="decode")
+        self._count_tokens(len(emitted), phase="decode")
 
     def _publish_window_blocks(self) -> None:
         """The cache's three counts of window blocks to the catalog: held
@@ -945,7 +968,7 @@ class LLMEngine:
                 self._pull_pool = DataPlanePool()
             pool = self._pull_pool
         prompt = [int(t) for t in manifest["tokens"]]
-        seq = Sequence(seq_id=uuid.uuid4().hex[:12], prompt=prompt,
+        seq = Sequence(seq_id=_new_seq_id(), prompt=prompt,
                        sampling=sampling)
         # link the decode-side tree to the prefill-side one: the
         # manifest's span (prefill_remote on the other engine) parents
@@ -1057,9 +1080,7 @@ class LLMEngine:
             while self._attached and len(items) < room:
                 items.append(self._attached.popleft())
         for seq, first in items:
-            self.sched.start_running(seq)
-            self._emit(seq, int(first))
-            self._maybe_finish(seq)
+            self._first_token(seq, int(first))
 
     def max_num_seqs_room(self) -> int:
         return self.cfg.max_num_seqs - len(self.sched.running)

@@ -409,8 +409,9 @@ class hot_span:
     entered from several threads at once may lose a count.
 
     Attribute values are str/int/float; a comma cuts a value short in
-    the profiler's encoding, so join lists with ``|``.  ``set()`` adds
-    attributes known only inside the span."""
+    the profiler's encoding, so join lists with ``|``; a string that
+    parses as a number is read back as that number, and an empty one not
+    at all.  ``set()`` adds attributes known only inside the span."""
 
     __slots__ = ("name", "totals", "dur", "_t0", "_ann")
 

@@ -21,7 +21,8 @@ from ray_tpu.util import tracing
 LOOP_SPANS = [
     "llm.step", "llm.step.admit", "llm.step.plan", "llm.step.publish",
     "llm.prefill", "llm.prefill.dispatch", "llm.prefill.pull",
-    "llm.prefill.scatter", "llm.decode", "llm.decode.slots",
+    "llm.prefill.scatter", "llm.prefill.commit", "llm.decode",
+    "llm.decode.slots",
     "llm.decode.tables", "llm.decode.dispatch", "llm.decode.pull",
     "llm.decode.commit", "llm.decode.drain", "llm.compile", "llm.preempt"]
 ALL_SPANS = LOOP_SPANS + ["llm.submit"]
@@ -83,7 +84,6 @@ def captured(tmp_path_factory):
     """One engine run under a jax.profiler capture on the CPU backend:
     (events by thread line, submitted ids, stats before, stats after)."""
     import jax
-    from jax.profiler import ProfileData
 
     out = str(tmp_path_factory.mktemp("capture"))
     eng = LLMEngine(small_pool_cfg())
@@ -97,6 +97,13 @@ def captured(tmp_path_factory):
         jax.profiler.stop_trace()
     finally:
         eng.shutdown()
+    return _llm_lines(out), ids, before, after
+
+
+def _llm_lines(out):
+    """The ``llm.*`` events of the capture under ``out``, by thread line:
+    (name, start_ns, end_ns, attributes), sorted by start."""
+    from jax.profiler import ProfileData
     path = sorted(glob.glob(out + "/plugins/profile/*/*.xplane.pb"))[-1]
     lines = []
     for plane in ProfileData.from_file(path).planes:
@@ -108,7 +115,7 @@ def captured(tmp_path_factory):
                    for e in ln.events if e.name.startswith("llm.")]
             if evs:
                 lines.append(sorted(evs, key=lambda e: e[1]))
-    return lines, ids, before, after
+    return lines
 
 
 def _loop_line(lines):
@@ -208,20 +215,23 @@ def test_spans_of_one_request_share_its_id(captured):
             assert e[3]["seq"] in ids and e[3]["ctx"] > 0
 
 
-def test_decode_spans_carry_the_blocks_their_contexts_hold(captured):
+def test_stats_carry_the_blocks_the_decode_steps_contexts_hold(captured):
+    """``stats()["attn_blocks_read"]`` and no attribute of ``llm.decode``
+    (PR 54: no metric read the span's ``blocks``): a step reads a block or
+    more a row, and a scripted run's exact count is held below."""
     lines, _, before, after = captured
-    blocks = [int(e[3]["blocks"]) for e in _loop_line(lines)
-              if e[0] == "llm.decode"]
-    assert len(blocks) == after["decode_steps"] - before["decode_steps"]
-    assert all(b > 0 for b in blocks)
-    assert sum(blocks) == \
-        after["attn_blocks_read"] - before["attn_blocks_read"]
+    decodes = [e[3] for e in _loop_line(lines) if e[0] == "llm.decode"]
+    assert len(decodes) == after["decode_steps"] - before["decode_steps"]
+    assert not any("blocks" in d for d in decodes)
+    assert after["attn_blocks_read"] - before["attn_blocks_read"] >= \
+        sum(int(d["batch"]) for d in decodes) > 0
 
 
-def test_decode_spans_and_stats_carry_the_pools_lane_padding(captured):
+def test_stats_carry_the_pools_lane_padding(captured):
     """What the pool's device format costs in memory, beside
     ``param_bytes``: N x L x 2 x bs x (F - KV x D) x 4 bytes of lanes
-    that pad a position's heads up to whole 128-lane tiles."""
+    that pad a position's heads up to whole 128-lane tiles.  A constant of
+    the engine: in ``stats()``, and since PR 54 on no span."""
     from ray_tpu.serve.llm.config import resolve_model
     lines, _, before, after = captured
     cfg = small_pool_cfg()
@@ -231,8 +241,7 @@ def test_decode_spans_and_stats_carry_the_pools_lane_padding(captured):
         * (-used % 128) * 4
     assert want > 0 and after["param_bytes"] > 0
     assert before["kv_lane_pad_bytes"] == after["kv_lane_pad_bytes"] == want
-    assert {int(e[3]["kv_lane_pad_bytes"]) for e in _loop_line(lines)
-            if e[0] == "llm.decode"} == {want}
+    assert not any("kv_lane_pad_bytes" in e[3] for e in _loop_line(lines))
 
 
 def test_pull_spans_carry_the_bytes_that_crossed(captured):
@@ -250,6 +259,221 @@ def test_pull_spans_carry_the_bytes_that_crossed(captured):
     assert after["logits_host_bytes"] == before["logits_host_bytes"] == 0
     assert after["sampled_on_host"] == 0
     assert after["sampled_on_device"] == after["tokens_out"]
+
+
+# ------------------------------------- the loop's line and the token stamps
+IDLE_CAUSES = ("empty", "blocked", "error")
+DRAIN_CAUSES = ("sampled", "pressure", "admit", "tail")
+
+
+def _wait_for(what, limit_s=10.0):
+    import time
+
+    from conftest import time_scale
+    deadline = time.monotonic() + limit_s * time_scale()
+    while not what():
+        assert time.monotonic() < deadline, "the engine's loop did not get there"
+        time.sleep(0.005)
+
+
+@pytest.fixture(scope="module")
+def loop_run(tmp_path_factory):
+    """The engine's own loop thread under a capture, through every wait it
+    has and every way a token reaches a stream: (the loop's line, each
+    stream's id -> tokens, stats before, stats after).
+
+    GPT-2's tiny preset repeats its prompt's last token for ever, so the
+    position embedding is made louder (as tests/test_serve_llm.py does):
+    a stop token can then hit in the middle of a run."""
+    import jax
+
+    from ray_tpu.serve.llm.config import resolve_model
+
+    cfg = small_pool_cfg()
+    mod, mcfg = resolve_model(cfg)
+    params = mod.init_params(jax.random.key(cfg.seed), mcfg)
+    eng = LLMEngine(cfg, params={**params, "wpe": params["wpe"] * 10})
+    out = str(tmp_path_factory.mktemp("loop_capture"))
+    prompt = list(range(1, 12))
+    tokens = {}
+
+    def finish(streams):
+        for s in streams:
+            tokens[s.seq_id] = s.tokens()
+
+    try:
+        solo = eng.generate(prompt, SamplingParams(max_tokens=20))
+        stop_at = max(i for i in range(1, 19) if solo[i] not in solo[:i])
+        before = _settled_stats(eng)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(out, profiler_options=options)
+        idles = lambda: eng.stats()["span_s"]["llm.idle"][0]  # noqa: E731
+        # blocked: something else holds the pool, the head cannot fit
+        eng.cache.alloc_seq("hog", 8 * cfg.block_size)
+        n = idles()
+        first = eng.submit(prompt, SamplingParams(max_tokens=4))
+        _wait_for(lambda: idles() >= n + 3)
+        eng.cache.free_seq("hog")
+        finish([first])
+        # four that outgrow the pool: preemptions, drains under pressure
+        # and at the tail
+        finish([eng.submit(list(range(1, 12 + i)),
+                           SamplingParams(max_tokens=20)) for i in range(4)])
+        # a request that arrives while a decode step is in flight
+        decode, late = eng.runner.decode, []
+
+        def joined(*args, **kwargs):
+            if not late:
+                late.append(eng.submit(prompt, SamplingParams(max_tokens=4)))
+            return decode(*args, **kwargs)
+        eng.runner.decode = joined
+        finish([eng.submit(prompt, SamplingParams(max_tokens=8))])
+        eng.runner.decode = decode
+        finish(late)
+        # empty: nothing pending until the next request
+        n = idles()
+        _wait_for(lambda: idles() >= n + 1)
+        # a row that samples beside a greedy one
+        finish([eng.submit(prompt, SamplingParams(max_tokens=6,
+                                                  temperature=0.8, seed=3)),
+                eng.submit(prompt, SamplingParams(max_tokens=6))])
+        # a stop token that hits at a decode step's commit, its row in the
+        # step enqueued behind: discarded
+        finish([eng.submit(prompt, SamplingParams(max_tokens=20,
+                                                  stop_token=solo[stop_at])),
+                eng.submit(list(range(1, 14)), SamplingParams(max_tokens=24))])
+        # error: one step raises
+        step = eng.step
+
+        def failing():
+            eng.step = step
+            raise RuntimeError("a step fails, on purpose")
+        eng.step = failing
+        finish([eng.submit(prompt, SamplingParams(max_tokens=3))])
+        after = _settled_stats(eng)
+        n = idles()
+        _wait_for(lambda: idles() >= n + 1)      # the last wait closes
+        jax.profiler.stop_trace()
+    finally:
+        eng.shutdown()
+    assert len(tokens[list(tokens)[-3]]) == stop_at + 1 < 20
+    return _loop_line(_llm_lines(out)), tokens, before, after
+
+
+def _named(loop, *names):
+    return [e for e in loop if e[0] in names]
+
+
+def test_loop_line_is_steps_and_idles_without_overlap(loop_run):
+    loop = loop_run[0]
+    line = _named(loop, "llm.step", "llm.idle")
+    assert {e[0] for e in line} == {"llm.step", "llm.idle"}
+    for a, b in zip(line, line[1:]):
+        assert a[2] <= b[1], (a, b)
+    # every other span of the loop's thread lies inside a step
+    steps = _named(loop, "llm.step")
+    for e in loop:
+        if e[0] not in ("llm.step", "llm.idle"):
+            assert any(s[1] <= e[1] and e[2] <= s[2] for s in steps), e
+
+
+@pytest.mark.parametrize("cause", IDLE_CAUSES)
+def test_idle_spans_say_why_the_loop_waited(loop_run, cause):
+    loop = loop_run[0]
+    causes = [e[3]["cause"] for e in _named(loop, "llm.idle")]
+    assert set(causes) <= set(IDLE_CAUSES) and cause in causes
+    waits = [e[2] - e[1] for e in _named(loop, "llm.idle")
+             if e[3]["cause"] == cause]
+    # a wait ends at its timeout or at a wake, never after
+    limit_ns = {"empty": 0.2, "blocked": 0.02, "error": 0.05}[cause] * 1e9
+    assert 0 < min(waits) and sorted(waits)[len(waits) // 2] < 3 * limit_ns
+    if cause == "error":
+        assert len(waits) == 1 and waits[0] >= limit_ns
+
+
+def test_commits_name_every_token_of_every_stream(loop_run):
+    loop, tokens, before, after = loop_run
+    got = {sid: 0 for sid in tokens}
+    for e in _named(loop, "llm.prefill.commit"):
+        got[e[3]["seq"]] += 1
+    decode_tokens = 0
+    for e in _named(loop, "llm.decode.commit"):
+        # (a commit whose rows were all discarded names nobody, and a
+        # capture drops an empty attribute)
+        members = [m for m in e[3].get("seqs", "").split("|") if m]
+        assert len(members) == int(e[3]["tokens"]) == len(set(members))
+        decode_tokens += len(members)
+        for sid in members:
+            got[sid] += 1
+    assert got == {sid: len(toks) for sid, toks in tokens.items()}
+    firsts = len(_named(loop, "llm.prefill.commit"))
+    assert firsts + decode_tokens == \
+        after["tokens_out"] - before["tokens_out"] == sum(got.values())
+    # a preempted sequence is prefilled again and gets a first token again
+    assert firsts == len(tokens) + \
+        after["preemptions"] - before["preemptions"] > len(tokens)
+
+
+def test_a_commit_names_the_step_it_read_and_not_the_rows_it_discarded(
+        loop_run):
+    loop, _, before, after = loop_run
+    batch = {int(e[3]["step"]): e[3] for e in _named(loop, "llm.decode")}
+    commits = _named(loop, "llm.decode.commit")
+    assert sorted(int(e[3]["step"]) for e in commits) == sorted(batch)
+    short = 0
+    for e in commits:
+        # the pull before it on the line is of the same step
+        pull = [p for p in _named(loop, "llm.decode.pull") if p[2] <= e[1]][-1]
+        assert int(pull[3]["step"]) == int(e[3]["step"])
+        enqueued = batch[int(e[3]["step"])]
+        assert set(e[3].get("seqs", "").split("|")) - {""} <= \
+            set(enqueued["seqs"].split("|"))
+        short += int(enqueued["batch"]) - int(e[3]["tokens"])
+    assert short == after["decode_rows_discarded"] \
+        - before["decode_rows_discarded"] >= 1
+
+
+@pytest.mark.parametrize("cause", DRAIN_CAUSES)
+def test_commits_inside_a_drain_of_each_cause_are_counted_too(loop_run,
+                                                              cause):
+    loop, _, before, after = loop_run
+    drains = [e for e in _named(loop, "llm.decode.drain")
+              if e[3]["cause"] == cause]
+    assert len(drains) == after["decode_drains"][cause] \
+        - before["decode_drains"][cause] > 0
+    for d in drains:
+        held = [e for e in _named(loop, "llm.decode.commit")
+                if d[1] <= e[1] and e[2] <= d[2]]
+        assert len(held) == 1 and "tokens" in held[0][3]
+
+
+def test_a_first_token_is_committed_after_its_prefill_in_the_same_step(
+        loop_run):
+    loop = loop_run[0]
+    prefills = _named(loop, "llm.prefill")
+    for e in _named(loop, "llm.prefill.commit"):
+        mine = [p for p in prefills if p[3]["seq"] == e[3]["seq"]
+                and p[2] <= e[1]]
+        step = next(s for s in _named(loop, "llm.step")
+                    if s[1] <= e[1] and e[2] <= s[2])
+        assert mine and step[1] <= mine[-1][1]
+
+
+def test_a_sequences_id_is_never_read_back_as_a_number(loop_run):
+    """A capture hands an attribute back as an int or a float where its
+    text parses as one (``000123456789``, ``12e345678901``): the spans
+    name a sequence by an id that never does."""
+    from ray_tpu.serve.llm.engine import _new_seq_id
+    ids = {_new_seq_id() for _ in range(2000)}
+    assert len(ids) == 2000
+    for sid in ids:
+        assert len(sid) == 12 and sid.isalnum()
+        with pytest.raises(ValueError):
+            float(sid)
+    loop, tokens = loop_run[0], loop_run[1]
+    named = {e[3]["seq"] for e in _named(loop, "llm.prefill.commit")}
+    assert named == set(tokens) and all(isinstance(s, str) for s in named)
 
 
 # -------------------------------------------------------- with no capture
