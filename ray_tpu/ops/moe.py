@@ -37,7 +37,10 @@ layer promises its indices (``_take_rows``) and XLA neither fills nor
 selects over the gathered rows.  The assignments are numbered slot by
 slot, so the k slots are the leading axis of the rows gathered back and
 the sum over them re-tiles nothing.  The counts are a compare of the ids
-with the E experts, summed; nothing of the layer is a scatter.
+with the E experts, summed; nothing of the layer is a scatter.  A layer
+that holds a share of the experts sorts its own experts' rows to the
+front and carries to the experts and back only those: two Pallas kernels
+whose work ends at ``held_rows`` (``spread_held_rows``, ``sum_held_slots``).
 """
 
 from __future__ import annotations
@@ -48,6 +51,7 @@ from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 from jax.sharding import PartitionSpec as P
 
 
@@ -166,59 +170,289 @@ def _take_rows(x, index):
     return x.at[index].get(mode="promise_in_bounds")
 
 
-def _spread_rows(x, order):
-    """(N, d) -> (N k, d): row r is token ``order[r] % N``."""
-    return _take_rows(x, order % x.shape[0])
+def _spread_rows(x, order, held_rows=None):
+    """(N, d) -> (N k, d): row r is token ``order[r] % N``.  With
+    ``held_rows`` (a layer that holds a share of the experts on the
+    kernels' path, ``_walks_held_rows``) only the rows before it are
+    made: the others are not written (``spread_held_rows``)."""
+    token = order % x.shape[0]
+    if held_rows is None:
+        return _take_rows(x, token)
+    return spread_held_rows(x, token, held_rows,
+                            interpret=jax.default_backend() != "tpu")
 
 
-def _sum_slots(rows, inverse, k):
+def _sum_slots(rows, inverse, k, held_rows=None):
     """(N k, d) rows in expert order -> (N, d): each token the float32
     sum of its k rows.  ``inverse`` puts them back as the assignments are
     numbered, slot by slot (k runs of N rows), so the k slots are the
     gathered rows' leading axis: splitting it copies nothing, where a k
     in the second-minor dimension of a tiled layout is a copy of all N k
-    rows."""
-    back = _take_rows(rows, inverse).reshape(k, -1, rows.shape[-1])
-    return back.sum(0, dtype=jnp.float32).astype(rows.dtype)
+    rows.  With ``held_rows`` only the rows before it are read and summed,
+    in the same order (``sum_held_slots``): the others are zero where they
+    were written at all."""
+    if held_rows is None:
+        back = _take_rows(rows, inverse).reshape(k, -1, rows.shape[-1])
+        return back.sum(0, dtype=jnp.float32).astype(rows.dtype)
+    n = rows.shape[0] // k
+    tile = _token_tile(n)
+    at, row, starts = _held_by_token(inverse, held_rows, n, k, tile)
+    return sum_held_slots(rows, at, row, starts, held_rows, k=k, tile=tile,
+                          interpret=jax.default_backend() != "tpu")
+
+
+# ------------------------------------------- the rows that meet a held expert
+# A layer that holds H of E experts sorts the rows of its own experts to the
+# front, and ``held_rows = group_sizes[:H].sum()`` of the N k are all that
+# the grouped matmuls visit.  The two kernels below are the row gathers with
+# a work list that ends there: what they cost follows the rows visited, and a
+# router skewed wholly onto the held experts makes them walk all N k (slower,
+# never wrong).  A row of a tiled array cannot be copied alone: a slice of
+# an HBM array starts and ends on a tile of 8 rows, and two 16-bit rows
+# share their 32-bit words.  So the way out holds its (N, d) source in VMEM
+# whole and picks a row out of it as 32-bit words, and the way back, whose
+# source is the N k rows, fetches a row as the aligned group of
+# ``_ROW_GROUP`` rows it lies in, one contiguous copy of 8 x d values, with
+# ``_IN_FLIGHT`` such copies under way, and picks it out of the group.
+_ROW_GROUP = 8
+_IN_FLIGHT = 32
+_TOKEN_TILES = (256, 128, 64, 32, 16)
+_SOURCE_BYTES = 64 * 2 ** 20    # the (N, d) source the way out holds in VMEM
+_VMEM_DEFAULT = 16 * 2 ** 20    # Mosaic's scoped limit where none is set
+
+
+def _token_tile(n: int) -> Optional[int]:
+    """Tokens a grid step of ``sum_held_slots``: the largest that divides."""
+    return next((t for t in _TOKEN_TILES if n % t == 0), None)
+
+
+def _walks_held_rows(n: int, k: int, d: int, f: int, held: int,
+                     num_experts: int, dtype) -> bool:
+    """Whether a layer's row passes stop at ``held_rows``: it holds a share
+    of the experts (else no row can be skipped, and XLA's gathers from
+    VMEM beat any copy by row), its rows take the megablox path, whose
+    kernels mask the rows behind ``held_rows`` themselves
+    (``grouped_matmul``), a value is float32 or its high half, and the
+    tokens fit the VMEM the way out holds them in."""
+    return (held < num_experts and jax.default_backend() == "tpu"
+            and gmm_tiling(n * k, d, f) is not None
+            and _token_tile(n) is not None
+            and dtype in (jnp.bfloat16, jnp.float32)
+            and n * d * jnp.dtype(dtype).itemsize <= _SOURCE_BYTES)
+
+
+def _walk_params(held_bytes: int):
+    """``pallas_call``'s compiler parameters for a kernel that holds
+    ``held_bytes`` in VMEM: Mosaic's default scoped limit while they fit
+    it with room, else a limit that holds them."""
+    from jax.experimental.pallas import tpu as pltpu
+    limit = {} if held_bytes + 4 * 2 ** 20 <= _VMEM_DEFAULT else {
+        "vmem_limit_bytes": min(held_bytes + 8 * 2 ** 20, 100 * 2 ** 20)}
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary",), **limit)
+
+
+def _picked_row(words, row, pack):
+    """Row ``row`` of an array read as (rows / pack, d) uint32 ``words``,
+    (1, d) uint32: the value itself (32 bits) or the value in the low
+    half (16 bits: two rows share a word, the even row the low half)."""
+    word = words[pl.ds(row >> (pack // 2), 1), :]
+    if pack == 1:
+        return word
+    return (word >> (16 * (row & 1)).astype(jnp.uint32)) & jnp.uint32(0xFFFF)
+
+
+def _spread_kernel(held_ref, token_ref, x_ref, out_ref, stage, *, pack):
+    """Grid step i: rows ``i tile .. (i + 1) tile`` of the result, as far
+    as ``held_rows`` reaches (to a whole word), each picked out of the
+    source ``x_ref``, which VMEM holds whole.  ``stage`` holds the tile as
+    words; steps behind the last held tile do nothing, and their block is
+    that tile's (the index map stays), so nothing of theirs is written."""
+    from jax.experimental.pallas import tpu as pltpu
+    tile = out_ref.shape[0]
+    first = pl.program_id(0) * tile
+    end = jnp.minimum((held_ref[0] + pack - 1) // pack * pack,
+                      token_ref.shape[0])
+    words = x_ref.bitcast(jnp.uint32)
+
+    @pl.loop(0, jnp.clip(end - first, 0, tile) // pack)
+    def _(q):
+        word = _picked_row(words, token_ref[first + q * pack], pack)
+        if pack == 2:
+            word |= _picked_row(words, token_ref[first + q * pack + 1],
+                                pack) << jnp.uint32(16)
+        stage[pl.ds(q, 1), :] = word
+
+    @pl.when(first < end)
+    def _():
+        out_ref[...] = pltpu.bitcast(stage[...], out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def spread_held_rows(x, token, held_rows, *, interpret=False):
+    """x (N, d), token (M,) int32 in ``0 .. N - 1``, held_rows a scalar ->
+    (M, d) whose row r is ``x[token[r]]`` for ``r < held_rows``; the rows
+    behind are NOT written (the tile ``held_rows`` falls in holds whatever
+    the kernel's buffer held, the tiles after it what the memory held).
+    Whoever reads the result masks them: megablox's ``gmm`` visits the
+    held groups' tiles and selects at the store, ``tgmm`` selects zeros
+    for its loaded rows outside the group (DESIGN.md, held experts).  A
+    jitted function of this name wraps the call so that the step's
+    instruction is ``spread_held_rows.<n>``, as ``gmm.<n>`` is megablox's:
+    its result has the sorted rows' shape, by which the experts' metrics
+    know their kernels among ``tpu_custom_call.<n>``."""
+    from jax.experimental.pallas import tpu as pltpu
+    (n, d), (m,) = x.shape, token.shape
+    size, tile = x.dtype.itemsize, _GMM_ROW_TILE
+
+    def block(i, held, token):
+        return jnp.minimum(i, jnp.maximum(pl.cdiv(held[0], tile) - 1, 0)), 0
+    return pl.pallas_call(
+        functools.partial(_spread_kernel, pack=4 // size),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(m // tile,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
+            out_specs=pl.BlockSpec((tile, d), block),
+            scratch_shapes=[pltpu.VMEM((tile * size // 4, d), jnp.uint32)]),
+        out_shape=jax.ShapeDtypeStruct((m, d), x.dtype),
+        compiler_params=_walk_params((n + 3 * tile) * d * size),
+        interpret=interpret, name="spread_held_rows",
+    )(held_rows.astype(jnp.int32).reshape(1), token, x)
+
+
+def _slot_bits(k: int) -> int:
+    """Bits that hold a slot ``0 .. k - 1`` under a token in one int32."""
+    return (k - 1).bit_length()
+
+
+def _held_by_token(inverse, held_rows, n, k, tile):
+    """The assignments that meet a held expert, listed by token and by slot
+    within a token -> (at (N k,), row (N k,), starts (N / tile + 1,)):
+    entry e < held_rows is sorted row ``row[e]`` of token ``at[e] >>
+    _slot_bits(k)`` (the slot in the low bits), and the tokens of tile j
+    are entries ``starts[j] .. starts[j + 1]``.  One sort of the N k, the
+    others behind the held."""
+    assignment = jax.lax.iota(jnp.int32, n * k)
+    at = (assignment % n << _slot_bits(k)) + assignment // n
+    at = jnp.where(inverse < held_rows, at, n << _slot_bits(k))
+    at, row = jax.lax.sort((at, inverse), num_keys=1)
+    edges = jnp.arange(0, n + 1, tile, dtype=jnp.int32) << _slot_bits(k)
+    starts = jnp.sum(at[None, :] < edges[:, None], axis=1, dtype=jnp.int32)
+    return at, row, starts
+
+
+def _sum_kernel(held_ref, starts_ref, at_ref, row_ref, rows_hbm, out_ref,
+                buf, sems, acc, *, pack, slot_bits):
+    """Grid step j: tokens ``j tile .. (j + 1) tile``, each the float32 sum
+    of its entries in the order they are listed (slot order), zero with
+    none.  Entry e's row arrives in slot ``e % _IN_FLIGHT`` of ``buf`` as
+    the aligned group it lies in; the fetches run ahead of the entries
+    across the steps."""
+    from jax.experimental.pallas import tpu as pltpu
+    j = pl.program_id(0)
+    held = held_ref[0]
+
+    def fetch(e):
+        slot = e & (_IN_FLIGHT - 1)
+        start = pl.multiple_of(row_ref[e] & -_ROW_GROUP, _ROW_GROUP)
+        return pltpu.make_async_copy(rows_hbm.at[pl.ds(start, _ROW_GROUP)],
+                                     buf.at[slot], sems.at[slot])
+
+    @pl.when(j == 0)
+    def _():
+        @pl.loop(0, jnp.minimum(held, _IN_FLIGHT))
+        def _(e):
+            fetch(e).start()
+
+    acc[...] = jnp.zeros_like(acc)
+
+    @pl.loop(starts_ref[j], starts_ref[j + 1])
+    def _(e):
+        fetch(e).wait()
+        word = _picked_row(buf.at[e & (_IN_FLIGHT - 1)].bitcast(jnp.uint32),
+                           row_ref[e] & (_ROW_GROUP - 1), pack)
+        if pack == 2:           # bfloat16 is float32's high half
+            word = word << jnp.uint32(16)
+        token = pl.ds((at_ref[e] >> slot_bits) - j * out_ref.shape[0], 1)
+        acc[token, :] += pltpu.bitcast(word, jnp.float32)
+
+        @pl.when(e + _IN_FLIGHT < held)
+        def _():
+            fetch(e + _IN_FLIGHT).start()
+
+    out_ref[...] = acc[...].astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "tile", "interpret"))
+def sum_held_slots(rows, at, row, starts, held_rows, *, k, tile,
+                   interpret=False):
+    """rows (M, d) and the list of ``_held_by_token`` -> (N, d): token t
+    the float32 sum of ``rows[row[e]]`` over its entries e, in their
+    order, and zero for a token with none.  Reads no row behind
+    ``held_rows`` and makes no (k, N, d) array.  Named as
+    ``spread_held_rows`` is, and for the same reason."""
+    from jax.experimental.pallas import tpu as pltpu
+    (_, d), n = rows.shape, (starts.shape[0] - 1) * tile
+    return pl.pallas_call(
+        functools.partial(_sum_kernel, pack=4 // rows.dtype.itemsize,
+                          slot_bits=_slot_bits(k)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(n // tile,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tile, d), lambda j, *_: (j, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((_IN_FLIGHT, _ROW_GROUP, d), rows.dtype),
+                pltpu.SemaphoreType.DMA((_IN_FLIGHT,)),
+                pltpu.VMEM((tile, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((n, d), rows.dtype),
+        compiler_params=_walk_params(d * (
+            (2 * tile + _IN_FLIGHT * _ROW_GROUP) * rows.dtype.itemsize
+            + 4 * tile)),
+        interpret=interpret, name="sum_held_slots",
+    )(held_rows.astype(jnp.int32).reshape(1), starts, at, row, rows)
 
 
 @jax.custom_vjp
-def _rows_to_experts(x, order, inverse):
+def _rows_to_experts(x, order, inverse, held_rows):
     """(N, d) tokens -> (N k, d) rows grouped by expert: row r holds token
     ``order[r] % N``.  ``order`` is a permutation of the N k (slot, token)
     assignments and ``inverse`` its inverse, so the backward is a gather
     and a sum over a token's k slots, not the scatter-add XLA would
-    derive from the forward gather."""
-    return _spread_rows(x, order)
+    derive from the forward gather.  ``held_rows``: None, or how many of
+    the rows, the first, both ways stop at (``_walks_held_rows``)."""
+    return _spread_rows(x, order, held_rows)
 
 
-def _rows_to_experts_fwd(x, order, inverse):
-    return _rows_to_experts(x, order, inverse), (x.shape[0], inverse)
+def _rows_to_experts_fwd(x, order, inverse, held_rows):
+    return (_rows_to_experts(x, order, inverse, held_rows),
+            (x.shape[0], inverse, held_rows))
 
 
 def _rows_to_experts_bwd(res, g):
-    n, inverse = res
-    return _sum_slots(g, inverse, g.shape[0] // n), None, None
+    n, inverse, held_rows = res
+    return (_sum_slots(g, inverse, g.shape[0] // n, held_rows), None, None,
+            None)
 
 
 _rows_to_experts.defvjp(_rows_to_experts_fwd, _rows_to_experts_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _experts_to_rows(out, order, inverse, k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _experts_to_rows(out, order, inverse, held_rows, k):
     """``_rows_to_experts`` turned round: (N k, d) rows grouped by expert
     -> (N, d) tokens, each the float32 sum of its k rows.  The backward is
     ``_rows_to_experts``' forward, a gather from the (N, d) gradient: it
     reads nothing of ``out``, so no row is saved or recomputed for it."""
-    return _sum_slots(out, inverse, k)
+    return _sum_slots(out, inverse, k, held_rows)
 
 
-def _experts_to_rows_fwd(out, order, inverse, k):
-    return _experts_to_rows(out, order, inverse, k), order
+def _experts_to_rows_fwd(out, order, inverse, held_rows, k):
+    return (_experts_to_rows(out, order, inverse, held_rows, k),
+            (order, held_rows))
 
 
-def _experts_to_rows_bwd(k, order, g):
-    return _spread_rows(g, order), None, None
+def _experts_to_rows_bwd(k, res, g):
+    order, held_rows = res
+    return _spread_rows(g, order, held_rows), None, None, None
 
 
 _experts_to_rows.defvjp(_experts_to_rows_fwd, _experts_to_rows_bwd)
@@ -434,16 +668,22 @@ def dropless_experts(x: jax.Array, expert_idx: jax.Array, weights: jax.Array,
     expert costs no matmul and adds nothing (its rows come out of
     ``grouped_matmul`` zero): what that expert would add is another
     chip's part, and on several chips the exchange of rows goes between
-    the sort and the matmuls (DESIGN.md, held experts).  No capacity: no
+    the sort and the matmuls (DESIGN.md, held experts).  With H < E on the
+    megablox path the rows that meet no held expert (they lie behind
+    ``held_rows = group_sizes[:H].sum()``) are neither carried to the
+    experts nor fetched back (``_walks_held_rows``).  No capacity: no
     (N, E, C) tensor exists and nothing is dropped among the held,
     whatever the imbalance.  ``group_sizes[i]`` counts the rows of expert
     ``first_held + i`` (mod E).
     """
-    k = expert_idx.shape[1]
+    (n, d), k, (held, _, f) = x.shape, expert_idx.shape[1], w_gate.shape
     with jax.named_scope("moe_dispatch"):
         order, inverse, w_sorted, group_sizes = _sorted_assignments(
             expert_idx, weights, num_experts, first_held)
-        rows = _rows_to_experts(x, order, inverse)               # (N k, d)
+        held_rows = None
+        if _walks_held_rows(n, k, d, f, held, num_experts, x.dtype):
+            held_rows = group_sizes[:held].sum()
+        rows = _rows_to_experts(x, order, inverse, held_rows)    # (N k, d)
     with jax.named_scope("moe_experts"):
         gate = grouped_matmul(rows, w_gate.astype(x.dtype), group_sizes)
         up = grouped_matmul(rows, w_up.astype(x.dtype), group_sizes)
@@ -452,7 +692,7 @@ def dropless_experts(x: jax.Array, expert_idx: jax.Array, weights: jax.Array,
         out = grouped_matmul(hidden.astype(x.dtype), w_down.astype(x.dtype),
                              group_sizes)
     with jax.named_scope("moe_combine"):
-        y = _experts_to_rows(out, order, inverse, k)
+        y = _experts_to_rows(out, order, inverse, held_rows, k)
     return y, group_sizes
 
 

@@ -56,6 +56,14 @@ def _kernel_results(text):
                       r'custom_call_target="tpu_custom_call"', text)
 
 
+def _kernel_names_and_results(text):
+    """(instruction name, first result) of every Mosaic kernel of a
+    compiled module: what a reducer of ``perfbench`` matches a kernel by
+    (``gmm.37``, ``bf16[98304,2048]``)."""
+    return re.findall(r"%(\S+) = \(?(bf16\[[\d,]+\])\S* .*custom-call\(.*"
+                      r'custom_call_target="tpu_custom_call"', text)
+
+
 def _assert_the_sorted_passes_are_only_the_layers_work(text, rows, slots):
     """What ``ops/moe.dropless_experts`` spares the compiler by telling it
     what the sort makes true (PR 49), read off a compiled module: the
@@ -304,6 +312,49 @@ def test_dropless_experts_at_olmoe_train_shape(v5e, monkeypatch, fn,
     assert not re.search(r"\[8192,64,\d+\]", text)
     _assert_the_sorted_passes_are_only_the_layers_work(
         text, "bf16[65536,2048]", "[8192,8,2048]")
+
+
+def _spread(x, order, held_rows):
+    from ray_tpu.ops.moe import _spread_rows
+    return _spread_rows(x, order, held_rows)
+
+
+def _sum(rows, inverse, held_rows, *, k):
+    from ray_tpu.ops.moe import _sum_slots
+    return _sum_slots(rows, inverse, k, held_rows)
+
+
+@pytest.mark.parametrize("n,k,d,dtype", [
+    pytest.param(16384, 6, 2048, jnp.bfloat16, id="kanana_step"),
+    pytest.param(2048, 4, 3072, jnp.bfloat16, id="trinity_chunk"),
+    # 8,192 lanes of float32: more than Mosaic's default scoped VMEM
+    pytest.param(1024, 2, 8192, jnp.float32, id="wide_float32"),
+])
+@pytest.mark.parametrize("which", ["spread", "sum"])
+def test_held_row_kernels_compile_at_the_cells_shapes(v5e, monkeypatch, which,
+                                                      n, k, d, dtype):
+    """The two passes that stop at ``held_rows`` (``ops/moe.py``): the way
+    out holds its source in VMEM whole (64 MiB at Kanana's shape, under a
+    raised scoped limit), the way back fetches a row as the aligned 8 rows
+    it lies in (a slice of an HBM array is whole tiles), and both pick the
+    row out as 32-bit words through a bitcast reference, none of which
+    interpret mode can refuse; each is one Mosaic kernel whose instruction
+    carries the wrapping function's name."""
+    import functools
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    index, scalar = ((n * k,), jnp.int32), ((), jnp.int32)
+    if which == "spread":
+        text = _compile(_spread, v5e, ((n, d), dtype), index, scalar)
+        name, result = "spread_held_rows", (n * k, d)
+    else:
+        text = _compile(functools.partial(_sum, k=k), v5e,
+                        ((n * k, d), dtype), index, scalar)
+        name, result = "sum_held_slots", (n, d)
+    (kernel,) = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+    shape = ",".join(map(str, result))
+    assert re.search(rf"%{name}\.\d+ = \w+\[{shape}\]", kernel), kernel[:200]
+    assert " gather(" not in text
 
 
 # ----------------------------------------------- the serving cell's decode
@@ -671,7 +722,17 @@ def test_kanana_step_compiles_with_its_kernels_and_fits(v5e, monkeypatch):
     combine's gradient reads no output row), each with a result shape its
     metric is keyed on, no float32 copy of the sorted rows, no select,
     re-tiled copy or scatter among the sorted passes, no ``ragged-dot``
-    fallback, and everything inside the chip."""
+    fallback, and everything inside the chip.  The layer holds 16 of 128
+    experts, so its five row passes are the kernels whose work list ends
+    at ``held_rows`` (PR 56): ``spread_held_rows`` three times (forward,
+    recomputed, the combine's backward) and ``sum_held_slots`` twice, under
+    names no reducer of the experts', the attention's or the
+    ``tpu_custom_call`` metrics matches, though a spread's result has the
+    sorted rows' shape; no gather over the 98,304 rows is left, and the
+    step's temporaries are its parent's 9,365,980,160 B (e45e91f, this
+    jax) to two megabytes: 1,082,880 B more, the sum's list by token (two
+    arrays of 393,216 B) and what the schedule made of it; the (6, 16,384,
+    2,048) rows gathered back, 403 MB, are gone but were never the peak."""
     import json
     from pathlib import Path
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -679,18 +740,31 @@ def test_kanana_step_compiles_with_its_kernels_and_fits(v5e, monkeypatch):
                                         v5e)
     compiled = prog.jitted_step.lower(state, batch).compile()
     text = compiled.as_text()
-    kernels = _kernel_results(text)
+    kernels = _kernel_names_and_results(text)
     metrics = Path(__file__).parent.parent / "perfbench" / "layer_metrics"
     keyed = {name: json.loads((metrics / f"{name}.json").read_text())
-             ["params"]["shapes"]
-             for name in ("mla.attention_ms", "moe.held_expert_ms")}
-    flash = [k for k in kernels if k in keyed["mla.attention_ms"]]
-    experts = [k for k in kernels if k in keyed["moe.held_expert_ms"]]
+             ["params"] for name in ("mla.attention_ms",
+                                     "moe.held_expert_ms")}
+
+    def read_by(metric):
+        """The kernels ``kernel_ms_per_step`` counts under ``metric``: a
+        name that holds one of its substrings, a result of its shapes."""
+        return [shape for name, shape in kernels
+                if shape in keyed[metric]["shapes"]
+                and any(part in name for part in keyed[metric]["names"])]
+    flash, experts = read_by("mla.attention_ms"), read_by("moe.held_expert_ms")
     assert flash == ["bf16[64,8192,128]"] * 4, kernels
     for joined in ("[2,8192,32,192]", "[2,32,8192,192]", "[64,8192,192]"):
         assert joined not in text
-    assert len(experts) == 11 and len(flash) + len(experts) == len(kernels)
-    assert set(experts) == set(keyed["moe.held_expert_ms"])
+    assert len(experts) == 11
+    assert set(experts) == set(keyed["moe.held_expert_ms"]["shapes"])
+    read = keyed["mla.attention_ms"]["names"] \
+        + keyed["moe.held_expert_ms"]["names"]
+    walks = sorted((name.split(".")[0], shape) for name, shape in kernels
+                   if not any(part in name for part in read))
+    assert walks == [("spread_held_rows", "bf16[98304,2048]")] * 3 \
+        + [("sum_held_slots", "bf16[16384,2048]")] * 2, kernels
+    assert len(flash) + len(experts) + len(walks) == len(kernels)
     assert "ragged-dot" not in text
     assert not re.search(r"\[16384,128,\d+\]", text)    # no dispatch tensor
     # the combine makes no float32 copy of the sorted rows, forward or back
@@ -698,10 +772,13 @@ def test_kanana_step_compiles_with_its_kernels_and_fits(v5e, monkeypatch):
     assert not re.search(r"= f32\[98304,2048\].*moe_combine", text)
     _assert_the_sorted_passes_are_only_the_layers_work(
         text, "bf16[98304,2048]", "[16384,6,2048]")
+    assert not re.search(r"= bf16\[98304,2048\]\S* gather\(", text)
     mem = compiled.memory_analysis()
     held = mem.argument_size_in_bytes + mem.output_size_in_bytes \
         - mem.alias_size_in_bytes + mem.temp_size_in_bytes
     assert mem.alias_size_in_bytes >= 5.46e9        # the state, donated
+    assert mem.temp_size_in_bytes <= 9_365_980_160 + 2 * 2 ** 20, \
+        mem.temp_size_in_bytes
     assert held < 16.9e9, held
 
 
@@ -783,6 +860,9 @@ def test_older_training_steps_lower_to_the_operations_and_kernels_they_had(
         prog.jitted_step.lower(state, batch).as_text())
     assert (digest, sum(ops.values()), sum(kernels.values())) == \
         PARENT_STEPS[cell]
+    # PR 56's kernels are for a layer that holds a share of its experts:
+    # OLMoE holds all 64 and XL has none, so both digests are e45e91f's
+    assert digest in ("479998fc66d84fe8", "bcfab170aaa276dc")
 
 
 def test_xl_step_holds_no_split_head_and_no_copy_round_its_kernels(
@@ -1226,8 +1306,8 @@ def test_afmoe_chunk_program_runs_the_band_and_carries_no_holder(
     staging (the full layer's 26,624 positions and the window layers' ring
     of 6,144: 0.42e9 bytes, donated and returned), no holder among its
     operands; the band kernel in the 4 sliding layers and the causal one in
-    the full layer; 8,192 assignments through megablox; the ids (4, 2048,
-    4)."""
+    the full layer; 8,192 assignments through megablox, carried there and
+    back only as far as ``held_rows``; the ids (4, 2048, 4)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     runner, ecfg, held, weights, on_chip = afmoe_runner
     staging = jax.tree.map(lambda s: on_chip(s.shape, s.dtype),
@@ -1248,6 +1328,10 @@ def test_afmoe_chunk_program_runs_the_band_and_carries_no_holder(
     assert kernels.count("band_prefill") == 4
     assert kernels.count("causal_prefill") == 1
     assert len(re.findall(r"%gmm[.\d]* = bf16\[8192,3072\]", text)) == 12
+    # 32 of 256 experts are held: the rows' way out and back stops at
+    # ``held_rows`` in each of the 4 routed layers (PR 56)
+    assert kernels.count("spread_held_rows") == 4
+    assert kernels.count("sum_held_slots") == 4
     assert "ragged_dot_tiling" not in text
     total, mem = _held_bytes(compiled)
     assert mem.alias_size_in_bytes >= 0.419e9           # the staging

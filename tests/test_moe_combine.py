@@ -218,3 +218,117 @@ def test_a_checkpointed_layer_recomputes_no_down_projection(k, held):
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(
         x, weights, *ws)
     assert _grouped_products(jaxpr.jaxpr) == 11
+
+
+# ----------------------------------------- the passes that stop at held_rows
+# A layer that holds a share of the experts, at shapes the kernels' tiles
+# divide: 1,536 sorted rows are three row tiles of 512, 256 tokens one
+# token tile.
+HN, HK, HD = 256, 6, 128
+
+
+def _onto_the_held(first_held, held):
+    """Every slot of every token chooses a held expert: the kernels walk
+    all N k rows (the dropless promise)."""
+    def route(expert_idx):
+        slot = jnp.arange(expert_idx.shape[1], dtype=expert_idx.dtype)
+        return jnp.broadcast_to((first_held + slot % held) % E,
+                                expert_idx.shape)
+    return route
+
+
+def _onto_the_absent(first_held, held):
+    """No slot chooses a held expert: ``held_rows`` is 0."""
+    def route(expert_idx):
+        return jnp.full_like(expert_idx, (first_held + held) % E)
+    return route
+
+
+def _as_drawn(first_held, held):
+    return lambda expert_idx: expert_idx
+
+
+HELD_CASES = [
+    pytest.param(dtype, route, held, first_held,
+                 id=f"{jnp.dtype(dtype).name}-{route.__name__.strip('_')}-"
+                    f"held-{held}-from-{first_held}")
+    for dtype in (jnp.bfloat16, jnp.float32)
+    for route, held, first_held in (
+        (_as_drawn, 3, 0), (_as_drawn, 3, 5), (_as_drawn, 2, 7),
+        (_onto_the_absent, 3, 0), (_onto_the_absent, 3, 5),
+        (_onto_the_held, 3, 0), (_onto_the_held, 3, 5))
+]
+
+
+def _held_layer(dtype, route, held, first_held, seed=3):
+    keys = jax.random.split(jax.random.key(seed), 7)
+    x = jax.random.normal(keys[0], (HN, HD), jnp.float32).astype(dtype)
+    expert_idx = route(first_held, held)(jnp.argsort(
+        jax.random.uniform(keys[1], (HN, E)), axis=-1)[:, :HK].astype(
+            jnp.int32))
+    weights = jax.random.uniform(keys[2], (HN, HK), jnp.float32, 0.1, 1.0)
+    w_gate, w_up = (jax.random.normal(key, (held, HD, F), jnp.float32) * 0.3
+                    for key in keys[3:5])
+    w_down = jax.random.normal(keys[5], (held, F, HD), jnp.float32) * 0.3
+    cotangent = jax.random.normal(keys[6], (HN, HD), jnp.float32)
+    return (x, expert_idx, weights, w_gate.astype(dtype), w_up.astype(dtype),
+            w_down.astype(dtype)), cotangent.astype(dtype)
+
+
+@pytest.mark.parametrize("dtype,route,held,first_held", HELD_CASES)
+def test_the_passes_that_stop_at_held_rows_equal_the_gathers(
+        monkeypatch, dtype, route, held, first_held):
+    """``spread_held_rows`` and ``sum_held_slots`` (interpret mode) against
+    ``_take_rows``' path: the same bits in every visited row and in every
+    token, forward, and through the layer the same output and the same
+    gradients of x, the weights and the three expert matrices, with
+    ``held_rows`` 0, between two row tiles and N k."""
+    (x, expert_idx, weights, *ws), cotangent = _held_layer(
+        dtype, route, held, first_held)
+    order, inverse, _, sizes = moe._sorted_assignments(
+        expert_idx, weights, E, first_held)
+    held_rows = sizes[:held].sum()
+    expected = {"onto_the_absent": 0, "onto_the_held": HN * HK}.get(
+        route.__name__.strip("_"))
+    if expected is None:
+        assert 0 < int(held_rows) < HN * HK
+        assert int(held_rows) % moe._GMM_ROW_TILE
+    else:
+        assert int(held_rows) == expected
+    visited = np.arange(HN * HK) < int(held_rows)
+
+    def bits(a):
+        return np.asarray(a.astype(jnp.float32))
+    np.testing.assert_array_equal(
+        bits(moe._spread_rows(x, order, held_rows))[visited],
+        bits(moe._spread_rows(x, order))[visited])
+    rows = jax.random.normal(jax.random.key(9), (HN * HK, HD),
+                             jnp.float32).astype(dtype)
+    np.testing.assert_array_equal(
+        bits(moe._sum_slots(rows, inverse, HK, held_rows)),
+        bits(moe._sum_slots(jnp.where(visited[:, None], rows, 0), inverse,
+                            HK)))
+
+    def layer(x, weights, *ws):
+        y = moe.dropless_experts(x, expert_idx, weights, *ws, num_experts=E,
+                                 first_held=first_held)[0]
+        return (y.astype(jnp.float32) * cotangent).sum(), y
+    both = []
+    for walks in (False, True):
+        monkeypatch.setattr(
+            moe, "_walks_held_rows",
+            lambda n, k, d, f, held, num_experts, dtype, walks=walks:
+            walks and held < num_experts)
+        step = jax.value_and_grad(layer, argnums=(0, 1, 2, 3, 4),
+                                  has_aux=True)
+        # forward: a spread and a sum; backward: each the other's
+        assert str(jax.make_jaxpr(step)(x, weights, *ws)).count(
+            "pallas_call") == (4 if walks else 0)
+        both.append(step(x, weights, *ws))
+    ((_, want_y), want), ((_, got_y), got) = both
+    np.testing.assert_array_equal(bits(got_y), bits(want_y))
+    for name, g, w in zip(("x", "weights", "w_gate", "w_up", "w_down"),
+                          got, want):
+        np.testing.assert_array_equal(bits(g), bits(w), err_msg=name)
+    if int(held_rows):
+        assert np.abs(bits(want[0])).max() > 0
