@@ -1,0 +1,96 @@
+"""The chunked gated delta rule alone on the chip, at the Qwen3-Next
+cell's shape (2 x 8,192 positions, 16 key and 32 value heads of 128,
+bf16): device time of one forward and of one forward-and-backward from a
+profiler capture, by chunk size and by the precision of the solve, beside
+each variant's distance from the token-by-token recurrence in float32.
+
+    chiprun -- python benchmarks/delta_rule_bench.py
+
+Prints one JSON line a variant and appends them to
+``chiprun_out/delta_rule_bench.jsonl``.  Fails off the chip.
+"""
+
+from __future__ import annotations
+
+import json
+import sys
+import tempfile
+from pathlib import Path
+
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+
+B, T, G, H, DK, DV = 2, 8192, 16, 32, 128, 128
+VARIANTS = [("chunk64_highest", 64, "highest"), ("chunk64_high", 64, "high"),
+            ("chunk64_bf16", 64, "bf16"), ("chunk32_highest", 32, "highest"),
+            ("chunk128_highest", 128, "highest")]
+
+
+def main() -> None:
+    import os
+    from ray_tpu._private.config import GLOBAL_CONFIG
+    GLOBAL_CONFIG.apply_xla_cache_env(os.environ)
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    from perfbench import trace
+    from perfbench.reference import qwen3_next_ref as ref
+    from ray_tpu.ops import delta_rule as dr
+
+    if jax.default_backend() != "tpu":
+        raise SystemExit("delta_rule_bench measures a TPU")
+    keys = jax.random.split(jax.random.key(0), 6)
+    q = dr.l2norm(jax.random.normal(keys[0], (B, T, G, DK))) * DK ** -0.5
+    k = dr.l2norm(jax.random.normal(keys[1], (B, T, G, DK)))
+    v = jax.random.normal(keys[2], (B, T, H, DV)) * 0.5
+    step = jnp.exp(jax.random.uniform(keys[3], (B, T, H), jnp.float32,
+                                      jnp.log(0.001), jnp.log(0.1)))
+    g = -step * jax.random.uniform(keys[4], (H,), jnp.float32, 0.0, 16.0)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[5], (B, T, H)))
+    q, k, v = (a.astype(jnp.bfloat16) for a in (q, k, v))
+    with jax.default_matmul_precision("highest"):
+        want = jnp.stack([jax.jit(ref.recurrence)(
+            jnp.repeat(q[i].astype(jnp.float32), H // G, 1),
+            jnp.repeat(k[i].astype(jnp.float32), H // G, 1),
+            v[i].astype(jnp.float32), g[i], beta[i])[0] for i in range(B)])
+    want = np.asarray(want)
+    mm = dr._mm
+    solves = {
+        "highest": mm,
+        "high": lambda a, b: jnp.matmul(a, b, precision=lax.Precision.HIGH),
+        "bf16": lambda a, b: jnp.matmul(
+            a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
+            preferred_element_type=jnp.float32),
+    }
+    out = Path("chiprun_out") / "delta_rule_bench.jsonl"
+    out.parent.mkdir(exist_ok=True)
+    for name, chunk, solve in VARIANTS:
+        dr._mm = solves[solve]
+        jax.clear_caches()
+        fwd = jax.jit(lambda *a: dr.gated_delta_rule(*a, chunk=chunk)[0])
+        both = jax.jit(jax.grad(lambda *a: dr.gated_delta_rule(
+            *a, chunk=chunk)[0].astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4)))
+        args = (q, k, v, g, beta)
+        got = np.asarray(fwd(*args), np.float32)
+        jax.block_until_ready(both(*args))
+        row = {"variant": name, "chunk": chunk, "solve": solve,
+               "max_abs_err_over_max": float(np.abs(got - want).max()
+                                             / np.abs(want).max())}
+        for label, fn in (("fwd_ms", fwd), ("fwd_bwd_ms", both)):
+            with tempfile.TemporaryDirectory() as d:
+                capture = trace.Capture(d)
+                capture.start()
+                for _ in range(3):
+                    jax.block_until_ready(fn(*args))
+                capture.stop()
+                traced = trace.load_window(capture)
+            row[label] = trace.busy_seconds(traced) * 1e3 / 3
+        print(json.dumps(row), flush=True)
+        with out.open("a") as f:
+            f.write(json.dumps(row) + "\n")
+    dr._mm = mm
+
+
+if __name__ == "__main__":
+    main()

@@ -232,13 +232,15 @@ def _walks_held_rows(n: int, k: int, d: int, f: int, held: int,
     of the experts (else no row can be skipped, and XLA's gathers from
     VMEM beat any copy by row), its rows take the megablox path, whose
     kernels mask the rows behind ``held_rows`` themselves
-    (``grouped_matmul``), a value is float32 or its high half, and the
-    tokens fit the VMEM the way out holds them in."""
+    (``grouped_matmul``), a value is float32 or its high half, the
+    tokens fit the VMEM the way out holds them in, and a list of the N k
+    assignments fits the scalar memory (``_one_list``)."""
     return (held < num_experts and jax.default_backend() == "tpu"
             and gmm_tiling(n * k, d, f) is not None
             and _token_tile(n) is not None
             and dtype in (jnp.bfloat16, jnp.float32)
-            and n * d * jnp.dtype(dtype).itemsize <= _SOURCE_BYTES)
+            and n * d * jnp.dtype(dtype).itemsize <= _SOURCE_BYTES
+            and 4 * n * k <= _LIST_BYTES)
 
 
 def _walk_params(held_bytes: int):
@@ -340,20 +342,25 @@ def _held_by_token(inverse, held_rows, n, k, tile):
     return at, row, starts
 
 
-def _sum_kernel(held_ref, starts_ref, at_ref, row_ref, rows_hbm, out_ref,
-                buf, sems, acc, *, pack, slot_bits):
+def _sum_kernel(held_ref, starts_ref, *refs, pack, slot_bits, tile_bits):
     """Grid step j: tokens ``j tile .. (j + 1) tile``, each the float32 sum
     of its entries in the order they are listed (slot order), zero with
     none.  Entry e's row arrives in slot ``e % _IN_FLIGHT`` of ``buf`` as
     the aligned group it lies in; the fetches run ahead of the entries
-    across the steps."""
+    across the steps.  The list is two arrays (``at``, ``row``) or, with
+    ``tile_bits``, one: the sorted row above the token's place in its
+    tile (``_one_list``)."""
     from jax.experimental.pallas import tpu as pltpu
+    *lists, rows_hbm, out_ref, buf, sems, acc = refs
     j = pl.program_id(0)
     held = held_ref[0]
 
+    def row_of(e):
+        return lists[1][e] if tile_bits is None else lists[0][e] >> tile_bits
+
     def fetch(e):
         slot = e & (_IN_FLIGHT - 1)
-        start = pl.multiple_of(row_ref[e] & -_ROW_GROUP, _ROW_GROUP)
+        start = pl.multiple_of(row_of(e) & -_ROW_GROUP, _ROW_GROUP)
         return pltpu.make_async_copy(rows_hbm.at[pl.ds(start, _ROW_GROUP)],
                                      buf.at[slot], sems.at[slot])
 
@@ -369,10 +376,14 @@ def _sum_kernel(held_ref, starts_ref, at_ref, row_ref, rows_hbm, out_ref,
     def _(e):
         fetch(e).wait()
         word = _picked_row(buf.at[e & (_IN_FLIGHT - 1)].bitcast(jnp.uint32),
-                           row_ref[e] & (_ROW_GROUP - 1), pack)
+                           row_of(e) & (_ROW_GROUP - 1), pack)
         if pack == 2:           # bfloat16 is float32's high half
             word = word << jnp.uint32(16)
-        token = pl.ds((at_ref[e] >> slot_bits) - j * out_ref.shape[0], 1)
+        if tile_bits is None:
+            token = pl.ds((lists[0][e] >> slot_bits) - j * out_ref.shape[0],
+                          1)
+        else:
+            token = pl.ds(lists[0][e] & ((1 << tile_bits) - 1), 1)
         acc[token, :] += pltpu.bitcast(word, jnp.float32)
 
         @pl.when(e + _IN_FLIGHT < held)
@@ -380,6 +391,20 @@ def _sum_kernel(held_ref, starts_ref, at_ref, row_ref, rows_hbm, out_ref,
             fetch(e + _IN_FLIGHT).start()
 
     out_ref[...] = acc[...].astype(out_ref.dtype)
+
+
+# The scalar memory a kernel's prefetched lists may take, of the v5e's 1 MiB
+# (the tables of ``starts`` and the compiler's own words need the rest).
+_LIST_BYTES = 896 * 2 ** 10
+
+
+def _one_list(m: int, tile: int) -> bool:
+    """Whether ``sum_held_slots`` is handed its list as ONE int32 an entry:
+    the two arrays of M entries do not fit the scalar memory together
+    (M = 163,840: 1.25 MiB), and a sorted row and a token's place in its
+    tile fit 31 bits.  Kanana's 98,304 entries fit as two."""
+    return 2 * 4 * m > _LIST_BYTES \
+        and (m - 1).bit_length() + (tile - 1).bit_length() <= 31
 
 
 @functools.partial(jax.jit, static_argnames=("k", "tile", "interpret"))
@@ -391,12 +416,16 @@ def sum_held_slots(rows, at, row, starts, held_rows, *, k, tile,
     ``held_rows`` and makes no (k, N, d) array.  Named as
     ``spread_held_rows`` is, and for the same reason."""
     from jax.experimental.pallas import tpu as pltpu
-    (_, d), n = rows.shape, (starts.shape[0] - 1) * tile
+    (m, d), n = rows.shape, (starts.shape[0] - 1) * tile
+    lists, tile_bits = (at, row), None
+    if _one_list(m, tile):
+        tile_bits = (tile - 1).bit_length()
+        lists = ((row << tile_bits) | ((at >> _slot_bits(k)) & (tile - 1)),)
     return pl.pallas_call(
         functools.partial(_sum_kernel, pack=4 // rows.dtype.itemsize,
-                          slot_bits=_slot_bits(k)),
+                          slot_bits=_slot_bits(k), tile_bits=tile_bits),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4, grid=(n // tile,),
+            num_scalar_prefetch=2 + len(lists), grid=(n // tile,),
             in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((tile, d), lambda j, *_: (j, 0)),
             scratch_shapes=[
@@ -408,7 +437,7 @@ def sum_held_slots(rows, at, row, starts, held_rows, *, k, tile,
             (2 * tile + _IN_FLIGHT * _ROW_GROUP) * rows.dtype.itemsize
             + 4 * tile)),
         interpret=interpret, name="sum_held_slots",
-    )(held_rows.astype(jnp.int32).reshape(1), starts, at, row, rows)
+    )(held_rows.astype(jnp.int32).reshape(1), starts, *lists, rows)
 
 
 @jax.custom_vjp
@@ -599,14 +628,20 @@ def grouped_matmul(rows: jax.Array, w: jax.Array,
     return out
 
 
-def route_softmax(x: jax.Array, w_router: jax.Array, k: int):
+def route_softmax(x: jax.Array, w_router: jax.Array, k: int,
+                  norm_topk: bool = False):
     """-> (expert_idx (N, k), weights (N, k), logits, probs (N, E)): a
     float32 softmax over all E experts whose top-k probabilities weigh
-    the experts' outputs as they are (not renormalised)."""
+    the experts' outputs as they are (OLMoE), or with ``norm_topk``
+    divided by their sum over the k chosen (Qwen3-Next's
+    ``norm_topk_prob``): over ALL the chosen, whichever of them a layer
+    that holds a share of the experts holds."""
     logits = jnp.dot(x, w_router.astype(x.dtype),
                      preferred_element_type=jnp.float32)         # (N, E)
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, expert_idx = jax.lax.top_k(probs, k)              # (N, k)
+    if norm_topk:
+        gate_vals = gate_vals / gate_vals.sum(-1, keepdims=True)
     return expert_idx, gate_vals, logits, probs
 
 
@@ -698,7 +733,7 @@ def dropless_experts(x: jax.Array, expert_idx: jax.Array, weights: jax.Array,
 
 def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
                      w_up: jax.Array, w_down: jax.Array, *, k: int,
-                     scoring: str = "softmax",
+                     scoring: str = "softmax", norm_topk: bool = False,
                      select_bias: Optional[jax.Array] = None,
                      weight_scale: float = 1.0, first_held: int = 0,
                      choices: bool = False,
@@ -710,9 +745,13 @@ def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
     x (N, d); w_router (d, E): the router always has its full width.
     w_gate, w_up (H, d, f); w_down (H, f, d): the H <= E experts held
     here, ``first_held`` the first (``dropless_experts``).  ``scoring``
-    ``"softmax"`` (``route_softmax``) returns ``RouterStats`` over all E;
-    ``"sigmoid"`` (``route_sigmoid`` with ``select_bias`` (E,) and
-    ``weight_scale``) has no auxiliary term and returns ``HeldStats``.
+    ``"softmax"`` (``route_softmax``, with ``norm_topk`` the weights
+    renormalised over the k chosen) returns ``RouterStats`` over all E
+    where every expert is held and ``HeldStats`` where the layer holds a
+    share (H < E: the auxiliary terms are sums over all the experts'
+    rows, which one share does not see); ``"sigmoid"`` (``route_sigmoid``
+    with ``select_bias`` (E,) and ``weight_scale``) has no auxiliary term
+    and returns ``HeldStats``.
     ``choices`` (a serving step): a third result, the expert ids (N, k)
     int32 that made ``y``; ``live`` (N,) bool with it: the rows that are
     some sequence's (``choice_of_live_rows``).
@@ -724,7 +763,9 @@ def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
                          "(expected softmax | sigmoid)")
     with jax.named_scope("router"):
         if scoring == "softmax":
-            expert_idx, weights, logits, probs = route_softmax(x, w_router, k)
+            route = functools.partial(route_softmax, norm_topk=True) \
+                if norm_topk else route_softmax
+            expert_idx, weights, logits, probs = route(x, w_router, k)
         else:
             expert_idx, weights = route_sigmoid(x, w_router, select_bias, k,
                                                 weight_scale)
@@ -735,7 +776,7 @@ def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
         num_experts=num_experts, first_held=first_held)
     chose = (expert_idx.astype(jnp.int32),) if choices else ()
     with jax.named_scope("router"):
-        if scoring == "sigmoid":
+        if scoring == "sigmoid" or held < num_experts:
             mine = group_sizes[:held].astype(jnp.float32)
             rows = mine.sum()
             return (y, HeldStats(rows, mine.max() / jnp.maximum(mine.mean(),

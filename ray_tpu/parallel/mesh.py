@@ -189,6 +189,21 @@ TRANSFORMER_RULES: Rules = [
     (r".*(dense|moe)_blocks/(shared/)?w_(gate|up)/kernel$", P("pipeline", "fsdp", "tensor")),
     (r".*(dense|moe)_blocks/(shared/)?w_down/kernel$", P("pipeline", "tensor", "fsdp")),
     (r".*(dense|moe)_blocks/router/(kernel|select_bias)$", P("pipeline")),
+    # stacked Qwen3-Next blocks (models/qwen3_next.py: gdn_blocks and
+    # attn_blocks, whose experts take the blocks/experts rules above):
+    # projections' heads and the conv's channels over ``tensor``, a value
+    # head's A_log and dt_bias with it; the router, the shared expert's
+    # gate and the norms are every tensor shard's
+    (r".*gdn_blocks/in_proj_(qkvz|ba)/kernel$", P("pipeline", "fsdp", "tensor")),
+    (r".*gdn_blocks/out_proj/kernel$",          P("pipeline", "tensor", "fsdp")),
+    (r".*gdn_blocks/conv/kernel$",              P("pipeline", None, "tensor")),
+    (r".*gdn_blocks/(A_log|dt_bias)$",          P("pipeline", "tensor")),
+    (r".*attn_blocks/(wq|wk|wv)/kernel$",       P("pipeline", "fsdp", "tensor")),
+    (r".*attn_blocks/wo/kernel$",               P("pipeline", "tensor", "fsdp")),
+    (r".*(gdn|attn)_blocks/shared/w_(gate|up)/kernel$", P("pipeline", "fsdp", "tensor")),
+    (r".*(gdn|attn)_blocks/shared/w_down/kernel$", P("pipeline", "tensor", "fsdp")),
+    (r".*(gdn|attn)_blocks/(router|shared_gate)/kernel$", P("pipeline")),
+    (r".*(gdn|attn)_blocks/\w+_norm/scale$",    P("pipeline")),
     # Non-stacked variants (single-layer modules, BERT/ResNet dense layers).
     (r".*attn_qkv/kernel$",         P("fsdp", None, "tensor")),
     (r".*attn_out/kernel$",         P("tensor", "fsdp")),

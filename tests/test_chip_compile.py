@@ -185,6 +185,51 @@ def test_flash_attention_at_olmoe_train_shape(v5e, fn):
 
 
 # ------------------------------------ Kanana's training cell: five operands
+def _gqa_flash_loss(q, k, v):
+    """16 query heads on 2 K/V heads repeated, as models/qwen3_next.py."""
+    k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], axis=2) for x in (k, v))
+    return _flash_loss(q, k, v)
+
+
+@pytest.mark.parametrize("fn", [_flash, jax.grad(_gqa_flash_loss,
+                                                 argnums=(0, 1, 2))],
+                         ids=["fwd", "bwd"])
+def test_flash_attention_at_qwen3_next_train_shape(v5e, fn):
+    """D = 256 at 8,192 positions (Kanana's 192-wide keys pad to 256
+    lanes, its values are 128): 8 MiB of resident keys and values a grid
+    row, under a raised scoped limit; the kernels' first results are what
+    ``attn.gated_flash_ms`` is keyed on."""
+    q = ((2, 8192, 16, 256), jnp.bfloat16)
+    kv = q if fn is _flash else ((2, 8192, 2, 256), jnp.bfloat16)
+    text = _compile(fn, v5e, q, kv, kv)
+    assert "bf16[32,8192,256]" in _kernel_results(text)
+
+
+def _rule_loss(q, k, v, g, beta):
+    from ray_tpu.ops.delta_rule import gated_delta_rule
+    return gated_delta_rule(q, k, v, g, beta)[0].astype(jnp.float32).sum()
+
+
+def test_the_delta_rule_compiles_at_qwen3_next_train_shape(v5e):
+    """``ops/delta_rule.gated_delta_rule`` forward and backward at 2 x
+    8,192 positions, 16 key and 32 value heads of 128 in bf16: plain XLA
+    (no Mosaic kernel), a scan over 8 spans round a scan over 16 chunks,
+    the solve's float32 products at their precision, and no more alive
+    at once than a span's matrices: under 1.5 GB of temporaries where the
+    whole sequence's would be 3 GB."""
+    shapes = [((2, 8192, 16, 128), jnp.bfloat16)] * 2 \
+        + [((2, 8192, 32, 128), jnp.bfloat16)] \
+        + [((2, 8192, 32), jnp.float32)] * 2
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
+    compiled = jax.jit(jax.grad(_rule_loss, argnums=(0, 1, 2, 3, 4))) \
+        .lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert text.count(" while(") >= 4          # two scans, each both ways
+    assert "f32[2,16,2,16,64,64]" in text      # a span's solved systems
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
 def _latent(*parts):
     from ray_tpu.ops.flash_attention import latent_flash_attention
     return latent_flash_attention(*parts, None, False)
@@ -326,6 +371,9 @@ def _sum(rows, inverse, held_rows, *, k):
 
 @pytest.mark.parametrize("n,k,d,dtype", [
     pytest.param(16384, 6, 2048, jnp.bfloat16, id="kanana_step"),
+    # 163,840 assignments: the way back's list is one int32 an entry (two
+    # arrays of them are 1.25 MiB of the 1 MiB scalar memory: PR 57)
+    pytest.param(16384, 10, 2048, jnp.bfloat16, id="qwen3_next_step"),
     pytest.param(2048, 4, 3072, jnp.bfloat16, id="trinity_chunk"),
     # 8,192 lanes of float32: more than Mosaic's default scoped VMEM
     pytest.param(1024, 2, 8192, jnp.float32, id="wide_float32"),
