@@ -1,10 +1,11 @@
 """The chunked gated delta rule alone on the chip, at the Qwen3-Next
 cell's shape (2 x 8,192 positions, 16 key and 32 value heads of 128,
 bf16): device time of one forward and of one forward-and-backward from a
-profiler capture, by chunk size and by the precision of the solve, beside
-each variant's distance from the token-by-token recurrence in float32.
+profiler capture, for the Pallas kernels the cell runs and for the XLA
+form by chunk size and by the precision of the solve, beside each
+variant's distance from the token-by-token recurrence in float32.
 
-    chiprun -- python benchmarks/delta_rule_bench.py
+    chiprun -- python benchmarks/delta_rule_bench.py [variant ...]
 
 Prints one JSON line a variant and appends them to
 ``chiprun_out/delta_rule_bench.jsonl``.  Fails off the chip.
@@ -20,9 +21,14 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 B, T, G, H, DK, DV = 2, 8192, 16, 32, 128, 128
-VARIANTS = [("chunk64_highest", 64, "highest"), ("chunk64_high", 64, "high"),
-            ("chunk64_bf16", 64, "bf16"), ("chunk32_highest", 32, "highest"),
-            ("chunk128_highest", 128, "highest")]
+# name, chunk, the solve's precision, whether the kernels may run (what
+# ``gated_delta_rule`` picks at this shape) or the XLA form is called
+VARIANTS = [("kernels", 64, "highest", True),
+            ("chunk64_highest", 64, "highest", False),
+            ("chunk64_high", 64, "high", False),
+            ("chunk64_bf16", 64, "bf16", False),
+            ("chunk32_highest", 32, "highest", False),
+            ("chunk128_highest", 128, "highest", False)]
 
 
 def main() -> None:
@@ -65,16 +71,24 @@ def main() -> None:
     }
     out = Path("chiprun_out") / "delta_rule_bench.jsonl"
     out.parent.mkdir(exist_ok=True)
-    for name, chunk, solve in VARIANTS:
+    zeros = jnp.zeros((B, H, DK, DV), jnp.float32)
+    asked = sys.argv[1:]
+    for name, chunk, solve, kernels in VARIANTS:
+        if asked and name not in asked:
+            continue
         dr._mm = solves[solve]
         jax.clear_caches()
-        fwd = jax.jit(lambda *a: dr.gated_delta_rule(*a, chunk=chunk)[0])
-        both = jax.jit(jax.grad(lambda *a: dr.gated_delta_rule(
-            *a, chunk=chunk)[0].astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4)))
+        rule = (lambda *a: dr.gated_delta_rule(*a, chunk=chunk)) if kernels \
+            else (lambda *a: dr._spans_form(*a, chunk, zeros))
+        fwd = jax.jit(lambda *a: rule(*a)[0])
+        both = jax.jit(jax.grad(
+            lambda *a: rule(*a)[0].astype(jnp.float32).sum(),
+            argnums=(0, 1, 2, 3, 4)))
         args = (q, k, v, g, beta)
         got = np.asarray(fwd(*args), np.float32)
         jax.block_until_ready(both(*args))
         row = {"variant": name, "chunk": chunk, "solve": solve,
+               "kernels": kernels,
                "max_abs_err_over_max": float(np.abs(got - want).max()
                                              / np.abs(want).max())}
         for label, fn in (("fwd_ms", fwd), ("fwd_bwd_ms", both)):
@@ -86,6 +100,13 @@ def main() -> None:
                 capture.stop()
                 traced = trace.load_window(capture)
             row[label] = trace.busy_seconds(traced) * 1e3 / 3
+            if kernels:     # the kernels' own time (a stand-alone program
+                # names them after the jitted function), and what is round them
+                row[f"{label[:-3]}_kernels_ms"] = \
+                    trace.op_seconds(traced, "delta_rule") * 1e3 / 3
+                row[f"{label[:-3]}_top_ops_ms"] = [
+                    [n, round(s * 1e3 / 3, 3)]
+                    for n, s in trace.top_ops(traced, 8)]
         print(json.dumps(row), flush=True)
         with out.open("a") as f:
             f.write(json.dumps(row) + "\n")

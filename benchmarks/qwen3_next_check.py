@@ -284,26 +284,56 @@ def main() -> None:
         controls = {}
         for variant in ref.VARIANTS:
             controls[variant] = worst(mine, reference_readables(variant, x_in))
-        product, solve = delta_rule._product, delta_rule._mm
+        # the program's own products, degraded where the rule makes them:
+        # ``_product`` / ``_mm`` in the XLA form, ``_low`` / ``_dot`` in
+        # the kernels (module globals, read when the step is traced)
+        hooks = ("_product", "_mm", "_low", "_dot")
+        kept = {attr: getattr(delta_rule, attr) for attr in hooks}
 
         def float8_product(spec, a, b, dtype):
             """Operands rounded to float8_e4m3's 3 mantissa bits
             (``reduce_precision`` is an operation XLA keeps)."""
             low = [lax.reduce_precision(x.astype(jnp.float32), 4, 3)
                    for x in (a, b)]
-            return product(spec, *low, dtype)
+            return kept["_product"](spec, *low, dtype)
+
+        def float8_low(dtype):
+            """The kernels' operand cast, through float8_e4m3's values
+            first: 3 mantissa bits above 2^-6, steps of 2^-9 below (Mosaic
+            has no ``reduce_precision``: the same rounding by bits)."""
+            cast, precision = kept["_low"](dtype)
+
+            def e4m3(x):
+                x = x.astype(jnp.float32)
+                bits = lax.bitcast_convert_type(x, jnp.uint32)
+                bits = (bits + jnp.uint32(1 << 19)) & jnp.uint32(0xFFF00000)
+                return jnp.where(jnp.abs(x) < 2.0 ** -6,
+                                 jnp.round(x * 512.0) / 512.0,
+                                 lax.bitcast_convert_type(bits, jnp.float32))
+            return (lambda x: cast(e4m3(x))), precision
 
         def bf16_solve(a, b):
             return jnp.matmul(a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
                               preferred_element_type=jnp.float32)
 
-        for name, patch in (("rule_products_float8", {"_product": float8_product}),
-                            ("rule_solve_bf16", {"_mm": bf16_solve})):
+        def bf16_dot(a, b, contract, precision=None):
+            """The kernels' float32 products (``T`` applied, and their
+            cotangents) with bf16 operands; the substitution that makes
+            ``T`` is float32 arithmetic and stays."""
+            if precision is not None:
+                a, b = a.astype(jnp.bfloat16), b.astype(jnp.bfloat16)
+            return kept["_dot"](a, b, contract)
+
+        for name, patch in (
+                ("rule_products_float8", {"_product": float8_product,
+                                          "_low": float8_low}),
+                ("rule_solve_bf16", {"_mm": bf16_solve, "_dot": bf16_dot})):
             for attr, fn in patch.items():
                 setattr(delta_rule, attr, fn)
             jax.clear_caches()
             controls[name] = worst(program_readables(params, x_in), right)
-            delta_rule._product, delta_rule._mm = product, solve
+            for attr, fn in kept.items():
+                setattr(delta_rule, attr, fn)
         jax.clear_caches()
         row["controls"] = controls
 

@@ -212,7 +212,7 @@ def _gdn_mixer(u: jax.Array, lp: Params, cfg: Qwen3NextConfig):
     """Normed hidden states (B, T, E) -> (W_out . gated delta rule
     (B, T, E), the mean decay exp(g) of the layer, the rule's last state
     (B, H, dk, dv) float32, which training drops)."""
-    from ray_tpu.ops.delta_rule import gated_delta_rule, l2norm
+    from ray_tpu.ops.delta_rule import gated_delta_rule, l2norm_heads
     from ray_tpu.ops.ssm import causal_conv
     B, T, _ = u.shape
     G, H = cfg.gdn_key_heads, cfg.gdn_value_heads
@@ -232,11 +232,15 @@ def _gdn_mixer(u: jax.Array, lp: Params, cfg: Qwen3NextConfig):
         beta = jax.nn.sigmoid(ba[..., :H])
         g = -jnp.exp(lp["A_log"].astype(jnp.float32)) * jax.nn.softplus(
             ba[..., H:] + lp["dt_bias"].astype(jnp.float32))
-        q = l2norm(qkv[..., :kw].reshape(B, T, G, dk)) * dk ** -0.5
-        k = l2norm(qkv[..., kw:2 * kw].reshape(B, T, G, dk))
+        # normed with the heads' lanes where the conv left them: the rule's
+        # kernels read (B, T, heads x 128) by column blocks
+        q = l2norm_heads(qkv[..., :kw], G) * dk ** -0.5
+        k = l2norm_heads(qkv[..., kw:2 * kw], G)
         v = qkv[..., 2 * kw:].reshape(B, T, H, dv)
-        o, state = gated_delta_rule(q.astype(cfg.dtype), k.astype(cfg.dtype),
-                                    v, g, beta, chunk=cfg.rule_chunk)
+        o, state = gated_delta_rule(
+            q.astype(cfg.dtype).reshape(B, T, G, dk),
+            k.astype(cfg.dtype).reshape(B, T, G, dk), v, g, beta,
+            chunk=cfg.rule_chunk)
     with jax.named_scope("gdn_norm"):
         o = o.astype(jnp.float32)
         o = o * lax.rsqrt((o * o).mean(-1, keepdims=True) + cfg.rms_eps) \

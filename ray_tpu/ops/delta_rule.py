@@ -24,6 +24,35 @@ the chunk wrote::
     o_i = exp(c_i) q_i^T S0 + sum_{j<=i} exp(c_i - c_j) (q_i . k_j) v'_j
     S_end = exp(c_C) S0 + sum_j exp(c_C - c_j) k_j v'_j^T
 
+Two forms of that one algorithm, at the same precisions; which runs is
+read from the call (``_kernels_run``), never set:
+
+*Pallas kernels* (PR 58), on a TPU with bf16 activations, chunks of 64
+and heads of whole 128-lane blocks (the Qwen3-Next cell).  Four, each
+under a name of its own, q, k, v and o read and written as the
+projections leave them, (B, T, heads x 128) by column blocks, the R value
+heads of a key head in one grid step (k . k and q . k made once for
+both), ``STEP_CHUNKS`` chunks a step.  ``delta_rule_solve`` makes ``A``
+and ``T = (I + A)^-1`` by substitution on the vector unit, float32
+multiplies and adds (no lower a precision than products at ``HIGHEST``):
+of a chunk's (C, C) matrices ``T`` alone reaches HBM, the R heads' side
+by side in 128 lanes, once a step: it carries the name ``INVERSE``, which
+a caller's checkpoint keeps.  ``delta_rule_fwd`` does what follows, the
+chunk axis sequential and the R states float32 in a VMEM scratch from
+the first chunk to the last; the decays, ``U``, ``W``, the scores and
+``d`` exist only in VMEM.  The backward is written out, not autodiff:
+``delta_rule_bwd`` walks the chunks from the last to the first with the
+states' cotangent in scratch, makes a chunk's matrices again from q, k,
+v, the decays, ``T`` and the state that entered the chunk (kept by the
+differentiated forward in float32: (B, H, chunks, dk, dv), 537 MB at the
+shape below, alive for one layer at a time under a block's checkpoint),
+and hands ``T``'s cotangent to ``delta_rule_solve_bwd`` (``dA = -T^T dT
+T^T`` below the diagonal, then k, the decays and beta).  XLA is left
+with the cumulative sum of g by chunk and its reverse (1 M numbers).
+
+*Plain XLA* (``_spans_form``), for everything else: float32, the tests'
+8-wide heads, other chunk sizes, the CPU.  It is the definition the tests
+hold to the recurrence at 1e-5, and one test holds the kernels to it.
 ``A``, ``T``, ``U``, ``W`` and the (C, C) scores are made for ``SPAN``
 chunks at once, as batched products; only the four products that read the
 entering state run chunk by chunk, in a ``lax.scan`` inside the span.  ``T`` is
@@ -44,9 +73,9 @@ decays and states are 3 GB a layer, a span of 16 chunks' an eighth).
 
 Precision.  Every exponent is <= 0 (a decay), cumulative sums, decays,
 the state and all accumulation are float32, and the solve (``T`` and the
-two products ``T`` is applied in) multiplies float32 operands at
-``HIGHEST``: an error in ``T`` is an error in every correction of the
-chunk.  The other products (k . k, q . k, and the four against the
+two products ``T`` is applied in, with their cotangents) multiplies
+float32 operands at ``HIGHEST`` or substitutes in float32: an error in
+``T`` is an error in every correction of the chunk.  The other products (k . k, q . k, and the four against the
 state) take their operands in the activations' type, bf16 in a bf16
 model, with float32 accumulation, as a flash kernel's scores do; with
 float32 activations everything is float32 at ``HIGHEST``, which is how
@@ -66,6 +95,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
 from jax.ad_checkpoint import checkpoint_name
 
 _HI = lax.Precision.HIGHEST
@@ -78,6 +108,23 @@ def l2norm(x: jax.Array, eps: float = 1e-6) -> jax.Array:
     """x / sqrt(sum x^2 + eps) over the last axis, float32."""
     x = x.astype(jnp.float32)
     return x * lax.rsqrt((x * x).sum(-1, keepdims=True) + eps)
+
+
+def l2norm_heads(x: jax.Array, heads: int, eps: float = 1e-6) -> jax.Array:
+    """``l2norm`` over each of ``heads`` equal runs of the last axis, the
+    runs left where they lie: (..., heads d) in and out, float32.  A
+    run's sum of squares, and its way back over the run's lanes, are
+    products with the runs' 0/1 indicator (heads d, heads) at ``HIGHEST``
+    (a product with 1 is exact), so nothing is laid out as (..., heads, d):
+    on the TPU that is a float32 copy of its own each way for every use
+    (1.1 ms each at 2 x 8,192 x 2,048, a dozen a layer: my chip run, PR
+    58), where the projection's own (B, T, heads d) needs none."""
+    x = x.astype(jnp.float32)
+    n = x.shape[-1]
+    runs = (jnp.arange(n)[:, None] // (n // heads)
+            == jnp.arange(heads)[None, :]).astype(jnp.float32)
+    squares = jnp.matmul(x * x, runs, precision=_HI)
+    return x * jnp.matmul(lax.rsqrt(squares + eps), runs.T, precision=_HI)
 
 
 def delta_rule_step(state: jax.Array, q: jax.Array, k: jax.Array,
@@ -182,19 +229,9 @@ def _span(state, xs, *, dtype):
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
-def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
-                     beta: jax.Array, chunk: int = CHUNK,
-                     state0: Optional[jax.Array] = None
-                     ) -> Tuple[jax.Array, jax.Array]:
-    """The recurrence of :func:`delta_rule_step` over a sequence.
-
-    q, k (B, T, G, dk), the keys l2-normed and the queries scaled by the
-    caller; v (B, T, H, dv) with H = G R: value head h reads key head
-    h // R (no repeated copy of q or k is made); g, beta (B, T, H);
-    state0 (B, H, dk, dv) float32 or None for zeros.  Returns (o (B, T, H,
-    dv) in v's type, the state after the last position (B, H, dk, dv)
-    float32).  Products that are no part of the solve take operands of
-    v's type (the module's head)."""
+def _spans_form(q, k, v, g, beta, chunk, state0):
+    """``gated_delta_rule`` in plain XLA, for any shape and type: a scan
+    over checkpointed spans round a scan over a span's chunks."""
     (B, T, G, dk), (H, dv) = q.shape, v.shape[2:]
     R, C = H // G, chunk
     dtype = jnp.dtype(v.dtype)
@@ -212,8 +249,6 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     xs = (spans(q, (G,)), spans(k, (G,)), spans(v, (G, R)),
           spans(g.astype(jnp.float32), (G, R)),
           spans(beta.astype(jnp.float32), (G, R)))
-    if state0 is None:
-        state0 = jnp.zeros((B, H, dk, dv), jnp.float32)
     state, o = lax.scan(
         jax.checkpoint(functools.partial(_span, dtype=dtype),
                        policy=jax.checkpoint_policies.save_only_these_names(
@@ -222,3 +257,466 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     # (S, B, G, R, m, C, dv) -> (B, T, H, dv)
     o = o.transpose(1, 0, 4, 5, 2, 3, 6).reshape(B, S * m * C, H, dv)[:, :T]
     return o, state.reshape(B, H, dk, dv)
+
+
+# ------------------------------------------------------------------- kernels
+STEP_CHUNKS = 4     # chunks a grid step of a kernel works through
+
+
+def _dot(a, b, contract, precision=None):
+    """A product with a float32 result, contracting axis ``contract[0]``
+    of ``a`` with ``contract[1]`` of ``b``."""
+    return lax.dot_general(a, b, (((contract[0],), (contract[1],)), ((), ())),
+                           precision=precision,
+                           preferred_element_type=jnp.float32)
+
+
+def _low(dtype):
+    """(cast to the products' operand type, their precision): one bf16
+    pass for bf16 activations; float32 ones (the tests') at ``HIGHEST``."""
+    return (lambda x: x.astype(dtype)), \
+        (_HI if dtype == jnp.float32 else None)
+
+
+def _column(row, eye):
+    """(1, C) -> (C, 1), exactly: the diagonal of the row laid over (C, C),
+    summed along the lanes."""
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _row(col, eye):
+    """(C, 1) -> (1, C), exactly."""
+    return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
+
+
+def _positions(C):
+    """(C, C) positions i (down) and j (across)."""
+    return (lax.broadcasted_iota(jnp.int32, (C, C), 0),
+            lax.broadcasted_iota(jnp.int32, (C, C), 1))
+
+
+def _decays(rows_ref, ci, r, R, i, j):
+    """Chunk ``ci``, value head ``r``: beta and the cumulative log-decay c
+    as columns (C, 1), c as a row, and c_i - c_j (C, C)."""
+    eye = i == j
+    c_row = rows_ref[ci, pl.ds(r, 1), :]                      # (1, C)
+    b_col = _column(rows_ref[ci, pl.ds(R + r, 1), :], eye)
+    c_col = _column(c_row, eye)
+    return b_col, c_col, c_row, c_col - c_row
+
+
+def _head_parts(rows_ref, t_ref, v_ref, k32, ci, r, R, C, dv, i, j):
+    """Chunk ``ci``, value head ``r``: the decays by row and by column, the
+    (C, C) decay ``exp(c_i - c_j)`` (j <= i, else 0), and ``T [beta v,
+    beta exp(c) k]`` (C, dv + dk) at the solve's precision."""
+    b_col, c_col, c_row, diff = _decays(rows_ref, ci, r, R, i, j)
+    last = jnp.sum(jnp.where(j[:1] == C - 1, c_row, 0.0), axis=1,
+                   keepdims=True)                             # (1, 1)
+    decay = jnp.where(j <= i, jnp.exp(jnp.minimum(diff, 0.0)), 0.0)
+    e_col, f_col = jnp.exp(c_col), jnp.exp(last - c_col)
+    keep = jnp.broadcast_to(jnp.exp(last), (1, dv))   # Mosaic broadcasts
+    #                                     one axis of a (1, 1) at a time
+    v32 = v_ref[pl.ds(ci * C, C), pl.ds(r * dv, dv)].astype(jnp.float32)
+    t = t_ref[ci, :, pl.ds(r * C, C)]                         # (C, C)
+    rhs = jnp.concatenate([b_col * v32, (b_col * e_col) * k32], axis=1)
+    uw = _dot(t, rhs, (1, 0), _HI)
+    return dict(b_col=b_col, diff=diff, decay=decay, e_col=e_col,
+                f_col=f_col, keep=keep, v32=v32, t=t, rhs=rhs,
+                u=uw[:, :dv], w=uw[:, dv:])
+
+
+def _fwd_kernel(rows_ref, q_ref, k_ref, v_ref, t_ref, s0_ref, o_ref, sn_ref,
+                states_ref, s_scr, *, R, m, C):
+    """Grid (batch x key head, step): ``m`` chunks of the R value heads
+    that read one key head; ``s_scr`` carries the R states from step to
+    step, ``states_ref`` keeps the state that entered each chunk."""
+    step = pl.program_id(1)
+    dv = v_ref.shape[-1] // R
+    low, prec = _low(q_ref.dtype)
+
+    @pl.when(step == 0)
+    def _():
+        s_scr[...] = s0_ref[...]
+
+    i, j = _positions(C)
+    for ci in range(m):
+        at = pl.ds(ci * C, C)
+        q, k = q_ref[at, :], k_ref[at, :]
+        q32, k32 = q.astype(jnp.float32), k.astype(jnp.float32)
+        qk = _dot(q, k, (1, 1), prec)
+        for r in range(R):
+            p = _head_parts(rows_ref, t_ref, v_ref, k32, ci, r, R, C, dv,
+                            i, j)
+            state = s_scr[r]
+            states_ref[ci, r] = state
+            s_low = low(state)
+            d = low(p["u"] - _dot(low(p["w"]), s_low, (1, 0), prec))
+            o = _dot(low(p["e_col"] * q32), s_low, (1, 0), prec) \
+                + _dot(low(qk * p["decay"]), d, (1, 0), prec)
+            o_ref[at, pl.ds(r * dv, dv)] = o.astype(o_ref.dtype)
+            s_scr[r] = p["keep"] * state \
+                + _dot(low(p["f_col"] * k32), d, (0, 0), prec)
+
+    @pl.when(step == pl.num_programs(1) - 1)
+    def _():
+        sn_ref[...] = s_scr[...]
+
+
+def _bwd_kernel(rows_ref, q_ref, k_ref, v_ref, t_ref, states_ref, do_ref,
+                dsn_ref, dq_ref, dk_ref, dv_ref, drows_ref, dt_ref, ds0_ref,
+                ds_scr, *, R, m, C):
+    """The forward's grid with the steps, and the chunks of a step, in
+    reverse: ``ds_scr`` carries the cotangent of the R states from the
+    last chunk to the first.  A chunk's matrices are made again from q,
+    k, v, the decays, ``T`` and the state that entered it."""
+    step = pl.program_id(1)
+    dv = v_ref.shape[-1] // R
+    low, prec = _low(q_ref.dtype)
+
+    @pl.when(step == 0)
+    def _():
+        ds_scr[...] = dsn_ref[...]
+
+    i, j = _positions(C)
+    eye = i == j
+    for ci in reversed(range(m)):
+        at = pl.ds(ci * C, C)
+        q, k = q_ref[at, :], k_ref[at, :]
+        q32, k32 = q.astype(jnp.float32), k.astype(jnp.float32)
+        qk = _dot(q, k, (1, 1), prec)
+        kq = _dot(k, q, (1, 1), prec)                    # qk's transpose
+        dq = jnp.zeros(q32.shape, jnp.float32)
+        dk = jnp.zeros(k32.shape, jnp.float32)
+        dqk = jnp.zeros((C, C), jnp.float32)
+        for r in range(R):
+            p = _head_parts(rows_ref, t_ref, v_ref, k32, ci, r, R, C, dv,
+                            i, j)
+            e_col, f_col, b_col, keep = (p[n] for n in (
+                "e_col", "f_col", "b_col", "keep"))
+            state = states_ref[ci, r]
+            s_low, w_low = low(state), low(p["w"])
+            d = low(p["u"] - _dot(w_low, s_low, (1, 0), prec))
+            scores = qk * p["decay"]
+            decay_t = jnp.where(i <= j, jnp.exp(jnp.minimum(-p["diff"], 0.0)),
+                                0.0)
+            q_in, k_out = low(e_col * q32), low(f_col * k32)
+            do = do_ref[at, pl.ds(r * dv, dv)]
+            ds = ds_scr[r]
+            ds_low = low(ds)
+            # the cotangents of d, of the (C, C) scores, and of the three
+            # operands that met the state
+            dd = _dot(low(kq * decay_t), do, (1, 0), prec) \
+                + _dot(k_out, ds_low, (1, 0), prec)
+            dd_low = low(dd)
+            dscores = _dot(do, d, (1, 1), prec)
+            dq_in = _dot(do, s_low, (1, 1), prec)
+            dw = -_dot(dd_low, s_low, (1, 1), prec)
+            dk_out = _dot(d, ds_low, (1, 1), prec)
+            dkeep = jnp.sum(jnp.sum(state * ds, axis=1, keepdims=True),
+                            axis=0, keepdims=True)               # (1, 1)
+            ds_scr[r] = keep * ds + _dot(
+                jnp.concatenate([q_in, -w_low], axis=0),
+                jnp.concatenate([do, dd_low], axis=0), (0, 0), prec)
+            # through T, at the solve's precision
+            duw = jnp.concatenate([dd, dw], axis=1)              # (C, dv+dk)
+            dt = _dot(duw, p["rhs"], (1, 1), _HI)
+            drhs = _dot(p["t"], duw, (0, 0), _HI)
+            dvb, dkb = drhs[:, :dv], drhs[:, dv:]
+            dv_ref[at, pl.ds(r * dv, dv)] = (b_col * dvb).astype(dv_ref.dtype)
+            from_kb = jnp.sum(dkb * k32, axis=1, keepdims=True)
+            from_k_out = f_col * jnp.sum(dk_out * k32, axis=1, keepdims=True)
+            dbeta = jnp.sum(dvb * p["v32"], axis=1, keepdims=True) \
+                + e_col * from_kb
+            dk = dk + (b_col * e_col) * dkb + f_col * dk_out
+            dq = dq + e_col * dq_in
+            through = dscores * scores          # d decay x decay, below
+            dc = (b_col * e_col) * from_kb - from_k_out \
+                + e_col * jnp.sum(dq_in * q32, axis=1, keepdims=True) \
+                + jnp.sum(through, axis=1, keepdims=True)
+            dlast = keep[:, :1] * dkeep + jnp.sum(from_k_out, axis=0, keepdims=True)
+            dc_row = _row(dc, eye) - jnp.sum(through, axis=0, keepdims=True) \
+                + jnp.where(j[:1] == C - 1, dlast, 0.0)
+            drows_ref[ci, pl.ds(r, 1), :] = dc_row
+            drows_ref[ci, pl.ds(R + r, 1), :] = _row(dbeta, eye)
+            dt_ref[ci, :, pl.ds(r * C, C)] = dt
+            dqk = dqk + dscores * p["decay"]
+        dqk_low = low(dqk)
+        dq_ref[at, :] = (dq + _dot(dqk_low, k, (1, 0), prec)
+                         ).astype(dq_ref.dtype)
+        dk_ref[at, :] = (dk + _dot(dqk_low, q, (0, 0), prec)
+                         ).astype(dk_ref.dtype)
+
+    @pl.when(step == pl.num_programs(1) - 1)
+    def _():
+        ds0_ref[...] = ds_scr[...]
+
+
+def _below(i, j, diff):
+    """exp(c_i - c_j) strictly below the diagonal, else 0."""
+    return jnp.where(j < i, jnp.exp(jnp.minimum(diff, 0.0)), 0.0)
+
+
+def _inverses_by_substitution(a):
+    """``(I + a)^-1`` for each strictly lower triangular (C, C) float32
+    matrix of ``a`` (S, C, C), by substitution: row i of an inverse is e_i
+    less the earlier rows times ``a``'s row i, so once row j stands,
+    ``a``'s column j times it goes off every row below.  Float32
+    multiplies and adds on the vector unit, a tile of 8 rows set aside as
+    it is finished (it has nothing more to take), the S systems in one array and so
+    step for step: one alone waits on its own row j 63 times over (my chip
+    run, PR 58: 7.0 ms a layer one after another, 4.9 eight in step), and
+    a step traced once for all of them is an eighth of the operations to
+    trace each time the step is built."""
+    S, C, _ = a.shape
+    pos = lax.broadcasted_iota(jnp.int32, (S, C, C), 2) \
+        - lax.broadcasted_iota(jnp.int32, (S, C, C), 1)
+    below = (pos == 0).astype(jnp.float32)      # the rows not yet finished
+    done = []
+    for j in range(C - 1):
+        if j % 8 == 0 and j:                    # a tile of 8 rows stands
+            done.append(below[:, :8])
+            below, a = below[:, 8:], a[:, 8:]
+        below = below - a[:, :, j:j + 1] * below[:, j % 8:j % 8 + 1]
+    return jnp.concatenate(done + [below], axis=1)
+
+
+def _solve_kernel(rows_ref, k_ref, t_ref, *, R, m, C):
+    """Grid (batch x key head, step): the solved systems ``T = (I + A)^-1``
+    of ``m`` chunks' R value heads, the heads' side by side."""
+    _, prec = _low(k_ref.dtype)
+    i, j = _positions(C)
+    systems = []
+    for ci in range(m):
+        k = k_ref[pl.ds(ci * C, C), :]
+        kk = _dot(k, k, (1, 1), prec)
+        for r in range(R):
+            b_col, _, _, diff = _decays(rows_ref, ci, r, R, i, j)
+            systems.append(b_col * (kk * _below(i, j, diff)))
+    t = _inverses_by_substitution(jnp.stack(systems))
+    for at in range(m * R):
+        t_ref[at // R, :, pl.ds(at % R * C, C)] = t[at]
+
+
+def _solve_bwd_kernel(rows_ref, k_ref, t_ref, dt_ref, dk_ref, drows_ref, *,
+                      R, m, C):
+    """``_solve_kernel``'s backward: ``dA = -T^T dT T^T`` below the
+    diagonal, and from it the cotangents of k (through k . k, summed over
+    the R value heads), of the log-decays and of beta."""
+    low, prec = _low(k_ref.dtype)
+    i, j = _positions(C)
+    eye = i == j
+    for ci in range(m):
+        at = pl.ds(ci * C, C)
+        k = k_ref[at, :]
+        kk = _dot(k, k, (1, 1), prec)
+        dkk = jnp.zeros((C, C), jnp.float32)
+        for r in range(R):
+            b_col, _, _, diff = _decays(rows_ref, ci, r, R, i, j)
+            decay = _below(i, j, diff)
+            t = t_ref[ci, :, pl.ds(r * C, C)]
+            da = -_dot(t, _dot(dt_ref[ci, :, pl.ds(r * C, C)], t, (1, 1),
+                               _HI), (0, 0), _HI)
+            below = kk * decay
+            scaled = b_col * da * decay            # zero on and above
+            through = scaled * kk
+            drows_ref[ci, pl.ds(r, 1), :] = _row(
+                jnp.sum(through, axis=1, keepdims=True), eye) \
+                - jnp.sum(through, axis=0, keepdims=True)
+            drows_ref[ci, pl.ds(R + r, 1), :] = _row(
+                jnp.sum(da * below, axis=1, keepdims=True), eye)
+            dkk = dkk + scaled
+        dkk = low(dkk)
+        dk_ref[at, :] = (_dot(dkk, k, (1, 0), prec)
+                         + _dot(dkk, k, (0, 0), prec)).astype(dk_ref.dtype)
+
+
+# name -> (body, operands, results, steps from the last to the first, a
+# state carried from step to step in scratch), operands and results by the
+# names of ``_kernel``'s specs
+_KERNELS = {
+    "delta_rule_solve": (_solve_kernel, ("rows", "qk"), ("t",), False, False),
+    "delta_rule_fwd": (_fwd_kernel, ("rows", "qk", "qk", "v", "t", "state"),
+                       ("v", "state", "states"), False, True),
+    "delta_rule_bwd": (_bwd_kernel, ("rows", "qk", "qk", "v", "t", "states",
+                                     "v", "state"),
+                       ("qk", "qk", "v", "rows", "t", "state"), True, True),
+    "delta_rule_solve_bwd": (_solve_bwd_kernel, ("rows", "qk", "t", "t"),
+                             ("qk", "rows"), False, False),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel(name, B, G, N, R, C, dk, dv, dtype, m, interpret):
+    """One of the four kernels at one shape: grid (batch x key head ``bg``,
+    step ``n`` of ``m`` chunks), q, k, v as (B, T, heads x width) column
+    blocks.  The ``pallas_call`` stands inside a jitted function of the
+    kernel's name: in the Qwen3-Next step the v5e names a custom call
+    after the function it stands in (``gated_delta_rule.76``) and,
+    standing in none, ``tpu_custom_call.149`` (my chip runs, PR 58), the
+    name ``kernels.custom_call_ms`` reads the flash kernels by.  Kept, so
+    that building a step traces a kernel's body once however often
+    ``custom_vjp`` and a checkpoint ask for it (a step's ``setup_s``)."""
+    from jax.experimental.pallas import tpu as pltpu
+    body, operands, results, back, carried = _KERNELS[name]
+    steps = N // m
+    at = (lambda n: steps - 1 - n) if back else (lambda n: n)
+    by_time = lambda width: (
+        jax.ShapeDtypeStruct((B, N * C, G * width), dtype),
+        pl.BlockSpec((None, m * C, width),
+                     lambda bg, n: (bg // G, at(n), bg % G)))
+    by_chunk = lambda *shape: (
+        jax.ShapeDtypeStruct((B * G, N, *shape), jnp.float32),
+        pl.BlockSpec((None, m, *shape),
+                     lambda bg, n: (bg, at(n)) + (0,) * len(shape)))
+    of = dict(qk=by_time(dk), v=by_time(R * dv), rows=by_chunk(2 * R, C),
+              t=by_chunk(C, R * C), states=by_chunk(R, dk, dv),
+              state=(jax.ShapeDtypeStruct((B * G, R, dk, dv), jnp.float32),
+                     pl.BlockSpec((None, R, dk, dv),
+                                  lambda bg, n: (bg, 0, 0, 0))))
+
+    def run(*args):
+        return pl.pallas_call(
+            functools.partial(body, R=R, m=m, C=C), grid=(B * G, steps),
+            in_specs=[of[x][1] for x in operands],
+            out_specs=[of[x][1] for x in results],
+            out_shape=[of[x][0] for x in results],
+            scratch_shapes=[pltpu.VMEM((R, dk, dv), jnp.float32)] * carried,
+            compiler_params=pltpu.CompilerParams(dimension_semantics=(
+                "parallel", "arbitrary" if carried else "parallel")),
+            interpret=interpret, name=name)(*args)
+    run.__name__ = run.__qualname__ = name
+    return jax.jit(run)
+
+
+def _call(name, rows, q, v, G, interpret, *args):
+    """Kernel ``name`` at the shape of rows (B G, N, 2 R, C), q (B, T, G
+    dk) and v (B, T, G R dv), on ``args``."""
+    BG, N, R2, C = rows.shape
+    R = R2 // 2
+    return _kernel(name, BG // G, G, N, R, C, q.shape[-1] // G,
+                   v.shape[-1] // (G * R), jnp.dtype(q.dtype),
+                   min(STEP_CHUNKS, N), interpret)(*args)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _solve(rows, k, G, interpret):
+    """rows, k as ``_rule`` takes them -> ``T = (I + A)^-1`` (B G, N, C,
+    R C) float32, ``A_ij = beta_i (k_i . k_j) exp(c_i - c_j)`` below the
+    diagonal: the chunk's k . k, the decays and the substitution in a
+    kernel, so that of a chunk's (C, C) matrices only ``T`` reaches HBM."""
+    return _call("delta_rule_solve", rows, k, k, G, interpret, rows, k)[0]
+
+
+def _solve_fwd(rows, k, G, interpret):
+    # named here, so that what a caller's checkpoint keeps is the value
+    # the backward reads too, and the kernel is not run again for it
+    t = checkpoint_name(_solve(rows, k, G, interpret), INVERSE)
+    return t, (rows, k, t)
+
+
+def _solve_bwd(G, interpret, res, dt):
+    rows, k, t = res
+    dk, drows = _call("delta_rule_solve_bwd", rows, k, k, G, interpret,
+                      rows, k, t, dt)
+    return drows, dk
+
+
+_solve.defvjp(_solve_fwd, _solve_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _rule(rows, q, k, v, t, state0, G, interpret):
+    """The chunks' work that follows the solve, in a kernel: rows (B G, N,
+    2 R, C) float32, a chunk's cumulative log-decays of the R value heads
+    of a key head and then their betas; q, k (B, T, G dk), v (B, T, H dv)
+    as the projections leave them; t (B G, N, C, R C) the solved systems,
+    the R heads' side by side; state0 (B G, R, dk, dv) float32 -> (o like
+    v, the last state like state0)."""
+    return _rule_fwd(rows, q, k, v, t, state0, G, interpret)[0]
+
+
+def _rule_fwd(rows, q, k, v, t, state0, G, interpret):
+    """Also the state entering every chunk (B G, N, R, dk, dv) float32,
+    which the backward reads: one kernel either way, so that a step is
+    built from one trace of it (a call that is not differentiated writes
+    them and drops them: 0.2 ms a layer at the cell's shape)."""
+    o, state, states = _call("delta_rule_fwd", rows, q, v, G, interpret,
+                             rows, q, k, v, t, state0)
+    return (o, state), (rows, q, k, v, t, states)
+
+
+def _rule_bwd(G, interpret, res, cts):
+    rows, q, k, v, t, states = res
+    do, dsn = cts
+    dq, dk, dv, drows, dt, ds0 = _call(
+        "delta_rule_bwd", rows, q, v, G, interpret, *res, do,
+        dsn.astype(jnp.float32))
+    return drows, dq, dk, dv, dt, ds0
+
+
+_rule.defvjp(_rule_fwd, _rule_bwd)
+
+
+def delta_rule_chunks(q, k, v, g, beta, state0, *, chunk: int = CHUNK,
+                      interpret: bool = False):
+    """``gated_delta_rule`` with the chunks' work in Pallas kernels
+    (``delta_rule_fwd``, ``delta_rule_bwd``): same arguments and results.
+    XLA makes the cumulative log-decays a chunk; one kernel the solved
+    systems ``T`` (kept across a caller's checkpoint by the name
+    ``INVERSE``: the solve is not run again), another everything that
+    follows; each has its backward written out as a kernel."""
+    (B, T, G, dk), (H, dv) = q.shape, v.shape[2:]
+    R, C = H // G, chunk
+    n = -(-T // C)
+    m = min(STEP_CHUNKS, n)
+    pad = -T % (C * m)
+    N = (T + pad) // C
+
+    def padded(x):
+        return jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+
+    def by_head(x):
+        """(B, T, H) -> (B, G, N, R, C) float32."""
+        x = padded(x.astype(jnp.float32)).reshape(B, N, C, G, R)
+        return x.transpose(0, 3, 1, 4, 2)
+    rows = jnp.concatenate([jnp.cumsum(by_head(g), axis=-1), by_head(beta)],
+                           axis=3).reshape(B * G, N, 2 * R, C)
+    q, k, v = (padded(x).reshape(B, N * C, -1) for x in (q, k, v))
+    o, state = _rule(
+        rows, q, k, v, _solve(rows, k, G, interpret),
+        state0.astype(jnp.float32).reshape(B * G, R, dk, dv), G, interpret)
+    return o.reshape(B, N * C, H, dv)[:, :T], state.reshape(B, H, dk, dv)
+
+
+def _kernels_run(q, v, chunk: int) -> bool:
+    """Whether a call's chunks run in the kernels: on a TPU, bf16
+    activations, chunks of 64, heads of whole 128-lane blocks and whole
+    groups of value heads a key head.  Everything else (float32, the
+    tests' 8-wide heads, other chunk sizes, the CPU) takes the XLA form."""
+    (G, dk), (H, dv) = q.shape[2:], v.shape[2:]
+    return (jax.default_backend() == "tpu" and chunk == CHUNK
+            and q.dtype == v.dtype == jnp.bfloat16
+            and dk % 128 == 0 and dv % 128 == 0 and H % G == 0)
+
+
+def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+                     beta: jax.Array, chunk: int = CHUNK,
+                     state0: Optional[jax.Array] = None
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """The recurrence of :func:`delta_rule_step` over a sequence.
+
+    q, k (B, T, G, dk), the keys l2-normed and the queries scaled by the
+    caller; v (B, T, H, dv) with H = G R: value head h reads key head
+    h // R (no repeated copy of q or k is made); g, beta (B, T, H);
+    state0 (B, H, dk, dv) float32 or None for zeros.  Returns (o (B, T, H,
+    dv) in v's type, the state after the last position (B, H, dk, dv)
+    float32).  Products that are no part of the solve take operands of
+    v's type (the module's head).  Which form runs is read from the call
+    (``_kernels_run``): both are the same chunked algorithm at the same
+    precisions."""
+    if state0 is None:
+        state0 = jnp.zeros((q.shape[0], *v.shape[2:3], q.shape[3],
+                            v.shape[3]), jnp.float32)
+    if _kernels_run(q, v, chunk):
+        return delta_rule_chunks(q, k, v, g, beta, state0, chunk=chunk)
+    return _spans_form(q, k, v, g, beta, chunk, state0)

@@ -59,8 +59,9 @@ def _kernel_results(text):
 def _kernel_names_and_results(text):
     """(instruction name, first result) of every Mosaic kernel of a
     compiled module: what a reducer of ``perfbench`` matches a kernel by
-    (``gmm.37``, ``bf16[98304,2048]``)."""
-    return re.findall(r"%(\S+) = \(?(bf16\[[\d,]+\])\S* .*custom-call\(.*"
+    (``gmm.37``, ``bf16[98304,2048]``; ``delta_rule_solve.1``,
+    ``f32[32,128,64,128]``)."""
+    return re.findall(r"%(\S+) = \(?(\w+\[[\d,]+\])\S* .*custom-call\(.*"
                       r'custom_call_target="tpu_custom_call"', text)
 
 
@@ -210,23 +211,53 @@ def _rule_loss(q, k, v, g, beta):
     return gated_delta_rule(q, k, v, g, beta)[0].astype(jnp.float32).sum()
 
 
-def test_the_delta_rule_compiles_at_qwen3_next_train_shape(v5e):
+@pytest.mark.parametrize("dtype", [
+    pytest.param(jnp.bfloat16, id="bf16_in_kernels"),
+    pytest.param(jnp.float32, id="float32_in_xla")])
+def test_the_delta_rule_compiles_at_qwen3_next_train_shape(v5e, monkeypatch,
+                                                           dtype):
     """``ops/delta_rule.gated_delta_rule`` forward and backward at 2 x
-    8,192 positions, 16 key and 32 value heads of 128 in bf16: plain XLA
-    (no Mosaic kernel), a scan over 8 spans round a scan over 16 chunks,
-    the solve's float32 products at their precision, and no more alive
-    at once than a span's matrices: under 1.5 GB of temporaries where the
-    whole sequence's would be 3 GB."""
-    shapes = [((2, 8192, 16, 128), jnp.bfloat16)] * 2 \
-        + [((2, 8192, 32, 128), jnp.bfloat16)] \
+    8,192 positions, 16 key and 32 value heads of 128, on a TPU.  In bf16
+    (the cell) four Mosaic kernels under their own names, which
+    ``gdn.rule_kernel_ms`` reads and the ``tpu_custom_call`` metrics do
+    not: the solve, what follows it, and each one's backward; no scan is
+    left, of a chunk's (64, 64) matrices only the solved systems ``T``
+    and their cotangent reach HBM, the R = 2 heads' side by side in 128
+    lanes (``f32[32,128,64,128]``, 134 MB), and the temporaries stay under
+    the 1.5 GB that the XLA form's spans were held to (the states that
+    enter the 128 chunks, kept for the backward, are 537 MB of them).
+    Float32 activations take the XLA form at the same shape: no kernel,
+    two scans each both ways, a span's solved systems."""
+    import json
+    from pathlib import Path
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shapes = [((2, 8192, 16, 128), dtype)] * 2 \
+        + [((2, 8192, 32, 128), dtype)] \
         + [((2, 8192, 32), jnp.float32)] * 2
     args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
     compiled = jax.jit(jax.grad(_rule_loss, argnums=(0, 1, 2, 3, 4))) \
         .lower(*args).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text
-    assert text.count(" while(") >= 4          # two scans, each both ways
-    assert "f32[2,16,2,16,64,64]" in text      # a span's solved systems
+    if dtype == jnp.float32:
+        assert "tpu_custom_call" not in text
+        assert text.count(" while(") >= 4          # two scans, each both ways
+        assert "f32[2,16,2,16,64,64]" in text      # a span's solved systems
+        return
+    kernels = _kernel_names_and_results(text)
+    assert sorted((name.split(".")[0], shape) for name, shape in kernels) == [
+        ("delta_rule_bwd", "bf16[2,8192,2048]"),
+        ("delta_rule_fwd", "bf16[2,8192,4096]"),
+        ("delta_rule_solve", "f32[32,128,64,128]"),
+        ("delta_rule_solve_bwd", "bf16[2,8192,2048]")], kernels
+    spec = json.loads((Path(__file__).parent.parent / "perfbench"
+                       / "layer_metrics" / "gdn.rule_kernel_ms.json"
+                       ).read_text())["params"]
+    for name, shape in kernels:
+        assert shape in spec["shapes"]
+        assert any(part in name for part in spec["names"])
+        assert "tpu_custom_call" not in name
+    assert " while(" not in text
+    assert not re.search(r"f32\[[\d,]*,64,64\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
