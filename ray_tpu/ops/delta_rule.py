@@ -720,3 +720,223 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     if _kernels_run(q, v, chunk):
         return delta_rule_chunks(q, k, v, g, beta, state0, chunk=chunk)
     return _spans_form(q, k, v, g, beta, chunk, state0)
+
+
+# ------------------------------------------- the per-channel gate (Kimi KDA)
+# Kimi Delta Attention: the same rule with a decay a CHANNEL of the key,
+# ``S <- diag(exp(g_t)) S`` with ``g_t`` (dk,), -5 < g < 0 (the model's
+# ``kda_safe_gate``).  The decay then does not factor out of ``k_i . k_j``
+# as a head's one number does: ``A_ij = beta_i sum_c k_ic k_jc exp(G_ic -
+# G_jc)`` with ``G`` the running sum of g inside the chunk.  The products
+# are formed from ``a_i exp(G_i - G_ref)`` and ``b_j exp(G_ref - G_j)`` in
+# float32 with ``G_ref`` at the first position of the ROW's block of
+# ``KDA_BLOCK`` positions: for the columns of earlier blocks both
+# exponents are <= 0, for the row's own block the second is at most
+# (KDA_BLOCK - 1) x 5 = 75 < 88, where float32 (and bf16, whose exponent
+# is float32's) still holds it; what lies past the diagonal is masked and
+# its exponent held at that bound.  Plain XLA, forward only (the family is
+# served, not trained: ROADMAP, Reach), float32 and bf16 operands as in
+# ``_span``.  The scalar-gated forms above are untouched.
+KDA_BLOCK = 16
+_KDA_MAX_EXPONENT = 80.0
+
+
+def kda_step(state: jax.Array, q: jax.Array, k: jax.Array, v: jax.Array,
+             g: jax.Array, beta: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """One token: state (..., dk, dv) float32, q, k, g (..., dk), v (...,
+    dv), beta (...) -> (o (..., dv) float32, the state one token on).
+    ``o = S^T q`` is taken from the decayed state and the correction, ``S1^T
+    q + (k . q) d``, so that the state is read once for both products."""
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    state = state * jnp.exp(g.astype(jnp.float32))[..., :, None]
+    seen = jnp.einsum("...kv,...nk->...nv", state, jnp.stack([k, q], -2),
+                      precision=_HI)
+    d = beta.astype(jnp.float32)[..., None] * (v - seen[..., 0, :])
+    o = seen[..., 1, :] + (k * q).sum(-1, keepdims=True) * d
+    return o, state + k[..., :, None] * d[..., None, :]
+
+
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def kda_chunks(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+               beta: jax.Array, state0: jax.Array, chunk: int = CHUNK
+               ) -> Tuple[jax.Array, jax.Array]:
+    """The recurrence of :func:`kda_step` over a sequence, in chunks.
+
+    q, k (B, T, H, dk), the keys l2-normed and the queries scaled by the
+    caller; v (B, T, H, dv); g (B, T, H, dk) with ``g >= -80 / (KDA_BLOCK -
+    1)``; beta (B, T, H); state0 (B, H, dk, dv) float32.  Returns (o (B, T,
+    H, dv) in v's type, the state after the last position).  A position
+    with ``g = 0`` and ``beta = 0`` leaves the state as it is (padding)."""
+    (B, T, H, dk), dv = q.shape, v.shape[-1]
+    C, blk = chunk, min(KDA_BLOCK, chunk)
+    nb, dtype, f32 = C // blk, jnp.dtype(v.dtype), jnp.float32
+    pad = -T % C
+    n = (T + pad) // C
+
+    def chunks(x):
+        """(B, T, H, ...) -> (B, H, n, C, ...), padded with zeros."""
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        return jnp.moveaxis(x.reshape(B, n, C, H, *x.shape[3:]), 3, 1)
+
+    q32, k32 = chunks(q).astype(f32), chunks(k).astype(f32)
+    v32, bc = chunks(v).astype(f32), chunks(beta.astype(f32))
+    G = jnp.cumsum(chunks(g.astype(f32)), axis=3)            # (B,H,n,C,dk)
+    ref = G[:, :, :, ::blk]                                  # (B,H,n,nb,dk)
+    # a row against its block's reference, a column against each row
+    # block's: exponents <= 0 and <= _KDA_MAX_EXPONENT
+    down = jnp.exp(G.reshape(B, H, n, nb, blk, dk) - ref[..., None, :])
+    up = jnp.exp(jnp.minimum(ref[..., None, :] - G[:, :, :, None],
+                             _KDA_MAX_EXPONENT))             # (B,H,n,nb,C,dk)
+    k_cols = k32[:, :, :, None] * up
+    rows = lambda x: x.reshape(B, H, n, nb, blk, dk) * down  # noqa: E731
+    kk = jnp.einsum("bhnrid,bhnrjd->bhnrij", rows(k32), k_cols,
+                    precision=_HI).reshape(B, H, n, C, C)
+    qk = _product("bhnrid,bhnrjd->bhnrij", rows(q32), k_cols,
+                  dtype).reshape(B, H, n, C, C)
+    pos = jnp.arange(C)
+    t = inverse_unit_lower(jnp.where(pos[:, None] > pos[None, :],
+                                     kk * bc[..., None], 0.0))
+    into = jnp.exp(G)                                        # from S0 on
+    u = _mm(t, bc[..., None] * v32)                          # (B,H,n,C,dv)
+    w = _mm(t, bc[..., None] * into * k32)                   # (B,H,n,C,dk)
+    scores = jnp.where(pos[:, None] >= pos[None, :], qk, 0.0).astype(dtype)
+    q_in = (into * q32).astype(dtype)
+    k_out = (jnp.exp(G[:, :, :, -1:] - G) * k32).astype(dtype)
+    keep = jnp.exp(G[:, :, :, -1])                           # (B,H,n,dk)
+
+    def chunk_step(state, xs):
+        u, w, scores, q_in, k_out, keep = xs
+        low = state.astype(dtype)
+        d = u - _product("bhik,bhkv->bhiv", w, low, dtype)
+        o = _product("bhik,bhkv->bhiv", q_in, low, dtype) \
+            + _product("bhij,bhjv->bhiv", scores, d, dtype)
+        state = keep[..., None] * state \
+            + _product("bhik,bhiv->bhkv", k_out, d, dtype)
+        return state, o.astype(dtype)
+
+    lead = lambda x: jnp.moveaxis(x, 2, 0)                   # noqa: E731
+    state, o = lax.scan(chunk_step, state0.astype(f32), (
+        lead(u), lead(w.astype(dtype)), lead(scores), lead(q_in),
+        lead(k_out), lead(keep)))
+    # (n, B, H, C, dv) -> (B, T, H, dv)
+    o = o.transpose(1, 0, 3, 2, 4).reshape(B, n * C, H, dv)[:, :T]
+    return o, state
+
+
+# ---------------------------------------- a decode step's rows, in one pass
+# A decode step of a served model steps B of a store's R rows of state,
+# ``store`` (layers, R, H, dk, dv) float32, 2 MB a row at 32 heads of 128.
+# Gathered, stepped and scattered by XLA that is three passes over the
+# rows and two copies of them (0.82 GB a layer step at 64 rows: 2.3 ms
+# where the bytes are 0.33: my chip run, PR 59).  ``kda_step_rows`` does
+# it in place on a TPU: a Pallas kernel whose grid walks the batch rows,
+# reads a row's heads where they lie (the row named by a prefetched
+# scalar), steps them on the vector unit and writes them back to the same
+# place, the store aliased to the result.  Everything a head's step needs
+# besides its state is made by XLA from the (B, H, .) operands (a few MB):
+# the four vectors that scale the state's ROWS (exp(g), k, k exp(g), q
+# exp(g)) as columns, (B, dk, 4 H) with a (kind, head) a lane (128 lanes at
+# 32 heads: laid out eight heads a block the lanes were 8 of 128 and the
+# array sixteen times its size), and the three that scale its COLUMNS
+# (beta v, beta, q . k) as rows (B, 3, H, dv), so the kernel transposes
+# nothing.  Elsewhere (the CPU, other shapes) it is the gather,
+# ``kda_step`` and the scatter.
+_ROW_BYTES = 4 << 20    # the most one row's heads may take of VMEM, each way
+
+
+def _kda_rows_kernel(rows_ref, layer_ref, c_ref, r_ref, s_ref, o_ref, s_out,
+                     *, heads, n_rows):
+    """One batch row: c_ref (1, dk, 4 heads) the columns, lane ``i heads +
+    h`` kind i of head h; r_ref (1, 3, heads, dv) the rows; s_ref / s_out
+    (1, 1, heads, dk, dv) the row's states; o_ref (1, heads, dv).  A batch
+    row that names no row of the store (padding up to the bucket) reads the
+    store's last row and writes it back as it is."""
+    del layer_ref
+    live = rows_ref[pl.program_id(0)] < n_rows
+
+    @pl.when(live)
+    def _():
+        for h in range(heads):
+            state = s_ref[0, 0, h]                              # (dk, dv)
+            col = lambda i: c_ref[                              # noqa: E731
+                0, :, i * heads + h:i * heads + h + 1]          # (dk, 1)
+            row = lambda i: r_ref[0, i, h:h + 1, :]             # noqa: E731
+            seen = jnp.sum(state * col(2), axis=0, keepdims=True)
+            read = jnp.sum(state * col(3), axis=0, keepdims=True)
+            d = row(0) - row(1) * seen                          # (1, dv)
+            o_ref[0, h:h + 1, :] = read + row(2) * d
+            s_out[0, 0, h] = state * col(0) + col(1) * d
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        s_out[...] = s_ref[...]
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _kda_rows_call(store, layer, rows, q, k, v, g, beta, *, interpret=False):
+    from jax.experimental.pallas import tpu as pltpu
+
+    n_layer, n_rows, H, dk, dv = store.shape
+    B, f32 = q.shape[0], jnp.float32
+    q, k, v = (a.astype(f32) for a in (q, k, v))
+    decay = jnp.exp(g.astype(f32))
+    beta = beta.astype(f32)[..., None]
+    # (B, 4, H, dk) -> (B, dk, 4 H): a (kind, head) a lane
+    cols = jnp.stack([decay, k, k * decay, q * decay], axis=1)
+    cols = cols.reshape(B, 4 * H, dk).transpose(0, 2, 1)
+    vecs = jnp.stack([beta * v, jnp.broadcast_to(beta, v.shape),
+                      jnp.broadcast_to((q * k).sum(-1, keepdims=True),
+                                       v.shape)], axis=1)       # (B,3,H,dv)
+
+    def at_row(b, rows_ref, layer_ref):
+        return (layer_ref[0], jnp.minimum(rows_ref[b], n_rows - 1), 0, 0, 0)
+
+    o, store = pl.pallas_call(
+        functools.partial(_kda_rows_kernel, heads=H, n_rows=n_rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, dk, 4 * H), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec((1, 3, H, dv), lambda b, *_: (b, 0, 0, 0)),
+                pl.BlockSpec((1, 1, H, dk, dv), at_row),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, H, dv), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec((1, 1, H, dk, dv), at_row),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((B, H, dv), f32),
+                   jax.ShapeDtypeStruct(store.shape, store.dtype)],
+        # operands: rows, layer, cols, vecs, store -> the store is result 1
+        input_output_aliases={4: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            # a row's heads in and out, each double-buffered
+            vmem_limit_bytes=4 * H * dk * dv * 4 + (8 << 20)),
+        interpret=interpret,
+        name="kda_step_rows",
+    )(rows.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      cols, vecs, store)
+    return o, store
+
+
+def kda_step_rows(store: jax.Array, layer, rows: jax.Array, q: jax.Array,
+                  k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """:func:`kda_step` on rows of a store, written back where they lie.
+
+    store (layers, R, H, dk, dv) float32; ``layer`` which of its layers;
+    ``rows`` (B,) int32 the row each batch row steps, DISTINCT, one ``>= R``
+    naming none (it reads the store's LAST row and writes it back as it
+    is, so no batch row may name that one: it is the engine's staging
+    row, which no sequence holds); q, k, g (B, H, dk),
+    v (B, H, dv), beta (B, H).  Returns (o (B, H, dv) float32, the store
+    with the named rows one token on)."""
+    n_rows, H, dk, dv = store.shape[1:]
+    if jax.default_backend() == "tpu" and store.dtype == jnp.float32 \
+            and dk % 128 == 0 and dv % 128 == 0 \
+            and H * dk * dv * 4 <= _ROW_BYTES:
+        return _kda_rows_call(store, layer, rows, q, k, v, g, beta)
+    state = store[layer][jnp.minimum(rows, n_rows - 1)]
+    o, state = kda_step(state, q, k, v, g, beta)
+    return o, store.at[layer, rows].set(state, mode="drop")

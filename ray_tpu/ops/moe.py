@@ -646,19 +646,28 @@ def route_softmax(x: jax.Array, w_router: jax.Array, k: int,
 
 
 def route_sigmoid(x: jax.Array, w_router: jax.Array, select_bias: jax.Array,
-                  k: int, weight_scale: float, eps: float = 1e-20):
+                  k: int, weight_scale: float, eps: float = 1e-20,
+                  n_group: int = 1, topk_group: int = 1):
     """-> (expert_idx (N, k), weights (N, k)): float32 sigmoid scores over
     all E experts; the k chosen are the top of score + ``select_bias``
     (the bias decides the choice and never a weight: it balances the load
     with no auxiliary loss and has no gradient), and their weights are
     the scores alone, renormalised over the k chosen and scaled.
     ``eps`` is what the family adds to the divisor (DeepSeek-V3's 1e-20,
-    LFM2's 1e-6): part of its arithmetic, not a setting."""
+    LFM2's 1e-6): part of its arithmetic, not a setting.
+
+    ``n_group`` > 1 (DeepSeek-V3's group-limited routing): the experts lie
+    in ``n_group`` equal groups by id, a group's score is the sum of its
+    two largest score + bias, and the k are chosen among the experts of
+    the ``topk_group`` best groups.  At ``n_group`` 1 nothing of that is
+    traced."""
     logits = jnp.dot(x, w_router.astype(x.dtype),
                      preferred_element_type=jnp.float32)         # (N, E)
     scores = jax.nn.sigmoid(logits)
-    _, expert_idx = jax.lax.top_k(
-        scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)), k)
+    select = scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32))
+    if n_group > 1:
+        select = _within_best_groups(select, n_group, topk_group)
+    _, expert_idx = jax.lax.top_k(select, k)
     chosen = jnp.take_along_axis(scores, expert_idx, axis=-1)
     weights = chosen / (chosen.sum(-1, keepdims=True) + eps)
     return expert_idx, weights * weight_scale
@@ -818,3 +827,17 @@ def init_moe_params(rng: jax.Array, d_model: int, d_ff: int,
         "w_out": (jax.random.normal(ko, (num_experts, d_ff, d_model))
                   * scale_out).astype(dtype),
     }
+
+
+def _within_best_groups(select: jax.Array, n_group: int,
+                        topk_group: int) -> jax.Array:
+    """select (N, E) with the experts outside each row's ``topk_group``
+    best groups (of ``n_group`` by id; a group's score the sum of its two
+    largest entries) at -inf: no top-k over it names one of them."""
+    n, e = select.shape
+    by_group = select.reshape(n, n_group, e // n_group)
+    group_score = jax.lax.top_k(by_group, 2)[0].sum(-1)          # (N, G)
+    _, best = jax.lax.top_k(group_score, topk_group)
+    kept = jnp.zeros((n, n_group), bool).at[
+        jnp.arange(n)[:, None], best].set(True)
+    return jnp.where(kept[:, :, None], by_group, -jnp.inf).reshape(n, e)

@@ -521,3 +521,194 @@ def paged_attention_decode(q: jax.Array, kv_pool: jax.Array, layer,
                                     window=window)
     return _paged_decode_gather(q, kv_pool, layer, block_tables, ctx_lens,
                                 k_new, v_new, pages, counts, window)
+
+
+# ------------------------------------------------------------- latent rows
+# A latent page (``serve/llm/kv_cache.py``, the latent row): a layer of
+# multi-head latent attention caches ONE row a position, ``[c | k_rope]``,
+# in a pool of one plane, ``(L, 1, N, bs, F)``.  Absorbed, every query head
+# scores that row whole (its first ``value_lanes`` lanes through the
+# up-projection folded into the query, the rest the rotary key) and sums
+# its first ``value_lanes`` lanes as the value: multi-query attention whose
+# pages are read once for keys and values.  The same two paths as above,
+# chosen the same way.
+_LATENT_CHUNK_TOKENS = 256
+
+
+def _latent_decode_gather(q, pool, layer, block_tables, ctx_lens, row_new,
+                          value_lanes):
+    """Gather-then-mask: the CPU path and the kernel's reference."""
+    f32 = jnp.float32
+    with jax.named_scope("paged_gather"):
+        rows = jnp.take(pool[layer, 0], block_tables.reshape(-1), axis=0)
+        rows = rows.reshape(q.shape[0], -1, pool.shape[-1])     # (B, T, F)
+    with jax.named_scope("paged_attention"):
+        logits = jnp.einsum("bhf,btf->bht", q, rows,
+                            preferred_element_type=f32,
+                            precision=lax.Precision.HIGHEST)
+        valid = jnp.arange(rows.shape[1])[None, :] < ctx_lens[:, None]
+        logits = jnp.where(valid[:, None, :], logits, NEG_INF)
+        own = jnp.einsum("bhf,bf->bh", q, row_new,
+                         preferred_element_type=f32,
+                         precision=lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(
+            jnp.concatenate([logits, own[..., None]], axis=-1), axis=-1)
+        values = jnp.where(valid[:, :, None], rows[..., :value_lanes], 0.0)
+        out = jnp.einsum("bht,btv->bhv", probs[..., :-1], values.astype(f32),
+                         precision=lax.Precision.HIGHEST)
+        return out + probs[..., -1:] * row_new[:, None, :value_lanes]
+
+
+def _latent_kernel(tables_ref, lens_ref, layer_ref, q_ref, new_ref, pool_hbm,
+                   o_ref, buf, sems, m_ref, l_ref, acc_ref, *, low):
+    """One sequence (grid step): walk its latent pages, a chunk at a time.
+    q_ref (1, H, F) the absorbed queries, pre-scaled, zero in the padding
+    lanes; new_ref (1, 1, F) the new token's own row; pool_hbm (L, 1, N,
+    bs, F) left where it is; buf (2, C, bs, F) the landing buffers.  A
+    page's rows are keys and values at once: the accumulator keeps all F
+    lanes and the caller cuts the value's.  ``low``: the type the two
+    products take their operands in (the rows were made in it)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    _, chunk, bs, f = buf.shape
+    t = chunk * bs
+    ctx = lens_ref[b]
+    layer = layer_ref[0]
+    n_blocks = pl.cdiv(ctx, bs)
+    n_chunks = pl.cdiv(n_blocks, chunk)
+    precision = lax.Precision.HIGHEST if low == jnp.float32 else None
+
+    def copies(c, slot):
+        out = []
+        for i in range(chunk):
+            j = c * chunk + i
+            blk = tables_ref[b, jnp.minimum(j, tables_ref.shape[1] - 1)]
+            out.append((j < n_blocks, pltpu.make_async_copy(
+                pool_hbm.at[layer, 0, blk], buf.at[slot, i], sems.at[slot])))
+        return out
+
+    def start(c, slot):
+        for live, copy in copies(c, slot):
+            @pl.when(live)
+            def _():
+                copy.start()
+
+    def wait(c, slot):
+        for live, copy in copies(c, slot):
+            @pl.when(live)
+            def _():
+                copy.wait()
+
+    @pl.when(n_chunks > 0)
+    def _():
+        start(0, 0)
+
+    q = q_ref[0]                                                # (H, F)
+    new = new_ref[0]                                            # (1, F)
+    m_ref[...] = jnp.sum(q * new, axis=-1, keepdims=True)
+    l_ref[...] = jnp.ones_like(l_ref)
+    acc_ref[...] = jnp.broadcast_to(new, acc_ref.shape)
+    q_low = q.astype(low)
+
+    @pl.loop(0, n_chunks)
+    def _(c):
+        slot = c % 2
+
+        @pl.when(c + 1 < n_chunks)
+        def _():
+            start(c + 1, 1 - slot)
+
+        wait(c, slot)
+        # what was not copied, and the last page's tail, may hold anything:
+        # select, never multiply by zero
+        live = c * t + lax.broadcasted_iota(jnp.int32, (t, 1), 0) < ctx
+        rows = jnp.where(live, buf[slot].reshape(t, f), 0.0).astype(low)
+        s = lax.dot_general(q_low, rows, (((1,), (1,)), ((), ())),
+                            precision=precision,
+                            preferred_element_type=jnp.float32)  # (H, T)
+        s = jnp.where(
+            c * t + lax.broadcasted_iota(jnp.int32, (1, t), 1) < ctx, s,
+            NEG_INF)
+        m = m_ref[...]
+        m_next = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_next)
+        p = jnp.exp(s - m_next)
+        m_ref[...] = m_next
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p.astype(low), rows, precision=precision,
+            preferred_element_type=jnp.float32)
+
+    o_ref[0] = acc_ref[...] / l_ref[...]
+
+
+def _latent_decode_kernel(q, pool, layer, block_tables, ctx_lens, row_new,
+                          value_lanes, *, interpret=False):
+    """The walk over latent pages as one Pallas call over the batch."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, f = q.shape
+    bs = pool.shape[3]
+    f32 = jnp.float32
+    low = jnp.bfloat16 if q.dtype == jnp.bfloat16 else f32
+    chunk = max(1, min(_LATENT_CHUNK_TOKENS // bs, block_tables.shape[1]))
+    row = lambda i, tables, lens, layer: (i, 0, 0)             # noqa: E731
+    with jax.named_scope("paged_attention"):
+        out = pl.pallas_call(
+            functools.partial(_latent_kernel, low=low),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(b,),
+                in_specs=[
+                    pl.BlockSpec((1, h, f), row),
+                    pl.BlockSpec((1, 1, f), row),
+                    pl.BlockSpec(memory_space=pl.ANY),
+                ],
+                out_specs=pl.BlockSpec((1, h, f), row),
+                scratch_shapes=[
+                    pltpu.VMEM((2, chunk, bs, f), pool.dtype),
+                    pltpu.SemaphoreType.DMA((2,)),
+                    pltpu.VMEM((h, 1), f32),
+                    pltpu.VMEM((h, 1), f32),
+                    pltpu.VMEM((h, f), f32),
+                ]),
+            out_shape=jax.ShapeDtypeStruct((b, h, f), f32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+            name="paged_decode_latent",
+        )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
+          jnp.asarray(layer, jnp.int32).reshape(1), q.astype(f32),
+          row_new.astype(f32)[:, None], pool)
+        return out[..., :value_lanes]
+
+
+def latent_attention_decode(q: jax.Array, latent_pool: jax.Array, layer,
+                            block_tables: jax.Array, ctx_lens: jax.Array,
+                            row_new: jax.Array, value_lanes: int) -> jax.Array:
+    """Single-token absorbed latent attention through a block table.
+
+    q:           (B, H, R)        — each head's absorbed query ``[q_nope
+                                    W_uk | q_rope]``, SCALED by the caller;
+                                    its type says what the products run in.
+    latent_pool: (L, 1, N, bs, F) — the engine's latent pool
+                                    (``kv_cache.device_shape(..., planes=1)``),
+                                    a position a row ``[c | k_rope]`` of R
+                                    lanes zero-padded to F; read-only.
+    layer:       int              — which latent layer's pages.
+    row_new:     (B, R)           — this token's own row, attended in
+                                    explicitly (the runner writes it after).
+    value_lanes: the row's first lanes that are the value (``c``).
+
+    Returns (B, H, value_lanes) float32: ``sum_t p_t c_t`` a head, which
+    the caller takes through its ``W_uv``."""
+    f = latent_pool.shape[-1]
+    pad = [(0, 0)] * (q.ndim - 1) + [(0, f - q.shape[-1])]
+    q, row_new = jnp.pad(q, pad), jnp.pad(row_new, pad[1:])
+    if jax.default_backend() == "tpu" and latent_pool.dtype == jnp.float32:
+        return _latent_decode_kernel(q, latent_pool, layer, block_tables,
+                                     ctx_lens, row_new, value_lanes)
+    return _latent_decode_gather(q.astype(jnp.float32), latent_pool, layer,
+                                 block_tables, ctx_lens,
+                                 row_new.astype(jnp.float32), value_lanes)

@@ -30,6 +30,7 @@ whose window it returns.  ``D * x`` and the gate belong to the model.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -157,3 +158,109 @@ def ssm_step(state: jax.Array, x: jax.Array, dt: jax.Array, a: jax.Array,
     s = dec * s + xs * b.astype(f32)[:, :, None, None, :]
     y = (s * c.astype(f32)[:, :, None, None, :]).sum(-1)
     return y.reshape(r, h, p), s.reshape(r, h, p, n)
+
+
+# ------------------------------------------ a decode step's tails, in place
+# A served model keeps a conv's tail a sequence as a row of a store,
+# ``(layers, R, (K - 1) C / lanes, lanes)`` float32: the K - 1 last inputs
+# one behind another, laid ``lanes`` wide (whole (8, 128) tiles at C =
+# 12,288; held as (K - 1, C) a row the TPU put the rows inside the tiles
+# and as one (K - 1) C-wide line the kernel's (1, .) block needed a copy of
+# the store that pads it to 8 sublanes, in every step: my chip runs, PR
+# 59).  Gathered, stepped and scattered by XLA the 64 rows of a decode
+# step are 64 dependent row copies each way (147 KB a row at C = 12,288:
+# 0.9 ms a layer step where the bytes are 0.02: my chip run, PR 59), so on
+# a TPU ``conv_step_rows`` steps them where they lie: a Pallas kernel a
+# batch row, the row named by a prefetched scalar, the store aliased to
+# the result.  Elsewhere it is the gather, :func:`conv_step` and the
+# scatter.
+def _conv_rows_kernel(rows_ref, layer_ref, x_ref, w_ref, t_ref, y_ref, t_out,
+                      *, n_rows):
+    """One batch row: x_ref (1, S, lanes) the new input (S lanes = C),
+    w_ref (K, S, lanes) the taps, t_ref / t_out (1, 1, (K - 1) S, lanes)
+    the row's tail, y_ref (1, S, lanes).  A batch row that names no row of
+    the store reads its last row and writes it back as it is."""
+    del layer_ref
+    from jax.experimental import pallas as pl
+    k_w, s = w_ref.shape[:2]
+    live = rows_ref[pl.program_id(0)] < n_rows
+
+    @pl.when(live)
+    def _():
+        window = [t_ref[0, 0, i * s:(i + 1) * s] for i in range(k_w - 1)]
+        window.append(x_ref[0])
+        y = w_ref[0] * window[0]
+        for i in range(1, k_w):
+            y = y + w_ref[i] * window[i]
+        y_ref[0] = y
+        for i in range(k_w - 1):
+            t_out[0, 0, i * s:(i + 1) * s] = window[i + 1]
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        t_out[...] = t_ref[...]
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+
+def _conv_rows_call(store, layer, rows, x, w, *, interpret=False):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n_layer, n_rows, lines, lanes = store.shape
+    (B, c), f32, k_w = x.shape, jnp.float32, w.shape[0]
+    s = c // lanes
+
+    def at_row(b, rows_ref, layer_ref):
+        return (layer_ref[0], jnp.minimum(rows_ref[b], n_rows - 1), 0, 0)
+
+    y, store = pl.pallas_call(
+        functools.partial(_conv_rows_kernel, n_rows=n_rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, s, lanes), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec((k_w, s, lanes), lambda b, *_: (0, 0, 0)),
+                pl.BlockSpec((1, 1, lines, lanes), at_row),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, s, lanes), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec((1, 1, lines, lanes), at_row),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((B, s, lanes), f32),
+                   jax.ShapeDtypeStruct(store.shape, f32)],
+        # operands: rows, layer, x, w, store -> the store is result 1
+        input_output_aliases={4: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="conv_step_rows",
+    )(rows.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      x.astype(f32).reshape(B, s, lanes),
+      w.astype(f32).reshape(k_w, s, lanes), store)
+    return y.reshape(B, c), store
+
+
+def conv_step_rows(store: jax.Array, layer, rows: jax.Array, x: jax.Array,
+                   w: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """:func:`conv_step` (no bias) on rows of a store, written back where
+    they lie.
+
+    store (layers, R, (K - 1) C / lanes, lanes) float32, a row the K - 1
+    last inputs one behind another, ``lanes`` wide (``lanes`` divides C);
+    ``layer`` which of its layers; ``rows`` (B,) int32 the
+    row each batch row steps, DISTINCT, one ``>= R`` naming none (it reads
+    the store's LAST row and writes it back as it is, so no batch row may
+    name that one: the engine's staging row); x (B, C) the new inputs; w
+    (K, C).  Returns (y (B, C) float32, the store with the named rows one
+    token on)."""
+    n_rows, lanes, c = store.shape[1], store.shape[3], x.shape[1]
+    if jax.default_backend() == "tpu" and store.dtype == jnp.float32 \
+            and lanes % 128 == 0 and (c // lanes) % 8 == 0:
+        return _conv_rows_call(store, layer, rows, x, w)
+    flat = store.reshape(-1, store.shape[2] * lanes)
+    tail = flat[layer * n_rows + jnp.minimum(rows, n_rows - 1)]
+    y, tail = conv_step(tail.reshape(-1, w.shape[0] - 1, c), x, w, None)
+    at = jnp.where(rows < n_rows, layer * n_rows + rows, flat.shape[0])
+    return y, flat.at[at].set(tail.reshape(tail.shape[0], -1),
+                              mode="drop").reshape(store.shape)

@@ -34,9 +34,10 @@ class EngineConfig:
 
     ``model`` is "<family>:<preset>" over the in-tree model zoo —
     ``gpt2:tiny``, ``gpt2:gpt2-124m``, ``llama:tiny``, ``llama:llama3-8b``,
-    ``falcon_h1:tiny``, ``lfm2:tiny``, ``minicpm_sala:tiny``, ``afmoe:tiny``
-    … (the PRESETS of ``models/gpt2.py``, ``llama.py``, ``falcon_h1.py``,
-    ``lfm2.py``, ``minicpm_sala.py``, ``afmoe.py``).
+    ``falcon_h1:tiny``, ``lfm2:tiny``, ``minicpm_sala:tiny``, ``afmoe:tiny``,
+    ``ling:tiny`` … (the PRESETS of ``models/gpt2.py``, ``llama.py``,
+    ``falcon_h1.py``, ``lfm2.py``, ``minicpm_sala.py``, ``afmoe.py``,
+    ``ling.py``).
     """
 
     model: str = "gpt2:tiny"
@@ -81,9 +82,11 @@ def resolve_model(cfg: EngineConfig):
         from ray_tpu.models import minicpm_sala as mod
     elif family == "afmoe":
         from ray_tpu.models import afmoe as mod
+    elif family == "ling":
+        from ray_tpu.models import ling as mod
     else:
         raise ValueError(f"unknown model family {family!r} (expected "
-                         "gpt2|llama|falcon_h1|lfm2|minicpm_sala|afmoe)")
+                         "gpt2|llama|falcon_h1|lfm2|minicpm_sala|afmoe|ling)")
     try:
         mcfg = mod.PRESETS[preset]()
     except KeyError:

@@ -182,7 +182,9 @@ class LLMEngine:
             state_layers=self.runner.state_layers,
             select_stride=select.get("stride", 0),
             window_layers=self.runner.window_layers,
-            window=self.runner.window)
+            window=self.runner.window,
+            latent_layers=self.runner.latent_layers,
+            latent_dim=self.runner.latent_dim)
         self.runner.cache = self.cache
         self.sched = IterationScheduler(cfg.max_num_seqs,
                                         cfg.max_prefill_tokens,
@@ -236,6 +238,10 @@ class LLMEngine:
         self.window_blocks_read = 0
         self.window_blocks_unwindowed = 0
         self._window_released_sent = 0
+        # a model with latent layers: the latent pages its decode steps'
+        # absorbed kernel walked, over live rows and latent layers
+        # (loop-owned)
+        self.latent_pages_read = 0
         # tokens by where they were chosen (the step program's argmax for
         # a greedy request; ModelRunner.sample on a pulled row for any
         # other) and the bytes of logits pulled for the latter.
@@ -761,13 +767,24 @@ class LLMEngine:
                 tags = {"model": self.cfg.model}
                 mcat.get("rtpu_llm_sparse_pages_read").inc(read, tags=tags)
                 mcat.get("rtpu_llm_sparse_pages_held").inc(held, tags=tags)
-        if flight.step.reads:
-            reads = flight.step.reads
+        reads = flight.step.reads
+        if reads:
             span.set(**reads)
+        if "window_blocks" in reads:
             self.window_blocks_read += reads["window_blocks"]
             self.window_blocks_unwindowed += reads["window_blocks_unwindowed"]
             if GLOBAL_CONFIG.metrics_enabled:
                 self._publish_window_blocks()
+        if "latent_pages_read" in reads:
+            self.latent_pages_read += reads["latent_pages_read"]
+            if GLOBAL_CONFIG.metrics_enabled:
+                tags = {"model": self.cfg.model}
+                mcat.get("rtpu_llm_latent_pages_read").inc(
+                    reads["latent_pages_read"], tags=tags)
+                mcat.get("rtpu_llm_state_rows_held").set(
+                    self.cache.state_rows_used(), tags=tags)
+                mcat.get("rtpu_llm_latent_blocks_held").set(
+                    self.cache.used_block_count(), tags=tags)
         # the span says whose tokens it put on their streams, and of which
         # step: a token is on its stream at this span's END
         with hot_span("llm.decode.commit", self.span_s,
@@ -1035,6 +1052,11 @@ class LLMEngine:
                 "a second pool under a second table, and the manifest "
                 "exports the one table's blocks; nothing moves the window "
                 "layers' yet, so nothing is moved")
+        if self.cache.latent_layers:
+            raise NotImplementedError(
+                f"{what}: {self.cfg.model} caches latent rows, and a "
+                "block's wire format is a K and a V a layer; nothing "
+                "exports a latent page yet, so nothing is moved")
 
     def _drain_cancels(self) -> None:
         with self._lock:
@@ -1217,5 +1239,8 @@ class LLMEngine:
                         self.cache.window_counts())),
                     window_blocks_read=self.window_blocks_read,
                     window_blocks_unwindowed=self.window_blocks_unwindowed,
+                    latent_layers=self.cache.latent_layers,
+                    latent_bytes=self.cache.latent_bytes,
+                    latent_pages_read=self.latent_pages_read,
                     span_s={k: list(v) for k, v in
                             list(self.span_s.items())})

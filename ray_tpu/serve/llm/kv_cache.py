@@ -125,6 +125,30 @@ the window blocks held now, given back so far, and what one table for all
 layers would hold for the sequences alive now.  A family that names no
 window has no ``"kvw"`` in its holder, no second list, and none of this in
 its programs.
+
+A latent page.  A family whose attention caches ONE row a position and not
+a K and a V (``models/ling.py``: multi-head latent attention, the row ``[c
+| k_rope]`` of ``kv_lora_rank + qk_rope_head_dim`` features; its
+``cache_layers`` counts a kind ``"latent"``) gets a third kind of page: a
+pool of ONE plane, ``"latent"``, :func:`device_shape` with ``planes=1`` =
+``(latent layers, 1, num_blocks, block_size, F)``, the row's features
+zero-padded to whole lanes (576 -> 640).  It has the other pool's
+``num_blocks`` and lies under the same table: block ``i`` of the one is
+block ``i`` of the other, so ``alloc_seq``, ``append_slot``,
+``rollback_slot``, ``free_seq`` and preemption know nothing of it, and it
+costs no list, no lock and no lifetime of its own.  It is written by the
+cache's own writers: :func:`write_rows` takes a pool of either form (``v``
+None where there is one plane), ``scatter_prefill`` lays a prompt's rows
+(handed as its ``ks``, ``(latent layers, T, 1, R)``; ``vs`` is not read)
+and ``write_token`` one token's.  The absorbed decode kernel
+(``ops/paged_attention.latent_attention_decode``) reads a page once for
+keys and values.  Such a family's layers hold no K/V today: its ``"kv"``
+pool has no layer and no byte, and stays in the holder so that every
+program keeps its operands' labels.  A latent page is not exported
+(``block_bytes`` / ``load_block`` refuse: the wire format is K and V) and
+not shared (``fork_seq`` refuses); the one family that has them keeps
+state rows too.  A family that names no latent layer has no ``"latent"``
+in its holder and none of this in its programs.
 """
 
 from __future__ import annotations
@@ -187,14 +211,16 @@ def _pid_alive(pid: int) -> bool:
 
 
 def device_shape(num_blocks: int, n_layer: int, block_size: int,
-                 n_kv: int, head_dim: int) -> tuple:
+                 n_kv: int, head_dim: int, planes: int = 2) -> tuple:
     """The pool's shape on the device, ``(L, 2, N, bs, F)``: the one
     place that says it (the cache, the tests and whoever compiles a step
     program for shapes alone ask here).  ``F`` is a position's
     ``n_kv * head_dim`` features padded up to whole 128-lane tiles, the
-    narrowest thing Mosaic copies out of HBM."""
+    narrowest thing Mosaic copies out of HBM.  ``planes=1``: a latent
+    pool, ``(L, 1, N, bs, F)``, one row a position (``n_kv`` 1,
+    ``head_dim`` the row's features) where the other holds a K and a V."""
     f = n_kv * head_dim
-    return (n_layer, 2, num_blocks, block_size, f + -f % 128)
+    return (n_layer, planes, num_blocks, block_size, f + -f % 128)
 
 
 def selector_shape(pool_shape: tuple, stride: int) -> tuple:
@@ -248,9 +274,10 @@ def _halves_rewritten(sel, pool, blocks, offsets):
     return flat.reshape(sel.shape)
 
 
-def write_rows(pool, blocks, offsets, k, v, sel=None):
+def write_rows(pool, blocks, offsets, k, v=None, sel=None):
     """``pool[:, 0 / 1, blocks[r], offsets[r]] = k / v[:, r]`` for every
-    row ``r``, cast to the pool's type; traceable.  With ``sel`` (the
+    row ``r``, cast to the pool's type; traceable.  A pool of one plane (a
+    latent pool) takes its rows as ``k`` and no ``v``.  With ``sel`` (the
     selector's cache of a family that has one) the half-kernels those rows
     fall in are brought up to date from the K just written, and the result
     is ``(pool, sel)``: whoever writes K writes them.
@@ -272,13 +299,16 @@ def write_rows(pool, blocks, offsets, k, v, sel=None):
     import jax.numpy as jnp
 
     from ray_tpu.ops.paged_attention import lane_flat
-    n_layer, _, num_blocks, bs, f = pool.shape
-    kv = jnp.stack([lane_flat(k, f), lane_flat(v, f)], axis=1)  # (L, 2, R, F)
+    n_layer, planes, num_blocks, bs, f = pool.shape
+    kv = jnp.stack([lane_flat(a, f) for a in (k, v)[:planes]],
+                   axis=1)                                      # (L, 2, R, F)
     per_slab = num_blocks * bs              # rows of one layer's K (or V)
     row = blocks * bs + offsets                                 # (R,)
-    rows = jnp.arange(2 * n_layer)[:, None] * per_slab + row    # (2 L, R)
+    rows = jnp.arange(planes * n_layer)[:, None] * per_slab \
+        + row                                                   # (2 L, R)
     # a row sent out of range lies outside every slab, not in the next
-    rows = jnp.where(blocks < num_blocks, rows, 2 * n_layer * per_slab)
+    rows = jnp.where(blocks < num_blocks, rows,
+                     planes * n_layer * per_slab)
     flat = pool.reshape(-1, f).at[rows.reshape(-1)].set(
         kv.reshape(-1, f).astype(pool.dtype), mode="drop")
     pool = flat.reshape(pool.shape)
@@ -321,6 +351,9 @@ def _programs() -> SimpleNamespace:
         if wblocks:
             return write_rows_by_kind(held, blocks, wblocks[0], offsets, k,
                                       v), None
+        if "latent" in held:
+            return {**held, "latent": write_rows(held["latent"], blocks,
+                                                 offsets, k)}, None
         sel = held.get("sel")
         if sel is None:
             return {**held, "kv": write_rows(held["kv"], blocks, offsets,
@@ -379,7 +412,14 @@ def _programs() -> SimpleNamespace:
                     slots.reshape(-1)].set(
                         halves.reshape(-1, f).astype(sel.dtype),
                         mode="drop").reshape(sel.shape)}
-            held = {**held, "kv": write_rows(pool, blocks, t % bs, ks, vs)}
+            if "latent" in held:
+                # a latent page: the prompt's rows are ``ks``, and the
+                # family's layers hold no K/V
+                held = {**held, "latent": write_rows(held["latent"], blocks,
+                                                     t % bs, ks)}
+            else:
+                held = {**held,
+                        "kv": write_rows(pool, blocks, t % bs, ks, vs)}
         if row:
             # recurrent state: the prompt's, which its prefill left in the
             # staging row, goes to the sequence's row
@@ -419,7 +459,8 @@ class DevicePool:
     that array}``; with ``state`` (a store's leaves as
     ``ShapeDtypeStruct``s) the store beside it, ``"state"``, with ``sel``
     the selector's cache, ``"sel"``, and with ``window`` the window
-    layers' pool, ``"kvw"``.
+    layers' pool, ``"kvw"``, and with ``latent`` the latent layers' pool
+    of one plane, ``"latent"``.
 
     A donating program deletes the array it was given and returns a new
     one over the same memory, so nobody may keep the array itself:
@@ -430,9 +471,13 @@ class DevicePool:
     for the enqueue only, and the device runs the programs in the order
     they were enqueued."""
 
-    def __init__(self, shape, dtype, state=None, sel=None, window=None):
+    def __init__(self, shape, dtype, state=None, sel=None, window=None,
+                 latent=None):
         self.shape, self.dtype = tuple(shape), dtype
         self.state = state
+        # the latent layers' pool of one plane (a ShapeDtypeStruct), for a
+        # family that caches a latent row a position: ``"latent"``
+        self.latent = latent
         # the window layers' pool (a ShapeDtypeStruct), for a family whose
         # layers hold pages of two kinds: one more entry, ``"kvw"``
         self.window = window
@@ -496,6 +541,8 @@ class DevicePool:
             held["sel"] = made(self.sel)
         if self.window is not None:
             held["kvw"] = made(self.window)
+        if self.latent is not None:
+            held["latent"] = made(self.latent)
         return held
 
 
@@ -506,7 +553,8 @@ class PagedKVCache:
                  n_kv: int, head_dim: int, dtype=np.float32, *,
                  state=None, max_seqs: int = 0, state_layers=None,
                  select_stride: int = 0, window_layers: int = 0,
-                 window: int = 0):
+                 window: int = 0, latent_layers: int = 0,
+                 latent_dim: int = 0):
         """``n_layer``: the layers that hold K/V.  ``state``: one
         sequence's recurrent state in one layer, name ->
         ``ShapeDtypeStruct`` (a model module's ``recurrent_state``), for a
@@ -519,7 +567,15 @@ class PagedKVCache:
         ``window`` positions only (a model module's ``cache_layers`` and
         sliding window), beside the ``n_layer`` that hold every position:
         a second pool of ``max_seqs`` x :func:`window_columns` blocks, which
-        the sequence slots cannot exhaust."""
+        the sequence slots cannot exhaust.  ``latent_layers`` and
+        ``latent_dim``: the layers that cache one latent row of
+        ``latent_dim`` features a position (a model module's
+        ``cache_layers`` and latent row), in a pool of one plane under the
+        same table; such a family's ``n_layer`` is 0."""
+        if latent_layers and (n_layer or window_layers or select_stride):
+            raise ValueError(
+                "latent pages beside K/V pages in one model are not "
+                "written: a prompt's rows reach the scatter as its K")
         self.num_blocks = num_blocks
         self.block_shape = (n_layer, 2, block_size, n_kv, head_dim)
         self.block_size = block_size
@@ -577,8 +633,17 @@ class PagedKVCache:
                              n_kv, head_dim), self.dtype)
         self.window_bytes = math.prod(wpool.shape) * self.dtype.itemsize \
             if wpool is not None else 0
+        self.latent_layers, self.latent_dim = latent_layers, latent_dim
+        lpool = None
+        if latent_layers:
+            import jax
+            lpool = jax.ShapeDtypeStruct(
+                device_shape(num_blocks, latent_layers, block_size, 1,
+                             latent_dim, planes=1), self.dtype)
+        self.latent_bytes = math.prod(lpool.shape) * self.dtype.itemsize \
+            if lpool is not None else 0
         self.pool = DevicePool(shape, self.dtype, state=store, sel=sel,
-                               window=wpool)
+                               window=wpool, latent=lpool)
         # one step region for the writers below (DESIGN.md §4q): a
         # scatter program per prefill bucket, write_token, load_block
         self._write_budget = compile_budget("llm.kv_write")
@@ -751,6 +816,10 @@ class PagedKVCache:
                 "window block would be given back by whichever holder's "
                 "context passes it first (prefix sharing across window "
                 "layers: ROADMAP R4)")
+        if self.latent_layers:
+            raise NotImplementedError(
+                "a sequence with latent pages cannot be forked: nothing "
+                "shares a prefix across latent layers yet (ROADMAP, Reach)")
         with self._lock:
             blocks = list(self._tables[seq_id])
             for b in blocks:
@@ -849,10 +918,26 @@ class PagedKVCache:
         """One block's contiguous bytes in its wire format (the data-plane
         export unit), gathered out of the pool and copied from the
         device."""
+        self._wire_holds_k_and_v("block_bytes")
         block = self.pool.read(_programs().read_block, np.int32(block_id),
                                heads=self.block_shape[3:])
         self._crossed(self.block_nbytes)
         return np.asarray(block).tobytes()
+
+    def _wire_holds_k_and_v(self, what: str) -> None:
+        if self.latent_layers:
+            raise NotImplementedError(
+                f"{what}: a block's wire format is a K and a V a layer, and "
+                "this cache's pages are latent rows; nothing exports a "
+                "latent page yet")
+
+    def latent_blocks(self) -> np.ndarray:
+        """Every latent page, ``(num_blocks, latent layers, bs,
+        latent_dim)`` without the lane padding, copied from the device
+        (the tests)."""
+        return np.asarray(self.pool.read(
+            lambda held: held["latent"][:, 0, ..., :self.latent_dim]
+        )).swapaxes(0, 1)
 
     def blocks(self) -> np.ndarray:
         """Every block in its wire format, ``(num_blocks,) + block_shape``,
@@ -864,6 +949,7 @@ class PagedKVCache:
     def load_block(self, block_id: int, raw) -> None:
         """Copy one imported block's bytes (wire format) to the device,
         into its block."""
+        self._wire_holds_k_and_v("load_block")
         block = np.frombuffer(raw, dtype=self.dtype).reshape(self.block_shape)
         self._write(_programs().load_block, np.int32(block_id), block,
                     host=(block,))

@@ -122,6 +122,20 @@ host (the serving check) copies the window's run and not the prompt.
 ``llm.decode.pull`` is told the positions and blocks the step's window
 layers read and what full layers would have read in their place.
 
+A latent page.  A module whose ``cache_layers(cfg)`` counts a kind
+``"latent"`` (``models/ling.py``: layers of multi-head latent attention,
+which cache one row ``[c | k_rope]`` of ``cfg.latent_row`` features a
+position) gets a holder with a pool of one plane, ``"latent"``, under the
+same block table (``kv_cache.py``, a latent page), beside ``"kv"`` (which
+then has no layer) and the store.  The decode program hands the forward
+that pool (``latent_pool=``) and writes the rows the forward returns as
+its ``k`` (``(latent layers, B, 1, R)``) at the slot a K/V would go to;
+the chunk program stages a prompt's rows as another family's stages K/V
+(``staging["latent"]``), and ``prefill_result`` hands them on as ``ks``
+(and again as ``vs``, which nobody reads).  ``llm.decode.pull`` is told
+``latent_pages_read``: the pages the kernel walked, summed over the live
+rows and the latent layers.
+
 The hand-over of the choice of experts.  A module that routes exports
 ``routed_layers(cfg)`` -> ``{"layers": n, "k": k}`` (None for a preset
 that does not); the runner offers it as ``route_spec``, asks the module's
@@ -221,8 +235,9 @@ class Enqueued(NamedTuple):
     carry: "jax.Array"               # the ids at the widest bucket's width
     n: int                           # the rows that are real
     logit_rows: Optional[Sequence[int]]
-    # what the step's window layers read (``ModelRunner._window_reads``),
-    # told to the pull's span; empty without window layers
+    # what the step's window layers read (``ModelRunner._window_reads``)
+    # or its latent layers (``latent_pages_read``), told to the pull's
+    # span; empty without either
     reads: dict = {}
 
 
@@ -273,6 +288,10 @@ class ModelRunner:
         # of their own (0: every K/V layer holds the whole context)
         self.window_layers = layers.get("window", 0)
         self.window = self.mcfg.sliding_window if self.window_layers else 0
+        # layers that cache one latent row a position, in a pool of one
+        # plane under the same table (0: none), and the row's features
+        self.latent_layers = layers.get("latent", 0)
+        self.latent_dim = self.mcfg.latent_row if self.latent_layers else 0
         # the choice of experts a module that routes hands over ({"layers",
         # "k"}; None: it does not route), and the last step's, on the device
         describe = getattr(self.mod, "routed_layers", None)
@@ -368,6 +387,10 @@ class ModelRunner:
                         held["kvw"].shape[2])
                     return write_rows_by_kind(held, blocks, wblocks,
                                               ctx_lens % bs, k, v)
+                if "latent" in held:
+                    # a latent page: the new rows came back as ``k``
+                    return {**held, "latent": write_rows(
+                        held["latent"], blocks, ctx_lens % bs, k)}
                 sel = held.get("sel")
                 out = write_rows(held["kv"], blocks, ctx_lens % bs, k, v,
                                  sel)
@@ -421,6 +444,8 @@ class ModelRunner:
                 window_tables = by_row.pop(0)
                 reads.update(window_pool=held["kvw"],
                              window_tables=window_tables)
+            if "latent" in held:
+                reads["latent_pool"] = held["latent"]
             logits, k, v, *ids = forward_decode(
                 params, tokens_in(tokens, last_ids, src), positions,
                 held["kv"], block_tables, ctx_lens,
@@ -644,6 +669,9 @@ class ModelRunner:
             first, run = self._state_cache().window_run(n_tokens)
             ks, vs = self._packed(self._staging, first, bucket=tb,
                                   positions=run)
+        elif self.latent_layers:
+            # a latent page: the prompt's rows, as the cache scatters them
+            ks = vs = self._staging["latent"][:, :tb, None]
         else:
             heads = (self.n_kv, self.head_dim)
             ks, vs = (self._staging[name][:, :tb].reshape(
@@ -716,6 +744,11 @@ class ModelRunner:
             # window layers read of them (for the pull's span)
             state_rows += (self._state_cache().window_tables(block_tables),)
             reads = self._window_reads(ctx_lens[:b])
+        if kv_pool.latent is not None:
+            # the pages the absorbed kernel walks, for the pull's span
+            reads = dict(latent_pages_read=int(
+                (-(-ctx_lens[:b].astype(np.int64) // self.cfg.block_size)
+                 ).sum()) * self.latent_layers)
         # dispatch holds the jitted call and ends at the ENQUEUE; pull
         # ends when the ids or the logits are on the host, so it holds the
         # wait for the step and nothing else: the pool stays where it is

@@ -271,6 +271,26 @@ CATALOG: Dict[str, dict] = {
                     "layers under one block table for all layers (a block "
                     "a column of every table), summed over the same steps",
         emitted_by="llm replica"),
+    "rtpu_llm_latent_pages_read": dict(
+        kind="counter", tag_keys=("model", "group"),
+        description="Latent pages the absorbed decode kernel walked in a "
+                    "model's latent-attention layers: ceil(context / block) "
+                    "a live row and latent layer, summed over committed "
+                    "decode steps",
+        emitted_by="llm replica"),
+    "rtpu_llm_state_rows_held": dict(
+        kind="gauge", tag_keys=("model", "group"),
+        description="Rows of recurrent state (a delta-rule or scan state "
+                    "and conv tails a layer) the live sequences of a model "
+                    "with latent layers hold, at its last committed decode "
+                    "step",
+        emitted_by="llm replica"),
+    "rtpu_llm_latent_blocks_held": dict(
+        kind="gauge", tag_keys=("model", "group"),
+        description="Blocks of the paged cache (each a latent page a "
+                    "latent layer) the live sequences hold, at the last "
+                    "committed decode step",
+        emitted_by="llm replica"),
     "rtpu_llm_tokens_total": dict(
         kind="counter", tag_keys=("model", "phase", "group"),
         description="Tokens processed by an LLM engine: 'prefill' = "

@@ -1417,3 +1417,208 @@ def test_afmoe_chunk_program_runs_the_band_and_carries_no_holder(
     assert mem.temp_size_in_bytes < 0.5e9
     pools = sum(math.prod(h.shape) * 4 for h in held.values())
     assert total + pools + AFMOE_REFERENCE_BYTES < 16.9e9, total
+
+
+# --------------------------------------------------- the Ling cell's programs
+def _latent_decode(q, pool, layer, tables, lens, row):
+    from ray_tpu.ops.paged_attention import latent_attention_decode
+    return latent_attention_decode(q, pool, layer, tables, lens, row, 512)
+
+
+def test_latent_decode_kernel_at_decode_shape(v5e, monkeypatch):
+    """64 rows, 32 absorbed query heads of 576 lanes, a float32 latent pool
+    of one plane and 64-position blocks handed over whole, a table of 240
+    columns: a page is (64, 640), whole tiles, copied once for keys and
+    values; nothing of the pool's size is made on the way in."""
+    from ray_tpu.serve.llm.kv_cache import device_shape
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pool = device_shape(2048, 1, 64, 1, 576, planes=1)
+    assert pool == (1, 1, 2048, 64, 640)
+    text = _compile(_latent_decode, v5e, ((64, 32, 576), jnp.bfloat16),
+                    (pool, jnp.float32), ((), jnp.int32),
+                    ((64, 240), jnp.int32), ((64,), jnp.int32),
+                    ((64, 576), jnp.bfloat16))
+    assert "paged_decode_latent" in text
+    assert not _made(text, math.prod(pool))
+
+
+def _kda_rows(store, rows, q, k, v, g, beta):
+    from ray_tpu.ops.delta_rule import kda_step_rows
+    return kda_step_rows(store, 3, rows, q, k, v, g, beta)
+
+
+def test_the_rows_of_state_are_stepped_in_place(v5e, monkeypatch):
+    """64 of a store's 65 rows of 32 x 128 x 128 float32, one layer of six:
+    the Pallas kernel reads and writes a row's heads where they lie, the
+    store aliased to the result; no copy of the store or of the rows."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    store = (6, 65, 32, 128, 128)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in (
+        (store, jnp.float32), ((64,), jnp.int32),
+        ((64, 32, 128), jnp.bfloat16), ((64, 32, 128), jnp.bfloat16),
+        ((64, 32, 128), jnp.bfloat16), ((64, 32, 128), jnp.float32),
+        ((64, 32), jnp.float32))]
+    compiled = jax.jit(_kda_rows, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "kda_step_rows" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == math.prod(store) * 4
+    assert mem.temp_size_in_bytes < 0.05e9
+    assert not _made(text, math.prod(store), 64 * 32 * 128 * 128)
+
+
+def _kda_chunks(q, k, v, g, beta, state):
+    from ray_tpu.ops.delta_rule import kda_chunks
+    return kda_chunks(q, k, v, g, beta, state)
+
+
+def test_the_per_channel_rule_compiles_at_a_prefill_chunk(v5e):
+    """A chunk of 2,048 positions, 32 heads of 128, bf16: plain XLA (no
+    Mosaic kernel), and nothing as large as a (C, C) matrix a channel."""
+    shape = (1, 2048, 32, 128)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in (
+        (shape, jnp.bfloat16), (shape, jnp.bfloat16), (shape, jnp.bfloat16),
+        (shape, jnp.float32), (shape[:3], jnp.float32),
+        ((1, 32, 128, 128), jnp.float32))]
+    compiled = jax.jit(_kda_chunks).lower(*args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+@pytest.fixture(scope="module")
+def ling_runner(v5e):
+    """The cell's runner over abstract weights, and what its holder holds
+    as shapes on the chip: a K/V pool of no layer, a latent pool of one
+    plane over the one MLA layer, and a store of state rows over the 6 KDA
+    layers."""
+    import json
+    from pathlib import Path
+
+    from ray_tpu.models import ling
+    from ray_tpu.serve.llm import EngineConfig
+    from ray_tpu.serve.llm.config import resolve_model
+    from ray_tpu.serve.llm.kv_cache import device_shape
+    from ray_tpu.serve.llm.model_runner import ModelRunner
+    engine = json.loads((Path(__file__).parent.parent / "perfbench" /
+                         "configs" / "ling-3.0-flash-vl.json").read_text()
+                        )["serve"]["engine"]
+    for key in ("decode_batch_buckets", "prefill_len_buckets"):
+        engine[key] = tuple(engine[key])
+    ecfg = EngineConfig(**engine)
+    mod, mcfg = resolve_model(ecfg)
+    assert mod is ling
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.eval_shape(lambda key: mod.init_params(key, mcfg),
+                            jax.random.key(0))
+    runner = ModelRunner(ecfg, params=params)
+    assert runner.params is params      # drawn in its serving type
+    assert (runner.kv_layers, runner.latent_layers, runner.state_layers,
+            runner.latent_dim, runner.chunk) == (0, 1, 6, 576, 2048)
+    held = {
+        "kv": on_chip(device_shape(ecfg.num_blocks, 0, ecfg.block_size,
+                                   runner.n_kv, runner.head_dim),
+                      jnp.float32),
+        "latent": on_chip(device_shape(ecfg.num_blocks, 1, ecfg.block_size,
+                                       1, runner.latent_dim, planes=1),
+                          jnp.float32),
+        "state": {name: on_chip((runner.state_layers, ecfg.max_num_seqs + 1)
+                                + s.shape, s.dtype)
+                  for name, s in runner.state_spec.items()}}
+    weights = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), params)
+    return runner, ecfg, held, weights, on_chip
+
+
+# the float32 reference beside the engine: a block of 4 experts widened,
+# their hidden rows at the check's 6,152 positions, a block of 256
+# queries' scores over them in 32 heads, and a layer's other leaves
+LING_REFERENCE_BYTES = 1.0e9
+
+
+def test_ling_decode_program_steps_rows_and_walks_latent_pages(
+        ling_runner, monkeypatch):
+    """The cell's decode step at its one bucket of 64 (7 layers at the
+    published widths): the absorbed kernel once (``paged_decode_latent``,
+    over the latent pool of one plane and the one table), the 6 KDA
+    layers' rows stepped in place in the donated store
+    (``kda_step_rows``, and ``conv_step_rows`` for the tails); the latent
+    pool and the
+    store donated (1.34e9 + 0.88e9 bytes), the pool touched by the update
+    of the step's 64 rows alone; 512 assignments through megablox, carried
+    there and back only as far as the held rows; the ids come back as (6,
+    64, 8) with the count of held experts touched behind the step's 64
+    ids."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, ecfg, held, weights, on_chip = ling_runner
+    bucket, i32 = ecfg.decode_batch_buckets[-1], jnp.int32
+    assert runner.param_bytes == 5_607_825_152
+    lowered = runner._decode.lower(
+        held, weights, on_chip((bucket,), i32), on_chip((bucket,), i32),
+        on_chip((bucket, ecfg.max_blocks_per_seq), i32),
+        on_chip((bucket,), i32), on_chip((), i32),
+        on_chip((bucket,), i32), on_chip((bucket,), i32),
+        on_chip((bucket,), i32))
+    ids = jax.tree.leaves(lowered.out_info)[-1]
+    assert ids.shape == (6, 64, 8) and ids.dtype == jnp.int32
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    root = next(line for line in text[text.index("ENTRY "):].splitlines()
+                if " ROOT " in line)
+    assert "s32[65]" in root and "s32[64]" in root and "s32[6,64,8]" in root
+    kernels = re.findall(r'custom_call_target="tpu_custom_call".*'
+                         r'op_name="[^"]*/(\w+)/pallas_call"', text)
+    assert kernels.count("paged_decode_latent") == 1
+    assert kernels.count("spread_held_rows") == 6
+    assert held["latent"].shape == (1, 1, 8192, 64, 640)
+    assert held["state"]["s"].shape == (6, 65, 32, 128, 128)
+    assert held["state"]["conv"].shape == (6, 65, 288, 128)
+    # the rows of S are stepped where they lie, once a KDA layer: nothing
+    # the size of the bucket's 64 rows is gathered out or scattered back
+    assert kernels.count("kda_step_rows") == 6
+    assert kernels.count("conv_step_rows") == 6
+    assert not _made(text, 64 * 32 * 128 * 128, 64 * 3 * 12288)
+    pool = held["latent"].shape
+    rows = f"{math.prod(pool[:-1])},{pool[-1]}"
+    assert sorted(_made(text, math.prod(pool))) == [
+        ("fusion", rows), ("scatter", rows)], _made(text, math.prod(pool))
+    total, mem = _held_bytes(compiled)
+    assert mem.alias_size_in_bytes >= 1.342e9 + 0.875e9
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert total + runner.staging_bytes + LING_REFERENCE_BYTES < 16.9e9, total
+
+
+def test_ling_chunk_program_carries_state_and_stages_latent_rows(
+        ling_runner, monkeypatch):
+    """The one prefill program: a chunk of 2,048 positions over the staging
+    (the MLA layer's latent rows of 16,384 positions, 37.7e6 bytes, donated
+    and returned) and through the donated holder (the state from the
+    store's staging row and back into it); the causal flash kernel once,
+    over the up-projected rows at 256 lanes a head; the rule in plain XLA;
+    16,384 assignments through megablox; the ids (6, 2048, 8)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, ecfg, held, weights, on_chip = ling_runner
+    staging = jax.tree.map(lambda s: on_chip(s.shape, s.dtype),
+                           runner.staging_spec)
+    assert {k: v.shape for k, v in staging.items()} == {
+        "latent": (1, 16384, 576)}
+    assert runner.staging_bytes == 37_748_736
+    lowered = runner._prefill_chunk.lower(
+        held, weights, staging, on_chip((1, 2048), jnp.int32),
+        on_chip((), jnp.int32), on_chip((), jnp.int32))
+    ids = jax.tree.leaves(lowered.out_info)[-1]
+    assert ids.shape == (6, 2048, 8) and ids.dtype == jnp.int32
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    kernels = re.findall(r'custom_call_target="tpu_custom_call".*'
+                         r'op_name="[^"]*/(\w+)/pallas_call"', text)
+    assert kernels.count("causal_prefill") == 1
+    assert kernels.count("spread_held_rows") == 6
+    assert kernels.count("sum_held_slots") == 6
+    assert len(re.findall(r"%gmm[.\d]* = bf16\[\d+,(?:768|2560)\]",
+                          text)) == 18
+    total, mem = _held_bytes(compiled)
+    assert mem.alias_size_in_bytes >= 1.342e9 + 0.875e9 + 0.037e9
+    assert mem.temp_size_in_bytes < 1.5e9
+    assert total + LING_REFERENCE_BYTES < 16.9e9, total
