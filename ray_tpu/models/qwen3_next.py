@@ -213,7 +213,7 @@ def _gdn_mixer(u: jax.Array, lp: Params, cfg: Qwen3NextConfig):
     (B, T, E), the mean decay exp(g) of the layer, the rule's last state
     (B, H, dk, dv) float32, which training drops)."""
     from ray_tpu.ops.delta_rule import gated_delta_rule, l2norm_heads
-    from ray_tpu.ops.ssm import causal_conv
+    from ray_tpu.ops.ssm import causal_conv_silu
     B, T, _ = u.shape
     G, H = cfg.gdn_key_heads, cfg.gdn_value_heads
     dk, dv, kw = cfg.gdn_key_dim, cfg.gdn_value_dim, cfg.gdn_key_width
@@ -226,8 +226,7 @@ def _gdn_mixer(u: jax.Array, lp: Params, cfg: Qwen3NextConfig):
         ba = jnp.dot(u, lp["in_proj_ba"]["kernel"].astype(cfg.dtype),
                      preferred_element_type=jnp.float32)
     with jax.named_scope("gdn_conv"):
-        qkv, _ = causal_conv(qkv, lp["conv"]["kernel"], None)
-        qkv = jax.nn.silu(qkv).astype(cfg.dtype)
+        qkv = causal_conv_silu(qkv, lp["conv"]["kernel"])
     with jax.named_scope("gdn_rule"):
         beta = jax.nn.sigmoid(ba[..., :H])
         g = -jnp.exp(lp["A_log"].astype(jnp.float32)) * jax.nn.softplus(

@@ -31,6 +31,7 @@ whose window it returns.  ``D * x`` and the gate belong to the model.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -72,6 +73,219 @@ def conv_step(tail: jax.Array, x: jax.Array, w: jax.Array,
         return (w.astype(jnp.float32) * window).sum(1), window[:, 1:]
     y = b.astype(jnp.float32) + (w.astype(jnp.float32) * window).sum(1)
     return y, window[:, 1:]
+
+
+# ------------------------------- the conv and its silu, one pass each way
+# ``silu(causal_conv(x, w))`` for a mixer that trains (Gated DeltaNet's q |
+# k | v).  As XLA runs the two lines it makes a float32 copy of the padded
+# sequence and reads it four times at sublane offsets 0-3, writes the
+# float32 product for the backward and sums the taps' gradient in a pass of
+# its own: 47 ms a step at 2 x 8,192 x 8,192 where the bytes need 7 (ledger,
+# PR 59).  On a TPU, with bf16 activations of whole 128-lane blocks and
+# whole time blocks, two Pallas kernels do it instead, each under a name of
+# its own (``causal_conv_fwd``, ``causal_conv_bwd``): bf16 in and out,
+# float32 only in VMEM, ``CONV_STEP`` rows at a time so that a step's
+# shifted copies stay in vector registers.  A position's K - 1 earlier
+# inputs are a sublane roll of the rows with the 8 before them; across a
+# time block's edge those 8 come from the tile before the block (a second
+# BlockSpec on the same array), zeros at a sequence's start.  The backward
+# is written out: it keeps ``x`` and ``w`` alone, makes the pre-activation
+# again, walks the time blocks from the last to the first with the 8 rows
+# of ``g = dy silu'(y)`` that follow a block in scratch (zeros past the
+# end), and sums ``dw[k] = sum g_t x_{t-(K-1)+k}`` by sublane in a float32
+# scratch across a channel block's batch rows and time blocks, written
+# once.  Everything else takes the two lines, which are the definition.
+CONV_ROWS = 1024        # positions a time block
+CONV_LANES = 512        # channels a block, at most
+CONV_STEP = 32          # rows of a block worked on at once
+_TILE = 16              # rows of a bf16 tile: what is fetched before a block
+
+
+def _last_rows(tile):
+    """A (16, c) tile -> its last 8 rows, float32."""
+    return tile.astype(jnp.float32)[_TILE - 8:]
+
+
+def _conv_rows(taps, before, x):
+    """x (R, c) float32, the 8 rows before it and the K taps (1, c) -> (x's
+    copy each tap reads, ``x_{t-(K-1)+k}``: the last is x itself; their
+    sum by the taps, in ``causal_conv``'s order)."""
+    from jax.experimental.pallas import tpu as pltpu
+    k_w = len(taps)
+    rows = jnp.concatenate([before, x], axis=0)
+    xs = [pltpu.roll(rows, k_w - 1 - k, 0)[8:] for k in range(k_w - 1)] + [x]
+    y = taps[0] * xs[0]
+    for tap, x_k in zip(taps[1:], xs[1:]):
+        y = y + tap * x_k
+    return xs, y
+
+
+def _conv_fwd_kernel(x_ref, tile_ref, w_ref, y_ref, *, k_w, step):
+    """x_ref, y_ref (bt, bc); tile_ref (16, bc) the rows before the block
+    (the block's own first rows where there are none); w_ref (8, bc)
+    float32, rows past K unused."""
+    from jax.experimental import pallas as pl
+    f32 = jnp.float32
+    taps = [w_ref[pl.ds(k, 1), :] for k in range(k_w)]
+    before = jnp.where(pl.program_id(2) == 0, 0.0, _last_rows(tile_ref[...]))
+
+    def rows(i, before):
+        r = pl.multiple_of(i * step, step)
+        x = x_ref[pl.ds(r, step), :].astype(f32)
+        _, y = _conv_rows(taps, before, x)
+        y_ref[pl.ds(r, step), :] = (y * jax.nn.sigmoid(y)).astype(y_ref.dtype)
+        return x[step - 8:]
+
+    lax.fori_loop(0, x_ref.shape[0] // step, rows, before)
+
+
+def _conv_bwd_kernel(x_ref, tile_ref, dy_ref, w_ref, dx_ref, dw_ref,
+                     after_ref, sums_ref, *, k_w, step):
+    """Time blocks from the last to the first (program 2 counts from the
+    end).  dx_ref (bt, bc); dw_ref (8, bc) float32, written at a channel
+    block's last step; after_ref (8, bc) float32 the g of the rows that
+    follow the block; sums_ref (8 K, bc) float32 the taps' sums by
+    sublane."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    f32 = jnp.float32
+    b, t = pl.program_id(1), pl.program_id(2)
+    last_b, last_t = pl.num_programs(1) - 1, pl.num_programs(2) - 1
+    n = x_ref.shape[0] // step
+    taps = [w_ref[pl.ds(k, 1), :] for k in range(k_w)]
+
+    @pl.when((b == 0) & (t == 0))
+    def _():
+        sums_ref[...] = jnp.zeros_like(sums_ref)
+
+    @pl.when(t == 0)
+    def _():
+        after_ref[...] = jnp.zeros_like(after_ref)
+
+    def rows(r, before, after):
+        """Rows r .. r + step with the 8 rows of x before them and the 8
+        rows of g after them -> their first 8 rows of g."""
+        x = x_ref[pl.ds(r, step), :].astype(f32)
+        xs, y = _conv_rows(taps, before, x)
+        sig = jax.nn.sigmoid(y)
+        g = dy_ref[pl.ds(r, step), :].astype(f32) * (
+            sig * (1.0 + y * (1.0 - sig)))
+        for k, x_k in enumerate(xs):
+            p = g * x_k
+            sums_ref[8 * k:8 * k + 8] += sum(
+                p[8 * j:8 * j + 8] for j in range(step // 8))
+        # dx_t = sum_k w[k] g_{t+(K-1)-k}
+        both = jnp.concatenate([g, after], axis=0)
+        dx = taps[k_w - 1] * g
+        for s in range(1, k_w):
+            dx = dx + taps[k_w - 1 - s] * pltpu.roll(
+                both, step + 8 - s, 0)[:step]
+        dx_ref[pl.ds(r, step), :] = dx.astype(dx_ref.dtype)
+        return g[:8]
+
+    def from_the_end(j, after):
+        r = pl.multiple_of((n - 1 - j) * step, step)
+        return rows(r, _last_rows(x_ref[pl.ds(r - _TILE, _TILE), :]), after)
+
+    after = lax.fori_loop(0, n - 1, from_the_end, after_ref[...])
+    before = jnp.where(t == last_t, 0.0, _last_rows(tile_ref[...]))
+    after_ref[...] = rows(0, before, after)
+
+    @pl.when((b == last_b) & (t == last_t))
+    def _():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+        for k in range(k_w):
+            dw_ref[pl.ds(k, 1), :] = sums_ref[8 * k:8 * k + 8].sum(
+                0, keepdims=True)
+
+
+@functools.lru_cache(maxsize=None)
+def _conv_kernel(name, B, T, C, k_w, dtype, bt, bc, step, interpret):
+    """``causal_conv_fwd`` (x, w -> y) or ``causal_conv_bwd`` (x, dy, w ->
+    dx, dw) at one shape: grid (channel block, batch row, time block) of
+    (bt, bc) blocks worked ``step`` rows at a time, w and dw (8, C)
+    float32.  The ``pallas_call`` stands inside a jitted
+    function of the kernel's name, so that the v5e's trace names it so and
+    not ``tpu_custom_call.<n>`` (``ops/delta_rule._kernel``), the name
+    ``kernels.custom_call_ms`` reads the flash kernels by."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    back = name == "causal_conv_bwd"
+    nt = T // bt
+    at = (lambda t: nt - 1 - t) if back else (lambda t: t)
+    seq = jax.ShapeDtypeStruct((B, T, C), dtype)
+    block = pl.BlockSpec((None, bt, bc), lambda c, b, t: (b, at(t), c))
+    # the tile that ends where the block begins
+    tile = pl.BlockSpec((None, _TILE, bc), lambda c, b, t: (
+        b, jnp.maximum(at(t) * (bt // _TILE) - 1, 0), c))
+    taps = (jax.ShapeDtypeStruct((8, C), jnp.float32),
+            pl.BlockSpec((8, bc), lambda c, b, t: (0, c)))
+    body = _conv_bwd_kernel if back else _conv_fwd_kernel
+
+    def run(*args):
+        return pl.pallas_call(
+            functools.partial(body, k_w=k_w, step=step),
+            grid=(C // bc, B, nt),
+            in_specs=[block, tile] + [block] * back + [taps[1]],
+            out_specs=[block, taps[1]] if back else block,
+            out_shape=[seq, taps[0]] if back else seq,
+            scratch_shapes=[pltpu.VMEM((8, bc), jnp.float32),
+                            pltpu.VMEM((8 * k_w, bc), jnp.float32)] * back,
+            compiler_params=pltpu.CompilerParams(dimension_semantics=(
+                ("parallel", "arbitrary", "arbitrary") if back
+                else ("parallel",) * 3)),
+            interpret=interpret, name=name)(*args)
+    run.__name__ = run.__qualname__ = name
+    return jax.jit(run)
+
+
+def _conv_call(name, x, w, interpret, *args):
+    """Kernel ``name`` at the shape of x (B, T, C) and w (K, C), on
+    ``args`` and the taps as the kernels read them."""
+    bc = math.gcd(x.shape[2], CONV_LANES)    # lane blocks that divide C
+    taps = jnp.pad(w.astype(jnp.float32), ((0, 8 - w.shape[0]), (0, 0)))
+    return _conv_kernel(name, *x.shape, w.shape[0], jnp.dtype(x.dtype),
+                        CONV_ROWS, bc, min(CONV_STEP, CONV_ROWS),
+                        interpret)(*args, taps)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _conv_silu_kernels(x, w, interpret):
+    """:func:`causal_conv_silu` in the kernels, whatever the backend."""
+    return _conv_call("causal_conv_fwd", x, w, interpret, x, x)
+
+
+def _conv_silu_fwd(x, w, interpret):
+    return _conv_silu_kernels(x, w, interpret), (x, w)
+
+
+def _conv_silu_bwd(interpret, res, dy):
+    x, w = res
+    dx, dw = _conv_call("causal_conv_bwd", x, w, interpret, x, x, dy)
+    return dx, dw[:w.shape[0]].astype(w.dtype)
+
+
+_conv_silu_kernels.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+def _conv_kernels_run(x, w) -> bool:
+    """Whether a call runs in the kernels: on a TPU, bf16 activations,
+    channels in whole 128-lane blocks, whole time blocks, at most 8 taps.
+    Everything else (float32, the tests' narrow widths, a ragged T, the
+    CPU) takes the XLA form."""
+    return (jax.default_backend() == "tpu" and x.dtype == jnp.bfloat16
+            and x.shape[2] % 128 == 0 and x.shape[1] % CONV_ROWS == 0
+            and w.shape[0] <= 8)
+
+
+def causal_conv_silu(x: jax.Array, w: jax.Array) -> jax.Array:
+    """``silu(causal_conv(x, w))`` without a bias, in x's type: x (B, T,
+    C), w (K, C).  float32 multiplies, adds and silu and one rounding on
+    the way out, in both forms; which runs is read from the call
+    (``_conv_kernels_run``)."""
+    if _conv_kernels_run(x, w):
+        return _conv_silu_kernels(x, w, False)
+    return jax.nn.silu(causal_conv(x, w, None)[0]).astype(x.dtype)
 
 
 # --------------------------------------------------------------------- scan

@@ -261,6 +261,58 @@ def test_the_delta_rule_compiles_at_qwen3_next_train_shape(v5e, monkeypatch,
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
+def _conv_loss(x, w):
+    """The mixer's conv, its silu and slices into q | k | v that are read
+    apart, as ``models/qwen3_next._gdn_mixer`` reads them."""
+    from ray_tpu.ops.ssm import causal_conv_silu
+    y = causal_conv_silu(x, w).astype(jnp.float32)
+    return y[..., :2048].sum() + 2 * y[..., 2048:4096].sum() \
+        + 3 * y[..., 4096:].sum()
+
+
+@pytest.mark.parametrize("dtype", [
+    pytest.param(jnp.bfloat16, id="bf16_in_kernels"),
+    pytest.param(jnp.float32, id="float32_in_xla")])
+def test_the_fused_conv_compiles_at_qwen3_next_train_shape(v5e, monkeypatch,
+                                                           dtype):
+    """``ops/ssm.causal_conv_silu`` forward, and forward and backward, at
+    2 x 8,192 positions of 8,192 channels and 4 taps, on a TPU.  In bf16
+    (the cell) exactly ``causal_conv_fwd`` and ``causal_conv_bwd`` under
+    their own names, which ``gdn.conv_kernel_ms`` reads and the
+    ``tpu_custom_call`` metrics do not, their first results in that
+    metric's ``shapes``; no float32 array of the sequence's size is left
+    (the padded copy and the product XLA's form wrote), and what is kept
+    for the backward is x alone.  Float32 activations take the XLA form at
+    the same shape: no kernel."""
+    import json
+    from pathlib import Path
+    from ray_tpu.ops.ssm import causal_conv_silu
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in
+            (((2, 8192, 8192), dtype), ((4, 8192), jnp.bfloat16))]
+    texts = [jax.jit(fn).lower(*args).compile().as_text()
+             for fn in (causal_conv_silu,
+                        jax.grad(_conv_loss, argnums=(0, 1)))]
+    if dtype == jnp.float32:
+        assert not [t for t in texts if "tpu_custom_call" in t]
+        return
+    kernels = [_kernel_names_and_results(t) for t in texts]
+    assert [[(name.split(".")[0], shape) for name, shape in ks]
+            for ks in kernels] == [
+        [("causal_conv_fwd", "bf16[2,8192,8192]")],
+        [("causal_conv_bwd", "bf16[2,8192,8192]")]], kernels
+    spec = json.loads((Path(__file__).parent.parent / "perfbench"
+                       / "layer_metrics" / "gdn.conv_kernel_ms.json"
+                       ).read_text())["params"]
+    for name, shape in kernels[0] + kernels[1]:
+        assert shape in spec["shapes"]
+        assert any(part in name for part in spec["names"])
+        assert "tpu_custom_call" not in name
+    for text in texts:
+        assert "f32[2,8192,8192]" not in text
+        assert "f32[2,8195,8192]" not in text
+
+
 def _latent(*parts):
     from ray_tpu.ops.flash_attention import latent_flash_attention
     return latent_flash_attention(*parts, None, False)

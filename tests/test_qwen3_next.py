@@ -8,6 +8,7 @@ held, the renormalised softmax choice, a softmax share's statistics, the
 one-list form of the rows' way back, the mesh rules."""
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -379,3 +380,48 @@ def test_the_mesh_rules_place_every_new_leaf(axes):
     for leaf, sh in zip(jax.tree_util.tree_leaves(shapes),
                         jax.tree_util.tree_leaves(shardings)):
         sh.shard_shape(leaf.shape)          # raises where it does not divide
+
+
+# ------------------------------------------- the conv's kernels in the mixer
+def test_the_mixers_conv_in_its_kernels_under_a_checkpoint(monkeypatch):
+    """What the cell's DeltaNet block does with ``ops/ssm.causal_conv_silu``
+    on the chip, here through the kernels' interpret mode at a conv of 128
+    channels (2 key heads of 16, 4 value heads of 16, bf16 activations,
+    two time blocks): the mixer under ``jax.checkpoint`` and ``jax.grad``
+    runs the forward kernel twice (the pass and its recomputation) and the
+    backward once, and value and every gradient, the taps' among them,
+    stay the XLA form's to bf16's rounding."""
+    from ray_tpu.ops import ssm
+    cfg = dataclasses.replace(CFG, gdn_key_dim=16, gdn_value_dim=16,
+                              dtype=jnp.bfloat16)
+    assert cfg.conv_width == 128
+    lp = _layer(random_tree(cfg, seed=7))
+    u = jax.random.normal(jax.random.key(12), (2, 64, cfg.n_embd)) \
+        .astype(cfg.dtype)
+    probe = jax.random.normal(jax.random.key(13), u.shape)
+
+    def loss(u, lp):
+        out = jax.checkpoint(lambda u, lp: qn._gdn_mixer(u, lp, cfg)[0])(u, lp)
+        return (out.astype(jnp.float32) * probe).sum()
+
+    run = jax.value_and_grad(loss, argnums=(0, 1))
+    want, want_grads = run(u, lp)
+    monkeypatch.setattr(ssm, "CONV_ROWS", 32)
+    monkeypatch.setattr(ssm, "CONV_STEP", 16)
+    monkeypatch.setattr(ssm, "causal_conv_silu",
+                        lambda x, w: ssm._conv_silu_kernels(x, w, True))
+    program = str(jax.make_jaxpr(run)(u, lp))
+    calls = re.findall(r"jit\[\s*name=(causal_conv_\w+)", program)
+    assert sorted(calls) == ["causal_conv_bwd"] + ["causal_conv_fwd"] * 2
+    got, got_grads = run(u, lp)
+    assert abs(float(got) - float(want)) < 0.02 * abs(float(want)) + 0.05
+    got_grads, want_grads = _flat(got_grads), _flat(want_grads)
+    assert any("conv" in key for key in want_grads)
+    for key, b in want_grads.items():
+        if "experts" in key or "router" in key or "shared" in key \
+                or "mlp_norm" in key or "mixer_norm" in key:
+            continue                    # the mixer reads none of them
+        a, b = np.float32(got_grads[key]), np.float32(b)
+        assert np.abs(b).max() > 0, key
+        np.testing.assert_allclose(a, b, atol=0.03 * np.abs(b).max(),
+                                   err_msg=key)
