@@ -32,12 +32,9 @@ class SamplingParams:
 class EngineConfig:
     """Engine-level knobs (model, cache geometry, batching limits).
 
-    ``model`` is "<family>:<preset>" over the in-tree model zoo —
-    ``gpt2:tiny``, ``gpt2:gpt2-124m``, ``llama:tiny``, ``llama:llama3-8b``,
-    ``falcon_h1:tiny``, ``lfm2:tiny``, ``minicpm_sala:tiny``, ``afmoe:tiny``,
-    ``ling:tiny`` … (the PRESETS of ``models/gpt2.py``, ``llama.py``,
-    ``falcon_h1.py``, ``lfm2.py``, ``minicpm_sala.py``, ``afmoe.py``,
-    ``ling.py``).
+    ``model`` is "<family>:<preset>": a family of ``SERVED_FAMILIES``, a
+    preset of its module's ``PRESETS`` (``gpt2:tiny``, ``gpt2:gpt2-124m``,
+    ``llama:llama3-8b``, ``ling:tiny`` ...).
     """
 
     model: str = "gpt2:tiny"
@@ -66,27 +63,21 @@ class EngineConfig:
         return self.model.replace(":", "_").replace("/", "_")
 
 
+# the families the engine serves: ``ray_tpu.models.<family>`` exports
+# ``forward_prefill`` / ``forward_decode`` and what ``model_runner.py`` lists
+SERVED_FAMILIES = ("gpt2", "llama", "falcon_h1", "lfm2", "minicpm_sala",
+                   "afmoe", "ling")
+
+
 def resolve_model(cfg: EngineConfig):
     """"<family>:<preset>" → (module, model cfg) from the in-tree zoo."""
+    import importlib
     family, _, preset = cfg.model.partition(":")
     preset = preset or "tiny"
-    if family == "gpt2":
-        from ray_tpu.models import gpt2 as mod
-    elif family == "llama":
-        from ray_tpu.models import llama as mod
-    elif family == "falcon_h1":
-        from ray_tpu.models import falcon_h1 as mod
-    elif family == "lfm2":
-        from ray_tpu.models import lfm2 as mod
-    elif family == "minicpm_sala":
-        from ray_tpu.models import minicpm_sala as mod
-    elif family == "afmoe":
-        from ray_tpu.models import afmoe as mod
-    elif family == "ling":
-        from ray_tpu.models import ling as mod
-    else:
+    if family not in SERVED_FAMILIES:     # outside input: no import of it
         raise ValueError(f"unknown model family {family!r} (expected "
-                         "gpt2|llama|falcon_h1|lfm2|minicpm_sala|afmoe|ling)")
+                         f"{'|'.join(SERVED_FAMILIES)})")
+    mod = importlib.import_module(f"ray_tpu.models.{family}")
     try:
         mcfg = mod.PRESETS[preset]()
     except KeyError:

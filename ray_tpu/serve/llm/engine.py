@@ -41,7 +41,7 @@ import queue
 import threading
 import time
 import uuid
-from collections import deque
+from collections import Counter, deque
 from typing import Dict, List, NamedTuple, Optional
 
 import numpy as np
@@ -62,6 +62,25 @@ logger = rtlog.get("serve.llm.engine")
 
 _DONE = "__llm_done__"
 _ERR = "__llm_err__"
+
+
+# What a decode step says of itself, by name (the attributes of
+# ``llm.decode.pull``: a step's riders and host reads; and the cache's own
+# counts at the step, ``PagedKVCache.held_counts``) -> the series it feeds
+# and how: the one place that publishes them is ``mcat.tell_step``.
+STEP_SERIES = {
+    "experts_touched": ("rtpu_llm_moe_experts_touched", "observe"),
+    "sparse_pages_read": ("rtpu_llm_sparse_pages_read", "inc"),
+    "sparse_pages_held": ("rtpu_llm_sparse_pages_held", "inc"),
+    "latent_pages_read": ("rtpu_llm_latent_pages_read", "inc"),
+    "window_blocks_held": ("rtpu_llm_kv_window_blocks_held", "inc"),
+    "window_blocks_held_unwindowed":
+        ("rtpu_llm_kv_window_blocks_unwindowed", "inc"),
+    "window_blocks_released":
+        ("rtpu_llm_kv_window_blocks_released_total", "inc"),
+    "state_rows_held": ("rtpu_llm_state_rows_held", "set"),
+    "latent_blocks_held": ("rtpu_llm_latent_blocks_held", "set"),
+}
 
 
 def _new_seq_id() -> str:
@@ -166,25 +185,9 @@ class LLMEngine:
         _weights.reap_orphans()
         self.cfg = cfg
         self.runner = ModelRunner(cfg, params)
-        select = self.runner.select_spec or {}
-        if select and select["block"] != cfg.block_size:
-            raise ValueError(
-                f"{cfg.model} selects pages of {select['block']} positions: "
-                f"block_size {cfg.block_size} is not its page")
-        # a family with recurrent state gets a row of it per sequence
-        # slot beside the blocks, in the same holder (kv_cache.py); the
-        # pool is laid out for the layers that hold K/V and the store for
-        # those that hold state, which the runner counts
-        self.cache = PagedKVCache(
-            cfg.num_blocks, self.runner.kv_layers, cfg.block_size,
-            self.runner.n_kv, self.runner.head_dim, dtype=np.float32,
-            state=self.runner.state_spec, max_seqs=cfg.max_num_seqs,
-            state_layers=self.runner.state_layers,
-            select_stride=select.get("stride", 0),
-            window_layers=self.runner.window_layers,
-            window=self.runner.window,
-            latent_layers=self.runner.latent_layers,
-            latent_dim=self.runner.latent_dim)
+        # what a sequence of this model keeps, the cache holds: blocks, and
+        # beside them whatever planes its module declares (kv_cache.PLANES)
+        self.cache = PagedKVCache.for_engine(cfg, self.runner.family.kept)
         self.runner.cache = self.cache
         self.sched = IterationScheduler(cfg.max_num_seqs,
                                         cfg.max_prefill_tokens,
@@ -217,31 +220,19 @@ class LLMEngine:
         # rows of recurrent state the compiled decode steps read and
         # wrote: the whole store each step, whatever the batch (loop-owned)
         self.state_rows_stepped = 0
-        # a model that routes: the distinct experts the decode steps' live
-        # rows chose, summed over steps and routed layers, and how many
-        # (step, routed layer) pairs that is: their ratio is the experts
-        # a layer's step has to read (loop-owned)
-        self.experts_touched = 0
-        self.routed_layer_steps = 0
-        # a model whose attention chooses its pages: the pages its decode
-        # steps read and the pages their rows' contexts held, over live
-        # rows, sparse layers and KV heads (the step program counts both);
-        # and the chunks of prompts run by a model that prefills in chunks
-        # (each is also one of prefill_steps) (loop-owned)
-        self.sparse_pages_read = 0
-        self.sparse_pages_held = 0
-        self.prefill_chunks = 0
-        # a model with window layers: the blocks its decode steps' window
-        # layers read and what full layers would have read in their place,
-        # over live rows and window layers (loop-owned; the cache counts
-        # the blocks held and given back)
-        self.window_blocks_read = 0
-        self.window_blocks_unwindowed = 0
-        self._window_released_sent = 0
-        # a model with latent layers: the latent pages its decode steps'
-        # absorbed kernel walked, over live rows and latent layers
+        # what the decode steps said of themselves, summed by name over the
+        # steps read (``Chosen.reads``: a routing model's experts touched,
+        # over routed layers; a selecting model's pages read and held, over
+        # live rows, sparse layers and KV heads; window layers' blocks read
+        # and what full layers would have read; latent pages walked), and
+        # ``steps``, how many were read; and the chunks of prompts run by a
+        # model that prefills in chunks (each is also one of prefill_steps)
         # (loop-owned)
-        self.latent_pages_read = 0
+        self.step_reads: Counter = Counter()
+        # the count that is told per routed layer: the layers it sums over
+        route = self.runner.route_spec
+        self._per = {"experts_touched": route["layers"]} if route else {}
+        self.prefill_chunks = 0
         # tokens by where they were chosen (the step program's argmax for
         # a greedy request; ModelRunner.sample on a pulled row for any
         # other) and the bytes of logits pulled for the latter.
@@ -739,7 +730,7 @@ class LLMEngine:
     def _commit(self, flight: _InFlight, span: hot_span) -> None:
         """Wait for ``flight``'s ids and give each sequence its token;
         ``span`` (the ``llm.decode`` or ``llm.decode.drain`` that reads the
-        step) is told what a routing model's step touched."""
+        step) is told what the step says of itself, as its pull was."""
         try:
             chosen = self.runner.pull_step(flight.step)
         except BaseException:
@@ -750,41 +741,13 @@ class LLMEngine:
                     self._return_slots(lost.batch, lost.slots)
             self._inflight = None
             raise
-        if chosen.touched is not None:
-            layers = self.runner.route_spec["layers"]
-            span.set(experts_touched=chosen.touched)
-            self.experts_touched += chosen.touched
-            self.routed_layer_steps += layers
-            if GLOBAL_CONFIG.metrics_enabled:
-                mcat.get("rtpu_llm_moe_experts_touched").observe(
-                    chosen.touched / layers, tags={"model": self.cfg.model})
-        if chosen.pages is not None:
-            read, held = chosen.pages
-            span.set(sparse_pages_read=read, sparse_pages_held=held)
-            self.sparse_pages_read += read
-            self.sparse_pages_held += held
-            if GLOBAL_CONFIG.metrics_enabled:
-                tags = {"model": self.cfg.model}
-                mcat.get("rtpu_llm_sparse_pages_read").inc(read, tags=tags)
-                mcat.get("rtpu_llm_sparse_pages_held").inc(held, tags=tags)
-        reads = flight.step.reads
+        reads = chosen.reads
         if reads:
             span.set(**reads)
-        if "window_blocks" in reads:
-            self.window_blocks_read += reads["window_blocks"]
-            self.window_blocks_unwindowed += reads["window_blocks_unwindowed"]
-            if GLOBAL_CONFIG.metrics_enabled:
-                self._publish_window_blocks()
-        if "latent_pages_read" in reads:
-            self.latent_pages_read += reads["latent_pages_read"]
-            if GLOBAL_CONFIG.metrics_enabled:
-                tags = {"model": self.cfg.model}
-                mcat.get("rtpu_llm_latent_pages_read").inc(
-                    reads["latent_pages_read"], tags=tags)
-                mcat.get("rtpu_llm_state_rows_held").set(
-                    self.cache.state_rows_used(), tags=tags)
-                mcat.get("rtpu_llm_latent_blocks_held").set(
-                    self.cache.used_block_count(), tags=tags)
+        self.step_reads.update(reads, steps=1)
+        if GLOBAL_CONFIG.metrics_enabled:
+            mcat.tell_step({**reads, **self.cache.held_counts()}, STEP_SERIES,
+                           {"model": self.cfg.model}, self._per)
         # the span says whose tokens it put on their streams, and of which
         # step: a token is on its stream at this span's END
         with hot_span("llm.decode.commit", self.span_s,
@@ -805,20 +768,6 @@ class LLMEngine:
             commit.set(tokens=len(emitted), seqs="|".join(emitted))
         self.decode_rows_discarded += discarded
         self._count_tokens(len(emitted), phase="decode")
-
-    def _publish_window_blocks(self) -> None:
-        """The cache's three counts of window blocks to the catalog: held
-        and what one table for all layers would hold as gauges' worth of
-        counters (each step adds what the live sequences hold now, so
-        their ratio is the mean over steps), given back as a total."""
-        tags = {"model": self.cfg.model}
-        held, released, unwindowed = self.cache.window_counts()
-        mcat.get("rtpu_llm_kv_window_blocks_held").inc(held, tags=tags)
-        mcat.get("rtpu_llm_kv_window_blocks_unwindowed").inc(
-            unwindowed, tags=tags)
-        mcat.get("rtpu_llm_kv_window_blocks_released_total").inc(
-            released - self._window_released_sent, tags=tags)
-        self._window_released_sent = released
 
     def _return_slots(self, batch: List[Sequence], slots: Dict) -> None:
         for s in batch:
@@ -1041,22 +990,10 @@ class LLMEngine:
     def _blocks_are_all_a_sequence_holds(self, what: str) -> None:
         """The manifest of ``prefill_remote`` / ``attach`` carries blocks
         and nothing else: half a sequence, for a model with more."""
-        if self.cache.state_rows:
-            raise NotImplementedError(
-                f"{what}: {self.cfg.model} keeps recurrent state beside "
-                "its K/V blocks, and the manifest exports blocks only; "
-                "nothing moves the state yet, so nothing is moved")
-        if self.cache.window_layers:
-            raise NotImplementedError(
-                f"{what}: {self.cfg.model} keeps its window layers' K/V in "
-                "a second pool under a second table, and the manifest "
-                "exports the one table's blocks; nothing moves the window "
-                "layers' yet, so nothing is moved")
-        if self.cache.latent_layers:
-            raise NotImplementedError(
-                f"{what}: {self.cfg.model} caches latent rows, and a "
-                "block's wire format is a K and a V a layer; nothing "
-                "exports a latent page yet, so nothing is moved")
+        for plane in self.cache.planes:
+            if plane.unexported:
+                raise NotImplementedError(
+                    f"{what}: {self.cfg.model} {plane.unexported}")
 
     def _drain_cancels(self) -> None:
         with self._lock:
@@ -1193,6 +1130,7 @@ class LLMEngine:
 
     # ------------------------------------------------------------------ stats
     def stats(self) -> dict:
+        read = self.step_reads
         return dict(prefill_steps=self.prefill_steps,
                     decode_steps=self.decode_steps,
                     decode_steps_ahead=self.decode_steps_ahead,
@@ -1225,11 +1163,12 @@ class LLMEngine:
                     state_commits=self.cache.state_commits,
                     kv_layers=self.cache.kv_layers,
                     state_layers=self.cache.state_layers,
-                    experts_touched=self.experts_touched,
-                    routed_layer_steps=self.routed_layer_steps,
+                    experts_touched=read["experts_touched"],
+                    routed_layer_steps=read["steps"] * self._per.get(
+                        "experts_touched", 0),
                     prefill_chunks=self.prefill_chunks,
-                    sparse_pages_read=self.sparse_pages_read,
-                    sparse_pages_held=self.sparse_pages_held,
+                    sparse_pages_read=read["sparse_pages_read"],
+                    sparse_pages_held=read["sparse_pages_held"],
                     select_bytes=self.cache.select_bytes,
                     staging_bytes=self.runner.staging_bytes,
                     window_layers=self.cache.window_layers,
@@ -1237,10 +1176,10 @@ class LLMEngine:
                     window_blocks=dict(zip(
                         ("held", "released", "unwindowed"),
                         self.cache.window_counts())),
-                    window_blocks_read=self.window_blocks_read,
-                    window_blocks_unwindowed=self.window_blocks_unwindowed,
+                    window_blocks_read=read["window_blocks"],
+                    window_blocks_unwindowed=read["window_blocks_unwindowed"],
                     latent_layers=self.cache.latent_layers,
                     latent_bytes=self.cache.latent_bytes,
-                    latent_pages_read=self.latent_pages_read,
+                    latent_pages_read=read["latent_pages_read"],
                     span_s={k: list(v) for k, v in
                             list(self.span_s.items())})

@@ -44,125 +44,74 @@ preempted sequence's blocks.  Shared blocks (an attached sequence
 re-exported, future prefix caching) are refcounted: ``free_seq`` returns
 a block to the free list only at refcount zero.
 
-Recurrent state.  A model family whose sequences hold more than K/V (a
-state-space mixer's scan state and conv tail: ``models/falcon_h1.py``)
-describes one sequence's state per layer, and the cache is built with
-that description (``state=``).  What ``cache.pool`` holds is always a
-dict, ``{"kv": the array}``, and for such a family ``{"kv": the array,
-"state": the store}``, the
-store one leaf per kind of state, ``(n_layer, max_seqs + 1, *shape)``: a
-*row* per sequence slot and a last one for staging.  The same holder, the
-same donating programs, one lifetime: ``alloc_seq`` gives a sequence its
-row with its blocks and ``free_seq`` (finish, cancel, preemption) takes
-both back.  The row is fixed-size and never paged.  A prompt's prefill
-program leaves its state in the staging row (``model_runner.py``), and
-``scatter_prefill`` moves it to the sequence's row in the program that
-scatters its K/V; the decode program steps the rows ``rows_of`` names
-for the tables it was given.  Without ``state=`` the dict has no
-``"state"`` and the programs no operand for it.
+The planes.  What a sequence keeps is declared once, in :data:`PLANES`: a
+row a kind of thing a sequence can keep (:class:`Plane`), five today.
+``cache.pool`` holds a dict with an entry for every plane the cache has,
+always ``"kv"``, and the same donating programs, the one lock and the one
+lifetime serve them all: ``alloc_seq`` gives a sequence what it needs of
+each and ``free_seq`` (finish, cancel, preemption) takes it back.  A row
+says the entry's shape, what the decode forward is handed for it, how a
+step's new rows and a prompt's rows are written into it, what it tells the
+pull's span, and why the block manifest cannot carry it; the cache, the
+runner and the engine walk the rows and know no kind by name.  Which planes
+a model has follows from what its module declares (:func:`kept_by`); a
+plane a family does not name is no entry, no operand, and nothing in its
+programs.  The kinds, in the table's order:
 
-Layers that differ in kind.  The pool's ``n_layer`` counts the layers that
-hold K/V and the store's leading axis the layers that hold state
-(``state_layers=``): the same number where every layer holds both
-(Falcon-H1), two numbers where some layers attend and the others keep a
-conv's tail and none does both (``models/lfm2.py``: the module counts them
-in ``cache_layers(cfg)``).  Each kind is numbered among its own in layer
-order; a pool laid out for all the layers of such a model would be mostly
-empty and the decode kernel's layer index wrong.  Nothing else here knows:
-a row is a row and a block a block.
+``"state"``: recurrent state (a state-space mixer's scan state and conv
+tail, ``models/falcon_h1.py``), one leaf a kind of state, ``(state layers,
+max_seqs + 1, *shape)``: a *row* a sequence slot and a last one for
+staging, fixed-size and never paged.  A prompt's prefill leaves its state
+in the staging row and ``scatter_prefill`` moves it to the sequence's row
+in the program that scatters its K/V; the decode program steps the rows
+``rows_of`` names.  The pool counts the layers that hold K/V and the store
+those that hold state: two numbers where no layer holds both
+(``models/lfm2.py``), each kind numbered among its own.
 
-A selector's cache.  A family whose attention chooses the pages it reads
-(``models/minicpm_sala.py``: block-sparse attention over compressed keys)
-holds a third thing, which, unlike a row of state, grows with the context:
-for every ``stride`` positions of every page of every layer that holds
-K/V, the sum of those positions' keys (a *half-kernel*:
-``ops/sparse_attention.py`` pools a compressed key from two neighbouring
-ones, so no pooled key straddles a page in storage even where its window
-does).  The cache is built with the stride (``select_stride=``, from the
-module's ``page_selector(cfg)``) and the holder then holds ``{"kv", "state",
-"sel"}``, ``sel`` :func:`selector_shape` = ``(n_layer, num_blocks,
-block_size / stride, F)``: paged by the sequence's own block table, so a
-page's slots come and go with the page (``alloc_seq``, ``free_seq``,
-preemption) and cost no allocator of their own.  It is written by the
-cache's own writers and by nobody else: ``scatter_prefill`` computes a
-prompt's slots from the K it scatters, and ``write_rows`` (the decode
-step's write, ``write_token``) sums the slot a new row falls in again
-from the pool's rows, so writing a row twice changes nothing and no
-caller can write K and forget its slot.  (``load_block`` writes none: the
-one family with a selector keeps state rows too, and the engine refuses to
-attach a sequence of such a model.)  A family that names no selector has
-no ``"sel"`` in its holder, and its programs none of this.
+``"sel"``: a selector's cache, for attention that chooses the pages it
+reads (``models/minicpm_sala.py``): for every ``stride`` positions of
+every page of every K/V layer the sum of those positions' keys (a
+*half-kernel*, ``ops/sparse_attention.py``), :func:`selector_shape`, paged
+by the sequence's own table.  Only the cache's writers write it, a scatter
+from the K it scatters and a step's write from the pool's rows again: so
+writing a row twice changes nothing and no caller can write K and forget
+its slot.
 
-Pages of two kinds.  A family whose attention is full in some layers and a
-sliding window in the others (``models/afmoe.py``; its ``cache_layers``
-counts a third kind, ``"window"``) needs every position of a context in
-the one kind of layer and the last ``window`` in the other.  Held in one
-pool under one table, the window layers would keep what they never read
-again: 4 of 5 layers at a 25k context.  So the holder has a second pool,
-``"kvw"``, :func:`device_shape` over the window layers and over
-``max_seqs x (ceil(window / bs) + 1)`` blocks (what the sequence slots can
-hold at once: it cannot run out, and the scheduler is told nothing of
-it), and the manager a second free list and a second table a sequence,
-under the one lock, with the one lifetime.  The window table is indexed
-like the other (column ``j`` is positions ``j bs ..``), so both grow in
-one :meth:`append_slot` and one ``grew`` undoes both; a column wholly
-behind the window names no block (``window_blocks``, out of range) and its
-block is back on the window free list: given back in ``alloc_seq`` (a
-prompt's window blocks are only those its first decode step can see) and
-in every ``append_slot`` that carries the window past a block's last
-position.  The decode kernel starts its walk at the window's first column
-(``ops/paged_attention.py``), so a released column is never read.  A
-released block may be handed to another sequence at once: every program
-that reads or writes the pool takes it donated, the device runs them in
-the order they were enqueued, and the step that last read the block was
-enqueued before any write of its next owner.  ``free_seq`` (finish,
-cancel, preemption) returns both tables' blocks; ``rollback_slot`` undoes
-a growth in both and leaves what was released released (it lies behind the
-window of the position reserved again); ``fork_seq`` refuses, because a
-shared window block would be given back by whichever holder passes it
-first.  ``window_held`` / ``window_released`` / ``window_unwindowed`` count
-the window blocks held now, given back so far, and what one table for all
-layers would hold for the sequences alive now.  A family that names no
-window has no ``"kvw"`` in its holder, no second list, and none of this in
-its programs.
+``"kvw"``: pages of a second kind, for layers that need only the last
+``window`` positions (``models/afmoe.py``) and under one table would keep
+what they never read again (4 of 5 layers at a 25k context): a second pool
+over the window layers and ``max_seqs x (ceil(window / bs) + 1)`` blocks
+(what the sequence slots can hold at once: it cannot run out), a second
+free list and a second table a sequence, indexed like the other.  A column
+wholly behind the window names no block (``window_blocks``, out of range)
+and its block is back on the free list (``alloc_seq``, ``append_slot``);
+the decode kernel starts its walk at the window's first column, and a
+released block may go to another sequence at once, because every program
+takes the pool donated and the device runs them in the order they were
+enqueued.
 
-A latent page.  A family whose attention caches ONE row a position and not
-a K and a V (``models/ling.py``: multi-head latent attention, the row ``[c
-| k_rope]`` of ``kv_lora_rank + qk_rope_head_dim`` features; its
-``cache_layers`` counts a kind ``"latent"``) gets a third kind of page: a
-pool of ONE plane, ``"latent"``, :func:`device_shape` with ``planes=1`` =
-``(latent layers, 1, num_blocks, block_size, F)``, the row's features
-zero-padded to whole lanes (576 -> 640).  It has the other pool's
-``num_blocks`` and lies under the same table: block ``i`` of the one is
-block ``i`` of the other, so ``alloc_seq``, ``append_slot``,
-``rollback_slot``, ``free_seq`` and preemption know nothing of it, and it
-costs no list, no lock and no lifetime of its own.  It is written by the
-cache's own writers: :func:`write_rows` takes a pool of either form (``v``
-None where there is one plane), ``scatter_prefill`` lays a prompt's rows
-(handed as its ``ks``, ``(latent layers, T, 1, R)``; ``vs`` is not read)
-and ``write_token`` one token's.  The absorbed decode kernel
-(``ops/paged_attention.latent_attention_decode``) reads a page once for
-keys and values.  Such a family's layers hold no K/V today: its ``"kv"``
-pool has no layer and no byte, and stays in the holder so that every
-program keeps its operands' labels.  A latent page is not exported
-(``block_bytes`` / ``load_block`` refuse: the wire format is K and V) and
-not shared (``fork_seq`` refuses); the one family that has them keeps
-state rows too.  A family that names no latent layer has no ``"latent"``
-in its holder and none of this in its programs.
+``"latent"``: a latent page, for attention that caches ONE row a position
+(``models/ling.py``: ``[c | k_rope]``), :func:`device_shape` with
+``planes=1``, under the K/V pool's own table: the allocator knows nothing
+of it.  A prompt's rows reach the scatter as its ``ks`` (``vs`` is not
+read).  Such a family's ``"kv"`` pool has no layer and no byte, and stays
+in the holder so that every program keeps its operands' labels.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 import os
 import threading
 from types import SimpleNamespace
-from typing import Dict, List
+from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
 from ray_tpu._private import rtlog
+from ray_tpu._private.flight_recorder import _pid_alive
 from ray_tpu._private.xla_watchdog import compile_budget
 
 logger = rtlog.get("serve.llm.kv")
@@ -198,16 +147,6 @@ def reap_orphan_export_spools(base) -> List[str]:
         logger.info("reaped %d orphaned export spool(s): %s",
                     len(reaped), reaped)
     return reaped
-
-
-def _pid_alive(pid: int) -> bool:
-    try:
-        os.kill(pid, 0)
-    except ProcessLookupError:
-        return False
-    except PermissionError:
-        return True
-    return True
 
 
 def device_shape(num_blocks: int, n_layer: int, block_size: int,
@@ -274,13 +213,10 @@ def _halves_rewritten(sel, pool, blocks, offsets):
     return flat.reshape(sel.shape)
 
 
-def write_rows(pool, blocks, offsets, k, v=None, sel=None):
+def write_rows(pool, blocks, offsets, k, v=None):
     """``pool[:, 0 / 1, blocks[r], offsets[r]] = k / v[:, r]`` for every
     row ``r``, cast to the pool's type; traceable.  A pool of one plane (a
-    latent pool) takes its rows as ``k`` and no ``v``.  With ``sel`` (the
-    selector's cache of a family that has one) the half-kernels those rows
-    fall in are brought up to date from the K just written, and the result
-    is ``(pool, sel)``: whoever writes K writes them.
+    latent pool) takes its rows as ``k`` and reads no ``v``.
 
     k, v: (L, R, KV, D), in head form as the models return them; they
     are laid flat along the lanes and zero-padded to the pool's ``F``
@@ -311,23 +247,321 @@ def write_rows(pool, blocks, offsets, k, v=None, sel=None):
                      planes * n_layer * per_slab)
     flat = pool.reshape(-1, f).at[rows.reshape(-1)].set(
         kv.reshape(-1, f).astype(pool.dtype), mode="drop")
-    pool = flat.reshape(pool.shape)
-    if sel is None:
-        return pool
-    return pool, _halves_rewritten(sel, pool, blocks, offsets)
+    return flat.reshape(pool.shape)
 
 
-def write_rows_by_kind(held, blocks, wblocks, offsets, k, v):
-    """:func:`write_rows` where pages are of two kinds: k, v (L, R, KV, D)
-    hold the full layers' rows first and then the window layers'; the one
-    go to ``held["kv"]`` at ``blocks``, the other to ``held["kvw"]`` at
-    ``wblocks``, both at ``offsets``.  Returns the holder's dict."""
-    n_full = held["kv"].shape[0]
-    return {**held,
-            "kv": write_rows(held["kv"], blocks, offsets, k[:n_full],
-                             v[:n_full]),
-            "kvw": write_rows(held["kvw"], wblocks, offsets, k[n_full:],
-                              v[n_full:])}
+def _halves_of_prompt(sel, ks, table, n_tokens, pool):
+    """A prompt's half-kernels from its K whole, ``ks`` (L, T, KV, D): a
+    slot each ``stride`` positions, in the pages ``table`` names; the last
+    may be part of one, which the decode steps' writes complete."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.paged_attention import lane_flat
+    from ray_tpu.ops.sparse_attention import halves_of
+    num_blocks, bs = pool.shape[2:4]
+    per_page, f = sel.shape[2:]
+    stride = bs // per_page
+    halves = halves_of(lane_flat(ks, f), n_tokens, stride)
+    first = jnp.arange(0, ks.shape[1], stride)
+    slots = table[first // bs] * per_page + first % bs // stride
+    slots = jnp.arange(sel.shape[0])[:, None] \
+        * (num_blocks * per_page) + slots
+    slots = jnp.where(first < n_tokens, slots, sel.size // f)
+    return sel.reshape(-1, f).at[slots.reshape(-1)].set(
+        halves.reshape(-1, f).astype(sel.dtype),
+        mode="drop").reshape(sel.shape)
+
+
+def _row_committed(store, row):
+    """The state a prompt's prefill left in the staging row (the store's
+    last) goes to the sequence's ``row``."""
+    import jax
+    from jax import lax
+    return jax.tree.map(lambda s: lax.dynamic_update_index_in_dim(
+        s, s[:, -1], row, 1), store)
+
+
+def window_reads(ctx_lens, block_size: int, window: int, layers: int) -> dict:
+    """What a decode step's window layers read, from its rows' context
+    lengths: positions (the window's, or the context where it is shorter),
+    blocks (the walk's columns, the first one whole), and the blocks full
+    layers would have read in their place; each summed over the rows and
+    the window layers."""
+    lens = np.asarray(ctx_lens, np.int64)
+    lo = np.maximum(lens - (window - 1), 0)
+    held = -(-lens // block_size)
+    return dict(
+        window_positions=int((lens - lo).sum()) * layers,
+        window_blocks=int((held - lo // block_size).sum()) * layers,
+        window_blocks_unwindowed=int(held.sum()) * layers)
+
+
+# ------------------------------------------------------------------ the planes
+def _declared(mod, mcfg, name):
+    """``mod.<name>(mcfg)``, or None for a module that does not export it."""
+    return getattr(mod, name)(mcfg) if hasattr(mod, name) else None
+
+
+@dataclasses.dataclass(frozen=True)
+class Kept:
+    """What one sequence of a model keeps on the device (:func:`kept_by`):
+    the K/V pool's sizes and :class:`PagedKVCache`'s keywords, which say
+    what each field is; the runner's plain reads."""
+
+    n_kv: int
+    head_dim: int
+    kv_layers: int
+    state: Optional[dict] = None
+    state_layers: int = 0
+    select_stride: int = 0
+    select_block: int = 0            # the page the selection wants
+    window_layers: int = 0
+    window: int = 0
+    latent_layers: int = 0
+    latent_dim: int = 0
+    # a prompt's prefill leaves its state in the holder: it runs through
+    # the holder, donated
+    staged: bool = False
+    # a prompt's rows lie under two tables and reach the scatter as one
+    # array a K and a V (:meth:`PagedKVCache.window_run`)
+    packed: bool = False
+
+
+def kept_by(mod, mcfg) -> Kept:
+    """The one place that reads what a model's module declares of its
+    cache: ``recurrent_state(cfg)`` (one sequence's state in one layer,
+    name -> shape and type), ``cache_layers(cfg)`` (the layers of each
+    kind: ``"kv"``, ``"state"`` and, where it has them, ``"window"`` /
+    ``"latent"``) and ``page_selector(cfg)`` (``{"stride", "block"}``).  A
+    module that declares none (GPT-2, Llama) keeps K/V in every layer; one
+    with state and no count keeps both in every layer."""
+    state = _declared(mod, mcfg, "recurrent_state")
+    layers = _declared(mod, mcfg, "cache_layers") or {
+        "kv": mcfg.n_layer, "state": mcfg.n_layer if state else 0}
+    select = _declared(mod, mcfg, "page_selector") or {}
+    window_layers = layers.get("window", 0)
+    latent_layers = layers.get("latent", 0)
+    return Kept(
+        getattr(mcfg, "n_kv_head", mcfg.n_head), mcfg.head_dim, layers["kv"],
+        state, layers["state"], select.get("stride", 0),
+        select.get("block", 0), window_layers,
+        mcfg.sliding_window if window_layers else 0, latent_layers,
+        mcfg.latent_row if latent_layers else 0, bool(state),
+        bool(window_layers))
+
+
+@dataclasses.dataclass(frozen=True)
+class Plane:
+    """One kind of thing a sequence keeps: an entry of the holder's dict
+    and all that the cache, the runner and the engine do with one.  A
+    family with a new kind of page adds a row to :data:`PLANES` (and its
+    kernel, and its module)."""
+
+    name: str                        # the entry's key
+    layers: str                      # :class:`Kept`'s count of its layers
+    # (cache) -> the entry as ShapeDtypeStruct(s); None: this cache has none
+    spec: Callable
+    # the decode forward's keywords, the entry's and the operand's, and
+    # (cache, block tables (bucket, MAXB), real rows) -> that operand, the
+    # step's host operand for it
+    handed: tuple = ()
+    by_row: Optional[Callable] = None
+    stepped: bool = False            # the forward returns it anew, first
+    # a pool of rows, (L, planes, N, bs, F): the plane whose table names
+    # its rows' blocks.  The pools share a step's or a prompt's rows in
+    # this table's order, each its layers.  ``beside``: (cache, block) ->
+    # a written token's block under a table of its own, which ``by_row``
+    # hands a step and ``prompt`` (its columns, the first position) a
+    # scatter
+    table: str = ""
+    beside: Optional[Callable] = None
+    # (cache, sequence id or None: a warm-up, tokens) -> the scatter's
+    # operands for it; ``committed`` (entry, that operand): its last act
+    prompt: Optional[Callable] = None
+    committed: Optional[Callable] = None
+    # kept beside the K/V pool: (entry, K (L, T, KV, D), table, tokens,
+    # pool), first in a scatter; (entry, pool, blocks, offsets), after a
+    # step's rows
+    from_prompt: Optional[Callable] = None
+    rewritten: Optional[Callable] = None
+    # (a step's real context lengths, cache) -> what ``llm.decode.pull`` is
+    # told; (cache) -> its own counts a step (``held_counts``)
+    reads: Optional[Callable] = None
+    holds: Optional[Callable] = None
+    # (staging, bucket, Kept) -> a chunked prompt's rows for the scatter
+    staged: Optional[Callable] = None
+    # why the block manifest cannot carry it / two sequences cannot share
+    # it, behind ``{what}: {model}`` / ``a sequence with``; "": they can
+    unexported: str = ""
+    unshared: str = ""
+
+
+def _sds(shape, dtype):
+    import jax
+    return jax.ShapeDtypeStruct(tuple(shape), dtype)
+
+
+def _nbytes(spec) -> int:
+    import jax
+    return sum(math.prod(s.shape) * np.dtype(s.dtype).itemsize
+               for s in jax.tree.leaves(spec))
+
+
+def _state_spec(c):
+    # a row a sequence slot and one for staging, in each layer of state
+    return {name: _sds((c.state_layers, c.state_rows + 1) + tuple(s.shape),
+                       s.dtype) for name, s in c.state_spec.items()} \
+        if c.state_spec else None
+
+
+def _sel_spec(c):
+    if c.select_stride and c.block_size % c.select_stride:
+        raise ValueError(
+            f"pages of {c.block_size} positions are no whole number "
+            f"of the selector's {c.select_stride}")
+    return _sds(selector_shape(c.kv_shape, c.select_stride), c.dtype) \
+        if c.select_stride else None
+
+
+def _window_spec(c):
+    if c.window_layers and (not c.max_seqs or c.window < 1):
+        raise ValueError(
+            "window layers need max_seqs (their pool is sized by "
+            "the sequence slots) and a window of positions")
+    return _sds(device_shape(c.window_blocks, c.window_layers, c.block_size,
+                             *c.block_shape[3:]), c.dtype) \
+        if c.window_layers else None
+
+
+def _state_rows(c, tables, n):
+    # whose table each is, the cache knows; padded rows name none
+    return np.concatenate([c.rows_of(tables[:n]),
+                           np.full(len(tables) - n, c.no_row, np.int32)])
+
+
+PLANES = (
+    Plane("kv", "kv_layers", lambda c: _sds(c.kv_shape, c.dtype), table="kv",
+          staged=lambda staging, bucket, kept: tuple(
+              staging[name][:, :bucket].reshape(
+                  kept.kv_layers, bucket, kept.n_kv, kept.head_dim)
+              for name in ("k", "v"))),
+    Plane("state", "state_layers", _state_spec, handed=("state", "rows"),
+          by_row=_state_rows,
+          stepped=True, prompt=lambda c, seq_id, n: (c._committing(seq_id),),
+          committed=_row_committed,
+          unexported="keeps recurrent state beside its K/V blocks, and the "
+          "manifest exports blocks only; nothing moves the state yet, so "
+          "nothing is moved",
+          unshared="recurrent state cannot be forked: its row is its own, "
+          "and a shared first block would name two rows"),
+    Plane("sel", "kv_layers", _sel_spec, handed=("selector",),
+          from_prompt=_halves_of_prompt, rewritten=_halves_rewritten),
+    Plane("kvw", "window_layers", _window_spec,
+          handed=("window_pool", "window_tables"),
+          by_row=lambda c, tables, n: c.window_tables(tables), table="kvw",
+          beside=lambda c, block: c._window_block_beside(block),
+          prompt=lambda c, seq_id, n: c._window_run(
+              c.window_table(seq_id) if seq_id else [], n),
+          reads=lambda lens, c: window_reads(lens, c.block_size, c.window,
+                                             c.window_layers),
+          holds=lambda c: c._window_holds(),
+          unexported="keeps its window layers' K/V in a second pool under a "
+          "second table, and the manifest exports the one table's blocks; "
+          "nothing moves the window layers' yet, so nothing is moved",
+          unshared="window layers cannot be forked: a shared window block "
+          "would be given back by whichever holder's context passes it "
+          "first (prefix sharing across window layers: ROADMAP R4)"),
+    Plane("latent", "latent_layers", lambda c: _sds(device_shape(
+              c.num_blocks, c.latent_layers, c.block_size, 1, c.latent_dim,
+              planes=1), c.dtype) if c.latent_layers else None,
+          handed=("latent_pool",), table="kv",
+          # the pages the absorbed kernel walks
+          reads=lambda lens, c: dict(latent_pages_read=int(
+              (-(-np.asarray(lens, np.int64) // c.block_size)).sum())
+              * c.latent_layers),
+          holds=lambda c: dict(state_rows_held=c.state_rows_used(),
+                               latent_blocks_held=c.used_block_count()),
+          staged=lambda staging, bucket, kept: (
+              staging["latent"][:, :bucket, None],) * 2,
+          unexported="caches latent rows, and a block's wire format is a K "
+          "and a V a layer; nothing exports a latent page yet, so nothing "
+          "is moved",
+          unshared="latent pages cannot be forked: nothing shares a prefix "
+          "across latent layers yet (ROADMAP, Reach)"),
+)
+
+
+def staged_rows(kept: Kept, staging, bucket: int) -> tuple:
+    """A chunked prompt's (ks, vs) for :meth:`PagedKVCache.scatter_prefill`
+    out of its chunks' ``staging``, where its rows lie under one table: cut
+    by the pool that has layers."""
+    plane = next(p for p in PLANES if p.staged and getattr(kept, p.layers))
+    return plane.staged(staging, bucket, kept)
+
+
+def planes_of(held) -> tuple:
+    """The rows of :data:`PLANES` whose entries ``held`` has, in the table's
+    order (``held``: a holder's dict, or anything that holds their names)."""
+    return tuple(p for p in PLANES if p.name in held)
+
+
+def handed_to_forward(held, by_row) -> tuple:
+    """(the decode forward's keywords for what the holder holds beside its
+    K/V pool, the tables a step's new rows go through): ``by_row`` are the
+    step's host operands, in the table's order as ``decode`` built them."""
+    by_row, keywords, tables = list(by_row), {}, {}
+    for plane in planes_of(held):
+        operand = by_row.pop(0) if plane.by_row else None
+        keywords.update(zip(plane.handed, (held[plane.name], operand)))
+        if plane.beside:
+            tables[plane.name] = operand
+    assert not by_row, f"{len(by_row)} operands no plane of {set(held)} takes"
+    return keywords, tables
+
+
+def stepped_by_forward(held, results: list) -> dict:
+    """The holder's dict with what the decode forward stepped (the first of
+    ``results``, its results behind K/V: they are taken off it)."""
+    return {**held, **{plane.name: results.pop(0)
+                       for plane in planes_of(held) if plane.stepped}}
+
+
+def slots_reserved(held, tables, ctx_lens, n_real) -> tuple:
+    """(blocks by table, offsets) of a decode step's new rows: the slot
+    ``append_slot`` reserved for each, ``(table[ctx // bs], ctx % bs)``
+    through every table of ``tables`` (name -> (B, MAXB)).  Rows padded up
+    to the bucket are sent out of range: they write nowhere."""
+    import jax.numpy as jnp
+    bs = held["kv"].shape[3]
+    rows = jnp.arange(ctx_lens.shape[0])
+    blocks = {name: jnp.where(rows < n_real, table[rows, ctx_lens // bs],
+                              held[name].shape[2])
+              for name, table in tables.items()}
+    return blocks, ctx_lens % bs
+
+
+def rows_written(held, blocks, offsets, k, v) -> dict:
+    """New rows into every plane that takes them, for a decode step and for
+    ``write_token`` alike: k, v (L, R, KV, D) hold the pools' rows one pool
+    behind another in the table's order (the full layers', then the window
+    layers'; a latent pool's are ``k``), each goes to its pool at the blocks
+    its table names (``blocks``: table -> (R,)) and ``offsets``; then what
+    is kept beside the K/V pool is brought up to date from it, so whoever
+    writes K writes that too.  Returns the holder's dict."""
+    at = 0
+    for plane in planes_of(held):
+        layers = held[plane.name].shape[0] if plane.table else 0
+        if layers:
+            mine = [a if layers == a.shape[0] else a[at:at + layers]
+                    for a in (k, v)]
+            held = {**held, plane.name: write_rows(
+                held[plane.name], blocks[plane.table], offsets, *mine)}
+            at += layers
+    for plane in planes_of(held):
+        if plane.rewritten:
+            held = {**held, plane.name: plane.rewritten(
+                held[plane.name], held["kv"], blocks["kv"], offsets)}
+    return held
 
 
 @functools.cache
@@ -343,89 +577,60 @@ def _programs() -> SimpleNamespace:
     from jax import lax
 
     from ray_tpu.ops.paged_attention import heads_apart, lane_flat
-    from ray_tpu.ops.sparse_attention import halves_of
 
-    def _write_rows(held, blocks, offsets, k, v, *wblocks):
-        # the half-kernels the rows fall in too, where a selector's cache
-        # is kept
-        if wblocks:
-            return write_rows_by_kind(held, blocks, wblocks[0], offsets, k,
-                                      v), None
-        if "latent" in held:
-            return {**held, "latent": write_rows(held["latent"], blocks,
-                                                 offsets, k)}, None
-        sel = held.get("sel")
-        if sel is None:
-            return {**held, "kv": write_rows(held["kv"], blocks, offsets,
-                                             k, v)}, None
-        pool, sel = write_rows(held["kv"], blocks, offsets, k, v, sel)
-        return {**held, "kv": pool, "sel": sel}, None
+    def _write_rows(held, blocks, offsets, k, v, *beside):
+        # ``beside``: the blocks under each table beside the sequence's own
+        own = [p.name for p in planes_of(held) if p.beside]
+        return rows_written(held, {"kv": blocks, **dict(zip(own, beside))},
+                            offsets, k, v), None
 
-    def _scatter_prefill(held, table, ks, vs, n_tokens, *row):
+    def _scatter_prefill(held, table, ks, vs, n_tokens, *prompt):
         # token t of the padded prompt -> slot t % bs of block table[t // bs];
-        # padding (t >= n_tokens) is sent out of range and dropped
-        pool = held["kv"]
-        num_blocks, bs = pool.shape[2:4]
-        if "kvw" in held:
-            # pages of two kinds: ks / vs are (1, rows, KV, D), the full
-            # layers' K/V of the padded prompt one behind another and then
-            # each window layer's run of positions ``first ..`` (whole
-            # blocks, the window's columns); ``wtable``: those columns
-            wtable, first = row
-            wpool = held["kvw"]
-            run = wtable.shape[0] * bs
-            n_full, n_win = pool.shape[0], wpool.shape[0]
-            heads = ks.shape[2:]
-            split = ks.shape[1] - n_win * run
-            with jax.named_scope("kv_write"):
-                t = jnp.arange(split // n_full)
-                blocks = jnp.where(t < n_tokens, table[t // bs], num_blocks)
-                pool = write_rows(
-                    pool, blocks, t % bs,
-                    ks[0, :split].reshape(n_full, -1, *heads),
-                    vs[0, :split].reshape(n_full, -1, *heads))
-                t = jnp.arange(run)
-                blocks = jnp.where(first + t < n_tokens, wtable[t // bs],
-                                   wpool.shape[2])
-                wpool = write_rows(
-                    wpool, blocks, t % bs,
-                    ks[0, split:].reshape(n_win, run, *heads),
-                    vs[0, split:].reshape(n_win, run, *heads))
-            return {**held, "kv": pool, "kvw": wpool}, None
+        # padding (t >= n_tokens) is sent out of range and dropped.  ks / vs
+        # (L, T, KV, D); where a pool lies under a table of its own they are
+        # (1, rows, KV, D): each pool's layers one behind another, the pools
+        # in the table's order, the second's a run of positions ``first ..``
+        # (whole blocks: its table's columns).  ``prompt``: the planes'
+        # operands, in the table's order
+        planes, prompt = planes_of(held), list(prompt)
+        mine = {p.name: [prompt.pop(0) for _ in range(1 + bool(p.beside))]
+                for p in planes if p.prompt}
+        num_blocks, bs = held["kv"].shape[2:4]
+        pools = [p for p in planes if p.table and held[p.name].shape[0]]
+        runs = {p.name: mine[p.name][0].shape[0] * bs
+                for p in pools if p.beside}
+        shared = sum(held[p.name].shape[0] for p in pools
+                     if p.name not in runs)
+        positions = (ks.shape[1] - sum(
+            held[name].shape[0] * run for name, run in runs.items())) \
+            // shared if runs else ks.shape[1]
+        heads, at = ks.shape[2:], 0
         with jax.named_scope("kv_write"):
-            t = jnp.arange(ks.shape[1])
+            t = jnp.arange(positions)
             blocks = jnp.where(t < n_tokens, table[t // bs], num_blocks)
-            sel = held.get("sel")
-            if sel is not None:
-                # a prompt's half-kernels from its K whole, a slot each
-                # ``stride`` positions; the last may be part of one, which
-                # the decode steps' writes complete
-                per_page, f = sel.shape[2:]
-                stride = bs // per_page
-                halves = halves_of(lane_flat(ks, f), n_tokens, stride)
-                first = jnp.arange(0, ks.shape[1], stride)
-                slots = table[first // bs] * per_page + first % bs // stride
-                slots = jnp.arange(sel.shape[0])[:, None] \
-                    * (num_blocks * per_page) + slots
-                slots = jnp.where(first < n_tokens, slots, sel.size // f)
-                held = {**held, "sel": sel.reshape(-1, f).at[
-                    slots.reshape(-1)].set(
-                        halves.reshape(-1, f).astype(sel.dtype),
-                        mode="drop").reshape(sel.shape)}
-            if "latent" in held:
-                # a latent page: the prompt's rows are ``ks``, and the
-                # family's layers hold no K/V
-                held = {**held, "latent": write_rows(held["latent"], blocks,
-                                                     t % bs, ks)}
-            else:
-                held = {**held,
-                        "kv": write_rows(pool, blocks, t % bs, ks, vs)}
-        if row:
-            # recurrent state: the prompt's, which its prefill left in the
-            # staging row, goes to the sequence's row
-            held = {**held, "state": jax.tree.map(
-                lambda s: lax.dynamic_update_index_in_dim(
-                    s, s[:, -1], row[0], 1), held["state"])}
+            for plane in planes:
+                if plane.from_prompt:
+                    held = {**held, plane.name: plane.from_prompt(
+                        held[plane.name], ks, table, n_tokens, held["kv"])}
+            for plane in pools:
+                pool, rows = held[plane.name], (ks, vs)
+                if plane.name in runs:
+                    columns, first = mine[plane.name]
+                    t = jnp.arange(runs[plane.name])
+                    blocks = jnp.where(first + t < n_tokens,
+                                       columns[t // bs], pool.shape[2])
+                offsets = t % bs
+                if runs:
+                    n = pool.shape[0] * t.shape[0]
+                    rows = [a[0, at:at + n].reshape(pool.shape[0], -1, *heads)
+                            for a in rows]
+                    at += n
+                held = {**held, plane.name: write_rows(pool, blocks, offsets,
+                                                       *rows)}
+        for plane in planes:
+            if plane.committed:
+                held = {**held, plane.name: plane.committed(
+                    held[plane.name], *mine[plane.name])}
         return held, None
 
     # a block between its wire format (L, 2, bs, KV, D) and the pool's
@@ -455,12 +660,10 @@ def _programs() -> SimpleNamespace:
 
 
 class DevicePool:
-    """The block pool's device array, whoever holds it now, as ``{"kv":
-    that array}``; with ``state`` (a store's leaves as
-    ``ShapeDtypeStruct``s) the store beside it, ``"state"``, with ``sel``
-    the selector's cache, ``"sel"``, and with ``window`` the window
-    layers' pool, ``"kvw"``, and with ``latent`` the latent layers' pool
-    of one plane, ``"latent"``.
+    """What a sequence's planes hold on the device, whoever holds it now:
+    a dict, ``{"kv": the block pool's array}`` and beside it an entry for
+    every other plane the cache has (:data:`PLANES`), built from
+    ``described``: name -> ``ShapeDtypeStruct`` (or a tree of them).
 
     A donating program deletes the array it was given and returns a new
     one over the same memory, so nobody may keep the array itself:
@@ -471,19 +674,9 @@ class DevicePool:
     for the enqueue only, and the device runs the programs in the order
     they were enqueued."""
 
-    def __init__(self, shape, dtype, state=None, sel=None, window=None,
-                 latent=None):
-        self.shape, self.dtype = tuple(shape), dtype
-        self.state = state
-        # the latent layers' pool of one plane (a ShapeDtypeStruct), for a
-        # family that caches a latent row a position: ``"latent"``
-        self.latent = latent
-        # the window layers' pool (a ShapeDtypeStruct), for a family whose
-        # layers hold pages of two kinds: one more entry, ``"kvw"``
-        self.window = window
-        # the selector's cache (a ShapeDtypeStruct), for a family that
-        # chooses its pages: one more entry of what is held, ``"sel"``
-        self.sel = sel
+    def __init__(self, described: dict):
+        self.described = described
+        self.planes = planes_of(described)
         self._pool_lock = threading.Lock()
         self._array = None                             # guarded by: _pool_lock
         self.fill(0)
@@ -492,8 +685,7 @@ class DevicePool:
         """What the holder holds, as shapes (for whoever lowers a program
         that takes it, without the array)."""
         import jax
-        kv = jax.ShapeDtypeStruct(self.shape, self.dtype)
-        return self._held(kv, lambda s: s)
+        return jax.tree.map(lambda s: s, self.described)
 
     def donate(self, program, *args):
         """Run ``program(array, *args) -> (array, result)``, which donates
@@ -525,29 +717,27 @@ class DevicePool:
         with self._pool_lock:
             for old in jax.tree.leaves(self._array):
                 old.block_until_ready().delete()
-            self._array = self._held(
-                jnp.full(self.shape, value, self.dtype),
-                lambda s: jnp.full(s.shape, value, s.dtype))
-
-    def _held(self, kv, made):
-        """What the holder holds around ``kv``: beside it the store and
-        the selector's cache of a family that has them, each leaf ``made``
-        from its description."""
-        import jax
-        held = {"kv": kv}
-        if self.state is not None:
-            held["state"] = jax.tree.map(made, self.state)
-        if self.sel is not None:
-            held["sel"] = made(self.sel)
-        if self.window is not None:
-            held["kvw"] = made(self.window)
-        if self.latent is not None:
-            held["latent"] = made(self.latent)
-        return held
+            self._array = jax.tree.map(
+                lambda s: jnp.full(s.shape, value, s.dtype), self.described)
 
 
 class PagedKVCache:
     """Block pool + tables + refcounts for one engine instance."""
+
+    @classmethod
+    def for_engine(cls, cfg, kept: Kept, dtype=np.float32) -> "PagedKVCache":
+        """The cache of an engine of ``cfg`` (an ``EngineConfig``) whose
+        model keeps ``kept``: a row of state a sequence slot beside the
+        blocks, the pool laid out for the layers that hold K/V."""
+        if kept.select_block and kept.select_block != cfg.block_size:
+            raise ValueError(
+                f"{cfg.model} selects pages of {kept.select_block} "
+                f"positions: block_size {cfg.block_size} is not its page")
+        planes = {name: value for name, value in vars(kept).items()
+                  if name not in ("n_kv", "head_dim", "kv_layers",
+                                  "select_block", "staged", "packed")}
+        return cls(cfg.num_blocks, kept.kv_layers, cfg.block_size, kept.n_kv,
+                   kept.head_dim, dtype, max_seqs=cfg.max_num_seqs, **planes)
 
     def __init__(self, num_blocks: int, n_layer: int, block_size: int,
                  n_kv: int, head_dim: int, dtype=np.float32, *,
@@ -555,23 +745,17 @@ class PagedKVCache:
                  select_stride: int = 0, window_layers: int = 0,
                  window: int = 0, latent_layers: int = 0,
                  latent_dim: int = 0):
-        """``n_layer``: the layers that hold K/V.  ``state``: one
-        sequence's recurrent state in one layer, name ->
-        ``ShapeDtypeStruct`` (a model module's ``recurrent_state``), for a
-        family that has one; the store then has ``max_seqs`` rows and one
-        for staging, in each of ``state_layers`` layers (None: as many as
-        hold K/V).  ``select_stride``: the positions a half-kernel of the
-        selector's cache pools (a model module's ``page_selector``), for a
-        family whose attention chooses its pages; 0: none is kept.
-        ``window_layers`` and ``window``: the layers that hold the last
-        ``window`` positions only (a model module's ``cache_layers`` and
-        sliding window), beside the ``n_layer`` that hold every position:
-        a second pool of ``max_seqs`` x :func:`window_columns` blocks, which
-        the sequence slots cannot exhaust.  ``latent_layers`` and
-        ``latent_dim``: the layers that cache one latent row of
-        ``latent_dim`` features a position (a model module's
-        ``cache_layers`` and latent row), in a pool of one plane under the
-        same table; such a family's ``n_layer`` is 0."""
+        """``n_layer``: the layers that hold K/V.  The keywords are
+        :class:`Kept`'s fields (:meth:`for_engine` hands them all; a test
+        names a plane with its own).  ``state``: one sequence's recurrent
+        state in one layer, name -> ``ShapeDtypeStruct``, in ``max_seqs``
+        rows and one for staging, in each of ``state_layers`` layers (None:
+        as many as hold K/V).  ``select_stride``: the positions a
+        half-kernel pools.  ``window_layers``, ``window``: the layers that
+        hold the last ``window`` positions only, in a pool of ``max_seqs``
+        x :func:`window_columns` blocks.  ``latent_layers``,
+        ``latent_dim``: the layers that cache one row of ``latent_dim``
+        features a position; such a family's ``n_layer`` is 0."""
         if latent_layers and (n_layer or window_layers or select_stride):
             raise ValueError(
                 "latent pages beside K/V pages in one model are not "
@@ -582,68 +766,35 @@ class PagedKVCache:
         self.dtype = np.dtype(dtype)
         self.block_nbytes = int(np.prod(self.block_shape)) * \
             self.dtype.itemsize
+        self.max_seqs, self.state_spec = max_seqs, state
         # rows of recurrent state a sequence can be given, the staging
         # row (the store's last) and the store's bytes; 0 without state
         self.state_rows = max_seqs if state else 0
         self.staging_row = self.state_rows
         # names no row: a decode step reads somewhere and writes nowhere
         self.no_row = self.state_rows + 1
-        store = None
         self.kv_layers = n_layer
-        self.state_layers = 0
-        if state:
-            import jax
-            self.state_layers = n_layer if state_layers is None \
-                else state_layers
-            store = {name: jax.ShapeDtypeStruct(
-                (self.state_layers, max_seqs + 1) + tuple(s.shape), s.dtype)
-                for name, s in state.items()}
-        self.state_bytes = sum(
-            int(np.prod(s.shape)) * np.dtype(s.dtype).itemsize
-            for s in (store or {}).values())
-        shape = device_shape(num_blocks, n_layer, block_size, n_kv, head_dim)
+        self.state_layers = (n_layer if state_layers is None
+                             else state_layers) if state else 0
+        self.select_stride = select_stride
+        self.window_layers, self.window = window_layers, window
+        self.window_blocks = max_seqs * window_columns(window, block_size) \
+            if window_layers else 0
+        self.latent_layers, self.latent_dim = latent_layers, latent_dim
+        self.kv_shape = device_shape(num_blocks, n_layer, block_size, n_kv,
+                                     head_dim)
+        # every plane this cache has, as shapes, and the planes' bytes
+        described = {p.name: p.spec(self) for p in PLANES}
+        self.pool = DevicePool({name: spec for name, spec in described.items()
+                                if spec is not None})
+        self.planes = self.pool.planes
+        self.state_bytes, self.select_bytes, self.window_bytes, \
+            self.latent_bytes = (_nbytes(described[name]) for name in (
+                "state", "sel", "kvw", "latent"))
         # what the device format costs in memory beside the wire format's
         # bytes: the lanes that pad F (LLMEngine.stats()["kv_lane_pad_bytes"])
-        self.lane_pad_bytes = int(np.prod(shape)) * self.dtype.itemsize \
+        self.lane_pad_bytes = _nbytes(described["kv"]) \
             - num_blocks * self.block_nbytes
-        sel = None
-        if select_stride:
-            import jax
-            if block_size % select_stride:
-                raise ValueError(
-                    f"pages of {block_size} positions are no whole number "
-                    f"of the selector's {select_stride}")
-            sel = jax.ShapeDtypeStruct(selector_shape(shape, select_stride),
-                                       self.dtype)
-        self.select_bytes = int(np.prod(sel.shape)) * self.dtype.itemsize \
-            if sel is not None else 0
-        self.window_layers, self.window = window_layers, window
-        self.window_blocks = 0
-        wpool = None
-        if window_layers:
-            import jax
-            if not max_seqs or window < 1:
-                raise ValueError(
-                    "window layers need max_seqs (their pool is sized by "
-                    "the sequence slots) and a window of positions")
-            self.window_blocks = max_seqs * window_columns(window,
-                                                           block_size)
-            wpool = jax.ShapeDtypeStruct(
-                device_shape(self.window_blocks, window_layers, block_size,
-                             n_kv, head_dim), self.dtype)
-        self.window_bytes = math.prod(wpool.shape) * self.dtype.itemsize \
-            if wpool is not None else 0
-        self.latent_layers, self.latent_dim = latent_layers, latent_dim
-        lpool = None
-        if latent_layers:
-            import jax
-            lpool = jax.ShapeDtypeStruct(
-                device_shape(num_blocks, latent_layers, block_size, 1,
-                             latent_dim, planes=1), self.dtype)
-        self.latent_bytes = math.prod(lpool.shape) * self.dtype.itemsize \
-            if lpool is not None else 0
-        self.pool = DevicePool(shape, self.dtype, state=store, sel=sel,
-                               window=wpool, latent=lpool)
         # one step region for the writers below (DESIGN.md §4q): a
         # scatter program per prefill bucket, write_token, load_block
         self._write_budget = compile_budget("llm.kv_write")
@@ -666,6 +817,7 @@ class PagedKVCache:
         # its first column that still names a block
         self._wfirst: Dict[str, int] = {}                            # guarded by: _lock
         self.window_released = 0                                     # guarded by: _lock
+        self._released_told = 0       # of those, told to held_counts()
         # bytes of pool data that crossed between host and device, either
         # way: K/V given as numpy, blocks exported or imported
         self.host_bytes = 0                                          # guarded by: _lock
@@ -681,10 +833,6 @@ class PagedKVCache:
     def used_block_count(self) -> int:
         with self._lock:
             return self.num_blocks - len(self._free)
-
-    def can_alloc(self, n_blocks: int) -> bool:
-        with self._lock:
-            return len(self._free) >= n_blocks
 
     def alloc_seq(self, seq_id: str, n_tokens: int) -> List[int]:
         """Allocate blocks for ``n_tokens`` of context; table starts full
@@ -806,20 +954,10 @@ class PagedKVCache:
     def fork_seq(self, seq_id: str, new_seq_id: str) -> None:
         """Share a sequence's blocks with a new id (refcount bump) —
         the prefix-sharing/export primitive."""
-        if self.state_rows:
-            raise NotImplementedError(
-                "a sequence with recurrent state cannot be forked: its row "
-                "is its own, and a shared first block would name two rows")
-        if self.window_layers:
-            raise NotImplementedError(
-                "a sequence with window layers cannot be forked: a shared "
-                "window block would be given back by whichever holder's "
-                "context passes it first (prefix sharing across window "
-                "layers: ROADMAP R4)")
-        if self.latent_layers:
-            raise NotImplementedError(
-                "a sequence with latent pages cannot be forked: nothing "
-                "shares a prefix across latent layers yet (ROADMAP, Reach)")
+        for plane in self.planes:
+            if plane.unshared:
+                raise NotImplementedError(
+                    f"a sequence with {plane.unshared}")
         with self._lock:
             blocks = list(self._tables[seq_id])
             for b in blocks:
@@ -871,6 +1009,22 @@ class PagedKVCache:
             return (self.window_blocks - len(self._wfree),
                     self.window_released,
                     sum(len(t) for t in self._wtables.values()))
+
+    def _window_holds(self) -> dict:
+        held, released, unwindowed = self.window_counts()
+        told, self._released_told = self._released_told, released
+        return dict(window_blocks_held=held,
+                    window_blocks_held_unwindowed=unwindowed,
+                    window_blocks_released=released - told)
+
+    def held_counts(self) -> dict:
+        """The planes' own counts at a step, for ``metrics_catalog.
+        tell_step``: window blocks held and what one table for all layers
+        would hold (counters: each step adds what the live sequences hold
+        now, so their ratio is the mean over steps), given back since last
+        asked; rows and latent blocks held."""
+        return {name: n for plane in self.planes if plane.holds
+                for name, n in plane.holds(self).items()}
 
     def window_pool_blocks(self) -> np.ndarray:
         """Every block of the window layers' pool in the wire format of
@@ -960,14 +1114,24 @@ class PagedKVCache:
         ``n_tokens`` positions are real, and only they are written).
         With recurrent state, the same program commits the state the
         prompt's prefill staged to the sequence's row."""
-        row = ()
-        if self.state_rows:
-            with self._lock:
-                row = (self._rows[seq_id],)
-                self.state_commits += 1
-        if self.window_layers:
-            row = self._window_run(self.window_table(seq_id), n_tokens)
-        self._scatter(self.table(seq_id), ks, vs, n_tokens, *row)
+        self._write(_programs().scatter_prefill, *self._scatter_args(
+            self.table(seq_id), ks, vs, n_tokens,
+            *self._prompt_operands(seq_id, n_tokens)), host=(ks, vs))
+
+    def _prompt_operands(self, seq_id, n_tokens: int) -> list:
+        """The scatter program's operands behind the prompt's length, in
+        the planes' order (``seq_id`` None: a warm-up's)."""
+        return [a for plane in self.planes if plane.prompt
+                for a in plane.prompt(self, seq_id, n_tokens)]
+
+    def _committing(self, seq_id) -> int:
+        """The row a scatter commits the staged state to (a warm-up: the
+        staging row, onto itself)."""
+        if seq_id is None:
+            return self.staging_row
+        with self._lock:
+            self.state_commits += 1
+            return self._rows[seq_id]
 
     def window_run(self, n_tokens: int) -> tuple:
         """(first position, positions) of the run of a prompt of
@@ -997,10 +1161,8 @@ class PagedKVCache:
         writing a token (the runner calls it with a bucket's first
         prefill, so the bucket's two programs are built together)."""
         from ray_tpu.util import tracing
-        row = (self.staging_row,) if self.state_rows else ()
-        if self.window_layers:
-            row = self._window_run([], 0)
-        args = self._scatter_args([], ks, vs, 0, *row)
+        args = self._scatter_args([], ks, vs, 0,
+                                  *self._prompt_operands(None, 0))
         tracing.register_program(
             f"llm.prefill.scatter.{ks.shape[1]}", _programs().scatter_prefill,
             (self.pool.abstract(), *tracing.abstract(args)))
@@ -1021,29 +1183,26 @@ class PagedKVCache:
         return (padded, ks, vs, np.int32(n_tokens),
                 *(np.int32(r) if np.ndim(r) == 0 else r for r in row))
 
-    def _scatter(self, table: List[int], ks, vs, n_tokens: int,
-                 *row: int) -> None:
-        self._write(_programs().scatter_prefill,
-                    *self._scatter_args(table, ks, vs, n_tokens, *row),
-                    host=(ks, vs))
-
     def write_token(self, block_id: int, offset: int, k, v) -> None:
         """Write one token's (L, KV, D) K/V into its slot.  The decode
         step writes its own (``ModelRunner.decode``); this is for a
         caller that holds K/V from elsewhere, and rewriting what a step
         wrote changes nothing."""
-        wblock = ()
-        if self.window_layers:
-            # the window block of the same column of whoever holds
-            # ``block_id``; k / v hold the full layers' rows first
-            with self._lock:
-                wblock = (np.int32([next(
-                    (self._wtables[sid][t.index(block_id)]
-                     for sid, t in self._tables.items() if block_id in t),
-                    self.window_blocks)]),)
+        beside = [plane.beside(self, block_id) for plane in self.planes
+                  if plane.beside]
         self._write(_programs().write_rows, np.int32([block_id]),
-                    np.int32([offset]), k[:, None], v[:, None], *wblock,
+                    np.int32([offset]), k[:, None], v[:, None], *beside,
                     host=(k, v))
+
+    def _window_block_beside(self, block_id: int) -> np.ndarray:
+        """The window block of the same column of whoever holds
+        ``block_id`` (a written token's k / v hold the full layers' rows
+        first)."""
+        with self._lock:
+            return np.int32([next(
+                (self._wtables[sid][t.index(block_id)]
+                 for sid, t in self._tables.items() if block_id in t),
+                self.window_blocks)])
 
     # -------------------------------------------------------------- teardown
     def close(self) -> None:

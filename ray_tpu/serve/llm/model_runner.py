@@ -10,165 +10,85 @@ len(decode_buckets)`` for the engine's life (SURVEY.md §7.3: replica
 cold starts are XLA compiles; bounding them is the TPU-serving
 equivalent of connection pooling).
 
-The runner is model-family-agnostic: ``models/gpt2.py``,
-``models/llama.py``, ``models/falcon_h1.py``, ``models/lfm2.py`` and
-``models/minicpm_sala.py`` each
-export ``forward_prefill`` / ``forward_decode`` (the decode step reads the paged
-pool through ``ops/paged_attention.py`` and returns the new token's K/V);
-the runner's decode program then writes that K/V into the pool, which it
-was given donated, so the pool stays on the device
-(``kv_cache.DevicePool``) and every family gets the write-back from one
-place.  Prefill leaves a prompt's K/V on the device for
-``PagedKVCache.scatter_prefill``.
+The contract with a model's module, said once.  A served family is
+``ray_tpu.models.<family>`` for a name in ``config.SERVED_FAMILIES``.  It
+exports ``forward_prefill`` / ``forward_decode`` (the decode step reads the
+paged pool through ``ops/paged_attention.py`` and returns the new token's
+rows), ``init_params``, ``PRESETS``, ``WIDE_PARAMS`` (the leaves that stay
+as stored; every other is held in the model's ``dtype``, converted once,
+``models/_common.serving_params``) and, each only where it applies, what
+``family_of`` asks once and keeps as ``runner.family``:
+
+* ``recurrent_state(cfg)``, ``cache_layers(cfg)``, ``page_selector(cfg)``:
+  what a sequence keeps beside or instead of K/V.  ``kv_cache.kept_by``
+  reads them; the cache is built from the answer and holds a *plane* for
+  each kind (``kv_cache.PLANES``: ``kv``, ``state``, ``sel``, ``kvw``,
+  ``latent``; DESIGN.md section 4g has the table of kinds and families).
+  The runner knows no kind by name: a plane's row says which keywords the
+  decode forward is handed for it, which per-row operand the cache makes
+  for a step, and how new rows are written.
+* ``routed_layers(cfg)`` -> ``{"layers", "k"}`` (``route_spec``): the
+  forwards are asked for the chosen expert ids (``choices=True``) and the
+  step programs return them as one more result, int32 ``(routed layers,
+  rows, k)``; the last step's stay on the device as ``runner.choices``.  A
+  decode program tells the forward which rows are live (``live=``).
+* ``forward_prefill_chunk`` and ``prefill_staging`` (``chunk``,
+  ``staging_spec``): ONE prefill program of ``cfg.prefill_chunk``
+  positions whatever the prompt's length, over a staging the runner keeps
+  and the program takes donated; ``prefill(prompt)`` is those chunks one
+  behind another and ``prefill_result`` after the last.  The prefill
+  buckets are then lengths in whole chunks and cost a scatter program
+  each.  The engine's loop calls ``prefill_chunk`` itself, one an
+  iteration with a decode step of the live rows behind it.
+* ``ROW_TABLES``: a tied head's table held a second time for the
+  embedding's gather where its rows are no whole lanes.
+
+The step programs.  One decode body and one prefill body (and one chunk
+body), each reading what the holder's dict has.  The decode program takes
+the holder donated and hands it back with the new rows written
+(``kv_cache.rows_written``: the one writer, the cache's own); a family that
+stages its state through the holder runs its prefill through it too, any
+other hands the same body no holder (``_NoHolder``).  Prefill leaves a
+prompt's rows on the device for ``PagedKVCache.scatter_prefill``.  Behind
+the eight operands every decode program takes ride the planes' operands
+``by_row``, in the table's order at both ends (``decode`` builds them in
+one loop, ``kv_cache.handed_to_forward`` takes them apart): the store's
+rows, then the window tables.
 
 What comes to the host.  Each step program returns, beside its float32
-logits, every row's greedy token (``argmax`` over the vocabulary, the
-first index of the maximum as ``np.argmax`` takes it): still one program
-a bucket, the logits a result of it that stays on the device.  A caller
-that names the rows whose logits it needs (``logit_rows=``: the engine's
-loop names those whose ``SamplingParams`` is not greedy, usually none)
-gets ``Chosen``: the ``(B,)`` ids and those rows.  Seeded temperature /
-top-k sampling stays host-side on a pulled ``(V,)`` row (``sample``).  A
-caller that names nothing gets all the logits on the host, as always.
+logits, every row's greedy token (``argmax``, the first index of the
+maximum as ``np.argmax`` takes it).  A caller that names the rows whose
+logits it needs (``logit_rows=``: the engine names those whose request
+samples, usually none) gets ``Chosen``: the ``(B,)`` ids, those rows, and
+``reads``, what the step says of itself by name.  Counts the device made
+ride behind the ids in the one pull, in ONE layout (``riders_of``:
+``experts_touched``, then ``sparse_pages_read``, ``sparse_pages_held``)
+that the program's pack and ``_pull``'s read both take from
+``family.layout``; counts the host reckons from the context lengths
+(``Plane.reads``: ``window_positions``, ``window_blocks``,
+``window_blocks_unwindowed``, ``latent_pages_read``) travel as
+``Enqueued.reads``.  ``llm.decode.pull`` is told both, with ``step`` and
+``bytes``.  Seeded temperature / top-k sampling stays host-side on a
+pulled ``(V,)`` row (``sample``).  A caller that names nothing gets all
+the logits on the host.
 
 One step behind another.  A greedy row's next token is the id the step
-before chose, and that id is on the device: the decode programs take the
-last step's ids (``last_ids``, at the widest bucket's width, which every
-bucket's program also returns its own at) and a row map ``src``: row i's
-token is ``last_ids[src[i]]``, or ``tokens[i]`` from the host where
-``src[i]`` is -1.  ``decode(..., after=step, rows=src, wait=False)``
-enqueues such a step and returns at the enqueue with an ``Enqueued``;
-``pull_step`` waits for one and brings its ids.  So the engine's loop keeps a
-step in flight and reads step n after it has enqueued step n+1.  A call
+before chose, on the device: the decode programs take the last step's ids
+(``last_ids``, at the widest bucket's width) and a row map ``src``: row
+i's token is ``last_ids[src[i]]``, or ``tokens[i]`` where ``src[i]`` is
+-1.  ``decode(..., after=step, rows=src, wait=False)`` enqueues such a
+step and returns an ``Enqueued``; ``pull_step`` waits for one.  A call
 without ``after`` sends zeros and a map of -1s of the same type and
-placement (``_no_ids``): one executable a bucket serves both, and the
-harness's warm call has built it.
-
-Recurrent state.  A family whose sequences hold more than K/V says so by
-exporting ``recurrent_state(cfg)``: one sequence's state in one layer,
-name -> shape and type.  That description is all the runner and the cache
-know of it (``runner.state_spec``; the engine builds its cache with it):
-what the cache's holder holds is then ``{"kv": pool, "state": store}``
-(``kv_cache.py``; ``{"kv": pool}`` for every other family).  There is one
-decode body and one prefill body, and each reads what the holder's dict
-has.  The prefill program of such a family takes the holder donated and
-writes the state at the prompt's last real position into the store's
-staging row, for ``scatter_prefill`` to commit to the sequence's row; a
-family without a store hands the same body no holder (``None``, no
-operand: a pool of 1.3 GB does not go through a donating program to
-change nothing).  The decode program hands the model the store and, for
-each batch row, the store row the cache names for its block table (rows
-padded up to the bucket name none, and write nowhere), an operand that
-exists only where there is a store.  ``prefill`` and ``decode`` keep
-their signatures and results: the state travels behind them.
-
-Layers that differ in kind.  A family in which some layers hold K/V and
-others recurrent state, and none both (``models/lfm2.py``), says how many
-of each with ``cache_layers(cfg)`` -> ``{"kv": n, "state": m}``, beside
-its ``recurrent_state``: the runner reads it into ``kv_layers`` /
-``state_layers`` and the engine builds the pool for the one count and the
-store for the other.  Without that export both are ``n_layer`` (the store's
-only where there is state), which is what every other family has.
-
-A selector's cache.  A module whose attention chooses the pages it reads
-(``models/minicpm_sala.py``; ``ops/sparse_attention.py``) exports
-``page_selector(cfg)`` -> ``{"stride", "block"}``: the positions one slot
-of the selector's cache pools, and the page the selection wants (the
-engine refuses another ``block_size``).  The runner offers it as
-``select_spec``; the holder then has a third entry, ``"sel"``
-(``kv_cache.py``), the decode program hands it to the forward read-only
-(``selector=``) and writes the new K's slot with the K, in
-``write_rows``; the forward returns the pages its sparse layers read and
-the pages its rows' contexts held, two numbers that ride behind the ids
-(``Chosen.pages``), as the count of touched experts does.
-
-A prompt in chunks.  A module that exports ``forward_prefill_chunk`` (and
-``prefill_staging``) has ONE prefill program, of ``cfg.prefill_chunk``
-positions, whatever the prompt's length: ``prefill_chunk(token_ids,
-index)`` runs chunk ``index`` over a staging K/V of the largest bucket's
-positions, which the runner keeps and the program takes donated and
-returns, and over the store's staging row, which carries the recurrent
-state from chunk to chunk; positions past the prompt's end move neither.
-``prefill(prompt)`` is those chunks one behind another and
-``prefill_result`` after the last: same signature, same results (logits,
-K/V of the bucket's length out of the staging, the state in the staging
-row), so ``scatter_prefill`` commits a chunked prompt as any other.  The
-prefill buckets of such a family are lengths in whole chunks and cost a
-scatter program each, not a model program.  The engine's loop calls
-``prefill_chunk`` itself, one an iteration with a decode step of the live
-rows behind it (``engine.py``), and ``after=`` keeps one chunk in flight.
-One prompt is in prefill at a time: a chunk 0 starts the next.  A module
-without the export has the one-program prefill it had.  A chunked family
-that carries no recurrent state (``models/afmoe.py``) hands the same chunk
-body no holder, as its one-program prefill would: what its chunks carry is
-the staging alone.
-
-Pages of two kinds.  A module whose ``cache_layers(cfg)`` counts a third
-kind, ``"window"`` (``models/afmoe.py``: layers that hold only the last
-``cfg.sliding_window`` positions), gets a holder with a second pool,
-``"kvw"`` (``kv_cache.py``).  The decode program hands the forward both
-pools and, beside the block tables, the window tables of the same rows
-(``window_tables=``: an operand that exists only then; the cache names
-them by who owns a table's first block, as it names rows of state), and
-writes the new K/V of the full layers into the one pool and of the window
-layers into the other, each through its own table at the same column and
-offset.  The forwards return K/V with the full layers first.  After a
-prompt's last chunk ``prefill_result`` packs what the cache scatters as
-ONE array a K and a V, ``(1, rows, KV, D)``: the full layers' K/V of the
-bucket's length and behind them each window layer's run of the prompt's
-last positions out of the ring (``PagedKVCache.window_run``), so that
-``prefill`` keeps its three results and a caller that copies them to the
-host (the serving check) copies the window's run and not the prompt.
-``llm.decode.pull`` is told the positions and blocks the step's window
-layers read and what full layers would have read in their place.
-
-A latent page.  A module whose ``cache_layers(cfg)`` counts a kind
-``"latent"`` (``models/ling.py``: layers of multi-head latent attention,
-which cache one row ``[c | k_rope]`` of ``cfg.latent_row`` features a
-position) gets a holder with a pool of one plane, ``"latent"``, under the
-same block table (``kv_cache.py``, a latent page), beside ``"kv"`` (which
-then has no layer) and the store.  The decode program hands the forward
-that pool (``latent_pool=``) and writes the rows the forward returns as
-its ``k`` (``(latent layers, B, 1, R)``) at the slot a K/V would go to;
-the chunk program stages a prompt's rows as another family's stages K/V
-(``staging["latent"]``), and ``prefill_result`` hands them on as ``ks``
-(and again as ``vs``, which nobody reads).  ``llm.decode.pull`` is told
-``latent_pages_read``: the pages the kernel walked, summed over the live
-rows and the latent layers.
-
-The hand-over of the choice of experts.  A module that routes exports
-``routed_layers(cfg)`` -> ``{"layers": n, "k": k}`` (None for a preset
-that does not); the runner offers it as ``route_spec``, asks the module's
-forwards for the ids (``choices=True``), and its step programs return them
-as one more result, int32 ``(routed layers, rows of the bucket, k)``: the
-last step's stay on the device as ``runner.choices`` until somebody reads
-them (the serving check of ``perfbench/jobs/serve.py``).  A decode program
-of such a module tells the forward which rows of the bucket are live
-(``live=``): a padded row takes a live row's choice and reads no expert of
-its own (``ops/moe.choice_of_live_rows``).  It also counts the distinct
-experts the step chose, summed over the routed layers, and sends that one
-number behind the ids it returns (``Chosen.touched``: 4 more bytes of the
-pull there is).
-``prefill`` and ``decode`` keep their signatures and results.
-
-Where the serving type of the weights is decided: here, once.  Whatever
-tree the runner ends up with (the caller's, ``init_params``' own in the
-model's ``param_dtype``, the shm plane's) goes through
-``models/_common.serving_params`` in ``__init__``: every leaf the
-family's forward casts to the model's ``dtype`` is stored in that type,
-the leaves the module names in ``WIDE_PARAMS`` stay as stored, and a
-table a module with a tied head names in ``ROW_TABLES`` is held a second
-time for the embedding's gather where its rows are no whole lanes.  The
-step programs take that tree (``runner.params``), so a weight is
-converted, and a table laid out as its gather reads it, once in an
-engine's life and not in every program run.
+placement (``_no_ids``): one executable a bucket serves both.
 """
 
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 from functools import partial
-from typing import Dict, NamedTuple, Optional, Sequence, Tuple, Union
+from typing import Callable, Dict, NamedTuple, Optional, Sequence, Tuple, \
+    Union
 
 import numpy as np
 
@@ -176,8 +96,9 @@ from ray_tpu._private import rtlog
 from ray_tpu._private.xla_watchdog import compile_budget
 from ray_tpu.serve.llm.config import EngineConfig, SamplingParams, \
     resolve_model
-from ray_tpu.serve.llm.kv_cache import DevicePool, PagedKVCache, \
-    write_rows, write_rows_by_kind
+from ray_tpu.serve.llm.kv_cache import DevicePool, Kept, PagedKVCache, \
+    _declared, handed_to_forward, kept_by, rows_written, slots_reserved, \
+    staged_rows, stepped_by_forward, window_reads
 from ray_tpu.util.tracing import abstract, hot_span, register_program
 
 logger = rtlog.get("serve.llm.runner")
@@ -206,13 +127,25 @@ class Chosen(NamedTuple):
 
     ids: np.ndarray                  # (B,) int32: each row's greedy token
     logits: Dict[int, np.ndarray]    # row -> (V,) float32, the rows named
-    # a decode step of a module that routes: the distinct experts its
-    # live rows chose, summed over the routed layers
-    touched: Optional[int] = None
-    # a decode step of a module that chooses its pages: (pages its sparse
-    # layers read, pages its rows' contexts hold), each summed over live
-    # rows, sparse layers and KV heads
-    pages: Optional[Tuple[int, int]] = None
+    # what a decode step says of itself, name -> int: what rode behind its
+    # ids (``riders_of``) and what the host reckoned (``Enqueued.reads``)
+    reads: dict = {}
+
+    @property
+    def touched(self) -> Optional[int]:
+        """A step of a module that routes: the distinct experts its live
+        rows chose, summed over the routed layers."""
+        return self.reads.get("experts_touched")
+
+    @property
+    def pages(self) -> Optional[Tuple[int, int]]:
+        """A step of a module that chooses its pages: (pages its sparse
+        layers read, pages its rows' contexts hold), each summed over live
+        rows, sparse layers and KV heads."""
+        if "sparse_pages_read" not in self.reads:
+            return None
+        return (self.reads["sparse_pages_read"],
+                self.reads["sparse_pages_held"])
 
     def token(self, row: int, sp: SamplingParams, step: int) -> int:
         """The row's next token: the device's choice for a greedy
@@ -235,10 +168,113 @@ class Enqueued(NamedTuple):
     carry: "jax.Array"               # the ids at the widest bucket's width
     n: int                           # the rows that are real
     logit_rows: Optional[Sequence[int]]
-    # what the step's window layers read (``ModelRunner._window_reads``)
-    # or its latent layers (``latent_pages_read``), told to the pull's
-    # span; empty without either
+    # what the planes say the step reads, reckoned on the host from its
+    # context lengths (``kv_cache.Plane.reads``), told to the pull's span
     reads: dict = {}
+
+
+class Rider(NamedTuple):
+    """Counts of a decode step that ride behind its ids, in the one pull."""
+
+    names: tuple                     # what ``llm.decode.pull`` is told
+    take: Callable                   # (the forward's results, a list) -> x
+    count: Callable                  # x -> (len(names),) int32, in the step
+
+
+def _experts_touched(ids, held=None):
+    """The distinct experts a decode step chose, summed over the routed
+    layers: ids (layers, rows, k), a padded row holding a live row's
+    choice.  Where the module holds a share of the experts (``held``:
+    first, count) the distinct ones AMONG THE HELD: what the step reads."""
+    import jax
+    import jax.numpy as jnp
+    with jax.named_scope("moe_router"):
+        flat = ids.reshape(ids.shape[0], -1)
+        if held:
+            first, count = held
+            flat = jnp.where((flat >= first) & (flat < first + count),
+                             flat, -1)
+        flat = jnp.sort(flat, axis=1)
+        distinct = 1 + (flat[:, 1:] != flat[:, :-1]).sum(1)
+        if held:
+            # the absent ones were all made one more, the lowest
+            distinct = distinct - (flat[:, 0] < 0)
+        return distinct.sum().astype(jnp.int32)
+
+
+def riders_of(route_spec, select_spec) -> Tuple[Rider, ...]:
+    """The one layout of what rides behind a decode step's ids: a routing
+    module's count of experts touched (of its choices, the first of the
+    forward's results, which stay a result), then a selecting module's two
+    counts of pages (the forward's last result, taken off them)."""
+    riders = ()
+    if route_spec:
+        held = route_spec.get("held")
+        riders += (Rider(("experts_touched",), lambda results: results[0],
+                         lambda ids: _experts_touched(ids, held)[None]),)
+    if select_spec:
+        riders += (Rider(("sparse_pages_read", "sparse_pages_held"),
+                         list.pop, lambda pages: pages),)
+    return riders
+
+
+def _ridden(ids, counts):
+    """``ids`` with each rider's ``counts`` behind them, in order."""
+    import jax.numpy as jnp
+    for count in counts:
+        ids = jnp.concatenate([ids, count])
+    return ids
+
+
+def _riders_read(ids: np.ndarray, width: int, names: tuple) -> dict:
+    """What rode behind the ``width`` ids of a pulled step, by name."""
+    if ids.shape != (width + len(names),):
+        raise ValueError(
+            f"a step of {width} rows with {names} behind its ids pulled "
+            f"{ids.shape}: the program packed another layout")
+    return dict(zip(names, map(int, ids[width:])))
+
+
+@dataclasses.dataclass(frozen=True)
+class Family:
+    """What the runner asks a model's module, once (:func:`family_of`)."""
+
+    kept: Kept                       # what a sequence keeps (kv_cache.py)
+    # the choice of experts a module that routes hands over ({"layers",
+    # "k"}; None: it does not route)
+    route_spec: Optional[dict]
+    # what the cache keeps a page for a module whose attention chooses its
+    # pages ({"stride", "block"}; None: every page is read)
+    select_spec: Optional[dict]
+    # the positions of one prefill program for a module that runs a prompt
+    # in chunks (0: a prompt is one program of its bucket), and the staging
+    # its chunks carry, as shapes
+    chunk: int
+    staging_spec: Optional[dict]
+    wide_params: tuple               # the leaves that stay as stored
+    row_tables: Optional[tuple]      # tables held again for their gather
+    riders: Tuple[Rider, ...]
+    layout: tuple                    # their names, in the order they ride
+
+
+def family_of(mod, mcfg, cfg: EngineConfig) -> Family:
+    """Every question the serving stack asks a module, asked here."""
+    route = _declared(mod, mcfg, "routed_layers")
+    select = _declared(mod, mcfg, "page_selector")
+    chunk = mcfg.prefill_chunk if hasattr(mod, "forward_prefill_chunk") else 0
+    if chunk and any(b % chunk for b in cfg.prefill_len_buckets):
+        raise ValueError(
+            f"{cfg.model} prefills in chunks of {chunk} positions: "
+            f"its prefill buckets {cfg.prefill_len_buckets} are lengths "
+            "in whole chunks")
+    # the staging holds the largest bucket's positions, in whole chunks
+    staging = mod.prefill_staging(
+        mcfg, -(-cfg.prefill_len_buckets[-1] // chunk) * chunk) \
+        if chunk else None
+    riders = riders_of(route, select)
+    return Family(kept_by(mod, mcfg), route, select, chunk, staging,
+                  mod.WIDE_PARAMS, getattr(mod, "ROW_TABLES", None), riders,
+                  tuple(name for rider in riders for name in rider.names))
 
 
 class ModelRunner:
@@ -252,6 +288,10 @@ class ModelRunner:
 
         self.cfg = cfg
         self.mod, self.mcfg = resolve_model(cfg)
+        # what the module declares, asked once; the attributes below are
+        # plain reads of it
+        self.family = family = family_of(self.mod, self.mcfg, cfg)
+        kept = family.kept
         self.weights_key: str = ""      # set when the shm plane is used
         # hot-span totals, name -> [count, seconds]; the engine shares
         # this dict with its own spans (LLMEngine.stats()["span_s"])
@@ -264,53 +304,26 @@ class ModelRunner:
         with hot_span("llm.weights.prepare", self.span_s,
                       bytes_in=tree_bytes(params)) as span:
             self.params = jax.block_until_ready(serving_params(
-                params, self.mcfg.dtype, self.mod.WIDE_PARAMS,
-                getattr(self.mod, "ROW_TABLES", None)))
+                params, self.mcfg.dtype, family.wide_params,
+                family.row_tables))
             # LLMEngine.stats()["param_bytes"]: what a step program reads
             self.param_bytes = tree_bytes(self.params)
             span.set(bytes_out=self.param_bytes)
         self.n_layer = self.mcfg.n_layer
-        self.n_kv = getattr(self.mcfg, "n_kv_head", self.mcfg.n_head)
-        self.head_dim = self.mcfg.head_dim
+        self.n_kv, self.head_dim = kept.n_kv, kept.head_dim
         self.vocab = self.mcfg.vocab_size
-        # one sequence's recurrent state in one layer, for a family that
-        # has one (None: K/V is all a sequence holds)
-        describe = getattr(self.mod, "recurrent_state", None)
-        self.state_spec = describe(self.mcfg) if describe else None
-        # the layers that hold K/V and those that hold state: a module
-        # whose layers differ in kind counts them (cache_layers)
-        describe = getattr(self.mod, "cache_layers", None)
-        layers = describe(self.mcfg) if describe else {
-            "kv": self.n_layer,
-            "state": self.n_layer if self.state_spec else 0}
-        self.kv_layers, self.state_layers = layers["kv"], layers["state"]
-        # layers that hold the last ``window`` positions only, in a pool
-        # of their own (0: every K/V layer holds the whole context)
-        self.window_layers = layers.get("window", 0)
-        self.window = self.mcfg.sliding_window if self.window_layers else 0
-        # layers that cache one latent row a position, in a pool of one
-        # plane under the same table (0: none), and the row's features
-        self.latent_layers = layers.get("latent", 0)
-        self.latent_dim = self.mcfg.latent_row if self.latent_layers else 0
-        # the choice of experts a module that routes hands over ({"layers",
-        # "k"}; None: it does not route), and the last step's, on the device
-        describe = getattr(self.mod, "routed_layers", None)
-        self.route_spec = describe(self.mcfg) if describe else None
+        # one sequence's recurrent state in one layer (None: none), and
+        # the layers of each kind of plane (kv_cache.Kept)
+        self.state_spec = kept.state
+        self.kv_layers, self.state_layers = kept.kv_layers, kept.state_layers
+        self.window_layers, self.window = kept.window_layers, kept.window
+        self.latent_layers, self.latent_dim = \
+            kept.latent_layers, kept.latent_dim
+        self.route_spec, self.select_spec = \
+            family.route_spec, family.select_spec
+        self.chunk = family.chunk
+        # a routing module's last step's choices, on the device
         self.choices = None
-        # what the cache keeps a page for a module whose attention chooses
-        # its pages ({"stride", "block"}; None: every page is read), and
-        # the positions of one prefill program for a module that runs a
-        # prompt in chunks (0: a prompt is one program of its bucket)
-        describe = getattr(self.mod, "page_selector", None)
-        self.select_spec = describe(self.mcfg) if describe else None
-        self.chunk = self.mcfg.prefill_chunk \
-            if hasattr(self.mod, "forward_prefill_chunk") else 0
-        if self.chunk and any(b % self.chunk
-                              for b in cfg.prefill_len_buckets):
-            raise ValueError(
-                f"{cfg.model} prefills in chunks of {self.chunk} positions: "
-                f"its prefill buckets {cfg.prefill_len_buckets} are lengths "
-                "in whole chunks")
         asked = {"choices": True} if self.route_spec else {}
         forward_prefill = partial(self.mod.forward_prefill, cfg=self.mcfg,
                                   **asked)
@@ -323,30 +336,10 @@ class ModelRunner:
             with jax.named_scope("lm_head"):
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-        def touched(ids):
-            # the distinct experts a decode step chose, summed over the
-            # routed layers: ids (layers, rows, k), a padded row holding a
-            # live row's choice (live_rows).  Where the module holds a
-            # share of the experts (``route_spec["held"]``: first, count)
-            # the distinct ones AMONG THE HELD: what the step reads
-            held = self.route_spec.get("held")
-            with jax.named_scope("moe_router"):
-                flat = ids.reshape(ids.shape[0], -1)
-                if held:
-                    first, count = held
-                    flat = jnp.where((flat >= first) & (flat < first + count),
-                                     flat, -1)
-                flat = jnp.sort(flat, axis=1)
-                distinct = 1 + (flat[:, 1:] != flat[:, :-1]).sum(1)
-                if held:
-                    # the absent ones were all made one more, the lowest
-                    distinct = distinct - (flat[:, 0] < 0)
-                return distinct.sum().astype(jnp.int32)
-
         def live_rows(tokens, n_real):
             # a routing module is told which rows of the bucket are some
             # sequence's: the padded ones then choose no expert of their
-            # own, and what ``touched`` counts is what the step reads
+            # own, and what ``_experts_touched`` counts is what the step reads
             if not self.route_spec:
                 return {}
             return {"live": jnp.arange(tokens.shape[0]) < n_real}
@@ -366,38 +359,6 @@ class ModelRunner:
             out = (logits, greedy(logits)), ks[:, 0], vs[:, 0], *ids
             return out if held is None else (held, out)
 
-        def new_kv_written(held, k, v, block_tables, ctx_lens, n_real,
-                           window_tables=None):
-            # a row's new K/V goes to the slot append_slot reserved,
-            # (table[ctx // bs], ctx % bs).  Rows padded up to the bucket
-            # are sent out of range: they write nowhere.  With a
-            # selector's cache the half-kernels are written too
-            bs = cfg.block_size
-            with jax.named_scope("kv_write"):
-                rows = jnp.arange(ctx_lens.shape[0])
-                blocks = jnp.where(rows < n_real,
-                                   block_tables[rows, ctx_lens // bs],
-                                   cfg.num_blocks)
-                if window_tables is not None:
-                    # pages of two kinds: the full layers' rows lead k / v;
-                    # the window layers' go to their pool through the
-                    # window table, same column, same offset
-                    wblocks = jnp.where(
-                        rows < n_real, window_tables[rows, ctx_lens // bs],
-                        held["kvw"].shape[2])
-                    return write_rows_by_kind(held, blocks, wblocks,
-                                              ctx_lens % bs, k, v)
-                if "latent" in held:
-                    # a latent page: the new rows came back as ``k``
-                    return {**held, "latent": write_rows(
-                        held["latent"], blocks, ctx_lens % bs, k)}
-                sel = held.get("sel")
-                out = write_rows(held["kv"], blocks, ctx_lens % bs, k, v,
-                                 sel)
-            if sel is None:
-                return {**held, "kv": out}
-            return {**held, "kv": out[0], "sel": out[1]}
-
         widest = cfg.decode_batch_buckets[-1]
 
         def tokens_in(tokens, last_ids, src):
@@ -407,56 +368,41 @@ class ModelRunner:
                 return jnp.where(src >= 0, last_ids[jnp.maximum(src, 0)],
                                  tokens)
 
-        def chosen_from(logits, chose=(), pages=None):
+        def chosen_from(logits, riding=()):
             # (logits, ids), and the ids at the width every bucket's
-            # program takes them back at.  ``chose``: a routing module's
-            # expert ids; the count of those its live rows touched rides
-            # behind the ids, in the pull there is.  ``pages``: a
-            # selecting module's two counts of pages, behind that
+            # program takes them back at.  ``riding``: (rider, what it
+            # took of the forward's results); their counts ride behind the
+            # ids in the layout's order, in the pull there is
             ids = greedy(logits)
             pad = widest - ids.shape[0]
             carry = jnp.pad(ids, (0, pad)) if pad else ids
-            if chose:
-                ids = jnp.concatenate([ids, touched(chose[0])[None]])
-            if pages is not None:
-                ids = jnp.concatenate([ids, pages])
-            return (logits, ids), carry
+            return (logits, _ridden(ids, [rider.count(x)
+                                          for rider, x in riding])), carry
 
         def decode_step(held, params, tokens, positions, block_tables,
                         ctx_lens, n_real, last_ids, src, *by_row):
             # the model reads the pool and attends the new token
-            # explicitly; its K/V is written after the reads.  Where the
-            # holder has a store the model steps the rows of it that
-            # state_rows names (the operand exists only then) and returns
-            # the store after its K/V.  Where it has a selector's cache the
-            # model reads it and says, last of its results, how many pages
-            # it read; the new K's half-kernels are written with the K
-            # ``by_row``: what the cache names for each row's table, an
-            # operand each: the store's rows where there is a store, the
-            # window tables where there is a window pool
-            reads, by_row = {}, list(by_row)
-            window_tables = None
-            if "state" in held:
-                reads.update(state=held["state"], rows=by_row.pop(0))
-            if "sel" in held:
-                reads["selector"] = held["sel"]
-            if "kvw" in held:
-                window_tables = by_row.pop(0)
-                reads.update(window_pool=held["kvw"],
-                             window_tables=window_tables)
-            if "latent" in held:
-                reads["latent_pool"] = held["latent"]
+            # explicitly; its K/V is written after the reads.  What else
+            # the holder holds the forward is handed under the keywords its
+            # plane names (kv_cache.PLANES), with the plane's operand of
+            # ``by_row`` where the cache names one for each row's table
+            # (the store's rows, the window tables: in the table's order,
+            # as ``decode`` built them); a plane the model steps comes
+            # back behind its K/V, and what a rider takes off the results
+            # is counted behind the ids.  The new rows, and what is kept
+            # beside the K, are written by the cache's own writer
+            handed, tables = handed_to_forward(held, by_row)
             logits, k, v, *ids = forward_decode(
                 params, tokens_in(tokens, last_ids, src), positions,
                 held["kv"], block_tables, ctx_lens,
-                **live_rows(tokens, n_real), **reads)
-            if "state" in held:
-                store, *ids = ids
-                held = {**held, "state": store}
-            pages = ids.pop() if "sel" in held else None
-            held = new_kv_written(held, k, v, block_tables, ctx_lens, n_real,
-                                  window_tables)
-            return held, (*chosen_from(logits, ids, pages), k, v, *ids)
+                **live_rows(tokens, n_real), **handed)
+            held = stepped_by_forward(held, ids)
+            riding = [(rider, rider.take(ids)) for rider in family.riders]
+            with jax.named_scope("kv_write"):
+                held = rows_written(held, *slots_reserved(
+                    held, {"kv": block_tables, **tables}, ctx_lens, n_real),
+                    k, v)
+            return held, (*chosen_from(logits, riding), k, v, *ids)
 
         def prefill_chunk_step(held, params, staging, toks, start, n_total):
             # one chunk of one prompt: K/V and half-kernels into the
@@ -485,27 +431,25 @@ class ModelRunner:
         # donated, for a family that stages its state there; no holder for
         # any other
         self._prefill_holder = (lambda: self._state_cache().pool) \
-            if self.state_spec else _NoHolder
+            if kept.staged else _NoHolder
         self.staging_bytes = 0
+        self._packed = None
         if self.chunk:
             # the staging is donated with the holder and comes back in the
             # program's result: the runner keeps it from chunk to chunk
             llm_prefill_chunk_step = jax.jit(prefill_chunk_step,
                                              donate_argnums=(0, 2))
             self._prefill_chunk = llm_prefill_chunk_step
-            positions = -(-cfg.prefill_len_buckets[-1] // self.chunk) \
-                * self.chunk
             # made with the first chunk: a runner over shapes alone (a
             # compile for a described chip) holds none
-            self.staging_spec = self.mod.prefill_staging(self.mcfg, positions)
+            self.staging_spec = family.staging_spec
             self._staging = None
             self._chunk_choices: list = []
             self.staging_bytes = tree_bytes(self.staging_spec)
-        if self.chunk:
             # a routing module's choices, chunk behind chunk
             self._joined = jax.jit(
                 lambda parts: jnp.concatenate(parts, axis=1))
-        if self.chunk and self.window_layers:
+        if self.chunk and kept.packed:
             def packed(staging, first, *, bucket, positions):
                 # what prefill_result hands the cache of a prompt's K/V
                 # where pages are of two kinds: (1, rows, KV, D), the full
@@ -662,20 +606,15 @@ class ModelRunner:
         logits (or ``Chosen``) and the prompt's K/V out of the staging,
         ``(L, bucket, KV, D)`` on the device."""
         tb = _bucket(n_tokens, self.cfg.prefill_len_buckets)
-        if self.window_layers:
-            # pages of two kinds: one array a K and a V, the full layers'
-            # rows one behind another and then each window layer's run of
-            # the prompt's last positions, as the cache scatters them
+        if self._packed is not None:
+            # rows under two tables: one array a K and a V, the full
+            # layers' rows one behind another and then each window layer's
+            # run of the prompt's last positions, as the cache scatters them
             first, run = self._state_cache().window_run(n_tokens)
             ks, vs = self._packed(self._staging, first, bucket=tb,
                                   positions=run)
-        elif self.latent_layers:
-            # a latent page: the prompt's rows, as the cache scatters them
-            ks = vs = self._staging["latent"][:, :tb, None]
         else:
-            heads = (self.n_kv, self.head_dim)
-            ks, vs = (self._staging[name][:, :tb].reshape(
-                self.kv_layers, tb, *heads) for name in ("k", "v"))
+            ks, vs = staged_rows(self.family.kept, self._staging, tb)
         if self._chunk_choices:
             self.choices = self._joined(self._chunk_choices)
             self._chunk_choices = []
@@ -732,28 +671,21 @@ class ModelRunner:
                 block_tables = np.concatenate(
                     [block_tables, np.zeros((pad, block_tables.shape[1]),
                                             np.int32)])
-        state_rows, reads = (), {}
-        if kv_pool.state:
-            # whose table each is, the cache knows; padded rows name none
-            cache = self._state_cache()
-            state_rows = (np.concatenate(
-                [cache.rows_of(block_tables[:b]),
-                 np.full(pad, cache.no_row, np.int32)]),)
-        if kv_pool.window is not None:
-            # the window tables of the same rows, and what the step's
-            # window layers read of them (for the pull's span)
-            state_rows += (self._state_cache().window_tables(block_tables),)
-            reads = self._window_reads(ctx_lens[:b])
-        if kv_pool.latent is not None:
-            # the pages the absorbed kernel walks, for the pull's span
-            reads = dict(latent_pages_read=int(
-                (-(-ctx_lens[:b].astype(np.int64) // self.cfg.block_size)
-                 ).sum()) * self.latent_layers)
+        # what the cache names for each row's table, an operand a plane
+        # that asks one, and what the planes say the step reads (for the
+        # pull's span): both in the table's order
+        by_row, reads = [], {}
+        for plane in kv_pool.planes:
+            if plane.by_row:
+                by_row.append(plane.by_row(self._state_cache(), block_tables,
+                                           b))
+            if plane.reads:
+                reads.update(plane.reads(ctx_lens[:b], self._state_cache()))
         # dispatch holds the jitted call and ends at the ENQUEUE; pull
         # ends when the ids or the logits are on the host, so it holds the
         # wait for the step and nothing else: the pool stays where it is
         args = (self.params, tokens, positions, block_tables, ctx_lens,
-                np.int32(b), last_ids, src, *state_rows)
+                np.int32(b), last_ids, src, *by_row)
         if compiling is not _SEEN:
             register_program(f"llm.decode.{bb}", self._decode,
                              (kv_pool.abstract(), *abstract(args)))
@@ -768,55 +700,39 @@ class ModelRunner:
         return (self.pull_step(step) if wait else step), ks, vs
 
     def _window_reads(self, ctx_lens: np.ndarray) -> dict:
-        """What a decode step's window layers read, from its rows'
-        context lengths: positions (the window's, or the context where it
-        is shorter), blocks (the walk's columns, the first one whole), and
-        the blocks full layers would have read in their place; each summed
-        over the rows and the window layers."""
-        bs, layers = self.cfg.block_size, self.window_layers
-        lens = np.asarray(ctx_lens, np.int64)
-        lo = np.maximum(lens - (self.window - 1), 0)
-        held = -(-lens // bs)
-        return dict(
-            window_positions=int((lens - lo).sum()) * layers,
-            window_blocks=int((held - lo // bs).sum()) * layers,
-            window_blocks_unwindowed=int(held.sum()) * layers)
+        """What a decode step's window layers read
+        (``kv_cache.window_reads``, at this runner's sizes)."""
+        return window_reads(ctx_lens, self.cfg.block_size, self.window,
+                            self.window_layers)
 
     def pull_step(self, step: Enqueued) -> Union[np.ndarray, Chosen]:
         """Wait for an enqueued decode step and bring the host what its
         caller named (``decode``'s first result), inside an
         ``llm.decode.pull`` span that says which step it is."""
-        return self._pull("llm.decode.pull", step.picked, step.n,
-                          step.logit_rows, touched=self.route_spec is not None,
-                          paged=self.select_spec is not None, step=step.step,
-                          **step.reads)
+        return self._pull(
+            "llm.decode.pull", step.picked, step.n, step.logit_rows,
+            width=_bucket(step.n, self.cfg.decode_batch_buckets),
+            riders=self.family.layout, reads=step.reads, step=step.step)
 
     def _pull(self, span: str, picked, n: int,
-              logit_rows: Optional[Sequence[int]], touched: bool = False,
-              paged: bool = False, **attrs):
+              logit_rows: Optional[Sequence[int]], width: int = 1,
+              riders: tuple = (), reads: dict = {}, **attrs):
         """A step's results for the host, inside ``span`` (whose ``bytes``
         is what crossed): all its logits, (n, V), for a caller that named
-        no rows; else the n ids and the rows named (``touched``: the count
-        that a routing module's decode step sends behind its ids)."""
+        no rows; else the n ids, the rows named, and what the step says of
+        itself by name: the ``riders`` behind its ``width`` ids and the
+        host's ``reads``, both told to the span."""
         logits, ids = picked
-        with hot_span(span, self.span_s, **attrs) as pull:
+        with hot_span(span, self.span_s, **attrs, **reads) as pull:
             if logit_rows is None:
                 pull.set(bytes=logits.nbytes)
                 return np.asarray(logits)[:n]
             ids = np.asarray(ids)
-            # behind the ids: a routing module's count, then a selecting
-            # module's two
-            pages = (int(ids[-2]), int(ids[-1])) if paged else None
-            count = int(ids[-3 if paged else -1]) if touched else None
+            rode = _riders_read(ids, width, riders)
             chosen = Chosen(ids[:n], {
                 int(row): np.asarray(self._logits_row(logits, np.int32(row)))
-                for row in logit_rows}, count, pages)
-            if touched:
-                pull.set(experts_touched=chosen.touched)
-            if paged:
-                pull.set(sparse_pages_read=pages[0],
-                         sparse_pages_held=pages[1])
-            pull.set(bytes=ids.nbytes + chosen.logits_nbytes)
+                for row in logit_rows}, {**rode, **reads})
+            pull.set(bytes=ids.nbytes + chosen.logits_nbytes, **rode)
             return chosen
 
     def _state_cache(self) -> PagedKVCache:

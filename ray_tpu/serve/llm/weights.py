@@ -32,7 +32,7 @@ import numpy as np
 
 from ray_tpu._private import rtlog
 from ray_tpu._private.shm_store import _SHM_DIR
-from ray_tpu.serve.llm.kv_cache import _pid_alive
+from ray_tpu._private.flight_recorder import _pid_alive
 
 logger = rtlog.get("serve.llm.weights")
 
@@ -203,15 +203,7 @@ def _lock_stale(lock: str) -> bool:
             pid = int(f.read().decode() or "0")
     except (OSError, ValueError):
         return False
-    if pid <= 0:
-        return False
-    try:
-        os.kill(pid, 0)
-    except ProcessLookupError:
-        return True
-    except PermissionError:
-        return False
-    return False
+    return pid > 0 and not _pid_alive(pid)
 
 
 def _publish(base: str, ready: str, params: Any) -> None:

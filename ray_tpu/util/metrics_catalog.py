@@ -516,6 +516,20 @@ _CACHE: Dict[str, "_metrics.Metric"] = {}
 _CACHE_GEN = [-1]
 
 
+def tell_step(counts: dict, series: dict, tags: dict, per: dict) -> None:
+    """Publish what a decode step of the LLM engine says of itself
+    (``counts``: name -> number) through ``series``, the engine's one table
+    name -> (cataloged series, ``inc`` / ``observe`` / ``set``); a name in
+    ``per`` is told divided by it (a routing model's experts touched, a
+    routed layer).  A count the table does not name (``window_positions``)
+    is the span's alone."""
+    for name, value in counts.items():
+        if name in series:
+            metric, how = series[name]
+            getattr(get(metric), how)(
+                value / per[name] if name in per else value, tags=tags)
+
+
 def get(name: str) -> "_metrics.Metric":
     """The shared instance of a cataloged built-in metric.
 
