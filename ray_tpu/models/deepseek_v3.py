@@ -259,6 +259,7 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array],
     report_step_metrics(
         moe_held_rows=stats.held_rows.mean(),
         moe_held_load_max_over_mean=stats.load_max_over_mean.max(),
-        moe_choice_share_held=stats.choice_share_held.mean())
+        moe_choice_share_held=stats.choice_share_held.mean(),
+        moe_tile_fill=stats.tile_fill.mean())
     return next_token_nll(logits, tgt)
 

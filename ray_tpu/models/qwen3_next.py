@@ -306,7 +306,7 @@ def _experts(h: jax.Array, lp: Params, cfg: Qwen3NextConfig):
         if not isinstance(stats, HeldStats):     # every expert is held
             rows = h.size // h.shape[-1] * cfg.experts_per_token
             stats = HeldStats(jnp.float32(rows), stats.load_max_over_mean,
-                              jnp.float32(1.0))
+                              jnp.float32(1.0), jnp.float32(1.0))
         with jax.named_scope("shared"):
             open_ = jax.nn.sigmoid(jnp.dot(
                 h, lp["shared_gate"]["kernel"].astype(cfg.dtype),
@@ -397,5 +397,6 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array],
         moe_held_rows=held.held_rows.mean(),
         moe_held_load_max_over_mean=held.load_max_over_mean.max(),
         moe_choice_share_held=held.choice_share_held.mean(),
+        moe_tile_fill=held.tile_fill.mean(),
         gdn_decay_mean=decay.mean())
     return next_token_nll(logits, tgt)

@@ -236,7 +236,7 @@ def _walks_held_rows(n: int, k: int, d: int, f: int, held: int,
     tokens fit the VMEM the way out holds them in, and a list of the N k
     assignments fits the scalar memory (``_one_list``)."""
     return (held < num_experts and jax.default_backend() == "tpu"
-            and gmm_tiling(n * k, d, f) is not None
+            and gmm_tiling(n * k, d, f, jnp.dtype(dtype).itemsize) is not None
             and _token_tile(n) is not None
             and dtype in (jnp.bfloat16, jnp.float32)
             and n * d * jnp.dtype(dtype).itemsize <= _SOURCE_BYTES
@@ -546,25 +546,90 @@ class HeldStats(NamedTuple):
     held_rows: jax.Array          # rows of the N k that went to held experts
     load_max_over_mean: jax.Array  # among the held: most-loaded / mean rows
     choice_share_held: jax.Array  # held_rows / (N k): H / E when even
+    tile_fill: jax.Array          # held_rows / rows multiplied (``tile_fill``)
 
 
-# megablox tiles, largest first.  A row tile of 512 and contracted and
-# output tiles of 1,024 were the fastest of those tried on the v5e at the
-# OLMoE cell's shape that fit VMEM (PERF.md section 6, PR 27); a dimension
-# those do not divide (768-wide experts) takes the largest that does.
-_GMM_ROW_TILE = 512
+# megablox's tile (rows, contracted, output), from what the chip said.
+# PR 27, at the OLMoE cell's shape (65,536 rows in 64 groups, 2,048 <->
+# 1,024): of (128, 128, 128), (512, 512, 512), (512, 1024, 1024) and (1024,
+# 1024, 1024) the third was fastest; a dimension 1,024 does not divide
+# (768-wide experts) takes the largest of ``_GMM_TILES`` that does (PR 34).
+# PR 62, at the Qwen3-Next cell's shape (163,840 rows, 64 of 512 groups
+# held, 2,048 <-> 512) and Kanana's (98,304 rows, 16 of 128, 2,048 <-> 768),
+# over the counts their routers really gave (a Qwen3-Next window goes from
+# 320 to 2,300 rows a held group as its router turns to the experts that are
+# there) and row tiles of 512 / 256 / 128 beside split and whole blocks:
+# where VMEM holds a group's WHOLE (d, f) matrix beside 256 rows, each of
+# the three products is faster at every count (7-32%; the three of a shape
+# together 13-26%): a visit is one grid step, a group's matrix stays in VMEM
+# over its consecutive row tiles and the float32 accumulator is written
+# once.  Beside a whole block 256 rows beat 512 by 3% where a group holds
+# ~2,000 rows and by 17% where it holds ~320; 128 win another 3% there and
+# lose 5% at ~2,000.  Beside a split block (OLMoE: 2,048 x 1,024 is too
+# large whole) 256 and 512 rows differ by 2%, neither in all three
+# products, and 512 stay.  The rows a group holds decided nothing the
+# block's size had not (PERF.md section 6, PR 62).
+_GMM_ROW_TILE = 512             # a megablox shape's rows are a multiple
+_GMM_ROW_TILES = (_GMM_ROW_TILE, 256)   # beside a split block, a whole one
 _GMM_TILES = (1024, 768, 512, 256, 128)
 
 
-def gmm_tiling(m: int, d: int, f: int) -> Optional[Tuple[int, int, int]]:
+def _gmm_vmem_bytes(rows: int, d: int, f: int, itemsize: int) -> int:
+    """What the hungriest of megablox's three kernels holds in VMEM at the
+    tile (rows, d, f): its operand and result blocks twice each (the
+    pipeline's two buffers) and a float32 accumulator the result's size:
+    rows x f forward, rows x d for the rows' gradient, d x f for the
+    weights'.  Mosaic refused every tile tried that this puts over its 16
+    MiB and took every one under 15 (the described v5e, PR 62)."""
+    blocks = 2 * itemsize * (rows * d + rows * f + d * f)
+    return blocks + 4 * max(rows * d, rows * f, d * f)
+
+
+def gmm_tiling(m: int, d: int, f: int,
+               itemsize: int = 2) -> Optional[Tuple[int, int, int]]:
     """megablox's (rows, contracted, output) tile for an (m, d) x (d, f)
-    product over ragged groups: the tile follows the shape.  None where
-    no tile divides (the caller then takes ``ragged_dot``)."""
+    product over ragged groups, the same numbers for the three products of
+    a shape (the rows' gradient takes the last two swapped): the group's
+    whole matrix beside 256 rows where VMEM holds that for operands of
+    ``itemsize`` bytes, else 512 rows beside the largest of ``_GMM_TILES``
+    that divides each dimension.  None where none divides or ``m`` is no
+    multiple of 512 (the caller then takes ``ragged_dot``): a decode
+    step's 16-192 rows never come here."""
     def tile(dim):
         return next((t for t in _GMM_TILES if dim % t == 0), None)
-    tiling = (_GMM_ROW_TILE if m % _GMM_ROW_TILE == 0 else None,
-              tile(d), tile(f))
-    return None if None in tiling else tiling
+    if m % _GMM_ROW_TILE or tile(d) is None or tile(f) is None:
+        return None
+    split, whole = _GMM_ROW_TILES
+    if _gmm_vmem_bytes(whole, d, f, itemsize) <= _VMEM_DEFAULT - 2 ** 20:
+        return whole, d, f
+    return split, tile(d), tile(f)
+
+
+def gmm_visits(group_sizes, held: int, row_tile: int):
+    """Grid steps along the rows of one ``gmm`` call over the ``held``
+    leading groups: the kernel visits a row tile once for every group that
+    touches it (``make_group_metadata``: a group's tiles run from its start
+    rounded down to its end rounded up, an empty group has none) and
+    multiplies the whole tile each time."""
+    ends = jnp.cumsum(group_sizes[:held])
+    starts = ends - group_sizes[:held]
+    tiles = (ends + row_tile - 1) // row_tile - starts // row_tile
+    return jnp.where(ends > starts, tiles, 0).sum()
+
+
+def tile_fill(group_sizes: jax.Array, held: int,
+              tiling: Optional[Tuple[int, int, int]]) -> jax.Array:
+    """How well the row tile fits the routing a step really had: of the
+    rows megablox multiplies for the ``held`` leading groups, the share
+    that lay in the visit's own group, ``held_rows / (visits x rows a
+    tile)`` (``gmm_visits``).  1 where every held group starts and ends on
+    a tile's edge, towards 0 as the groups grow small beside the tile; 0
+    with no held row, and 1 where no tile is chosen (``ragged_dot`` walks
+    no tile of ours)."""
+    if tiling is None:
+        return jnp.float32(1.0)
+    rows = gmm_visits(group_sizes, held, tiling[0]) * tiling[0]
+    return group_sizes[:held].sum() / jnp.maximum(rows, 1).astype(jnp.float32)
 
 
 @jax.custom_vjp
@@ -576,7 +641,8 @@ def _megablox(rows, w, group_sizes):
     counts: the leading ones, and rows of the others come out zero."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
     (m, d), f = rows.shape, w.shape[-1]
-    return gmm(rows, w, group_sizes, rows.dtype, gmm_tiling(m, d, f))
+    return gmm(rows, w, group_sizes, rows.dtype,
+               gmm_tiling(m, d, f, rows.dtype.itemsize))
 
 
 def _megablox_fwd(rows, w, group_sizes):
@@ -586,11 +652,11 @@ def _megablox_fwd(rows, w, group_sizes):
 def _megablox_bwd(res, g):
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
     rows, w, group_sizes = res
-    (m, d), f = rows.shape, w.shape[-1]
-    d_rows = gmm(g, w, group_sizes, rows.dtype, gmm_tiling(m, f, d),
+    (m, d), f, size = rows.shape, w.shape[-1], rows.dtype.itemsize
+    d_rows = gmm(g, w, group_sizes, rows.dtype, gmm_tiling(m, f, d, size),
                  transpose_rhs=True)
     d_w = tgmm(rows.swapaxes(0, 1), g, group_sizes, w.dtype,
-               gmm_tiling(m, d, f), num_actual_groups=w.shape[0])
+               gmm_tiling(m, d, f, size), num_actual_groups=w.shape[0])
     return d_rows, d_w, None
 
 
@@ -607,14 +673,19 @@ def grouped_matmul(rows: jax.Array, w: jax.Array,
     On a TPU, where a tile divides the shapes (``gmm_tiling``): megablox's
     Pallas ``gmm`` (kernels ``gmm`` and, for the weights' gradient,
     ``tgmm``), which read the transposed weights in place for the rows'
-    gradient.  Elsewhere ``jax.lax.ragged_dot``, which XLA lowers on a TPU
-    to its own kernels (``ragged-dot*``): slower there by a fifth to a
-    third in all three products, and its backward copies the weights
+    gradient, each of the three products under the tile of its own shape:
+    a group's whole matrix beside 256 rows where VMEM holds it (512-wide
+    and 768-wide experts of a 2,048-wide model), 512 rows beside blocks of
+    up to 1,024 otherwise; ``tile_fill`` says how the row tile fits the
+    counts a step had.  Elsewhere ``jax.lax.ragged_dot``, which XLA lowers
+    on a TPU to its own kernels (``ragged-dot*``): slower there by a fifth
+    to a third in all three products, and its backward copies the weights
     transposed; with ``H < E`` its rows behind the held groups are set to
     zero here, which the TPU's kernel does not do itself.
     """
     (m, d), (held, _, f) = rows.shape, w.shape
-    if jax.default_backend() == "tpu" and gmm_tiling(m, d, f):
+    if jax.default_backend() == "tpu" and gmm_tiling(
+            m, d, f, rows.dtype.itemsize):
         return _megablox(rows, w, group_sizes)
     out = jax.lax.ragged_dot(rows, w, group_sizes[:held])
     if jax.default_backend() == "tpu" and held < group_sizes.shape[0]:
@@ -788,9 +859,12 @@ def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
         if scoring == "sigmoid" or held < num_experts:
             mine = group_sizes[:held].astype(jnp.float32)
             rows = mine.sum()
+            tiling = gmm_tiling(n * k, *w_gate.shape[1:], x.dtype.itemsize)
             return (y, HeldStats(rows, mine.max() / jnp.maximum(mine.mean(),
                                                                 1e-9),
-                                 rows / (n * k)), *chose)
+                                 rows / (n * k),
+                                 tile_fill(group_sizes, held, tiling)),
+                    *chose)
         if first_held:
             group_sizes = jnp.roll(group_sizes, first_held)
         share = group_sizes.astype(jnp.float32) / (n * k)        # f_e

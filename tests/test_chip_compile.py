@@ -442,6 +442,39 @@ def test_dropless_experts_at_olmoe_train_shape(v5e, monkeypatch, fn,
         text, "bf16[65536,2048]", "[8192,8,2048]")
 
 
+def _grouped_products(rows, w, group_sizes):
+    """Forward, the rows' gradient and the weights' gradient of one
+    grouped matmul, each under the tile of its own shape."""
+    from ray_tpu.ops.moe import grouped_matmul
+
+    def loss(rows, w):
+        return grouped_matmul(rows, w, group_sizes).astype(jnp.float32).sum()
+    return jax.grad(loss, argnums=(0, 1))(rows, w)
+
+
+@pytest.mark.parametrize("m,groups,held,d,f", [
+    pytest.param(163840, 512, 64, 2048, 512, id="qwen3_next_gate_and_up"),
+    pytest.param(163840, 512, 64, 512, 2048, id="qwen3_next_down"),
+    pytest.param(98304, 128, 16, 2048, 768, id="kanana_gate_and_up"),
+    pytest.param(98304, 128, 16, 768, 2048, id="kanana_down"),
+])
+def test_a_whole_expert_matrix_beside_256_rows_fits_the_chip(
+        v5e, monkeypatch, m, groups, held, d, f):
+    """Where ``gmm_tiling`` answers a group's whole (d, f) matrix beside
+    256 rows (PR 62: the Qwen3-Next and Kanana cells), Mosaic takes all
+    three kernels within its default scoped VMEM: the forward (recomputed
+    under the gradient), the rows' gradient and ``tgmm``, whose float32
+    accumulator is the whole matrix."""
+    from ray_tpu.ops import moe
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert moe.gmm_tiling(m, d, f) == (256, d, f)
+    assert moe._gmm_vmem_bytes(256, d, f, 2) <= 15 * 2 ** 20
+    text = _compile(_grouped_products, v5e, ((m, d), jnp.bfloat16),
+                    ((held, d, f), jnp.bfloat16), ((groups,), jnp.int32))
+    assert sorted(_kernel_results(text)) == sorted(
+        [f"bf16[{m},{d}]", f"bf16[{held},{d},{f}]"]), text[:2000]
+
+
 def _spread(x, order, held_rows):
     from ray_tpu.ops.moe import _spread_rows
     return _spread_rows(x, order, held_rows)

@@ -281,7 +281,7 @@ def test_the_bias_is_untouched_by_a_step_with_weight_decay(batch):
         after = jax.tree_util.tree_map(np.asarray, state.params)
         assert np.isfinite(float(metrics["loss"]))
         for name in ("moe_held_rows", "moe_held_load_max_over_mean",
-                     "moe_choice_share_held"):
+                     "moe_choice_share_held", "moe_tile_fill"):
             assert float(metrics[name]) > 0, name
         assert 0 < float(metrics["moe_choice_share_held"]) < 1
         for (path, a), b in zip(
@@ -295,8 +295,19 @@ def test_the_bias_is_untouched_by_a_step_with_weight_decay(batch):
 @pytest.mark.parametrize("shape,tiling", [
     ((65536, 2048, 1024), (512, 1024, 1024)),       # OLMoE's gate and up
     ((65536, 1024, 2048), (512, 1024, 1024)),       # OLMoE's down
-    ((98304, 2048, 768), (512, 1024, 768)),         # 768-wide experts
-    ((98304, 768, 2048), (512, 768, 1024)),
+    # a group's whole matrix beside 256 rows, where VMEM holds it (PR 62)
+    ((98304, 2048, 768), (256, 2048, 768)),         # Kanana's 768-wide
+    ((98304, 768, 2048), (256, 768, 2048)),
+    ((163840, 2048, 512), (256, 2048, 512)),        # Qwen3-Next's 512-wide
+    ((163840, 512, 2048), (256, 512, 2048)),
+    ((163840, 2048, 512, 4), (512, 1024, 512)),     # not in float32
+    # the serving cells' experts are too large whole: as they were
+    ((512, 2560, 768), (512, 512, 768)),            # Ling's decode step
+    ((16384, 2560, 768), (512, 512, 768)),          # and its chunk
+    ((4096, 2048, 1536), (512, 1024, 768)),         # LFM2's 1,024 prompt
+    ((8192, 3072, 3072), (512, 1024, 1024)),        # Trinity's chunk
+    ((64, 3072, 3072), None),                       # a decode step's rows
+    ((128, 2048, 1536), None),
     ((100, 64, 32), None),                          # a test's size
     ((98304, 2048, 700), None),
 ])
@@ -304,10 +315,13 @@ def test_the_grouped_matmuls_tile_follows_the_shape(shape, tiling):
     got = moe.gmm_tiling(*shape)
     assert got == tiling
     if got:
-        m, d, f = shape
+        m, d, f, *size = shape
         assert m % got[0] == 0 and d % got[1] == 0 and f % got[2] == 0
         # the rows' gradient contracts f and puts out d: its own tile
-        assert moe.gmm_tiling(m, f, d) == (got[0], got[2], got[1])
+        assert moe.gmm_tiling(m, f, d, *size) == (got[0], got[2], got[1])
+        assert got[0] in moe._GMM_ROW_TILES
+        if got[1:] == (d, f):       # whole: within Mosaic's default limit
+            assert moe._gmm_vmem_bytes(*got, *(size or [2])) < 15 * 2 ** 20
 
 
 def test_flash_attention_takes_keys_wider_than_values():

@@ -292,7 +292,8 @@ def test_the_passes_that_stop_at_held_rows_equal_the_gathers(
         route.__name__.strip("_"))
     if expected is None:
         assert 0 < int(held_rows) < HN * HK
-        assert int(held_rows) % moe._GMM_ROW_TILE
+        # inside a row tile, whichever of them the rule gives
+        assert all(int(held_rows) % t for t in moe._GMM_ROW_TILES)
     else:
         assert int(held_rows) == expected
     visited = np.arange(HN * HK) < int(held_rows)

@@ -156,10 +156,13 @@ def test_the_step_reports_what_the_experts_saw_and_how_the_states_forget(
         qn.loss_fn(params, batch, CFG)
     n = batch["inputs"].size
     assert set(sink) == {"moe_held_rows", "moe_held_load_max_over_mean",
-                         "moe_choice_share_held", "gdn_decay_mean"}
+                         "moe_choice_share_held", "moe_tile_fill",
+                         "gdn_decay_mean"}
     assert 0 < float(sink["moe_held_rows"]) < n * CFG.experts_per_token
     assert float(sink["moe_choice_share_held"]) == pytest.approx(
         float(sink["moe_held_rows"]) / (n * CFG.experts_per_token))
+    # no megablox tile divides a test's rows: nothing of ours to fit
+    assert float(sink["moe_tile_fill"]) == 1.0
     # dt in (0.001, 0.1), A in (0, 16): the states remember
     assert 0.5 < float(sink["gdn_decay_mean"]) < 1.0
 
