@@ -8,6 +8,14 @@ the loop: a prefill or a decode) and the model runner's ``prefill`` and
 collector thread polls the streams and stamps each token as a client would
 see it.  A thread per request would take the interpreter lock from the
 engine's host-bound loop and lengthen the very step being measured.
+
+The collector looks at the streams when an iteration of the loop has ended
+(``Served.stepped``, set by the wrapper round ``engine.step``) and every
+``POLL_S`` besides.  On a sleep of ``POLL_S`` alone a stamp falls on a tick
+of ~2.15 ms, and a step of 2.5 ticks reads as two or three, half and half:
+the median of all gaps then lies in the empty valley between two modes and
+moves by 1-3% from run to run on steps that repeat to 0.5% (PERF.md section
+7).  The stamp is the collector's own clock when it finds the token.
 """
 
 from __future__ import annotations
@@ -35,8 +43,20 @@ from perfbench import manifest, stats, trace, traffic
 # the run that made the logits.  The reference is computed UNDER that choice
 # and audits it in its own scores; the two limits of the audit are the
 # configuration's too, and a routed configuration without them is refused.
+#
+# A family that does not step by tokens (``families/<f>.stepping(config)`` is
+# not None) says how its sequences are stepped: an object with ``warm(served)``
+# and ``check(served, prompt, k)`` in the place of ``TokenStepping`` below.
+# What is compared, with what and under which limits stays here: ``check``
+# hands back a list of ``Compared``, each one plain forward's worth,
+#   {"fed": [ids], "rows": [(phase, position, logits (V,)), ...],
+#    "choices": ids (routed layers, len(fed), k), for a family that routes}
+# ``fed`` every id one pass of the program saw at positions 0..len-1 (a mask
+# id where it saw one), ``rows`` the positions of ``fed`` whose logits that
+# pass produced, each under "prefill" or "decode" (perfbench/README.md).
 POLL_S = 0.002
 ROUTE_LIMITS = ("route_margin", "route_differing_share")
+PHASES = ("prefill", "decode")
 
 
 class _Rec:
@@ -73,6 +93,9 @@ class Served:
         self.fam.check_sizes(self.config, mcfg)
         describe = getattr(self.fam, "routed", None)
         self.routed = describe(self.config) if describe else None
+        describe = getattr(self.fam, "stepping", None)
+        self.stepping = (describe(self.config) if describe else None) \
+            or TokenStepping()
         for key in ROUTE_LIMITS if self.routed else ():
             if key not in self.config["serve"] \
                     or f"why_{key}" not in self.config["serve"]:
@@ -100,13 +123,14 @@ class Served:
                 "experts the program chose there is no reference for its "
                 "logits (perfbench/README.md, a routed family)")
         self.steps = []         # (t0, t1, kind, running, waiting, preempted)
+        self.stepped = threading.Event()    # an iteration ended: look now
         self._instrument()
-        self._warm_programs()
+        self.stepping.warm(self)
         marks["programs_s"] = time.perf_counter() - t_start
         self.eng.start()
 
     def _instrument(self) -> None:
-        eng, log = self.eng, self.steps
+        eng, log, stepped = self.eng, self.steps, self.stepped
         step, runner = eng.step, eng.runner
         prefill, decode = runner.prefill, runner.decode
 
@@ -122,6 +146,7 @@ class Served:
                     else "none")
             log.append((t0, t1, kind, before["running"], before["waiting"],
                         after["preemptions"]))
+            stepped.set()
             return ran
 
         def spanned(name, fn):
@@ -134,24 +159,6 @@ class Served:
         runner.prefill = spanned("pb.prefill.run", prefill)
         runner.decode = spanned("pb.decode.run", decode)
 
-    def _warm_programs(self) -> None:
-        """Every program the traffic will use, once; and every page of the
-        KV pool touched, since the whole pool crosses to the device in
-        each decode step and its pages exist only after a first write."""
-        eng, ecfg = self.eng, self.ecfg
-        eng.cache.pool.fill(0)
-        buckets = sorted({_bucket(p, ecfg.prefill_len_buckets)
-                          for p, _ in traffic.length_grid(self.spec)})
-        for b in buckets:
-            eng.runner.prefill([0] * b)
-        maxb = ecfg.max_blocks_per_seq
-        for b in ecfg.decode_batch_buckets:
-            if b <= _bucket(ecfg.max_num_seqs, ecfg.decode_batch_buckets):
-                eng.runner.decode(
-                    np.zeros(b, np.int32), np.zeros(b, np.int32),
-                    eng.cache.pool, np.zeros((b, maxb), np.int32),
-                    np.ones(b, np.int32))
-
     # ------------------------------------------------------------ measuring
     def measure(self, ctx: dict, rate_scale: float = 1.0) -> dict:
         """Play the cycle: ``warm_seconds`` of it, then the window."""
@@ -162,7 +169,7 @@ class Served:
         period = traffic.cycle_seconds(spec, rate_scale)
         plan = traffic.schedule(cycle, period, warm_s, seconds)
         recs = [_Rec(r.due_s, r.max_tokens) for r in plan]
-        stop = threading.Event()
+        stop, stepped = threading.Event(), self.stepped
         t_open = time.perf_counter() + warm_s + 0.05     # time 0 of the plan
         active, inbox = [], []
 
@@ -199,7 +206,8 @@ class Served:
                     if done:
                         rec.done = True
                         active.remove(rec)
-                time.sleep(POLL_S)
+                stepped.wait(POLL_S)
+                stepped.clear()
 
         threads = [threading.Thread(target=generate, name="pb-generator"),
                    threading.Thread(target=collect, name="pb-collector")]
@@ -318,43 +326,161 @@ class Served:
         }
 
     # -------------------------------------------------------- correctness
-    def _choices(self, rows: int) -> np.ndarray:
-        """The expert ids the step just run chose, for its first ``rows``
-        rows: (routed layers, rows, k), as the runner holds them."""
+    def _expert_ids(self, ids, rows: int, whose: str,
+                    or_more: bool = False) -> np.ndarray:
+        """``ids`` as int32 (routed layers, ``rows``, k), each an expert the
+        configuration has; refused, in ``whose`` name, where they are not."""
         want = self.routed
-        ids = np.asarray(self.eng.runner.choices)
+        ids = np.asarray(ids)
         if ids.ndim != 3 or ids.shape[0] != want["layers"] \
-                or ids.shape[1] < rows or ids.shape[2] != want["k"] \
-                or ids.dtype.kind not in "iu":
-            raise ValueError(f"the runner's choices are {ids.dtype}"
-                             f"{list(ids.shape)}: not {rows} rows or more of "
-                             f"{want}")
+                or ids.shape[2] != want["k"] or ids.dtype.kind not in "iu" \
+                or not (rows <= ids.shape[1] if or_more
+                        else rows == ids.shape[1]):
+            raise ValueError(f"{whose} choices are {ids.dtype}"
+                             f"{list(ids.shape)}: not {rows} rows"
+                             f"{' or more' if or_more else ''} of {want}")
         ids = ids[:, :rows].astype(np.int32)
         if ids.min() < 0 or ids.max() >= want["experts"]:
-            raise ValueError("the runner's choices name an expert outside "
+            raise ValueError(f"{whose} choices name an expert outside "
                              f"0..{want['experts'] - 1}")
         return ids
 
+    def _choices(self, rows: int) -> np.ndarray:
+        """The expert ids the step just run chose, for its first ``rows``
+        rows: (routed layers, rows, k), as the runner holds them."""
+        return self._expert_ids(self.eng.runner.choices, rows,
+                                "the runner's", or_more=True)
+
+    def _sound(self, one: dict):
+        """(fed, rows, choices) of one ``Compared``, refused by name where
+        it is not what the header says."""
+        if set(one) - {"fed", "rows", "choices"}:
+            raise ValueError(f"a Compared has the keys {sorted(one)}: not "
+                             "fed, rows and, for a routed family, choices")
+        fed = [int(t) for t in one["fed"]]
+        vocab = self.config["vocab_size"]
+        if not fed or min(fed) < 0 or max(fed) >= vocab:
+            raise ValueError(f"a Compared's fed holds {len(fed)} ids, which "
+                             f"have to be one or more of 0..{vocab - 1}")
+        rows = [(phase, int(at), np.asarray(logits, np.float32))
+                for phase, at, logits in one["rows"]]
+        for phase, at, _ in rows:
+            if phase not in PHASES or not 0 <= at < len(fed):
+                raise ValueError(
+                    f"a Compared's row ({phase!r}, {at}, ...) is not one of "
+                    f"{PHASES} at a position of its {len(fed)} fed ids")
+        if not rows:
+            raise ValueError("a Compared has no rows: nothing of its pass "
+                             "is compared")
+        has = one.get("choices") is not None
+        if bool(self.routed) != has:
+            raise ValueError(
+                f"the configuration routes ({self.routed}) and a Compared "
+                f"{'has' if has else 'has no'} choices")
+        # an expert of every routed layer's k at every position of fed, or
+        # the reference has nothing to go under
+        return fed, rows, (self._expert_ids(one["choices"], len(fed),
+                                            "a Compared's")
+                           if self.routed else None)
+
     def check_logits(self, seed: int) -> dict:
-        """Outside the window: one prompt through prefill, then decode
-        steps through the paged cache as the engine's loop makes them,
-        against the plain reference's full forward; for a family that
-        routes, the reference under the experts those steps chose, and the
-        choice against the reference's own scores."""
-        eng, spec = self.eng, self.spec
-        runner, cache = eng.runner, eng.cache
+        """Outside the window: one prompt stepped as the family says (the
+        job's own ``TokenStepping``: prefill, then decode steps through the
+        paged cache as the engine's loop makes them), and every pass of it
+        against the plain reference's full forward over what that pass was
+        fed; for a family that routes, the reference under the experts the
+        pass chose, and the choice against the reference's own scores."""
+        spec = self.spec
         n, k = spec["check_prompt_tokens"], spec["check_decode_steps"]
         prompt = [int(t) for t in traffic.rng_for(seed, "serve_check")
                   .integers(0, self.config["vocab_size"], n)]
+        return self._judge(self.stepping.check(self, prompt, k))
+
+    def _judge(self, compared: list) -> dict:
+        """Each ``Compared`` against one reference forward over its ``fed``:
+        the largest difference over the rows of each phase, the audits of a
+        routed family merged, under the configuration's limits."""
+        limits, diffs, audits = self.config["serve"], {}, []
+        for one in compared:
+            fed, rows, choices = self._sound(one)
+            if self.routed:
+                ref, audit = self.fam.reference_logits(
+                    self.params, [fed], self.config, choices=choices)
+                audits.append(audit)
+            else:
+                ref = self.fam.reference_logits(self.params, [fed],
+                                                self.config)
+            ref = np.asarray(ref)[0]
+            for phase, at, logits in rows:
+                if logits.shape != ref[at].shape:
+                    raise ValueError(
+                        f"a Compared's {phase} row at {at} has logits "
+                        f"{list(logits.shape)} and the reference's are "
+                        f"{list(ref[at].shape)}")
+                diffs.setdefault(phase, []).append(
+                    float(np.abs(logits - ref[at]).max()))
+        for phase in PHASES:
+            if phase not in diffs:
+                raise ValueError(f"the family's check compared no {phase} "
+                                 "row: both phases decide `correct`")
+        # numpy's max and not Python's: a NaN among the rows stays a NaN,
+        # which is under no limit
+        out = {"prefill_logit_diff": float(np.max(diffs["prefill"])),
+               "decode_logit_diff": float(np.max(diffs["decode"])),
+               "logit_atol": limits["logit_atol"]}
+        if audits:
+            out.update(
+                route_decisions=sum(a["decisions"] for a in audits),
+                route_differing=sum(a["differing"] for a in audits),
+                route_worst_margin=max(a["worst_margin"] for a in audits),
+                route_margin=limits["route_margin"],
+                route_differing_share=limits["route_differing_share"])
+        out["ok"] = all(value <= limit
+                        for value, limit in _compared(out).values())
+        return out
+
+    def close(self) -> None:
+        self.eng.shutdown()
+
+
+class TokenStepping:
+    """The job's own stepping, for a family that says nothing of its own
+    (``stepping`` absent or None): a step is one new position a row, whose
+    K/V the step that computes it writes."""
+
+    def warm(self, served: Served) -> None:
+        """Every program the traffic will use, once; and every page of the
+        KV pool touched, since the whole pool crosses to the device in
+        each decode step and its pages exist only after a first write."""
+        eng, ecfg = served.eng, served.ecfg
+        eng.cache.pool.fill(0)
+        buckets = sorted({_bucket(p, ecfg.prefill_len_buckets)
+                          for p, _ in traffic.length_grid(served.spec)})
+        for b in buckets:
+            eng.runner.prefill([0] * b)
+        maxb = ecfg.max_blocks_per_seq
+        for b in ecfg.decode_batch_buckets:
+            if b <= _bucket(ecfg.max_num_seqs, ecfg.decode_batch_buckets):
+                eng.runner.decode(
+                    np.zeros(b, np.int32), np.zeros(b, np.int32),
+                    eng.cache.pool, np.zeros((b, maxb), np.int32),
+                    np.ones(b, np.int32))
+
+    def check(self, served: Served, prompt: list, k: int) -> list:
+        """The prompt through prefill, then ``k`` greedy decode steps of one
+        token: ONE ``Compared`` over the final sequence, the prefill's last
+        position and each step's."""
+        runner, cache = served.eng.runner, served.eng.cache
+        n = len(prompt)
         sid = "pb_check"
         cache.alloc_seq(sid, n)
         try:
             logits, ks, vs = runner.prefill(prompt)
-            chose = [self._choices(n)] if self.routed else []
+            chose = [served._choices(n)] if served.routed else []
             cache.scatter_prefill(sid, np.asarray(ks, np.float32),
                                   np.asarray(vs, np.float32), n)
             got, seq = [logits], list(prompt)
-            maxb = self.ecfg.max_blocks_per_seq
+            maxb = served.ecfg.max_blocks_per_seq
             for _ in range(k):
                 seq.append(int(np.argmax(got[-1])))
                 blk, off, _ = cache.append_slot(sid)
@@ -365,40 +491,20 @@ class Served:
                 lg, ks, vs = runner.decode(
                     np.asarray([seq[-1]], np.int32), at, cache.pool,
                     tables, at)
-                if self.routed:
-                    chose.append(self._choices(1))
+                if served.routed:
+                    chose.append(served._choices(1))
                 cache.write_token(blk, off, np.asarray(ks[:, 0], np.float32),
                                   np.asarray(vs[:, 0], np.float32))
                 got.append(lg[0])
         finally:
             cache.free_seq(sid)
-        limits, audit = self.config["serve"], None
-        if self.routed:
+        one = {"fed": seq,
+               "rows": [("prefill" if i == 0 else "decode", n - 1 + i, g)
+                        for i, g in enumerate(got)]}
+        if served.routed:
             # the ids of every position of seq, (routed layers, n + k, K)
-            ref, audit = self.fam.reference_logits(
-                self.params, [seq], self.config,
-                choices=np.concatenate(chose, axis=1))
-        else:
-            ref = self.fam.reference_logits(self.params, [seq], self.config)
-        ref = np.asarray(ref)[0]
-        diffs = [float(np.abs(g - ref[n - 1 + i]).max())
-                 for i, g in enumerate(got)]
-        out = {"prefill_logit_diff": diffs[0],
-               "decode_logit_diff": max(diffs[1:]),
-               "logit_atol": limits["logit_atol"]}
-        if audit:
-            out.update(
-                route_decisions=audit["decisions"],
-                route_differing=audit["differing"],
-                route_worst_margin=audit["worst_margin"],
-                route_margin=limits["route_margin"],
-                route_differing_share=limits["route_differing_share"])
-        out["ok"] = all(value <= limit
-                        for value, limit in _compared(out).values())
-        return out
-
-    def close(self) -> None:
-        self.eng.shutdown()
+            one["choices"] = np.concatenate(chose, axis=1)
+        return [one]
 
 
 def _bucket(n: int, buckets) -> int:

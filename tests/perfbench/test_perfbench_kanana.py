@@ -198,7 +198,7 @@ def test_the_new_metrics_are_appended_for_this_cell_only():
     # by name, wherever later PRs' entries put them: each once
     assert sorted(m["name"] for m in mine) == sorted(NEW_METRICS)
     for m in mine:
-        assert m["workloads"] == [CELL]
+        assert CELL in m["workloads"]
         assert m["source"] == "device_trace"
         assert m["moves"] == "train_tokens_per_s_per_chip"
         spec = manifest.metric_spec("per_layer", m["name"])
@@ -214,10 +214,12 @@ def test_the_new_metrics_are_appended_for_this_cell_only():
     assert [w["config"] for w in bench["workloads"] if w["name"] == CELL] \
         == ["kanana-2-30b-a3b"]
     assert [c["name"] for c in bench["configs"]].count("kanana-2-30b-a3b") == 1
-    # OLMoE's expert metrics keep OLMoE's cell alone
+    # OLMoE's expert metrics are keyed on OLMoE's result shapes: this cell
+    # is not among their cells, whoever else is
     for m in bench["per_layer"]:
         if m["name"].startswith("moe.expert_matmul"):
-            assert m["workloads"] == ["olmoe-1b-7b.train-b2-s4096"]
+            assert "olmoe-1b-7b.train-b2-s4096" in m["workloads"] \
+                and CELL not in m["workloads"]
 
 
 def test_the_new_metrics_read_their_kernels_and_no_other(fam):

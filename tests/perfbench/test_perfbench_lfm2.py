@@ -111,7 +111,7 @@ def test_the_manifest_has_the_configuration_the_cell_and_the_metrics():
     assert set(MINE) <= set(layer)
     for name in MINE:
         m = manifest.find(bench["per_layer"], name, "metric")
-        assert m["workloads"] == [CELL] \
+        assert CELL in m["workloads"] \
             and m["moves"] == "serve_out_tokens_per_s"
         spec = manifest.metric_spec("per_layer", name)
         assert (spec["layer"], spec["unit"], spec["better"],

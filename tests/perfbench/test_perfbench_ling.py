@@ -29,11 +29,16 @@ CATALOG = Path("/opt/skills/guides/model-configs/architectures.jsonl")
 MINE = ("kda.decode_state_ms", "kda.decode_state_hbm_share",
         "kda.prefill_chunk_ms", "kda.prefill_chunk_peak_share",
         "mla.decode_latent_ms", "mla.decode_latent_hbm_share",
-        "mla.prefill_attn_ms", "moe.group_decode_experts_ms",
+        "mla.prefill_attn_ms", "moe.decode_experts_ms",
         "moe.group_decode_expert_hbm_share",
-        "moe.group_decode_experts_touched", "engine.latent_prefill_chunk_ms")
+        "moe.decode_experts_touched", "engine.prefill_chunk_ms")
 SHARED = ("engine.ttft_p50_ms", "scheduler.batch_occupancy",
-          "scheduler.preemptions", "scheduler.queue_wait_mean_ms")
+          "scheduler.preemptions", "scheduler.queue_wait_mean_ms",
+          # PR 54's token stamps and idle by cause, listed since PR 63
+          "engine.token_gap_p50_ms", "engine.token_gap_p95_ms",
+          "device.idle_unoffered_share", "device.idle_with_work_share",
+          "device.idle_per_prefill_ms", "engine.compiles_in_window",
+          "engine.first_token_p50_ms")
 REDUCED = ["num_hidden_layers", "first_k_dense_replace", "num_experts",
            "vocab_size"]
 LIMITS = {"logit_atol": 5e-3, "why_logit_atol": "float32 in another order",
@@ -157,10 +162,10 @@ def test_the_manifest_has_the_configuration_the_cell_and_the_metrics():
     assert reported == {"serve_out_tokens_per_s", "setup_s"}
     layer = {m["name"]: m for m in
              manifest.metrics_of_cell(bench, "per_layer", CELL)}
-    assert set(layer) == set(MINE) | set(SHARED)
+    assert set(MINE) | set(SHARED) <= set(layer)
     for name in MINE:
         m = manifest.find(bench["per_layer"], name, "metric")
-        assert m["workloads"] == [CELL] \
+        assert CELL in m["workloads"] \
             and m["moves"] == "serve_out_tokens_per_s"
         spec = manifest.metric_spec("per_layer", name)
         assert (spec["layer"], spec["unit"], spec["better"],
@@ -277,7 +282,7 @@ def test_the_serving_job_runs_the_family_and_its_check_passes():
         return sum(s["value"] for s in snap[name]["series"])
     assert total("rtpu_llm_latent_pages_read") > 0
     spec = manifest.metric_spec("per_layer",
-                                "moe.group_decode_experts_touched")
+                                "moe.decode_experts_touched")
     touched = manifest.reducer(spec["reducer"])(facts, spec["params"])
     assert 0 <= touched <= 4                # of the 4 held
 

@@ -33,7 +33,11 @@ MINE = ("sparse.decode_select_ms", "sparse.decode_attn_ms",
         "sparse.prefill_attn_ms", "lightning.prefill_scan_ms",
         "sparse.prefill_attn_peak_share")
 SHARED = ("engine.ttft_p50_ms", "scheduler.batch_occupancy",
-          "scheduler.preemptions", "scheduler.queue_wait_mean_ms")
+          "scheduler.preemptions", "scheduler.queue_wait_mean_ms",
+          # PR 54's token stamps and idle by cause, listed since PR 63
+          "engine.token_gap_p50_ms", "engine.token_gap_p95_ms",
+          "device.idle_unoffered_share", "device.idle_with_work_share",
+          "device.idle_per_prefill_ms", "engine.compiles_in_window")
 TINY_ATOL = 5e-3
 
 
@@ -129,10 +133,10 @@ def test_the_manifest_has_the_configuration_the_cell_and_the_metrics():
     assert reported == {"serve_out_tokens_per_s", "setup_s"}
     layer = {m["name"]: m for m in
              manifest.metrics_of_cell(bench, "per_layer", CELL)}
-    assert set(layer) == set(MINE) | set(SHARED)
+    assert set(MINE) | set(SHARED) <= set(layer)
     for name in MINE:
         m = manifest.find(bench["per_layer"], name, "metric")
-        assert m["workloads"] == [CELL] \
+        assert CELL in m["workloads"] \
             and m["moves"] == "serve_out_tokens_per_s"
         spec = manifest.metric_spec("per_layer", name)
         assert (spec["layer"], spec["unit"], spec["better"],

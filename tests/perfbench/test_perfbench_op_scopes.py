@@ -290,7 +290,10 @@ def test_new_metrics_are_in_the_manifest_by_name_with_their_scopes():
         assert spec["moves"] == entry["moves"]
         train = spec["reducer"] == "scope_ms_per_step"
         assert train or spec["reducer"] == "scope_ms_in_program_span"
-        assert set(entry["workloads"]) <= set(TRAIN if train else SERVE)
+        # whichever cells list it, now or later, are cells of its job
+        kinds = {manifest.load_cell(bench, w)["traffic_file"]["kind"]
+                 for w in entry["workloads"]}
+        assert kinds == {"train" if train else "serve"}
         assert entry["moves"] == ("train_tokens_per_s_per_chip" if train
                                   else "serve_itl_p50_ms")
         assert spec["params"]["program"] == ("train.step" if train
@@ -304,11 +307,12 @@ def test_new_metrics_are_in_the_manifest_by_name_with_their_scopes():
                 assert scope in spec["what"], (name, scope)
     for name in ("train_program.optimizer_ms", "train_program.head_loss_ms",
                  "train_program.attn_scope_ms", "train_program.unscoped_ms"):
-        assert by_name[name]["workloads"] == TRAIN
-    assert by_name["train_program.mlp_ms"]["workloads"] == TRAIN[:1]
+        assert set(TRAIN) <= set(by_name[name]["workloads"])
+    assert set(TRAIN[:1]) <= set(by_name["train_program.mlp_ms"]["workloads"])
     for name in ("moe.dispatch_ms", "moe.experts_scope_ms"):
-        assert by_name[name]["workloads"] == TRAIN[1:]
+        assert set(TRAIN[1:]) <= set(by_name[name]["workloads"])
+        assert TRAIN[0] not in by_name[name]["workloads"]   # XL routes nothing
     for name in ("model_step.decode_mlp_ms", "model_step.decode_head_ms",
                  "model_step.decode_attn_ms"):
-        assert by_name[name]["workloads"] == SERVE
-    assert by_name["ssm.decode_mixer_ms"]["workloads"] == SERVE[1:]
+        assert set(SERVE) <= set(by_name[name]["workloads"])
+    assert set(SERVE[1:]) <= set(by_name["ssm.decode_mixer_ms"]["workloads"])

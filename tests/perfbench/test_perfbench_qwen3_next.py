@@ -23,11 +23,14 @@ CATALOG = Path("/opt/skills/guides/model-configs/architectures.jsonl")
 REDUCED = ["num_hidden_layers", "num_experts", "vocab_size"]
 NEW_METRICS = ("gdn.mixer_ms", "gdn.rule_ms", "gdn.conv_ms",
                "gdn.rule_peak_share", "attn.gated_flash_ms",
-               "attn.gated_flash_peak_share", "moe.train_share_dispatch_ms",
-               "moe.train_share_experts_ms",
+               "attn.gated_flash_peak_share", "moe.dispatch_ms",
+               "moe.experts_scope_ms",
                "moe.train_share_expert_peak_share")
 SHARED_METRICS = ("train_program.step_ms", "train_program.mfu",
-                  "kernels.custom_call_ms", "device.train_idle_share")
+                  "kernels.custom_call_ms", "device.train_idle_share",
+                  # PR 36's scopes, listed since PR 63
+                  "train_program.optimizer_ms", "train_program.head_loss_ms",
+                  "train_program.attn_scope_ms", "train_program.unscoped_ms")
 
 
 def _config():
@@ -246,7 +249,7 @@ def test_the_manifest_has_the_configuration_the_cell_and_the_metrics():
     # by name, wherever later PRs' entries put them: each once
     assert sorted(m["name"] for m in mine) == sorted(NEW_METRICS)
     for m in mine:
-        assert m["workloads"] == [CELL]
+        assert CELL in m["workloads"]
         assert m["source"] == "device_trace"
         assert m["moves"] == "train_tokens_per_s_per_chip"
         spec = manifest.metric_spec("per_layer", m["name"])
@@ -268,11 +271,15 @@ def test_the_manifest_has_the_configuration_the_cell_and_the_metrics():
     (config,) = [c for c in bench["configs"] if c["name"] == CONFIG]
     assert config["reduced"] == REDUCED
     assert config["source"] == _config()["source"]
-    # the metrics whose lists a test pins keep their cells
-    for m in bench["per_layer"]:
-        if m["name"] in ("moe.dispatch_ms", "moe.experts_scope_ms",
-                         "train_program.optimizer_ms"):
-            assert CELL not in m["workloads"]
+    # since PR 63 no test pins a metric's list of cells: the two scope
+    # metrics of the routed training cells list this one too, once, and
+    # their copies under names of its own are gone
+    for name in ("moe.dispatch_ms", "moe.experts_scope_ms"):
+        m = manifest.find(bench["per_layer"], name, "metric")
+        assert m["workloads"].count(CELL) == 1
+    assert not [m for m in bench["per_layer"]
+                if m["name"].startswith("moe.train_share_")
+                and m["name"] != "moe.train_share_expert_peak_share"]
 
 
 def _joined(events):
@@ -320,8 +327,8 @@ def test_the_new_metrics_read_their_scopes_and_kernels_and_no_other(
     assert read("gdn.rule_ms") == pytest.approx(120.0)
     assert read("gdn.conv_ms") == pytest.approx(30.0)
     assert read("gdn.mixer_ms") == pytest.approx(220.0)
-    assert read("moe.train_share_dispatch_ms") == pytest.approx(60.0)
-    assert read("moe.train_share_experts_ms") == pytest.approx(70.0)
+    assert read("moe.dispatch_ms") == pytest.approx(60.0)
+    assert read("moe.experts_scope_ms") == pytest.approx(70.0)
     assert read("attn.gated_flash_ms") == pytest.approx(30.0)
     sizes = fam.sizes(_config())
     rule = flops_qwen3_next.rule_flops_per_token(sizes) * 16384

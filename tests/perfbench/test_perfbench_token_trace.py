@@ -369,7 +369,7 @@ def test_the_seven_metrics_are_in_the_manifest_for_their_cells():
         "per_layer", "device.idle_per_prefill_ms")["reducer"]
     assert chunks["params"]["per"] == "llm.prefill.chunk"
     for name, m in mine.items():
-        assert m["workloads"] == CELLS[name]
+        assert set(CELLS[name]) <= set(m["workloads"])
         assert m["better"] == "lower"
         assert m["moves"] == "serve_out_tokens_per_s"
         device = name.startswith("device.")
