@@ -7,6 +7,17 @@ pre-RMSNorm, rotary position embeddings, grouped-query attention, SwiGLU
 MLP, untied output head.  Layout follows gpt2.py: stacked per-layer params
 + ``lax.scan`` (pipeline-axis ready), bf16 activations / f32 params,
 attention through ``ops.attention.causal_attention``, which chooses.
+
+Three stacks live here, told apart by the configuration alone: the dense
+Llama block; OLMoE's (``qk_norm`` over the whole projection, ``n_experts``
+dropless experts weighed by their probabilities as they are); and the
+Qwen3-MoE stack that SDAR-30B-A3B generates with by diffusion over blocks
+(``head_size``: heads of their own size, ``H x D != E``; ``qk_norm_heads``:
+the norm a head; ``norm_topk``; ``block_length`` / ``mask_token_id``: the
+block-causal mask in training and prefill, and ``block_stepping``, which
+tells the serving stack to step a sequence a block of positions at a time:
+``_forward_decode_blocks``).  The serving forwards keep the experts outside
+the layer scan's slices (``_split_experts``).
 """
 
 from __future__ import annotations
@@ -54,8 +65,14 @@ class LlamaConfig:
     attn_impl: str = "auto"      # auto | dense | flash | ring | ulysses
     context_axis: Optional[str] = None
     # RMSNorm over the whole projected q and k (own scales), before the
-    # split into heads and before RoPE (OLMoE)
+    # split into heads and before RoPE (OLMoE); with ``qk_norm_heads`` a
+    # head at a time over its head_dim lanes, one scale for all heads
+    # (Qwen3)
     qk_norm: bool = False
+    qk_norm_heads: bool = False
+    # a head's size where it is not n_embd / n_head (0: it is): q and wo
+    # are then n_head * head_size wide beside n_embd
+    head_size: int = 0
     # > 0: the FFN is n_experts SwiGLU experts of width ffn_dim,
     # experts_per_token of them a token, dropless (ops/moe.py); the loss
     # gains the two router terms at these coefficients
@@ -63,12 +80,30 @@ class LlamaConfig:
     experts_per_token: int = 0
     router_aux_coef: float = 0.0
     router_z_coef: float = 0.0
+    # the chosen experts' weights over their own sum (ops/moe.route_softmax)
+    norm_topk: bool = False
     # False: wo and w_down start at 0.02 like every other matrix
     scaled_residual_init: bool = True
+    # every matrix normal at 1 / sqrt(fan_in) (the experts' w_down sqrt(k)
+    # more), so that random weights give logits of unit spread: a served
+    # configuration whose check compares logits (perfbench/SDAR.md)
+    fan_in_init: bool = False
+    # > 1: generation by diffusion over blocks of this many positions.  The
+    # mask is block-causal (a position sees every earlier block whole and
+    # its own in both directions, blocks counted from position 0), an
+    # undecided position is fed ``mask_token_id``, and the serving stack
+    # steps a sequence a block at a time (``block_stepping``).  0: a model
+    # that steps by tokens under the causal mask
+    block_length: int = 0
+    mask_token_id: Optional[int] = None
+    # denoise passes a block (0: block_length): each fixes the
+    # block_length / denoising_steps undecided positions of highest
+    # confidence
+    denoising_steps: int = 0
 
     @property
     def head_dim(self) -> int:
-        return self.n_embd // self.n_head
+        return self.head_size or self.n_embd // self.n_head
 
 
 def llama2_7b() -> LlamaConfig:
@@ -95,8 +130,38 @@ def tiny_moe(vocab: int = 200, seq: int = 48) -> LlamaConfig:
                        scaled_residual_init=False)
 
 
+def tiny_sdar(vocab: int = 200, seq: int = 96) -> LlamaConfig:
+    """SDAR's (Qwen3-MoE's) block at a test's size: heads of 16 beside a
+    hidden size of 32 (H x D != E), a QK-norm a head, 8 experts, 2 a token
+    with their weights renormalised, blocks of 4 positions."""
+    return LlamaConfig(vocab_size=vocab, max_positions=seq, n_embd=32,
+                       n_layer=2, n_head=4, n_kv_head=2, head_size=16,
+                       ffn_dim=16, rope_theta=1e6, rms_eps=1e-6,
+                       qk_norm=True, qk_norm_heads=True, n_experts=8,
+                       experts_per_token=2, norm_topk=True,
+                       scaled_residual_init=False, fan_in_init=True,
+                       block_length=4, mask_token_id=vocab - 1,
+                       denoising_steps=4)
+
+
+def sdar_30b_a3b_l6() -> LlamaConfig:
+    """SDAR-30B-A3B-Chat's widths with 6 of its 48 layers (one of eight
+    pipeline stages; perfbench/configs/sdar-30b-a3b-chat.json), served in
+    bf16 (``param_dtype``: the tree is drawn in its serving type)."""
+    return LlamaConfig(vocab_size=151936, max_positions=32768, n_embd=2048,
+                       n_layer=6, n_head=32, n_kv_head=4, head_size=128,
+                       ffn_dim=768, rope_theta=1e6, rms_eps=1e-6,
+                       qk_norm=True, qk_norm_heads=True, n_experts=128,
+                       experts_per_token=8, norm_topk=True,
+                       scaled_residual_init=False, fan_in_init=True,
+                       param_dtype=jnp.bfloat16, remat=False,
+                       block_length=4, mask_token_id=151669,
+                       denoising_steps=4)
+
+
 PRESETS = {"llama2-7b": llama2_7b, "llama3-8b": llama3_8b, "tiny": tiny,
-           "tiny-moe": tiny_moe}
+           "tiny-moe": tiny_moe, "tiny-sdar": tiny_sdar,
+           "sdar-30b-a3b-l6": sdar_30b_a3b_l6}
 
 
 # ------------------------------------------------------------------- params
@@ -105,39 +170,50 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Params:
     on an n_experts axis behind it), each drawn in one call."""
     pd = cfg.param_dtype
     E, L, F = cfg.n_embd, cfg.n_layer, cfg.ffn_dim
+    q_dim = cfg.n_head * cfg.head_dim        # E, but for a head_size
     kv_dim = cfg.n_kv_head * cfg.head_dim
     k = iter(jax.random.split(rng, 10))
     out_scale = 0.02 / math.sqrt(2 * L) if cfg.scaled_residual_init else 0.02
+    fan_in = cfg.fan_in_init
 
-    def stacked(*shape, scale=0.02):
+    def stacked(*shape, scale=0.02, gain=1.0):
+        if fan_in:
+            scale = gain / math.sqrt(shape[-2])
         return normal_init(next(k), (L, *shape), pd, scale)
 
     blocks = {
         "attn_norm": {"scale": jnp.ones((L, E), pd)},
-        "wq": {"kernel": stacked(E, E)},
+        "wq": {"kernel": stacked(E, q_dim)},
         "wk": {"kernel": stacked(E, kv_dim)},
         "wv": {"kernel": stacked(E, kv_dim)},
-        "wo": {"kernel": stacked(E, E, scale=out_scale)},
+        "wo": {"kernel": stacked(q_dim, E, scale=out_scale)},
         "mlp_norm": {"scale": jnp.ones((L, E), pd)},
     }
     if cfg.qk_norm:
-        blocks["q_norm"] = {"scale": jnp.ones((L, E), pd)}
-        blocks["k_norm"] = {"scale": jnp.ones((L, kv_dim), pd)}
+        per = cfg.head_dim if cfg.qk_norm_heads else 0
+        blocks["q_norm"] = {"scale": jnp.ones((L, per or q_dim), pd)}
+        blocks["k_norm"] = {"scale": jnp.ones((L, per or kv_dim), pd)}
     if cfg.n_experts:
         X = cfg.n_experts
         blocks["router"] = {"kernel": stacked(E, X)}
+        # under fan_in_init sqrt(k) more: k experts weighed about 1 / k
+        # each then add what one ffn adds
         blocks["experts"] = {"w_gate": stacked(X, E, F),
                              "w_up": stacked(X, E, F),
-                             "w_down": stacked(X, F, E, scale=out_scale)}
+                             "w_down": stacked(
+                                 X, F, E, scale=out_scale,
+                                 gain=math.sqrt(cfg.experts_per_token))}
     else:
         blocks["w_gate"] = {"kernel": stacked(E, F)}
         blocks["w_up"] = {"kernel": stacked(E, F)}
         blocks["w_down"] = {"kernel": stacked(F, E, scale=out_scale)}
+    table = 1.0 / math.sqrt(E) if fan_in else 0.02
     return {
-        "wte": normal_init(next(k), (cfg.vocab_size, E), pd),
+        "wte": normal_init(next(k), (cfg.vocab_size, E), pd, table),
         "blocks": blocks,
         "norm_f": {"scale": jnp.ones((E,), pd)},
-        "lm_head": {"kernel": normal_init(next(k), (E, cfg.vocab_size), pd)},
+        "lm_head": {"kernel": normal_init(next(k), (E, cfg.vocab_size), pd,
+                                          table)},
     }
 
 
@@ -183,31 +259,42 @@ def _qkv(h: jax.Array, lp: Params, cfg: LlamaConfig):
         q = h @ lp["wq"]["kernel"].astype(cfg.dtype)
         k = h @ lp["wk"]["kernel"].astype(cfg.dtype)
         v = h @ lp["wv"]["kernel"].astype(cfg.dtype)
+    lead = h.shape[:-1]
+    if cfg.qk_norm and cfg.qk_norm_heads:
+        # a head at a time, over its D lanes, one scale for all heads
+        with jax.named_scope("qk_norm"):
+            q = _rms_norm(q.reshape(*lead, H, D), lp["q_norm"]["scale"],
+                          cfg.rms_eps)
+            k = _rms_norm(k.reshape(*lead, KV, D), lp["k_norm"]["scale"],
+                          cfg.rms_eps)
+        return q, k, v.reshape(*lead, KV, D)
     if cfg.qk_norm:
         with jax.named_scope("qk_norm"):
             q = _rms_norm(q, lp["q_norm"]["scale"], cfg.rms_eps)
             k = _rms_norm(k, lp["k_norm"]["scale"], cfg.rms_eps)
-    lead = h.shape[:-1]
     return (q.reshape(*lead, H, D), k.reshape(*lead, KV, D),
             v.reshape(*lead, KV, D))
 
 
-def _ffn(h: jax.Array, lp: Params, cfg: LlamaConfig, choices: bool = False,
-         live: Optional[jax.Array] = None):
+def _ffn(h: jax.Array, lp: Params, cfg: LlamaConfig,
+         served: Optional[tuple] = None, live: Optional[jax.Array] = None):
     """The block's feed-forward on normed hidden states (..., E): one
     SwiGLU, or the dropless experts.  Returns (out, RouterStats | None);
-    training, prefill and decode all come through here.  ``choices`` (a
-    serving step of a model with experts): the second result is the
-    chosen expert ids, (rows, k) int32, from the routing that made
-    ``out``; ``live`` (rows,) bool: a decode step's rows that are some
-    sequence's (``ops/moe.choice_of_live_rows``)."""
+    training, prefill and decode all come through here.  ``served`` (a
+    serving forward of a model with experts): (the experts of EVERY layer
+    as one run of groups, :func:`_split_experts`; this layer's index,
+    traced), and the second result is then the chosen expert ids, (rows,
+    k) int32, from the routing that made ``out``; ``live`` (rows,) bool: a
+    decode step's rows that are some sequence's
+    (``ops/moe.choice_of_live_rows``)."""
     if cfg.n_experts:
         from ray_tpu.ops.moe import dropless_moe_ffn
-        ex = lp["experts"]
+        ex, layer = served or (lp["experts"], None)
         out, *told = dropless_moe_ffn(
             h.reshape(-1, h.shape[-1]), lp["router"]["kernel"],
             ex["w_gate"], ex["w_up"], ex["w_down"], k=cfg.experts_per_token,
-            choices=choices, live=live)
+            norm_topk=cfg.norm_topk, choices=served is not None, live=live,
+            stack_at=layer)
         return out.reshape(h.shape), told[-1]        # the stats, or the ids
     with jax.named_scope("mlp"):
         gate = jax.nn.silu(h @ lp["w_gate"]["kernel"].astype(cfg.dtype))
@@ -215,8 +302,32 @@ def _ffn(h: jax.Array, lp: Params, cfg: LlamaConfig, choices: bool = False,
         return (gate * up) @ lp["w_down"]["kernel"].astype(cfg.dtype), None
 
 
+def _split_experts(blocks: Params, cfg: LlamaConfig):
+    """The stacked block leaves -> (what a serving forward's layer scan
+    slices a layer of: every leaf but the experts'; the experts' leaves
+    whole, ``(layers x experts, ...)``, or None for a dense model).
+
+    The grouped matmuls are kernels, and a kernel's operand is a buffer: a
+    layer's experts sliced out of their stack by the scan are copied whole
+    for each of the three (3 x 1.2 GB a layer at SDAR's widths, 22 of a
+    40 ms pass: seen in the cell's first trace, PERF.md PR 64; LFM2 met the
+    same in PR 39).  So in the serving forwards the experts stay outside the
+    scan's slices: all layers' experts are ONE run of groups, a layer names
+    its own as groups ``layer x X .. layer x X + X - 1`` and the other
+    layers' groups are empty (``ops/moe.dropless_moe_ffn``'s ``stack_at``).
+    Training scans the stack as it did."""
+    if not cfg.n_experts:
+        return blocks, None
+    sliced = {k: v for k, v in blocks.items() if k != "experts"}
+    # cast as stored (a no-op on a serving tree), then one run of groups
+    whole = {k: w.astype(cfg.dtype).reshape(-1, *w.shape[2:])
+             for k, w in blocks["experts"].items()}
+    return sliced, whole
+
+
 def _block(x: jax.Array, lp: Params, cfg: LlamaConfig,
-           collect_kv: bool = False, choices: bool = False):
+           collect_kv: bool = False, choices: bool = False,
+           served: Optional[tuple] = None):
     """One decoder block -> (out, RouterStats | None); with ``collect_kv``
     -> (out, (k, v)), post-RoPE and pre-GQA-expand: the SAME body serves
     training and the serving engine's prefill cache fill, so the paths
@@ -232,13 +343,17 @@ def _block(x: jax.Array, lp: Params, cfg: LlamaConfig,
     ke, ve = _gqa_expand(k, H), _gqa_expand(v, H)
     with jax.named_scope("attn"):
         from ray_tpu.ops.attention import causal_attention
+        # under a block length the mask is block-causal, in training and
+        # in prefill alike
+        masked = {"block": cfg.block_length} if cfg.block_length > 1 else {}
         a = causal_attention(q, ke, ve, impl=cfg.attn_impl,
-                             context_axis=cfg.context_axis).reshape(B, T, E)
+                             context_axis=cfg.context_axis,
+                             **masked).reshape(B, T, H * cfg.head_dim)
     with jax.named_scope("attn_out"):
         x = x + a @ lp["wo"]["kernel"].astype(cfg.dtype)
     with jax.named_scope("ln_2"):
         h = _rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
-    f, stats = _ffn(h, lp, cfg, choices)
+    f, stats = _ffn(h, lp, cfg, served)
     out = x + f
     if collect_kv:
         return out, ((k, v, stats) if choices else (k, v))
@@ -267,6 +382,26 @@ def forward(params: Params, tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:
 
 
 # -------------------------------------------------- inference (KV cache)
+def block_stepping(cfg: LlamaConfig) -> Optional[Dict[str, Any]]:
+    """How the serving stack steps a sequence of a preset that generates by
+    diffusion over blocks (``serve/llm/model_runner.py``, ``engine.py``):
+    ``block`` positions a decode pass, undecided ones fed ``mask_id``;
+    ``per_pass`` positions fixed a pass, those of highest confidence.
+    None: the preset steps by tokens."""
+    if cfg.block_length <= 1:
+        return None
+    if cfg.mask_token_id is None or not 0 <= cfg.mask_token_id < \
+            cfg.vocab_size:
+        raise ValueError(f"blocks of {cfg.block_length} positions need a "
+                         f"mask_token_id inside the {cfg.vocab_size} rows")
+    steps = cfg.denoising_steps or cfg.block_length
+    if cfg.block_length % steps:
+        raise ValueError(f"{steps} denoising steps do not divide a block "
+                         f"of {cfg.block_length}")
+    return {"block": cfg.block_length, "mask_id": cfg.mask_token_id,
+            "per_pass": cfg.block_length // steps}
+
+
 def routed_layers(cfg: LlamaConfig) -> Optional[Dict[str, int]]:
     """What the serving step programs of a preset with experts hand over
     beside the logits (``serve/llm/model_runner.py``): the expert ids each
@@ -287,14 +422,33 @@ def forward_prefill(params: Params, tokens: jax.Array, cfg: LlamaConfig,
     chosen, (L, B x T, k) int32.
 
     ``last_pos`` (traced scalar): logits only at that position as
-    (B, V); None returns the full (B, T, V) — see gpt2.forward_prefill."""
+    (B, V); None returns the full (B, T, V) — see gpt2.forward_prefill.
+
+    A preset with a ``block_length`` runs under the block-causal mask
+    (``_block``); its prompts come in whole blocks and ``last_pos`` is the
+    last position of one, whose block's logits come back, (B, block, V)."""
     x = _embed(params, tokens, cfg)
+    sliced, experts = _split_experts(params["blocks"], cfg)
 
-    def body(carry, lp):
-        return _block(carry, lp, cfg, collect_kv=True, choices=choices)
+    def body(carry, xs):
+        lp, layer = xs
+        return _block(carry, lp, cfg, collect_kv=True, choices=choices,
+                      served=(experts, layer))
 
-    x, kept = lax.scan(body, x, params["blocks"])
+    if experts is None:
+        # a dense model: the scan it had, over the stack alone
+        x, kept = lax.scan(lambda carry, lp: _block(
+            carry, lp, cfg, collect_kv=True, choices=choices),
+            x, params["blocks"])
+    else:
+        x, kept = lax.scan(body, x, (sliced, jnp.arange(cfg.n_layer)))
     x = _final_norm(params, x, cfg)
+    if last_pos is not None and cfg.block_length > 1:
+        # the logits of the whole block that ``last_pos`` ends, (B, block,
+        # V): each position's own token is decided there
+        x = lax.dynamic_slice_in_dim(x, last_pos - (cfg.block_length - 1),
+                                     cfg.block_length, axis=1)
+        return _head(params, x, cfg), *kept
     if last_pos is not None:
         x = lax.dynamic_slice_in_dim(x, last_pos, 1, axis=1)
     return (_head(params, x, cfg, only_position=last_pos is not None),
@@ -313,8 +467,12 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
     and, with ``choices``, the experts chosen, (L, B, k) int32 (``live``
     (B,) bool: the rows that are not padding, see ``_ffn``)."""
     from ray_tpu.ops.paged_attention import paged_attention_decode
+    if tokens.ndim == 2:
+        return _forward_decode_blocks(params, tokens, positions, kv_pool,
+                                      block_tables, ctx_lens, cfg, choices,
+                                      live)
     B = tokens.shape[0]
-    E = cfg.n_embd
+    E = cfg.n_head * cfg.head_dim
     x = _embed(params, tokens, cfg)                             # (B, E)
 
     def body(carry, xs):
@@ -332,10 +490,59 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
             x = x + a @ lp["wo"]["kernel"].astype(cfg.dtype)
         with jax.named_scope("ln_2"):
             h = _rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
-        f, ids = _ffn(h, lp, cfg, choices, live)
+        f, ids = _ffn(h, lp, cfg, (experts, layer), live)
         return x + f, ((k, v, ids) if choices else (k, v))
 
-    x, kept = lax.scan(body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
+    sliced, experts = _split_experts(params["blocks"], cfg)
+    x, kept = lax.scan(body, x, (sliced, jnp.arange(cfg.n_layer)))
+    return (_head(params, _final_norm(params, x, cfg), cfg), *kept)
+
+
+def _forward_decode_blocks(params, tokens, positions, kv_pool, block_tables,
+                           ctx_lens, cfg, choices, live):
+    """One pass over a block of positions a row (a preset with a
+    ``block_length``): tokens (R, B), the row's block as fed, a mask id
+    where a position is undecided; positions (R,) = ctx_lens, the block's
+    first position, the committed positions before it being what the pool
+    holds.  The block's B queries read the row's pages once and see all B
+    new keys and values, in both directions
+    (``ops/paged_attention.paged_attention_decode``, the block form); the
+    pool is read-only here, and the block's K/V is written by whoever calls,
+    for the rows whose pass commits.
+
+    Returns (logits (R, B, V) f32, new_k, new_v (L, R, B, KV, D)) and, with
+    ``choices``, the experts chosen, (L, R x B, k) int32 (``live`` (R,))."""
+    from ray_tpu.ops.paged_attention import paged_attention_decode
+    R, B = tokens.shape
+    x = _embed(params, tokens, cfg)                             # (R, B, E)
+    at = (positions[:, None] + jnp.arange(B)).reshape(-1)       # (R B,)
+    if live is not None:
+        live = jnp.repeat(live, B)
+
+    def rope(a):
+        flat = a.reshape(R * B, *a.shape[2:])
+        return _rope_at(flat, at, cfg.rope_theta).reshape(a.shape)
+
+    def body(carry, xs):
+        x = carry
+        lp, layer = xs
+        with jax.named_scope("ln_1"):
+            h = _rms_norm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
+        q, k, v = _qkv(h, lp, cfg)
+        with jax.named_scope("rope"):
+            q, k = rope(q), rope(k)
+        with jax.named_scope("attn_block"):
+            a = paged_attention_decode(q, kv_pool, layer, block_tables,
+                                       ctx_lens, k, v).reshape(R, B, -1)
+        with jax.named_scope("attn_out"):
+            x = x + a @ lp["wo"]["kernel"].astype(cfg.dtype)
+        with jax.named_scope("ln_2"):
+            h = _rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
+        f, ids = _ffn(h, lp, cfg, (experts, layer), live)
+        return x + f, ((k, v, ids) if choices else (k, v))
+
+    sliced, experts = _split_experts(params["blocks"], cfg)
+    x, kept = lax.scan(body, x, (sliced, jnp.arange(cfg.n_layer)))
     return (_head(params, _final_norm(params, x, cfg), cfg), *kept)
 
 

@@ -76,20 +76,27 @@ def causal_mask(q_pos: jax.Array, k_pos: jax.Array) -> jax.Array:
 
 def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     *, causal: bool = True,
-                    q_offset: int | jax.Array = 0) -> jax.Array:
+                    q_offset: int | jax.Array = 0,
+                    block: int = 1) -> jax.Array:
     """Plain O(T²) attention (B,T,H,D); the XLA-fused short-sequence path.
     ``v`` may be (B,T,H,Dv) with Dv != D: the scale is 1/sqrt(D), the
     keys' width, and the output is Dv wide.
 
     ``q_offset`` shifts query positions for causal masking when q is a
     chunk of a longer sequence (used by decode / chunked prefill).
+    ``block`` > 1: the mask is block-causal, a position sees every earlier
+    block of that many positions whole and its own in both directions
+    (``k_pos // block <= q_pos // block``); 1 is the causal mask.
     """
     scale = 1.0 / math.sqrt(q.shape[-1])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     if causal:
         q_pos = q_offset + jnp.arange(q.shape[1])
-        mask = causal_mask(q_pos, jnp.arange(k.shape[1]))
+        k_pos = jnp.arange(k.shape[1])
+        if block > 1:
+            q_pos, k_pos = q_pos // block, k_pos // block
+        mask = causal_mask(q_pos, k_pos)
         logits = jnp.where(mask[None, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -152,7 +159,8 @@ def unsplit_causal_attention(qkv: jax.Array, n_head: int) -> jax.Array:
 
 def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                      impl: str = "auto",
-                     context_axis: Optional[str] = None) -> jax.Array:
+                     context_axis: Optional[str] = None,
+                     block: int = 1) -> jax.Array:
     """Causal self-attention over (B, T, H, D) for training and prefill;
     values may be narrower than keys, which the flash kernel and the dense
     path take as they are, with no padding.  (A caller with a rotary key
@@ -162,10 +170,20 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kernel where ``flash_runs`` says so, XLA's dense attention
     elsewhere), ``dense``, ``flash``, or one of the context-parallel
     schedules ``ring`` / ``ulysses`` over the ambient mesh's
-    ``context_axis`` (dense where the mesh does not split that axis)."""
+    ``context_axis`` (dense where the mesh does not split that axis).
+
+    ``block`` > 1: the block-causal mask of a model that generates by
+    diffusion over blocks (``dense_attention``); the flash kernel masks its
+    diagonal tile by blocks, and the context-parallel schedules have no
+    such mask."""
     if flash_runs(q.shape[1], impl):
         from ray_tpu.ops.flash_attention import flash_attention
-        return flash_attention(q, k, v, True)
+        return flash_attention(q, k, v, block if block > 1 else True)
+    if block > 1:
+        if impl in ("ring", "ulysses"):
+            raise NotImplementedError(
+                f"attn_impl {impl!r} has no block-causal mask")
+        return dense_attention(q, k, v, causal=True, block=block)
     if impl in ("ring", "ulysses"):
         from ray_tpu.parallel import mesh as mesh_lib
         axis = context_axis or "context"

@@ -84,19 +84,36 @@ def _scores(qs, ks):
     return s
 
 
+def _mask_span(causal, tile: int) -> int:
+    """``causal`` as the kernels take it: False, True (the causal mask), or
+    an int > 1, the block-causal mask of a model that generates by diffusion
+    over blocks: a position sees every earlier block of that many positions
+    whole and its own in both directions.  The span divides the tile, so
+    only the diagonal tile's mask differs from the causal one; 1 (True) is
+    the causal program to the bit."""
+    span = int(causal)
+    if span > 1 and tile % span:
+        raise ValueError(f"a mask by blocks of {span} positions needs "
+                         f"tiles of whole blocks, not {tile}")
+    return span
+
+
 def _fwd_tile(qs, ks, v, carry, s_scale, diagonal):
     """One (q-block, k-block) tile of the forward's online softmax on
     loaded operands: -> the new (acc, m, l).  ``diagonal``: None for a
     tile strictly under the diagonal, all of it visible, else ``(qi,
-    block_q, j, block_k)``, the tile's place, for the causal mask."""
+    block_q, j, block_k, span)``, the tile's place, for the causal mask:
+    ``span`` > 1 masks by blocks of that many positions (``_mask_span``)."""
     acc, m, l = carry
     s = _scores(qs, ks) * s_scale
     if diagonal is not None:
-        qi, block_q, j, block_k = diagonal
+        qi, block_q, j, block_k, span = diagonal
         q_pos = qi * block_q + lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0)
         k_pos = j * block_k + lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
+        if span > 1:
+            q_pos, k_pos = q_pos // span, k_pos // span
         s = jnp.where(q_pos >= k_pos, s, NEG_INF)
     m_new = jnp.maximum(m, s.max(axis=-1))
     p = jnp.exp2(s - m_new[:, None])
@@ -122,12 +139,13 @@ def _flash_kernel(*refs, parts: int, block_q: int, block_k: int,
     qs = [q_ref[0] for q_ref in q_refs]               # (block_q, D) bf16
     Dv = v_ref.shape[-1]          # values may be narrower than keys (MLA)
     s_scale = scale * LOG2E
+    span = _mask_span(causal, block_k)
 
     def tile(j, carry, masked):
         ks = [k_ref[0, pl.ds(j * block_k, block_k), :] for k_ref in k_refs]
         v = v_ref[0, pl.ds(j * block_k, block_k), :]
         return _fwd_tile(qs, ks, v, carry, s_scale,
-                         (qi, block_q, j, block_k) if masked else None)
+                         (qi, block_q, j, block_k, span) if masked else None)
 
     acc0 = jnp.zeros((block_q, Dv), jnp.float32)
     m0 = jnp.full((block_q,), NEG_INF)
@@ -155,15 +173,17 @@ def _bwd_tile(ks, ks_scaled, v, qs, do, lse, delta, carry, dq_accs, rows,
     """One (k-block, q-block) tile of the backward, keys-down, on loaded
     operands: -> the new (dks, dv); dq's share is added to ``dq_accs`` at
     ``rows``.  ``diagonal``: None, or the tile's place ``(kj, block_k, i,
-    block_q)`` for the causal mask."""
+    block_q, span)`` for the causal mask (``_fwd_tile``)."""
     dks, dv = carry
     sT = _scores(ks, qs) * s_scale        # (block_k, block_q)
     if diagonal is not None:
-        kj, block_k, i, block_q = diagonal
+        kj, block_k, i, block_q, span = diagonal
         k_pos = kj * block_k + lax.broadcasted_iota(
             jnp.int32, (block_k, block_q), 0)
         q_pos = i * block_q + lax.broadcasted_iota(
             jnp.int32, (block_k, block_q), 1)
+        if span > 1:
+            q_pos, k_pos = q_pos // span, k_pos // span
         sT = jnp.where(q_pos >= k_pos, sT, NEG_INF)
     pT = jnp.exp2(sT - lse)
     dv = dv + jax.lax.dot_general(
@@ -242,6 +262,7 @@ def _bwd_kernel(*refs, parts: int, block_q: int, block_k: int, seq_len: int,
     ks_scaled = [(k.astype(jnp.float32) * scale).astype(k.dtype) for k in ks]
     Dv = v.shape[-1]
     s_scale = scale * LOG2E
+    span = _mask_span(causal, block_q)
 
     @pl.when(kj == 0)
     def _init_dq():
@@ -256,7 +277,7 @@ def _bwd_kernel(*refs, parts: int, block_q: int, block_k: int, seq_len: int,
         delta = delta_ref[0, :, rows]         # they are stored in
         return _bwd_tile(ks, ks_scaled, v, qs, do, lse, delta, carry,
                          dq_accs, rows, s_scale,
-                         (kj, block_k, i, block_q) if masked else None)
+                         (kj, block_k, i, block_q, span) if masked else None)
 
     dks0 = tuple(jnp.zeros(k.shape, jnp.float32) for k in ks)
     dv0 = jnp.zeros((block_k, Dv), jnp.float32)
@@ -616,7 +637,7 @@ def _flash_pairs_kernel(q_ref, k_ref, v_ref, o_ref, *lse_out, block: int,
         # lanes past E would meet q's zeros in the contraction: 0 x NaN
         k = _lanes_of(inside, k_ref[0, 0, rows, :])
         v = v_ref[0, 0, rows, :]      # its lanes past E reach only o's
-        diagonal = (qi, block, j, block) if masked else None
+        diagonal = (qi, block, j, block, 1) if masked else None
         return tuple(_fwd_tile((q_h,), (k,), v, c, s_scale, diagonal)
                      for q_h, c in zip(qs, carry))
 
@@ -682,7 +703,7 @@ def _flash_pairs_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         rows = pl.ds(i * block, block)
         q = _lanes_of(inside, q_ref[0, 0, rows, :])
         do = _lanes_of(inside, do_ref[0, rows, :])
-        diagonal = (kj, block, i, block) if masked else None
+        diagonal = (kj, block, i, block, 1) if masked else None
         return tuple(_bwd_tile(
             (ks[h],), (ks_scaled[h],), vs[h], (q,), do,
             lse_ref[0, 0, pl.ds(h, 1), rows], delta_acc[pl.ds(h, 1), rows],

@@ -817,7 +817,8 @@ def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
                      select_bias: Optional[jax.Array] = None,
                      weight_scale: float = 1.0, first_held: int = 0,
                      choices: bool = False,
-                     live: Optional[jax.Array] = None):
+                     live: Optional[jax.Array] = None,
+                     stack_at=None):
     """Token-choice SwiGLU experts with no capacity: every token is
     computed by each of its top-k experts that is held here, whatever the
     imbalance.
@@ -835,9 +836,17 @@ def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
     ``choices`` (a serving step): a third result, the expert ids (N, k)
     int32 that made ``y``; ``live`` (N,) bool with it: the rows that are
     some sequence's (``choice_of_live_rows``).
+    ``stack_at`` (a traced scalar, a serving forward): the weights are the
+    experts of a whole STACK of layers as one run of groups, (layers x E,
+    ...), and this layer's are groups ``stack_at x E .. stack_at x E + E -
+    1``; the other layers' groups are empty (a kernel's operand is a
+    buffer: a layer's experts sliced out of their stack by a scan would be
+    copied whole).  The stats are then this layer's, over its own E.
     """
     n = x.shape[0]
     num_experts, held = w_router.shape[-1], w_gate.shape[0]
+    if stack_at is not None:
+        held = num_experts
     if scoring not in ("softmax", "sigmoid"):
         raise ValueError(f"unknown scoring {scoring!r} "
                          "(expected softmax | sigmoid)")
@@ -851,9 +860,16 @@ def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
                                                 weight_scale)
     if live is not None:
         expert_idx = choice_of_live_rows(expert_idx, live)
-    y, group_sizes = dropless_experts(
-        x, expert_idx, weights, w_gate, w_up, w_down,
-        num_experts=num_experts, first_held=first_held)
+    if stack_at is None:
+        y, group_sizes = dropless_experts(
+            x, expert_idx, weights, w_gate, w_up, w_down,
+            num_experts=num_experts, first_held=first_held)
+    else:
+        y, group_sizes = dropless_experts(
+            x, expert_idx + stack_at * num_experts, weights, w_gate, w_up,
+            w_down, num_experts=w_gate.shape[0])
+        group_sizes = jax.lax.dynamic_slice_in_dim(
+            group_sizes, stack_at * num_experts, num_experts)
     chose = (expert_idx.astype(jnp.int32),) if choices else ()
     with jax.named_scope("router"):
         if scoring == "sigmoid" or held < num_experts:

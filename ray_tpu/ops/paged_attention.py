@@ -49,6 +49,17 @@ not read, and the cache has given their blocks back
 (``serve/llm/kv_cache.py``, pages of two kinds).  A static argument: a
 layer without one compiles to the walk it had.
 
+A block of positions.  A model that generates by diffusion over blocks
+(``models/llama.py``, ``block_length``) hands the call ``q (R, B, H, D)`` and
+``k_new, v_new (R, B, KV, D)``: the B positions of a row's open block.  Its
+B queries read the row's pages ONCE and see all B new keys and values, in
+both directions (the mask inside a block is full); the pool holds the
+committed positions before the block and is read-only here, as for one new
+token.  On a TPU ``_block_decode_kernel``: a (row, KV head) a grid step,
+the head's ``B x rep`` query rows against its own ``D`` lanes of a page;
+elsewhere ``_block_decode_gather``, its oracle.  The walks above are not
+touched by it.
+
 The pool's format, ``(L, 2, N, bs, F)`` (layer, K or V, block, position
 in the block, the position's ``KV * D`` features flat along the lanes and
 zero-padded to whole 128-lane tiles: 25 x 64 -> 1,664), belongs to its
@@ -477,6 +488,189 @@ def _listed_decode_kernel(q, kv_pool, layer, block_tables, ctx_lens, k_new,
         return out.reshape(b, h, d)
 
 
+# ------------------------------------------------------- a block of positions
+def _block_decode_gather(q, kv_pool, layer, block_tables, ctx_lens, k_new,
+                         v_new):
+    """Gather-then-mask for a block of B positions a row: the CPU path and
+    the block kernel's reference.  Every query of the block sees the row's
+    ``ctx_lens`` pooled positions and all B new ones."""
+    r, b, h, d = q.shape
+    kvh = k_new.shape[2]
+    rep, f32 = h // kvh, jnp.float32
+    with jax.named_scope("kv_layout"):
+        k_pool, v_pool = heads_apart(kv_pool[layer], kvh, d)
+    k_ctx = gather_kv(k_pool, block_tables)          # (R, T, KV, D)
+    v_ctx = gather_kv(v_pool, block_tables)
+    t = k_ctx.shape[1]
+    with jax.named_scope("paged_attention"):
+        qg = q.reshape(r, b, kvh, rep, d)
+        scale = 1.0 / math.sqrt(d)
+        pooled = jnp.einsum("rbgpd,rtgd->rbgpt", qg, k_ctx,
+                            preferred_element_type=f32) * scale
+        valid = jnp.arange(t)[None, :] < ctx_lens[:, None]      # (R, T)
+        pooled = jnp.where(valid[:, None, None, None, :], pooled, NEG_INF)
+        own = jnp.einsum("rbgpd,rcgd->rbgpc", qg, k_new,
+                         preferred_element_type=f32) * scale
+        probs = jax.nn.softmax(jnp.concatenate([pooled, own], -1), axis=-1)
+        out = jnp.einsum("rbgpt,rtgd->rbgpd", probs[..., :t],
+                         v_ctx.astype(f32)) \
+            + jnp.einsum("rbgpc,rcgd->rbgpd", probs[..., t:],
+                         v_new.astype(f32))
+        return out.reshape(r, b, h, d).astype(q.dtype)
+
+
+def _block_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_new_ref,
+                  v_new_ref, pool_hbm, o_ref, k_buf, v_buf, sems, m_ref,
+                  l_ref, acc_ref, *, head_dim, block):
+    """One (row, KV head) (grid step): the head's ``block x rep`` query rows
+    (q_ref (1, 1, Q, D), pre-scaled, position-major) against the row's
+    pages, a chunk at a time, each page's copy the head's own D lanes:
+    ``pool_hbm[layer, 0 / 1, table[j], :, g D : g D + D]``.  The block's own
+    ``block`` keys and values (k_new_ref, v_new_ref (1, 1, 8, D), the rows
+    past ``block`` padding) seed the running softmax: every query sees all
+    of them."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    r, g = pl.program_id(0), pl.program_id(1)
+    _, chunk, bs, d = k_buf.shape
+    t = chunk * bs
+    ctx = lens_ref[r]
+    layer = layer_ref[0]
+    n_blocks = pl.cdiv(ctx, bs)
+    n_chunks = pl.cdiv(n_blocks, chunk)
+    lanes = pl.ds(pl.multiple_of(g * head_dim, head_dim), head_dim)
+    hi = lax.Precision.HIGHEST      # float32 K/V stay float32 on the MXU
+
+    def copies(c, slot):
+        out = []
+        for i in range(chunk):
+            j = c * chunk + i
+            blk = tables_ref[r, jnp.minimum(j, tables_ref.shape[1] - 1)]
+            out.append((j < n_blocks, (
+                pltpu.make_async_copy(pool_hbm.at[layer, 0, blk, :, lanes],
+                                      k_buf.at[slot, i], sems.at[0, slot]),
+                pltpu.make_async_copy(pool_hbm.at[layer, 1, blk, :, lanes],
+                                      v_buf.at[slot, i], sems.at[1, slot]))))
+        return out
+
+    def start(c, slot):
+        for live, (ck, cv) in copies(c, slot):
+            @pl.when(live)
+            def _():
+                ck.start()
+                cv.start()
+
+    def wait(c, slot):
+        for live, (ck, cv) in copies(c, slot):
+            @pl.when(live)
+            def _():
+                ck.wait()
+                cv.wait()
+
+    @pl.when(n_chunks > 0)
+    def _():
+        start(0, 0)
+
+    # the block's own positions, one at a time on the VPU: a (Q, D) x
+    # (block, D) product is too narrow a matmul to ask of the MXU
+    q = q_ref[0, 0]                                             # (Q, D)
+    own = [jnp.sum(q * k_new_ref[0, 0, pl.ds(c, 1), :], axis=-1,
+                   keepdims=True) for c in range(block)]        # (Q, 1) each
+    m = functools.reduce(jnp.maximum, own)
+    p_own = [jnp.exp(s - m) for s in own]
+    m_ref[...] = m
+    l_ref[...] = functools.reduce(jnp.add, p_own)
+    acc_ref[...] = functools.reduce(jnp.add, [
+        p * v_new_ref[0, 0, pl.ds(c, 1), :] for c, p in enumerate(p_own)])
+
+    @pl.loop(0, n_chunks)
+    def _(c):
+        slot = c % 2
+
+        @pl.when(c + 1 < n_chunks)
+        def _():
+            start(c + 1, 1 - slot)
+
+        wait(c, slot)
+        k = k_buf[slot].reshape(t, d)
+        v = v_buf[slot].reshape(t, d)
+        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())), precision=hi,
+                            preferred_element_type=jnp.float32)  # (Q, T)
+        # what was not copied, and the last page's tail, may hold
+        # anything: select, never multiply by zero
+        s = jnp.where(
+            c * t + lax.broadcasted_iota(jnp.int32, (1, t), 1) < ctx, s,
+            NEG_INF)
+        v = jnp.where(
+            c * t + lax.broadcasted_iota(jnp.int32, (t, 1), 0) < ctx, v, 0.0)
+        m = m_ref[...]
+        m_next = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_next)
+        p = jnp.exp(s - m_next)
+        m_ref[...] = m_next
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p, v, precision=hi, preferred_element_type=jnp.float32)
+
+    o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def _block_decode_kernel(q, kv_pool, layer, block_tables, ctx_lens, k_new,
+                         v_new, *, interpret=False):
+    """The walk for a block of positions a row as one Pallas call over the
+    batch's (row, KV head) pairs: a row's pages are read once a pass for
+    all its B positions."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    r, b, h, d = q.shape
+    bs = kv_pool.shape[3]
+    kvh = k_new.shape[2]
+    rep, f32 = h // kvh, jnp.float32
+    rows = b * rep
+    chunk = max(1, min(_LIST_CHUNK_TOKENS // bs, block_tables.shape[1]))
+    with jax.named_scope("paged_attention"):
+        # the head's query rows, position-major: row (c, p) is position c
+        # of the block, member p of the KV head's group
+        qg = q.astype(f32).reshape(r, b, kvh, rep, d).transpose(0, 2, 1, 3, 4)
+        qg = qg.reshape(r, kvh, rows, d) * (1.0 / math.sqrt(d))
+
+        def own(x):                                   # (R, B, KV, D)
+            x = x.astype(f32).transpose(0, 2, 1, 3)
+            return jnp.pad(x, ((0, 0), (0, 0), (0, -b % 8), (0, 0)))
+        pair = lambda i, g, *prefetched: (i, g, 0, 0)          # noqa: E731
+        new_rows = b + -b % 8
+        out = pl.pallas_call(
+            functools.partial(_block_kernel, head_dim=d, block=b),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(r, kvh),
+                in_specs=[
+                    pl.BlockSpec((1, 1, rows, d), pair),
+                    pl.BlockSpec((1, 1, new_rows, d), pair),
+                    pl.BlockSpec((1, 1, new_rows, d), pair),
+                    pl.BlockSpec(memory_space=pl.ANY),
+                ],
+                out_specs=pl.BlockSpec((1, 1, rows, d), pair),
+                scratch_shapes=[
+                    pltpu.VMEM((2, chunk, bs, d), kv_pool.dtype),
+                    pltpu.VMEM((2, chunk, bs, d), kv_pool.dtype),
+                    pltpu.SemaphoreType.DMA((2, 2)),
+                    pltpu.VMEM((rows, 1), f32),
+                    pltpu.VMEM((rows, 1), f32),
+                    pltpu.VMEM((rows, d), f32),
+                ]),
+            out_shape=jax.ShapeDtypeStruct((r, kvh, rows, d), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")),
+            interpret=interpret,
+            name="paged_decode_block",
+        )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
+          jnp.asarray(layer, jnp.int32).reshape(1), qg, own(k_new),
+          own(v_new), kv_pool)
+        out = out.reshape(r, kvh, b, rep, d).transpose(0, 2, 1, 3, 4)
+        return out.reshape(r, b, h, d)
+
+
 def paged_attention_decode(q: jax.Array, kv_pool: jax.Array, layer,
                            block_tables: jax.Array, ctx_lens: jax.Array,
                            k_new: jax.Array, v_new: jax.Array,
@@ -509,7 +703,23 @@ def paged_attention_decode(q: jax.Array, kv_pool: jax.Array, layer,
 
     Returns (B, H, D) in q.dtype.  On a TPU the blocks a context holds
     are all that is read; elsewhere every table column is gathered.
+
+    A block of positions a row (a model that generates by diffusion over
+    blocks): q (R, B, H, D), k_new, v_new (R, B, KV, D), ``ctx_lens`` the
+    committed positions the pool holds BEFORE the block; every query of the
+    block sees those and all B new keys and values.  Returns (R, B, H, D).
     """
+    if q.ndim == 4:
+        if pages is not None or window is not None:
+            raise NotImplementedError(
+                "a block of positions under a list of pages or a window")
+        if jax.default_backend() == "tpu" and kv_pool.dtype == jnp.float32 \
+                and q.shape[2] % k_new.shape[2] == 0 \
+                and q.shape[3] % 128 == 0:
+            return _block_decode_kernel(q, kv_pool, layer, block_tables,
+                                        ctx_lens, k_new, v_new)
+        return _block_decode_gather(q, kv_pool, layer, block_tables,
+                                    ctx_lens, k_new, v_new)
     # the kernel is built for whole query groups per KV head and a
     # float32 pool, as the engine's is
     # (a listed walk copies a head's own lanes: whole tiles of them)

@@ -23,6 +23,27 @@ with a row that samples, whose token is drawn here from pulled logits
 (``sampled``), and nothing left to enqueue (``tail``).  DESIGN.md,
 "Scheduler loop".
 
+Blocks.  A model that generates by diffusion over blocks
+(``runner.block``: ``models/llama.block_stepping``) is stepped a block of B
+positions at a time, on the same loop.  A prompt's whole blocks are
+prefilled under the block-causal mask and yield no token; what is left over
+is the given part of the first open block.  A decode step is a *pass*: each
+row's open block (``scheduler.OpenBlock``) through the model against the
+sequence's pages.  A denoise pass writes no K/V and fixes some undecided
+positions by the remasking rule, on the device; when none is undecided a
+commit pass runs the final block once more and writes its K/V into the
+slots reserved when the block opened (``PagedKVCache.append_block``), and
+its tokens go on the stream, in order, cut at ``max_tokens`` and at a stop
+token, when that pass is read.  Rows of one step stand at different passes:
+a row's kind and flags are operands of one program.  A pass fixes a known
+number of positions, so the loop knows which pass is a block's commit
+without reading the one before it and keeps one pass in flight as it keeps
+one step; the open block stays on the device between passes.  Preemption
+folds only committed tokens into the prompt; the open block is lost with
+its passes (``blocks_lost``).
+Requests that sample, ``prefill_remote`` and ``attach`` are refused for
+such a model.  DESIGN.md, "Block stepping".
+
 Disaggregated prefill/decode rides the PR-4 data plane:
 ``prefill_remote()`` copies the filled blocks from the device into a
 tmpfs export spool
@@ -53,7 +74,8 @@ from ray_tpu.serve.llm.kv_cache import NoFreeBlocks, PagedKVCache
 from ray_tpu.serve.llm.model_runner import (Chosen, Enqueued, ModelRunner,
                                             _bucket)
 from ray_tpu.serve.llm.scheduler import (FAILED, FINISHED, RUNNING,
-                                         IterationScheduler, Plan, Sequence)
+                                         IterationScheduler, OpenBlock, Plan,
+                                         Sequence)
 from ray_tpu.util import metrics_catalog as mcat
 from ray_tpu.util import tracing
 from ray_tpu.util.tracing import hot_span
@@ -62,6 +84,9 @@ logger = rtlog.get("serve.llm.engine")
 
 _DONE = "__llm_done__"
 _ERR = "__llm_err__"
+# the "first token" of a prompt that yields none (a model stepped by
+# blocks): the sequence started, and nothing goes on its stream
+_NO_TOKEN = -1
 
 
 # What a decode step says of itself, by name (the attributes of
@@ -105,6 +130,9 @@ class _InFlight(NamedTuple):
     batch: List[Sequence]            # row i of the step is batch[i]
     rows: Dict[str, int]             # sequence id -> its row
     slots: Dict[str, tuple]          # the pool slots reserved for it
+    # a step of a model stepped by blocks: sequence id -> (the block its
+    # row passed over, whether the pass commits it)
+    blocks: Dict[str, tuple] = {}
 
 
 class RequestStream:
@@ -249,6 +277,12 @@ class LLMEngine:
         self.decode_steps_ahead = 0
         self.decode_drains = dict(sampled=0, pressure=0, admit=0, tail=0)
         self.decode_rows_discarded = 0
+        # a model stepped by blocks: blocks whose commit pass was read,
+        # row-passes by kind, and blocks whose passes (or part of whose
+        # tokens) were thrown away, by cause (loop-owned)
+        self.blocks_committed = 0
+        self.block_passes = dict(denoise=0, commit=0)
+        self.blocks_lost = dict(preempt=0, stop=0, cut=0)
         # hot-span totals of the loop and the runner, name ->
         # [count, seconds] (tracing.hot_span); the names are a contract,
         # PERF.md section 3 lists each with the metric that reads it
@@ -300,6 +334,11 @@ class LLMEngine:
     def submit(self, prompt: List[int],
                sampling: Optional[SamplingParams] = None) -> RequestStream:
         sampling = sampling or SamplingParams()
+        if self.runner.block and not sampling.greedy:
+            raise ValueError(
+                f"{self.cfg.model} generates by diffusion over blocks and "
+                "its remasking rule runs on the device over greedy "
+                "candidates: a request that samples is not written")
         seq_id = _new_seq_id()
         with hot_span("llm.submit", self.span_s, seq=seq_id):
             seq = Sequence(seq_id=seq_id, prompt=[int(t) for t in prompt],
@@ -441,7 +480,8 @@ class LLMEngine:
     def _started(self, seq: Sequence, tok: Optional[int], t0: float,
                  span: hot_span) -> None:
         """A prefilled sequence joins the running ones with its first
-        token (None: it did not start)."""
+        token (None: it did not start; ``_NO_TOKEN``: a model stepped by
+        blocks, whose prompt yields none)."""
         if tok is None:
             return
         if seq.trace is not None:
@@ -458,6 +498,12 @@ class LLMEngine:
         """``seq`` joins the running ones and its first token goes on its
         stream: there at the END of this ``llm.prefill.commit``, as a later
         one is at the end of the ``llm.decode.commit`` that names ``seq``."""
+        if tok == _NO_TOKEN:
+            # no token, so the commit names nobody (a token is on its stream
+            # at the end of the commit that names its sequence)
+            with hot_span("llm.prefill.commit", self.span_s):
+                self.sched.start_running(seq)
+            return
         with hot_span("llm.prefill.commit", self.span_s, seq=seq.seq_id):
             self.sched.start_running(seq)
             self._emit(seq, tok)
@@ -533,38 +579,51 @@ class LLMEngine:
                 seq.chunk_flight = None
             with self._lock:
                 self._count_chosen_locked(chosen)
-            return self._scattered(seq, chosen, ks, vs)
+            return self._scattered(seq, chosen, ks, vs, len(seq.prompt))
+        # a model stepped by blocks prefills the prompt's whole blocks,
+        # under the block-causal mask; what is left over opens the first
+        # block it decodes
+        n, prompt = seq.ctx_len, seq.prompt
+        if self.runner.block:
+            n -= n % self.runner.block["block"]
+            prompt = prompt[:n]
         try:
-            self.cache.alloc_seq(seq.seq_id, seq.ctx_len)
+            self.cache.alloc_seq(seq.seq_id, n)
         except NoFreeBlocks:
             # plan() checked free blocks, but be safe: requeue
             self.sched.waiting.appendleft(seq)
             return None
-        span.set(bucket=_bucket(len(seq.prompt),
-                                self.cfg.prefill_len_buckets),
+        span.set(bucket=_bucket(n, self.cfg.prefill_len_buckets),
                  queue_ms=round(1e3 * self._note_admission(seq), 3))
+        if not n:
+            return _NO_TOKEN            # a prompt shorter than a block
         try:
             chosen, ks, vs = self.runner.prefill(
-                seq.prompt, logit_rows=_sampled_rows([seq.sampling]))
+                prompt, logit_rows=_sampled_rows([seq.sampling]))
         except Exception as e:  # noqa: BLE001 - surface to the caller
             self.cache.free_seq(seq.seq_id)
             self._finish(seq, FAILED, f"prefill failed: {e!r}")
             return None
         with self._lock:
             self.prefill_steps += 1
-            self._count_chosen_locked(chosen)
-        return self._scattered(seq, chosen, ks, vs)
+            if not self.runner.block:
+                self._count_chosen_locked(chosen)
+        return self._scattered(seq, chosen, ks, vs, n)
 
-    def _scattered(self, seq: Sequence, chosen: Chosen, ks, vs) -> int:
-        """The prompt's K/V into its blocks (and its state to its row);
-        the first token."""
+    def _scattered(self, seq: Sequence, chosen: Chosen, ks, vs,
+                   n: int) -> int:
+        """The K/V of the prompt's ``n`` prefilled positions into its
+        blocks (and its state to its row); the first token."""
         # K/V never left the device: the scatter is the enqueue of a
         # second device program; with recurrent state it also commits the
         # prompt's to the sequence's row, which the span then names
         row = {"row": self.cache.state_row(seq.seq_id)} \
             if self.cache.state_rows else {}
         with hot_span("llm.prefill.scatter", self.span_s, **row):
-            self.cache.scatter_prefill(seq.seq_id, ks, vs, len(seq.prompt))
+            self.cache.scatter_prefill(seq.seq_id, ks, vs, n)
+        if self.runner.block:
+            seq.kv_len = n
+            return _NO_TOKEN
         # sampling step = tokens generated so far RELATIVE TO THE
         # ORIGINAL prompt, so a preemption re-prefill (k tokens folded
         # into the prompt) draws the same rng stream position as the
@@ -590,6 +649,8 @@ class LLMEngine:
 
     # ----------------------------------------------------------------- decode
     def _do_decode(self, seqs: List[Sequence]) -> None:
+        if self.runner.block:
+            return self._do_decode_blocks(seqs)
         # a sequence whose token in flight is its last by length is known
         # to end at that token's commit: no row of this step is spent on it
         flight = self._inflight
@@ -731,6 +792,8 @@ class LLMEngine:
         """Wait for ``flight``'s ids and give each sequence its token;
         ``span`` (the ``llm.decode`` or ``llm.decode.drain`` that reads the
         step) is told what the step says of itself, as its pull was."""
+        if self.runner.block:
+            return self._commit_blocks(flight, span)
         try:
             chosen = self.runner.pull_step(flight.step)
         except BaseException:
@@ -769,6 +832,209 @@ class LLMEngine:
         self.decode_rows_discarded += discarded
         self._count_tokens(len(emitted), phase="decode")
 
+    # ---------------------------------------------------------------- blocks
+    def _block_lost(self, cause: str) -> None:
+        self.blocks_lost[cause] += 1
+        if GLOBAL_CONFIG.metrics_enabled:
+            mcat.get("rtpu_llm_blocks_lost").inc(
+                tags={"model": self.cfg.model, "cause": cause})
+
+    def _do_decode_blocks(self, seqs: List[Sequence]) -> None:
+        # a sequence whose commit pass in flight ends it by length: no row
+        # of this step is spent on a block it will never have
+        flight, span = self._inflight, self.runner.block["block"]
+        if flight is not None:
+            def ends(s):
+                ob, commits = flight.blocks.get(s.seq_id, (None, False))
+                return commits and s.generated + span - ob.given \
+                    >= s.sampling.max_tokens
+            seqs = [s for s in seqs if not ends(s)]
+        if not seqs:
+            self._drain("tail")
+            return
+        with hot_span("llm.decode", self.span_s) as span_:
+            self._block_batch(seqs, span_)
+
+    def _open_blocks(self, seqs: List[Sequence]):
+        """The slots of a whole block for every sequence that has none
+        open, preempting under cache pressure as ``_reserve_slots`` does:
+        (the blocks opened here by sequence id, the batch that remains)."""
+        spec = self.runner.block
+        span, opened = spec["block"], {}
+        batch = list(seqs)
+        for seq in list(batch):
+            while seq.open_block is None and seq in self.sched.running:
+                try:
+                    grew = self.cache.append_block(seq.seq_id, span)
+                except NoFreeBlocks:
+                    if self._inflight is not None:
+                        self._drain("pressure")
+                    elif not self._preempt_one(opened):
+                        raise RuntimeError(
+                            "no preemption victim with a growing "
+                            "sequence running")
+                    continue
+                # the tokens the prompt (or what was committed before a
+                # preemption) puts in the block are decided; the rest are
+                # fed the mask id
+                given = (seq.prompt + seq.output)[seq.kv_len:]
+                rest = span - len(given)
+                seq.open_block = opened[seq.seq_id] = OpenBlock(
+                    seq.kv_len, given + [spec["mask_id"]] * rest,
+                    [True] * len(given) + [False] * rest, len(given), grew,
+                    planned=len(given))
+            batch = [s for s in batch if s in self.sched.running]
+        return opened, batch
+
+    def _block_batch(self, seqs: List[Sequence], span_: hot_span) -> None:
+        """One pass: enqueue it for ``seqs``, each row at its own pass of
+        its own block, then read and commit the pass that was in flight."""
+        spans, spec = self.span_s, self.runner.block
+        span = spec["block"]
+        with hot_span("llm.decode.slots", spans):
+            opened, batch = self._open_blocks(seqs)
+        if not batch:
+            return
+        flight = self._inflight
+        n = len(batch)
+        with hot_span("llm.decode.tables", spans):
+            maxb = self.cfg.max_blocks_per_seq
+            tables = np.zeros((n, maxb), np.int32)
+            toks = np.zeros((n, span), np.int32)
+            decided = np.ones((n, span), bool)
+            commit = np.zeros(n, bool)
+            src = np.full(n, -1, np.int32)
+            lens = np.zeros(n, np.int32)
+            passed = {}
+            for i, s in enumerate(batch):
+                t = self.cache.table(s.seq_id)
+                tables[i, :len(t)] = t
+                ob = s.open_block
+                lens[i] = ob.start
+                commit[i] = ob.planned >= span
+                passed[s.seq_id] = (ob, bool(commit[i]))
+                # the pass in flight over this same block left it on the
+                # device, ids and flags: taken from there by its row
+                before = flight.blocks.get(s.seq_id) if flight else None
+                if before is not None and before[0] is ob:
+                    src[i] = flight.rows[s.seq_id]
+                else:
+                    toks[i], decided[i] = ob.ids, ob.decided
+            commits = int(commit.sum())
+            span_.set(batch=n, ahead=int(flight is not None), block=span,
+                      denoise=n - commits, commit=commits,
+                      seqs="|".join(s.seq_id for s in batch),
+                      kv_layers=self.cache.kv_layers,
+                      state_layers=self.cache.state_layers)
+            self.attn_blocks_read += int(
+                (-(-lens // self.cfg.block_size)).sum())
+            self.attn_blocks_table += maxb * _bucket(
+                n, self.cfg.decode_batch_buckets)
+        try:
+            step, _, _ = self.runner.decode(
+                toks, lens, self.cache.pool, tables, lens, decided=decided,
+                commit=commit, logit_rows=(), wait=False, rows=src,
+                after=None if flight is None else flight.step)
+        except BaseException:
+            for s in batch:
+                if s.seq_id in opened:
+                    self.cache.rollback_block(s.seq_id, s.open_block.grew)
+                    s.open_block = None
+            raise
+        self.decode_steps += 1
+        span_.set(step=step.step)
+        for s in batch:
+            ob = s.open_block
+            if ob.planned >= span:
+                # its K/V is written by this pass: the next pass of this
+                # sequence opens the block behind it
+                s.kv_len, s.open_block = ob.start + span, None
+            else:
+                ob.planned = min(span, ob.planned + spec["per_pass"])
+        self.block_passes["commit"] += commits
+        self.block_passes["denoise"] += n - commits
+        if GLOBAL_CONFIG.metrics_enabled:
+            for kind, rows in (("denoise", n - commits), ("commit", commits)):
+                mcat.get("rtpu_llm_block_passes").inc(
+                    rows, tags={"model": self.cfg.model, "kind": kind})
+        self._inflight = _InFlight(
+            step, batch, {s.seq_id: i for i, s in enumerate(batch)}, {},
+            passed)
+        if flight is not None:
+            self.decode_steps_ahead += 1
+            self._commit(flight, span_)
+
+    def _commit_blocks(self, flight: _InFlight, span: hot_span) -> None:
+        """Wait for a pass's blocks: a denoise pass's rows bring the host
+        their block as the rule left it; a commit pass's rows put the
+        block's tokens on their streams, in order, cut at ``max_tokens``
+        and at a stop token."""
+        width = self.runner.block["block"]
+        try:
+            chosen = self.runner.pull_step(flight.step)
+        except BaseException as e:
+            # none of it arrived, and the pass behind was fed from it: the
+            # sequences of both end here, their pages go back
+            for lost in (flight, self._inflight):
+                for s in lost.batch if lost is not None else ():
+                    if s.state == RUNNING:
+                        self.cache.free_seq(s.seq_id)
+                        self.sched.finish(s, FAILED)
+                        self._finish(s, FAILED, f"decode failed: {e!r}")
+            self._inflight = None
+            raise
+        reads = chosen.reads
+        if reads:
+            span.set(**reads)
+        self.step_reads.update(reads, steps=1)
+        if GLOBAL_CONFIG.metrics_enabled:
+            mcat.tell_step({**reads, **self.cache.held_counts()}, STEP_SERIES,
+                           {"model": self.cfg.model}, self._per)
+        with hot_span("llm.decode.commit", self.span_s,
+                      step=flight.step.step) as commit:
+            gave, discarded, blocks = [], 0, 0
+            for i, s in enumerate(flight.batch):
+                ob, commits = flight.blocks[s.seq_id]
+                if s.state != RUNNING:
+                    # ended by a stop token a commit before this one, when
+                    # this pass was enqueued already: the row is nobody's
+                    discarded += 1
+                    continue
+                if not commits:
+                    ob.ids = [int(t) for t in chosen.ids[i]]
+                    ob.decided = [bool(d) for d in chosen.decided[i]]
+                    continue
+                blocks += 1
+                told = 0
+                for tok in chosen.ids[i][ob.given:]:
+                    self._emit(s, int(tok))
+                    told += 1
+                    if s.finish_reason() is not None:
+                        break
+                reason = s.finish_reason()
+                if reason == "stop" and (told < width - ob.given
+                                         or s.open_block is not None):
+                    # the rest of its block, or the block a pass behind
+                    # this one opened already
+                    self._block_lost("stop")
+                elif reason == "length" and told < width - ob.given:
+                    self._block_lost("cut")
+                self._maybe_finish(s)
+                gave.append((s.seq_id, told))
+            tokens = sum(told for _, told in gave)
+            with self._lock:
+                self.sampled_on_device += tokens
+            commit.set(tokens=tokens, blocks=blocks,
+                       seqs="|".join(sid for sid, _ in gave),
+                       tokens_by_seq="|".join(str(told) for _, told in gave))
+        self.blocks_committed += blocks
+        if blocks and GLOBAL_CONFIG.metrics_enabled:
+            tags = {"model": self.cfg.model}
+            mcat.get("rtpu_llm_blocks_committed").inc(blocks, tags=tags)
+            mcat.get("rtpu_llm_block_tokens").inc(tokens, tags=tags)
+        self.decode_rows_discarded += discarded
+        self._count_tokens(tokens, phase="decode")
+
     def _return_slots(self, batch: List[Sequence], slots: Dict) -> None:
         for s in batch:
             ent = slots.get(s.seq_id)
@@ -801,6 +1067,8 @@ class LLMEngine:
                               ctx=victim.ctx_len)
         self.cache.free_seq(victim.seq_id)
         slots.pop(victim.seq_id, None)
+        if victim.open_block is not None:
+            self._block_lost("preempt")
         self.sched.preempt(victim)
         self.preemptions += 1
         if GLOBAL_CONFIG.metrics_enabled:
@@ -994,6 +1262,11 @@ class LLMEngine:
             if plane.unexported:
                 raise NotImplementedError(
                     f"{what}: {self.cfg.model} {plane.unexported}")
+        if self.runner.block:
+            raise NotImplementedError(
+                f"{what}: {self.cfg.model} is stepped by blocks, and a "
+                "manifest carries a first token where such a prompt yields "
+                "none and an open block may stand")
 
     def _drain_cancels(self) -> None:
         with self._lock:
@@ -1136,6 +1409,11 @@ class LLMEngine:
                     decode_steps_ahead=self.decode_steps_ahead,
                     decode_drains=dict(self.decode_drains),
                     decode_rows_discarded=self.decode_rows_discarded,
+                    # a model stepped by blocks says three things more
+                    **(dict(blocks_committed=self.blocks_committed,
+                            block_passes=dict(self.block_passes),
+                            blocks_lost=dict(self.blocks_lost))
+                       if self.runner.block else {}),
                     preemptions=self.preemptions,
                     tokens_out=self.tokens_out,
                     running=len(self.sched.running),

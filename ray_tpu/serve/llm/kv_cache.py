@@ -540,6 +540,21 @@ def slots_reserved(held, tables, ctx_lens, n_real) -> tuple:
     return blocks, ctx_lens % bs
 
 
+def block_slots_reserved(held, table, ctx_lens, commit, span: int) -> tuple:
+    """(blocks by table, offsets) of a block pass's new rows, ``span`` a
+    row, row-major: position ``ctx + j`` of row ``r`` goes to the slot
+    ``append_block`` reserved for it, ``(table[r, (ctx + j) // bs], (ctx +
+    j) % bs)``.  ``commit`` (R,) bool: the rows whose pass writes; every
+    other (a denoise pass, a row padded up to the bucket) is sent out of
+    range and writes nowhere."""
+    import jax.numpy as jnp
+    num_blocks, bs = held["kv"].shape[2:4]
+    at = ctx_lens[:, None] + jnp.arange(span)                   # (R, span)
+    rows = jnp.arange(ctx_lens.shape[0])[:, None]
+    blocks = jnp.where(commit[:, None], table[rows, at // bs], num_blocks)
+    return {"kv": blocks.reshape(-1)}, (at % bs).reshape(-1)
+
+
 def rows_written(held, blocks, offsets, k, v) -> dict:
     """New rows into every plane that takes them, for a decode step and for
     ``write_token`` alike: k, v (L, R, KV, D) hold the pools' rows one pool
@@ -926,6 +941,26 @@ class PagedKVCache:
                 if self._ref[b] == 0:
                     del self._ref[b]
                     self._free.append(b)
+
+    def append_block(self, seq_id: str, n: int) -> List[bool]:
+        """Reserve the next ``n`` token slots for ``seq_id`` at once (a
+        block of positions, written together by its commit pass): all of
+        them or, under cache pressure, none (``NoFreeBlocks``).  Returns
+        each slot's ``grew``, what :meth:`rollback_block` takes."""
+        grew: List[bool] = []
+        try:
+            for _ in range(n):
+                grew.append(self.append_slot(seq_id)[2])
+        except NoFreeBlocks:
+            self.rollback_block(seq_id, grew)
+            raise
+        return grew
+
+    def rollback_block(self, seq_id: str, grew: List[bool]) -> None:
+        """Undo one :meth:`append_block` (a block whose passes were thrown
+        away: a failed step, a stream cut inside it)."""
+        for one in reversed(grew):
+            self.rollback_slot(seq_id, one)
 
     def free_seq(self, seq_id: str) -> int:
         """Release a sequence's blocks (refcounted); returns #freed."""

@@ -42,6 +42,11 @@ as stored; every other is held in the model's ``dtype``, converted once,
   iteration with a decode step of the live rows behind it.
 * ``ROW_TABLES``: a tied head's table held a second time for the
   embedding's gather where its rows are no whole lanes.
+* ``block_stepping(cfg)`` -> ``{"block", "mask_id", "per_pass"}``
+  (``block``): the model generates by diffusion over blocks.
+  A decode step is then a *pass* over a block of ``block`` positions a row
+  ("A block a row", below), a prompt is prefilled in whole blocks under the
+  block-causal mask and yields no token.
 
 The step programs.  One decode body and one prefill body (and one chunk
 body), each reading what the holder's dict has.  The decode program takes
@@ -72,6 +77,20 @@ that the program's pack and ``_pull``'s read both take from
 pulled ``(V,)`` row (``sample``).  A caller that names nothing gets all
 the logits on the host.
 
+A block a row.  For a family that steps by blocks ``decode`` takes
+``tokens (R, B)`` (a row's open block), ``decided (R, B)`` (which of its
+positions hold a token: the others are fed the mask id, whatever id they
+carry) and ``commit (R,)`` (the row's pass writes the block's K/V into the
+slots ``PagedKVCache.append_block`` reserved; any other pass writes
+nothing); ``positions`` = ``ctx_lens``, the committed positions before the
+block.  The one program a bucket runs the forward over ``(R, B)`` positions,
+takes each undecided position's ``argmax`` and its softmax probability (the
+confidence, float32) and applies the remasking rule ON THE DEVICE: the
+block after the pass, ids and decided-flags, stays there as the step's
+``carry`` and feeds the pass behind it by row (``rows=src``), and what
+crosses to the host is ``ChosenBlocks``: ``(R, B)`` ids, confidences and
+flags in one pull, never ``(R, B, V)`` logits unless a row is named.
+
 One step behind another.  A greedy row's next token is the id the step
 before chose, on the device: the decode programs take the last step's ids
 (``last_ids``, at the widest bucket's width) and a row map ``src``: row
@@ -97,8 +116,9 @@ from ray_tpu._private.xla_watchdog import compile_budget
 from ray_tpu.serve.llm.config import EngineConfig, SamplingParams, \
     resolve_model
 from ray_tpu.serve.llm.kv_cache import DevicePool, Kept, PagedKVCache, \
-    _declared, handed_to_forward, kept_by, rows_written, slots_reserved, \
-    staged_rows, stepped_by_forward, window_reads
+    _declared, block_slots_reserved, handed_to_forward, kept_by, \
+    rows_written, slots_reserved, staged_rows, stepped_by_forward, \
+    window_reads
 from ray_tpu.util.tracing import abstract, hot_span, register_program
 
 logger = rtlog.get("serve.llm.runner")
@@ -153,6 +173,21 @@ class Chosen(NamedTuple):
         if sp.greedy:
             return int(self.ids[row])
         return ModelRunner.sample(self.logits[row], sp, step)
+
+    @property
+    def logits_nbytes(self) -> int:
+        return sum(row.nbytes for row in self.logits.values())
+
+
+class ChosenBlocks(NamedTuple):
+    """What a pass over blocks hands a caller that named ``logit_rows``:
+    each row's block AFTER the pass's remasking rule."""
+
+    ids: np.ndarray                  # (R, B) int32: the block's tokens
+    conf: np.ndarray                 # (R, B) float32: softmax[argmax]
+    decided: np.ndarray              # (R, B) bool: the positions that hold one
+    logits: Dict[int, np.ndarray]    # row -> (B, V) float32, the rows named
+    reads: dict = {}
 
     @property
     def logits_nbytes(self) -> int:
@@ -255,6 +290,9 @@ class Family:
     row_tables: Optional[tuple]      # tables held again for their gather
     riders: Tuple[Rider, ...]
     layout: tuple                    # their names, in the order they ride
+    # how a module that generates by diffusion over blocks is stepped
+    # ({"block", "mask_id", "per_pass"}; None: by tokens)
+    block_spec: Optional[dict] = None
 
 
 def family_of(mod, mcfg, cfg: EngineConfig) -> Family:
@@ -272,9 +310,57 @@ def family_of(mod, mcfg, cfg: EngineConfig) -> Family:
         mcfg, -(-cfg.prefill_len_buckets[-1] // chunk) * chunk) \
         if chunk else None
     riders = riders_of(route, select)
-    return Family(kept_by(mod, mcfg), route, select, chunk, staging,
+    blocks = _declared(mod, mcfg, "block_stepping")
+    kept = kept_by(mod, mcfg)
+    if blocks:
+        _blocks_fit(cfg, kept, chunk, blocks["block"])
+    return Family(kept, route, select, chunk, staging,
                   mod.WIDE_PARAMS, getattr(mod, "ROW_TABLES", None), riders,
-                  tuple(name for rider in riders for name in rider.names))
+                  tuple(name for rider in riders for name in rider.names),
+                  blocks)
+
+
+def _blocks_fit(cfg: EngineConfig, kept: Kept, chunk: int, span: int) -> None:
+    """A family that steps by blocks of ``span`` positions: what the stack
+    has written for it, or a refusal that says what is missing."""
+    if chunk or kept.state or kept.window_layers or kept.latent_layers \
+            or kept.select_stride:
+        raise NotImplementedError(
+            f"{cfg.model} steps by blocks of {span} positions: only K/V "
+            "pages under one table and a prompt in one program are written "
+            "for such a step")
+    if cfg.block_size % span or any(b % span
+                                    for b in cfg.prefill_len_buckets):
+        raise ValueError(
+            f"{cfg.model} steps by blocks of {span} positions: pages of "
+            f"{cfg.block_size} and the prefill buckets "
+            f"{cfg.prefill_len_buckets} are whole blocks")
+
+
+def remasked(logits, tokens, decided, per_pass: int):
+    """A pass's remasking rule, on the device: logits (R, B, V) float32, the
+    row's block ``tokens`` (R, B) and which positions are ``decided`` ->
+    (the block after the pass, its decided-flags, conf (R, B) float32).
+    Each undecided position's candidate is its own logits' argmax and its
+    confidence that token's softmax probability; the ``per_pass`` undecided
+    positions of highest confidence are fixed, the lower position first on
+    a tie.  A block with nothing undecided (a commit pass) comes back as it
+    was."""
+    import jax
+    import jax.numpy as jnp
+    with jax.named_scope("remask"):
+        top = logits.max(-1)
+        x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        conf = jnp.exp(top - jax.nn.logsumexp(logits, axis=-1))
+        span = tokens.shape[1]
+        left = jnp.where(decided, -1.0, conf)
+        newly = jnp.zeros(decided.shape, bool)
+        for _ in range(per_pass):
+            best = jnp.argmax(left, axis=-1)                    # first max
+            hot = (jnp.arange(span) == best[:, None]) & (
+                left.max(-1, keepdims=True) >= 0.0)
+            newly, left = newly | hot, jnp.where(hot, -1.0, left)
+        return jnp.where(newly, x0, tokens), decided | newly, conf
 
 
 class ModelRunner:
@@ -322,6 +408,8 @@ class ModelRunner:
         self.route_spec, self.select_spec = \
             family.route_spec, family.select_spec
         self.chunk = family.chunk
+        # a module stepped by blocks: its spec (None: by tokens)
+        self.block = family.block_spec
         # a routing module's last step's choices, on the device
         self.choices = None
         asked = {"choices": True} if self.route_spec else {}
@@ -356,7 +444,11 @@ class ModelRunner:
                 held = {**held, "state": jax.tree.map(
                     lambda s, new: s.at[:, -1].set(new[:, 0]),
                     held["state"], state)}
-            out = (logits, greedy(logits)), ks[:, 0], vs[:, 0], *ids
+            chose = greedy(logits)
+            if self.block:
+                # the last block's (1, B) candidates, flat as a step's ids
+                chose = chose.reshape(-1)
+            out = (logits, chose), ks[:, 0], vs[:, 0], *ids
             return out if held is None else (held, out)
 
         widest = cfg.decode_batch_buckets[-1]
@@ -404,6 +496,46 @@ class ModelRunner:
                     k, v)
             return held, (*chosen_from(logits, riding), k, v, *ids)
 
+        def block_step(held, params, tokens, positions, block_tables,
+                       ctx_lens, n_real, last, src, decided, commit):
+            # a pass over a block a row.  A row the pass before also held
+            # takes its block, ids and flags, from where that pass left it
+            # (``last``, at the widest bucket's width); the undecided
+            # positions are fed the mask id, whatever id they carry.  The
+            # rule runs here, on the float32 logits; the block's K/V is
+            # written for the rows whose pass commits and for no other
+            spec = self.block
+            span = spec["block"]
+            with jax.named_scope("embed"):
+                took = (src >= 0)[:, None]
+                at = jnp.maximum(src, 0)
+                tokens = jnp.where(took, last[0][at], tokens)
+                decided = jnp.where(took, last[1][at], decided)
+                fed = jnp.where(decided, tokens, jnp.int32(spec["mask_id"]))
+            live = live_rows(tokens, n_real)
+            logits, k, v, *ids = forward_decode(
+                params, fed, positions, held["kv"], block_tables, ctx_lens,
+                **live)
+            riding = [(rider, rider.take(ids)) for rider in family.riders]
+            block, flags, conf = remasked(logits, tokens, decided,
+                                         spec["per_pass"])
+            with jax.named_scope("kv_write"):
+                writes = commit & (jnp.arange(tokens.shape[0]) < n_real)
+                rows = k.shape[1] * span                    # (L, R, B, ..)
+                held = rows_written(
+                    held, *block_slots_reserved(held, block_tables, ctx_lens,
+                                                writes, span),
+                    *(a.reshape(a.shape[0], rows, *a.shape[3:])
+                      for a in (k, v)))
+            packed = _ridden(jnp.concatenate([
+                block.reshape(-1),
+                jax.lax.bitcast_convert_type(conf, jnp.int32).reshape(-1),
+                flags.astype(jnp.int32).reshape(-1)]),
+                [rider.count(x) for rider, x in riding])
+            pad = ((0, widest - block.shape[0]), (0, 0))
+            carry = (jnp.pad(block, pad), jnp.pad(flags, pad))
+            return held, ((logits, packed), carry, k, v, *ids)
+
         def prefill_chunk_step(held, params, staging, toks, start, n_total):
             # one chunk of one prompt: K/V and half-kernels into the
             # staging, the state from the store's staging row and back
@@ -424,7 +556,8 @@ class ModelRunner:
         # bound to a name of its own: jaxlint pins a donating jit by the
         # name it is assigned to (lock_watchdog.DONATED)
         llm_prefill_step = jax.jit(prefill_step, donate_argnums=(0,))
-        llm_decode_step = jax.jit(decode_step, donate_argnums=(0,))
+        llm_decode_step = jax.jit(block_step if self.block else decode_step,
+                                  donate_argnums=(0,))
         self._prefill = llm_prefill_step
         self._decode = llm_decode_step
         # the prefill body's two call forms: through the cache's holder,
@@ -477,9 +610,15 @@ class ModelRunner:
         # than a real step's ids would be a second entry, and its first
         # call a miss inside somebody's measured window
         weights = jax.tree.leaves(self.params)
-        self._no_ids = jax.device_put(np.zeros(widest, np.int32), next(
+        placed = next(
             (w.sharding for w in weights if getattr(w, "committed", False)),
-            None))
+            None)
+        self._no_ids = jax.device_put(np.zeros(widest, np.int32), placed)
+        if self.block:
+            # a pass with no pass before it: no row takes a block from it
+            self._no_ids = tuple(jax.device_put(
+                np.zeros((widest, self.block["block"]), t), placed)
+                for t in (np.int32, bool))
         self.steps_enqueued = 0    # decode steps, this runner's life
         # the engine's cache: a bucket's scatter program is built with
         # the bucket's first prefill (None: a runner on its own)
@@ -520,6 +659,14 @@ class ModelRunner:
         true length)."""
         import jax.numpy as jnp
         n = len(token_ids)
+        # a family stepped by blocks: whole blocks, and the last block's
+        # B rows in the place of the last position's one
+        span = self.block["block"] if self.block else 1
+        if n % span:
+            raise ValueError(
+                f"{self.cfg.model} prefills whole blocks of {span} "
+                f"positions, not {n}: what is left over opens the first "
+                "block it decodes")
         tb = _bucket(n, self.cfg.prefill_len_buckets)
         if self.chunk:
             picked = None
@@ -549,7 +696,8 @@ class ModelRunner:
                      *abstract((self.params, toks, last_pos))))
                 if self.cache is not None:
                     self.cache.warm_scatter(ks, vs)
-        out = self._pull("llm.prefill.pull", picked, 1, logit_rows)
+        out = self._pull("llm.prefill.pull", picked, span, logit_rows,
+                         width=span)
         return (out[0] if logit_rows is None else out), ks, vs
 
     # ------------------------------------------------------ prefill in chunks
@@ -631,9 +779,11 @@ class ModelRunner:
                ctx_lens: np.ndarray, *,
                logit_rows: Optional[Sequence[int]] = None,
                after: Optional[Enqueued] = None,
-               rows: Optional[np.ndarray] = None, wait: bool = True
-               ) -> Tuple[Union[np.ndarray, Chosen, Enqueued], "jax.Array",
-                          "jax.Array"]:
+               rows: Optional[np.ndarray] = None, wait: bool = True,
+               decided: Optional[np.ndarray] = None,
+               commit: Optional[np.ndarray] = None
+               ) -> Tuple[Union[np.ndarray, Chosen, ChosenBlocks, Enqueued],
+                          "jax.Array", "jax.Array"]:
         """One iteration over a batch of sequences.
 
         tokens/positions/ctx_lens (B,); block_tables (B, MAXB);
@@ -653,7 +803,21 @@ class ModelRunner:
         not looked at (-1: the row was not in that step, ``tokens[i]`` it
         is).  With ``wait=False`` the call returns at the enqueue and the
         first result is the ``Enqueued`` that ``pull_step`` takes.
+
+        A family stepped by blocks (``self.block``): tokens (B, span), a
+        row's open block; ``decided`` (B, span) bool, the positions that
+        hold a token (None: all); ``commit`` (B,) bool, the rows whose pass
+        writes the block's K/V at ``ctx_lens .. ctx_lens + span - 1`` (None:
+        none); positions = ctx_lens, the committed positions before the
+        block.  Logits are (B, span, V), new_k / new_v (L, bucket, span, KV,
+        D), and with ``logit_rows`` the first result is ``ChosenBlocks``.
         """
+        if self.block:
+            tokens = np.asarray(tokens, np.int32)
+            decided = np.ones(tokens.shape, bool) if decided is None \
+                else np.asarray(decided, bool)
+            commit = np.zeros(len(tokens), bool) if commit is None \
+                else np.asarray(commit, bool)
         b = len(tokens)
         bb = _bucket(b, self.cfg.decode_batch_buckets)
         compiling = self._note_shape("decode", bb)
@@ -661,9 +825,15 @@ class ModelRunner:
         last_ids, src = self._no_ids, np.full(bb, -1, np.int32)
         if after is not None:
             last_ids, src[:b] = after.carry, rows
+        if pad and self.block:
+            decided = np.concatenate([decided, np.ones((pad,)
+                                                       + decided.shape[1:],
+                                                       bool)])
+            commit = np.concatenate([commit, np.zeros(pad, bool)])
         if pad:
             with hot_span("llm.decode.tables", self.span_s):
-                tokens = np.concatenate([tokens, np.zeros(pad, np.int32)])
+                tokens = np.concatenate([tokens, np.zeros(
+                    (pad,) + tokens.shape[1:], np.int32)])
                 positions = np.concatenate([positions,
                                             np.zeros(pad, np.int32)])
                 ctx_lens = np.concatenate([ctx_lens,
@@ -681,6 +851,12 @@ class ModelRunner:
                                            b))
             if plane.reads:
                 reads.update(plane.reads(ctx_lens[:b], self._state_cache()))
+        if self.block:
+            # a row's pages, walked once a pass for all its positions
+            by_row += [decided, commit]
+            reads["pages_read"] = self.kv_layers * int((-(
+                -np.asarray(ctx_lens[:b], np.int64)
+                // self.cfg.block_size)).sum())
         # dispatch holds the jitted call and ends at the ENQUEUE; pull
         # ends when the ids or the logits are on the host, so it holds the
         # wait for the step and nothing else: the pool stays where it is
@@ -709,10 +885,37 @@ class ModelRunner:
         """Wait for an enqueued decode step and bring the host what its
         caller named (``decode``'s first result), inside an
         ``llm.decode.pull`` span that says which step it is."""
+        width = _bucket(step.n, self.cfg.decode_batch_buckets)
+        if self.block:
+            return self._pull_blocks(step, width)
         return self._pull(
             "llm.decode.pull", step.picked, step.n, step.logit_rows,
-            width=_bucket(step.n, self.cfg.decode_batch_buckets),
-            riders=self.family.layout, reads=step.reads, step=step.step)
+            width=width, riders=self.family.layout, reads=step.reads,
+            step=step.step)
+
+    def _pull_blocks(self, step: Enqueued, width: int):
+        """A pass over blocks for the host: all its logits, (n, B, V), for
+        a caller that named no rows; else ``ChosenBlocks`` from the ONE
+        array the program packed (ids, confidences' bits, flags, each
+        ``width`` x B, and the riders behind them)."""
+        logits, packed = step.picked
+        n, span = step.n, self.block["block"]
+        with hot_span("llm.decode.pull", self.span_s, step=step.step,
+                      **step.reads) as pull:
+            if step.logit_rows is None:
+                pull.set(bytes=logits.nbytes)
+                return np.asarray(logits)[:n]
+            packed = np.asarray(packed)
+            rode = _riders_read(packed, 3 * width * span, self.family.layout)
+            ids, conf, flags = packed[:3 * width * span].reshape(
+                3, width, span)
+            chosen = ChosenBlocks(
+                ids[:n], conf[:n].view(np.float32), flags[:n] != 0,
+                {int(row): np.asarray(self._logits_row(logits,
+                                                       np.int32(row)))
+                 for row in step.logit_rows}, {**rode, **step.reads})
+            pull.set(bytes=packed.nbytes + chosen.logits_nbytes, **rode)
+            return chosen
 
     def _pull(self, span: str, picked, n: int,
               logit_rows: Optional[Sequence[int]], width: int = 1,

@@ -37,6 +37,25 @@ WAITING, RUNNING, FINISHED, FAILED = ("waiting", "running", "finished",
 
 
 @dataclass
+class OpenBlock:
+    """The block a sequence of a model that generates by diffusion over
+    blocks is denoising: ``span`` positions from ``start`` on, whose slots
+    in the pool are reserved and not yet written."""
+
+    start: int                       # its first position (= the K/V before)
+    # the block as the host last knew it: the tokens given by the prompt,
+    # then what the last pass READ brought; the passes in flight know more
+    ids: List[int]
+    decided: List[bool]              # by position, never by comparing ids
+    given: int                       # positions the prompt decided
+    grew: List[bool]                 # its slots (PagedKVCache.append_block)
+    # positions decided once every pass enqueued so far has run: the static
+    # rule fixes a known number a pass, so the loop knows which pass is the
+    # commit without reading the one before
+    planned: int = 0
+
+
+@dataclass
 class Sequence:
     """One request's generation state inside the engine."""
 
@@ -67,6 +86,12 @@ class Sequence:
     # them, which the next waits for (model_runner.prefill_chunk)
     chunks_done: int = 0
     chunk_flight: Optional[object] = None
+    # a model that generates by diffusion over blocks: the positions whose
+    # K/V the pool holds, or a commit pass enqueued will write (whole
+    # blocks; ``ctx_len`` counts the tokens, which may run ahead of it by
+    # the prompt's last part-block), and the block open behind them
+    kv_len: int = 0
+    open_block: Optional[OpenBlock] = None
 
     def __post_init__(self):
         if not self.orig_len:
@@ -152,6 +177,8 @@ class IterationScheduler:
         self.running.remove(seq)
         seq.prompt = seq.prompt + seq.output
         seq.output = []
+        # an open block is lost with its passes; only committed tokens fold
+        seq.kv_len, seq.open_block = 0, None
         seq.state = WAITING
         seq.preemptions += 1
         seq.queued_at = time.monotonic()

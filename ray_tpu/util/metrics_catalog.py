@@ -233,6 +233,31 @@ CATALOG: Dict[str, dict] = {
                     "to read; observed once per decode step of a model "
                     "that routes",
         emitted_by="llm replica"),
+    "rtpu_llm_block_passes": dict(
+        kind="counter", tag_keys=("model", "group", "kind"),
+        description="Row-passes of a model that generates by diffusion over "
+                    "blocks, by kind: a denoise pass fixes some of a block's "
+                    "undecided positions and writes no K/V, a commit pass "
+                    "writes the final block's",
+        emitted_by="llm replica"),
+    "rtpu_llm_blocks_committed": dict(
+        kind="counter", tag_keys=("model", "group"),
+        description="Blocks whose commit pass was read and whose tokens "
+                    "went on their stream",
+        emitted_by="llm replica"),
+    "rtpu_llm_blocks_lost": dict(
+        kind="counter", tag_keys=("model", "group", "cause"),
+        description="Blocks whose passes, or part of whose tokens, were "
+                    "thrown away, by cause: preempt (an open block lost "
+                    "with its sequence's pages), stop (a stop token inside "
+                    "a block, or a block opened behind it), cut "
+                    "(max_tokens inside the last block)",
+        emitted_by="llm replica"),
+    "rtpu_llm_block_tokens": dict(
+        kind="counter", tag_keys=("model", "group"),
+        description="Tokens the commit passes put on their sequences' "
+                    "streams: 0 to the block's length a commit a row",
+        emitted_by="llm replica"),
     "rtpu_llm_prefill_chunks_total": dict(
         kind="counter", tag_keys=("model", "group"),
         description="Chunks of prompts a model that prefills in chunks "

@@ -1707,3 +1707,133 @@ def test_ling_chunk_program_carries_state_and_stages_latent_rows(
     assert mem.alias_size_in_bytes >= 1.342e9 + 0.875e9 + 0.037e9
     assert mem.temp_size_in_bytes < 1.5e9
     assert total + LING_REFERENCE_BYTES < 16.9e9, total
+
+
+# ------------------------------------------------- the SDAR cell's programs
+def _paged_block(q, kv_pool, layer, tables, lens, k_new, v_new):
+    from ray_tpu.ops.paged_attention import _block_decode_kernel
+    return _block_decode_kernel(q, kv_pool, layer, tables, lens, k_new,
+                                v_new)
+
+
+def test_block_decode_kernel_at_the_sdar_cells_shape(v5e):
+    """32 rows of a block of 4 positions, 32 query / 4 KV heads of 128, the
+    cell's float32 pool of 4,096 pages over six layers handed over whole
+    and a table of 256 columns: a (row, KV head) a grid step, 32 query rows
+    against the head's own 128 lanes of a page.  Nothing of the pool's size
+    is made on the way in."""
+    from ray_tpu.serve.llm.kv_cache import device_shape
+    q = ((32, 4, 32, 128), jnp.bfloat16)
+    new = ((32, 4, 4, 128), jnp.bfloat16)
+    pool = device_shape(4096, 6, 16, 4, 128)
+    text = _compile(_paged_block, v5e, q, (pool, jnp.float32),
+                    ((), jnp.int32), ((32, 256), jnp.int32),
+                    ((32,), jnp.int32), new, new)
+    assert "paged_decode_block" in text
+    assert not _made(text, math.prod(pool), math.prod(pool[1:]),
+                     math.prod(pool[2:]))
+
+
+@pytest.fixture(scope="module")
+def sdar_runner(v5e):
+    """The SDAR cell's runner over abstract weights (drawn in bf16, the
+    serving type), and its K/V pool as a shape on the chip."""
+    import json
+    from pathlib import Path
+
+    from ray_tpu.models import llama
+    from ray_tpu.serve.llm import EngineConfig
+    from ray_tpu.serve.llm.config import resolve_model
+    from ray_tpu.serve.llm.kv_cache import device_shape
+    from ray_tpu.serve.llm.model_runner import ModelRunner
+    engine = json.loads((Path(__file__).parent.parent / "perfbench" /
+                         "configs" / "sdar-30b-a3b-chat.json").read_text()
+                        )["serve"]["engine"]
+    for key in ("decode_batch_buckets", "prefill_len_buckets"):
+        engine[key] = tuple(engine[key])
+    ecfg = EngineConfig(**engine)
+    mod, mcfg = resolve_model(ecfg)
+    assert mod is llama and mcfg.n_head * mcfg.head_dim == 2 * mcfg.n_embd
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.eval_shape(lambda key: mod.init_params(key, mcfg),
+                            jax.random.key(0))
+    runner = ModelRunner(ecfg, params=params)
+    assert runner.params is params      # drawn in its serving type
+    assert runner.block == {"block": 4, "mask_id": 151669, "per_pass": 1}
+    held = {"kv": on_chip(device_shape(ecfg.num_blocks, runner.kv_layers,
+                                       ecfg.block_size, mcfg.n_kv_head,
+                                       mcfg.head_dim), jnp.float32)}
+    weights = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), params)
+    return runner, ecfg, held, weights, on_chip
+
+
+# the float32 reference widens a layer's experts a block of 8 at a time and
+# holds the 151,936 x 2,048 head in float32 beside the engine it checks
+SDAR_REFERENCE_BYTES = 1.25e9 + 0.2e9
+
+
+def test_sdar_block_step_program_fits_and_writes_by_rows(sdar_runner,
+                                                         monkeypatch):
+    """The cell's pass at its one bucket of 32 rows x 4 positions (6 layers
+    at the published widths, every expert, the whole head): the block
+    kernel once in the layer scan's body, reading the pool where it lies;
+    the pool donated and written by the commit rows' 128 slots alone; what
+    goes out for the host is ONE int32 array, 32 x 4 ids, confidences' bits
+    and flags with the count of touched experts behind them, and the block
+    again, ids and flags, for the pass enqueued behind this one; the chosen
+    experts come back as (6, 128, 8); and everything fits the chip with
+    the check's reference beside it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, ecfg, held, weights, on_chip = sdar_runner
+    bucket, i32 = ecfg.decode_batch_buckets[-1], jnp.int32
+    assert runner.param_bytes == 8_722_111_488
+    last = (on_chip((bucket, 4), i32), on_chip((bucket, 4), jnp.bool_))
+    lowered = runner._decode.lower(
+        held, weights, on_chip((bucket, 4), i32), on_chip((bucket,), i32),
+        on_chip((bucket, ecfg.max_blocks_per_seq), i32),
+        on_chip((bucket,), i32), on_chip((), i32), last,
+        on_chip((bucket,), i32), on_chip((bucket, 4), jnp.bool_),
+        on_chip((bucket,), jnp.bool_))
+    ids = jax.tree.leaves(lowered.out_info)[-1]
+    assert ids.shape == (6, 128, 8) and ids.dtype == jnp.int32
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    root = next(line for line in text[text.index("ENTRY "):].splitlines()
+                if " ROOT " in line)
+    assert "s32[385]" in root and "s32[32,4]" in root \
+        and "pred[32,4]" in root and "s32[6,128,8]" in root
+    assert "paged_decode_block" in text
+    # the experts stay outside the scan's slices (llama._split_experts):
+    # nothing the size of a layer's experts is made (sliced out of the
+    # stack each of 18 would be a 0.4 GB copy: 22 of the cell's first
+    # trace's 40 ms a pass)
+    assert not re.search(r"= bf16\[128,(2048,768|768,2048)\]", text)
+    assert held["kv"].shape == (6, 2, 4096, 16, 512)
+    _assert_the_pool_is_read_in_place_and_written_by_rows(
+        text, held["kv"].shape, lanes_used=4 * 128)
+    total, mem = _held_bytes(compiled)
+    assert mem.alias_size_in_bytes >= 1.6e9
+    assert total + SDAR_REFERENCE_BYTES < 16.9e9, total
+
+
+def test_sdar_prefill_program_fits_at_bucket_4096(sdar_runner, monkeypatch):
+    """The prefill program at the largest bucket: flash attention at 32
+    heads x 128 under the block mask, 32,768 assignments through the
+    grouped matmuls, the last block's logits (1, 4, 151936), the ids
+    (6, 4096, 8); it is handed no holder."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, _, held, weights, on_chip = sdar_runner
+    lowered = runner._prefill.lower(
+        None, weights, on_chip((1, 4096), jnp.int32), on_chip((), jnp.int32))
+    ids = jax.tree.leaves(lowered.out_info)[-1]
+    assert ids.shape == (6, 4096, 8) and ids.dtype == jnp.int32
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "flash_fwd" in text and "f32[1,4,151936]" in text
+    assert not re.search(r"= bf16\[128,(2048,768|768,2048)\]", text)
+    total, _ = _held_bytes(compiled)
+    # beside the pool the engine holds while a prompt runs
+    assert total + 1.61e9 + SDAR_REFERENCE_BYTES < 16.9e9, total
