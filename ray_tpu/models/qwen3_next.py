@@ -213,7 +213,7 @@ def _gdn_mixer(u: jax.Array, lp: Params, cfg: Qwen3NextConfig):
     (B, T, E), the mean decay exp(g) of the layer, the rule's last state
     (B, H, dk, dv) float32, which training drops)."""
     from ray_tpu.ops.delta_rule import gated_delta_rule, l2norm_heads
-    from ray_tpu.ops.ssm import causal_conv_silu
+    from ray_tpu.ops.ssm import causal_conv_silu, gated_rms_norm
     B, T, _ = u.shape
     G, H = cfg.gdn_key_heads, cfg.gdn_value_heads
     dk, dv, kw = cfg.gdn_key_dim, cfg.gdn_value_dim, cfg.gdn_key_width
@@ -241,12 +241,9 @@ def _gdn_mixer(u: jax.Array, lp: Params, cfg: Qwen3NextConfig):
             k.astype(cfg.dtype).reshape(B, T, G, dk), v, g, beta,
             chunk=cfg.rule_chunk)
     with jax.named_scope("gdn_norm"):
-        o = o.astype(jnp.float32)
-        o = o * lax.rsqrt((o * o).mean(-1, keepdims=True) + cfg.rms_eps) \
-            * lp["out_norm"]["scale"].astype(jnp.float32)
-        o = o * jax.nn.silu(z.astype(jnp.float32).reshape(B, T, H, dv))
+        o = gated_rms_norm(o, z, lp["out_norm"]["scale"], cfg.rms_eps)
     with jax.named_scope("gdn_out"):
-        out = o.astype(cfg.dtype).reshape(B, T, H * dv) \
+        out = o.reshape(B, T, H * dv) \
             @ lp["out_proj"]["kernel"].astype(cfg.dtype)
     return out, jnp.exp(g).mean(), state
 

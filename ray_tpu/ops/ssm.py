@@ -288,6 +288,186 @@ def causal_conv_silu(x: jax.Array, w: jax.Array) -> jax.Array:
     return jax.nn.silu(causal_conv(x, w, None)[0]).astype(x.dtype)
 
 
+# ------------------------- the gated output norm, one pass each way
+# Gated DeltaNet's ``rms_norm(o over a head's lanes) * scale * silu(z)``
+# (the norm gated AFTER: the published ``Qwen3NextRMSNormGated``).  As XLA
+# runs the four lines of ``_gated_rms_norm_xla`` it passes over float32
+# arrays of the sequence's size, forward, recomputation and backward: 32 ms
+# a step at 2 x 8,192 x 32 heads of 128 where the bytes need 5 (ledger, PR
+# 64).  On a TPU, with bf16 activations, heads of whole 128-lane tiles and
+# whole time blocks, two Pallas kernels do it instead in the conv's manner
+# (``gated_norm_fwd``, ``gated_norm_bwd``): bf16 in and out, float32 only in
+# VMEM, ``NORM_STEP`` rows of one head at a time, a head's mean of squares
+# a lane reduce.  The backward is written out: it keeps ``o``, ``z`` and
+# ``scale`` alone, makes the head's ``rsqrt`` again, and sums ``dscale`` by
+# sublane across a lane block's batch rows and time blocks in its float32
+# result, which the caller folds over sublanes and heads.  Everything else
+# takes the four lines, which are the definition.
+NORM_ROWS = 1024        # positions a time block
+NORM_LANES = 512        # lanes a block, at most: whole heads
+NORM_STEP = 64          # rows of a head worked on at once
+
+
+def _gated_rms_norm_xla(o, z, scale, eps):
+    """o, z (..., H, dv), scale (dv,) -> o's type."""
+    o32 = o.astype(jnp.float32)
+    o32 = o32 * lax.rsqrt((o32 * o32).mean(-1, keepdims=True) + eps) \
+        * scale.astype(jnp.float32)
+    return (o32 * jax.nn.silu(z.astype(jnp.float32))).astype(o.dtype)
+
+
+def _norm_steps(ref, dv, step, rows, carry=None):
+    """``rows(at, carry_h) -> carry_h`` over a (bt, bc) block ``step`` rows
+    of one ``dv``-lane head at a time, ``at`` the index of those rows in the
+    block's references; returns the heads' carries."""
+    from jax.experimental import pallas as pl
+    heads = range(ref.shape[1] // dv)
+
+    def body(i, carry):
+        r = pl.multiple_of(i * step, step)
+        return [rows((pl.ds(r, step), slice(h * dv, (h + 1) * dv)), carry[h])
+                for h in heads]
+
+    return lax.fori_loop(0, ref.shape[0] // step, body,
+                         [carry for _ in heads])
+
+
+def _norm_fwd_kernel(o_ref, z_ref, s_ref, y_ref, *, dv, eps, step):
+    """o_ref, z_ref, y_ref (bt, bc) of whole heads; s_ref (1, dv) float32."""
+    f32 = jnp.float32
+    s = s_ref[...]
+
+    def rows(at, _):
+        o, z = o_ref[at].astype(f32), z_ref[at].astype(f32)
+        n = o * lax.rsqrt((o * o).mean(-1, keepdims=True) + eps) * s
+        y_ref[at] = (n * (z * jax.nn.sigmoid(z))).astype(y_ref.dtype)
+
+    _norm_steps(o_ref, dv, step, rows)
+
+
+def _norm_bwd_kernel(o_ref, z_ref, dy_ref, s_ref, do_ref, dz_ref, ds_ref,
+                     *, dv, eps, step):
+    """do_ref, dz_ref (bt, bc); ds_ref (8, bc) float32, the same block
+    through a lane block's batch rows and time blocks: ``dscale``'s terms
+    summed by sublane."""
+    from jax.experimental import pallas as pl
+    f32 = jnp.float32
+    s = s_ref[...]
+
+    @pl.when((pl.program_id(1) == 0) & (pl.program_id(2) == 0))
+    def _():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+
+    def rows(at, sums):
+        o, z = o_ref[at].astype(f32), z_ref[at].astype(f32)
+        dy = dy_ref[at].astype(f32)
+        r = lax.rsqrt((o * o).mean(-1, keepdims=True) + eps)
+        n = o * r
+        sig = jax.nn.sigmoid(z)
+        gate = z * sig
+        dyn = dy * n
+        dz_ref[at] = (dyn * s * (sig * (1.0 + z * (1.0 - sig)))
+                      ).astype(dz_ref.dtype)
+        # y = n s gate with n = o r: do = r (dn - n mean(dn n))
+        dn = dy * s * gate
+        do_ref[at] = (r * (dn - n * (dn * n).mean(-1, keepdims=True))
+                      ).astype(do_ref.dtype)
+        p = dyn * gate
+        return sums + sum(p[8 * j:8 * j + 8] for j in range(step // 8))
+
+    sums = _norm_steps(o_ref, dv, step, rows, jnp.zeros((8, dv), f32))
+    ds_ref[...] += jnp.concatenate(sums, axis=1)
+
+
+@functools.lru_cache(maxsize=None)
+def _norm_kernel(name, B, T, C, dv, eps, dtype, bt, bc, step, interpret):
+    """``gated_norm_fwd`` (o, z, scale -> y) or ``gated_norm_bwd`` (o, z,
+    dy, scale -> do, dz, dscale's sums (8, C) float32) at one shape: grid
+    (lane block, batch row, time block) of (bt, bc) blocks, scale (1, dv)
+    float32.  Inside a jitted function of the kernel's name, as
+    ``_conv_kernel``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    back = name == "gated_norm_bwd"
+    seq = jax.ShapeDtypeStruct((B, T, C), dtype)
+    block = pl.BlockSpec((None, bt, bc), lambda c, b, t: (b, t, c))
+    scale = pl.BlockSpec((1, dv), lambda c, b, t: (0, 0))
+    sums = (jax.ShapeDtypeStruct((8, C), jnp.float32),
+            pl.BlockSpec((8, bc), lambda c, b, t: (0, c)))
+    body = _norm_bwd_kernel if back else _norm_fwd_kernel
+
+    def run(*args):
+        return pl.pallas_call(
+            functools.partial(body, dv=dv, eps=eps, step=step),
+            grid=(C // bc, B, T // bt),
+            in_specs=[block] * (3 if back else 2) + [scale],
+            out_specs=[block, block, sums[1]] if back else block,
+            out_shape=[seq, seq, sums[0]] if back else seq,
+            compiler_params=pltpu.CompilerParams(dimension_semantics=(
+                ("parallel", "arbitrary", "arbitrary") if back
+                else ("parallel",) * 3)),
+            interpret=interpret, name=name)(*args)
+    run.__name__ = run.__qualname__ = name
+    return jax.jit(run)
+
+
+def _norm_call(name, o, scale, eps, interpret, *args):
+    """Kernel ``name`` at the shape of o (B, T, C) and scale (dv,), on
+    ``args`` and the scale as the kernels read it."""
+    dv = scale.shape[0]
+    bc = dv * math.gcd(o.shape[2] // dv, max(NORM_LANES // dv, 1))
+    return _norm_kernel(name, *o.shape, dv, eps, jnp.dtype(o.dtype),
+                        NORM_ROWS, bc, min(NORM_STEP, NORM_ROWS), interpret)(
+        *args, scale.astype(jnp.float32)[None])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _gated_norm_kernels(o, z, scale, eps, interpret):
+    """:func:`gated_rms_norm` on (B, T, C) in the kernels, whatever the
+    backend."""
+    return _norm_call("gated_norm_fwd", o, scale, eps, interpret, o, z)
+
+
+def _gated_norm_fwd(o, z, scale, eps, interpret):
+    return _gated_norm_kernels(o, z, scale, eps, interpret), (o, z, scale)
+
+
+def _gated_norm_bwd(eps, interpret, res, dy):
+    o, z, scale = res
+    do, dz, sums = _norm_call("gated_norm_bwd", o, scale, eps, interpret,
+                              o, z, dy)
+    return do, dz, sums.reshape(-1, scale.shape[0]).sum(0).astype(scale.dtype)
+
+
+_gated_norm_kernels.defvjp(_gated_norm_fwd, _gated_norm_bwd)
+
+
+def _norm_kernels_run(o, z, scale) -> bool:
+    """Whether a call runs in the kernels: on a TPU, bf16 activations,
+    heads of whole 128-lane tiles, whole time blocks.  Everything else
+    (float32, the tests' narrow heads, a ragged T, the CPU) takes the XLA
+    form."""
+    return (jax.default_backend() == "tpu"
+            and o.dtype == z.dtype == jnp.bfloat16
+            and scale.shape[0] % 128 == 0 and o.shape[1] % NORM_ROWS == 0)
+
+
+def gated_rms_norm(o: jax.Array, z: jax.Array, scale: jax.Array,
+                   eps: float) -> jax.Array:
+    """``rms_norm(o over each head's dv lanes, eps) * scale * silu(z)`` in
+    o's type and shape: o, z (B, T, H dv) or (B, T, H, dv), scale (dv,).
+    float32 arithmetic and one rounding on the way out, in both forms;
+    which runs is read from the call (``_norm_kernels_run``)."""
+    B, T, dv = *o.shape[:2], scale.shape[0]
+    if _norm_kernels_run(o, z, scale):
+        y = _gated_norm_kernels(o.reshape(B, T, -1), z.reshape(B, T, -1),
+                                scale, eps, False)
+    else:
+        y = _gated_rms_norm_xla(o.reshape(B, T, -1, dv),
+                                z.reshape(B, T, -1, dv), scale, eps)
+    return y.reshape(o.shape)
+
+
 # --------------------------------------------------------------------- scan
 def _grouped(a: jax.Array, groups: int) -> jax.Array:
     """(..., H, *rest) -> (..., G, H/G, *rest) on the axis after batch and

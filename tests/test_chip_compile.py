@@ -313,6 +313,63 @@ def test_the_fused_conv_compiles_at_qwen3_next_train_shape(v5e, monkeypatch,
         assert "f32[2,8195,8192]" not in text
 
 
+def _norm_loss(o, z, scale):
+    """The mixer's gated output norm under a cotangent that differs by
+    head and by lane, as ``gdn_out`` hands one back."""
+    from ray_tpu.ops.ssm import gated_rms_norm
+    y = gated_rms_norm(o, z, scale, 1e-6).astype(jnp.float32)
+    return (y * jnp.arange(4096, dtype=jnp.float32)).sum()
+
+
+@pytest.mark.parametrize("dtype", [
+    pytest.param(jnp.bfloat16, id="bf16_in_kernels"),
+    pytest.param(jnp.float32, id="float32_in_xla")])
+def test_the_gated_norm_compiles_at_qwen3_next_train_shape(v5e, monkeypatch,
+                                                           dtype, capsys):
+    """``ops/ssm.gated_rms_norm`` forward, and forward and backward, at
+    2 x 8,192 positions of 32 heads of 128, on a TPU.  In bf16 (the cell)
+    exactly ``gated_norm_fwd`` and ``gated_norm_bwd`` under their own
+    names, which ``gdn.norm_kernel_ms`` reads and the ``tpu_custom_call``
+    metrics do not, their first results in that metric's ``shapes``; no
+    float32 array of the sequence's size is left, and what is kept for the
+    backward is o, z and the scale alone.  Float32 activations take the
+    XLA form at the same shape: no kernel."""
+    import json
+    from pathlib import Path
+    from jax.ad_checkpoint import print_saved_residuals
+    from ray_tpu.ops.ssm import gated_rms_norm
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in
+            (((2, 8192, 4096), dtype), ((2, 8192, 4096), dtype),
+             ((128,), jnp.float32))]
+    texts = [jax.jit(fn).lower(*args).compile().as_text()
+             for fn in (lambda o, z, s: gated_rms_norm(o, z, s, 1e-6),
+                        jax.grad(_norm_loss, argnums=(0, 1, 2)))]
+    if dtype == jnp.float32:
+        assert not [t for t in texts if "tpu_custom_call" in t]
+        return
+    kernels = [_kernel_names_and_results(t) for t in texts]
+    assert [[(name.split(".")[0], shape) for name, shape in ks]
+            for ks in kernels] == [
+        [("gated_norm_fwd", "bf16[2,8192,4096]")],
+        [("gated_norm_bwd", "bf16[2,8192,4096]")]], kernels
+    spec = json.loads((Path(__file__).parent.parent / "perfbench"
+                       / "layer_metrics" / "gdn.norm_kernel_ms.json"
+                       ).read_text())["params"]
+    for name, shape in kernels[0] + kernels[1]:
+        assert shape in spec["shapes"]
+        assert any(part in name for part in spec["names"])
+        assert "tpu_custom_call" not in name
+    for text in texts:
+        assert not re.search(r"f32\[2,8192,(4096|32,128)\]", text)
+    capsys.readouterr()
+    print_saved_residuals(lambda o, z, scale: gated_rms_norm(
+        o, z, scale, 1e-6), *args)
+    kept = capsys.readouterr().out.splitlines()
+    assert [line.split(" from ")[1] for line in kept] == [
+        "the argument o", "the argument z", "the argument scale"], kept
+
+
 def _latent(*parts):
     from ray_tpu.ops.flash_attention import latent_flash_attention
     return latent_flash_attention(*parts, None, False)

@@ -428,3 +428,70 @@ def test_the_mixers_conv_in_its_kernels_under_a_checkpoint(monkeypatch):
         assert np.abs(b).max() > 0, key
         np.testing.assert_allclose(a, b, atol=0.03 * np.abs(b).max(),
                                    err_msg=key)
+
+
+# ------------------------------------------ the gated output norm's entry
+def _four_lines(o, z, scale, eps):
+    """The mixer's gated output norm as it stood in ``_gdn_mixer`` before
+    ``ops/ssm.gated_rms_norm``, with the rounding ``gdn_out`` carried."""
+    dtype = o.dtype
+    o = o.astype(jnp.float32)
+    o = o * jax.lax.rsqrt((o * o).mean(-1, keepdims=True) + eps) \
+        * scale.astype(jnp.float32)
+    o = o * jax.nn.silu(z.astype(jnp.float32).reshape(o.shape))
+    return o.astype(dtype)
+
+
+@pytest.mark.parametrize("form", ["the_xla_form", "the_kernels"])
+def test_the_mixer_with_the_norms_entry_is_the_four_lines(monkeypatch, form):
+    """``_gdn_mixer`` under ``jax.checkpoint`` and ``jax.grad`` with
+    ``ops/ssm.gated_rms_norm`` beside the same mixer with the four lines
+    written out.  As the CPU takes the entry (float32, 12-wide heads) the
+    two are the same bits, value and every gradient.  As the cell takes it
+    (bf16, 4 value heads of 128, two time blocks; the kernels' interpret
+    mode here) the forward kernel runs twice (the pass and its
+    recomputation) and the backward once, and everything stays the four
+    lines' to bf16's rounding, the scale's gradient among them."""
+    from ray_tpu.ops import ssm
+    kernels = form == "the_kernels"
+    cfg = dataclasses.replace(CFG, gdn_value_dim=128, dtype=jnp.bfloat16) \
+        if kernels else CFG
+    lp = _layer(random_tree(cfg, seed=8))
+    u = jax.random.normal(jax.random.key(14), (2, 64, cfg.n_embd)) \
+        .astype(cfg.dtype)
+    probe = jax.random.normal(jax.random.key(15), u.shape)
+
+    def loss(u, lp):
+        out = jax.checkpoint(lambda u, lp: qn._gdn_mixer(u, lp, cfg)[0])(u, lp)
+        return (out.astype(jnp.float32) * probe).sum()
+
+    run = jax.value_and_grad(loss, argnums=(0, 1))
+    if kernels:
+        monkeypatch.setattr(ssm, "NORM_ROWS", 32)
+        monkeypatch.setattr(ssm, "NORM_STEP", 16)
+        monkeypatch.setattr(
+            ssm, "gated_rms_norm", lambda o, z, s, eps:
+            ssm._gated_norm_kernels(o.reshape(z.shape), z, s, eps, True)
+            .reshape(o.shape))
+        program = str(jax.make_jaxpr(run)(u, lp))
+        calls = re.findall(r"jit\[\s*name=(gated_norm_\w+)", program)
+        assert sorted(calls) == ["gated_norm_bwd"] + ["gated_norm_fwd"] * 2
+    got, got_grads = run(u, lp)
+    monkeypatch.setattr(ssm, "gated_rms_norm", _four_lines)
+    want, want_grads = run(u, lp)
+    got_grads, want_grads = _flat(got_grads), _flat(want_grads)
+    assert np.abs(np.float32(want_grads[
+        next(key for key in want_grads if "out_norm" in key)])).max() > 0
+    if not kernels:
+        assert float(got) == float(want)
+        for key, b in want_grads.items():
+            np.testing.assert_array_equal(np.float32(got_grads[key]),
+                                          np.float32(b), err_msg=key)
+        return
+    assert abs(float(got) - float(want)) < 0.02 * abs(float(want)) + 0.05
+    for key, b in want_grads.items():
+        a, b = np.float32(got_grads[key]), np.float32(b)
+        if np.abs(b).max() == 0:
+            continue                    # the mixer reads none of the MoE's
+        np.testing.assert_allclose(a, b, atol=0.03 * np.abs(b).max(),
+                                   err_msg=key)
