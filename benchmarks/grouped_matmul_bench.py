@@ -27,14 +27,31 @@ of skews, the counts a step's layers really saw (a JSON file with a list
 experts trained alone draws the router onto itself, and the rows a group
 holds grow sevenfold inside one window of the Qwen3-Next cell).
 
+``--stack L`` (PR 66): beside each megablox measurement, the same three
+products as a training scan's layer calls them since PR 66
+(``ops/moe._megablox_at``): the weights are a STACK of ``L`` layers' held
+experts, ``(L x H, d, f)``, read in place, and the counts are one layer's
+(the middle one's) laid among the stack's groups, every other group empty
+(``ops/moe._sizes_in_stack``); the weights' gradient is the layer's own
+``tgmm`` either way.  What the empty groups cost is the difference of the
+two lines.  With no ``--tilings`` it measures the tile ``gmm_tiling``
+picks for the shape:
+
+    chiprun -- python benchmarks/grouped_matmul_bench.py --stack 3 \
+        --skews even                                        # OLMoE
+    ... --stack 7 --rows 98304 --groups 128 --held 16 --d 2048 --f 768
+    ... --stack 3 --rows 163840 --groups 512 --held 64 --d 2048 --f 512
+
 Prints one JSON line a measurement and writes them all to
 ``chiprun_out/grouped_matmul_bench.jsonl`` (``--out``).  Each line has a
 product's host-clock time (``*_ms``), the kernel's own device time from a
 profiler capture (``*_kernel_ms``: what a cell's ``moe.*_peak_share``
-divides, apart from XLA's passes round a kernel that stands alone in its
-program) and, for megablox, ``visits`` (the (group, row tile) overlaps
-the kernel's grid walks, ``ops/moe.gmm_visits``)
-and ``fill`` (held rows / (visits x row tile): the share of the rows
+divides), every other device operation's of the call
+(``*_other_device_ms``: XLA's passes round a kernel that stands alone in
+its program, and the operations that make megablox's metadata from the
+counts, which is where a stack's empty groups cost) and, for megablox,
+``visits`` (the (group, row tile) overlaps the kernel's grid walks,
+``ops/moe.gmm_visits``) and ``fill`` (held rows / (visits x row tile): the share of the rows
 multiplied that lay in the visit's own group).  Fails off the chip: a
 time from a CPU is no device number.
 """
@@ -55,7 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from attention_bench import device_seconds  # noqa: E402
-from ray_tpu.ops.moe import gmm_visits  # noqa: E402
+from ray_tpu.ops.moe import _sizes_in_stack, gmm_tiling, gmm_visits  # noqa: E402
 
 PEAK = 197e12       # v5e bf16, perfbench/peaks.json
 
@@ -83,12 +100,15 @@ def timed(fn, *args, iters: int = 10):
 
 
 def kernel_seconds(fn, *args):
-    """The grouped-matmul kernels' own device seconds a call of ``fn``."""
+    """-> (the grouped-matmul kernels' own device seconds a call of ``fn``,
+    every other device operation's: the counts' metadata, XLA's zeroing
+    pass round a kernel over a held share)."""
     name, top, others = device_seconds(fn, *args)
     ops = [(name, top), *others]
     own = [s for n, s in ops
            if any(part in n.lower() for part in ("gmm", "custom", "ragged"))]
-    return sum(own) if own else top
+    kernels = sum(own) if own else top
+    return kernels, sum(s for _, s in ops) - kernels
 
 
 TILINGS = ((128, 128, 128), (512, 512, 512), (512, 1024, 1024),
@@ -130,6 +150,20 @@ def megablox_products(tiling, held):
     return fwd, dlhs, drhs
 
 
+def stacked_products(tiling, held, layers):
+    """``megablox_products`` as ``ops/moe._megablox_at`` calls them: ``w``
+    holds ``layers`` layers' held groups and the counts ``gs`` are the
+    middle layer's."""
+    fwd, dlhs, drhs = megablox_products(tiling, held)
+
+    def in_stack(gs):
+        return _sizes_in_stack(gs, held, layers * held,
+                               jnp.int32(layers // 2))
+
+    return (lambda x, w, gs: fwd(x, w, in_stack(gs)),
+            lambda dy, w, gs: dlhs(dy, w, in_stack(gs)), drhs)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rows", type=int, default=65536)
@@ -148,6 +182,10 @@ def main() -> None:
     ap.add_argument("--tilings", nargs="+", default=None,
                     help="megablox tiles 'rows,d,f' (default PR 27's four)")
     ap.add_argument("--no-ragged-dot", action="store_true")
+    ap.add_argument("--stack", type=int, default=0,
+                    help="also measure megablox over a stack of this many "
+                    "layers' held experts read in place, one layer's "
+                    "groups filled (ops/moe._megablox_at)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="chiprun_out/grouped_matmul_bench.jsonl")
     args = ap.parse_args()
@@ -159,10 +197,8 @@ def main() -> None:
         else ((args.d, args.f),)
     tilings = TILINGS if args.tilings is None else tuple(
         tuple(int(t) for t in text.split(",")) for text in args.tilings)
-    impls = [] if args.no_ragged_dot else [
-        ("ragged_dot", None, ragged_dot_products(held))]
-    impls += [(f"megablox{t}", t, megablox_products(t, held))
-              for t in tilings]
+    ragged = [] if args.no_ragged_dot or args.stack else [
+        ("ragged_dot", None, 1, ragged_dot_products(held))]
     rng = np.random.default_rng(args.seed)
     out = Path(args.out)
     out.parent.mkdir(parents=True, exist_ok=True)
@@ -183,11 +219,22 @@ def main() -> None:
             key = jax.random.key(args.seed)
             x = jax.random.normal(key, (args.rows, d), jnp.bfloat16)
             dy = jax.random.normal(key, (args.rows, f), jnp.bfloat16)
-            w = jax.random.normal(key, (held, d, f), jnp.bfloat16)
+            w = jax.random.normal(key, (max(args.stack, 1) * held, d, f),
+                                  jnp.bfloat16)
+            w_layer = w[:held]          # what a call without a stack reads
             flops = 2.0 * held_rows * d * f
             dense = timed(jax.jit(lambda a, b: a @ b), x, w[0])
-            for name, tiling, (fwd, dlhs, drhs) in impls:
+            chosen = tilings if args.tilings or not args.stack \
+                else (gmm_tiling(args.rows, d, f),)
+            impls = ragged + [(f"megablox{t}", t, 1,
+                               megablox_products(t, held)) for t in chosen]
+            impls += [(f"megablox{t}", t, args.stack,
+                       stacked_products(t, held, args.stack))
+                      for t in chosen if args.stack]
+            for name, tiling, layers, (fwd, dlhs, drhs) in impls:
+                w_read = w if layers > 1 else w_layer
                 row = {"impl": name, "rows": args.rows, "groups": args.groups,
+                       "stack": layers,
                        "held": held, "held_rows": held_rows, "d": d, "f": f,
                        "skew": skew, "seed": args.seed,
                        "device": jax.devices()[0].device_kind,
@@ -197,15 +244,16 @@ def main() -> None:
                 if tiling:
                     row["visits"] = int(gmm_visits(gs_host, held, tiling[0]))
                     row["fill"] = held_rows / max(row["visits"] * tiling[0], 1)
-                for what, fn, a in (("fwd", fwd, (x, w, gs)),
-                                    ("dlhs", dlhs, (dy, w, gs)),
+                for what, fn, a in (("fwd", fwd, (x, w_read, gs)),
+                                    ("dlhs", dlhs, (dy, w_read, gs)),
                                     ("drhs", drhs, (x, dy, gs))):
                     try:
                         fn = jax.jit(fn)
                         s = timed(fn, *a)
-                        kernel = kernel_seconds(fn, *a)
+                        kernel, rest = kernel_seconds(fn, *a)
                         row[f"{what}_ms"] = s * 1e3
                         row[f"{what}_kernel_ms"] = kernel * 1e3
+                        row[f"{what}_other_device_ms"] = rest * 1e3
                         row[f"{what}_peak_share"] = \
                             100 * flops / kernel / PEAK
                     except Exception as e:  # noqa: BLE001 - a tiling

@@ -158,6 +158,24 @@ def remat_block(block: Callable, policy: str, flash_runs: bool) -> Callable:
         block, policy=jax.checkpoint_policies.save_only_these_names(*names))
 
 
+def experts_in_place(experts: Dict[str, jax.Array]) -> Tuple[jax.Array, ...]:
+    """A stack's expert leaves ``w_gate``, ``w_up``, ``w_down``, each
+    (layers, H, ...), as a training scan's body takes them beside its own
+    slice: whole, ``(layers x H, ...)`` (a bitcast), gradient stopped.
+
+    The grouped matmuls are kernels, and a kernel's operand is a buffer: a
+    layer's experts sliced out of their stack by the scan were copied whole
+    once in the forward loop and once in the backward's (805 MB a layer a
+    loop at OLMoE's widths, 14 of a 250 ms step: PERF.md, PR 66).  So the
+    body closes over these and hands them on with the layer's index
+    (``ops/moe.dropless_experts``' ``stack``): the kernels read the layer's
+    groups where they lie, the scan's own slice is read by nothing, is
+    dropped from both loops, and is where the layer's gradient goes, in the
+    layer's shape.  A constant of both loops, not a residual a layer."""
+    return tuple(lax.stop_gradient(experts[name]).reshape(
+        -1, *experts[name].shape[2:]) for name in ("w_gate", "w_up", "w_down"))
+
+
 def split_batch(batch: Dict[str, jax.Array]) -> Tuple[jax.Array, jax.Array]:
     """A training batch's (inputs, targets), each (B, T): the pair as
     given, or ``{"tokens": (B, T+1)}`` shifted by one."""

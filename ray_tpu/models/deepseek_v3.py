@@ -37,15 +37,15 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models._common import (  # noqa: F401
-    _rms_norm, _rope_interleaved, next_token_nll, normal_init, remat_block,
-    split_batch)
+    _rms_norm, _rope_interleaved, experts_in_place, next_token_nll,
+    normal_init, remat_block, split_batch)
 
 Params = Dict[str, Any]
 
@@ -188,9 +188,12 @@ def _swiglu(h: jax.Array, lp: Params, cfg: DeepseekV3Config) -> jax.Array:
     return (gate * up) @ lp["w_down"]["kernel"].astype(cfg.dtype)
 
 
-def _experts(h: jax.Array, lp: Params, cfg: DeepseekV3Config):
+def _experts(h: jax.Array, lp: Params, cfg: DeepseekV3Config,
+             stack: Optional[tuple] = None):
     """Normed hidden states (B, T, E) -> (the held routed experts' part
-    plus the shared expert, HeldStats)."""
+    plus the shared expert, HeldStats).  ``stack``:
+    (``_common.experts_in_place`` of the sparse stack, this layer's index
+    in it, traced), beside ``lp``'s own slice."""
     from ray_tpu.ops.moe import dropless_moe_ffn
     with jax.named_scope("moe"):
         ex, router = lp["experts"], lp["router"]
@@ -198,13 +201,15 @@ def _experts(h: jax.Array, lp: Params, cfg: DeepseekV3Config):
             h.reshape(-1, h.shape[-1]), router["kernel"], ex["w_gate"],
             ex["w_up"], ex["w_down"], k=cfg.experts_per_token,
             scoring="sigmoid", select_bias=router["select_bias"],
-            weight_scale=cfg.routed_scale, first_held=cfg.first_held_expert)
+            weight_scale=cfg.routed_scale, first_held=cfg.first_held_expert,
+            stack=stack)
         with jax.named_scope("shared"):
             shared = _swiglu(h, lp["shared"], cfg)
     return routed.reshape(h.shape) + shared, stats
 
 
-def _block(x: jax.Array, lp: Params, cfg: DeepseekV3Config, sparse: bool):
+def _block(x: jax.Array, lp: Params, cfg: DeepseekV3Config, sparse: bool,
+           stack: Optional[tuple] = None):
     """One decoder block -> (out, HeldStats | None).  The layer kinds
     shared with the other decoders run under GPT-2's scope names (ln_1,
     attn_qkv, attn_out, ln_2, mlp; models/gpt2.py)."""
@@ -214,7 +219,7 @@ def _block(x: jax.Array, lp: Params, cfg: DeepseekV3Config, sparse: bool):
     with jax.named_scope("ln_2"):
         h = _rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
     if sparse:
-        f, stats = _experts(h, lp, cfg)
+        f, stats = _experts(h, lp, cfg, stack)
         return x + f, stats
     with jax.named_scope("mlp"):
         return x + _swiglu(h, lp, cfg), None
@@ -227,12 +232,20 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: DeepseekV3Config):
         x = params["wte"].astype(cfg.dtype)[tokens]
     stats = None
     for name, sparse in (("dense_blocks", False), ("moe_blocks", True)):
+        blocks = params[name]
         block = partial(_block, cfg=cfg, sparse=sparse)
+        if sparse:
+            # the kernels read a layer's experts in the stack, in place
+            whole, blocks = (experts_in_place(blocks["experts"]),
+                             (blocks, jnp.arange(cfg.n_sparse_layer)))
+
+            def block(x, xs):
+                return _block(x, xs[0], cfg, True, (whole, xs[1]))
         if cfg.remat:
             from ray_tpu.ops.attention import flash_runs
             block = remat_block(block, cfg.remat_policy,
                                 flash_runs(tokens.shape[1], cfg.attn_impl))
-        x, stats = lax.scan(block, x, params[name])
+        x, stats = lax.scan(block, x, blocks)
     with jax.named_scope("ln_f"):
         return _rms_norm(x, params["norm_f"]["scale"], cfg.rms_eps), stats
 

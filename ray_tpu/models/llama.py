@@ -33,8 +33,8 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models._common import (  # noqa: F401
-    _gqa_expand, _rms_norm, _rope, _rope_at, next_token_nll, normal_init,
-    param_count, remat_block, split_batch)
+    _gqa_expand, _rms_norm, _rope, _rope_at, experts_in_place,
+    next_token_nll, normal_init, param_count, remat_block, split_batch)
 
 Params = Dict[str, Any]
 
@@ -277,7 +277,8 @@ def _qkv(h: jax.Array, lp: Params, cfg: LlamaConfig):
 
 
 def _ffn(h: jax.Array, lp: Params, cfg: LlamaConfig,
-         served: Optional[tuple] = None, live: Optional[jax.Array] = None):
+         served: Optional[tuple] = None, live: Optional[jax.Array] = None,
+         stack: Optional[tuple] = None):
     """The block's feed-forward on normed hidden states (..., E): one
     SwiGLU, or the dropless experts.  Returns (out, RouterStats | None);
     training, prefill and decode all come through here.  ``served`` (a
@@ -286,7 +287,9 @@ def _ffn(h: jax.Array, lp: Params, cfg: LlamaConfig,
     traced), and the second result is then the chosen expert ids, (rows,
     k) int32, from the routing that made ``out``; ``live`` (rows,) bool: a
     decode step's rows that are some sequence's
-    (``ops/moe.choice_of_live_rows``)."""
+    (``ops/moe.choice_of_live_rows``).  ``stack`` (a training forward):
+    (``_common.experts_in_place`` of the stack, this layer's index,
+    traced), beside ``lp``'s own slice."""
     if cfg.n_experts:
         from ray_tpu.ops.moe import dropless_moe_ffn
         ex, layer = served or (lp["experts"], None)
@@ -294,7 +297,7 @@ def _ffn(h: jax.Array, lp: Params, cfg: LlamaConfig,
             h.reshape(-1, h.shape[-1]), lp["router"]["kernel"],
             ex["w_gate"], ex["w_up"], ex["w_down"], k=cfg.experts_per_token,
             norm_topk=cfg.norm_topk, choices=served is not None, live=live,
-            stack_at=layer)
+            stack_at=layer, stack=stack)
         return out.reshape(h.shape), told[-1]        # the stats, or the ids
     with jax.named_scope("mlp"):
         gate = jax.nn.silu(h @ lp["w_gate"]["kernel"].astype(cfg.dtype))
@@ -315,7 +318,9 @@ def _split_experts(blocks: Params, cfg: LlamaConfig):
     scan's slices: all layers' experts are ONE run of groups, a layer names
     its own as groups ``layer x X .. layer x X + X - 1`` and the other
     layers' groups are empty (``ops/moe.dropless_moe_ffn``'s ``stack_at``).
-    Training scans the stack as it did."""
+    Training reads the stack in place too, by a road that keeps a layer's
+    gradient in the layer's shape: :func:`forward_hidden` scans the whole
+    stack and hands ``_common.experts_in_place`` beside each slice."""
     if not cfg.n_experts:
         return blocks, None
     sliced = {k: v for k, v in blocks.items() if k != "experts"}
@@ -327,7 +332,7 @@ def _split_experts(blocks: Params, cfg: LlamaConfig):
 
 def _block(x: jax.Array, lp: Params, cfg: LlamaConfig,
            collect_kv: bool = False, choices: bool = False,
-           served: Optional[tuple] = None):
+           served: Optional[tuple] = None, stack: Optional[tuple] = None):
     """One decoder block -> (out, RouterStats | None); with ``collect_kv``
     -> (out, (k, v)), post-RoPE and pre-GQA-expand: the SAME body serves
     training and the serving engine's prefill cache fill, so the paths
@@ -353,7 +358,7 @@ def _block(x: jax.Array, lp: Params, cfg: LlamaConfig,
         x = x + a @ lp["wo"]["kernel"].astype(cfg.dtype)
     with jax.named_scope("ln_2"):
         h = _rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
-    f, stats = _ffn(h, lp, cfg, served)
+    f, stats = _ffn(h, lp, cfg, served, stack=stack)
     out = x + f
     if collect_kv:
         return out, ((k, v, stats) if choices else (k, v))
@@ -365,13 +370,21 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig):
     cfg.dtype, the layers' RouterStats stacked on a leading n_layer axis,
     or None for a dense model)."""
     x = _embed(params, tokens, cfg)
+    blocks = params["blocks"]
     block = partial(_block, cfg=cfg)
+    if cfg.n_experts:
+        # the kernels read a layer's experts in the stack, in place
+        whole, blocks = (experts_in_place(blocks["experts"]),
+                         (blocks, jnp.arange(cfg.n_layer)))
+
+        def block(x, xs):
+            return _block(x, xs[0], cfg, stack=(whole, xs[1]))
     if cfg.remat:
         from ray_tpu.ops.attention import flash_runs
         block = remat_block(block, cfg.remat_policy,
                             flash_runs(tokens.shape[1], cfg.attn_impl))
 
-    x, stats = lax.scan(block, x, params["blocks"])
+    x, stats = lax.scan(block, x, blocks)
     return _final_norm(params, x, cfg), stats
 
 

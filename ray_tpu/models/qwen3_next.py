@@ -51,16 +51,15 @@ forwards (rows of state and conv tails in the cache) are not written.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models._common import (  # noqa: F401
-    _gqa_expand, _rope, next_token_nll, normal_init, remat_block,
-    split_batch)
+    _gqa_expand, _rope, experts_in_place, next_token_nll, normal_init,
+    remat_block, split_batch)
 
 Params = Dict[str, Any]
 
@@ -289,9 +288,12 @@ def _swiglu(h: jax.Array, lp: Params, cfg: Qwen3NextConfig) -> jax.Array:
     return (gate * up) @ lp["w_down"]["kernel"].astype(cfg.dtype)
 
 
-def _experts(h: jax.Array, lp: Params, cfg: Qwen3NextConfig):
+def _experts(h: jax.Array, lp: Params, cfg: Qwen3NextConfig,
+             stack: Optional[tuple] = None):
     """Normed hidden states (B, T, E) -> (the held routed experts' part
-    plus the gated shared expert, HeldStats)."""
+    plus the gated shared expert, HeldStats).  ``stack``:
+    (``_common.experts_in_place`` of the layer's stack, its index in it,
+    traced), beside ``lp``'s own slice."""
     from ray_tpu.ops.moe import HeldStats, dropless_moe_ffn
     with jax.named_scope("moe"):
         ex = lp["experts"]
@@ -299,7 +301,7 @@ def _experts(h: jax.Array, lp: Params, cfg: Qwen3NextConfig):
             h.reshape(-1, h.shape[-1]), lp["router"]["kernel"], ex["w_gate"],
             ex["w_up"], ex["w_down"], k=cfg.experts_per_token,
             scoring="softmax", norm_topk=True,
-            first_held=cfg.first_held_expert)
+            first_held=cfg.first_held_expert, stack=stack)
         if not isinstance(stats, HeldStats):     # every expert is held
             rows = h.size // h.shape[-1] * cfg.experts_per_token
             stats = HeldStats(jnp.float32(rows), stats.load_max_over_mean,
@@ -313,7 +315,8 @@ def _experts(h: jax.Array, lp: Params, cfg: Qwen3NextConfig):
     return routed.reshape(h.shape) + shared, stats
 
 
-def _block(x: jax.Array, lp: Params, cfg: Qwen3NextConfig, kind: str):
+def _block(x: jax.Array, lp: Params, cfg: Qwen3NextConfig, kind: str,
+           stack: Optional[tuple] = None):
     """One decoder block -> (out, (HeldStats, mean decay | None)).  The
     norms run under GPT-2's scope names (ln_1, ln_2; models/gpt2.py)."""
     with jax.named_scope("ln_1"):
@@ -326,7 +329,7 @@ def _block(x: jax.Array, lp: Params, cfg: Qwen3NextConfig, kind: str):
     x = x + mixed
     with jax.named_scope("ln_2"):
         h = _rms0(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
-    f, stats = _experts(h, lp, cfg)
+    f, stats = _experts(h, lp, cfg, stack)
     return x + f, (stats, decay)
 
 
@@ -337,8 +340,13 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: Qwen3NextConfig):
     (periods,)))."""
     with jax.named_scope("embed"):
         x = params["wte"].astype(cfg.dtype)[tokens]
-    blocks = {kind: partial(_block, cfg=cfg, kind=kind)
-              for kind in ("gdn", "attn")}
+
+    def block_of(kind):
+        # the kernels read a layer's experts in its stack, in place
+        whole = experts_in_place(params[f"{kind}_blocks"]["experts"])
+        return lambda x, xs: _block(x, xs[0], cfg, kind, (whole, xs[1]))
+
+    blocks = {kind: block_of(kind) for kind in ("gdn", "attn")}
     if cfg.remat:
         # the attention block as the other decoders' (``attn`` keeps the
         # flash kernel's output and lse); a DeltaNet block has none of
@@ -356,12 +364,15 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: Qwen3NextConfig):
         lambda a: a.reshape(cfg.n_period, cfg.attn_interval - 1,
                             *a.shape[1:]), params["gdn_blocks"])
 
-    def period(x, lps):
-        x, gdn_stats = lax.scan(blocks["gdn"], x, lps[0])
-        x, (attn_stats, _) = blocks["attn"](x, lps[1])
+    def period(x, xs):
+        lps, at = xs
+        x, gdn_stats = lax.scan(blocks["gdn"], x, (lps[0], at[0]))
+        x, (attn_stats, _) = blocks["attn"](x, (lps[1], at[1]))
         return x, (gdn_stats, attn_stats)
 
-    x, stats = lax.scan(period, x, (gdn, params["attn_blocks"]))
+    layers = (jnp.arange(cfg.n_layer - cfg.n_period).reshape(
+        cfg.n_period, -1), jnp.arange(cfg.n_period))
+    x, stats = lax.scan(period, x, ((gdn, params["attn_blocks"]), layers))
     with jax.named_scope("ln_f"):
         return _rms0(x, params["norm_f"]["scale"], cfg.rms_eps), stats
 

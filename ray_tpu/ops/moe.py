@@ -663,12 +663,73 @@ def _megablox_bwd(res, g):
 _megablox.defvjp(_megablox_fwd, _megablox_bwd)
 
 
-def grouped_matmul(rows: jax.Array, w: jax.Array,
-                   group_sizes: jax.Array) -> jax.Array:
+def _sizes_in_stack(group_sizes, held: int, stack_groups: int, at):
+    """A layer's counts (E,) as counts over the groups of its whole stack:
+    the ``held`` leading ones at groups ``at x held ..``, every other
+    layer's group empty (an empty group costs ``gmm`` no grid step).  Where
+    the layer holds a share, one more group stands behind the stack's, as
+    the groups not held stand behind ``w``'s in ``_megablox``: megablox then
+    zeroes the rows that no held group covers."""
+    sizes = jnp.zeros(stack_groups + (held < group_sizes.shape[0]),
+                      group_sizes.dtype)
+    return jax.lax.dynamic_update_slice(sizes, group_sizes[:held],
+                                        (at * held,))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _megablox_at(rows, w, stack, sizes_in_stack, group_sizes, held):
+    """``_megablox`` for a layer whose ``held`` matrices ``w`` (H, d, f) are
+    one layer of ``stack`` (layers x H, d, f), the same leaf whole.  A
+    kernel's operand is a buffer, so a slice of the stack handed to it is
+    copied whole; every product here that reads the weights reads ``stack``
+    in place instead, under the layer's counts laid among the stack's
+    groups (``_sizes_in_stack``).  ``w``'s VALUE IS NEVER READ: it is the
+    operand the layer's cotangent goes to, one ``tgmm`` result of ``w``'s
+    own shape over the layer's own counts, as in ``_megablox``; a slice
+    nothing reads is dead code in a scan's body.  ``stack`` gets no
+    cotangent (the caller stops its gradient)."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    (m, d), f = rows.shape, stack.shape[-1]
+    return gmm(rows, stack, sizes_in_stack, rows.dtype,
+               gmm_tiling(m, d, f, rows.dtype.itemsize))
+
+
+def _megablox_at_fwd(rows, w, stack, sizes_in_stack, group_sizes, held):
+    return (_megablox_at(rows, w, stack, sizes_in_stack, group_sizes, held),
+            (rows, stack, sizes_in_stack, group_sizes))
+
+
+def _megablox_at_bwd(held, res, g):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+    rows, stack, sizes_in_stack, group_sizes = res
+    (m, d), f, size = rows.shape, stack.shape[-1], rows.dtype.itemsize
+    d_rows = gmm(g, stack, sizes_in_stack, rows.dtype,
+                 gmm_tiling(m, f, d, size), transpose_rhs=True)
+    d_w = tgmm(rows.swapaxes(0, 1), g, group_sizes, stack.dtype,
+               gmm_tiling(m, d, f, size), num_actual_groups=held)
+    return d_rows, d_w, None, None, None
+
+
+_megablox_at.defvjp(_megablox_at_fwd, _megablox_at_bwd)
+
+
+def grouped_matmul(rows: jax.Array, w: jax.Array, group_sizes: jax.Array,
+                   stack: Optional[Tuple[jax.Array, jax.Array]] = None
+                   ) -> jax.Array:
     """rows (M, d) sorted by group, w (H, d, f), group_sizes (E,) with
     H <= E -> (M, f): row r times the matrix of the group it lies in.
     ``w`` holds the first H of the E groups; rows of the other groups
     (they lie behind the held ones) cost no matmul and come out zero.
+
+    ``stack`` (a layer of a training scan): ``(whole, sizes)``, the leaf
+    that ``w`` is a layer of, viewed ``(layers x H, d, f)`` with its
+    gradient stopped, and the layer's counts laid among its groups
+    (``_sizes_in_stack``).  Where the kernels below run and ``whole`` is
+    stored in the rows' type, they read it in place and ``w`` only names
+    where the gradient goes (``_megablox_at``); elsewhere ``w`` is read as
+    it always was (a cast of the slice is work and no copy, and XLA's own
+    ``ragged_dot`` reads a slice where it lies), so values and gradients
+    are those of the call without ``stack``.
 
     On a TPU, where a tile divides the shapes (``gmm_tiling``): megablox's
     Pallas ``gmm`` (kernels ``gmm`` and, for the weights' gradient,
@@ -686,6 +747,8 @@ def grouped_matmul(rows: jax.Array, w: jax.Array,
     (m, d), (held, _, f) = rows.shape, w.shape
     if jax.default_backend() == "tpu" and gmm_tiling(
             m, d, f, rows.dtype.itemsize):
+        if stack is not None and stack[0].dtype == rows.dtype:
+            return _megablox_at(rows, w, *stack, group_sizes, held)
         return _megablox(rows, w, group_sizes)
     out = jax.lax.ragged_dot(rows, w, group_sizes[:held])
     if jax.default_backend() == "tpu" and held < group_sizes.shape[0]:
@@ -757,7 +820,8 @@ def choice_of_live_rows(expert_idx: jax.Array, live: jax.Array) -> jax.Array:
 
 def dropless_experts(x: jax.Array, expert_idx: jax.Array, weights: jax.Array,
                      w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
-                     *, num_experts: int, first_held: int = 0):
+                     *, num_experts: int, first_held: int = 0,
+                     stack: Optional[tuple] = None):
     """Each token through those of its chosen experts that are held here,
     weighted and summed -> (y (N, d), group_sizes (E,)).
 
@@ -789,9 +853,13 @@ def dropless_experts(x: jax.Array, expert_idx: jax.Array, weights: jax.Array,
     experts nor fetched back (``_walks_held_rows``).  No capacity: no
     (N, E, C) tensor exists and nothing is dropped among the held,
     whatever the imbalance.  ``group_sizes[i]`` counts the rows of expert
-    ``first_held + i`` (mod E).
+    ``first_held + i`` (mod E).  ``stack`` (a layer of a training scan):
+    ((the three leaves whole, each ``(layers x H, ...)`` with its gradient
+    stopped), this layer's index, traced): what ``grouped_matmul`` may read
+    in place of the slices.
     """
     (n, d), k, (held, _, f) = x.shape, expert_idx.shape[1], w_gate.shape
+    in_gate = in_up = in_down = None          # ``grouped_matmul``'s stack
     with jax.named_scope("moe_dispatch"):
         order, inverse, w_sorted, group_sizes = _sorted_assignments(
             expert_idx, weights, num_experts, first_held)
@@ -799,13 +867,18 @@ def dropless_experts(x: jax.Array, expert_idx: jax.Array, weights: jax.Array,
         if _walks_held_rows(n, k, d, f, held, num_experts, x.dtype):
             held_rows = group_sizes[:held].sum()
         rows = _rows_to_experts(x, order, inverse, held_rows)    # (N k, d)
+        if stack is not None:
+            leaves, at = stack
+            sizes = _sizes_in_stack(group_sizes, held, leaves[0].shape[0], at)
+            in_gate, in_up, in_down = ((whole, sizes) for whole in leaves)
     with jax.named_scope("moe_experts"):
-        gate = grouped_matmul(rows, w_gate.astype(x.dtype), group_sizes)
-        up = grouped_matmul(rows, w_up.astype(x.dtype), group_sizes)
+        gate = grouped_matmul(rows, w_gate.astype(x.dtype), group_sizes,
+                              in_gate)
+        up = grouped_matmul(rows, w_up.astype(x.dtype), group_sizes, in_up)
         hidden = (jax.nn.silu(gate.astype(jnp.float32))
                   * up.astype(jnp.float32) * w_sorted[:, None])
         out = grouped_matmul(hidden.astype(x.dtype), w_down.astype(x.dtype),
-                             group_sizes)
+                             group_sizes, in_down)
     with jax.named_scope("moe_combine"):
         y = _experts_to_rows(out, order, inverse, held_rows, k)
     return y, group_sizes
@@ -818,7 +891,7 @@ def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
                      weight_scale: float = 1.0, first_held: int = 0,
                      choices: bool = False,
                      live: Optional[jax.Array] = None,
-                     stack_at=None):
+                     stack_at=None, stack: Optional[tuple] = None):
     """Token-choice SwiGLU experts with no capacity: every token is
     computed by each of its top-k experts that is held here, whatever the
     imbalance.
@@ -842,6 +915,13 @@ def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
     1``; the other layers' groups are empty (a kernel's operand is a
     buffer: a layer's experts sliced out of their stack by a scan would be
     copied whole).  The stats are then this layer's, over its own E.
+    ``stack`` (a training forward's layer scan, for the same reason): the
+    weights are the layer's own slice and ``stack`` is ((the three leaves
+    of the whole stack, ``models/_common.experts_in_place``), the layer's
+    index, traced): the grouped matmuls read the layer's groups in the
+    stack and the slice's value is read by nobody, where
+    ``grouped_matmul`` says; the counts, the sort and the stats are over
+    the layer's own E either way.
     """
     n = x.shape[0]
     num_experts, held = w_router.shape[-1], w_gate.shape[0]
@@ -863,7 +943,7 @@ def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
     if stack_at is None:
         y, group_sizes = dropless_experts(
             x, expert_idx, weights, w_gate, w_up, w_down,
-            num_experts=num_experts, first_held=first_held)
+            num_experts=num_experts, first_held=first_held, stack=stack)
     else:
         y, group_sizes = dropless_experts(
             x, expert_idx + stack_at * num_experts, weights, w_gate, w_up,

@@ -84,6 +84,32 @@ def _assert_the_sorted_passes_are_only_the_layers_work(text, rows, slots):
                 if "scatter" in line and "moe_dispatch" in line]
 
 
+def _assert_no_layers_experts_are_made(text, shapes, also=()):
+    """What ``ops/moe._megablox_at`` spares a training step (PR 66), read
+    off a compiled module: the grouped matmuls read a layer's experts in
+    their stack, in place, so nothing in either loop has a layer's expert
+    matrix (one of ``shapes``, as HLO prints them) as its result but the
+    weights' gradient: a ``tgmm`` kernel, and where the compiler moves that
+    result to another memory on its way into the gradients' stack, the
+    ``ConcatBitcast`` of its slices.  The parent's scans sliced the layer
+    out of the stack for the kernels, a ``dynamic-slice_bitcast_fusion``
+    each, three a loop, and copied some of those again.  A fused
+    computation's ``parameter`` names a shape and makes nothing; ``also``:
+    opcodes a cell's other operations of that shape have."""
+    made = [(m.group(1), m.group(3), m.group(0)) for shape in shapes
+            for m in re.finditer(
+                rf"%(\S+) = ({re.escape(shape)})\S* ([\w-]+)\(.*", text)]
+    kernels = [name for name, op, line in made
+               if op == "custom-call" and "tpu_custom_call" in line]
+    assert kernels and all(k.split(".")[0] == "tgmm" for k in kernels), made
+    moved = [line for _, op, line in made
+             if op == "custom-call" and "tpu_custom_call" not in line]
+    assert all('custom_call_target="ConcatBitcast"' in line
+               and "slice-done" in line for line in moved), moved
+    rest = {op for _, op, _ in made} - {"custom-call", "parameter", *also}
+    assert not rest, [(name, op) for name, op, _ in made if op in rest]
+
+
 def _flash(q, k, v):
     return flash_attention(q, k, v, True, None, False)
 
@@ -953,7 +979,13 @@ def test_kanana_step_compiles_with_its_kernels_and_fits(v5e, monkeypatch):
     step's temporaries are its parent's 9,365,980,160 B (e45e91f, this
     jax) to two megabytes: 1,082,880 B more, the sum's list by token (two
     arrays of 393,216 B) and what the schedule made of it; the (6, 16,384,
-    2,048) rows gathered back, 403 MB, are gone but were never the peak."""
+    2,048) rows gathered back, 403 MB, are gone but were never the peak.
+    Since PR 66 the kernels read a sparse layer's 16 held experts in their
+    stack of 7 layers, ``bf16[112,...]``, in place: no instruction but the
+    three ``tgmm`` results and their way into the gradients' stack has a
+    layer's expert matrix as its result (the parent: three sliced copies
+    in each loop, and a second copy of five of them to another memory),
+    and the temporaries read 9,265,399,808 B under the bound kept here."""
     import json
     from pathlib import Path
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -994,6 +1026,9 @@ def test_kanana_step_compiles_with_its_kernels_and_fits(v5e, monkeypatch):
     _assert_the_sorted_passes_are_only_the_layers_work(
         text, "bf16[98304,2048]", "[16384,6,2048]")
     assert not re.search(r"= bf16\[98304,2048\]\S* gather\(", text)
+    _assert_no_layers_experts_are_made(
+        text, ("bf16[16,2048,768]", "bf16[16,768,2048]"))
+    assert "bf16[7,112," not in text and "bf16[7,16,112," not in text
     mem = compiled.memory_analysis()
     held = mem.argument_size_in_bytes + mem.output_size_in_bytes \
         - mem.alias_size_in_bytes + mem.temp_size_in_bytes
@@ -1065,9 +1100,18 @@ def _operations_and_kernels(lowered_text):
 # the backward kernel), so the slices, reshapes, transposes, sharding
 # constraints and the delta pass round the old kernels are gone: 1,795
 # operations where there were 1,851, still 2 kernels, other bodies.  OLMoE's
-# stands: ``flash_attention`` lowers to what it lowered to.
+# stood: ``flash_attention`` lowers to what it lowered to.  PR 66 replaced
+# OLMoE's on purpose (479998fc66d84fe8, 3,905 operations until then): the
+# layer scan's body closes over the three expert leaves whole,
+# ``(3 x 64, ...)``, takes the layer's index among its ``xs``, and the six
+# ``gmm`` calls a layer that read the weights read the stack under the
+# layer's counts laid among its 192 groups (``ops/moe._megablox_at``, one
+# ``dynamic_update_slice`` of 64 counts a layer); what LEFT is every use of
+# the scan's own slice of the experts, which now only names where the
+# ``tgmm`` results go: 4,089 operations, the same 9 kernels with the same
+# bodies, the ``gmm`` ones over ``192`` groups where they had 64.
 PARENT_STEPS = {
-    "olmoe-1b-7b.train-b2-s4096": ("479998fc66d84fe8", 3905, 9),
+    "olmoe-1b-7b.train-b2-s4096": ("0d4c147702843346", 4089, 9),
     "gpt2-xl-1558m.train-b8-s1024": ("bcfab170aaa276dc", 1795, 2),
 }
 
@@ -1082,8 +1126,8 @@ def test_older_training_steps_lower_to_the_operations_and_kernels_they_had(
     assert (digest, sum(ops.values()), sum(kernels.values())) == \
         PARENT_STEPS[cell]
     # PR 56's kernels are for a layer that holds a share of its experts:
-    # OLMoE holds all 64 and XL has none, so both digests are e45e91f's
-    assert digest in ("479998fc66d84fe8", "bcfab170aaa276dc")
+    # OLMoE holds all 64 and XL has none; XL's digest is e45e91f's still
+    assert digest in ("0d4c147702843346", "bcfab170aaa276dc")
 
 
 def test_xl_step_holds_no_split_head_and_no_copy_round_its_kernels(
@@ -1126,22 +1170,88 @@ def test_xl_step_holds_no_split_head_and_no_copy_round_its_kernels(
 
 def test_olmoe_step_holds_no_more_temporaries_than_its_parent(v5e,
                                                               monkeypatch):
-    """The whole step of ``olmoe-1b-7b.train-b2-s4096`` stands at 95.6% of
+    """The whole step of ``olmoe-1b-7b.train-b2-s4096`` stood at 95.6% of
     the chip (8.79e9 B of state, donated, and 7.36e9 of temporaries), so a
-    change to the expert layer may add no array to it: the temporaries are
-    no more than the 7,358,946,304 B of PR 49's parent (5278554, this jax),
+    change to the expert layer may add no array to it.  Until PR 66 the
+    bound was the 7,358,946,304 B of PR 49's parent (5278554, this jax),
     whose ``jnp.take`` filled, re-tiled ``bf16[8192,8,2048]`` and counted
-    by scatter-add; and the sorted passes of the whole step are the
+    by scatter-add; PR 66's parent read 7,358,591,488.  Since PR 66 the
+    kernels read a layer's 64 experts in their stack of 3 layers,
+    ``bf16[192,...]`` (a bitcast of the parameter, one constant of both
+    loops), in place: no instruction but the three ``tgmm`` results has a
+    layer's expert matrix as its result (the parent: a
+    ``dynamic-slice_bitcast_fusion`` each, three in each loop, 805 MB a
+    loop and layer read and written), no second array of the stack's size
+    is made for it, and the temporaries are 6,753,362,432 B, 605 MB less,
+    to two megabytes; and the sorted passes of the whole step are the
     layer's work alone."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     prog, state, batch = _train_program("olmoe-1b-7b.train-b2-s4096", v5e)
     compiled = prog.jitted_step.lower(state, batch).compile()
+    text = compiled.as_text()
     _assert_the_sorted_passes_are_only_the_layers_work(
-        compiled.as_text(), "bf16[65536,2048]", "[8192,8,2048]")
+        text, "bf16[65536,2048]", "[8192,8,2048]")
+    _assert_no_layers_experts_are_made(
+        text, ("bf16[64,2048,1024]", "bf16[64,1024,2048]"))
+    # the stack the kernels read is the parameter itself, seen whole
+    whole = re.findall(r"= bf16\[192,(?:2048,1024|1024,2048)\]\S* ([\w-]+)\(",
+                       text)
+    assert whole and set(whole) <= {"bitcast", "get-tuple-element",
+                                    "parameter"}, set(whole)
+    assert "bf16[3,192," not in text
+    # the gradients' stacks are zeroed once and written a layer at a time,
+    # as they were: nothing else of the backward has the stack's size
+    backward = re.findall(
+        r"%(\S+) = bf16\[3,64,(?:2048,1024|1024,2048)\]\S* "
+        r"(?:add|copy|fusion)\(", text)
+    assert sorted(name.split(".")[0] for name in backward if not
+                  name.startswith("fusion")) == \
+        ["bitcast_dynamic-update-slice_fusion"] * 3, backward
     held, mem = _held_bytes(compiled)
     assert mem.alias_size_in_bytes >= 8.78e9        # the state, donated
-    assert mem.temp_size_in_bytes <= 7_358_946_304, mem.temp_size_in_bytes
+    assert mem.temp_size_in_bytes <= 6_753_362_432 + 2 * 2 ** 20, \
+        mem.temp_size_in_bytes
     assert held < 16.9e9, held
+
+
+def test_qwen3_next_step_reads_its_experts_in_their_stacks(v5e, monkeypatch):
+    """The whole step of ``qwen3-next-80b-a3b.train-b2-s8192`` (one period:
+    three DeltaNet layers in the inner scan, one attention layer): since
+    PR 66 the kernels read a DeltaNet layer's 64 held experts in their
+    stack of 3 layers, ``bf16[192,...]``, in place, so no instruction but
+    the ``tgmm`` results has a layer's expert matrix as its result (the
+    parent: a ``dynamic-slice_bitcast_fusion`` each, three in each loop of
+    the inner scan); the attention layer's stack is one layer, a
+    ``bitcast`` of its parameter on both sides, which the optimizer's
+    fusions ``convert`` into.  Every grouped matmul keeps the result its
+    share's metric knows it by, and the temporaries are 9,424,205,824 B
+    (the parent: 9,693,356,544) to two megabytes.  ~70 s."""
+    import json
+    from pathlib import Path
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    prog, state, batch = _train_program("qwen3-next-80b-a3b.train-b2-s8192",
+                                        v5e)
+    compiled = prog.jitted_step.lower(state, batch).compile()
+    text = compiled.as_text()
+    _assert_no_layers_experts_are_made(
+        text, ("bf16[64,2048,512]", "bf16[64,512,2048]"),
+        also=("bitcast", "convert"))
+    assert "bf16[3,192," not in text
+    keyed = json.loads((Path(__file__).parent.parent / "perfbench"
+                        / "layer_metrics"
+                        / "moe.train_share_expert_peak_share.json"
+                        ).read_text())["params"]
+    experts = [shape for name, shape in _kernel_names_and_results(text)
+               if shape in keyed["shapes"]
+               and any(part in name for part in keyed["names"])]
+    # 11 a layer as in Kanana's step, written once for the inner scan's
+    # two loops and once for the attention layer
+    assert len(experts) == 2 * 11
+    assert set(experts) == set(keyed["shapes"])
+    assert "ragged-dot" not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= 9_424_205_824 + 2 * 2 ** 20, \
+        mem.temp_size_in_bytes
 
 
 # --------------------------------------------- the LFM2-MoE cell's programs
