@@ -18,6 +18,22 @@ block-causal mask in training and prefill, and ``block_stepping``, which
 tells the serving stack to step a sequence a block of positions at a time:
 ``_forward_decode_blocks``).  The serving forwards keep the experts outside
 the layer scan's slices (``_split_experts``).
+
+A learned index (``index_topk`` > 0; Keye-VL-2.0-30B-A3B's language model,
+DeepSeek Sparse Attention's lightning indexer on the Qwen3-MoE stack).
+Every layer holds an indexer beside its attention: ``index_heads`` query
+heads of ``index_dim`` lanes, ONE key head (LayerNorm, then RoPE over all
+its lanes) and a weight a head, all projected from the attention's own
+input; a query attends to the ``index_topk`` positions of largest ``I_t,s =
+sum_j w_t,j ReLU(qI_t,j . kI_s)``, ``s <= t`` (``ops/indexed_attention.py``:
+exact, a position's own).  Such a preset is served alone: a prompt runs in
+chunks of ``prefill_chunk`` positions over a staging that carries the index
+keys beside K/V (:func:`forward_prefill_chunk`, :func:`prefill_staging`;
+:func:`forward_prefill` is the same code over a whole prompt as one run),
+the decode step is handed the index plane (``index_pool``), and a
+position's index key leaves every serving forward as ONE MORE HEAD OF ITS
+K (its first ``index_dim`` lanes; ``kv_cache.py``, the ``"index"`` row, says
+why).  With ``index_topk`` 0 none of this is in a program or a tree.
 """
 
 from __future__ import annotations
@@ -100,6 +116,26 @@ class LlamaConfig:
     # block_length / denoising_steps undecided positions of highest
     # confidence
     denoising_steps: int = 0
+    # > 0: every layer holds an indexer (``index_heads`` x ``index_dim``
+    # queries, one key head, a weight a head) and a query attends to the
+    # ``index_topk`` positions it scores highest; 0: none, and nothing of
+    # it is in the tree or in a program
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
+    # positions one prefill program runs where a prompt runs in chunks (a
+    # preset with an index); 0: a prompt is one program of its bucket
+    prefill_chunk: int = 0
+
+    def __post_init__(self):
+        if self.index_topk and (
+                self.block_length > 1 or not 0 < self.index_dim
+                <= self.head_dim or self.index_heads < 1
+                or self.prefill_chunk < 1):
+            raise ValueError(
+                "an index needs heads, a key of 1..head_dim lanes (it "
+                "leaves the forwards as one more head of K), a "
+                "prefill_chunk, and a model that steps by tokens")
 
     @property
     def head_dim(self) -> int:
@@ -159,9 +195,39 @@ def sdar_30b_a3b_l6() -> LlamaConfig:
                        denoising_steps=4)
 
 
+def tiny_keye(vocab: int = 200, seq: int = 128) -> LlamaConfig:
+    """Keye-VL-2.0's block at a test's size: ``tiny_sdar``'s stack stepped
+    by tokens, an indexer of 4 heads x 8 in every layer, 12 positions
+    chosen (far under its contexts), chunks of 32."""
+    return LlamaConfig(vocab_size=vocab, max_positions=seq, n_embd=32,
+                       n_layer=2, n_head=4, n_kv_head=2, head_size=16,
+                       ffn_dim=16, rope_theta=1e7, rms_eps=1e-6,
+                       qk_norm=True, qk_norm_heads=True, n_experts=8,
+                       experts_per_token=2, norm_topk=True,
+                       scaled_residual_init=False, fan_in_init=True,
+                       index_heads=4, index_dim=8, index_topk=12,
+                       prefill_chunk=32, dtype=jnp.float32)
+
+
+def keye_vl_2_30b_a3b_l6() -> LlamaConfig:
+    """Keye-VL-2.0-30B-A3B's language model at its published widths with 6
+    of its 48 layers (one of eight pipeline stages;
+    perfbench/configs/keye-vl-2.0-30b-a3b.json), served in bf16."""
+    return LlamaConfig(vocab_size=151936, max_positions=262144, n_embd=2048,
+                       n_layer=6, n_head=32, n_kv_head=4, head_size=128,
+                       ffn_dim=768, rope_theta=1e7, rms_eps=1e-6,
+                       qk_norm=True, qk_norm_heads=True, n_experts=128,
+                       experts_per_token=8, norm_topk=True,
+                       scaled_residual_init=False, fan_in_init=True,
+                       param_dtype=jnp.bfloat16, remat=False,
+                       index_heads=16, index_dim=64, index_topk=2048,
+                       prefill_chunk=2048)
+
+
 PRESETS = {"llama2-7b": llama2_7b, "llama3-8b": llama3_8b, "tiny": tiny,
            "tiny-moe": tiny_moe, "tiny-sdar": tiny_sdar,
-           "sdar-30b-a3b-l6": sdar_30b_a3b_l6}
+           "sdar-30b-a3b-l6": sdar_30b_a3b_l6, "tiny-keye": tiny_keye,
+           "keye-vl-2.0-30b-a3b-l6": keye_vl_2_30b_a3b_l6}
 
 
 # ------------------------------------------------------------------- params
@@ -207,6 +273,17 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Params:
         blocks["w_gate"] = {"kernel": stacked(E, F)}
         blocks["w_up"] = {"kernel": stacked(E, F)}
         blocks["w_down"] = {"kernel": stacked(F, E, scale=out_scale)}
+    if cfg.index_topk:
+        # keys of its own, so that every other leaf is drawn as it was
+        rest, k = k, iter(jax.random.split(jax.random.fold_in(rng, 1), 3))
+        IH, ID = cfg.index_heads, cfg.index_dim
+        blocks["index"] = {
+            "wq": {"kernel": stacked(E, IH * ID)},
+            "wk": {"kernel": stacked(E, ID)},
+            "ww": {"kernel": stacked(E, IH)},
+            "index_norm": {"scale": jnp.ones((L, ID), pd),
+                           "bias": jnp.zeros((L, ID), pd)}}
+        k = rest
     table = 1.0 / math.sqrt(E) if fan_in else 0.02
     return {
         "wte": normal_init(next(k), (cfg.vocab_size, E), pd, table),
@@ -222,7 +299,8 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Params:
 # ``.astype(cfg.dtype)`` here or by ``w.astype(x.dtype)`` in
 # ops/moe.dropless_moe_ffn (the router and the experts), so
 # _common.serving_params may store it in cfg.dtype.
-WIDE_PARAMS = ("attn_norm", "mlp_norm", "q_norm", "k_norm", "norm_f")
+WIDE_PARAMS = ("attn_norm", "mlp_norm", "q_norm", "k_norm", "norm_f",
+               "index_norm")
 
 
 # ------------------------------------------------------------------ forward
@@ -274,6 +352,39 @@ def _qkv(h: jax.Array, lp: Params, cfg: LlamaConfig):
             k = _rms_norm(k, lp["k_norm"]["scale"], cfg.rms_eps)
     return (q.reshape(*lead, H, D), k.reshape(*lead, KV, D),
             v.reshape(*lead, KV, D))
+
+
+def _index_proj(x: jax.Array, lp: Params, cfg: LlamaConfig,
+                positions: jax.Array):
+    """The layer's indexer on the attention's own input: the stream x (T,
+    E), its positions (T,) -> (qI (T, IH, ID) and kI (T, ID), rotated over
+    all their lanes; w (T, IH)), all float32.  The key goes through a
+    LayerNorm (scale and bias) before its rotation; the queries are neither
+    normed nor scaled.
+
+    In float32 whatever ``cfg.dtype`` is: the attention's input is normed
+    once more without the rounding to ``cfg.dtype``, and the three
+    projections run at full precision.  A score decides a position's
+    membership and not a weight, so what rounding moves it by is positions
+    exchanged at the cut, and an average over ``index_topk`` values moves
+    with every exchange: with the projections in bf16 a first layer's
+    attention, which at random weights is most of its stream, differed from
+    the float32 reference's by a sixth of itself (perfbench/KEYE.md).  The
+    cost is 1,104 columns a layer; the index plane is float32 already."""
+    ix, IH, ID = lp["index"], cfg.index_heads, cfg.index_dim
+    f32, hi = jnp.float32, lax.Precision.HIGHEST
+    with jax.named_scope("index_proj"):
+        h = _rms_norm(x.astype(f32), lp["attn_norm"]["scale"], cfg.rms_eps)
+        q = jnp.dot(h, ix["wq"]["kernel"].astype(f32),
+                    precision=hi).reshape(-1, IH, ID)
+        k = jnp.dot(h, ix["wk"]["kernel"].astype(f32), precision=hi)
+        w = jnp.dot(h, ix["ww"]["kernel"].astype(f32), precision=hi)
+        k = k - k.mean(-1, keepdims=True)
+        k = k * lax.rsqrt((k * k).mean(-1, keepdims=True) + cfg.rms_eps)
+        k = k * ix["index_norm"]["scale"] + ix["index_norm"]["bias"]
+        q = _rope_at(q, positions, cfg.rope_theta)
+        k = _rope_at(k[:, None], positions, cfg.rope_theta)[:, 0]
+    return q, k, w
 
 
 def _ffn(h: jax.Array, lp: Params, cfg: LlamaConfig,
@@ -369,6 +480,10 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig):
     """tokens (B, T) int32 -> (final-norm hidden states (B, T, E) in
     cfg.dtype, the layers' RouterStats stacked on a leading n_layer axis,
     or None for a dense model)."""
+    if cfg.index_topk:
+        raise NotImplementedError(
+            "training under a learned index is not written: the indexer "
+            "has no loss of its own here (ROADMAP, Reach)")
     x = _embed(params, tokens, cfg)
     blocks = params["blocks"]
     block = partial(_block, cfg=cfg)
@@ -390,6 +505,8 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig):
 
 def forward(params: Params, tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:
     """tokens (B, T) int32 → logits (B, T, vocab) f32."""
+    if cfg.index_topk:
+        return forward_prefill(params, tokens, cfg)[0]
     x, _ = forward_hidden(params, tokens, cfg)
     return _head(params, x, cfg)
 
@@ -424,6 +541,144 @@ def routed_layers(cfg: LlamaConfig) -> Optional[Dict[str, int]]:
     return {"layers": cfg.n_layer, "k": cfg.experts_per_token}
 
 
+def cache_layers(cfg: LlamaConfig) -> Dict[str, int]:
+    """Every layer holds K/V and, under an index, one index key a position
+    beside it (``serve/llm/kv_cache.py``, the ``"index"`` row)."""
+    kinds = {"kv": cfg.n_layer, "state": 0}
+    if cfg.index_topk:
+        kinds["index"] = cfg.n_layer
+    return kinds
+
+
+def prefill_staging(cfg: LlamaConfig,
+                    positions: int) -> Dict[str, jax.ShapeDtypeStruct]:
+    """What a prompt's chunks keep between them (a preset with an index):
+    every layer's K and V, a position a row of ``KV x D`` lanes, and its
+    index key in the first ``index_dim`` lanes of a row a head wide, the
+    form in which it rides behind K's heads; ``positions`` whole chunks."""
+    kv = jax.ShapeDtypeStruct(
+        (cfg.n_layer, positions, cfg.n_kv_head * cfg.head_dim), jnp.float32)
+    return {"k": kv, "v": kv, "index": jax.ShapeDtypeStruct(
+        (cfg.n_layer, positions, -(-cfg.head_dim // 128) * 128), jnp.float32)}
+
+
+def _run_indexed(params: Params, tokens: jax.Array, cfg: LlamaConfig, start,
+                 staging: Dict[str, jax.Array], choices: bool):
+    """A run of one prompt's positions ``start .. start + T - 1`` through
+    every layer of a preset with an index: tokens (T,), those past the
+    prompt's end padding (a query admits nothing past its own position, so
+    no real query sees them and the next prompt overwrites what they
+    stage); ``staging`` as :func:`prefill_staging` says, holding every
+    earlier position.  Returns (the stream (T, E), the staging with
+    this run's positions, the chosen expert ids (L, T, k) or None)."""
+    from ray_tpu.ops import indexed_attention as indexed
+    T = tokens.shape[0]
+    H, KV, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
+    positions = start + jnp.arange(T, dtype=jnp.int32)
+    x = _embed(params, tokens, cfg)
+    sliced, experts = _split_experts(params["blocks"], cfg)
+    f32 = jnp.float32
+
+    def body(carry, xs):
+        x, staging = carry
+        lp, layer = xs
+        with jax.named_scope("ln_1"):
+            h = _rms_norm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
+        q, k, v = _qkv(h, lp, cfg)
+        with jax.named_scope("rope"):
+            q = _rope_at(q, positions, cfg.rope_theta)
+            k = _rope_at(k, positions, cfg.rope_theta)
+        qi, ki, w = _index_proj(x, lp, cfg, positions)
+        with jax.named_scope("kv_stage"):
+            lanes = staging["index"].shape[-1]
+            rows = {"k": k.reshape(T, KV * D), "v": v.reshape(T, KV * D),
+                    "index": jnp.pad(ki, ((0, 0),
+                                          (0, lanes - cfg.index_dim)))}
+            staging = {name: lax.dynamic_update_slice(
+                staging[name], rows[name].astype(f32)[None],
+                (layer, start, 0)) for name in staging}
+            staged = {name: lax.dynamic_index_in_dim(
+                staging[name], layer, 0, keepdims=False) for name in staging}
+        with jax.named_scope("index_score"):
+            scores = indexed.index_scores(qi, w, staged["index"], start)
+        chosen = indexed.topk_mask(scores, cfg.index_topk)
+        with jax.named_scope("attn_indexed"):
+            a = indexed.prefill_attention(
+                q.reshape(T, KV, H // KV, D), staged["k"], staged["v"],
+                chosen, positions, start + T).reshape(T, H * D)
+        with jax.named_scope("attn_out"):
+            x = x + a @ lp["wo"]["kernel"].astype(cfg.dtype)
+        with jax.named_scope("ln_2"):
+            h = _rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
+        f, ids = _ffn(h, lp, cfg, (experts, layer) if experts else None)
+        return (x + f, staging), (ids if choices else None)
+
+    (x, staging), ids = lax.scan(body, (x, staging),
+                                 (sliced, jnp.arange(cfg.n_layer)))
+    return x, staging, ids
+
+
+def forward_prefill_chunk(params: Params, tokens: jax.Array,
+                          cfg: LlamaConfig, start, n_total,
+                          staging: Dict[str, jax.Array], state=None,
+                          choices: bool = False):
+    """One chunk of one prompt (a preset with an index): tokens (1, C), its
+    positions ``start .. start + C - 1`` of a prompt of ``n_total``.
+    Returns (logits (1, V) at the prompt's last position where this chunk
+    holds it (else at the chunk's first), the staging, None: it keeps no
+    recurrent state) and, with ``choices``, the experts chosen, (L, C, k)
+    int32."""
+    x, staging, ids = _run_indexed(params, tokens[0], cfg, start, staging,
+                                   choices)
+    last = jnp.clip(n_total - 1 - start, 0, tokens.shape[1] - 1)
+    x = lax.dynamic_slice_in_dim(x, last, 1, axis=0)
+    logits = _head(params, _final_norm(params, x, cfg), cfg)
+    return (logits, staging, None, *([ids] if choices else []))
+
+
+def _forward_prefill_indexed(params, tokens, cfg, last_pos, choices):
+    """:func:`forward_prefill` of a preset with an index: each prompt ONE
+    run from an empty staging, the code its chunks run.  K comes back with
+    the index key as one more head, (L, B, T, KV + 1, D): its first
+    ``index_dim`` lanes, zeros behind them."""
+    B, T = tokens.shape
+    KV, D = cfg.n_kv_head, cfg.head_dim
+    padded = T + -T % (512 if T > 256 else 8)        # whole tiles
+    empty = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                         prefill_staging(cfg, padded))
+    out = []
+    for b in range(B):
+        toks = jnp.pad(tokens[b], (0, padded - T))
+        x, staged, ids = _run_indexed(params, toks, cfg, 0, empty, choices)
+        x = x[:T] if last_pos is None \
+            else lax.dynamic_slice_in_dim(x, last_pos, 1, axis=0)
+        logits = _head(params, _final_norm(params, x, cfg)[None], cfg,
+                       only_position=last_pos is not None)[0]
+        ks, vs = with_index_head(staged, T, KV, D)
+        out.append((logits, ks, vs) + ((ids[:, :T],) if choices else ()))
+    logits, ks, vs, *ids = (jnp.stack(part, axis=axis) for part, axis in
+                            zip(zip(*out), (0, 1, 1, 1)))
+    if ids:
+        # (L, B x T, k), a prompt's positions behind another's
+        ids = [ids[0].reshape(ids[0].shape[0], B * T, -1)]
+    # K stays float32: its own heads hold cfg.dtype's values, the index
+    # key's head float32 ones
+    return (logits, ks, vs.astype(cfg.dtype), *ids)
+
+
+def with_index_head(staged: Dict[str, jax.Array], positions: int, n_kv: int,
+                    head_dim: int):
+    """A staging's first ``positions`` rows as the (ks, vs) a cache
+    scatters: ks (L, positions, KV + 1, D), the index key one more head of
+    K; vs (L, positions, KV, D)."""
+    k, v, index = (staged[name][:, :positions] for name in
+                   ("k", "v", "index"))
+    lead = k.shape[:2]
+    k, v = (a.reshape(*lead, n_kv, head_dim) for a in (k, v))
+    index = index[..., None, :head_dim]
+    return jnp.concatenate([k, index], axis=2), v
+
+
 def forward_prefill(params: Params, tokens: jax.Array, cfg: LlamaConfig,
                     last_pos: Optional[jax.Array] = None,
                     choices: bool = False):
@@ -440,6 +695,9 @@ def forward_prefill(params: Params, tokens: jax.Array, cfg: LlamaConfig,
     A preset with a ``block_length`` runs under the block-causal mask
     (``_block``); its prompts come in whole blocks and ``last_pos`` is the
     last position of one, whose block's logits come back, (B, block, V)."""
+    if cfg.index_topk:
+        return _forward_prefill_indexed(params, tokens, cfg, last_pos,
+                                        choices)
     x = _embed(params, tokens, cfg)
     sliced, experts = _split_experts(params["blocks"], cfg)
 
@@ -472,13 +730,20 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
                    kv_pool: jax.Array, block_tables: jax.Array,
                    ctx_lens: jax.Array, cfg: LlamaConfig,
                    choices: bool = False,
-                   live: Optional[jax.Array] = None):
+                   live: Optional[jax.Array] = None,
+                   index_pool: Optional[jax.Array] = None):
     """One decode step over the engine's paged KV pool, handed whole to
     ``ops/paged_attention`` with the layer's index (read-only here).
 
     Returns (logits (B, V) f32, new_k (L, B, KV, D), new_v (L, B, KV, D))
     and, with ``choices``, the experts chosen, (L, B, k) int32 (``live``
-    (B,) bool: the rows that are not padding, see ``_ffn``)."""
+    (B,) bool: the rows that are not padding, see ``_ffn``).
+
+    A preset with an index is handed ``index_pool`` (L, 1, N, bs, F), the
+    index plane, read-only: a layer scores every cached position of a row
+    and the new token's own, takes the exact ``index_topk`` of them and
+    attends to those positions' rows and to no other.  ``new_k`` is then
+    (L, B, KV + 1, D) float32: the new token's index key its last head."""
     from ray_tpu.ops.paged_attention import paged_attention_decode
     if tokens.ndim == 2:
         return _forward_decode_blocks(params, tokens, positions, kv_pool,
@@ -497,8 +762,13 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
         with jax.named_scope("rope"):
             q = _rope_at(q, positions, cfg.rope_theta)
             k = _rope_at(k, positions, cfg.rope_theta)
-        a = paged_attention_decode(q, kv_pool, layer, block_tables,
-                                   ctx_lens, k, v).reshape(B, E)
+        if cfg.index_topk:
+            a, k = _attend_indexed(x, lp, cfg, q, k, v, positions, kv_pool,
+                                   index_pool, layer, block_tables, ctx_lens)
+        else:
+            a = paged_attention_decode(q, kv_pool, layer, block_tables,
+                                       ctx_lens, k, v)
+        a = a.reshape(B, E)
         with jax.named_scope("attn_out"):
             x = x + a @ lp["wo"]["kernel"].astype(cfg.dtype)
         with jax.named_scope("ln_2"):
@@ -509,6 +779,30 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
     sliced, experts = _split_experts(params["blocks"], cfg)
     x, kept = lax.scan(body, x, (sliced, jnp.arange(cfg.n_layer)))
     return (_head(params, _final_norm(params, x, cfg), cfg), *kept)
+
+
+def _attend_indexed(x, lp, cfg, q, k, v, positions, kv_pool, index_pool,
+                    layer, block_tables, ctx_lens):
+    """A decode step's attention in one layer under the index: the score
+    pass over the row's index keys, the exact cut, the walk over the chosen
+    positions.  Returns (the heads' output (B, H, D), K with the new
+    token's index key as one more head, (B, KV + 1, D) float32: K's own
+    heads hold cfg.dtype's values, the key's head float32 ones)."""
+    from ray_tpu.ops import indexed_attention as indexed
+    from ray_tpu.ops.paged_attention import indexed_attention_decode
+    qi, ki, w = _index_proj(x, lp, cfg, positions)
+    with jax.named_scope("index_score"):
+        scores = indexed.decode_scores(qi, w, index_pool, layer,
+                                       block_tables, ctx_lens, ki)
+    with jax.named_scope("index_topk"):
+        rows, count = indexed.top_positions(
+            scores, ctx_lens, cfg.index_topk,
+            indexed.pool_rows(block_tables, kv_pool.shape[3]))
+    with jax.named_scope("attn_indexed"):
+        a = indexed_attention_decode(q, kv_pool, layer, block_tables,
+                                     ctx_lens, k, v, None, count, rows)
+    head = jnp.pad(ki, ((0, 0), (0, cfg.head_dim - cfg.index_dim)))
+    return a, jnp.concatenate([k.astype(jnp.float32), head[:, None]], axis=1)
 
 
 def _forward_decode_blocks(params, tokens, positions, kv_pool, block_tables,

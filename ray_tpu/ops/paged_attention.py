@@ -60,6 +60,18 @@ the head's ``B x rep`` query rows against its own ``D`` lanes of a page;
 elsewhere ``_block_decode_gather``, its oracle.  The walks above are not
 touched by it.
 
+A list of positions.  A layer whose indexer picks positions one by one
+(``ops/indexed_attention.py``; ``models/llama.py``, ``index_topk``) calls
+:func:`indexed_attention_decode` with ``positions`` (B, K), a row's chosen
+positions in any order, and ``count`` (B,).  Where the listed form copies
+the whole pages its columns name and the block form whole blocks, this one
+reads the K and V ROWS of the positions it is given, ``(table[p // bs], p %
+bs)``, out of the pool where it lies, and the new token's own where the
+list names ``ctx_lens``: ``min(context, K)`` rows a KV plane whatever the
+context.  A gather of rows by XLA on every backend: Mosaic copies no single
+row of an HBM array (a row is part of a tile), and the 8 rows a row lies in
+are, over chosen positions spread through a context, the whole context.
+
 The pool's format, ``(L, 2, N, bs, F)`` (layer, K or V, block, position
 in the block, the position's ``KV * D`` features flat along the lanes and
 zero-padded to whole 128-lane tiles: 25 x 64 -> 1,664), belongs to its
@@ -922,3 +934,69 @@ def latent_attention_decode(q: jax.Array, latent_pool: jax.Array, layer,
     return _latent_decode_gather(q.astype(jnp.float32), latent_pool, layer,
                                  block_tables, ctx_lens,
                                  row_new.astype(jnp.float32), value_lanes)
+
+
+# --------------------------------------------------------- listed positions
+def indexed_attention_decode(q: jax.Array, kv_pool: jax.Array, layer,
+                             block_tables: jax.Array, ctx_lens: jax.Array,
+                             k_new: jax.Array, v_new: jax.Array,
+                             positions: jax.Array, count: jax.Array,
+                             rows: jax.Array = None) -> jax.Array:
+    """Single-token decode attention over a LIST OF POSITIONS a row.
+
+    q (B, H, D); kv_pool (L, 2, N, bs, F), read-only; layer: traced;
+    block_tables (B, MAXB); ctx_lens (B,): positions in the pool; k_new,
+    v_new (B, KV, D): the new token's, which stands at ``ctx_lens``;
+    positions (B, K) int32: the positions the row's query attends to, in
+    any order, ``ctx_lens`` naming the new token itself (it is attended to
+    only if listed); count (B,): the first ``count`` entries are real;
+    rows (B, K) int32, where the caller has them (``positions`` may then be
+    None): each listed position's row of a layer's K (or V) slab,
+    ``table[p // bs] * bs + p % bs``, and -1 for the new token's own
+    (``ops/indexed_attention.pool_rows``, which the list was made of: the
+    table is then not looked up again, 8,192 scalar reads a layer at the
+    Keye cell's shape).
+
+    Returns (B, H, D) in q.dtype.  What is read of the pool is the listed
+    positions' rows of K and of V, gathered by row number out of the pool
+    taken as ``L x 2 x N x bs`` rows of ``F`` (as ``kv_cache.write_rows``
+    writes them): the layer's slab is not sliced out and no page is copied
+    whole."""
+    n_layer, _, n_blocks, bs, f = kv_pool.shape
+    b, h, d = q.shape
+    kvh = k_new.shape[1]
+    f32 = jnp.float32
+    per_slab = n_blocks * bs
+    with jax.named_scope("paged_gather"):
+        if rows is None:
+            own = positions == ctx_lens[:, None]                   # (B, K)
+            blocks = jnp.take_along_axis(
+                block_tables, jnp.minimum(positions // bs,
+                                          block_tables.shape[1] - 1), axis=1)
+            rows = blocks * bs + positions % bs
+        else:
+            own = rows < 0
+        row = jnp.clip(rows, 0, per_slab - 1)
+        flat = kv_pool.reshape(-1, f)
+        k_sel = flat[(2 * layer) * per_slab + row]                  # (B, K, F)
+        v_sel = flat[(2 * layer + 1) * per_slab + row]
+        k_sel = jnp.where(own[..., None], lane_flat(k_new, f)[:, None]
+                          .astype(f32), k_sel)
+        v_sel = jnp.where(own[..., None], lane_flat(v_new, f)[:, None]
+                          .astype(f32), v_sel)
+    with jax.named_scope("paged_attention"):
+        k_sel, v_sel = heads_apart(k_sel, kvh, d), heads_apart(v_sel, kvh, d)
+        # float32 products at full precision: for a product that rounds
+        # its operands to bf16 the compiler converts the WHOLE pool to
+        # bf16, once a step outside the layers' loop, and gathers from
+        # that (1.3 GB made a step at the Keye cell's pool: seen in its
+        # first compile); the products here are 2,048 positions a row
+        hi = lax.Precision.HIGHEST
+        qg = q.reshape(b, kvh, h // kvh, d).astype(f32)
+        logits = jnp.einsum("bgrd,bkgd->bgrk", qg, k_sel, precision=hi
+                            ) * (1.0 / math.sqrt(d))
+        live = jnp.arange(row.shape[1])[None, :] < count[:, None]
+        logits = jnp.where(live[:, None, None, :], logits, NEG_INF)
+        probs = jax.nn.softmax(logits, axis=-1)
+        out = jnp.einsum("bgrk,bkgd->bgrd", probs, v_sel, precision=hi)
+        return out.reshape(b, h, d).astype(q.dtype)

@@ -105,6 +105,9 @@ STEP_SERIES = {
         ("rtpu_llm_kv_window_blocks_released_total", "inc"),
     "state_rows_held": ("rtpu_llm_state_rows_held", "set"),
     "latent_blocks_held": ("rtpu_llm_latent_blocks_held", "set"),
+    "positions_scored": ("rtpu_llm_index_positions_scored", "inc"),
+    "positions_read": ("rtpu_llm_index_positions_read", "inc"),
+    "index_blocks_held": ("rtpu_llm_index_blocks_held", "set"),
 }
 
 
@@ -1459,5 +1462,9 @@ class LLMEngine:
                     latent_layers=self.cache.latent_layers,
                     latent_bytes=self.cache.latent_bytes,
                     latent_pages_read=read["latent_pages_read"],
+                    index_layers=self.cache.index_layers,
+                    index_bytes=self.cache.index_bytes,
+                    positions_scored=read["positions_scored"],
+                    positions_read=read["positions_read"],
                     span_s={k: list(v) for k, v in
                             list(self.span_s.items())})

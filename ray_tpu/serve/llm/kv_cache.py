@@ -45,7 +45,7 @@ re-exported, future prefix caching) are refcounted: ``free_seq`` returns
 a block to the free list only at refcount zero.
 
 The planes.  What a sequence keeps is declared once, in :data:`PLANES`: a
-row a kind of thing a sequence can keep (:class:`Plane`), five today.
+row a kind of thing a sequence can keep (:class:`Plane`), six today.
 ``cache.pool`` holds a dict with an entry for every plane the cache has,
 always ``"kv"``, and the same donating programs, the one lock and the one
 lifetime serve them all: ``alloc_seq`` gives a sequence what it needs of
@@ -96,6 +96,21 @@ enqueued.
 of it.  A prompt's rows reach the scatter as its ``ks`` (``vs`` is not
 read).  Such a family's ``"kv"`` pool has no layer and no byte, and stays
 in the holder so that every program keeps its operands' labels.
+
+``"index"``: an indexer's cache, for attention that picks its positions one
+by one from scores of its own (``models/llama.py``, ``index_topk``;
+``ops/indexed_attention.py``): ONE key of ``index_dim`` lanes a position a
+layer, :func:`device_shape` with ``planes=1`` (its lanes padded to a whole
+tile: 64 -> 128), under the K/V pool's own table, so the allocator knows
+nothing of it.  It is a projection of its own and not a sum of K, as
+``"sel"`` is, so no writer can make it from the pool: it is written from
+the forward's own rows.  Those rows are *carried*: a position's index key
+leaves every forward as one more head of its K (its first ``index_dim``
+lanes; ``Plane.carried``), so that whoever writes a position's K, a step's
+program, ``write_token`` or a prompt's scatter, handed device arrays or
+numpy ones, writes its index key in the same act, and K's own heads reach
+the pool without it (:func:`_carried_apart`).  A step reads it whole over a
+row's context, and then the K/V rows of the positions it chose.
 """
 
 from __future__ import annotations
@@ -165,7 +180,11 @@ def device_shape(num_blocks: int, n_layer: int, block_size: int,
 def selector_shape(pool_shape: tuple, stride: int) -> tuple:
     """The selector's cache beside a pool of ``pool_shape``: ``(L, N, bs /
     stride, F)``, a half-kernel (``ops/sparse_attention.py``) for every
-    ``stride`` positions of every page of every layer that holds K/V."""
+    ``stride`` positions of every page of every layer that holds K/V.  Its
+    lanes are the pool's, because a half-kernel is a sum of the pool's K
+    rows; the index plane's are not (a key of the indexer's own width, one
+    a position and not one a ``stride``), so that plane's shape is
+    :func:`device_shape`'s with one plane and says nothing of the pool."""
     n_layer, _, num_blocks, bs, f = pool_shape
     return (n_layer, num_blocks, bs // stride, f)
 
@@ -319,6 +338,9 @@ class Kept:
     window: int = 0
     latent_layers: int = 0
     latent_dim: int = 0
+    index_layers: int = 0
+    index_dim: int = 0
+    index_topk: int = 0              # the positions a query attends to
     # a prompt's prefill leaves its state in the holder: it runs through
     # the holder, donated
     staged: bool = False
@@ -332,7 +354,8 @@ def kept_by(mod, mcfg) -> Kept:
     cache: ``recurrent_state(cfg)`` (one sequence's state in one layer,
     name -> shape and type), ``cache_layers(cfg)`` (the layers of each
     kind: ``"kv"``, ``"state"`` and, where it has them, ``"window"`` /
-    ``"latent"``) and ``page_selector(cfg)`` (``{"stride", "block"}``).  A
+    ``"latent"`` / ``"index"``) and ``page_selector(cfg)`` (``{"stride",
+    "block"}``).  A
     module that declares none (GPT-2, Llama) keeps K/V in every layer; one
     with state and no count keeps both in every layer."""
     state = _declared(mod, mcfg, "recurrent_state")
@@ -341,13 +364,16 @@ def kept_by(mod, mcfg) -> Kept:
     select = _declared(mod, mcfg, "page_selector") or {}
     window_layers = layers.get("window", 0)
     latent_layers = layers.get("latent", 0)
+    index_layers = layers.get("index", 0)
+    indexed = dict(index_layers=index_layers, index_dim=mcfg.index_dim,
+                   index_topk=mcfg.index_topk) if index_layers else {}
     return Kept(
         getattr(mcfg, "n_kv_head", mcfg.n_head), mcfg.head_dim, layers["kv"],
         state, layers["state"], select.get("stride", 0),
         select.get("block", 0), window_layers,
         mcfg.sliding_window if window_layers else 0, latent_layers,
-        mcfg.latent_row if latent_layers else 0, bool(state),
-        bool(window_layers))
+        mcfg.latent_row if latent_layers else 0, staged=bool(state),
+        packed=bool(window_layers), **indexed)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -390,6 +416,13 @@ class Plane:
     holds: Optional[Callable] = None
     # (staging, bucket, Kept) -> a chunked prompt's rows for the scatter
     staged: Optional[Callable] = None
+    # a pool of one plane whose rows are carried: a position's row leaves
+    # the forwards as one more head of its K, behind K's own, and is taken
+    # off it by every writer (:func:`_carried_apart`).  ``chunk_reads``:
+    # (a chunk's first position, its end, cache) -> what the chunk's span
+    # is told, as ``reads`` tells a step's
+    carried: bool = False
+    chunk_reads: Optional[Callable] = None
     # why the block manifest cannot carry it / two sequences cannot share
     # it, behind ``{what}: {model}`` / ``a sequence with``; "": they can
     unexported: str = ""
@@ -431,6 +464,16 @@ def _window_spec(c):
     return _sds(device_shape(c.window_blocks, c.window_layers, c.block_size,
                              *c.block_shape[3:]), c.dtype) \
         if c.window_layers else None
+
+
+def index_reads(contexts, topk: int, layers: int) -> dict:
+    """What queries that see ``contexts`` positions each (their own the
+    last) have to read under an index, from the mathematics whatever walks
+    it: every position's index key, then the K/V of ``min(context, topk)``
+    positions; each summed over the queries and the index layers."""
+    seen = np.asarray(contexts, np.int64)
+    return dict(positions_scored=int(seen.sum()) * layers,
+                positions_read=int(np.minimum(seen, topk).sum()) * layers)
 
 
 def _state_rows(c, tables, n):
@@ -488,15 +531,53 @@ PLANES = (
           "is moved",
           unshared="latent pages cannot be forked: nothing shares a prefix "
           "across latent layers yet (ROADMAP, Reach)"),
+    Plane("index", "index_layers", lambda c: _sds(device_shape(
+              c.num_blocks, c.index_layers, c.block_size, 1, c.index_dim,
+              planes=1), c.dtype) if c.index_layers else None,
+          handed=("index_pool",), carried=True,
+          # a step's query sees its row's cached positions and its own
+          reads=lambda lens, c: index_reads(
+              np.asarray(lens, np.int64) + 1, c.index_topk, c.index_layers),
+          chunk_reads=lambda first, end, c: index_reads(
+              np.arange(first, end, dtype=np.int64) + 1, c.index_topk,
+              c.index_layers),
+          holds=lambda c: dict(index_blocks_held=c.used_block_count()),
+          unexported="keeps an index key a position beside its K/V, and a "
+          "block's wire format is a K and a V a layer; nothing exports an "
+          "index page yet, so nothing is moved",
+          unshared="an index plane cannot be forked: nothing shares a prefix "
+          "across index pages yet (ROADMAP, Reach)"),
 )
+
+
+def _carried_apart(held, k):
+    """(K's own heads, {plane: its carried rows (L, R, 1, lanes)}): ``k``
+    (L, R, heads, D) holds, behind K's own heads, one head for every plane
+    of ``held`` whose rows are carried, in the table's order; a head's
+    first lanes, as many as the plane's pool has or the head is wide, are
+    the row."""
+    carried = [p for p in planes_of(held) if p.carried]
+    if not carried:
+        return k, {}
+    own = k.shape[2] - len(carried)
+    return k[:, :, :own], {
+        p.name: k[:, :, own + i:own + i + 1, :held[p.name].shape[-1]]
+        for i, p in enumerate(carried)}
 
 
 def staged_rows(kept: Kept, staging, bucket: int) -> tuple:
     """A chunked prompt's (ks, vs) for :meth:`PagedKVCache.scatter_prefill`
     out of its chunks' ``staging``, where its rows lie under one table: cut
     by the pool that has layers."""
+    import jax.numpy as jnp
     plane = next(p for p in PLANES if p.staged and getattr(kept, p.layers))
-    return plane.staged(staging, bucket, kept)
+    ks, vs = plane.staged(staging, bucket, kept)
+    for plane in PLANES:
+        if plane.carried and getattr(kept, plane.layers):
+            # its staged rows, a head wide, ride behind K's heads
+            ks = jnp.concatenate([ks, staging[plane.name][
+                :, :bucket, None, :kept.head_dim].astype(ks.dtype)], axis=2)
+    return ks, vs
 
 
 def planes_of(held) -> tuple:
@@ -562,8 +643,14 @@ def rows_written(held, blocks, offsets, k, v) -> dict:
     layers'; a latent pool's are ``k``), each goes to its pool at the blocks
     its table names (``blocks``: table -> (R,)) and ``offsets``; then what
     is kept beside the K/V pool is brought up to date from it, so whoever
-    writes K writes that too.  Returns the holder's dict."""
+    writes K writes that too.  Carried rows (``Plane.carried``) are taken
+    off K first and go to their own pools at the K/V table's blocks.
+    Returns the holder's dict."""
     at = 0
+    k, carried = _carried_apart(held, k)
+    for name, rows in carried.items():
+        held = {**held, name: write_rows(held[name], blocks["kv"], offsets,
+                                         rows)}
     for plane in planes_of(held):
         layers = held[plane.name].shape[0] if plane.table else 0
         if layers:
@@ -608,6 +695,7 @@ def _programs() -> SimpleNamespace:
         # (whole blocks: its table's columns).  ``prompt``: the planes'
         # operands, in the table's order
         planes, prompt = planes_of(held), list(prompt)
+        ks, carried = _carried_apart(held, ks)
         mine = {p.name: [prompt.pop(0) for _ in range(1 + bool(p.beside))]
                 for p in planes if p.prompt}
         num_blocks, bs = held["kv"].shape[2:4]
@@ -642,6 +730,12 @@ def _programs() -> SimpleNamespace:
                     at += n
                 held = {**held, plane.name: write_rows(pool, blocks, offsets,
                                                        *rows)}
+            for name, rows in carried.items():
+                # under the K/V pool's own table, the prompt's positions
+                t = jnp.arange(positions)
+                held = {**held, name: write_rows(
+                    held[name], jnp.where(t < n_tokens, table[t // bs],
+                                          num_blocks), t % bs, rows)}
         for plane in planes:
             if plane.committed:
                 held = {**held, plane.name: plane.committed(
@@ -759,7 +853,8 @@ class PagedKVCache:
                  state=None, max_seqs: int = 0, state_layers=None,
                  select_stride: int = 0, window_layers: int = 0,
                  window: int = 0, latent_layers: int = 0,
-                 latent_dim: int = 0):
+                 latent_dim: int = 0, index_layers: int = 0,
+                 index_dim: int = 0, index_topk: int = 0):
         """``n_layer``: the layers that hold K/V.  The keywords are
         :class:`Kept`'s fields (:meth:`for_engine` hands them all; a test
         names a plane with its own).  ``state``: one sequence's recurrent
@@ -770,7 +865,18 @@ class PagedKVCache:
         hold the last ``window`` positions only, in a pool of ``max_seqs``
         x :func:`window_columns` blocks.  ``latent_layers``,
         ``latent_dim``: the layers that cache one row of ``latent_dim``
-        features a position; such a family's ``n_layer`` is 0."""
+        features a position; such a family's ``n_layer`` is 0.
+        ``index_layers``, ``index_dim``, ``index_topk``: the layers that
+        cache an index key of ``index_dim`` lanes a position beside their
+        K/V (all of them), and the positions a query then attends to."""
+        if index_layers and (index_layers != n_layer or window_layers
+                             or select_stride or latent_layers
+                             or not 0 < index_dim <= head_dim):
+            raise ValueError(
+                "an index plane lies under the K/V pool's one table, a key "
+                "a position of every K/V layer, carried as a head of K: "
+                f"{index_layers} index layers of {index_dim} lanes beside "
+                f"{n_layer} K/V layers with heads of {head_dim}")
         if latent_layers and (n_layer or window_layers or select_stride):
             raise ValueError(
                 "latent pages beside K/V pages in one model are not "
@@ -796,6 +902,8 @@ class PagedKVCache:
         self.window_blocks = max_seqs * window_columns(window, block_size) \
             if window_layers else 0
         self.latent_layers, self.latent_dim = latent_layers, latent_dim
+        self.index_layers, self.index_dim = index_layers, index_dim
+        self.index_topk = index_topk
         self.kv_shape = device_shape(num_blocks, n_layer, block_size, n_kv,
                                      head_dim)
         # every plane this cache has, as shapes, and the planes' bytes
@@ -804,8 +912,9 @@ class PagedKVCache:
                                 if spec is not None})
         self.planes = self.pool.planes
         self.state_bytes, self.select_bytes, self.window_bytes, \
-            self.latent_bytes = (_nbytes(described[name]) for name in (
-                "state", "sel", "kvw", "latent"))
+            self.latent_bytes, self.index_bytes = (
+                _nbytes(described[name]) for name in (
+                    "state", "sel", "kvw", "latent", "index"))
         # what the device format costs in memory beside the wire format's
         # bytes: the lanes that pad F (LLMEngine.stats()["kv_lane_pad_bytes"])
         self.lane_pad_bytes = _nbytes(described["kv"]) \
@@ -1119,6 +1228,14 @@ class PagedKVCache:
                 f"{what}: a block's wire format is a K and a V a layer, and "
                 "this cache's pages are latent rows; nothing exports a "
                 "latent page yet")
+
+    def index_keys(self) -> np.ndarray:
+        """Every page of the index plane, ``(num_blocks, index layers, bs,
+        index_dim)`` without the lane padding, copied from the device (the
+        tests)."""
+        return np.asarray(self.pool.read(
+            lambda held: held["index"][:, 0, ..., :self.index_dim]
+        )).swapaxes(0, 1)
 
     def latent_blocks(self) -> np.ndarray:
         """Every latent page, ``(num_blocks, latent layers, bs,

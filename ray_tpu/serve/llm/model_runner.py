@@ -23,7 +23,8 @@ as stored; every other is held in the model's ``dtype``, converted once,
   what a sequence keeps beside or instead of K/V.  ``kv_cache.kept_by``
   reads them; the cache is built from the answer and holds a *plane* for
   each kind (``kv_cache.PLANES``: ``kv``, ``state``, ``sel``, ``kvw``,
-  ``latent``; DESIGN.md section 4g has the table of kinds and families).
+  ``latent``, ``index``; DESIGN.md section 4g has the table of kinds and
+  families).
   The runner knows no kind by name: a plane's row says which keywords the
   decode forward is handed for it, which per-row operand the cache makes
   for a step, and how new rows are written.
@@ -71,7 +72,8 @@ ride behind the ids in the one pull, in ONE layout (``riders_of``:
 that the program's pack and ``_pull``'s read both take from
 ``family.layout``; counts the host reckons from the context lengths
 (``Plane.reads``: ``window_positions``, ``window_blocks``,
-``window_blocks_unwindowed``, ``latent_pages_read``) travel as
+``window_blocks_unwindowed``, ``latent_pages_read``, ``positions_scored``,
+``positions_read``) travel as
 ``Enqueued.reads``.  ``llm.decode.pull`` is told both, with ``step`` and
 ``bytes``.  Seeded temperature / top-k sampling stays host-side on a
 pulled ``(V,)`` row (``sample``).  A caller that names nothing gets all
@@ -734,8 +736,15 @@ class ModelRunner:
         if compiling is not _SEEN:
             register_program(f"llm.prefill.chunk.{c}", self._prefill_chunk,
                              (pool.abstract(), *abstract(args)))
+        # what the planes say the chunk's real positions read (an index
+        # plane: the positions scored and read), for the chunk's span
+        first = index * c
+        reads = {name: count for plane in (
+            self.cache.planes if self.cache is not None else ())
+            if plane.chunk_reads for name, count in plane.chunk_reads(
+                first, min(first + c, n), self.cache).items()}
         with compiling, hot_span("llm.prefill.chunk", self.span_s,
-                                 chunk=index, tokens=n), \
+                                 chunk=index, tokens=n, **reads), \
                 self._prefill_budget:
             if after is not None:
                 np.asarray(after[1])    # its greedy id: 4 bytes, the wait

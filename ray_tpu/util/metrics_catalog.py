@@ -316,6 +316,26 @@ CATALOG: Dict[str, dict] = {
                     "latent layer) the live sequences hold, at the last "
                     "committed decode step",
         emitted_by="llm replica"),
+    "rtpu_llm_index_positions_scored": dict(
+        kind="counter", tag_keys=("model", "group"),
+        description="Positions whose index key a model's indexers had to "
+                    "score: the context of every live row (its cached "
+                    "positions and its own) an index layer, summed over "
+                    "committed decode steps",
+        emitted_by="llm replica"),
+    "rtpu_llm_index_positions_read": dict(
+        kind="counter", tag_keys=("model", "group"),
+        description="Positions whose K/V the same steps' attention had to "
+                    "read under the index: min(context, topk) a live row "
+                    "and index layer (over rtpu_llm_index_positions_scored: "
+                    "how far the contexts let the selection bite)",
+        emitted_by="llm replica"),
+    "rtpu_llm_index_blocks_held": dict(
+        kind="gauge", tag_keys=("model", "group"),
+        description="Blocks of the paged cache (each an index page an "
+                    "index layer beside its K/V) the live sequences hold, "
+                    "at the last committed decode step",
+        emitted_by="llm replica"),
     "rtpu_llm_tokens_total": dict(
         kind="counter", tag_keys=("model", "phase", "group"),
         description="Tokens processed by an LLM engine: 'prefill' = "

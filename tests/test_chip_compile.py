@@ -2004,3 +2004,162 @@ def test_sdar_prefill_program_fits_at_bucket_4096(sdar_runner, monkeypatch):
     total, _ = _held_bytes(compiled)
     # beside the pool the engine holds while a prompt runs
     assert total + 1.61e9 + SDAR_REFERENCE_BYTES < 16.9e9, total
+
+
+# --------------------------------------------- Keye: attention under an index
+@pytest.fixture(scope="module")
+def keye_runner(v5e):
+    """The Keye cell's runner over abstract weights (drawn in bf16, the
+    serving type), its K/V pool and its index plane as shapes on the
+    chip."""
+    import json
+    from pathlib import Path
+
+    from ray_tpu.models import llama
+    from ray_tpu.serve.llm import EngineConfig
+    from ray_tpu.serve.llm.config import resolve_model
+    from ray_tpu.serve.llm.kv_cache import device_shape
+    from ray_tpu.serve.llm.model_runner import ModelRunner
+    engine = json.loads((Path(__file__).parent.parent / "perfbench" /
+                         "configs" / "keye-vl-2.0-30b-a3b.json").read_text()
+                        )["serve"]["engine"]
+    for key in ("decode_batch_buckets", "prefill_len_buckets"):
+        engine[key] = tuple(engine[key])
+    ecfg = EngineConfig(**engine)
+    mod, mcfg = resolve_model(ecfg)
+    assert mod is llama and mcfg.index_topk == 2048
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.eval_shape(lambda key: mod.init_params(key, mcfg),
+                            jax.random.key(0))
+    runner = ModelRunner(ecfg, params=params)
+    assert runner.params is params      # drawn in its serving type
+    assert runner.chunk == 2048 and runner.block is None
+    kept = runner.family.kept
+    assert (kept.kv_layers, kept.index_layers, kept.index_dim) == (6, 6, 64)
+    held = {"kv": on_chip(device_shape(ecfg.num_blocks, 6, ecfg.block_size,
+                                       mcfg.n_kv_head, mcfg.head_dim),
+                          jnp.float32),
+            "index": on_chip(device_shape(ecfg.num_blocks, 6,
+                                          ecfg.block_size, 1, 64, planes=1),
+                             jnp.float32)}
+    weights = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), params)
+    return runner, ecfg, held, weights, on_chip
+
+
+# the float32 reference beside the engine: a block of 256 queries' index
+# products and scores over the check's 6,152 positions, eight experts
+# widened, a quarter of the head in float32
+KEYE_REFERENCE_BYTES = 1.2e9
+
+
+def test_keye_decode_program_reads_positions_and_fits(keye_runner,
+                                                      monkeypatch):
+    """The cell's decode step at its one bucket of 4 (6 layers at the
+    published widths, every expert, the whole head; 1,664 pages of 64):
+    the only Mosaic kernels are the experts' grouped matmuls and the cut's
+    counting passes (``index_topk_cut``, the step's rows one tile); what
+    is sorted is ONE int32 key a scored entry, never the float32 scores;
+    the K/V pool
+    (2.62e9 bytes) and the index plane (0.33e9) donated and each touched by
+    the update of the step's 4 rows alone; nothing of the pool's size is
+    made and no layer's K or V sliced out: the chosen positions' rows are
+    gathered from the pool where it lies, and NO copy of the pool in a
+    narrower type stands before the layers' loop (the first compile made
+    one, 1.3e9 bytes a step); K goes out with the index key as a fifth
+    head; the program's temporaries stay under 0.01e9 bytes."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, ecfg, held, weights, on_chip = keye_runner
+    bucket, i32 = ecfg.decode_batch_buckets[-1], jnp.int32
+    assert runner.param_bytes == 8_749_244_928
+    assert held["kv"].shape == (6, 2, 1664, 64, 512)
+    assert held["index"].shape == (6, 1, 1664, 64, 128)
+    lowered = runner._decode.lower(
+        held, weights, on_chip((bucket,), i32), on_chip((bucket,), i32),
+        on_chip((bucket, ecfg.max_blocks_per_seq), i32),
+        on_chip((bucket,), i32), on_chip((), i32),
+        on_chip((bucket,), i32), on_chip((bucket,), i32))
+    out = jax.tree.leaves(lowered.out_info)
+    assert out[-1].shape == (6, 4, 8) and out[-1].dtype == jnp.int32
+    assert out[-3].shape == (6, 4, 5, 128) and out[-2].shape == (6, 4, 4, 128)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    for scope in ("index_proj", "index_score", "index_topk", "attn_indexed"):
+        assert f"/{scope}/" in text, scope
+    assert not re.search(r"= bf16\[6,2,1664,64,512\]", text)
+    assert not re.search(r"= bf16\[128,(2048,768|768,2048)\]", text)
+    assert "index_topk_cut" in text
+    # (the router's top 8 of 128 and the experts' dispatch sort too)
+    sorts = [found for line in text.splitlines() if " sort(" in line
+             for found in re.findall(r"(\w+)\[4,(\d{4,})\]",
+                                     line.split(" sort(")[0])]
+    assert sorts == [("s32", str(416 * 64 + 1))], sorts
+    _assert_the_pool_is_read_in_place_and_written_by_rows(
+        text, held["kv"].shape, lanes_used=4 * 128)
+    plane = held["index"].shape
+    rows = f"{math.prod(plane[:-1])},{plane[-1]}"
+    assert sorted(_made(text, math.prod(plane))) == [
+        ("fusion", rows), ("scatter", rows)]
+    total, mem = _held_bytes(compiled)
+    assert mem.alias_size_in_bytes >= 2.62e9 + 0.32e9
+    assert mem.temp_size_in_bytes < 0.01e9
+    assert total + 0.74e9 + KEYE_REFERENCE_BYTES < 16.9e9, total
+
+
+def test_keye_chunk_program_is_one_and_carries_no_holder(keye_runner,
+                                                         monkeypatch):
+    """The one prefill program: a chunk of 2,048 positions over a staging
+    of 26,624 (K, V and index keys: 0.74e9 bytes, donated and returned);
+    in the layer scan's body the score kernel, the cut's kernel and the
+    masked flash kernel beside the experts' three; it is handed no holder,
+    so the pools go untouched; its temporaries (the (2,048, 26,624) scores,
+    their keys and the mask a position wide) stay under 1.0e9 bytes."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, ecfg, held, weights, on_chip = keye_runner
+    staging = jax.tree.map(lambda s: on_chip(s.shape, s.dtype),
+                           runner.staging_spec)
+    assert staging["k"].shape == (6, 26624, 512)
+    assert staging["index"].shape == (6, 26624, 128)
+    assert runner.staging_bytes == 6 * 26624 * (512 + 512 + 128) * 4
+    i32 = jnp.int32
+    lowered = runner._prefill_chunk.lower(
+        None, weights, staging, on_chip((1, runner.chunk), i32),
+        on_chip((), i32), on_chip((), i32))
+    ids = jax.tree.leaves(lowered.out_info)[-1]
+    assert ids.shape == (6, 2048, 8) and ids.dtype == jnp.int32
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 6
+    for kernel in ("index_score", "index_topk_cut", "sparse_prefill"):
+        assert kernel in text, kernel
+    assert not re.search(r"= bf16\[128,(2048,768|768,2048)\]", text)
+    total, mem = _held_bytes(compiled)
+    assert mem.alias_size_in_bytes >= 0.73e9        # the staging
+    assert mem.temp_size_in_bytes < 1.0e9
+    # beside the pools the engine holds while a prompt runs
+    assert total + 2.95e9 + KEYE_REFERENCE_BYTES < 16.9e9, total
+
+
+@pytest.mark.parametrize("bucket", [8192, 26624])
+def test_keye_scatter_program_writes_both_planes_by_rows(keye_runner,
+                                                         monkeypatch, bucket):
+    """A prompt's scatter at the smallest and the largest bucket: K comes
+    with the index key as a fifth head and leaves it to the index plane;
+    both pools are donated and written by rows."""
+    from ray_tpu.serve.llm.kv_cache import _programs
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, ecfg, held, weights, on_chip = keye_runner
+    f32, i32 = jnp.float32, jnp.int32
+    compiled = _programs().scatter_prefill.lower(
+        held, on_chip((bucket // ecfg.block_size,), i32),
+        on_chip((6, bucket, 5, 128), f32), on_chip((6, bucket, 4, 128), f32),
+        on_chip((), i32)).compile()
+    text = compiled.as_text()
+    assert not _made(text, math.prod(held["kv"].shape[1:]))
+    total, mem = _held_bytes(compiled)
+    assert mem.alias_size_in_bytes >= 2.62e9 + 0.32e9
+    assert mem.temp_size_in_bytes < 1.4e9 * bucket / 26624 + 0.1e9
+    # beside the weights and the staging
+    assert total + 8.75e9 + 0.74e9 < 16.9e9, total

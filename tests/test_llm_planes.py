@@ -78,7 +78,9 @@ PARENT_STATS_KEYS = {
     "staging_bytes", "state_bytes", "state_commits", "state_layers",
     "state_rows", "state_rows_stepped", "state_rows_used", "tokens_out",
     "waiting", "window_blocks", "window_blocks_read",
-    "window_blocks_unwindowed", "window_bytes", "window_layers"}
+    "window_blocks_unwindowed", "window_bytes", "window_layers"} | {
+    # PR 67: the index plane's four, 0 for a family without one
+    "index_layers", "index_bytes", "positions_scored", "positions_read"}
 
 
 def _cfg(family):
@@ -145,7 +147,8 @@ def test_the_planes_built_from_the_declaration_are_the_parents_holder(family):
     # the cache's planes are the table's rows for its entries, in its order
     names = [p.name for p in kvmod.PLANES]
     assert [p.name for p in cache.planes] == [n for n in names if n in held]
-    assert names == ["kv", "state", "sel", "kvw", "latent"]
+    assert names == ["kv", "state", "sel", "kvw", "latent", "index"]
+    assert cache.index_bytes == 0 and "index" not in held
     cache.close()
 
 
