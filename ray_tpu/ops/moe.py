@@ -762,6 +762,139 @@ def grouped_matmul(rows: jax.Array, w: jax.Array, group_sizes: jax.Array,
     return out
 
 
+# ------------------------------------------------------ the router's choice
+# ``lax.top_k`` of k in E sorts every row of E with an iota payload and slices
+# k off (1.3-1.5 ms a call at (16384, 512) on the v5e), ``take_along_axis``
+# gathers the N k chosen numbers one by one (8 ns each) and the backward of
+# either is a scatter-add into a zeroed (N, E).  The kernel below holds a tile
+# of tokens in VMEM and takes k maxima off it; the backward is a select.
+_CHOICE_TOKENS = 256            # tokens a grid step of ``router_choice``
+
+
+def _choice_kernel(*refs, k, same):
+    """Grid step i: tokens ``i tile .. (i + 1) tile``.  The block arrives
+    (tile, E) as the router made it and is turned (E, tile) in VMEM: the
+    experts along the sublanes, a token a lane, so a row's maximum is
+    elementwise across E / 8 vregs and a reduce inside one, and a round's
+    result is a whole lane row of the (k, N) results.  k rounds: the
+    maximum, the FIRST expert that holds it (``lax.top_k``'s tie), the
+    payload there (a sum of one term), the expert struck out.  A key of
+    ``-inf`` (a group that ``_within_best_groups`` shut out) reads as the
+    least finite number, so a struck expert is never met again and a
+    row's k are distinct whatever it holds."""
+    keys_ref, *payload_ref, idx_ref, picked_ref = refs
+    x = jnp.maximum(keys_ref[...].T, jnp.finfo(jnp.float32).min)
+    payload = None if same else payload_ref[0][...].T
+    num_experts = x.shape[0]
+    # ids as float32, exact below 2 ** 24: the vector unit has a float
+    # minimum and makes an integer one of a compare and a select
+    expert = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0).astype(
+        jnp.float32)
+    for j in range(k):
+        best = jnp.max(x, axis=0, keepdims=True)
+        idx = jnp.min(jnp.where(x == best, expert, num_experts), axis=0,
+                      keepdims=True)
+        hit = expert == idx
+        idx_ref[j:j + 1, :] = idx.astype(jnp.int32)
+        picked_ref[j:j + 1, :] = best if same else jnp.sum(
+            jnp.where(hit, payload, 0.0), axis=0, keepdims=True)
+        x = jnp.where(hit, -jnp.inf, x)
+
+
+@functools.lru_cache(maxsize=None)
+def _router_choice(n: int, num_experts: int, k: int, same: bool,
+                   interpret: bool):
+    """The kernel at one shape: keys (and, unless they are the ``same``,
+    the payload) (N, E) float32 -> (expert_idx int32, picked float32), each
+    (k, N) in rows of whole sublane tiles, which the caller slices and
+    turns.  The ``pallas_call`` stands inside a jitted function of the
+    kernel's name so that the v5e's trace of a step prints it
+    (``ops/delta_rule._kernel``); kept, so that a step traces the body once
+    a shape however often ``custom_vjp`` and a checkpoint ask for it."""
+    from jax.experimental.pallas import tpu as pltpu
+    tile, rows = _CHOICE_TOKENS, -(-k // 8) * 8
+    by_token = pl.BlockSpec((rows, tile), lambda i: (0, i))
+
+    def router_choice(*operands):
+        return pl.pallas_call(
+            functools.partial(_choice_kernel, k=k, same=same),
+            grid=(n // tile,),
+            in_specs=[pl.BlockSpec((tile, num_experts), lambda i: (i, 0))]
+            * len(operands),
+            out_specs=[by_token, by_token],
+            out_shape=[jax.ShapeDtypeStruct((rows, n), jnp.int32),
+                       jax.ShapeDtypeStruct((rows, n), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",)),
+            interpret=interpret, name="router_choice")(*operands)
+    return jax.jit(router_choice)
+
+
+def _choice_by_kernel(keys, payload, k: int, *, interpret: bool = False):
+    """``choose_experts``' results from the kernel."""
+    operands = (keys,) if payload is None else (keys, payload)
+    idx, picked = _router_choice(*keys.shape, k, payload is None,
+                                 interpret)(*operands)
+    return idx[:k].T, picked[:k].T
+
+
+def _choice_in_kernel(n: int, num_experts: int) -> bool:
+    """Whether a call's experts are picked by the kernel: on a TPU, the
+    experts whole 128-lane blocks and the tokens whole tiles (Qwen3-Next's
+    and Kanana's steps, SDAR's and Keye's prompts).  Everything else takes
+    ``lax.top_k``: the CPU, 64 or 32 experts (OLMoE, LFM2, Ling, Trinity),
+    a decode step's 4-128 rows."""
+    return (jax.default_backend() == "tpu" and num_experts % 128 == 0
+            and n > 0 and n % _CHOICE_TOKENS == 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _choose(keys, payload, k, same):
+    """``choose_experts`` on keys whose gradient is stopped; ``same``: the
+    keys are the payload too, and ``payload`` only names where the
+    gradient goes."""
+    if _choice_in_kernel(*keys.shape):
+        return _choice_by_kernel(keys, None if same else payload, k,
+                                 interpret=jax.default_backend() != "tpu")
+    vals, idx = jax.lax.top_k(keys, k)
+    return idx, vals if same else jnp.take_along_axis(payload, idx, axis=-1)
+
+
+def _choose_fwd(keys, payload, k, same):
+    idx, picked = _choose(keys, payload, k, same)
+    return (idx, picked), (idx, keys.shape[1])
+
+
+def _choose_bwd(k, same, res, cts):
+    idx, num_experts = res
+    expert = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], num_experts),
+                                      1)
+    d_payload = jnp.zeros(expert.shape, cts[1].dtype)
+    for j in range(k):
+        # + and not a select alone: a scatter-add's 0 + -0.0 is +0.0
+        d_payload += jnp.where(expert == idx[:, j:j + 1],
+                               cts[1][:, j:j + 1], 0)
+    return None, d_payload
+
+
+_choose.defvjp(_choose_fwd, _choose_bwd)
+
+
+def choose_experts(keys: jax.Array, payload: Optional[jax.Array], k: int):
+    """-> (expert_idx (N, k) int32, picked (N, k) float32): the k largest
+    ``keys`` (N, E) of every row in falling order, of equal keys the lower
+    id first, as ``lax.top_k`` names them, and ``payload`` (N, E) there;
+    None: the keys themselves.  The keys decide and carry no gradient; the
+    payload's is ``d picked`` put where it was picked, as a select over
+    (N, E) in one fusion: a row's k are distinct, so it equals the
+    scatter-add of ``top_k``'s and ``take_along_axis``'s own derivative bit
+    for bit.  Which forward runs is read from the call
+    (``_choice_in_kernel``)."""
+    same = payload is None
+    return _choose(jax.lax.stop_gradient(keys), keys if same else payload,
+                   k, same)
+
+
 def route_softmax(x: jax.Array, w_router: jax.Array, k: int,
                   norm_topk: bool = False):
     """-> (expert_idx (N, k), weights (N, k), logits, probs (N, E)): a
@@ -773,7 +906,7 @@ def route_softmax(x: jax.Array, w_router: jax.Array, k: int,
     logits = jnp.dot(x, w_router.astype(x.dtype),
                      preferred_element_type=jnp.float32)         # (N, E)
     probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, k)              # (N, k)
+    expert_idx, gate_vals = choose_experts(probs, None, k)       # (N, k)
     if norm_topk:
         gate_vals = gate_vals / gate_vals.sum(-1, keepdims=True)
     return expert_idx, gate_vals, logits, probs
@@ -801,8 +934,7 @@ def route_sigmoid(x: jax.Array, w_router: jax.Array, select_bias: jax.Array,
     select = scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32))
     if n_group > 1:
         select = _within_best_groups(select, n_group, topk_group)
-    _, expert_idx = jax.lax.top_k(select, k)
-    chosen = jnp.take_along_axis(scores, expert_idx, axis=-1)
+    expert_idx, chosen = choose_experts(select, scores, k)
     weights = chosen / (chosen.sum(-1, keepdims=True) + eps)
     return expert_idx, weights * weight_scale
 

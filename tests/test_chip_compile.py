@@ -110,6 +110,39 @@ def _assert_no_layers_experts_are_made(text, shapes, also=()):
     assert not rest, [(name, op) for name, op, _ in made if op in rest]
 
 
+def _assert_the_router_picks_in_vmem(text, tokens, experts, calls):
+    """What ``ops/moe.choose_experts`` spares a step (PR 68), read off a
+    compiled module: the k experts a token are picked by ``calls`` Mosaic
+    kernels named ``router_choice`` whose first result, the ids as whole
+    sublane tiles of (k, N), is a shape ``moe.router_choice_ms`` knows them
+    by; nothing sorts the (N, E) scores (the parent: ``sort.382`` /
+    ``.392``, ``(f32[16384,128], s32[16384,128])``, ``lax.top_k``), nothing
+    under the router's scope gathers (``take_along_axis``: 98,304 single
+    numbers a call) and nothing scatters into an array the scores' size
+    (their derivative: ``scatter.94`` into ``f32[2097152]``)."""
+    import json
+    from pathlib import Path
+    keyed = json.loads((Path(__file__).parent.parent / "perfbench"
+                        / "layer_metrics" / "moe.router_choice_ms.json"
+                        ).read_text())["params"]
+    picked = [shape for name, shape in _kernel_names_and_results(text)
+              if any(part in name for part in keyed["names"])]
+    assert len(picked) == calls and set(picked) <= set(keyed["shapes"]), \
+        picked
+    scores = (f"f32[{tokens},{experts}]", f"s32[{tokens},{experts}]",
+              f"f32[{tokens * experts}]")
+    lines = text.splitlines()
+    for op in ("sort", "scatter"):
+        over = [line[:160] for line in lines if f" {op}(" in line
+                and any(s in line.split(f" {op}(")[0] for s in scores)]
+        assert not over, over
+    under = [line for line in lines
+             if re.search(r'op_name="[^"]*\brouter\b', line)]
+    assert len(under) > 50, len(under)
+    assert not [line[:160] for line in under
+                if re.search(r" (sort|scatter|gather)\(", line)]
+
+
 def _flash(q, k, v):
     return flash_attention(q, k, v, True, None, False)
 
@@ -604,6 +637,40 @@ def test_held_row_kernels_compile_at_the_cells_shapes(v5e, monkeypatch, which,
     assert " gather(" not in text
 
 
+def _choice_loss(keys, *payload, k):
+    from ray_tpu.ops.moe import choose_experts
+    idx, picked = choose_experts(keys, payload[0] if payload else None, k)
+    return picked.sum(), idx
+
+
+@pytest.mark.parametrize("n,e,k,biased", [
+    pytest.param(16384, 512, 10, False, id="qwen3_next_step"),
+    pytest.param(16384, 128, 6, True, id="kanana_step"),
+    pytest.param(2048, 128, 8, False, id="prefill_chunk"),
+])
+def test_the_routers_choice_compiles_at_the_cells_shapes(v5e, monkeypatch, n,
+                                                         e, k, biased):
+    """``ops/moe.choose_experts`` with its gradient: one Mosaic kernel, a
+    tile of 256 tokens' (256, E) keys (and payload) turned in VMEM inside
+    Mosaic's default scoped limit, its instruction named after the jitted
+    function round it and its first result the ids as (k to whole sublane
+    tiles, N); the backward a select in XLA: no sort, no gather and no
+    scatter anywhere in the program."""
+    import functools
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    scores = ((n, e), jnp.float32)
+    fn = jax.value_and_grad(functools.partial(_choice_loss, k=k),
+                            argnums=int(biased), has_aux=True)
+    text = _compile(fn, v5e, *[scores] * (1 + biased))
+    (kernel,) = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+    rows = -(-k // 8) * 8
+    assert re.search(rf"%router_choice\.\d+ = \(s32\[{rows},{n}\]", kernel), \
+        kernel[:200]
+    assert "vmem_limit_bytes" not in kernel
+    assert not re.search(r" (sort|scatter|gather)\(", text)
+
+
 # ----------------------------------------------- the serving cell's decode
 def _paged(q, kv_pool, layer, tables, lens, k_new, v_new):
     from ray_tpu.ops.paged_attention import _paged_decode_kernel
@@ -985,7 +1052,9 @@ def test_kanana_step_compiles_with_its_kernels_and_fits(v5e, monkeypatch):
     three ``tgmm`` results and their way into the gradients' stack has a
     layer's expert matrix as its result (the parent: three sliced copies
     in each loop, and a second copy of five of them to another memory),
-    and the temporaries read 9,265,399,808 B under the bound kept here."""
+    and the temporaries read 9,265,399,808 B under the bound kept here.
+    Since PR 68 a sparse layer's router picks its 6 of 128 experts in the
+    kernel ``router_choice``, once in each loop (9,240,136,704 B)."""
     import json
     from pathlib import Path
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -1015,8 +1084,10 @@ def test_kanana_step_compiles_with_its_kernels_and_fits(v5e, monkeypatch):
         + keyed["moe.held_expert_ms"]["names"]
     walks = sorted((name.split(".")[0], shape) for name, shape in kernels
                    if not any(part in name for part in read))
-    assert walks == [("spread_held_rows", "bf16[98304,2048]")] * 3 \
+    assert walks == [("router_choice", "s32[8,16384]")] * 2 \
+        + [("spread_held_rows", "bf16[98304,2048]")] * 3 \
         + [("sum_held_slots", "bf16[16384,2048]")] * 2, kernels
+    _assert_the_router_picks_in_vmem(text, 16384, 128, calls=2)
     assert len(flash) + len(experts) + len(walks) == len(kernels)
     assert "ragged-dot" not in text
     assert not re.search(r"\[16384,128,\d+\]", text)    # no dispatch tensor
@@ -1109,9 +1180,16 @@ def _operations_and_kernels(lowered_text):
 # ``dynamic_update_slice`` of 64 counts a layer); what LEFT is every use of
 # the scan's own slice of the experts, which now only names where the
 # ``tgmm`` results go: 4,089 operations, the same 9 kernels with the same
-# bodies, the ``gmm`` ones over ``192`` groups where they had 64.
+# bodies, the ``gmm`` ones over ``192`` groups where they had 64.  PR 68
+# replaced OLMoE's on purpose (0d4c147702843346, 4,089 operations until
+# then): the router's ``lax.top_k`` of 8 in 64 stands inside
+# ``ops/moe.choose_experts``' ``custom_vjp`` (64 experts are no whole lane
+# block, so the forward is the parent's), whose backward is 8 compares,
+# selects and adds over (8192, 64); what LEFT is a ``scatter``, ``top_k``'s
+# own derivative into the zeroed scores (38 -> 37): 4,139 operations, the
+# same 9 kernels.  XL's has no router and stood.
 PARENT_STEPS = {
-    "olmoe-1b-7b.train-b2-s4096": ("0d4c147702843346", 4089, 9),
+    "olmoe-1b-7b.train-b2-s4096": ("b4a26fbc3b10a6a5", 4139, 9),
     "gpt2-xl-1558m.train-b8-s1024": ("bcfab170aaa276dc", 1795, 2),
 }
 
@@ -1127,7 +1205,7 @@ def test_older_training_steps_lower_to_the_operations_and_kernels_they_had(
         PARENT_STEPS[cell]
     # PR 56's kernels are for a layer that holds a share of its experts:
     # OLMoE holds all 64 and XL has none; XL's digest is e45e91f's still
-    assert digest in ("0d4c147702843346", "bcfab170aaa276dc")
+    assert digest in ("b4a26fbc3b10a6a5", "bcfab170aaa276dc")
 
 
 def test_xl_step_holds_no_split_head_and_no_copy_round_its_kernels(
@@ -1249,6 +1327,9 @@ def test_qwen3_next_step_reads_its_experts_in_their_stacks(v5e, monkeypatch):
     assert len(experts) == 2 * 11
     assert set(experts) == set(keyed["shapes"])
     assert "ragged-dot" not in text
+    # 10 of 512: once in each loop of the inner scan, twice for the
+    # attention layer (PR 68; the temporaries read 9,359,284,736 B)
+    _assert_the_router_picks_in_vmem(text, 16384, 512, calls=4)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes <= 9_424_205_824 + 2 * 2 ** 20, \
         mem.temp_size_in_bytes
@@ -1506,6 +1587,9 @@ def test_minicpm_sala_chunk_program_is_one_and_keeps_the_pool_out(
 # sort of (expert id, iota, weight) and one of the order back, gathers that
 # promise their indices, the assignments numbered slot by slot, the counts
 # a compare and a sum: 1,372 operations, none of them a scatter-add).
+# PR 68 put ``route_sigmoid``'s ``top_k`` and ``take_along_axis`` inside
+# ``ops/moe.choose_experts``; a decode step's 32 rows of 64 experts take
+# them as they did and no program differentiates them: all three stood.
 PARENT_DECODE_STEPS = {
     "xl": ("b5e4a17574d47c3c", 413, 1),
     "falcon_h1": ("6dc9e09f46d43408", 673, 1),
@@ -2113,7 +2197,9 @@ def test_keye_chunk_program_is_one_and_carries_no_holder(keye_runner,
     """The one prefill program: a chunk of 2,048 positions over a staging
     of 26,624 (K, V and index keys: 0.74e9 bytes, donated and returned);
     in the layer scan's body the score kernel, the cut's kernel and the
-    masked flash kernel beside the experts' three; it is handed no holder,
+    masked flash kernel beside the experts' three and, since PR 68, the
+    router's choice of 8 in 128 (``router_choice``: 2,048 rows are whole
+    tiles, a decode step's 4 are not); it is handed no holder,
     so the pools go untouched; its temporaries (the (2,048, 26,624) scores,
     their keys and the mask a position wide) stay under 1.0e9 bytes."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -2131,8 +2217,9 @@ def test_keye_chunk_program_is_one_and_carries_no_holder(keye_runner,
     assert ids.shape == (6, 2048, 8) and ids.dtype == jnp.int32
     compiled = lowered.compile()
     text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 6
-    for kernel in ("index_score", "index_topk_cut", "sparse_prefill"):
+    assert text.count('custom_call_target="tpu_custom_call"') == 7
+    for kernel in ("index_score", "index_topk_cut", "sparse_prefill",
+                   "router_choice"):
         assert kernel in text, kernel
     assert not re.search(r"= bf16\[128,(2048,768|768,2048)\]", text)
     total, mem = _held_bytes(compiled)

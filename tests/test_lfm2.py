@@ -611,7 +611,11 @@ def test_bf16_engine_stays_within_the_bf16_tolerance():
 # combine is the dispatch transposed; one grouped matmul fewer a layer) and
 # since PR 49 did again (the same function: one sort carries the weights
 # and gives the order, its inverse and the weights' gradient; the gathers
-# promise their indices; the k slots lead; the counts are a compare).
+# promise their indices; the k slots lead; the counts are a compare) and
+# since PR 68 did a third time (``ops/moe.choose_experts``: the choice of
+# the k experts stands inside a ``custom_vjp`` whose backward is k compares
+# and selects over (N, E); what left each step is one ``scatter``, the
+# derivative of ``top_k`` / ``take_along_axis``: 3 -> 2 and 5 -> 4).
 # MiniCPM-SALA's three were lowered at the parent of PR 46 (commit ac47f06),
 # before that PR merged the runner's step bodies under them.  It renamed
 # three modules and changed nothing else of their texts (CHANGES.md has the
@@ -625,8 +629,8 @@ PARENT_LOWERINGS = {
     "minicpm_sala chunk": "050e0ff1c618ee13",
     "minicpm_sala decode": "95b933bcb41266bb",
     "minicpm_sala scatter": "1b0195ec5bd06f38",
-    "olmoe train": "4289a77c8300a44d",
-    "kanana train": "22560f398dcec728",
+    "olmoe train": "d56f5a70d52c70f1",
+    "kanana train": "105d3e7e4481e223",
 }
 
 

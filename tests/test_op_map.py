@@ -214,12 +214,15 @@ def test_a_new_program_of_a_name_replaces_the_old():
 # changed it on purpose (ops/moe.dropless_experts: the router's weight on
 # the hidden rows, the combine the dispatch transposed) and PR 49 did again
 # (the same function: everything read off one sort, gathers that promise
-# their indices, the k slots leading, the counts a compare and a sum).
+# their indices, the k slots leading, the counts a compare and a sum) and
+# PR 68 a third time (ops/moe.choose_experts: the router's ``top_k`` stands
+# inside a ``custom_vjp`` whose backward is a select over (N, E); what left
+# is the ``scatter`` of ``top_k``'s own derivative).
 PARENT_TRAIN_STEPS = {
     "gpt2:tiny": (gpt2, gpt2.tiny, 64, "f76d5da583f18826"),
     "llama:tiny": (llama, llama.PRESETS["tiny"], 32, "4d2cd3d1fa54a1c8"),
     "llama:tiny-moe": (llama, llama.PRESETS["tiny-moe"], 32,
-                       "484dd4b681c5563a"),
+                       "72148e509ba80f38"),
 }
 
 
