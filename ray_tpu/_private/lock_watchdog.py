@@ -559,6 +559,8 @@ DONATED: Dict[str, Tuple[int, ...]] = {
     # a prompt's chunk also donates the runner's staging K/V (argnum 2),
     # which comes back in the program's result and is rebound there
     "llm_prefill_chunk_step": (0, 2),
+    # the fold of a window that closes in decode: the pool's holder
+    "llm_fold_step": (0,),
     "kv_write_rows": (0,),
     "kv_scatter_prefill": (0,),
     "kv_load_block": (0,),

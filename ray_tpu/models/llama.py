@@ -34,6 +34,23 @@ the decode step is handed the index plane (``index_pool``), and a
 position's index key leaves every serving forward as ONE MORE HEAD OF ITS
 K (its first ``index_dim`` lanes; ``kv_cache.py``, the ``"index"`` row, says
 why).  With ``index_topk`` 0 none of this is in a program or a tree.
+
+A folded cache (``eva_window`` > 0; EvaByte, Llama-2-7B's stack to the last
+width under EVA's attention, ``ops/eva_attention.py``).  A query sees its own
+window of ``eva_window`` positions exactly and every window that has closed
+as one folded key and value for every ``eva_chunk`` positions, through one
+softmax.  Such a preset is served alone too: a prompt runs in chunks of one
+window over a staging laid out as the rows a sequence HOLDS (``[a closed
+window's folded rows]* + [the open window's rows]``), each chunk causal in
+those coordinates and folded at its end (:func:`_run_eva`); a decode step is
+handed the rows a sequence holds as its ``ctx_lens`` and the positions it has
+seen as ``positions``, and reads the shrunk table through the plain paged
+walk; a window that closes in decode is folded out of the pool by
+:func:`fold_window`.  The stream stays float32 between layers
+(``residual_f32``), the norms multiply by ``1 + g`` (``norm_offset``) and the
+head is ``pred_heads`` heads of ``vocab_size`` side by side, head 0 the next
+token: the serving forwards return head 0's logits, :func:`forward` all of
+them.  With ``eva_window`` 0 none of this is in a program or a tree.
 """
 
 from __future__ import annotations
@@ -126,8 +143,30 @@ class LlamaConfig:
     # positions one prefill program runs where a prompt runs in chunks (a
     # preset with an index); 0: a prompt is one program of its bucket
     prefill_chunk: int = 0
+    # > 0: EVA's folded attention.  A query sees the positions of its own
+    # window of ``eva_window`` exactly and every closed window as one
+    # folded key and value for every ``eva_chunk`` positions (``phi``,
+    # ``mu`` a head a layer: ``blocks["eva"]``); a prompt runs in chunks of
+    # one window.  0: none, and nothing of it is in the tree or a program
+    eva_window: int = 0
+    eva_chunk: int = 0
+    # heads of ``vocab_size`` logits side by side in ``lm_head`` (head i
+    # scores the token at t + 1 + i); the serving forwards return head 0
+    pred_heads: int = 1
+    # RMSNorm multiplies by ``1 + g`` (the scales start at 0)
+    norm_offset: bool = False
+    # the stream between the layers is float32: every residual add
+    residual_f32: bool = False
 
     def __post_init__(self):
+        if self.eva_window and (
+                self.eva_chunk < 1 or self.eva_window % self.eva_chunk
+                or self.prefill_chunk != self.eva_window or self.n_experts
+                or self.index_topk or self.block_length > 1):
+            raise ValueError(
+                "a folded cache needs a window of whole chunks, a "
+                "prefill_chunk of one window, and a dense model without an "
+                "index that steps by tokens")
         if self.index_topk and (
                 self.block_length > 1 or not 0 < self.index_dim
                 <= self.head_dim or self.index_heads < 1
@@ -224,10 +263,35 @@ def keye_vl_2_30b_a3b_l6() -> LlamaConfig:
                        prefill_chunk=2048)
 
 
+def tiny_eva(vocab: int = 64, seq: int = 160) -> LlamaConfig:
+    """EvaByte's block at a test's size: windows of 32 positions folded 4
+    to 1, 4 heads of 16, 2 prediction heads, float32."""
+    return LlamaConfig(vocab_size=vocab, max_positions=seq, n_embd=64,
+                       n_layer=2, n_head=4, n_kv_head=4, ffn_dim=128,
+                       rope_theta=1e5, scaled_residual_init=False,
+                       fan_in_init=True, eva_window=32, eva_chunk=4,
+                       prefill_chunk=32, pred_heads=2, norm_offset=True,
+                       residual_f32=True, dtype=jnp.float32)
+
+
+def evabyte_6_5b_l8() -> LlamaConfig:
+    """EvaByte's published widths with 8 of its 32 layers (one of four
+    pipeline stages; perfbench/configs/evabyte-6.5b.json), served in
+    bf16."""
+    return LlamaConfig(vocab_size=320, max_positions=32768, n_embd=4096,
+                       n_layer=8, n_head=32, n_kv_head=32, ffn_dim=11008,
+                       rope_theta=1e5, rms_eps=1e-5,
+                       scaled_residual_init=False, fan_in_init=True,
+                       param_dtype=jnp.bfloat16, remat=False,
+                       eva_window=2048, eva_chunk=16, prefill_chunk=2048,
+                       pred_heads=8, norm_offset=True, residual_f32=True)
+
+
 PRESETS = {"llama2-7b": llama2_7b, "llama3-8b": llama3_8b, "tiny": tiny,
            "tiny-moe": tiny_moe, "tiny-sdar": tiny_sdar,
            "sdar-30b-a3b-l6": sdar_30b_a3b_l6, "tiny-keye": tiny_keye,
-           "keye-vl-2.0-30b-a3b-l6": keye_vl_2_30b_a3b_l6}
+           "keye-vl-2.0-30b-a3b-l6": keye_vl_2_30b_a3b_l6,
+           "tiny-eva": tiny_eva, "evabyte-6.5b-l8": evabyte_6_5b_l8}
 
 
 # ------------------------------------------------------------------- params
@@ -247,13 +311,15 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Params:
             scale = gain / math.sqrt(shape[-2])
         return normal_init(next(k), (L, *shape), pd, scale)
 
+    # under norm_offset a norm multiplies by 1 + g: g starts at 0
+    ones = jnp.zeros if cfg.norm_offset else jnp.ones
     blocks = {
-        "attn_norm": {"scale": jnp.ones((L, E), pd)},
+        "attn_norm": {"scale": ones((L, E), pd)},
         "wq": {"kernel": stacked(E, q_dim)},
         "wk": {"kernel": stacked(E, kv_dim)},
         "wv": {"kernel": stacked(E, kv_dim)},
         "wo": {"kernel": stacked(q_dim, E, scale=out_scale)},
-        "mlp_norm": {"scale": jnp.ones((L, E), pd)},
+        "mlp_norm": {"scale": ones((L, E), pd)},
     }
     if cfg.qk_norm:
         per = cfg.head_dim if cfg.qk_norm_heads else 0
@@ -284,13 +350,20 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Params:
             "index_norm": {"scale": jnp.ones((L, ID), pd),
                            "bias": jnp.zeros((L, ID), pd)}}
         k = rest
+    if cfg.eva_window:
+        # keys of its own; normal, clipped to +-1, at 1 / sqrt(head_dim)
+        D = cfg.head_dim
+        drawn = jax.random.normal(jax.random.fold_in(rng, 2),
+                                  (2, L, cfg.n_kv_head, D), jnp.float32)
+        phi, mu = (jnp.clip(drawn, -1.0, 1.0) / math.sqrt(D)).astype(pd)
+        blocks["eva"] = {"phi": phi, "mu": mu}
     table = 1.0 / math.sqrt(E) if fan_in else 0.02
     return {
         "wte": normal_init(next(k), (cfg.vocab_size, E), pd, table),
         "blocks": blocks,
-        "norm_f": {"scale": jnp.ones((E,), pd)},
-        "lm_head": {"kernel": normal_init(next(k), (E, cfg.vocab_size), pd,
-                                          table)},
+        "norm_f": {"scale": ones((E,), pd)},
+        "lm_head": {"kernel": normal_init(
+            next(k), (E, cfg.pred_heads * cfg.vocab_size), pd, table)},
     }
 
 
@@ -484,6 +557,10 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig):
         raise NotImplementedError(
             "training under a learned index is not written: the indexer "
             "has no loss of its own here (ROADMAP, Reach)")
+    if cfg.eva_window:
+        raise NotImplementedError(
+            "training under a folded cache is not written: the fold has "
+            "no backward here (ROADMAP, Reach)")
     x = _embed(params, tokens, cfg)
     blocks = params["blocks"]
     block = partial(_block, cfg=cfg)
@@ -504,7 +581,10 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig):
 
 
 def forward(params: Params, tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:
-    """tokens (B, T) int32 → logits (B, T, vocab) f32."""
+    """tokens (B, T) int32 → logits (B, T, vocab) f32; under
+    ``pred_heads`` > 1 every head's, (B, T, pred_heads, vocab)."""
+    if cfg.eva_window:
+        return _forward_eva(params, tokens, cfg, None, heads=True)[0]
     if cfg.index_topk:
         return forward_prefill(params, tokens, cfg)[0]
     x, _ = forward_hidden(params, tokens, cfg)
@@ -550,12 +630,32 @@ def cache_layers(cfg: LlamaConfig) -> Dict[str, int]:
     return kinds
 
 
+def folded_cache(cfg: LlamaConfig) -> Optional[Dict[str, int]]:
+    """What ``kv_cache.kept_by`` is told of a sequence that folds: a closed
+    ``window`` of positions is kept as one row a ``chunk``.  None: a
+    sequence keeps every position's row."""
+    if not cfg.eva_window:
+        return None
+    return {"window": cfg.eva_window, "chunk": cfg.eva_chunk}
+
+
 def prefill_staging(cfg: LlamaConfig,
                     positions: int) -> Dict[str, jax.ShapeDtypeStruct]:
     """What a prompt's chunks keep between them (a preset with an index):
     every layer's K and V, a position a row of ``KV x D`` lanes, and its
     index key in the first ``index_dim`` lanes of a row a head wide, the
-    form in which it rides behind K's heads; ``positions`` whole chunks."""
+    form in which it rides behind K's heads; ``positions`` whole chunks.
+
+    Under a folded cache: K and V alone, laid out as the rows a sequence
+    HOLDS, the folded rows of the windows before the last and the last
+    window's own (whole key tiles where there are several)."""
+    if cfg.eva_window:
+        per = cfg.eva_window // cfg.eva_chunk
+        rows = (positions // cfg.eva_window - 1) * per + cfg.eva_window
+        rows += -rows % (512 if rows > 512 else per)
+        kv = jax.ShapeDtypeStruct(
+            (cfg.n_layer, rows, cfg.n_kv_head * cfg.head_dim), jnp.float32)
+        return {"k": kv, "v": kv}
     kv = jax.ShapeDtypeStruct(
         (cfg.n_layer, positions, cfg.n_kv_head * cfg.head_dim), jnp.float32)
     return {"k": kv, "v": kv, "index": jax.ShapeDtypeStruct(
@@ -627,7 +727,17 @@ def forward_prefill_chunk(params: Params, tokens: jax.Array,
     Returns (logits (1, V) at the prompt's last position where this chunk
     holds it (else at the chunk's first), the staging, None: it keeps no
     recurrent state) and, with ``choices``, the experts chosen, (L, C, k)
-    int32."""
+    int32.
+
+    Under a folded cache the chunk is one window, attends to the folded
+    rows of every window before it and to itself causally, and is folded
+    at its end where the prompt fills it."""
+    if cfg.eva_window:
+        x, staging = _run_eva(params, tokens[0], cfg, start, n_total,
+                              staging)
+        last = jnp.clip(n_total - 1 - start, 0, tokens.shape[1] - 1)
+        x = lax.dynamic_slice_in_dim(x, last, 1, axis=0)
+        return _head_eva(params, x, cfg), staging, None
     x, staging, ids = _run_indexed(params, tokens[0], cfg, start, staging,
                                    choices)
     last = jnp.clip(n_total - 1 - start, 0, tokens.shape[1] - 1)
@@ -694,7 +804,12 @@ def forward_prefill(params: Params, tokens: jax.Array, cfg: LlamaConfig,
 
     A preset with a ``block_length`` runs under the block-causal mask
     (``_block``); its prompts come in whole blocks and ``last_pos`` is the
-    last position of one, whose block's logits come back, (B, block, V)."""
+    last position of one, whose block's logits come back, (B, block, V).
+
+    Under a folded cache k/v are the rows a sequence HOLDS after the
+    prompt, (L, B, held rows, KV, D) float32 (:func:`_forward_eva`)."""
+    if cfg.eva_window:
+        return _forward_eva(params, tokens, cfg, last_pos, heads=False)
     if cfg.index_topk:
         return _forward_prefill_indexed(params, tokens, cfg, last_pos,
                                         choices)
@@ -749,6 +864,9 @@ def forward_decode(params: Params, tokens: jax.Array, positions: jax.Array,
         return _forward_decode_blocks(params, tokens, positions, kv_pool,
                                       block_tables, ctx_lens, cfg, choices,
                                       live)
+    if cfg.eva_window:
+        return _forward_decode_eva(params, tokens, positions, kv_pool,
+                                   block_tables, ctx_lens, cfg)
     B = tokens.shape[0]
     E = cfg.n_head * cfg.head_dim
     x = _embed(params, tokens, cfg)                             # (B, E)
@@ -851,6 +969,195 @@ def _forward_decode_blocks(params, tokens, positions, kv_pool, block_tables,
     sliced, experts = _split_experts(params["blocks"], cfg)
     x, kept = lax.scan(body, x, (sliced, jnp.arange(cfg.n_layer)))
     return (_head(params, _final_norm(params, x, cfg), cfg), *kept)
+
+
+# ----------------------------------------------------------- a folded cache
+def _norm(x: jax.Array, scale: jax.Array, cfg: LlamaConfig) -> jax.Array:
+    return _rms_norm(x, scale + 1.0 if cfg.norm_offset else scale,
+                     cfg.rms_eps)
+
+
+def _head_eva(params: Params, x: jax.Array, cfg: LlamaConfig,
+              heads: bool = False) -> jax.Array:
+    """The stream (rows, E) -> float32 logits: head 0's, (rows, V), what a
+    serving program returns; with ``heads`` every head's, (rows, pred_heads,
+    V).  The products are ``cfg.dtype``'s, the sums float32."""
+    with jax.named_scope("ln_f"):
+        x = _norm(x, params["norm_f"]["scale"], cfg).astype(cfg.dtype)
+    with jax.named_scope("lm_head"):
+        w = params["lm_head"]["kernel"].astype(cfg.dtype)
+        if not heads:
+            w = w[:, :cfg.vocab_size]
+        logits = jnp.dot(x, w, preferred_element_type=jnp.float32)
+        return logits.reshape(x.shape[0], -1, cfg.vocab_size) if heads \
+            else logits
+
+
+def _eva_block(x: jax.Array, lp: Params, cfg: LlamaConfig,
+               positions: jax.Array, attend):
+    """One block on a stream of rows (rows, E), each at its own position:
+    ``attend(q (rows, H, D), k, v (rows, KV, D))`` -> (the heads' output,
+    what the caller keeps of the layer).  A prompt's chunk and a decode
+    step run this body and differ in ``attend`` alone."""
+    with jax.named_scope("ln_1"):
+        h = _norm(x, lp["attn_norm"]["scale"], cfg).astype(cfg.dtype)
+    q, k, v = _qkv(h, lp, cfg)
+    with jax.named_scope("rope"):
+        q = _rope_at(q, positions, cfg.rope_theta)
+        k = _rope_at(k, positions, cfg.rope_theta)
+    a, kept = attend(q, k, v)
+    with jax.named_scope("attn_out"):
+        x = x + (a.reshape(x.shape[0], -1)
+                 @ lp["wo"]["kernel"].astype(cfg.dtype)).astype(x.dtype)
+    with jax.named_scope("ln_2"):
+        h = _norm(x, lp["mlp_norm"]["scale"], cfg).astype(cfg.dtype)
+    f, _ = _ffn(h, lp, cfg)
+    return x + f.astype(x.dtype), kept
+
+
+def _stream(params: Params, tokens: jax.Array, cfg: LlamaConfig):
+    return _embed(params, tokens, cfg).astype(
+        jnp.float32 if cfg.residual_f32 else cfg.dtype)
+
+
+def _run_eva(params: Params, tokens: jax.Array, cfg: LlamaConfig, start,
+             n_total, staging: Dict[str, jax.Array]):
+    """One window of one prompt through every layer: tokens (W,), the
+    positions ``start .. start + W - 1`` (``start`` whole windows) of a
+    prompt of ``n_total``, those past its end padding.  ``staging`` as
+    :func:`prefill_staging` says: rows ``0 .. base - 1`` the folded rows of
+    the windows before, ``base = start / W x (W / chunk)``; this window's
+    K/V goes to rows ``base ..`` and its queries attend causally IN THOSE
+    COORDINATES: a folded row lies before every query, an exact one before
+    the queries at or after its own position, so one causal kernel serves
+    (``ops/window_attention.chunk_attention``).  Where the prompt fills the
+    window its fold then takes the place of its first ``W / chunk`` rows,
+    and the next window's rows overwrite the rest.  Returns (the stream (W,
+    E), the staging)."""
+    from ray_tpu.ops.eva_attention import fold_rows
+    from ray_tpu.ops.window_attention import chunk_attention
+    T = tokens.shape[0]
+    H, KV, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
+    per = cfg.eva_window // cfg.eva_chunk
+    base = start // cfg.eva_window * per
+    positions = start + jnp.arange(T, dtype=jnp.int32)
+    full = n_total >= start + T
+    rows = staging["k"].shape[1]
+    segment = 512 if rows % 512 == 0 else per
+    chunk_of = jnp.arange(rows // segment, dtype=jnp.int32)
+    f32 = jnp.float32
+
+    def body(carry, xs):
+        x, staging = carry
+        lp, layer = xs
+
+        def attend(q, k, v):
+            with jax.named_scope("kv_stage"):
+                new = {"k": k.reshape(T, KV * D).astype(f32),
+                       "v": v.reshape(T, KV * D).astype(f32)}
+                staged = {name: lax.dynamic_update_slice(
+                    staging[name], new[name][None], (layer, base, 0))
+                    for name in new}
+                mine = {name: lax.dynamic_index_in_dim(
+                    staged[name], layer, 0, keepdims=False) for name in new}
+            with jax.named_scope("attn_eva"):
+                a = chunk_attention(q.reshape(T, KV, H // KV, D), mine["k"],
+                                    mine["v"], base, chunk_of)
+            kf, vf = fold_rows(k, v, lp["eva"]["phi"], lp["eva"]["mu"],
+                               cfg.eva_chunk)
+            with jax.named_scope("eva_fold"):
+                folded = {"k": kf, "v": vf}
+                staged = {name: lax.dynamic_update_slice(
+                    staged[name], jnp.where(
+                        full, folded[name].reshape(per, KV * D),
+                        new[name][:per])[None], (layer, base, 0))
+                    for name in new}
+            return a, staged
+
+        x, staging = _eva_block(x, lp, cfg, positions, attend)
+        return (x, staging), None
+
+    (x, staging), _ = lax.scan(
+        body, (_stream(params, tokens, cfg), staging),
+        (params["blocks"], jnp.arange(cfg.n_layer)))
+    return x, staging
+
+
+def _forward_eva(params, tokens, cfg, last_pos, heads: bool):
+    """Whole prompts under a folded cache, each ONE pass of its windows
+    from an empty staging, the code its chunks run: tokens (B, T) -> (logits
+    (B, T, V), or (B, V) at ``last_pos``, or every head's with ``heads``;
+    ks, vs (L, B, held rows, KV, D) float32, the rows a sequence holds after
+    the prompt, padded to the staging's)."""
+    B, T = tokens.shape
+    W = cfg.eva_window
+    chunks = -(-T // W)
+    spec = prefill_staging(cfg, chunks * W)
+    out = []
+    for b in range(B):
+        toks = jnp.pad(tokens[b], (0, chunks * W - T))
+        staging = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), spec)
+        xs = []
+        for i in range(chunks):
+            x, staging = _run_eva(params, toks[i * W:(i + 1) * W], cfg,
+                                  i * W, T, staging)
+            xs.append(x)
+        x = jnp.concatenate(xs)[:T]
+        if last_pos is not None:
+            x = lax.dynamic_slice_in_dim(x, last_pos, 1, axis=0)
+        logits = _head_eva(params, x, cfg, heads)
+        out.append((logits[0] if last_pos is not None else logits, *(
+            staging[name].reshape(cfg.n_layer, -1, cfg.n_kv_head,
+                                  cfg.head_dim) for name in ("k", "v"))))
+    return tuple(jnp.stack(part, axis=axis)
+                 for part, axis in zip(zip(*out), (0, 1, 1)))
+
+
+def _forward_decode_eva(params, tokens, positions, kv_pool, block_tables,
+                        ctx_lens, cfg):
+    """A decode step under a folded cache: ``positions`` (B,) the positions
+    each row has SEEN (its token's own, for RoPE), ``ctx_lens`` (B,) the
+    rows it HOLDS in the pool, folded and exact alike: to the query a folded
+    pair is one more key and value, so the walk is the plain paged one.
+    Returns (head 0's logits (B, V) f32, new_k, new_v (L, B, KV, D))."""
+    from ray_tpu.ops.paged_attention import paged_attention_decode
+
+    def body(x, xs):
+        lp, layer = xs
+
+        def attend(q, k, v):
+            with jax.named_scope("attn_eva"):
+                return paged_attention_decode(q, kv_pool, layer, block_tables,
+                                              ctx_lens, k, v), (k, v)
+
+        return _eva_block(x, lp, cfg, positions, attend)
+
+    x, kept = lax.scan(body, _stream(params, tokens, cfg),
+                       (params["blocks"], jnp.arange(cfg.n_layer)))
+    return (_head_eva(params, x, cfg), *kept)
+
+
+def fold_window(params: Params, cfg: LlamaConfig, kv_pool: jax.Array,
+                pages: jax.Array):
+    """The fold of one closed window out of the pool: ``pages`` (W / bs,)
+    the blocks that hold its rows, in order -> (kf, vf) (L, W / chunk, KV,
+    D) float32, every layer's, for whoever writes them
+    (``serve/llm/model_runner.py``: into the first of those pages)."""
+    from ray_tpu.ops.eva_attention import fold_rows
+    L, _, N, bs, F = kv_pool.shape
+    KV, D = cfg.n_kv_head, cfg.head_dim
+    with jax.named_scope("eva_fold"):
+        # the pool as what it is in memory, L x 2 x N x bs rows of F lanes,
+        # and the window's rows gathered by number (indexed by block the
+        # TPU compiler re-lays the whole pool first: kv_cache.write_rows)
+        at = (pages[:, None] * bs + jnp.arange(bs)).reshape(-1)    # (W,)
+        at = jnp.arange(2 * L)[:, None] * (N * bs) + at            # (2 L, W)
+        rows = kv_pool.reshape(-1, F)[at.reshape(-1)][:, :KV * D]
+        rows = rows.reshape(L, 2, -1, KV, D)
+    eva = params["blocks"]["eva"]
+    return jax.vmap(partial(fold_rows, chunk=cfg.eva_chunk))(
+        rows[:, 0], rows[:, 1], eva["phi"], eva["mu"])
+
 
 
 def loss_fn(params: Params, batch: Dict[str, jax.Array],

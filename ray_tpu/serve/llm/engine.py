@@ -108,6 +108,9 @@ STEP_SERIES = {
     "positions_scored": ("rtpu_llm_index_positions_scored", "inc"),
     "positions_read": ("rtpu_llm_index_positions_read", "inc"),
     "index_blocks_held": ("rtpu_llm_index_blocks_held", "set"),
+    "fold_blocks_held": ("rtpu_llm_kv_fold_blocks_held", "inc"),
+    "fold_blocks_unfolded": ("rtpu_llm_kv_fold_blocks_unfolded", "inc"),
+    "windows_folded": ("rtpu_llm_kv_windows_folded", "inc"),
 }
 
 
@@ -747,7 +750,8 @@ class LLMEngine:
             span.set(kv_layers=self.cache.kv_layers,
                      state_layers=self.cache.state_layers)
             self.attn_blocks_read += int(
-                (-(-lens // self.cfg.block_size)).sum())
+                (-(-self.cache.held_rows(lens)
+                   // self.cfg.block_size)).sum())
             self.attn_blocks_table += maxb * _bucket(
                 len(batch), self.cfg.decode_batch_buckets)
             if self.cache.state_rows:
@@ -930,7 +934,8 @@ class LLMEngine:
                       kv_layers=self.cache.kv_layers,
                       state_layers=self.cache.state_layers)
             self.attn_blocks_read += int(
-                (-(-lens // self.cfg.block_size)).sum())
+                (-(-self.cache.held_rows(lens)
+                   // self.cfg.block_size)).sum())
             self.attn_blocks_table += maxb * _bucket(
                 n, self.cfg.decode_batch_buckets)
         try:
@@ -1261,10 +1266,8 @@ class LLMEngine:
     def _blocks_are_all_a_sequence_holds(self, what: str) -> None:
         """The manifest of ``prefill_remote`` / ``attach`` carries blocks
         and nothing else: half a sequence, for a model with more."""
-        for plane in self.cache.planes:
-            if plane.unexported:
-                raise NotImplementedError(
-                    f"{what}: {self.cfg.model} {plane.unexported}")
+        for why in self.cache.unexported():
+            raise NotImplementedError(f"{what}: {self.cfg.model} {why}")
         if self.runner.block:
             raise NotImplementedError(
                 f"{what}: {self.cfg.model} is stepped by blocks, and a "
@@ -1466,5 +1469,10 @@ class LLMEngine:
                     index_bytes=self.cache.index_bytes,
                     positions_scored=read["positions_scored"],
                     positions_read=read["positions_read"],
+                    **(dict(fold_window=self.cache.fold_window,
+                            windows_folded=self.cache.windows_folded,
+                            rows_read=read["rows_read"],
+                            positions_seen=read["positions_seen"])
+                       if self.cache.fold_window else {}),
                     span_s={k: list(v) for k, v in
                             list(self.span_s.items())})

@@ -111,6 +111,28 @@ program, ``write_token`` or a prompt's scatter, handed device arrays or
 numpy ones, writes its index key in the same act, and K's own heads reach
 the pool without it (:func:`_carried_apart`).  A step reads it whole over a
 row's context, and then the K/V rows of the positions it chose.
+
+A table that shrinks.  A sequence whose model folds (``models/llama.py``,
+``eva_window``; :func:`kept_by` reads ``folded_cache(cfg)``) keeps its open
+window of ``fold_window`` positions exactly and every window that has closed
+as ``fold_window / fold_chunk`` folded rows, each a K and a V of the pool's
+own lanes.  So a folded row is a row of ``"kv"`` and no seventh pool, and the
+folding is a property of that plane's TABLE: a sequence's table is ``[the
+pages of a closed window's folded rows]* + [the open window's pages]``, and
+the pool, its writers and the decode kernel see a plain table under a
+shorter context.  Two lengths a sequence follow: the positions it has SEEN
+(``_fill``; RoPE, the scheduler, the streams) and the rows it HOLDS
+(:func:`held_rows` of that: the kernels' context, the scatter's count,
+:meth:`PagedKVCache.blocks_needed`).  A window closes inside
+:meth:`PagedKVCache.append_slot`, when the slot asked for is the first of
+the next window: the fold is a program the runner hands the cache
+(``PagedKVCache.folder``: it needs the model's parameters), which reads the
+window's pages and writes the folded rows over the first of them, in the
+order of the enqueues; the other pages go back to the free list at once and
+the table's columns close up.  The pages of folded rows must be whole
+(``fold_window / fold_chunk`` a multiple of the block size: refused at
+set-up otherwise).  A folded page is immutable and could be shared or
+exported; nothing does yet (:meth:`PagedKVCache.unshared`).
 """
 
 from __future__ import annotations
@@ -201,6 +223,38 @@ def window_first_column(n_tokens: int, window: int, block_size: int) -> int:
     positions are cached: the one that holds position ``n_tokens - window +
     1``, the first that the token at ``n_tokens`` sees."""
     return max(0, n_tokens - window + 1) // block_size
+
+
+def held_rows(seen, window: int, chunk: int):
+    """The rows a sequence that has seen ``seen`` positions HOLDS where a
+    closed ``window`` is kept as a row a ``chunk`` (0: nothing folds): the
+    folded rows of its closed windows and the open window's own.  Host
+    ints, numpy arrays and traced arrays alike."""
+    if not window:
+        return seen
+    return seen // window * (window // chunk) + seen % window
+
+
+def held_rows_most(seen: int, window: int, chunk: int) -> int:
+    """The most rows a sequence holds at once on its way to ``seen``
+    positions: with the last window it fills still exact, or at its end."""
+    if not window:
+        return seen
+    full, per = seen // window, window // chunk
+    return max((full - 1) * per + window if full else 0,
+               held_rows(seen, window, chunk))
+
+
+def fold_reads(seen, own: bool, window: int, chunk: int, layers: int):
+    """What queries that have ``seen`` positions each behind them (``own``:
+    and their own, a chunk's; a decode step's new row is not in the pool)
+    read under a folded cache, from the mathematics: the rows they hold,
+    folded and exact, beside the positions a plain cache would hold; each
+    summed over the queries and the layers."""
+    seen = np.asarray(seen, np.int64)
+    return dict(
+        rows_read=int((held_rows(seen, window, chunk) + own).sum()) * layers,
+        positions_seen=int((seen + own).sum()) * layers)
 
 
 def _halves_rewritten(sel, pool, blocks, offsets):
@@ -341,6 +395,10 @@ class Kept:
     index_layers: int = 0
     index_dim: int = 0
     index_topk: int = 0              # the positions a query attends to
+    # a closed window of ``fold_window`` positions is kept as a row a
+    # ``fold_chunk`` (0: every position keeps its row)
+    fold_window: int = 0
+    fold_chunk: int = 0
     # a prompt's prefill leaves its state in the holder: it runs through
     # the holder, donated
     staged: bool = False
@@ -354,8 +412,8 @@ def kept_by(mod, mcfg) -> Kept:
     cache: ``recurrent_state(cfg)`` (one sequence's state in one layer,
     name -> shape and type), ``cache_layers(cfg)`` (the layers of each
     kind: ``"kv"``, ``"state"`` and, where it has them, ``"window"`` /
-    ``"latent"`` / ``"index"``) and ``page_selector(cfg)`` (``{"stride",
-    "block"}``).  A
+    ``"latent"`` / ``"index"``), ``page_selector(cfg)`` (``{"stride",
+    "block"}``) and ``folded_cache(cfg)`` (``{"window", "chunk"}``).  A
     module that declares none (GPT-2, Llama) keeps K/V in every layer; one
     with state and no count keeps both in every layer."""
     state = _declared(mod, mcfg, "recurrent_state")
@@ -367,6 +425,9 @@ def kept_by(mod, mcfg) -> Kept:
     index_layers = layers.get("index", 0)
     indexed = dict(index_layers=index_layers, index_dim=mcfg.index_dim,
                    index_topk=mcfg.index_topk) if index_layers else {}
+    fold = _declared(mod, mcfg, "folded_cache")
+    if fold:
+        indexed.update(fold_window=fold["window"], fold_chunk=fold["chunk"])
     return Kept(
         getattr(mcfg, "n_kv_head", mcfg.n_head), mcfg.head_dim, layers["kv"],
         state, layers["state"], select.get("stride", 0),
@@ -571,6 +632,8 @@ def staged_rows(kept: Kept, staging, bucket: int) -> tuple:
     by the pool that has layers."""
     import jax.numpy as jnp
     plane = next(p for p in PLANES if p.staged and getattr(kept, p.layers))
+    # a staging of a model that folds is laid out as the rows held
+    bucket = held_rows_most(bucket, kept.fold_window, kept.fold_chunk)
     ks, vs = plane.staged(staging, bucket, kept)
     for plane in PLANES:
         if plane.carried and getattr(kept, plane.layers):
@@ -854,7 +917,8 @@ class PagedKVCache:
                  select_stride: int = 0, window_layers: int = 0,
                  window: int = 0, latent_layers: int = 0,
                  latent_dim: int = 0, index_layers: int = 0,
-                 index_dim: int = 0, index_topk: int = 0):
+                 index_dim: int = 0, index_topk: int = 0,
+                 fold_window: int = 0, fold_chunk: int = 0):
         """``n_layer``: the layers that hold K/V.  The keywords are
         :class:`Kept`'s fields (:meth:`for_engine` hands them all; a test
         names a plane with its own).  ``state``: one sequence's recurrent
@@ -868,7 +932,19 @@ class PagedKVCache:
         features a position; such a family's ``n_layer`` is 0.
         ``index_layers``, ``index_dim``, ``index_topk``: the layers that
         cache an index key of ``index_dim`` lanes a position beside their
-        K/V (all of them), and the positions a query then attends to."""
+        K/V (all of them), and the positions a query then attends to.
+        ``fold_window``, ``fold_chunk``: a closed window of so many
+        positions is kept as one row a chunk (a table that shrinks)."""
+        if fold_window and (
+                fold_chunk < 1 or fold_window % fold_chunk
+                or (fold_window // fold_chunk) % block_size
+                or state or window_layers or select_stride or latent_layers
+                or index_layers):
+            raise ValueError(
+                f"a window of {fold_window} positions folded {fold_chunk} "
+                f"to 1 is {fold_window / max(fold_chunk, 1):g} rows: no "
+                f"whole number of pages of {block_size}, or beside a plane "
+                "the fold is not written for (K/V under one table alone)")
         if index_layers and (index_layers != n_layer or window_layers
                              or select_stride or latent_layers
                              or not 0 < index_dim <= head_dim):
@@ -904,6 +980,10 @@ class PagedKVCache:
         self.latent_layers, self.latent_dim = latent_layers, latent_dim
         self.index_layers, self.index_dim = index_layers, index_dim
         self.index_topk = index_topk
+        self.fold_window, self.fold_chunk = fold_window, fold_chunk
+        # (the window's pages) -> None: the fold of one closed window, the
+        # runner's program (it needs the model's parameters)
+        self.folder: Optional[Callable] = None
         self.kv_shape = device_shape(num_blocks, n_layer, block_size, n_kv,
                                      head_dim)
         # every plane this cache has, as shapes, and the planes' bytes
@@ -942,13 +1022,24 @@ class PagedKVCache:
         self._wfirst: Dict[str, int] = {}                            # guarded by: _lock
         self.window_released = 0                                     # guarded by: _lock
         self._released_told = 0       # of those, told to held_counts()
+        # a table that shrinks: a sequence's closed windows that are folded
+        # (its table's first columns), and the windows folded so far
+        self._folded: Dict[str, int] = {}                            # guarded by: _lock
+        self.windows_folded = 0                                      # guarded by: _lock
+        self._folded_told = 0         # of those, told to held_counts()
         # bytes of pool data that crossed between host and device, either
         # way: K/V given as numpy, blocks exported or imported
         self.host_bytes = 0                                          # guarded by: _lock
 
     # ------------------------------------------------------------ allocation
+    def held_rows(self, n_tokens):
+        """The rows a sequence of ``n_tokens`` positions holds
+        (:func:`held_rows` at this cache's fold; ``n_tokens`` itself where
+        nothing folds)."""
+        return held_rows(n_tokens, self.fold_window, self.fold_chunk)
+
     def blocks_needed(self, n_tokens: int) -> int:
-        return max(1, -(-n_tokens // self.block_size))
+        return max(1, -(-self.held_rows(n_tokens) // self.block_size))
 
     def free_block_count(self) -> int:
         with self._lock:
@@ -990,6 +1081,9 @@ class PagedKVCache:
                 self._ref[b] = 1
             self._tables[seq_id] = blocks
             self._fill[seq_id] = n_tokens
+            if self.fold_window:
+                # a prompt's closed windows reach the scatter folded
+                self._folded[seq_id] = n_tokens // self.fold_window
             if self.state_rows or self.window_layers:
                 self._owner[blocks[0]] = seq_id
             if self.window_layers:
@@ -1006,11 +1100,17 @@ class PagedKVCache:
         NoFreeBlocks under cache pressure — the scheduler's preemption
         trigger.  A reservation whose decode step then fails must be
         returned with :meth:`rollback_slot` or every later slot is off
-        by one."""
+        by one.
+
+        Under a table that shrinks the slot is that of the next row HELD,
+        and a window the sequence has filled is folded first
+        (:meth:`_close_windows`)."""
+        if self.fold_window:
+            self._close_windows(seq_id)
         with self._lock:
             fill = self._fill[seq_id]
             table = self._tables[seq_id]
-            blk_i, off = divmod(fill, self.block_size)
+            blk_i, off = divmod(self.held_rows(fill), self.block_size)
             grew = False
             if blk_i == len(table):
                 if not self._free:
@@ -1036,6 +1136,46 @@ class PagedKVCache:
             self._fill[seq_id] = fill + 1
             return table[blk_i], off, grew
 
+    def _close_windows(self, seq_id: str) -> None:
+        """Fold every window ``seq_id`` has filled and still holds exactly
+        (one, when its next slot is the first of the next window): the
+        folder reads the window's pages and writes the folded rows over the
+        first of them, the rest go back to the free list at once and the
+        table's columns close up.  The program is enqueued behind the step
+        that wrote the window's last row and before any that reads the
+        table as it is after."""
+        window, bs = self.fold_window, self.block_size
+        keep, pages = window // self.fold_chunk // bs, window // bs
+        while True:
+            with self._lock:
+                done = self._folded[seq_id]
+                if self._fill[seq_id] // window <= done:
+                    return
+                at = done * keep
+                closing = self._tables[seq_id][at:at + pages]
+            if self.folder is None:
+                raise RuntimeError(
+                    f"sequence {seq_id!r} has filled a window of {window} "
+                    "positions and the cache was handed no folder (the "
+                    "runner's program: ModelRunner.cache = this cache)")
+            self.folder(np.asarray(closing, np.int32))
+            with self._lock:
+                del self._tables[seq_id][at + keep:at + pages]
+                for b in closing[keep:]:
+                    self._given_back(b)
+                self._folded[seq_id] = done + 1
+                self.windows_folded += 1
+
+    def _given_back(self, b: int) -> bool:
+        """One holder less of block ``b`` (``_lock`` held); True where it
+        went back to the free list."""
+        self._ref[b] -= 1
+        if self._ref[b]:
+            return False
+        del self._ref[b]
+        self._free.append(b)
+        return True
+
     def rollback_slot(self, seq_id: str, grew: bool) -> None:
         """Undo one :meth:`append_slot` reservation (failed decode step)."""
         with self._lock:
@@ -1045,11 +1185,7 @@ class PagedKVCache:
             if grew and self.window_layers:
                 self._wfree.append(self._wtables[seq_id].pop())
             if grew:
-                b = self._tables[seq_id].pop()
-                self._ref[b] -= 1
-                if self._ref[b] == 0:
-                    del self._ref[b]
-                    self._free.append(b)
+                self._given_back(self._tables[seq_id].pop())
 
     def append_block(self, seq_id: str, n: int) -> List[bool]:
         """Reserve the next ``n`` token slots for ``seq_id`` at once (a
@@ -1084,30 +1220,40 @@ class PagedKVCache:
             self._wfree.extend(b for b in self._wtables.pop(seq_id, ())
                                if b != self.window_blocks)
             self._wfirst.pop(seq_id, None)
+            self._folded.pop(seq_id, None)
             if not blocks:
                 return 0
-            freed = 0
-            for b in blocks:
-                self._ref[b] -= 1
-                if self._ref[b] == 0:
-                    del self._ref[b]
-                    self._free.append(b)
-                    freed += 1
-            return freed
+            return sum(self._given_back(b) for b in blocks)
 
     def fork_seq(self, seq_id: str, new_seq_id: str) -> None:
         """Share a sequence's blocks with a new id (refcount bump) —
         the prefix-sharing/export primitive."""
-        for plane in self.planes:
-            if plane.unshared:
-                raise NotImplementedError(
-                    f"a sequence with {plane.unshared}")
+        for why in self.unshared():
+            raise NotImplementedError(f"a sequence with {why}")
         with self._lock:
             blocks = list(self._tables[seq_id])
             for b in blocks:
                 self._ref[b] += 1
             self._tables[new_seq_id] = blocks
             self._fill[new_seq_id] = self._fill[seq_id]
+
+    def unexported(self) -> List[str]:
+        """Why the block manifest cannot carry a sequence of this cache
+        (behind ``{what}: {model}``): a reason a plane that has one, and
+        the table's own where it shrinks; empty: it can."""
+        folds = ["keeps its closed windows folded, and a manifest names a "
+                 "block a run of positions; nothing exports a folded page "
+                 "yet, so nothing is moved"] if self.fold_window else []
+        return [p.unexported for p in self.planes if p.unexported] + folds
+
+    def unshared(self) -> List[str]:
+        """Why two sequences cannot share blocks of this cache (behind ``a
+        sequence with``); empty: they can."""
+        folds = ["a table that shrinks cannot be forked: a folded page is "
+                 "immutable and could be shared, but the open window's "
+                 "pages are folded and given back by whoever closes it "
+                 "first (ROADMAP, Reach)"] if self.fold_window else []
+        return [p.unshared for p in self.planes if p.unshared] + folds
 
     # ------------------------------------------------------------- accessors
     def table(self, seq_id: str) -> List[int]:
@@ -1161,14 +1307,30 @@ class PagedKVCache:
                     window_blocks_held_unwindowed=unwindowed,
                     window_blocks_released=released - told)
 
+    def _fold_holds(self) -> dict:
+        """Blocks the live sequences hold under the table that shrinks,
+        what they would hold with every position's row kept, and the
+        windows folded since last asked."""
+        if not self.fold_window:
+            return {}
+        with self._lock:
+            unfolded = sum(-(-n // self.block_size)
+                           for n in self._fill.values())
+            held = self.num_blocks - len(self._free)
+            told, self._folded_told = self._folded_told, self.windows_folded
+            return dict(fold_blocks_held=held, fold_blocks_unfolded=unfolded,
+                        windows_folded=self.windows_folded - told)
+
     def held_counts(self) -> dict:
         """The planes' own counts at a step, for ``metrics_catalog.
         tell_step``: window blocks held and what one table for all layers
         would hold (counters: each step adds what the live sequences hold
         now, so their ratio is the mean over steps), given back since last
-        asked; rows and latent blocks held."""
-        return {name: n for plane in self.planes if plane.holds
-                for name, n in plane.holds(self).items()}
+        asked; rows and latent blocks held; under a table that shrinks the
+        blocks held, what an unfolded table would hold, windows folded."""
+        return {**{name: n for plane in self.planes if plane.holds
+                   for name, n in plane.holds(self).items()},
+                **self._fold_holds()}
 
     def window_pool_blocks(self) -> np.ndarray:
         """Every block of the window layers' pool in the wire format of
@@ -1266,8 +1428,10 @@ class PagedKVCache:
         ``n_tokens`` positions are real, and only they are written).
         With recurrent state, the same program commits the state the
         prompt's prefill staged to the sequence's row."""
+        # under a table that shrinks ks / vs are the rows HELD after the
+        # prompt, and so is the count the program writes
         self._write(_programs().scatter_prefill, *self._scatter_args(
-            self.table(seq_id), ks, vs, n_tokens,
+            self.table(seq_id), ks, vs, self.held_rows(n_tokens),
             *self._prompt_operands(seq_id, n_tokens)), host=(ks, vs))
 
     def _prompt_operands(self, seq_id, n_tokens: int) -> list:

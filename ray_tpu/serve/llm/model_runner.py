@@ -93,6 +93,18 @@ block after the pass, ids and decided-flags, stays there as the step's
 crosses to the host is ``ChosenBlocks``: ``(R, B)`` ids, confidences and
 flags in one pull, never ``(R, B, V)`` logits unless a row is named.
 
+Two lengths a row.  A family whose sequences fold (``folded_cache(cfg)``:
+``kv_cache.py``, a table that shrinks) has two: the positions a row has SEEN
+and the rows it HOLDS.  ``prefill`` / ``prefill_chunk`` / ``decode`` are
+handed the first, as for any family (``positions`` and ``ctx_lens`` alike,
+the prompt's length); the second is a pure function of it
+(``kv_cache.held_rows``), taken inside the decode program for the kernels'
+context and the new row's slot, and by the cache for a prompt's scatter.  The
+fold of a window that closes in decode is this runner's program too
+(``fold_windows``: it needs the model's ``phi`` and ``mu``), handed to the
+cache when the cache is (``ModelRunner.cache``), which runs it inside
+``append_slot``; built and run once, on pages out of range, at that hand-over.
+
 One step behind another.  A greedy row's next token is the id the step
 before chose, on the device: the decode programs take the last step's ids
 (``last_ids``, at the widest bucket's width) and a row map ``src``: row
@@ -118,9 +130,9 @@ from ray_tpu._private.xla_watchdog import compile_budget
 from ray_tpu.serve.llm.config import EngineConfig, SamplingParams, \
     resolve_model
 from ray_tpu.serve.llm.kv_cache import DevicePool, Kept, PagedKVCache, \
-    _declared, block_slots_reserved, handed_to_forward, kept_by, \
-    rows_written, slots_reserved, staged_rows, stepped_by_forward, \
-    window_reads
+    _declared, block_slots_reserved, fold_reads, handed_to_forward, \
+    held_rows, kept_by, rows_written, slots_reserved, staged_rows, \
+    stepped_by_forward, window_reads, write_rows
 from ray_tpu.util.tracing import abstract, hot_span, register_program
 
 logger = rtlog.get("serve.llm.runner")
@@ -486,6 +498,9 @@ class ModelRunner:
             # is counted behind the ids.  The new rows, and what is kept
             # beside the K, are written by the cache's own writer
             handed, tables = handed_to_forward(held, by_row)
+            # the rows each sequence HOLDS: what it has seen, but under a
+            # table that shrinks (``positions`` stay the positions seen)
+            ctx_lens = held_rows(ctx_lens, kept.fold_window, kept.fold_chunk)
             logits, k, v, *ids = forward_decode(
                 params, tokens_in(tokens, last_ids, src), positions,
                 held["kv"], block_tables, ctx_lens,
@@ -555,8 +570,24 @@ class ModelRunner:
             return {**held, "state": store}, (
                 staging, (logits, greedy(logits)), *ids)
 
+        def fold_step(held, params, pages):
+            # one closed window out of the pool: its folded rows over the
+            # first of its own pages (every row is read before one is
+            # written: the program is a function of the pool it was given)
+            kf, vf = self.mod.fold_window(params, self.mcfg, held["kv"],
+                                          pages)
+            bs = held["kv"].shape[3]
+            keep = kf.shape[1] // bs
+            with jax.named_scope("eva_fold"):
+                pool = write_rows(
+                    held["kv"], jnp.repeat(pages[:keep], bs),
+                    jnp.tile(jnp.arange(bs), keep), kf, vf)
+            return {**held, "kv": pool}, None
+
         # bound to a name of its own: jaxlint pins a donating jit by the
         # name it is assigned to (lock_watchdog.DONATED)
+        llm_fold_step = jax.jit(fold_step, donate_argnums=(0,))
+        self._fold = llm_fold_step
         llm_prefill_step = jax.jit(prefill_step, donate_argnums=(0,))
         llm_decode_step = jax.jit(block_step if self.block else decode_step,
                                   donate_argnums=(0,))
@@ -624,7 +655,7 @@ class ModelRunner:
         self.steps_enqueued = 0    # decode steps, this runner's life
         # the engine's cache: a bucket's scatter program is built with
         # the bucket's first prefill (None: a runner on its own)
-        self.cache: Optional[PagedKVCache] = None
+        self._cache: Optional[PagedKVCache] = None
         self.compiles = 0          # observability: distinct programs built
         self._shapes_seen: set = set()
         # XLA watchdog step regions (DESIGN.md §4q): one compile per
@@ -635,6 +666,37 @@ class ModelRunner:
             "llm.prefill", len(cfg.prefill_len_buckets))
         self._decode_budget = compile_budget(
             "llm.decode", len(cfg.decode_batch_buckets))
+
+    @property
+    def cache(self) -> Optional[PagedKVCache]:
+        return self._cache
+
+    @cache.setter
+    def cache(self, cache: Optional[PagedKVCache]) -> None:
+        """The engine's cache; one whose table shrinks is handed the fold
+        program, which is built and run here once on pages out of range
+        (read clamped, written nowhere): no window closes on a compile."""
+        self._cache = cache
+        if cache is not None and cache.fold_window:
+            cache.folder = self.fold_windows
+            pages = cache.fold_window // cache.block_size
+            self.fold_windows(np.full(pages, cache.num_blocks, np.int32),
+                              windows=0)
+
+    def fold_windows(self, pages: np.ndarray, windows: int = 1) -> None:
+        """Fold one closed window of the pool, ``pages`` its blocks in
+        order: enqueued behind whatever wrote them, inside an
+        ``llm.window.fold`` span that says what it folds (``rows_folded``:
+        the window's rows x layers)."""
+        cache = self._cache
+        compiling = self._note_shape("fold", len(pages))
+        if compiling is not _SEEN:
+            register_program("llm.window.fold", self._fold, (
+                cache.pool.abstract(), *abstract((self.params, pages))))
+        with compiling, hot_span(
+                "llm.window.fold", self.span_s, windows=windows,
+                rows_folded=windows * cache.fold_window * self.kv_layers):
+            cache._write(self._fold, self.params, pages)
 
     def _load_params(self):
         import jax
@@ -743,6 +805,8 @@ class ModelRunner:
             self.cache.planes if self.cache is not None else ())
             if plane.chunk_reads for name, count in plane.chunk_reads(
                 first, min(first + c, n), self.cache).items()}
+        reads.update(self._fold_reads(np.arange(first, min(first + c, n)),
+                                      True))
         with compiling, hot_span("llm.prefill.chunk", self.span_s,
                                  chunk=index, tokens=n, **reads), \
                 self._prefill_budget:
@@ -860,6 +924,7 @@ class ModelRunner:
                                            b))
             if plane.reads:
                 reads.update(plane.reads(ctx_lens[:b], self._state_cache()))
+        reads.update(self._fold_reads(ctx_lens[:b], False))
         if self.block:
             # a row's pages, walked once a pass for all its positions
             by_row += [decided, commit]
@@ -883,6 +948,22 @@ class ModelRunner:
         step = Enqueued(self.steps_enqueued, picked, carry, b, logit_rows,
                         reads)
         return (self.pull_step(step) if wait else step), ks, vs
+
+    def _fold_reads(self, seen, own: bool) -> dict:
+        """What queries with ``seen`` positions behind them (``own``: and
+        their own) read where sequences fold: the rows held beside the
+        positions seen, from the step's or the chunk's own lengths
+        (``kv_cache.fold_reads``); nothing where nothing folds."""
+        kept = self.family.kept
+        if not kept.fold_window:
+            return {}
+        reads = fold_reads(seen, own, kept.fold_window, kept.fold_chunk,
+                           kept.kv_layers)
+        if own:
+            # the positions of a chunk the prompt fills are folded with it
+            full = len(seen) == kept.fold_window
+            reads["rows_folded"] = full * len(seen) * kept.kv_layers
+        return reads
 
     def _window_reads(self, ctx_lens: np.ndarray) -> dict:
         """What a decode step's window layers read

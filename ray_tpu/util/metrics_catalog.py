@@ -336,6 +336,27 @@ CATALOG: Dict[str, dict] = {
                     "index layer beside its K/V) the live sequences hold, "
                     "at the last committed decode step",
         emitted_by="llm replica"),
+    "rtpu_llm_kv_fold_blocks_held": dict(
+        kind="counter", tag_keys=("model", "group"),
+        description="Blocks of the paged cache the live sequences of a "
+                    "model that folds hold (two a closed window and the "
+                    "open window's), added up at every committed decode "
+                    "step (over rtpu_llm_kv_fold_blocks_unfolded: what "
+                    "the fold leaves of the cache, the mean over steps)",
+        emitted_by="llm replica"),
+    "rtpu_llm_kv_fold_blocks_unfolded": dict(
+        kind="counter", tag_keys=("model", "group"),
+        description="Blocks the same sequences would hold at the same "
+                    "steps with every position's row kept: ceil(positions "
+                    "seen / block size) a sequence",
+        emitted_by="llm replica"),
+    "rtpu_llm_kv_windows_folded": dict(
+        kind="counter", tag_keys=("model", "group"),
+        description="Windows folded out of the paged cache in decode (a "
+                    "sequence's token closed one: its pages read, the "
+                    "folded rows written over the first of them, the rest "
+                    "given back), told at committed decode steps",
+        emitted_by="llm replica"),
     "rtpu_llm_tokens_total": dict(
         kind="counter", tag_keys=("model", "phase", "group"),
         description="Tokens processed by an LLM engine: 'prefill' = "

@@ -2250,3 +2250,161 @@ def test_keye_scatter_program_writes_both_planes_by_rows(keye_runner,
     assert mem.temp_size_in_bytes < 1.4e9 * bucket / 26624 + 0.1e9
     # beside the weights and the staging
     assert total + 8.75e9 + 0.74e9 < 16.9e9, total
+
+
+@pytest.fixture(scope="module")
+def evabyte_runner(v5e):
+    """The EvaByte cell's runner over abstract weights (drawn in bf16, the
+    serving type) and its ONE K/V pool as a shape on the chip: folded rows
+    lie in it as K/V rows do."""
+    import json
+    from pathlib import Path
+
+    from ray_tpu.models import llama
+    from ray_tpu.serve.llm import EngineConfig
+    from ray_tpu.serve.llm.config import resolve_model
+    from ray_tpu.serve.llm.kv_cache import device_shape
+    from ray_tpu.serve.llm.model_runner import ModelRunner
+    engine = json.loads((Path(__file__).parent.parent / "perfbench" /
+                         "configs" / "evabyte-6.5b.json").read_text()
+                        )["serve"]["engine"]
+    for key in ("decode_batch_buckets", "prefill_len_buckets"):
+        engine[key] = tuple(engine[key])
+    ecfg = EngineConfig(**engine)
+    mod, mcfg = resolve_model(ecfg)
+    assert mod is llama and (mcfg.eva_window, mcfg.eva_chunk) == (2048, 16)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.eval_shape(lambda key: mod.init_params(key, mcfg),
+                            jax.random.key(0))
+    runner = ModelRunner(ecfg, params=params)
+    assert runner.params is params      # drawn in its serving type
+    assert runner.chunk == 2048 and runner.block is None
+    kept = runner.family.kept
+    assert (kept.kv_layers, kept.fold_window, kept.fold_chunk) == (8, 2048, 16)
+    held = {"kv": on_chip(device_shape(ecfg.num_blocks, 8, ecfg.block_size,
+                                       mcfg.n_kv_head, mcfg.head_dim),
+                          jnp.float32)}
+    weights = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), params)
+    return runner, ecfg, held, weights, on_chip
+
+
+# the float32 reference beside the engine: a layer's seven matrices widened
+# (0.81e9), a block of 512 queries' scores over the check's 4,098 positions
+# in 32 heads, the stream and q, k, v in float32
+EVABYTE_REFERENCE_BYTES = 1.6e9
+
+
+def test_evabyte_decode_program_walks_the_shrunk_table_and_fits(
+        evabyte_runner, monkeypatch):
+    """The cell's decode step at its one bucket of 8 (8 layers at the
+    published widths, the whole head of 8 x 320; 448 pages of 64 rows x
+    4,096 lanes): ONE Mosaic kernel in the layer scan's body, the plain
+    paged walk at 32 / 32 heads of 128 (no new decode kernel: to the query
+    a folded row is one more key and value); the rows a sequence holds are
+    reckoned from the positions it has seen inside the program; the pool
+    (7.52e9 bytes) donated and touched by the update of the step's 8 rows
+    alone; head 0's 320 logits and no other leave it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, ecfg, held, weights, on_chip = evabyte_runner
+    bucket, i32 = ecfg.decode_batch_buckets[-1], jnp.int32
+    assert runner.param_bytes == 3_261_865_984
+    assert held["kv"].shape == (8, 2, 448, 64, 4096)
+    lowered = runner._decode.lower(
+        held, weights, on_chip((bucket,), i32), on_chip((bucket,), i32),
+        on_chip((bucket, ecfg.max_blocks_per_seq), i32),
+        on_chip((bucket,), i32), on_chip((), i32),
+        on_chip((bucket,), i32), on_chip((bucket,), i32))
+    out = jax.tree.leaves(lowered.out_info)
+    assert out[0].shape == (8, 2, 448, 64, 4096)
+    assert (8, 320) in [o.shape for o in out]
+    assert out[-1].shape == (8, 8, 32, 128)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "/attn_eva/" in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    _assert_the_pool_is_read_in_place_and_written_by_rows(
+        text, held["kv"].shape, lanes_used=32 * 128)
+    total, mem = _held_bytes(compiled)
+    assert mem.alias_size_in_bytes >= 7.5e9
+    assert mem.temp_size_in_bytes < 0.05e9
+    assert total + 0.94e9 + EVABYTE_REFERENCE_BYTES < 16.9e9, total
+
+
+def test_evabyte_chunk_program_is_one_window_and_folds_it(evabyte_runner,
+                                                         monkeypatch):
+    """The one prefill program: a chunk of 2,048 positions = one window over
+    a staging of the 3,584 rows a prompt of 26,624 HOLDS (1,536 folded and
+    one window, K and V: 0.94e9 bytes, donated and returned); the causal
+    flash kernel in the coordinates of the rows held, the fold under its
+    own scope; it is handed no holder, so the pool goes untouched."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, ecfg, held, weights, on_chip = evabyte_runner
+    staging = jax.tree.map(lambda s: on_chip(s.shape, s.dtype),
+                           runner.staging_spec)
+    assert staging["k"].shape == (8, 3584, 4096) and set(staging) == {"k", "v"}
+    assert runner.staging_bytes == 8 * 3584 * 4096 * 2 * 4
+    i32 = jnp.int32
+    compiled = runner._prefill_chunk.lower(
+        None, weights, staging, on_chip((1, runner.chunk), i32),
+        on_chip((), i32), on_chip((), i32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "causal_prefill" in text
+    for scope in ("attn_eva", "eva_fold", "kv_stage"):
+        assert f"/{scope}/" in text, scope
+    total, mem = _held_bytes(compiled)
+    assert mem.alias_size_in_bytes >= 0.93e9        # the staging
+    assert mem.temp_size_in_bytes < 0.6e9
+    # beside the pool the engine holds while a prompt runs
+    assert total + 7.52e9 + EVABYTE_REFERENCE_BYTES < 16.9e9, total
+
+
+def test_evabyte_fold_program_reads_one_window_and_writes_by_rows(
+        evabyte_runner, monkeypatch):
+    """The fold of a window that closes in decode: the window's 32 pages
+    gathered out of the donated pool in every layer (0.54e9 bytes), 128
+    folded rows a layer written over the first two of them by rows, and
+    nothing of the pool's size made beside that update."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, ecfg, held, weights, on_chip = evabyte_runner
+    compiled = runner._fold.lower(
+        held, weights, on_chip((2048 // ecfg.block_size,), jnp.int32)
+    ).compile()
+    text = compiled.as_text()
+    assert "/eva_fold/" in text and "tpu_custom_call" not in text
+    pool = held["kv"].shape
+    rows = f"{math.prod(pool[:-1])},{pool[-1]}"
+    assert sorted(_made(text, math.prod(pool))) == [
+        ("fusion", rows), ("scatter", rows)], _made(text, math.prod(pool))
+    total, mem = _held_bytes(compiled)
+    assert mem.alias_size_in_bytes >= 7.5e9
+    assert mem.temp_size_in_bytes < 1.7e9
+    assert total + 0.94e9 < 16.9e9, total
+
+
+@pytest.mark.parametrize("bucket", [8192, 26624])
+def test_evabyte_scatter_program_writes_the_rows_held(evabyte_runner,
+                                                      monkeypatch, bucket):
+    """A prompt's scatter at the smallest and the largest bucket: what it
+    is handed are the rows the prompt HOLDS (2,432 for up to 8,192
+    positions, 3,584 for up to 26,624), written by rows into the donated
+    pool."""
+    from ray_tpu.serve.llm.kv_cache import _programs, held_rows_most
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, ecfg, held, weights, on_chip = evabyte_runner
+    rows = held_rows_most(bucket, 2048, 16)
+    assert rows == {8192: 2432, 26624: 3584}[bucket]
+    f32, i32 = jnp.float32, jnp.int32
+    compiled = _programs().scatter_prefill.lower(
+        held, on_chip((rows // ecfg.block_size,), i32),
+        on_chip((8, rows, 32, 128), f32), on_chip((8, rows, 32, 128), f32),
+        on_chip((), i32)).compile()
+    total, mem = _held_bytes(compiled)
+    assert mem.alias_size_in_bytes >= 7.5e9
+    # K and V stacked, lane-flat, once: twice the rows handed
+    assert mem.temp_size_in_bytes < 2.1 * 2 * 8 * rows * 4096 * 4
+    # beside the weights and the staging the rows were cut from
+    assert total + 3.26e9 + 0.94e9 < 16.9e9, total
