@@ -6,6 +6,15 @@ form by chunk size and by the precision of the solve, beside each
 variant's distance from the token-by-token recurrence in float32.
 
     chiprun -- python benchmarks/delta_rule_bench.py [variant ...]
+    chiprun -- python benchmarks/delta_rule_bench.py --qkv
+
+``--qkv`` (PR 70) times the rule on a conv's output, q | k | v (2, 8192,
+8192) unnormed, in the two ways a mixer can hand it over: ``whole``
+(``gated_delta_rule_qkv``: the kernels read the one array and norm what
+they load) beside ``apart`` (XLA's ``l2norm_heads`` and slices, then the
+same kernels on three normed arrays), forward and backward with respect
+to qkv, g and beta: each kernel's own ms a call with and without the norm,
+what XLA does round them, and how far the two results differ.
 
 Prints one JSON line a variant and appends them to
 ``chiprun_out/delta_rule_bench.jsonl``.  Fails off the chip.
@@ -13,6 +22,7 @@ Prints one JSON line a variant and appends them to
 
 from __future__ import annotations
 
+import functools
 import json
 import sys
 import tempfile
@@ -29,6 +39,71 @@ VARIANTS = [("kernels", 64, "highest", True),
             ("chunk64_bf16", 64, "bf16", False),
             ("chunk32_highest", 32, "highest", False),
             ("chunk128_highest", 128, "highest", False)]
+KERNELS = ("delta_rule_solve_bwd", "delta_rule_solve", "delta_rule_bwd",
+           "delta_rule_fwd")    # a name that holds another stands before it
+
+
+def ms_a_call_by_kernel(traced, calls):
+    """{kernel: its device ms a call}, over the devices' events."""
+    out = dict.fromkeys(KERNELS, 0.0)
+    for events in traced["device"].values():
+        for name, _, d in events:
+            hit = next((k for k in KERNELS if k in name.lower()), None)
+            if hit:
+                out[hit] += d * 1e3 / calls / len(traced["device"])
+    return out
+
+
+def whole_and_apart(jax, jnp, np, trace, dr, out) -> None:
+    """The ``--qkv`` rows."""
+    keys = jax.random.split(jax.random.key(1), 4)
+    # a conv's silu leaves small numbers of either sign
+    qkv = jax.nn.silu(jax.random.normal(
+        keys[0], (B, T, 2 * G * DK + H * DV))).astype(jnp.bfloat16)
+    step = jnp.exp(jax.random.uniform(keys[1], (B, T, H), jnp.float32,
+                                      jnp.log(0.001), jnp.log(0.1)))
+    g = -step * jax.random.uniform(keys[2], (H,), jnp.float32, 0.0, 16.0)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[3], (B, T, H)))
+
+    def apart(qkv, g, beta):
+        return dr.gated_delta_rule(*dr.apart(qkv, G, DK, H), g, beta)[0]
+
+    def whole(qkv, g, beta):
+        return dr.gated_delta_rule_qkv(qkv, g, beta, G, DK)[0]
+
+    def loss(rule, *a):
+        o = rule(*a)
+        return o.astype(jnp.float32).sum(), o
+    got = {}
+    for name, rule in (("whole", whole), ("apart", apart)):
+        both = jax.jit(jax.value_and_grad(functools.partial(loss, rule),
+                                          argnums=(0, 1, 2), has_aux=True))
+        (_, o), (dqkv, _, _) = both(qkv, g, beta)
+        got[name] = [np.asarray(x, np.float32) for x in (o, dqkv)]
+        with tempfile.TemporaryDirectory() as d:
+            capture = trace.Capture(d)
+            capture.start()
+            for _ in range(3):
+                jax.block_until_ready(both(qkv, g, beta))
+            capture.stop()
+            traced = trace.load_window(capture)
+        kernels = ms_a_call_by_kernel(traced, 3)
+        busy = trace.busy_seconds(traced) * 1e3 / 3
+        row = {"variant": f"qkv_{name}", "fwd_bwd_ms": busy,
+               "kernel_ms": kernels,
+               "kernels_ms": sum(kernels.values()),
+               "xla_ms": busy - sum(kernels.values()),
+               "top_ops_ms": [[n, round(s * 1e3 / 3, 3)]
+                              for n, s in trace.top_ops(traced, 12)]}
+        if name == "apart":
+            for what, a, b in zip(("o", "dqkv"), got["whole"], got["apart"]):
+                row[f"{what}_max_abs_diff_over_max"] = float(
+                    np.abs(a - b).max() / np.abs(b).max())
+                row[f"{what}_share_of_entries_that_differ"] = float(
+                    (a != b).mean())
+        print(json.dumps(row), flush=True)
+        with out.open("a") as f:
+            f.write(json.dumps(row) + "\n")
 
 
 def main() -> None:
@@ -46,6 +121,10 @@ def main() -> None:
 
     if jax.default_backend() != "tpu":
         raise SystemExit("delta_rule_bench measures a TPU")
+    out = Path("chiprun_out") / "delta_rule_bench.jsonl"
+    out.parent.mkdir(exist_ok=True)
+    if sys.argv[1:] == ["--qkv"]:
+        return whole_and_apart(jax, jnp, np, trace, dr, out)
     keys = jax.random.split(jax.random.key(0), 6)
     q = dr.l2norm(jax.random.normal(keys[0], (B, T, G, DK))) * DK ** -0.5
     k = dr.l2norm(jax.random.normal(keys[1], (B, T, G, DK)))
@@ -69,8 +148,6 @@ def main() -> None:
             a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
             preferred_element_type=jnp.float32),
     }
-    out = Path("chiprun_out") / "delta_rule_bench.jsonl"
-    out.parent.mkdir(exist_ok=True)
     zeros = jnp.zeros((B, H, DK, DV), jnp.float32)
     asked = sys.argv[1:]
     for name, chunk, solve, kernels in VARIANTS:

@@ -211,11 +211,11 @@ def _gdn_mixer(u: jax.Array, lp: Params, cfg: Qwen3NextConfig):
     """Normed hidden states (B, T, E) -> (W_out . gated delta rule
     (B, T, E), the mean decay exp(g) of the layer, the rule's last state
     (B, H, dk, dv) float32, which training drops)."""
-    from ray_tpu.ops.delta_rule import gated_delta_rule, l2norm_heads
+    from ray_tpu.ops.delta_rule import gated_delta_rule_qkv
     from ray_tpu.ops.ssm import causal_conv_silu, gated_rms_norm
     B, T, _ = u.shape
     G, H = cfg.gdn_key_heads, cfg.gdn_value_heads
-    dk, dv, kw = cfg.gdn_key_dim, cfg.gdn_value_dim, cfg.gdn_key_width
+    dk, dv = cfg.gdn_key_dim, cfg.gdn_value_dim
     with jax.named_scope("gdn_in"):
         # the conv's channels and the gate are read by column group: a
         # slice of the weight, not of 16,384 rows of activations
@@ -230,15 +230,11 @@ def _gdn_mixer(u: jax.Array, lp: Params, cfg: Qwen3NextConfig):
         beta = jax.nn.sigmoid(ba[..., :H])
         g = -jnp.exp(lp["A_log"].astype(jnp.float32)) * jax.nn.softplus(
             ba[..., H:] + lp["dt_bias"].astype(jnp.float32))
-        # normed with the heads' lanes where the conv left them: the rule's
-        # kernels read (B, T, heads x 128) by column blocks
-        q = l2norm_heads(qkv[..., :kw], G) * dk ** -0.5
-        k = l2norm_heads(qkv[..., kw:2 * kw], G)
-        v = qkv[..., 2 * kw:].reshape(B, T, H, dv)
-        o, state = gated_delta_rule(
-            q.astype(cfg.dtype).reshape(B, T, G, dk),
-            k.astype(cfg.dtype).reshape(B, T, G, dk), v, g, beta,
-            chunk=cfg.rule_chunk)
+        # q | k | v where the conv left them, q and k not yet normed: the
+        # rule's kernels read (B, T, heads x 128) by column blocks and
+        # norm what they load
+        o, state = gated_delta_rule_qkv(qkv, g, beta, G, dk,
+                                        chunk=cfg.rule_chunk)
     with jax.named_scope("gdn_norm"):
         o = gated_rms_norm(o, z, lp["out_norm"]["scale"], cfg.rms_eps)
     with jax.named_scope("gdn_out"):

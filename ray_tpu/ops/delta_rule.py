@@ -25,14 +25,31 @@ the chunk wrote::
     S_end = exp(c_C) S0 + sum_j exp(c_C - c_j) k_j v'_j^T
 
 Two forms of that one algorithm, at the same precisions; which runs is
-read from the call (``_kernels_run``), never set:
+read from the call (``_kernels_run``), never set, whichever entry takes
+it:
 
 *Pallas kernels* (PR 58), on a TPU with bf16 activations, chunks of 64
 and heads of whole 128-lane blocks (the Qwen3-Next cell).  Four, each
 under a name of its own, q, k, v and o read and written as the
 projections leave them, (B, T, heads x 128) by column blocks, the R value
 heads of a key head in one grid step (k . k and q . k made once for
-both), ``STEP_CHUNKS`` chunks a step.  ``delta_rule_solve`` makes ``A``
+both), ``STEP_CHUNKS`` chunks a step.  Two entries hand them their
+operands.  ``gated_delta_rule`` takes q, k and v as three arrays, q and k
+normed by the caller.  ``gated_delta_rule_qkv`` (PR 70, the mixer's) takes
+a DeltaNet mixer's conv output whole and unnormed, (B, T, q | k | v): the
+same kernels under a static switch read the three as column blocks of
+the ONE array (k's behind the G blocks of q, v's behind both: no slice
+of it is made) and l2-norm the (64, 128) block of q and of k they have
+loaded, where a head's norm is a reduce over the block's lanes: float32
+sum of squares, ``rsqrt(. + 1e-6)``, q scaled by ``dk ** -0.5``, one
+rounding to the activations' type, which is then q or k wherever the
+kernel uses them; the two backward kernels take their float32 dq and dk
+through the norm's derivative before the one rounding, so what they write
+is the cotangent of the conv's output.  (XLA made those values in float32
+passes over (B, T, G dk), a head's sum and its way back as products with
+an indicator at ``HIGHEST``, forward, recomputed and backward, and copied
+v out twice a layer-step: 11.7 ms of the cell's 444 ms step, PERF.md
+section 5.)  ``delta_rule_solve`` makes ``A``
 and ``T = (I + A)^-1`` by substitution on the vector unit, float32
 multiplies and adds (no lower a precision than products at ``HIGHEST``):
 of a chunk's (C, C) matrices ``T`` alone reaches HBM, the R heads' side
@@ -48,10 +65,13 @@ differentiated forward in float32: (B, H, chunks, dk, dv), 537 MB at the
 shape below, alive for one layer at a time under a block's checkpoint),
 and hands ``T``'s cotangent to ``delta_rule_solve_bwd`` (``dA = -T^T dT
 T^T`` below the diagonal, then k, the decays and beta).  XLA is left
-with the cumulative sum of g by chunk and its reverse (1 M numbers).
+with the cumulative sum of g by chunk and its reverse (1 M numbers), the
+sum of k's two cotangents and, of the conv's whole output, the one pass
+that lays dq | dk | dv side by side.
 
 *Plain XLA* (``_spans_form``), for everything else: float32, the tests'
-8-wide heads, other chunk sizes, the CPU.  It is the definition the tests
+8-wide heads, other chunk sizes, the CPU; round it, for the conv's whole
+output, XLA's slices and ``l2norm_heads``.  It is the definition the tests
 hold to the recurrence at 1e-5, and one test holds the kernels to it.
 ``A``, ``T``, ``U``, ``W`` and the (C, C) scores are made for ``SPAN``
 chunks at once, as batched products; only the four products that read the
@@ -325,14 +345,52 @@ def _head_parts(rows_ref, t_ref, v_ref, k32, ci, r, R, C, dv, i, j):
                 u=uw[:, :dv], w=uw[:, dv:])
 
 
+_EPS = 1e-6         # ``l2norm``'s
+
+
+def _unit(x):
+    """A chunk of a key head (C, dk) -> (its rows at length 1 but for
+    ``_EPS``, float32; the rows' ``rsqrt`` (C, 1)): ``l2norm``, the sum
+    of squares a reduce over the head's lanes."""
+    x32 = x.astype(jnp.float32)
+    r = lax.rsqrt(jnp.sum(x32 * x32, axis=1, keepdims=True) + _EPS)
+    return x32 * r, r
+
+
+def _normed(x, norm, scale=1.0):
+    """A chunk of a key head, (C, dk) as loaded -> as the products take it.
+    ``norm`` False: the caller normed it.  True: it is the conv's output,
+    and what ``l2norm_heads`` and the model's cast made of it is made
+    here: float32 sum of squares over the head's lanes, ``rsqrt``, the
+    queries' ``scale``, one rounding to the activations' type."""
+    if not norm:
+        return x
+    n, _ = _unit(x)
+    return (n if scale == 1.0 else n * scale).astype(x.dtype)
+
+
+def _through_norm(dy, x, norm, scale=1.0):
+    """The float32 cotangent ``dy`` of ``_normed(x)`` -> that of ``x``:
+    ``scale r (dy - n sum(n dy))`` a row with ``n = x r``, the rounding's
+    derivative the identity (as ``astype``'s is).  ``n`` and ``r`` are
+    made again from the block, which is in VMEM: kept since the chunk's
+    head they would be live across all its products."""
+    if not norm:
+        return dy
+    n, r = _unit(x)
+    return (r if scale == 1.0 else r * scale) \
+        * (dy - n * jnp.sum(n * dy, axis=1, keepdims=True))
+
+
 def _fwd_kernel(rows_ref, q_ref, k_ref, v_ref, t_ref, s0_ref, o_ref, sn_ref,
-                states_ref, s_scr, *, R, m, C):
+                states_ref, s_scr, *, R, m, C, norm):
     """Grid (batch x key head, step): ``m`` chunks of the R value heads
     that read one key head; ``s_scr`` carries the R states from step to
     step, ``states_ref`` keeps the state that entered each chunk."""
     step = pl.program_id(1)
     dv = v_ref.shape[-1] // R
     low, prec = _low(q_ref.dtype)
+    q_scale = q_ref.shape[-1] ** -0.5
 
     @pl.when(step == 0)
     def _():
@@ -341,7 +399,8 @@ def _fwd_kernel(rows_ref, q_ref, k_ref, v_ref, t_ref, s0_ref, o_ref, sn_ref,
     i, j = _positions(C)
     for ci in range(m):
         at = pl.ds(ci * C, C)
-        q, k = q_ref[at, :], k_ref[at, :]
+        q = _normed(q_ref[at, :], norm, q_scale)
+        k = _normed(k_ref[at, :], norm)
         q32, k32 = q.astype(jnp.float32), k.astype(jnp.float32)
         qk = _dot(q, k, (1, 1), prec)
         for r in range(R):
@@ -364,7 +423,7 @@ def _fwd_kernel(rows_ref, q_ref, k_ref, v_ref, t_ref, s0_ref, o_ref, sn_ref,
 
 def _bwd_kernel(rows_ref, q_ref, k_ref, v_ref, t_ref, states_ref, do_ref,
                 dsn_ref, dq_ref, dk_ref, dv_ref, drows_ref, dt_ref, ds0_ref,
-                ds_scr, *, R, m, C):
+                ds_scr, *, R, m, C, norm):
     """The forward's grid with the steps, and the chunks of a step, in
     reverse: ``ds_scr`` carries the cotangent of the R states from the
     last chunk to the first.  A chunk's matrices are made again from q,
@@ -372,6 +431,7 @@ def _bwd_kernel(rows_ref, q_ref, k_ref, v_ref, t_ref, states_ref, do_ref,
     step = pl.program_id(1)
     dv = v_ref.shape[-1] // R
     low, prec = _low(q_ref.dtype)
+    q_scale = q_ref.shape[-1] ** -0.5
 
     @pl.when(step == 0)
     def _():
@@ -381,7 +441,8 @@ def _bwd_kernel(rows_ref, q_ref, k_ref, v_ref, t_ref, states_ref, do_ref,
     eye = i == j
     for ci in reversed(range(m)):
         at = pl.ds(ci * C, C)
-        q, k = q_ref[at, :], k_ref[at, :]
+        q = _normed(q_ref[at, :], norm, q_scale)
+        k = _normed(k_ref[at, :], norm)
         q32, k32 = q.astype(jnp.float32), k.astype(jnp.float32)
         qk = _dot(q, k, (1, 1), prec)
         kq = _dot(k, q, (1, 1), prec)                    # qk's transpose
@@ -441,10 +502,12 @@ def _bwd_kernel(rows_ref, q_ref, k_ref, v_ref, t_ref, states_ref, do_ref,
             dt_ref[ci, :, pl.ds(r * C, C)] = dt
             dqk = dqk + dscores * p["decay"]
         dqk_low = low(dqk)
-        dq_ref[at, :] = (dq + _dot(dqk_low, k, (1, 0), prec)
-                         ).astype(dq_ref.dtype)
-        dk_ref[at, :] = (dk + _dot(dqk_low, q, (0, 0), prec)
-                         ).astype(dk_ref.dtype)
+        dq_ref[at, :] = _through_norm(
+            dq + _dot(dqk_low, k, (1, 0), prec), q_ref[at, :], norm,
+            q_scale).astype(dq_ref.dtype)
+        dk_ref[at, :] = _through_norm(
+            dk + _dot(dqk_low, q, (0, 0), prec), k_ref[at, :], norm
+            ).astype(dk_ref.dtype)
 
     @pl.when(step == pl.num_programs(1) - 1)
     def _():
@@ -480,14 +543,14 @@ def _inverses_by_substitution(a):
     return jnp.concatenate(done + [below], axis=1)
 
 
-def _solve_kernel(rows_ref, k_ref, t_ref, *, R, m, C):
+def _solve_kernel(rows_ref, k_ref, t_ref, *, R, m, C, norm):
     """Grid (batch x key head, step): the solved systems ``T = (I + A)^-1``
     of ``m`` chunks' R value heads, the heads' side by side."""
     _, prec = _low(k_ref.dtype)
     i, j = _positions(C)
     systems = []
     for ci in range(m):
-        k = k_ref[pl.ds(ci * C, C), :]
+        k = _normed(k_ref[pl.ds(ci * C, C), :], norm)
         kk = _dot(k, k, (1, 1), prec)
         for r in range(R):
             b_col, _, _, diff = _decays(rows_ref, ci, r, R, i, j)
@@ -498,7 +561,7 @@ def _solve_kernel(rows_ref, k_ref, t_ref, *, R, m, C):
 
 
 def _solve_bwd_kernel(rows_ref, k_ref, t_ref, dt_ref, dk_ref, drows_ref, *,
-                      R, m, C):
+                      R, m, C, norm):
     """``_solve_kernel``'s backward: ``dA = -T^T dT T^T`` below the
     diagonal, and from it the cotangents of k (through k . k, summed over
     the R value heads), of the log-decays and of beta."""
@@ -507,7 +570,7 @@ def _solve_bwd_kernel(rows_ref, k_ref, t_ref, dt_ref, dk_ref, drows_ref, *,
     eye = i == j
     for ci in range(m):
         at = pl.ds(ci * C, C)
-        k = k_ref[at, :]
+        k = _normed(k_ref[at, :], norm)
         kk = _dot(k, k, (1, 1), prec)
         dkk = jnp.zeros((C, C), jnp.float32)
         for r in range(R):
@@ -526,30 +589,34 @@ def _solve_bwd_kernel(rows_ref, k_ref, t_ref, dt_ref, dk_ref, drows_ref, *,
                 jnp.sum(da * below, axis=1, keepdims=True), eye)
             dkk = dkk + scaled
         dkk = low(dkk)
-        dk_ref[at, :] = (_dot(dkk, k, (1, 0), prec)
-                         + _dot(dkk, k, (0, 0), prec)).astype(dk_ref.dtype)
+        dk_ref[at, :] = _through_norm(
+            _dot(dkk, k, (1, 0), prec) + _dot(dkk, k, (0, 0), prec),
+            k_ref[at, :], norm).astype(dk_ref.dtype)
 
 
 # name -> (body, operands, results, steps from the last to the first, a
 # state carried from step to step in scratch), operands and results by the
 # names of ``_kernel``'s specs
 _KERNELS = {
-    "delta_rule_solve": (_solve_kernel, ("rows", "qk"), ("t",), False, False),
-    "delta_rule_fwd": (_fwd_kernel, ("rows", "qk", "qk", "v", "t", "state"),
-                       ("v", "state", "states"), False, True),
-    "delta_rule_bwd": (_bwd_kernel, ("rows", "qk", "qk", "v", "t", "states",
-                                     "v", "state"),
-                       ("qk", "qk", "v", "rows", "t", "state"), True, True),
-    "delta_rule_solve_bwd": (_solve_bwd_kernel, ("rows", "qk", "t", "t"),
-                             ("qk", "rows"), False, False),
+    "delta_rule_solve": (_solve_kernel, ("rows", "k"), ("t",), False, False),
+    "delta_rule_fwd": (_fwd_kernel, ("rows", "q", "k", "v", "t", "state"),
+                       ("o", "state", "states"), False, True),
+    "delta_rule_bwd": (_bwd_kernel, ("rows", "q", "k", "v", "t", "states",
+                                     "o", "state"),
+                       ("dqk", "dqk", "o", "rows", "t", "state"), True, True),
+    "delta_rule_solve_bwd": (_solve_bwd_kernel, ("rows", "k", "t", "t"),
+                             ("dqk", "rows"), False, False),
 }
 
 
 @functools.lru_cache(maxsize=None)
-def _kernel(name, B, G, N, R, C, dk, dv, dtype, m, interpret):
+def _kernel(name, B, G, N, R, C, dk, dv, dtype, m, whole, interpret):
     """One of the four kernels at one shape: grid (batch x key head ``bg``,
     step ``n`` of ``m`` chunks), q, k, v as (B, T, heads x width) column
-    blocks.  The ``pallas_call`` stands inside a jitted function of the
+    blocks: of three arrays the caller normed, or (``whole``) of the
+    conv's one unnormed (B, T, q | k | v), k's blocks behind the G of q,
+    v's behind both, which the body then norms.  The ``pallas_call``
+    stands inside a jitted function of the
     kernel's name: in the Qwen3-Next step the v5e names a custom call
     after the function it stands in (``gated_delta_rule.76``) and,
     standing in none, ``tpu_custom_call.149`` (my chip runs, PR 58), the
@@ -560,15 +627,22 @@ def _kernel(name, B, G, N, R, C, dk, dv, dtype, m, interpret):
     body, operands, results, back, carried = _KERNELS[name]
     steps = N // m
     at = (lambda n: steps - 1 - n) if back else (lambda n: n)
-    by_time = lambda width: (
-        jax.ShapeDtypeStruct((B, N * C, G * width), dtype),
-        pl.BlockSpec((None, m * C, width),
-                     lambda bg, n: (bg // G, at(n), bg % G)))
+
+    def by_time(width, first=0, lanes=None):
+        """(B, T, ``lanes``), a key head's ``width`` lanes a block, head
+        0's the block ``first``."""
+        return (jax.ShapeDtypeStruct((B, N * C, lanes or G * width), dtype),
+                pl.BlockSpec((None, m * C, width),
+                             lambda bg, n: (bg // G, at(n), first + bg % G)))
     by_chunk = lambda *shape: (
         jax.ShapeDtypeStruct((B * G, N, *shape), jnp.float32),
         pl.BlockSpec((None, m, *shape),
                      lambda bg, n: (bg, at(n)) + (0,) * len(shape)))
-    of = dict(qk=by_time(dk), v=by_time(R * dv), rows=by_chunk(2 * R, C),
+    # three arrays, or the one's lanes and head 0's block of q, k and v
+    lanes = G * (2 * dk + R * dv) if whole else None
+    q0, k0, v0 = (0, G, 2 * G * dk // (R * dv)) if whole else (0, 0, 0)
+    of = dict(q=by_time(dk, q0, lanes), k=by_time(dk, k0, lanes),
+              v=by_time(R * dv, v0, lanes), dqk=by_time(dk), o=by_time(R * dv), rows=by_chunk(2 * R, C),
               t=by_chunk(C, R * C), states=by_chunk(R, dk, dv),
               state=(jax.ShapeDtypeStruct((B * G, R, dk, dv), jnp.float32),
                      pl.BlockSpec((None, R, dk, dv),
@@ -576,7 +650,8 @@ def _kernel(name, B, G, N, R, C, dk, dv, dtype, m, interpret):
 
     def run(*args):
         return pl.pallas_call(
-            functools.partial(body, R=R, m=m, C=C), grid=(B * G, steps),
+            functools.partial(body, R=R, m=m, C=C, norm=whole),
+            grid=(B * G, steps),
             in_specs=[of[x][1] for x in operands],
             out_specs=[of[x][1] for x in results],
             out_shape=[of[x][0] for x in results],
@@ -588,85 +663,78 @@ def _kernel(name, B, G, N, R, C, dk, dv, dtype, m, interpret):
     return jax.jit(run)
 
 
-def _call(name, rows, q, v, G, interpret, *args):
-    """Kernel ``name`` at the shape of rows (B G, N, 2 R, C), q (B, T, G
-    dk) and v (B, T, G R dv), on ``args``."""
+def _call(name, rows, x, G, dk, interpret, *args):
+    """Kernel ``name`` at the shape of rows (B G, N, 2 R, C) and ``x``,
+    ``_chunks``'s, on ``args``."""
     BG, N, R2, C = rows.shape
     R = R2 // 2
-    return _kernel(name, BG // G, G, N, R, C, q.shape[-1] // G,
-                   v.shape[-1] // (G * R), jnp.dtype(q.dtype),
-                   min(STEP_CHUNKS, N), interpret)(*args)
+    dv = (sum(a.shape[-1] for a in x) - 2 * G * dk) // (G * R)
+    return _kernel(name, BG // G, G, N, R, C, dk, dv, jnp.dtype(x[0].dtype),
+                   min(STEP_CHUNKS, N), len(x) == 1, interpret)(*args)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _solve(rows, k, G, interpret):
-    """rows, k as ``_rule`` takes them -> ``T = (I + A)^-1`` (B G, N, C,
-    R C) float32, ``A_ij = beta_i (k_i . k_j) exp(c_i - c_j)`` below the
-    diagonal: the chunk's k . k, the decays and the substitution in a
-    kernel, so that of a chunk's (C, C) matrices only ``T`` reaches HBM."""
-    return _call("delta_rule_solve", rows, k, k, G, interpret, rows, k)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _chunks(rows, x, state0, G, dk, interpret):
+    """The chunks' work in kernels: rows (B G, N, 2 R, C) float32, a
+    chunk's cumulative log-decays of the R value heads of a key head and
+    then their betas; ``x`` either (q, k (B, T, G dk), v (B, T, H dv)),
+    normed by the caller, or (qkv (B, T, 2 G dk + H dv),), the conv's
+    output as it lies, which the kernels norm; state0 (B G, R, dk, dv)
+    float32 -> (o like v, the last state like state0)."""
+    return _chunks_fwd(rows, x, state0, G, dk, interpret)[0]
 
 
-def _solve_fwd(rows, k, G, interpret):
-    # named here, so that what a caller's checkpoint keeps is the value
-    # the backward reads too, and the kernel is not run again for it
-    t = checkpoint_name(_solve(rows, k, G, interpret), INVERSE)
-    return t, (rows, k, t)
-
-
-def _solve_bwd(G, interpret, res, dt):
-    rows, k, t = res
-    dk, drows = _call("delta_rule_solve_bwd", rows, k, k, G, interpret,
-                      rows, k, t, dt)
-    return drows, dk
-
-
-_solve.defvjp(_solve_fwd, _solve_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _rule(rows, q, k, v, t, state0, G, interpret):
-    """The chunks' work that follows the solve, in a kernel: rows (B G, N,
-    2 R, C) float32, a chunk's cumulative log-decays of the R value heads
-    of a key head and then their betas; q, k (B, T, G dk), v (B, T, H dv)
-    as the projections leave them; t (B G, N, C, R C) the solved systems,
-    the R heads' side by side; state0 (B G, R, dk, dv) float32 -> (o like
-    v, the last state like state0)."""
-    return _rule_fwd(rows, q, k, v, t, state0, G, interpret)[0]
-
-
-def _rule_fwd(rows, q, k, v, t, state0, G, interpret):
-    """Also the state entering every chunk (B G, N, R, dk, dv) float32,
-    which the backward reads: one kernel either way, so that a step is
-    built from one trace of it (a call that is not differentiated writes
-    them and drops them: 0.2 ms a layer at the cell's shape)."""
-    o, state, states = _call("delta_rule_fwd", rows, q, v, G, interpret,
+def _chunks_fwd(rows, x, state0, G, dk, interpret):
+    """``delta_rule_solve`` makes ``T = (I + A)^-1`` (B G, N, C, R C)
+    float32, ``A_ij = beta_i (k_i . k_j) exp(c_i - c_j)`` below the
+    diagonal, so that of a chunk's (C, C) matrices only ``T`` reaches HBM;
+    it is named here, so that what a caller's checkpoint keeps is the
+    value the backward reads too, and the kernel is not run again for it.
+    ``delta_rule_fwd`` does what follows and writes the state entering
+    every chunk too (B G, N, R, dk, dv) float32, which the backward
+    reads: one kernel either way, so that a step is built from one trace
+    of it (a call that is not differentiated writes them and drops them:
+    0.2 ms a layer at the cell's shape)."""
+    q, k, v = x if len(x) == 3 else x * 3
+    t = checkpoint_name(
+        _call("delta_rule_solve", rows, x, G, dk, interpret, rows, k)[0],
+        INVERSE)
+    o, state, states = _call("delta_rule_fwd", rows, x, G, dk, interpret,
                              rows, q, k, v, t, state0)
-    return (o, state), (rows, q, k, v, t, states)
+    return (o, state), (rows, x, t, states)
 
 
-def _rule_bwd(G, interpret, res, cts):
-    rows, q, k, v, t, states = res
+def _chunks_bwd(G, dk, interpret, res, cts):
+    """The two written-out backwards; k's cotangent is the sum of both,
+    and the conv's output's is the three side by side, in one pass."""
+    rows, x, t, states = res
+    q, k, v = x if len(x) == 3 else x * 3
     do, dsn = cts
-    dq, dk, dv, drows, dt, ds0 = _call(
-        "delta_rule_bwd", rows, q, v, G, interpret, *res, do,
-        dsn.astype(jnp.float32))
-    return drows, dq, dk, dv, dt, ds0
+    dq, dk_rule, dv, drows, dt, ds0 = _call(
+        "delta_rule_bwd", rows, x, G, dk, interpret, rows, q, k, v, t,
+        states, do, dsn.astype(jnp.float32))
+    dk_solve, drows_solve = _call("delta_rule_solve_bwd", rows, x, G, dk,
+                                  interpret, rows, k, t, dt)
+    if len(x) == 3:
+        return drows + drows_solve, (dq, dk_rule + dk_solve, dv), ds0
+    # each where it lies in a zero (B, T, W) and the four summed: XLA makes
+    # one fusion of it (a concatenate left k's sum a pass of its own)
+    kw, W = dq.shape[-1], x[0].shape[-1]
+    dx = sum(jnp.pad(part, ((0, 0), (0, 0), (at, W - at - part.shape[-1])))
+             for at, part in ((0, dq), (kw, dk_rule), (kw, dk_solve),
+                              (2 * kw, dv)))
+    return drows + drows_solve, (dx,), ds0
 
 
-_rule.defvjp(_rule_fwd, _rule_bwd)
+_chunks.defvjp(_chunks_fwd, _chunks_bwd)
 
 
-def delta_rule_chunks(q, k, v, g, beta, state0, *, chunk: int = CHUNK,
-                      interpret: bool = False):
-    """``gated_delta_rule`` with the chunks' work in Pallas kernels
-    (``delta_rule_fwd``, ``delta_rule_bwd``): same arguments and results.
-    XLA makes the cumulative log-decays a chunk; one kernel the solved
-    systems ``T`` (kept across a caller's checkpoint by the name
-    ``INVERSE``: the solve is not run again), another everything that
-    follows; each has its backward written out as a kernel."""
-    (B, T, G, dk), (H, dv) = q.shape, v.shape[2:]
-    R, C = H // G, chunk
+def _in_kernels(x, g, beta, state0, G, dk, chunk, interpret):
+    """``_chunks`` on a call's arrays: ``x`` as it takes them, g, beta (B,
+    T, H), state0 (B, H, dk, dv) -> (o (B, T, H, dv), the last state).
+    XLA makes the cumulative log-decays a chunk."""
+    (B, T), H, C = g.shape[:2], g.shape[2], chunk
+    R = H // G
     n = -(-T // C)
     m = min(STEP_CHUNKS, n)
     pad = -T % (C * m)
@@ -681,22 +749,45 @@ def delta_rule_chunks(q, k, v, g, beta, state0, *, chunk: int = CHUNK,
         return x.transpose(0, 3, 1, 4, 2)
     rows = jnp.concatenate([jnp.cumsum(by_head(g), axis=-1), by_head(beta)],
                            axis=3).reshape(B * G, N, 2 * R, C)
-    q, k, v = (padded(x).reshape(B, N * C, -1) for x in (q, k, v))
-    o, state = _rule(
-        rows, q, k, v, _solve(rows, k, G, interpret),
-        state0.astype(jnp.float32).reshape(B * G, R, dk, dv), G, interpret)
-    return o.reshape(B, N * C, H, dv)[:, :T], state.reshape(B, H, dk, dv)
+    o, state = _chunks(
+        rows, tuple(padded(a) for a in x),
+        state0.astype(jnp.float32).reshape(B * G, R, dk, -1), G, dk,
+        interpret)
+    return o.reshape(B, N * C, H, -1)[:, :T], state.reshape(state0.shape)
 
 
-def _kernels_run(q, v, chunk: int) -> bool:
+def delta_rule_chunks(q, k, v, g, beta, state0, *, chunk: int = CHUNK,
+                      interpret: bool = False):
+    """``gated_delta_rule`` with the chunks' work in Pallas kernels: same
+    arguments and results.  One kernel makes the solved systems ``T``
+    (kept across a caller's checkpoint by the name ``INVERSE``: the solve
+    is not run again), another everything that follows; each has its
+    backward written out as a kernel."""
+    (B, T, G, dk) = q.shape
+    return _in_kernels(tuple(a.reshape(B, T, -1) for a in (q, k, v)), g,
+                       beta, state0, G, dk, chunk, interpret)
+
+
+def delta_rule_chunks_qkv(qkv, g, beta, state0, key_heads: int, key_dim: int,
+                          *, chunk: int = CHUNK, interpret: bool = False):
+    """``gated_delta_rule_qkv`` in the same kernels, which read q, k and v
+    out of ``qkv`` where they lie and norm the q and k they have loaded."""
+    return _in_kernels((qkv,), g, beta, state0, key_heads, key_dim, chunk,
+                       interpret)
+
+
+def _kernels_run(dtype, G: int, dk: int, H: int, dv: int, chunk: int,
+                 whole: bool = False) -> bool:
     """Whether a call's chunks run in the kernels: on a TPU, bf16
     activations, chunks of 64, heads of whole 128-lane blocks and whole
-    groups of value heads a key head.  Everything else (float32, the
-    tests' 8-wide heads, other chunk sizes, the CPU) takes the XLA form."""
-    (G, dk), (H, dv) = q.shape[2:], v.shape[2:]
+    groups of value heads a key head; of the conv's ``whole`` output, a
+    group's values in whole blocks behind q and k.  Everything else
+    (float32, the tests' 8-wide heads, other chunk sizes, the CPU) takes
+    the XLA form."""
     return (jax.default_backend() == "tpu" and chunk == CHUNK
-            and q.dtype == v.dtype == jnp.bfloat16
-            and dk % 128 == 0 and dv % 128 == 0 and H % G == 0)
+            and dtype == jnp.bfloat16
+            and dk % 128 == 0 and dv % 128 == 0 and H % G == 0
+            and not (whole and 2 * G * dk % (H // G * dv)))
 
 
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
@@ -714,12 +805,50 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     v's type (the module's head).  Which form runs is read from the call
     (``_kernels_run``): both are the same chunked algorithm at the same
     precisions."""
+    (B, _, G, dk), (H, dv) = q.shape, v.shape[2:]
     if state0 is None:
-        state0 = jnp.zeros((q.shape[0], *v.shape[2:3], q.shape[3],
-                            v.shape[3]), jnp.float32)
-    if _kernels_run(q, v, chunk):
+        state0 = jnp.zeros((B, H, dk, dv), jnp.float32)
+    if q.dtype == v.dtype and _kernels_run(v.dtype, G, dk, H, dv, chunk):
         return delta_rule_chunks(q, k, v, g, beta, state0, chunk=chunk)
     return _spans_form(q, k, v, g, beta, chunk, state0)
+
+
+def gated_delta_rule_qkv(qkv: jax.Array, g: jax.Array, beta: jax.Array,
+                         key_heads: int, key_dim: int, chunk: int = CHUNK,
+                         state0: Optional[jax.Array] = None
+                         ) -> Tuple[jax.Array, jax.Array]:
+    """:func:`gated_delta_rule` of a DeltaNet mixer's conv output, whole
+    and unnormed: qkv (B, T, 2 G dk + H dv), the G query heads, the G key
+    heads and the H value heads side by side; q and k are l2-normed a
+    head (``l2norm_heads``: float32, eps 1e-6), q scaled by ``dk ** -0.5``,
+    both rounded once to qkv's type; g, beta, state0 and the results as
+    there.  Where the kernels run they read the three out of ``qkv`` by
+    column blocks and norm the blocks they have loaded, so no slice and
+    no normed copy is made, and the backward writes the cotangent of
+    ``qkv``; elsewhere the slices and the norms are XLA's, round the XLA
+    form."""
+    (B, _, W), H, G, dk = qkv.shape, g.shape[-1], key_heads, key_dim
+    dv = (W - 2 * G * dk) // H
+    if state0 is None:
+        state0 = jnp.zeros((B, H, dk, dv), jnp.float32)
+    if _kernels_run(qkv.dtype, G, dk, H, dv, chunk, whole=True):
+        return delta_rule_chunks_qkv(qkv, g, beta, state0, G, dk,
+                                     chunk=chunk)
+    return _spans_form(*apart(qkv, G, dk, H), g, beta, chunk, state0)
+
+
+def apart(qkv: jax.Array, key_heads: int, key_dim: int, value_heads: int
+          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """A conv's q | k | v (B, T, 2 G dk + H dv) -> what
+    :func:`gated_delta_rule` takes: q, k (B, T, G, dk) l2-normed a head,
+    q scaled by ``dk ** -0.5``, both rounded once to qkv's type, and v
+    (B, T, H, dv): XLA's slices and norms, which the kernels spare."""
+    (B, T, _), G, dk, kw = qkv.shape, key_heads, key_dim, key_heads * key_dim
+    q = l2norm_heads(qkv[..., :kw], G) * dk ** -0.5
+    k = l2norm_heads(qkv[..., kw:2 * kw], G)
+    return (q.astype(qkv.dtype).reshape(B, T, G, dk),
+            k.astype(qkv.dtype).reshape(B, T, G, dk),
+            qkv[..., 2 * kw:].reshape(B, T, value_heads, -1))
 
 
 # ------------------------------------------- the per-channel gate (Kimi KDA)

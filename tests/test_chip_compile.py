@@ -65,6 +65,15 @@ def _kernel_names_and_results(text):
                       r'custom_call_target="tpu_custom_call"', text)
 
 
+def _under_scope(text, scope):
+    """(instruction name, first result) of everything a compiled module
+    holds under a ``jax.named_scope``, as ``scope_ms_per_step`` joins a
+    trace with the program's op map."""
+    from ray_tpu.util import tracing
+    return [(name, op["shape"]) for name, op in tracing.op_map(text).items()
+            if scope in op.get("scope", "")]
+
+
 def _assert_the_sorted_passes_are_only_the_layers_work(text, rows, slots):
     """What ``ops/moe.dropless_experts`` spares the compiler by telling it
     what the sort makes true (PR 49), read off a compiled module: the
@@ -267,40 +276,71 @@ def test_flash_attention_at_qwen3_next_train_shape(v5e, fn):
 
 def _rule_loss(q, k, v, g, beta):
     from ray_tpu.ops.delta_rule import gated_delta_rule
-    return gated_delta_rule(q, k, v, g, beta)[0].astype(jnp.float32).sum()
+    with jax.named_scope("gdn_rule"):
+        o, _ = gated_delta_rule(q, k, v, g, beta)
+    return o.astype(jnp.float32).sum()
 
 
-@pytest.mark.parametrize("dtype", [
-    pytest.param(jnp.bfloat16, id="bf16_in_kernels"),
-    pytest.param(jnp.float32, id="float32_in_xla")])
+def _rule_loss_qkv(qkv, g, beta):
+    """The rule as ``models/qwen3_next._gdn_mixer`` calls it, on the
+    conv's output whole and unnormed, under the scope ``gdn.rule_ms``
+    reads."""
+    from ray_tpu.ops.delta_rule import gated_delta_rule_qkv
+    with jax.named_scope("gdn_rule"):
+        o, _ = gated_delta_rule_qkv(qkv, g, beta, 16, 128)
+    return o.astype(jnp.float32).sum()
+
+
+_RULE_Q_K_V = [(2, 8192, 16, 128)] * 2 + [(2, 8192, 32, 128)]
+_RULE_QKV = [(2, 8192, 8192)]
+
+
+@pytest.mark.parametrize("loss,operands,dtype", [
+    pytest.param(_rule_loss_qkv, _RULE_QKV, jnp.bfloat16,
+                 id="bf16_whole_in_kernels"),
+    pytest.param(_rule_loss, _RULE_Q_K_V, jnp.bfloat16,
+                 id="bf16_in_kernels"),
+    pytest.param(_rule_loss_qkv, _RULE_QKV, jnp.float32,
+                 id="float32_in_xla")])
 def test_the_delta_rule_compiles_at_qwen3_next_train_shape(v5e, monkeypatch,
+                                                           loss, operands,
                                                            dtype):
-    """``ops/delta_rule.gated_delta_rule`` forward and backward at 2 x
-    8,192 positions, 16 key and 32 value heads of 128, on a TPU.  In bf16
-    (the cell) four Mosaic kernels under their own names, which
-    ``gdn.rule_kernel_ms`` reads and the ``tpu_custom_call`` metrics do
-    not: the solve, what follows it, and each one's backward; no scan is
-    left, of a chunk's (64, 64) matrices only the solved systems ``T``
-    and their cotangent reach HBM, the R = 2 heads' side by side in 128
-    lanes (``f32[32,128,64,128]``, 134 MB), and the temporaries stay under
-    the 1.5 GB that the XLA form's spans were held to (the states that
-    enter the 128 chunks, kept for the backward, are 537 MB of them).
-    Float32 activations take the XLA form at the same shape: no kernel,
-    two scans each both ways, a span's solved systems."""
+    """``ops/delta_rule.gated_delta_rule_qkv`` (the cell's call: the
+    conv's (2, 8192, 8192) output whole) and ``gated_delta_rule`` (three
+    normed arrays) forward and backward at 2 x 8,192 positions, 16 key and
+    32 value heads of 128, on a TPU.  In bf16 four Mosaic kernels under
+    their own names, which ``gdn.rule_kernel_ms`` reads and the
+    ``tpu_custom_call`` metrics do not: the solve, what follows it, and
+    each one's backward; no scan is left, of a chunk's (64, 64) matrices
+    only the solved systems ``T`` and their cotangent reach HBM, the R = 2
+    heads' side by side in 128 lanes (``f32[32,128,64,128]``, 134 MB), and
+    the temporaries stay under the 1.5 GB that the XLA form's spans were
+    held to (the states that enter the 128 chunks, kept for the backward,
+    are 537 MB of them).  Of the conv's whole output the kernels read q,
+    k and v where they lie and norm what they load: under ``gdn_rule``
+    XLA makes no float32 array the size of q (the parent's l2 norms:
+    9.5 ms a step) and no slice the size of v (2.2 ms), and puts the
+    cotangent together in one fusion.  Float32 activations take the XLA
+    form at the same shape: no kernel, two scans each both ways, a span's
+    solved systems, and XLA's norms round them."""
     import json
     from pathlib import Path
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    shapes = [((2, 8192, 16, 128), dtype)] * 2 \
-        + [((2, 8192, 32, 128), dtype)] \
+    shapes = [(s, dtype) for s in operands] \
         + [((2, 8192, 32), jnp.float32)] * 2
     args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
-    compiled = jax.jit(jax.grad(_rule_loss, argnums=(0, 1, 2, 3, 4))) \
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(len(args))))) \
         .lower(*args).compile()
     text = compiled.as_text()
+    under_rule = _under_scope(text, "gdn_rule")
+    assert len(under_rule) > 20
+    xlas_norms = [name for name, shape in under_rule
+                  if shape == "f32[2,8192,2048]"]
     if dtype == jnp.float32:
         assert "tpu_custom_call" not in text
         assert text.count(" while(") >= 4          # two scans, each both ways
         assert "f32[2,16,2,16,64,64]" in text      # a span's solved systems
+        assert xlas_norms
         return
     kernels = _kernel_names_and_results(text)
     assert sorted((name.split(".")[0], shape) for name, shape in kernels) == [
@@ -318,6 +358,12 @@ def test_the_delta_rule_compiles_at_qwen3_next_train_shape(v5e, monkeypatch,
     assert " while(" not in text
     assert not re.search(r"f32\[[\d,]*,64,64\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    assert not xlas_norms
+    assert not [name for name, shape in under_rule
+                if name.startswith("slice") and shape == "bf16[2,8192,4096]"]
+    if loss is _rule_loss_qkv:      # the cotangent of qkv: one fusion
+        assert len([name for name, shape in under_rule if "fusion" in name
+                    and shape == "bf16[2,8192,8192]"]) == 1, under_rule
 
 
 def _conv_loss(x, w):
@@ -1302,8 +1348,13 @@ def test_qwen3_next_step_reads_its_experts_in_their_stacks(v5e, monkeypatch):
     the inner scan); the attention layer's stack is one layer, a
     ``bitcast`` of its parameter on both sides, which the optimizer's
     fusions ``convert`` into.  Every grouped matmul keeps the result its
-    share's metric knows it by, and the temporaries are 9,424,205,824 B
-    (the parent: 9,693,356,544) to two megabytes.  ~70 s."""
+    share's metric knows it by.  Since PR 70 the rule takes the conv's
+    output whole: in the step, under the block's checkpoint, the solve
+    stands once, the kernel that follows twice (the pass and its
+    recomputation) and each backward once, XLA makes no float32 array
+    the size of q and no slice the size of v under ``gdn_rule``, and the
+    temporaries are 8,819,227,648 B (PR 68's step: 9,359,284,736; PR
+    66's 9,424,205,824) to two megabytes.  ~70 s."""
     import json
     from pathlib import Path
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -1330,8 +1381,17 @@ def test_qwen3_next_step_reads_its_experts_in_their_stacks(v5e, monkeypatch):
     # 10 of 512: once in each loop of the inner scan, twice for the
     # attention layer (PR 68; the temporaries read 9,359,284,736 B)
     _assert_the_router_picks_in_vmem(text, 16384, 512, calls=4)
+    assert sorted(name.split(".")[0] for name, _
+                  in _kernel_names_and_results(text) if "delta_rule" in name
+                  ) == ["delta_rule_bwd", "delta_rule_fwd", "delta_rule_fwd",
+                        "delta_rule_solve", "delta_rule_solve_bwd"]
+    under_rule = _under_scope(text, "gdn_rule")
+    assert len(under_rule) > 100
+    assert not [name for name, shape in under_rule
+                if shape == "f32[2,8192,2048]"
+                or name.startswith("slice") and shape == "bf16[2,8192,4096]"]
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes <= 9_424_205_824 + 2 * 2 ** 20, \
+    assert mem.temp_size_in_bytes <= 8_819_227_648 + 2 * 2 ** 20, \
         mem.temp_size_in_bytes
 
 

@@ -430,6 +430,85 @@ def test_the_mixers_conv_in_its_kernels_under_a_checkpoint(monkeypatch):
                                    err_msg=key)
 
 
+# ----------------------------------------- the rule takes the conv's output
+def _norms_and_slices(qkv, g, beta, heads, dk, chunk):
+    """The rule's call as it stood in ``_gdn_mixer`` before
+    ``ops/delta_rule.gated_delta_rule_qkv``: XLA's l2 norms of q and k,
+    v's slice, three arrays handed over."""
+    from ray_tpu.ops.delta_rule import gated_delta_rule, l2norm_heads
+    (B, T, _), H, kw = qkv.shape, g.shape[-1], heads * dk
+    q = l2norm_heads(qkv[..., :kw], heads) * dk ** -0.5
+    k = l2norm_heads(qkv[..., kw:2 * kw], heads)
+    v = qkv[..., 2 * kw:].reshape(B, T, H, -1)
+    return gated_delta_rule(q.astype(qkv.dtype).reshape(B, T, heads, dk),
+                            k.astype(qkv.dtype).reshape(B, T, heads, dk),
+                            v, g, beta, chunk=chunk)
+
+
+@pytest.mark.parametrize("form", ["the_xla_form", "the_kernels"])
+def test_the_mixer_with_the_rules_entry_is_the_norms_and_slices(monkeypatch,
+                                                                form):
+    """``_gdn_mixer`` under the DeltaNet block's checkpoint and
+    ``jax.grad`` with ``gated_delta_rule_qkv`` beside the same mixer with
+    the norms and slices written out.  As the CPU takes the entry
+    (float32, 8-wide heads: the XLA form, unchanged) the two are the same
+    bits, loss and every gradient.  As the cell takes it (bf16, 2 key and
+    4 value heads of 128, two chunks of 64; the kernels' interpret mode
+    here) the solve runs ONCE (its ``T`` is what the checkpoint keeps),
+    the kernel that follows twice (the pass and its recomputation), each
+    backward once, and everything stays the written-out lines' to bf16's
+    rounding."""
+    from ray_tpu.ops import delta_rule
+    kernels = form == "the_kernels"
+    cfg = dataclasses.replace(CFG, gdn_key_dim=128, gdn_value_dim=128,
+                              rule_chunk=64, dtype=jnp.bfloat16) \
+        if kernels else CFG
+    lp = _layer(random_tree(cfg, seed=9))
+    u = jax.random.normal(jax.random.key(16), (2, 128, cfg.n_embd)) \
+        .astype(cfg.dtype)
+    probe = jax.random.normal(jax.random.key(17), u.shape)
+    kept = jax.checkpoint_policies.save_only_these_names(delta_rule.INVERSE)
+
+    def loss(u, lp):
+        out = jax.checkpoint(lambda u, lp: qn._gdn_mixer(u, lp, cfg)[0],
+                             policy=kept)(u, lp)
+        return (out.astype(jnp.float32) * probe).sum()
+
+    run = jax.value_and_grad(loss, argnums=(0, 1))
+    if kernels:
+        monkeypatch.setattr(delta_rule, "STEP_CHUNKS", 1)
+        monkeypatch.setattr(
+            delta_rule, "gated_delta_rule_qkv",
+            lambda qkv, g, beta, heads, dk, chunk:
+            delta_rule.delta_rule_chunks_qkv(
+                qkv, g, beta, jnp.zeros((2, 4, 128, 128)), heads, dk,
+                chunk=chunk, interpret=True))
+        program = str(jax.make_jaxpr(run)(u, lp))
+        calls = re.findall(r"jit\[\s*name=(delta_rule_\w+)", program)
+        assert sorted(calls) == [
+            "delta_rule_bwd", "delta_rule_fwd", "delta_rule_fwd",
+            "delta_rule_solve", "delta_rule_solve_bwd"]
+    got, got_grads = run(u, lp)
+    monkeypatch.setattr(delta_rule, "gated_delta_rule_qkv", _norms_and_slices)
+    want, want_grads = run(u, lp)
+    got_grads, want_grads = _flat(got_grads), _flat(want_grads)
+    assert np.abs(np.float32(want_grads[
+        next(key for key in want_grads if "in_proj_qkvz" in key)])).max() > 0
+    if not kernels:
+        assert float(got) == float(want)
+        for key, b in want_grads.items():
+            np.testing.assert_array_equal(np.float32(got_grads[key]),
+                                          np.float32(b), err_msg=key)
+        return
+    assert abs(float(got) - float(want)) < 0.02 * abs(float(want)) + 0.05
+    for key, b in want_grads.items():
+        a, b = np.float32(got_grads[key]), np.float32(b)
+        if np.abs(b).max() == 0:
+            continue                    # the mixer reads none of the MoE's
+        np.testing.assert_allclose(a, b, atol=0.03 * np.abs(b).max(),
+                                   err_msg=key)
+
+
 # ------------------------------------------ the gated output norm's entry
 def _four_lines(o, z, scale, eps):
     """The mixer's gated output norm as it stood in ``_gdn_mixer`` before
