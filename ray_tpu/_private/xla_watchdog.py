@@ -26,10 +26,17 @@ context managers wrapped around the steady-state jit dispatches
   sit OUTSIDE the regions and stay legal.
 
 - **Zero steady-state recompiles.**  Every backend compile is observed
-  through ``jax.monitoring`` (the ``/jax/core/compile/
-  backend_compile_duration`` event fires once per distinct program,
-  never on a cache hit) and charged to the innermost active region's
-  owner.  A region owner exceeding ``budget +
+  through ``jax.monitoring``: the ``/jax/core/compile/
+  backend_compile_duration`` event wraps ``compile_or_get_cached``
+  (``jax/_src/interpreters/pxla.py``, jax 0.9.0), so it fires once per
+  distinct program a process builds, on a hit in the PERSISTENT
+  compilation cache too, and is silent only on jit's in-memory hit (the
+  program was built in this process already).  The budget counts both
+  as a compile: either way the step path stalled for a program it did
+  not have.  The oracle registers no listener of its own: it subscribes
+  to ``util.tracing``'s (``listen_to_compiles``), the one in the
+  process, armed or not.  Each compile is charged to the innermost
+  active region's owner.  A region owner exceeding ``budget +
   RAY_TPU_XLA_WATCHDOG_WARMUP`` raises on region exit — generalizing
   the LLM engine's ad-hoc bounded-compiles assertion into a declared
   contract (``lock_watchdog.COMPILE_BUDGETS``; jaxlint proves the
@@ -39,7 +46,7 @@ context managers wrapped around the steady-state jit dispatches
   recorder.
 
 Zero-cost when disarmed: ``compile_budget`` is a no-op context
-manager, nothing is interposed, no listener does any work.
+manager, nothing is interposed and nothing subscribes.
 """
 
 from __future__ import annotations
@@ -51,8 +58,6 @@ import traceback
 from typing import Dict, List, Tuple
 
 from ray_tpu._private.lock_watchdog import COMPILE_BUDGETS
-
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 class XlaHygieneViolation(RuntimeError):
@@ -173,12 +178,10 @@ def _install_interposers() -> None:
         if _installed:
             return
         import jax
-        import jax.monitoring
         import numpy as np
 
-        jax.monitoring.register_event_duration_secs_listener(
-            lambda event, _dur, **kw: (
-                _note_compile() if event == _COMPILE_EVENT else None))
+        from ray_tpu.util import tracing
+        tracing.listen_to_compiles(_note_compile)
 
         orig_device_get = jax.device_get
 

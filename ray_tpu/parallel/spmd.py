@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from functools import partial
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -23,6 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.parallel import mesh as mesh_lib
 from ray_tpu.parallel.mesh import MeshConfig, Rules, TRANSFORMER_RULES
+from ray_tpu.util import tracing
 
 
 @dataclass
@@ -118,7 +119,14 @@ def state_specs(state: TrainState, rules: Rules) -> TrainState:
 
 @dataclass
 class SpmdProgram:
-    """A compiled distributed training step and its placement metadata."""
+    """A compiled distributed training step and its placement metadata.
+
+    ``init_fn`` and ``step_fn`` are plain functions, what a trainer calls
+    (each carries its set-up span and the step its compile budget).
+    Whatever needs the ``jax.jit`` object goes through ``jitted_init`` /
+    ``jitted_step``: ``.lower()`` / ``.compile()``, and
+    ``jax.eval_shape(program.jitted_init, key)``, which gives the state's
+    shapes WITH their shardings (off a plain function it gives none)."""
     mesh: Mesh
     mesh_config: MeshConfig
     init_fn: Callable[[jax.Array], TrainState]     # sharded init
@@ -128,15 +136,20 @@ class SpmdProgram:
     # the jax.jit object behind step_fn, for .lower()/.compile() (what
     # the step was compiled to: kernels, collectives, memory)
     jitted_step: Any = None
+    # the jax.jit object behind init_fn: ``jax.eval_shape(jitted_init,
+    # key)`` is the state as shapes WITH its shardings
+    jitted_init: Any = None
     # (state, batch) as shapes, kept at the step's first call
     abstract_args: Optional[tuple] = None
+    # set-up span totals, name -> [count, seconds] (tracing.setup_span):
+    # ``train.init`` a call of init_fn, ``train.compile`` the step's first
+    span_s: Dict[str, list] = field(default_factory=dict)
 
     def op_map(self) -> Dict[str, dict]:
         """What each instruction of the compiled step is
         (``tracing.op_map``): lowers with the first call's shapes and
         compiles, both hits in jax's in-memory caches in the process that
         ran the step, then parses the module's text."""
-        from ray_tpu.util import tracing
         if self.abstract_args is None:
             raise RuntimeError("the step has not been called yet: its "
                                "batch's shape is not known")
@@ -172,6 +185,7 @@ def build_train_program(
     ``accum_dtype`` sets the accumulator dtype (default: the grad dtype —
     pass ``jnp.bfloat16`` to halve accumulator HBM when params are f32).
     """
+    tracing.listen_to_compiles()
     optimizer = optimizer or default_optimizer()
     if mesh is None:
         mesh_config = (mesh_config or MeshConfig()).resolved(
@@ -202,7 +216,14 @@ def build_train_program(
         return TrainState(step=jnp.zeros((), jnp.int32), params=params,
                           opt_state=optimizer.init(params))
 
-    init_fn = jax.jit(_init, out_shardings=state_sh)
+    jitted_init = jax.jit(_init, out_shardings=state_sh)
+
+    def init_fn(rng: jax.Array) -> TrainState:
+        # once in a program's life, so every call carries the span.  A
+        # plain function: ``jax.eval_shape`` reads the state's shardings
+        # off ``program.jitted_init``, not off this
+        with tracing.setup_span("train.init", program.span_s, "train.init"):
+            return jitted_init(rng)
 
     def _loss_and_reported(params: Any, batch: Any):
         with _collect_step_metrics() as reported:
@@ -296,23 +317,29 @@ def build_train_program(
     from ray_tpu._private.xla_watchdog import compile_budget
     step_budget = compile_budget("train.step")
 
+    def first_step(state: TrainState, batch: Any):
+        # keep the shapes the step is called with, so that
+        # tracing.op_maps() can say what its operations are.  A reference
+        # and nothing else: nothing is lowered or parsed until someone asks
+        program.abstract_args = tracing.abstract((state, batch))
+        tracing.register_program("train.step", step_fn,
+                                 program.abstract_args)
+        # what building the step costs, to the enqueue of its first run
+        with tracing.setup_span("train.compile", program.span_s,
+                                "train.step"), step_budget:
+            return step_fn(state, batch)
+
     def guarded_step(state: TrainState, batch: Any):
         if program.abstract_args is None:
-            # the first call: keep the shapes it is called with, so that
-            # tracing.op_maps() can say what the step's operations are.
-            # A reference and nothing else: nothing is lowered or parsed
-            # until someone asks
-            from ray_tpu.util import tracing
-            program.abstract_args = tracing.abstract((state, batch))
-            tracing.register_program("train.step", step_fn,
-                                     program.abstract_args)
+            return first_step(state, batch)
         with step_budget:
             return step_fn(state, batch)
 
     program = SpmdProgram(
         mesh=mesh, mesh_config=mesh_config, init_fn=init_fn,
         step_fn=guarded_step, state_shardings=state_sh,
-        batch_sharding=batch_sh, jitted_step=step_fn)
+        batch_sharding=batch_sh, jitted_step=step_fn,
+        jitted_init=jitted_init)
     return program
 
 

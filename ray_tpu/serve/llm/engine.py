@@ -78,7 +78,7 @@ from ray_tpu.serve.llm.scheduler import (FAILED, FINISHED, RUNNING,
                                          Sequence)
 from ray_tpu.util import metrics_catalog as mcat
 from ray_tpu.util import tracing
-from ray_tpu.util.tracing import hot_span
+from ray_tpu.util.tracing import hot_span, setup_span
 
 logger = rtlog.get("serve.llm.engine")
 
@@ -221,7 +221,12 @@ class LLMEngine:
         self.runner = ModelRunner(cfg, params)
         # what a sequence of this model keeps, the cache holds: blocks, and
         # beside them whatever planes its module declares (kv_cache.PLANES)
-        self.cache = PagedKVCache.for_engine(cfg, self.runner.family.kept)
+        # (a set-up span: the fills of the device pool are programs too,
+        # and it ends when they are enqueued)
+        with setup_span("llm.cache.build", self.runner.span_s,
+                        "llm.cache") as span:
+            self.cache = PagedKVCache.for_engine(cfg, self.runner.family.kept)
+            span.set(bytes=self.cache.pool.nbytes)
         self.runner.cache = self.cache
         self.sched = IterationScheduler(cfg.max_num_seqs,
                                         cfg.max_prefill_tokens,

@@ -851,6 +851,7 @@ class DevicePool:
         self.planes = planes_of(described)
         self._pool_lock = threading.Lock()
         self._array = None                             # guarded by: _pool_lock
+        self.nbytes = _nbytes(described)    # on the device, every plane
         self.fill(0)
 
     def abstract(self):

@@ -133,7 +133,8 @@ from ray_tpu.serve.llm.kv_cache import DevicePool, Kept, PagedKVCache, \
     _declared, block_slots_reserved, fold_reads, handed_to_forward, \
     held_rows, kept_by, rows_written, slots_reserved, staged_rows, \
     stepped_by_forward, window_reads, write_rows
-from ray_tpu.util.tracing import abstract, hot_span, register_program
+from ray_tpu.util.tracing import abstract, hot_span, \
+    listen_to_compiles, register_program, setup_span
 
 logger = rtlog.get("serve.llm.runner")
 
@@ -396,13 +397,14 @@ class ModelRunner:
         # hot-span totals, name -> [count, seconds]; the engine shares
         # this dict with its own spans (LLMEngine.stats()["span_s"])
         self.span_s: dict = {}
+        listen_to_compiles()
         if params is None:
             params = self._load_params()
         # once in a runner's life, like llm.compile absent from a window;
         # bytes_out - bytes_in of a tree that was in its serving type
         # already: the table held a second time for the gather, or 0
-        with hot_span("llm.weights.prepare", self.span_s,
-                      bytes_in=tree_bytes(params)) as span:
+        with setup_span("llm.weights.prepare", self.span_s, "llm.weights",
+                        bytes_in=tree_bytes(params)) as span:
             self.params = jax.block_until_ready(serving_params(
                 params, self.mcfg.dtype, family.wide_params,
                 family.row_tables))
@@ -1038,15 +1040,17 @@ class ModelRunner:
 
     def _note_shape(self, program: str, bucket: int):
         """A context for the call that follows: an ``llm.compile`` span
-        around the first call of a (program, bucket), nothing after."""
+        around the first call of a (program, bucket), nothing after.  It
+        is a set-up span: what jax says of tracing, lowering and compiling
+        inside it is charged to ``llm.<program>`` and written on it."""
         key = (program, bucket)
         if key in self._shapes_seen:
             return _SEEN
         self._shapes_seen.add(key)
         self.compiles += 1
         logger.info("compiling %s program (total %d)", key, self.compiles)
-        return hot_span("llm.compile", self.span_s, program=program,
-                        bucket=bucket)
+        return setup_span("llm.compile", self.span_s, "llm." + program,
+                          program=program, bucket=bucket)
 
     # --------------------------------------------------------------- sampling
     @staticmethod

@@ -542,6 +542,27 @@ CATALOG: Dict[str, dict] = {
         kind="gauge", tag_keys=("device", "kind"),
         description="HBM allocator capacity (PJRT memory_stats)",
         emitted_by="driver collect (device_memory_gauges)"),
+    # --- set-up, seen from inside (util/tracing.py's listener) --------------
+    "rtpu_xla_compile_seconds": dict(
+        kind="histogram", tag_keys=("stage", "program"),
+        buckets=LATENCY_BUCKETS,
+        description="What building a program cost, as jax.monitoring times "
+                    "it on the compiling thread, by stage (trace | lower | "
+                    "backend: XLA's compile or the persistent cache's read "
+                    "and load | cache_read: the read alone, inside backend "
+                    "| total: wall time of the outermost set-up span) and "
+                    "by the program of the innermost set-up span open "
+                    "(llm.decode | llm.prefill | llm.prefill_chunk | "
+                    "llm.fold | llm.weights | llm.cache | train.step | "
+                    "train.init | other: outside every such span)",
+        emitted_by="every process that builds a step program"),
+    "rtpu_xla_cache_lookups_total": dict(
+        kind="counter", tag_keys=("result", "program"),
+        description="Lookups in jax's persistent compilation cache by how "
+                    "they ended (hit | miss: the lookup ended without a hit "
+                    "and XLA compiled), by program as above; a compile that "
+                    "never asked the cache counts under neither",
+        emitted_by="every process that builds a step program"),
 }
 
 

@@ -56,6 +56,17 @@ of the keys: PERF.md section 3).
 ``hot_span`` and ``register_program`` / ``op_map`` / ``op_maps`` are the
 two things a hot path may use from this module.
 
+Set-up is seen from inside.  ``listen_to_compiles()`` registers the
+process's ONE listener with ``jax.monitoring`` (the places that build step
+programs call it; the XLA watchdog subscribes to it and registers none of
+its own), and a ``setup_span`` is a ``hot_span`` round a piece of the
+program's own set-up that takes what jax says of tracing, lowering,
+compiling and its persistent cache while it is open: as attributes on the
+span and as the catalog's ``rtpu_xla_compile_seconds`` /
+``rtpu_xla_cache_lookups_total`` by ``program`` (PERF.md §3,
+perfbench/SETUP_TRACE.md).  It works at compile events only: a step after
+its first call runs none of it.
+
 Span context lives in a ``contextvars.ContextVar`` (not a bare
 ``threading.local``): each thread still has its own current span, and the
 context additionally flows into asyncio tasks scheduled from a thread
@@ -440,6 +451,160 @@ class hot_span:
         total = self.totals.setdefault(self.name, [0, 0.0])
         total[0] += 1
         total[1] += self.dur
+
+
+# ------------------------------------------------- set-up, seen from inside
+# JAX times its own compiles and tells whoever listens (``jax.monitoring``),
+# on the thread that compiles: each stage below when it starts (a scalar)
+# and when it ends (a duration), and what the persistent cache said.
+# ``backend_compile_duration`` wraps ``compile_or_get_cached``, so it ends on
+# a persistent-cache hit too and is silent only on jit's in-memory hit.
+_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_LOOKUP = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+# called, with nothing, each time a backend compile (or its load from the
+# persistent cache) ends, on the thread that compiled: the XLA watchdog
+_ON_COMPILE: List[Any] = []
+_LISTEN_LOCK = threading.Lock()
+
+
+def _catalog():
+    """The metrics catalog, or None where built-in series are off."""
+    from ray_tpu._private.config import GLOBAL_CONFIG
+    if not GLOBAL_CONFIG.metrics_enabled:
+        return None
+    from ray_tpu.util import metrics_catalog
+    return metrics_catalog
+
+
+class _Compiles(threading.local):
+    """The process's one listener on jax's compile events; what it holds
+    is each thread's own: the set-up spans open on it, innermost last,
+    and what jax has said of the compile in progress."""
+
+    listening = False           # the process's, under _LISTEN_LOCK
+
+    def __init__(self):
+        self.spans: List["setup_span"] = []
+        self.open = 0           # jax's timed stages, one inside another
+        self.looked = self.hit = False
+
+    def started(self, event: str, _start: float, **_kw) -> None:
+        if event in _STAGES:
+            self.open += 1
+
+    def said(self, event: str, **_kw) -> None:
+        if event == _CACHE_LOOKUP:
+            self.looked, self.hit = True, False
+        elif event == _CACHE_HIT:
+            self.hit = True
+
+    def took(self, event: str, seconds: float, **_kw) -> None:
+        stage = _STAGES.get(event)
+        if stage is None:
+            if event == _CACHE_READ:    # a part of the backend's seconds
+                self.charge("cache_read", seconds)
+            return
+        self.open = max(0, self.open - 1)
+        # a stage inside another (a jitted function traced inside a trace,
+        # an operation a trace runs eagerly) is in the outer one's seconds
+        if not self.open:
+            self.charge(stage, seconds)
+        if stage == "backend":
+            # a miss is a lookup that ended without a hit: jax's own
+            # ``cache_misses`` fires only where an entry is WRITTEN, which
+            # the cache's size and time thresholds decide
+            if self.looked:
+                self.looked = False
+                self.count("hit" if self.hit else "miss")
+            for tell in _ON_COMPILE:
+                tell()
+
+    def program(self) -> str:
+        return self.spans[-1].program if self.spans else "other"
+
+    def charge(self, stage: str, seconds: float) -> None:
+        if self.spans:
+            self.spans[-1].seen[stage + "_s"] += seconds
+        mcat = _catalog()
+        if mcat:
+            mcat.get("rtpu_xla_compile_seconds").observe(
+                seconds, tags={"stage": stage, "program": self.program()})
+
+    def count(self, result: str) -> None:
+        if self.spans:
+            self.spans[-1].seen[
+                "cache_hits" if result == "hit" else "cache_misses"] += 1
+        mcat = _catalog()
+        if mcat:
+            mcat.get("rtpu_xla_cache_lookups_total").inc(
+                tags={"result": result, "program": self.program()})
+
+
+_COMPILES = _Compiles()
+
+
+def listen_to_compiles(on_compile: Any = None) -> None:
+    """Register the process's one listener with ``jax.monitoring``, once
+    (the places that build step programs call this: they have jax
+    imported); ``on_compile``, if given, is called with nothing after
+    every backend compile from then on, on the thread that compiled."""
+    with _LISTEN_LOCK:
+        if on_compile is not None and on_compile not in _ON_COMPILE:
+            _ON_COMPILE.append(on_compile)
+        if _Compiles.listening:
+            return
+        import jax.monitoring as monitoring
+        monitoring.register_scalar_listener(_COMPILES.started)
+        monitoring.register_event_listener(_COMPILES.said)
+        monitoring.register_event_duration_secs_listener(_COMPILES.took)
+        _Compiles.listening = True
+
+
+class setup_span(hot_span):
+    """A ``hot_span`` round a piece of the program's own set-up (weights,
+    the cache's pool, the first call of a step program): while it is the
+    innermost one open on its thread, what jax says of compiles is charged
+    to its ``program`` (the tag of ``rtpu_xla_compile_seconds`` and
+    ``rtpu_xla_cache_lookups_total``; a short closed set, PERF.md §3), and
+    on exit the span carries what it saw as attributes: ``trace_s``,
+    ``lower_s``, ``backend_s`` (of which ``cache_read_s``), ``cache_hits``,
+    ``cache_misses``.  Its own seconds less the first three are the enqueue
+    and the Python round it.  The outermost span alone observes its wall
+    time under ``stage="total"``, so the sum over programs counts nothing
+    twice.  ``program`` is positional only: ``llm.compile`` has an
+    attribute of that name."""
+
+    __slots__ = ("program", "seen")
+
+    def __init__(self, name: str, totals: Dict[str, list], program: str, /,
+                 **attrs):
+        super().__init__(name, totals, **attrs)
+        self.program = program
+        self.seen = {"trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+                     "cache_read_s": 0.0, "cache_hits": 0, "cache_misses": 0}
+
+    def __enter__(self) -> "setup_span":
+        _COMPILES.spans.append(self)
+        return super().__enter__()
+
+    def __exit__(self, *exc) -> None:
+        self.set(**self.seen)
+        super().__exit__(*exc)
+        spans = _COMPILES.spans
+        spans.remove(self)
+        if spans:               # inside another: the outer one's wall time
+            return
+        mcat = _catalog()
+        if mcat:
+            mcat.get("rtpu_xla_compile_seconds").observe(
+                self.dur, tags={"stage": "total", "program": self.program})
 
 
 def profile_event_lists(out_dir: str):

@@ -1061,7 +1061,7 @@ def _train_program(cell_name, v5e):
         mesh=mesh, mesh_config=mc)
     state = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e),
-        jax.eval_shape(prog.init_fn, jax.random.key(0)))
+        jax.eval_shape(prog.jitted_init, jax.random.key(0)))
     batch = {k: jax.ShapeDtypeStruct((spec["batch"], spec["seq"]), jnp.int32,
                                      sharding=v5e)
              for k in ("inputs", "targets")}

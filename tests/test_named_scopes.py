@@ -68,7 +68,7 @@ def _step_scopes(batch_rows: int, **kwargs) -> set:
         init_params_fn=lambda rng: gpt2.init_params(rng, CFG),
         mesh=mesh_lib.build_mesh(mc, jax.devices()[:1]), mesh_config=mc,
         **kwargs)
-    state = jax.eval_shape(prog.init_fn, jax.random.key(0))
+    state = jax.eval_shape(prog.jitted_init, jax.random.key(0))
     batch = {k: jax.ShapeDtypeStruct((batch_rows, 16), jnp.int32)
              for k in ("inputs", "targets")}
     return _scopes(prog.jitted_step.lower(state, batch))

@@ -38,7 +38,7 @@ def _program(mod, cfg):
 
 
 def _shapes(prog, seq, batch=2):
-    state = jax.eval_shape(prog.init_fn, jax.random.key(0))
+    state = jax.eval_shape(prog.jitted_init, jax.random.key(0))
     tokens = S((batch, seq), jnp.int32)
     return state, {"inputs": tokens, "targets": tokens}
 

@@ -42,7 +42,7 @@ def test_a_scalar_loss_steps_as_it_always_did(accum_steps):
     step beyond the state's leaves and those three."""
     cfg = gpt2.tiny()
     prog = _program(gpt2, cfg, accum_steps=accum_steps)
-    state = jax.eval_shape(prog.init_fn, jax.random.key(0))
+    state = jax.eval_shape(prog.jitted_init, jax.random.key(0))
     new, metrics = jax.eval_shape(prog.jitted_step, state, _batch(cfg))
     assert set(metrics) == STEP_KEYS
     text = prog.jitted_step.lower(state, _batch(cfg)).as_text()
@@ -86,7 +86,7 @@ def test_reporting_outside_a_step_is_a_no_op():
 
 def test_the_expert_layers_scopes_name_the_step():
     prog = _program(llama, MOE)
-    state = jax.eval_shape(prog.init_fn, jax.random.key(0))
+    state = jax.eval_shape(prog.jitted_init, jax.random.key(0))
     text = prog.jitted_step.lower(state, _batch(MOE)).as_text(debug_info=True)
     names = "\n".join(re.findall(r'loc\("([^"]*)"', text))
     for scope in ("qk_norm", "rope", "router", "moe_dispatch", "moe_experts",
