@@ -159,9 +159,10 @@ def remat_block(block: Callable, policy: str, flash_runs: bool) -> Callable:
 
 
 def experts_in_place(experts: Dict[str, jax.Array]) -> Tuple[jax.Array, ...]:
-    """A stack's expert leaves ``w_gate``, ``w_up``, ``w_down``, each
-    (layers, H, ...), as a training scan's body takes them beside its own
-    slice: whole, ``(layers x H, ...)`` (a bitcast), gradient stopped.
+    """A stack's expert leaves ``w_gate`` (where the family's expert has
+    one: ``ops/moe.dropless_experts``), ``w_up``, ``w_down``, each (layers,
+    H, ...), as a training scan's body takes them beside its own slice:
+    whole, ``(layers x H, ...)`` (a bitcast), gradient stopped.
 
     The grouped matmuls are kernels, and a kernel's operand is a buffer: a
     layer's experts sliced out of their stack by the scan were copied whole
@@ -173,7 +174,8 @@ def experts_in_place(experts: Dict[str, jax.Array]) -> Tuple[jax.Array, ...]:
     dropped from both loops, and is where the layer's gradient goes, in the
     layer's shape.  A constant of both loops, not a residual a layer."""
     return tuple(lax.stop_gradient(experts[name]).reshape(
-        -1, *experts[name].shape[2:]) for name in ("w_gate", "w_up", "w_down"))
+        -1, *experts[name].shape[2:]) for name in ("w_gate", "w_up", "w_down")
+        if name in experts)
 
 
 def split_batch(batch: Dict[str, jax.Array]) -> Tuple[jax.Array, jax.Array]:

@@ -320,12 +320,9 @@ def _gated_norm(y: jax.Array, z: jax.Array, lp: Params,
     """RMSNorm over each group of ``y * silu(z)`` (the gate first:
     ``mamba_norm_before_gate`` false), float32 in, cfg.dtype out."""
     with jax.named_scope("ssm_norm"):
-        lead = y.shape[:-2]
-        g = y.reshape(*lead, cfg.d_ssm) * jax.nn.silu(z)
-        g = g.reshape(*lead, cfg.ssm_groups, cfg.d_ssm // cfg.ssm_groups)
-        g = g * lax.rsqrt((g * g).mean(-1, keepdims=True) + cfg.rms_eps)
-        return (g.reshape(*lead, cfg.d_ssm)
-                * lp["ssm_norm"]["scale"]).astype(cfg.dtype)
+        return ssm.gate_then_group_norm(y, z, lp["ssm_norm"]["scale"],
+                                        cfg.ssm_groups, cfg.rms_eps,
+                                        cfg.dtype)
 
 
 def _ssm_out(g: jax.Array, lp: Params, cfg: FalconH1Config) -> jax.Array:

@@ -571,7 +571,7 @@ class HeldStats(NamedTuple):
 # block's size had not (PERF.md section 6, PR 62).
 _GMM_ROW_TILE = 512             # a megablox shape's rows are a multiple
 _GMM_ROW_TILES = (_GMM_ROW_TILE, 256)   # beside a split block, a whole one
-_GMM_TILES = (1024, 768, 512, 256, 128)
+_GMM_TILES = (1024, 768, 512, 384, 256, 128)
 
 
 def _gmm_vmem_bytes(rows: int, d: int, f: int, itemsize: int) -> int:
@@ -592,17 +592,23 @@ def gmm_tiling(m: int, d: int, f: int,
     a shape (the rows' gradient takes the last two swapped): the group's
     whole matrix beside 256 rows where VMEM holds that for operands of
     ``itemsize`` bytes, else 512 rows beside the largest of ``_GMM_TILES``
-    that divides each dimension.  None where none divides or ``m`` is no
-    multiple of 512 (the caller then takes ``ragged_dot``): a decode
-    step's 16-192 rows never come here."""
+    that divides each dimension.  A dimension that no 128-lane tile
+    divides (Nemotron-H's 1,856-wide experts: 29 x 64) is taken whole, as a
+    block may be whatever its width, where VMEM holds the tile that makes
+    (2,688 <-> 1,856: 512 rows, 384 of the 2,688, all 1,856).  None where
+    nothing fits or ``m`` is no multiple of 512 (the caller then takes
+    ``ragged_dot``): a decode step's 16-192 rows never come here."""
     def tile(dim):
-        return next((t for t in _GMM_TILES if dim % t == 0), None)
-    if m % _GMM_ROW_TILE or tile(d) is None or tile(f) is None:
+        return next((t for t in _GMM_TILES if dim % t == 0),
+                    None if dim % 16 else dim)
+    tile_d, tile_f = tile(d), tile(f)
+    if m % _GMM_ROW_TILE or tile_d is None or tile_f is None:
         return None
     split, whole = _GMM_ROW_TILES
-    if _gmm_vmem_bytes(whole, d, f, itemsize) <= _VMEM_DEFAULT - 2 ** 20:
-        return whole, d, f
-    return split, tile(d), tile(f)
+    for tiling in ((whole, d, f), (split, tile_d, tile_f)):
+        if _gmm_vmem_bytes(*tiling, itemsize) <= _VMEM_DEFAULT - 2 ** 20:
+            return tiling
+    return None
 
 
 def gmm_visits(group_sizes, held: int, row_tile: int):
@@ -951,11 +957,18 @@ def choice_of_live_rows(expert_idx: jax.Array, live: jax.Array) -> jax.Array:
 
 
 def dropless_experts(x: jax.Array, expert_idx: jax.Array, weights: jax.Array,
-                     w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
-                     *, num_experts: int, first_held: int = 0,
-                     stack: Optional[tuple] = None):
+                     w_gate: Optional[jax.Array], w_up: jax.Array,
+                     w_down: jax.Array, *, num_experts: int,
+                     first_held: int = 0, stack: Optional[tuple] = None):
     """Each token through those of its chosen experts that are held here,
     weighted and summed -> (y (N, d), group_sizes (E,)).
+
+    Which expert runs is read from the call.  With ``w_gate``: SwiGLU,
+    three matrices, ``down(silu(gate(x)) * up(x))``.  With ``w_gate`` None:
+    the two-matrix form ``down(relu(up(x)) ** 2)`` (Nemotron-H's ``relu2``
+    experts, which have no gate matrix): two grouped matmuls forward and
+    four backward, and no zero or unit matrix in the gate's place.  What
+    follows holds for both but the hidden rows' formula.
 
     ``w_gate``, ``w_up`` (H, d, f) and ``w_down`` (H, f, d) are experts
     ``first_held .. first_held + H - 1`` of the ``num_experts`` the router
@@ -966,11 +979,12 @@ def dropless_experts(x: jax.Array, expert_idx: jax.Array, weights: jax.Array,
     back is its inverse).  Both are permutations of the assignments
     because a sort over an iota made them, so the gathers that carry the
     rows to the experts and back promise their indices and nothing is
-    filled, selected or scattered.  Three grouped matmuls run over the
-    held groups, and the rows are put back, a token's k slots leading,
-    and summed per token in float32.  The weight multiplies
-    the hidden rows ``silu(gate) * up`` in float32, inside the fusion that
-    makes them, and not the output rows: the down projection is linear,
+    filled, selected or scattered.  Three grouped matmuls (two) run over
+    the held groups, and the rows are put back, a token's k slots leading,
+    and summed per token in float32.  The weight multiplies the hidden
+    rows ``silu(gate) * up`` (``relu(up) ** 2``) in float32, inside the
+    fusion that makes them, and not the output rows: the down projection
+    is linear,
     so ``down(w h) = w down(h)``, and the combine is then the dispatch
     transposed (``_experts_to_rows``), whose gradient reads no output row.
     (A weight on the output rows has the gradient ``sum_d out * g``: a
@@ -986,11 +1000,11 @@ def dropless_experts(x: jax.Array, expert_idx: jax.Array, weights: jax.Array,
     (N, E, C) tensor exists and nothing is dropped among the held,
     whatever the imbalance.  ``group_sizes[i]`` counts the rows of expert
     ``first_held + i`` (mod E).  ``stack`` (a layer of a training scan):
-    ((the three leaves whole, each ``(layers x H, ...)`` with its gradient
-    stopped), this layer's index, traced): what ``grouped_matmul`` may read
-    in place of the slices.
+    ((the leaves whole in the arguments' order, three or two, each
+    ``(layers x H, ...)`` with its gradient stopped), this layer's index,
+    traced or not): what ``grouped_matmul`` may read in place of the slices.
     """
-    (n, d), k, (held, _, f) = x.shape, expert_idx.shape[1], w_gate.shape
+    (n, d), k, (held, _, f) = x.shape, expert_idx.shape[1], w_up.shape
     in_gate = in_up = in_down = None          # ``grouped_matmul``'s stack
     with jax.named_scope("moe_dispatch"):
         order, inverse, w_sorted, group_sizes = _sorted_assignments(
@@ -1002,13 +1016,18 @@ def dropless_experts(x: jax.Array, expert_idx: jax.Array, weights: jax.Array,
         if stack is not None:
             leaves, at = stack
             sizes = _sizes_in_stack(group_sizes, held, leaves[0].shape[0], at)
-            in_gate, in_up, in_down = ((whole, sizes) for whole in leaves)
+            in_gate, in_up, in_down = [None] * (3 - len(leaves)) + [
+                (whole, sizes) for whole in leaves]
     with jax.named_scope("moe_experts"):
-        gate = grouped_matmul(rows, w_gate.astype(x.dtype), group_sizes,
-                              in_gate)
+        gate = None if w_gate is None else grouped_matmul(
+            rows, w_gate.astype(x.dtype), group_sizes, in_gate)
         up = grouped_matmul(rows, w_up.astype(x.dtype), group_sizes, in_up)
-        hidden = (jax.nn.silu(gate.astype(jnp.float32))
-                  * up.astype(jnp.float32) * w_sorted[:, None])
+        if gate is None:
+            hidden = jnp.square(jax.nn.relu(up.astype(jnp.float32)))
+        else:
+            hidden = (jax.nn.silu(gate.astype(jnp.float32))
+                      * up.astype(jnp.float32))
+        hidden = hidden * w_sorted[:, None]
         out = grouped_matmul(hidden.astype(x.dtype), w_down.astype(x.dtype),
                              group_sizes, in_down)
     with jax.named_scope("moe_combine"):
@@ -1016,17 +1035,19 @@ def dropless_experts(x: jax.Array, expert_idx: jax.Array, weights: jax.Array,
     return y, group_sizes
 
 
-def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
-                     w_up: jax.Array, w_down: jax.Array, *, k: int,
+def dropless_moe_ffn(x: jax.Array, w_router: jax.Array,
+                     w_gate: Optional[jax.Array], w_up: jax.Array,
+                     w_down: jax.Array, *, k: int,
                      scoring: str = "softmax", norm_topk: bool = False,
                      select_bias: Optional[jax.Array] = None,
                      weight_scale: float = 1.0, first_held: int = 0,
                      choices: bool = False,
                      live: Optional[jax.Array] = None,
                      stack_at=None, stack: Optional[tuple] = None):
-    """Token-choice SwiGLU experts with no capacity: every token is
-    computed by each of its top-k experts that is held here, whatever the
-    imbalance.
+    """Token-choice experts with no capacity: every token is computed by
+    each of its top-k experts that is held here, whatever the imbalance.
+    SwiGLU experts, or with ``w_gate`` None the two-matrix ``relu ** 2``
+    ones (``dropless_experts``).
 
     x (N, d); w_router (d, E): the router always has its full width.
     w_gate, w_up (H, d, f); w_down (H, f, d): the H <= E experts held
@@ -1056,7 +1077,7 @@ def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
     the layer's own E either way.
     """
     n = x.shape[0]
-    num_experts, held = w_router.shape[-1], w_gate.shape[0]
+    num_experts, held = w_router.shape[-1], w_up.shape[0]
     if stack_at is not None:
         held = num_experts
     if scoring not in ("softmax", "sigmoid"):
@@ -1079,7 +1100,7 @@ def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
     else:
         y, group_sizes = dropless_experts(
             x, expert_idx + stack_at * num_experts, weights, w_gate, w_up,
-            w_down, num_experts=w_gate.shape[0])
+            w_down, num_experts=w_up.shape[0])
         group_sizes = jax.lax.dynamic_slice_in_dim(
             group_sizes, stack_at * num_experts, num_experts)
     chose = (expert_idx.astype(jnp.int32),) if choices else ()
@@ -1087,7 +1108,7 @@ def dropless_moe_ffn(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
         if scoring == "sigmoid" or held < num_experts:
             mine = group_sizes[:held].astype(jnp.float32)
             rows = mine.sum()
-            tiling = gmm_tiling(n * k, *w_gate.shape[1:], x.dtype.itemsize)
+            tiling = gmm_tiling(n * k, *w_up.shape[1:], x.dtype.itemsize)
             return (y, HeldStats(rows, mine.max() / jnp.maximum(mine.mean(),
                                                                 1e-9),
                                  rows / (n * k),

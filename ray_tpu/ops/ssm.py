@@ -468,6 +468,21 @@ def gated_rms_norm(o: jax.Array, z: jax.Array, scale: jax.Array,
     return y.reshape(o.shape)
 
 
+def gate_then_group_norm(y: jax.Array, z: jax.Array, scale: jax.Array,
+                         groups: int, eps: float, dtype) -> jax.Array:
+    """Mamba-2's gated output norm with the gate FIRST (the published
+    ``MambaRMSNormGated`` at ``norm_before_gate`` false: Falcon-H1's and
+    Nemotron-H's): RMSNorm of ``y * silu(z)`` over each of ``groups`` equal
+    runs of channels, under a weight as wide as all of them.  y (..., H, P)
+    and z (..., H P) float32, scale (H P,); ``dtype`` out.  Not
+    :func:`gated_rms_norm`, which norms a head's lanes and gates after."""
+    lead, d = y.shape[:-2], z.shape[-1]
+    g = y.reshape(*lead, d) * jax.nn.silu(z)
+    g = g.reshape(*lead, groups, d // groups)
+    g = g * lax.rsqrt((g * g).mean(-1, keepdims=True) + eps)
+    return (g.reshape(*lead, d) * scale).astype(dtype)
+
+
 # --------------------------------------------------------------------- scan
 def _grouped(a: jax.Array, groups: int) -> jax.Array:
     """(..., H, *rest) -> (..., G, H/G, *rest) on the axis after batch and

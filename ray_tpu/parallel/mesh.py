@@ -204,6 +204,20 @@ TRANSFORMER_RULES: Rules = [
     (r".*(gdn|attn)_blocks/shared/w_down/kernel$", P("pipeline", "tensor", "fsdp")),
     (r".*(gdn|attn)_blocks/(router|shared_gate)/kernel$", P("pipeline")),
     (r".*(gdn|attn)_blocks/\w+_norm/scale$",    P("pipeline")),
+    # stacked Nemotron-H blocks (models/nemotron_h.py: mamba_blocks,
+    # expert_blocks and attn_blocks, whose experts take the blocks/experts
+    # rules and whose attention the attn_blocks rules above): the mixer's
+    # heads and the conv's channels over ``tensor``, a head's A_log, D and
+    # dt_bias with it; the router, its bias and the norms are every shard's
+    (r".*mamba_blocks/in_proj/kernel$",         P("pipeline", "fsdp", "tensor")),
+    (r".*mamba_blocks/out_proj/kernel$",        P("pipeline", "tensor", "fsdp")),
+    (r".*mamba_blocks/conv/kernel$",            P("pipeline", None, "tensor")),
+    (r".*mamba_blocks/(conv/bias|ssm_norm/scale|A_log|D|dt_bias)$",
+     P("pipeline", "tensor")),
+    (r".*expert_blocks/shared/w_up/kernel$",    P("pipeline", "fsdp", "tensor")),
+    (r".*expert_blocks/shared/w_down/kernel$",  P("pipeline", "tensor", "fsdp")),
+    (r".*expert_blocks/router/(kernel|select_bias)$", P("pipeline")),
+    (r".*(mamba|expert|attn)_blocks/norm/scale$", P("pipeline")),
     # Non-stacked variants (single-layer modules, BERT/ResNet dense layers).
     (r".*attn_qkv/kernel$",         P("fsdp", None, "tensor")),
     (r".*attn_out/kernel$",         P("tensor", "fsdp")),

@@ -637,6 +637,77 @@ def test_a_whole_expert_matrix_beside_256_rows_fits_the_chip(
         [f"bf16[{m},{d}]", f"bf16[{held},{d},{f}]"]), text[:2000]
 
 
+def _relu2_experts_loss(x, w_router, w_up, w_down):
+    from ray_tpu.ops.moe import dropless_moe_ffn
+    y, _ = dropless_moe_ffn(
+        x, w_router, None, w_up, w_down, k=6, scoring="sigmoid",
+        select_bias=jnp.zeros((w_router.shape[-1],), jnp.float32),
+        weight_scale=2.5)
+    return y.astype(jnp.float32).sum()
+
+
+def test_the_two_matrix_experts_compile_at_nemotron_train_shape(v5e,
+                                                                monkeypatch):
+    """The Nemotron-H cell's expert layer (PR 73): 16,384 tokens, 6 of 128
+    experts each, 16 held, the two-matrix ``relu ** 2`` form at 2,688 <->
+    1,856.  No 128-lane tile divides 1,856 (29 x 64), so ``gmm_tiling``
+    takes it whole beside 384 of the 2,688 and 512 rows, and Mosaic takes
+    all of megablox's kernels at that tile: the up projection forward
+    (recomputed under the gradient it would be twice), the hidden rows'
+    and the rows' gradients, and the two ``tgmm``, each with the result
+    ``moe.relu2_expert_peak_share`` is keyed on; no ``ragged-dot``, and
+    the router picks its 6 in the kernel."""
+    import json
+    from pathlib import Path
+    from ray_tpu.ops import moe
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, d, f, width, held = 16384, 2688, 1856, 128, 16
+    assert moe.gmm_tiling(n * 6, d, f) == (512, 384, 1856)
+    assert moe.gmm_tiling(n * 6, f, d) == (512, 1856, 384)
+    bf = jnp.bfloat16
+    text = _compile(jax.value_and_grad(_relu2_experts_loss,
+                                       argnums=(0, 1, 2, 3)),
+                    v5e, ((n, d), bf), ((d, width), bf), ((held, d, f), bf),
+                    ((held, f, d), bf))
+    kernels = _kernel_names_and_results(text)
+    keyed = json.loads((Path(__file__).parent.parent / "perfbench"
+                        / "layer_metrics" / "moe.relu2_expert_peak_share.json"
+                        ).read_text())["params"]
+    experts = sorted(shape for name, shape in kernels
+                     if shape in keyed["shapes"]
+                     and any(part in name for part in keyed["names"]))
+    assert experts == sorted(
+        ["bf16[98304,1856]"] * 2 + ["bf16[98304,2688]"] * 2
+        + ["bf16[16,2688,1856]", "bf16[16,1856,2688]"]), kernels
+    assert [shape for name, shape in kernels
+            if name.startswith("router_choice")] == ["s32[8,16384]"]
+    assert "ragged-dot" not in text
+
+
+def _scan_loss(x, dt, a, b, c):
+    from ray_tpu.ops.ssm import ssd_scan
+    y, state = ssd_scan(x, dt, a, b, c, 128)
+    return y.sum() + state.sum()
+
+
+def test_the_scan_and_its_backward_compile_at_nemotron_train_shape(v5e):
+    """``ops/ssm.ssd_scan`` at the Nemotron-H cell's shape (PR 73: 2 x 8,192
+    positions, 64 heads of 64 on 8 groups of 128 state columns, chunks of
+    128) with its first backward: plain XLA, no kernel, and a layer's
+    forward and backward hold 2.2e9 B of temporaries (the (2, 64, 128, 128,
+    64) float32 decays and their product with the scores, which autodiff
+    keeps), which is what a block's recomputation has to fit beside the
+    state."""
+    f32, bf = jnp.float32, jnp.bfloat16
+    shapes = (((2, 8192, 64, 64), bf), ((2, 8192, 64), f32), ((64,), f32),
+              ((2, 8192, 8, 128), bf), ((2, 8192, 8, 128), bf))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
+    compiled = jax.jit(jax.grad(_scan_loss, argnums=(0, 1, 2, 3, 4))) \
+        .lower(*args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.6e9
+
+
 def _spread(x, order, held_rows):
     from ray_tpu.ops.moe import _spread_rows
     return _spread_rows(x, order, held_rows)
