@@ -216,9 +216,6 @@ def _mamba(u: jax.Array, lp: Params, cfg: NemotronHConfig):
     E), the layer's mean decay exp(dt A)), from a zero state.  The
     mixer's parts run under Falcon-H1's scope names (ssm_*)."""
     from ray_tpu.ops import ssm
-    B, T, _ = u.shape
-    H, P, G, N = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
-                  cfg.ssm_state)
     f32 = jnp.float32
     with jax.named_scope("ssm_in"):
         # z, the conv's channels and dt by column group: a slice of the
@@ -231,19 +228,17 @@ def _mamba(u: jax.Array, lp: Params, cfg: NemotronHConfig):
     with jax.named_scope("ssm_conv"):
         xbc = jax.nn.silu(ssm.causal_conv(xbc, lp["conv"]["kernel"],
                                           lp["conv"]["bias"])[0])
-        x = xbc[..., :cfg.d_ssm].reshape(B, T, H, P)
-        b = xbc[..., cfg.d_ssm:cfg.d_ssm + G * N].reshape(B, T, G, N)
-        c = xbc[..., cfg.d_ssm + G * N:].reshape(B, T, G, N)
     with jax.named_scope("ssm_scan"):
         dt = jax.nn.softplus(dt + lp["dt_bias"].astype(f32))
         a = -jnp.exp(lp["A_log"].astype(f32))
-        y, _ = ssm.ssd_scan(x, dt, a, b, c, cfg.ssm_chunk)
-        y = y + lp["D"].astype(f32)[:, None] * x
+        # x | B | C read where the conv left them, and D x added there
+        y = ssm.ssd_scan_train(xbc, dt, a, lp["D"], cfg.ssm_head_dim,
+                               cfg.ssm_groups, cfg.ssm_chunk)
         decay = jnp.exp(dt * a).mean()
     with jax.named_scope("ssm_norm"):
         g = ssm.gate_then_group_norm(y, z.astype(f32),
-                                     lp["ssm_norm"]["scale"], G, cfg.rms_eps,
-                                     cfg.dtype)
+                                     lp["ssm_norm"]["scale"], cfg.ssm_groups,
+                                     cfg.rms_eps, cfg.dtype)
     with jax.named_scope("ssm_out"):
         return g @ lp["out_proj"]["kernel"].astype(cfg.dtype), decay
 

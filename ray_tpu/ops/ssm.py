@@ -20,6 +20,23 @@ the activations are), and the matrix products ask for ``HIGHEST``: they
 are a small part of a block next to its projections, and a state that
 lives for a thousand tokens keeps what each step rounds away.
 
+Two forms of the chunked scan, at the same precision; which runs is read
+from the call, never set.  ``ssd_scan`` is the definition, plain XLA,
+for any shape: a state in, a state out, ``dt = 0`` padding, forward as a
+serving prefill runs it (Falcon-H1, MiniCPM-SALA) and under autodiff.
+``ssd_scan_train`` (PR 74) is a TRAINING step's scan: from a zero state,
+y alone with the skip ``D x``, differentiable, on a Mamba-2 mixer's conv
+output whole.  On a TPU, at chunks of 128, 128 state columns, a group's
+heads in whole 128-lane blocks and a whole number of chunks
+(``_scan_kernels_run``: the Nemotron-H cell) it is two Pallas kernels,
+``ssd_scan_fwd`` and a written-out ``ssd_scan_bwd`` under
+``jax.custom_vjp``, that keep a chunk's (Q, Q) decays and scores in VMEM
+and the state in scratch (the section "the scan of a training step"
+below); everywhere else (the CPU, the tests' narrow shapes, a ragged T)
+it is XLA's slices round ``ssd_scan(...)[0]`` under autodiff.  The two
+share the definition and no code: a backward walk that keeps entering
+states and a prefill that hands a state on want different things.
+
 Padding.  A serving prompt is padded up to its bucket, and for a
 recurrence the padding is poison: it would be folded into the state.
 Both sequence functions take what freezes it: ``ssd_scan`` a ``dt`` that
@@ -547,6 +564,399 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
                        precision=_HI) * jnp.exp(cum)[..., None]
     y = y.reshape(bsz, t + pad, h, p)[:, :t]
     return y, state.reshape(bsz, h, p, n)
+
+
+# ------------------------------ the scan of a training step, in two kernels
+# ``ssd_scan`` under autodiff writes a chunk's (Q, Q) decays and their
+# product with the scores for every head to HBM, keeps both for the
+# backward and multiplies them by the inputs as a batched product with the
+# head minor: 165 ms of the Nemotron-H cell's 725 ms step at 2% of the
+# chip's peak (ledger, PR 73).  A training step's scan starts from a zero
+# state, wants y alone and has a backward; on a TPU, at chunks of 128, 128
+# state columns, a group's heads in whole 128-lane blocks and a whole
+# number of chunks, two Pallas kernels do it instead, each under a name of
+# its own (``ssd_scan_fwd``, ``ssd_scan_bwd``).  They read x | B | C as
+# column blocks of the conv's ONE (B, T, x | B | C) float32 result: grid
+# (batch, group, step of ``SCAN_CHUNKS`` chunks), the step axis sequential,
+# the group's states side by side and transposed, (N, heads x P) float32,
+# in a VMEM scratch from the first chunk to the last.  A chunk: ``C . B^T``
+# once for the group's heads; a head's masked decay ``exp(cum_l - cum_s)``
+# made in VMEM and multiplied into the scores and ``dt_s``, then by x; the
+# entering states read by ``C`` and the chunk's addition ``B^T (x dt
+# exp(cum_Q - cum))`` for all the heads at once, 128 lanes a product.  A
+# head narrower than 128 lanes shares its block with its neighbours: its
+# products run on the block whole and a select keeps its lanes, which
+# costs the matrix unit nothing (a tile is 128 wide either way) and needs
+# no slice inside a tile.  Every product takes float32 operands at
+# ``HIGHEST`` and accumulates in float32, every exponent is <= 0: the
+# precision is ``ssd_scan``'s.  Of a chunk only y and the state it entered
+# with reach HBM ((B, G, chunks, N, heads x P) float32, 268 MB at the
+# cell's shape, alive for one layer at a time under a block's checkpoint).
+# The backward is written out: one walk from the last chunk to the first
+# with the states' cotangent in scratch; a chunk's decays and scores are
+# made again from x, B, C, the decays and the entering state; dB and dC are
+# summed over the group's heads in the kernel; ``D x`` is added where x is
+# loaded, so ``dD`` is the kernel's too.  XLA keeps the small things: ``dt
+# a``, its cumulative sum by chunk, laying dt and the sums out for the
+# kernels (a column and a row a head: (B, T, H) numbers) and the way back
+# through all that, and the one pass that lays dx | dB | dC side by side.
+# Everything else (the CPU, the tests' narrow shapes, a ragged T) takes
+# ``ssd_scan(...)[0]`` under autodiff, which is the definition.
+SCAN_CHUNK = 128        # positions a chunk: the kernels' only size
+# chunks a grid step works through, at most: the backward's step of two
+# took 6.80 ms a layer where one takes 6.01, the forward's 2.50 where one
+# takes 2.74, and either of four ran out of VMEM (my chip run, PR 74)
+SCAN_CHUNKS = {"ssd_scan_fwd": 2, "ssd_scan_bwd": 1}
+
+
+def _dot(a, b, contract):
+    """A float32 product at ``HIGHEST``, contracting axis ``contract[0]``
+    of ``a`` with ``contract[1]`` of ``b``."""
+    return lax.dot_general(a, b, (((contract[0],), (contract[1],)), ((), ())),
+                           precision=_HI, preferred_element_type=jnp.float32)
+
+
+def _block_heads(j, p):
+    """The heads of a group whose lanes lie in its 128-lane block ``j``, at
+    ``p`` lanes a head: (head, its lanes of the block as a (1, 128) mask |
+    None where the block is the head's own)."""
+    if p == 128:
+        return [(j, None)]
+    lane = lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    return [(j * (128 // p) + k, (lane >= k * p) & (lane < (k + 1) * p))
+            for k in range(128 // p)]
+
+
+def _over_lanes(values, heads):
+    """A number a head -> the block's 128 lanes: ``values[i]`` ((Q, 1) or
+    (1, 1)) laid over the lanes of ``heads[i]``."""
+    out = jnp.broadcast_to(values[0], (values[0].shape[0], 128))
+    for v, (_, mask) in zip(values[1:], heads[1:]):
+        out = jnp.where(mask, v, out)
+    return out
+
+
+def _head_sums(x, heads):
+    """A block (Q, 128) -> its sum over each head's lanes, [(Q, 1)]."""
+    return [jnp.sum(x if mask is None else jnp.where(mask, x, 0.0), axis=1,
+                    keepdims=True) for _, mask in heads]
+
+
+def _columns(rows_ref, ci, cols_scr):
+    """Chunk ``ci``'s rows (2 hg, Q) -> columns: ``cols_scr`` (Q, Q) holds
+    row r as its lane r afterwards.  One transpose of a whole tile, where
+    a column made from its row by a masked reduce is sixteen."""
+    cols_scr[0:rows_ref.shape[1], :] = rows_ref[ci]
+    cols_scr[...] = cols_scr[...].T
+
+
+def _head_decays(rows_ref, cols_scr, ci, h, q):
+    """Chunk ``ci``, head ``h`` of its group: dt and the cumulative
+    log-decay as columns (Q, 1) and as rows (1, Q), and what follows from
+    them: ``e = exp(cum)``, ``f = exp(cum_Q - cum)`` and ``w = dt f`` as
+    columns, ``keep = exp(cum_Q)`` (1, 1), and the masked (Q, Q) decay
+    ``exp(cum_l - cum_s)`` (s <= l, else 0)."""
+    hg = rows_ref.shape[1] // 2
+    l = lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    s = lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    dt_col, cum_col = cols_scr[:, h:h + 1], cols_scr[:, hg + h:hg + h + 1]
+    dt_row = rows_ref[ci, h:h + 1, :]
+    cum_row = rows_ref[ci, hg + h:hg + h + 1, :]
+    last = jnp.sum(jnp.where(s[:1] == q - 1, cum_row, 0.0), axis=1,
+                   keepdims=True)                             # (1, 1)
+    f_col = jnp.exp(last - cum_col)
+    decay = jnp.where(s <= l, jnp.exp(jnp.minimum(cum_col - cum_row, 0.0)),
+                      0.0)
+    return dict(dt_row=dt_row, e_col=jnp.exp(cum_col), f_col=f_col,
+                w_col=dt_col * f_col, keep=jnp.exp(last), decay=decay)
+
+
+def _scan_fwd_kernel(x_ref, b_ref, c_ref, rows_ref, d_ref, y_ref, states_ref,
+                     s_scr, cols_scr, *, m, q, p):
+    """Grid (batch, group, step): ``m`` chunks of a group's heads.  x_ref,
+    y_ref (m Q, hg P); b_ref, c_ref (m Q, N); rows_ref (m, 2 hg, Q) a
+    chunk's dt, a head a row, and then its cumulative log-decays; d_ref
+    (1, hg P) the skip ``D`` over its head's lanes; states_ref (m, N,
+    hg P) the state each chunk entered with, which ``s_scr`` carries."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_scr[...] = jnp.zeros_like(s_scr)
+
+    for ci in range(m):
+        at = pl.ds(ci * q, q)
+        _columns(rows_ref, ci, cols_scr)
+        bm, cm = b_ref[at, :], c_ref[at, :]
+        scores = _dot(cm, bm, (1, 1))                         # (Ql, Qs)
+        b_t = bm.T
+        for j in range(x_ref.shape[-1] // 128):
+            lanes = slice(128 * j, 128 * j + 128)
+            heads = _block_heads(j, p)
+            parts = [_head_decays(rows_ref, cols_scr, ci, h, q)
+                     for h, _ in heads]
+            x, state = x_ref[at, lanes], s_scr[:, lanes]
+            states_ref[ci, :, lanes] = state
+            y = _over_lanes([v["e_col"] for v in parts], heads) \
+                * _dot(cm, state, (1, 0)) + d_ref[:, lanes] * x
+            for v, (_, mask) in zip(parts, heads):
+                part = _dot(scores * v["decay"] * v["dt_row"], x, (1, 0))
+                y = y + (part if mask is None else jnp.where(mask, part, 0.0))
+            y_ref[at, lanes] = y
+            s_scr[:, lanes] = \
+                _over_lanes([v["keep"] for v in parts], heads) * state \
+                + _dot(b_t, x * _over_lanes([v["w_col"] for v in parts],
+                                            heads), (1, 0))
+
+
+def _scan_bwd_kernel(x_ref, b_ref, c_ref, rows_ref, d_ref, states_ref, dy_ref,
+                     dx_ref, db_ref, dc_ref, drows_ref, dd_ref, ds_scr,
+                     cols_scr, dcols_scr, *, m, q, p):
+    """The forward's grid with the steps, and the chunks of a step, from
+    the last to the first: ``ds_scr`` carries the cotangent of the group's
+    states.  dx_ref like x_ref, db_ref, dc_ref like b_ref, drows_ref like
+    rows_ref (what reaches a position as a column is gathered in
+    ``dcols_scr`` and turned once a chunk); dd_ref (8, hg P) float32, the
+    same block through a group's steps: ``dD``'s terms summed by
+    sublane."""
+    from jax.experimental import pallas as pl
+    hg = rows_ref.shape[1] // 2
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_scr[...] = jnp.zeros_like(ds_scr)
+        dd_ref[...] = jnp.zeros_like(dd_ref)
+
+    at_end = lax.broadcasted_iota(jnp.int32, (q, 1), 0) == q - 1
+    for ci in reversed(range(m)):
+        at = pl.ds(ci * q, q)
+        _columns(rows_ref, ci, cols_scr)
+        bm, cm = b_ref[at, :], c_ref[at, :]
+        scores = _dot(cm, bm, (1, 1))
+        c_t = cm.T
+        dscores = jnp.zeros((q, q), jnp.float32)
+        db = jnp.zeros(bm.shape, jnp.float32)
+        dc = jnp.zeros(cm.shape, jnp.float32)
+        for j in range(x_ref.shape[-1] // 128):
+            lanes = slice(128 * j, 128 * j + 128)
+            heads = _block_heads(j, p)
+            parts = [_head_decays(rows_ref, cols_scr, ci, h, q)
+                     for h, _ in heads]
+            over = lambda name: _over_lanes([v[name] for v in parts], heads)
+            x, dy = x_ref[at, lanes], dy_ref[at, lanes]
+            state, ds = states_ref[ci, :, lanes], ds_scr[:, lanes]
+            # y = e (C S) + D x + ...;  S' = keep S + B^T (x w)
+            w = over("w_col")
+            dye, xw = dy * over("e_col"), x * w
+            to_xw = _dot(bm, ds, (1, 0))                      # (Q, 128)
+            dx = w * to_xw + d_ref[:, lanes] * dy
+            de = _head_sums(dy * _dot(cm, state, (1, 0)), heads)
+            dw = _head_sums(x * to_xw, heads)
+            dkeep = _head_sums(jnp.sum(state * ds, axis=0, keepdims=True),
+                               heads)
+            dc = dc + _dot(dye, state, (1, 1))
+            db = db + _dot(xw, ds, (1, 1))
+            ds_scr[:, lanes] = over("keep") * ds + _dot(c_t, dye, (1, 0))
+            by_sublane = dy * x
+            dd_ref[:, lanes] += sum(by_sublane[8 * i:8 * i + 8]
+                                    for i in range(q // 8))
+            for v, (h, mask), de_h, dw_h, dkeep_h in zip(parts, heads, de, dw,
+                                                         dkeep):
+                # y_h += (scores decay dt_s) x_h
+                dy_h = dy if mask is None else jnp.where(mask, dy, 0.0)
+                weights = v["decay"] * v["dt_row"]
+                dx = dx + _dot(scores * weights, dy_h, (0, 0))
+                dmat = _dot(dy_h, x, (1, 1))                  # (Ql, Qs)
+                dscores = dscores + dmat * weights
+                through = dmat * scores * v["decay"]
+                ddt_row = jnp.sum(through, axis=0, keepdims=True)
+                dlast = jnp.sum(dw_h * v["w_col"], axis=0, keepdims=True) \
+                    + v["keep"] * dkeep_h                     # (1, 1)
+                drows_ref[ci, h:h + 1, :] = ddt_row
+                drows_ref[ci, hg + h:hg + h + 1, :] = -v["dt_row"] * ddt_row
+                dcols_scr[:, h:h + 1] = dw_h * v["f_col"]
+                dcols_scr[:, hg + h:hg + h + 1] = \
+                    jnp.sum(through * v["dt_row"], axis=1, keepdims=True) \
+                    + v["e_col"] * de_h - dw_h * v["w_col"] \
+                    + jnp.where(at_end, dlast, 0.0)
+            dx_ref[at, lanes] = dx
+        drows_ref[ci] += dcols_scr[...].T[:2 * hg]
+        db_ref[at, :] = db + _dot(dscores, cm, (0, 0))
+        dc_ref[at, :] = dc + _dot(dscores, bm, (1, 0))
+
+
+@functools.lru_cache(maxsize=None)
+def _scan_kernel(name, B, T, H, P, G, N, m, interpret):
+    """``ssd_scan_fwd`` (xbc x 3, rows, d -> y, states) or ``ssd_scan_bwd``
+    (the same, states, dy -> dx, dB, dC, drows, dD's sums) at one shape:
+    grid (batch, group, step of ``m`` chunks), x, B and C column blocks of
+    the one (B, T, H P + 2 G N) array.  The ``pallas_call`` stands inside
+    a jitted function of the kernel's name, after which the v5e's trace
+    names it in a step (``ops/delta_rule._kernel``); kept, so that a
+    step's four layers and three passes trace each body once."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    back = name == "ssd_scan_bwd"
+    q, hg, f32 = SCAN_CHUNK, H // G, jnp.float32
+    wg, steps = hg * P, T // (SCAN_CHUNK * m)
+    at = (lambda n: steps - 1 - n) if back else (lambda n: n)
+
+    def by_time(lanes, width, first=0):
+        """(B, T, ``lanes``), a group's ``width`` lanes a block, group 0's
+        the block ``first``."""
+        return (jax.ShapeDtypeStruct((B, T, lanes), f32),
+                pl.BlockSpec((None, m * q, width),
+                             lambda b, g, n: (b, at(n), first + g)))
+
+    def by_chunk(*shape):
+        """(B, G, chunks, ...): ``m`` chunks of a group a block."""
+        rest = (0,) * len(shape)
+        return (jax.ShapeDtypeStruct((B, G, T // q, *shape), f32),
+                pl.BlockSpec((None, None, m, *shape),
+                             lambda b, g, n: (b, g, at(n)) + rest))
+    w = H * P + 2 * G * N
+    of = dict(x=by_time(w, wg), b=by_time(w, N, H * P // N),
+              c=by_time(w, N, (H * P + G * N) // N),
+              y=by_time(H * P, wg), bc=by_time(G * N, N),
+              rows=by_chunk(2 * hg, q), states=by_chunk(N, wg),
+              d=(jax.ShapeDtypeStruct((G, 1, wg), f32),
+                 pl.BlockSpec((None, 1, wg), lambda b, g, n: (g, 0, 0))),
+              dd=(jax.ShapeDtypeStruct((B, G, 8, wg), f32),
+                  pl.BlockSpec((None, None, 8, wg),
+                               lambda b, g, n: (b, g, 0, 0))))
+    operands = ["x", "b", "c", "rows", "d"] + ["states", "y"] * back
+    results = ["y", "bc", "bc", "rows", "dd"] if back else ["y", "states"]
+    body = _scan_bwd_kernel if back else _scan_fwd_kernel
+
+    def run(*args):
+        return pl.pallas_call(
+            functools.partial(body, m=m, q=q, p=P),
+            grid=(B, G, steps),
+            in_specs=[of[x][1] for x in operands],
+            out_specs=[of[x][1] for x in results],
+            out_shape=[of[x][0] for x in results],
+            # the carried states (or their cotangent); columns in and out
+            scratch_shapes=[pltpu.VMEM((N, wg), f32)]
+            + [pltpu.VMEM((q, q), f32)] * (2 if back else 1),
+            compiler_params=pltpu.CompilerParams(dimension_semantics=(
+                "parallel", "parallel", "arbitrary")),
+            interpret=interpret, name=name)(*args)
+    run.__name__ = run.__qualname__ = name
+    return jax.jit(run)
+
+
+def _scan_call(name, xbc, rows, d, interpret, *more):
+    """Kernel ``name`` at the shape of xbc (B, T, H P + 2 G N), rows (B, G,
+    chunks, 2 H / G, Q) and d (G, 1, H P / G), on them and ``more``."""
+    (B, T, w), (G, chunks), wg = xbc.shape, rows.shape[1:3], d.shape[-1]
+    hg = rows.shape[3] // 2
+    m = max(i for i in range(1, SCAN_CHUNKS[name] + 1) if chunks % i == 0)
+    return _scan_kernel(name, B, T, G * hg, wg // hg, G,
+                        (w - G * wg) // (2 * G), m, interpret)(
+        xbc, xbc, xbc, rows, d, *more)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _scan_kernels(xbc, rows, d, interpret):
+    """The chunks' work in the kernels: xbc (B, T, H P + 2 G N) float32,
+    x | B | C; rows (B, G, chunks, 2 H / G, Q) a chunk's dt, a head a
+    row, and then its cumulative log-decays; d (G, 1, H P / G) -> y + D x
+    (B, T, H P), all float32."""
+    return _scan_kernels_fwd(xbc, rows, d, interpret)[0]
+
+
+def _scan_kernels_fwd(xbc, rows, d, interpret):
+    """One kernel whether differentiated or not, so that a step is built
+    from one trace of it: a call that is not writes the entering states
+    and drops them."""
+    y, states = _scan_call("ssd_scan_fwd", xbc, rows, d, interpret)
+    return y, (xbc, rows, d, states)
+
+
+def _scan_kernels_bwd(interpret, res, dy):
+    xbc, rows, d, states = res
+    dx, db, dc, drows, dd = _scan_call("ssd_scan_bwd", xbc, rows, d,
+                                       interpret, states, dy)
+    # the conv's cotangent, dx | dB | dC side by side: each where it lies in
+    # a zero (B, T, W) and the three summed, which XLA makes one fusion of
+    # (a concatenate became three passes: ``delta_rule._chunks_bwd``)
+    w, at = xbc.shape[-1], 0
+    dxbc = 0.0
+    for part in (dx, db, dc):
+        dxbc = dxbc + jnp.pad(part, ((0, 0), (0, 0),
+                                     (at, w - at - part.shape[-1])))
+        at += part.shape[-1]
+    return dxbc, drows, dd.sum((0, 2))[:, None]
+
+
+_scan_kernels.defvjp(_scan_kernels_fwd, _scan_kernels_bwd)
+
+
+def ssd_scan_kernels(xbc: jax.Array, dt: jax.Array, a: jax.Array,
+                     d: jax.Array, head_dim: int, groups: int,
+                     interpret: bool = False) -> jax.Array:
+    """:func:`ssd_scan_train` in the kernels, whatever the backend (a
+    shape ``_scan_kernels_run`` takes).  XLA lays dt out, a head a row,
+    and makes the cumulative log-decays a chunk: as a product with the
+    (Q, Q) triangle of ones at ``HIGHEST`` (a product with 1 is exact),
+    because a ``cumsum`` along 128 lanes is a reduce-window on the TPU
+    and its reverse four times that: 0.38 and 1.62 ms a layer (my chip
+    run, PR 74)."""
+    f32, q = jnp.float32, SCAN_CHUNK
+    (B, T, _), H, G = xbc.shape, dt.shape[-1], groups
+    # (B, T, H) -> (B, G, chunks, H / G, Q)
+    dt = dt.astype(f32).reshape(B, T // q, q, G, H // G) \
+        .transpose(0, 3, 1, 4, 2)
+    up_to = (jnp.arange(q)[:, None] <= jnp.arange(q)[None, :]).astype(f32)
+    cum = jnp.matmul(dt * a.astype(f32).reshape(G, 1, H // G, 1), up_to,
+                     precision=_HI)
+    y = _scan_kernels(
+        xbc.astype(f32), jnp.concatenate([dt, cum], axis=3),
+        jnp.repeat(d.astype(f32).reshape(G, 1, H // G), head_dim, axis=2),
+        interpret)
+    return y.reshape(B, T, H, head_dim)
+
+
+def _scan_kernels_run(xbc, heads: int, head_dim: int, groups: int,
+                      chunk: int) -> bool:
+    """Whether a training call's scan runs in the kernels: on a TPU, the
+    conv's float32 result, chunks of 128, 128 state columns, heads of 128
+    lanes or of a whole share of them, a group's heads in whole 128-lane
+    blocks, a whole number of chunks.  Everything else (the CPU, the
+    tests' narrow shapes, a ragged T) takes the XLA form."""
+    state = (xbc.shape[2] - heads * head_dim) // (2 * groups)
+    return (jax.default_backend() == "tpu" and xbc.dtype == jnp.float32
+            and chunk == SCAN_CHUNK and state == 128
+            and 128 % head_dim == 0 and heads % groups == 0
+            and heads // groups * head_dim % 128 == 0
+            and xbc.shape[1] % SCAN_CHUNK == 0)
+
+
+def ssd_scan_train(xbc: jax.Array, dt: jax.Array, a: jax.Array, d: jax.Array,
+                   head_dim: int, groups: int, chunk: int) -> jax.Array:
+    """The recurrence over a training step's sequences, from a zero state,
+    with the skip: ``ssd_scan(x, dt, a, B, C, chunk)[0] + D x`` of a
+    Mamba-2 mixer's conv output, whole: xbc (B, T, H P + 2 G N), the H
+    heads' x, the G groups' B and the G groups' C side by side; dt (B, T,
+    H) after the softplus; a, d (H,).  Returns y (B, T, H, P) float32;
+    differentiable.  Where the kernels run they read x, B and C out of
+    ``xbc`` by column blocks, so no slice of it is made, and the backward
+    writes the cotangent of ``xbc``; elsewhere the slices are XLA's, round
+    :func:`ssd_scan` under autodiff.  Which runs is read from the call
+    (``_scan_kernels_run``)."""
+    if _scan_kernels_run(xbc, dt.shape[-1], head_dim, groups, chunk):
+        return ssd_scan_kernels(xbc, dt, a, d, head_dim, groups)
+    return _scan_train_xla(xbc, dt, a, d, head_dim, groups, chunk)
+
+
+def _scan_train_xla(xbc, dt, a, d, head_dim, groups, chunk):
+    """:func:`ssd_scan_train` as XLA's slices round :func:`ssd_scan`."""
+    (B, T, w), H, P, G = xbc.shape, dt.shape[-1], head_dim, groups
+    n = (w - H * P) // (2 * G)
+    x = xbc[..., :H * P].reshape(B, T, H, P)
+    y, _ = ssd_scan(x, dt, a, xbc[..., H * P:H * P + G * n].reshape(
+        B, T, G, n), xbc[..., H * P + G * n:].reshape(B, T, G, n), chunk)
+    return y + d.astype(jnp.float32)[:, None] * x
 
 
 def ssm_step(state: jax.Array, x: jax.Array, dt: jax.Array, a: jax.Array,

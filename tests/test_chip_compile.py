@@ -708,6 +708,47 @@ def test_the_scan_and_its_backward_compile_at_nemotron_train_shape(v5e):
     assert compiled.memory_analysis().temp_size_in_bytes < 2.6e9
 
 
+def _train_scan_loss(xbc, dt, a, d):
+    """The scan as ``models/nemotron_h._mamba`` calls it, on the conv's
+    output whole, under the scope ``ssm.train_scan_ms`` reads."""
+    from ray_tpu.ops.ssm import ssd_scan_train
+    with jax.named_scope("ssm_scan"):
+        return ssd_scan_train(xbc, dt, a, d, 64, 8, 128).sum()
+
+
+def test_the_training_scans_kernels_compile_at_nemotron_train_shape(
+        v5e, monkeypatch):
+    """``ops/ssm.ssd_scan_train`` and its backward at the Nemotron-H cell's
+    shape (PR 74: the conv's (2, 8192, 6144) float32 result whole, 64
+    heads of 64 on 8 groups of 128 state columns, chunks of 128), on a
+    TPU: two Mosaic kernels under their own names, both attributed to
+    ``ssm_scan``, each within the default scoped VMEM (no limit is
+    raised); no scan of XLA's is left and nothing (Q, Q) a head reaches
+    HBM; the temporaries, the 268 MB of entering states among them, stay
+    far under the 2.2e9 B that ``ssd_scan`` under autodiff holds (the test
+    above)."""
+    from ray_tpu.ops import ssm
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    f32 = jnp.float32
+    shapes = (((2, 8192, 6144), f32), ((2, 8192, 64), f32), ((64,), f32),
+              ((64,), f32))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
+    assert ssm._scan_kernels_run(args[0], 64, 64, 8, 128)
+    compiled = jax.jit(jax.grad(_train_scan_loss, argnums=(0, 1, 2, 3))) \
+        .lower(*args).compile()
+    text = compiled.as_text()
+    kernels = _kernel_names_and_results(text)
+    assert sorted((name.split(".")[0], shape) for name, shape in kernels) == [
+        ("ssd_scan_bwd", "f32[2,8192,4096]"),
+        ("ssd_scan_fwd", "f32[2,8192,4096]")], kernels
+    under = dict(_under_scope(text, "ssm_scan"))
+    assert all(name in under for name, _ in kernels), sorted(under)
+    assert " while(" not in text
+    assert not re.search(r"f32\[[\d,]*,128,128,64\]", text)    # the decays
+    assert "f32[2,8,64,128,512]" in text                # the entering states
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.8e9
+
+
 def _spread(x, order, held_rows):
     from ray_tpu.ops.moe import _spread_rows
     return _spread_rows(x, order, held_rows)

@@ -193,6 +193,54 @@ def test_a_later_token_changes_no_earlier_logit(params, batch,
     assert np.abs(got[:, at:] - program_logits[:, at:]).max() > 1e-3
 
 
+def test_the_mixer_through_the_scans_kernels_equals_the_reference(
+        monkeypatch):
+    """On the CPU the model takes ``ssd_scan`` under autodiff; here
+    ``_mamba`` runs the training scan's two Pallas kernels (PR 74) in
+    interpret mode at a shape their rule takes (4 heads of 64 on 2 groups
+    of 128 state columns, 2 chunks of 128) and is held to the reference's
+    token-by-token mixer: values, and the gradients of the hidden states
+    and of every leaf of the layer."""
+    from ray_tpu.ops import ssm
+    cfg = dataclasses.replace(
+        CFG, pattern="M", n_embd=32, ssm_heads=4, ssm_head_dim=64,
+        ssm_groups=2, ssm_state=128, ssm_chunk=128)
+    tree = random_tree(cfg)["mamba_blocks"]
+    lp = jax.tree_util.tree_map(lambda a: a[0], tree)
+    u = jax.random.normal(jax.random.key(5), (2, 256, cfg.n_embd))
+    probe = jax.random.normal(jax.random.key(6), u.shape)
+    ran, kernels = [], ssm.ssd_scan_kernels
+
+    def interpreted(xbc, *rest):
+        ran.append(xbc.shape)
+        return kernels(xbc, *rest, interpret=True)
+    monkeypatch.setattr(ssm, "_scan_kernels_run", lambda *a: True)
+    monkeypatch.setattr(ssm, "ssd_scan_kernels", interpreted)
+
+    def ours(u, lp):
+        return (nh._mamba(u, lp, cfg)[0] * probe).sum()
+
+    def theirs(u, lp):
+        mixed = jnp.stack([ref.mamba_mixer(
+            row, lp, heads=4, head_dim=64, groups=2, state=128,
+            eps=cfg.rms_eps, chunk=128)[0] for row in u])
+        return (mixed * probe).sum()
+    with jax.default_matmul_precision("highest"):
+        got_v, got = jax.value_and_grad(ours, (0, 1))(u, lp)
+        want_v, want = jax.value_and_grad(theirs, (0, 1))(u, lp)
+    assert ran == [(2, 256, 4 * 64 + 2 * 2 * 128)]
+    assert float(got_v) == pytest.approx(float(want_v), rel=1e-4)
+    got, want = _flat(got), _flat(want)
+    assert set(got) == set(want) and len(got) == 10
+    for key, w in want.items():
+        scale = np.abs(w).max()
+        if "['norm']" in key:           # the block's, applied before it
+            assert scale == 0 and np.abs(got[key]).max() == 0
+            continue
+        assert scale > 0, key
+        assert np.abs(got[key] - w).max() < 2e-4 * scale, key
+
+
 # -------------------------------------------------------------- the pattern
 def test_the_cells_pattern_builds_its_blocks_in_that_order():
     cfg = nh.tiny()

@@ -2,9 +2,13 @@
 ``relu ** 2`` form of ``ops/moe.dropless_experts`` (what training
 Nemotron-H forced), on the CPU at small sizes: ``jax.grad`` through the
 chunked scan against ``jax.grad`` through the token-by-token recurrence,
-for every operand and the entering state; the experts against a loop over
-tokens and picks, forward and gradients, with all experts held and with a
-share.  The SwiGLU form's own cases are tests/test_moe_combine.py's."""
+for every operand and the entering state; the training step's pair of
+Pallas kernels (``ssd_scan_train``, PR 74) in interpret mode against both;
+the experts against a loop over tokens and picks, forward and gradients,
+with all experts held and with a share.  The SwiGLU form's own cases are
+tests/test_moe_combine.py's."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -111,6 +115,131 @@ def test_a_sequence_in_two_calls_has_the_whole_sequences_gradient(operands):
                                 tuple(range(6))))(*args)
     for g, w in zip(got, want):
         assert np.abs(g - w).max() < 1e-4 * np.abs(w).max()
+
+
+# ------------------------------------------ the training step's two kernels
+# 4 chunks of 128 in 2 grid steps, 2 groups of 2 heads that share B and C
+# (the ratio ``recurrence`` reads from this module), 128 state columns
+KB, KT, KH, KG, KN = 2, 512, 4, 2, 128
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_operands(p):
+    keys = jax.random.split(jax.random.key(p), 6)
+    return {"xbc": jax.random.normal(keys[0], (KB, KT, KH * p + 2 * KG * KN)),
+            "dt": jax.nn.softplus(jax.random.normal(keys[1], (KB, KT, KH))
+                                  - 1.0),
+            "a": -jnp.exp(jax.random.normal(keys[2], (KH,))),
+            "d": jax.random.normal(keys[3], (KH,)),
+            "probe": jax.random.normal(keys[4], (KB, KT, KH, p))}
+
+
+def _apart(xbc, p, n=KN):
+    """The conv's x | B | C -> x (B, T, H, P), B and C (B, T, G, N)."""
+    lead = xbc.shape[:2]
+    return (xbc[..., :KH * p].reshape(*lead, KH, p),
+            xbc[..., KH * p:KH * p + KG * n].reshape(*lead, KG, n),
+            xbc[..., KH * p + KG * n:].reshape(*lead, KG, n))
+
+
+def _by_the_scan(xbc, dt, a, d, p):
+    x, b, c = _apart(xbc, p)
+    return ssm.ssd_scan(x, dt, a, b, c, ssm.SCAN_CHUNK)[0] + d[:, None] * x
+
+
+def _by_the_recurrence(xbc, dt, a, d, p):
+    x, b, c = _apart(xbc, p)
+    zero = jnp.zeros((KB, KH, p, KN))
+    return recurrence(x, dt, a, b, c, zero)[0] + d[:, None] * x
+
+
+@pytest.mark.parametrize("p", [
+    pytest.param(64, id="two_heads_share_a_lane_block"),
+    pytest.param(128, id="a_head_a_lane_block")])
+@pytest.mark.parametrize("definition", [_by_the_scan, _by_the_recurrence])
+def test_the_training_kernels_equal_the_scan_and_the_recurrence(definition,
+                                                                p):
+    """``ssd_scan_fwd`` and the written-out ``ssd_scan_bwd`` in interpret
+    mode: y, and the gradients of x, B and C (as the cotangent of the
+    conv's one array), dt, a and D, float32."""
+    ops = _kernel_operands(p)
+    args = [ops[k] for k in ("xbc", "dt", "a", "d")]
+    # two chunks a grid step forward, one backward: two steps and four
+    assert ssm.SCAN_CHUNKS == {"ssd_scan_fwd": 2, "ssd_scan_bwd": 1}
+
+    def loss(form):
+        return lambda *args: (form(*args) * ops["probe"]).sum()
+    kernels = functools.partial(ssm.ssd_scan_kernels, head_dim=p, groups=KG,
+                                interpret=True)
+    with jax.default_matmul_precision("highest"):
+        got_v, got = jax.jit(jax.value_and_grad(loss(kernels),
+                                                (0, 1, 2, 3)))(*args)
+        want_v, want = jax.jit(jax.value_and_grad(
+            loss(functools.partial(definition, p=p)), (0, 1, 2, 3)))(*args)
+        y, want_y = kernels(*args), definition(*args, p=p)
+    # log-decays down to -125 a chunk: how a chunk's sums are added up (a
+    # product with the triangle of ones here, ``cumsum`` there) shows
+    assert float(got_v) == pytest.approx(float(want_v), rel=1e-4)
+    np.testing.assert_allclose(y, want_y, atol=1e-4 * np.abs(want_y).max())
+    gx, gb, gc = _apart(got[0], p)
+    wx, wb, wc = _apart(want[0], p)
+    for name, g, w in zip(("x", "b", "c", "dt", "a", "d"),
+                          (gx, gb, gc, *got[1:]), (wx, wb, wc, *want[1:])):
+        scale = np.abs(w).max()
+        assert scale > 0, name
+        assert np.abs(g - w).max() < 1e-4 * scale, name
+
+
+def test_the_chunks_a_grid_step_takes_change_no_number(monkeypatch):
+    """``SCAN_CHUNKS`` is how the walk is cut into grid steps, forward
+    and backward: one chunk a step and two give the same y and the same
+    gradients (the backward's step of two walks its chunks from the
+    second to the first)."""
+    p = 64
+    ops = _kernel_operands(p)
+    args = [ops[k] for k in ("xbc", "dt", "a", "d")]
+
+    def both():
+        kernels = functools.partial(ssm.ssd_scan_kernels, head_dim=p,
+                                    groups=KG, interpret=True)
+        return jax.value_and_grad(
+            lambda *args: (kernels(*args) * ops["probe"]).sum(),
+            (0, 1, 2, 3))(*args)
+    monkeypatch.setattr(ssm, "SCAN_CHUNKS",
+                        {"ssd_scan_fwd": 1, "ssd_scan_bwd": 2})
+    got_v, got = both()
+    monkeypatch.undo()
+    want_v, want = both()
+    assert float(got_v) == pytest.approx(float(want_v), rel=1e-6)
+    for g, w in zip(got, want):
+        assert np.abs(g - w).max() < 1e-5 * np.abs(w).max()
+
+
+@pytest.mark.parametrize("t,n,runs", [
+    pytest.param(512, 128, True, id="whole_chunks_of_128_columns"),
+    pytest.param(500, 128, False, id="ragged_t"),
+    pytest.param(512, 64, False, id="narrow_state")])
+def test_a_shape_the_kernels_rule_refuses_takes_the_scan(monkeypatch, t, n,
+                                                         runs):
+    """Which form a training call takes is read from the call: on a TPU,
+    and at a shape the kernels' rule takes; everything else is
+    ``ssd_scan`` under autodiff, with the same numbers."""
+    p = 64
+    ops = _kernel_operands(p)
+    xbc = ops["xbc"][:, :t, :KH * p + 2 * KG * n]
+    dt = ops["dt"][:, :t]
+    assert not ssm._scan_kernels_run(xbc, KH, p, KG, 128)   # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ssm._scan_kernels_run(xbc, KH, p, KG, 128) == runs
+    assert not ssm._scan_kernels_run(xbc, KH, p, KG, 64)
+    assert not ssm._scan_kernels_run(xbc.astype(jnp.bfloat16), KH, p, KG, 128)
+    if runs:
+        return
+    x, b, c = _apart(xbc, p, n)
+    want = ssm.ssd_scan(x, dt, ops["a"], b, c, 128)[0] \
+        + ops["d"][:, None] * x
+    got = ssm.ssd_scan_train(xbc, dt, ops["a"], ops["d"], p, KG, 128)
+    np.testing.assert_array_equal(got, want)
 
 
 # --------------------------------------------- the two-matrix relu^2 experts
